@@ -1,0 +1,979 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process drives the two main paths once, through the entry points a user
+calls, at the full width of the models the bench uses (weights random, from
+a seed), and checks what comes out by the repo's own means:
+
+  0 device   the chip is there and is the default context; block_until_ready
+             is a real barrier; what one tiny dispatch costs
+  1 train    ResNet-50, 224², batch 256, bf16, parallel.SPMDTrainer on a
+             one-device mesh — the bench's path
+  2 fit      the same network through mx.mod.Module(context=mx.tpu(0)).fit
+             on a synthetic iterator, f32, batch 32 — the user's path
+  3 serve    Transformer-base through serving.PagedKVDecoder (8 lanes x 1024
+             slots): greedy tokens against a full re-forward on the device;
+             then prefix cache + K-token megasteps
+  4 kernels  every Pallas kernel a gate or pattern can reach, compiled by
+             Mosaic, forward and backward, against its XLA reference; then a
+             ResNet-50 step and a transformer step with the kernels forced
+  5 4 chips  (when >= 4 devices) phase 1 on a {"data": 4} mesh, Module on
+             four contexts, ring attention on {"data": 2, "seq": 2}
+
+Every phase prints PASS, FAIL or SKIP <reason>; a skip is never the result
+of an exception. Any FAIL makes the exit code 1. With no TPU the script
+exits 2 before running anything and prints no result line. The last line of
+stdout on a run of all phases is one JSON object:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+``--phases 0,3`` runs a subset (the JSON then also carries "phases").
+``--rehearse-cpu`` is for debugging this script in a sandbox without a chip:
+tiny sizes, Pallas in interpret mode, loudly labelled, no result line.
+"""
+import argparse
+import contextlib
+import gc
+import json
+import os
+import shutil
+import sys
+import time
+import traceback
+
+REHEARSE = "--rehearse-cpu" in sys.argv
+if REHEARSE:
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                               " --xla_force_host_platform_device_count=4")
+
+import jax  # noqa: E402
+
+DEV = jax.devices()[0]
+if DEV.platform != "tpu" and not REHEARSE:
+    sys.stderr.write(
+        "chip_smoke: JAX found no TPU (platform=%s); this script runs on the "
+        "chip only — no result.\n" % DEV.platform)
+    sys.exit(2)
+
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+import mxnet_tpu as mx  # noqa: E402  (sets the compile-cache directory)
+from mxnet_tpu import models, parallel, telemetry  # noqa: E402
+
+# full sizes are the contract; the rehearsal sizes only debug the script
+if not REHEARSE:
+    SZ = dict(
+        image=224, classes=1000, train_batch=256, train_dtype="bfloat16",
+        fit_batch=32, fit_batches=8,
+        tf=dict(vocab_size=32000, num_layers=6, num_heads=8, model_dim=512,
+                ffn_dim=2048),
+        lanes=8, slots=1024, page=16, prompts=(5, 17, 40, 64, 100),
+        new_tokens=33, mega_k=4, shared_prefix=48,
+        tf_train_batch=8, tf_train_seq=512,
+        attn=(8, 8, 512, 64), mba=(4096, 512, 2048), ln=(4096, 512),
+        conv_batch=256,
+        conv_sites=[((1, 1), (1, 1), 128, 512, 28, True),
+                    ((3, 3), (1, 1), 128, 128, 28, False)],
+        matmul_n=8192,
+    )
+else:
+    SZ = dict(
+        image=32, classes=16, train_batch=8, train_dtype=None,
+        fit_batch=4, fit_batches=6,
+        tf=dict(vocab_size=64, num_layers=2, num_heads=2, model_dim=128,
+                ffn_dim=256),
+        lanes=4, slots=64, page=8, prompts=(3, 9, 14), new_tokens=9,
+        mega_k=4, shared_prefix=16,
+        tf_train_batch=2, tf_train_seq=16,
+        attn=(1, 2, 16, 8), mba=(16, 16, 128), ln=(16, 128),
+        conv_batch=2,
+        conv_sites=[((1, 1), (1, 1), 8, 16, 8, True),
+                    ((3, 3), (1, 1), 8, 8, 8, False)],
+        matmul_n=256,
+    )
+
+
+# ------------------------------------------------------------ measuring aids
+class Compiles:
+    """Every XLA compile request this process makes (a persistent-cache hit
+    still counts as a request; its seconds are then the load time)."""
+
+    def __init__(self):
+        self.n = self.hits = self.misses = 0
+        self.seconds = 0.0
+        jax.monitoring.register_event_duration_secs_listener(self._dur)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _dur(self, name, secs, **_):
+        if name == "/jax/core/compile/backend_compile_duration":
+            self.n += 1
+            self.seconds += secs
+
+    def _event(self, name, **_):
+        if name == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif name == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def snap(self):
+        return (self.n, self.seconds, self.hits, self.misses)
+
+
+COMPILES = Compiles()
+
+
+def say(msg=""):
+    print(msg, flush=True)
+
+
+def check(cond, what):
+    if not cond:
+        raise AssertionError(what)
+    say("    ok: %s" % what)
+
+
+def on_tpu(arr):
+    """Whether a jax array (or NDArray) lives on the accelerator."""
+    data = arr._jax() if hasattr(arr, "_jax") else arr
+    want = "cpu" if REHEARSE else "tpu"
+    return all(d.platform == want for d in data.devices())
+
+
+def peak_gb():
+    stats = DEV.memory_stats() or {}
+    return stats.get("peak_bytes_in_use", 0) / 2 ** 30
+
+
+def rel_l2(a, b):
+    a = np.asarray(a, np.float32)
+    b = np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / (np.linalg.norm(b) + 1e-12))
+
+
+def counters_since(before):
+    now = telemetry.counters()
+    return {k: v - before.get(k, 0) for k, v in now.items()
+            if v != before.get(k, 0)}
+
+
+@contextlib.contextmanager
+def environ(**env):
+    """Set environment variables for a block (the fusion gates read them at
+    plan/trace time), then put back what was there."""
+    saved = {k: os.environ.get(k) for k in env}
+    os.environ.update(env)
+    try:
+        yield
+    finally:
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+
+
+# ------------------------------------------------------------------ phase 0
+def phase_device():
+    import jaxlib
+
+    say("    platform=%s device_kind=\"%s\" count=%d"
+        % (DEV.platform, DEV.device_kind, len(jax.devices())))
+    try:
+        import libtpu
+        libtpu_version = libtpu.__version__
+    except ImportError:
+        libtpu_version = "absent"
+    say("    jax %s  jaxlib %s  libtpu %s" % (jax.__version__,
+                                             jaxlib.__version__,
+                                             libtpu_version))
+    from mxnet_tpu import compile_cache
+
+    say("    compile cache: %s (JAX_COMPILATION_CACHE_DIR %s)"
+        % (compile_cache.directory(),
+           "set" if os.environ.get("JAX_COMPILATION_CACHE_DIR") else "unset"))
+    check(compile_cache.directory(), "a persistent compile cache is placed")
+
+    from mxnet_tpu import engine, image_native, io_native
+
+    native = {"engine": engine.get().native,
+              "io": io_native.available(),
+              "image": image_native.available()}
+    say("    native libraries: " + "  ".join(
+        "%s: %s" % (k, "native" if v else "python") for k, v in native.items()))
+    if shutil.which("g++"):
+        check(all(native.values()),
+              "with a toolchain present every native library built and loaded")
+
+    if not REHEARSE:
+        check(mx.current_context() == mx.tpu(0), "default context is tpu(0)")
+    ones = mx.nd.ones((2, 3))
+    check(on_tpu(ones) and (ones.asnumpy() == 1).all(),
+          "mx.nd.ones((2,3)) lives on the device and reads back")
+    try:
+        mx.tpu(len(jax.devices())).jax_device
+    except mx.MXNetError as exc:
+        say("    ok: a chip id past the visible chips raises (%s)" % exc)
+    else:
+        if not REHEARSE:
+            raise AssertionError("tpu(n) past the visible chips resolved")
+
+    # is block_until_ready a real barrier? A chain of large matmuls timed to
+    # block_until_ready and timed to a host scalar fetch must agree, and
+    # both must dwarf the enqueue.
+    n = SZ["matmul_n"]
+    x = jnp.full((n, n), 0.001, jnp.bfloat16)
+
+    @jax.jit
+    def chain(a):
+        for _ in range(8):
+            a = (a @ a) * 0.001
+        return a
+
+    @jax.jit
+    def corner(a):
+        return jnp.sum(a[0, :8].astype(jnp.float32))
+
+    float(corner(chain(x)))  # compile both
+    rows = []
+    for _ in range(3):
+        t0 = time.perf_counter()
+        y = chain(x)
+        t_enq = time.perf_counter() - t0
+        y.block_until_ready()
+        t_bur = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        float(corner(chain(x)))
+        rows.append((t_enq, t_bur, time.perf_counter() - t0))
+    t_enq, t_bur, t_fetch = min(rows, key=lambda r: r[1])
+    say("    8 chained %d² bf16 matmuls: enqueue %.2f ms, to block_until_ready "
+        "%.2f ms (%.1f TFLOP/s), to host scalar fetch %.2f ms"
+        % (n, t_enq * 1e3, t_bur * 1e3, 16 * n ** 3 / t_bur / 1e12,
+           t_fetch * 1e3))
+    if not REHEARSE:
+        check(abs(t_bur - t_fetch) <= 0.1 * t_fetch + 2e-3 and
+              t_enq < 0.2 * t_bur,
+              "block_until_ready waits for the device (agrees with a host "
+              "fetch; dispatch itself is asynchronous)")
+        # the NDArray barriers rest on it: wait_to_read may not return
+        # before the chip could possibly have finished, and must leave the
+        # array ready
+        from mxnet_tpu.device_info import bf16_peak_flops
+
+        a = mx.nd.array(np.full((n, n), 0.001, "float32"))
+        mx.nd.dot(a, a).wait_to_read()  # compile
+        t0 = time.perf_counter()
+        b = a
+        for _ in range(4):
+            b = mx.nd.dot(b, a)
+        b.wait_to_read()
+        t_wait = time.perf_counter() - t0
+        floor = 4 * 2 * n ** 3 / bf16_peak_flops(DEV.device_kind)
+        check(b._jax().is_ready() and t_wait >= floor,
+              "NDArray.wait_to_read is a barrier (4 chained %d² f32 "
+              "mx.nd.dot: returned after %.1f ms with the result ready; the "
+              "chip's peak allows no less than %.1f ms)"
+              % (n, t_wait * 1e3, floor * 1e3))
+        mx.nd.waitall()
+        del a, b
+
+    # what a trivially small jitted dispatch costs
+    @jax.jit
+    def tiny(a):
+        return a + 1.0
+
+    a = tiny(jnp.zeros((8,), jnp.float32))
+    a.block_until_ready()
+    reps = 1000
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        a = tiny(a)
+    a.block_until_ready()
+    chained = (time.perf_counter() - t0) / reps
+    t0 = time.perf_counter()
+    for _ in range(200):
+        a = tiny(a)
+        a.block_until_ready()
+    synced = (time.perf_counter() - t0) / 200
+    t0 = time.perf_counter()
+    for _ in range(200):
+        a = tiny(a)
+        float(a[0])
+    fetched = (time.perf_counter() - t0) / 200
+    say("    tiny jitted dispatch: %.0f us chained, %.0f us with "
+        "block_until_ready each, %.0f us with a host read of one element each"
+        % (chained * 1e6, synced * 1e6, fetched * 1e6))
+
+
+# ------------------------------------------------------------------ phase 1
+def resnet50():
+    return models.get_symbol(
+        "resnet-50", num_classes=SZ["classes"],
+        image_shape="3,%d,%d" % (SZ["image"], SZ["image"]))
+
+
+def make_trainer(net, mesh, batch):
+    """bench.py:_make_trainer, on the given mesh."""
+    trainer = parallel.SPMDTrainer(
+        net, mesh, optimizer="sgd",
+        optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
+        compute_dtype=SZ["train_dtype"], data_names=("data",),
+        label_names=("softmax_label",))
+    image = SZ["image"]
+    trainer.init_params({"data": (batch, 3, image, image)},
+                        {"softmax_label": (batch,)}, seed=0)
+    return trainer
+
+
+def train_batch(trainer, batch):
+    rs = np.random.RandomState(0)
+    image = SZ["image"]
+    x = rs.rand(batch, 3, image, image).astype("float32")
+    if SZ["train_dtype"]:
+        x = x.astype(jnp.dtype(SZ["train_dtype"]))
+    y = rs.randint(0, SZ["classes"], (batch,)).astype("float32")
+    place = lambda a: jax.device_put(a, trainer.rules.named(
+        trainer.rules.batch_spec(a.shape)))
+    return place(x), place(y)
+
+
+def check_probs(out, batch):
+    out = np.asarray(out, np.float32)
+    check(out.shape == (batch, SZ["classes"]) and np.isfinite(out).all(),
+          "outputs are finite with shape %s" % (out.shape,))
+    check(np.allclose(out.sum(axis=1), 1.0, atol=2e-2),
+          "softmax rows sum to 1 (max deviation %.1e)"
+          % np.abs(out.sum(axis=1) - 1).max())
+
+
+def run_trainer_steps(trainer, x, y, batch, timed=5):
+    """Warm up (compile), then ``timed`` steps that must compile nothing.
+    Returns (first-step outputs on host, seconds per step)."""
+    watch = ("fc1_weight", "bn_data_beta", "stage1_unit1_conv1_weight")
+    names = [n for n in watch if n in trainer.params] or \
+        sorted(trainer.params)[:3]
+    before = {n: np.asarray(trainer.params[n], np.float32) for n in names}
+    outs = trainer.step({"data": x}, {"softmax_label": y})
+    first = np.asarray(outs[0], np.float32)
+    outs = trainer.step({"data": x}, {"softmax_label": y})
+    jax.block_until_ready(outs)
+    n0 = COMPILES.n
+    t0 = time.perf_counter()
+    for _ in range(timed):
+        outs = trainer.step({"data": x}, {"softmax_label": y})
+    jax.block_until_ready(outs)
+    step_s = (time.perf_counter() - t0) / timed
+    check(COMPILES.n == n0, "zero compiles in %d steps after warm-up" % timed)
+    check_probs(first, batch)
+    check_probs(outs[0], batch)
+    for n in names:
+        check(not np.array_equal(before[n],
+                                 np.asarray(trainer.params[n], np.float32)),
+              "parameter %s changed" % n)
+    check(all(on_tpu(v) for v in trainer.params.values()),
+          "all %d parameters live on the device" % len(trainer.params))
+    return first, step_s
+
+
+FIRST_STEP = {}  # phase 1's first-step outputs, for phases 4 and 5
+
+
+def phase_train():
+    batch = SZ["train_batch"]
+    net = resnet50()
+    mesh = parallel.make_mesh((1,), axis_names=("data",), devices=[DEV])
+    trainer = make_trainer(net, mesh, batch)
+    x, y = train_batch(trainer, batch)
+    first, step_s = run_trainer_steps(trainer, x, y, batch)
+    FIRST_STEP["probs"] = first
+    say("    info: batch %d (stated, not laddered), %s compute, %.1f ms/step, "
+        "%.0f img/s, peak device memory so far %.2f GiB"
+        % (batch, SZ["train_dtype"] or "float32", step_s * 1e3,
+           batch / step_s, peak_gb()))
+
+
+# ------------------------------------------------------------------ phase 2
+def phase_fit():
+    batch, n_batches = SZ["fit_batch"], SZ["fit_batches"]
+    image = SZ["image"]
+    rs = np.random.RandomState(1)
+    data = rs.rand(batch * n_batches, 3, image, image).astype("float32")
+    label = rs.randint(0, SZ["classes"],
+                       (batch * n_batches,)).astype("float32")
+    train = mx.io.NDArrayIter(data, label, batch_size=batch)
+    ctx = mx.cpu(0) if REHEARSE else mx.tpu(0)
+    mod = mx.mod.Module(resnet50(), context=ctx)
+    mod.bind(train.provide_data, train.provide_label)
+    mod.init_params(mx.init.Xavier(rnd_type="gaussian", factor_type="in",
+                                   magnitude=2))
+    before = {k: v.asnumpy() for k, v in mod.get_params()[0].items()}
+    seen = [COMPILES.n]  # compile requests before fit, then after each batch
+
+    def record(param):
+        seen.append(COMPILES.n)
+
+    metric = mx.metric.create("acc")
+    t0 = time.perf_counter()
+    mod.fit(train, num_epoch=1, eval_metric=metric, kvstore="local",
+            optimizer="sgd",
+            optimizer_params={"learning_rate": 0.05, "momentum": 0.9,
+                              "wd": 1e-4},
+            batch_end_callback=[mx.callback.Speedometer(batch, 2), record])
+    wall = time.perf_counter() - t0
+    check(len(seen) == n_batches + 1, "fit ran %d batches" % n_batches)
+    check(seen[-1] == seen[3],
+          "zero compiles after the third batch (requests by batch: %s)"
+          % [b - a for a, b in zip(seen, seen[1:])])
+    name, value = metric.get()
+    check(np.isfinite(value), "metric %s is finite" % name)
+    after, aux = mod.get_params()
+    changed = [k for k in before
+               if not np.array_equal(before[k], after[k].asnumpy())]
+    check(len(changed) == len(before),
+          "get_params(): all %d parameters changed" % len(before))
+    check(all(np.isfinite(v.asnumpy()).all()
+              for v in list(after.values()) + list(aux.values())),
+          "parameters and BatchNorm moving statistics are finite")
+    exe = mod._exec_group.execs[0]
+    check(all(on_tpu(a) for a in exe.arg_arrays) and
+          all(a.context == ctx for a in exe.arg_arrays),
+          "executor arrays live on %r" % ctx)
+    say("    info: batch %d f32 (this path has no bf16 switch), fit of %d "
+        "batches took %.1f s including compilation"
+        % (batch, n_batches, wall))
+
+
+# ------------------------------------------------------------------ phase 3
+def transformer_params(seq_len, seed=0):
+    """Random Transformer-base weights at the training graph's own shapes
+    (the prefill / decode / chunk programs bind the same names)."""
+    from mxnet_tpu.models import transformer as tfm
+
+    net = tfm.get_symbol(seq_len=seq_len, **SZ["tf"])
+    shapes, _, _ = net.infer_shape(data=(1, seq_len),
+                                   softmax_label=(1, seq_len))
+    rs = np.random.RandomState(seed)
+    params = {}
+    for name, shape in zip(net.list_arguments(), shapes):
+        if name in ("data", "softmax_label"):
+            continue
+        if name.endswith(("_gamma",)):
+            params[name] = np.ones(shape, "float32")
+        elif name.endswith(("_beta", "_bias")):
+            params[name] = np.zeros(shape, "float32")
+        else:
+            params[name] = (rs.randn(*shape) * 0.05).astype("float32")
+    return net, params
+
+
+class Reference:
+    """The training graph's full forward over a whole sequence, on the same
+    device — the oracle tests/test_kv_decode.py uses. One teacher-forced
+    forward per sequence checks every generated token at once: token t must
+    be the arg-max of the reference at position prompt+t-1."""
+
+    def __init__(self, net, params, seq_len, ctx):
+        self.seq_len = seq_len
+        self.exe = net.simple_bind(ctx, grad_req="null", data=(1, seq_len),
+                                   softmax_label=(1, seq_len))
+        for k, v in params.items():
+            self.exe.arg_dict[k][:] = v
+
+    def check(self, prompt, tokens, label):
+        L, n = len(prompt), len(tokens)
+        seq = np.zeros((1, self.seq_len), np.float32)
+        seq[0, :L] = prompt
+        seq[0, L:L + n - 1] = tokens[:-1]
+        self.exe.arg_dict["data"][:] = seq
+        self.exe.forward(is_train=False)
+        probs = self.exe.outputs[0].asnumpy().reshape(self.seq_len, -1)
+        rows = probs[L - 1:L - 1 + n]
+        want = rows.argmax(axis=-1)
+        same = int((want == tokens).sum())
+        # a token that is not the arg-max may only be a near-tie: both paths
+        # run f32 matmuls at the chip's default precision, in different
+        # orders, so two candidates closer than that rounding can swap
+        logp = np.log(np.maximum(rows, 1e-30))
+        deficit = logp.max(axis=-1) - logp[np.arange(n), tokens]
+        spread = float((logp.max(axis=-1) - logp.mean(axis=-1)).mean())
+        tol = 0.01 * spread
+        check((deficit <= tol).all(),
+              "%s: %d/%d tokens are the re-forward's arg-max; worst "
+              "log-prob deficit %.2e (near-tie bound %.2e)"
+              % (label, same, n, deficit.max(), tol))
+        return same, n
+
+
+def phase_serve():
+    from mxnet_tpu.serving import PagedKVDecoder
+
+    telemetry.set_mode("counters")
+    ctx = mx.current_context()
+    slots, lanes, page = SZ["slots"], SZ["lanes"], SZ["page"]
+    net, params = transformer_params(slots)
+    ref = Reference(net, params, slots, ctx)
+    rs = np.random.RandomState(7)
+    vocab = SZ["tf"]["vocab_size"]
+    n_new, k = SZ["new_tokens"], SZ["mega_k"]
+
+    def prompts_of(lengths, shared=0):
+        head = rs.randint(1, vocab, (shared,))
+        return [np.concatenate([head, rs.randint(1, vocab, (n,))])
+                .astype(np.float32) for n in lengths]
+
+    def run(dec, prompts, label, k):
+        toks = dec.greedy(prompts, n_new, k=k)
+        same = total = 0
+        for i, (p, t) in enumerate(zip(prompts, toks)):
+            s, n = ref.check(p.astype(np.int64), np.asarray(t, np.int64),
+                             "%s prompt %d (len %d)" % (label, i, len(p)))
+            same, total = same + s, total + n
+        return same, total
+
+    say("  -- paged decode, one token per dispatch")
+    dec = PagedKVDecoder(params, max_len=slots, page_size=page, lanes=lanes,
+                         ctx=ctx, **SZ["tf"])
+    dec.warmup()
+    same, total = run(dec, prompts_of(SZ["prompts"]), "k=1", 1)
+    c0, n0 = telemetry.counters(), COMPILES.n
+    s2, t2 = run(dec, prompts_of(SZ["prompts"][::-1]), "k=1 again", 1)
+    ran = counters_since(c0)
+    check(COMPILES.n == n0 and not ran.get("executor.retrace")
+          and not ran.get("executor.compile"),
+          "second pass: zero compiles, zero retraces")
+    check(dec.stats()["active"] == 0 and dec.stats()["pages_in_use"] == 0,
+          "every lane retired and every page returned")
+    say("    info: %d/%d tokens identical to the re-forward arg-max"
+        % (same + s2, total + t2))
+    del dec
+    gc.collect()
+
+    say("  -- prefix cache + %d-token megasteps" % k)
+    dec = PagedKVDecoder(params, max_len=slots, page_size=page, lanes=lanes,
+                         prefix_cache=True, ctx=ctx, **SZ["tf"])
+    dec.warmup()
+    lengths = [n for n in SZ["prompts"] if n < slots - n_new][:lanes]
+    shared = SZ["shared_prefix"]
+    same, total = run(dec, prompts_of(lengths, shared), "prefix k=%d" % k, k)
+    stats = dec.stats()
+    check(stats["prefix_hit_rate"] > 0,
+          "prompts sharing a %d-token head hit the prefix cache (chunk hit "
+          "rate %.2f)" % (shared, stats["prefix_hit_rate"]))
+    c0, n0 = telemetry.counters(), COMPILES.n
+    s2, t2 = run(dec, prompts_of(lengths[::-1], shared),
+                 "prefix k=%d again" % k, k)
+    ran = counters_since(c0)
+    check(COMPILES.n == n0 and not ran.get("executor.retrace")
+          and not ran.get("executor.compile"),
+          "second pass: zero compiles, zero retraces")
+    say("    info: %d/%d tokens identical to the re-forward arg-max; peak "
+        "device memory so far %.2f GiB"
+        % (same + s2, total + t2, peak_gb()))
+
+
+# ------------------------------------------------------------------ phase 4
+def is_mosaic(fn, *args):
+    """The lowering carries a Mosaic custom call: compiled, not interpreted."""
+    return "tpu_custom_call" in jax.jit(fn).lower(*args).as_text()
+
+
+def grads_of(fn, args, cots):
+    """Gradients of sum(out * cot). The cotangents are jit ARGUMENTS: as
+    closure constants they would be baked into the executable."""
+    def loss(cots, *a):
+        outs = jax.tree_util.tree_leaves(fn(*a))
+        return sum(jnp.sum(o.astype(jnp.float32) * c)
+                   for o, c in zip(outs, cots))
+    idx = tuple(i + 1 for i, a in enumerate(args) if a is not None)
+    return jax.jit(jax.grad(loss, argnums=idx))(cots, *args)
+
+
+def compare(label, got, want, tol):
+    errs = [rel_l2(g, w) for g, w in zip(jax.tree_util.tree_leaves(got),
+                                         jax.tree_util.tree_leaves(want))]
+    check(max(errs) <= tol and all(np.isfinite(np.asarray(g, np.float32))
+                                   .all() for g in
+                                   jax.tree_util.tree_leaves(got)),
+          "%s: relative L2 error %s <= %.0e"
+          % (label, ", ".join("%.1e" % e for e in errs), tol))
+
+
+def kernel_case(name, kernel, reference, args, tol_fwd, tol_bwd, seed=0):
+    """One kernel, forward and backward, against its XLA reference (run at
+    the highest matmul precision so the comparison is about the kernel)."""
+    if not REHEARSE:
+        check(is_mosaic(kernel, *args),
+              "%s lowers to a Mosaic custom call (interpret=False)" % name)
+    rs = np.random.RandomState(seed)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(reference)(*args)
+    got = jax.jit(kernel)(*args)
+    compare("%s forward" % name, got, want, tol_fwd)
+    cots = [jnp.asarray(rs.randn(*o.shape).astype("float32"))
+            for o in jax.tree_util.tree_leaves(want)]
+    with jax.default_matmul_precision("highest"):
+        gwant = grads_of(reference, args, cots)
+    ggot = grads_of(kernel, args, cots)
+    compare("%s backward" % name, ggot, gwant, tol_bwd)
+
+
+def phase_kernels():
+    from mxnet_tpu.ops import pallas_attention as pa
+    from mxnet_tpu.ops import pallas_conv_bn as pc
+    from mxnet_tpu.ops import pallas_matmul_bias_act as pm
+    from mxnet_tpu.ops import pallas_norm_residual as pn
+
+    interp = REHEARSE
+    if not REHEARSE:
+        check(not any(m._interpret_mode() for m in (pc, pm, pn)),
+              "the kernels pick Mosaic, not interpret mode, on this backend")
+    rs = np.random.RandomState(4)
+    bf = jnp.bfloat16
+
+    def arr(shape, dtype=bf, scale=1.0):
+        return jnp.asarray(rs.randn(*shape).astype("float32") * scale, dtype)
+
+    say("  -- pallas_attention.flash_attention, causal, %s bf16"
+        % (SZ["attn"],))
+    B, H, T, D = SZ["attn"]
+    q, k, v = (arr((B, H, T, D)) for _ in range(3))
+
+    def dense(q, k, v):
+        q32, k32, v32 = (t.astype(jnp.float32) for t in (q, k, v))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q32, k32) / np.sqrt(D)
+        s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -jnp.inf)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1),
+                          v32).astype(q.dtype)
+
+    kernel_case("flash_attention",
+                lambda q, k, v: pa.flash_attention(q, k, v, causal=True,
+                                                   interpret=interp),
+                dense, (q, k, v), 2e-2, 5e-2)
+
+    say("  -- pallas_matmul_bias_act, relu, %s bf16" % (SZ["mba"],))
+    M, K, N = SZ["mba"]
+    a, w, b = arr((M, K)), arr((N, K), scale=0.05), arr((N,))
+    check(pm.supported(M, K, N, "relu"), "the planner accepts the shape")
+    kernel_case("matmul_bias_act",
+                lambda a, w, b: pm.matmul_bias_act(a, w, b, "relu"),
+                lambda a, w, b: jnp.maximum(
+                    jnp.dot(a, w.T, preferred_element_type=jnp.float32)
+                    + b.astype(jnp.float32), 0).astype(a.dtype),
+                (a, w, b), 1e-2, 2e-2)
+
+    say("  -- pallas_norm_residual.layer_norm_affine, %s f32" % (SZ["ln"],))
+    R, Dm = SZ["ln"]
+    x = arr((R, Dm), jnp.float32)
+    g, be = arr((Dm,), jnp.float32), arr((Dm,), jnp.float32)
+    check(pn.supported((R, Dm)), "the planner accepts the shape")
+
+    def ln_ref(x, g, be):
+        mean = jnp.mean(x, -1, keepdims=True)
+        cent = x - mean
+        var = jnp.mean(cent * cent, -1, keepdims=True)
+        return cent * jax.lax.rsqrt(var + 1e-5) * g + be
+
+    kernel_case("layer_norm_affine",
+                lambda x, g, be: pn.layer_norm_affine(x, g, be,
+                                                      interpret=interp),
+                ln_ref, (x, g, be), 1e-5, 1e-4)
+
+    cb = SZ["conv_batch"]
+    for kernel, stride, K, N, H, res in SZ["conv_sites"]:
+        tag = "k%d s%d %d->%d at %d²%s, batch %d bf16" % (
+            kernel[0], stride[0], K, N, H, " +skip" if res else "", cb)
+        say("  -- pallas_conv_bn.conv_block, %s" % tag)
+        xs, ws = (cb, K, H, H), (N, K) + kernel
+        check(pc.supported(xs, ws, stride, 2, True, res),
+              "the forward planner accepts the shape")
+        x = arr(xs)
+        w = arr(ws, scale=0.05)
+        scale = jnp.asarray(rs.uniform(0.5, 1.5, (K,)), jnp.float32)
+        shift = jnp.asarray(rs.uniform(-0.2, 0.2, (K,)), jnp.float32)
+        Ho, Wo = pc.strided_dims(H, H, stride)
+        r = arr((cb, N, Ho, Wo)) if res else None
+
+        def ref(x, w, scale, shift, r=None):
+            c = pc._xla_conv(x, w, scale, shift, r, kernel, stride, True)
+            return (c,) + tuple(pc._stats_of(c))
+
+        for bwd in ("xla", "recompute", "stash"):
+            if bwd != "xla":
+                planned = pc.plan_bwd_blocks(xs, ws, stride, 2, True, res,
+                                             stash=(bwd == "stash"))
+                check(planned is not None,
+                      "the backward planner returns bk=%s for %s"
+                      % (planned, bwd))
+            kernel_case(
+                "conv_block[bwd=%s]" % bwd,
+                lambda x, w, scale, shift, r=None, _b=bwd: pc.conv_block(
+                    x, w, scale, shift, r, kernel, stride, True, True, _b,
+                    None),
+                ref, (x, w, scale, shift, r), 2e-2, 6e-2)
+        if not res:
+            got = jax.jit(lambda *a: pc.conv_block_infer(
+                *a, kernel, stride, True))(x, w, scale, shift)
+            with jax.default_matmul_precision("highest"):
+                want = jax.jit(ref)(x, w, scale, shift)[0]
+            compare("conv_block_infer forward", got, want, 2e-2)
+        del x, w, r
+        gc.collect()
+
+    say("  -- ResNet-50 training step with the conv+BN kernels forced "
+        "(forward, and backward by recompute)")
+    telemetry.set_mode("counters")
+    with environ(MXNET_FUSED_CONV_BN="1", MXNET_FUSED_CONV_BN_BWD="recompute"):
+        c0 = telemetry.counters()
+        batch = SZ["train_batch"]
+        mesh = parallel.make_mesh((1,), axis_names=("data",), devices=[DEV])
+        trainer = make_trainer(resnet50(), mesh, batch)
+        x, y = train_batch(trainer, batch)
+        first, step_s = run_trainer_steps(trainer, x, y, batch, timed=2)
+        ran = counters_since(c0)
+        say("    fusion counters: %s" % {k: v for k, v in sorted(ran.items())
+                                         if k.startswith("fusion.")})
+        check(ran.get("fusion.fwd_engaged", 0) > 0,
+              "fusion.fwd_engaged = %d" % ran.get("fusion.fwd_engaged", 0))
+        check(ran.get("fusion.bwd_engaged", 0) > 0,
+              "fusion.bwd_engaged = %d (the planner sends %d site(s) to the "
+              "XLA backward)" % (ran.get("fusion.bwd_engaged", 0),
+                                 ran.get("fusion.bwd_xla", 0)))
+        check(not ran.get("fusion.tune_error"), "no tuner/candidate error")
+        if "probs" in FIRST_STEP:
+            # same seed, same batch as phase 1: the fused step's first
+            # outputs must tell the same story as the XLA step's
+            compare("first-step outputs vs the unfused step of phase 1",
+                    first, FIRST_STEP["probs"], 5e-2)
+        say("    info: %.1f ms/step with every gate-accepted site forced "
+            "(information, not a claim)" % (step_s * 1e3))
+        del trainer, x, y
+    gc.collect()
+
+    say("  -- Transformer-base training step with every pattern forced")
+    from mxnet_tpu.models import transformer as tfm
+
+    B, T = SZ["tf_train_batch"], SZ["tf_train_seq"]
+    net = tfm.get_symbol(seq_len=T, **SZ["tf"])
+    _, params = transformer_params(T, seed=3)
+    rs2 = np.random.RandomState(5)
+    vocab = SZ["tf"]["vocab_size"]
+    tokens = rs2.randint(1, vocab, (B, T)).astype("float32")
+    labels = rs2.randint(1, vocab, (B, T)).astype("float32")
+    watch = ("layer0_qkv_weight", "layer0_ln1_gamma", "layer0_ffn1_bias",
+             "embed_weight")
+
+    def train_step(**env):
+        with environ(**env):
+            exe = net.simple_bind(mx.current_context(), grad_req="write",
+                                  data=(B, T), softmax_label=(B, T))
+            for kname, val in params.items():
+                exe.arg_dict[kname][:] = val
+            exe.arg_dict["data"][:] = tokens
+            exe.arg_dict["softmax_label"][:] = labels
+            outs = exe.forward_backward()
+            return (outs[0].asnumpy(),
+                    [exe.grad_dict[n].asnumpy() for n in watch])
+
+    # Both steps run their f32 matmuls at the chip's default (bf16-pass)
+    # precision, in different lowerings, and six layers amplify that
+    # rounding in the gradients. So the yardstick is the unfused step at the
+    # HIGHEST precision: the forced step may sit as far from it as the
+    # unfused default step itself does (x2, plus a floor), no farther.
+    with jax.default_matmul_precision("highest"):
+        ref_out, ref_grads = train_step(MXNET_FUSED_PATTERNS="0")
+    base_out, base_grads = train_step(MXNET_FUSED_PATTERNS="0")
+    c0 = telemetry.counters()
+    got_out, got_grads = train_step(
+        MXNET_GRAPHREWRITE="on",  # roots the zoo LayerNorm composition
+        MXNET_FUSED_PATTERNS="attention=pallas_flash,matmul_bias_act=1,"
+                             "norm_residual=pallas,elemwise_chain=1")
+    ran = counters_since(c0)
+    say("    fusion counters: %s" % {k: v for k, v in sorted(ran.items())
+                                     if k.startswith("fusion.")})
+    for pat in ("attention", "matmul_bias_act", "norm_residual"):
+        check(ran.get("fusion.pattern_engaged.%s" % pat, 0) > 0,
+              "fusion.pattern_engaged.%s = %d (fallbacks %d)"
+              % (pat, ran.get("fusion.pattern_engaged.%s" % pat, 0),
+                 ran.get("fusion.pattern_fallback.%s" % pat, 0)))
+    # elemwise_chain roots only where two unary ops follow each other; the
+    # zoo transformer has no such run, so it has nothing to engage
+    check(not ran.get("fusion.pattern_fallback.elemwise_chain"),
+          "elemwise_chain: no site fell back")
+    check(not ran.get("fusion.tune_error"), "no tuner/candidate error")
+    check(np.isfinite(got_out).all() and
+          all(np.isfinite(g).all() for g in got_grads),
+          "forced step's outputs and gradients are finite")
+    for what, got, base, ref in (
+            ("outputs", [got_out], [base_out], [ref_out]),
+            ("gradients (%s)" % ", ".join(watch), got_grads, base_grads,
+             ref_grads)):
+        e_got = max(rel_l2(g, r) for g, r in zip(got, ref))
+        e_base = max(rel_l2(b, r) for b, r in zip(base, ref))
+        check(e_got <= 2 * e_base + 1e-2,
+              "%s vs the highest-precision unfused step: forced %.1e, "
+              "unfused at default precision %.1e (relative L2)"
+              % (what, e_got, e_base))
+
+
+# ------------------------------------------------------------------ phase 5
+def phase_four_chips():
+    devices = jax.devices()[:4]
+    batch = SZ["train_batch"]
+
+    say("  -- SPMDTrainer on a {\"data\": 4} mesh, global batch %d" % batch)
+    mesh = parallel.make_mesh({"data": 4}, devices=devices)
+    trainer = make_trainer(resnet50(), mesh, batch)
+    x, y = train_batch(trainer, batch)
+    first, step_s = run_trainer_steps(trainer, x, y, batch)
+    shards = {s.device for s in x.addressable_shards}
+    check(len(shards) == 4, "the batch has shards on 4 distinct devices")
+    w = next(iter(trainer.params.values()))
+    check(len({s.device for s in w.addressable_shards}) == 4,
+          "parameters are laid out on 4 devices")
+    if not REHEARSE:
+        used = [d.memory_stats()["bytes_in_use"] for d in devices]
+        check(all(u > 0 for u in used),
+              "every chip holds memory (%s MiB)"
+              % [u >> 20 for u in used])
+    lr = jnp.asarray(0.1, "float32")
+    hlo = trainer._step_fn.lower(
+        trainer.params, trainer.aux, trainer.opt_state,
+        {"data": x, "softmax_label": y}, trainer._base_key,
+        lr).compile().as_text()
+    check("all-reduce" in hlo,
+          "the compiled step contains a cross-device all-reduce")
+    if "probs" in FIRST_STEP:
+        compare("first-step outputs vs the one-chip run at the same global "
+                "batch", first, FIRST_STEP["probs"], 5e-2)
+    say("    info: %.1f ms/step on 4 chips (information, not a claim)"
+        % (step_s * 1e3))
+    del trainer, x, y, hlo
+    gc.collect()
+
+    say("  -- Module(context=[tpu(0..3)]).fit")
+    fit_batch = 4 * SZ["fit_batch"]
+    image = SZ["image"]
+    rs = np.random.RandomState(2)
+    n_batches = 4
+    data = rs.rand(fit_batch * n_batches, 3, image, image).astype("float32")
+    label = rs.randint(0, SZ["classes"],
+                       (fit_batch * n_batches,)).astype("float32")
+    train = mx.io.NDArrayIter(data, label, batch_size=fit_batch)
+    ctxs = [mx.cpu(i) if REHEARSE else mx.tpu(i) for i in range(4)]
+    mod = mx.mod.Module(resnet50(), context=ctxs)
+    metric = mx.metric.create("acc")
+    mod.fit(train, num_epoch=1, eval_metric=metric, kvstore="local",
+            optimizer="sgd",
+            optimizer_params={"learning_rate": 0.05, "momentum": 0.9},
+            initializer=mx.init.Xavier(rnd_type="gaussian",
+                                       factor_type="in", magnitude=2))
+    check(mod._spmd is not None,
+          "spmd_adapter accepted the fused step (4 distinct devices)")
+    check(mod._spmd.trainer.mesh.devices.size == 4,
+          "the fused step's mesh spans 4 devices")
+    check(np.isfinite(metric.get()[1]), "metric is finite")
+    args, _ = mod.get_params()
+    check(all(np.isfinite(v.asnumpy()).all() for v in args.values()),
+          "parameters are finite after fit")
+    del mod
+    gc.collect()
+
+    say("  -- ring attention on a {\"data\": 2, \"seq\": 2} mesh")
+    from mxnet_tpu.models import transformer as tfm
+    from mxnet_tpu.ops import attention as attn_op
+
+    mesh = parallel.make_mesh({"data": 2, "seq": 2}, devices=devices)
+    B, T = SZ["tf_train_batch"], SZ["tf_train_seq"]
+    net = tfm.get_symbol(seq_len=T, **SZ["tf"])
+    trainer = parallel.SPMDTrainer(
+        net, mesh, optimizer="sgd", optimizer_params={"learning_rate": 0.05},
+        rules=parallel.ShardingRules(mesh, seq_axis="seq"))
+    trainer.init_params({"data": (B, T)}, {"softmax_label": (B, T)}, seed=0)
+    vocab = SZ["tf"]["vocab_size"]
+    tokens = rs.randint(1, vocab, (B, T)).astype("float32")
+    labels = rs.randint(1, vocab, (B, T)).astype("float32")
+    before = attn_op.DISPATCH_COUNTS["ring"]
+    outs = trainer.step({"data": tokens}, {"softmax_label": labels})
+    out = np.asarray(outs[0], np.float32)
+    moved = attn_op.DISPATCH_COUNTS["ring"] - before
+    check(moved >= SZ["tf"]["num_layers"],
+          "attention.DISPATCH_COUNTS[\"ring\"] moved by %d" % moved)
+    check(out.shape == (B * T, vocab) and np.isfinite(out).all() and
+          np.allclose(out.sum(axis=1), 1.0, atol=1e-3),
+          "outputs are finite softmax rows")
+
+
+# --------------------------------------------------------------------- main
+PHASES = [
+    ("device", phase_device),
+    ("train: SPMDTrainer, ResNet-50", phase_train),
+    ("fit: Module.fit, ResNet-50", phase_fit),
+    ("serve: PagedKVDecoder, Transformer-base", phase_serve),
+    ("kernels: Mosaic vs XLA, forced steps", phase_kernels),
+    ("four chips", phase_four_chips),
+]
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--phases", default=None,
+                    help="comma list of phase numbers (default: all)")
+    ap.add_argument("--rehearse-cpu", action="store_true",
+                    help="debug run without a chip: tiny sizes, no result")
+    args = ap.parse_args()
+    want = (sorted({int(p) for p in args.phases.split(",")})
+            if args.phases else list(range(len(PHASES))))
+    import logging
+
+    # Speedometer and the fused-step notices log at INFO
+    logging.basicConfig(level=logging.INFO, stream=sys.stdout,
+                        format="    log: %(message)s")
+    if REHEARSE:
+        say("*** REHEARSAL on the CPU at tiny sizes: this debugs the script "
+            "and is NOT a chip result ***")
+    failed, t_start = [], time.perf_counter()
+    for i in want:
+        name, fn = PHASES[i]
+        say("PHASE %d %s" % (i, name))
+        if i == 5 and len(jax.devices()) < 4:
+            say("PHASE 5 SKIP %d device(s) visible, the phase needs 4"
+                % len(jax.devices()))
+            continue
+        n0, s0, h0, m0 = COMPILES.snap()
+        t0 = time.perf_counter()
+        try:
+            fn()
+            verdict = "PASS"
+        except Exception:  # noqa: BLE001 — reported, counted, exit code 1
+            traceback.print_exc(file=sys.stdout)
+            verdict = "FAIL"
+            failed.append(i)
+        n1, s1, h1, m1 = COMPILES.snap()
+        say("PHASE %d %s  %.1f s wall; set-up: %d compile requests, %.1f s "
+            "(persistent cache: %d hits, %d misses)"
+            % (i, verdict, time.perf_counter() - t0, n1 - n0, s1 - s0,
+               h1 - h0, m1 - m0))
+        gc.collect()
+    n, s, h, m = COMPILES.snap()
+    say("TOTAL %.1f s wall; set-up: %d compile requests, %.1f s (persistent "
+        "cache: %d hits, %d misses; programs under JAX's 1 s threshold are "
+        "never persisted); peak device memory %.2f GiB"
+        % (time.perf_counter() - t_start, n, s, h, m, peak_gb()))
+    if failed:
+        say("FAILED phases: %s" % failed)
+    if REHEARSE:
+        say("*** REHEARSAL %s — no result line ***"
+            % ("FAILED" if failed else "passed"))
+        return 1 if failed else 0
+    result = {"ok": not failed,
+              "device": {"platform": DEV.platform, "kind": DEV.device_kind,
+                         "count": len(jax.devices())}}
+    if args.phases:
+        result["phases"] = want
+    say(json.dumps(result))
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -3,12 +3,20 @@
 All the runtime's C++ pieces (src/engine_native.cc, io_native.cc,
 image_native.cc, predict_api.cc) share the same lifecycle: compile on first
 use with the system toolchain, cache under build/, rebuild when the source
-is newer, degrade gracefully (return None) when no compiler exists. The
-publish is atomic (temp file + os.replace) so concurrent processes never
-dlopen a half-written .so.
+or the compile command changed, degrade (return None, with one logged
+warning carrying the compiler's message) when the toolchain or the compile
+fails. The publish is atomic (temp file + os.replace) so concurrent
+processes never dlopen a half-written .so.
+
+Freshness is a hash of the source bytes and the compile command, stored
+beside the library (``<lib>.key``) — not mtimes: ``build/`` is git-ignored
+and gets copied between machines and checkouts, and a copied ``.so`` must
+never stand in for a ``src/*.cc`` it was not built from.
 """
 from __future__ import annotations
 
+import hashlib
+import logging
 import os
 import subprocess
 
@@ -20,24 +28,36 @@ def source_path(name):
     return os.path.join(_ROOT, "src", name)
 
 
-def build_lib(src, libname, extra_flags=(), opt="-O2", force=False):
-    """Compile ``src`` (absolute path) into build/<libname> if stale.
-    Returns the .so path, or None when the toolchain/compile fails.
-    ``force`` rebuilds even when mtimes say fresh (compile inputs the
-    staleness check can't see — e.g. a Python version switch)."""
+def build_lib(src, libname, extra_flags=(), opt="-O2"):
+    """Compile ``src`` (absolute path) into build/<libname> unless the
+    library there was built from exactly this source and command. Returns
+    the .so path, or None when the toolchain/compile fails."""
     out = os.path.join(_BUILD_DIR, libname)
+    keyfile = out + ".key"
+    cmd = ["g++", "-std=c++17", opt, "-shared", "-fPIC", "-pthread", src,
+           *extra_flags]
     try:
-        if not force and os.path.isfile(out) and (
-                not os.path.isfile(src)
-                or os.path.getmtime(src) <= os.path.getmtime(out)):
-            return out
+        with open(src, "rb") as f:
+            key = hashlib.sha256(
+                f.read() + b"\0" + "\0".join(cmd).encode()).hexdigest()
+        try:
+            with open(keyfile) as f:
+                if f.read() == key and os.path.isfile(out):
+                    return out
+        except FileNotFoundError:
+            pass
         os.makedirs(_BUILD_DIR, exist_ok=True)
-        tmp = out + ".%d.tmp" % os.getpid()
-        subprocess.run(
-            ["g++", "-std=c++17", opt, "-shared", "-fPIC", "-pthread", src,
-             "-o", tmp] + list(extra_flags),
-            check=True, capture_output=True)
+        tmp = "%s.%d.tmp" % (out, os.getpid())
+        subprocess.run(cmd + ["-o", tmp], check=True, capture_output=True)
         os.replace(tmp, out)
+        with open(tmp, "w") as f:
+            f.write(key)
+        os.replace(tmp, keyfile)
         return out
-    except Exception:
+    except (OSError, subprocess.CalledProcessError) as exc:
+        detail = getattr(exc, "stderr", b"") or b""
+        logging.getLogger("mxnet_tpu").warning(
+            "native build of %s failed (%s)%s — the pure-Python path runs "
+            "instead", libname, exc,
+            ": " + detail.decode(errors="replace")[-400:] if detail else "")
         return None

@@ -29,13 +29,13 @@ def lib_path():
     return os.path.join(_BUILD_DIR, "libmxtpu_c.so")
 
 
-def build(force=False):
+def build():
     """Compile (if stale) and return the .so path; None if no toolchain."""
     with _lock:
         inc = sysconfig.get_paths()["include"]
         libdir = sysconfig.get_config_var("LIBDIR")
         pyver = "python%d.%d" % sys.version_info[:2]
-        return build_lib(_SRC, "libmxtpu_c.so", force=force,
+        return build_lib(_SRC, "libmxtpu_c.so",
                          extra_flags=["-I", inc, "-L", libdir, "-l", pyver])
 
 

@@ -83,9 +83,9 @@ def log_train_metric(period, auto_reset=False):
 class Speedometer:
     """Log samples/sec every ``frequent`` batches (reference: callback.py:89) —
     the throughput number the benchmarks track — plus step time, and MFU when
-    ``flops_per_sample`` is given and the device's bf16 peak is known
-    (device_info.py). Training logs then carry the BASELINE scoreboard
-    numbers directly.
+    ``flops_per_sample`` is given (the device's bf16 peak must then be in
+    device_info.py: an unknown device kind is an error). Training logs then
+    carry the BASELINE scoreboard numbers directly.
 
     When telemetry is enabled the window duration comes from the registry's
     per-step rows (``Module.fit`` marks one per batch) — ONE wall-clock
@@ -139,15 +139,13 @@ class Speedometer:
         if not self.flops_per_sample:
             return None
         if self._peak is None:
-            try:
-                import jax
+            import jax
 
-                from .device_info import bf16_peak_flops
+            from .device_info import bf16_peak_flops
 
-                self._peak = bf16_peak_flops(jax.devices()[0].device_kind) or 0
-            except Exception:
-                self._peak = 0
-        return speed * self.flops_per_sample / self._peak if self._peak else None
+            # an unknown device kind raises: no utilisation against a guess
+            self._peak = bf16_peak_flops(jax.devices()[0].device_kind)
+        return speed * self.flops_per_sample / self._peak
 
     def __call__(self, param):
         count = param.nbatch

@@ -10,7 +10,8 @@ Each Context resolves lazily to a concrete ``jax.Device``. ``cpu(i)`` for i>0
 maps onto virtual host devices when ``--xla_force_host_platform_device_count``
 is set (the multi-device-without-hardware test trick, SURVEY.md §4), else all
 cpu ids alias device 0 — same semantics as the reference where cpu dev_id is a
-hint (include/mxnet/base.h:141-143).
+hint (include/mxnet/base.h:141-143). An accelerator id is not a hint:
+``tpu(i)``/``gpu(i)`` with ``i`` past the visible chips raises ``MXNetError``.
 """
 from __future__ import annotations
 
@@ -73,32 +74,28 @@ class Context:
         if self.device_type in ("cpu", "cpu_pinned"):
             # local_devices: in a multi-process job each process may only
             # address its own devices (jax.devices() lists the whole job's)
-            devs = [d for d in jax.local_devices() if d.platform == "cpu"]
-            if not devs:
-                try:
-                    devs = jax.local_devices(backend="cpu")
-                except RuntimeError:
-                    devs = jax.devices("cpu")
+            devs = jax.local_devices(backend="cpu")
             return devs[self.device_id % len(devs)]
+        # an accelerator id names one chip: tpu(3) on a one-chip host is an
+        # error, never chip 0 under another name
         accels = _accelerator_devices()
-        if not accels:
-            if self.device_type == "gpu":
-                raise MXNetError("no GPU/TPU device available for %r" % self)
-            raise MXNetError("no TPU device available")
-        return accels[self.device_id % len(accels)]
+        if not 0 <= self.device_id < len(accels):
+            raise MXNetError(
+                "%r: this process sees %d accelerator device(s)"
+                % (self, len(accels)))
+        return accels[self.device_id]
 
     def empty_cache(self):  # parity with later mxnet; no-op under PJRT
         pass
 
 
 def _accelerator_devices():
+    """This process's non-CPU devices. A backend that was asked for
+    (``JAX_PLATFORMS``) and failed to initialise raises from here — that is
+    an error, not "no accelerator"."""
     import jax
 
-    try:
-        devs = jax.local_devices()
-    except RuntimeError:
-        return []
-    return [d for d in devs if d.platform != "cpu"]
+    return [d for d in jax.local_devices() if d.platform != "cpu"]
 
 
 def cpu(device_id=0):

@@ -4,9 +4,11 @@ The reference never needed this — CUDA exposes clock×cores — but TPU peak
 comes from public spec sheets keyed on ``device_kind``. Used by bench.py and
 callback.Speedometer's MFU display.
 """
+from .base import MXNetError
+
 __all__ = ["bf16_peak_flops"]
 
-# public spec-sheet numbers
+# public spec-sheet numbers, keyed by the exact ``jax.Device.device_kind``
 _PEAK = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
@@ -20,13 +22,11 @@ _PEAK = {
 
 
 def bf16_peak_flops(device_kind):
-    """bf16 peak for a device kind, tolerant of naming variants ("TPU v5p
-    slice" → "TPU v5p"); None when unknown — callers must not guess."""
-    if device_kind in _PEAK:
+    """bf16 peak of exactly this device kind. An unknown kind is an error:
+    a utilisation computed against a neighbour's peak is a wrong number."""
+    try:
         return _PEAK[device_kind]
-    best = None
-    for kind, peak in _PEAK.items():
-        if device_kind.startswith(kind):
-            if best is None or len(kind) > len(best[0]):
-                best = (kind, peak)
-    return best[1] if best else None
+    except KeyError:
+        raise MXNetError(
+            "no bf16 peak known for device kind %r (known: %s)"
+            % (device_kind, ", ".join(sorted(_PEAK)))) from None

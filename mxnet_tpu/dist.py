@@ -102,7 +102,6 @@ def init(coordinator_address=None, num_processes=None, process_id=None):
                      else os.environ.get(ENV_WORKER_ID, "0"))
     import jax
 
-    _enable_cpu_collectives()
     try:
         if elastic_enabled():
             _init_elastic(coordinator_address, num_processes, process_id)
@@ -132,31 +131,30 @@ def init(coordinator_address=None, num_processes=None, process_id=None):
 def _init_elastic(coordinator_address, num_processes, process_id):
     """Elastic bootstrap: the same coordination service/client pair
     ``jax.distributed.initialize`` would build, but with death propagation
-    disabled — ``max_missing_heartbeats`` effectively infinite on both ends
-    and ``shutdown_on_destruction=False`` (a survivor tearing down its old
+    disabled — a heartbeat timeout that never expires on both ends and
+    ``shutdown_on_destruction=False`` (a survivor tearing down its old
     backend must not shut the service down for its peers). The client and
     service OUTLIVE backend re-forms: ``reform()`` rebuilds the XLA backend
     over the survivor set while this client keeps its original node id for
     barriers and the membership KV protocol."""
     global _elastic, _members, _orig_rank, _orig_world, _generation
     from jax._src import distributed as jdist
-    from jax._src.lib import xla_extension as xe
+    from jax._src.lib import _jax
 
     gs = jdist.global_state
     if gs.client is not None:
         raise RuntimeError("jax.distributed already initialized")
-    # ~heartbeat_interval * max_missing seconds of tolerance ≈ 3 years:
-    # the coordination service never declares a node dead on its own
-    never = 10 ** 7
+    # seconds (~3 years): the coordination service never declares a node
+    # dead on its own
+    never = 10 ** 8
     if process_id == 0:
         bind = "[::]:" + coordinator_address.rsplit(":", 1)[1]
-        gs.service = xe.get_distributed_runtime_service(
-            bind, num_processes, heartbeat_interval=10,
-            max_missing_heartbeats=never)
-    gs.client = xe.get_distributed_runtime_client(
+        gs.service = _jax.get_distributed_runtime_service(
+            bind, num_processes, heartbeat_timeout=never)
+    gs.client = _jax.get_distributed_runtime_client(
         coordinator_address, process_id, init_timeout=300,
-        heartbeat_interval=10, max_missing_heartbeats=never,
-        shutdown_on_destruction=False, use_compression=True)
+        heartbeat_timeout=never, shutdown_on_destruction=False,
+        use_compression=True)
     gs.client.connect()
     gs.process_id = process_id
     gs.num_processes = num_processes
@@ -166,27 +164,6 @@ def _init_elastic(coordinator_address, num_processes, process_id):
     _members = list(range(num_processes))
     _orig_rank = process_id
     _orig_world = num_processes
-
-
-def _enable_cpu_collectives():
-    """Multi-process collectives on the CPU backend need jax's gloo
-    cross-process collectives implementation; without it every dist
-    collective dies with "Multiprocess computations aren't implemented on
-    the CPU backend". Selected here — before ``jax.distributed.initialize``
-    — when the job is pinned to CPU (tests, CI, tools/launch.py
-    --cpu-devices). No-op on TPU/GPU platforms and on jax builds without
-    the option."""
-    if not (os.environ.get("JAX_PLATFORMS", "") == "cpu"
-            or os.environ.get("MXNET_DEFAULT_CONTEXT", "") == "cpu"):
-        return
-    import jax
-
-    try:
-        if getattr(jax.config, "jax_cpu_collectives_implementation", None):
-            return  # the operator already chose an implementation
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception as e:  # old jax / no gloo build
-        logging.debug("mxnet_tpu.dist: cpu collectives unavailable: %s", e)
 
 
 def _start_heartbeat(process_id):

@@ -43,7 +43,8 @@ Gating env (docs/ENV_VARS.md):
 
 Telemetry (docs/OBSERVABILITY.md): ``fusion.tune`` counts actual
 measurements (a warm cache keeps this at zero), ``fusion.tune_cache_hit``
-counts verdicts served from the cache.
+counts verdicts served from the cache, ``fusion.tune_error`` counts sites
+and candidates discarded because they failed to build, compile or run.
 """
 from __future__ import annotations
 
@@ -73,6 +74,7 @@ _lock = threading.Lock()
 # device_kind -> {key: record}; None means "not loaded yet"
 _mem = {}
 _warned_paths = set()
+_warned_errors = set()
 
 
 # ------------------------------------------------------------------- gating
@@ -170,6 +172,21 @@ def reset():
     with _lock:
         _mem.clear()
         _warned_paths.clear()
+        _warned_errors.clear()
+
+
+def _note_error(what, exc):
+    """A site or candidate that failed to build, compile or run is discarded
+    — but never silently: counted (``fusion.tune_error``) and its message
+    logged once. On the chip this is where a kernel the compiler refuses
+    shows up instead of quietly taking the XLA lowering."""
+    msg = "%s: %s" % (type(exc).__name__, exc)
+    if _tm.enabled():
+        _tm.counter("fusion.tune_error").inc()
+    if msg not in _warned_errors:
+        _warned_errors.add(msg)
+        log.warning("fusion_tune: %s failed and is discarded: %s", what, msg)
+    return msg
 
 
 # ------------------------------------------------------------------ storage
@@ -309,7 +326,7 @@ def verdict(key, measure):
         rec = measure()
     except Exception as exc:  # noqa: BLE001 — a tune failure must not sink a trace
         rec = {"engage": False, "lowering": None,
-               "error": "%s: %s" % (type(exc).__name__, exc)}
+               "error": _note_error("site %s" % key, exc)}
     rec.setdefault("engage", False)
     rec["tune_s"] = round(time.perf_counter() - t0, 4)
     # schedule-search annotations (schema v2): the winner's parsed schedule
@@ -515,7 +532,7 @@ def _measure_impl(baseline, candidates, args, train, iters, rel_tol,
             table.append((name, runners))
         except Exception as exc:  # noqa: BLE001 — one bad candidate ≠ no verdict
             rec["measured"][name] = {
-                "error": "%s: %s" % (type(exc).__name__, exc)}
+                "error": _note_error("candidate %s" % name, exc)}
     times = {name: [float("inf"), float("inf")] for name, _ in table}
     for _ in range(_ROUNDS):
         for name, runners in table:

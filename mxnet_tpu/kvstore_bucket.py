@@ -894,7 +894,6 @@ class BucketEngine:
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from .parallel.mesh import shard_map_compat
 
         coll = self._coll()
         opt = self._kv._optimizer
@@ -923,8 +922,8 @@ class BucketEngine:
                     + (P("worker"),) * n_states
                     + (P(None), P(None), P("worker")))
         out_specs = (P(None),) + (P("worker"),) * n_states
-        fn = jax.jit(shard_map_compat(body, mesh, in_specs=in_specs,
-                                      out_specs=out_specs))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                   out_specs=out_specs, check_vma=False))
         # persistent flat weight (replicated) + optimizer state (sharded).
         # States seed, in priority order, from (1) a preloaded checkpoint
         # shard (same-W shard-direct resume, mxnet_tpu.checkpoint — this

@@ -10,9 +10,9 @@ so the backward re-derives x̂ without re-reducing.
 
 Backward is a second Pallas kernel over the same row tiling: rows are
 independent, so every grid step computes its block's dx in VMEM and emits
-per-block partial dgamma/dbeta rows ((n_blocks, D), summed by XLA — a
-cheap (n_blocks, D) reduction instead of a serialized accumulator, keeping
-the grid fully parallel).
+per-block partial dgamma/dbeta tiles ((8·n_blocks, D), summed by XLA — a
+cheap reduction instead of a serialized accumulator, keeping the grid fully
+parallel).
 
 Layout: x flattened to (R, D) rows; gamma/beta (D,). ``supported`` gates
 on the TPU tiling constraints (D lane-aligned, row blocks sublane-aligned);
@@ -89,6 +89,11 @@ def _fwd_kernel(x_ref, g_ref, b_ref, y_ref, mean_ref, rstd_ref, *, eps):
     rstd_ref[...] = rstd
 
 
+def _fold_rows(v):
+    """(br, D) f32 → (8, D): row r accumulates into sublane r % 8."""
+    return jnp.sum(v.reshape(-1, 8, v.shape[-1]), axis=0)
+
+
 def _bwd_kernel(x_ref, g_ref, mean_ref, rstd_ref, dy_ref, dx_ref,
                 dg_ref, db_ref):
     x = x_ref[...].astype(jnp.float32)
@@ -96,9 +101,12 @@ def _bwd_kernel(x_ref, g_ref, mean_ref, rstd_ref, dy_ref, dx_ref,
     mean, rstd = mean_ref[...], rstd_ref[...]
     xhat = (x - mean) * rstd
     g = g_ref[...].astype(jnp.float32)
-    # per-block partial parameter grads: one (1, D) row per grid step
-    dg_ref[...] = jnp.sum(dy * xhat, axis=0, keepdims=True)
-    db_ref[...] = jnp.sum(dy, axis=0, keepdims=True)
+    # per-block partial parameter grads, one (8, D) tile per grid step: rows
+    # fold onto the 8 sublanes (whole-vreg adds, no cross-sublane reduce) and
+    # XLA sums the tiles. A (1, D) block would not lower — Mosaic wants the
+    # last two block dims in multiples of (8, 128).
+    dg_ref[...] = _fold_rows(dy * xhat)
+    db_ref[...] = _fold_rows(dy)
     dxhat = dy * g
     m1 = jnp.mean(dxhat, axis=-1, keepdims=True)
     m2 = jnp.mean(dxhat * xhat, axis=-1, keepdims=True)
@@ -158,13 +166,13 @@ def _bwd_call(x2, gamma, mean, rstd, dy2, br, interpret):
         ],
         out_specs=[
             pl.BlockSpec((br, D), lambda i: (i, 0)),
-            pl.BlockSpec((1, D), lambda i: (i, 0)),
-            pl.BlockSpec((1, D), lambda i: (i, 0)),
+            pl.BlockSpec((8, D), lambda i: (i, 0)),
+            pl.BlockSpec((8, D), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((R, D), x2.dtype),
-            jax.ShapeDtypeStruct((nb, D), jnp.float32),
-            jax.ShapeDtypeStruct((nb, D), jnp.float32),
+            jax.ShapeDtypeStruct((nb * 8, D), jnp.float32),
+            jax.ShapeDtypeStruct((nb * 8, D), jnp.float32),
         ],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,

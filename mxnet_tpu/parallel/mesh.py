@@ -13,7 +13,7 @@ import contextvars
 import numpy as np
 
 __all__ = ["make_mesh", "local_mesh", "trace_mesh", "current_trace_mesh",
-           "shard_map_compat", "MeshSpec", "parse_mesh_spec"]
+           "MeshSpec", "parse_mesh_spec"]
 
 
 class MeshSpec:
@@ -80,23 +80,6 @@ def parse_mesh_spec(spec):
         return MeshSpec(spec)
     return MeshSpec.of(spec)
 
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check=False):
-    """``shard_map`` across the jax versions this repo meets: new jax
-    exposes ``jax.shard_map`` (replication checker flag ``check_vma``),
-    0.4.x has ``jax.experimental.shard_map.shard_map`` (``check_rep``).
-    ``check=False`` disables the checker either way — the callers' specs
-    are simple enough to state outright, and pallas_call out_shapes carry
-    no vma annotation for the new checker to verify."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check)
 
 _TRACE_MESH = contextvars.ContextVar("mxtpu_trace_mesh", default=None)
 

@@ -94,10 +94,7 @@ def ring_attention(q, k, v, mesh, seq_axis="seq", causal=False, scale=None,
         B, t, H, D = qb.shape
         # initial accumulators are constants; mark them device-varying so the
         # scan carry type matches the per-shard outputs (shard_map vma check)
-        if hasattr(jax.lax, "pcast"):
-            pvary = lambda x, axes: jax.lax.pcast(x, axes, to="varying")
-        else:
-            pvary = getattr(jax.lax, "pvary", lambda x, _: x)
+        pvary = lambda x, axes: jax.lax.pcast(x, axes, to="varying")
         vary_axes = (seq_axis,) + ((batch_axis,) if batch_axis else ())
         o0 = pvary(jnp.zeros((B, t, H, D), "float32"), vary_axes)
         m0 = pvary(jnp.full((B, H, t), -jnp.inf, "float32"), vary_axes)
@@ -110,8 +107,6 @@ def ring_attention(q, k, v, mesh, seq_axis="seq", causal=False, scale=None,
         return out.astype(qb.dtype)
 
     spec = P(batch_axis, seq_axis, None, None)
-    from .mesh import shard_map_compat
-
-    fn = shard_map_compat(local, mesh=mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec, check=True)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec)
     return fn(q, k, v)

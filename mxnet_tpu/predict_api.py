@@ -26,11 +26,11 @@ def lib_path():
     return os.path.join(_BUILD_DIR, "libmxtpu_predict.so")
 
 
-def build(force=False):
+def build():
     """Compile (if stale) and return the .so path; None if no toolchain."""
     with _lock:
         inc = sysconfig.get_paths()["include"]
         libdir = sysconfig.get_config_var("LIBDIR")
         pyver = "python%d.%d" % sys.version_info[:2]
-        return build_lib(_SRC, "libmxtpu_predict.so", force=force,
+        return build_lib(_SRC, "libmxtpu_predict.so",
                          extra_flags=["-I", inc, "-L", libdir, "-l", pyver])

@@ -12,10 +12,10 @@ per-request compilation.
 Persistence (TVM's measure-and-cache discipline, PAPERS.md): the warmed
 bucket set is written as a JSON manifest under
 ``{cache_dir}/{device_kind}/{model_key}.json`` so the next process warms
-the same buckets without being told, and JAX's persistent compilation
-cache is pointed at ``{cache_dir}/xla`` so the XLA *artifacts* themselves
-survive restarts on the same device kind (compile once per fleet rollout,
-not once per process).
+the same buckets without being told. The XLA *artifacts* themselves survive
+restarts through JAX's persistent compilation cache, whose location is
+decided in ``mxnet_tpu/compile_cache.py`` (``JAX_COMPILATION_CACHE_DIR``, else
+a fixed directory in the checkout) — never here.
 """
 from __future__ import annotations
 
@@ -36,43 +36,11 @@ __all__ = ["PersistentExecutableCache", "serve_cache_dir"]
 
 log = logging.getLogger("mxnet_tpu.serving")
 
-_xla_cache_lock = _tm.named_lock("serving.cache.xla_compile")
-_xla_cache_dir = None
-
-
 def serve_cache_dir():
     """The configured on-disk cache root (``MXNET_SERVE_CACHE_DIR``), or
     None when persistence is off (the default)."""
     d = os.environ.get("MXNET_SERVE_CACHE_DIR", "").strip()
     return d or None
-
-
-def _enable_xla_persistence(root):
-    """Point JAX's persistent compilation cache at ``{root}/xla`` (once per
-    process — the setting is global). Best-effort: serving must work on jax
-    builds without the feature."""
-    global _xla_cache_dir
-    with _xla_cache_lock:
-        if _xla_cache_dir is not None:
-            return
-        import jax
-
-        target = os.path.join(root, "xla")
-        try:
-            os.makedirs(target, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", target)
-            # serving executables are small; without this the default
-            # min-compile-time floor would skip persisting exactly them
-            try:
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0)
-            except Exception:
-                pass
-            _xla_cache_dir = target
-        except Exception as exc:
-            log.warning("serving: XLA persistent cache unavailable (%s); "
-                        "manifest-only persistence", exc)
-            _xla_cache_dir = ""
 
 
 def _device_kind():
@@ -155,8 +123,6 @@ class PersistentExecutableCache:
         self._digest = digest
         self._cache_dir = cache_dir if cache_dir is not None \
             else serve_cache_dir()
-        if self._cache_dir:
-            _enable_xla_persistence(self._cache_dir)
 
     # ------------------------------------------------------------- binding
     @property

@@ -35,6 +35,7 @@ import time
 from ...base import MXNetError
 from ... import telemetry as _tm
 from ... import faultinject as _fi
+from ... import chips as _chips
 from ..engine import _env_float, _env_int
 
 __all__ = ["ReplicaSupervisor", "ReplicaHandle"]
@@ -105,6 +106,9 @@ class ReplicaSupervisor:
                 os.path.join(self.workdir, "replica-%d.port" % rid),
                 os.path.join(self.workdir, "replica-%d.hb" % rid))
             self._handles.append(h)
+        # more replicas than chips (or a parent that already holds the chip)
+        # raises here, to the caller, instead of hanging a spawn
+        self._child_env(0)
         self._lock = _tm.named_lock("fleet.supervisor")
         self._stop = threading.Event()
         self._monitor = None
@@ -119,6 +123,17 @@ class ReplicaSupervisor:
         with open(tmp, "w") as f:
             json.dump(spec, f, indent=1)
         os.replace(tmp, h.spec_path)
+
+    def _child_env(self, rid):
+        """Replica ``rid``'s environment. One process per chip: replicas
+        that open the TPU are each pinned to their own (chips.py)."""
+        env = dict(os.environ)
+        # the child must import THIS mxnet_tpu even when the parent found
+        # it via sys.path manipulation rather than an install
+        pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.dirname(os.path.abspath(__file__)))))
+        env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
+        return _chips.pin_children([env] * self.n_replicas)[rid]
 
     def _spawn_cmd(self, h: ReplicaHandle):
         """The replica launch command — a seam tests override to spawn a
@@ -137,14 +152,8 @@ class ReplicaSupervisor:
         now = time.perf_counter()
         try:
             _fi.fire("fleet.replica_spawn")
-            # the child must import THIS mxnet_tpu even when the parent
-            # found it via sys.path manipulation rather than an install
-            env = dict(os.environ)
-            pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(
-                os.path.dirname(os.path.abspath(__file__)))))
-            env["PYTHONPATH"] = pkg_root + os.pathsep + \
-                env.get("PYTHONPATH", "")
-            h.proc = subprocess.Popen(self._spawn_cmd(h), env=env)
+            h.proc = subprocess.Popen(self._spawn_cmd(h),
+                                      env=self._child_env(h.rid))
         except Exception as exc:
             # injected or organic spawn failure: back off and retry — the
             # slot is not abandoned

@@ -172,7 +172,7 @@ def test_untileable_bwd_demotes_to_xla(monkeypatch):
     """A shape the backward planner rejects must silently take the XLA vjp
     (never an in-jit assert), even with a policy forced — the full demotion
     chain stash -> recompute -> xla."""
-    monkeypatch.setattr(pcb, "_VMEM_BUDGET", 0)
+    monkeypatch.setattr(pcb, "_SCOPED_VMEM_LIMIT", 0)
     assert pcb.plan_bwd_blocks((2, 8, 8, 8), (16, 8, 1, 1)) is None
     g_pal, g_ref = _case((1, 1), (1, 1), "p", "stash", jnp.float32)
     for ga, gb in zip(g_pal, g_ref):

@@ -1,0 +1,133 @@
+"""Every kept Pallas kernel must pass Mosaic for the chip, checked here
+without one.
+
+The CPU tests run the kernels in interpret mode, which accepts block shapes
+and vector ops the TPU compiler refuses. libtpu can compile for a "TPU v5
+lite" from this sandbox through the topology API, so each kernel is lowered
+with ``interpret=False`` and compiled, forward and backward, at one
+chip_smoke.py shape. This is the guard that a kernel edited on the CPU still
+compiles on the chip (the first on-chip run found two that did not).
+"""
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from mxnet_tpu.ops import pallas_attention as pa
+from mxnet_tpu.ops import pallas_conv_bn as pc
+from mxnet_tpu.ops import pallas_matmul_bias_act as pm
+from mxnet_tpu.ops import pallas_norm_residual as pn
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """A sharding on one device of an abstract v5e 2x2 host."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 — no libtpu, or one that cannot
+        pytest.skip("libtpu cannot build the v5e topology: %s" % exc)
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def mosaic_not_interpret(monkeypatch):
+    # the kernels pick interpret mode from the default backend (CPU here)
+    for mod in (pc, pm, pn):
+        monkeypatch.setattr(mod, "_interpret_mode", lambda: False)
+
+
+def _compile(sharding, fn, *shapes_dtypes):
+    specs = [None if sd is None else
+             jax.ShapeDtypeStruct(sd[0], jnp.dtype(sd[1]), sharding=sharding)
+             for sd in shapes_dtypes]
+    lowered = jax.jit(fn).lower(*specs)
+    assert "tpu_custom_call" in lowered.as_text(), "kernel was interpreted"
+    lowered.compile()  # raises with Mosaic's message on a refusal
+
+
+def _with_grads(fn, n_args):
+    def fwd_bwd(*args):
+        return jax.grad(lambda *a: sum(
+            jnp.sum(o.astype(jnp.float32))
+            for o in jax.tree_util.tree_leaves(fn(*a))),
+            argnums=tuple(range(n_args)))(*args)
+    return fwd_bwd
+
+
+def test_flash_attention_fwd_bwd(v5e):
+    qkv = ((8, 8, 512, 64), "bfloat16")
+    fn = lambda q, k, v: pa.flash_attention(q, k, v, causal=True,
+                                            interpret=False)
+    _compile(v5e, _with_grads(fn, 3), qkv, qkv, qkv)
+
+
+def test_matmul_bias_act_fwd_bwd(v5e):
+    fn = lambda a, w, b: pm.matmul_bias_act(a, w, b, "relu")
+    _compile(v5e, _with_grads(fn, 3), ((4096, 512), "bfloat16"),
+             ((2048, 512), "bfloat16"), ((2048,), "bfloat16"))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_layer_norm_affine_fwd_bwd(v5e, dtype):
+    fn = lambda x, g, b: pn.layer_norm_affine(x, g, b, interpret=False)
+    _compile(v5e, _with_grads(fn, 3), ((4096, 512), dtype), ((512,), dtype),
+             ((512,), dtype))
+
+
+# ResNet-50 sites at the bench batch: a 1x1 with the skip add, the 3x3, and
+# the 7x7-spatial tail whose 49-wide rows pad to 128 lanes (the VMEM case)
+_CONV_SITES = [
+    ((1, 1), (1, 1), 128, 512, 28, True),
+    ((3, 3), (1, 1), 128, 128, 28, False),
+    ((1, 1), (2, 2), 1024, 2048, 14, False),
+]
+
+
+@pytest.mark.parametrize("bwd", ["xla", "recompute", "stash"])
+@pytest.mark.parametrize("site", _CONV_SITES,
+                         ids=lambda s: "k%ds%d_K%d_N%d_H%d%s" % (
+                             s[0][0], s[1][0], s[2], s[3], s[4],
+                             "_res" if s[5] else ""))
+def test_conv_bn_fwd_bwd(v5e, site, bwd):
+    kernel, stride, K, N, H, res = site
+    B, dt = 256, "bfloat16"
+    x, w = (B, K, H, H), (N, K) + kernel
+    assert pc.supported(x, w, stride, 2, True, res)
+    if bwd != "xla":
+        # what the planner passes, the compiler must accept
+        assert pc.plan_bwd_blocks(x, w, stride, 2, True, res,
+                                  stash=(bwd == "stash")) is not None
+    Ho, Wo = pc.strided_dims(H, H, stride)
+    r = ((B, N, Ho, Wo), dt) if res else None
+
+    def fn(x, w, scale, shift, r=None):
+        return pc.conv_block(x, w, scale, shift, r, kernel, stride, True,
+                             True, bwd, None)
+
+    _compile(v5e, _with_grads(fn, 5 if res else 4), (x, dt), (w, dt),
+             ((K,), "float32"), ((K,), "float32"), r)
+
+
+def test_conv_bn_infer(v5e):
+    fn = lambda x, w, scale, shift: pc.conv_block_infer(
+        x, w, scale, shift, (3, 3), (1, 1), True)
+    _compile(v5e, fn, ((256, 128, 28, 28), "bfloat16"),
+             ((128, 128, 3, 3), "bfloat16"), ((128,), "float32"),
+             ((128,), "float32"))
+
+
+def test_backward_planner_declines_what_the_compiler_refuses():
+    """k1 K64→N256 at 56² with the skip add: no lane-aligned K stripe fits
+    the scoped VMEM limit, so the planner — not a caught compile error —
+    sends the backward to XLA."""
+    x, w = (256, 64, 56, 56), (256, 64, 1, 1)
+    assert pc.supported(x, w, (1, 1), 2, True, True)
+    assert pc.plan_bwd_blocks(x, w, (1, 1), 2, True, True) is None
+    # a K stripe is the lane dim of the weight block: whole K or 128-multiples
+    assert pc.choose_bwd_blocks(256, 256, 64, 3136, 2, prologue=True) in (
+        128, 256)

@@ -48,8 +48,7 @@ def main():
     dev = jax.devices()[0]
     on_tpu = dev.platform == "tpu"
 
-    def sync(x):
-        return np.asarray(jnp.sum(x.astype(jnp.float32)))
+    sync = jax.block_until_ready  # a real barrier on the chip (docs/PERF.md §0)
 
     def timeit(fn, *arrs):
         @jax.jit

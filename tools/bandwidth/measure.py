@@ -28,11 +28,6 @@ def measure(size_mb, n_iter=10):
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
     devs = jax.local_devices()
     n = len(devs)
     mesh = Mesh(np.array(devs), ("x",))
@@ -43,8 +38,8 @@ def measure(size_mb, n_iter=10):
 
     @jax.jit
     def allreduce(v):
-        return shard_map(lambda s: jax.lax.psum(s, "x"), mesh=mesh,
-                         in_specs=P("x"), out_specs=P(None))(v)
+        return jax.shard_map(lambda s: jax.lax.psum(s, "x"), mesh=mesh,
+                             in_specs=P("x"), out_specs=P(None))(v)
 
     out = allreduce(x)  # compile + warmup
     jax.block_until_ready(out)

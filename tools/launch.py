@@ -11,7 +11,10 @@ service.
 Launchers:
   * ``local`` — N processes on this host (the reference's ``--launcher local``
     used by tests/nightly/dist_sync_kvstore.py). With ``--cpu-devices K`` each
-    worker gets K virtual CPU devices (testing without TPU hardware).
+    worker gets K virtual CPU devices and ``JAX_PLATFORMS=cpu`` (testing
+    without TPU hardware). Without it, on a TPU host, each worker is pinned
+    to its own chip (mxnet_tpu/chips.py) and more workers than chips is an
+    error: a chip belongs to one process.
   * ``ssh``  — one worker per host from --hostfile via ssh (reference's ssh
     tracker); workers see the coordinator via this host's address.
 
@@ -29,6 +32,8 @@ import signal
 import socket
 import subprocess
 import sys
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _free_port():
@@ -56,6 +61,9 @@ def _worker_env(base, args, coordinator, rank, hb_dir=None):
             flags + " --xla_force_host_platform_device_count=%d" % args.cpu_devices
         ).strip()
         env["MXNET_DEFAULT_CONTEXT"] = "cpu"
+        # a CPU worker must never open the TPU backend: a chip belongs to
+        # one process, and N workers on one chip fail or hang
+        env["JAX_PLATFORMS"] = "cpu"
     return env
 
 
@@ -184,8 +192,18 @@ def launch_local(args, command):
             if (args.heartbeat_timeout > 0 or args.elastic) else None
         procs = []
         try:
-            for rank in range(args.num_workers):
-                env = _worker_env(os.environ, args, coordinator, rank, hb_dir)
+            # workers that open the TPU get one chip each and form one job
+            # over the host's chips; more workers than chips raises here
+            envs = [_worker_env(os.environ, args, coordinator, rank, hb_dir)
+                    for rank in range(args.num_workers)]
+            if envs[0].get("JAX_PLATFORMS") != "cpu":
+                if _ROOT not in sys.path:
+                    sys.path.insert(0, _ROOT)
+                from mxnet_tpu import chips  # opens no jax backend
+
+                envs = chips.pin_children(
+                    envs, job_ports=[_free_port() for _ in envs])
+            for env in envs:
                 procs.append(subprocess.Popen(command, env=env))
             code = _wait_all(procs, hb_dir, args.heartbeat_timeout,
                              elastic=args.elastic)

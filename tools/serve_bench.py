@@ -87,19 +87,21 @@ def _model_kwargs(name):
 
 
 def _build_model(name):
+    """Symbol + random host-side params. Shapes come from ``infer_shape``,
+    not a bind: the fleet leg's parent must not touch a device its replica
+    children need (a chip belongs to one process)."""
     from mxnet_tpu import models
-    from mxnet_tpu import context as _ctx
 
     item = ITEM_SHAPES[name]
     net = models.get_symbol(name, **_model_kwargs(name))
-    probe = net.simple_bind(_ctx.current_context(), grad_req="null",
-                            data=(1,) + item)
+    arg_shapes, _, aux_shapes = net.infer_shape(data=(1,) + item)
     rs = np.random.RandomState(0)
-    arg_params = {k: (rs.randn(*a.shape) * 0.1).astype("float32")
-                  for k, a in probe.arg_dict.items()
+    arg_params = {k: (rs.randn(*shape) * 0.1).astype("float32")
+                  for k, shape in zip(net.list_arguments(), arg_shapes)
                   if k not in ("data", "softmax_label")}
-    aux_params = {k: np.abs(rs.randn(*a.shape)).astype("float32") + 0.5
-                  for k, a in probe.aux_dict.items()}
+    aux_params = {k: np.abs(rs.randn(*shape)).astype("float32") + 0.5
+                  for k, shape in zip(net.list_auxiliary_states(),
+                                      aux_shapes)}
     return net, arg_params, aux_params, item
 
 
