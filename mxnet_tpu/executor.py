@@ -20,6 +20,7 @@ Semantics kept from the reference:
 """
 from __future__ import annotations
 
+import re
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -33,12 +34,34 @@ from . import telemetry as _tm
 __all__ = ["Executor", "bind", "simple_bind"]
 
 
+def _named(fn, name):
+    """``fn`` under the name a trace shows it by: jax.jit calls the XLA
+    module ``jit_<name>`` and the host's dispatch event
+    ``PjitFunction(<name>)``. The name is part of the persistent compile
+    cache's key, so it holds nothing that differs between two runs of one
+    program (no counter, address or time)."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+def _compiled_cost(lowered):
+    """XLA's cost analysis of a lowered jit call, compiled ahead of time:
+    a dict with ``flops`` and ``bytes accessed``. The AOT compile does not
+    share jit's executable cache, so this costs one compile (or one load
+    from the persistent compile cache)."""
+    cost = lowered.compile().cost_analysis()
+    return cost[0] if isinstance(cost, (list, tuple)) else cost
+
+
 class _GraphProgram:
     """The traced interpretation of a Symbol: pure functions over arg/aux
-    tuples, compiled lazily per (is_train, shapes) by jax.jit."""
+    tuples, compiled lazily per (is_train, shapes) by jax.jit. ``label``
+    names the forward program for whoever owns it (``mx_decode``); without
+    one the programs are ``mx_<head name>_fwd`` / ``_fwd_bwd``."""
 
     def __init__(self, symbol, group2ctx=None, fusion=True):
         self.symbol = symbol
+        self.label = None  # set by the owner before the first call
         self.topo = symbol._topo()
         self.group2ctx = dict(group2ctx or {})
         # fusion plan (fusion.py): structural rewrite map covering the
@@ -154,6 +177,13 @@ class _GraphProgram:
                     % exc
         return self._retrace_reason
 
+    def program_name(self, kind):
+        """The jitted program's name: ``kind`` is ``fwd`` or ``fwd_bwd``."""
+        if self.label and kind == "fwd":
+            return self.label
+        head = re.sub(r"[^A-Za-z0-9_]", "_", self.outputs[0][0].name)
+        return "mx_%s_%s" % (head, kind)
+
     # ---------------------------------------------------------------- tracing
     def interpret(self, arg_vals, aux_vals, is_train, rng):
         """Run the graph on jax values. Returns (outputs, new_aux_tuple)."""
@@ -184,31 +214,35 @@ class _GraphProgram:
                 # the plain op-by-op lowering (byte-identical eval); generic
                 # pattern directives stay live
                 directive = None
-            if directive is not None:
-                outs, aux_out = _fusion.execute(
-                    directive, node,
-                    ins[: len(ins) - n_aux] if n_aux else ins,
-                    ins[len(ins) - n_aux :] if n_aux else [],
-                    is_train)
-                if not isinstance(outs, tuple):
-                    outs = (outs,)
-            else:
-                if fusion_on:
-                    ins = [_fusion.resolve(x) for x in ins]
-                dev = self._node_devices.get(id(node))
-                if dev is not None:
-                    # cross-device copy at a ctx-group boundary
-                    ins = [jax.device_put(x, dev) for x in ins]
-                node_rng = None
-                if opdef.needs_rng:
-                    node_rng = jax.random.fold_in(rng, self._rng_ids[id(node)])
-                outs, aux_out = opdef.apply(
-                    parsed,
-                    ins[: len(ins) - n_aux] if n_aux else ins,
-                    aux=ins[len(ins) - n_aux :] if n_aux else [],
-                    is_train=is_train,
-                    rng=node_rng,
-                )
+            # trace-time only: every HLO instruction this node lowers to
+            # carries the node's name in its op_name metadata, so a trace
+            # viewer shows which layers a fusion.N holds
+            with jax.named_scope(node.name):
+                if directive is not None:
+                    outs, aux_out = _fusion.execute(
+                        directive, node,
+                        ins[: len(ins) - n_aux] if n_aux else ins,
+                        ins[len(ins) - n_aux :] if n_aux else [],
+                        is_train)
+                    if not isinstance(outs, tuple):
+                        outs = (outs,)
+                else:
+                    if fusion_on:
+                        ins = [_fusion.resolve(x) for x in ins]
+                    dev = self._node_devices.get(id(node))
+                    if dev is not None:
+                        # cross-device copy at a ctx-group boundary
+                        ins = [jax.device_put(x, dev) for x in ins]
+                    node_rng = None
+                    if opdef.needs_rng:
+                        node_rng = jax.random.fold_in(rng, self._rng_ids[id(node)])
+                    outs, aux_out = opdef.apply(
+                        parsed,
+                        ins[: len(ins) - n_aux] if n_aux else ins,
+                        aux=ins[len(ins) - n_aux :] if n_aux else [],
+                        is_train=is_train,
+                        rng=node_rng,
+                    )
             for i, o in enumerate(outs):
                 vals[(id(node), i)] = o
             if n_aux:
@@ -233,7 +267,7 @@ class _GraphProgram:
         def run(args, aux, rng):
             return self.interpret(args, aux, is_train, rng)
 
-        self._jit_cache[key] = jax.jit(run)
+        self._jit_cache[key] = jax.jit(_named(run, self.program_name("fwd")))
         return self._jit_cache[key]
 
     def _fwd_bwd_cached(self, with_head_grads):
@@ -263,7 +297,7 @@ class _GraphProgram:
             (grads,) = vjp_fn(cot)
             return outs, grads, new_aux
 
-        return jax.jit(run)
+        return jax.jit(_named(run, self.program_name("fwd_bwd")))
 
 
 class Executor:
@@ -371,6 +405,17 @@ class Executor:
         if is_train:
             self._write_aux(new_aux)
         return self._set_outputs(outs)
+
+    def cost_analysis(self, is_train=False):
+        """XLA's cost analysis of the bound forward program at the bound
+        shapes — a dict with ``flops`` and ``bytes accessed`` (the
+        compiler's count for its own program, not the least the algorithm
+        needs). Executes nothing and draws no random key."""
+        import jax
+
+        args, aux = self._collect()
+        return _compiled_cost(self._prog._fwd(bool(is_train)).lower(
+            args, aux, jax.random.PRNGKey(0)))
 
     def _note_telemetry(self, sp, key, args, aux, extra=()):
         """Count compile/cache_hit/retrace for this call and attach the

@@ -308,7 +308,10 @@ class SPMDTrainer:
     def _build_step(self):
         import jax
 
-        return jax.jit(self._make_step_fn(), donate_argnums=(0, 1, 2))
+        from ..executor import _named
+
+        return jax.jit(_named(self._make_step_fn(), "mx_train_step"),
+                       donate_argnums=(0, 1, 2))
 
     def _build_megastep(self, n, with_lr):
         """N fused steps in ONE dispatch: a ``lax.scan`` of the SAME step
@@ -340,7 +343,10 @@ class SPMDTrainer:
                 return p, a, o, outs
             return p, a, o, outs, fvs
 
-        return jax.jit(megastep, donate_argnums=(0, 1, 2))
+        from ..executor import _named
+
+        return jax.jit(_named(megastep, "mx_train_megastep%d" % n),
+                       donate_argnums=(0, 1, 2))
 
     @property
     def _spans_processes(self):
@@ -387,18 +393,21 @@ class SPMDTrainer:
             # host-side dispatch time only: the XLA step itself is async
             sp = _tm.span("trainer.step", n=self._step_count)
         with sp:
-            placed = self._place_batch(data, label)
+            with _tm.span("trainer.place"):
+                placed = self._place_batch(data, label)
             if lr is None:
                 lr = self._opt_static_lr  # may stay None → apply() uses its own lr
             self._step_count += 1
-            res = self._step_fn(
-                self.params, self.aux, self.opt_state, placed, self._base_key,
-                None if lr is None else jnp.asarray(lr, "float32"))
-            if self._anomaly_mode is None:
-                self.params, self.aux, self.opt_state, outs = res
-            else:
-                self.params, self.aux, self.opt_state, outs, finite = res
-                self._check_anomaly(finite)
+            with _tm.span("trainer.dispatch"):
+                res = self._step_fn(
+                    self.params, self.aux, self.opt_state, placed,
+                    self._base_key,
+                    None if lr is None else jnp.asarray(lr, "float32"))
+                if self._anomaly_mode is None:
+                    self.params, self.aux, self.opt_state, outs = res
+                else:
+                    self.params, self.aux, self.opt_state, outs, finite = res
+                    self._check_anomaly(finite)
         return outs
 
     def step_many(self, data_list, label_list=None, lrs=None):
@@ -567,17 +576,17 @@ class SPMDTrainer:
         training-loop one."""
         import jax.numpy as jnp
 
+        from ..executor import _compiled_cost
+
         if not self.params and self.param_names:
             raise MXNetError("call init_params first")
         if self._step_fn is None:
             self._step_fn = self._build_step()
         placed = self._place_batch(data, label)
         lr = self._opt_static_lr
-        lowered = self._step_fn.lower(
+        return _compiled_cost(self._step_fn.lower(
             self.params, self.aux, self.opt_state, placed, self._base_key,
-            None if lr is None else jnp.asarray(lr, "float32"))
-        cost = lowered.compile().cost_analysis()
-        return cost[0] if isinstance(cost, (list, tuple)) else cost
+            None if lr is None else jnp.asarray(lr, "float32")))
 
     # ------------------------------------------------------------------ misc
     def get_params(self):

@@ -8,8 +8,16 @@ recorders:
     (viewable in TensorBoard/Perfetto, a superset of the chrome-trace
     contract), and
   * the framework telemetry spans (mxnet_tpu.telemetry) — engine/executor/
-    fusion/kvstore/io seams, forced to ``trace`` mode for the window even
-    when ``MXNET_TELEMETRY`` is off.
+    fusion/kvstore/io/serving/trainer seams, forced to ``trace`` mode for
+    the window even when ``MXNET_TELEMETRY`` is off.
+
+The two share one clock: in ``trace`` mode every telemetry span is also a
+``jax.profiler.TraceAnnotation`` of the same name, so the XLA capture's
+``.xplane.pb`` holds the framework's spans on the ``/host:CPU`` thread
+lines beside ``/device:TPU:n``'s ``XLA Ops`` (whose programs are named
+``jit_mx_decode``, ``jit_mx_train_step``...): one capture shows what the
+device ran and what the framework was doing meanwhile
+(docs/OBSERVABILITY.md).
 
 ``dump_profile()`` finalizes both and honors the reference ``MXDumpProfile``
 contract: it writes the framework spans as chrome-trace JSON to the

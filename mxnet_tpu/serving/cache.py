@@ -80,15 +80,18 @@ class PersistentExecutableCache:
     ``arg_params``/``aux_params`` are {name: NDArray-or-ndarray}; every
     symbol argument that is not a param is an INPUT whose shape the bucket
     key carries. ``model_key`` names the on-disk manifest (defaults to a
-    digest of the symbol JSON + dtype).
+    digest of the symbol JSON + dtype). ``program_label`` names the
+    compiled program in a profiler trace (``jit_<label>``; executor.py
+    ``_GraphProgram.label``).
     """
 
     def __init__(self, symbol, arg_params=None, aux_params=None, ctx=None,
                  dtype="float32", model_key=None, cache_dir=None,
-                 max_executables=None):
+                 max_executables=None, program_label=None):
         from ..context import current_context
 
         self._sym = symbol
+        self._program_label = program_label
         self._ctx = ctx or current_context()
         self._dtype = str(dtype)
         self._arg_params = dict(arg_params or {})
@@ -194,9 +197,12 @@ class PersistentExecutableCache:
         # each bucket gets its OWN graph program (no shared_exec): sharing
         # the jit entry would classify buckets 2..N's warmup compiles as
         # retraces in telemetry, polluting the zero-retrace contract
-        return self._sym.bind(self._ctx, args, args_grad=None,
-                              grad_req="null",
-                              aux_states=dict(self._shared_aux))
+        exe = self._sym.bind(self._ctx, args, args_grad=None,
+                             grad_req="null",
+                             aux_states=dict(self._shared_aux))
+        # the program is jitted at its first forward: name it before that
+        exe._prog.label = self._program_label
+        return exe
 
     def _retrace_diagnosis(self):
         try:

@@ -91,6 +91,14 @@ def _gap_return(dec):
         dec._last_return_t = time.perf_counter()
 
 
+def _swap_kv(exe, num_layers):
+    """Hand each layer's updated K/V (program outputs) back as the next
+    dispatch's inputs — device-side pointer swaps, no copy."""
+    for i in range(num_layers):
+        exe.arg_dict["kv_k_%d" % i]._set_jax(exe.outputs[1 + 2 * i]._jax())
+        exe.arg_dict["kv_v_%d" % i]._set_jax(exe.outputs[2 + 2 * i]._jax())
+
+
 # ------------------------------------------------------------------ megastep
 class _Sampler:
     """On-device sampling config for megasteps: ``greedy`` takes the
@@ -172,7 +180,7 @@ class _DecodeMegastep:
         import jax
         import jax.numpy as jnp
 
-        from ..executor import _GraphProgram
+        from ..executor import _GraphProgram, _named
         from ..models import transformer as _tf
 
         self.k = int(k)
@@ -260,7 +268,7 @@ class _DecodeMegastep:
                 body, (tok0, done0, base_mask, kvs), xs)
             return toks, acts, kv_f, done_f
 
-        self._fn = jax.jit(run)
+        self._fn = jax.jit(_named(run, "mx_megastep%d" % self.k))
         self._sig = None
 
     @staticmethod
@@ -348,7 +356,7 @@ class _ChunkProgram:
     def __init__(self, dec, t):
         import jax
 
-        from ..executor import _GraphProgram
+        from ..executor import _GraphProgram, _named
         from ..models import transformer as _tf
 
         self.t = int(t)
@@ -382,7 +390,7 @@ class _ChunkProgram:
             new_kv = tuple(outs[1 + j] for j in range(2 * L))
             return outs[0], new_kv, outs[-1]
 
-        self._fn = jax.jit(run)
+        self._fn = jax.jit(_named(run, "mx_chunk%d" % self.t))
         self._sig = None
 
     def _zero_inputs(self):
@@ -581,30 +589,29 @@ class KVCacheDecoder:
             raise MXNetError(
                 "kv_decode: position %d exceeds the trained position table "
                 "(%d rows)" % (p, self.pos_len))
-        tok = np.asarray(tokens, dtype=np.float32).reshape(self.batch, 1)
-        slot = p % S
-        oh = np.zeros((S,), np.float32)
-        oh[slot] = 1.0
-        mask = np.zeros((S,), np.float32)
-        if p + 1 < S:
-            mask[p + 1:] = _NEG  # slots beyond the history are empty
-        exe = self._dec_exe
-        exe.arg_dict["data"][:] = tok
-        exe.arg_dict["pos_idx"][:] = np.full((self.batch, 1), p, np.float32)
-        exe.arg_dict["slot_onehot"][:] = oh
-        exe.arg_dict["kv_mask"][:] = mask
+        with _tm.span("serving.step.stage"):
+            tok = np.asarray(tokens, dtype=np.float32).reshape(self.batch, 1)
+            slot = p % S
+            oh = np.zeros((S,), np.float32)
+            oh[slot] = 1.0
+            mask = np.zeros((S,), np.float32)
+            if p + 1 < S:
+                mask[p + 1:] = _NEG  # slots beyond the history are empty
+            exe = self._dec_exe
+            exe.arg_dict["data"][:] = tok
+            exe.arg_dict["pos_idx"][:] = np.full((self.batch, 1), p,
+                                                 np.float32)
+            exe.arg_dict["slot_onehot"][:] = oh
+            exe.arg_dict["kv_mask"][:] = mask
         _gap_mark(self, "serving.decode_step")
         return exe, p
 
     def _finish_step(self, exe):
         """Post-pull bookkeeping: ring KV write-back (device pointer
         swaps), position advance, counters."""
-        for i in range(self.num_layers):
-            exe.arg_dict["kv_k_%d" % i]._set_jax(
-                exe.outputs[1 + 2 * i]._jax())
-            exe.arg_dict["kv_v_%d" % i]._set_jax(
-                exe.outputs[2 + 2 * i]._jax())
-        self._pos += 1
+        with _tm.span("serving.step.commit"):
+            _swap_kv(exe, self.num_layers)
+            self._pos += 1
         if _tm.enabled():
             _tm.counter("serving.decode_tokens").inc(self.batch)
             _tm.gauge("decode.tokens_per_dispatch").set(self.batch)
@@ -617,8 +624,10 @@ class KVCacheDecoder:
         exe, p = self._stage_step(tokens)
         t0 = time.perf_counter()
         with _tm.span("serving.decode_step", rows=self.batch, pos=p):
-            exe.forward(is_train=False)
-            logits = exe.outputs[0].asnumpy()
+            with _tm.span("serving.step.dispatch"):
+                exe.forward(is_train=False)
+            with _tm.span("serving.step.read"):
+                logits = exe.outputs[0].asnumpy()
         if _tm.enabled():
             _tm.timer("serving.decode_step").add(time.perf_counter() - t0)
         _gap_return(self)
@@ -640,9 +649,11 @@ class KVCacheDecoder:
         t0 = time.perf_counter()
         with _tm.span("serving.decode_step", rows=self.batch, pos=p,
                       greedy=True):
-            exe.forward(is_train=False)
-            # graphlint: waive GL701 -- single-step tail of the megastep loop; the K-amortized body is the lax.scan in decode_megastep
-            nxt = exe.outputs[-1].asnumpy()
+            with _tm.span("serving.step.dispatch"):
+                exe.forward(is_train=False)
+            with _tm.span("serving.step.read"):
+                # graphlint: waive GL701 -- single-step tail of the megastep loop; the K-amortized body is the lax.scan in decode_megastep
+                nxt = exe.outputs[-1].asnumpy()
         if _tm.enabled():
             _tm.timer("serving.decode_step").add(time.perf_counter() - t0)
         _gap_return(self)
@@ -687,10 +698,12 @@ class KVCacheDecoder:
         _gap_mark(self, "serving.decode_megastep")
         t0 = time.perf_counter()
         with _tm.span("serving.decode_megastep", rows=B, pos=p, k=k):
-            toks, acts, new_kvs, _done = ms.run(
-                self, tok0, posv, slots, base_mask, done0, eos)
-            ids = np.asarray(toks)       # (K, B): the only host pull
-            acts_h = np.asarray(acts)
+            with _tm.span("serving.step.dispatch"):
+                toks, acts, new_kvs, _done = ms.run(
+                    self, tok0, posv, slots, base_mask, done0, eos)
+            with _tm.span("serving.step.read"):
+                ids = np.asarray(toks)       # (K, B): the only host pull
+                acts_h = np.asarray(acts)
         if _tm.enabled():
             _tm.timer("serving.decode_megastep").add(
                 time.perf_counter() - t0)
@@ -929,14 +942,15 @@ class PagedKVDecoder:
         self._pf_cache = PersistentExecutableCache(
             _tf.get_prefill_symbol(prefill_len=self.prefill_len, **cfg),
             arg_params, {}, ctx=ctx, dtype=dtype, cache_dir=cache_dir,
-            model_key=key + "-prefill")
+            model_key=key + "-prefill", program_label="mx_prefill")
         self._dec_cache = PersistentExecutableCache(
             _tf.get_decode_symbol(max_len=self.total_slots,
                                   per_stream_slots=True,
                                   global_slots=True, **cfg),
             arg_params, {}, ctx=ctx, dtype=dtype, cache_dir=cache_dir,
-            model_key=key + "-decode")
+            model_key=key + "-decode", program_label="mx_decode")
         self._dec_exe = None
+        self._decode_xla_bytes = None  # read at warmup when telemetry is on
         self._lanes: Dict[int, _Lane] = {}   # lane index -> _Lane
         self._seq_lane: Dict[int, int] = {}  # seq_id -> lane index
         self._next_seq = 0
@@ -968,6 +982,11 @@ class PagedKVDecoder:
         self._dec_cache.warmup([self._decode_shapes()])
         self._dec_exe = self._dec_cache.executable(self._decode_shapes())
         self._warm = True
+        if _tm.enabled():
+            # XLA's own byte count for one decode dispatch, read once here
+            # so step() can add it to serving.decode_xla_bytes for free
+            self._decode_xla_bytes = int(
+                self._dec_exe.cost_analysis()["bytes accessed"])
         if self._prefix is None:
             self._pf_cache.warmup([{"data": (1, self.prefill_len)}])
         else:
@@ -1110,27 +1129,31 @@ class PagedKVDecoder:
         padded[:, :L] = prompt
         with _tm.span("serving.paged_admit", seq=lane.seq_id,
                       prompt_len=L, lane=idx):
-            pf = self._pf_cache.executable(
-                {"data": (1, self.prefill_len)})
-            pf.arg_dict["data"][:] = padded
-            pf.forward(is_train=False)
-            logits = np.asarray(
-                pf.outputs[0]._jax().reshape(
-                    1, self.prefill_len, self.vocab_size)[0, L - 1, :])
+            with _tm.span("serving.admit.stage"):
+                pf = self._pf_cache.executable(
+                    {"data": (1, self.prefill_len)})
+                pf.arg_dict["data"][:] = padded
+            with _tm.span("serving.admit.prefill"):
+                pf.forward(is_train=False)
+            with _tm.span("serving.admit.logits"):
+                logits = np.asarray(
+                    pf.outputs[0]._jax().reshape(
+                        1, self.prefill_len, self.vocab_size)[0, L - 1, :])
             # scatter the prompt's K/V into THIS lane's physical
             # slots — device-side; only the last position's logits
             # crossed above
-            phys_idx = np.asarray(phys)
-            exe = self._dec_exe
-            for i in range(self.num_layers):
-                for tag, out in (("kv_k_%d" % i,
-                                  pf.outputs[1 + 2 * i]),
-                                 ("kv_v_%d" % i,
-                                  pf.outputs[2 + 2 * i])):
-                    ring = exe.arg_dict[tag]._jax()
-                    exe.arg_dict[tag]._set_jax(
-                        ring.at[:, phys_idx, :].set(
-                            out._jax()[0, :, :L, :]))
+            with _tm.span("serving.admit.scatter"):
+                phys_idx = np.asarray(phys)
+                exe = self._dec_exe
+                for i in range(self.num_layers):
+                    for tag, out in (("kv_k_%d" % i,
+                                      pf.outputs[1 + 2 * i]),
+                                     ("kv_v_%d" % i,
+                                      pf.outputs[2 + 2 * i])):
+                        ring = exe.arg_dict[tag]._jax()
+                        exe.arg_dict[tag]._set_jax(
+                            ring.at[:, phys_idx, :].set(
+                                out._jax()[0, :, :L, :]))
         return logits
 
     def _chunk_for(self, t):
@@ -1170,9 +1193,11 @@ class PagedKVDecoder:
         _gap_mark(self, "serving.chunk_prefill")
         with _tm.span("serving.chunk_prefill", t=T, rows=n,
                       write=bool(write)):
-            logits, new_kvs, _tok = prog.run(self, data, pos_idx, w_oh,
-                                             mask)
-            out = np.asarray(logits)[:n]
+            with _tm.span("serving.step.dispatch"):
+                logits, new_kvs, _tok = prog.run(self, data, pos_idx, w_oh,
+                                                 mask)
+            with _tm.span("serving.step.read"):
+                out = np.asarray(logits)[:n]
         _gap_return(self)
         if write:
             for name, arr in zip(prog.kv_names, new_kvs):
@@ -1249,6 +1274,7 @@ class PagedKVDecoder:
         idx = self._seq_lane.get(seq_id)
         if idx is None:
             raise MXNetError("paged_kv: unknown seq_id %r" % (seq_id,))
+        _tm.event("serving.retire", seq=seq_id, pos=self._lanes[idx].pos)
         self._evict(idx)
         if _tm.enabled():
             _tm.counter("serving.paged_retires").inc()
@@ -1356,56 +1382,61 @@ class PagedKVDecoder:
         self.warmup()
         if not tokens:
             return {}
-        B, S = self.lanes, self.total_slots
-        data = np.zeros((B, 1), np.float32)
-        pos_idx = np.zeros((B, 1), np.float32)
-        oh = np.zeros((B, S), np.float32)
-        mask = np.full((B, S), _NEG, np.float32)
-        stepped = []
-        for seq_id, tok in tokens.items():
-            idx = self._seq_lane.get(seq_id)
-            if idx is None:
-                raise MXNetError("paged_kv: unknown seq_id %r" % (seq_id,))
-            lane = self._lanes[idx]
-            if lane.pos >= self.pos_len:
-                raise MXNetError(
-                    "paged_kv: seq %d at position %d exceeds the trained "
-                    "position table (%d rows)"
-                    % (seq_id, lane.pos, self.pos_len))
-            phys = self._phys_slot(lane, lane.pos)
-            data[idx, 0] = float(np.asarray(tok).reshape(()))
-            pos_idx[idx, 0] = lane.pos
-            oh[idx, phys] = 1.0
-            mask[idx, self._lane_slots(lane)] = 0.0
-            mask[idx, phys] = 0.0
-            stepped.append((seq_id, idx, lane, phys))
-        exe = self._dec_exe
-        exe.arg_dict["data"][:] = data
-        exe.arg_dict["pos_idx"][:] = pos_idx
-        exe.arg_dict["slot_onehot"][:] = oh
-        exe.arg_dict["kv_mask"][:] = mask
-        _gap_mark(self, "serving.paged_step")
-        with _tm.span("serving.decode_step", rows=len(stepped),
-                      paged=True):
-            exe.forward(is_train=False)
-            # graphlint: waive GL701 -- single-step tail of the megastep loop; the K-amortized body is the lax.scan in step_megastep
-            logits = exe.outputs[0].asnumpy()
-        _gap_return(self)
-        for i in range(self.num_layers):
-            exe.arg_dict["kv_k_%d" % i]._set_jax(
-                exe.outputs[1 + 2 * i]._jax())
-            exe.arg_dict["kv_v_%d" % i]._set_jax(
-                exe.outputs[2 + 2 * i]._jax())
-        out = {}
-        for seq_id, idx, lane, phys in stepped:
-            lane.pos += 1
-            out[seq_id] = logits[idx]
-        if _tm.enabled():
-            _tm.counter("serving.decode_tokens").inc(len(stepped))
-            _tm.counter("serving.paged_steps").inc()
-            _tm.gauge("decode.tokens_per_dispatch").set(len(stepped))
-            _tm.gauge("serving.paged_pages_in_use").set(self.pool.in_use)
-        return out
+        with _tm.span("serving.paged_step", rows=len(tokens), paged=True):
+            B, S = self.lanes, self.total_slots
+            exe = self._dec_exe
+            with _tm.span("serving.step.stage"):
+                data = np.zeros((B, 1), np.float32)
+                pos_idx = np.zeros((B, 1), np.float32)
+                oh = np.zeros((B, S), np.float32)
+                mask = np.full((B, S), _NEG, np.float32)
+                stepped = []
+                for seq_id, tok in tokens.items():
+                    idx = self._seq_lane.get(seq_id)
+                    if idx is None:
+                        raise MXNetError("paged_kv: unknown seq_id %r"
+                                         % (seq_id,))
+                    lane = self._lanes[idx]
+                    if lane.pos >= self.pos_len:
+                        raise MXNetError(
+                            "paged_kv: seq %d at position %d exceeds the "
+                            "trained position table (%d rows)"
+                            % (seq_id, lane.pos, self.pos_len))
+                    phys = self._phys_slot(lane, lane.pos)
+                    data[idx, 0] = float(np.asarray(tok).reshape(()))
+                    pos_idx[idx, 0] = lane.pos
+                    oh[idx, phys] = 1.0
+                    mask[idx, self._lane_slots(lane)] = 0.0
+                    mask[idx, phys] = 0.0
+                    stepped.append((seq_id, idx, lane, phys))
+                exe.arg_dict["data"][:] = data
+                exe.arg_dict["pos_idx"][:] = pos_idx
+                exe.arg_dict["slot_onehot"][:] = oh
+                exe.arg_dict["kv_mask"][:] = mask
+            _gap_mark(self, "serving.paged_step")
+            with _tm.span("serving.decode_step", rows=len(stepped),
+                          paged=True):
+                with _tm.span("serving.step.dispatch"):
+                    exe.forward(is_train=False)
+                with _tm.span("serving.step.read"):
+                    # graphlint: waive GL701 -- single-step tail of the megastep loop; the K-amortized body is the lax.scan in step_megastep
+                    logits = exe.outputs[0].asnumpy()
+            _gap_return(self)
+            out = {}
+            with _tm.span("serving.step.commit"):
+                _swap_kv(exe, self.num_layers)
+                for seq_id, idx, lane, phys in stepped:
+                    lane.pos += 1
+                    out[seq_id] = logits[idx]
+            if _tm.enabled():
+                _tm.counter("serving.decode_tokens").inc(len(stepped))
+                _tm.counter("serving.paged_steps").inc()
+                if self._decode_xla_bytes:
+                    _tm.counter("serving.decode_xla_bytes").inc(
+                        self._decode_xla_bytes)
+                _tm.gauge("decode.tokens_per_dispatch").set(len(stepped))
+                _tm.gauge("serving.paged_pages_in_use").set(self.pool.in_use)
+            return out
 
     def step_megastep(self, tokens: Dict[int, object], k=None, eos_id=None,
                       sample=None, temperature=None, top_k=None):
@@ -1461,10 +1492,12 @@ class PagedKVDecoder:
         _gap_mark(self, "serving.paged_megastep")
         with _tm.span("serving.decode_megastep", rows=len(stepped),
                       paged=True, k=k):
-            toks, acts, new_kvs, _done = ms.run(
-                self, tok0, posv, slots, base_mask, done0, eos)
-            ids = np.asarray(toks)       # (K, B): the only host pull
-            acts_h = np.asarray(acts)
+            with _tm.span("serving.step.dispatch"):
+                toks, acts, new_kvs, _done = ms.run(
+                    self, tok0, posv, slots, base_mask, done0, eos)
+            with _tm.span("serving.step.read"):
+                ids = np.asarray(toks)       # (K, B): the only host pull
+                acts_h = np.asarray(acts)
         _gap_return(self)
         for name, arr in zip(ms.kv_names, new_kvs):
             self._dec_exe.arg_dict[name]._set_jax(arr)

@@ -10,7 +10,13 @@ Modes (``MXNET_TELEMETRY``):
   * ``counters`` — the registry (counters/gauges/timers + StepStats) is
     live, span events are NOT buffered.
   * ``trace`` — counters plus span events into a bounded ring buffer, for
-    chrome-trace export (trace.py).
+    chrome-trace export (trace.py). Each span carries a process-unique
+    ``id`` and the ``parent`` id of the span open on its thread (in its
+    attrs, so every consumer of the tuple sees them), and is ALSO entered
+    as a ``jax.profiler.TraceAnnotation`` of the same name when jax is
+    loaded: under a profiler capture the program's spans sit in the
+    ``.xplane.pb`` on the host thread lines, on the device trace's clock;
+    with no capture running the annotation is the profiler's own no-op.
 
 ``set_mode()`` overrides the env for the process (tests, profiler capture
 windows); ``None`` reverts to the env value. Span timestamps are
@@ -21,7 +27,9 @@ chrome-trace ``ts`` contract, microseconds).
 from __future__ import annotations
 
 import collections
+import itertools
 import os
+import sys
 import threading
 import time
 
@@ -201,14 +209,50 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+_span_ids = itertools.count(1)   # process-unique; next() is atomic
+
+
+class _OpenSpans(threading.local):
+    """Per thread: the ids of its open spans, innermost last."""
+
+    def __init__(self):
+        self.stack = []
+
+
+_open = _OpenSpans()
+
+_ANNOTATED = (bool, int, float, str)  # attr types the profiler keeps
+
+
+def _annotation(name, attrs):
+    """The span's twin on the profiler's clock, or None before jax is
+    imported (telemetry itself never imports it)."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    return profiler.TraceAnnotation(
+        name, **{k: v for k, v in attrs.items()
+                 if isinstance(v, _ANNOTATED)})
+
+
 class _Span:
-    __slots__ = ("name", "attrs", "_t0")
+    __slots__ = ("name", "attrs", "_t0", "_ann")
 
     def __init__(self, name, attrs):
         self.name = name
         self.attrs = attrs
 
     def __enter__(self):
+        stack = _open.stack
+        sid = self.attrs["id"] = next(_span_ids)
+        if stack:
+            self.attrs["parent"] = stack[-1]
+        stack.append(sid)
+        # the annotation encloses the perf_counter interval, so nesting
+        # reads the same in the ring buffer and in the profiler's trace
+        self._ann = _annotation(self.name, self.attrs)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -218,6 +262,11 @@ class _Span:
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        stack = _open.stack
+        if stack and stack[-1] == self.attrs["id"]:
+            stack.pop()
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
         tid = trace_context()

@@ -259,3 +259,61 @@ def test_backward_does_not_recompute_forward():
         "backward re-ran the forward %d extra time(s)" % (counters["fwd"] - 1))
     g = exe.grad_dict["fc_weight"].asnumpy()
     assert np.isfinite(g).all() and np.abs(g).sum() > 0
+
+
+# ------------------------------------------------- names in the lowered text
+_NAMED_NET = r"""
+import re, sys
+import jax
+import mxnet_tpu as mx
+for _ in range(int(sys.argv[1])):      # shift every auto-name counter
+    mx.sym.FullyConnected(mx.sym.Variable("x"), num_hidden=2)
+net = mx.sym.FullyConnected(mx.sym.Variable("data"), num_hidden=4,
+                            name="fc_head")
+net = mx.sym.SoftmaxOutput(net, name="softmax")
+exe = net.simple_bind(mx.cpu(), data=(2, 3), softmax_label=(2,))
+fwd = exe._prog._fwd(False).lower(*exe._collect(), jax.random.PRNGKey(0))
+both = exe._prog._fwd_bwd_cached(False).lower(
+    *exe._collect(), (), jax.random.PRNGKey(0))
+text = fwd.as_text(debug_info=True)
+print(re.search(r"module @(\S+)", text).group(1),
+      re.search(r"module @(\S+)", both.as_text()).group(1),
+      int("/fc_head/dot_general" in text))
+"""
+
+
+def _lowered_names(shift):
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", _NAMED_NET, str(shift)],
+                         capture_output=True, text=True, timeout=300,
+                         env=env, cwd=os.path.dirname(os.path.dirname(
+                             os.path.abspath(__file__))))
+    assert out.returncode == 0, out.stderr
+    return out.stdout.split()
+
+
+def test_jitted_programs_are_named_for_what_they_are_in_every_process():
+    """The lowered module of a bound symbol is ``jit_mx_<head>_<kind>``,
+    not ``jit_run``; a node's name is in its instructions' ``op_name``
+    metadata; and a second process, whose auto-name counters stand
+    elsewhere, gives the same names (the compile cache keys on them)."""
+    first, second = _lowered_names(0), _lowered_names(5)
+    assert first == ["jit_mx_softmax_fwd", "jit_mx_softmax_fwd_bwd", "1"]
+    assert second == first
+
+
+def test_program_label_names_the_forward_program():
+    from mxnet_tpu.executor import _GraphProgram
+
+    net = mx.sym.FullyConnected(mx.sym.Variable("data"), num_hidden=4,
+                                name="odd-name.1")
+    prog = _GraphProgram(net)
+    assert prog.program_name("fwd") == "mx_odd_name_1_fwd"
+    assert prog.program_name("fwd_bwd") == "mx_odd_name_1_fwd_bwd"
+    prog.label = "mx_decode"
+    assert prog.program_name("fwd") == "mx_decode"
+    assert prog._fwd(False).__name__ == "mx_decode"

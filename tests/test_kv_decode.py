@@ -369,3 +369,147 @@ def test_dispatch_host_gap_timer_ticks_only_when_enabled(tm):
     assert agg.total_ms > 0.0
     site = tm.timer("dispatch.host_gap.serving.decode_step")
     assert site.count == agg.count
+
+
+# ------------------------------------------------ phase spans and XLA bytes
+ADMIT_PHASES = ("stage", "prefill", "logits", "scatter")
+STEP_PHASES = ("stage", "dispatch", "read", "commit")
+
+
+def _tiny_paged(S=16):
+    from mxnet_tpu.serving import PagedKVDecoder
+
+    _, _, params = _trained_params(S)
+    return PagedKVDecoder(params, max_len=S, page_size=4, lanes=2,
+                          prefill_len=8, pos_len=S, **CFG)
+
+
+@pytest.fixture
+def traced_request(tm):
+    """One admit, two steps and a retire of a tiny paged decoder in trace
+    mode: (events oldest-first by start, counter readings around the steps,
+    the seq id)."""
+    tm.set_mode("trace")
+    dec = _tiny_paged().warmup()
+    tm.clear_events()
+    sid, logits = dec.admit(np.array([3, 1, 4, 1, 5], np.float32))
+    bytes_seen = [tm.counters().get("serving.decode_xla_bytes", 0)]
+    for _ in range(2):
+        logits = dec.step({sid: int(np.argmax(logits))})[sid]
+        bytes_seen.append(tm.counters()["serving.decode_xla_bytes"])
+    dec.retire(sid)
+    events = sorted(tm.drain_events(), key=lambda e: e[1])
+    return events, bytes_seen, sid
+
+
+def _children(events, parent):
+    pid = parent[4]["id"]
+    return [e for e in events if e[4].get("parent") == pid]
+
+
+def _inside_and_disjoint(parent, kids):
+    t = parent[1]
+    for k in sorted(kids, key=lambda e: e[1]):
+        assert t <= k[1], k[0]          # starts after the previous one ended
+        t = k[1] + k[2]
+    assert t <= parent[1] + parent[2]   # the last one ends inside the parent
+
+
+def test_admit_yields_one_span_per_phase_inside_paged_admit(traced_request):
+    events, _bytes, sid = traced_request
+    (admit,) = [e for e in events if e[0] == "serving.paged_admit"]
+    assert admit[4]["seq"] == sid and admit[4]["prompt_len"] == 5
+    kids = _children(events, admit)
+    assert [k[0] for k in kids] == ["serving.admit." + p
+                                    for p in ADMIT_PHASES]
+    _inside_and_disjoint(admit, kids)
+    # the executor's own span nests in the prefill phase, not beside it
+    (prefill,) = [k for k in kids if k[0] == "serving.admit.prefill"]
+    assert [e[0] for e in _children(events, prefill)] == ["executor.forward"]
+
+
+def test_step_yields_one_span_per_phase_inside_paged_step(traced_request):
+    events, _bytes, _sid = traced_request
+    steps = [e for e in events if e[0] == "serving.paged_step"]
+    assert len(steps) == 2
+    for step in steps:
+        assert step[4]["rows"] == 1 and step[4]["paged"] is True
+        kids = _children(events, step)
+        assert [k[0] for k in kids] == ["serving.step.stage",
+                                        "serving.decode_step",
+                                        "serving.step.commit"]
+        _inside_and_disjoint(step, kids)
+        inner = _children(events, kids[1])
+        assert [k[0] for k in inner] == ["serving.step.dispatch",
+                                         "serving.step.read"]
+        _inside_and_disjoint(kids[1], inner)
+    names = [e[0] for e in events]
+    for phase in STEP_PHASES:
+        assert names.count("serving.step." + phase) == 2
+
+
+def test_retire_event_closes_the_request(traced_request):
+    events, _bytes, sid = traced_request
+    (retire,) = [e for e in events if e[0] == "serving.retire"]
+    # 5 prompt tokens + 2 decoded: the position the lane reached
+    assert retire[4] == {"seq": sid, "pos": 7} and retire[2] == 0.0
+    assert retire[1] >= max(e[1] for e in events
+                            if e[0] == "serving.paged_step")
+
+
+def test_decode_xla_bytes_rises_by_the_program_s_count_per_step(
+        traced_request):
+    _events, seen, _sid = traced_request
+    assert seen[0] == 0                       # an admission adds none
+    assert seen[1] > 0 and seen[2] == 2 * seen[1]
+
+
+def test_executor_cost_analysis_counts_the_bound_program(tm):
+    dec = _tiny_paged().warmup()
+    assert dec._decode_xla_bytes is None      # telemetry off: never read
+    cost = dec._dec_exe.cost_analysis()
+    assert cost["flops"] > 0 and cost["bytes accessed"] > 0
+    tm.set_mode("counters")
+    assert _tiny_paged().warmup()._decode_xla_bytes == \
+        int(cost["bytes accessed"])
+
+
+def test_admit_and_step_logits_are_bitwise_the_same_with_telemetry_off(tm):
+    prompt = np.array([3, 1, 4, 1, 5], np.float32)
+
+    def serve():
+        dec = _tiny_paged().warmup()
+        sid, first = dec.admit(prompt)
+        rows = [np.asarray(first)]
+        for _ in range(3):
+            rows.append(np.asarray(
+                dec.step({sid: int(np.argmax(rows[-1]))})[sid]))
+        dec.retire(sid)
+        return np.stack(rows)
+
+    tm.set_mode("0")
+    off = serve()
+    assert tm.drain_events() == [] and tm.counters() == {}
+    tm.set_mode("trace")
+    on = serve()
+    assert tm.counters()["serving.paged_steps"] == 3
+    assert off.dtype == on.dtype and np.array_equal(off, on)
+
+
+def test_ring_decoder_shares_the_step_phase_names(tm):
+    """KVCacheDecoder's decode step has the same four phases under the
+    same names (its stage and commit are the helpers both of its step
+    methods share)."""
+    tm.set_mode("trace")
+    S = 16
+    _, _, params = _trained_params(S)
+    dec = KVCacheDecoder(params, max_len=S, prefill_len=8, pos_len=S,
+                         batch=1, **CFG)
+    logits = dec.prefill(np.array([[3, 1, 4]], np.float32))
+    tm.clear_events()
+    dec.decode_step(np.argmax(logits, axis=-1))
+    names = [e[0] for e in sorted(tm.drain_events(), key=lambda e: e[1])]
+    assert [n for n in names if n.startswith("serving.")] == [
+        "serving.step.stage", "serving.decode_step",
+        "serving.step.dispatch", "serving.step.read",
+        "serving.step.commit"]

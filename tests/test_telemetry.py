@@ -760,3 +760,200 @@ def test_slo_availability_objective(tm):
     mon.observe(available=False, t=11.0)   # replica-less tick
     r = mon.evaluate(t=11.2)
     assert not r["ok"] and r["objectives"]["avail_pct"]["firing"]
+
+
+# ------------------------------ span ids, parents, and the profiler's clock
+def _by_name(events):
+    return {e[0]: e for e in events}
+
+
+def test_span_ids_and_parents_nest_per_thread(tm):
+    """Every trace-mode span carries a process-unique ``id`` and the
+    ``parent`` id of the span open on ITS thread; roots carry no parent."""
+    tm.set_mode("trace")
+    ready = threading.Barrier(3)
+
+    def work(k):
+        with tm.span("t%d.outer" % k):
+            ready.wait(timeout=30)  # all three outers open at once
+            with tm.span("t%d.mid" % k):
+                with tm.span("t%d.leaf" % k):
+                    pass
+            with tm.span("t%d.second" % k):
+                pass
+
+    threads = [threading.Thread(target=work, args=(k,)) for k in range(3)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
+    ev = _by_name(tm.drain_events())
+    ids = [e[4]["id"] for e in ev.values()]
+    assert len(ev) == 12 and len(set(ids)) == 12
+    assert all(isinstance(i, int) for i in ids)
+    for k in range(3):
+        outer = ev["t%d.outer" % k][4]
+        assert "parent" not in outer
+        assert ev["t%d.mid" % k][4]["parent"] == outer["id"]
+        assert ev["t%d.second" % k][4]["parent"] == outer["id"]
+        assert ev["t%d.leaf" % k][4]["parent"] == ev["t%d.mid" % k][4]["id"]
+        # one thread's spans never adopt another thread's open span
+        assert len({ev["t%d.%s" % (k, n)][3]
+                    for n in ("outer", "mid", "leaf", "second")}) == 1
+
+
+def test_span_parent_stack_is_popped_when_the_body_raises(tm):
+    tm.set_mode("trace")
+    with tm.span("t.root"):
+        with pytest.raises(ValueError):
+            with tm.span("t.raises"):
+                with tm.span("t.inside"):
+                    raise ValueError("boom")
+        with tm.span("t.after"):
+            pass
+    with tm.span("t.next_root"):
+        pass
+    ev = _by_name(tm.drain_events())
+    root = ev["t.root"][4]["id"]
+    assert ev["t.raises"][4]["parent"] == root
+    assert ev["t.raises"][4]["error"] == "ValueError"
+    assert ev["t.inside"][4]["parent"] == ev["t.raises"][4]["id"]
+    # the raise left nothing on the stack: siblings and later roots are right
+    assert ev["t.after"][4]["parent"] == root
+    assert "parent" not in ev["t.next_root"][4]
+
+
+def test_ids_and_parents_reach_the_chrome_trace_unchanged(tm):
+    tm.set_mode("trace")
+    with tm.span("t.root", seq=7):
+        with tm.span("t.child"):
+            pass
+    rows = {e["name"]: e for e in telemetry.build_trace()["traceEvents"]
+            if e.get("ph") == "X"}
+    assert rows["t.root"]["args"]["seq"] == 7
+    assert rows["t.child"]["args"]["parent"] == rows["t.root"]["args"]["id"]
+
+
+@pytest.mark.parametrize("mode", ["0", "counters"])
+def test_off_and_counters_build_no_span_draw_no_id_no_annotation(
+        tm, mode, monkeypatch):
+    from mxnet_tpu.telemetry import spans
+
+    def built(*_a, **_k):
+        raise AssertionError("the %s path must not get here" % mode)
+
+    monkeypatch.setattr(spans, "_Span", built)
+    monkeypatch.setattr(spans, "_annotation", built)
+    monkeypatch.setattr(spans, "_span_ids", iter(()))  # next() would raise
+    tm.set_mode(mode)
+    with tm.span("t.off", a=1) as s:
+        s.set(b=2)
+    assert s is telemetry.NULL_SPAN
+    tm.event("t.event")
+    tm.record_span("t.rec", 0.0, 1.0)
+    assert tm.drain_events() == []
+
+
+_ALONE = r"""
+import importlib.util, os, sys
+pkg = os.path.join(sys.argv[1], "mxnet_tpu", "telemetry")
+spec = importlib.util.spec_from_file_location(
+    "telemetry_alone", os.path.join(pkg, "__init__.py"),
+    submodule_search_locations=[pkg])
+tm = importlib.util.module_from_spec(spec)
+sys.modules["telemetry_alone"] = tm
+spec.loader.exec_module(tm)
+for mode in ("0", "counters", "trace"):
+    tm.set_mode(mode)
+    with tm.span("alone.outer"):
+        with tm.span("alone.inner"):
+            tm.counter("alone.count").inc()
+names = [e[0] for e in tm.drain_events()]
+assert names == ["alone.inner", "alone.outer"], names
+loaded = sorted(m for m in sys.modules if m == "jax" or m.startswith("jax."))
+assert not loaded, loaded
+print("ok")
+"""
+
+
+def test_telemetry_alone_imports_no_jax_in_any_mode():
+    """The package by itself, outside mxnet_tpu: spans in all three modes,
+    and neither jax nor jax.profiler was imported on its account (trace
+    mode annotates only when the process has jax loaded already)."""
+    out = subprocess.run([sys.executable, "-c", _ALONE, ROOT],
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
+def _host_events(trace_dir):
+    """{name: [(thread line's index, start ns, end ns)]} of the /host:CPU
+    plane (thread lines share names, so a line is known by its place)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                out.setdefault(e.name, []).append(
+                    (i, e.start_ns, e.start_ns + e.duration_ns))
+    return out
+
+
+def test_spans_land_in_the_profiler_trace_with_the_same_nesting(tm, tmp_path):
+    """One span, two sinks: under a jax.profiler capture every ring-buffer
+    span has exactly one event of its name on a /host:CPU thread line of the
+    .xplane.pb, enclosing its children there as in the ring buffer, with a
+    duration within 0.2 ms of the perf_counter one."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    tm.set_mode("trace")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        def side():
+            with tm.span("p.side", worker=1):
+                time.sleep(0.001)
+
+        with tm.span("p.root", seq=3, label="x", ratio=0.5, flag=True,
+                     skipped=(1, 2)):       # a non-scalar attr is left out
+            with tm.span("p.sleep"):
+                time.sleep(0.002)
+            with tm.span("p.device"):
+                with tm.span("p.device.inner"):
+                    (jnp.ones((8, 8)) @ jnp.ones((8, 8))).block_until_ready()
+            t = threading.Thread(target=side)
+            t.start()
+            t.join(timeout=30)
+            assert not t.is_alive()
+    finally:
+        jax.profiler.stop_trace()
+    ring = _by_name(tm.drain_events())
+    assert set(ring) == {"p.root", "p.sleep", "p.device", "p.device.inner",
+                         "p.side"}
+    host = _host_events(str(tmp_path))
+    for name, (_n, _t0, dur, _tid, _attrs) in ring.items():
+        assert len(host.get(name, [])) == 1, (name, host.get(name))
+        _line, start, end = host[name][0]
+        assert abs((end - start) / 1e9 - dur) < 2e-4, name
+    by_id = {e[4]["id"]: e[0] for e in ring.values()}
+    for name, e in ring.items():
+        parent = by_id.get(e[4].get("parent"))
+        if parent is None:
+            continue
+        line, start, end = host[name][0]
+        pline, pstart, pend = host[parent][0]
+        assert line == pline and pstart <= start and end <= pend, name
+    assert host["p.side"][0][0] != host["p.root"][0][0]  # its own thread line
+    assert "parent" not in ring["p.side"][4]
