@@ -93,10 +93,19 @@ def _gap_return(dec):
 
 def _swap_kv(exe, num_layers):
     """Hand each layer's updated K/V (program outputs) back as the next
-    dispatch's inputs — device-side pointer swaps, no copy."""
+    dispatch's inputs — device-side pointer swaps, no copy. ``arg_dict``
+    owns the pool from here on: ``exe.outputs[1..]`` still names the same
+    arrays, and they die with the next donated update (``_AdmitScatter``),
+    so every reader takes the pool from ``arg_dict`` at the time of use."""
     for i in range(num_layers):
         exe.arg_dict["kv_k_%d" % i]._set_jax(exe.outputs[1 + 2 * i]._jax())
         exe.arg_dict["kv_v_%d" % i]._set_jax(exe.outputs[2 + 2 * i]._jax())
+
+
+def _kv_names(num_layers):
+    """The pool's argument names in program order: K then V, per layer."""
+    return [n for i in range(num_layers)
+            for n in ("kv_k_%d" % i, "kv_v_%d" % i)]
 
 
 # ------------------------------------------------------------------ megastep
@@ -203,8 +212,7 @@ class _DecodeMegastep:
         if prog.aux_names:
             raise MXNetError("decode megastep: the decode graph must carry "
                              "no aux state, got %r" % (prog.aux_names,))
-        self.kv_names = [n for i in range(L)
-                         for n in ("kv_k_%d" % i, "kv_v_%d" % i)]
+        self.kv_names = _kv_names(L)
         step_inputs = {"data", "pos_idx", "slot_onehot", "kv_mask"}
         step_inputs.update(self.kv_names)
         # weight names are shared across every serving graph — the values
@@ -371,8 +379,7 @@ class _ChunkProgram:
         if prog.aux_names:
             raise MXNetError("chunk program: the chunk graph must carry "
                              "no aux state, got %r" % (prog.aux_names,))
-        self.kv_names = [n for i in range(L)
-                         for n in ("kv_k_%d" % i, "kv_v_%d" % i)]
+        self.kv_names = _kv_names(L)
         step_inputs = {"data", "pos_idx", "write_onehot", "att_mask"}
         step_inputs.update(self.kv_names)
         self.weight_names = [n for n in prog.arg_names
@@ -437,6 +444,113 @@ class _ChunkProgram:
         kvs = tuple(dec._dec_exe.arg_dict[n]._jax() for n in self.kv_names)
         return self._fn(weights, kvs, data, pos_idx, w_oh, mask,
                         _sampling_key(dec))
+
+
+class _AdmitScatter:
+    """The pool update of a classic admission as ONE program: the 2·layers
+    pool buffers go in DONATED and come back updated in place with the
+    prefill's K/V at positions ``0..length-1`` of the lane's page frames.
+
+    ``run(kvs, new, frames, length)``: ``new`` is the prefill executable's
+    2·layers K/V outputs ``(1, H, prefill_len, dh)``, ``frames`` the lane's
+    page-frame table padded to ``ceil(prefill_len / page_size)`` entries,
+    ``length`` the prompt length. Every shape is the decoder's, none the
+    prompt's, so one compile serves every prompt length. The update walks
+    the prompt's pages with ``dynamic_update_slice`` — a page is a
+    contiguous slot run — and blends the last, partial page with what the
+    pool holds there, so exactly the slots of positions ``< length``
+    change. A scatter over the slot axis would say the same, but the TPU
+    keeps the pool with slots minor-most and re-lays the WHOLE buffer out
+    around a scatter, twice per buffer; the page walk leaves it in place.
+    Sealed like the megastep and chunk programs: one warm-time compile,
+    signature drift is a hard retrace error."""
+
+    def __init__(self, dec):
+        import jax
+        import jax.numpy as jnp
+
+        from ..executor import _named
+
+        self.kv_names = _kv_names(dec.num_layers)
+        H, dh, ps = dec.num_heads, dec.dh, dec.page_size
+        self.n_pages = -(-dec.prefill_len // ps)
+        tail = self.n_pages * ps - dec.prefill_len
+
+        def run(kvs, new, frames, length):
+            rows = [n[0] for n in new]
+            if tail:  # so a page-sized slice never runs off the end
+                rows = [jnp.pad(r, ((0, 0), (0, tail), (0, 0)))
+                        for r in rows]
+            in_page = jnp.arange(ps, dtype=jnp.int32)[None, :, None]
+
+            def page(j, kvs):
+                dst = frames[j] * ps
+                live = in_page < length - j * ps
+                out = []
+                for kv, r in zip(kvs, rows):
+                    blk = jax.lax.dynamic_slice(r, (0, j * ps, 0),
+                                                (H, ps, dh))
+                    old = jax.lax.dynamic_slice(kv, (0, dst, 0),
+                                                (H, ps, dh))
+                    out.append(jax.lax.dynamic_update_slice(
+                        kv, jnp.where(live, blk, old), (0, dst, 0)))
+                return tuple(out)
+
+            return jax.lax.fori_loop(0, (length + ps - 1) // ps, page,
+                                     tuple(kvs))
+
+        self._fn = jax.jit(_named(run, "mx_admit_scatter"),
+                           donate_argnums=(0,))
+        self._sig = None
+
+    def _call(self, dec, new, frames, length):
+        """Donate the pool, run, hand the updated buffers back: from the
+        enqueue on, the arrays ``arg_dict`` held before are dead."""
+        args = dec._dec_exe.arg_dict
+        kvs = tuple(args[n]._jax() for n in self.kv_names)
+        for name, arr in zip(self.kv_names,
+                             self._fn(kvs, new, frames, length)):
+            args[name]._set_jax(arr)
+
+    def warm(self, dec):
+        """Compile NOW on the live pool with a zero-length prompt (no page
+        is walked, every buffer comes back bitwise as it went in), counted
+        as this program's one ``executor.compile``. The K/V come from a
+        prefill staged the way an admission stages it, so the arrays are
+        of the kind a real call passes and jit never compiles again."""
+        import jax
+
+        pf = dec._pf_cache.executable({"data": (1, dec.prefill_len)})
+        pf.arg_dict["data"][:] = np.zeros((1, dec.prefill_len), np.float32)
+        pf.forward(is_train=False)
+        new = dec._prefill_kv(pf)
+        with _tm.span("serving.admit_scatter_compile"):
+            self._call(dec, new, np.zeros((self.n_pages,), np.int32),
+                       np.int32(0))
+            # graphlint: waive GL7xx -- warm-time compile barrier, not the dispatch path
+            jax.block_until_ready(
+                [dec._dec_exe.arg_dict[n]._jax() for n in self.kv_names])
+        self._sig = _DecodeMegastep._sig_of(*new)
+        if _tm.enabled():
+            _tm.counter("executor.compile").inc()
+
+    def run(self, dec, new, frames, length):
+        """One pool update, enqueued. ``frames`` is the lane's frame table
+        (any length up to ``n_pages``), ``length`` the prompt length."""
+        sig = _DecodeMegastep._sig_of(*new)
+        if sig != self._sig:
+            if _tm.enabled():
+                _tm.counter("executor.retrace").inc()
+            raise MXNetError(
+                "admit scatter: input signature drifted from the warmed "
+                "shapes (%r != %r) — the pool-update program is sealed "
+                "like the executable cache" % (sig, self._sig))
+        if _tm.enabled():
+            _tm.counter("executor.cache_hit").inc()
+            _tm.counter("serving.admit_scatter_dispatches").inc()
+        table = np.zeros((self.n_pages,), np.int32)
+        table[:len(frames)] = frames
+        self._call(dec, new, table, np.int32(length))
 
 
 class KVCacheDecoder:
@@ -958,6 +1072,7 @@ class PagedKVDecoder:
         self._last_return_t = None  # dispatch.host_gap interval start
         self._megasteps = {}        # (K, sampler) -> _DecodeMegastep
         self._chunks = {}           # T -> _ChunkProgram
+        self._admit_scatter = None  # _AdmitScatter, built in warmup
         self._sample_seed = sample_seed
         self._sample_key = None
 
@@ -973,10 +1088,11 @@ class PagedKVDecoder:
 
     def warmup(self):
         """Compile the multiplexed decode executable plus the admit-side
-        program — the batch-1 prefill bucket classically, the C-token
-        chunk program when the prefix cache is on (chunked admit never
-        touches the prefill bucket: cold and cached admits must replay
-        the SAME program for the bitwise parity gate to hold)."""
+        programs — classically the batch-1 prefill bucket and the donated
+        pool update (``_AdmitScatter``), the C-token chunk program when
+        the prefix cache is on (chunked admit never touches the prefill
+        bucket: cold and cached admits must replay the SAME program for
+        the bitwise parity gate to hold)."""
         if self._warm:
             return self
         self._dec_cache.warmup([self._decode_shapes()])
@@ -989,9 +1105,15 @@ class PagedKVDecoder:
                 self._dec_exe.cost_analysis()["bytes accessed"])
         if self._prefix is None:
             self._pf_cache.warmup([{"data": (1, self.prefill_len)}])
+            self._admit_scatter = _AdmitScatter(self)
+            self._admit_scatter.warm(self)
         else:
             self._chunk_for(self.prefix_chunk)
         return self
+
+    def _prefill_kv(self, pf):
+        """The prefill executable's K/V outputs, in the pool's order."""
+        return tuple(o._jax() for o in pf.outputs[1:1 + 2 * self.num_layers])
 
     def stats(self):
         out = {"lanes": self.lanes,
@@ -1121,10 +1243,13 @@ class PagedKVDecoder:
         return seq_id, logits
 
     def _admit_prefill(self, prompt, lane, idx):
-        """Classic admit: one batch-1 prefill dispatch, device-side
-        scatter of the prompt's K/V into the lane's physical slots."""
+        """Classic admit: one batch-1 prefill dispatch, then ONE donated
+        program that writes the prompt's K/V into the lane's page frames
+        in place (``_AdmitScatter``)."""
         L = prompt.shape[1]
-        phys = [self._phys_slot(lane, p) for p in range(L)]
+        # a frame per page of the prompt, acquired before any device work
+        for p in range(0, L, self.page_size):
+            self._phys_slot(lane, p)
         padded = np.zeros((1, self.prefill_len), np.float32)
         padded[:, :L] = prompt
         with _tm.span("serving.paged_admit", seq=lane.seq_id,
@@ -1139,21 +1264,11 @@ class PagedKVDecoder:
                 logits = np.asarray(
                     pf.outputs[0]._jax().reshape(
                         1, self.prefill_len, self.vocab_size)[0, L - 1, :])
-            # scatter the prompt's K/V into THIS lane's physical
-            # slots — device-side; only the last position's logits
-            # crossed above
+            # the pool update stays on the device; only the last
+            # position's logits crossed above
             with _tm.span("serving.admit.scatter"):
-                phys_idx = np.asarray(phys)
-                exe = self._dec_exe
-                for i in range(self.num_layers):
-                    for tag, out in (("kv_k_%d" % i,
-                                      pf.outputs[1 + 2 * i]),
-                                     ("kv_v_%d" % i,
-                                      pf.outputs[2 + 2 * i])):
-                        ring = exe.arg_dict[tag]._jax()
-                        exe.arg_dict[tag]._set_jax(
-                            ring.at[:, phys_idx, :].set(
-                                out._jax()[0, :, :L, :]))
+                self._admit_scatter.run(self, self._prefill_kv(pf),
+                                        lane.frames, L)
         return logits
 
     def _chunk_for(self, t):

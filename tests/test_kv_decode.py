@@ -513,3 +513,138 @@ def test_ring_decoder_shares_the_step_phase_names(tm):
         "serving.step.stage", "serving.decode_step",
         "serving.step.dispatch", "serving.step.read",
         "serving.step.commit"]
+
+
+# ---- the pool update of an admission: one sealed, donated program ---------
+def _paged_for_pool(lanes):
+    """Pages of 4 under a prefill window that is no multiple of a page, so
+    the program's last page-sized slice needs its pad."""
+    from mxnet_tpu.serving import PagedKVDecoder
+
+    S = 16
+    _, _, params = _trained_params(S)
+    return PagedKVDecoder(params, max_len=S, page_size=4, lanes=lanes,
+                          prefill_len=10, pos_len=S, **CFG)
+
+
+def _pool(dec):
+    """The pool's arrays through their owner, ``arg_dict``."""
+    return [dec._dec_exe.arg_dict[n]._jax()
+            for n in dec._admit_scatter.kv_names]
+
+
+@pytest.mark.parametrize("case", ["L=1", "L=page-1", "L=page", "L=page+1",
+                                  "L=prefill_len", "frames-not-contiguous"])
+def test_admit_pool_update_is_bitwise_the_op_by_op_scatter(case):
+    """After ``admit`` every pool buffer is what the parent's
+    ``ring.at[:, phys, :].set(new[0, :, :L, :])`` gave: the slots of
+    positions 0..L-1 hold the prefill's K/V, every other slot its old bits
+    (the rest of the last page included)."""
+    dec = _paged_for_pool(lanes=3).warmup()
+    page, P = dec.page_size, dec.prefill_len
+    rs = np.random.RandomState(7)
+    for name in dec._admit_scatter.kv_names:      # nothing to hide behind
+        arr = dec._dec_exe.arg_dict[name]
+        arr[:] = rs.randn(*arr.shape).astype("float32")
+    if case == "frames-not-contiguous":
+        sids = [dec.admit(np.full((page,), 1 + i, np.float32))[0]
+                for i in range(3)]
+        dec.retire(sids[1])
+        L = P
+    else:
+        L = {"L=1": 1, "L=page-1": page - 1, "L=page": page,
+             "L=page+1": page + 1, "L=prefill_len": P}[case]
+    before = [np.asarray(a).copy() for a in _pool(dec)]
+    sid, _ = dec.admit(rs.randint(1, CFG["vocab_size"], size=L)
+                       .astype(np.float32))
+    lane = dec._lanes[dec._seq_lane[sid]]
+    if case == "frames-not-contiguous":
+        assert np.any(np.abs(np.diff(lane.frames)) != 1)
+    phys = [lane.frames[p // page] * page + p % page for p in range(L)]
+    pf = dec._pf_cache.executable({"data": (1, P)})
+    for old, got, new in zip(before, _pool(dec), pf.outputs[1:]):
+        want = old.copy()
+        want[:, phys, :] = np.asarray(new._jax())[0, :, :L, :]
+        got = np.asarray(got)
+        assert got.dtype == want.dtype and np.array_equal(got, want)
+        rest = np.setdiff1d(np.arange(dec.total_slots), phys)
+        assert np.array_equal(got[:, rest, :], old[:, rest, :])
+
+
+def test_admit_scatter_is_one_sealed_donated_program(tm):
+    """One compile serves every prompt length; each admission enqueues one
+    pool-update program, which takes the pool donated: the arrays held
+    before are dead afterwards and no second copy of a buffer lives."""
+    import gc
+
+    import jax
+
+    tm.set_mode("counters")
+    dec = _paged_for_pool(lanes=5).warmup()   # a pool shape no other test has
+    prog = dec._admit_scatter
+    shape = tuple(_pool(dec)[0].shape)
+
+    def live():
+        gc.collect()
+        return sum(1 for a in jax.live_arrays() if tuple(a.shape) == shape)
+
+    def moved(since):
+        now = tm.counters()
+        return {k: now.get(k, 0) - since.get(k, 0)
+                for k in ("serving.admit_scatter_dispatches",
+                          "serving.paged_admits", "executor.compile",
+                          "executor.retrace")}
+
+    # a step first, so the decode executable's outputs ARE the pool: the
+    # state every admission after the first dispatch finds
+    sid, logits = dec.admit(np.array([3, 1, 4], np.float32))
+    dec.step({sid: int(np.argmax(logits))})
+    assert live() == 2 * dec.num_layers
+    for L in (1, 6, 10):
+        held, c0 = _pool(dec), tm.counters()
+        dec.admit(np.arange(1, L + 1, dtype=np.float32))
+        assert moved(c0) == {"serving.admit_scatter_dispatches": 1,
+                             "serving.paged_admits": 1,
+                             "executor.compile": 0, "executor.retrace": 0}
+        assert all(a.is_deleted() for a in held)
+        assert not any(a.is_deleted() for a in _pool(dec))
+        assert live() == 2 * dec.num_layers
+    assert prog._fn._cache_size() == 1        # jit's own count: one compile
+
+    # a drifted signature is the sealed-program error, before any donation
+    held, c0 = _pool(dec), tm.counters()
+    pf = dec._pf_cache.executable({"data": (1, dec.prefill_len)})
+    new = dec._prefill_kv(pf)
+    with pytest.raises(MXNetError, match="sealed"):
+        prog.run(dec, tuple(a[:, :, :-1] for a in new), [0], 1)
+    assert moved(c0)["executor.retrace"] == 1
+    assert moved(c0)["serving.admit_scatter_dispatches"] == 0
+    assert not any(a.is_deleted() for a in held)
+
+
+def test_pool_readers_after_a_donated_admit_stay_token_identical():
+    """``step``, ``fork``, ``rollback``, a megastep and a chunk dispatch
+    after an admission that donated the pool (the decode executable's
+    outputs name dead arrays by then) read the pool through ``arg_dict``
+    and reproduce ``greedy`` token for token."""
+    prompt = np.array([3, 1, 4, 1, 5], np.float32)
+    ref = _paged_for_pool(lanes=3).greedy([prompt], 6, k=1)[0]
+
+    dec = _paged_for_pool(lanes=3).warmup()
+    sid, logits = dec.admit(prompt)
+    assert int(np.argmax(logits)) == ref[0]
+    assert int(np.argmax(dec.step({sid: ref[0]})[sid])) == ref[1]
+    other, _ = dec.admit(np.array([2, 7, 1, 8, 2, 8], np.float32))
+    assert all(o._jax().is_deleted() for o in dec._dec_exe.outputs[1:-1])
+    twin = dec.fork(sid)
+    out = dec.step({sid: ref[1], twin: ref[1]})
+    assert np.array_equal(out[sid], out[twin])
+    assert int(np.argmax(out[sid])) == ref[2]
+    dec.retire(other)
+    dec.admit(np.array([9, 9, 9], np.float32))    # donates again, mid-flight
+    assert list(dec.step_megastep({sid: ref[2]}, k=2)[sid]) == list(ref[3:5])
+    rows = dec.verify_chunk(twin, ref[2:4])
+    assert [int(np.argmax(r)) for r in rows] == list(ref[3:5])
+    dec.rollback(twin, dec.position(twin) - 1)
+    assert int(np.argmax(dec.step({twin: ref[3]})[twin])) == ref[4]
+    assert int(np.argmax(dec.step({sid: ref[4]})[sid])) == ref[5]

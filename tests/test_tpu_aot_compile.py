@@ -131,3 +131,33 @@ def test_backward_planner_declines_what_the_compiler_refuses():
     # a K stripe is the lane dim of the weight block: whole K or 128-multiples
     assert pc.choose_bwd_blocks(256, 256, 64, 3136, 2, prologue=True) in (
         128, 256)
+
+
+def test_admit_pool_update_stays_in_place_on_the_chip(v5e):
+    """The pool update of an admission (serving/kv_decode.py
+    ``_AdmitScatter``) at the benchmark's sizes: all 12 pool buffers are
+    aliased to their outputs and the program holds no whole-buffer
+    temporary. The chip keeps the pool with slots minor-most, so a scatter
+    over the slot axis — the form this program replaced — re-lays every
+    134 MB buffer out before and after (269 MB of temporaries, aliased or
+    not); the page walk must not."""
+    from types import SimpleNamespace
+
+    from mxnet_tpu.serving.kv_decode import _AdmitScatter
+
+    layers, heads, dh, slots, prefill, page = 6, 8, 64, 64 * 1024, 1024, 16
+    prog = _AdmitScatter(SimpleNamespace(
+        num_layers=layers, num_heads=heads, dh=dh, page_size=page,
+        prefill_len=prefill))
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
+
+    mem = prog._fn.lower(
+        tuple(spec((heads, slots, dh), "float32") for _ in range(2 * layers)),
+        tuple(spec((1, heads, prefill, dh), "float32")
+              for _ in range(2 * layers)),
+        spec((prefill // page,), "int32"), spec((), "int32"),
+    ).compile().memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * layers * heads * slots * dh * 4
+    assert mem.temp_size_in_bytes < 1 << 20
