@@ -8,6 +8,19 @@ is mean/var composed from broadcast ops to stay faithful to the op set)."""
 import numpy as np
 
 from .. import symbol as sym
+from ..base import MXNetError
+
+ARCHS = ("vaswani", "olmoe")
+
+
+def _refuse_arch(arch, what):
+    """Everything but the serving prefill and the shared-pool decode graph
+    knows the Vaswani block only (ROADMAP D2: one block, every graph
+    derived from it)."""
+    if arch not in ARCHS:
+        raise MXNetError("unknown arch %r (have: %s)" % (arch, ", ".join(ARCHS)))
+    if arch != "vaswani":
+        raise MXNetError("%s is not built for arch %r yet" % (what, arch))
 
 
 def _layer_norm(x, name, dim):
@@ -128,6 +141,7 @@ def get_symbol_mt(vocab_size=32000, num_layers=6, num_heads=8, model_dim=512,
     lengths (pad to bucket shapes; BucketingModule handles the rest) —
     padding attends as ordinary tokens, the toy/bucketed regime this model
     targets."""
+    _refuse_arch(kwargs.get("arch", "vaswani"), "get_symbol_mt")
     src = sym.Variable("data")
     tgt = sym.Variable("dec_data")
     label = sym.Variable("softmax_label")
@@ -165,7 +179,7 @@ def get_symbol_mt(vocab_size=32000, num_layers=6, num_heads=8, model_dim=512,
 # --------------------------------------------------------------------- serving
 def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
                        model_dim=512, ffn_dim=2048, prefill_len=64,
-                       pos_len=None, **kwargs):
+                       pos_len=None, arch="vaswani", **kwargs):
     """Serving prefill graph (docs/SERVING.md): the decoder-only LM of
     ``get_symbol`` over a fixed ``prefill_len`` bucket, additionally
     exporting every layer's head-major key/value tensors so the serving
@@ -179,7 +193,20 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
 
     Outputs: ``[logits (B·P, vocab), k_0, v_0, ..., k_{L-1}, v_{L-1}]``
     with each k/v of shape (B, H, P, dh).
+
+    ``arch="olmoe"`` builds the sparse-expert block instead (``_olmoe_layer``;
+    keywords ``head_dim``, ``num_experts``, ``num_experts_per_tok``,
+    ``rope_theta``, ``rms_eps``, ``dtype``; ``ffn_dim`` is one expert's
+    width): no position table, K exported AFTER its norm and rotation, and
+    one more output after the K/V, ``moe_load (layers, experts)``: the rows
+    each expert received, padding positions included.
     """
+    if arch == "olmoe":
+        return _olmoe_prefill_symbol(
+            vocab_size=vocab_size, num_layers=num_layers,
+            num_heads=num_heads, model_dim=model_dim, ffn_dim=ffn_dim,
+            prefill_len=prefill_len, **kwargs)
+    _refuse_arch(arch, "get_prefill_symbol")
     pos_len = pos_len or prefill_len
     data = sym.Variable("data")  # (B, P) int tokens, right-padded
     embed = sym.Embedding(data=data, input_dim=vocab_size,
@@ -210,7 +237,7 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
 def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
                       model_dim=512, ffn_dim=2048, max_len=64, pos_len=None,
                       per_stream_slots=False, global_slots=False,
-                      token_out=True, **kwargs):
+                      token_out=True, arch="vaswani", **kwargs):
     """Serving single-token decode graph (docs/SERVING.md): ONE token per
     stream through the ``get_symbol`` stack, attending over a preallocated
     ring KV buffer of ``max_len`` slots per layer. Compiles ONCE — every
@@ -280,7 +307,21 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     scan carries the KV outputs back into the KV inputs and feeds each
     step's sampled token to the next, keeping the whole K-token loop
     device-resident (docs/SERVING.md §megasteps).
+
+    ``arch="olmoe"`` (``global_slots=True`` only) runs the sparse-expert
+    block of ``_olmoe_layer`` over the same shared pool: positions reach the
+    rotary operator as data (``pos_idx``), there is no position table, and
+    the pool keeps the weights' ``dtype`` while the one-hots and masks stay
+    float32 inputs.
     """
+    if arch == "olmoe":
+        if not global_slots:
+            _refuse_arch(arch, "get_decode_symbol without global_slots")
+        return _olmoe_decode_symbol(
+            vocab_size=vocab_size, num_layers=num_layers,
+            num_heads=num_heads, model_dim=model_dim, ffn_dim=ffn_dim,
+            total_slots=max_len, token_out=token_out, **kwargs)
+    _refuse_arch(arch, "get_decode_symbol")
     pos_len = pos_len or max_len
     dh = model_dim // num_heads
     scale = 1.0 / float(np.sqrt(dh))
@@ -361,6 +402,165 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
         outs.append(sym.argmax(logits, axis=-1, name="greedy_token"))
     return sym.Group(outs)
 
+# --------------------------------------------------------------------- OLMoE
+def _olmoe_layer(x, i, positions, seq_len, attend, *, num_heads, head_dim,
+                 model_dim, ffn_dim, num_experts, num_experts_per_tok,
+                 rope_theta, rms_eps):
+    """One OLMoE block on x (B, T, M) -> (x', load (E,)): pre-norm RMSNorm,
+    one bias-free qkv projection, RMSNorm over the WHOLE projected q and k
+    vectors before the split into heads, rotary positions on q and k, a
+    bias-free output projection, then the sparse-expert feed-forward.
+    ``attend(i, q, k, v)`` is the one thing the prefill and the decode
+    graph do differently: it takes the normed, rotated head-major
+    (B, H, T, dh) tensors and returns attention's (B, H, T, dh) output."""
+    name = "layer%d" % i
+    width = num_heads * head_dim
+    h = sym.RMSNorm(x, eps=rms_eps, name="%s_ln1" % name)
+    qkv = sym.FullyConnected(data=h, num_hidden=3 * width, no_bias=True,
+                             flatten=False, name="%s_qkv" % name)
+    q, k, v = (sym.slice_axis(qkv, axis=2, begin=j * width,
+                              end=(j + 1) * width) for j in range(3))
+    q = sym.RMSNorm(q, eps=rms_eps, name="%s_qnorm" % name)
+    k = sym.RMSNorm(k, eps=rms_eps, name="%s_knorm" % name)
+    q, k, v = (_split_heads(a, seq_len, num_heads, head_dim)
+               for a in (q, k, v))
+    q = sym.RotaryEmbedding(q, positions, base=rope_theta,
+                            name="%s_qrope" % name)
+    k = sym.RotaryEmbedding(k, positions, base=rope_theta,
+                            name="%s_krope" % name)
+    att = _merge_heads(attend(i, q, k, v), seq_len, width)
+    x = x + sym.FullyConnected(data=att, num_hidden=model_dim, no_bias=True,
+                               flatten=False, name="%s_proj" % name)
+    h = sym.RMSNorm(x, eps=rms_eps, name="%s_ln2" % name)
+    moe = sym.MoEFeedForward(
+        sym.Reshape(h, shape=(-1, model_dim)),
+        sym.Variable("%s_router_weight" % name),
+        sym.Variable("%s_experts_gate_weight" % name),
+        sym.Variable("%s_experts_up_weight" % name),
+        sym.Variable("%s_experts_down_weight" % name),
+        num_experts=num_experts, num_hidden=ffn_dim,
+        num_experts_per_tok=num_experts_per_tok, name="%s_moe" % name)
+    return x + sym.Reshape(moe[0], shape=(-1, seq_len, model_dim)), moe[1]
+
+
+def _olmoe_head(x, vocab_size, model_dim, rms_eps):
+    """Final norm and the untied, bias-free head; logits leave in float32
+    (the matmul's accumulator) whatever the weights' type."""
+    x = sym.RMSNorm(x, eps=rms_eps, name="final_ln")
+    return sym.FullyConnected(
+        data=sym.Reshape(x, shape=(-1, model_dim)), num_hidden=vocab_size,
+        no_bias=True, out_dtype="float32", name="lm_head")
+
+
+def _olmoe_sizes(num_heads, model_dim, ffn_dim, head_dim=None, num_experts=64,
+                 num_experts_per_tok=8, rope_theta=10000.0, rms_eps=1e-5,
+                 **kwargs):
+    """``_olmoe_layer``'s keywords from a builder's (defaults: OLMoE-1B-7B's;
+    keywords of the other architecture, such as ``pos_len``, are dropped)."""
+    return dict(num_heads=num_heads, head_dim=head_dim or model_dim // num_heads,
+                model_dim=model_dim, ffn_dim=ffn_dim, num_experts=num_experts,
+                num_experts_per_tok=num_experts_per_tok,
+                rope_theta=rope_theta, rms_eps=rms_eps)
+
+
+def _olmoe_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
+    block = _olmoe_sizes(**sizes)
+    data = sym.Variable("data")  # (B, P) token ids, right-padded
+    x = sym.Embedding(data=data, input_dim=vocab_size,
+                      output_dim=block["model_dim"], name="embed")
+    positions = sym.Reshape(sym._arange(start=0, stop=prefill_len),
+                            shape=(1, prefill_len))
+    kvs, loads = [], []
+
+    def attend(i, q, k, v):
+        kvs.extend([k, v])
+        return sym.MultiHeadAttention(query=q, key=k, value=v, causal=True,
+                                      name="layer%d_att" % i)
+
+    for i in range(num_layers):
+        x, load = _olmoe_layer(x, i, positions, prefill_len, attend, **block)
+        loads.append(sym.Reshape(load, shape=(1, -1)))
+    logits = _olmoe_head(x, vocab_size, block["model_dim"], block["rms_eps"])
+    return sym.Group([logits] + kvs
+                     + [sym.Concat(*loads, dim=0, name="moe_load")])
+
+
+def _olmoe_decode_symbol(vocab_size, num_layers, total_slots, token_out=True,
+                         dtype="float32", **sizes):
+    block = _olmoe_sizes(**sizes)
+    num_heads, dh, model_dim = (block[k] for k in ("num_heads", "head_dim",
+                                                   "model_dim"))
+    S, scale = int(total_slots), 1.0 / float(np.sqrt(dh))
+    data = sym.Variable("data")
+    pos_idx = sym.Variable("pos_idx")
+    oh = sym.Variable("slot_onehot")
+    msk3 = sym.Reshape(sym.Variable("kv_mask"), shape=(-1, 1, S))
+    # the blend runs in the pool's type, so the pool comes back in the type
+    # it went in (a float32 pool out of a bfloat16 one would retrace every
+    # step and double the cache); 0 and 1 are exact in any of them
+    oh4 = sym.Cast(sym.Reshape(oh, shape=(-1, 1, S, 1)), dtype=dtype)
+    keep3 = sym.Cast(1.0 - sym.Reshape(sym.sum(oh, axis=0),
+                                       shape=(1, S, 1)), dtype=dtype)
+    kv_outs = []
+
+    def attend(i, q, k_new, v_new):
+        """``get_decode_symbol``'s ``global_slots`` branch: every lane's new
+        K/V row is blended into its one-hot slot of the ONE pool, then every
+        lane reads the whole pool under its own mask — scores, softmax and
+        the weighted sum in float32."""
+        upd = []
+        for tag, new in (("k", k_new), ("v", v_new)):
+            pool = sym.Variable("kv_%s_%d" % (tag, i))
+            wr = sym.sum(sym.broadcast_mul(new, oh4), axis=0)
+            upd.append(sym.broadcast_add(
+                sym.broadcast_mul(pool, keep3), wr,
+                name="layer%d_%supd" % (i, tag)))
+        kv_outs.extend(upd)
+        k_att, v_att = (sym.Cast(sym.Reshape(a, shape=(-1, num_heads, S, dh)),
+                                 dtype="float32") for a in upd)
+        scores = sym.sum(sym.broadcast_mul(sym.Cast(q, dtype="float32"),
+                                           k_att), axis=3) * scale
+        p = sym.softmax(sym.broadcast_add(scores, msk3), axis=-1)  # (B,H,S)
+        ctx = sym.sum(sym.broadcast_mul(sym.expand_dims(p, axis=3), v_att),
+                      axis=2)  # (B, H, dh)
+        return sym.Cast(sym.Reshape(ctx, shape=(-1, num_heads, 1, dh)),
+                        dtype=dtype)
+
+    x = sym.Embedding(data=data, input_dim=vocab_size, output_dim=model_dim,
+                      name="embed")  # (B, 1, M)
+    for i in range(num_layers):
+        x, _ = _olmoe_layer(x, i, pos_idx, 1, attend, **block)
+    logits = _olmoe_head(x, vocab_size, model_dim, block["rms_eps"])
+    outs = [logits] + kv_outs
+    if token_out:
+        outs.append(sym.argmax(logits, axis=-1, name="greedy_token"))
+    return sym.Group(outs)
+
+
+def param_shapes(arch, vocab_size, num_layers, num_heads, model_dim, ffn_dim,
+                 head_dim=None, num_experts=64, **kwargs):
+    """{name: shape} of the checkpoint the serving graphs of ``arch`` load,
+    from the sizes alone (``olmoe`` only: the Vaswani checkpoint is spelled
+    where it always was, by its drivers and tests)."""
+    if arch != "olmoe":
+        raise MXNetError("param_shapes knows arch 'olmoe' only, not %r"
+                         % (arch,))
+    d = model_dim
+    width = num_heads * (head_dim or d // num_heads)
+    shapes = {"embed_weight": (vocab_size, d), "final_ln_gamma": (d,),
+              "lm_head_weight": (vocab_size, d)}
+    for i in range(num_layers):
+        n = "layer%d_" % i
+        shapes.update({
+            n + "ln1_gamma": (d,), n + "qkv_weight": (3 * width, d),
+            n + "qnorm_gamma": (width,), n + "knorm_gamma": (width,),
+            n + "proj_weight": (d, width), n + "ln2_gamma": (d,),
+            n + "router_weight": (num_experts, d),
+            n + "experts_gate_weight": (num_experts, d, ffn_dim),
+            n + "experts_up_weight": (num_experts, d, ffn_dim),
+            n + "experts_down_weight": (num_experts, ffn_dim, d)})
+    return shapes
+
 
 def get_chunk_symbol(vocab_size=32000, num_layers=6, num_heads=8,
                      model_dim=512, ffn_dim=2048, chunk_len=8,
@@ -397,6 +597,7 @@ def get_chunk_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     ``token_out=True`` — a trailing on-device ``chunk_token (T,)`` argmax
     head so the speculative accept loop pulls T ids, not T·vocab floats.
     """
+    _refuse_arch(kwargs.get("arch", "vaswani"), "get_chunk_symbol")
     T, S = int(chunk_len), int(total_slots)
     dh = model_dim // num_heads
     scale = 1.0 / float(np.sqrt(dh))
@@ -478,6 +679,7 @@ def draft_config(cfg, num_layers=1):
 
 def get_symbol(vocab_size=32000, num_layers=6, num_heads=8, model_dim=512,
                ffn_dim=2048, seq_len=64, **kwargs):
+    _refuse_arch(kwargs.get("arch", "vaswani"), "get_symbol")
     data = sym.Variable("data")  # (B, T) int tokens
     label = sym.Variable("softmax_label")
     embed = sym.Embedding(data=data, input_dim=vocab_size,

@@ -22,6 +22,7 @@ from . import ctc  # noqa: F401
 from . import rnn  # noqa: F401
 from . import vision  # noqa: F401
 from . import attention  # noqa: F401
+from . import moe  # noqa: F401
 from . import custom  # noqa: F401
 
 __all__ = [

@@ -89,3 +89,41 @@ def _multi_head_attention(attrs, query, key, value):
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", p, v)
     return out.astype(query.dtype)
+
+
+@register(
+    "_contrib_RMSNorm",
+    attrs={"eps": AttrSpec("float", default=1e-5)},
+    input_names=("data", "gamma"),
+    aliases=("RMSNorm",),
+)
+def _rms_norm(attrs, data, gamma):
+    """``x / sqrt(mean(x^2) + eps) * gamma`` over the last axis; the
+    statistics are float32 whatever the IO dtype."""
+    x = data.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    y = x / jnp.sqrt(ms + attrs["eps"]) * gamma.astype(jnp.float32)
+    return y.astype(data.dtype)
+
+
+@register(
+    "_contrib_RotaryEmbedding",
+    attrs={"base": AttrSpec("float", default=10000.0)},
+    input_names=("data", "positions"),
+    aliases=("RotaryEmbedding",),
+)
+def _rotary_embedding(attrs, data, positions):
+    """Rotary position embedding of ``data`` (B, H, T, dh) at ``positions``
+    (B, T), which are DATA (a decode step's lanes each sit at their own):
+    the half-split rotation (``rotate_half``: feature i pairs with
+    i + dh/2), ``inv_freq_i = base^(-2i/dh)``. Angles, sine and cosine are
+    float32 whatever the IO dtype."""
+    dh = data.shape[-1]
+    inv_freq = attrs["base"] ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
+    angle = positions.astype(jnp.float32)[:, None, :, None] * inv_freq
+    cos = jnp.concatenate([jnp.cos(angle), jnp.cos(angle)], axis=-1)
+    sin = jnp.concatenate([jnp.sin(angle), jnp.sin(angle)], axis=-1)
+    x = data.astype(jnp.float32)
+    x1, x2 = x[..., :dh // 2], x[..., dh // 2:]
+    y = x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+    return y.astype(data.dtype)

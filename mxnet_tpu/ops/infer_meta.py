@@ -175,6 +175,19 @@ register_meta("SparseEmbedding",
               dtype_policy="first",
               param_slots=("weight",), shard_rule="row_sparse_embedding",
               aliases=("row_sparse_embedding",))
+register_meta("_contrib_RMSNorm", input_ranks={"data": (1, None), "gamma": 1},
+              dtype_policy="first", param_slots=("gamma",),
+              shard_rule="elementwise", aliases=("RMSNorm",))
+register_meta("_contrib_RotaryEmbedding",
+              input_ranks={"data": 4, "positions": 2}, dtype_policy="first",
+              aliases=("RotaryEmbedding",))
+register_meta("_contrib_MoEFeedForward",
+              input_ranks={"data": 2, "router_weight": 2, "gate_weight": 3,
+                           "up_weight": 3, "down_weight": 3},
+              dtype_policy="first",
+              param_slots=("router_weight", "gate_weight", "up_weight",
+                           "down_weight"),
+              aliases=("MoEFeedForward",))
 register_meta("RNN",
               input_ranks={"data": 3, "parameters": 1,
                            "state": 3, "state_cell": 3},

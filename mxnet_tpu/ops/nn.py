@@ -35,18 +35,23 @@ def _fc_names(attrs):
         "num_hidden": AttrSpec("int", required=True),
         "no_bias": AttrSpec("bool", default=False),
         "flatten": AttrSpec("bool", default=True),
+        "out_dtype": AttrSpec("dtype", default=None),
     },
     input_names=_fc_names,
 )
 def _fully_connected(attrs, data, weight, bias=None):
     """y = x · Wᵀ + b. Batched 2D matmul → single MXU op. With flatten=False
     the matmul applies over the last axis, keeping leading axes (the later
-    reference semantics the attr advertises)."""
+    reference semantics the attr advertises). ``out_dtype`` asks the matmul
+    for its accumulator's type (bfloat16 operands, float32 logits) instead
+    of rounding the result to the operands' type."""
+    out = attrs.get("out_dtype")
     if attrs.get("flatten", True):
         x = data.reshape((data.shape[0], -1)) if data.ndim != 2 else data
-        y = jnp.dot(x, weight.T)
+        y = jnp.dot(x, weight.T, preferred_element_type=out)
     else:
-        y = jnp.einsum("...i,oi->...o", data, weight)
+        y = jnp.einsum("...i,oi->...o", data, weight,
+                       preferred_element_type=out)
     if bias is not None:
         y = y + bias
     return y

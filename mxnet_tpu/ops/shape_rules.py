@@ -109,6 +109,34 @@ def _embedding(attrs, shapes):
     return shapes
 
 
+@rule("_contrib_RMSNorm")
+@rule("RMSNorm")
+def _rms_norm(attrs, shapes):
+    if shapes[0] is not None and shapes[1] is None:
+        shapes[1] = (shapes[0][-1],)
+    return shapes
+
+
+@rule("_contrib_RotaryEmbedding")
+@rule("RotaryEmbedding")
+def _rotary(attrs, shapes):
+    if shapes[0] is not None and shapes[1] is None:
+        shapes[1] = (shapes[0][0], shapes[0][2])    # (B, H, T, dh) -> (B, T)
+    return shapes
+
+
+@rule("_contrib_MoEFeedForward")
+@rule("MoEFeedForward")
+def _moe(attrs, shapes):
+    data = shapes[0]
+    if data is not None:
+        e, f, d = attrs["num_experts"], attrs["num_hidden"], data[-1]
+        for i, s in enumerate(((e, d), (e, d, f), (e, d, f), (e, f, d)), 1):
+            if shapes[i] is None:
+                shapes[i] = s
+    return shapes
+
+
 @rule("RNN")
 def _rnn_shapes(attrs, shapes):
     data = shapes[0]

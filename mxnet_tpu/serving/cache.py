@@ -82,18 +82,23 @@ class PersistentExecutableCache:
     key carries. ``model_key`` names the on-disk manifest (defaults to a
     digest of the symbol JSON + dtype). ``program_label`` names the
     compiled program in a profiler trace (``jit_<label>``; executor.py
-    ``_GraphProgram.label``).
+    ``_GraphProgram.label``). ``dtype`` types every input;
+    ``input_dtypes`` ({name: dtype}) names the inputs that differ (a
+    bfloat16 KV pool beside float32 token ids and masks).
     """
 
     def __init__(self, symbol, arg_params=None, aux_params=None, ctx=None,
                  dtype="float32", model_key=None, cache_dir=None,
-                 max_executables=None, program_label=None):
+                 max_executables=None, program_label=None,
+                 input_dtypes=None):
         from ..context import current_context
 
         self._sym = symbol
         self._program_label = program_label
         self._ctx = ctx or current_context()
         self._dtype = str(dtype)
+        self._input_dtypes = {n: str(t) for n, t in
+                              sorted((input_dtypes or {}).items())}
         self._arg_params = dict(arg_params or {})
         self._aux_params = dict(aux_params or {})
         # ONE set of param/aux device arrays shared by every bucket
@@ -119,8 +124,10 @@ class PersistentExecutableCache:
         self._sites_lock = _tm.named_lock("serving.cache.sites")
         self._lock = _tm.named_rlock("serving.cache")
         self._sealed = False
+        typed = self._dtype + ("|%r" % self._input_dtypes
+                               if self._input_dtypes else "")
         digest = hashlib.sha1(
-            (symbol.tojson() + "|" + self._dtype).encode()).hexdigest()[:16]
+            (symbol.tojson() + "|" + typed).encode()).hexdigest()[:16]
         self._model_key = re.sub(r"[^A-Za-z0-9_.-]+", "_",
                                  model_key or digest)
         self._digest = digest
@@ -156,7 +163,7 @@ class PersistentExecutableCache:
             shapes.setdefault(n, tuple(v.shape))
             types[n] = np.dtype(getattr(v, "dtype", self._dtype)).name
         for n in shapes:
-            types.setdefault(n, self._dtype)
+            types.setdefault(n, self._input_dtypes.get(n, self._dtype))
         return self._sym._infer_impl(
             shapes, {k: np_dtype(v) for k, v in types.items()},
             partial=False)

@@ -566,9 +566,10 @@ class KVCacheDecoder:
                  max_len=64, prefill_len: Optional[int] = None,
                  pos_len: Optional[int] = None, batch=1, ctx=None,
                  dtype="float32", cache_dir=None, model_key=None,
-                 sample_seed=None):
+                 sample_seed=None, arch="vaswani"):
         from ..models import transformer as _tf
 
+        _tf._refuse_arch(arch, "KVCacheDecoder")
         self.vocab_size = int(vocab_size)
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
@@ -998,6 +999,16 @@ class PagedKVDecoder:
     all pages this way). ``rollback`` truncates a sequence by releasing
     whole rejected pages — the speculative-decoding accept/reject
     primitive (serving/speculative.py).
+
+    ``arch="olmoe"`` serves the sparse-expert block of
+    ``models/transformer.py`` (``head_dim``, ``num_experts``,
+    ``num_experts_per_tok``, ``rope_theta``, ``rms_eps``; ``ffn_dim`` is one
+    expert's width) through the same admission, pool and single-step decode.
+    It has no position table, so a lane is bounded by ``max_len`` alone;
+    ``dtype`` is then the type of the weights AND of the pool, while token
+    ids, positions, one-hots and masks stay float32 (a token id of 50,303
+    does not survive bfloat16). The prefix cache, the chunk and verify
+    programs and the megastep are not built for it yet and raise.
     """
 
     def __init__(self, arg_params: Dict[str, object], vocab_size,
@@ -1007,9 +1018,12 @@ class PagedKVDecoder:
                  pos_len: Optional[int] = None, prefix_cache=None,
                  prefix_chunk=None, ctx=None,
                  dtype="float32", cache_dir=None, model_key=None,
-                 sample_seed=None):
+                 sample_seed=None, arch="vaswani", head_dim=None,
+                 num_experts=None, num_experts_per_tok=None,
+                 rope_theta=None, rms_eps=None):
         from ..models import transformer as _tf
 
+        self.arch = arch  # an unknown one is refused by the graph builders
         self.vocab_size = int(vocab_size)
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
@@ -1018,8 +1032,10 @@ class PagedKVDecoder:
         self.max_len = int(max_len)
         self.lanes = int(lanes)
         self.prefill_len = int(prefill_len or max_len)
-        self.pos_len = int(pos_len or max_len)
-        self.dh = self.model_dim // self.num_heads
+        # rows of the trained position table; None where the architecture
+        # has none and max_len alone bounds a lane
+        self.pos_len = int(pos_len or max_len) if arch == "vaswani" else None
+        self.dh = int(head_dim or self.model_dim // self.num_heads)
         if self.prefill_len > self.max_len:
             raise MXNetError("paged_kv: prefill_len %d > max_len %d"
                              % (self.prefill_len, self.max_len))
@@ -1035,6 +1051,7 @@ class PagedKVDecoder:
         if prefix_cache:
             from .prefix_cache import PrefixCache
 
+            self._refuse_arch("prefix_cache=True")
             if prefix_chunk is None:
                 raw = os.environ.get("MXNET_SERVE_PREFIX_CHUNK",
                                      "").strip()
@@ -1053,16 +1070,30 @@ class PagedKVDecoder:
         # purpose: the decode graph's KV shapes changed, and a stale
         # on-disk cache under the old key must not satisfy this one
         key = model_key or "transformer_paged_global_decode"
+        binding = dict(ctx=ctx, dtype=dtype, cache_dir=cache_dir)
+        if arch != "vaswani":
+            given = dict(head_dim=head_dim, num_experts=num_experts,
+                         num_experts_per_tok=num_experts_per_tok,
+                         rope_theta=rope_theta, rms_eps=rms_eps)
+            cfg.update(arch=arch, dtype=dtype, **{
+                k: v for k, v in given.items() if v is not None})
+            del cfg["pos_len"]
+            if model_key is None:  # one architecture never answers for another
+                key += "-" + arch
+            # inputs are float32 whatever the weights are; only the pool
+            # takes the weights' type
+            binding.update(dtype="float32", input_dtypes={
+                n: dtype for n in _kv_names(self.num_layers)})
         self._pf_cache = PersistentExecutableCache(
             _tf.get_prefill_symbol(prefill_len=self.prefill_len, **cfg),
-            arg_params, {}, ctx=ctx, dtype=dtype, cache_dir=cache_dir,
-            model_key=key + "-prefill", program_label="mx_prefill")
+            arg_params, {}, model_key=key + "-prefill",
+            program_label="mx_prefill", **binding)
         self._dec_cache = PersistentExecutableCache(
             _tf.get_decode_symbol(max_len=self.total_slots,
                                   per_stream_slots=True,
                                   global_slots=True, **cfg),
-            arg_params, {}, ctx=ctx, dtype=dtype, cache_dir=cache_dir,
-            model_key=key + "-decode", program_label="mx_decode")
+            arg_params, {}, model_key=key + "-decode",
+            program_label="mx_decode", **binding)
         self._dec_exe = None
         self._decode_xla_bytes = None  # read at warmup when telemetry is on
         self._lanes: Dict[int, _Lane] = {}   # lane index -> _Lane
@@ -1075,6 +1106,14 @@ class PagedKVDecoder:
         self._admit_scatter = None  # _AdmitScatter, built in warmup
         self._sample_seed = sample_seed
         self._sample_key = None
+
+    def _refuse_arch(self, what):
+        """The chunk, verify and megastep programs, and with them the
+        prefix cache and speculation, know the Vaswani block only
+        (ROADMAP D2)."""
+        if self.arch != "vaswani":
+            raise MXNetError("paged_kv: %s is not built for arch %r yet"
+                             % (what, self.arch))
 
     # ------------------------------------------------------------ lifecycle
     def _decode_shapes(self):
@@ -1269,10 +1308,19 @@ class PagedKVDecoder:
             with _tm.span("serving.admit.scatter"):
                 self._admit_scatter.run(self, self._prefill_kv(pf),
                                         lane.frames, L)
+        if self.arch == "olmoe" and _tm.enabled():
+            # rows each expert received, per layer, over every position the
+            # prefill computed (padding included: the grouped matmul's work)
+            load = np.asarray(pf.outputs[1 + 2 * self.num_layers]._jax())
+            _tm.counter("serving.moe.assignments").inc(int(load.sum()))
+            _tm.counter("serving.moe.max_expert_assignments").inc(
+                int(load.max(axis=1).sum()))
         return logits
 
     def _chunk_for(self, t):
         """The sealed T-token chunk program, compiled on first use."""
+        self._refuse_arch("the chunk program (prefix cache, verify_chunk, "
+                          "speculation)")
         prog = self._chunks.get(t)
         if prog is None:
             prog = _ChunkProgram(self, t)
@@ -1464,6 +1512,7 @@ class PagedKVDecoder:
         ``step`` calls fused). Advances the position by T; the caller
         accepts a prefix and ``rollback``s the rest. Returns (T, vocab)
         logits. This is the speculative-decoding verify pass."""
+        self._refuse_arch("verify_chunk")
         self.warmup()
         idx = self._seq_lane.get(seq_id)
         if idx is None:
@@ -1512,7 +1561,8 @@ class PagedKVDecoder:
                         raise MXNetError("paged_kv: unknown seq_id %r"
                                          % (seq_id,))
                     lane = self._lanes[idx]
-                    if lane.pos >= self.pos_len:
+                    if self.pos_len is not None \
+                            and lane.pos >= self.pos_len:
                         raise MXNetError(
                             "paged_kv: seq %d at position %d exceeds the "
                             "trained position table (%d rows)"
@@ -1567,6 +1617,7 @@ class PagedKVDecoder:
         (all-zero onehot rows); with ``eos_id`` a lane that emits eos
         mid-megastep writes nothing for its remaining steps and only its
         pre-eos slots become valid. Returns {seq_id: (K,) int64 ids}."""
+        self._refuse_arch("step_megastep")
         self.warmup()
         k = int(k) if k is not None else decode_megastep_k()
         if k < 1:
