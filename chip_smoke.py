@@ -11,7 +11,9 @@ a seed), and checks what comes out by the repo's own means:
              one-device mesh — the bench's path
   2 fit      the same network through mx.mod.Module(context=mx.tpu(0)).fit
              on a synthetic iterator, f32, batch 32 — the user's path
-  3 serve    Transformer-base through serving.PagedKVDecoder (8 lanes x 1024
+  3 serve    the shared pool's write and read operators at the benchmark's
+             64 lanes x 65,536 slots (the written pool bit for bit); then
+             Transformer-base through serving.PagedKVDecoder (8 lanes x 1024
              slots): greedy tokens against a full re-forward on the device;
              then prefix cache + K-token megasteps
   4 kernels  every Pallas kernel a gate or pattern can reach, compiled by
@@ -72,6 +74,7 @@ if not REHEARSE:
         lanes=8, slots=1024, page=16, prompts=(5, 17, 40, 64, 100),
         new_tokens=33, mega_k=4, shared_prefix=48,
         tf_train_batch=8, tf_train_seq=512,
+        pool=(64, 8, 64 * 1024, 64),
         attn=(8, 8, 512, 64), mba=(4096, 512, 2048), ln=(4096, 512),
         conv_batch=256,
         conv_sites=[((1, 1), (1, 1), 128, 512, 28, True),
@@ -87,6 +90,7 @@ else:
         lanes=4, slots=64, page=8, prompts=(3, 9, 14), new_tokens=9,
         mega_k=4, shared_prefix=16,
         tf_train_batch=2, tf_train_seq=16,
+        pool=(4, 2, 64, 8),
         attn=(1, 2, 16, 8), mba=(16, 16, 128), ln=(16, 128),
         conv_batch=2,
         conv_sites=[((1, 1), (1, 1), 8, 16, 8, True),
@@ -505,9 +509,63 @@ class Reference:
         return same, n
 
 
+def check_pool_operators():
+    """The decode step's write into the shared pool and its read of it
+    (ops/attention.py), at the benchmark's lanes x heads x slots x dh. The
+    write against the blend of broadcast products it replaced, which
+    multiplies by exactly 0 and 1: bit for bit, float32 (on the chip only
+    ``Precision.HIGHEST`` keeps a row's 24 bits through the one-hot matmul)
+    and bfloat16. The read, two default-precision contractions, against the
+    same sums at the highest precision."""
+    from mxnet_tpu.ops.attention import _kv_pool_attention, _kv_pool_write
+
+    R, H, S, D = SZ["pool"]
+    rs = np.random.RandomState(11)
+    slots = rs.choice(S, R - 1, replace=False)   # the last lane is idle
+    onehot = np.zeros((R, S), "float32")
+    onehot[np.arange(R - 1), slots] = 1.0
+    mask = np.full((R, S), -1e9, "float32")
+    for r in range(R - 1):
+        mask[r, rs.choice(S, S // 64, replace=False)] = 0.0
+        mask[r, slots[r]] = 0.0
+    onehot, mask = jnp.asarray(onehot), jnp.asarray(mask)
+
+    def blend(pool, rows, onehot):
+        dt = pool.dtype
+        keep = (1.0 - jnp.sum(onehot, axis=0).reshape(1, S, 1)).astype(dt)
+        return pool * keep + jnp.sum(
+            rows[:, :, None, :] * onehot[:, None, :, None].astype(dt), axis=0)
+
+    for dt in ("float32", "bfloat16"):
+        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(len(dt)), 3)
+        pool = jax.random.normal(k1, (H, S, D), jnp.float32).astype(dt)
+        rows = jax.random.normal(k2, (R, H, D), jnp.float32).astype(dt)
+        got = jax.jit(lambda *a: _kv_pool_write({}, *a))(pool, rows, onehot)
+        bits = jnp.uint16 if dt == "bfloat16" else jnp.uint32
+        same = jax.jit(lambda a, b: jnp.all(
+            jax.lax.bitcast_convert_type(a, bits)
+            == jax.lax.bitcast_convert_type(b, bits)))
+        check(bool(same(got, jax.jit(blend)(pool, rows, onehot)))
+              and bool(same(got[:, slots], rows[:R - 1].transpose(1, 0, 2))),
+              "KVPoolWrite %s %s: %d written slots hold their rows and the "
+              "pool is the broadcast blend's, bit for bit"
+              % (dt, (H, S, D), R - 1))
+        q = jax.random.normal(k3, (R, H, D), jnp.float32).astype(dt)
+        ctx = jax.jit(lambda *a: _kv_pool_attention({"scale": -1.0}, *a))(
+            q, got, pool, mask)
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(lambda *a: _kv_pool_attention(
+                {"scale": -1.0}, *(t.astype(jnp.float32) for t in a)))(
+                    q, got, pool, mask)
+        compare("KVPoolAttention %s (one bfloat16 pass)" % dt, [ctx], [want],
+                1e-2)
+
+
 def phase_serve():
     from mxnet_tpu.serving import PagedKVDecoder
 
+    say("  -- the shared pool's write and read operators, %s" % (SZ["pool"],))
+    check_pool_operators()
     telemetry.set_mode("counters")
     ctx = mx.current_context()
     slots, lanes, page = SZ["slots"], SZ["lanes"], SZ["page"]

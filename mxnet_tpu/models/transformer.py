@@ -58,6 +58,15 @@ def _split_fused(fused, n_parts, seq_len, num_heads, dh):
     return outs
 
 
+def _split_rows(fused, n_parts, num_heads, dh):
+    """Split one fused (B, T, n_parts·M) projection into n_parts row-major
+    (B·T, H, dh) tensors: the rows the shared-pool operators take (the
+    lanes of a decode step, T = 1, or the positions of a chunk, B = 1)."""
+    fused = sym.Reshape(fused, shape=(-1, n_parts, num_heads, dh))
+    return [sym.Reshape(sym.slice_axis(fused, axis=1, begin=i, end=i + 1),
+                        shape=(-1, num_heads, dh)) for i in range(n_parts)]
+
+
 def _attention_block(x, name, num_heads, model_dim, seq_len, causal=True,
                      return_kv=False):
     """Self-attention with ONE fused 3·M-wide qkv GEMM (better MXU shape
@@ -284,10 +293,18 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     Masked empty slots contribute exp(-1e9)=0 exactly, so per-lane math
     is unchanged from the per-lane-ring variant at equal positions.
 
-    T=1 collapses attention to a masked weighted sum, so it is composed
-    from broadcast primitives (scores = Σ_d q·k, softmax, Σ_s p·v) instead
-    of the fused MultiHeadAttention op — same math, fp32-exact against the
-    full-sequence forward at matching positions.
+    T=1 collapses attention to a masked weighted sum. The shared-pool
+    variant spells it, and the write before it, as the two registry
+    operators of ops/attention.py: ``KVPoolWrite`` (a matmul with the
+    one-hots at ``Precision.HIGHEST``: the stored row is the row bit for
+    bit) and ``KVPoolAttention`` (scores and context as contractions at
+    the default matmul precision with a float32 accumulator and softmax).
+    On the CPU that is float32 arithmetic, a few ulp from the
+    full-sequence forward at matching positions; on the chip the two
+    reads are one bfloat16 pass each, as ``MultiHeadAttention`` gives the
+    same tokens in the prefill, and all four run on the matrix unit. The
+    per-lane variants (no cell runs them; ROADMAP D2 deletes them) keep
+    the broadcast products: scores = Σ_d q·k, softmax, Σ_s p·v.
 
     Outputs: ``[logits (B, vocab), k'_0, v'_0, ..., k'_{L-1}, v'_{L-1}]``,
     plus — with ``token_out=True`` (the default) — a trailing
@@ -320,7 +337,7 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
         return _olmoe_decode_symbol(
             vocab_size=vocab_size, num_layers=num_layers,
             num_heads=num_heads, model_dim=model_dim, ffn_dim=ffn_dim,
-            total_slots=max_len, token_out=token_out, **kwargs)
+            token_out=token_out, **kwargs)
     _refuse_arch(arch, "get_decode_symbol")
     pos_len = pos_len or max_len
     dh = model_dim // num_heads
@@ -329,20 +346,10 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     pos_idx = sym.Variable("pos_idx")
     oh = sym.Variable("slot_onehot")
     msk = sym.Variable("kv_mask")
-    if per_stream_slots or global_slots:
-        oh4 = sym.Reshape(oh, shape=(-1, 1, max_len, 1))
-        msk3 = sym.Reshape(msk, shape=(-1, 1, max_len))
-    else:
-        oh4 = sym.Reshape(oh, shape=(1, 1, max_len, 1))
-        msk3 = sym.Reshape(msk, shape=(1, 1, max_len))
-    if global_slots:
-        # every lane's write folds into the ONE pool: sum the per-lane
-        # onehots over the batch axis (disjoint slots, so the sum is
-        # still 0/1) for the keep mask, and sum the per-lane writes below
-        keep3 = 1.0 - sym.Reshape(sym.sum(oh, axis=0),
-                                  shape=(1, max_len, 1))
-        keep4 = None
-    else:
+    if not global_slots:    # the pool operators take both 2-D, as they are
+        lanes = -1 if per_stream_slots else 1
+        oh4 = sym.Reshape(oh, shape=(lanes, 1, max_len, 1))
+        msk3 = sym.Reshape(msk, shape=(lanes, 1, max_len))
         keep4 = 1.0 - oh4
     emb = sym.Embedding(data=data, input_dim=vocab_size,
                         output_dim=model_dim, name="embed")
@@ -355,40 +362,32 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
         ln = _layer_norm(x, "%s_ln1" % name, model_dim)
         qkv = sym.FullyConnected(data=ln, num_hidden=3 * model_dim,
                                  flatten=False, name="%s_qkv" % name)
-        q, k_new, v_new = _split_fused(qkv, 3, 1, num_heads, dh)
         kv_k = sym.Variable("kv_k_%d" % i)
         kv_v = sym.Variable("kv_v_%d" % i)
         if global_slots:
-            # pool buffers are (H, S, dh): blend each lane's (B,H,1,dh)
-            # new K/V into its onehot slot, summed over lanes (slots are
-            # writer-disjoint, so the sum IS the scatter)
-            wr_k = sym.sum(sym.broadcast_mul(k_new, oh4), axis=0)
-            wr_v = sym.sum(sym.broadcast_mul(v_new, oh4), axis=0)
-            k_upd = sym.broadcast_add(sym.broadcast_mul(kv_k, keep3),
-                                      wr_k, name="%s_kupd" % name)
-            v_upd = sym.broadcast_add(sym.broadcast_mul(kv_v, keep3),
-                                      wr_v, name="%s_vupd" % name)
-            kv_outs += [k_upd, v_upd]
-            k_att = sym.Reshape(k_upd, shape=(-1, num_heads, max_len, dh))
-            v_att = sym.Reshape(v_upd, shape=(-1, num_heads, max_len, dh))
+            # pool buffers are (H, S, dh): every lane's new K/V row lands
+            # in its one-hot slot of the ONE pool, then every lane reads
+            # the whole pool under its own mask, both as contractions
+            q, k_new, v_new = _split_rows(qkv, 3, num_heads, dh)
+            k_upd = sym.KVPoolWrite(kv_k, k_new, oh, name="%s_kupd" % name)
+            v_upd = sym.KVPoolWrite(kv_v, v_new, oh, name="%s_vupd" % name)
+            ctx = sym.KVPoolAttention(q, k_upd, v_upd, msk,
+                                      name="%s_att" % name)  # (B, H, dh)
         else:
+            q, k_new, v_new = _split_fused(qkv, 3, 1, num_heads, dh)
             k_upd = sym.broadcast_add(sym.broadcast_mul(kv_k, keep4),
                                       sym.broadcast_mul(k_new, oh4),
                                       name="%s_kupd" % name)
             v_upd = sym.broadcast_add(sym.broadcast_mul(kv_v, keep4),
                                       sym.broadcast_mul(v_new, oh4),
                                       name="%s_vupd" % name)
-            kv_outs += [k_upd, v_upd]
-            k_att, v_att = k_upd, v_upd
-        scores = sym.sum(sym.broadcast_mul(q, k_att), axis=3) * scale
-        scores = sym.broadcast_add(scores, msk3)  # (B, H, S)
-        p = sym.softmax(scores, axis=-1)
-        ctx = sym.sum(sym.broadcast_mul(sym.expand_dims(p, axis=3), v_att),
-                      axis=2)  # (B, H, dh)
-        att = sym.Reshape(
-            sym.SwapAxis(sym.Reshape(ctx, shape=(-1, num_heads, 1, dh)),
-                         dim1=1, dim2=2),
-            shape=(-1, 1, model_dim))
+            scores = sym.sum(sym.broadcast_mul(q, k_upd), axis=3) * scale
+            scores = sym.broadcast_add(scores, msk3)  # (B, H, S)
+            p = sym.softmax(scores, axis=-1)
+            ctx = sym.sum(sym.broadcast_mul(sym.expand_dims(p, axis=3),
+                                            v_upd), axis=2)  # (B, H, dh)
+        kv_outs += [k_upd, v_upd]
+        att = sym.Reshape(ctx, shape=(-1, 1, model_dim))
         x = x + sym.FullyConnected(data=att, num_hidden=model_dim,
                                    flatten=False, name="%s_proj" % name)
         x = x + _ffn(_layer_norm(x, "%s_ln2" % name, model_dim), name,
@@ -485,46 +484,31 @@ def _olmoe_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
                      + [sym.Concat(*loads, dim=0, name="moe_load")])
 
 
-def _olmoe_decode_symbol(vocab_size, num_layers, total_slots, token_out=True,
-                         dtype="float32", **sizes):
+def _olmoe_decode_symbol(vocab_size, num_layers, token_out=True, **sizes):
     block = _olmoe_sizes(**sizes)
     num_heads, dh, model_dim = (block[k] for k in ("num_heads", "head_dim",
                                                    "model_dim"))
-    S, scale = int(total_slots), 1.0 / float(np.sqrt(dh))
     data = sym.Variable("data")
     pos_idx = sym.Variable("pos_idx")
     oh = sym.Variable("slot_onehot")
-    msk3 = sym.Reshape(sym.Variable("kv_mask"), shape=(-1, 1, S))
-    # the blend runs in the pool's type, so the pool comes back in the type
-    # it went in (a float32 pool out of a bfloat16 one would retrace every
-    # step and double the cache); 0 and 1 are exact in any of them
-    oh4 = sym.Cast(sym.Reshape(oh, shape=(-1, 1, S, 1)), dtype=dtype)
-    keep3 = sym.Cast(1.0 - sym.Reshape(sym.sum(oh, axis=0),
-                                       shape=(1, S, 1)), dtype=dtype)
+    msk = sym.Variable("kv_mask")
     kv_outs = []
 
     def attend(i, q, k_new, v_new):
         """``get_decode_symbol``'s ``global_slots`` branch: every lane's new
-        K/V row is blended into its one-hot slot of the ONE pool, then every
-        lane reads the whole pool under its own mask — scores, softmax and
-        the weighted sum in float32."""
-        upd = []
-        for tag, new in (("k", k_new), ("v", v_new)):
-            pool = sym.Variable("kv_%s_%d" % (tag, i))
-            wr = sym.sum(sym.broadcast_mul(new, oh4), axis=0)
-            upd.append(sym.broadcast_add(
-                sym.broadcast_mul(pool, keep3), wr,
-                name="layer%d_%supd" % (i, tag)))
+        K/V row lands in its one-hot slot of the ONE pool, which comes back
+        in the type it went in (a float32 pool out of a bfloat16 one would
+        retrace every step and double the cache), then every lane reads the
+        whole pool under its own float32 mask."""
+        upd = [sym.KVPoolWrite(sym.Variable("kv_%s_%d" % (tag, i)),
+                               sym.Reshape(new, shape=(-1, num_heads, dh)),
+                               oh, name="layer%d_%supd" % (i, tag))
+               for tag, new in (("k", k_new), ("v", v_new))]
         kv_outs.extend(upd)
-        k_att, v_att = (sym.Cast(sym.Reshape(a, shape=(-1, num_heads, S, dh)),
-                                 dtype="float32") for a in upd)
-        scores = sym.sum(sym.broadcast_mul(sym.Cast(q, dtype="float32"),
-                                           k_att), axis=3) * scale
-        p = sym.softmax(sym.broadcast_add(scores, msk3), axis=-1)  # (B,H,S)
-        ctx = sym.sum(sym.broadcast_mul(sym.expand_dims(p, axis=3), v_att),
-                      axis=2)  # (B, H, dh)
-        return sym.Cast(sym.Reshape(ctx, shape=(-1, num_heads, 1, dh)),
-                        dtype=dtype)
+        ctx = sym.KVPoolAttention(sym.Reshape(q, shape=(-1, num_heads, dh)),
+                                  upd[0], upd[1], msk,
+                                  name="layer%d_att" % i)  # (B, H, dh)
+        return sym.Reshape(ctx, shape=(-1, num_heads, 1, dh))
 
     x = sym.Embedding(data=data, input_dim=vocab_size, output_dim=model_dim,
                       name="embed")  # (B, 1, M)
@@ -581,8 +565,10 @@ def get_chunk_symbol(vocab_size=32000, num_layers=6, num_heads=8,
         global pool. An ALL-ZERO row writes nothing — that is both the
         pad-row idiom and the zero-write REPLAY mode (a fully-cached
         prompt re-scores its last chunk against the stored pages:
-        ``kv·1 + Σ(new·0) = kv`` bitwise, so replay logits are
+        ``kv·1 + Σ(0·new) = kv`` bitwise, so replay logits are
         bit-identical to the cold chunked prefill that wrote them).
+        The write and the read are ``KVPoolWrite`` / ``KVPoolAttention``,
+        the decode graph's operators over T rows instead of B lanes.
       - ``att_mask`` (T, total_slots): additive score mask per row — 0 on
         the lane's earlier slots AND on in-chunk slots of positions
         <= row j (intra-chunk causality is enforced HERE: all T writes
@@ -598,16 +584,12 @@ def get_chunk_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     head so the speculative accept loop pulls T ids, not T·vocab floats.
     """
     _refuse_arch(kwargs.get("arch", "vaswani"), "get_chunk_symbol")
-    T, S = int(chunk_len), int(total_slots)
+    T = int(chunk_len)
     dh = model_dim // num_heads
-    scale = 1.0 / float(np.sqrt(dh))
     data = sym.Variable("data")
     pos_idx = sym.Variable("pos_idx")
     w_oh = sym.Variable("write_onehot")
     msk = sym.Variable("att_mask")
-    w4 = sym.Reshape(w_oh, shape=(1, T, S, 1))
-    keep3 = 1.0 - sym.Reshape(sym.sum(w_oh, axis=0), shape=(1, S, 1))
-    msk3 = sym.Reshape(msk, shape=(1, T, S))
     emb = sym.Embedding(data=data, input_dim=vocab_size,
                         output_dim=model_dim, name="embed")
     posrow = sym.Embedding(data=pos_idx, input_dim=pos_len,
@@ -619,33 +601,18 @@ def get_chunk_symbol(vocab_size=32000, num_layers=6, num_heads=8,
         ln = _layer_norm(x, "%s_ln1" % name, model_dim)
         qkv = sym.FullyConnected(data=ln, num_hidden=3 * model_dim,
                                  flatten=False, name="%s_qkv" % name)
-        q, k_new, v_new = _split_fused(qkv, 3, T, num_heads, dh)
-        kv_k = sym.Variable("kv_k_%d" % i)
-        kv_v = sym.Variable("kv_v_%d" % i)
-        # scatter the T new rows into the pool: (H,T,1,dh)·(1,T,S,1)
-        # summed over the row axis — writer-disjoint slots, so the sum
-        # IS the scatter (all-zero rows vanish)
-        k_rows = sym.Reshape(k_new, shape=(num_heads, T, 1, dh))
-        v_rows = sym.Reshape(v_new, shape=(num_heads, T, 1, dh))
-        wr_k = sym.sum(sym.broadcast_mul(k_rows, w4), axis=1)
-        wr_v = sym.sum(sym.broadcast_mul(v_rows, w4), axis=1)
-        k_upd = sym.broadcast_add(sym.broadcast_mul(kv_k, keep3), wr_k,
-                                  name="%s_kupd" % name)
-        v_upd = sym.broadcast_add(sym.broadcast_mul(kv_v, keep3), wr_v,
-                                  name="%s_vupd" % name)
+        q, k_new, v_new = _split_rows(qkv, 3, num_heads, dh)
+        # the T new rows land in their slots of the pool (writer-disjoint,
+        # all-zero rows vanish), then every row reads the pool: the decode
+        # graph's two operators, the rows being positions here, not lanes
+        k_upd = sym.KVPoolWrite(sym.Variable("kv_k_%d" % i), k_new, w_oh,
+                                name="%s_kupd" % name)
+        v_upd = sym.KVPoolWrite(sym.Variable("kv_v_%d" % i), v_new, w_oh,
+                                name="%s_vupd" % name)
         kv_outs += [k_upd, v_upd]
-        q4 = sym.Reshape(q, shape=(num_heads, T, 1, dh))
-        k4 = sym.Reshape(k_upd, shape=(num_heads, 1, S, dh))
-        v4 = sym.Reshape(v_upd, shape=(num_heads, 1, S, dh))
-        scores = sym.sum(sym.broadcast_mul(q4, k4), axis=3) * scale
-        scores = sym.broadcast_add(scores, msk3)  # (H, T, S)
-        p = sym.softmax(scores, axis=-1)
-        ctx = sym.sum(sym.broadcast_mul(sym.expand_dims(p, axis=3), v4),
-                      axis=2)  # (H, T, dh)
-        att = sym.Reshape(
-            sym.SwapAxis(sym.Reshape(ctx, shape=(-1, num_heads, T, dh)),
-                         dim1=1, dim2=2),
-            shape=(-1, T, model_dim))
+        ctx = sym.KVPoolAttention(q, k_upd, v_upd, msk,
+                                  name="%s_att" % name)  # (T, H, dh)
+        att = sym.Reshape(ctx, shape=(-1, T, model_dim))
         x = x + sym.FullyConnected(data=att, num_hidden=model_dim,
                                    flatten=False, name="%s_proj" % name)
         x = x + _ffn(_layer_norm(x, "%s_ln2" % name, model_dim), name,

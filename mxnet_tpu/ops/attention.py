@@ -127,3 +127,56 @@ def _rotary_embedding(attrs, data, positions):
     x1, x2 = x[..., :dh // 2], x[..., dh // 2:]
     y = x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
     return y.astype(data.dtype)
+
+
+@register(
+    "_contrib_KVPoolWrite",
+    input_names=("pool", "rows", "onehot"),
+    aliases=("KVPoolWrite",),
+)
+def _kv_pool_write(attrs, pool, rows, onehot):
+    """The write into the shared KV pool of the paged decoder: ``pool``
+    (H, S, dh), ``rows`` (R, H, dh) and ``onehot`` (R, S), row r's slot,
+    give ``pool * (1 - sum_r onehot) + einsum('rs,rhd->hsd', onehot, rows)``
+    in the pool's dtype. Rows are the lanes of a decode step or the
+    positions of a chunk; their slots are disjoint, so the matmul with the
+    one-hots IS the scatter, and an all-zero one-hot row writes nothing.
+
+    A stored row is the row bit for bit. On the chip a float32 matmul at
+    the default precision would round it to bfloat16, so the contraction
+    asks for ``Precision.HIGHEST``: the one-hot is exact in one bfloat16
+    piece and the row's three pieces add back to the float32 value. A
+    bfloat16 pool is exact in one pass: a sum of one row and zeros rounds
+    nowhere, whatever the accumulator."""
+    dt = pool.dtype
+    keep = (1.0 - jnp.sum(onehot, axis=0)).astype(dt)
+    written = jnp.einsum("rs,rhd->shd", onehot.astype(dt), rows.astype(dt),
+                         precision=jax.lax.Precision.HIGHEST)
+    return pool * keep[None, :, None] + written.transpose(1, 0, 2)
+
+
+@register(
+    "_contrib_KVPoolAttention",
+    attrs={"scale": AttrSpec("float", default=-1.0)},
+    input_names=("query", "pool_k", "pool_v", "mask"),
+    aliases=("KVPoolAttention",),
+)
+def _kv_pool_attention(attrs, query, pool_k, pool_v, mask):
+    """The read of the shared KV pool: every row of ``query`` (R, H, dh)
+    attends the whole of ``pool_k`` / ``pool_v`` (H, S, dh) under its own
+    additive ``mask`` (R, S): ``softmax(einsum('rhd,hsd->rhs') * scale +
+    mask)`` then ``einsum('rhs,hsd->rhd')``. Both contractions run on the
+    matrix unit at the default matmul precision (what
+    ``_multi_head_attention`` gives the same tokens in the prefill) with a
+    float32 accumulator, and the softmax is float32 whatever the pool's
+    dtype. A fully masked row comes out finite: the softmax subtracts the
+    row's maximum first."""
+    scale = attrs["scale"] if attrs["scale"] > 0 \
+        else 1.0 / np.sqrt(query.shape[-1])
+    s = jnp.einsum("rhd,hsd->rhs", query, pool_k,
+                   preferred_element_type=jnp.float32)
+    p = jax.nn.softmax(s * scale + mask.astype(jnp.float32)[:, None, :],
+                       axis=-1)
+    out = jnp.einsum("rhs,hsd->rhd", p, pool_v,
+                     preferred_element_type=jnp.float32)
+    return out.astype(query.dtype)

@@ -181,6 +181,12 @@ register_meta("_contrib_RMSNorm", input_ranks={"data": (1, None), "gamma": 1},
 register_meta("_contrib_RotaryEmbedding",
               input_ranks={"data": 4, "positions": 2}, dtype_policy="first",
               aliases=("RotaryEmbedding",))
+register_meta("_contrib_KVPoolWrite",
+              input_ranks={"pool": 3, "rows": 3, "onehot": 2},
+              dtype_policy="first", aliases=("KVPoolWrite",))
+register_meta("_contrib_KVPoolAttention",
+              input_ranks={"query": 3, "pool_k": 3, "pool_v": 3, "mask": 2},
+              dtype_policy="first", aliases=("KVPoolAttention",))
 register_meta("_contrib_MoEFeedForward",
               input_ranks={"data": 2, "router_weight": 2, "gate_weight": 3,
                            "up_weight": 3, "down_weight": 3},

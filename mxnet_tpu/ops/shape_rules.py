@@ -125,6 +125,29 @@ def _rotary(attrs, shapes):
     return shapes
 
 
+@rule("_contrib_KVPoolWrite")
+@rule("KVPoolWrite")
+def _kv_pool_write(attrs, shapes):
+    pool, rows, onehot = shapes     # (H, S, dh), (R, H, dh), (R, S)
+    if pool is None and rows is not None and onehot is not None:
+        shapes[0] = (rows[1], onehot[1], rows[2])
+    elif onehot is None and pool is not None and rows is not None:
+        shapes[2] = (rows[0], pool[1])
+    return shapes
+
+
+@rule("_contrib_KVPoolAttention")
+@rule("KVPoolAttention")
+def _kv_pool_attention(attrs, shapes):
+    query, pool_k, pool_v, mask = shapes    # (R, H, dh), 2 x (H, S, dh), (R, S)
+    pool = pool_k or pool_v
+    if pool is not None:
+        shapes[1] = shapes[2] = pool
+        if mask is None and query is not None:
+            shapes[3] = (query[0], pool[1])
+    return shapes
+
+
 @rule("_contrib_MoEFeedForward")
 @rule("MoEFeedForward")
 def _moe(attrs, shapes):

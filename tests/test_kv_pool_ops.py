@@ -1,0 +1,193 @@
+"""The two operators of the shared KV pool (``KVPoolWrite``,
+``KVPoolAttention``; ops/attention.py) against the spelling they replaced in
+``models/transformer.py``: broadcast products summed over an axis, written
+out here as the decode, the OLMoE and the chunk builders had them.
+
+The write must agree bit for bit (it multiplies by exactly 0 and 1); the
+read sums the same float32 products in another order.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import mxnet_tpu as mx
+from mxnet_tpu.ops.attention import _kv_pool_attention, _kv_pool_write
+
+H, S, DH = 4, 96, 16
+SCALE = 1.0 / np.sqrt(DH)
+
+
+def _parent_write(pool, rows, onehot, chunk):
+    """``kv·keep + Σ_r new_r·onehot_r`` in the pool's type: the one-hots and
+    the keep mask are cast to it (the OLMoE builder did; in float32 the cast
+    is nothing), the sum runs over lanes (decode) or chunk rows."""
+    dt = pool.dtype
+    keep3 = (1.0 - jnp.sum(onehot, axis=0).reshape(1, S, 1)).astype(dt)
+    if chunk:   # (H, T, 1, dh) · (1, T, S, 1), summed over the rows
+        wr = jnp.sum(rows.transpose(1, 0, 2)[:, :, None, :]
+                     * onehot[None, :, :, None].astype(dt), axis=1)
+    else:       # (B, H, 1, dh) · (B, 1, S, 1), summed over the lanes
+        wr = jnp.sum(rows[:, :, None, :]
+                     * onehot[:, None, :, None].astype(dt), axis=0)
+    return pool * keep3 + wr
+
+
+def _parent_read(q, pool_k, pool_v, mask):
+    """scores = Σ_d q·k, softmax, Σ_s p·v as float32 broadcast products."""
+    q, k, v = (a.astype(jnp.float32) for a in (q, pool_k, pool_v))
+    scores = jnp.sum(q[:, :, None, :] * k[None], axis=3) * SCALE
+    p = jax.nn.softmax(scores + mask[:, None, :], axis=-1)
+    return jnp.sum(p[..., None] * v[None], axis=2)
+
+
+def _case(dtype, rows, seed=0):
+    """A pool, R new rows and their inputs. Decode: 5 lanes at scattered
+    slots, lane 3 idle (no write, nothing visible). Chunk: 4 positions of one
+    lane in a row of slots, each seeing the lane's past and the chunk up to
+    itself, the last a pad row (no write, fully masked)."""
+    rs = np.random.RandomState(seed)
+    R = 4 if rows == "chunk" else 5
+    pool_k, pool_v = (jnp.asarray(rs.randn(H, S, DH), dtype) for _ in "kv")
+    q, k_new, v_new = (jnp.asarray(rs.randn(R, H, DH), dtype) for _ in "qkv")
+    onehot = np.zeros((R, S), "f")
+    mask = np.full((R, S), -1e9, "f")
+    if rows == "chunk":
+        for j in range(R - 1):
+            onehot[j, 40 + j] = 1.0
+            mask[j, 8:24] = 0.0
+            mask[j, 40:41 + j] = 0.0
+    else:
+        for lane, slot in ((0, 7), (1, 64), (2, 95), (4, 0)):
+            onehot[lane, slot] = 1.0
+            mask[lane, rs.choice(S, 20, replace=False)] = 0.0
+            mask[lane, slot] = 0.0
+    return (pool_k, pool_v, q, k_new, v_new, jnp.asarray(onehot),
+            jnp.asarray(mask))
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(np.uint16 if a.dtype.itemsize == 2 else np.uint32)
+
+
+CASES = pytest.mark.parametrize("rows", ["decode", "chunk"])
+DTYPES = pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+
+
+@DTYPES
+@CASES
+def test_pool_write_is_the_parents_blend_bit_for_bit(dtype, rows):
+    pool, _, _, k_new, _, onehot, _ = _case(dtype, rows)
+    got = _kv_pool_write({}, pool, k_new, onehot)
+    assert got.dtype == pool.dtype and got.shape == pool.shape
+    want = _parent_write(pool, k_new, onehot, chunk=(rows == "chunk"))
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    # a written slot holds the row itself, every other slot what it held
+    written = np.asarray(onehot).sum(0) > 0
+    np.testing.assert_array_equal(_bits(got)[:, ~written],
+                                  _bits(pool)[:, ~written])
+    for r, s in zip(*np.nonzero(np.asarray(onehot))):
+        np.testing.assert_array_equal(_bits(got)[:, s], _bits(k_new)[r])
+    assert written.sum() == len(onehot) - 1     # the idle lane, the pad row
+
+
+@DTYPES
+def test_pool_write_with_no_onehot_leaves_the_pool_bitwise(dtype):
+    """The replay of a fully cached prompt: every row's one-hot is zero."""
+    pool, _, _, k_new, _, onehot, _ = _case(dtype, "chunk")
+    got = _kv_pool_write({}, pool, k_new, jnp.zeros_like(onehot))
+    np.testing.assert_array_equal(_bits(got), _bits(pool))
+
+
+@DTYPES
+@CASES
+def test_pool_attention_is_the_parents_masked_weighted_sum(dtype, rows):
+    pool_k, pool_v, q, _, _, _, mask = _case(dtype, rows)
+    got = _kv_pool_attention({"scale": -1.0}, q, pool_k, pool_v, mask)
+    assert got.dtype == q.dtype and got.shape == q.shape
+    want = _parent_read(q, pool_k, pool_v, mask)
+    err = (np.linalg.norm(np.asarray(got, "f") - np.asarray(want), axis=-1)
+           / np.linalg.norm(np.asarray(want), axis=-1))
+    # float32: the same products (exact ones, of bfloat16 values) summed in
+    # another order; a bfloat16 query takes the context back in bfloat16,
+    # one rounding of 2^-9 an element
+    assert err.max() < (1e-6 if dtype == "float32" else 2.0 ** -8)
+    explicit = _kv_pool_attention({"scale": SCALE}, q, pool_k, pool_v, mask)
+    np.testing.assert_array_equal(_bits(explicit), _bits(got))
+
+
+@DTYPES
+@CASES
+def test_a_fully_masked_row_reads_finite_and_moves_no_other(dtype, rows):
+    """The idle lane of a decode step and the pad row of a chunk see no slot:
+    the softmax subtracts the row's maximum, so they come out finite (and are
+    discarded), and the other rows read what they read without them."""
+    pool_k, pool_v, q, _, _, _, mask = _case(dtype, rows)
+    dead = 3
+    assert float(mask[dead].max()) == -1e9
+    got = np.asarray(_kv_pool_attention({"scale": -1.0}, q, pool_k, pool_v,
+                                        mask), "f")
+    assert np.isfinite(got).all()
+    live = [r for r in range(len(q)) if r != dead]
+    alone = np.asarray(_kv_pool_attention(
+        {"scale": -1.0}, q[jnp.asarray(live)], pool_k, pool_v,
+        mask[jnp.asarray(live)]), "f")
+    np.testing.assert_allclose(got[live], alone, rtol=1e-6, atol=1e-6)
+
+
+def test_the_write_asks_for_exact_products_and_the_read_for_the_default():
+    """What reaches the compiler: the one-hot matmul at HIGHEST (on the chip
+    a default-precision float32 matmul rounds the stored row to bfloat16),
+    the two contractions of the read at the default precision, as the
+    prefill's attention has them."""
+    pool_k, pool_v, q, k_new, _, onehot, mask = _case("float32", "decode")
+    write = jax.jit(lambda *a: _kv_pool_write({}, *a)).lower(
+        pool_k, k_new, onehot).as_text()
+    assert write.count("dot_general") == 1
+    assert "precision = [HIGHEST, HIGHEST]" in write
+    read = jax.jit(lambda *a: _kv_pool_attention({"scale": -1.0}, *a)).lower(
+        q, pool_k, pool_v, mask).as_text()
+    assert read.count("dot_general") == 2 and "HIGHEST" not in read
+
+
+def test_symbols_infer_the_pool_and_the_row_inputs():
+    v = mx.sym.Variable
+    w = mx.sym.KVPoolWrite(v("pool"), v("rows"), v("onehot"), name="w")
+    assert w.list_arguments() == ["pool", "rows", "onehot"]
+    shapes = [(H, S, DH), (5, H, DH), (5, S)]
+    assert w.infer_shape(rows=shapes[1], onehot=shapes[2]) \
+        == (shapes, [shapes[0]], [])
+    assert w.infer_shape(pool=shapes[0], rows=shapes[1])[0] == shapes
+    a = mx.sym.KVPoolAttention(v("q"), w, v("pool_v"), v("mask"), name="a")
+    arg_shapes, out_shapes, _ = a.infer_shape(
+        q=(5, H, DH), pool=shapes[0], rows=shapes[1])
+    assert dict(zip(a.list_arguments(), arg_shapes)) == {
+        "q": (5, H, DH), "pool": shapes[0], "rows": shapes[1],
+        "onehot": (5, S), "pool_v": shapes[0], "mask": (5, S)}
+    assert out_shapes == [(5, H, DH)]
+
+
+@DTYPES
+def test_one_step_through_the_executor_is_the_two_operators(dtype):
+    """Bound as a graph (what the decode builders do): the written pool and
+    the context of one step, against the operators called directly."""
+    pool_k, pool_v, q, k_new, v_new, onehot, mask = _case(dtype, "decode", 5)
+    v = mx.sym.Variable
+    k_upd = mx.sym.KVPoolWrite(v("kv_k"), v("k_new"), v("oh"), name="kupd")
+    v_upd = mx.sym.KVPoolWrite(v("kv_v"), v("v_new"), v("oh"), name="vupd")
+    ctx = mx.sym.KVPoolAttention(v("q"), k_upd, v_upd, v("msk"), name="att")
+    exe = mx.sym.Group([ctx, k_upd, v_upd]).bind(mx.cpu(), {
+        name: mx.nd.NDArray(a) for name, a in dict(
+            kv_k=pool_k, kv_v=pool_v, k_new=k_new, v_new=v_new, oh=onehot,
+            q=q, msk=mask).items()})
+    exe.forward()
+    got_ctx, got_k, got_v = (o._jax() for o in exe.outputs)
+    want_k = _parent_write(pool_k, k_new, onehot, chunk=False)
+    want_v = _parent_write(pool_v, v_new, onehot, chunk=False)
+    np.testing.assert_array_equal(_bits(got_k), _bits(want_k))
+    np.testing.assert_array_equal(_bits(got_v), _bits(want_v))
+    want = _kv_pool_attention({"scale": -1.0}, q, want_k, want_v, mask)
+    np.testing.assert_allclose(np.asarray(got_ctx, "f"), np.asarray(want, "f"),
+                               rtol=1e-6, atol=1e-6)

@@ -8,6 +8,9 @@ with ``interpret=False`` and compiled, forward and backward, at one
 chip_smoke.py shape. This is the guard that a kernel edited on the CPU still
 compiles on the chip (the first on-chip run found two that did not).
 """
+import math
+import re
+
 import pytest
 
 import jax
@@ -163,6 +166,84 @@ def test_admit_pool_update_stays_in_place_on_the_chip(v5e):
     assert mem.temp_size_in_bytes < 1 << 20
 
 
+def _compile_program(sharding, sym, args):
+    """``sym`` as the executor jits its forward, compiled for the chip from
+    ``args``, {argument: (shape, dtype)}."""
+    from mxnet_tpu.executor import _GraphProgram
+
+    prog = _GraphProgram(sym)
+    specs = tuple(jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                       sharding=sharding)
+                  for shape, dtype in (args[n] for n in prog.arg_names))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=sharding)
+    return prog._fwd(False).lower(specs, (), key).compile()
+
+
+_INSTRUCTION = re.compile(
+    r"%?([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(%?([\w.\-]+)")
+
+
+def _assert_pool_step_contracts(compiled, layers, rows, heads, slots, dh,
+                                temp_bytes):
+    """A shared-pool decode program as the chip runs it: each layer's two
+    writes and two reads of the pool are ``convolution``s or ``dot``s (the
+    matrix unit), nothing multiplies rows x heads x slots x dh elements out
+    to ``reduce`` them (the vector unit: 2.1 ms a product at the benchmark's
+    sizes), no buffer the size of a pool is copied or transposed (the chip
+    keeps a dh = 64 pool slots-minor and a dh = 128 one dh-minor; a
+    contraction spelled against that re-lays 134 MB out, 8 ms a step), and
+    the temporaries stay under ``temp_bytes``."""
+    hlo = compiled.as_text()
+    size, found = {}, []
+    for name, dims, op, arg in _INSTRUCTION.findall(hlo):
+        size[name] = math.prod(int(d) for d in dims.split(",") if d)
+        found.append((op, name, arg))
+    contractions = [line for line in hlo.splitlines()
+                    if re.search(r" (convolution|dot)\(", line)]
+    for i in range(layers):
+        for node, count in (("kupd", 1), ("vupd", 1), ("att", 2)):
+            tag = "layer%d_%s/" % (i, node)
+            assert sum(tag in line for line in contractions) == count, tag
+    pool, product = heads * slots * dh, rows * heads * slots * dh
+    assert not [(op, name) for op, name, arg in found
+                if op == "reduce" and size.get(arg, 0) >= product]
+    assert max(size.values()) < product
+    assert not [(op, name) for op, name, _ in found
+                if op in ("copy", "transpose") and size[name] >= pool]
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_bytes
+
+
+def test_transformer_base_decode_step_contracts_on_the_chip(v5e):
+    """``transformer-base.generate``'s decode program (two of its six layers,
+    64 lanes x 65,536 slots, float32) lowered for the v5e. The temporaries
+    are one layer's float32 scores, 64 x 8 x 65,536 x 4 = 134 MB, and small
+    change; the parent's broadcast spelling held 145 MB and ten reduces over
+    2.1 G elements a layer."""
+    from mxnet_tpu.models import transformer as tf
+
+    layers, lanes, slots, heads, dh = 2, 64, 64 * 1024, 8, 64
+    sym = tf.get_decode_symbol(
+        vocab_size=32000, num_layers=layers, num_heads=heads, model_dim=512,
+        ffn_dim=2048, max_len=slots, pos_len=1024, per_stream_slots=True,
+        global_slots=True)
+    arg_shapes, _, _ = sym.infer_shape(
+        data=(lanes, 1), pos_idx=(lanes, 1), slot_onehot=(lanes, slots),
+        kv_mask=(lanes, slots),
+        **{"kv_%s_%d" % (t, i): (heads, slots, dh)
+           for t in "kv" for i in range(layers)})
+    compiled = _compile_program(v5e, sym, {
+        n: (shape, "float32")
+        for n, shape in zip(sym.list_arguments(), arg_shapes)})
+    _assert_pool_step_contracts(compiled, layers, lanes, heads, slots, dh,
+                                temp_bytes=160 << 20)
+    # the stored row is the row: the one-hot matmuls keep float32's 24 bits
+    assert compiled.as_text().count(
+        "operand_precision={highest,highest}") == 2 * layers
+    # XLA's count: 1.38 GB a layer of pool, scores, one-hots and masks, and
+    # the feed-forward and head; the parent's spelling read 2.37 GB a layer
+    assert compiled.cost_analysis()["bytes accessed"] < 3.5e9
+
+
 @pytest.mark.parametrize("program", ["prefill", "decode"])
 def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
     """The two graphs ``PagedKVDecoder(arch="olmoe")`` runs, lowered for the
@@ -172,7 +253,6 @@ def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
     is found here, without a chip. The experts stay XLA's grouped matmul
     (no per-expert dense expansion: the compiler's FLOP count is the sparse
     one), and the decode step hands the pool back in the type it came in."""
-    from mxnet_tpu.executor import _GraphProgram
     from mxnet_tpu.models import transformer as tf
 
     lanes, max_len = 8, 2048
@@ -194,12 +274,7 @@ def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
                   "kv_mask": ((lanes, slots), "float32"),
                   "kv_k_0": ((16, slots, 128), "bfloat16"),
                   "kv_v_0": ((16, slots, 128), "bfloat16")}
-    prog = _GraphProgram(sym)
-    specs = tuple(jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
-                  for shape, dtype in ({**weights, **inputs}[n]
-                                       for n in prog.arg_names))
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e)
-    compiled = prog._fwd(False).lower(specs, (), key).compile()
+    compiled = _compile_program(v5e, sym, {**weights, **inputs})
     assert "ragged" in compiled.as_text().lower()
     flops = compiled.cost_analysis()["flops"]
     if program == "prefill":
@@ -213,3 +288,6 @@ def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
         assert [str(s.dtype) for s in compiled.out_info[0]] \
             == ["float32", "bfloat16", "bfloat16", "float32"]
         assert compiled.out_info[0][1].shape == (16, slots, 128)
+        # no temporary of a pool's size (16 x 16,384 x 128 bfloat16 = 67 MB)
+        _assert_pool_step_contracts(compiled, 1, lanes, 16, slots, 128,
+                                    temp_bytes=48 << 20)
