@@ -738,8 +738,8 @@ def _bench_serving():
                     ("qps", "p50_ms", "p99_ms", "batch_occupancy",
                      "retraces_post_warmup", "batching_speedup",
                      "qps_single_replica_closed", "replicas",
-                     "redispatches", "replica_restarts", "paged_kv",
-                     "host_gap_ms", "host_gap_per_token", "host_argmax",
+                     "redispatches", "replica_restarts",
+                     "host_gap_ms", "host_gap_per_token",
                      "megastep", "workload", "prefix", "spec")
                     if rec.get(k) is not None}
             if name == "fleet":

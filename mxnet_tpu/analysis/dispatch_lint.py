@@ -53,7 +53,7 @@ _log = logging.getLogger("mxnet_tpu.graphlint")
 
 # call-site vocabulary ------------------------------------------------------
 # a method call by one of these names enqueues device work. The megastep
-# entry points (serving/kv_decode.py decode_megastep/step_megastep) are
+# entry points (serving/kv_decode.py step_megastep) are
 # dispatches too — K tokens per call, but still one host round-trip each,
 # so a loop over them is a (K-amortized) GL701 site.
 _DISPATCH_NAMES = frozenset({"forward", "decode_step", "greedy_step",

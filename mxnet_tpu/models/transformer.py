@@ -5,8 +5,6 @@ MultiHeadAttention op from ops/attention.py).
 Pre-norm blocks: x + MHA(LN(x)), x + FFN(LN(x)); LN via the registry's
 LayerNorm-equivalent composition (InstanceNorm is channel-first, so LN here
 is mean/var composed from broadcast ops to stay faithful to the op set)."""
-import numpy as np
-
 from .. import symbol as sym
 from ..base import MXNetError
 
@@ -67,26 +65,28 @@ def _split_rows(fused, n_parts, num_heads, dh):
                         shape=(-1, num_heads, dh)) for i in range(n_parts)]
 
 
-def _attention_block(x, name, num_heads, model_dim, seq_len, causal=True,
-                     return_kv=False):
-    """Self-attention with ONE fused 3·M-wide qkv GEMM (better MXU shape
-    than three M-wide projections; used for every q==kv site).
-    ``return_kv`` also hands back the head-major (B, H, T, dh) key/value
-    tensors — the serving prefill graph (get_prefill_symbol) exports them
-    to seed the decode path's ring KV buffer."""
-    dh = model_dim // num_heads
-    qkv = sym.FullyConnected(data=x, num_hidden=3 * model_dim, flatten=False,
-                             name="%s_qkv" % name)
-    q, k, v = _split_fused(qkv, 3, seq_len, num_heads, dh)
+def _self_attend(qkv, name, num_heads, model_dim, seq_len, causal=True,
+                 kvs=None):
+    """Full-sequence self-attention on ONE fused (B, T, 3·M) qkv projection
+    (better MXU shape than three M-wide ones) -> (B, T, M). ``kvs``, when a
+    list, collects the head-major (B, H, T, dh) key and value: what the
+    serving prefill exports to seed the decode path's KV pool."""
+    q, k, v = _split_fused(qkv, 3, seq_len, num_heads, model_dim // num_heads)
+    if kvs is not None:
+        kvs += [k, v]
     att = sym.MultiHeadAttention(query=q, key=k, value=v, causal=causal,
                                  name="%s_att" % name)
-    att = sym.SwapAxis(att, dim1=1, dim2=2)  # (B,T,H,D)
-    att = sym.Reshape(att, shape=(-1, seq_len, model_dim))
-    proj = sym.FullyConnected(data=att, num_hidden=model_dim, flatten=False,
+    return _merge_heads(att, seq_len, model_dim)
+
+
+def _attention_block(x, name, num_heads, model_dim, seq_len, causal=True):
+    """The translation model's self-attention sub-layer: fused qkv,
+    ``_self_attend``, output projection."""
+    qkv = sym.FullyConnected(data=x, num_hidden=3 * model_dim, flatten=False,
+                             name="%s_qkv" % name)
+    att = _self_attend(qkv, name, num_heads, model_dim, seq_len, causal)
+    return sym.FullyConnected(data=att, num_hidden=model_dim, flatten=False,
                               name="%s_proj" % name)
-    if return_kv:
-        return proj, k, v
-    return proj
 
 
 def _split_heads(x, seq_len, num_heads, dh):
@@ -185,6 +185,69 @@ def get_symbol_mt(vocab_size=32000, num_layers=6, num_heads=8, model_dim=512,
     return sym.SoftmaxOutput(data=logits, label=label_flat, name="softmax")
 
 
+# ------------------------------------------------------------ the Vaswani block
+def _vaswani_layer(x, i, attend, model_dim, ffn_dim):
+    """One pre-norm Vaswani block on x (B, T, M): x + proj(attend(LN(x)·Wqkv)),
+    then x + FFN(LN(x)). ``attend(i, qkv)`` is the one thing the graphs do
+    differently: it takes the fused (B, T, 3·M) projection and returns
+    attention's (B, T, M) output, over the sequence itself (``_self_attend``:
+    training and the prefill) or over the shared KV pool (``_pool_rows_symbol``:
+    decode and chunk). It takes the projection unsplit because the two split it
+    differently, head-major against row-major."""
+    name = "layer%d" % i
+    qkv = sym.FullyConnected(
+        data=_layer_norm(x, "%s_ln1" % name, model_dim),
+        num_hidden=3 * model_dim, flatten=False, name="%s_qkv" % name)
+    x = x + sym.FullyConnected(data=attend(i, qkv), num_hidden=model_dim,
+                               flatten=False, name="%s_proj" % name)
+    return x + _ffn(_layer_norm(x, "%s_ln2" % name, model_dim), name,
+                    model_dim, ffn_dim)
+
+
+def _vaswani_head(x, vocab_size, model_dim):
+    """Final norm and the head: (B, T, M) -> logits (B·T, vocab)."""
+    x = _layer_norm(x, "final_ln", model_dim)
+    return sym.FullyConnected(
+        data=sym.Reshape(x, shape=(-1, model_dim)), num_hidden=vocab_size,
+        name="lm_head")
+
+
+def _vaswani_sequence(vocab_size, num_layers, num_heads, model_dim, ffn_dim,
+                      seq_len, pos_len=None, kvs=None):
+    """The decoder-only stack over ``data`` (B, seq_len) -> logits
+    (B·seq_len, vocab): training (``get_symbol``) and the serving prefill,
+    which reads the first ``seq_len`` rows of a ``pos_len``-row position table
+    and collects every layer's K/V in ``kvs``."""
+    pos_len = pos_len or seq_len
+    data = sym.Variable("data")  # (B, T) int tokens
+    embed = sym.Embedding(data=data, input_dim=vocab_size,
+                          output_dim=model_dim, name="embed")
+    pos = sym.Variable("pos_embed_weight", shape=(pos_len, model_dim))
+    if seq_len != pos_len:
+        pos = sym.slice_axis(pos, axis=0, begin=0, end=seq_len)
+    x = sym.broadcast_add(
+        embed, sym.Reshape(pos, shape=(1, seq_len, model_dim)),
+        name="pos_add")
+
+    def attend(i, qkv):
+        return _self_attend(qkv, "layer%d" % i, num_heads, model_dim,
+                            seq_len, kvs=kvs)
+
+    for i in range(num_layers):
+        x = _vaswani_layer(x, i, attend, model_dim, ffn_dim)
+    return _vaswani_head(x, vocab_size, model_dim)
+
+
+def get_symbol(vocab_size=32000, num_layers=6, num_heads=8, model_dim=512,
+               ffn_dim=2048, seq_len=64, **kwargs):
+    _refuse_arch(kwargs.get("arch", "vaswani"), "get_symbol")
+    label = sym.Variable("softmax_label")
+    logits = _vaswani_sequence(vocab_size, num_layers, num_heads, model_dim,
+                               ffn_dim, seq_len)
+    label_flat = sym.Reshape(label, shape=(-1,))
+    return sym.SoftmaxOutput(data=logits, label=label_flat, name="softmax")
+
+
 # --------------------------------------------------------------------- serving
 def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
                        model_dim=512, ffn_dim=2048, prefill_len=64,
@@ -192,7 +255,7 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     """Serving prefill graph (docs/SERVING.md): the decoder-only LM of
     ``get_symbol`` over a fixed ``prefill_len`` bucket, additionally
     exporting every layer's head-major key/value tensors so the serving
-    path can seed the decode executable's ring KV buffer.
+    path can seed the decode executable's KV pool.
 
     Weight names are IDENTICAL to ``get_symbol`` — a trained checkpoint
     loads into either. ``pos_len`` is the trained position table's length
@@ -216,190 +279,180 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
             num_heads=num_heads, model_dim=model_dim, ffn_dim=ffn_dim,
             prefill_len=prefill_len, **kwargs)
     _refuse_arch(arch, "get_prefill_symbol")
-    pos_len = pos_len or prefill_len
-    data = sym.Variable("data")  # (B, P) int tokens, right-padded
-    embed = sym.Embedding(data=data, input_dim=vocab_size,
-                          output_dim=model_dim, name="embed")
-    pos = sym.Variable("pos_embed_weight", shape=(pos_len, model_dim))
-    if prefill_len != pos_len:
-        pos = sym.slice_axis(pos, axis=0, begin=0, end=prefill_len)
-    x = sym.broadcast_add(
-        embed, sym.Reshape(pos, shape=(1, prefill_len, model_dim)),
-        name="pos_add")
     kvs = []
-    for i in range(num_layers):
-        name = "layer%d" % i
-        a, k, v = _attention_block(
-            _layer_norm(x, "%s_ln1" % name, model_dim), name, num_heads,
-            model_dim, prefill_len, causal=True, return_kv=True)
-        kvs += [k, v]
-        x = x + a
-        x = x + _ffn(_layer_norm(x, "%s_ln2" % name, model_dim), name,
-                     model_dim, ffn_dim)
-    x = _layer_norm(x, "final_ln", model_dim)
-    logits = sym.FullyConnected(
-        data=sym.Reshape(x, shape=(-1, model_dim)), num_hidden=vocab_size,
-        name="lm_head")
+    logits = _vaswani_sequence(vocab_size, num_layers, num_heads, model_dim,
+                               ffn_dim, prefill_len, pos_len, kvs)
     return sym.Group([logits] + kvs)
+
+
+def _pool_attend(i, q, k_new, v_new, onehot, mask, kv_outs):
+    """Layer ``i``'s write into and read of the ONE shared KV pool, on rows
+    (N, H, dh): each row's new K/V lands in its one-hot slot of ``kv_k_i`` /
+    ``kv_v_i`` (H, slots, dh), which come back in the type they went in and
+    are collected in ``kv_outs``; then each row reads the whole updated pool
+    under its own additive float32 mask. Returns the context (N, H, dh)."""
+    upd = [sym.KVPoolWrite(sym.Variable("kv_%s_%d" % (tag, i)), new, onehot,
+                           name="layer%d_%supd" % (i, tag))
+           for tag, new in (("k", k_new), ("v", v_new))]
+    kv_outs += upd
+    return sym.KVPoolAttention(q, upd[0], upd[1], mask,
+                               name="layer%d_att" % i)
+
+
+def _token_head(logits, kv_outs, token_name):
+    """``[logits, k'_0, v'_0, ...]`` plus, where named, the on-device arg-max
+    head: a greedy driver then pulls one id a row, not a row of logits."""
+    outs = [logits] + kv_outs
+    if token_name:
+        outs.append(sym.argmax(logits, axis=-1, name=token_name))
+    return sym.Group(outs)
+
+
+def _pool_rows_symbol(vocab_size, num_layers, num_heads, model_dim, ffn_dim,
+                      pos_len, seq_len, onehot, mask, token_name):
+    """The Vaswani stack over N rows against the shared pool. The rows are
+    the lanes of a decode step (``data`` (B, 1), ``seq_len`` 1) or the
+    positions of one lane's chunk (``data`` (1, T), ``seq_len`` T); either
+    way ``pos_idx`` has ``data``'s shape and the inputs named ``onehot`` and
+    ``mask`` are (N, slots)."""
+    dh = model_dim // num_heads
+    data = sym.Variable("data")
+    pos_idx = sym.Variable("pos_idx")
+    oh = sym.Variable(onehot)
+    msk = sym.Variable(mask)
+    emb = sym.Embedding(data=data, input_dim=vocab_size,
+                        output_dim=model_dim, name="embed")
+    posrow = sym.Embedding(data=pos_idx, input_dim=pos_len,
+                           output_dim=model_dim, name="pos_embed")
+    x = emb + posrow
+    kv_outs = []
+
+    def attend(i, qkv):
+        q, k_new, v_new = _split_rows(qkv, 3, num_heads, dh)
+        ctx = _pool_attend(i, q, k_new, v_new, oh, msk, kv_outs)
+        return sym.Reshape(ctx, shape=(-1, seq_len, model_dim))
+
+    for i in range(num_layers):
+        x = _vaswani_layer(x, i, attend, model_dim, ffn_dim)
+    return _token_head(_vaswani_head(x, vocab_size, model_dim), kv_outs,
+                       token_name)
 
 
 def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
                       model_dim=512, ffn_dim=2048, max_len=64, pos_len=None,
-                      per_stream_slots=False, global_slots=False,
                       token_out=True, arch="vaswani", **kwargs):
     """Serving single-token decode graph (docs/SERVING.md): ONE token per
-    stream through the ``get_symbol`` stack, attending over a preallocated
-    ring KV buffer of ``max_len`` slots per layer. Compiles ONCE — every
-    decode step replays the same executable regardless of position.
+    lane through the ``get_symbol`` stack, every lane writing into and
+    attending over ONE shared KV pool of ``max_len`` slots per layer (for
+    ``PagedKVDecoder``, lanes x slots a lane). Compiles ONCE — every decode
+    step replays the same executable whatever the lanes' positions.
 
     Inputs beyond the weights:
-      - ``data`` (B, 1): the current token ids.
+      - ``data`` (B, 1): each lane's current token id.
       - ``pos_idx`` (B, 1): absolute positions (rows of the trained
         position table, so ``pos < pos_len``).
-      - ``slot_onehot`` (max_len,): one-hot of the ring slot this token
-        writes (``pos % max_len``). The KV update is in-graph:
-        ``kv' = kv·(1-oh) + kv_new·oh`` — no per-step host scatter, no
-        per-slot recompile.
-      - ``kv_mask`` (max_len,): additive score mask — 0 on slots holding
-        real context (INCLUDING the current slot), a large negative on
-        empty slots.
-      - ``kv_k_i`` / ``kv_v_i`` (B, H, max_len, dh) per layer: the ring
-        buffers. The updated buffers are program OUTPUTS; the caller swaps
-        them back in as the next step's inputs (KVCacheDecoder does).
+      - ``slot_onehot`` (B, max_len): one-hot of the pool slot each lane's
+        token writes. The KV update is in-graph — no per-step host scatter,
+        no per-slot recompile. Lane one-hots are disjoint by construction
+        (the page allocator hands a frame to one writer at a time), and an
+        all-zero row writes nothing, which is how idle lanes ride along for
+        free.
+      - ``kv_mask`` (B, max_len): additive score mask per lane — 0 on the
+        slots holding that lane's context (INCLUDING the current slot), a
+        large negative elsewhere. Masked slots contribute exp(-1e9) = 0
+        exactly, so N lanes can read the SAME physical page: that is what
+        makes a shared prefix page a refcount instead of a copy (docs/
+        SERVING.md §Prefix cache). Attention over slots is order-agnostic
+        (positions live in the embeddings), so a lane's tokens may occupy
+        ANY slots — what the allocator's non-contiguous placement relies on.
+      - ``kv_k_i`` / ``kv_v_i`` (H, max_len, dh) per layer: the pool. The
+        updated buffers are program OUTPUTS; the caller swaps them back in
+        as the next step's inputs (``PagedKVDecoder`` does).
 
-    ``per_stream_slots=True`` is the paged/multiplexed variant
-    (PagedKVDecoder): ``slot_onehot`` and ``kv_mask`` become (B, max_len)
-    so every batch lane carries its OWN write slot, its own valid-slot set
-    and its own position — one decode dispatch serves B *independent*
-    sequences at arbitrary, different positions. An all-zero onehot row
-    writes nothing (that lane's KV passes through unchanged), which is how
-    idle lanes ride along for free. Attention over slots stays
-    order-agnostic (positions live in the embeddings), so a lane's tokens
-    may occupy ANY physical slots — the property the paged allocator's
-    non-contiguous page placement relies on. The math per lane is
-    identical to the shared-slot graph at the same position.
-
-    ``global_slots=True`` (implies per-stream staging) is the
-    SHARED-POOL variant behind the copy-on-write prefix cache
-    (docs/SERVING.md §Prefix cache): the KV buffers collapse from one
-    ring per lane to ONE global slot axis — ``kv_k_i``/``kv_v_i`` become
-    (H, max_len, dh) with ``max_len`` now the TOTAL pool slots — and
-    ``slot_onehot``/``kv_mask`` stay (B, max_len) over that shared axis.
-    Every lane's write is summed into the one pool (lane onehots are
-    disjoint by construction — the page allocator hands a frame to one
-    writer at a time), and every lane attends the whole pool under its
-    own additive mask, so N lanes can read the SAME physical page: that
-    is what makes a shared prefix page a refcount instead of a copy.
-    Masked empty slots contribute exp(-1e9)=0 exactly, so per-lane math
-    is unchanged from the per-lane-ring variant at equal positions.
-
-    T=1 collapses attention to a masked weighted sum. The shared-pool
-    variant spells it, and the write before it, as the two registry
-    operators of ops/attention.py: ``KVPoolWrite`` (a matmul with the
-    one-hots at ``Precision.HIGHEST``: the stored row is the row bit for
-    bit) and ``KVPoolAttention`` (scores and context as contractions at
-    the default matmul precision with a float32 accumulator and softmax).
-    On the CPU that is float32 arithmetic, a few ulp from the
-    full-sequence forward at matching positions; on the chip the two
-    reads are one bfloat16 pass each, as ``MultiHeadAttention`` gives the
-    same tokens in the prefill, and all four run on the matrix unit. The
-    per-lane variants (no cell runs them; ROADMAP D2 deletes them) keep
-    the broadcast products: scores = Σ_d q·k, softmax, Σ_s p·v.
+    One token a row collapses attention to a masked weighted sum, spelled,
+    with the write before it, as the two registry operators of
+    ops/attention.py: ``KVPoolWrite`` (a matmul with the one-hots at
+    ``Precision.HIGHEST``: the stored row is the row bit for bit) and
+    ``KVPoolAttention`` (scores and context as contractions at the default
+    matmul precision with a float32 accumulator and softmax). On the CPU
+    that is float32 arithmetic, a few ulp from the full-sequence forward at
+    matching positions; on the chip the two reads are one bfloat16 pass
+    each, as ``MultiHeadAttention`` gives the same tokens in the prefill,
+    and all four run on the matrix unit.
 
     Outputs: ``[logits (B, vocab), k'_0, v'_0, ..., k'_{L-1}, v'_{L-1}]``,
     plus — with ``token_out=True`` (the default) — a trailing
     ``greedy_token (B,)`` head: ``argmax(logits, axis=-1)`` lowered ON
-    DEVICE, so a greedy driver pulls one id per stream instead of the
-    full (B, vocab) logits row (GL703; the KV outputs keep their
-    ``1 + 2*i`` positions either way). The ``greedy_token`` NAME is a
-    detection contract: ``KVCacheDecoder.warmup`` decides whether a
-    (possibly disk-cached) compiled program carries the head by looking
-    for it in ``output_dict`` by name — rename it and stale caches start
-    masquerading as token-less programs.
+    DEVICE, so a greedy driver pulls one id per lane instead of the full
+    (B, vocab) logits row (GL703; the KV outputs keep their ``1 + 2*i``
+    positions either way).
 
-    This graph is also the megastep building block
-    (serving/kv_decode.py ``_DecodeMegastep``): the per-stream variant is
-    pure in its (data, pos_idx, slot_onehot, kv_mask, kv_*) inputs, so K
-    decode steps compose as a ``lax.scan`` over ONE compiled body — the
-    scan carries the KV outputs back into the KV inputs and feeds each
-    step's sampled token to the next, keeping the whole K-token loop
-    device-resident (docs/SERVING.md §megasteps).
+    This graph is also the megastep building block (serving/kv_decode.py
+    ``_DecodeMegastep``): it is pure in its (data, pos_idx, slot_onehot,
+    kv_mask, kv_*) inputs, so K decode steps compose as a ``lax.scan`` over
+    ONE compiled body — the scan carries the KV outputs back into the KV
+    inputs and feeds each step's sampled token to the next, keeping the
+    whole K-token loop device-resident (docs/SERVING.md §megasteps).
 
-    ``arch="olmoe"`` (``global_slots=True`` only) runs the sparse-expert
-    block of ``_olmoe_layer`` over the same shared pool: positions reach the
-    rotary operator as data (``pos_idx``), there is no position table, and
-    the pool keeps the weights' ``dtype`` while the one-hots and masks stay
-    float32 inputs.
+    ``arch="olmoe"`` runs the sparse-expert block of ``_olmoe_layer`` over
+    the same pool: positions reach the rotary operator as data
+    (``pos_idx``), there is no position table, and the pool keeps the
+    weights' ``dtype`` while the one-hots and masks stay float32 inputs.
     """
     if arch == "olmoe":
-        if not global_slots:
-            _refuse_arch(arch, "get_decode_symbol without global_slots")
         return _olmoe_decode_symbol(
             vocab_size=vocab_size, num_layers=num_layers,
             num_heads=num_heads, model_dim=model_dim, ffn_dim=ffn_dim,
             token_out=token_out, **kwargs)
     _refuse_arch(arch, "get_decode_symbol")
-    pos_len = pos_len or max_len
-    dh = model_dim // num_heads
-    scale = 1.0 / float(np.sqrt(dh))
-    data = sym.Variable("data")
-    pos_idx = sym.Variable("pos_idx")
-    oh = sym.Variable("slot_onehot")
-    msk = sym.Variable("kv_mask")
-    if not global_slots:    # the pool operators take both 2-D, as they are
-        lanes = -1 if per_stream_slots else 1
-        oh4 = sym.Reshape(oh, shape=(lanes, 1, max_len, 1))
-        msk3 = sym.Reshape(msk, shape=(lanes, 1, max_len))
-        keep4 = 1.0 - oh4
-    emb = sym.Embedding(data=data, input_dim=vocab_size,
-                        output_dim=model_dim, name="embed")
-    posrow = sym.Embedding(data=pos_idx, input_dim=pos_len,
-                           output_dim=model_dim, name="pos_embed")
-    x = emb + posrow  # (B, 1, M)
-    kv_outs = []
-    for i in range(num_layers):
-        name = "layer%d" % i
-        ln = _layer_norm(x, "%s_ln1" % name, model_dim)
-        qkv = sym.FullyConnected(data=ln, num_hidden=3 * model_dim,
-                                 flatten=False, name="%s_qkv" % name)
-        kv_k = sym.Variable("kv_k_%d" % i)
-        kv_v = sym.Variable("kv_v_%d" % i)
-        if global_slots:
-            # pool buffers are (H, S, dh): every lane's new K/V row lands
-            # in its one-hot slot of the ONE pool, then every lane reads
-            # the whole pool under its own mask, both as contractions
-            q, k_new, v_new = _split_rows(qkv, 3, num_heads, dh)
-            k_upd = sym.KVPoolWrite(kv_k, k_new, oh, name="%s_kupd" % name)
-            v_upd = sym.KVPoolWrite(kv_v, v_new, oh, name="%s_vupd" % name)
-            ctx = sym.KVPoolAttention(q, k_upd, v_upd, msk,
-                                      name="%s_att" % name)  # (B, H, dh)
-        else:
-            q, k_new, v_new = _split_fused(qkv, 3, 1, num_heads, dh)
-            k_upd = sym.broadcast_add(sym.broadcast_mul(kv_k, keep4),
-                                      sym.broadcast_mul(k_new, oh4),
-                                      name="%s_kupd" % name)
-            v_upd = sym.broadcast_add(sym.broadcast_mul(kv_v, keep4),
-                                      sym.broadcast_mul(v_new, oh4),
-                                      name="%s_vupd" % name)
-            scores = sym.sum(sym.broadcast_mul(q, k_upd), axis=3) * scale
-            scores = sym.broadcast_add(scores, msk3)  # (B, H, S)
-            p = sym.softmax(scores, axis=-1)
-            ctx = sym.sum(sym.broadcast_mul(sym.expand_dims(p, axis=3),
-                                            v_upd), axis=2)  # (B, H, dh)
-        kv_outs += [k_upd, v_upd]
-        att = sym.Reshape(ctx, shape=(-1, 1, model_dim))
-        x = x + sym.FullyConnected(data=att, num_hidden=model_dim,
-                                   flatten=False, name="%s_proj" % name)
-        x = x + _ffn(_layer_norm(x, "%s_ln2" % name, model_dim), name,
-                     model_dim, ffn_dim)
-    x = _layer_norm(x, "final_ln", model_dim)
-    logits = sym.FullyConnected(
-        data=sym.Reshape(x, shape=(-1, model_dim)), num_hidden=vocab_size,
-        name="lm_head")
-    outs = [logits] + kv_outs
-    if token_out:
-        outs.append(sym.argmax(logits, axis=-1, name="greedy_token"))
-    return sym.Group(outs)
+    return _pool_rows_symbol(
+        vocab_size, num_layers, num_heads, model_dim, ffn_dim,
+        pos_len or max_len, seq_len=1, onehot="slot_onehot", mask="kv_mask",
+        token_name="greedy_token" if token_out else None)
+
+
+def get_chunk_symbol(vocab_size=32000, num_layers=6, num_heads=8,
+                     model_dim=512, ffn_dim=2048, chunk_len=8,
+                     total_slots=64, pos_len=64, token_out=True, **kwargs):
+    """Rectangular T-token chunk graph over the shared KV pool
+    (docs/SERVING.md §Prefix cache & speculative decoding): ONE lane's
+    next ``chunk_len`` positions scored — and optionally written — in a
+    single dispatch. This is both the chunked-prefill program (admit
+    computes only the un-cached tail of a prompt, chunk by chunk) and the
+    speculative VERIFY program (the target model scores all γ+1 draft
+    positions at once) — same symbol, different T. It is the decode
+    graph with positions for rows instead of lanes (``_pool_rows_symbol``).
+
+    Inputs beyond the weights:
+      - ``data`` (1, T): the chunk's token ids (pad rows = 0).
+      - ``pos_idx`` (1, T): absolute positions per row (pad rows clamp
+        to 0; their writes are zeroed so the value never lands).
+      - ``write_onehot`` (T, total_slots): row j's write slot in the
+        pool. An ALL-ZERO row writes nothing — that is both the
+        pad-row idiom and the zero-write REPLAY mode (a fully-cached
+        prompt re-scores its last chunk against the stored pages:
+        ``kv·1 + Σ(0·new) = kv`` bitwise, so replay logits are
+        bit-identical to the cold chunked prefill that wrote them).
+      - ``att_mask`` (T, total_slots): additive score mask per row — 0 on
+        the lane's earlier slots AND on in-chunk slots of positions
+        <= row j (intra-chunk causality is enforced HERE: all T writes
+        land in the pool before attention, the mask hides the future
+        ones). A fully-masked pad row softmaxes uniformly over garbage
+        and is discarded — finite, never NaN (max-subtraction zeroes the
+        row first).
+      - ``kv_k_i`` / ``kv_v_i`` (H, total_slots, dh): the pool buffers,
+        as in ``get_decode_symbol``.
+
+    Outputs: ``[logits (T, vocab), k'_0, v'_0, ...]`` plus — with
+    ``token_out=True`` — a trailing on-device ``chunk_token (T,)`` argmax
+    head so the speculative accept loop pulls T ids, not T·vocab floats.
+    """
+    _refuse_arch(kwargs.get("arch", "vaswani"), "get_chunk_symbol")
+    return _pool_rows_symbol(
+        vocab_size, num_layers, num_heads, model_dim, ffn_dim, pos_len,
+        seq_len=int(chunk_len), onehot="write_onehot", mask="att_mask",
+        token_name="chunk_token" if token_out else None)
+
 
 # --------------------------------------------------------------------- OLMoE
 def _olmoe_layer(x, i, positions, seq_len, attend, *, num_heads, head_dim,
@@ -495,30 +548,20 @@ def _olmoe_decode_symbol(vocab_size, num_layers, token_out=True, **sizes):
     kv_outs = []
 
     def attend(i, q, k_new, v_new):
-        """``get_decode_symbol``'s ``global_slots`` branch: every lane's new
-        K/V row lands in its one-hot slot of the ONE pool, which comes back
-        in the type it went in (a float32 pool out of a bfloat16 one would
-        retrace every step and double the cache), then every lane reads the
-        whole pool under its own float32 mask."""
-        upd = [sym.KVPoolWrite(sym.Variable("kv_%s_%d" % (tag, i)),
-                               sym.Reshape(new, shape=(-1, num_heads, dh)),
-                               oh, name="layer%d_%supd" % (i, tag))
-               for tag, new in (("k", k_new), ("v", v_new))]
-        kv_outs.extend(upd)
-        ctx = sym.KVPoolAttention(sym.Reshape(q, shape=(-1, num_heads, dh)),
-                                  upd[0], upd[1], msk,
-                                  name="layer%d_att" % i)  # (B, H, dh)
+        # one token a lane: the head-major (B, H, 1, dh) tensors are the
+        # pool's rows (B, H, dh)
+        k_new, v_new, q = (sym.Reshape(a, shape=(-1, num_heads, dh))
+                           for a in (k_new, v_new, q))
+        ctx = _pool_attend(i, q, k_new, v_new, oh, msk, kv_outs)
         return sym.Reshape(ctx, shape=(-1, num_heads, 1, dh))
 
     x = sym.Embedding(data=data, input_dim=vocab_size, output_dim=model_dim,
                       name="embed")  # (B, 1, M)
     for i in range(num_layers):
         x, _ = _olmoe_layer(x, i, pos_idx, 1, attend, **block)
-    logits = _olmoe_head(x, vocab_size, model_dim, block["rms_eps"])
-    outs = [logits] + kv_outs
-    if token_out:
-        outs.append(sym.argmax(logits, axis=-1, name="greedy_token"))
-    return sym.Group(outs)
+    return _token_head(
+        _olmoe_head(x, vocab_size, model_dim, block["rms_eps"]), kv_outs,
+        "greedy_token" if token_out else None)
 
 
 def param_shapes(arch, vocab_size, num_layers, num_heads, model_dim, ffn_dim,
@@ -546,87 +589,6 @@ def param_shapes(arch, vocab_size, num_layers, num_heads, model_dim, ffn_dim,
     return shapes
 
 
-def get_chunk_symbol(vocab_size=32000, num_layers=6, num_heads=8,
-                     model_dim=512, ffn_dim=2048, chunk_len=8,
-                     total_slots=64, pos_len=64, token_out=True, **kwargs):
-    """Rectangular T-token chunk graph over the GLOBAL paged slot pool
-    (docs/SERVING.md §Prefix cache & speculative decoding): ONE lane's
-    next ``chunk_len`` positions scored — and optionally written — in a
-    single dispatch. This is both the chunked-prefill program (admit
-    computes only the un-cached tail of a prompt, chunk by chunk) and the
-    speculative VERIFY program (the target model scores all γ+1 draft
-    positions at once) — same symbol, different T.
-
-    Inputs beyond the weights:
-      - ``data`` (1, T): the chunk's token ids (pad rows = 0).
-      - ``pos_idx`` (1, T): absolute positions per row (pad rows clamp
-        to 0; their writes are zeroed so the value never lands).
-      - ``write_onehot`` (T, total_slots): row j's write slot in the
-        global pool. An ALL-ZERO row writes nothing — that is both the
-        pad-row idiom and the zero-write REPLAY mode (a fully-cached
-        prompt re-scores its last chunk against the stored pages:
-        ``kv·1 + Σ(0·new) = kv`` bitwise, so replay logits are
-        bit-identical to the cold chunked prefill that wrote them).
-        The write and the read are ``KVPoolWrite`` / ``KVPoolAttention``,
-        the decode graph's operators over T rows instead of B lanes.
-      - ``att_mask`` (T, total_slots): additive score mask per row — 0 on
-        the lane's earlier slots AND on in-chunk slots of positions
-        <= row j (intra-chunk causality is enforced HERE: all T writes
-        land in ``k_upd`` before attention, the mask hides the future
-        ones). A fully-masked pad row softmaxes uniformly over garbage
-        and is discarded — finite, never NaN (max-subtraction zeroes the
-        row first).
-      - ``kv_k_i`` / ``kv_v_i`` (H, total_slots, dh): the global pool
-        buffers, as in ``get_decode_symbol(global_slots=True)``.
-
-    Outputs: ``[logits (T, vocab), k'_0, v'_0, ...]`` plus — with
-    ``token_out=True`` — a trailing on-device ``chunk_token (T,)`` argmax
-    head so the speculative accept loop pulls T ids, not T·vocab floats.
-    """
-    _refuse_arch(kwargs.get("arch", "vaswani"), "get_chunk_symbol")
-    T = int(chunk_len)
-    dh = model_dim // num_heads
-    data = sym.Variable("data")
-    pos_idx = sym.Variable("pos_idx")
-    w_oh = sym.Variable("write_onehot")
-    msk = sym.Variable("att_mask")
-    emb = sym.Embedding(data=data, input_dim=vocab_size,
-                        output_dim=model_dim, name="embed")
-    posrow = sym.Embedding(data=pos_idx, input_dim=pos_len,
-                           output_dim=model_dim, name="pos_embed")
-    x = emb + posrow  # (1, T, M)
-    kv_outs = []
-    for i in range(num_layers):
-        name = "layer%d" % i
-        ln = _layer_norm(x, "%s_ln1" % name, model_dim)
-        qkv = sym.FullyConnected(data=ln, num_hidden=3 * model_dim,
-                                 flatten=False, name="%s_qkv" % name)
-        q, k_new, v_new = _split_rows(qkv, 3, num_heads, dh)
-        # the T new rows land in their slots of the pool (writer-disjoint,
-        # all-zero rows vanish), then every row reads the pool: the decode
-        # graph's two operators, the rows being positions here, not lanes
-        k_upd = sym.KVPoolWrite(sym.Variable("kv_k_%d" % i), k_new, w_oh,
-                                name="%s_kupd" % name)
-        v_upd = sym.KVPoolWrite(sym.Variable("kv_v_%d" % i), v_new, w_oh,
-                                name="%s_vupd" % name)
-        kv_outs += [k_upd, v_upd]
-        ctx = sym.KVPoolAttention(q, k_upd, v_upd, msk,
-                                  name="%s_att" % name)  # (T, H, dh)
-        att = sym.Reshape(ctx, shape=(-1, T, model_dim))
-        x = x + sym.FullyConnected(data=att, num_hidden=model_dim,
-                                   flatten=False, name="%s_proj" % name)
-        x = x + _ffn(_layer_norm(x, "%s_ln2" % name, model_dim), name,
-                     model_dim, ffn_dim)
-    x = _layer_norm(x, "final_ln", model_dim)
-    logits = sym.FullyConnected(
-        data=sym.Reshape(x, shape=(-1, model_dim)), num_hidden=vocab_size,
-        name="lm_head")
-    outs = [logits] + kv_outs
-    if token_out:
-        outs.append(sym.argmax(logits, axis=-1, name="chunk_token"))
-    return sym.Group(outs)
-
-
 def draft_config(cfg, num_layers=1):
     """Speculative-decoding draft config: the FIRST ``num_layers`` blocks
     of a target model's config. Weight names are positional
@@ -642,32 +604,3 @@ def draft_config(cfg, num_layers=1):
     out = dict(cfg)
     out["num_layers"] = k
     return out
-
-
-def get_symbol(vocab_size=32000, num_layers=6, num_heads=8, model_dim=512,
-               ffn_dim=2048, seq_len=64, **kwargs):
-    _refuse_arch(kwargs.get("arch", "vaswani"), "get_symbol")
-    data = sym.Variable("data")  # (B, T) int tokens
-    label = sym.Variable("softmax_label")
-    embed = sym.Embedding(data=data, input_dim=vocab_size,
-                          output_dim=model_dim, name="embed")
-    pos = sym.Variable("pos_embed_weight", shape=(seq_len, model_dim))
-    x = sym.broadcast_add(embed, sym.Reshape(pos, shape=(1, seq_len, model_dim)),
-                          name="pos_add")
-    for i in range(num_layers):
-        name = "layer%d" % i
-        a = _attention_block(_layer_norm(x, "%s_ln1" % name, model_dim),
-                             name, num_heads, model_dim, seq_len)
-        x = x + a
-        h = _layer_norm(x, "%s_ln2" % name, model_dim)
-        h = sym.FullyConnected(data=h, num_hidden=ffn_dim, flatten=False,
-                               name="%s_ffn1" % name)
-        h = sym.Activation(h, act_type="relu")
-        h = sym.FullyConnected(data=h, num_hidden=model_dim, flatten=False,
-                               name="%s_ffn2" % name)
-        x = x + h
-    x = _layer_norm(x, "final_ln", model_dim)
-    x = sym.Reshape(x, shape=(-1, model_dim))
-    logits = sym.FullyConnected(data=x, num_hidden=vocab_size, name="lm_head")
-    label_flat = sym.Reshape(label, shape=(-1,))
-    return sym.SoftmaxOutput(data=logits, label=label_flat, name="softmax")

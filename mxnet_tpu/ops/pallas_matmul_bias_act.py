@@ -11,8 +11,7 @@ zero extra reads:
 
 W rides in the framework's FullyConnected layout (N, K); the kernel
 contracts over each operand's axis 1 directly (``dot_general``), so no
-transpose materializes. Grid (N/bn, M/bm) with K whole per tile, the
-``ops/pallas_matmul_stats.py`` geometry.
+transpose materializes. Grid (N/bn, M/bm) with K whole per tile.
 
 Backward is deliberately XLA (``custom_vjp``): dpre is recovered FROM THE
 ACTIVATED OUTPUT (relu: mask(y>0); sigmoid: y(1−y); tanh: 1−y²; softrelu:
@@ -44,8 +43,8 @@ ACTIVATIONS = {
 
 
 def supported(m, k, n, act, block_m=512, block_n=256, itemsize=2):
-    """Whether (M, K) @ (N, K)ᵀ tiles within the VMEM budget (the
-    pallas_matmul_stats contract: K whole per tile, bm % 8, bn % 128)."""
+    """Whether (M, K) @ (N, K)ᵀ tiles within the VMEM budget (K whole per
+    tile, bm % 8, bn % 128)."""
     if act not in ACTIVATIONS:
         return False
     bm, bn = min(block_m, m), min(block_n, n)

@@ -10,9 +10,9 @@ device kind, with any post-warmup recompile a HARD error diagnosed by the
 GL201-203 retrace guard. ``InferenceEngine`` feeds those executables from a
 thread-safe request queue with continuous batching over the buckets
 (pad-to-bucket, admit mid-flight until ``MXNET_SERVE_MAX_DELAY_MS``).
-``KVCacheDecoder`` is the autoregressive variant: a prefill-bucket
-executable plus a single-token decode executable over a preallocated ring
-KV buffer (models/transformer.py serving symbols).
+``PagedKVDecoder`` is the autoregressive variant: a prefill-bucket
+executable plus a single-token decode executable over a preallocated, paged
+KV pool its lanes share (models/transformer.py serving symbols).
 
     cache = serving.PersistentExecutableCache(sym, arg_params, aux_params)
     eng = serving.InferenceEngine(cache, buckets=(1, 2, 4, 8),
@@ -27,13 +27,13 @@ from __future__ import annotations
 from .cache import PersistentExecutableCache
 from .engine import (InferenceEngine, ServeFuture, ServeDeadlineError,
                      ServeOverloadError, ServeClosedError)
-from .kv_decode import KVCacheDecoder, PagedKVDecoder, PagedKVExhausted
+from .kv_decode import PagedKVDecoder, PagedKVExhausted
 from .prefix_cache import PrefixCache
 from .speculative import SpeculativeDecoder, spec_decode_enabled, spec_gamma
 from . import fleet
 
 __all__ = ["PersistentExecutableCache", "InferenceEngine", "ServeFuture",
            "ServeDeadlineError", "ServeOverloadError", "ServeClosedError",
-           "KVCacheDecoder", "PagedKVDecoder", "PagedKVExhausted",
+           "PagedKVDecoder", "PagedKVExhausted",
            "PrefixCache", "SpeculativeDecoder", "spec_decode_enabled",
            "spec_gamma", "fleet"]

@@ -2,39 +2,35 @@
 
 Autoregressive serving without per-step recompilation: ONE prefill
 executable (prompt bucket, exports every layer's K/V) plus ONE
-single-token decode executable over a preallocated ring KV buffer of
-``max_len`` slots per layer. Both come from a sealed
-``PersistentExecutableCache``, so after warmup a greedy decode of any
+single-token decode executable over a preallocated KV pool. Both come from
+a sealed ``PersistentExecutableCache``, so after warmup a decode of any
 length replays exactly two XLA programs — the full-sequence re-forward it
 replaces costs O(T) work per token and a recompile per prompt length.
 
-Ring layout: position ``p`` writes slot ``p % max_len``; the write happens
-IN-GRAPH (``slot_onehot`` blend, models/transformer.py
-``get_decode_symbol``), and the updated buffers are program outputs the
-decoder swaps back in as the next step's inputs — a device-side pointer
-swap, no copy, no host round-trip. Attention over slots is
-order-agnostic (position information lives in the embeddings), so ring
-wraparound needs no rotation: once ``p >= max_len`` every slot is valid
-and the oldest token is simply the one overwritten.
+One layout (``PagedKVDecoder``): the decode batch's rows are ``lanes``, one
+per sequence, and the lanes share ONE slot axis (``lanes * max_len`` slots
+per layer, KV ``(H, slots, dh)``) carved into refcounted page frames. A
+token's write happens IN-GRAPH (the ``slot_onehot`` rows of
+models/transformer.py ``get_decode_symbol``), and the updated buffers are
+program outputs the decoder swaps back in as the next step's inputs — a
+device-side pointer swap, no copy, no host round-trip. Attention over
+slots is order-agnostic (position information lives in the embeddings), so
+a sequence's tokens may sit in any frames. Physical sharing is then free —
+the prefix cache (serving/prefix_cache.py) parks whole prompt chunks at a
+refcount and cached admits adopt them without recompute; ``fork`` clones a
+sequence by increfing its frames; a write into a shared page
+copy-on-writes; and ``rollback``/``verify_chunk`` give speculative decoding
+(serving/speculative.py) its accept/reject primitives.
 
 Megasteps (``MXNET_DECODE_MEGASTEP_K``, docs/SERVING.md §megasteps): the
 per-token loop above still pays one host round-trip per token.
-``decode_megastep``/``step_megastep`` fold K decode steps into ONE
-compiled program — a ``lax.scan`` over the same decode graph with
-on-device sampling (greedy argmax head, or temperature/top-k via the
-PRNG machinery) — so only (K, B) token ids cross the host per dispatch.
-Per-lane early exit reuses the all-zero ``slot_onehot`` idle-lane idiom:
-once a lane emits ``eos_id`` its remaining scan steps write NOTHING to
-its KV slots. K=1 keeps today's single-step path byte-for-byte.
-
-Paged pool (``PagedKVDecoder``): the lanes share ONE global slot axis
-(``lanes * max_len`` slots per layer), carved into refcounted page
-frames. Physical sharing is then free — the prefix cache
-(serving/prefix_cache.py) parks whole prompt chunks at a refcount and
-cached admits adopt them without recompute; ``fork`` clones a sequence
-by increfing its frames; a write into a shared page copy-on-writes; and
-``rollback``/``verify_chunk`` give speculative decoding
-(serving/speculative.py) its accept/reject primitives.
+``step_megastep`` folds K decode steps into ONE compiled program — a
+``lax.scan`` over the same decode graph with on-device sampling (greedy
+argmax head, or temperature/top-k via the PRNG machinery) — so only (K, B)
+token ids cross the host per dispatch. Per-lane early exit reuses the
+all-zero ``slot_onehot`` idle-lane idiom: once a lane emits ``eos_id`` its
+remaining scan steps write NOTHING to its KV slots. K=1 keeps the
+single-step path byte-for-byte.
 """
 from __future__ import annotations
 
@@ -48,8 +44,7 @@ from ..base import MXNetError
 from .. import telemetry as _tm
 from .cache import PersistentExecutableCache
 
-__all__ = ["KVCacheDecoder", "PagedKVDecoder", "PagedKVExhausted",
-           "decode_megastep_k"]
+__all__ = ["PagedKVDecoder", "PagedKVExhausted", "decode_megastep_k"]
 
 _NEG = np.float32(-1e9)
 
@@ -168,59 +163,134 @@ def _sampling_key(dec):
     return dec._sample_key
 
 
-class _DecodeMegastep:
+class _SealedProgram:
+    """One jitted program of the decoder, compiled ONCE at warm time and
+    sealed like the ``PersistentExecutableCache``: every later dispatch is a
+    jit cache hit (``executor.cache_hit``), and inputs whose shapes or types
+    drift from the warmed ones are a hard retrace error
+    (``executor.retrace``), never a silent recompile.
+
+    A subclass hands its function to ``_jit`` and gives two things:
+    ``_dummy(dec)``, the ``(sealed, rest)`` inputs of a dispatch that changes
+    nothing, and ``_dispatch(dec, sealed, *rest)``, which enqueues the
+    program; its ``run`` goes through ``_run``. ``sealed`` is the tuple of
+    arrays whose signature is held; weights and pool are read from the live
+    decode executable at dispatch time (``_live``), so a hitless
+    ``swap_params`` or a donated pool lands in the very next one."""
+
+    def __init__(self, what, rule, span, **span_args):
+        self._what, self._rule = what, rule     # of the drift error
+        self._span, self._span_args = span, span_args   # of the warm compile
+        self._fn = self._sig = None
+
+    def _jit(self, fn, label, donate_argnums=()):
+        import jax
+
+        from ..executor import _named
+
+        self._fn = jax.jit(_named(fn, label), donate_argnums=donate_argnums)
+
+    @staticmethod
+    def _sig_of(arrays):
+        return tuple((tuple(a.shape), str(a.dtype)) for a in arrays)
+
+    @staticmethod
+    def _live(dec, names):
+        """The decode executable's arguments ``names`` as they are NOW."""
+        args = dec._dec_exe.arg_dict
+        return tuple(args[n]._jax() for n in names)
+
+    def _graph(self, symbol, inputs, num_layers):
+        """Bind a serving graph: ``interpret(weights, kvs, feed, key)`` runs
+        it on ``feed`` (its ``inputs`` by name) and the pool and returns its
+        outputs. Every other argument is a weight, shared by name across the
+        serving graphs (``weight_names``)."""
+        from ..executor import _GraphProgram
+
+        prog = _GraphProgram(symbol)
+        if prog.aux_names:
+            raise MXNetError("%s: the graph must carry no aux state, got %r"
+                             % (self._what, prog.aux_names))
+        self.kv_names = _kv_names(num_layers)
+        fed = set(inputs).union(self.kv_names)
+        self.weight_names = [n for n in prog.arg_names if n not in fed]
+
+        def interpret(weights, kvs, feed, key):
+            feed = dict(feed, **dict(zip(self.kv_names, kvs)))
+            args = [feed[n] if n in feed else weights[n]
+                    for n in prog.arg_names]
+            return prog.interpret(args, (), False, key)[0]
+
+        return interpret
+
+    def _weights_and_pool(self, dec):
+        """A bound graph's first two arguments, read at dispatch time."""
+        return (dict(zip(self.weight_names,
+                         self._live(dec, self.weight_names))),
+                self._live(dec, self.kv_names))
+
+    def warm(self, dec):
+        """Compile NOW with the subclass's do-nothing dispatch and seal its
+        signature, counted as the one ``executor.compile`` this program
+        ever charges — bench warmup snapshots see it, the steady state
+        never does."""
+        import jax
+
+        sealed, rest = self._dummy(dec)
+        with _tm.span(self._span, **self._span_args):
+            out = self._dispatch(dec, sealed, *rest)
+            # graphlint: waive GL7xx -- warm-time compile barrier, not the dispatch path
+            jax.block_until_ready(out)
+        self._sig = self._sig_of(sealed)
+        if _tm.enabled():
+            _tm.counter("executor.compile").inc()
+
+    def _run(self, dec, sealed, *rest):
+        """One dispatch; a drifted signature is refused before anything is
+        enqueued."""
+        sig = self._sig_of(sealed)
+        if sig != self._sig:
+            if _tm.enabled():
+                _tm.counter("executor.retrace").inc()
+            raise MXNetError(
+                "%s: input signature drifted from the warmed shapes "
+                "(%r != %r) — %s sealed like the executable cache"
+                % (self._what, sig, self._sig, self._rule))
+        if _tm.enabled():
+            _tm.counter("executor.cache_hit").inc()
+        return self._dispatch(dec, sealed, *rest)
+
+
+class _DecodeMegastep(_SealedProgram):
     """K decode steps folded into ONE compiled program.
 
-    A ``jax.jit``-ted ``lax.scan`` over the per-stream decode graph
+    A ``jax.jit``-ted ``lax.scan`` over the decode graph
     (``_GraphProgram.interpret`` is pure and jit-safe): the scan carries
-    (next token, done mask, attention mask, KV buffers), each step blends
+    (next token, done mask, attention mask, KV pool), each step blends
     its KV write in-graph through the host-staged slot plan, samples the
     next token ON DEVICE, and only the stacked (K, B) ids + activity
     mask ever cross to the host. EOS'd / idle lanes carry an all-zero
     ``slot_onehot`` row — their KV passes through bitwise-unchanged (the
-    idle-lane idiom the paged decoder already relies on).
-
-    Shapes are fixed at build time, so after the warm-time compile every
-    dispatch is a jit cache hit; input-signature drift is a hard retrace
-    error, mirroring the sealed ``PersistentExecutableCache`` contract.
-    """
+    idle-lane idiom ``step`` already relies on)."""
 
     def __init__(self, dec, k, sampler):
         import jax
         import jax.numpy as jnp
 
-        from ..executor import _GraphProgram, _named
         from ..models import transformer as _tf
 
         self.k = int(k)
-        self.sampler = sampler
-        self.rows = dec.batch if hasattr(dec, "batch") else dec.lanes
-        # the paged decoder's pool is ONE global slot axis shared by all
-        # lanes (kv (H, S_tot, dh)); the classic decoders carry a ring
-        # per lane (kv (B, H, S, dh)) — same scan, different slot space
-        self.global_slots = bool(getattr(dec, "_global_slots", False))
-        B, L = self.rows, dec.num_layers
-        S = dec.total_slots if self.global_slots else dec.max_len
-        self._S = S
+        B, L, S = dec.lanes, dec.num_layers, dec.total_slots
         pos_len = dec.pos_len
-        sym = _tf.get_decode_symbol(
-            vocab_size=dec.vocab_size, num_layers=L,
-            num_heads=dec.num_heads, model_dim=dec.model_dim,
-            ffn_dim=dec.ffn_dim, max_len=S, pos_len=pos_len,
-            per_stream_slots=True, global_slots=self.global_slots)
-        prog = _GraphProgram(sym)
-        if prog.aux_names:
-            raise MXNetError("decode megastep: the decode graph must carry "
-                             "no aux state, got %r" % (prog.aux_names,))
-        self.kv_names = _kv_names(L)
-        step_inputs = {"data", "pos_idx", "slot_onehot", "kv_mask"}
-        step_inputs.update(self.kv_names)
-        # weight names are shared across every serving graph — the values
-        # are pulled from the live executable at DISPATCH time, so a
-        # hitless swap_params lands in the very next megastep
-        self.weight_names = [n for n in prog.arg_names
-                             if n not in step_inputs]
-        arg_names = list(prog.arg_names)
+        super().__init__("decode megastep (K=%d)" % self.k,
+                         "megastep programs are", "serving.megastep_compile",
+                         k=self.k, rows=B, sampler=sampler.mode)
+        interpret = self._graph(
+            _tf.get_decode_symbol(
+                vocab_size=dec.vocab_size, num_layers=L,
+                num_heads=dec.num_heads, model_dim=dec.model_dim,
+                ffn_dim=dec.ffn_dim, max_len=S, pos_len=pos_len),
+            ("data", "pos_idx", "slot_onehot", "kv_mask"), L)
         mode, temp, top_k = sampler.mode, sampler.temperature, sampler.top_k
         lane_ids = jnp.arange(B)
 
@@ -253,14 +323,11 @@ class _DecodeMegastep:
                 # table; their onehot row is all-zero so the value is
                 # never written anywhere
                 pos_t = jnp.clip(pos + t, 0, pos_len - 1)
-                feed = {"data": tok.astype(jnp.float32)[:, None],
-                        "pos_idx": pos_t.astype(jnp.float32)[:, None],
-                        "slot_onehot": oh, "kv_mask": mask}
-                for i, name in enumerate(self.kv_names):
-                    feed[name] = kv[i]
-                args = [feed[n] if n in feed else weights[n]
-                        for n in arg_names]
-                outs, _ = prog.interpret(args, (), False, key)
+                outs = interpret(
+                    weights, kv,
+                    {"data": tok.astype(jnp.float32)[:, None],
+                     "pos_idx": pos_t.astype(jnp.float32)[:, None],
+                     "slot_onehot": oh, "kv_mask": mask}, key)
                 new_kv = tuple(outs[1 + j] for j in range(2 * L))
                 if mode == "greedy":
                     nxt = outs[-1].astype(jnp.int32)  # on-device argmax head
@@ -276,66 +343,25 @@ class _DecodeMegastep:
                 body, (tok0, done0, base_mask, kvs), xs)
             return toks, acts, kv_f, done_f
 
-        self._fn = jax.jit(_named(run, "mx_megastep%d" % self.k))
-        self._sig = None
+        self._jit(run, "mx_megastep%d" % self.k)
 
-    @staticmethod
-    def _sig_of(*arrays):
-        return tuple((tuple(a.shape), str(a.dtype)) for a in arrays)
+    def _dummy(self, dec):
+        B, S = dec.lanes, dec.total_slots
+        return (np.zeros((B,), np.int32), np.zeros((B,), np.int32),
+                np.zeros((B, self.k), np.int32),
+                np.full((B, S), _NEG, np.float32),
+                # every lane idle: compiles, writes nothing
+                np.ones((B,), bool)), (np.int32(-1),)
 
-    def _zero_inputs(self):
-        B, S = self.rows, self._S
-        tok0 = np.zeros((B,), np.int32)
-        pos = np.zeros((B,), np.int32)
-        slots = np.zeros((B, self.k), np.int32)
-        base_mask = np.full((B, S), _NEG, np.float32)
-        done0 = np.ones((B,), bool)  # every lane idle: compiles, writes nothing
-        return tok0, pos, slots, base_mask, done0
-
-    def warm(self, dec):
-        """Compile the megastep NOW (a dummy all-idle dispatch), counted
-        as the one ``executor.compile`` this program ever charges — bench
-        warmup snapshots see it, the steady state never does."""
-        import jax
-        import jax.numpy as jnp
-
-        B, S = self.rows, self._S
-        H, dh = dec.num_heads, dec.dh
-        weights = {n: dec._dec_exe.arg_dict[n]._jax()
-                   for n in self.weight_names}
-        kv_shape = (H, S, dh) if self.global_slots else (B, H, S, dh)
-        kvs = tuple(jnp.zeros(kv_shape, jnp.float32)
-                    for _ in self.kv_names)
-        z = self._zero_inputs()
-        with _tm.span("serving.megastep_compile", k=self.k, rows=B,
-                      sampler=self.sampler.mode):
-            out = self._fn(weights, kvs, *z, _sampling_key(dec),
-                           np.int32(-1))
-            # graphlint: waive GL7xx -- warm-time compile barrier, not the dispatch path
-            jax.block_until_ready(out)
-        self._sig = self._sig_of(*z)
-        if _tm.enabled():
-            _tm.counter("executor.compile").inc()
+    def _dispatch(self, dec, sealed, eos):
+        return self._fn(*self._weights_and_pool(dec), *sealed,
+                        _sampling_key(dec), eos)
 
     def run(self, dec, tok0, pos, slots, base_mask, done0, eos):
         """One megastep dispatch. Returns device-resident
         ``(toks (K,B) i32, acts (K,B) bool, new_kvs, done)`` — the caller
         pulls the ids (the only host transfer) and pointer-swaps the KV."""
-        sig = self._sig_of(tok0, pos, slots, base_mask, done0)
-        if self._sig is not None and sig != self._sig:
-            if _tm.enabled():
-                _tm.counter("executor.retrace").inc()
-            raise MXNetError(
-                "decode megastep (K=%d): input signature drifted from the "
-                "warmed shapes (%r != %r) — megastep programs are sealed "
-                "like the executable cache" % (self.k, sig, self._sig))
-        if _tm.enabled():
-            _tm.counter("executor.cache_hit").inc()
-        weights = {n: dec._dec_exe.arg_dict[n]._jax()
-                   for n in self.weight_names}
-        kvs = tuple(dec._dec_exe.arg_dict[n]._jax() for n in self.kv_names)
-        return self._fn(weights, kvs, tok0, pos, slots, base_mask, done0,
-                        _sampling_key(dec), eos)
+        return self._run(dec, (tok0, pos, slots, base_mask, done0), eos)
 
 
 def _megastep_for(dec, k, sampler):
@@ -350,127 +376,80 @@ def _megastep_for(dec, k, sampler):
     return ms
 
 
-class _ChunkProgram:
+class _ChunkProgram(_SealedProgram):
     """T tokens of ONE lane scored (and optionally written) in a single
-    rectangular dispatch over the global paged pool
-    (models/transformer.py ``get_chunk_symbol``). Chunked prefill — admit
-    computes only a prompt's un-cached tail, C tokens per dispatch — and
-    the speculative draft-verify pass (γ+1 candidate positions at once)
-    are the SAME program at different T. Sealed exactly like the
-    megastep: one warm-time compile, signature drift is a hard retrace
-    error, weights are pulled from the live decode executable at dispatch
-    time so a hitless swap_params lands in the next chunk."""
+    rectangular dispatch over the pool (models/transformer.py
+    ``get_chunk_symbol``). Chunked prefill — admit computes only a
+    prompt's un-cached tail, C tokens per dispatch — and the speculative
+    draft-verify pass (γ+1 candidate positions at once) are the SAME
+    program at different T."""
 
     def __init__(self, dec, t):
-        import jax
-
-        from ..executor import _GraphProgram, _named
         from ..models import transformer as _tf
 
         self.t = int(t)
         S, L = dec.total_slots, dec.num_layers
-        self._S = S
-        symb = _tf.get_chunk_symbol(
-            vocab_size=dec.vocab_size, num_layers=L,
-            num_heads=dec.num_heads, model_dim=dec.model_dim,
-            ffn_dim=dec.ffn_dim, chunk_len=self.t, total_slots=S,
-            pos_len=dec.pos_len)
-        prog = _GraphProgram(symb)
-        if prog.aux_names:
-            raise MXNetError("chunk program: the chunk graph must carry "
-                             "no aux state, got %r" % (prog.aux_names,))
-        self.kv_names = _kv_names(L)
-        step_inputs = {"data", "pos_idx", "write_onehot", "att_mask"}
-        step_inputs.update(self.kv_names)
-        self.weight_names = [n for n in prog.arg_names
-                             if n not in step_inputs]
-        arg_names = list(prog.arg_names)
+        super().__init__("chunk program (T=%d)" % self.t,
+                         "chunk programs are", "serving.chunk_compile",
+                         t=self.t)
+        interpret = self._graph(
+            _tf.get_chunk_symbol(
+                vocab_size=dec.vocab_size, num_layers=L,
+                num_heads=dec.num_heads, model_dim=dec.model_dim,
+                ffn_dim=dec.ffn_dim, chunk_len=self.t, total_slots=S,
+                pos_len=dec.pos_len),
+            ("data", "pos_idx", "write_onehot", "att_mask"), L)
 
         def run(weights, kvs, data, pos_idx, w_oh, mask, key):
-            feed = {"data": data, "pos_idx": pos_idx,
-                    "write_onehot": w_oh, "att_mask": mask}
-            for i, name in enumerate(self.kv_names):
-                feed[name] = kvs[i]
-            args = [feed[n] if n in feed else weights[n]
-                    for n in arg_names]
-            outs, _ = prog.interpret(args, (), False, key)
+            outs = interpret(weights, kvs,
+                             {"data": data, "pos_idx": pos_idx,
+                              "write_onehot": w_oh, "att_mask": mask}, key)
             new_kv = tuple(outs[1 + j] for j in range(2 * L))
             return outs[0], new_kv, outs[-1]
 
-        self._fn = jax.jit(_named(run, "mx_chunk%d" % self.t))
-        self._sig = None
+        self._jit(run, "mx_chunk%d" % self.t)
 
-    def _zero_inputs(self):
-        T, S = self.t, self._S
-        data = np.zeros((1, T), np.float32)
-        pos_idx = np.zeros((1, T), np.float32)
-        w_oh = np.zeros((T, S), np.float32)
-        mask = np.full((T, S), _NEG, np.float32)
-        return data, pos_idx, w_oh, mask
+    def _dummy(self, dec):
+        """An all-pad chunk: zero writes, fully masked."""
+        T, S = self.t, dec.total_slots
+        return (np.zeros((1, T), np.float32), np.zeros((1, T), np.float32),
+                np.zeros((T, S), np.float32),
+                np.full((T, S), _NEG, np.float32)), ()
 
-    def warm(self, dec):
-        """Compile NOW with an all-pad (zero-write, fully-masked) chunk,
-        counted as this program's one ``executor.compile``."""
-        import jax
-
-        weights = {n: dec._dec_exe.arg_dict[n]._jax()
-                   for n in self.weight_names}
-        kvs = tuple(dec._dec_exe.arg_dict[n]._jax() for n in self.kv_names)
-        z = self._zero_inputs()
-        with _tm.span("serving.chunk_compile", t=self.t):
-            out = self._fn(weights, kvs, *z, _sampling_key(dec))
-            # graphlint: waive GL7xx -- warm-time compile barrier, not the dispatch path
-            jax.block_until_ready(out)
-        self._sig = _DecodeMegastep._sig_of(*z)
-        if _tm.enabled():
-            _tm.counter("executor.compile").inc()
+    def _dispatch(self, dec, sealed):
+        return self._fn(*self._weights_and_pool(dec), *sealed,
+                        _sampling_key(dec))
 
     def run(self, dec, data, pos_idx, w_oh, mask):
         """One chunk dispatch. Returns device-resident
         ``(logits (T, vocab), new_kvs, tokens (T,))`` — the caller
         pointer-swaps the KV and pulls only what it needs."""
-        sig = _DecodeMegastep._sig_of(data, pos_idx, w_oh, mask)
-        if self._sig is not None and sig != self._sig:
-            if _tm.enabled():
-                _tm.counter("executor.retrace").inc()
-            raise MXNetError(
-                "chunk program (T=%d): input signature drifted from the "
-                "warmed shapes (%r != %r) — chunk programs are sealed "
-                "like the executable cache" % (self.t, sig, self._sig))
-        if _tm.enabled():
-            _tm.counter("executor.cache_hit").inc()
-        weights = {n: dec._dec_exe.arg_dict[n]._jax()
-                   for n in self.weight_names}
-        kvs = tuple(dec._dec_exe.arg_dict[n]._jax() for n in self.kv_names)
-        return self._fn(weights, kvs, data, pos_idx, w_oh, mask,
-                        _sampling_key(dec))
+        return self._run(dec, (data, pos_idx, w_oh, mask))
 
 
-class _AdmitScatter:
+class _AdmitScatter(_SealedProgram):
     """The pool update of a classic admission as ONE program: the 2·layers
     pool buffers go in DONATED and come back updated in place with the
     prefill's K/V at positions ``0..length-1`` of the lane's page frames.
 
-    ``run(kvs, new, frames, length)``: ``new`` is the prefill executable's
-    2·layers K/V outputs ``(1, H, prefill_len, dh)``, ``frames`` the lane's
-    page-frame table padded to ``ceil(prefill_len / page_size)`` entries,
-    ``length`` the prompt length. Every shape is the decoder's, none the
-    prompt's, so one compile serves every prompt length. The update walks
+    ``run(dec, new, frames, length)``: ``new`` is the prefill executable's
+    2·layers K/V outputs ``(1, H, prefill_len, dh)`` (the sealed inputs),
+    ``frames`` the lane's page-frame table, ``length`` the prompt length.
+    Every shape is the decoder's, none the prompt's, so one compile serves
+    every prompt length. The update walks
     the prompt's pages with ``dynamic_update_slice`` — a page is a
     contiguous slot run — and blends the last, partial page with what the
     pool holds there, so exactly the slots of positions ``< length``
     change. A scatter over the slot axis would say the same, but the TPU
     keeps the pool with slots minor-most and re-lays the WHOLE buffer out
-    around a scatter, twice per buffer; the page walk leaves it in place.
-    Sealed like the megastep and chunk programs: one warm-time compile,
-    signature drift is a hard retrace error."""
+    around a scatter, twice per buffer; the page walk leaves it in place."""
 
     def __init__(self, dec):
         import jax
         import jax.numpy as jnp
 
-        from ..executor import _named
-
+        super().__init__("admit scatter", "the pool-update program is",
+                         "serving.admit_scatter_compile")
         self.kv_names = _kv_names(dec.num_layers)
         H, dh, ps = dec.num_heads, dec.dh, dec.page_size
         self.n_pages = -(-dec.prefill_len // ps)
@@ -499,369 +478,35 @@ class _AdmitScatter:
             return jax.lax.fori_loop(0, (length + ps - 1) // ps, page,
                                      tuple(kvs))
 
-        self._fn = jax.jit(_named(run, "mx_admit_scatter"),
-                           donate_argnums=(0,))
-        self._sig = None
+        self._jit(run, "mx_admit_scatter", donate_argnums=(0,))
 
-    def _call(self, dec, new, frames, length):
-        """Donate the pool, run, hand the updated buffers back: from the
-        enqueue on, the arrays ``arg_dict`` held before are dead."""
-        args = dec._dec_exe.arg_dict
-        kvs = tuple(args[n]._jax() for n in self.kv_names)
-        for name, arr in zip(self.kv_names,
-                             self._fn(kvs, new, frames, length)):
-            args[name]._set_jax(arr)
-
-    def warm(self, dec):
-        """Compile NOW on the live pool with a zero-length prompt (no page
-        is walked, every buffer comes back bitwise as it went in), counted
-        as this program's one ``executor.compile``. The K/V come from a
-        prefill staged the way an admission stages it, so the arrays are
-        of the kind a real call passes and jit never compiles again."""
-        import jax
-
+    def _dummy(self, dec):
+        """A zero-length prompt on the live pool: no page is walked, every
+        buffer comes back bitwise as it went in. The K/V come from a prefill
+        staged the way an admission stages it, so the arrays are of the kind
+        a real call passes and jit never compiles again."""
         pf = dec._pf_cache.executable({"data": (1, dec.prefill_len)})
         pf.arg_dict["data"][:] = np.zeros((1, dec.prefill_len), np.float32)
         pf.forward(is_train=False)
-        new = dec._prefill_kv(pf)
-        with _tm.span("serving.admit_scatter_compile"):
-            self._call(dec, new, np.zeros((self.n_pages,), np.int32),
-                       np.int32(0))
-            # graphlint: waive GL7xx -- warm-time compile barrier, not the dispatch path
-            jax.block_until_ready(
-                [dec._dec_exe.arg_dict[n]._jax() for n in self.kv_names])
-        self._sig = _DecodeMegastep._sig_of(*new)
-        if _tm.enabled():
-            _tm.counter("executor.compile").inc()
+        return dec._prefill_kv(pf), ((), 0)
+
+    def _dispatch(self, dec, new, frames, length):
+        """Donate the pool, run, hand the updated buffers back: from the
+        enqueue on, the arrays ``arg_dict`` held before are dead."""
+        table = np.zeros((self.n_pages,), np.int32)
+        table[:len(frames)] = frames
+        out = self._fn(self._live(dec, self.kv_names), new, table,
+                       np.int32(length))
+        for name, arr in zip(self.kv_names, out):
+            dec._dec_exe.arg_dict[name]._set_jax(arr)
+        return out
 
     def run(self, dec, new, frames, length):
         """One pool update, enqueued. ``frames`` is the lane's frame table
         (any length up to ``n_pages``), ``length`` the prompt length."""
-        sig = _DecodeMegastep._sig_of(*new)
-        if sig != self._sig:
-            if _tm.enabled():
-                _tm.counter("executor.retrace").inc()
-            raise MXNetError(
-                "admit scatter: input signature drifted from the warmed "
-                "shapes (%r != %r) — the pool-update program is sealed "
-                "like the executable cache" % (sig, self._sig))
+        self._run(dec, new, frames, length)
         if _tm.enabled():
-            _tm.counter("executor.cache_hit").inc()
             _tm.counter("serving.admit_scatter_dispatches").inc()
-        table = np.zeros((self.n_pages,), np.int32)
-        table[:len(frames)] = frames
-        self._call(dec, new, table, np.int32(length))
-
-
-class KVCacheDecoder:
-    """Batched greedy/streaming decode over the serving transformer.
-
-    ``arg_params`` is the trained {name: array} dict of
-    ``models/transformer.get_symbol`` (embed/pos/layerN_*/final_ln/lm_head
-    weights — the serving graphs share those names exactly).
-    """
-
-    def __init__(self, arg_params: Dict[str, object], vocab_size,
-                 num_layers=2, num_heads=2, model_dim=32, ffn_dim=64,
-                 max_len=64, prefill_len: Optional[int] = None,
-                 pos_len: Optional[int] = None, batch=1, ctx=None,
-                 dtype="float32", cache_dir=None, model_key=None,
-                 sample_seed=None, arch="vaswani"):
-        from ..models import transformer as _tf
-
-        _tf._refuse_arch(arch, "KVCacheDecoder")
-        self.vocab_size = int(vocab_size)
-        self.num_layers = int(num_layers)
-        self.num_heads = int(num_heads)
-        self.model_dim = int(model_dim)
-        self.ffn_dim = int(ffn_dim)
-        self.max_len = int(max_len)
-        self.prefill_len = int(prefill_len or max_len)
-        self.pos_len = int(pos_len or max_len)
-        self.batch = int(batch)
-        self.dh = self.model_dim // self.num_heads
-        if self.prefill_len > self.max_len:
-            raise MXNetError("kv_decode: prefill_len %d > max_len %d"
-                             % (self.prefill_len, self.max_len))
-        cfg = dict(vocab_size=self.vocab_size, num_layers=self.num_layers,
-                   num_heads=self.num_heads, model_dim=self.model_dim,
-                   ffn_dim=int(ffn_dim), pos_len=self.pos_len)
-        key = model_key or "transformer_decode"
-        self._pf_cache = PersistentExecutableCache(
-            _tf.get_prefill_symbol(prefill_len=self.prefill_len, **cfg),
-            arg_params, {}, ctx=ctx, dtype=dtype, cache_dir=cache_dir,
-            model_key=key + "-prefill")
-        self._dec_cache = PersistentExecutableCache(
-            _tf.get_decode_symbol(max_len=self.max_len, **cfg),
-            arg_params, {}, ctx=ctx, dtype=dtype, cache_dir=cache_dir,
-            model_key=key + "-decode")
-        self._dec_exe = None
-        self._pos = 0
-        self._warm = False
-        self._token_out = False
-        self._last_return_t = None  # dispatch.host_gap interval start
-        self._megasteps = {}        # (K, sampler) -> _DecodeMegastep
-        self._sample_seed = sample_seed
-        self._sample_key = None
-
-    # ------------------------------------------------------------ lifecycle
-    def _decode_shapes(self):
-        B, S, H, dh = self.batch, self.max_len, self.num_heads, self.dh
-        shapes = {"data": (B, 1), "pos_idx": (B, 1), "slot_onehot": (S,),
-                  "kv_mask": (S,)}
-        for i in range(self.num_layers):
-            shapes["kv_k_%d" % i] = (B, H, S, dh)
-            shapes["kv_v_%d" % i] = (B, H, S, dh)
-        return shapes
-
-    def warmup(self):
-        """Compile the prefill and decode executables; seal both caches —
-        any later shape drift is a hard retrace error, not a recompile."""
-        if self._warm:
-            return self
-        self._pf_cache.warmup([{"data": (self.batch, self.prefill_len)}])
-        self._dec_cache.warmup([self._decode_shapes()])
-        self._dec_exe = self._dec_cache.executable(self._decode_shapes())
-        # trailing greedy_token head (transformer.get_decode_symbol
-        # token_out=True)? A stale on-disk cache may hold the old program,
-        # so trust the compiled executable, not the symbol we asked for —
-        # and detect the head BY NAME, not by output count: a count check
-        # misreads any program whose output arity merely coincides (e.g.
-        # a cached token-less program at a different layer count)
-        self._token_out = any(
-            name.startswith("greedy_token")
-            for name in self._dec_exe.output_dict)
-        self._warm = True
-        return self
-
-    def reset(self):
-        """Forget all context (the KV slots are masked out, not zeroed —
-        the mask is the source of truth for validity)."""
-        self._pos = 0
-        self._last_return_t = None
-
-    @property
-    def position(self):
-        return self._pos
-
-    # -------------------------------------------------------------- prefill
-    def prefill(self, tokens):
-        """Consume a (B, L<=prefill_len) prompt in one executable call:
-        seeds the ring KV buffer with positions 0..L-1 and returns the
-        (B, vocab) logits at position L-1 (the first generation step's
-        distribution)."""
-        self.warmup()
-        tokens = np.asarray(tokens, dtype=np.float32)
-        if tokens.ndim == 1:
-            tokens = tokens[None]
-        B, L = tokens.shape
-        if B != self.batch:
-            raise MXNetError("kv_decode: prefill batch %d != engine batch %d"
-                             % (B, self.batch))
-        if not 0 < L <= self.prefill_len:
-            raise MXNetError("kv_decode: prompt length %d not in "
-                             "(0, %d]" % (L, self.prefill_len))
-        P = self.prefill_len
-        padded = np.zeros((B, P), np.float32)
-        padded[:, :L] = tokens
-        with _tm.span("serving.prefill", rows=B, prompt_len=L):
-            pf = self._pf_cache.executable({"data": (B, P)})
-            pf.arg_dict["data"][:] = padded
-            pf.forward(is_train=False)
-            # only the last real position's logits cross to the host
-            logits = np.asarray(
-                pf.outputs[0]._jax().reshape(
-                    B, P, self.vocab_size)[:, L - 1, :])
-        # seed the decode ring: slots 0..P-1 <- prefill K/V, entirely
-        # device-side — pointer swap when the ring is exactly the prefill
-        # window, a device scatter otherwise; the K/V tensors never round-
-        # trip through the host (slots >= L are garbage but masked until
-        # their positions are actually written)
-        exe = self._dec_exe
-        for i in range(self.num_layers):
-            for tag, out in (("kv_k_%d" % i, pf.outputs[1 + 2 * i]),
-                             ("kv_v_%d" % i, pf.outputs[2 + 2 * i])):
-                if P == self.max_len:
-                    exe.arg_dict[tag]._set_jax(out._jax())
-                else:
-                    ring = exe.arg_dict[tag]._jax()
-                    exe.arg_dict[tag]._set_jax(
-                        ring.at[:, :, 0:P, :].set(out._jax()))
-        self._pos = L
-        self._last_return_t = None  # new sequence: no prior decode return
-        if _tm.enabled():
-            _tm.counter("serving.prefill_tokens").inc(B * L)
-        return logits
-
-    # --------------------------------------------------------------- decode
-    def _stage_step(self, tokens):
-        """Host-side staging shared by ``decode_step``/``greedy_step``:
-        validate position, write the step's inputs, note the host gap.
-        Returns ``(exe, position)`` ready to dispatch."""
-        self.warmup()
-        p, S = self._pos, self.max_len
-        if p >= self.pos_len:
-            raise MXNetError(
-                "kv_decode: position %d exceeds the trained position table "
-                "(%d rows)" % (p, self.pos_len))
-        with _tm.span("serving.step.stage"):
-            tok = np.asarray(tokens, dtype=np.float32).reshape(self.batch, 1)
-            slot = p % S
-            oh = np.zeros((S,), np.float32)
-            oh[slot] = 1.0
-            mask = np.zeros((S,), np.float32)
-            if p + 1 < S:
-                mask[p + 1:] = _NEG  # slots beyond the history are empty
-            exe = self._dec_exe
-            exe.arg_dict["data"][:] = tok
-            exe.arg_dict["pos_idx"][:] = np.full((self.batch, 1), p,
-                                                 np.float32)
-            exe.arg_dict["slot_onehot"][:] = oh
-            exe.arg_dict["kv_mask"][:] = mask
-        _gap_mark(self, "serving.decode_step")
-        return exe, p
-
-    def _finish_step(self, exe):
-        """Post-pull bookkeeping: ring KV write-back (device pointer
-        swaps), position advance, counters."""
-        with _tm.span("serving.step.commit"):
-            _swap_kv(exe, self.num_layers)
-            self._pos += 1
-        if _tm.enabled():
-            _tm.counter("serving.decode_tokens").inc(self.batch)
-            _tm.gauge("decode.tokens_per_dispatch").set(self.batch)
-
-    def decode_step(self, tokens):
-        """One token per stream through the decode executable. ``tokens``
-        is (B,) or (B, 1); returns (B, vocab) logits for the NEXT
-        position. The ring KV update happens in-graph; host-side this is
-        arg/output pointer swaps only."""
-        exe, p = self._stage_step(tokens)
-        t0 = time.perf_counter()
-        with _tm.span("serving.decode_step", rows=self.batch, pos=p):
-            with _tm.span("serving.step.dispatch"):
-                exe.forward(is_train=False)
-            with _tm.span("serving.step.read"):
-                logits = exe.outputs[0].asnumpy()
-        if _tm.enabled():
-            _tm.timer("serving.decode_step").add(time.perf_counter() - t0)
-        _gap_return(self)
-        self._finish_step(exe)
-        return logits
-
-    def greedy_step(self, tokens):
-        """One GREEDY token per stream: same dispatch as ``decode_step``
-        but only the on-device ``greedy_token`` head crosses to the host —
-        (B,) int64 ids, one scalar per stream, instead of the full
-        (B, vocab) logits row (the first GL703 fix). Falls back to a host
-        argmax when the compiled decode program has no token head."""
-        if not self._token_out:
-            self.warmup()
-            if not self._token_out:
-                # graphlint: waive GL703 -- fallback for stale token-less programs
-                return np.argmax(self.decode_step(tokens), axis=-1)
-        exe, p = self._stage_step(tokens)
-        t0 = time.perf_counter()
-        with _tm.span("serving.decode_step", rows=self.batch, pos=p,
-                      greedy=True):
-            with _tm.span("serving.step.dispatch"):
-                exe.forward(is_train=False)
-            with _tm.span("serving.step.read"):
-                # graphlint: waive GL701 -- single-step tail of the megastep loop; the K-amortized body is the lax.scan in decode_megastep
-                nxt = exe.outputs[-1].asnumpy()
-        if _tm.enabled():
-            _tm.timer("serving.decode_step").add(time.perf_counter() - t0)
-        _gap_return(self)
-        self._finish_step(exe)
-        return nxt.astype(np.int64)
-
-    def decode_megastep(self, tokens, k=None, eos_id=None, sample=None,
-                        temperature=None, top_k=None):
-        """K tokens per stream in ONE dispatch: the K-step decode loop
-        runs as a ``lax.scan`` INSIDE the compiled program — in-graph
-        ring writes, on-device sampling (greedy argmax by default;
-        ``sample='topk'`` with ``temperature``/``top_k`` draws through
-        the PRNG machinery) — and only the (B, K) token ids cross back
-        to the host. ``eos_id`` arms per-lane early exit: once a lane
-        emits it, its later scan steps write NOTHING to the KV buffers
-        (all-zero slot_onehot rows) and its remaining outputs are eos
-        filler; the lockstep position still advances by K for every
-        lane. Returns (B, K) int64 ids. ``tokens`` is the (B,) step
-        input, exactly as for ``greedy_step``."""
-        self.warmup()
-        k = int(k) if k is not None else decode_megastep_k()
-        if k < 1:
-            raise MXNetError("decode_megastep: K must be >= 1, got %d" % k)
-        p, S, B = self._pos, self.max_len, self.batch
-        if p + k > self.pos_len:
-            raise MXNetError(
-                "decode_megastep: positions %d..%d exceed the trained "
-                "position table (%d rows)" % (p, p + k - 1, self.pos_len))
-        ms = _megastep_for(self, k,
-                           _sampler_from(sample, temperature, top_k))
-        tok0 = np.asarray(tokens, np.int32).reshape(B)
-        posv = np.full((B,), p, np.int32)
-        # slot plan: K consecutive ring slots, staged host-side exactly
-        # like _stage_step stages one
-        slots = np.tile((np.arange(p, p + k) % S).astype(np.int32), (B, 1))
-        valid = np.arange(S) < min(p, S)
-        base_mask = np.broadcast_to(
-            np.where(valid, np.float32(0), _NEG), (B, S)) \
-            .astype(np.float32).copy()
-        done0 = np.zeros((B,), bool)
-        eos = np.int32(-1 if eos_id is None else int(eos_id))
-        _gap_mark(self, "serving.decode_megastep")
-        t0 = time.perf_counter()
-        with _tm.span("serving.decode_megastep", rows=B, pos=p, k=k):
-            with _tm.span("serving.step.dispatch"):
-                toks, acts, new_kvs, _done = ms.run(
-                    self, tok0, posv, slots, base_mask, done0, eos)
-            with _tm.span("serving.step.read"):
-                ids = np.asarray(toks)       # (K, B): the only host pull
-                acts_h = np.asarray(acts)
-        if _tm.enabled():
-            _tm.timer("serving.decode_megastep").add(
-                time.perf_counter() - t0)
-        _gap_return(self)
-        for name, arr in zip(ms.kv_names, new_kvs):
-            self._dec_exe.arg_dict[name]._set_jax(arr)
-        self._pos = p + k
-        if _tm.enabled():
-            _tm.counter("serving.decode_tokens").inc(int(acts_h.sum()))
-            _tm.counter("serving.megasteps").inc()
-            _tm.gauge("decode.tokens_per_dispatch").set(ids.size)
-        return ids.T.astype(np.int64)
-
-    def greedy(self, prompt, n_tokens, k=None, eos_id=None):
-        """Greedy-decode ``n_tokens`` continuations of a (B, L) prompt.
-        With ``k`` > 1 (default ``MXNET_DECODE_MEGASTEP_K``) the loop
-        advances K tokens per dispatch through ``decode_megastep``; the
-        sub-K tail reuses the single-step program (both are warm — no
-        extra compiles). K=1 reproduces the classic per-token loop call
-        for call. Returns (B, n_tokens) int64 token ids."""
-        k = int(k) if k is not None else decode_megastep_k()
-        logits = self.prefill(prompt)
-        # prompt-head argmax: once per SEQUENCE, and the prefill API hands
-        # these logits to the host anyway; the per-token loop below stays
-        # on device via greedy_step
-        nxt = np.argmax(logits, axis=-1)  # graphlint: waive GL703 -- once per sequence, logits already host-side
-        out = np.zeros((self.batch, n_tokens), np.int64)
-        if n_tokens:
-            out[:, 0] = nxt
-        t = 1
-        while t < n_tokens:
-            if k > 1 and n_tokens - t >= k:
-                # graphlint: waive GL702 -- K steps already folded into one lax.scan dispatch; the carried token is K-amortized
-                chunk = self.decode_megastep(nxt, k=k, eos_id=eos_id)
-                out[:, t:t + k] = chunk
-                nxt = chunk[:, -1]
-                t += k
-            else:
-                # graphlint: waive GL702 -- sub-K tail: fewer than K tokens left, single-step program is already warm
-                nxt = self.greedy_step(nxt)
-                out[:, t] = nxt
-                t += 1
-        return out
 
 
 # --------------------------------------------------------------- paged decode
@@ -967,28 +612,26 @@ class PagedKVDecoder:
     """Multiplexed KV-cache decode: ONE decode batch serves many
     concurrent, independently-positioned sequences (docs/SERVING.md).
 
-    ``KVCacheDecoder`` is per-request-shaped — all B streams march in
-    lockstep from one prefill. This decoder instead treats the decode
-    executable's batch rows as ``lanes``: sequences are admitted one at a
-    time (a batch-1 prefill seeds that lane's slots), advance at their own
-    positions, and retire independently — the continuous-batching idea
-    applied to autoregressive decode. Slot storage is paged: each lane's
-    ring is carved into ``page_size``-slot frames allocated on demand from
-    a ``_PagePool`` (and freed at retire), so short sequences don't
-    reserve ``max_len`` slots of KV for their whole life and admission
-    fails with a structured ``PagedKVExhausted`` instead of an OOM.
+    The decode executable's batch rows are ``lanes``: sequences are
+    admitted one at a time (a batch-1 prefill seeds that lane's slots),
+    advance at their own positions, and retire independently — the
+    continuous-batching idea applied to autoregressive decode; ``lanes``
+    prompts admitted together and stepped together are a lockstep batch.
+    Slot storage is paged: a lane's ``max_len`` slots are carved into
+    ``page_size``-slot frames allocated on demand from a ``_PagePool``
+    (and freed at retire), so short sequences don't reserve ``max_len``
+    slots of KV for their whole life and admission fails with a structured
+    ``PagedKVExhausted`` instead of an OOM.
 
-    Per-lane math is identical to a batch-1 ``KVCacheDecoder`` at the same
-    position (the per-stream decode graph differs only in carrying one
-    slot_onehot/kv_mask row per lane), so multiplexed decode is
+    Per-lane math does not depend on what the other lanes hold (each lane
+    carries its own slot_onehot/kv_mask row), so multiplexed decode is
     token-identical to sequential per-request decode — the acceptance
     test pins exactly that.
 
-    KV storage is ONE global slot pool (``get_decode_symbol``
-    ``global_slots=True``): per layer the buffers are
-    (H, lanes·max_len, dh) and every lane's onehot/mask row indexes the
-    shared axis, so a page frame is just a slot range ANY lane can
-    reference. That is the substrate for cross-request prefix reuse
+    KV storage is ONE slot pool (``get_decode_symbol``): per layer the
+    buffers are (H, lanes·max_len, dh) and every lane's onehot/mask row
+    indexes the shared axis, so a page frame is just a slot range ANY lane
+    can reference. That is the substrate for cross-request prefix reuse
     (serving/prefix_cache.py): with ``prefix_cache=True`` (or
     ``MXNET_SERVE_PREFIX_CACHE=1``) admit hashes the prompt in
     ``prefix_chunk``-token chunks, adopts the cached pages of the longest
@@ -1043,7 +686,6 @@ class PagedKVDecoder:
                               budget=page_budget)
         self.page_size = self.pool.page_size
         self.total_slots = self.lanes * self.max_len
-        self._global_slots = True
         if prefix_cache is None:
             prefix_cache = os.environ.get(
                 "MXNET_SERVE_PREFIX_CACHE", "").strip().lower() \
@@ -1089,9 +731,7 @@ class PagedKVDecoder:
             arg_params, {}, model_key=key + "-prefill",
             program_label="mx_prefill", **binding)
         self._dec_cache = PersistentExecutableCache(
-            _tf.get_decode_symbol(max_len=self.total_slots,
-                                  per_stream_slots=True,
-                                  global_slots=True, **cfg),
+            _tf.get_decode_symbol(max_len=self.total_slots, **cfg),
             arg_params, {}, model_key=key + "-decode",
             program_label="mx_decode", **binding)
         self._dec_exe = None
@@ -1193,9 +833,9 @@ class PagedKVDecoder:
         exe = self._dec_exe
         for i in range(self.num_layers):
             for tag in ("kv_k_%d" % i, "kv_v_%d" % i):
-                ring = exe.arg_dict[tag]._jax()
+                buf = exe.arg_dict[tag]._jax()
                 exe.arg_dict[tag]._set_jax(
-                    ring.at[:, dst, :].set(ring[:, src, :]))
+                    buf.at[:, dst, :].set(buf[:, src, :]))
         self.pool.release([frame])
         lane.frames[page] = fresh
         if _tm.enabled():

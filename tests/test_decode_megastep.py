@@ -1,10 +1,8 @@
-"""Decode megasteps (mxnet_tpu/serving/kv_decode.py decode_megastep /
-step_megastep, docs/SERVING.md §Megasteps): K tokens per dispatch through
-one lax.scan program. Gates: token-identical parity with single-step
-greedy, seeded top-k reproducibility across K partitionings, EOS
-early-exit lanes write NOTHING (KV bitwise-unchanged past eos), paged
-pre-acquire backpressure, and the name-based token-head detection that
-keeps a disk-cached K=1 program from masquerading as a megastep one."""
+"""Decode megasteps (mxnet_tpu/serving/kv_decode.py step_megastep,
+docs/SERVING.md §Megasteps): K tokens per dispatch through one lax.scan
+program. Gates: token-identical parity with single-step greedy, seeded
+top-k reproducibility across K partitionings, EOS early-exit lanes write
+NOTHING (KV bitwise-unchanged past eos), and pre-acquire backpressure."""
 import numpy as np
 import pytest
 
@@ -12,7 +10,7 @@ import mxnet_tpu as mx
 from mxnet_tpu import telemetry
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.models import transformer as tfm
-from mxnet_tpu.serving import KVCacheDecoder, PagedKVDecoder, PagedKVExhausted
+from mxnet_tpu.serving import PagedKVDecoder, PagedKVExhausted
 from mxnet_tpu.serving.kv_decode import decode_megastep_k
 
 CFG = dict(vocab_size=50, num_layers=2, num_heads=2, model_dim=32,
@@ -45,14 +43,30 @@ def _params(S, seed=0):
     return params
 
 
-def _decoder(params, S, B, **kw):
-    return KVCacheDecoder(params, max_len=S, prefill_len=8, pos_len=S,
-                          batch=B, **CFG, **kw)
+def _decoder(params, S, B, page_size=4, **kw):
+    return PagedKVDecoder(params, max_len=S, page_size=page_size, lanes=B,
+                          prefill_len=8, pos_len=S, **CFG, **kw)
 
 
 def _prompt(B, seed=3, L=4):
     rs = np.random.RandomState(seed)
     return rs.randint(1, CFG["vocab_size"], (B, L)).astype(np.float32)
+
+
+def _admit(dec, prompt):
+    """One lane per row of ``prompt``: (seq ids, each one's first token)."""
+    sids, tok = [], []
+    for row in prompt:
+        sid, logits = dec.admit(row)
+        sids.append(sid)
+        tok.append(int(np.argmax(logits)))
+    return sids, np.asarray(tok)
+
+
+def _megastep(dec, sids, tok, **kw):
+    """``step_megastep`` of every lane as one (B, K) array."""
+    out = dec.step_megastep(dict(zip(sids, tok)), **kw)
+    return np.stack([out[sid] for sid in sids])
 
 
 # ------------------------------------------------------------------ knobs
@@ -68,26 +82,14 @@ def test_megastep_k_env(monkeypatch):
 
 
 # ----------------------------------------------------------------- parity
-def test_megastep_greedy_token_identical(tm):
-    """The acceptance gate: K-chunked greedy == single-step greedy,
-    token for token — the scan body IS the single-step math."""
-    tm.set_mode("counters")
-    S, B, n = 32, 2, 17
-    params = _params(S)
-    prompt = _prompt(B)
-    seq = _decoder(params, S, B).greedy(prompt, n, k=1)
-    mega = _decoder(params, S, B).greedy(prompt, n, k=4)
-    np.testing.assert_array_equal(seq, mega)
-
-
 def test_megastep_env_default_drives_greedy(tm, monkeypatch):
     tm.set_mode("counters")
     S, B, n = 32, 2, 9
     params = _params(S)
     prompt = _prompt(B)
-    base = _decoder(params, S, B).greedy(prompt, n, k=1)
+    base = _decoder(params, S, B).greedy(list(prompt), n, k=1)
     monkeypatch.setenv("MXNET_DECODE_MEGASTEP_K", "4")
-    got = _decoder(params, S, B).greedy(prompt, n)
+    got = _decoder(params, S, B).greedy(list(prompt), n)
     np.testing.assert_array_equal(base, got)
 
 
@@ -99,12 +101,11 @@ def test_megastep_zero_retrace_and_sealed(tm):
     S, B, K = 32, 2, 4
     params = _params(S)
     dec = _decoder(params, S, B)
-    logits = dec.prefill(_prompt(B))
-    tok = np.argmax(logits, axis=-1)
-    chunk = dec.decode_megastep(tok, k=K)  # compiles + seals here
+    sids, tok = _admit(dec, _prompt(B))
+    chunk = _megastep(dec, sids, tok, k=K)  # compiles + seals here
     c0 = tm.counters()
     for _ in range(3):
-        chunk = dec.decode_megastep(chunk[:, -1], k=K)
+        chunk = _megastep(dec, sids, chunk[:, -1], k=K)
     c1 = tm.counters()
     assert c1.get("executor.retrace", 0) == c0.get("executor.retrace", 0)
     assert c1.get("executor.compile", 0) == c0.get("executor.compile", 0)
@@ -115,9 +116,8 @@ def test_megastep_counters_and_gauge(tm):
     tm.set_mode("counters")
     S, B, K = 32, 2, 4
     dec = _decoder(_params(S), S, B)
-    logits = dec.prefill(_prompt(B))
-    tok = np.argmax(logits, axis=-1)
-    dec.decode_megastep(tok, k=K)
+    sids, tok = _admit(dec, _prompt(B))
+    _megastep(dec, sids, tok, k=K)
     c = tm.counters()
     assert c.get("serving.megasteps", 0) == 1
     assert c.get("serving.decode_tokens", 0) >= B * K
@@ -127,10 +127,9 @@ def test_megastep_counters_and_gauge(tm):
 def test_megastep_position_budget_raises():
     S, B = 16, 1
     dec = _decoder(_params(S), S, B)
-    logits = dec.prefill(_prompt(B, L=4))
-    tok = np.argmax(logits, axis=-1)
+    sids, tok = _admit(dec, _prompt(B, L=4))
     with pytest.raises(MXNetError):
-        dec.decode_megastep(tok, k=S)  # pos 4 + 16 > pos_len 16
+        _megastep(dec, sids, tok, k=S)  # pos 4 + 16 > pos_len 16
 
 
 # --------------------------------------------------------------- sampling
@@ -144,13 +143,13 @@ def test_topk_sampling_reproducible_across_k(tm):
     kw = dict(sample="topk", temperature=0.8, top_k=5)
 
     d4 = _decoder(params, S, B, sample_seed=11)
-    tok = np.argmax(d4.prefill(prompt), axis=-1)
-    full = d4.decode_megastep(tok, k=4, **kw)
+    sids, tok = _admit(d4, prompt)
+    full = _megastep(d4, sids, tok, k=4, **kw)
 
     d2 = _decoder(params, S, B, sample_seed=11)
-    tok = np.argmax(d2.prefill(prompt), axis=-1)
-    a = d2.decode_megastep(tok, k=2, **kw)
-    b = d2.decode_megastep(a[:, -1], k=2, **kw)
+    sids, tok = _admit(d2, prompt)
+    a = _megastep(d2, sids, tok, k=2, **kw)
+    b = _megastep(d2, sids, a[:, -1], k=2, **kw)
     np.testing.assert_array_equal(full, np.concatenate([a, b], axis=1))
 
 
@@ -170,8 +169,8 @@ def test_eos_early_exit_writes_nothing(tm):
     kw = dict(sample="topk", temperature=1.5, top_k=10)
 
     probe_dec = _decoder(params, S, B, sample_seed=23)
-    tok0 = np.argmax(probe_dec.prefill(prompt), axis=-1)
-    probe = probe_dec.decode_megastep(tok0, k=K, **kw)  # (B, K) eos-free
+    sids, tok0 = _admit(probe_dec, prompt)
+    probe = _megastep(probe_dec, sids, tok0, k=K, **kw)  # (B, K) eos-free
 
     # an eos candidate lane 0 emits mid-megastep, not emitted earlier by
     # lane 0 and never emitted by lane 1 (keeps lane 1 assertions exact)
@@ -184,13 +183,13 @@ def test_eos_early_exit_writes_nothing(tm):
     assert eos is not None, "no usable eos candidate in %r" % probe
 
     dec = _decoder(params, S, B, sample_seed=23)
-    tok0 = np.argmax(dec.prefill(prompt), axis=-1)
-    p = dec.position
+    sids, tok0 = _admit(dec, prompt)
+    p = dec.position(sids[0])
     kv_names = [n for n in dec._dec_exe.arg_dict
                 if n.startswith(("kv_k_", "kv_v_"))]
     before = {n: np.asarray(dec._dec_exe.arg_dict[n]._jax()).copy()
               for n in kv_names}
-    out = dec.decode_megastep(tok0, k=K, eos_id=eos, **kw)
+    out = _megastep(dec, sids, tok0, k=K, eos_id=eos, **kw)
 
     # lane 0: tokens up to and including eos match the eos-free run, the
     # rest is eos filler
@@ -201,40 +200,50 @@ def test_eos_early_exit_writes_nothing(tm):
 
     after = {n: np.asarray(dec._dec_exe.arg_dict[n]._jax())
              for n in kv_names}
-    # step t writes slot p+t for its INPUT token; the eos EMITTED at step
-    # j latches done, so steps j+1.. write nothing for lane 0
-    dead = [(p + t) % S for t in range(j + 1, K)]
-    live = [(p + t) % S for t in range(0, j + 1)]
+    # step t writes the slot of position p+t for its INPUT token; the eos
+    # EMITTED at step j latches done, so steps j+1.. write nothing for
+    # lane 0, which stays at the position of its last write
+    assert dec.position(sids[0]) == p + j + 1
+    assert dec.position(sids[1]) == p + K
+    # the slots of all K positions were acquired up front, for both lanes
+    slots = [dec._lane_slots(dec._lanes[dec._seq_lane[sid]], upto=p + K)
+             for sid in sids]
+    dead = [slots[0][p + t] for t in range(j + 1, K)]
+    live = [slots[0][p + t] for t in range(0, j + 1)]
+    other = [slots[1][p + t] for t in range(j + 1, K)]
     for n in kv_names:
         np.testing.assert_array_equal(
-            after[n][0][:, dead, :], before[n][0][:, dead, :],
+            after[n][:, dead, :], before[n][:, dead, :],
             err_msg="%s: EOS'd lane wrote past its eos step" % n)
         # sanity: the pre-eos slots DID get written
-        assert not np.array_equal(after[n][0][:, live, :],
-                                  before[n][0][:, live, :])
+        assert not np.array_equal(after[n][:, live, :],
+                                  before[n][:, live, :])
         # lane 1 wrote all K slots
-        assert not np.array_equal(after[n][1][:, dead, :],
-                                  before[n][1][:, dead, :])
+        assert not np.array_equal(after[n][:, other, :],
+                                  before[n][:, other, :])
 
 
 # ------------------------------------------------------------------ paged
-def test_paged_megastep_parity_with_page_crossing(tm):
-    """Paged K-chunked greedy == paged single-step greedy with page_size 4
-    and enough tokens that every lane crosses a page boundary mid-run."""
+@pytest.mark.parametrize("case", ["lockstep", "staggered"])
+def test_paged_megastep_parity_with_page_crossing(tm, case):
+    """The acceptance gate: K-chunked greedy == single-step greedy, token
+    for token (the scan body IS the single-step math), with page_size 4
+    and enough tokens that every lane crosses a page boundary mid-run.
+    ``lockstep``: 2 prompts of one length, 17 tokens; ``staggered``: 3
+    prompts of 2, 3 and 4 tokens, so three positions in every dispatch."""
     tm.set_mode("counters")
-    S, n_streams, n = 32, 3, 13
+    S = 32
     params = _params(S)
-    rs = np.random.RandomState(9)
-    prompts = [rs.randint(1, CFG["vocab_size"], (2 + i,)).astype(np.float32)
-               for i in range(n_streams)]
-
-    def mk():
-        return PagedKVDecoder(params, max_len=S, page_size=4,
-                              lanes=n_streams, prefill_len=8, pos_len=S,
-                              **CFG)
-
-    seq = mk().greedy(prompts, n, k=1)
-    mega = mk().greedy(prompts, n, k=4)
+    if case == "lockstep":
+        n_streams, n, prompts = 2, 17, list(_prompt(2))
+    else:
+        n_streams, n = 3, 13
+        rs = np.random.RandomState(9)
+        prompts = [rs.randint(1, CFG["vocab_size"],
+                              (2 + i,)).astype(np.float32)
+                   for i in range(n_streams)]
+    seq = _decoder(params, S, n_streams).greedy(prompts, n, k=1)
+    mega = _decoder(params, S, n_streams).greedy(prompts, n, k=4)
     for a, b in zip(seq, mega):
         np.testing.assert_array_equal(a, b)
 
@@ -246,8 +255,7 @@ def test_paged_megastep_backpressure_before_dispatch(tm):
     tm.set_mode("counters")
     S = 16
     params = _params(S)
-    dec = PagedKVDecoder(params, max_len=S, page_size=2, lanes=2,
-                         prefill_len=8, pos_len=S, page_budget=5, **CFG)
+    dec = _decoder(params, S, 2, page_size=2, page_budget=5)
     rs = np.random.RandomState(1)
     pa = rs.randint(1, CFG["vocab_size"], (3,)).astype(np.float32)
     pb = rs.randint(1, CFG["vocab_size"], (3,)).astype(np.float32)
@@ -285,8 +293,7 @@ def test_paged_megastep_matches_single_steps(tm):
             toks[sid] = int(np.argmax(logits))
         return toks
 
-    d1 = PagedKVDecoder(params, max_len=S, page_size=4, lanes=2,
-                        prefill_len=8, pos_len=S, **CFG)
+    d1 = _decoder(params, S, 2)
     toks = admit_all(d1)
     want = {sid: [] for sid in toks}
     cur = dict(toks)
@@ -296,29 +303,9 @@ def test_paged_megastep_matches_single_steps(tm):
         for sid in cur:
             want[sid].append(cur[sid])
 
-    d2 = PagedKVDecoder(params, max_len=S, page_size=4, lanes=2,
-                        prefill_len=8, pos_len=S, **CFG)
+    d2 = _decoder(params, S, 2)
     toks2 = admit_all(d2)
     assert toks2 == toks
     got = d2.step_megastep(toks2, k=K)
     for sid in toks:
         np.testing.assert_array_equal(got[sid], np.asarray(want[sid]))
-
-
-# -------------------------------------------------- token-head detection
-def test_token_out_detected_by_name_not_arity(tm):
-    """warmup() must key the greedy-token head off the OUTPUT NAME, not
-    the output count: a coincidental arity match (e.g. a disk-cached K=1
-    program with 1 + 2*layers outputs) must not masquerade as a
-    token-head program."""
-    tm.set_mode("counters")
-    S, B = 16, 1
-    dec = _decoder(_params(S), S, B)
-    dec.warmup()
-    names = list(dec._dec_exe.output_dict)
-    assert any(n.startswith("greedy_token") for n in names)
-    assert dec._token_out is True
-    # a program with the same ARITY but no greedy_token output must read
-    # as token_out=False — the old count-based sniff got this wrong
-    fake = {("out%d" % i): None for i in range(len(names))}
-    assert not any(n.startswith("greedy_token") for n in fake)

@@ -1,7 +1,8 @@
 """KV-cache incremental decode (mxnet_tpu/serving/kv_decode.py +
 models/transformer.py serving symbols, docs/SERVING.md): token-identical
 greedy parity against full-sequence re-forward, prefill-length
-independence, ring wraparound mechanics, and the zero-retrace contract."""
+independence, the paged pool, the sealed programs, and the zero-retrace
+contract."""
 import os
 
 import numpy as np
@@ -11,7 +12,7 @@ import mxnet_tpu as mx
 from mxnet_tpu import telemetry
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.models import transformer as tfm
-from mxnet_tpu.serving import KVCacheDecoder
+from mxnet_tpu.serving import PagedKVDecoder, PagedKVExhausted
 
 CFG = dict(vocab_size=50, num_layers=2, num_heads=2, model_dim=32,
            ffn_dim=64)
@@ -43,6 +44,11 @@ def _trained_params(S, seed=0):
         arr[:] = w
         params[name] = w
     return net, exe, params
+
+
+def _paged(params, S, lanes, prefill_len=8, **kw):
+    return PagedKVDecoder(params, max_len=S, page_size=4, lanes=lanes,
+                          prefill_len=prefill_len, pos_len=S, **CFG, **kw)
 
 
 def _ref_greedy(exe, prompt, n_tokens, S, vocab):
@@ -78,10 +84,9 @@ def test_greedy_decode_token_identical_32(tm):
         rexe.arg_dict[k][:] = v
     rs = np.random.RandomState(3)
     prompt = rs.randint(1, CFG["vocab_size"], (B, 4))
-    dec = KVCacheDecoder(params, max_len=S, prefill_len=8, pos_len=S,
-                         batch=B, **CFG)
+    dec = _paged(params, S, lanes=B)
     c0 = tm.counters()
-    got = dec.greedy(prompt.astype(np.float32), 32)
+    got = np.stack(dec.greedy(list(prompt.astype(np.float32)), 32))
     c1 = tm.counters()
     want = _ref_greedy(rexe, prompt, 32, S, CFG["vocab_size"])
     np.testing.assert_array_equal(got, want)
@@ -89,8 +94,7 @@ def test_greedy_decode_token_identical_32(tm):
     assert c1.get("executor.retrace", 0) == c0.get("executor.retrace", 0)
     # (snapshot after the oracle ran: its own first forward compiles too)
     warm_compiles = tm.counters().get("executor.compile", 0)
-    dec.reset()
-    dec.greedy(prompt.astype(np.float32), 8)
+    dec.greedy(list(prompt.astype(np.float32)), 8)
     assert tm.counters().get("executor.compile", 0) == warm_compiles, \
         "a second decode recompiled something"
 
@@ -101,9 +105,8 @@ def test_prefill_logits_match_full_forward():
     rs = np.random.RandomState(5)
     L = 6
     prompt = rs.randint(1, CFG["vocab_size"], (1, L)).astype(np.float32)
-    dec = KVCacheDecoder(params, max_len=S, prefill_len=16, pos_len=S,
-                         batch=1, **CFG)
-    logits = dec.prefill(prompt)
+    dec = _paged(params, S, lanes=1, prefill_len=16)
+    logits = dec.admit(prompt)[1][None]
     pad = np.zeros((1, S), np.float32)
     pad[:, :L] = prompt
     exe.arg_dict["data"][:] = pad
@@ -115,36 +118,29 @@ def test_prefill_logits_match_full_forward():
     np.testing.assert_allclose(p, probs[:, L - 1, :], rtol=1e-4, atol=1e-5)
 
 
-def test_ring_wraparound_mechanics():
-    """Decode past max_len: the ring overwrites the oldest slot and keeps
-    going (sliding-window attention). Output stays finite, position
-    tracking advances, and no executable churn occurs."""
-    S = 8
-    _, _, params = _trained_params(16)
-    dec = KVCacheDecoder(params, max_len=S, prefill_len=4, pos_len=16,
-                         batch=1, **CFG)
-    logits = dec.prefill(np.ones((1, 3), np.float32))
-    for _ in range(13):  # crosses pos=8 (wrap) while pos < pos_len=16
-        logits = dec.decode_step(np.argmax(logits, axis=-1))
-    assert dec.position == 16
-    assert np.isfinite(logits).all()
-    # trained position table exhausted -> structured error, not OOB
-    with pytest.raises(MXNetError, match="position table"):
-        dec.decode_step(np.zeros((1,), np.float32))
-
-
 def test_decoder_input_validation():
     S = 16
     _, _, params = _trained_params(S)
     with pytest.raises(MXNetError, match="prefill_len"):
-        KVCacheDecoder(params, max_len=8, prefill_len=16, pos_len=S,
-                       batch=1, **CFG)
-    dec = KVCacheDecoder(params, max_len=S, prefill_len=8, pos_len=S,
-                         batch=2, **CFG)
-    with pytest.raises(MXNetError, match="batch"):
-        dec.prefill(np.ones((1, 4), np.float32))
+        _paged(params, 8, lanes=1, prefill_len=16)
+    dec = _paged(params, S, lanes=2)
     with pytest.raises(MXNetError, match="length"):
-        dec.prefill(np.ones((2, 9), np.float32))
+        dec.admit(np.ones((9,), np.float32))
+    with pytest.raises(MXNetError, match="unknown seq_id"):
+        dec.step({7: 1})
+    # a lane is bounded by the trained position table and by max_len:
+    # structured errors, not an out-of-bounds gather or a wrapped write
+    short = PagedKVDecoder(params, max_len=S, page_size=4, lanes=1,
+                           prefill_len=8, pos_len=6, **CFG)
+    sid, _ = short.admit(np.ones((6,), np.float32))
+    with pytest.raises(MXNetError, match="position table"):
+        short.step({sid: 1})
+    sid, logits = dec.admit(np.ones((8,), np.float32))
+    for _ in range(S - 8):
+        logits = dec.step({sid: int(np.argmax(logits))})[sid]
+    assert dec.position(sid) == S and np.isfinite(logits).all()
+    with pytest.raises(MXNetError, match="position table|slot quota"):
+        dec.step({sid: 1})
 
 
 def test_serving_symbols_share_training_weight_names():
@@ -211,8 +207,6 @@ def test_paged_multiplexed_token_identical():
     decode batch, admitted at different times and advancing at different
     positions, produce token-identical output to sequential per-request
     decode — and the multiplexed path never retraces."""
-    from mxnet_tpu.serving import PagedKVDecoder
-
     telemetry.reset()
     telemetry.set_mode("counters")
     try:
@@ -222,11 +216,9 @@ def test_paged_multiplexed_token_identical():
         prompts = [rs.randint(1, CFG["vocab_size"], (n,)).astype(np.float32)
                    for n in (3, 5, 2)]
 
-        # oracle: each prompt decoded alone through a batch-1 ring decoder
+        # oracle: each prompt decoded alone through a one-lane decoder
         def solo(prompt, n_tok):
-            dec = KVCacheDecoder(params, max_len=S, prefill_len=8,
-                                 pos_len=S, batch=1, **CFG)
-            return dec.greedy(prompt[None], n_tok)[0]
+            return _paged(params, S, lanes=1).greedy([prompt], n_tok)[0]
 
         want = [solo(p, 6) for p in prompts]
 
@@ -281,8 +273,6 @@ def test_paged_admission_backpressure_and_reuse():
     PagedKVExhausted (admission backpressure); retiring frees the lane
     and its pages for the next sequence, which lands on recycled
     (non-contiguous) frames and still decodes identically."""
-    from mxnet_tpu.serving import PagedKVDecoder, PagedKVExhausted
-
     S = 16
     _, _, params = _trained_params(S)
     rs = np.random.RandomState(11)
@@ -296,9 +286,7 @@ def test_paged_admission_backpressure_and_reuse():
         paged.admit(prompt)
     paged.retire(s0)
     s2, lg = paged.admit(prompt)  # recycled lane + frames
-    dec = KVCacheDecoder(params, max_len=S, prefill_len=8, pos_len=S,
-                         batch=1, **CFG)
-    want = dec.greedy(prompt[None], 4)[0]
+    want = _paged(params, S, lanes=1).greedy([prompt], 4)[0]
     toks = []
     for _ in range(4):
         t = int(np.argmax(lg))
@@ -316,28 +304,30 @@ def test_paged_admission_backpressure_and_reuse():
 
 # ---------------------------------------------- on-device greedy head (GL703)
 def test_greedy_step_on_device_argmax_token_parity(tm):
-    """The GL703 fix gate: greedy_step (on-device argmax head, host pulls
-    ONE id per stream) is token-identical to pulling the full logits row
-    and arg-maxing on host, step for step."""
+    """The GL703 fix gate: the decode program's on-device ``greedy_token``
+    head (one id per lane for a greedy driver to pull) is token-identical
+    to pulling the full logits row and arg-maxing on host, step for step."""
     tm.set_mode("counters")
     S, B = 32, 2
     _, _, params = _trained_params(S)
     rs = np.random.RandomState(7)
     prompt = rs.randint(1, CFG["vocab_size"], (B, 5)).astype(np.float32)
-    dev = KVCacheDecoder(params, max_len=S, prefill_len=8, pos_len=S,
-                         batch=B, **CFG)
-    host = KVCacheDecoder(params, max_len=S, prefill_len=8, pos_len=S,
-                          batch=B, **CFG)
-    tok_d = np.argmax(dev.prefill(prompt), axis=-1)
-    tok_h = np.argmax(host.prefill(prompt), axis=-1)
-    np.testing.assert_array_equal(tok_d, tok_h)
+    dec = _paged(params, S, lanes=B)
+    toks = {}
+    for row in prompt:
+        sid, logits = dec.admit(row)
+        toks[sid] = int(np.argmax(logits))
     for _ in range(12):
-        tok_d = dev.greedy_step(tok_d)
-        tok_h = np.argmax(host.decode_step(tok_h), axis=-1)
-        np.testing.assert_array_equal(tok_d, tok_h)
-    # the compiled decode program really carries the trailing token head
-    assert dev._token_out
-    assert tok_d.dtype == np.int64
+        logits = dec.step(toks)
+        tok_d = dec._dec_exe.outputs[-1].asnumpy()
+        tok_h = {sid: int(np.argmax(logits[sid])) for sid in toks}
+        for sid in toks:
+            assert int(tok_d[dec._seq_lane[sid]]) == tok_h[sid]
+        toks = tok_h
+    # the compiled decode program really carries the trailing token head,
+    # under the name a driver finds it by
+    assert list(dec._dec_exe.output_dict)[-1].startswith("greedy_token")
+    assert tok_d.shape == (B,)
 
 
 def test_dispatch_host_gap_timer_ticks_only_when_enabled(tm):
@@ -346,9 +336,8 @@ def test_dispatch_host_gap_timer_ticks_only_when_enabled(tm):
     never touches the registry (the zero-overhead contract)."""
     S, B = 16, 1
     _, _, params = _trained_params(S)
-    prompt = np.ones((B, 3), np.float32)
-    dec = KVCacheDecoder(params, max_len=S, prefill_len=4, pos_len=S,
-                         batch=B, **CFG)
+    prompt = list(np.ones((B, 3), np.float32))
+    dec = _paged(params, S, lanes=B, prefill_len=4)
 
     tm.set_mode(None)
     env = os.environ.pop("MXNET_TELEMETRY", None)
@@ -360,14 +349,13 @@ def test_dispatch_host_gap_timer_ticks_only_when_enabled(tm):
             os.environ["MXNET_TELEMETRY"] = env
 
     tm.set_mode("counters")
-    dec.reset()
     dec.greedy(prompt, 4)
     agg = tm.timer("dispatch.host_gap")
-    # 3 greedy_steps; the first after prefill has no prior return to gap
-    # against (prefill resets the chain), so 2 steady-state intervals
+    # 3 steps; the first after the admission has no prior return to gap
+    # against (admit resets the chain), so 2 steady-state intervals
     assert agg.count == 2
     assert agg.total_ms > 0.0
-    site = tm.timer("dispatch.host_gap.serving.decode_step")
+    site = tm.timer("dispatch.host_gap.serving.paged_step")
     assert site.count == agg.count
 
 
@@ -377,11 +365,8 @@ STEP_PHASES = ("stage", "dispatch", "read", "commit")
 
 
 def _tiny_paged(S=16):
-    from mxnet_tpu.serving import PagedKVDecoder
-
     _, _, params = _trained_params(S)
-    return PagedKVDecoder(params, max_len=S, page_size=4, lanes=2,
-                          prefill_len=8, pos_len=S, **CFG)
+    return _paged(params, S, lanes=2)
 
 
 @pytest.fixture
@@ -496,35 +481,13 @@ def test_admit_and_step_logits_are_bitwise_the_same_with_telemetry_off(tm):
     assert off.dtype == on.dtype and np.array_equal(off, on)
 
 
-def test_ring_decoder_shares_the_step_phase_names(tm):
-    """KVCacheDecoder's decode step has the same four phases under the
-    same names (its stage and commit are the helpers both of its step
-    methods share)."""
-    tm.set_mode("trace")
-    S = 16
-    _, _, params = _trained_params(S)
-    dec = KVCacheDecoder(params, max_len=S, prefill_len=8, pos_len=S,
-                         batch=1, **CFG)
-    logits = dec.prefill(np.array([[3, 1, 4]], np.float32))
-    tm.clear_events()
-    dec.decode_step(np.argmax(logits, axis=-1))
-    names = [e[0] for e in sorted(tm.drain_events(), key=lambda e: e[1])]
-    assert [n for n in names if n.startswith("serving.")] == [
-        "serving.step.stage", "serving.decode_step",
-        "serving.step.dispatch", "serving.step.read",
-        "serving.step.commit"]
-
-
 # ---- the pool update of an admission: one sealed, donated program ---------
 def _paged_for_pool(lanes):
     """Pages of 4 under a prefill window that is no multiple of a page, so
     the program's last page-sized slice needs its pad."""
-    from mxnet_tpu.serving import PagedKVDecoder
-
     S = 16
     _, _, params = _trained_params(S)
-    return PagedKVDecoder(params, max_len=S, page_size=4, lanes=lanes,
-                          prefill_len=10, pos_len=S, **CFG)
+    return _paged(params, S, lanes=lanes, prefill_len=10)
 
 
 def _pool(dec):
@@ -620,6 +583,71 @@ def test_admit_scatter_is_one_sealed_donated_program(tm):
     assert moved(c0)["executor.retrace"] == 1
     assert moved(c0)["serving.admit_scatter_dispatches"] == 0
     assert not any(a.is_deleted() for a in held)
+
+
+def _sealed_program_case(dec, program):
+    """(the program, a call with its warmed inputs, one with a drifted
+    input, whether the good call donates the pool)."""
+    from mxnet_tpu.serving import kv_decode as kd
+
+    if program == "megastep":
+        prog = kd._megastep_for(dec, 2, kd._sampler_from("greedy"))
+    elif program == "chunk":
+        prog = dec._chunk_for(4)
+    else:
+        prog = dec._admit_scatter
+    sealed, rest = prog._dummy(dec)
+    if program == "admit_scatter":
+        drifted = tuple(a[:, :, :-1] for a in sealed)
+        return (prog, lambda: prog.run(dec, sealed, *rest),
+                lambda: prog.run(dec, drifted, *rest), True)
+    drifted = (sealed[0][..., :-1],) + tuple(sealed[1:])
+    return (prog, lambda: prog.run(dec, *sealed, *rest),
+            lambda: prog.run(dec, *drifted, *rest), False)
+
+
+@pytest.mark.parametrize("program", ["megastep", "chunk", "admit_scatter"])
+def test_sealed_program_compiles_once_and_refuses_a_drifted_signature(
+        tm, program):
+    """The contract the three programs share through ``_SealedProgram``: one
+    ``executor.compile`` at warm time, a cache hit and no compile for every
+    dispatch with the warmed signature, and for a drifted one the sealed
+    error under the program's own name and ``executor.retrace`` + 1 before
+    anything is enqueued (the pool is not donated, jit holds one program)."""
+    tm.set_mode("counters")
+    dec = _paged_for_pool(lanes=2).warmup()
+    c0 = tm.counters()
+    prog, good, bad, donates = _sealed_program_case(dec, program)
+
+    def moved(since):
+        now = tm.counters()
+        return [now.get(k, 0) - since.get(k, 0)
+                for k in ("executor.compile", "executor.cache_hit",
+                          "executor.retrace")]
+
+    # built here: its one compile; built by warmup(): none since (the hit
+    # is the prefill that ``_AdmitScatter._dummy`` stages its K/V with)
+    assert moved(c0) == ([0, 1, 0] if program == "admit_scatter"
+                         else [1, 0, 0])
+    c0 = tm.counters()
+    for _ in range(2):
+        good()
+    assert moved(c0) == [0, 2, 0]
+    held, c0 = _pool(dec), tm.counters()
+    name = {"megastep": r"decode megastep \(K=2\)",
+            "chunk": r"chunk program \(T=4\)",
+            "admit_scatter": "admit scatter"}[program]
+    with pytest.raises(MXNetError, match=name + ": input signature drifted "
+                       "from the warmed shapes .* sealed like the executable "
+                       "cache"):
+        bad()
+    assert moved(c0) == [0, 0, 1]
+    assert not any(a.is_deleted() for a in held)
+    assert all(a is b for a, b in zip(held, _pool(dec)))
+    assert prog._fn._cache_size() == 1
+    good()                                  # and the seal still holds
+    assert moved(c0) == [0, 1, 1]
+    assert all(a.is_deleted() for a in held) == donates
 
 
 def test_pool_readers_after_a_donated_admit_stay_token_identical():

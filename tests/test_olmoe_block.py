@@ -15,7 +15,7 @@ import mxnet_tpu as mx
 from mxnet_tpu import telemetry
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.models import transformer as tf
-from mxnet_tpu.serving import KVCacheDecoder, PagedKVDecoder
+from mxnet_tpu.serving import PagedKVDecoder
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -254,11 +254,6 @@ def test_unported_entry_points_refuse_the_architecture():
     for build in (tf.get_symbol, tf.get_symbol_mt, tf.get_chunk_symbol):
         with pytest.raises(MXNetError, match=refusal):
             build(**CFG)
-    for mode in ({}, {"per_stream_slots": True}):     # ring, per-lane rings
-        with pytest.raises(MXNetError, match=refusal):
-            tf.get_decode_symbol(max_len=64, **mode, **CFG)
-    with pytest.raises(MXNetError, match=refusal):
-        KVCacheDecoder(nd, vocab_size=600, arch="olmoe")
     with pytest.raises(MXNetError, match=refusal):
         PagedKVDecoder(nd, prefix_cache=True, **SERVE, **CFG)
     dec = _decoder(params, "float32")

@@ -224,8 +224,7 @@ def test_transformer_base_decode_step_contracts_on_the_chip(v5e):
     layers, lanes, slots, heads, dh = 2, 64, 64 * 1024, 8, 64
     sym = tf.get_decode_symbol(
         vocab_size=32000, num_layers=layers, num_heads=heads, model_dim=512,
-        ffn_dim=2048, max_len=slots, pos_len=1024, per_stream_slots=True,
-        global_slots=True)
+        ffn_dim=2048, max_len=slots, pos_len=1024)
     arg_shapes, _, _ = sym.infer_shape(
         data=(lanes, 1), pos_idx=(lanes, 1), slot_onehot=(lanes, slots),
         kv_mask=(lanes, slots),
@@ -266,8 +265,7 @@ def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
         sym = tf.get_prefill_symbol(prefill_len=max_len, **cfg)
         inputs = {"data": ((1, max_len), "float32")}
     else:
-        sym = tf.get_decode_symbol(max_len=slots, per_stream_slots=True,
-                                   global_slots=True, **cfg)
+        sym = tf.get_decode_symbol(max_len=slots, **cfg)
         inputs = {"data": ((lanes, 1), "float32"),
                   "pos_idx": ((lanes, 1), "float32"),
                   "slot_onehot": ((lanes, slots), "float32"),

@@ -52,8 +52,7 @@
 # 4 replica processes with injected dispatch faults, a mid-run replica
 # SIGKILL (supervised restart), and a mid-run fleet-wide hitless rollout —
 # gated on zero hung/lost requests, aggregate QPS above the single-replica
-# closed-loop baseline, recovery to healthy, and paged-KV multiplexed
-# decode parity.
+# closed-loop baseline and recovery to healthy.
 # Step 9 runs the elastic fault-tolerance chaos smoke
 # (tests/nightly/dist_elastic_chaos.py --orchestrate): an 8-process
 # Module.fit in sharded-update mode with periodic async checkpoints, one
@@ -200,7 +199,7 @@ payload = json.load(open(sys.argv[1]))
 sites = payload["sites"]
 kv = [s for s in sites if s["file"].endswith("serving/kv_decode.py")]
 gl701 = {s["function"] for s in kv if s["code"] == "GL701"}
-need = {"KVCacheDecoder.greedy", "PagedKVDecoder.greedy"}
+need = {"PagedKVDecoder.greedy"}
 assert need <= gl701, \
     "kv_decode GL701 anchors missing: %s (got %s)" % (need - gl701, gl701)
 # re-anchored for the megastep era: the megastep lax.scan is the default
@@ -464,8 +463,7 @@ echo "== [8/10] serving fleet: 4-replica router chaos smoke (docs/SERVING.md §F
 # and one mid-run fleet-wide hitless rollout. The gate asserts zero
 # hung/lost requests (every request reaches a terminal state),
 # completed>0, the rollout applied, the fleet back to healthy, aggregate
-# QPS above the single-replica closed-loop baseline, p99 in bound, and
-# paged-KV multiplexed decode token-identical to sequential decode.
+# QPS above the single-replica closed-loop baseline and p99 in bound.
 # The same run also drives the fleet OBSERVABILITY plane
 # (docs/OBSERVABILITY.md §Fleet): --check additionally gates the
 # fleet.request histogram p50/p99 against client-side percentiles, the

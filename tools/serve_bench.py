@@ -19,9 +19,9 @@ reports
     number.
 
 ``--model transformer-decode`` measures the KV-cache autoregressive path
-instead: per-token decode-step latency and tokens/s over batched streams
-(prefill bucket + single-token decode executable, zero retraces across
-positions). ``--megastep-k K`` (default 8) adds the decode-megastep
+instead: per-token decode-step latency and tokens/s over ``--rows`` lanes
+of a ``PagedKVDecoder`` (prefill bucket + single-token decode executable,
+zero retraces across positions). ``--megastep-k K`` (default 8) adds the decode-megastep
 comparison leg: the same streams decoded K tokens per dispatch through
 the ``lax.scan`` megastep program (docs/SERVING.md §Megasteps), gated
 under ``--check`` on token-identical parity with single-step greedy AND
@@ -284,43 +284,47 @@ def bench_engine(args):
 
 def bench_decode(args):
     from mxnet_tpu import telemetry
-    from mxnet_tpu.serving import KVCacheDecoder
+    from mxnet_tpu.serving import PagedKVDecoder
 
     cfg = dict(vocab_size=256, num_layers=2, num_heads=2, model_dim=64,
                ffn_dim=128)
     S = 64
-    # random weights straight from the decode graph's own shapes
-    from mxnet_tpu.models import transformer as _tf
-    from mxnet_tpu import context as _ctx
-
-    probe_sym = _tf.get_symbol(seq_len=S, **cfg)
-    probe = probe_sym.simple_bind(_ctx.current_context(), grad_req="null",
-                                  data=(1, S), softmax_label=(1, S))
-    rs = np.random.RandomState(0)
-    params = {k: (rs.randn(*a.shape) * 0.1).astype("float32")
-              for k, a in probe.arg_dict.items()
-              if k not in ("data", "softmax_label")}
+    params = _decode_params(cfg, S)
     B = args.rows
-    dec = KVCacheDecoder(params, max_len=S, prefill_len=16, pos_len=S,
-                         batch=B, cache_dir=args.cache_dir, **cfg)
+    dec = PagedKVDecoder(params, max_len=S, page_size=8, lanes=B,
+                         prefill_len=16, pos_len=S, cache_dir=args.cache_dir,
+                         **cfg)
     dec.warmup()
-    prompt = rs.randint(1, 256, (B, 8)).astype("float32")
+    rs = np.random.RandomState(1)
+    prompts = list(rs.randint(1, 256, (B, 8)).astype("float32"))
+
+    def admit_all():
+        """One lane per prompt: {seq id: its first token}."""
+        toks = {}
+        for p in prompts:
+            sid, logits = dec.admit(p)
+            toks[sid] = int(np.argmax(logits))
+        return toks
+
+    def retire_all(toks):
+        for sid in toks:
+            dec.retire(sid)
+
     K = max(0, int(args.megastep_k))
     if K > 1:
         # compile + seal the K-step megastep program BEFORE the counter
         # snapshot, exactly like warmup() does for the per-step executables
         # — the measured window must replay it with zero compiles
-        wl = dec.prefill(prompt)
-        wtok = np.argmax(wl, axis=-1)  # graphlint: waive GL703 -- warm leg, pre-snapshot
-        dec.decode_megastep(wtok, k=K)
-        dec.reset()
+        toks = admit_all()
+        dec.step_megastep(toks, k=K)
+        retire_all(toks)
+    def arg_max(logits):
+        return {sid: int(np.argmax(row)) for sid, row in logits.items()}
+
     c_warm = _counters()
-    logits = dec.prefill(prompt)
-    # first token from the prompt head: prefill already pulled the logits
-    tok = np.argmax(logits, axis=-1)  # graphlint: waive GL703 -- once per sequence
     # one burn-in step: the first post-warmup dispatch pays one-time jax
     # dispatch-path setup that would otherwise read as a fake p99 outlier
-    tok = dec.greedy_step(tok)
+    toks = arg_max(dec.step(admit_all()))
     steps = min(int(args.qps * args.duration), S - 8 - 2) or 1
     gap_t = telemetry.timer("dispatch.host_gap")
     lat = []
@@ -329,30 +333,17 @@ def bench_decode(args):
     for _ in range(steps):
         t1 = time.perf_counter()
         # graphlint: waive GL702 -- measuring the per-token loop IS the bench
-        tok = dec.greedy_step(tok)
+        toks = arg_max(dec.step(toks))
         lat.append((time.perf_counter() - t1) * 1000.0)
     elapsed = time.perf_counter() - t0
     gap_ms = gap_t.total_ms - gap0_ms
+    retire_all(toks)
     p50, p99 = _percentiles(lat)
-    # comparison leg: a short window in the pre-token-head shape (full
-    # logits pull + host argmax) so the report carries the measured
-    # host-gap delta the on-device greedy head buys
-    dec.reset()
-    logits = dec.prefill(prompt)
-    cmp_steps = max(4, min(steps, 16))
-    cgap0_ms = gap_t.total_ms
-    t0c = time.perf_counter()
-    for _ in range(cmp_steps):
-        tok = np.argmax(logits, axis=-1)   # graphlint: waive GL703 -- comparison leg
-        logits = dec.decode_step(tok)      # graphlint: waive GL702 -- comparison leg
-    cmp_elapsed = time.perf_counter() - t0c
-    cmp_gap_ms = gap_t.total_ms - cgap0_ms
     res = {
         "mode": "kv_decode",
         "model": "transformer-decode",
         "streams": B,
         "decode_steps": steps,
-        "decode_path": "greedy_step" if dec._token_out else "decode_step",
         "qps": round(B * steps / elapsed, 2),  # tokens/s across streams
         "p50_ms": round(p50, 3),
         "p99_ms": round(p99, 3),
@@ -361,37 +352,29 @@ def bench_decode(args):
         # (the dispatch.host_gap timer), amortized per generated token
         "host_gap_ms": round(gap_ms, 3),
         "host_gap_per_token": round(gap_ms / (B * steps), 6),
-        "host_argmax": {
-            "steps": cmp_steps,
-            "tokens_per_s": round(B * cmp_steps / cmp_elapsed, 2),
-            "host_gap_per_token": round(cmp_gap_ms / (B * cmp_steps), 6),
-        },
     }
     if K > 1:
         # megastep leg: parity first (K-chunked greedy must be
         # token-identical to single-step greedy), then a timed window of
         # K-token dispatches for the ≥2x host-gap-per-token gate
         n_par = 2 * K + 1
-        dec.reset()
-        seq = dec.greedy(prompt, n_par, k=1)
-        dec.reset()
-        mega = dec.greedy(prompt, n_par, k=K)
-        parity = bool(np.array_equal(seq, mega))
-        dec.reset()
-        logits = dec.prefill(prompt)
-        tok = np.argmax(logits, axis=-1)  # graphlint: waive GL703 -- once per sequence
+        seq = dec.greedy(prompts, n_par, k=1)
+        mega = dec.greedy(prompts, n_par, k=K)
+        parity = all(np.array_equal(a, b) for a, b in zip(seq, mega))
+        def last(chunk):
+            return {sid: int(ids[-1]) for sid, ids in chunk.items()}
+
         # burn-in megastep, then as many full-K chunks as positions allow
-        chunk = dec.decode_megastep(tok, k=K)
-        tok = chunk[:, -1]
-        m_chunks = max(1, (S - prompt.shape[1] - K) // K)
+        toks = last(dec.step_megastep(admit_all(), k=K))
+        m_chunks = max(1, (S - len(prompts[0]) - K) // K)
         mgap0_ms = gap_t.total_ms
         t0m = time.perf_counter()
         for _ in range(m_chunks):
             # graphlint: waive GL702 -- measuring the megastep loop IS the bench
-            chunk = dec.decode_megastep(tok, k=K)
-            tok = chunk[:, -1]
+            toks = last(dec.step_megastep(toks, k=K))
         m_elapsed = time.perf_counter() - t0m
         m_gap_ms = gap_t.total_ms - mgap0_ms
+        retire_all(toks)
         m_tokens = B * m_chunks * K
         m_gap_per_tok = round(m_gap_ms / m_tokens, 6)
         res["megastep"] = {
@@ -746,8 +729,7 @@ def bench_fleet(args):
     """The fleet smoke (docs/SERVING.md §Fleet): N replica PROCESSES
     behind the router under open-loop load with a seeded chaos plan —
     injected router-dispatch faults, one replica SIGKILLed mid-run (the
-    supervisor restarts it), and one fleet-wide hitless rollout — plus
-    the paged-KV multiplexed-decode parity check. Reports aggregate
+    supervisor restarts it), and one fleet-wide hitless rollout. Reports aggregate
     QPS/p99, redispatches, restarts, and the single-replica closed-loop
     baseline the aggregate must beat."""
     import shutil
@@ -1009,10 +991,6 @@ def bench_fleet(args):
     finally:
         fleet.close()
         shutil.rmtree(workdir, ignore_errors=True)
-
-    # ---- paged-KV multiplexed decode parity (the decode-side half of
-    # the fleet story: one decode batch, many concurrent sequences)
-    res["paged_kv"] = _paged_kv_parity()
     return res
 
 
@@ -1039,40 +1017,6 @@ def _fleet_trace_stats(merged):
             "traced_requests": len(by_tid),
             "cross_process_traces": cross,
             "dropped": other.get("dropped", 0)}
-
-
-def _paged_kv_parity(n_streams=3, n_tokens=6):
-    """>=2 concurrent sequences multiplexed through ONE decode batch must
-    be token-identical to sequential per-request decode."""
-    from mxnet_tpu.models import transformer as _tf
-    from mxnet_tpu import context as _ctx
-    from mxnet_tpu.serving import KVCacheDecoder, PagedKVDecoder
-
-    cfg = dict(vocab_size=64, num_layers=2, num_heads=2, model_dim=32,
-               ffn_dim=64)
-    S = 16
-    probe = _tf.get_symbol(seq_len=S, **cfg).simple_bind(
-        _ctx.current_context(), grad_req="null", data=(1, S),
-        softmax_label=(1, S))
-    rs = np.random.RandomState(0)
-    params = {k: (rs.randn(*a.shape) * 0.1).astype("float32")
-              for k, a in probe.arg_dict.items()
-              if k not in ("data", "softmax_label")}
-    prompts = [rs.randint(1, 64, (2 + i,)).astype("float32")
-               for i in range(n_streams)]
-    seq_out = []
-    for p in prompts:
-        dec = KVCacheDecoder(params, max_len=S, prefill_len=8, pos_len=S,
-                             batch=1, **cfg)
-        seq_out.append(dec.greedy(p[None], n_tokens)[0])
-    paged = PagedKVDecoder(params, max_len=S, page_size=4,
-                           lanes=n_streams, prefill_len=8, pos_len=S,
-                           **cfg)
-    pg_out = paged.greedy(prompts, n_tokens)
-    identical = all(np.array_equal(a, b)
-                    for a, b in zip(seq_out, pg_out))
-    return {"streams": n_streams, "tokens_per_stream": n_tokens,
-            "token_identical": bool(identical)}
 
 
 def _check_fleet(res):
@@ -1120,9 +1064,6 @@ def _check_fleet(res):
     if p99 is None or not math.isfinite(p99) or p99 > res["p99_bound_ms"]:
         _fail("p99 of completed requests %r ms outside bound %r ms"
               % (p99, res["p99_bound_ms"]))
-    if not res["paged_kv"]["token_identical"]:
-        _fail("paged-KV multiplexed decode diverged from sequential "
-              "per-request decode: %s" % res["paged_kv"])
     # ---- observability-plane gates (docs/OBSERVABILITY.md §Fleet)
     agree = res.get("fleet_hist_vs_client") or {}
     if not agree.get("agree"):
@@ -1246,7 +1187,7 @@ def _check(res, trace_families):
     if res.get("compiles_post_warmup"):
         _fail("post-warmup compiles: %d" % res["compiles_post_warmup"])
     need = {"serving.dispatch"} if res["mode"] == "engine" \
-        else {"serving.decode_step", "serving.prefill"}
+        else {"serving.decode_step", "serving.paged_admit"}
     ms = res.get("megastep")
     if ms is not None:
         need.add("serving.decode_megastep")
@@ -1318,8 +1259,7 @@ def main(argv=None):
                     help="fleet smoke (docs/SERVING.md §Fleet): N replica "
                          "processes behind the router under open-loop "
                          "load + chaos (kill-one-replica, injected "
-                         "dispatch faults, one mid-run fleet rollout) "
-                         "plus the paged-KV parity check")
+                         "dispatch faults, one mid-run fleet rollout)")
     ap.add_argument("--fleet-replicas", type=int, default=4)
     ap.add_argument("--chaos", action="store_true",
                     help="serving resilience smoke: open-loop load with "
