@@ -509,6 +509,37 @@ class Reference:
         return same, n
 
 
+def check_step_inputs(onehot, slots):
+    """What a decode step makes on the device of a write slot and a page
+    table a lane (``KVSlotOneHot``, ``KVPageMask``) against the host's
+    arrays: ``onehot``, whose last lane is idle, and the mask of lanes at
+    random positions in frames scattered over the pool; float32, exactly."""
+    from mxnet_tpu.ops.attention import _kv_page_mask, _kv_slot_onehot
+
+    (R, S), page = onehot.shape, SZ["page"]
+    write_slot = np.append(slots, -1).astype("float32")[:, None]
+    got = jax.jit(lambda w: _kv_slot_onehot({"num_slots": S}, w))(write_slot)
+    rs = np.random.RandomState(13)
+    max_pages = S // R // page
+    table = rs.permutation(S // page)[:R * max_pages].reshape(R, max_pages)
+    pos = rs.randint(0, max_pages * page, (R, 1))
+    pos[0], pos[1] = 0, page - 1        # one slot; a context that ends a page
+    phys = np.take_along_axis(table, pos // page, 1) * page + pos % page
+    phys[-1] = -1
+    mask = np.full((R, S), -1e9, "float32")
+    for r in range(R - 1):
+        seen = (table[r, :, None] * page + np.arange(page)).reshape(-1)
+        mask[r, seen[:pos[r, 0] + 1]] = 0.0
+    got_mask = jax.jit(lambda *a: _kv_page_mask(
+        {"page_size": page, "num_slots": S}, *a))(
+            table.astype("float32"), pos.astype("float32"),
+            phys.astype("float32"))
+    check(got.dtype == got_mask.dtype == jnp.float32
+          and bool(jnp.all(got == onehot)) and bool(jnp.all(got_mask == mask)),
+          "KVSlotOneHot and KVPageMask %s, pages of %d: the host's one-hots "
+          "and masks, element for element" % ((R, S), page))
+
+
 def check_pool_operators():
     """The decode step's write into the shared pool and its read of it
     (ops/attention.py), at the benchmark's lanes x heads x slots x dh. The
@@ -528,6 +559,7 @@ def check_pool_operators():
     for r in range(R - 1):
         mask[r, rs.choice(S, S // 64, replace=False)] = 0.0
         mask[r, slots[r]] = 0.0
+    check_step_inputs(onehot, slots)
     onehot, mask = jnp.asarray(onehot), jnp.asarray(mask)
 
     def blend(pool, rows, onehot):

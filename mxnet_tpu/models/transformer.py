@@ -299,6 +299,21 @@ def _pool_attend(i, q, k_new, v_new, onehot, mask, kv_outs):
                                name="layer%d_att" % i)
 
 
+def _pool_step_inputs(pos_idx, num_slots, page_size):
+    """``_pool_attend``'s one-hots and masks for a decode step, made ON THE
+    DEVICE, once in front of the layers, from what the host knows of a lane:
+    ``write_slot`` (B, 1), the pool slot its token lands in (negative: the
+    lane rides along, writes nothing and sees nothing), and ``page_table``
+    (B, pages a lane), the frames of its pages in order. With ``pos_idx``
+    that is the lane's whole context (``KVPageMask``)."""
+    write_slot = sym.Variable("write_slot")
+    return (sym.KVSlotOneHot(write_slot, num_slots=num_slots,
+                             name="slot_onehot"),
+            sym.KVPageMask(sym.Variable("page_table"), pos_idx, write_slot,
+                           page_size=page_size, num_slots=num_slots,
+                           name="kv_mask"))
+
+
 def _token_head(logits, kv_outs, token_name):
     """``[logits, k'_0, v'_0, ...]`` plus, where named, the on-device arg-max
     head: a greedy driver then pulls one id a row, not a row of logits."""
@@ -309,17 +324,16 @@ def _token_head(logits, kv_outs, token_name):
 
 
 def _pool_rows_symbol(vocab_size, num_layers, num_heads, model_dim, ffn_dim,
-                      pos_len, seq_len, onehot, mask, token_name):
+                      pos_len, seq_len, pool_inputs, token_name):
     """The Vaswani stack over N rows against the shared pool. The rows are
     the lanes of a decode step (``data`` (B, 1), ``seq_len`` 1) or the
     positions of one lane's chunk (``data`` (1, T), ``seq_len`` T); either
-    way ``pos_idx`` has ``data``'s shape and the inputs named ``onehot`` and
-    ``mask`` are (N, slots)."""
+    way ``pos_idx`` has ``data``'s shape and ``pool_inputs(pos_idx)`` gives
+    the rows' one-hots and masks, (N, slots) each."""
     dh = model_dim // num_heads
     data = sym.Variable("data")
     pos_idx = sym.Variable("pos_idx")
-    oh = sym.Variable(onehot)
-    msk = sym.Variable(mask)
+    oh, msk = pool_inputs(pos_idx)
     emb = sym.Embedding(data=data, input_dim=vocab_size,
                         output_dim=model_dim, name="embed")
     posrow = sym.Embedding(data=pos_idx, input_dim=pos_len,
@@ -340,7 +354,7 @@ def _pool_rows_symbol(vocab_size, num_layers, num_heads, model_dim, ffn_dim,
 
 def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
                       model_dim=512, ffn_dim=2048, max_len=64, pos_len=None,
-                      token_out=True, arch="vaswani", **kwargs):
+                      token_out=True, arch="vaswani", page_size=8, **kwargs):
     """Serving single-token decode graph (docs/SERVING.md): ONE token per
     lane through the ``get_symbol`` stack, every lane writing into and
     attending over ONE shared KV pool of ``max_len`` slots per layer (for
@@ -351,20 +365,27 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
       - ``data`` (B, 1): each lane's current token id.
       - ``pos_idx`` (B, 1): absolute positions (rows of the trained
         position table, so ``pos < pos_len``).
-      - ``slot_onehot`` (B, max_len): one-hot of the pool slot each lane's
-        token writes. The KV update is in-graph — no per-step host scatter,
-        no per-slot recompile. Lane one-hots are disjoint by construction
-        (the page allocator hands a frame to one writer at a time), and an
-        all-zero row writes nothing, which is how idle lanes ride along for
-        free.
-      - ``kv_mask`` (B, max_len): additive score mask per lane — 0 on the
-        slots holding that lane's context (INCLUDING the current slot), a
-        large negative elsewhere. Masked slots contribute exp(-1e9) = 0
-        exactly, so N lanes can read the SAME physical page: that is what
-        makes a shared prefix page a refcount instead of a copy (docs/
-        SERVING.md §Prefix cache). Attention over slots is order-agnostic
-        (positions live in the embeddings), so a lane's tokens may occupy
-        ANY slots — what the allocator's non-contiguous placement relies on.
+      - ``write_slot`` (B, 1): the pool slot each lane's token writes, as an
+        index; negative for a lane that rides along (it writes nothing and
+        attends nothing). The graph makes the (B, max_len) one-hots of it
+        once, in front of the layers (``KVSlotOneHot``), and the KV update
+        is in-graph — no per-step host scatter, no per-slot recompile. Lane
+        slots are disjoint by construction (the page allocator hands a frame
+        to one writer at a time), and an all-zero one-hot row writes
+        nothing, which is how idle lanes ride along for free.
+      - ``page_table`` (B, pages a lane): the frames of each lane's pages,
+        in order, ``page_size`` slots each (entries past the lane's last
+        page are never read). From it, ``pos_idx`` and ``write_slot`` the
+        graph makes the additive (B, max_len) score mask per lane
+        (``KVPageMask``) — 0 on the slots holding that lane's context
+        (INCLUDING the current slot), a large negative elsewhere — so what
+        the host ships a step is a few numbers a lane, not two arrays of
+        the pool's length. Masked slots contribute exp(-1e9) = 0 exactly,
+        so N lanes can read the SAME physical page: that is what makes a
+        shared prefix page a refcount instead of a copy (docs/SERVING.md
+        §Prefix cache). Attention over slots is order-agnostic (positions
+        live in the embeddings), so a lane's tokens may occupy ANY frames —
+        what the allocator's non-contiguous placement relies on.
       - ``kv_k_i`` / ``kv_v_i`` (H, max_len, dh) per layer: the pool. The
         updated buffers are program OUTPUTS; the caller swaps them back in
         as the next step's inputs (``PagedKVDecoder`` does).
@@ -388,8 +409,8 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     positions either way).
 
     This graph is also the megastep building block (serving/kv_decode.py
-    ``_DecodeMegastep``): it is pure in its (data, pos_idx, slot_onehot,
-    kv_mask, kv_*) inputs, so K decode steps compose as a ``lax.scan`` over
+    ``_DecodeMegastep``): it is pure in its (data, pos_idx, write_slot,
+    page_table, kv_*) inputs, so K decode steps compose as a ``lax.scan`` over
     ONE compiled body — the scan carries the KV outputs back into the KV
     inputs and feeds each step's sampled token to the next, keeping the
     whole K-token loop device-resident (docs/SERVING.md §megasteps).
@@ -397,17 +418,23 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     ``arch="olmoe"`` runs the sparse-expert block of ``_olmoe_layer`` over
     the same pool: positions reach the rotary operator as data
     (``pos_idx``), there is no position table, and the pool keeps the
-    weights' ``dtype`` while the one-hots and masks stay float32 inputs.
+    weights' ``dtype`` while ids, positions, slots and frames stay float32
+    inputs (and the one-hots and masks made of them float32).
+
+    ``page_size`` is the decoder's (``PagedKVDecoder``'s default here); it
+    must divide ``max_len``.
     """
     if arch == "olmoe":
         return _olmoe_decode_symbol(
             vocab_size=vocab_size, num_layers=num_layers,
-            num_heads=num_heads, model_dim=model_dim, ffn_dim=ffn_dim,
-            token_out=token_out, **kwargs)
+            num_slots=max_len, page_size=page_size, num_heads=num_heads,
+            model_dim=model_dim, ffn_dim=ffn_dim, token_out=token_out,
+            **kwargs)
     _refuse_arch(arch, "get_decode_symbol")
     return _pool_rows_symbol(
         vocab_size, num_layers, num_heads, model_dim, ffn_dim,
-        pos_len or max_len, seq_len=1, onehot="slot_onehot", mask="kv_mask",
+        pos_len or max_len, seq_len=1,
+        pool_inputs=lambda pos: _pool_step_inputs(pos, max_len, page_size),
         token_name="greedy_token" if token_out else None)
 
 
@@ -450,7 +477,9 @@ def get_chunk_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     _refuse_arch(kwargs.get("arch", "vaswani"), "get_chunk_symbol")
     return _pool_rows_symbol(
         vocab_size, num_layers, num_heads, model_dim, ffn_dim, pos_len,
-        seq_len=int(chunk_len), onehot="write_onehot", mask="att_mask",
+        seq_len=int(chunk_len),
+        pool_inputs=lambda pos: (sym.Variable("write_onehot"),
+                                 sym.Variable("att_mask")),
         token_name="chunk_token" if token_out else None)
 
 
@@ -537,14 +566,14 @@ def _olmoe_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
                      + [sym.Concat(*loads, dim=0, name="moe_load")])
 
 
-def _olmoe_decode_symbol(vocab_size, num_layers, token_out=True, **sizes):
+def _olmoe_decode_symbol(vocab_size, num_layers, num_slots, page_size,
+                         token_out=True, **sizes):
     block = _olmoe_sizes(**sizes)
     num_heads, dh, model_dim = (block[k] for k in ("num_heads", "head_dim",
                                                    "model_dim"))
     data = sym.Variable("data")
     pos_idx = sym.Variable("pos_idx")
-    oh = sym.Variable("slot_onehot")
-    msk = sym.Variable("kv_mask")
+    oh, msk = _pool_step_inputs(pos_idx, num_slots, page_size)
     kv_outs = []
 
     def attend(i, q, k_new, v_new):

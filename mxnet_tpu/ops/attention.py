@@ -14,7 +14,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..base import MXNetError
 from .registry import AttrSpec, register
+
+# the additive mask of a pool slot a row does not hold: exp(-1e9) is 0 exactly
+_NEG = np.float32(-1e9)
 
 # trace-time dispatch counters (observability for tests and the multichip
 # dryrun: proves the seq-parallel path actually engaged)
@@ -180,3 +184,60 @@ def _kv_pool_attention(attrs, query, pool_k, pool_v, mask):
     out = jnp.einsum("rhs,hsd->rhd", p, pool_v,
                      preferred_element_type=jnp.float32)
     return out.astype(query.dtype)
+
+
+@register(
+    "_contrib_KVSlotOneHot",
+    attrs={"num_slots": AttrSpec("int", required=True)},
+    input_names=("write_slot",),
+    aliases=("KVSlotOneHot",),
+)
+def _kv_slot_onehot(attrs, write_slot):
+    """``KVPoolWrite``'s one-hots from one slot index a row: ``write_slot``
+    (R, 1) gives (R, num_slots) float32, row r 1.0 at its slot and all zero
+    where the slot is negative (a lane that rides along and writes nothing).
+    The indices arrive as the float32 the executor binds its inputs in,
+    exact below 2^24 slots."""
+    slot = write_slot.reshape(-1, 1).astype(jnp.int32)
+    slots = jnp.arange(attrs["num_slots"], dtype=jnp.int32)
+    return (slots[None, :] == slot).astype(jnp.float32)
+
+
+@register(
+    "_contrib_KVPageMask",
+    attrs={"page_size": AttrSpec("int", required=True),
+           "num_slots": AttrSpec("int", required=True)},
+    input_names=("page_table", "pos_idx", "write_slot"),
+    aliases=("KVPageMask",),
+)
+def _kv_page_mask(attrs, page_table, pos_idx, write_slot):
+    """``KVPoolAttention``'s additive mask from each row's page table:
+    ``page_table`` (R, max_pages) names the frames of the row's pages in
+    order, ``pos_idx`` (R, 1) its position and ``write_slot`` (R, 1) whether
+    it writes there, so its context is ``n = pos + 1`` slots, or none where
+    the slot is negative. The result is (R, num_slots) float32: 0.0 on the
+    ``page_size`` slots of each of the first ``ceil(n / page_size)`` frames
+    but, in the last of them, only on its first ``n - (pages - 1) *
+    page_size``; ``-1e9`` everywhere else. Table entries past those pages
+    are never looked at, and a frame two rows share shows in both.
+
+    Every page is compared with every frame of the pool (R x max_pages x
+    frames integer compares, reduced over the pages): a few microseconds
+    against the reads of the pool that follow."""
+    page, slots = attrs["page_size"], attrs["num_slots"]
+    if page < 1 or slots % page:
+        raise MXNetError("KVPageMask: page_size %d must divide num_slots %d"
+                         % (page, slots))
+    rows, max_pages = page_table.shape
+    n = jnp.where(write_slot.reshape(rows) >= 0,
+                  pos_idx.reshape(rows).astype(jnp.int32) + 1, 0)
+    # context slots in each of the row's pages: a full page, the last one's
+    # remainder, none past it
+    held = jnp.clip(
+        n[:, None] - page * jnp.arange(max_pages, dtype=jnp.int32)[None, :],
+        0, page)
+    frames = jnp.arange(slots // page, dtype=jnp.int32)
+    at_frame = page_table.astype(jnp.int32)[:, :, None] == frames
+    held_in_frame = jnp.max(jnp.where(at_frame, held[:, :, None], 0), axis=1)
+    live = jnp.arange(page, dtype=jnp.int32) < held_in_frame[:, :, None]
+    return jnp.where(live, jnp.float32(0), _NEG).reshape(rows, slots)

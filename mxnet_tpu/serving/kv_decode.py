@@ -10,10 +10,12 @@ replaces costs O(T) work per token and a recompile per prompt length.
 One layout (``PagedKVDecoder``): the decode batch's rows are ``lanes``, one
 per sequence, and the lanes share ONE slot axis (``lanes * max_len`` slots
 per layer, KV ``(H, slots, dh)``) carved into refcounted page frames. A
-token's write happens IN-GRAPH (the ``slot_onehot`` rows of
-models/transformer.py ``get_decode_symbol``), and the updated buffers are
-program outputs the decoder swaps back in as the next step's inputs — a
-device-side pointer swap, no copy, no host round-trip. Attention over
+token's write happens IN-GRAPH, through one-hot rows the program makes of
+each lane's ``write_slot`` (models/transformer.py ``get_decode_symbol``), as
+it makes each lane's attention mask of its ``page_table``: a step hands the
+device a few numbers a lane. The updated buffers are program outputs the
+decoder swaps back in as the next step's inputs — a device-side pointer
+swap, no copy, no host round-trip. Attention over
 slots is order-agnostic (position information lives in the embeddings), so
 a sequence's tokens may sit in any frames. Physical sharing is then free —
 the prefix cache (serving/prefix_cache.py) parks whole prompt chunks at a
@@ -28,7 +30,7 @@ per-token loop above still pays one host round-trip per token.
 ``lax.scan`` over the same decode graph with on-device sampling (greedy
 argmax head, or temperature/top-k via the PRNG machinery) — so only (K, B)
 token ids cross the host per dispatch. Per-lane early exit reuses the
-all-zero ``slot_onehot`` idle-lane idiom: once a lane emits ``eos_id`` its
+negative ``write_slot`` idle-lane idiom: once a lane emits ``eos_id`` its
 remaining scan steps write NOTHING to its KV slots. K=1 keeps the
 single-step path byte-for-byte.
 """
@@ -42,11 +44,10 @@ import numpy as np
 
 from ..base import MXNetError
 from .. import telemetry as _tm
+from ..ops.attention import _NEG
 from .cache import PersistentExecutableCache
 
 __all__ = ["PagedKVDecoder", "PagedKVExhausted", "decode_megastep_k"]
-
-_NEG = np.float32(-1e9)
 
 
 def decode_megastep_k(default=1):
@@ -266,12 +267,13 @@ class _DecodeMegastep(_SealedProgram):
 
     A ``jax.jit``-ted ``lax.scan`` over the decode graph
     (``_GraphProgram.interpret`` is pure and jit-safe): the scan carries
-    (next token, done mask, attention mask, KV pool), each step blends
-    its KV write in-graph through the host-staged slot plan, samples the
-    next token ON DEVICE, and only the stacked (K, B) ids + activity
-    mask ever cross to the host. EOS'd / idle lanes carry an all-zero
-    ``slot_onehot`` row — their KV passes through bitwise-unchanged (the
-    idle-lane idiom ``step`` already relies on)."""
+    (next token, done mask, KV pool), each step hands the graph its lanes'
+    write slots and the page table of all K positions (constant over the
+    scan: the graph reads ``pos + 1`` slots of it), samples the next token
+    ON DEVICE, and only the stacked (K, B) ids + activity mask ever cross
+    to the host. EOS'd / idle lanes carry a negative ``write_slot`` — their
+    KV passes through bitwise-unchanged (the idle-lane idiom ``step``
+    already relies on)."""
 
     def __init__(self, dec, k, sampler):
         import jax
@@ -289,8 +291,9 @@ class _DecodeMegastep(_SealedProgram):
             _tf.get_decode_symbol(
                 vocab_size=dec.vocab_size, num_layers=L,
                 num_heads=dec.num_heads, model_dim=dec.model_dim,
-                ffn_dim=dec.ffn_dim, max_len=S, pos_len=pos_len),
-            ("data", "pos_idx", "slot_onehot", "kv_mask"), L)
+                ffn_dim=dec.ffn_dim, max_len=S, pos_len=pos_len,
+                page_size=dec.page_size),
+            ("data", "pos_idx", "write_slot", "page_table"), L)
         mode, temp, top_k = sampler.mode, sampler.temperature, sampler.top_k
         lane_ids = jnp.arange(B)
 
@@ -309,25 +312,22 @@ class _DecodeMegastep(_SealedProgram):
 
             return jax.vmap(draw)(pos_abs, lane_ids, lg)
 
-        def run(weights, kvs, tok0, pos, slots, base_mask, done0, key, eos):
+        def run(weights, kvs, tok0, pos, slots, table, done0, key, eos):
             def body(carry, xs):
-                tok, done, mask, kv = carry
+                tok, done, kv = carry
                 t, slot_col = xs
                 act = jnp.logical_not(done)
-                oh = jax.nn.one_hot(slot_col, S, dtype=jnp.float32) \
-                    * act.astype(jnp.float32)[:, None]
-                # the slot written this step becomes attendable now and
-                # for the rest of the scan (the carried mask accumulates)
-                mask = jnp.where(oh > 0, jnp.float32(0), mask)
                 # idle/done lanes clamp their position into the trained
-                # table; their onehot row is all-zero so the value is
+                # table; their write slot is negative so the value is
                 # never written anywhere
                 pos_t = jnp.clip(pos + t, 0, pos_len - 1)
                 outs = interpret(
                     weights, kv,
                     {"data": tok.astype(jnp.float32)[:, None],
                      "pos_idx": pos_t.astype(jnp.float32)[:, None],
-                     "slot_onehot": oh, "kv_mask": mask}, key)
+                     "write_slot": jnp.where(act, slot_col, -1).astype(
+                         jnp.float32)[:, None],
+                     "page_table": table}, key)
                 new_kv = tuple(outs[1 + j] for j in range(2 * L))
                 if mode == "greedy":
                     nxt = outs[-1].astype(jnp.int32)  # on-device argmax head
@@ -336,20 +336,19 @@ class _DecodeMegastep(_SealedProgram):
                 nxt = jnp.where(act, nxt, jnp.maximum(eos, 0))
                 done = jnp.logical_or(
                     done, jnp.logical_and(act, (eos >= 0) & (nxt == eos)))
-                return (nxt, done, mask, new_kv), (nxt, act)
+                return (nxt, done, new_kv), (nxt, act)
 
             xs = (jnp.arange(self.k), jnp.transpose(slots))
-            (_tok, done_f, _mask, kv_f), (toks, acts) = jax.lax.scan(
-                body, (tok0, done0, base_mask, kvs), xs)
+            (_tok, done_f, kv_f), (toks, acts) = jax.lax.scan(
+                body, (tok0, done0, kvs), xs)
             return toks, acts, kv_f, done_f
 
         self._jit(run, "mx_megastep%d" % self.k)
 
     def _dummy(self, dec):
-        B, S = dec.lanes, dec.total_slots
+        B = dec.lanes
         return (np.zeros((B,), np.int32), np.zeros((B,), np.int32),
-                np.zeros((B, self.k), np.int32),
-                np.full((B, S), _NEG, np.float32),
+                np.zeros((B, self.k), np.int32), dec._page_table(),
                 # every lane idle: compiles, writes nothing
                 np.ones((B,), bool)), (np.int32(-1),)
 
@@ -357,11 +356,11 @@ class _DecodeMegastep(_SealedProgram):
         return self._fn(*self._weights_and_pool(dec), *sealed,
                         _sampling_key(dec), eos)
 
-    def run(self, dec, tok0, pos, slots, base_mask, done0, eos):
+    def run(self, dec, tok0, pos, slots, table, done0, eos):
         """One megastep dispatch. Returns device-resident
         ``(toks (K,B) i32, acts (K,B) bool, new_kvs, done)`` — the caller
         pulls the ids (the only host transfer) and pointer-swaps the KV."""
-        return self._run(dec, (tok0, pos, slots, base_mask, done0), eos)
+        return self._run(dec, (tok0, pos, slots, table, done0), eos)
 
 
 def _megastep_for(dec, k, sampler):
@@ -624,14 +623,14 @@ class PagedKVDecoder:
     ``PagedKVExhausted`` instead of an OOM.
 
     Per-lane math does not depend on what the other lanes hold (each lane
-    carries its own slot_onehot/kv_mask row), so multiplexed decode is
+    carries its own write slot and page-table row), so multiplexed decode is
     token-identical to sequential per-request decode — the acceptance
     test pins exactly that.
 
     KV storage is ONE slot pool (``get_decode_symbol``): per layer the
-    buffers are (H, lanes·max_len, dh) and every lane's onehot/mask row
-    indexes the shared axis, so a page frame is just a slot range ANY lane
-    can reference. That is the substrate for cross-request prefix reuse
+    buffers are (H, lanes·max_len, dh) and every lane's write slot and page
+    table index the shared axis, so a page frame is just a slot range ANY
+    lane can reference. That is the substrate for cross-request prefix reuse
     (serving/prefix_cache.py): with ``prefix_cache=True`` (or
     ``MXNET_SERVE_PREFIX_CACHE=1``) admit hashes the prompt in
     ``prefix_chunk``-token chunks, adopts the cached pages of the longest
@@ -649,7 +648,7 @@ class PagedKVDecoder:
     expert's width) through the same admission, pool and single-step decode.
     It has no position table, so a lane is bounded by ``max_len`` alone;
     ``dtype`` is then the type of the weights AND of the pool, while token
-    ids, positions, one-hots and masks stay float32 (a token id of 50,303
+    ids, positions, slots and frames stay float32 (a token id of 50,303
     does not survive bfloat16). The prefix cache, the chunk and verify
     programs and the megastep are not built for it yet and raise.
     """
@@ -686,6 +685,11 @@ class PagedKVDecoder:
                               budget=page_budget)
         self.page_size = self.pool.page_size
         self.total_slots = self.lanes * self.max_len
+        if self.total_slots > 1 << 24:
+            # a step's slots and frames reach the program as float32
+            raise MXNetError("paged_kv: %d lanes x %d slots is past the 2^24 "
+                             "slots a float32 index counts exactly"
+                             % (self.lanes, self.max_len))
         if prefix_cache is None:
             prefix_cache = os.environ.get(
                 "MXNET_SERVE_PREFIX_CACHE", "").strip().lower() \
@@ -731,7 +735,8 @@ class PagedKVDecoder:
             arg_params, {}, model_key=key + "-prefill",
             program_label="mx_prefill", **binding)
         self._dec_cache = PersistentExecutableCache(
-            _tf.get_decode_symbol(max_len=self.total_slots, **cfg),
+            _tf.get_decode_symbol(max_len=self.total_slots,
+                                  page_size=self.page_size, **cfg),
             arg_params, {}, model_key=key + "-decode",
             program_label="mx_decode", **binding)
         self._dec_exe = None
@@ -758,8 +763,8 @@ class PagedKVDecoder:
     # ------------------------------------------------------------ lifecycle
     def _decode_shapes(self):
         B, S, H, dh = self.lanes, self.total_slots, self.num_heads, self.dh
-        shapes = {"data": (B, 1), "pos_idx": (B, 1),
-                  "slot_onehot": (B, S), "kv_mask": (B, S)}
+        shapes = {"data": (B, 1), "pos_idx": (B, 1), "write_slot": (B, 1),
+                  "page_table": (B, self.pool.frames_per_lane)}
         for i in range(self.num_layers):
             shapes["kv_k_%d" % i] = (H, S, dh)
             shapes["kv_v_%d" % i] = (H, S, dh)
@@ -856,6 +861,15 @@ class PagedKVDecoder:
             lane.frames.append(self._acquire_frame())
         frame = self._cow_page(lane, page)
         return frame * self.page_size + off
+
+    def _page_table(self, lanes=()):
+        """The decode graph's ``page_table`` (lanes, pages a lane) float32:
+        row ``idx`` holds the frames of ``lane``'s pages in order for each
+        ``(idx, lane)`` given, zeros past them and in every other row."""
+        table = np.zeros((self.lanes, self.pool.frames_per_lane), np.float32)
+        for idx, lane in lanes:
+            table[idx, :len(lane.frames)] = lane.frames
+        return table
 
     def _lane_slots(self, lane: _Lane, upto=None):
         """Physical slots of positions 0..n-1 (n = ``lane.pos`` unless
@@ -1180,20 +1194,26 @@ class PagedKVDecoder:
         """One multiplexed decode dispatch: ``tokens`` maps seq_id -> next
         token id for any subset of active sequences; every stepped
         sequence advances at ITS OWN position in the one batch. Returns
-        {seq_id: (vocab,) logits}. Lanes not stepped (or unoccupied) ride
-        along with an all-zero write-onehot — their KV is untouched and
-        their logits discarded."""
+        {seq_id: (vocab,) logits}. What the host hands the program is, a
+        lane, its token, its position, the slot the token lands in and the
+        frames of its pages (``data``, ``pos_idx``, ``write_slot``,
+        ``page_table``: ``lanes * (3 + max_len / page_size)`` float32 in
+        all); the one-hots and masks over the pool's slots are made of them
+        on the device. Lanes not stepped (or unoccupied) ride along with a
+        negative write slot — their KV is untouched and their logits
+        discarded."""
+        import jax
+
         self.warmup()
         if not tokens:
             return {}
         with _tm.span("serving.paged_step", rows=len(tokens), paged=True):
-            B, S = self.lanes, self.total_slots
+            B = self.lanes
             exe = self._dec_exe
             with _tm.span("serving.step.stage"):
                 data = np.zeros((B, 1), np.float32)
                 pos_idx = np.zeros((B, 1), np.float32)
-                oh = np.zeros((B, S), np.float32)
-                mask = np.full((B, S), _NEG, np.float32)
+                write_slot = np.full((B, 1), -1, np.float32)
                 stepped = []
                 for seq_id, tok in tokens.items():
                     idx = self._seq_lane.get(seq_id)
@@ -1207,17 +1227,21 @@ class PagedKVDecoder:
                             "paged_kv: seq %d at position %d exceeds the "
                             "trained position table (%d rows)"
                             % (seq_id, lane.pos, self.pos_len))
-                    phys = self._phys_slot(lane, lane.pos)
+                    # resolves the frame first: a new page, or a private
+                    # copy of a shared one
+                    write_slot[idx, 0] = self._phys_slot(lane, lane.pos)
                     data[idx, 0] = float(np.asarray(tok).reshape(()))
                     pos_idx[idx, 0] = lane.pos
-                    oh[idx, phys] = 1.0
-                    mask[idx, self._lane_slots(lane)] = 0.0
-                    mask[idx, phys] = 0.0
-                    stepped.append((seq_id, idx, lane, phys))
-                exe.arg_dict["data"][:] = data
-                exe.arg_dict["pos_idx"][:] = pos_idx
-                exe.arg_dict["slot_onehot"][:] = oh
-                exe.arg_dict["kv_mask"][:] = mask
+                    stepped.append((seq_id, idx, lane))
+                staged = {"data": data, "pos_idx": pos_idx,
+                          "write_slot": write_slot,
+                          "page_table": self._page_table(
+                              (idx, lane) for _, idx, lane in stepped)}
+                # ONE batched transfer: a host-to-device copy of a few KB
+                # costs the host 0.2 ms whatever its size
+                for name, arr in zip(staged,
+                                     jax.device_put(list(staged.values()))):
+                    exe.arg_dict[name]._set_jax(arr)
             _gap_mark(self, "serving.paged_step")
             with _tm.span("serving.decode_step", rows=len(stepped),
                           paged=True):
@@ -1230,12 +1254,14 @@ class PagedKVDecoder:
             out = {}
             with _tm.span("serving.step.commit"):
                 _swap_kv(exe, self.num_layers)
-                for seq_id, idx, lane, phys in stepped:
+                for seq_id, idx, lane in stepped:
                     lane.pos += 1
                     out[seq_id] = logits[idx]
             if _tm.enabled():
                 _tm.counter("serving.decode_tokens").inc(len(stepped))
                 _tm.counter("serving.paged_steps").inc()
+                _tm.counter("serving.step_input_bytes").inc(
+                    sum(a.nbytes for a in staged.values()))
                 if self._decode_xla_bytes:
                     _tm.counter("serving.decode_xla_bytes").inc(
                         self._decode_xla_bytes)
@@ -1254,7 +1280,7 @@ class PagedKVDecoder:
         backpressure is admission backpressure: already-acquired frames
         stay with their lanes (a retry after ``retire`` reuses them) and
         the KV state is untouched. Unstepped lanes ride along idle
-        (all-zero onehot rows); with ``eos_id`` a lane that emits eos
+        (a negative write slot); with ``eos_id`` a lane that emits eos
         mid-megastep writes nothing for its remaining steps and only its
         pre-eos slots become valid. Returns {seq_id: (K,) int64 ids}."""
         self._refuse_arch("step_megastep")
@@ -1264,7 +1290,7 @@ class PagedKVDecoder:
             raise MXNetError("step_megastep: K must be >= 1, got %d" % k)
         if not tokens:
             return {}
-        B, S = self.lanes, self.total_slots
+        B = self.lanes
         stepped = []
         for seq_id, tok in tokens.items():
             idx = self._seq_lane.get(seq_id)
@@ -1286,13 +1312,13 @@ class PagedKVDecoder:
         tok0 = np.zeros((B,), np.int32)
         posv = np.zeros((B,), np.int32)
         slots = np.zeros((B, k), np.int32)
-        base_mask = np.full((B, S), _NEG, np.float32)
+        # frames of all K positions: a step of the scan reads pos + 1 slots
+        table = self._page_table((idx, lane) for _, idx, lane, _ in stepped)
         done0 = np.ones((B,), bool)  # idle unless stepped
         for seq_id, idx, lane, tok in stepped:
             tok0[idx] = int(np.asarray(tok).reshape(()))
             posv[idx] = lane.pos
             slots[idx] = phys[seq_id]
-            base_mask[idx, self._lane_slots(lane)] = 0.0
             done0[idx] = False
         eos = np.int32(-1 if eos_id is None else int(eos_id))
         _gap_mark(self, "serving.paged_megastep")
@@ -1300,7 +1326,7 @@ class PagedKVDecoder:
                       paged=True, k=k):
             with _tm.span("serving.step.dispatch"):
                 toks, acts, new_kvs, _done = ms.run(
-                    self, tok0, posv, slots, base_mask, done0, eos)
+                    self, tok0, posv, slots, table, done0, eos)
             with _tm.span("serving.step.read"):
                 ids = np.asarray(toks)       # (K, B): the only host pull
                 acts_h = np.asarray(acts)

@@ -785,8 +785,8 @@ def test_graph_gl703_fires_on_tokenless_decode_symbol_only():
     cfg = dict(vocab_size=64, num_layers=2, num_heads=2, model_dim=32,
                ffn_dim=64)
     B, S, H, dh = 2, 8, 2, 16
-    sh = {"data": (B, 1), "pos_idx": (B, 1), "slot_onehot": (B, S),
-          "kv_mask": (B, S)}
+    sh = {"data": (B, 1), "pos_idx": (B, 1), "write_slot": (B, 1),
+          "page_table": (B, 1)}
     for i in range(cfg["num_layers"]):
         sh["kv_k_%d" % i] = (H, S, dh)
         sh["kv_v_%d" % i] = (H, S, dh)

@@ -152,7 +152,7 @@ def test_serving_symbols_share_training_weight_names():
                                          **CFG).list_arguments())
     # every serving weight exists in the training graph (data/kv/mask
     # inputs are serving-only by construction)
-    serving_only = {"data", "pos_idx", "slot_onehot", "kv_mask"} | \
+    serving_only = {"data", "pos_idx", "write_slot", "page_table"} | \
         {"kv_%s_%d" % (t, i) for t in ("k", "v")
          for i in range(CFG["num_layers"])}
     assert (pf_args - {"data"}) <= train_args
@@ -447,6 +447,98 @@ def test_decode_xla_bytes_rises_by_the_program_s_count_per_step(
     _events, seen, _sid = traced_request
     assert seen[0] == 0                       # an admission adds none
     assert seen[1] > 0 and seen[2] == 2 * seen[1]
+
+
+def test_a_step_hands_the_program_a_few_numbers_a_lane(tm):
+    """``serving.step_input_bytes`` over ``serving.paged_steps``: a token, a
+    position, a write slot and a page table a lane, whatever the lanes hold
+    and however many of them step; no input of the decode program has the
+    pool's length."""
+    tm.set_mode("trace")
+    S, lanes, page = 16, 3, 4
+    _, _, params = _trained_params(S)
+    dec = _paged(params, S, lanes=lanes).warmup()
+    assert tm.counters().get("serving.step_input_bytes", 0) == 0
+    a, la = dec.admit(np.array([3, 1, 4, 1, 5], np.float32))
+    b, lb = dec.admit(np.array([2, 7, 1], np.float32))
+    assert tm.counters().get("serving.step_input_bytes", 0) == 0
+    nxt = {a: int(np.argmax(la)), b: int(np.argmax(lb))}
+    for seqs in ((a, b), (a,), (b, a), (a, b)):
+        out = dec.step({s: nxt[s] for s in seqs})
+        nxt.update({s: int(np.argmax(out[s])) for s in seqs})
+    c = tm.counters()
+    assert c["serving.paged_steps"] == 4
+    assert c["serving.step_input_bytes"] == 4 * lanes * (3 + S // page) * 4
+    inputs = {n: a.shape for n, a in dec._dec_exe.arg_dict.items()
+              if n in dec._decode_shapes() and not n.startswith("kv_")}
+    assert inputs == {"data": (lanes, 1), "pos_idx": (lanes, 1),
+                      "write_slot": (lanes, 1),
+                      "page_table": (lanes, S // page)}
+
+
+def _cold_probs(exe, tokens, S):
+    """The training graph's next-token distribution after ``tokens``."""
+    pad = np.zeros((1, S), np.float32)
+    pad[0, :len(tokens)] = tokens
+    exe.arg_dict["data"][:] = pad
+    exe.forward(is_train=False)
+    return exe.outputs[0].asnumpy().reshape(S, -1)[len(tokens) - 1]
+
+
+def _softmax(logits):
+    p = np.exp(logits - logits.max())
+    return p / p.sum()
+
+
+@pytest.mark.parametrize("case", ["fork", "copy_on_write", "rollback"])
+def test_a_step_reads_a_cold_re_forward_after(tm, case):
+    """The mask is made on the device from the lane's frame table, so what a
+    lane sees after its table changed under it — a fork's shared frames, the
+    private copy a write into one makes, the pages and the stale tail a
+    rollback drops — is what a re-forward of its tokens from nothing sees."""
+    tm.set_mode("counters")
+    S = 16
+    _, exe, params = _trained_params(S)
+    dec = _paged(params, S, lanes=3).warmup()
+    prompt = [3, 1, 4, 1, 5, 9]          # position 6: mid-page, pages of 4
+    sid, logits = dec.admit(np.asarray(prompt, np.float32))
+    t0 = int(np.argmax(logits))
+
+    def check(got, tokens):
+        np.testing.assert_allclose(_softmax(got), _cold_probs(exe, tokens, S),
+                                   rtol=1e-4, atol=1e-5)
+
+    if case == "fork":
+        twin = dec.fork(sid)
+        t1 = (t0 + 1) % CFG["vocab_size"]
+        out = dec.step({sid: t0, twin: t1})     # both write the shared page
+        check(out[sid], prompt + [t0])
+        check(out[twin], prompt + [t1])
+        nxt = dec.step({twin: 7})[twin]         # and the other rides along
+        check(nxt, prompt + [t1, 7])
+    elif case == "copy_on_write":
+        twin = dec.fork(sid)
+        shared = list(dec._lanes[dec._seq_lane[sid]].frames)
+        t1 = (t0 + 1) % CFG["vocab_size"]
+        check(dec.step({twin: t1})[twin], prompt + [t1])
+        assert tm.counters()["serving.cow_copies"] == 1
+        mine = dec._lanes[dec._seq_lane[twin]].frames
+        assert mine[0] == shared[0] and mine[1] != shared[1]
+        # the first writer's page was copied, not written: the other lane
+        # still reads its own tokens there, then writes it in place
+        check(dec.step({sid: t0})[sid], prompt + [t0])
+        assert tm.counters()["serving.cow_copies"] == 1
+        assert dec._lanes[dec._seq_lane[sid]].frames == shared
+    else:
+        toks = [t0]
+        for _ in range(4):                      # positions 6..10: three pages
+            toks.append(int(np.argmax(dec.step({sid: toks[-1]})[sid])))
+        assert len(dec._lanes[dec._seq_lane[sid]].frames) == 3
+        dec.rollback(sid, 7)                    # page 2 goes, page 1 keeps 3
+        assert len(dec._lanes[dec._seq_lane[sid]].frames) == 2
+        other = (toks[1] + 1) % CFG["vocab_size"]
+        check(dec.step({sid: other})[sid], prompt + [t0, other])
+        check(dec.step({sid: 2})[sid], prompt + [t0, other, 2])
 
 
 def test_executor_cost_analysis_counts_the_bound_program(tm):

@@ -5,7 +5,13 @@ out here as the decode, the OLMoE and the chunk builders had them.
 
 The write must agree bit for bit (it multiplies by exactly 0 and 1); the
 read sums the same float32 products in another order.
+
+And the two operators that make a decode step's one-hots and masks on the
+device (``KVSlotOneHot``, ``KVPageMask``) against the arrays
+``PagedKVDecoder.step`` used to build on the host, element for element.
 """
+import functools
+
 import numpy as np
 import pytest
 
@@ -13,7 +19,9 @@ import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx
-from mxnet_tpu.ops.attention import _kv_pool_attention, _kv_pool_write
+from mxnet_tpu.base import MXNetError
+from mxnet_tpu.ops.attention import (_kv_page_mask, _kv_pool_attention,
+                                     _kv_pool_write, _kv_slot_onehot)
 
 H, S, DH = 4, 96, 16
 SCALE = 1.0 / np.sqrt(DH)
@@ -191,3 +199,131 @@ def test_one_step_through_the_executor_is_the_two_operators(dtype):
     want = _kv_pool_attention({"scale": -1.0}, q, want_k, want_v, mask)
     np.testing.assert_allclose(np.asarray(got_ctx, "f"), np.asarray(want, "f"),
                                rtol=1e-6, atol=1e-6)
+
+
+# ------------------------------------------------ a step's inputs, on device
+# lanes x slots a lane, page size: a small pool, olmoe-1b-7b.score's and
+# transformer-base.generate's
+GEOMETRIES = {"small": (7, 32, 8), "olmoe": (8, 2048, 16),
+              "generate": (64, 1024, 16)}
+# lane -> (pages held, position of the token it writes; None: it rides along)
+LANES = {
+    "one_slot": lambda P: (1, 0),               # its whole context is one slot
+    "mid_page": lambda P: (3, 2 * P + 3),
+    "page_boundary": lambda P: (2, 2 * P - 1),  # the context ends a page
+    "new_page": lambda P: (3, 2 * P),           # the token opens a page
+    "idle": lambda P: (2, None),
+    "shared_a": lambda P: (2, P + 1),           # first frame: shared_b's too
+    "shared_b": lambda P: (3, 2 * P + P // 2),
+}
+
+
+def _host_row(frames, pos, page, slots):
+    """What the parent's ``PagedKVDecoder.step`` wrote into a lane's rows of
+    ``slot_onehot`` and ``kv_mask``: ``_lane_slots`` plus the current slot."""
+    onehot = np.zeros((slots,), np.float32)
+    mask = np.full((slots,), np.float32(-1e9), np.float32)
+    if pos is None:
+        return -1, onehot, mask
+    phys = frames[pos // page] * page + pos % page
+    held = np.asarray(frames[:(pos + page - 1) // page], np.int64)
+    seen = (held[:, None] * page + np.arange(page)[None, :]).reshape(-1)[:pos]
+    onehot[phys] = 1.0
+    mask[seen] = 0.0
+    mask[phys] = 0.0
+    return phys, onehot, mask
+
+
+@functools.lru_cache(maxsize=None)
+def _step_inputs(geometry):
+    """One step of a seeded pool: every lane of ``LANES`` at frames drawn
+    without order from the whole pool (never frame 0, which the table's
+    padding names), the rest of the lanes mid-context; then the operators'
+    arrays and the host's."""
+    lanes, per_lane, page = GEOMETRIES[geometry]
+    slots, max_pages = lanes * per_lane, per_lane // page
+    rs = np.random.RandomState(len(geometry))
+    free = list(1 + rs.permutation(slots // page - 1))
+    kinds = list(LANES) + ["mid_page"] * (lanes - len(LANES))
+    table = np.zeros((lanes, max_pages), np.float32)
+    pos_idx = np.zeros((lanes, 1), np.float32)
+    write_slot = np.full((lanes, 1), -1, np.float32)
+    want_oh, want_mask, shared = [], [], None
+    for r, kind in enumerate(kinds):
+        n_pages, pos = LANES[kind](page)
+        frames = [int(free.pop()) for _ in range(n_pages)]
+        if kind == "shared_a":
+            shared = frames[0]
+        elif kind == "shared_b":
+            frames[0] = shared
+        table[r, :n_pages] = frames
+        phys, onehot, mask = _host_row(frames, pos, page, slots)
+        write_slot[r, 0] = phys
+        pos_idx[r, 0] = 0 if pos is None else pos
+        want_oh.append(onehot)
+        want_mask.append(mask)
+    got_oh = _kv_slot_onehot({"num_slots": slots}, jnp.asarray(write_slot))
+    got_mask = _kv_page_mask({"page_size": page, "num_slots": slots},
+                             jnp.asarray(table), jnp.asarray(pos_idx),
+                             jnp.asarray(write_slot))
+    return (kinds, np.asarray(got_oh), np.asarray(got_mask),
+            np.stack(want_oh), np.stack(want_mask), table)
+
+
+@pytest.mark.parametrize("lane", list(LANES))
+@pytest.mark.parametrize("geometry", list(GEOMETRIES))
+def test_a_steps_onehot_and_mask_are_the_hosts_element_for_element(
+        geometry, lane):
+    kinds, got_oh, got_mask, want_oh, want_mask, _ = _step_inputs(geometry)
+    lanes, per_lane, page = GEOMETRIES[geometry]
+    assert got_oh.shape == got_mask.shape == (lanes, lanes * per_lane)
+    assert got_oh.dtype == got_mask.dtype == np.float32
+    r = kinds.index(lane)
+    np.testing.assert_array_equal(_bits(got_oh[r]), _bits(want_oh[r]))
+    np.testing.assert_array_equal(_bits(got_mask[r]), _bits(want_mask[r]))
+    n_pages, pos = LANES[lane](page)
+    seen = 0 if pos is None else pos + 1
+    assert (got_mask[r] == 0).sum() == seen
+    assert got_oh[r].sum() == (pos is not None)
+    assert set(np.unique(got_mask[r])) <= {np.float32(0), np.float32(-1e9)}
+
+
+@pytest.mark.parametrize("geometry", list(GEOMETRIES))
+def test_every_lane_of_a_step_and_the_frame_two_lanes_share(geometry):
+    kinds, got_oh, got_mask, want_oh, want_mask, table = \
+        _step_inputs(geometry)
+    np.testing.assert_array_equal(_bits(got_oh), _bits(want_oh))
+    np.testing.assert_array_equal(_bits(got_mask), _bits(want_mask))
+    page = GEOMETRIES[geometry][2]
+    a, b = kinds.index("shared_a"), kinds.index("shared_b")
+    first = int(table[a, 0]) * page
+    assert table[a, 0] == table[b, 0]
+    assert (got_mask[[a, b], first:first + page] == 0).all()
+    # nobody else sees that frame, and nobody sees the frame the padding names
+    others = [r for r in range(len(kinds)) if r not in (a, b)]
+    assert (got_mask[others, first:first + page] == -1e9).all()
+    assert (got_mask[:, :page] == -1e9).all()
+    # the written slots are disjoint: KVPoolWrite's matmul is a scatter
+    assert got_oh.sum(0).max() == 1
+
+
+def test_page_mask_refuses_a_page_that_does_not_tile_the_pool():
+    args = (jnp.zeros((2, 3)), jnp.zeros((2, 1)), jnp.zeros((2, 1)))
+    with pytest.raises(MXNetError, match="must divide"):
+        _kv_page_mask({"page_size": 5, "num_slots": 24}, *args)
+    assert _kv_page_mask({"page_size": 4, "num_slots": 24},
+                         *args).shape == (2, 24)
+
+
+def test_symbols_infer_a_steps_inputs_from_the_row_count():
+    v = mx.sym.Variable
+    oh = mx.sym.KVSlotOneHot(v("write_slot"), num_slots=S, name="oh")
+    assert oh.infer_shape(write_slot=(5, 1))[1] == [(5, S)]
+    msk = mx.sym.KVPageMask(v("page_table"), v("pos_idx"), v("write_slot"),
+                            page_size=8, num_slots=S, name="msk")
+    assert msk.list_arguments() == ["page_table", "pos_idx", "write_slot"]
+    assert msk.infer_shape(page_table=(5, 4), pos_idx=(5, 1),
+                           write_slot=(5, 1))[1] == [(5, S)]
+    _, types, _ = msk.infer_type(page_table="float32", pos_idx="float32",
+                                 write_slot="float32")
+    assert [np.dtype(t) for t in types] == [np.dtype("float32")]

@@ -654,8 +654,10 @@ EXEMPT = {
     "WarpCTC": "tests/test_ctc.py",
     "_contrib_MultiBoxDetection": "tests/test_vision.py",
     "_contrib_MultiBoxPrior": "tests/test_vision.py",
+    "_contrib_KVPageMask": "tests/test_kv_pool_ops.py",
     "_contrib_KVPoolAttention": "tests/test_kv_pool_ops.py",
     "_contrib_KVPoolWrite": "tests/test_kv_pool_ops.py",
+    "_contrib_KVSlotOneHot": "tests/test_kv_pool_ops.py",
     "_contrib_MoEFeedForward": "tests/test_olmoe_block.py",
     "_contrib_MultiBoxTarget": "tests/test_vision.py",
     "_contrib_MultiHeadAttention": "tests/test_attention.py",

@@ -191,9 +191,18 @@ def _assert_pool_step_contracts(compiled, layers, rows, heads, slots, dh,
     to ``reduce`` them (the vector unit: 2.1 ms a product at the benchmark's
     sizes), no buffer the size of a pool is copied or transposed (the chip
     keeps a dh = 64 pool slots-minor and a dh = 128 one dh-minor; a
-    contraction spelled against that re-lays 134 MB out, 8 ms a step), and
-    the temporaries stay under ``temp_bytes``."""
+    contraction spelled against that re-lays 134 MB out, 8 ms a step), the
+    temporaries stay under ``temp_bytes``, and no parameter of the program
+    is rows x slots: the one-hots and masks are made on the device."""
     hlo = compiled.as_text()
+    entry = hlo[hlo.index("\nENTRY "):]
+    params = [tuple(int(d) for d in dims.split(",") if d) for dims in
+              re.findall(r" = \w+\[([\d,]*)\]\S* parameter\(",
+                         entry[:entry.index("\n}")])]
+    assert (heads, slots, dh) in params and (rows, 1) in params
+    assert (rows, slots) not in params
+    assert not [p for p in params if len(p) == 2 and math.prod(p) >= slots
+                and slots in p]
     size, found = {}, []
     for name, dims, op, arg in _INSTRUCTION.findall(hlo):
         size[name] = math.prod(int(d) for d in dims.split(",") if d)
@@ -221,13 +230,13 @@ def test_transformer_base_decode_step_contracts_on_the_chip(v5e):
     2.1 G elements a layer."""
     from mxnet_tpu.models import transformer as tf
 
-    layers, lanes, slots, heads, dh = 2, 64, 64 * 1024, 8, 64
+    layers, lanes, slots, heads, dh, page = 2, 64, 64 * 1024, 8, 64, 16
     sym = tf.get_decode_symbol(
         vocab_size=32000, num_layers=layers, num_heads=heads, model_dim=512,
-        ffn_dim=2048, max_len=slots, pos_len=1024)
+        ffn_dim=2048, max_len=slots, pos_len=1024, page_size=page)
     arg_shapes, _, _ = sym.infer_shape(
-        data=(lanes, 1), pos_idx=(lanes, 1), slot_onehot=(lanes, slots),
-        kv_mask=(lanes, slots),
+        data=(lanes, 1), pos_idx=(lanes, 1), write_slot=(lanes, 1),
+        page_table=(lanes, slots // lanes // page),
         **{"kv_%s_%d" % (t, i): (heads, slots, dh)
            for t in "kv" for i in range(layers)})
     compiled = _compile_program(v5e, sym, {
@@ -248,7 +257,7 @@ def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
     """The two graphs ``PagedKVDecoder(arch="olmoe")`` runs, lowered for the
     v5e at OLMoE-1B-7B's published widths with one layer and the benchmark's
     serving sizes (8 lanes x 2,048 slots, bfloat16 weights and pool, float32
-    ids, positions, one-hots and masks): a shape or layout XLA:TPU refuses
+    ids, positions, write slots and page tables): a shape or layout XLA:TPU refuses
     is found here, without a chip. The experts stay XLA's grouped matmul
     (no per-expert dense expansion: the compiler's FLOP count is the sparse
     one), and the decode step hands the pool back in the type it came in."""
@@ -265,11 +274,11 @@ def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
         sym = tf.get_prefill_symbol(prefill_len=max_len, **cfg)
         inputs = {"data": ((1, max_len), "float32")}
     else:
-        sym = tf.get_decode_symbol(max_len=slots, **cfg)
+        sym = tf.get_decode_symbol(max_len=slots, page_size=16, **cfg)
         inputs = {"data": ((lanes, 1), "float32"),
                   "pos_idx": ((lanes, 1), "float32"),
-                  "slot_onehot": ((lanes, slots), "float32"),
-                  "kv_mask": ((lanes, slots), "float32"),
+                  "write_slot": ((lanes, 1), "float32"),
+                  "page_table": ((lanes, max_len // 16), "float32"),
                   "kv_k_0": ((16, slots, 128), "bfloat16"),
                   "kv_v_0": ((16, slots, 128), "bfloat16")}
     compiled = _compile_program(v5e, sym, {**weights, **inputs})
