@@ -340,6 +340,12 @@ class Executor:
                 self._monitor_callback(name, arr)
         return self.outputs
 
+    def release_outputs(self):
+        """Forget the last run's outputs, so the device may free them: after
+        a run whose results nobody reads (a warm-up dispatch), they would
+        otherwise live until the next ``forward`` has made its own."""
+        self.outputs, self.output_dict = [], {}
+
     def _write_aux(self, new_aux):
         for arr, new in zip(self.aux_arrays, new_aux):
             arr._set_jax(new)

@@ -8,7 +8,7 @@ is mean/var composed from broadcast ops to stay faithful to the op set)."""
 from .. import symbol as sym
 from ..base import MXNetError
 
-ARCHS = ("vaswani", "olmoe")
+ARCHS = ("vaswani", "olmoe", "granite_hybrid")
 
 
 def _refuse_arch(arch, what):
@@ -272,9 +272,22 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     width): no position table, K exported AFTER its norm and rotation, and
     one more output after the K/V, ``moe_load (layers, experts)``: the rows
     each expert received, padding positions included.
+
+    ``arch="granite_hybrid"`` builds the Mamba-2 / attention hybrid block
+    (``_granite_layer``, its mixer chosen by ``layer_types``). Its prefill
+    takes a second input, ``length`` (B, 1): a recurrence, unlike causal
+    attention, reads its padding unless told where the prompt ends. After the
+    logits come the cache's values in ``decode_cache`` order: a Mamba layer's
+    recurrent state at ``length`` and its last convolution columns, float32,
+    an attention layer's K and V (B, Hkv, P, dh).
     """
     if arch == "olmoe":
         return _olmoe_prefill_symbol(
+            vocab_size=vocab_size, num_layers=num_layers,
+            num_heads=num_heads, model_dim=model_dim, ffn_dim=ffn_dim,
+            prefill_len=prefill_len, **kwargs)
+    if arch == "granite_hybrid":
+        return _granite_prefill_symbol(
             vocab_size=vocab_size, num_layers=num_layers,
             num_heads=num_heads, model_dim=model_dim, ffn_dim=ffn_dim,
             prefill_len=prefill_len, **kwargs)
@@ -285,28 +298,31 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     return sym.Group([logits] + kvs)
 
 
-def _pool_attend(i, q, k_new, v_new, onehot, mask, kv_outs):
+def _pool_attend(i, q, k_new, v_new, onehot, mask, kv_outs, **attrs):
     """Layer ``i``'s write into and read of the ONE shared KV pool, on rows
     (N, H, dh): each row's new K/V lands in its one-hot slot of ``kv_k_i`` /
     ``kv_v_i`` (H, slots, dh), which come back in the type they went in and
     are collected in ``kv_outs``; then each row reads the whole updated pool
-    under its own additive float32 mask. Returns the context (N, H, dh)."""
+    under its own additive float32 mask (``attrs``: a ``scale`` other than
+    1/sqrt(dh)). Returns the context (N, H, dh)."""
     upd = [sym.KVPoolWrite(sym.Variable("kv_%s_%d" % (tag, i)), new, onehot,
                            name="layer%d_%supd" % (i, tag))
            for tag, new in (("k", k_new), ("v", v_new))]
     kv_outs += upd
     return sym.KVPoolAttention(q, upd[0], upd[1], mask,
-                               name="layer%d_att" % i)
+                               name="layer%d_att" % i, **attrs)
 
 
-def _pool_step_inputs(pos_idx, num_slots, page_size):
+def _pool_step_inputs(pos_idx, num_slots, page_size, write_slot=None):
     """``_pool_attend``'s one-hots and masks for a decode step, made ON THE
     DEVICE, once in front of the layers, from what the host knows of a lane:
     ``write_slot`` (B, 1), the pool slot its token lands in (negative: the
     lane rides along, writes nothing and sees nothing), and ``page_table``
     (B, pages a lane), the frames of its pages in order. With ``pos_idx``
-    that is the lane's whole context (``KVPageMask``)."""
-    write_slot = sym.Variable("write_slot")
+    that is the lane's whole context (``KVPageMask``). A graph that reads
+    ``write_slot`` elsewhere too hands its Variable in."""
+    if write_slot is None:
+        write_slot = sym.Variable("write_slot")
     return (sym.KVSlotOneHot(write_slot, num_slots=num_slots,
                              name="slot_onehot"),
             sym.KVPageMask(sym.Variable("page_table"), pos_idx, write_slot,
@@ -421,11 +437,20 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     weights' ``dtype`` while ids, positions, slots and frames stay float32
     inputs (and the one-hots and masks made of them float32).
 
+    ``arch="granite_hybrid"`` runs ``_granite_layer``: only its attention
+    layers have ``kv_k_i`` / ``kv_v_i`` (Hkv, max_len, dh); a Mamba layer
+    takes and returns ``ssm_state_i`` (B, H, P, N) and ``conv_state_i``
+    (B, K-1, H*P + 2N), float32, one row a lane, and ``write_slot`` also
+    tells it which lanes ride along (their rows come back bit for bit). The
+    cache outputs follow the logits in ``decode_cache`` order.
+
     ``page_size`` is the decoder's (``PagedKVDecoder``'s default here); it
     must divide ``max_len``.
     """
-    if arch == "olmoe":
-        return _olmoe_decode_symbol(
+    if arch in ("olmoe", "granite_hybrid"):
+        build = _olmoe_decode_symbol if arch == "olmoe" \
+            else _granite_decode_symbol
+        return build(
             vocab_size=vocab_size, num_layers=num_layers,
             num_slots=max_len, page_size=page_size, num_heads=num_heads,
             model_dim=model_dim, ffn_dim=ffn_dim, token_out=token_out,
@@ -593,14 +618,239 @@ def _olmoe_decode_symbol(vocab_size, num_layers, num_slots, page_size,
         "greedy_token" if token_out else None)
 
 
+# ------------------------------------------------------------ Granite hybrid
+def _granite_sizes(num_layers, num_heads, model_dim, ffn_dim, layer_types,
+                   num_kv_heads=None, head_dim=None, mamba_heads=None,
+                   mamba_head_dim=64, mamba_state=128, mamba_conv=4,
+                   mamba_chunk=256, embedding_multiplier=1.0,
+                   attention_multiplier=None, residual_multiplier=1.0,
+                   logits_scaling=1.0, rms_eps=1e-5, dtype="float32",
+                   **kwargs):
+    """``_granite_layer``'s keywords from a builder's (defaults: a Mamba-2
+    mixer of expansion 2 and attention scaled by 1/sqrt(head_dim); keywords of
+    the other architectures are dropped)."""
+    kinds = tuple(layer_types)
+    if len(kinds) != num_layers or set(kinds) - {"mamba", "attention"}:
+        raise MXNetError("granite_hybrid: layer_types must name %d layers, "
+                         "each 'mamba' or 'attention', got %r"
+                         % (num_layers, kinds))
+    head_dim = head_dim or model_dim // num_heads
+    return dict(
+        layer_types=kinds, num_heads=num_heads,
+        num_kv_heads=num_kv_heads or num_heads, head_dim=head_dim,
+        model_dim=model_dim, ffn_dim=ffn_dim,
+        mamba_heads=mamba_heads or 2 * model_dim // mamba_head_dim,
+        mamba_head_dim=mamba_head_dim, mamba_state=mamba_state,
+        mamba_conv=mamba_conv, mamba_chunk=mamba_chunk,
+        embedding_multiplier=float(embedding_multiplier),
+        attention_multiplier=float(attention_multiplier
+                                   or head_dim ** -0.5),
+        residual_multiplier=float(residual_multiplier),
+        logits_scaling=float(logits_scaling), rms_eps=rms_eps, dtype=dtype)
+
+
+def _mamba_core(op, i, xbc, dt, block, **inputs):
+    """One of ops/ssm.py's two operators on Mamba layer ``i``'s weights."""
+    name = "layer%d" % i
+    return op(xbc, dt, *(sym.Variable("%s_mamba_%s" % (name, w)) for w in
+                         ("conv_weight", "conv_bias", "dt_bias", "A_log", "D")),
+              num_heads=block["mamba_heads"], head_dim=block["mamba_head_dim"],
+              state_size=block["mamba_state"],
+              conv_kernel=block["mamba_conv"], name="%s_mamba_core" % name,
+              **inputs)
+
+
+def _granite_layer(x, i, seq_len, attend, scan, block):
+    """One Granite 4.0-H block on x (B, T, M): pre-norm RMSNorm, a mixer
+    chosen by ``layer_types[i]``, then the gated SiLU MLP every layer has,
+    both branches scaled by ``residual_multiplier``.
+
+    ``mamba``: one bias-free projection to [z | xBC | dt], asked for in
+    float32 (the core computes so); ``scan(i, xbc, dt)`` runs the core and
+    returns y (B, T, H*P); ``rms(y * silu(z))`` over all H*P features, back to
+    the weights' type, and the output projection. ``attention``: one
+    bias-free projection to [q | k | v] with fewer key/value heads than query
+    heads, no positions; ``attend(i, q, k, v)`` takes the head-major
+    (B, H or Hkv, T, dh) tensors and returns (B, H, T, dh). ``scan`` and
+    ``attend`` are the two things the prefill and the decode graph do
+    differently."""
+    name = "layer%d" % i
+    d, eps, res = block["model_dim"], block["rms_eps"], \
+        block["residual_multiplier"]
+    fc = lambda data, width, tag, **kw: sym.FullyConnected(
+        data=data, num_hidden=width, no_bias=True, flatten=False,
+        name="%s_%s" % (name, tag), **kw)
+    h = sym.RMSNorm(x, eps=eps, name="%s_ln1" % name)
+    if block["layer_types"][i] == "mamba":
+        inner = block["mamba_heads"] * block["mamba_head_dim"]
+        conv_dim = inner + 2 * block["mamba_state"]
+        zxbcdt = fc(h, inner + conv_dim + block["mamba_heads"], "mamba_in",
+                    out_dtype="float32")
+        ends = (0, inner, inner + conv_dim,
+                inner + conv_dim + block["mamba_heads"])
+        z, xbc, dt = (sym.slice_axis(zxbcdt, axis=2, begin=a, end=b)
+                      for a, b in zip(ends, ends[1:]))
+        y = scan(i, xbc, dt) * sym.Activation(z, act_type="silu")
+        y = sym.Cast(sym.RMSNorm(y, eps=eps, name="%s_mamba_norm" % name),
+                     dtype=block["dtype"])
+        mixed = fc(y, d, "mamba_out")
+    else:
+        hq, hkv, dh = block["num_heads"], block["num_kv_heads"], \
+            block["head_dim"]
+        qkv = fc(h, (hq + 2 * hkv) * dh, "qkv")
+        q, k, v = (_split_heads(
+            sym.slice_axis(qkv, axis=2, begin=a * dh, end=(a + n) * dh),
+            seq_len, n, dh) for a, n in ((0, hq), (hq, hkv), (hq + hkv, hkv)))
+        mixed = fc(_merge_heads(attend(i, q, k, v), seq_len, hq * dh), d,
+                   "proj")
+    x = x + mixed * res
+    ffn = block["ffn_dim"]
+    ab = fc(sym.RMSNorm(x, eps=eps, name="%s_ln2" % name), 2 * ffn, "mlp_in")
+    gated = sym.Activation(sym.slice_axis(ab, axis=2, begin=0, end=ffn),
+                           act_type="silu") \
+        * sym.slice_axis(ab, axis=2, begin=ffn, end=2 * ffn)
+    return x + fc(gated, d, "mlp_out") * res
+
+
+def _granite_stack(data, vocab_size, num_layers, seq_len, attend, scan, block):
+    """Embedding (scaled, and tied to the head), the layers, the final norm
+    and the head: ``data`` (B, T) -> float32 logits (B·T, vocab)."""
+    table = sym.Variable("embed_weight")
+    d = block["model_dim"]
+    x = sym.Embedding(data=data, weight=table, input_dim=vocab_size,
+                      output_dim=d, name="embed") \
+        * block["embedding_multiplier"]
+    for i in range(num_layers):
+        x = _granite_layer(x, i, seq_len, attend, scan, block)
+    x = sym.RMSNorm(x, eps=block["rms_eps"], name="final_ln")
+    return sym.FullyConnected(
+        data=sym.Reshape(x, shape=(-1, d)), weight=table,
+        num_hidden=vocab_size, no_bias=True, out_dtype="float32",
+        name="lm_head") / block["logits_scaling"]
+
+
+def _granite_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
+    block = _granite_sizes(num_layers, **sizes)
+    length = sym.Variable("length")     # (B, 1): real tokens of the bucket
+    cache = []      # the layers are built in order, so is this
+
+    def attend(i, q, k, v):
+        cache.extend([k, v])
+        return sym.MultiHeadAttention(
+            query=q, key=k, value=v, causal=True,
+            scale=block["attention_multiplier"], name="layer%d_att" % i)
+
+    def scan(i, xbc, dt):
+        core = _mamba_core(sym.Mamba2Scan, i, xbc, dt, block, length=length,
+                           chunk_size=block["mamba_chunk"])
+        cache.extend([core[1], core[2]])
+        return core[0]
+
+    logits = _granite_stack(sym.Variable("data"), vocab_size, num_layers,
+                            prefill_len, attend, scan, block)
+    return sym.Group([logits] + cache)
+
+
+def _granite_decode_symbol(vocab_size, num_layers, num_slots, page_size,
+                           token_out=True, **sizes):
+    block = _granite_sizes(num_layers, **sizes)
+    hq, hkv, dh = (block[k] for k in ("num_heads", "num_kv_heads", "head_dim"))
+    pos_idx = sym.Variable("pos_idx")
+    write_slot = sym.Variable("write_slot")
+    oh, msk = _pool_step_inputs(pos_idx, num_slots, page_size, write_slot)
+    cache = []      # the layers are built in order, so is this
+
+    def attend(i, q, k_new, v_new):
+        # one token a lane: the head-major (B, H, 1, dh) tensors are the
+        # pool's rows (B, H, dh)
+        q, k_new, v_new = (sym.Reshape(a, shape=(-1, n, dh)) for a, n in
+                           ((q, hq), (k_new, hkv), (v_new, hkv)))
+        ctx = _pool_attend(i, q, k_new, v_new, oh, msk, cache,
+                           scale=block["attention_multiplier"])
+        return sym.Reshape(ctx, shape=(-1, hq, 1, dh))
+
+    def scan(i, xbc, dt):
+        core = _mamba_core(
+            sym.Mamba2Step, i, sym.Reshape(xbc, shape=(0, -1)),
+            sym.Reshape(dt, shape=(0, -1)), block,
+            ssm_state=sym.Variable("ssm_state_%d" % i),
+            conv_state=sym.Variable("conv_state_%d" % i), stepped=write_slot)
+        cache.extend([core[1], core[2]])
+        return sym.Reshape(core[0], shape=(0, 1, -1))
+
+    logits = _granite_stack(sym.Variable("data"), vocab_size, num_layers, 1,
+                            attend, scan, block)
+    return _token_head(logits, cache, "greedy_token" if token_out else None)
+
+
+def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
+                 **sizes):
+    """What a decode graph of ``arch`` keeps between steps, in the order its
+    cache inputs' updates follow the logits (and the prefill's values do):
+    ``[(name, kind, shape)]``. A ``"pool"`` is addressed by slot, ``shape``
+    is (heads, dh) and the buffer (heads, slots, dh); a ``"row"`` is
+    addressed by lane, ``shape`` is one lane's and the buffer (lanes,) +
+    shape, float32."""
+    if arch != "granite_hybrid":
+        pool = (num_heads, head_dim or model_dim // num_heads)
+        return [("kv_%s_%d" % (t, i), "pool", pool)
+                for i in range(num_layers) for t in "kv"]
+    block = _granite_sizes(num_layers, num_heads=num_heads,
+                           model_dim=model_dim, head_dim=head_dim, **sizes)
+    h, p, n = (block[k] for k in ("mamba_heads", "mamba_head_dim",
+                                  "mamba_state"))
+    per_kind = {
+        "attention": [("kv_k_%d", "pool",
+                       (block["num_kv_heads"], block["head_dim"])),
+                      ("kv_v_%d", "pool",
+                       (block["num_kv_heads"], block["head_dim"]))],
+        "mamba": [("ssm_state_%d", "row", (h, p, n)),
+                  ("conv_state_%d", "row",
+                   (block["mamba_conv"] - 1, h * p + 2 * n))]}
+    return [(name % i, kind, shape)
+            for i, layer in enumerate(block["layer_types"])
+            for name, kind, shape in per_kind[layer]]
+
+
+def _granite_param_shapes(vocab_size, num_layers, **sizes):
+    block = _granite_sizes(num_layers, **sizes)
+    d, ffn = block["model_dim"], block["ffn_dim"]
+    hq, hkv, dh = (block[k] for k in ("num_heads", "num_kv_heads", "head_dim"))
+    h, k = block["mamba_heads"], block["mamba_conv"]
+    inner = h * block["mamba_head_dim"]
+    conv_dim = inner + 2 * block["mamba_state"]
+    shapes = {"embed_weight": (vocab_size, d), "final_ln_gamma": (d,)}
+    for i, kind in enumerate(block["layer_types"]):
+        n = "layer%d_" % i
+        shapes.update({n + "ln1_gamma": (d,), n + "ln2_gamma": (d,),
+                       n + "mlp_in_weight": (2 * ffn, d),
+                       n + "mlp_out_weight": (d, ffn)})
+        if kind == "attention":
+            shapes.update({n + "qkv_weight": ((hq + 2 * hkv) * dh, d),
+                           n + "proj_weight": (d, hq * dh)})
+            continue
+        shapes.update({
+            n + "mamba_in_weight": (inner + conv_dim + h, d),
+            n + "mamba_conv_weight": (conv_dim, k),
+            n + "mamba_conv_bias": (conv_dim,), n + "mamba_dt_bias": (h,),
+            n + "mamba_A_log": (h,), n + "mamba_D": (h,),
+            n + "mamba_norm_gamma": (inner,),
+            n + "mamba_out_weight": (d, inner)})
+    return shapes
+
+
 def param_shapes(arch, vocab_size, num_layers, num_heads, model_dim, ffn_dim,
                  head_dim=None, num_experts=64, **kwargs):
     """{name: shape} of the checkpoint the serving graphs of ``arch`` load,
-    from the sizes alone (``olmoe`` only: the Vaswani checkpoint is spelled
-    where it always was, by its drivers and tests)."""
+    from the sizes alone (``olmoe`` and ``granite_hybrid``: the Vaswani
+    checkpoint is spelled where it always was, by its drivers and tests)."""
+    if arch == "granite_hybrid":
+        return _granite_param_shapes(
+            vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
+            ffn_dim=ffn_dim, head_dim=head_dim, **kwargs)
     if arch != "olmoe":
-        raise MXNetError("param_shapes knows arch 'olmoe' only, not %r"
-                         % (arch,))
+        raise MXNetError("param_shapes knows archs 'olmoe' and "
+                         "'granite_hybrid', not %r" % (arch,))
     d = model_dim
     width = num_heads * (head_dim or d // num_heads)
     shapes = {"embed_weight": (vocab_size, d), "final_ln_gamma": (d,),

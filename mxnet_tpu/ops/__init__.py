@@ -23,6 +23,7 @@ from . import rnn  # noqa: F401
 from . import vision  # noqa: F401
 from . import attention  # noqa: F401
 from . import moe  # noqa: F401
+from . import ssm  # noqa: F401
 from . import custom  # noqa: F401
 
 __all__ = [

@@ -44,20 +44,29 @@ def _multi_head_attention(attrs, query, key, value):
     ``seq`` axis (parallel.make_mesh({"data": dp, "seq": sp})), self-attention
     dispatches to ring attention (parallel/ring_attention.py) — q stays put,
     k/v blocks rotate over ICI via ppermute, softmax accumulates online.
-    Disable with MXNET_RING_ATTENTION=0."""
+    Disable with MXNET_RING_ATTENTION=0.
+
+    Grouped queries: ``key`` / ``value`` may carry fewer heads (B, Hkv, S, D)
+    than ``query`` (B, H, T, D), Hkv dividing H; key/value head j then serves
+    query heads j * H/Hkv .. (j + 1) * H/Hkv - 1. The group is an axis of the
+    query that both contractions carry (the keys are never repeated), of
+    size 1 where the head counts are equal. Fewer key/value heads take the
+    dense path only."""
     import os
 
+    b, h, t, d = query.shape
+    hkv, s_len = key.shape[1], key.shape[2]
+    g = _kv_groups(h, hkv, "MultiHeadAttention")
     mesh = None
-    if os.environ.get("MXNET_RING_ATTENTION", "1") == "1":
+    if g == 1 and os.environ.get("MXNET_RING_ATTENTION", "1") == "1":
         from ..parallel.mesh import current_trace_mesh
 
         mesh = current_trace_mesh()
     if (mesh is not None and "seq" in mesh.axis_names
             and mesh.shape["seq"] > 1):
-        T = query.shape[2]
         batch_ok = ("data" not in mesh.axis_names
-                    or query.shape[0] % mesh.shape["data"] == 0)
-        if key.shape[2] == T and T % mesh.shape["seq"] == 0 and batch_ok:
+                    or b % mesh.shape["data"] == 0)
+        if s_len == t and t % mesh.shape["seq"] == 0 and batch_ok:
             # self-attention with divisible shards only; else dense fallback
             from ..parallel.ring_attention import ring_attention
 
@@ -70,7 +79,7 @@ def _multi_head_attention(attrs, query, key, value):
                 batch_axis="data" if "data" in mesh.axis_names else None)
             return out.transpose(0, 2, 1, 3)
 
-    if os.environ.get("MXNET_USE_PALLAS_ATTENTION", "0") == "1":
+    if g == 1 and os.environ.get("MXNET_USE_PALLAS_ATTENTION", "0") == "1":
         from . import pallas_attention as pa
 
         if pa.supported(query.shape, key.shape, causal=attrs["causal"]):
@@ -78,21 +87,25 @@ def _multi_head_attention(attrs, query, key, value):
             return pa.flash_attention(
                 query, key, value, causal=attrs["causal"],
                 scale=max(attrs["scale"], 0.0), interpret=not on_tpu)
-    d = query.shape[-1]
     scale = attrs["scale"] if attrs["scale"] > 0 else 1.0 / np.sqrt(d)
-    q = query.astype("float32")
-    k = key.astype("float32")
-    v = value.astype("float32")
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    q = query.astype("float32").reshape(b, hkv, g, t, d)
+    s = jnp.einsum("bkgqd,bkud->bkgqu", q, key.astype("float32")) * scale
     if attrs["causal"]:
         # bottom-right aligned so a rectangular (decode) call — T queries over
         # S >= T keys — lets each query see all S-T+q past keys
-        T, S = s.shape[-2], s.shape[-1]
-        mask = jnp.tril(jnp.ones((T, S), bool), k=S - T)
+        mask = jnp.tril(jnp.ones((t, s_len), bool), k=s_len - t)
         s = jnp.where(mask, s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bhkd->bhqd", p, v)
-    return out.astype(query.dtype)
+    out = jnp.einsum("bkgqu,bkud->bkgqd", jax.nn.softmax(s, axis=-1),
+                     value.astype("float32"))
+    return out.reshape(b, h, t, d).astype(query.dtype)
+
+
+def _kv_groups(heads, kv_heads, what):
+    """Query heads a key/value head serves; 1 where the counts are equal."""
+    if kv_heads < 1 or heads % kv_heads:
+        raise MXNetError("%s: %d query heads do not divide over %d key/value "
+                         "heads" % (what, heads, kv_heads))
+    return heads // kv_heads
 
 
 @register(
@@ -174,16 +187,22 @@ def _kv_pool_attention(attrs, query, pool_k, pool_v, mask):
     ``_multi_head_attention`` gives the same tokens in the prefill) with a
     float32 accumulator, and the softmax is float32 whatever the pool's
     dtype. A fully masked row comes out finite: the softmax subtracts the
-    row's maximum first."""
+    row's maximum first. A pool of fewer heads (Hkv, S, dh) than the
+    query's serves them in groups, as ``MultiHeadAttention`` does: the group
+    is an axis of the query that both contractions carry (size 1 where the
+    counts are equal), so the pool is read once and never repeated."""
     scale = attrs["scale"] if attrs["scale"] > 0 \
         else 1.0 / np.sqrt(query.shape[-1])
-    s = jnp.einsum("rhd,hsd->rhs", query, pool_k,
+    r, h, dh = query.shape
+    hkv = pool_k.shape[0]
+    q = query.reshape(r, hkv, _kv_groups(h, hkv, "KVPoolAttention"), dh)
+    s = jnp.einsum("rkgd,ksd->rkgs", q, pool_k,
                    preferred_element_type=jnp.float32)
-    p = jax.nn.softmax(s * scale + mask.astype(jnp.float32)[:, None, :],
-                       axis=-1)
-    out = jnp.einsum("rhs,hsd->rhd", p, pool_v,
+    p = jax.nn.softmax(
+        s * scale + mask.astype(jnp.float32)[:, None, None, :], axis=-1)
+    out = jnp.einsum("rkgs,ksd->rkgd", p, pool_v,
                      preferred_element_type=jnp.float32)
-    return out.astype(query.dtype)
+    return out.reshape(r, h, dh).astype(query.dtype)
 
 
 @register(

@@ -194,6 +194,17 @@ register_meta("_contrib_MoEFeedForward",
               param_slots=("router_weight", "gate_weight", "up_weight",
                            "down_weight"),
               aliases=("MoEFeedForward",))
+_MAMBA2_WEIGHTS = {"conv_weight": 2, "conv_bias": 1, "dt_bias": 1, "A_log": 1,
+                   "D": 1}
+register_meta("_contrib_Mamba2Scan",
+              input_ranks=dict(_MAMBA2_WEIGHTS, data=3, dt=3, length=2),
+              dtype_policy="first", param_slots=tuple(_MAMBA2_WEIGHTS),
+              aliases=("Mamba2Scan",))
+register_meta("_contrib_Mamba2Step",
+              input_ranks=dict(_MAMBA2_WEIGHTS, data=2, dt=2, ssm_state=4,
+                               conv_state=3, stepped=2),
+              dtype_policy="first", param_slots=tuple(_MAMBA2_WEIGHTS),
+              aliases=("Mamba2Step",))
 register_meta("RNN",
               input_ranks={"data": 3, "parameters": 1,
                            "state": 3, "state_cell": 3},

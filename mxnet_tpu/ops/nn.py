@@ -193,8 +193,11 @@ def _pooling(attrs, data):
 # --- Activations --------------------------------------------------------------
 @register("Activation", attrs={"act_type": AttrSpec("str", required=True)})
 def _activation(attrs, data):
-    """(reference: activation.cc) act_type ∈ relu|sigmoid|tanh|softrelu."""
+    """(reference: activation.cc) act_type ∈ relu|sigmoid|tanh|softrelu, and
+    silu (x * sigmoid(x)), which the reference's era did not have."""
     t = attrs["act_type"]
+    if t == "silu":
+        return jax.nn.silu(data)
     if t == "relu":
         return jnp.maximum(data, 0)
     if t == "sigmoid":

@@ -160,6 +160,44 @@ def _moe(attrs, shapes):
     return shapes
 
 
+def _mamba2_weights(attrs, shapes):
+    """conv_weight, conv_bias, dt_bias, A_log, D (slots 2..6) from the
+    operator's sizes; returns (heads, head_dim, state, kernel, channels)."""
+    h, p, n = attrs["num_heads"], attrs["head_dim"], attrs["state_size"]
+    k, c = attrs.get("conv_kernel", 4), h * p + 2 * n
+    for i, s in enumerate(((c, k), (c,), (h,), (h,), (h,)), 2):
+        if shapes[i] is None:
+            shapes[i] = s
+    return h, p, n, k, c
+
+
+@rule("_contrib_Mamba2Scan")
+@rule("Mamba2Scan")
+def _mamba2_scan(attrs, shapes):
+    h = _mamba2_weights(attrs, shapes)[0]
+    data = shapes[0]
+    if data is not None:            # (B, T, C): dt (B, T, H), length (B, 1)
+        if shapes[1] is None:
+            shapes[1] = (data[0], data[1], h)
+        if shapes[7] is None:
+            shapes[7] = (data[0], 1)
+    return shapes
+
+
+@rule("_contrib_Mamba2Step")
+@rule("Mamba2Step")
+def _mamba2_step(attrs, shapes):
+    h, p, n, k, c = _mamba2_weights(attrs, shapes)
+    data = shapes[0]
+    if data is not None:            # (R, C): one token a row
+        r = data[0]
+        for i, s in ((1, (r, h)), (7, (r, h, p, n)), (8, (r, k - 1, c)),
+                     (9, (r, 1))):
+            if shapes[i] is None:
+                shapes[i] = s
+    return shapes
+
+
 @rule("RNN")
 def _rnn_shapes(attrs, shapes):
     data = shapes[0]

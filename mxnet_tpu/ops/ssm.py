@@ -1,0 +1,174 @@
+"""The Mamba-2 mixer's core (Dao & Gu 2024, "Transformers are SSMs"): the
+depthwise causal convolution, its activation and the selective state-space
+recurrence, as two registry operators in plain ``jax.numpy``.
+
+``Mamba2Scan`` runs T positions of a right-padded sequence whose LENGTH is
+data, in the chunked (state-space-dual) form, and hands back the outputs and
+the two pieces of state a decoder keeps at that length. ``Mamba2Step``
+advances that state by one token a row. Both compute in float32 whatever
+they are fed, and the state is float32 (the published ``mamba_ssm`` kernels
+keep it so). The projections around the core, the gate and its norm stay in
+the graph (models/transformer.py ``_granite_layer``).
+
+Per head h of P features, state N, one group of B and C, kernel K:
+    xBC_t = silu(sum_{j<K} w[:, j] * xBC_{t-K+1+j} + b)     zeros left of t = 0
+    [x (H x P) | B (N) | C (N)] = xBC_t;  dt_t = softplus(dt_t + dt_bias)
+    S_t = exp(dt_t A_h) S_{t-1} + dt_t * x_t (outer) B_t,    A_h = -exp(A_log_h)
+    y_t = S_t C_t + D_h x_t
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from ..base import MXNetError
+from .registry import AttrSpec, register
+
+_HI = jax.lax.Precision.HIGHEST  # float32 arithmetic on the matrix unit too
+
+_SIZES = {
+    "num_heads": AttrSpec("int", required=True),
+    "head_dim": AttrSpec("int", required=True),
+    "state_size": AttrSpec("int", required=True),
+    "conv_kernel": AttrSpec("int", default=4),
+}
+_WEIGHTS = ("conv_weight", "conv_bias", "dt_bias", "A_log", "D")
+
+
+def _split(attrs, xbc):
+    """[x (..., H, P) | B (..., N) | C (..., N)] of the activated xBC."""
+    h, p, n = attrs["num_heads"], attrs["head_dim"], attrs["state_size"]
+    if xbc.shape[-1] != h * p + 2 * n:
+        raise MXNetError("Mamba2: xBC has %d features, %d heads of %d and "
+                         "two states of %d need %d"
+                         % (xbc.shape[-1], h, p, n, h * p + 2 * n))
+    x = xbc[..., :h * p].reshape(xbc.shape[:-1] + (h, p))
+    return x, xbc[..., h * p:h * p + n], xbc[..., h * p + n:]
+
+
+def _f32(*arrays):
+    return tuple(a.astype(jnp.float32) for a in arrays)
+
+
+def _chunked_scan(x, dt, a, b, c, chunk):
+    """The recurrence over T positions, ``chunk`` at a time: inside a chunk
+    every position reads every earlier one through the product of the decays
+    between them (a masked matrix product: the "dual" form), and a chunk
+    reads the ones before it through ONE state (B, H, P, N) that a
+    ``lax.scan`` carries from chunk to chunk. x (B, T, H, P); dt (B, T, H)
+    after its softplus, 0 where a position is padding (then the state
+    passes through it unchanged); a (H,); b, c (B, T, N). Returns
+    (y (B, T, H, P), the state after position T - 1)."""
+    bsz, t, h, p = x.shape
+    n = b.shape[-1]
+    q = min(chunk, t)
+    pad = -t % q
+    if pad:  # dt = 0: padding changes no state
+        x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+                       for v in (x, dt, b, c))
+    nc = (t + pad) // q
+    # chunk-major for the scan: (nc, B, q, ...)
+    blocks = lambda v: jnp.moveaxis(v.reshape((bsz, nc, q) + v.shape[2:]), 1, 0)
+    xs = blocks(x * dt[..., None])
+    cum = jnp.cumsum(blocks(dt * a), axis=2)        # log decay, inclusive
+    earlier = jnp.tril(jnp.ones((q, q), bool))[None, :, :, None]
+
+    def one(state, blk):
+        xs_c, cum_c, b_c, c_c = blk
+        # position l reads s <= l through exp(cum_l - cum_s)
+        decay = jnp.exp(jnp.where(
+            earlier, cum_c[:, :, None, :] - cum_c[:, None, :, :], -jnp.inf))
+        cb = jnp.einsum("bln,bsn->bls", c_c, b_c, precision=_HI)
+        y = jnp.einsum("blsh,bshp->blhp", cb[..., None] * decay, xs_c,
+                       precision=_HI)
+        # what the chunk inherits, decayed to each of its positions
+        y = y + jnp.einsum("bln,bhpn->blhp", c_c, state, precision=_HI) \
+            * jnp.exp(cum_c)[..., None]
+        # the state at the chunk's end
+        last = cum_c[:, -1]
+        tail = jnp.exp(last[:, None, :] - cum_c)
+        state = state * jnp.exp(last)[:, :, None, None] + jnp.einsum(
+            "bsn,bshp->bhpn", b_c, xs_c * tail[..., None], precision=_HI)
+        return state, y
+
+    state, y = jax.lax.scan(one, jnp.zeros((bsz, h, p, n), jnp.float32),
+                            (xs, cum, blocks(b), blocks(c)))
+    return jnp.moveaxis(y, 0, 1).reshape(bsz, nc * q, h, p)[:, :t], state
+
+
+@register(
+    "_contrib_Mamba2Scan",
+    attrs=dict(_SIZES, chunk_size=AttrSpec("int", default=256)),
+    input_names=("data", "dt") + _WEIGHTS + ("length",),
+    num_outputs=3,
+    output_names=("output", "ssm_state", "conv_state"),
+    aliases=("Mamba2Scan",),
+)
+def _mamba2_scan(attrs, data, dt, conv_weight, conv_bias, dt_bias, A_log, D,
+                 length):
+    """The core over a right-padded sequence: ``data`` (B, T, H*P + 2N) is
+    the projected xBC before its convolution, ``dt`` (B, T, H) the raw step
+    sizes, ``length`` (B, 1) the number of real positions a row (data, so one
+    program serves every length). Returns ``(y (B, T, H*P), ssm_state
+    (B, H, P, N), conv_state (B, K-1, H*P + 2N))``: the outputs in ``data``'s
+    type (those past the length are meaningless), and in float32 the
+    recurrent state after position ``length - 1`` and the last K-1
+    PRE-activation xBC columns before ``length`` (zeros where the sequence is
+    shorter). Positions at and past the length get ``dt = 0``, so the state
+    passes through them unchanged; the columns come from a slice whose start
+    is the length. ``chunk_size`` is the chunk of the chunked form and
+    changes no function."""
+    k = attrs["conv_kernel"]
+    xbc, dt, w, bias, dt_bias, a_log, d = _f32(
+        data, dt, conv_weight, conv_bias, dt_bias, A_log, D)
+    bsz, t, _ = xbc.shape
+    n_real = length.reshape(bsz).astype(jnp.int32)
+    padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
+    conv = jax.nn.silu(bias + sum(padded[:, j:j + t] * w[:, j]
+                                  for j in range(k)))
+    # position p sits at padded index p + K - 1, so the K-1 columns before
+    # the length start at padded index ``length``
+    conv_state = jax.vmap(lambda row, at: jax.lax.dynamic_slice_in_dim(
+        row, at, k - 1, axis=0))(padded, n_real)
+    x, b, c = _split(attrs, conv)
+    live = jnp.arange(t)[None, :] < n_real[:, None]
+    dt = jnp.where(live[..., None], jax.nn.softplus(dt + dt_bias), 0.0)
+    y, state = _chunked_scan(x, dt, -jnp.exp(a_log), b, c,
+                             attrs["chunk_size"])
+    y = y + d[:, None] * x
+    return y.reshape(bsz, t, -1).astype(data.dtype), state, conv_state
+
+
+@register(
+    "_contrib_Mamba2Step",
+    attrs=dict(_SIZES),
+    input_names=("data", "dt") + _WEIGHTS + ("ssm_state", "conv_state",
+                                             "stepped"),
+    num_outputs=3,
+    output_names=("output", "ssm_state", "conv_state"),
+    aliases=("Mamba2Step",),
+)
+def _mamba2_step(attrs, data, dt, conv_weight, conv_bias, dt_bias, A_log, D,
+                 ssm_state, conv_state, stepped):
+    """One token a row: ``data`` (R, H*P + 2N) and ``dt`` (R, H) as in
+    ``Mamba2Scan``, ``ssm_state`` (R, H, P, N) and ``conv_state``
+    (R, K-1, H*P + 2N) the row's state, ``stepped`` (R, 1) negative for a row
+    that rides along (a decode step's ``write_slot``). Returns ``(y (R, H*P),
+    ssm_state', conv_state')``; the state of a row that rides along comes
+    back bit for bit and its ``y`` is meaningless. Elementwise float32 and a
+    sum over the state's last axis: nothing here rounds through the matrix
+    unit."""
+    k = attrs["conv_kernel"]
+    xbc, dt, w, bias, dt_bias, a_log, d = _f32(
+        data, dt, conv_weight, conv_bias, dt_bias, A_log, D)
+    window = jnp.concatenate([conv_state, xbc[:, None, :]], axis=1)
+    conv = jax.nn.silu(bias + sum(window[:, j] * w[:, j] for j in range(k)))
+    x, b, c = _split(attrs, conv)
+    dt = jax.nn.softplus(dt + dt_bias)
+    new = jnp.exp(dt * -jnp.exp(a_log))[:, :, None, None] * ssm_state \
+        + (dt[..., None] * x)[..., None] * b[:, None, None, :]
+    y = jnp.sum(new * c[:, None, None, :], axis=-1) + d[:, None] * x
+    moved = stepped.reshape(-1) >= 0
+    return (y.reshape(y.shape[0], -1).astype(data.dtype),
+            jnp.where(moved[:, None, None, None], new, ssm_state),
+            jnp.where(moved[:, None, None], window[:, 1:], conv_state))

@@ -9,7 +9,12 @@ replaces costs O(T) work per token and a recompile per prompt length.
 
 One layout (``PagedKVDecoder``): the decode batch's rows are ``lanes``, one
 per sequence, and the lanes share ONE slot axis (``lanes * max_len`` slots
-per layer, KV ``(H, slots, dh)``) carved into refcounted page frames. A
+per layer, KV ``(H, slots, dh)``) carved into refcounted page frames. What
+the decode graph keeps between steps is a list of named buffers the model
+gives (``models.transformer.decode_cache``), of two kinds: those pools,
+addressed by slot, and, where a layer keeps a recurrent state instead
+(``arch="granite_hybrid"``), per-lane rows ``(lanes, ...)`` addressed by
+lane. A
 token's write happens IN-GRAPH, through one-hot rows the program makes of
 each lane's ``write_slot`` (models/transformer.py ``get_decode_symbol``), as
 it makes each lane's attention mask of its ``page_table``: a step hands the
@@ -87,21 +92,15 @@ def _gap_return(dec):
         dec._last_return_t = time.perf_counter()
 
 
-def _swap_kv(exe, num_layers):
-    """Hand each layer's updated K/V (program outputs) back as the next
-    dispatch's inputs — device-side pointer swaps, no copy. ``arg_dict``
-    owns the pool from here on: ``exe.outputs[1..]`` still names the same
-    arrays, and they die with the next donated update (``_AdmitScatter``),
-    so every reader takes the pool from ``arg_dict`` at the time of use."""
-    for i in range(num_layers):
-        exe.arg_dict["kv_k_%d" % i]._set_jax(exe.outputs[1 + 2 * i]._jax())
-        exe.arg_dict["kv_v_%d" % i]._set_jax(exe.outputs[2 + 2 * i]._jax())
-
-
-def _kv_names(num_layers):
-    """The pool's argument names in program order: K then V, per layer."""
-    return [n for i in range(num_layers)
-            for n in ("kv_k_%d" % i, "kv_v_%d" % i)]
+def _swap_cache(exe, names):
+    """Hand the updated cache buffers (program outputs, in the cache's order
+    after the logits) back as the next dispatch's inputs — device-side
+    pointer swaps, no copy. ``arg_dict`` owns the cache from here on:
+    ``exe.outputs[1..]`` still names the same arrays, and they die with the
+    next donated update (``_AdmitScatter``), so every reader takes a buffer
+    from ``arg_dict`` at the time of use."""
+    for j, name in enumerate(names):
+        exe.arg_dict[name]._set_jax(exe.outputs[1 + j]._jax())
 
 
 # ------------------------------------------------------------------ megastep
@@ -201,18 +200,19 @@ class _SealedProgram:
         args = dec._dec_exe.arg_dict
         return tuple(args[n]._jax() for n in names)
 
-    def _graph(self, symbol, inputs, num_layers):
+    def _graph(self, symbol, inputs, cache_names):
         """Bind a serving graph: ``interpret(weights, kvs, feed, key)`` runs
-        it on ``feed`` (its ``inputs`` by name) and the pool and returns its
-        outputs. Every other argument is a weight, shared by name across the
-        serving graphs (``weight_names``)."""
+        it on ``feed`` (its ``inputs`` by name) and the pool
+        (``cache_names``) and returns its outputs. Every other argument is a
+        weight, shared by name across the serving graphs
+        (``weight_names``)."""
         from ..executor import _GraphProgram
 
         prog = _GraphProgram(symbol)
         if prog.aux_names:
             raise MXNetError("%s: the graph must carry no aux state, got %r"
                              % (self._what, prog.aux_names))
-        self.kv_names = _kv_names(num_layers)
+        self.kv_names = list(cache_names)
         fed = set(inputs).union(self.kv_names)
         self.weight_names = [n for n in prog.arg_names if n not in fed]
 
@@ -293,7 +293,8 @@ class _DecodeMegastep(_SealedProgram):
                 num_heads=dec.num_heads, model_dim=dec.model_dim,
                 ffn_dim=dec.ffn_dim, max_len=S, pos_len=pos_len,
                 page_size=dec.page_size),
-            ("data", "pos_idx", "write_slot", "page_table"), L)
+            ("data", "pos_idx", "write_slot", "page_table"),
+            dec._cache_names)
         mode, temp, top_k = sampler.mode, sampler.temperature, sampler.top_k
         lane_ids = jnp.arange(B)
 
@@ -397,7 +398,8 @@ class _ChunkProgram(_SealedProgram):
                 num_heads=dec.num_heads, model_dim=dec.model_dim,
                 ffn_dim=dec.ffn_dim, chunk_len=self.t, total_slots=S,
                 pos_len=dec.pos_len),
-            ("data", "pos_idx", "write_onehot", "att_mask"), L)
+            ("data", "pos_idx", "write_onehot", "att_mask"),
+            dec._cache_names)
 
         def run(weights, kvs, data, pos_idx, w_oh, mask, key):
             outs = interpret(weights, kvs,
@@ -427,35 +429,43 @@ class _ChunkProgram(_SealedProgram):
 
 
 class _AdmitScatter(_SealedProgram):
-    """The pool update of a classic admission as ONE program: the 2·layers
-    pool buffers go in DONATED and come back updated in place with the
-    prefill's K/V at positions ``0..length-1`` of the lane's page frames.
+    """The cache update of a classic admission as ONE program: every cache
+    buffer goes in DONATED and comes back updated in place — a pool with the
+    prefill's K/V at positions ``0..length-1`` of the lane's page frames, a
+    per-lane buffer with the prefill's state in the lane's row.
 
-    ``run(dec, new, frames, length)``: ``new`` is the prefill executable's
-    2·layers K/V outputs ``(1, H, prefill_len, dh)`` (the sealed inputs),
-    ``frames`` the lane's page-frame table, ``length`` the prompt length.
-    Every shape is the decoder's, none the prompt's, so one compile serves
-    every prompt length. The update walks
+    ``run(dec, new, frames, length, lane)``: ``new`` is the prefill
+    executable's cache outputs, ``(1, H, prefill_len, dh)`` for a pool and
+    ``(1,) + row`` for a per-lane buffer (the sealed inputs), ``frames`` the
+    lane's page-frame table, ``length`` the prompt length, ``lane`` the
+    lane's index. Every shape is the decoder's, none the prompt's, so one
+    compile serves every prompt length. The pool update walks
     the prompt's pages with ``dynamic_update_slice`` — a page is a
     contiguous slot run — and blends the last, partial page with what the
     pool holds there, so exactly the slots of positions ``< length``
     change. A scatter over the slot axis would say the same, but the TPU
     keeps the pool with slots minor-most and re-lays the WHOLE buffer out
-    around a scatter, twice per buffer; the page walk leaves it in place."""
+    around a scatter, twice per buffer; the page walk leaves it in place.
+    A row is one ``dynamic_update_slice`` at the lane's index."""
 
     def __init__(self, dec):
         import jax
         import jax.numpy as jnp
 
-        super().__init__("admit scatter", "the pool-update program is",
+        super().__init__("admit scatter", "the cache-update program is",
                          "serving.admit_scatter_compile")
-        self.kv_names = _kv_names(dec.num_layers)
-        H, dh, ps = dec.num_heads, dec.dh, dec.page_size
+        self.kv_names = [name for name, _, _ in dec._cache]
+        self.pools = pools = [j for j, (_, kind, _) in enumerate(dec._cache)
+                              if kind == "pool"]
+        self.rows = rows_at = [j for j in range(len(dec._cache))
+                               if j not in pools]
+        ps = dec.page_size
         self.n_pages = -(-dec.prefill_len // ps)
         tail = self.n_pages * ps - dec.prefill_len
 
-        def run(kvs, new, frames, length):
-            rows = [n[0] for n in new]
+        def run(bufs, new, frames, at):
+            length, lane = at[0], at[1]
+            rows = [new[j][0] for j in pools]
             if tail:  # so a page-sized slice never runs off the end
                 rows = [jnp.pad(r, ((0, 0), (0, tail), (0, 0)))
                         for r in rows]
@@ -466,44 +476,61 @@ class _AdmitScatter(_SealedProgram):
                 live = in_page < length - j * ps
                 out = []
                 for kv, r in zip(kvs, rows):
-                    blk = jax.lax.dynamic_slice(r, (0, j * ps, 0),
-                                                (H, ps, dh))
-                    old = jax.lax.dynamic_slice(kv, (0, dst, 0),
-                                                (H, ps, dh))
+                    page_shape = (kv.shape[0], ps, kv.shape[2])
+                    blk = jax.lax.dynamic_slice(r, (0, j * ps, 0), page_shape)
+                    old = jax.lax.dynamic_slice(kv, (0, dst, 0), page_shape)
                     out.append(jax.lax.dynamic_update_slice(
                         kv, jnp.where(live, blk, old), (0, dst, 0)))
                 return tuple(out)
 
-            return jax.lax.fori_loop(0, (length + ps - 1) // ps, page,
-                                     tuple(kvs))
+            out = list(bufs)
+            for j, kv in zip(pools, jax.lax.fori_loop(
+                    0, (length + ps - 1) // ps, page,
+                    tuple(bufs[j] for j in pools))):
+                out[j] = kv
+            for j in rows_at:
+                at = (lane,) + (0,) * (bufs[j].ndim - 1)
+                # a zero-length prompt (the warm dispatch) changes nothing
+                row = jnp.where(length > 0, new[j], jax.lax.dynamic_slice(
+                    bufs[j], at, new[j].shape))
+                out[j] = jax.lax.dynamic_update_slice(bufs[j], row, at)
+            return tuple(out)
 
         self._jit(run, "mx_admit_scatter", donate_argnums=(0,))
 
     def _dummy(self, dec):
-        """A zero-length prompt on the live pool: no page is walked, every
-        buffer comes back bitwise as it went in. The K/V come from a prefill
-        staged the way an admission stages it, so the arrays are of the kind
-        a real call passes and jit never compiles again."""
-        pf = dec._pf_cache.executable({"data": (1, dec.prefill_len)})
+        """A zero-length prompt on the live cache: no page is walked, every
+        buffer comes back bitwise as it went in. The new values come from a
+        prefill staged the way an admission stages it, so the arrays are of
+        the kind a real call passes and jit never compiles again."""
+        pf = dec._pf_cache.executable(dec._prefill_shapes())
         pf.arg_dict["data"][:] = np.zeros((1, dec.prefill_len), np.float32)
         pf.forward(is_train=False)
-        return dec._prefill_kv(pf), ((), 0)
+        return dec._prefill_cache(pf), ((), 0, 0)
 
-    def _dispatch(self, dec, new, frames, length):
-        """Donate the pool, run, hand the updated buffers back: from the
+    def _dispatch(self, dec, new, frames, length, lane):
+        """Donate the cache, run, hand the updated buffers back: from the
         enqueue on, the arrays ``arg_dict`` held before are dead."""
         table = np.zeros((self.n_pages,), np.int32)
         table[:len(frames)] = frames
+        # length and lane travel as ONE small array: a host-to-device copy
+        # costs the same however small
         out = self._fn(self._live(dec, self.kv_names), new, table,
-                       np.int32(length))
-        for name, arr in zip(self.kv_names, out):
-            dec._dec_exe.arg_dict[name]._set_jax(arr)
+                       np.array([length, lane], np.int32))
+        args = dec._dec_exe.arg_dict
+        for j in self.pools:
+            args[self.kv_names[j]]._set_jax(out[j])
+        if self.rows:
+            with _tm.span("serving.admit.state", buffers=len(self.rows)):
+                for j in self.rows:
+                    args[self.kv_names[j]]._set_jax(out[j])
         return out
 
-    def run(self, dec, new, frames, length):
-        """One pool update, enqueued. ``frames`` is the lane's frame table
-        (any length up to ``n_pages``), ``length`` the prompt length."""
-        self._run(dec, new, frames, length)
+    def run(self, dec, new, frames, length, lane):
+        """One cache update, enqueued. ``frames`` is the lane's frame table
+        (any length up to ``n_pages``), ``length`` the prompt length,
+        ``lane`` the lane's index."""
+        self._run(dec, new, frames, length, lane)
         if _tm.enabled():
             _tm.counter("serving.admit_scatter_dispatches").inc()
 
@@ -651,6 +678,17 @@ class PagedKVDecoder:
     ids, positions, slots and frames stay float32 (a token id of 50,303
     does not survive bfloat16). The prefix cache, the chunk and verify
     programs and the megastep are not built for it yet and raise.
+
+    ``arch="granite_hybrid"`` serves the Mamba-2 / attention hybrid block
+    (further sizes of ``models.transformer``'s builder as keywords:
+    ``layer_types``, ``num_kv_heads``, ``mamba_heads``, ``mamba_state``...).
+    Its attention layers alone have K/V pools; every Mamba layer keeps a
+    lane's recurrent state and last convolution columns in per-lane row
+    buffers, float32 whatever ``dtype`` is, written at admission by the same
+    donated program as the pages and advanced by every step that steps the
+    lane. The prefill is told the prompt's length. ``fork`` and ``rollback``
+    raise too: a state that is one row cannot be shared or taken back
+    without a snapshot.
     """
 
     def __init__(self, arg_params: Dict[str, object], vocab_size,
@@ -662,7 +700,7 @@ class PagedKVDecoder:
                  dtype="float32", cache_dir=None, model_key=None,
                  sample_seed=None, arch="vaswani", head_dim=None,
                  num_experts=None, num_experts_per_tok=None,
-                 rope_theta=None, rms_eps=None):
+                 rope_theta=None, rms_eps=None, **arch_sizes):
         from ..models import transformer as _tf
 
         self.arch = arch  # an unknown one is refused by the graph builders
@@ -717,23 +755,37 @@ class PagedKVDecoder:
         # on-disk cache under the old key must not satisfy this one
         key = model_key or "transformer_paged_global_decode"
         binding = dict(ctx=ctx, dtype=dtype, cache_dir=cache_dir)
-        if arch != "vaswani":
+        if arch == "vaswani":
+            if arch_sizes:
+                raise TypeError("PagedKVDecoder: unexpected keywords %s"
+                                % sorted(arch_sizes))
+        else:
             given = dict(head_dim=head_dim, num_experts=num_experts,
                          num_experts_per_tok=num_experts_per_tok,
-                         rope_theta=rope_theta, rms_eps=rms_eps)
+                         rope_theta=rope_theta, rms_eps=rms_eps, **arch_sizes)
             cfg.update(arch=arch, dtype=dtype, **{
                 k: v for k, v in given.items() if v is not None})
             del cfg["pos_len"]
             if model_key is None:  # one architecture never answers for another
                 key += "-" + arch
-            # inputs are float32 whatever the weights are; only the pool
-            # takes the weights' type
+        # what the decode graph keeps between steps, in program order:
+        # (name, "pool" | "row", shape) — pools (heads, slots, dh) addressed
+        # by slot, per-lane rows (lanes,) + shape addressed by lane
+        self._cache = _tf.decode_cache(**dict(cfg, arch=arch))
+        self._cache_names = [name for name, _, _ in self._cache]
+        self._pool_names = [name for name, kind, _ in self._cache
+                            if kind == "pool"]
+        self._has_rows = len(self._pool_names) < len(self._cache)
+        if arch != "vaswani":
+            # inputs are float32 whatever the weights are, a lane's recurrent
+            # state among them; only the pools take the weights' type
             binding.update(dtype="float32", input_dtypes={
-                n: dtype for n in _kv_names(self.num_layers)})
+                n: dtype for n in self._pool_names})
         self._pf_cache = PersistentExecutableCache(
             _tf.get_prefill_symbol(prefill_len=self.prefill_len, **cfg),
             arg_params, {}, model_key=key + "-prefill",
             program_label="mx_prefill", **binding)
+        self._prefill_takes_length = "length" in self._pf_cache.input_names
         self._dec_cache = PersistentExecutableCache(
             _tf.get_decode_symbol(max_len=self.total_slots,
                                   page_size=self.page_size, **cfg),
@@ -760,44 +812,74 @@ class PagedKVDecoder:
             raise MXNetError("paged_kv: %s is not built for arch %r yet"
                              % (what, self.arch))
 
+    def _refuse_rows(self, what):
+        """Sharing or dropping pages says nothing of a lane's recurrent
+        state: it is one row, overwritten at every token, and going back
+        needs a snapshot nobody keeps yet (ROADMAP R6)."""
+        if self._has_rows:
+            raise MXNetError(
+                "paged_kv: %s is not built for arch %r yet: a recurrent "
+                "state cannot be shared or rolled back without a snapshot"
+                % (what, self.arch))
+
     # ------------------------------------------------------------ lifecycle
     def _decode_shapes(self):
-        B, S, H, dh = self.lanes, self.total_slots, self.num_heads, self.dh
+        B, S = self.lanes, self.total_slots
         shapes = {"data": (B, 1), "pos_idx": (B, 1), "write_slot": (B, 1),
                   "page_table": (B, self.pool.frames_per_lane)}
-        for i in range(self.num_layers):
-            shapes["kv_k_%d" % i] = (H, S, dh)
-            shapes["kv_v_%d" % i] = (H, S, dh)
+        for name, kind, shape in self._cache:
+            shapes[name] = (shape[0], S, shape[1]) if kind == "pool" \
+                else (B,) + tuple(shape)
         return shapes
 
-    def warmup(self):
+    def _prefill_shapes(self):
+        """The prefill bucket's inputs: the padded prompt and, where the
+        graph asks for it, the prompt's length (a recurrence reads its
+        padding unless told where the prompt ends)."""
+        shapes = {"data": (1, self.prefill_len)}
+        if self._prefill_takes_length:
+            shapes["length"] = (1, 1)
+        return shapes
+
+    def warmup(self, release_outputs=False):
         """Compile the multiplexed decode executable plus the admit-side
         programs — classically the batch-1 prefill bucket and the donated
         pool update (``_AdmitScatter``), the C-token chunk program when
         the prefix cache is on (chunked admit never touches the prefill
         bucket: cold and cached admits must replay the SAME program for
-        the bitwise parity gate to hold)."""
+        the bitwise parity gate to hold).
+
+        The warm dispatch leaves a whole copy of the cache in the decode
+        executable's outputs, which nothing reads, so the FIRST step holds
+        three copies (its inputs, those, its own outputs) where every later
+        one holds two. ``release_outputs=True`` drops that copy here: what a
+        deployment whose cache fills the chip asks for."""
         if self._warm:
             return self
         self._dec_cache.warmup([self._decode_shapes()])
         self._dec_exe = self._dec_cache.executable(self._decode_shapes())
+        if release_outputs:
+            self._dec_exe.release_outputs()
         self._warm = True
         if _tm.enabled():
             # XLA's own byte count for one decode dispatch, read once here
             # so step() can add it to serving.decode_xla_bytes for free
             self._decode_xla_bytes = int(
                 self._dec_exe.cost_analysis()["bytes accessed"])
+            _tm.gauge("serving.state_bytes").set(sum(
+                4 * self.lanes * int(np.prod(shape))
+                for _, kind, shape in self._cache if kind == "row"))
         if self._prefix is None:
-            self._pf_cache.warmup([{"data": (1, self.prefill_len)}])
+            self._pf_cache.warmup([self._prefill_shapes()])
             self._admit_scatter = _AdmitScatter(self)
             self._admit_scatter.warm(self)
         else:
             self._chunk_for(self.prefix_chunk)
         return self
 
-    def _prefill_kv(self, pf):
-        """The prefill executable's K/V outputs, in the pool's order."""
-        return tuple(o._jax() for o in pf.outputs[1:1 + 2 * self.num_layers])
+    def _prefill_cache(self, pf):
+        """The prefill executable's cache outputs, in the cache's order."""
+        return tuple(o._jax() for o in pf.outputs[1:1 + len(self._cache)])
 
     def stats(self):
         out = {"lanes": self.lanes,
@@ -836,11 +918,9 @@ class PagedKVDecoder:
         src = frame * P + np.arange(P)
         dst = fresh * P + np.arange(P)
         exe = self._dec_exe
-        for i in range(self.num_layers):
-            for tag in ("kv_k_%d" % i, "kv_v_%d" % i):
-                buf = exe.arg_dict[tag]._jax()
-                exe.arg_dict[tag]._set_jax(
-                    buf.at[:, dst, :].set(buf[:, src, :]))
+        for tag in self._pool_names:
+            buf = exe.arg_dict[tag]._jax()
+            exe.arg_dict[tag]._set_jax(buf.at[:, dst, :].set(buf[:, src, :]))
         self.pool.release([frame])
         lane.frames[page] = fresh
         if _tm.enabled():
@@ -938,7 +1018,8 @@ class PagedKVDecoder:
     def _admit_prefill(self, prompt, lane, idx):
         """Classic admit: one batch-1 prefill dispatch, then ONE donated
         program that writes the prompt's K/V into the lane's page frames
-        in place (``_AdmitScatter``)."""
+        and its recurrent state, where it has one, into the lane's row, in
+        place (``_AdmitScatter``)."""
         L = prompt.shape[1]
         # a frame per page of the prompt, acquired before any device work
         for p in range(0, L, self.page_size):
@@ -948,9 +1029,10 @@ class PagedKVDecoder:
         with _tm.span("serving.paged_admit", seq=lane.seq_id,
                       prompt_len=L, lane=idx):
             with _tm.span("serving.admit.stage"):
-                pf = self._pf_cache.executable(
-                    {"data": (1, self.prefill_len)})
+                pf = self._pf_cache.executable(self._prefill_shapes())
                 pf.arg_dict["data"][:] = padded
+                if self._prefill_takes_length:
+                    pf.arg_dict["length"][:] = np.full((1, 1), L, np.float32)
             with _tm.span("serving.admit.prefill"):
                 pf.forward(is_train=False)
             with _tm.span("serving.admit.logits"):
@@ -960,12 +1042,12 @@ class PagedKVDecoder:
             # the pool update stays on the device; only the last
             # position's logits crossed above
             with _tm.span("serving.admit.scatter"):
-                self._admit_scatter.run(self, self._prefill_kv(pf),
-                                        lane.frames, L)
+                self._admit_scatter.run(self, self._prefill_cache(pf),
+                                        lane.frames, L, idx)
         if self.arch == "olmoe" and _tm.enabled():
             # rows each expert received, per layer, over every position the
             # prefill computed (padding included: the grouped matmul's work)
-            load = np.asarray(pf.outputs[1 + 2 * self.num_layers]._jax())
+            load = np.asarray(pf.outputs[1 + len(self._cache)]._jax())
             _tm.counter("serving.moe.assignments").inc(int(load.sum()))
             _tm.counter("serving.moe.max_expert_assignments").inc(
                 int(load.max(axis=1).sum()))
@@ -1104,12 +1186,26 @@ class PagedKVDecoder:
     def position(self, seq_id):
         return self._lanes[self._seq_lane[seq_id]].pos
 
+    def lane_state(self, seq_id, names=None):
+        """{name: array} of what the sequence's lane carries beside its
+        pages: its row of every per-lane buffer of the cache (``names``: of
+        those only), as the last ``admit`` or ``step`` left it. A recurrent
+        model's state; empty where the cache is pools only."""
+        idx = self._seq_lane.get(seq_id)
+        if idx is None:
+            raise MXNetError("paged_kv: unknown seq_id %r" % (seq_id,))
+        self.warmup()
+        return {name: self._dec_exe.arg_dict[name]._jax()[idx]
+                for name, kind, _ in self._cache if kind == "row"
+                and (names is None or name in names)}
+
     # ----------------------------------------------------- fork / rollback
     def fork(self, seq_id):
         """Clone a sequence into a free lane by SHARING every page frame
         at a refcount — zero copy, zero recompute (the parallel-sampling
         idiom). Either side's next write into a shared page triggers its
         private copy-on-write. Returns the clone's seq_id."""
+        self._refuse_rows("fork")
         idx = self._seq_lane.get(seq_id)
         if idx is None:
             raise MXNetError("paged_kv: unknown seq_id %r" % (seq_id,))
@@ -1141,6 +1237,7 @@ class PagedKVDecoder:
         page is kept with its stale tail slots simply excluded from the
         derived valid-slot set. No copy, no device work — this is the
         speculative-decoding reject primitive."""
+        self._refuse_rows("rollback")
         idx = self._seq_lane.get(seq_id)
         if idx is None:
             raise MXNetError("paged_kv: unknown seq_id %r" % (seq_id,))
@@ -1253,12 +1350,15 @@ class PagedKVDecoder:
             _gap_return(self)
             out = {}
             with _tm.span("serving.step.commit"):
-                _swap_kv(exe, self.num_layers)
+                _swap_cache(exe, self._cache_names)
                 for seq_id, idx, lane in stepped:
                     lane.pos += 1
                     out[seq_id] = logits[idx]
             if _tm.enabled():
                 _tm.counter("serving.decode_tokens").inc(len(stepped))
+                # what the stepped lanes attended: position + 1 each
+                _tm.counter("serving.step_context_tokens").inc(
+                    sum(lane.pos for _, _, lane in stepped))
                 _tm.counter("serving.paged_steps").inc()
                 _tm.counter("serving.step_input_bytes").inc(
                     sum(a.nbytes for a in staged.values()))

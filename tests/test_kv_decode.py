@@ -669,9 +669,9 @@ def test_admit_scatter_is_one_sealed_donated_program(tm):
     # a drifted signature is the sealed-program error, before any donation
     held, c0 = _pool(dec), tm.counters()
     pf = dec._pf_cache.executable({"data": (1, dec.prefill_len)})
-    new = dec._prefill_kv(pf)
+    new = dec._prefill_cache(pf)
     with pytest.raises(MXNetError, match="sealed"):
-        prog.run(dec, tuple(a[:, :, :-1] for a in new), [0], 1)
+        prog.run(dec, tuple(a[:, :, :-1] for a in new), [0], 1, 0)
     assert moved(c0)["executor.retrace"] == 1
     assert moved(c0)["serving.admit_scatter_dispatches"] == 0
     assert not any(a.is_deleted() for a in held)

@@ -658,6 +658,8 @@ EXEMPT = {
     "_contrib_KVPoolAttention": "tests/test_kv_pool_ops.py",
     "_contrib_KVPoolWrite": "tests/test_kv_pool_ops.py",
     "_contrib_KVSlotOneHot": "tests/test_kv_pool_ops.py",
+    "_contrib_Mamba2Scan": "tests/test_granite_hybrid_block.py",
+    "_contrib_Mamba2Step": "tests/test_granite_hybrid_block.py",
     "_contrib_MoEFeedForward": "tests/test_olmoe_block.py",
     "_contrib_MultiBoxTarget": "tests/test_vision.py",
     "_contrib_MultiHeadAttention": "tests/test_attention.py",

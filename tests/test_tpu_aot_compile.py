@@ -150,8 +150,9 @@ def test_admit_pool_update_stays_in_place_on_the_chip(v5e):
 
     layers, heads, dh, slots, prefill, page = 6, 8, 64, 64 * 1024, 1024, 16
     prog = _AdmitScatter(SimpleNamespace(
-        num_layers=layers, num_heads=heads, dh=dh, page_size=page,
-        prefill_len=prefill))
+        _cache=[("kv_%s_%d" % (t, i), "pool", (heads, dh))
+                for i in range(layers) for t in "kv"],
+        page_size=page, prefill_len=prefill))
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
@@ -160,7 +161,7 @@ def test_admit_pool_update_stays_in_place_on_the_chip(v5e):
         tuple(spec((heads, slots, dh), "float32") for _ in range(2 * layers)),
         tuple(spec((1, heads, prefill, dh), "float32")
               for _ in range(2 * layers)),
-        spec((prefill // page,), "int32"), spec((), "int32"),
+        spec((prefill // page,), "int32"), spec((2,), "int32"),
     ).compile().memory_analysis()
     assert mem.alias_size_in_bytes == 2 * layers * heads * slots * dh * 4
     assert mem.temp_size_in_bytes < 1 << 20
@@ -298,3 +299,69 @@ def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
         # no temporary of a pool's size (16 x 16,384 x 128 bfloat16 = 67 MB)
         _assert_pool_step_contracts(compiled, 1, lanes, 16, slots, 128,
                                     temp_bytes=48 << 20)
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_granite_hybrid_serving_programs_compile_for_the_chip(v5e, program):
+    """The two graphs ``PagedKVDecoder(arch="granite_hybrid")`` runs, lowered
+    for the v5e at granite-4.0-h-micro's published widths with the first six
+    layers (five Mamba-2, one attention) and the benchmark's serving sizes
+    (32 lanes x 2,048 slots, a 512 bucket, bfloat16 weights and pool,
+    float32 state). The chunked scan and the one-token update are plain
+    ``jax.numpy``: what has to hold on the chip is that neither program
+    copies or transposes a buffer the size of a lane's recurrent state
+    (32 x 64 x 64 x 128 float32 = 67 MB a layer) or of a pool (8 x 65,536 x
+    64 bfloat16 = 67 MB), that the state comes back float32 and the pool in
+    the type it went in, and that a step's temporaries stay small beside the
+    3 GB of cache it rewrites."""
+    from mxnet_tpu.models import transformer as tf
+
+    lanes, max_len, bucket, page, layers = 32, 2048, 512, 16, 6
+    slots = lanes * max_len
+    cfg = dict(arch="granite_hybrid", vocab_size=100352, num_layers=layers,
+               num_heads=32, num_kv_heads=8, head_dim=64, model_dim=2048,
+               ffn_dim=8192, layer_types=["mamba"] * 5 + ["attention"],
+               mamba_heads=64, mamba_head_dim=64, mamba_state=128,
+               mamba_conv=4, mamba_chunk=256, embedding_multiplier=12.0,
+               attention_multiplier=0.015625, residual_multiplier=0.22,
+               logits_scaling=8.0, rms_eps=1e-5, dtype="bfloat16")
+    weights = {n: (s, "bfloat16") for n, s in tf.param_shapes(**cfg).items()}
+    cache = tf.decode_cache(**cfg)
+    assert [kind for _, kind, _ in cache] == ["row"] * 10 + ["pool"] * 2
+    state, pool = lanes * 64 * 64 * 128, 8 * slots * 64
+    if program == "prefill":
+        sym = tf.get_prefill_symbol(prefill_len=bucket, **cfg)
+        inputs = {"data": ((1, bucket), "float32"),
+                  "length": ((1, 1), "float32")}
+        want_types = ["float32"] * 11 + ["bfloat16"] * 2
+    else:
+        sym = tf.get_decode_symbol(max_len=slots, page_size=page, **cfg)
+        inputs = {"data": ((lanes, 1), "float32"),
+                  "pos_idx": ((lanes, 1), "float32"),
+                  "write_slot": ((lanes, 1), "float32"),
+                  "page_table": ((lanes, max_len // page), "float32")}
+        for name, kind, shape in cache:
+            inputs[name] = ((shape[0], slots, shape[1]), "bfloat16") \
+                if kind == "pool" else ((lanes,) + tuple(shape), "float32")
+        want_types = ["float32"] * 11 + ["bfloat16"] * 2 + ["float32"]
+    compiled = _compile_program(v5e, sym, {**weights, **inputs})
+    assert [str(s.dtype) for s in compiled.out_info[0]] == want_types
+    hlo = compiled.as_text()
+    size, moved = {}, []
+    for name, dims, op, _arg in _INSTRUCTION.findall(hlo):
+        size[name] = math.prod(int(d) for d in dims.split(",") if d)
+        if op in ("copy", "transpose"):
+            moved.append(name)
+    assert not [n for n in moved if size[n] >= min(state, pool)]
+    mem = compiled.memory_analysis()
+    if program == "decode":
+        assert compiled.out_info[0][1].shape == (lanes, 64, 64, 128)
+        assert compiled.out_info[0][11].shape == (8, slots, 64)
+        # the scores of one attention layer, 32 x 32 x 65,536 float32 =
+        # 268 MB, and small change; no second copy of any cache buffer
+        assert mem.temp_size_in_bytes < 400 << 20
+    else:
+        # 2 x 512 tokens x (5 x 76.2 M + 60.8 M + 205.5 M) MACs of matrices
+        # and the head, and the chunked scan's products beside them
+        assert 0.66e12 < compiled.cost_analysis()["flops"] < 0.80e12
+        assert mem.temp_size_in_bytes < 400 << 20
