@@ -327,10 +327,25 @@ class Executor:
         return args, aux
 
     def _next_rng(self):
+        """This call's key: drawn from the global stream where the graph has
+        a random node, the process's constant key where it has none (the
+        program ignores it, and a draw is a device dispatch)."""
         from . import random as _random
 
-        self._last_rng = _random._next_key()
+        self._last_rng = _random._next_key() if self._prog._rng_ids \
+            else _random._constant_key()
         return self._last_rng
+
+    def rebind(self, names, arrays):
+        """Hand ``arrays`` on as the arguments ``names``: a buffer that is
+        already the argument's shape and type changes hands by reference
+        (``executor.rebind``), any other value is written as ``a[:] = v``
+        writes it (``executor.rebind_copy``)."""
+        args = self.arg_dict
+        taken = [args[n]._set_jax(a) for n, a in zip(names, arrays)]
+        if _tm.enabled():
+            _tm.counter("executor.rebind").inc(sum(taken))
+            _tm.counter("executor.rebind_copy").inc(len(taken) - sum(taken))
 
     def _set_outputs(self, outs):
         self.outputs = [NDArray(chunk=_Chunk(o, self._ctx), shape=o.shape) for o in outs]

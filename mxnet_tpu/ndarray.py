@@ -111,13 +111,24 @@ class NDArray:
         return d
 
     def _set_jax(self, value):
-        """Write through the chunk (view-aware)."""
+        """Write through the chunk (view-aware). A ``jax.Array`` that is
+        already the whole chunk's shape and (strong) type changes hands by
+        reference (``jax.Array``s are immutable, so two chunks may name
+        one): returns True then, False when the value was shaped, cast or
+        blended in."""
+        import jax
         import jax.numpy as jnp
 
         if not self._writable:
             raise MXNetError("trying to write to a read-only NDArray")
         d = self._chunk.data
         if self._begin is None:
+            if (isinstance(value, jax.Array)
+                    and value.shape == d.shape == self._shape
+                    and value.dtype == d.dtype
+                    and not value.aval.weak_type):
+                self._chunk.data = value
+                return True
             region_shape = d.shape
             new = jnp.broadcast_to(value.reshape(self._shape) if hasattr(value, "reshape") and tuple(getattr(value, "shape", ())) == self._shape else value, self._shape)
             self._chunk.data = jnp.asarray(new).reshape(region_shape).astype(d.dtype)
@@ -125,6 +136,7 @@ class NDArray:
             region = d[self._begin : self._end]
             new = jnp.broadcast_to(value, self._shape).reshape(region.shape).astype(d.dtype)
             self._chunk.data = d.at[self._begin : self._end].set(new)
+        return False
 
     # ------------------------------------------------------------- properties
     @property

@@ -12,6 +12,7 @@ import numpy as np
 __all__ = ["seed", "uniform", "normal"]
 
 _KEY = None
+_CONSTANT_KEY = None
 
 
 def _next_key():
@@ -22,6 +23,18 @@ def _next_key():
         _KEY = jax.random.PRNGKey(np.random.randint(0, 2**31 - 1))
     _KEY, sub = jax.random.split(_KEY)
     return sub
+
+
+def _constant_key():
+    """One key, made once a process, for a program that has no random node
+    and so ignores its key: the same shape and type as a drawn one (no
+    retrace), and the global stream stays where it was."""
+    global _CONSTANT_KEY
+    if _CONSTANT_KEY is None:
+        import jax
+
+        _CONSTANT_KEY = jax.random.PRNGKey(0)
+    return _CONSTANT_KEY
 
 
 def _next_seed() -> int:
@@ -50,7 +63,8 @@ def refresh_backend():
     error. A key whose buffer is unreadable is dropped; the next draw
     re-seeds (weights/optimizer state come from the checkpoint, so RNG
     continuity across a crash is best-effort by design)."""
-    global _KEY
+    global _KEY, _CONSTANT_KEY
+    _CONSTANT_KEY = None  # its buffer is the old backend's too; remade on use
     if _KEY is None:
         return
     import jax.numpy as jnp

@@ -99,8 +99,7 @@ def _swap_cache(exe, names):
     ``exe.outputs[1..]`` still names the same arrays, and they die with the
     next donated update (``_AdmitScatter``), so every reader takes a buffer
     from ``arg_dict`` at the time of use."""
-    for j, name in enumerate(names):
-        exe.arg_dict[name]._set_jax(exe.outputs[1 + j]._jax())
+    exe.rebind(names, [o._jax() for o in exe.outputs[1:1 + len(names)]])
 
 
 # ------------------------------------------------------------------ megastep
@@ -517,13 +516,13 @@ class _AdmitScatter(_SealedProgram):
         # costs the same however small
         out = self._fn(self._live(dec, self.kv_names), new, table,
                        np.array([length, lane], np.int32))
-        args = dec._dec_exe.arg_dict
-        for j in self.pools:
-            args[self.kv_names[j]]._set_jax(out[j])
+        exe = dec._dec_exe
+        exe.rebind([self.kv_names[j] for j in self.pools],
+                   [out[j] for j in self.pools])
         if self.rows:
             with _tm.span("serving.admit.state", buffers=len(self.rows)):
-                for j in self.rows:
-                    args[self.kv_names[j]]._set_jax(out[j])
+                exe.rebind([self.kv_names[j] for j in self.rows],
+                           [out[j] for j in self.rows])
         return out
 
     def run(self, dec, new, frames, length, lane):
@@ -918,9 +917,10 @@ class PagedKVDecoder:
         src = frame * P + np.arange(P)
         dst = fresh * P + np.arange(P)
         exe = self._dec_exe
+        # a buffer at a time: its old copy may die before the next is made
         for tag in self._pool_names:
             buf = exe.arg_dict[tag]._jax()
-            exe.arg_dict[tag]._set_jax(buf.at[:, dst, :].set(buf[:, src, :]))
+            exe.rebind([tag], [buf.at[:, dst, :].set(buf[:, src, :])])
         self.pool.release([frame])
         lane.frames[page] = fresh
         if _tm.enabled():
@@ -1099,8 +1099,7 @@ class PagedKVDecoder:
                 out = np.asarray(logits)[:n]
         _gap_return(self)
         if write:
-            for name, arr in zip(prog.kv_names, new_kvs):
-                self._dec_exe.arg_dict[name]._set_jax(arr)
+            self._dec_exe.rebind(prog.kv_names, new_kvs)
         return out
 
     def _admit_chunked(self, prompt, lane):
@@ -1336,9 +1335,7 @@ class PagedKVDecoder:
                               (idx, lane) for _, idx, lane in stepped)}
                 # ONE batched transfer: a host-to-device copy of a few KB
                 # costs the host 0.2 ms whatever its size
-                for name, arr in zip(staged,
-                                     jax.device_put(list(staged.values()))):
-                    exe.arg_dict[name]._set_jax(arr)
+                exe.rebind(staged, jax.device_put(list(staged.values())))
             _gap_mark(self, "serving.paged_step")
             with _tm.span("serving.decode_step", rows=len(stepped),
                           paged=True):
@@ -1431,8 +1428,7 @@ class PagedKVDecoder:
                 ids = np.asarray(toks)       # (K, B): the only host pull
                 acts_h = np.asarray(acts)
         _gap_return(self)
-        for name, arr in zip(ms.kv_names, new_kvs):
-            self._dec_exe.arg_dict[name]._set_jax(arr)
+        self._dec_exe.rebind(ms.kv_names, new_kvs)
         out = {}
         written = 0
         for seq_id, idx, lane, tok in stepped:
