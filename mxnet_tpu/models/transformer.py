@@ -8,7 +8,7 @@ is mean/var composed from broadcast ops to stay faithful to the op set)."""
 from .. import symbol as sym
 from ..base import MXNetError
 
-ARCHS = ("vaswani", "olmoe", "granite_hybrid")
+ARCHS = ("vaswani", "olmoe", "granite_hybrid", "deepseek_v3")
 
 
 def _refuse_arch(arch, what):
@@ -280,14 +280,19 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     logits come the cache's values in ``decode_cache`` order: a Mamba layer's
     recurrent state at ``length`` and its last convolution columns, float32,
     an attention layer's K and V (B, Hkv, P, dh).
+
+    ``arch="deepseek_v3"`` builds the latent-attention block
+    (``_deepseek_v3_layer``) in its MATERIALISED form: every head's key and
+    value are made of the latent and attended densely. After the logits
+    comes ONE tensor a layer, the normed latent beside the rotated shared
+    key, (B, 1, P, kv_lora_rank + qk_rope_head_dim): all the cache keeps;
+    then ``moe_load (expert layers, experts)`` as for ``olmoe``.
     """
-    if arch == "olmoe":
-        return _olmoe_prefill_symbol(
-            vocab_size=vocab_size, num_layers=num_layers,
-            num_heads=num_heads, model_dim=model_dim, ffn_dim=ffn_dim,
-            prefill_len=prefill_len, **kwargs)
-    if arch == "granite_hybrid":
-        return _granite_prefill_symbol(
+    builders = {"olmoe": _olmoe_prefill_symbol,
+                "granite_hybrid": _granite_prefill_symbol,
+                "deepseek_v3": _deepseek_v3_prefill_symbol}
+    if arch in builders:
+        return builders[arch](
             vocab_size=vocab_size, num_layers=num_layers,
             num_heads=num_heads, model_dim=model_dim, ffn_dim=ffn_dim,
             prefill_len=prefill_len, **kwargs)
@@ -444,13 +449,21 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     tells it which lanes ride along (their rows come back bit for bit). The
     cache outputs follow the logits in ``decode_cache`` order.
 
+    ``arch="deepseek_v3"`` runs ``_deepseek_v3_layer`` in its ABSORBED form
+    over ONE pool a layer, ``kv_c_i`` (1, max_len, kv_lora_rank +
+    qk_rope_head_dim): a lane's row is its normed latent beside its rotated
+    shared key, no key or value of a head is ever made, and after the cache
+    outputs and the token head comes ``moe_load (expert layers, experts)``,
+    the rows each expert received from ALL the lanes of the step.
+
     ``page_size`` is the decoder's (``PagedKVDecoder``'s default here); it
     must divide ``max_len``.
     """
-    if arch in ("olmoe", "granite_hybrid"):
-        build = _olmoe_decode_symbol if arch == "olmoe" \
-            else _granite_decode_symbol
-        return build(
+    builders = {"olmoe": _olmoe_decode_symbol,
+                "granite_hybrid": _granite_decode_symbol,
+                "deepseek_v3": _deepseek_v3_decode_symbol}
+    if arch in builders:
+        return builders[arch](
             vocab_size=vocab_size, num_layers=num_layers,
             num_slots=max_len, page_size=page_size, num_heads=num_heads,
             model_dim=model_dim, ffn_dim=ffn_dim, token_out=token_out,
@@ -660,6 +673,18 @@ def _mamba_core(op, i, xbc, dt, block, **inputs):
               **inputs)
 
 
+def _gated_mlp(fc, h, width, out_width, tag):
+    """The gated SiLU MLP on h (B, T, M): ``fc(h, width, tag)`` is the
+    layer's bias-free projection; gate and up rows live in ONE matrix
+    ``<tag>_in`` (gate rows first), ``silu(gate) * up`` goes through
+    ``<tag>_out``."""
+    ab = fc(h, 2 * width, tag + "_in")
+    gated = sym.Activation(sym.slice_axis(ab, axis=2, begin=0, end=width),
+                           act_type="silu") \
+        * sym.slice_axis(ab, axis=2, begin=width, end=2 * width)
+    return fc(gated, out_width, tag + "_out")
+
+
 def _granite_layer(x, i, seq_len, attend, scan, block):
     """One Granite 4.0-H block on x (B, T, M): pre-norm RMSNorm, a mixer
     chosen by ``layer_types[i]``, then the gated SiLU MLP every layer has,
@@ -704,12 +729,8 @@ def _granite_layer(x, i, seq_len, attend, scan, block):
         mixed = fc(_merge_heads(attend(i, q, k, v), seq_len, hq * dh), d,
                    "proj")
     x = x + mixed * res
-    ffn = block["ffn_dim"]
-    ab = fc(sym.RMSNorm(x, eps=eps, name="%s_ln2" % name), 2 * ffn, "mlp_in")
-    gated = sym.Activation(sym.slice_axis(ab, axis=2, begin=0, end=ffn),
-                           act_type="silu") \
-        * sym.slice_axis(ab, axis=2, begin=ffn, end=2 * ffn)
-    return x + fc(gated, d, "mlp_out") * res
+    return x + _gated_mlp(fc, sym.RMSNorm(x, eps=eps, name="%s_ln2" % name),
+                          block["ffn_dim"], d, "mlp") * res
 
 
 def _granite_stack(data, vocab_size, num_layers, seq_len, attend, scan, block):
@@ -783,6 +804,214 @@ def _granite_decode_symbol(vocab_size, num_layers, num_slots, page_size,
     return _token_head(logits, cache, "greedy_token" if token_out else None)
 
 
+# --------------------------------------------------- DeepSeek-V3 (latent attention)
+def _deepseek_v3_sizes(num_layers, num_heads, model_dim, ffn_dim=None,
+                       moe_ffn_dim=None, num_experts=64,
+                       num_experts_per_tok=6, num_shared_experts=1,
+                       first_dense_layers=1, qk_nope_head_dim=128,
+                       qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=512,
+                       rope_theta=10000.0, rms_eps=1e-6,
+                       routed_scaling_factor=1.0, norm_topk_prob=True,
+                       **kwargs):
+    """``_deepseek_v3_layer``'s keywords from a builder's (``ffn_dim`` is the
+    leading dense layers' width, ``moe_ffn_dim`` one expert's; keywords of
+    the other architectures are dropped)."""
+    if not 0 <= first_dense_layers <= num_layers:
+        raise MXNetError("deepseek_v3: first_dense_layers %d outside [0, %d]"
+                         % (first_dense_layers, num_layers))
+    if qk_rope_head_dim % 2:
+        raise MXNetError("deepseek_v3: qk_rope_head_dim %d is odd"
+                         % qk_rope_head_dim)
+    return dict(
+        num_layers=num_layers, num_heads=num_heads, model_dim=model_dim,
+        ffn_dim=ffn_dim, moe_ffn_dim=moe_ffn_dim, num_experts=num_experts,
+        num_experts_per_tok=num_experts_per_tok,
+        num_shared_experts=num_shared_experts,
+        first_dense_layers=first_dense_layers, nope=qk_nope_head_dim,
+        rope=qk_rope_head_dim, v_dim=v_head_dim, latent=kv_lora_rank,
+        rope_theta=float(rope_theta), rms_eps=rms_eps,
+        routed_scaling_factor=float(routed_scaling_factor),
+        norm_topk_prob=bool(norm_topk_prob),
+        # the published softmax_scale: over the WHOLE query-key width
+        scale=float(qk_nope_head_dim + qk_rope_head_dim) ** -0.5)
+
+
+def _deepseek_v3_layer(x, i, positions, seq_len, attend, block):
+    """One ``model_type: deepseek_v3`` block (no query-side low-rank
+    projection) on x (B, T, M) -> (x', load (E,) or None for a dense layer).
+
+    Latent attention: a bias-free query projection to H heads of
+    [q_nope | q_rope]; ONE bias-free projection to [c | k_r], the latent
+    (normed, ``kvnorm``) and a single rotary key every head shares; rotary
+    positions over interleaved pairs on q_rope and k_r. ``attend(i, q_nope,
+    q_rope, c, k_r)`` is the one thing the prefill and the decode graph do
+    differently: it takes (B, H, T, nope), (B, H, T, rope), (B, T, latent)
+    and (B, 1, T, rope) and returns attention's (B, H, T, v_dim) output,
+    through ``layer<i>_kvb_weight`` (H x [k_nope | v] rows over the latent)
+    either materialised into keys and values or absorbed into the query and
+    the output. Then the feed-forward: the gated SiLU MLP in the first
+    ``first_dense_layers`` layers; after them sigmoid-routed experts
+    (selected on the biased score, weighted by the unbiased one,
+    renormalised, scaled) beside a shared gated MLP every token takes."""
+    name = "layer%d" % i
+    d, eps, hq = block["model_dim"], block["rms_eps"], block["num_heads"]
+    nope, rope, lat = block["nope"], block["rope"], block["latent"]
+    fc = lambda data, width, tag: sym.FullyConnected(
+        data=data, num_hidden=width, no_bias=True, flatten=False,
+        name="%s_%s" % (name, tag))
+    rotate = lambda a, tag: sym.RotaryEmbedding(
+        a, positions, base=block["rope_theta"], interleaved=True,
+        name="%s_%s" % (name, tag))
+    h = sym.RMSNorm(x, eps=eps, name="%s_ln1" % name)
+    q = _split_heads(fc(h, hq * (nope + rope), "q"), seq_len, hq, nope + rope)
+    kva = fc(h, lat + rope, "kva")
+    c = sym.RMSNorm(sym.slice_axis(kva, axis=2, begin=0, end=lat), eps=eps,
+                    name="%s_kvnorm" % name)
+    k_r = sym.Reshape(sym.slice_axis(kva, axis=2, begin=lat, end=lat + rope),
+                      shape=(-1, 1, seq_len, rope))
+    att = attend(i, sym.slice_axis(q, axis=3, begin=0, end=nope),
+                 rotate(sym.slice_axis(q, axis=3, begin=nope,
+                                       end=nope + rope), "qrope"),
+                 c, rotate(k_r, "krope"))
+    x = x + fc(_merge_heads(att, seq_len, hq * block["v_dim"]), d, "proj")
+    h = sym.RMSNorm(x, eps=eps, name="%s_ln2" % name)
+    if i < block["first_dense_layers"]:
+        return x + _gated_mlp(fc, h, block["ffn_dim"], d, "mlp"), None
+    moe = sym.MoEFeedForward(
+        sym.Reshape(h, shape=(-1, d)),
+        *(sym.Variable("%s_%s" % (name, w)) for w in (
+            "router_weight", "experts_gate_weight", "experts_up_weight",
+            "experts_down_weight", "router_bias")),
+        num_experts=block["num_experts"], num_hidden=block["moe_ffn_dim"],
+        num_experts_per_tok=block["num_experts_per_tok"], scoring="sigmoid",
+        router_bias=True, norm_topk_prob=block["norm_topk_prob"],
+        routed_scaling_factor=block["routed_scaling_factor"],
+        name="%s_moe" % name)
+    shared = _gated_mlp(
+        fc, h, block["num_shared_experts"] * block["moe_ffn_dim"], d,
+        "shared")
+    return x + sym.Reshape(moe[0], shape=(-1, seq_len, d)) + shared, moe[1]
+
+
+def _deepseek_v3_stack(vocab_size, seq_len, positions, attend, block):
+    """Embedding, the layers, final norm and untied head: ``data`` (B, T) ->
+    (float32 logits (B·T, vocab), moe_load (expert layers, experts))."""
+    x = sym.Embedding(data=sym.Variable("data"), input_dim=vocab_size,
+                      output_dim=block["model_dim"], name="embed")
+    loads = []
+    for i in range(block["num_layers"]):
+        x, load = _deepseek_v3_layer(x, i, positions, seq_len, attend, block)
+        if load is not None:
+            loads.append(sym.Reshape(load, shape=(1, -1)))
+    logits = _olmoe_head(x, vocab_size, block["model_dim"], block["rms_eps"])
+    return logits, [sym.Concat(*loads, dim=0, name="moe_load")] if loads \
+        else []
+
+
+def _deepseek_v3_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
+    block = _deepseek_v3_sizes(num_layers, **sizes)
+    hq, nope, v_dim, lat = (block[k] for k in ("num_heads", "nope", "v_dim",
+                                               "latent"))
+    positions = sym.Reshape(sym._arange(start=0, stop=prefill_len),
+                            shape=(1, prefill_len))
+    cache = []
+
+    def attend(i, q_nope, q_rope, c, k_r):
+        # what the cache keeps, as a pool's rows: one head of [c | k_r]
+        cache.append(sym.Concat(
+            sym.Reshape(c, shape=(-1, 1, prefill_len, lat)), k_r, dim=3))
+        # materialised: every head's key and value, made of the latent
+        kv = _split_heads(sym.FullyConnected(
+            data=c, num_hidden=hq * (nope + v_dim), no_bias=True,
+            flatten=False, name="layer%d_kvb" % i), prefill_len, hq,
+            nope + v_dim)
+        key = sym.Concat(sym.slice_axis(kv, axis=3, begin=0, end=nope),
+                         sym.broadcast_axis(k_r, axis=1, size=hq), dim=3)
+        return sym.MultiHeadAttention(
+            query=sym.Concat(q_nope, q_rope, dim=3), key=key,
+            value=sym.slice_axis(kv, axis=3, begin=nope, end=nope + v_dim),
+            causal=True, scale=block["scale"], name="layer%d_att" % i)
+
+    logits, load = _deepseek_v3_stack(vocab_size, prefill_len, positions,
+                                      attend, block)
+    return sym.Group([logits] + cache + load)
+
+
+def _deepseek_v3_decode_symbol(vocab_size, num_layers, num_slots, page_size,
+                               token_out=True, **sizes):
+    block = _deepseek_v3_sizes(num_layers, **sizes)
+    hq, nope, rope, v_dim, lat = (block[k] for k in (
+        "num_heads", "nope", "rope", "v_dim", "latent"))
+    pos_idx = sym.Variable("pos_idx")
+    oh, msk = _pool_step_inputs(pos_idx, num_slots, page_size)
+    cache = []
+
+    def attend(i, q_nope, q_rope, c, k_r):
+        # one token a lane: its row of the latent pool is [c | k_r]
+        row = sym.Concat(sym.Reshape(c, shape=(-1, 1, lat)),
+                         sym.Reshape(k_r, shape=(-1, 1, rope)), dim=2)
+        pool = sym.KVPoolWrite(sym.Variable("kv_c_%d" % i), row, oh,
+                               name="layer%d_cupd" % i)
+        cache.append(pool)
+        # absorbed: a head's rows of kvb are [Wuk_h | Wuv_h] over the
+        # latent; Wuk goes into the query, Wuv onto the context, and no
+        # key or value of a head is ever made
+        w = sym.Reshape(sym.Variable("layer%d_kvb_weight" % i,
+                                     shape=(hq * (nope + v_dim), lat)),
+                        shape=(hq, nope + v_dim, lat))
+        by_head = lambda a, width: sym.SwapAxis(
+            sym.Reshape(a, shape=(-1, hq, width)), dim1=0, dim2=1)
+        q_lat = sym.batch_dot(by_head(q_nope, nope),
+                              sym.slice_axis(w, axis=1, begin=0, end=nope))
+        query = sym.Concat(sym.SwapAxis(q_lat, dim1=0, dim2=1),
+                           sym.Reshape(q_rope, shape=(-1, hq, rope)), dim=2)
+        # the pool is key (all its columns) and value (its first ``lat``)
+        ctx = sym.KVPoolAttention(query, pool, pool, msk, scale=block["scale"],
+                                  value_dim=lat, name="layer%d_att" % i)
+        out = sym.batch_dot(
+            by_head(ctx, lat),
+            sym.slice_axis(w, axis=1, begin=nope, end=nope + v_dim),
+            transpose_b=True)
+        return sym.Reshape(sym.SwapAxis(out, dim1=0, dim2=1),
+                           shape=(-1, hq, 1, v_dim))
+
+    logits, load = _deepseek_v3_stack(vocab_size, 1, pos_idx, attend, block)
+    outs = [logits] + cache
+    if token_out:
+        outs.append(sym.argmax(logits, axis=-1, name="greedy_token"))
+    return sym.Group(outs + load)
+
+
+def _deepseek_v3_param_shapes(vocab_size, num_layers, **sizes):
+    block = _deepseek_v3_sizes(num_layers, **sizes)
+    d, hq, e = block["model_dim"], block["num_heads"], block["num_experts"]
+    nope, rope, v_dim, lat = (block[k] for k in ("nope", "rope", "v_dim",
+                                                 "latent"))
+    f = block["moe_ffn_dim"]
+    shapes = {"embed_weight": (vocab_size, d), "final_ln_gamma": (d,),
+              "lm_head_weight": (vocab_size, d)}
+    for i in range(num_layers):
+        n = "layer%d_" % i
+        shapes.update({
+            n + "ln1_gamma": (d,), n + "q_weight": (hq * (nope + rope), d),
+            n + "kva_weight": (lat + rope, d), n + "kvnorm_gamma": (lat,),
+            n + "kvb_weight": (hq * (nope + v_dim), lat),
+            n + "proj_weight": (d, hq * v_dim), n + "ln2_gamma": (d,)})
+        if i < block["first_dense_layers"]:
+            shapes.update({n + "mlp_in_weight": (2 * block["ffn_dim"], d),
+                           n + "mlp_out_weight": (d, block["ffn_dim"])})
+            continue
+        shared = block["num_shared_experts"] * f
+        shapes.update({
+            n + "router_weight": (e, d), n + "router_bias": (e,),
+            n + "experts_gate_weight": (e, d, f),
+            n + "experts_up_weight": (e, d, f),
+            n + "experts_down_weight": (e, f, d),
+            n + "shared_in_weight": (2 * shared, d),
+            n + "shared_out_weight": (d, shared)})
+    return shapes
+
+
 def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
                  **sizes):
     """What a decode graph of ``arch`` keeps between steps, in the order its
@@ -790,7 +1019,13 @@ def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
     ``[(name, kind, shape)]``. A ``"pool"`` is addressed by slot, ``shape``
     is (heads, dh) and the buffer (heads, slots, dh); a ``"row"`` is
     addressed by lane, ``shape`` is one lane's and the buffer (lanes,) +
-    shape, float32."""
+    shape, float32. Latent attention keeps ONE pool a layer, of one head:
+    the old kind, no new one."""
+    if arch == "deepseek_v3":
+        block = _deepseek_v3_sizes(num_layers, num_heads=num_heads,
+                                   model_dim=model_dim, **sizes)
+        return [("kv_c_%d" % i, "pool", (1, block["latent"] + block["rope"]))
+                for i in range(num_layers)]
     if arch != "granite_hybrid":
         pool = (num_heads, head_dim or model_dim // num_heads)
         return [("kv_%s_%d" % (t, i), "pool", pool)
@@ -842,15 +1077,19 @@ def _granite_param_shapes(vocab_size, num_layers, **sizes):
 def param_shapes(arch, vocab_size, num_layers, num_heads, model_dim, ffn_dim,
                  head_dim=None, num_experts=64, **kwargs):
     """{name: shape} of the checkpoint the serving graphs of ``arch`` load,
-    from the sizes alone (``olmoe`` and ``granite_hybrid``: the Vaswani
+    from the sizes alone (every arch of ``ARCHS`` but ``vaswani``, whose
     checkpoint is spelled where it always was, by its drivers and tests)."""
     if arch == "granite_hybrid":
         return _granite_param_shapes(
             vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
             ffn_dim=ffn_dim, head_dim=head_dim, **kwargs)
+    if arch == "deepseek_v3":
+        return _deepseek_v3_param_shapes(
+            vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
+            ffn_dim=ffn_dim, num_experts=num_experts, **kwargs)
     if arch != "olmoe":
-        raise MXNetError("param_shapes knows archs 'olmoe' and "
-                         "'granite_hybrid', not %r" % (arch,))
+        raise MXNetError("param_shapes knows archs %s, not %r" % (
+            ", ".join(repr(a) for a in ARCHS if a != "vaswani"), arch))
     d = model_dim
     width = num_heads * (head_dim or d // num_heads)
     shapes = {"embed_weight": (vocab_size, d), "final_ln_gamma": (d,),

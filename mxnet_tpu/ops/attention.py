@@ -51,7 +51,9 @@ def _multi_head_attention(attrs, query, key, value):
     query heads j * H/Hkv .. (j + 1) * H/Hkv - 1. The group is an axis of the
     query that both contractions carry (the keys are never repeated), of
     size 1 where the head counts are equal. Fewer key/value heads take the
-    dense path only."""
+    dense path only. ``value`` may be narrower or wider than ``key`` (latent
+    attention's 128 under a 192-wide key): the output takes the value's
+    width, on the dense path."""
     import os
 
     b, h, t, d = query.shape
@@ -97,7 +99,7 @@ def _multi_head_attention(attrs, query, key, value):
         s = jnp.where(mask, s, -jnp.inf)
     out = jnp.einsum("bkgqu,bkud->bkgqd", jax.nn.softmax(s, axis=-1),
                      value.astype("float32"))
-    return out.reshape(b, h, t, d).astype(query.dtype)
+    return out.reshape(b, h, t, value.shape[-1]).astype(query.dtype)
 
 
 def _kv_groups(heads, kv_heads, what):
@@ -125,7 +127,8 @@ def _rms_norm(attrs, data, gamma):
 
 @register(
     "_contrib_RotaryEmbedding",
-    attrs={"base": AttrSpec("float", default=10000.0)},
+    attrs={"base": AttrSpec("float", default=10000.0),
+           "interleaved": AttrSpec("bool", default=False)},
     input_names=("data", "positions"),
     aliases=("RotaryEmbedding",),
 )
@@ -133,11 +136,19 @@ def _rotary_embedding(attrs, data, positions):
     """Rotary position embedding of ``data`` (B, H, T, dh) at ``positions``
     (B, T), which are DATA (a decode step's lanes each sit at their own):
     the half-split rotation (``rotate_half``: feature i pairs with
-    i + dh/2), ``inv_freq_i = base^(-2i/dh)``. Angles, sine and cosine are
-    float32 whatever the IO dtype."""
+    i + dh/2), ``inv_freq_i = base^(-2i/dh)``. ``interleaved`` pairs
+    feature 2i with 2i + 1 instead (``rope_interleave`` of
+    ``model_type: deepseek_v3``), same frequencies. Angles, sine and cosine
+    are float32 whatever the IO dtype."""
     dh = data.shape[-1]
     inv_freq = attrs["base"] ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
     angle = positions.astype(jnp.float32)[:, None, :, None] * inv_freq
+    if attrs.get("interleaved"):
+        x = data.astype(jnp.float32).reshape(data.shape[:-1] + (dh // 2, 2))
+        x1, x2 = x[..., 0], x[..., 1]
+        cos, sin = jnp.cos(angle), jnp.sin(angle)
+        y = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+        return y.reshape(data.shape).astype(data.dtype)
     cos = jnp.concatenate([jnp.cos(angle), jnp.cos(angle)], axis=-1)
     sin = jnp.concatenate([jnp.sin(angle), jnp.sin(angle)], axis=-1)
     x = data.astype(jnp.float32)
@@ -174,7 +185,8 @@ def _kv_pool_write(attrs, pool, rows, onehot):
 
 @register(
     "_contrib_KVPoolAttention",
-    attrs={"scale": AttrSpec("float", default=-1.0)},
+    attrs={"scale": AttrSpec("float", default=-1.0),
+           "value_dim": AttrSpec("int", default=0)},
     input_names=("query", "pool_k", "pool_v", "mask"),
     aliases=("KVPoolAttention",),
 )
@@ -190,7 +202,14 @@ def _kv_pool_attention(attrs, query, pool_k, pool_v, mask):
     row's maximum first. A pool of fewer heads (Hkv, S, dh) than the
     query's serves them in groups, as ``MultiHeadAttention`` does: the group
     is an axis of the query that both contractions carry (size 1 where the
-    counts are equal), so the pool is read once and never repeated."""
+    counts are equal), so the pool is read once and never repeated.
+
+    ``value_dim`` > 0 takes the value from the first ``value_dim`` columns
+    of ``pool_v``, which may then BE ``pool_k``: a latent cache keeps one
+    row a token, [c | k_r], that is the key whole and the value in its
+    first columns. The context is contracted over the whole row and cut
+    after, so the pool is one operand of both matmuls and is never sliced
+    into a copy. The output is (R, H, value width) either way."""
     scale = attrs["scale"] if attrs["scale"] > 0 \
         else 1.0 / np.sqrt(query.shape[-1])
     r, h, dh = query.shape
@@ -202,7 +221,9 @@ def _kv_pool_attention(attrs, query, pool_k, pool_v, mask):
         s * scale + mask.astype(jnp.float32)[:, None, None, :], axis=-1)
     out = jnp.einsum("rkgs,ksd->rkgd", p, pool_v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(r, h, dh).astype(query.dtype)
+    if attrs.get("value_dim", 0) > 0:
+        out = out[..., :attrs["value_dim"]]
+    return out.reshape(r, h, out.shape[-1]).astype(query.dtype)
 
 
 @register(

@@ -189,10 +189,11 @@ register_meta("_contrib_KVPoolAttention",
               dtype_policy="first", aliases=("KVPoolAttention",))
 register_meta("_contrib_MoEFeedForward",
               input_ranks={"data": 2, "router_weight": 2, "gate_weight": 3,
-                           "up_weight": 3, "down_weight": 3},
+                           "up_weight": 3, "down_weight": 3,
+                           "router_bias": 1},
               dtype_policy="first",
               param_slots=("router_weight", "gate_weight", "up_weight",
-                           "down_weight"),
+                           "down_weight", "router_bias"),
               aliases=("MoEFeedForward",))
 _MAMBA2_WEIGHTS = {"conv_weight": 2, "conv_bias": 1, "dt_bias": 1, "A_log": 1,
                    "D": 1}

@@ -12,7 +12,17 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..base import MXNetError
 from .registry import AttrSpec, register
+
+_SCORES = {"softmax": lambda z: jax.nn.softmax(z, axis=-1),
+           "sigmoid": jax.nn.sigmoid}
+
+
+def _moe_names(attrs):
+    names = ["data", "router_weight", "gate_weight", "up_weight",
+             "down_weight"]
+    return names + ["router_bias"] if attrs.get("router_bias") else names
 
 
 @register(
@@ -21,19 +31,29 @@ from .registry import AttrSpec, register
         "num_experts": AttrSpec("int", required=True),
         "num_hidden": AttrSpec("int", required=True),
         "num_experts_per_tok": AttrSpec("int", required=True),
+        "scoring": AttrSpec("str", default="softmax"),
+        "router_bias": AttrSpec("bool", default=False),
+        "norm_topk_prob": AttrSpec("bool", default=False),
+        "routed_scaling_factor": AttrSpec("float", default=1.0),
     },
-    input_names=("data", "router_weight", "gate_weight", "up_weight",
-                 "down_weight"),
+    input_names=_moe_names,
     num_outputs=2,
     output_names=("output", "load"),
     aliases=("MoEFeedForward",),
 )
 def _moe_feed_forward(attrs, data, router_weight, gate_weight, up_weight,
-                      down_weight):
+                      down_weight, router_bias=None):
     """``y = sum_{e in top-k} p_e * down_e(silu(gate_e x) * (up_e x))`` for
     every row x of ``data`` (N, D), with ``p = softmax(x router^T)`` over ALL
     experts and NOT renormalised over the chosen k (OLMoE's
-    ``norm_topk_prob: false``). ``router_weight`` is (E, D); the experts'
+    ``norm_topk_prob: false``): the defaults. ``scoring="sigmoid"`` scores
+    every expert on its own; ``router_bias=True`` takes a sixth input
+    ``router_bias`` (E,) that is added to the scores for the SELECTION alone
+    (``e_score_correction_bias`` of ``topk_method: noaux_tc``, one group):
+    the weights stay the unbiased scores of the chosen; ``norm_topk_prob``
+    divides them by their sum over the chosen (+ 1e-20) and
+    ``routed_scaling_factor`` multiplies them. ``router_weight`` is (E, D);
+    the experts'
     matrices are stored (in, out) — ``gate_weight``/``up_weight`` (E, D, F),
     ``down_weight`` (E, F, D) — which is what ``ragged_dot``'s
     (group, k, n) operand takes without a transpose. Returns ``(y (N, D),
@@ -45,10 +65,22 @@ def _moe_feed_forward(attrs, data, router_weight, gate_weight, up_weight,
     expert products multiply in the storage type and accumulate in float32."""
     k, n_exp = attrs["num_experts_per_tok"], attrs["num_experts"]
     n = data.shape[0]
-    probs = jax.nn.softmax(
+    scoring = attrs.get("scoring", "softmax")
+    if scoring not in _SCORES:
+        raise MXNetError("MoEFeedForward: scoring %r is not one of %s"
+                         % (scoring, sorted(_SCORES)))
+    probs = _SCORES[scoring](
         jnp.dot(data.astype(jnp.float32), router_weight.astype(jnp.float32).T,
-                precision=jax.lax.Precision.HIGHEST), axis=-1)
-    weight, expert = jax.lax.top_k(probs, k)                # (N, k) each
+                precision=jax.lax.Precision.HIGHEST))
+    if router_bias is None:
+        weight, expert = jax.lax.top_k(probs, k)            # (N, k) each
+    else:
+        _, expert = jax.lax.top_k(probs + router_bias.astype(jnp.float32), k)
+        weight = jnp.take_along_axis(probs, expert, axis=-1)
+    if attrs.get("norm_topk_prob"):
+        weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
+    if attrs.get("routed_scaling_factor", 1.0) != 1.0:
+        weight = weight * attrs["routed_scaling_factor"]
     expert = expert.reshape(-1)
     order = jnp.argsort(expert, stable=True)                # rows by expert
     load = jnp.bincount(expert, length=n_exp).astype(jnp.int32)

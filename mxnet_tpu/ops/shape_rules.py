@@ -154,7 +154,8 @@ def _moe(attrs, shapes):
     data = shapes[0]
     if data is not None:
         e, f, d = attrs["num_experts"], attrs["num_hidden"], data[-1]
-        for i, s in enumerate(((e, d), (e, d, f), (e, d, f), (e, f, d)), 1):
+        slots = ((e, d), (e, d, f), (e, d, f), (e, f, d), (e,))
+        for i, s in enumerate(slots[:len(shapes) - 1], 1):   # router_bias last
             if shapes[i] is None:
                 shapes[i] = s
     return shapes

@@ -92,6 +92,15 @@ def _gap_return(dec):
         dec._last_return_t = time.perf_counter()
 
 
+def _moe_load_at(symbol):
+    """Where a graph of sparse experts reports the rows each expert
+    received, ``moe_load (layers, experts)``: the output's index, or None
+    for a graph that has none."""
+    outs = symbol.list_outputs()
+    return outs.index("moe_load_output") if "moe_load_output" in outs \
+        else None
+
+
 def _swap_cache(exe, names):
     """Hand the updated cache buffers (program outputs, in the cache's order
     after the logits) back as the next dispatch's inputs — device-side
@@ -780,15 +789,17 @@ class PagedKVDecoder:
             # state among them; only the pools take the weights' type
             binding.update(dtype="float32", input_dtypes={
                 n: dtype for n in self._pool_names})
+        prefill = _tf.get_prefill_symbol(prefill_len=self.prefill_len, **cfg)
+        decode = _tf.get_decode_symbol(max_len=self.total_slots,
+                                       page_size=self.page_size, **cfg)
+        self._pf_moe_load = _moe_load_at(prefill)
+        self._dec_moe_load = _moe_load_at(decode)
         self._pf_cache = PersistentExecutableCache(
-            _tf.get_prefill_symbol(prefill_len=self.prefill_len, **cfg),
-            arg_params, {}, model_key=key + "-prefill",
+            prefill, arg_params, {}, model_key=key + "-prefill",
             program_label="mx_prefill", **binding)
         self._prefill_takes_length = "length" in self._pf_cache.input_names
         self._dec_cache = PersistentExecutableCache(
-            _tf.get_decode_symbol(max_len=self.total_slots,
-                                  page_size=self.page_size, **cfg),
-            arg_params, {}, model_key=key + "-decode",
+            decode, arg_params, {}, model_key=key + "-decode",
             program_label="mx_decode", **binding)
         self._dec_exe = None
         self._decode_xla_bytes = None  # read at warmup when telemetry is on
@@ -868,6 +879,11 @@ class PagedKVDecoder:
             _tm.gauge("serving.state_bytes").set(sum(
                 4 * self.lanes * int(np.prod(shape))
                 for _, kind, shape in self._cache if kind == "row"))
+            latent = [self._dec_exe.arg_dict[name]._jax().nbytes
+                      for name in self._pool_names
+                      if name.startswith("kv_c_")]
+            if latent:  # one pool a layer: a token's latent, not its heads
+                _tm.gauge("serving.latent_pool_bytes").set(sum(latent))
         if self._prefix is None:
             self._pf_cache.warmup([self._prefill_shapes()])
             self._admit_scatter = _AdmitScatter(self)
@@ -1044,10 +1060,10 @@ class PagedKVDecoder:
             with _tm.span("serving.admit.scatter"):
                 self._admit_scatter.run(self, self._prefill_cache(pf),
                                         lane.frames, L, idx)
-        if self.arch == "olmoe" and _tm.enabled():
+        if self._pf_moe_load is not None and _tm.enabled():
             # rows each expert received, per layer, over every position the
             # prefill computed (padding included: the grouped matmul's work)
-            load = np.asarray(pf.outputs[1 + len(self._cache)]._jax())
+            load = np.asarray(pf.outputs[self._pf_moe_load]._jax())
             _tm.counter("serving.moe.assignments").inc(int(load.sum()))
             _tm.counter("serving.moe.max_expert_assignments").inc(
                 int(load.max(axis=1).sum()))
@@ -1362,6 +1378,14 @@ class PagedKVDecoder:
                 if self._decode_xla_bytes:
                     _tm.counter("serving.decode_xla_bytes").inc(
                         self._decode_xla_bytes)
+                if self._dec_moe_load is not None:
+                    # rows each expert received from ALL the step's lanes
+                    # (those that ride along pass through the experts too)
+                    load = exe.outputs[self._dec_moe_load].asnumpy()
+                    _tm.counter("serving.moe.step_assignments").inc(
+                        int(load.sum()))
+                    _tm.counter("serving.moe.step_experts_touched").inc(
+                        int(np.count_nonzero(load)))
                 _tm.gauge("decode.tokens_per_dispatch").set(len(stepped))
                 _tm.gauge("serving.paged_pages_in_use").set(self.pool.in_use)
             return out

@@ -327,3 +327,65 @@ def test_symbols_infer_a_steps_inputs_from_the_row_count():
     _, types, _ = msk.infer_type(page_table="float32", pos_idx="float32",
                                  write_slot="float32")
     assert [np.dtype(t) for t in types] == [np.dtype("float32")]
+
+
+# ------------------------- a value of another width than the key (PR 32)
+def _plain_attention(q, k, v, scale, mask):
+    """softmax(q k^T * scale + mask) v in float32 ``jax.numpy``; q (..., T,
+    dk), k (..., S, dk), v (..., S, dv), mask broadcast over the scores."""
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    scores = jnp.einsum("...td,...sd->...ts", q, k) * scale + mask
+    return jnp.einsum("...ts,...sd->...td", jax.nn.softmax(scores, axis=-1),
+                      v)
+
+
+@pytest.mark.parametrize("dk,dv,hkv", [(24, 16, 4), (24, 16, 1), (16, 24, 2),
+                                       (16, 16, 4)])
+def test_dense_attention_takes_its_output_width_from_the_value(dk, dv, hkv):
+    """``MultiHeadAttention`` with a value narrower (latent attention: 128
+    under a 192-wide key) or wider than the key, equal and grouped heads,
+    against plain ``jax.numpy``; float32 both sides, order of the sums only."""
+    rs = np.random.RandomState(dk + dv + hkv)
+    b, h, t = 2, 4, 7
+    q = jnp.asarray(rs.randn(b, h, t, dk), jnp.float32)
+    k = jnp.asarray(rs.randn(b, hkv, t, dk), jnp.float32)
+    v = jnp.asarray(rs.randn(b, hkv, t, dv), jnp.float32)
+    got = mx.nd.MultiHeadAttention(*(mx.nd.NDArray(a) for a in (q, k, v)),
+                                   causal=True, scale=0.3).asnumpy()
+    assert got.shape == (b, h, t, dv)
+    causal = jnp.where(jnp.tril(jnp.ones((t, t), bool)), 0.0, -jnp.inf)
+    rep = lambda a: jnp.repeat(a, h // hkv, axis=1)
+    want = _plain_attention(q, rep(k), rep(v), 0.3, causal)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("heads", [1, 4])
+def test_pool_attention_reads_a_value_that_is_a_prefix_of_the_key(
+        heads, dtype, tol):
+    """``KVPoolAttention(q, pool, pool, mask, value_dim=)``: ONE pool of one
+    head whose row is the key whole and the value in its first columns (a
+    latent cache's [c | k_r]), ``heads`` query heads on it, ``scale`` given;
+    against plain ``jax.numpy`` on the sliced pool. bfloat16: the pool and
+    the query are bfloat16-valued on both sides, what differs is the
+    probabilities' one bfloat16 rounding in the second contraction (2^-9)."""
+    rs = np.random.RandomState(heads)
+    r, s, dk, dv = 5, 48, 24, 16
+    q = jnp.asarray(rs.randn(r, heads, dk), dtype)
+    pool = jnp.asarray(rs.randn(1, s, dk), dtype)
+    mask = jnp.asarray(np.where(rs.rand(r, s) < 0.5, 0.0, -1e9), jnp.float32)
+    mask = mask.at[:, 0].set(0.0)       # no row is masked whole
+    got = _kv_pool_attention({"scale": 0.25, "value_dim": dv}, q, pool, pool,
+                             mask)
+    assert got.shape == (r, heads, dv) and got.dtype == q.dtype
+    want = _plain_attention(q.transpose(1, 0, 2), pool, pool[..., :dv], 0.25,
+                            mask[None]).transpose(1, 0, 2)
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               rtol=tol, atol=tol)
+    # without value_dim the pool's whole row is the value, as it always was
+    whole = _kv_pool_attention({"scale": 0.25, "value_dim": 0}, q, pool,
+                               pool, mask)
+    assert whole.shape == (r, heads, dk)
+    np.testing.assert_allclose(np.asarray(whole, np.float32)[..., :dv],
+                               np.asarray(got, np.float32), rtol=tol,
+                               atol=tol)

@@ -365,3 +365,65 @@ def test_granite_hybrid_serving_programs_compile_for_the_chip(v5e, program):
         # and the head, and the chunked scan's products beside them
         assert 0.66e12 < compiled.cost_analysis()["flops"] < 0.80e12
         assert mem.temp_size_in_bytes < 400 << 20
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_latent_attention_serving_programs_compile_for_the_chip(v5e, program):
+    """The two graphs ``PagedKVDecoder(arch="deepseek_v3")`` runs, lowered for
+    the v5e at kanana-2-30b-a3b's published widths with the dense layer and
+    the first two expert layers and the benchmark's serving sizes (32 lanes
+    x 2,048 slots, a 1,024 bucket, bfloat16 weights and latent pool). What
+    has to hold on the chip: the cache is ONE (1, 65,536, 576) pool a layer
+    that comes back in the type it went in, the step makes no key or value
+    of a head (32 heads x 65,536 slots x 128 would be 268 MB in bfloat16, a
+    layer and kind), its temporaries are one layer's float32 scores (32 x
+    32 x 65,536 = 268 MB) and small change, and both programs keep the
+    grouped matmul and report the experts' load last."""
+    from mxnet_tpu.models import transformer as tf
+
+    lanes, max_len, bucket, page, layers = 32, 2048, 1024, 16, 3
+    slots = lanes * max_len
+    cfg = dict(arch="deepseek_v3", vocab_size=128256, num_layers=layers,
+               num_heads=32, model_dim=2048, ffn_dim=6144, moe_ffn_dim=768,
+               num_experts=128, num_experts_per_tok=6, num_shared_experts=2,
+               first_dense_layers=1, qk_nope_head_dim=128,
+               qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=512,
+               rope_theta=1e6, rms_eps=1e-6, routed_scaling_factor=2.448,
+               norm_topk_prob=True, dtype="bfloat16")
+    weights = {n: (s, "bfloat16") for n, s in tf.param_shapes(**cfg).items()}
+    cache = tf.decode_cache(**cfg)
+    assert cache == [("kv_c_%d" % i, "pool", (1, 576)) for i in range(layers)]
+    if program == "prefill":
+        sym = tf.get_prefill_symbol(prefill_len=bucket, **cfg)
+        inputs = {"data": ((1, bucket), "float32")}
+        want = [((bucket, 128256), "float32")] \
+            + [((1, 1, bucket, 576), "bfloat16")] * layers \
+            + [((layers - 1, 128), "float32")]
+    else:
+        sym = tf.get_decode_symbol(max_len=slots, page_size=page, **cfg)
+        inputs = {"data": ((lanes, 1), "float32"),
+                  "pos_idx": ((lanes, 1), "float32"),
+                  "write_slot": ((lanes, 1), "float32"),
+                  "page_table": ((lanes, max_len // page), "float32")}
+        inputs.update({name: ((1, slots, 576), "bfloat16")
+                       for name, _, _ in cache})
+        want = [((lanes, 128256), "float32")] \
+            + [((1, slots, 576), "bfloat16")] * layers \
+            + [((lanes,), "float32"), ((layers - 1, 128), "float32")]
+    compiled = _compile_program(v5e, sym, {**weights, **inputs})
+    assert [(s.shape, str(s.dtype)) for s in compiled.out_info[0]] == want
+    hlo = compiled.as_text()
+    assert "ragged" in hlo.lower()
+    mem = compiled.memory_analysis()
+    if program == "decode":
+        heads_wide = 32 * slots * 128
+        sizes = [math.prod(int(d) for d in dims.split(",") if d)
+                 for _n, dims, _op, _a in _INSTRUCTION.findall(hlo)]
+        assert not [n for n in sizes if n >= heads_wide
+                    and n != lanes * 32 * slots]    # only the scores are
+        assert mem.temp_size_in_bytes < 400 << 20
+        # 2 x (32 x 32 x 65,536 x (576 + 576) the latent read, a layer) and
+        # the matrices of 32 rows: the head 8.4 G, little else
+        assert 0.45e12 < compiled.cost_analysis()["flops"] < 0.60e12
+    else:
+        assert mem.temp_size_in_bytes < 400 << 20
