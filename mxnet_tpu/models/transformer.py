@@ -303,36 +303,42 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     return sym.Group([logits] + kvs)
 
 
-def _pool_attend(i, q, k_new, v_new, onehot, mask, kv_outs, **attrs):
+def _pool_attend(i, q, k_new, v_new, onehot, read, kv_outs, **attrs):
     """Layer ``i``'s write into and read of the ONE shared KV pool, on rows
     (N, H, dh): each row's new K/V lands in its one-hot slot of ``kv_k_i`` /
     ``kv_v_i`` (H, slots, dh), which come back in the type they went in and
-    are collected in ``kv_outs``; then each row reads the whole updated pool
-    under its own additive float32 mask (``attrs``: a ``scale`` other than
-    1/sqrt(dh)). Returns the context (N, H, dh)."""
+    are collected in ``kv_outs``; then each row reads the updated pool as
+    ``read`` says, ``KVPoolAttention``'s operands after the pools by name:
+    its additive float32 ``mask`` (N, slots) and, in a decode step, what the
+    mask was made of (``_pool_step_inputs``). ``attrs``: a ``scale`` other
+    than 1/sqrt(dh). Returns the context (N, H, dh)."""
     upd = [sym.KVPoolWrite(sym.Variable("kv_%s_%d" % (tag, i)), new, onehot,
                            name="layer%d_%supd" % (i, tag))
            for tag, new in (("k", k_new), ("v", v_new))]
     kv_outs += upd
-    return sym.KVPoolAttention(q, upd[0], upd[1], mask,
-                               name="layer%d_att" % i, **attrs)
+    return sym.KVPoolAttention(q, upd[0], upd[1], name="layer%d_att" % i,
+                               **read, **attrs)
 
 
 def _pool_step_inputs(pos_idx, num_slots, page_size, write_slot=None):
-    """``_pool_attend``'s one-hots and masks for a decode step, made ON THE
+    """``_pool_attend``'s one-hots and read for a decode step, made ON THE
     DEVICE, once in front of the layers, from what the host knows of a lane:
     ``write_slot`` (B, 1), the pool slot its token lands in (negative: the
     lane rides along, writes nothing and sees nothing), and ``page_table``
     (B, pages a lane), the frames of its pages in order. With ``pos_idx``
-    that is the lane's whole context (``KVPageMask``). A graph that reads
-    ``write_slot`` elsewhere too hands its Variable in."""
+    that is the lane's whole context: the read is its mask over the pool
+    (``KVPageMask``) beside the three it is made of, so ``KVPoolAttention``
+    may read a lane's own pages instead. A graph that reads ``write_slot``
+    elsewhere too hands its Variable in."""
     if write_slot is None:
         write_slot = sym.Variable("write_slot")
+    pages = dict(page_table=sym.Variable("page_table"), pos_idx=pos_idx,
+                 write_slot=write_slot)
     return (sym.KVSlotOneHot(write_slot, num_slots=num_slots,
                              name="slot_onehot"),
-            sym.KVPageMask(sym.Variable("page_table"), pos_idx, write_slot,
-                           page_size=page_size, num_slots=num_slots,
-                           name="kv_mask"))
+            dict(pages, page_size=page_size, mask=sym.KVPageMask(
+                page_size=page_size, num_slots=num_slots, name="kv_mask",
+                **pages)))
 
 
 def _token_head(logits, kv_outs, token_name):
@@ -350,11 +356,11 @@ def _pool_rows_symbol(vocab_size, num_layers, num_heads, model_dim, ffn_dim,
     the lanes of a decode step (``data`` (B, 1), ``seq_len`` 1) or the
     positions of one lane's chunk (``data`` (1, T), ``seq_len`` T); either
     way ``pos_idx`` has ``data``'s shape and ``pool_inputs(pos_idx)`` gives
-    the rows' one-hots and masks, (N, slots) each."""
+    the rows' one-hots (N, slots) and ``_pool_attend``'s ``read``."""
     dh = model_dim // num_heads
     data = sym.Variable("data")
     pos_idx = sym.Variable("pos_idx")
-    oh, msk = pool_inputs(pos_idx)
+    oh, read = pool_inputs(pos_idx)
     emb = sym.Embedding(data=data, input_dim=vocab_size,
                         output_dim=model_dim, name="embed")
     posrow = sym.Embedding(data=pos_idx, input_dim=pos_len,
@@ -364,7 +370,7 @@ def _pool_rows_symbol(vocab_size, num_layers, num_heads, model_dim, ffn_dim,
 
     def attend(i, qkv):
         q, k_new, v_new = _split_rows(qkv, 3, num_heads, dh)
-        ctx = _pool_attend(i, q, k_new, v_new, oh, msk, kv_outs)
+        ctx = _pool_attend(i, q, k_new, v_new, oh, read, kv_outs)
         return sym.Reshape(ctx, shape=(-1, seq_len, model_dim))
 
     for i in range(num_layers):
@@ -406,7 +412,11 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
         shared prefix page a refcount instead of a copy (docs/SERVING.md
         §Prefix cache). Attention over slots is order-agnostic (positions
         live in the embeddings), so a lane's tokens may occupy ANY frames —
-        what the allocator's non-contiguous placement relies on.
+        what the allocator's non-contiguous placement relies on. The same
+        three inputs reach every layer's ``KVPoolAttention`` beside the
+        mask, which may then gather a lane's own frames and never read the
+        mask: the operator's choice, from its operands' shapes
+        (``ops.attention.pool_read_own_pages``).
       - ``kv_k_i`` / ``kv_v_i`` (H, max_len, dh) per layer: the pool. The
         updated buffers are program OUTPUTS; the caller swaps them back in
         as the next step's inputs (``PagedKVDecoder`` does).
@@ -517,7 +527,7 @@ def get_chunk_symbol(vocab_size=32000, num_layers=6, num_heads=8,
         vocab_size, num_layers, num_heads, model_dim, ffn_dim, pos_len,
         seq_len=int(chunk_len),
         pool_inputs=lambda pos: (sym.Variable("write_onehot"),
-                                 sym.Variable("att_mask")),
+                                 dict(mask=sym.Variable("att_mask"))),
         token_name="chunk_token" if token_out else None)
 
 
@@ -611,7 +621,7 @@ def _olmoe_decode_symbol(vocab_size, num_layers, num_slots, page_size,
                                                    "model_dim"))
     data = sym.Variable("data")
     pos_idx = sym.Variable("pos_idx")
-    oh, msk = _pool_step_inputs(pos_idx, num_slots, page_size)
+    oh, read = _pool_step_inputs(pos_idx, num_slots, page_size)
     kv_outs = []
 
     def attend(i, q, k_new, v_new):
@@ -619,7 +629,7 @@ def _olmoe_decode_symbol(vocab_size, num_layers, num_slots, page_size,
         # pool's rows (B, H, dh)
         k_new, v_new, q = (sym.Reshape(a, shape=(-1, num_heads, dh))
                            for a in (k_new, v_new, q))
-        ctx = _pool_attend(i, q, k_new, v_new, oh, msk, kv_outs)
+        ctx = _pool_attend(i, q, k_new, v_new, oh, read, kv_outs)
         return sym.Reshape(ctx, shape=(-1, num_heads, 1, dh))
 
     x = sym.Embedding(data=data, input_dim=vocab_size, output_dim=model_dim,
@@ -778,7 +788,7 @@ def _granite_decode_symbol(vocab_size, num_layers, num_slots, page_size,
     hq, hkv, dh = (block[k] for k in ("num_heads", "num_kv_heads", "head_dim"))
     pos_idx = sym.Variable("pos_idx")
     write_slot = sym.Variable("write_slot")
-    oh, msk = _pool_step_inputs(pos_idx, num_slots, page_size, write_slot)
+    oh, read = _pool_step_inputs(pos_idx, num_slots, page_size, write_slot)
     cache = []      # the layers are built in order, so is this
 
     def attend(i, q, k_new, v_new):
@@ -786,7 +796,7 @@ def _granite_decode_symbol(vocab_size, num_layers, num_slots, page_size,
         # pool's rows (B, H, dh)
         q, k_new, v_new = (sym.Reshape(a, shape=(-1, n, dh)) for a, n in
                            ((q, hq), (k_new, hkv), (v_new, hkv)))
-        ctx = _pool_attend(i, q, k_new, v_new, oh, msk, cache,
+        ctx = _pool_attend(i, q, k_new, v_new, oh, read, cache,
                            scale=block["attention_multiplier"])
         return sym.Reshape(ctx, shape=(-1, hq, 1, dh))
 
@@ -943,7 +953,7 @@ def _deepseek_v3_decode_symbol(vocab_size, num_layers, num_slots, page_size,
     hq, nope, rope, v_dim, lat = (block[k] for k in (
         "num_heads", "nope", "rope", "v_dim", "latent"))
     pos_idx = sym.Variable("pos_idx")
-    oh, msk = _pool_step_inputs(pos_idx, num_slots, page_size)
+    oh, read = _pool_step_inputs(pos_idx, num_slots, page_size)
     cache = []
 
     def attend(i, q_nope, q_rope, c, k_r):
@@ -966,8 +976,9 @@ def _deepseek_v3_decode_symbol(vocab_size, num_layers, num_slots, page_size,
         query = sym.Concat(sym.SwapAxis(q_lat, dim1=0, dim2=1),
                            sym.Reshape(q_rope, shape=(-1, hq, rope)), dim=2)
         # the pool is key (all its columns) and value (its first ``lat``)
-        ctx = sym.KVPoolAttention(query, pool, pool, msk, scale=block["scale"],
-                                  value_dim=lat, name="layer%d_att" % i)
+        ctx = sym.KVPoolAttention(query, pool, pool, scale=block["scale"],
+                                  value_dim=lat, name="layer%d_att" % i,
+                                  **read)
         out = sym.batch_dot(
             by_head(ctx, lat),
             sym.slice_axis(w, axis=1, begin=nope, end=nope + v_dim),

@@ -183,44 +183,135 @@ def _kv_pool_write(attrs, pool, rows, onehot):
     return pool * keep[None, :, None] + written.transpose(1, 0, 2)
 
 
+def pool_read_own_pages(query, pool_k, pool_v, page_table, page_size):
+    """Whether ``KVPoolAttention`` gathers each row's own pages (True) or
+    scores the whole pool (False): the smaller count of the bytes either
+    form moves through the chip's memory, from the operands' shapes and
+    types alone. Each operand carries ``.shape`` and ``.dtype``: ``query``
+    (R, H, dh), the pools (Hkv, S, d) (``pool_v`` None where the value is
+    read from the key's pool), ``page_table`` (R, max_pages) or None for a
+    read that was handed no table, which scores the whole pool.
+
+    Whole: the pools once and the float32 scores, R x H x S, three times
+    (written, read by the softmax, read by the context). Own pages: the
+    pools re-laid from slots-minor to rows (read and written), then a copy
+    of R x Hkv x max_pages x page_size rows, the minor dimension padded to
+    the chip's 128 lanes, written by the gather and read by it and by both
+    contractions, and the small scores. So the gather pays where the pool is
+    small beside its scores (one wide latent row read by every head) and
+    loses where a 64-wide row pads to twice its size and R x max_pages x
+    page_size is the whole pool again."""
+    if page_table is None or page_size < 1:
+        return False
+    rows, heads = query.shape[:2]
+    own_slots = page_table.shape[1] * page_size
+    pool_bytes = copy_bytes = 0
+    for pool in (pool_k,) if pool_v is None else (pool_k, pool_v):
+        hkv, _, d = pool.shape
+        size = jnp.dtype(pool.dtype).itemsize
+        pool_bytes += int(np.prod(pool.shape)) * size
+        copy_bytes += rows * hkv * own_slots * -(-d // 128) * 128 * size
+    whole = pool_bytes + 3 * 4 * rows * heads * pool_k.shape[1]
+    own = 2 * pool_bytes + 4 * copy_bytes + 3 * 4 * rows * heads * own_slots
+    return own < whole
+
+
+def _pool_softmax_context(scores, scale, mask, values, contraction):
+    """``softmax(scores * scale + mask)`` in float32, then the context's
+    contraction with a float32 accumulator: what both forms of the pool's
+    read share."""
+    p = jax.nn.softmax(scores * scale + mask, axis=-1)
+    return jnp.einsum(contraction, p, values,
+                      preferred_element_type=jnp.float32)
+
+
+def _context_slots(pos_idx, write_slot):
+    """A row's context in slots, (R,) int32: ``pos + 1`` where it writes
+    (its own slot included), none where its write slot is negative."""
+    return jnp.where(write_slot.reshape(-1) >= 0,
+                     pos_idx.reshape(-1).astype(jnp.int32) + 1, 0)
+
+
+def _own_pages(pool, table, page):
+    """``pool`` (Hkv, S, d) at the frames ``table`` (R, max_pages) names:
+    (Hkv, R, max_pages * page, d), a row's pages in order. The host's table
+    is in bounds (frames, zeros past them), so nothing is clipped or
+    filled: the default mode adds a ``select`` over the whole copy."""
+    hkv, slots, d = pool.shape
+    rows, max_pages = table.shape
+    own = pool.reshape(hkv, slots // page, page, d).at[:, table].get(
+        mode="promise_in_bounds")
+    return own.reshape(hkv, rows, max_pages * page, d)
+
+
 @register(
     "_contrib_KVPoolAttention",
     attrs={"scale": AttrSpec("float", default=-1.0),
-           "value_dim": AttrSpec("int", default=0)},
-    input_names=("query", "pool_k", "pool_v", "mask"),
+           "value_dim": AttrSpec("int", default=0),
+           "page_size": AttrSpec("int", default=0)},
+    input_names=lambda attrs: ("query", "pool_k", "pool_v", "mask") + (
+        ("page_table", "pos_idx", "write_slot")
+        if attrs.get("page_size", 0) > 0 else ()),
     aliases=("KVPoolAttention",),
 )
-def _kv_pool_attention(attrs, query, pool_k, pool_v, mask):
+def _kv_pool_attention(attrs, query, pool_k, pool_v, mask, page_table=None,
+                       pos_idx=None, write_slot=None):
     """The read of the shared KV pool: every row of ``query`` (R, H, dh)
-    attends the whole of ``pool_k`` / ``pool_v`` (H, S, dh) under its own
-    additive ``mask`` (R, S): ``softmax(einsum('rhd,hsd->rhs') * scale +
-    mask)`` then ``einsum('rhs,hsd->rhd')``. Both contractions run on the
-    matrix unit at the default matmul precision (what
-    ``_multi_head_attention`` gives the same tokens in the prefill) with a
-    float32 accumulator, and the softmax is float32 whatever the pool's
-    dtype. A fully masked row comes out finite: the softmax subtracts the
-    row's maximum first. A pool of fewer heads (Hkv, S, dh) than the
-    query's serves them in groups, as ``MultiHeadAttention`` does: the group
-    is an axis of the query that both contractions carry (size 1 where the
-    counts are equal), so the pool is read once and never repeated.
+    attends ``pool_k`` / ``pool_v`` (H, S, dh) under its own additive
+    ``mask`` (R, S): ``softmax(einsum('rhd,hsd->rhs') * scale + mask)`` then
+    ``einsum('rhs,hsd->rhd')``. Both contractions run on the matrix unit at
+    the default matmul precision (what ``_multi_head_attention`` gives the
+    same tokens in the prefill) with a float32 accumulator, and the softmax
+    is float32 whatever the pool's dtype. A fully masked row comes out
+    finite: the softmax subtracts the row's maximum first. A pool of fewer
+    heads (Hkv, S, dh) than the query's serves them in groups, as
+    ``MultiHeadAttention`` does: the group is an axis of the query that both
+    contractions carry (size 1 where the counts are equal), so the pool is
+    read once and never repeated.
 
     ``value_dim`` > 0 takes the value from the first ``value_dim`` columns
     of ``pool_v``, which may then BE ``pool_k``: a latent cache keeps one
     row a token, [c | k_r], that is the key whole and the value in its
     first columns. The context is contracted over the whole row and cut
     after, so the pool is one operand of both matmuls and is never sliced
-    into a copy. The output is (R, H, value width) either way."""
+    into a copy. The output is (R, H, value width) either way.
+
+    ``page_size`` > 0 hands the read what ``mask`` was made of (a decode
+    step's ``KVPageMask``): ``page_table`` (R, max_pages), ``pos_idx`` and
+    ``write_slot`` (R, 1). The read may then take only the frames a row's
+    table names: it gathers them into (Hkv, R, max_pages * page_size, dh),
+    scores and contracts row by row over those slots, the first ``pos + 1``
+    of them live and none where the write slot is negative, and never reads
+    ``mask`` (a program none of whose reads does builds none). The same
+    mathematics in the same types: a slot outside a row's context weighs
+    exactly 0 in both forms, so they differ by the order of a float32 sum.
+    ``pool_read_own_pages`` chooses, from the shapes and types of the
+    operands; no caller does."""
     scale = attrs["scale"] if attrs["scale"] > 0 \
         else 1.0 / np.sqrt(query.shape[-1])
     r, h, dh = query.shape
     hkv = pool_k.shape[0]
     q = query.reshape(r, hkv, _kv_groups(h, hkv, "KVPoolAttention"), dh)
-    s = jnp.einsum("rkgd,ksd->rkgs", q, pool_k,
-                   preferred_element_type=jnp.float32)
-    p = jax.nn.softmax(
-        s * scale + mask.astype(jnp.float32)[:, None, None, :], axis=-1)
-    out = jnp.einsum("rkgs,ksd->rkgd", p, pool_v,
-                     preferred_element_type=jnp.float32)
+    page = attrs.get("page_size", 0)
+    shared = pool_v is pool_k
+    if pool_read_own_pages(query, pool_k, None if shared else pool_v,
+                           page_table, page):
+        table = page_table.astype(jnp.int32)
+        own_k = _own_pages(pool_k, table, page)
+        own_v = own_k if shared else _own_pages(pool_v, table, page)
+        live = jnp.arange(own_k.shape[2], dtype=jnp.int32) \
+            < _context_slots(pos_idx, write_slot)[:, None]
+        s = jnp.einsum("rkgd,krud->rkgu", q, own_k,
+                       preferred_element_type=jnp.float32)
+        out = _pool_softmax_context(
+            s, scale, jnp.where(live, jnp.float32(0), _NEG)[:, None, None, :],
+            own_v, "rkgu,krud->rkgd")
+    else:
+        s = jnp.einsum("rkgd,ksd->rkgs", q, pool_k,
+                       preferred_element_type=jnp.float32)
+        out = _pool_softmax_context(
+            s, scale, mask.astype(jnp.float32)[:, None, None, :], pool_v,
+            "rkgs,ksd->rkgd")
     if attrs.get("value_dim", 0) > 0:
         out = out[..., :attrs["value_dim"]]
     return out.reshape(r, h, out.shape[-1]).astype(query.dtype)
@@ -269,8 +360,7 @@ def _kv_page_mask(attrs, page_table, pos_idx, write_slot):
         raise MXNetError("KVPageMask: page_size %d must divide num_slots %d"
                          % (page, slots))
     rows, max_pages = page_table.shape
-    n = jnp.where(write_slot.reshape(rows) >= 0,
-                  pos_idx.reshape(rows).astype(jnp.int32) + 1, 0)
+    n = _context_slots(pos_idx, write_slot)
     # context slots in each of the row's pages: a full page, the last one's
     # remainder, none past it
     held = jnp.clip(

@@ -185,7 +185,8 @@ register_meta("_contrib_KVPoolWrite",
               input_ranks={"pool": 3, "rows": 3, "onehot": 2},
               dtype_policy="first", aliases=("KVPoolWrite",))
 register_meta("_contrib_KVPoolAttention",
-              input_ranks={"query": 3, "pool_k": 3, "pool_v": 3, "mask": 2},
+              input_ranks={"query": 3, "pool_k": 3, "pool_v": 3, "mask": 2,
+                           "page_table": 2, "pos_idx": 2, "write_slot": 2},
               dtype_policy="first", aliases=("KVPoolAttention",))
 register_meta("_contrib_MoEFeedForward",
               input_ranks={"data": 2, "router_weight": 2, "gate_weight": 3,

@@ -139,7 +139,8 @@ def _kv_pool_write(attrs, shapes):
 @rule("_contrib_KVPoolAttention")
 @rule("KVPoolAttention")
 def _kv_pool_attention(attrs, shapes):
-    query, pool_k, pool_v, mask = shapes    # (R, H, dh), 2 x (H, S, dh), (R, S)
+    # (R, H, dh), 2 x (H, S, dh), (R, S); a step's table and rows are bound
+    query, pool_k, pool_v, mask = shapes[:4]
     pool = pool_k or pool_v
     if pool is not None:
         shapes[1] = shapes[2] = pool

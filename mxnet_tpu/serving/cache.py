@@ -148,9 +148,10 @@ class PersistentExecutableCache:
         with self._lock:
             return list(self._exes)
 
-    def _infer_full(self, input_shapes):
+    def _infer_full(self, input_shapes, symbol=None):
         """Full static shape/type inference at these input shapes (the
-        param/aux hints come from the checkpoint) — no bind, no compile."""
+        param/aux hints come from the checkpoint) — no bind, no compile.
+        ``symbol``: entries of the graph to infer instead of its outputs."""
         from ..base import np_dtype
 
         shapes = {n: tuple(s) for n, s in input_shapes.items()}
@@ -164,7 +165,7 @@ class PersistentExecutableCache:
             types[n] = np.dtype(getattr(v, "dtype", self._dtype)).name
         for n in shapes:
             types.setdefault(n, self._input_dtypes.get(n, self._dtype))
-        return self._sym._infer_impl(
+        return (symbol or self._sym)._infer_impl(
             shapes, {k: np_dtype(v) for k, v in types.items()},
             partial=False)
 

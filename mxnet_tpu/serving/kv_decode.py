@@ -101,6 +101,37 @@ def _moe_load_at(symbol):
         else None
 
 
+def _pool_read_slots(cache, input_shapes):
+    """What one dispatch of a decode program reads of its pools, a layer:
+    ``[(own pages?, slots scored)]`` over the graph's ``KVPoolAttention``
+    nodes at these input shapes. The form is the operator's own rule
+    (``pool_read_own_pages``) asked of each node's operands as inferred,
+    which is what it is asked of when the program is traced."""
+    import jax
+
+    from ..ops.attention import pool_read_own_pages
+    from ..symbol import Symbol
+
+    reads = [n for n in cache._sym._topo()
+             if n.op == "_contrib_KVPoolAttention"]
+    if not reads:
+        return []
+    res = cache._infer_full(
+        input_shapes, Symbol([e for n in reads for e in n.inputs]))
+    structs = (jax.ShapeDtypeStruct(s, t) for s, t in zip(res[1], res[4]))
+    out = []
+    for n in reads:
+        attrs = n.parsed_attrs()
+        ops = dict(zip(n.opdef().input_names(attrs), structs))
+        page, table = attrs.get("page_size", 0), ops.get("page_table")
+        own = pool_read_own_pages(
+            ops["query"], ops["pool_k"],
+            None if n.inputs[1] == n.inputs[2] else ops["pool_v"], table, page)
+        out.append((own, ops["query"].shape[0] * (
+            table.shape[1] * page if own else ops["pool_k"].shape[1])))
+    return out
+
+
 def _swap_cache(exe, names):
     """Hand the updated cache buffers (program outputs, in the cache's order
     after the logits) back as the next dispatch's inputs — device-side
@@ -803,6 +834,7 @@ class PagedKVDecoder:
             program_label="mx_decode", **binding)
         self._dec_exe = None
         self._decode_xla_bytes = None  # read at warmup when telemetry is on
+        self._step_gathered_slots = 0  # likewise: slots a dispatch scores
         self._lanes: Dict[int, _Lane] = {}   # lane index -> _Lane
         self._seq_lane: Dict[int, int] = {}  # seq_id -> lane index
         self._next_seq = 0
@@ -876,6 +908,14 @@ class PagedKVDecoder:
             # so step() can add it to serving.decode_xla_bytes for free
             self._decode_xla_bytes = int(
                 self._dec_exe.cost_analysis()["bytes accessed"])
+            reads = _pool_read_slots(self._dec_cache, self._decode_shapes())
+            _tm.gauge("serving.pool_read.own_pages_layers").set(
+                sum(own for own, _ in reads))
+            _tm.gauge("serving.pool_read.whole_pool_layers").set(
+                sum(not own for own, _ in reads))
+            # a layer's: the mean over the program's reads, which are alike
+            self._step_gathered_slots = \
+                sum(slots for _, slots in reads) // max(len(reads), 1)
             _tm.gauge("serving.state_bytes").set(sum(
                 4 * self.lanes * int(np.prod(shape))
                 for _, kind, shape in self._cache if kind == "row"))
@@ -1373,6 +1413,8 @@ class PagedKVDecoder:
                 _tm.counter("serving.step_context_tokens").inc(
                     sum(lane.pos for _, _, lane in stepped))
                 _tm.counter("serving.paged_steps").inc()
+                _tm.counter("serving.step_gathered_slots").inc(
+                    self._step_gathered_slots)
                 _tm.counter("serving.step_input_bytes").inc(
                     sum(a.nbytes for a in staged.values()))
                 if self._decode_xla_bytes:

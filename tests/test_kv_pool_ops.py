@@ -9,6 +9,9 @@ read sums the same float32 products in another order.
 And the two operators that make a decode step's one-hots and masks on the
 device (``KVSlotOneHot``, ``KVPageMask``) against the arrays
 ``PagedKVDecoder.step`` used to build on the host, element for element.
+
+And the read's second form, a row's own pages gathered by its table, against
+the whole-pool read under the mask made of the same table.
 """
 import functools
 
@@ -20,8 +23,10 @@ import jax.numpy as jnp
 
 import mxnet_tpu as mx
 from mxnet_tpu.base import MXNetError
+from mxnet_tpu.ops import attention
 from mxnet_tpu.ops.attention import (_kv_page_mask, _kv_pool_attention,
-                                     _kv_pool_write, _kv_slot_onehot)
+                                     _kv_pool_write, _kv_slot_onehot,
+                                     pool_read_own_pages)
 
 H, S, DH = 4, 96, 16
 SCALE = 1.0 / np.sqrt(DH)
@@ -389,3 +394,187 @@ def test_pool_attention_reads_a_value_that_is_a_prefix_of_the_key(
     np.testing.assert_allclose(np.asarray(whole, np.float32)[..., :dv],
                                np.asarray(got, np.float32), rtol=tol,
                                atol=tol)
+
+
+# ------------------------------------------------- a row reads its own pages
+# case -> (query heads, pool heads, row width, value_dim)
+OWN_PAGES = {
+    "partial_last_page": (4, 4, 16, 0),
+    "one_token": (4, 4, 16, 0),
+    "rides_along": (4, 4, 16, 0),
+    "shared_frame": (4, 4, 16, 0),
+    "grouped_32_over_8": (32, 8, 16, 0),
+    "value_dim_576_to_512": (4, 1, 576, 512),
+    "unused_entries_zero": (4, 4, 16, 0),
+}
+
+
+def _both_reads(monkeypatch, attrs, q, pool_k, pool_v, table, pos_idx,
+                write_slot, page):
+    """(own pages, whole pool): the operator with the rule held to each
+    answer, on one step's operands."""
+    slots = pool_k.shape[1]
+    operands = [jnp.asarray(a) for a in (table, pos_idx, write_slot)]
+    mask = _kv_page_mask({"page_size": page, "num_slots": slots}, *operands)
+    got = []
+    for own in (True, False):
+        monkeypatch.setattr(attention, "pool_read_own_pages",
+                            lambda *a, own=own: own)
+        got.append(np.asarray(_kv_pool_attention(
+            dict(attrs, page_size=page), q, pool_k, pool_v, mask, *operands),
+            np.float32))
+    return got
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-6), ("bfloat16", 1e-2)])
+@pytest.mark.parametrize("case", list(OWN_PAGES))
+def test_own_pages_read_is_the_whole_pool_read(monkeypatch, case, dtype, tol):
+    """One step of the seeded ``small`` pool (7 lanes x 32 slots, pages of
+    8; ``_step_inputs``: a context of one token, a last page held in part, a
+    lane that rides along, two lanes on one frame, table entries past a
+    lane's pages 0) read both ways. Each row agrees to ``tol`` of its norm:
+    float32 differs by the order of a sum, a bfloat16 pool by the
+    probabilities' one rounding in the second contraction."""
+    hq, hkv, d, value_dim = OWN_PAGES[case]
+    lanes, per_lane, page = GEOMETRIES["small"]
+    slots = lanes * per_lane
+    kinds, got_oh, *_, table = _step_inputs("small")
+    rs = np.random.RandomState(len(case))
+    pool_k = jnp.asarray(rs.randn(hkv, slots, d), dtype)
+    pool_v = pool_k if value_dim else jnp.asarray(rs.randn(hkv, slots, d),
+                                                  dtype)
+    q = jnp.asarray(rs.randn(lanes, hq, d) * (4.0 / np.sqrt(d)), dtype)
+    pos_idx = np.asarray([[0 if LANES[k](page)[1] is None
+                           else LANES[k](page)[1]] for k in kinds], "f")
+    write_slot = np.where(got_oh.any(axis=1), got_oh.argmax(axis=1),
+                          -1).astype("f").reshape(lanes, 1)
+    attrs = {"scale": 0.25, "value_dim": value_dim}
+    step = (q, pool_k, pool_v, table, pos_idx, write_slot, page)
+    own, whole = _both_reads(monkeypatch, attrs, *step)
+    assert own.shape == whole.shape == (lanes, hq, value_dim or d)
+    assert np.isfinite(own).all()
+    live = [r for r, k in enumerate(kinds) if k != "idle"]
+    rows = {"partial_last_page": [kinds.index("mid_page")],
+            "one_token": [kinds.index("one_slot")],
+            "shared_frame": [kinds.index("shared_a"),
+                             kinds.index("shared_b")]}.get(case, live)
+    for r in rows:
+        norm = np.linalg.norm(whole[r], axis=-1, keepdims=True)
+        assert np.abs(own[r] - whole[r]).max() <= tol * norm.max(), r
+    idle = kinds.index("idle")
+    if case == "one_token":
+        # the softmax of one slot is 1: the context IS that slot's value
+        r = rows[0]
+        want = np.asarray(pool_v, np.float32)[:, int(write_slot[r, 0])]
+        np.testing.assert_allclose(own[r].reshape(hkv, -1, d),
+                                   np.broadcast_to(want[:, None], (hkv, 1, d)),
+                                   rtol=tol, atol=tol)
+    if case == "rides_along":
+        # whatever the riding lane's table and position say, it comes out
+        # finite and no other row moves by a bit
+        moved_table, moved_pos = table.copy(), pos_idx.copy()
+        moved_table[idle] = rs.permutation(slots // page)[:table.shape[1]]
+        moved_pos[idle] = per_lane - 1
+        moved, _ = _both_reads(monkeypatch, attrs, q, pool_k, pool_v,
+                               moved_table, moved_pos, write_slot, page)
+        assert np.isfinite(moved[idle]).all()
+        np.testing.assert_array_equal(_bits(moved[live]), _bits(own[live]))
+    if case == "unused_entries_zero":
+        # entries past a lane's pages are gathered and weigh exactly 0:
+        # naming other frames there changes no bit
+        n_pages = {r: LANES[k](page)[0] for r, k in enumerate(kinds)}
+        other = table.copy()
+        for r in live:
+            assert (table[r, n_pages[r]:] == 0).all()
+            other[r, n_pages[r]:] = 1 + rs.randint(
+                slots // page - 1, size=table.shape[1] - n_pages[r])
+        filled, _ = _both_reads(monkeypatch, attrs, q, pool_k, pool_v, other,
+                                pos_idx, write_slot, page)
+        np.testing.assert_array_equal(_bits(filled[live]), _bits(own[live]))
+
+
+# cell -> (lanes, query heads, pool heads, row width, slots a lane, type,
+# pools, own pages?) at the benchmark's serving sizes, pages of 16
+POOL_READS = {
+    "kanana-2-30b-a3b": (32, 32, 1, 576, 2048, "bfloat16", 1, True),
+    "granite-4.0-h-micro": (32, 32, 8, 64, 2048, "bfloat16", 2, False),
+    "transformer-base": (64, 8, 8, 64, 1024, "float32", 2, False),
+    "olmoe-1b-7b": (8, 16, 16, 128, 2048, "bfloat16", 2, False),
+}
+
+
+@pytest.mark.parametrize("cell", list(POOL_READS))
+def test_the_read_takes_own_pages_for_the_latent_pool_alone(cell):
+    """The rule's answer at the shapes of the four serving configurations:
+    one 576-wide latent row read by 32 heads is small beside its scores and
+    is gathered; the pools of 64- and 128-wide heads are not. And a read
+    that was handed no table scores the whole pool."""
+    lanes, hq, hkv, d, per_lane, dtype, pools, own = POOL_READS[cell]
+    spec = jax.ShapeDtypeStruct
+    query = spec((lanes, hq, d), dtype)
+    pool = spec((hkv, lanes * per_lane, d), dtype)
+    table = spec((lanes, per_lane // 16), "float32")
+    pool_v = pool if pools == 2 else None
+    assert pool_read_own_pages(query, pool, pool_v, table, 16) is own
+    assert pool_read_own_pages(query, pool, pool_v, None, 0) is False
+
+
+# ------------------------------------------- the two forms through the decoder
+def _latent_decoder(monkeypatch, own):
+    """A ``deepseek_v3`` decoder small enough for the CPU whose read the rule
+    sends to own pages (64 heads on one 128-wide row, 8 lanes), or, with the
+    rule held to "no", the same decoder over the whole pool."""
+    from mxnet_tpu.models import transformer as tf
+    from mxnet_tpu.serving import PagedKVDecoder
+
+    if not own:
+        monkeypatch.setattr(attention, "pool_read_own_pages",
+                            lambda *a: False)
+    cfg = dict(vocab_size=64, num_layers=3, num_heads=64, model_dim=32,
+               ffn_dim=64, moe_ffn_dim=16, num_experts=8,
+               num_experts_per_tok=2, num_shared_experts=1,
+               first_dense_layers=1, qk_nope_head_dim=4, qk_rope_head_dim=8,
+               v_head_dim=4, kv_lora_rank=120)
+    rs = np.random.RandomState(5)
+    params = {n: mx.nd.array(rs.randn(*shape).astype("f") * 0.2)
+              for n, shape in tf.param_shapes(arch="deepseek_v3",
+                                              **cfg).items()}
+    return PagedKVDecoder(params, arch="deepseek_v3", max_len=64, page_size=8,
+                          lanes=8, prefill_len=16, **cfg)
+
+
+def test_a_decoder_steps_alike_in_both_forms_and_says_which(monkeypatch):
+    """Admissions of unequal lengths, a lane that joins late and one that
+    retires, stepped side by side: the logits of the decoder whose lanes
+    read their own pages are the whole-pool decoder's to float32's sum
+    order, and its telemetry says what a step read."""
+    from mxnet_tpu import telemetry as tm
+
+    saved = tm.current_override()
+    tm.set_mode("counters")
+    try:
+        logits = []
+        for own in (True, False):
+            tm.reset()
+            dec = _latent_decoder(monkeypatch, own).warmup()
+            snap = tm.snapshot()
+            assert snap["serving.pool_read.own_pages_layers"] == 3 * own
+            assert snap["serving.pool_read.whole_pool_layers"] == 3 * (not own)
+            seqs = [dec.admit(np.arange(n) % 61)[0] for n in (3, 9, 16)]
+            rows = []
+            for t in range(12):
+                if t == 4:
+                    seqs.append(dec.admit(np.arange(5) % 59)[0])
+                if t == 8:
+                    dec.retire(seqs.pop(0))
+                out = dec.step({s: (7 * t + s) % 64 for s in seqs})
+                rows += [out[s] for s in seqs]
+            logits.append(np.stack(rows))
+            moved = tm.snapshot()
+            # own pages: 8 lanes x 8 pages of 8; whole: 8 lanes x 512 slots
+            assert moved["serving.step_gathered_slots"] \
+                == moved["serving.paged_steps"] * (512 if own else 8 * 512)
+        np.testing.assert_allclose(logits[0], logits[1], rtol=2e-5, atol=2e-5)
+    finally:
+        tm.set_mode(saved)
+        tm.reset()

@@ -253,6 +253,21 @@ def test_transformer_base_decode_step_contracts_on_the_chip(v5e):
     assert compiled.cost_analysis()["bytes accessed"] < 3.5e9
 
 
+# OLMoE-1B-7B's published widths with one layer, granite-4.0-h-micro's with
+# its first six (five Mamba-2, one attention)
+_OLMOE = dict(arch="olmoe", vocab_size=50304, num_layers=1, num_heads=16,
+              head_dim=128, model_dim=2048, ffn_dim=1024, num_experts=64,
+              num_experts_per_tok=8, rope_theta=10000.0, rms_eps=1e-5,
+              dtype="bfloat16")
+_GRANITE = dict(arch="granite_hybrid", vocab_size=100352, num_layers=6,
+                num_heads=32, num_kv_heads=8, head_dim=64, model_dim=2048,
+                ffn_dim=8192, layer_types=["mamba"] * 5 + ["attention"],
+                mamba_heads=64, mamba_head_dim=64, mamba_state=128,
+                mamba_conv=4, mamba_chunk=256, embedding_multiplier=12.0,
+                attention_multiplier=0.015625, residual_multiplier=0.22,
+                logits_scaling=8.0, rms_eps=1e-5, dtype="bfloat16")
+
+
 @pytest.mark.parametrize("program", ["prefill", "decode"])
 def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
     """The two graphs ``PagedKVDecoder(arch="olmoe")`` runs, lowered for the
@@ -266,10 +281,7 @@ def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
 
     lanes, max_len = 8, 2048
     slots = lanes * max_len
-    cfg = dict(arch="olmoe", vocab_size=50304, num_layers=1, num_heads=16,
-               head_dim=128, model_dim=2048, ffn_dim=1024, num_experts=64,
-               num_experts_per_tok=8, rope_theta=10000.0, rms_eps=1e-5,
-               dtype="bfloat16")
+    cfg = _OLMOE
     weights = {n: (s, "bfloat16") for n, s in tf.param_shapes(**cfg).items()}
     if program == "prefill":
         sym = tf.get_prefill_symbol(prefill_len=max_len, **cfg)
@@ -318,13 +330,8 @@ def test_granite_hybrid_serving_programs_compile_for_the_chip(v5e, program):
 
     lanes, max_len, bucket, page, layers = 32, 2048, 512, 16, 6
     slots = lanes * max_len
-    cfg = dict(arch="granite_hybrid", vocab_size=100352, num_layers=layers,
-               num_heads=32, num_kv_heads=8, head_dim=64, model_dim=2048,
-               ffn_dim=8192, layer_types=["mamba"] * 5 + ["attention"],
-               mamba_heads=64, mamba_head_dim=64, mamba_state=128,
-               mamba_conv=4, mamba_chunk=256, embedding_multiplier=12.0,
-               attention_multiplier=0.015625, residual_multiplier=0.22,
-               logits_scaling=8.0, rms_eps=1e-5, dtype="bfloat16")
+    cfg = _GRANITE
+    assert cfg["num_layers"] == layers
     weights = {n: (s, "bfloat16") for n, s in tf.param_shapes(**cfg).items()}
     cache = tf.decode_cache(**cfg)
     assert [kind for _, kind, _ in cache] == ["row"] * 10 + ["pool"] * 2
@@ -376,8 +383,11 @@ def test_latent_attention_serving_programs_compile_for_the_chip(v5e, program):
     has to hold on the chip: the cache is ONE (1, 65,536, 576) pool a layer
     that comes back in the type it went in, the step makes no key or value
     of a head (32 heads x 65,536 slots x 128 would be 268 MB in bfloat16, a
-    layer and kind), its temporaries are one layer's float32 scores (32 x
-    32 x 65,536 = 268 MB) and small change, and both programs keep the
+    layer and kind) and scores no lane against the whole pool (32 x 32 x
+    65,536 float32 would be 268 MB and 155 GFLOP a layer): it gathers each
+    lane's 128 frames (an 84 MB copy, in bounds by promise, so no ``select``
+    passes over it) and scores 2,048 slots a lane, and builds no (32,
+    65,536) mask, which none of its reads looks at. Both programs keep the
     grouped matmul and report the experts' load last."""
     from mxnet_tpu.models import transformer as tf
 
@@ -416,14 +426,102 @@ def test_latent_attention_serving_programs_compile_for_the_chip(v5e, program):
     assert "ragged" in hlo.lower()
     mem = compiled.memory_analysis()
     if program == "decode":
-        heads_wide = 32 * slots * 128
-        sizes = [math.prod(int(d) for d in dims.split(",") if d)
-                 for _n, dims, _op, _a in _INSTRUCTION.findall(hlo)]
-        assert not [n for n in sizes if n >= heads_wide
-                    and n != lanes * 32 * slots]    # only the scores are
-        assert mem.temp_size_in_bytes < 400 << 20
-        # 2 x (32 x 32 x 65,536 x (576 + 576) the latent read, a layer) and
-        # the matrices of 32 rows: the head 8.4 G, little else
-        assert 0.45e12 < compiled.cost_analysis()["flops"] < 0.60e12
+        found = [(math.prod(int(d) for d in dims.split(",") if d), op)
+                 for _n, dims, op, _a in _INSTRUCTION.findall(hlo)
+                 if op != "parameter"]
+        # nothing made is as large as the lanes' scores over the pool (67 M
+        # elements), let alone the heads' keys (a bitcast of the head's
+        # weights is), and no read's mask is built
+        assert not [n for n, _ in found
+                    if n >= lanes * 32 * slots and n != 128256 * 2048]
+        assert "kv_mask" not in hlo and "slot_onehot" in hlo
+        assert not [n for n, op in found
+                    if op == "select" and n >= lanes * max_len * 576]
+        gathers = [line for line in hlo.splitlines() if " gather(" in line]
+        for i in range(layers):     # ONE gather a layer: key and value
+            assert sum("layer%d_att/" % i in g for g in gathers) == 1
+        # the re-layout in front of it: the pool arrives slots-minor and the
+        # gather wants rows (a pool whose row is a page would need none)
+        assert sum(" copy(" in line and "[1,%d,576]" % slots in line
+                   for line in hlo.splitlines()) == layers
+        assert mem.temp_size_in_bytes < 200 << 20
+        # 2 x (32 x 32 x 2,048 x (576 + 576)) the latent read, a layer, the
+        # write's one-hot matmul 2.4 G a layer, the head 8.4 G, the
+        # matrices of 32 rows; the whole-pool read was 0.51e12
+        assert compiled.cost_analysis()["flops"] < 0.1e12
     else:
         assert mem.temp_size_in_bytes < 400 << 20
+
+
+def _program_alone(hlo):
+    """A compiled program's text without what names the source it was
+    traced from: the module's name (the graph's last node, numbered as it
+    was built), the tables of files, functions and locations in front of
+    the computations and every instruction's ``metadata={...}``."""
+    blocks = [b for b in hlo.split("\n\n") if b.split("\n", 1)[0] not in (
+        "FileNames", "FunctionNames", "FileLocations", "StackFrames")]
+    return re.sub(r",? metadata=\{[^{}]*\}|^HloModule [^,]+", "",
+                  "\n\n".join(blocks))
+
+
+# cell -> (the builder's sizes, lanes, slots a lane, weights' type)
+_WHOLE_POOL_CELLS = {
+    "transformer-base": (dict(vocab_size=32000, num_layers=2, num_heads=8,
+                              model_dim=512, ffn_dim=2048, pos_len=1024),
+                         64, 1024, "float32"),
+    "olmoe-1b-7b": (_OLMOE, 8, 2048, "bfloat16"),
+    "granite-4.0-h-micro": (_GRANITE, 32, 2048, "bfloat16"),
+}
+
+
+@pytest.mark.parametrize("cell", list(_WHOLE_POOL_CELLS))
+def test_a_pool_of_narrow_heads_keeps_its_decode_program(v5e, monkeypatch,
+                                                         cell):
+    """The decode programs of the three configurations whose pools hold 64-
+    and 128-wide heads, at their cells' serving sizes: handing the read its
+    page table changes nothing the chip runs. The rule says whole pool, so
+    the program is, instruction for instruction, the one compiled from the
+    graph that hands ``KVPoolAttention`` a mask and nothing else, which is
+    the graph of before the read could take a table."""
+    from mxnet_tpu.models import transformer as tf
+
+    cfg, lanes, max_len, dtype = _WHOLE_POOL_CELLS[cell]
+    slots, page = lanes * max_len, 16
+    def mask_only(*a):
+        onehot, read = step_inputs(*a)
+        return onehot, {"mask": read["mask"]}
+
+    step_inputs = tf._pool_step_inputs
+
+    def build(mask_alone):
+        with monkeypatch.context() as patch:
+            if mask_alone:
+                patch.setattr(tf, "_pool_step_inputs", mask_only)
+            return tf.get_decode_symbol(max_len=slots, page_size=page, **cfg)
+
+    args = {n: ((lanes, 1), "float32")
+            for n in ("data", "pos_idx", "write_slot")}
+    args["page_table"] = ((lanes, max_len // page), "float32")
+    if "arch" in cfg:
+        args.update({n: (shape, dtype)
+                     for n, shape in tf.param_shapes(**cfg).items()})
+        for name, kind, shape in tf.decode_cache(**cfg):
+            args[name] = ((shape[0], slots, shape[1]), dtype) \
+                if kind == "pool" else ((lanes,) + tuple(shape), "float32")
+    else:
+        heads, dh = cfg["num_heads"], cfg["model_dim"] // cfg["num_heads"]
+        pools = {"kv_%s_%d" % (t, i): (heads, slots, dh)
+                 for t in "kv" for i in range(cfg["num_layers"])}
+        sym = build(False)
+        shapes, _, _ = sym.infer_shape(**pools, **{
+            n: s for n, (s, _) in args.items()})
+        args = {n: (s, dtype) for n, s in zip(sym.list_arguments(), shapes)}
+    with_table, mask_alone = build(False), build(True)
+    operands = [[len(n.inputs) for n in sym._topo()
+                 if n.op == "_contrib_KVPoolAttention"]
+                for sym in (with_table, mask_alone)]
+    assert operands[0] and set(operands[0]) == {7}
+    assert len(operands[1]) == len(operands[0]) and set(operands[1]) == {4}
+    programs = [_program_alone(_compile_program(v5e, sym, args).as_text())
+                for sym in (with_table, mask_alone)]
+    assert programs[0] == programs[1]
