@@ -60,7 +60,10 @@ _DISPATCH_NAMES = frozenset({"forward", "decode_step", "greedy_step",
                              "step", "prefill", "run",
                              "decode_megastep", "step_megastep"})
 # a call by one of these names blocks on a device->host transfer
-_PULL_NAMES = frozenset({"asnumpy", "block_until_ready", "item", "tolist"})
+# (``_dispatch_and_pull``: the decoder's one enqueue-wait-copy helper,
+# serving/kv_decode.py — a caller of it holds the host sync)
+_PULL_NAMES = frozenset({"asnumpy", "block_until_ready", "item", "tolist",
+                         "_dispatch_and_pull"})
 # host reductions numpy performs that sym.* can lower on device instead
 _HOST_REDUCERS = frozenset({"argmax", "argmin", "argsort", "argpartition",
                             "choice"})  # np.random.choice = host sampling

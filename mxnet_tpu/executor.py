@@ -53,6 +53,12 @@ def _compiled_cost(lowered):
     return cost[0] if isinstance(cost, (list, tuple)) else cost
 
 
+def _signature(arrays):
+    """What ``jax.jit`` keys a call's arrays on, as far as the telemetry
+    tells calls apart: each one's shape and type."""
+    return tuple((tuple(a.shape), str(a.dtype)) for a in arrays)
+
+
 class _GraphProgram:
     """The traced interpretation of a Symbol: pure functions over arg/aux
     tuples, compiled lazily per (is_train, shapes) by jax.jit. ``label``
@@ -142,14 +148,15 @@ class _GraphProgram:
 
     # -------------------------------------------------------------- telemetry
     def _note_call(self, key, args, aux, extra=()):
-        """Classify one compiled-entry call: ``compile`` (first signature
-        for this jit key), ``cache_hit`` (signature seen before), or
+        """Classify an executor's FIRST noted call of a compiled entry:
+        ``compile`` (first signature for this jit key), ``cache_hit``
+        (another executor of these shapes and types got there before), or
         ``retrace`` (a NEW signature after the first — jax.jit compiles a
         fresh XLA program). Returns ``(kind, reason)``; ``reason`` is the
-        cached GL201-203 retrace-guard diagnosis on retraces."""
-        sig = (tuple((tuple(a.shape), str(a.dtype)) for a in args),
-               tuple((tuple(a.shape), str(a.dtype)) for a in aux),
-               extra)
+        cached GL201-203 retrace-guard diagnosis on retraces. Building the
+        signature walks every argument, so an executor asks once a jit key
+        and remembers (``Executor._note_telemetry``)."""
+        sig = (_signature(args), _signature(aux), extra)
         seen = self._seen_sigs.setdefault(key, set())
         if sig in seen:
             return "cache_hit", None
@@ -319,6 +326,7 @@ class Executor:
         self._last_rng = None
         self._monitor_callback = None
         self._cached_vjp = None
+        self._noted = set()  # (jit key, head signature) already classified
 
     # ----------------------------------------------------------------- running
     def _collect(self):
@@ -441,8 +449,20 @@ class Executor:
     def _note_telemetry(self, sp, key, args, aux, extra=()):
         """Count compile/cache_hit/retrace for this call and attach the
         classification (plus the GL201-203 diagnosis on retraces) to the
-        span. Caller guards with ``_tm.enabled()``."""
-        kind, reason = self._prog._note_call(key, args, aux, extra)
+        span. Caller guards with ``_tm.enabled()``.
+
+        An executor's arguments keep the shapes and types it was bound
+        with (``NDArray._set_jax`` shapes and casts every value to its
+        chunk's; ``reshape`` makes a new executor), so a jit entry it has
+        noted once is a cache hit ever after, without looking at an
+        argument. Only its first call of an entry asks the program, which
+        may be shared (``Executor(program=...)``) and so may have seen
+        another signature, or this one, before."""
+        if (key, extra) in self._noted:
+            kind, reason = "cache_hit", None
+        else:
+            kind, reason = self._prog._note_call(key, args, aux, extra)
+            self._noted.add((key, extra))
         _tm.counter("executor." + kind).inc()
         sp.set(cache=kind)
         if reason is not None:
@@ -491,7 +511,7 @@ class Executor:
             sp = _tm.span("executor.backward", path="fused_fwd_bwd")
             self._note_telemetry(
                 sp, ("fwd_bwd", with_head), args, aux,
-                extra=tuple((tuple(h.shape), str(h.dtype)) for h in head))
+                extra=_signature(head))
         with sp:
             fn = self._prog._fwd_bwd_cached(with_head)
             outs, grads, _ = fn(args, aux, head, rng)
@@ -511,7 +531,7 @@ class Executor:
             sp = _tm.span("executor.forward_backward", train=bool(is_train))
             self._note_telemetry(
                 sp, ("fwd_bwd", with_head), args, aux,
-                extra=tuple((tuple(h.shape), str(h.dtype)) for h in head))
+                extra=_signature(head))
         with sp:
             fn = self._prog._fwd_bwd_cached(with_head)
             outs, grads, new_aux = fn(args, aux, head, rng)

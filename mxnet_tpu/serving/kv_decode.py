@@ -70,11 +70,11 @@ def decode_megastep_k(default=1):
 
 
 def _gap_mark(dec, site):
-    """``dispatch.host_gap``: host time from the previous executable's
-    return (the blocking pull) to this dispatch's enqueue — the seam the
-    GL7xx analyzer prices (docs/OBSERVABILITY.md). Recorded per call site
-    and in aggregate. Off-mode cost is one predicate — no span objects,
-    no clock reads."""
+    """``dispatch.host_gap``: host time from the moment the previous
+    dispatch's result was ready on the device (``_gap_return``) to this
+    dispatch's enqueue — the seam the GL7xx analyzer prices
+    (docs/OBSERVABILITY.md). Recorded per call site and in aggregate.
+    Off-mode cost is one predicate — no span objects, no clock reads."""
     if not _tm.enabled():
         return
     now = time.perf_counter()
@@ -86,10 +86,46 @@ def _gap_mark(dec, site):
 
 
 def _gap_return(dec):
-    """Stamp the executable-return side of the ``dispatch.host_gap``
-    interval (called right after the blocking pull completes)."""
+    """Stamp the start of the ``dispatch.host_gap`` interval: where the
+    wait for the device ends (``serving.step.wait`` closes), so the copy to
+    the host, during which the device has nothing to run, is inside it."""
     if _tm.enabled():
         dec._last_return_t = time.perf_counter()
+
+
+def _dispatch_and_pull(dec, site, span, enqueue, **span_args):
+    """One decode-side dispatch and the blocking read of its result, the
+    same with telemetry on and off. ``enqueue()`` enqueues the program and
+    returns ``(pulled, kept)``: the device arrays the host reads now, and
+    what stays on the device for the caller. Returns ``(host arrays,
+    kept)``.
+
+    Inside ``span``: ``serving.step.dispatch`` around the enqueue, then
+    ``serving.step.read`` around its two halves, ``serving.step.wait`` (the
+    host blocked while the device runs the program) and
+    ``serving.step.copy`` (device to host of arrays that are ready: the
+    device idle; the copy itself is queued behind the program, so the span
+    holds what is left of it once the host has woken).
+    ``dispatch.host_gap`` runs from the wait's end to the next dispatch's
+    ``_gap_mark``."""
+    import jax
+
+    _gap_mark(dec, site)
+    with _tm.span(span, **span_args):
+        with _tm.span("serving.step.dispatch"):
+            pulled, kept = enqueue()
+        with _tm.span("serving.step.read"):
+            # queued behind the program: the copy starts the moment the
+            # device finishes, not a host wake-up later (0.12 ms a read)
+            for a in pulled:
+                a.copy_to_host_async()
+            with _tm.span("serving.step.wait"):
+                jax.block_until_ready(pulled)
+            _gap_return(dec)
+            with _tm.span("serving.step.copy",
+                          bytes=sum(a.nbytes for a in pulled)):
+                host = [np.asarray(a) for a in pulled]
+    return host, kept
 
 
 def _moe_load_at(symbol):
@@ -1092,9 +1128,15 @@ class PagedKVDecoder:
             with _tm.span("serving.admit.prefill"):
                 pf.forward(is_train=False)
             with _tm.span("serving.admit.logits"):
-                logits = np.asarray(
-                    pf.outputs[0]._jax().reshape(
-                        1, self.prefill_len, self.vocab_size)[0, L - 1, :])
+                row = pf.outputs[0]._jax().reshape(
+                    1, self.prefill_len, self.vocab_size)[0, L - 1, :]
+                # the host blocked while the device runs the prefill; what
+                # is left of `logits` is enqueueing the two op-by-op
+                # programs above and reading one row
+                row.copy_to_host_async()
+                with _tm.span("serving.admit.wait"):
+                    row.block_until_ready()
+                logits = np.asarray(row)
             # the pool update stays on the device; only the last
             # position's logits crossed above
             with _tm.span("serving.admit.scatter"):
@@ -1145,15 +1187,15 @@ class PagedKVDecoder:
                 w_oh[j, phys[j]] = 1.0
             mask[j, seen] = 0.0
             mask[j, phys[: j + 1]] = 0.0
-        _gap_mark(self, "serving.chunk_prefill")
-        with _tm.span("serving.chunk_prefill", t=T, rows=n,
-                      write=bool(write)):
-            with _tm.span("serving.step.dispatch"):
-                logits, new_kvs, _tok = prog.run(self, data, pos_idx, w_oh,
-                                                 mask)
-            with _tm.span("serving.step.read"):
-                out = np.asarray(logits)[:n]
-        _gap_return(self)
+
+        def enqueue():
+            logits, new_kvs, _tok = prog.run(self, data, pos_idx, w_oh, mask)
+            return (logits,), new_kvs
+
+        (out,), new_kvs = _dispatch_and_pull(
+            self, "serving.chunk_prefill", "serving.chunk_prefill", enqueue,
+            t=T, rows=n, write=bool(write))
+        out = out[:n]
         if write:
             self._dec_exe.rebind(prog.kv_names, new_kvs)
         return out
@@ -1392,15 +1434,15 @@ class PagedKVDecoder:
                 # ONE batched transfer: a host-to-device copy of a few KB
                 # costs the host 0.2 ms whatever its size
                 exe.rebind(staged, jax.device_put(list(staged.values())))
-            _gap_mark(self, "serving.paged_step")
-            with _tm.span("serving.decode_step", rows=len(stepped),
-                          paged=True):
-                with _tm.span("serving.step.dispatch"):
-                    exe.forward(is_train=False)
-                with _tm.span("serving.step.read"):
-                    # graphlint: waive GL701 -- single-step tail of the megastep loop; the K-amortized body is the lax.scan in step_megastep
-                    logits = exe.outputs[0].asnumpy()
-            _gap_return(self)
+
+            def enqueue():
+                exe.forward(is_train=False)
+                return (exe.outputs[0]._jax(),), None
+
+            # graphlint: waive GL701 -- single-step tail of the megastep loop; the K-amortized body is the lax.scan in step_megastep
+            (logits,), _ = _dispatch_and_pull(
+                self, "serving.paged_step", "serving.decode_step", enqueue,
+                rows=len(stepped), paged=True)
             out = {}
             with _tm.span("serving.step.commit"):
                 _swap_cache(exe, self._cache_names)
@@ -1408,28 +1450,33 @@ class PagedKVDecoder:
                     lane.pos += 1
                     out[seq_id] = logits[idx]
             if _tm.enabled():
-                _tm.counter("serving.decode_tokens").inc(len(stepped))
-                # what the stepped lanes attended: position + 1 each
-                _tm.counter("serving.step_context_tokens").inc(
-                    sum(lane.pos for _, _, lane in stepped))
-                _tm.counter("serving.paged_steps").inc()
-                _tm.counter("serving.step_gathered_slots").inc(
-                    self._step_gathered_slots)
-                _tm.counter("serving.step_input_bytes").inc(
-                    sum(a.nbytes for a in staged.values()))
-                if self._decode_xla_bytes:
-                    _tm.counter("serving.decode_xla_bytes").inc(
-                        self._decode_xla_bytes)
-                if self._dec_moe_load is not None:
-                    # rows each expert received from ALL the step's lanes
-                    # (those that ride along pass through the experts too)
-                    load = exe.outputs[self._dec_moe_load].asnumpy()
-                    _tm.counter("serving.moe.step_assignments").inc(
-                        int(load.sum()))
-                    _tm.counter("serving.moe.step_experts_touched").inc(
-                        int(np.count_nonzero(load)))
-                _tm.gauge("decode.tokens_per_dispatch").set(len(stepped))
-                _tm.gauge("serving.paged_pages_in_use").set(self.pool.in_use)
+                # the instrument's own work in a step, under its own name
+                with _tm.span("serving.step.account"):
+                    _tm.counter("serving.decode_tokens").inc(len(stepped))
+                    # what the stepped lanes attended: position + 1 each
+                    _tm.counter("serving.step_context_tokens").inc(
+                        sum(lane.pos for _, _, lane in stepped))
+                    _tm.counter("serving.paged_steps").inc()
+                    _tm.counter("serving.step_gathered_slots").inc(
+                        self._step_gathered_slots)
+                    _tm.counter("serving.step_input_bytes").inc(
+                        sum(a.nbytes for a in staged.values()))
+                    if self._decode_xla_bytes:
+                        _tm.counter("serving.decode_xla_bytes").inc(
+                            self._decode_xla_bytes)
+                    if self._dec_moe_load is not None:
+                        # rows each expert received from ALL the step's
+                        # lanes (those that ride along pass through the
+                        # experts too)
+                        # graphlint: waive GL701 -- the instrument's own read, telemetry on only (serving.step.account)
+                        load = exe.outputs[self._dec_moe_load].asnumpy()
+                        _tm.counter("serving.moe.step_assignments").inc(
+                            int(load.sum()))
+                        _tm.counter("serving.moe.step_experts_touched").inc(
+                            int(np.count_nonzero(load)))
+                    _tm.gauge("decode.tokens_per_dispatch").set(len(stepped))
+                    _tm.gauge("serving.paged_pages_in_use").set(
+                        self.pool.in_use)
             return out
 
     def step_megastep(self, tokens: Dict[int, object], k=None, eos_id=None,
@@ -1484,16 +1531,16 @@ class PagedKVDecoder:
             slots[idx] = phys[seq_id]
             done0[idx] = False
         eos = np.int32(-1 if eos_id is None else int(eos_id))
-        _gap_mark(self, "serving.paged_megastep")
-        with _tm.span("serving.decode_megastep", rows=len(stepped),
-                      paged=True, k=k):
-            with _tm.span("serving.step.dispatch"):
-                toks, acts, new_kvs, _done = ms.run(
-                    self, tok0, posv, slots, table, done0, eos)
-            with _tm.span("serving.step.read"):
-                ids = np.asarray(toks)       # (K, B): the only host pull
-                acts_h = np.asarray(acts)
-        _gap_return(self)
+        def enqueue():
+            toks, acts, new_kvs, _done = ms.run(
+                self, tok0, posv, slots, table, done0, eos)
+            return (toks, acts), new_kvs
+
+        # (K, B) ids and the active mask: the only host pull
+        # graphlint: waive GL701 -- one round-trip a K tokens: the amortized shape the single-step tail is measured against
+        (ids, acts_h), new_kvs = _dispatch_and_pull(
+            self, "serving.paged_megastep", "serving.decode_megastep",
+            enqueue, rows=len(stepped), paged=True, k=k)
         self._dec_exe.rebind(ms.kv_names, new_kvs)
         out = {}
         written = 0

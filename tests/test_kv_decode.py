@@ -4,6 +4,7 @@ greedy parity against full-sequence re-forward, prefill-length
 independence, the paged pool, the sealed programs, and the zero-retrace
 contract."""
 import os
+import types
 
 import numpy as np
 import pytest
@@ -358,6 +359,28 @@ def test_dispatch_host_gap_timer_ticks_only_when_enabled(tm):
     site = tm.timer("dispatch.host_gap.serving.paged_step")
     assert site.count == agg.count
 
+    # the interval starts where the device has just finished: the stamp
+    # sits between ``serving.step.wait``'s close and ``serving.step.copy``'s
+    # open, so the copy to the host is inside the gap that follows
+    tm.set_mode("trace")
+    tm.reset()
+    tm.clear_events()
+    sid, logits = dec.admit(np.ones((3,), np.float32))
+    for _ in range(2):
+        logits = dec.step({sid: int(np.argmax(logits))})[sid]
+    by_name = {}
+    for e in sorted(tm.drain_events(), key=lambda e: e[1]):
+        by_name.setdefault(e[0], []).append(e)
+    wait, copy = by_name["serving.step.wait"], by_name["serving.step.copy"]
+    assert wait[1][1] + wait[1][2] <= dec._last_return_t <= copy[1][1]
+    gap = tm.timer("dispatch.host_gap")
+    assert gap.count == 1
+    # wait[0]'s close -> dispatch[1]'s open holds copy[0] and commit[0]
+    commit = by_name["serving.step.commit"][0]
+    assert gap.total_ms >= 1e3 * (copy[0][2] + commit[2])
+    dispatch = by_name["serving.step.dispatch"][1]
+    assert gap.total_ms <= 1e3 * (dispatch[1] - wait[0][1] - wait[0][2])
+
 
 # ------------------------------------------------ phase spans and XLA bytes
 ADMIT_PHASES = ("stage", "prefill", "logits", "scatter")
@@ -422,15 +445,136 @@ def test_step_yields_one_span_per_phase_inside_paged_step(traced_request):
         kids = _children(events, step)
         assert [k[0] for k in kids] == ["serving.step.stage",
                                         "serving.decode_step",
-                                        "serving.step.commit"]
+                                        "serving.step.commit",
+                                        "serving.step.account"]
         _inside_and_disjoint(step, kids)
         inner = _children(events, kids[1])
         assert [k[0] for k in inner] == ["serving.step.dispatch",
                                          "serving.step.read"]
         _inside_and_disjoint(kids[1], inner)
     names = [e[0] for e in events]
-    for phase in STEP_PHASES:
+    for phase in STEP_PHASES + ("wait", "copy", "account"):
         assert names.count("serving.step." + phase) == 2
+
+
+def _read_halves(events, under):
+    """Every ``serving.step.read`` of ``events`` whose parent is a span
+    called ``under``, with its children in order of start."""
+    by_id = {e[4]["id"]: e for e in events if "id" in e[4]}
+    reads = [e for e in events if e[0] == "serving.step.read"
+             and by_id[e[4]["parent"]][0] == under]
+    return [(r, _children(events, r)) for r in reads]
+
+
+@pytest.mark.parametrize("path", ["step", "step_megastep", "chunk"])
+def test_the_blocking_read_is_a_wait_and_then_a_copy(tm, path):
+    """One helper, three sites: inside ``serving.step.read`` the host first
+    waits for the device (``serving.step.wait``), then copies arrays that are
+    ready (``serving.step.copy``, ``bytes``: what crossed)."""
+    tm.set_mode("trace")
+    dec = _tiny_paged().warmup()
+    sid, logits = dec.admit(np.array([3, 1, 4, 1, 5], np.float32))
+    tok = int(np.argmax(logits))
+    lanes, vocab = dec.lanes, CFG["vocab_size"]
+    if path == "chunk":
+        dec._chunk_for(3)               # its warm compile is not a dispatch
+    elif path == "step_megastep":
+        dec.step_megastep({sid: tok}, k=2)
+    tm.clear_events()
+    if path == "step":
+        dec.step({sid: tok})
+        under, crossed = "serving.decode_step", lanes * vocab * 4
+    elif path == "step_megastep":
+        dec.step_megastep({sid: tok}, k=2)
+        # (K, lanes) int32 ids and the (K, lanes) active mask
+        under, crossed = "serving.decode_megastep", 2 * lanes * (4 + 1)
+    else:
+        dec.verify_chunk(sid, [tok, 7, 9])
+        under, crossed = "serving.chunk_prefill", 3 * vocab * 4
+    events = sorted(tm.drain_events(), key=lambda e: e[1])
+    ((read, halves),) = _read_halves(events, under)
+    assert [h[0] for h in halves] == ["serving.step.wait",
+                                      "serving.step.copy"]
+    _inside_and_disjoint(read, halves)
+    assert halves[1][4]["bytes"] == crossed
+    assert "bytes" not in halves[0][4]
+    # the dispatch is the read's sibling, before it
+    siblings = _children(events, next(
+        e for e in events if e[4].get("id") == read[4]["parent"]))
+    assert [k[0] for k in siblings] == ["serving.step.dispatch",
+                                        "serving.step.read"]
+
+
+def test_the_pull_queues_its_copy_before_it_waits(tm):
+    """The copy to the host is queued behind the program before the host
+    blocks, as a bare ``np.asarray`` of a pending array would queue it: the
+    wait then costs a read no second trip to the device."""
+    from mxnet_tpu.serving import kv_decode as kd
+
+    calls = []
+
+    class Pending:
+        nbytes = 12
+
+        def copy_to_host_async(self):
+            calls.append("copy_to_host_async")
+
+        def block_until_ready(self):
+            calls.append("block_until_ready")
+            return self
+
+        def __array__(self, dtype=None, copy=None):
+            calls.append("__array__")
+            return np.arange(3, dtype=np.float32)
+
+    def enqueue():
+        calls.append("enqueue")
+        return (Pending(),), "kept"
+
+    dec = types.SimpleNamespace(_last_return_t=None)
+    tm.set_mode("0")
+    (host,), kept = kd._dispatch_and_pull(dec, "site", "span", enqueue)
+    assert calls == ["enqueue", "copy_to_host_async", "block_until_ready",
+                     "__array__"]
+    assert kept == "kept" and host.tolist() == [0.0, 1.0, 2.0]
+    assert dec._last_return_t is None and tm.drain_events() == []
+    tm.set_mode("trace")
+    kd._dispatch_and_pull(dec, "site", "span", enqueue, rows=1)
+    names = [e[0] for e in sorted(tm.drain_events(), key=lambda e: e[1])]
+    assert names == ["span", "serving.step.dispatch", "serving.step.read",
+                     "serving.step.wait", "serving.step.copy"]
+    assert dec._last_return_t is not None
+
+
+def test_admit_waits_for_the_prefill_inside_the_logits_phase(traced_request):
+    events, _bytes, _sid = traced_request
+    (logits,) = [e for e in events if e[0] == "serving.admit.logits"]
+    kids = _children(events, logits)
+    assert [k[0] for k in kids] == ["serving.admit.wait"]
+    _inside_and_disjoint(logits, kids)
+
+
+def test_a_step_builds_no_span_object_with_telemetry_off(tm, monkeypatch):
+    """The split costs an untraced step one ``block_until_ready`` on a
+    buffer about to be read, and nothing of the instrument: no span, no id,
+    no annotation, no clock read for the gap, no registry object."""
+    from mxnet_tpu.telemetry import spans
+
+    dec = _tiny_paged().warmup()
+    sid, logits = dec.admit(np.array([3, 1, 4], np.float32))
+
+    def built(*_a, **_k):
+        raise AssertionError("telemetry is off: nothing may be built")
+
+    monkeypatch.setattr(spans, "_Span", built)
+    monkeypatch.setattr(spans, "_annotation", built)
+    monkeypatch.setattr(spans, "_span_ids", iter(()))  # next() would raise
+    tm.set_mode("0")
+    for _ in range(2):
+        logits = dec.step({sid: int(np.argmax(logits))})[sid]
+    assert logits.shape == (CFG["vocab_size"],)
+    assert dec._last_return_t is None
+    assert tm.drain_events() == [] and tm.counters() == {}
 
 
 def test_retire_event_closes_the_request(traced_request):

@@ -265,6 +265,113 @@ def test_retrace_counter_on_cache_busting_rebind(tm):
     assert tm.counter("executor.cache_hit").value == 2
 
 
+def _sum_of(n_args):
+    """A graph of ``n_args`` arguments, all of one shape."""
+    net = mx.sym.Variable("a0")
+    for i in range(1, n_args):
+        net = net + mx.sym.Variable("a%d" % i)
+    return net
+
+
+def test_a_second_executor_of_another_shape_still_counts_one_retrace(tm):
+    """An executor asks the shared program once a jit entry; the program
+    still tells a new signature from one it has seen, whoever brought it."""
+    tm.set_mode("trace")
+    sym = _sum_of(3)
+    exe = mx.executor.simple_bind(sym, mx.cpu(), grad_req="null",
+                                  a0=(2, 4), a1=(2, 4), a2=(2, 4))
+    other = exe.reshape(allow_up_sizing=True, a0=(3, 4), a1=(3, 4),
+                        a2=(3, 4))
+    back = other.reshape(a0=(2, 4), a1=(2, 4), a2=(2, 4))
+    assert other._prog is exe._prog is back._prog
+
+    def counts():
+        c = tm.counters()
+        return [c.get("executor." + k, 0)
+                for k in ("compile", "cache_hit", "retrace")]
+
+    exe.forward()
+    assert counts() == [1, 0, 0]
+    other.forward()
+    assert counts() == [1, 0, 1]
+    assert tm.gauge("executor.last_retrace_reason").value
+    other.forward()
+    exe.forward()
+    assert counts() == [1, 2, 1]
+    back.forward()                      # its first call: a shape seen before
+    assert counts() == [1, 3, 1]
+    spans = [e[4] for e in sorted(tm.drain_events(), key=lambda e: e[1])
+             if e[0] == "executor.forward"]
+    assert [a["cache"] for a in spans] == ["compile", "retrace", "cache_hit",
+                                           "cache_hit", "cache_hit"]
+    assert "retrace_reason" in spans[1] and "retrace_reason" not in spans[2]
+    # train and inference are two jit entries of one program
+    exe.forward(is_train=True)
+    assert counts() == [2, 3, 1]
+
+
+@pytest.mark.parametrize("n_args", [2, 48])
+def test_a_steady_forward_is_classified_without_looking_at_an_argument(
+        tm, monkeypatch, n_args):
+    """A thousand ``forward``s of one executor: one compile, 999 cache hits,
+    and after the first call neither the signature is rebuilt nor the
+    program asked, however many arguments the executable takes."""
+    from mxnet_tpu import executor as ex
+
+    tm.set_mode("counters")
+    shapes = {"a%d" % i: (2, 2) for i in range(n_args)}
+    exe = mx.executor.simple_bind(_sum_of(n_args), mx.cpu(), grad_req="null",
+                                  **shapes)
+    calls = {"signature": 0, "note_call": 0}
+    signature, note_call = ex._signature, ex._GraphProgram._note_call
+
+    def counted_signature(arrays):
+        calls["signature"] += 1
+        return signature(arrays)
+
+    def counted_note_call(self, *args, **kwargs):
+        calls["note_call"] += 1
+        return note_call(self, *args, **kwargs)
+
+    monkeypatch.setattr(ex, "_signature", counted_signature)
+    monkeypatch.setattr(ex._GraphProgram, "_note_call", counted_note_call)
+    exe.forward()
+    first = dict(calls)
+    assert first == {"signature": 2, "note_call": 1}    # args and aux, once
+    for _ in range(999):
+        exe.forward()
+    assert calls == first
+    c = tm.counters()
+    assert c["executor.compile"] == 1 and c["executor.cache_hit"] == 999
+    assert c.get("executor.retrace", 0) == 0
+
+
+def test_a_bound_argument_keeps_its_shape_and_type_through_every_write(tm):
+    """What lets an executor classify a call without its arguments: nothing
+    written into a bound array changes the shape or the type it was bound
+    with, whether the value changes hands by reference, is cast, is
+    broadcast, or lands in a view."""
+    import jax.numpy as jnp
+
+    tm.set_mode("counters")
+    exe = mx.executor.simple_bind(_sum_of(2), mx.cpu(), grad_req="null",
+                                  a0=(4, 3), a1=(4, 3))
+    arr = exe.arg_dict["a0"]
+    bound = (arr._jax().shape, arr._jax().dtype)
+    exe.rebind(["a0"], [jnp.ones((4, 3), jnp.float32)])        # by reference
+    exe.rebind(["a0"], [jnp.ones((4, 3), jnp.bfloat16)])       # cast
+    exe.rebind(["a0"], [np.ones((4, 3), np.float64)])          # host, cast
+    arr[:] = 2.0                                               # broadcast
+    arr[1:3] = np.ones((2, 3), np.float16)                     # a view
+    arr[:] = mx.nd.ones((4, 3), dtype="int32")
+    assert (arr._jax().shape, arr._jax().dtype) == bound
+    c = tm.counters()
+    assert c["executor.rebind"] == 1 and c["executor.rebind_copy"] == 2
+    exe.forward()
+    exe.forward()
+    assert tm.counters()["executor.cache_hit"] == 1
+
+
 # ------------------------------------------------- fusion counter parity
 def test_fused_counter_parity_with_bench_report(tm):
     import importlib.util
