@@ -128,13 +128,90 @@ def _dispatch_and_pull(dec, site, span, enqueue, **span_args):
     return host, kept
 
 
-def _moe_load_at(symbol):
-    """Where a graph of sparse experts reports the rows each expert
-    received, ``moe_load (layers, experts)``: the output's index, or None
-    for a graph that has none."""
+class _LogitsRow(np.lib.mixins.NDArrayOperatorsMixin):
+    """One lane's ``(vocab,)`` logits of a decode step, as ``step`` returns
+    them: an array whose bytes cross to the host when somebody reads them.
+
+    The rows of a step share ``block``, a one-element list that holds the
+    step's ``(lanes, vocab)`` device array until the first read of any row
+    and the host's copy of it from then on: one transfer a step, inside
+    ``serving.step.logits_pull``. ``np.asarray(row)``, indexing, arithmetic
+    and every other ``ndarray`` method read; ``shape``, ``dtype`` and
+    ``len`` do not, and neither does ``argmax()`` with no axis (what
+    ``np.argmax(row)`` calls), which answers with the token the program's
+    own ``greedy_token`` head chose for the lane."""
+
+    __slots__ = ("_block", "_lane", "_token")
+
+    def __init__(self, block, lane, token):
+        self._block, self._lane, self._token = block, lane, token
+
+    def _host(self):
+        block = self._block
+        if not isinstance(block[0], np.ndarray):
+            with _tm.span("serving.step.logits_pull",
+                          bytes=block[0].nbytes):
+                block[0] = np.asarray(block[0])
+            if _tm.enabled():
+                _tm.counter("serving.step_logits_pulls").inc()
+                _tm.counter("serving.step_logits_pull_bytes").inc(
+                    block[0].nbytes)
+        return block[0][self._lane]
+
+    @property
+    def shape(self):
+        return self._block[0].shape[1:]
+
+    @property
+    def dtype(self):
+        return np.dtype(self._block[0].dtype)
+
+    def __len__(self):
+        return self.shape[0]
+
+    def __array__(self, dtype=None, copy=None):
+        row = self._host()
+        if dtype is not None and row.dtype != dtype:
+            return row.astype(dtype)
+        return row.copy() if copy else row
+
+    def __array_ufunc__(self, ufunc, method, *inputs, **kwargs):
+        def host(x):
+            return x._host() if isinstance(x, _LogitsRow) else x
+
+        if any(isinstance(x, _LogitsRow) for x in kwargs.get("out", ())):
+            return NotImplemented  # a row is what the program gave: read-only
+        return getattr(ufunc, method)(*(host(x) for x in inputs), **kwargs)
+
+    def __getitem__(self, item):
+        return self._host()[item]
+
+    def __iter__(self):
+        return iter(self._host())
+
+    def __getattr__(self, name):  # the rest of ndarray: of the host's copy
+        if name.startswith("_"):
+            raise AttributeError(name)
+        return getattr(self._host(), name)
+
+    def argmax(self, axis=None, out=None, **kwargs):
+        if axis is None and out is None and not kwargs:
+            return self._token
+        return self._host().argmax(axis, out, **kwargs)
+
+    def __repr__(self):
+        return "_LogitsRow(lane=%d, token=%d, %s)" % (
+            self._lane, self._token,
+            "on the host" if isinstance(self._block[0], np.ndarray)
+            else "on the device")
+
+
+def _output_at(symbol, name):
+    """The index of a graph's output ``name``, or None for a graph that has
+    none: ``moe_load (layers, experts)``, where a graph of sparse experts
+    reports the rows each expert received; ``greedy_token (lanes,)``."""
     outs = symbol.list_outputs()
-    return outs.index("moe_load_output") if "moe_load_output" in outs \
-        else None
+    return outs.index(name + "_output") if name + "_output" in outs else None
 
 
 def _pool_read_slots(cache, input_shapes):
@@ -859,8 +936,9 @@ class PagedKVDecoder:
         prefill = _tf.get_prefill_symbol(prefill_len=self.prefill_len, **cfg)
         decode = _tf.get_decode_symbol(max_len=self.total_slots,
                                        page_size=self.page_size, **cfg)
-        self._pf_moe_load = _moe_load_at(prefill)
-        self._dec_moe_load = _moe_load_at(decode)
+        self._pf_moe_load = _output_at(prefill, "moe_load")
+        self._dec_moe_load = _output_at(decode, "moe_load")
+        self._dec_token = _output_at(decode, "greedy_token")
         self._pf_cache = PersistentExecutableCache(
             prefill, arg_params, {}, model_key=key + "-prefill",
             program_label="mx_prefill", **binding)
@@ -1388,7 +1466,11 @@ class PagedKVDecoder:
         """One multiplexed decode dispatch: ``tokens`` maps seq_id -> next
         token id for any subset of active sequences; every stepped
         sequence advances at ITS OWN position in the one batch. Returns
-        {seq_id: (vocab,) logits}. What the host hands the program is, a
+        {seq_id: (vocab,) logits}: what crosses to the host in the step is
+        the program's ``greedy_token``, one id a lane, which
+        ``np.argmax(row)`` answers with; the ``(lanes, vocab)`` block
+        follows when a row is read as an array, once for the step's rows
+        (``_LogitsRow``). What the host hands the program is, a
         lane, its token, its position, the slot the token lands in and the
         frames of its pages (``data``, ``pos_idx``, ``write_slot``,
         ``page_table``: ``lanes * (3 + max_len / page_size)`` float32 in
@@ -1437,10 +1519,13 @@ class PagedKVDecoder:
 
             def enqueue():
                 exe.forward(is_train=False)
-                return (exe.outputs[0]._jax(),), None
+                # the logits stay where they are, in the one-element list
+                # the step's rows share (``_LogitsRow``)
+                return ((exe.outputs[self._dec_token]._jax(),),
+                        [exe.outputs[0]._jax()])
 
             # graphlint: waive GL701 -- single-step tail of the megastep loop; the K-amortized body is the lax.scan in step_megastep
-            (logits,), _ = _dispatch_and_pull(
+            (chosen,), block = _dispatch_and_pull(
                 self, "serving.paged_step", "serving.decode_step", enqueue,
                 rows=len(stepped), paged=True)
             out = {}
@@ -1448,7 +1533,7 @@ class PagedKVDecoder:
                 _swap_cache(exe, self._cache_names)
                 for seq_id, idx, lane in stepped:
                     lane.pos += 1
-                    out[seq_id] = logits[idx]
+                    out[seq_id] = _LogitsRow(block, idx, int(chosen[idx]))
             if _tm.enabled():
                 # the instrument's own work in a step, under its own name
                 with _tm.span("serving.step.account"):
@@ -1592,7 +1677,7 @@ class PagedKVDecoder:
                 else:
                     # graphlint: waive GL702 -- sub-K tail: fewer than K tokens left, single-step program is already warm
                     lg = self.step(nxt)
-                    # graphlint: waive GL703 -- sub-K tail host argmax, one id per lane on already-pulled logits
+                    # graphlint: waive GL703 -- np.argmax of a step's row answers with the program's greedy_token, the one id a lane the step pulled; no logits cross
                     nxt = {sid: int(np.argmax(lg[sid])) for sid in seqs}
                     for sid in seqs:
                         out[sid][t] = nxt[sid]

@@ -178,7 +178,7 @@ class SpeculativeDecoder:
                     fed = cur
                     # graphlint: waive GL702 -- position-table tail; single-step program is already warm
                     lg = self.target.step({seq_id: fed})
-                    # graphlint: waive GL703 -- one id from already-pulled logits
+                    # graphlint: waive GL703 -- the row answers with the program's greedy_token; no logits cross
                     cur = int(np.argmax(lg[seq_id]))
                     # keep the draft aligned in case room returns later
                     # graphlint: waive GL702 -- draft shadow step, same warm program

@@ -304,31 +304,204 @@ def test_paged_admission_backpressure_and_reuse():
 
 
 # ---------------------------------------------- on-device greedy head (GL703)
-def test_greedy_step_on_device_argmax_token_parity(tm):
-    """The GL703 fix gate: the decode program's on-device ``greedy_token``
-    head (one id per lane for a greedy driver to pull) is token-identical
-    to pulling the full logits row and arg-maxing on host, step for step."""
+ARCHS = {
+    "vaswani": CFG,
+    "olmoe": dict(arch="olmoe", vocab_size=60, num_layers=2, num_heads=4,
+                  head_dim=8, model_dim=32, ffn_dim=16, num_experts=4,
+                  num_experts_per_tok=2, rope_theta=10000.0, rms_eps=1e-5),
+    "granite_hybrid": dict(
+        arch="granite_hybrid", vocab_size=60, num_layers=3, num_heads=4,
+        num_kv_heads=2, head_dim=8, model_dim=32, ffn_dim=48,
+        layer_types=["mamba", "attention", "mamba"], mamba_heads=4,
+        mamba_head_dim=8, mamba_state=8, mamba_conv=4, mamba_chunk=8,
+        embedding_multiplier=12.0, attention_multiplier=0.125,
+        residual_multiplier=0.22, logits_scaling=8.0, rms_eps=1e-5),
+    "deepseek_v3": dict(
+        arch="deepseek_v3", vocab_size=60, num_layers=2, num_heads=4,
+        model_dim=32, ffn_dim=48, moe_ffn_dim=16, num_experts=4,
+        num_experts_per_tok=2, num_shared_experts=1, first_dense_layers=1,
+        qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+        kv_lora_rank=16, rope_theta=10000.0, rms_eps=1e-6,
+        routed_scaling_factor=2.448, norm_topk_prob=True),
+}
+
+
+def _arch_decoder(arch, lanes=3, twin_rows=False):
+    """A decoder of ``arch`` at a test's size. ``twin_rows``: every row of
+    the output head (and of the embedding, which a tied head IS) equals its
+    even neighbour's, so each logit has a twin and every maximum is a tie."""
+    cfg, S = ARCHS[arch], 32
+    if arch == "vaswani":
+        params = _trained_params(S)[2]
+    else:
+        rs = np.random.RandomState(0)
+        params = {}
+        for name, shape in sorted(tfm.param_shapes(**cfg).items()):
+            if name.endswith(("gamma", "_D")):
+                v = np.ones(shape)
+            elif name.endswith("A_log"):
+                v = np.log(rs.uniform(1, 16, shape))
+            elif name.endswith("dt_bias"):
+                v = np.log(np.expm1(rs.uniform(1e-3, 1e-1, shape)))
+            elif "_conv_" in name:
+                v = rs.uniform(-0.5, 0.5, shape)
+            else:
+                v = rs.randn(*shape) * 0.1
+            params[name] = v.astype("float32")
+    if twin_rows:
+        for name in ("embed_weight", "lm_head_weight", "lm_head_bias"):
+            if name in params:
+                params[name] = np.repeat(params[name][0::2], 2, axis=0)
+    serve = dict(max_len=S, page_size=4, lanes=lanes, prefill_len=16)
+    serve.update(dict(pos_len=S) if arch == "vaswani"
+                 else dict(dtype="float32"))
+    return PagedKVDecoder(params, **serve, **cfg)
+
+
+def _admit_prompts(dec, prompts=([3, 1, 4, 1, 5, 9], [2, 7, 1])):
+    nxt = {}
+    for prompt in prompts:
+        sid, logits = dec.admit(np.asarray(prompt, np.float32))
+        nxt[sid] = int(np.argmax(logits))
+    return nxt
+
+
+def _pulls(tm):
+    c = tm.counters()
+    return (c.get("serving.step_logits_pulls", 0),
+            c.get("serving.step_logits_pull_bytes", 0))
+
+
+@pytest.mark.parametrize("arch", sorted(ARCHS))
+def test_greedy_step_on_device_argmax_token_parity(tm, arch):
+    """The GL703 fix gate: the token ``np.argmax(row)`` answers with, the
+    decode program's own ``greedy_token`` and no pull, is the arg-max of the
+    row's pulled logits, step for step. Every logit has a twin, so each
+    maximum is a tie that both sides settle for the first; the third lane
+    rides along, and so does the second sequence in one step of three."""
     tm.set_mode("counters")
-    S, B = 32, 2
-    _, _, params = _trained_params(S)
-    rs = np.random.RandomState(7)
-    prompt = rs.randint(1, CFG["vocab_size"], (B, 5)).astype(np.float32)
-    dec = _paged(params, S, lanes=B)
-    toks = {}
-    for row in prompt:
-        sid, logits = dec.admit(row)
-        toks[sid] = int(np.argmax(logits))
-    for _ in range(12):
-        logits = dec.step(toks)
-        tok_d = dec._dec_exe.outputs[-1].asnumpy()
-        tok_h = {sid: int(np.argmax(logits[sid])) for sid in toks}
-        for sid in toks:
-            assert int(tok_d[dec._seq_lane[sid]]) == tok_h[sid]
-        toks = tok_h
-    # the compiled decode program really carries the trailing token head,
-    # under the name a driver finds it by
-    assert list(dec._dec_exe.output_dict)[-1].startswith("greedy_token")
-    assert tok_d.shape == (B,)
+    dec = _arch_decoder(arch, twin_rows=True)
+    nxt = _admit_prompts(dec)
+    first = next(iter(nxt))
+    for t in range(12):
+        out = dec.step(nxt if t % 3 else {first: nxt[first]})
+        assert len(out) == (2 if t % 3 else 1)
+        for sid, row in out.items():
+            before = _pulls(tm)
+            tok_d = int(np.argmax(row))
+            assert _pulls(tm) == before
+            logits = np.asarray(row)
+            assert isinstance(logits, np.ndarray)
+            tok_h = int(np.argmax(logits))
+            assert tok_d == tok_h
+            assert tok_h % 2 == 0 and logits[tok_h] == logits[tok_h + 1]
+            nxt[sid] = tok_h
+    # the compiled decode program really carries the token head, under the
+    # name the decoder finds it by
+    exe = dec._dec_exe
+    assert list(exe.output_dict)[dec._dec_token].startswith("greedy_token")
+    assert exe.outputs[dec._dec_token].shape == (dec.lanes,)
+
+
+def test_rows_that_are_only_argmaxed_leave_the_logits_on_the_device(tm):
+    """A greedy caller's step: the host copies one id a lane
+    (``serving.step.copy``'s ``bytes``) and the ``(lanes, vocab)`` block
+    never crosses: no pull counted, no ``serving.step.logits_pull`` span."""
+    tm.set_mode("trace")
+    dec = _tiny_paged().warmup()
+    nxt = _admit_prompts(dec)
+    tm.clear_events()
+    for _ in range(3):
+        out = dec.step(nxt)
+        nxt = {sid: int(np.argmax(row)) for sid, row in out.items()}
+        assert all(row.shape == (CFG["vocab_size"],)
+                   and row.dtype == np.float32
+                   and len(row) == CFG["vocab_size"] for row in out.values())
+    events = tm.drain_events()
+    copies = [e for e in events if e[0] == "serving.step.copy"]
+    assert [e[4]["bytes"] for e in copies] == [dec.lanes * 4] * 3
+    assert not [e for e in events if e[0] == "serving.step.logits_pull"]
+    assert _pulls(tm) == (0, 0)
+    assert tm.counters()["serving.paged_steps"] == 3
+
+
+def test_one_read_pulls_the_block_once_for_the_whole_step(tm):
+    """``np.asarray`` of any row moves the step's block to the host, inside
+    ``serving.step.logits_pull``, and every row of the step is a slice of
+    that one copy: the logits output's own lanes, bit for bit."""
+    tm.set_mode("trace")
+    dec = _tiny_paged().warmup()
+    nxt = _admit_prompts(dec)
+    block = dec.lanes * CFG["vocab_size"] * 4
+    for n in (1, 2):
+        out = dec.step(nxt)
+        want = dec._dec_exe.outputs[0].asnumpy()  # what the parent pulled
+        tm.clear_events()
+        for sid in reversed(sorted(out)):
+            got = np.asarray(out[sid])
+            assert got.dtype == np.float32
+            np.testing.assert_array_equal(got, want[dec._seq_lane[sid]])
+            assert _pulls(tm) == (n, n * block)
+        pulled = [e for e in tm.drain_events()
+                  if e[0] == "serving.step.logits_pull"]
+        assert [e[4]["bytes"] for e in pulled] == [block]
+        nxt = {sid: int(np.argmax(row)) for sid, row in out.items()}
+    assert tm.counters()["serving.paged_steps"] == 2
+
+
+def test_a_row_read_later_still_holds_its_own_steps_logits(tm):
+    """A row keeps ITS step's block, on the device, however many steps
+    follow before somebody reads it."""
+    tm.set_mode("counters")
+    dec = _tiny_paged().warmup()
+    nxt = _admit_prompts(dec)
+    kept, want = [], []
+    for _ in range(3):
+        out = dec.step(nxt)
+        kept.append(out)
+        want.append(dec._dec_exe.outputs[0].asnumpy().copy())
+        nxt = {sid: int(np.argmax(row)) for sid, row in out.items()}
+    assert _pulls(tm)[0] == 0
+    assert not np.array_equal(want[0], want[2])
+    for out, block in zip(kept, want):
+        for sid, row in out.items():
+            np.testing.assert_array_equal(row, block[dec._seq_lane[sid]])
+    assert _pulls(tm)[0] == 3
+
+
+def test_a_row_is_an_array_to_numpy(tm):
+    """What callers do with a row: index it, do arithmetic on it, stack it,
+    compare it, reduce it along an axis. All of it reads the same copy."""
+    tm.set_mode("counters")
+    dec = _tiny_paged().warmup()
+    out = dec.step(_admit_prompts(dec))
+    rows = [out[sid] for sid in sorted(out)]
+    want = dec._dec_exe.outputs[0].asnumpy()[
+        [dec._seq_lane[sid] for sid in sorted(out)]]
+    np.testing.assert_array_equal(np.stack(rows), want)
+    np.testing.assert_allclose(rows[0], want[0], rtol=0, atol=0)
+    assert rows[0][3] == want[0][3] and list(rows[1][:2]) == list(want[1][:2])
+    np.testing.assert_array_equal(rows[0] - rows[1], want[0] - want[1])
+    np.testing.assert_array_equal(2.0 * rows[0], 2.0 * want[0])
+    np.testing.assert_array_equal(np.exp(rows[1]), np.exp(want[1]))
+    np.testing.assert_array_equal(rows[0] > 0, want[0] > 0)
+    assert rows[0].max() == want[0].max() == np.max(rows[0])
+    assert np.argmax(rows[0], axis=-1) == rows[0].argmax() == want[0].argmax()
+    assert np.argsort(rows[1])[-1] == want[1].argmax()
+    assert rows[0].astype(np.float64).dtype == np.float64
+    assert _pulls(tm) == (1, dec.lanes * CFG["vocab_size"] * 4)
+    # with telemetry off the read touches no counter
+    tm.reset()
+    tm.set_mode(None)
+    env = os.environ.pop("MXNET_TELEMETRY", None)
+    try:
+        row = next(iter(dec.step({s: int(np.argmax(r))
+                                  for s, r in out.items()}).values()))
+        assert np.asarray(row).shape == (CFG["vocab_size"],)
+        assert _pulls(tm) == (0, 0)
+    finally:
+        if env is not None:
+            os.environ["MXNET_TELEMETRY"] = env
 
 
 def test_dispatch_host_gap_timer_ticks_only_when_enabled(tm):
@@ -483,7 +656,8 @@ def test_the_blocking_read_is_a_wait_and_then_a_copy(tm, path):
     tm.clear_events()
     if path == "step":
         dec.step({sid: tok})
-        under, crossed = "serving.decode_step", lanes * vocab * 4
+        # one id a lane: the logits stay on the device (``_LogitsRow``)
+        under, crossed = "serving.decode_step", lanes * 4
     elif path == "step_megastep":
         dec.step_megastep({sid: tok}, k=2)
         # (K, lanes) int32 ids and the (K, lanes) active mask
