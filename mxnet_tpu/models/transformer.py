@@ -8,7 +8,7 @@ is mean/var composed from broadcast ops to stay faithful to the op set)."""
 from .. import symbol as sym
 from ..base import MXNetError
 
-ARCHS = ("vaswani", "olmoe", "granite_hybrid", "deepseek_v3")
+ARCHS = ("vaswani", "olmoe", "granite_hybrid", "deepseek_v3", "lfm2_moe")
 
 
 def _refuse_arch(arch, what):
@@ -287,10 +287,21 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     comes ONE tensor a layer, the normed latent beside the rotated shared
     key, (B, 1, P, kv_lora_rank + qk_rope_head_dim): all the cache keeps;
     then ``moe_load (expert layers, experts)`` as for ``olmoe``.
+
+    ``arch="lfm2_moe"`` builds the gated-short-convolution / attention block
+    with sparse experts (``_lfm2_moe_layer``, its mixer chosen by
+    ``layer_types``, its feed-forward by depth). Its prefill takes
+    ``length`` (B, 1) as ``granite_hybrid``'s does: a convolution's state is
+    the last columns of the PROMPT, not of the bucket. After the logits come
+    the cache's values in ``decode_cache`` order: a conv layer's last
+    ``conv_kernel - 1`` gated columns before ``length``, float32, an
+    attention layer's K (normed per head and rotated) and V
+    (B, Hkv, P, dh); then ``moe_load``.
     """
     builders = {"olmoe": _olmoe_prefill_symbol,
                 "granite_hybrid": _granite_prefill_symbol,
-                "deepseek_v3": _deepseek_v3_prefill_symbol}
+                "deepseek_v3": _deepseek_v3_prefill_symbol,
+                "lfm2_moe": _lfm2_moe_prefill_symbol}
     if arch in builders:
         return builders[arch](
             vocab_size=vocab_size, num_layers=num_layers,
@@ -466,12 +477,20 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     outputs and the token head comes ``moe_load (expert layers, experts)``,
     the rows each expert received from ALL the lanes of the step.
 
+    ``arch="lfm2_moe"`` runs ``_lfm2_moe_layer``: an attention layer has
+    ``kv_k_i`` / ``kv_v_i`` (Hkv, max_len, dh), a conv layer takes and
+    returns ``conv_state_i`` (B, conv_kernel - 1, M), float32, one row a
+    lane, and ``write_slot`` also tells it which lanes ride along (their
+    rows come back bit for bit). The cache outputs follow the logits in
+    ``decode_cache`` order, then the token head, then ``moe_load``.
+
     ``page_size`` is the decoder's (``PagedKVDecoder``'s default here); it
     must divide ``max_len``.
     """
     builders = {"olmoe": _olmoe_decode_symbol,
                 "granite_hybrid": _granite_decode_symbol,
-                "deepseek_v3": _deepseek_v3_decode_symbol}
+                "deepseek_v3": _deepseek_v3_decode_symbol,
+                "lfm2_moe": _lfm2_moe_decode_symbol}
     if arch in builders:
         return builders[arch](
             vocab_size=vocab_size, num_layers=num_layers,
@@ -695,6 +714,16 @@ def _gated_mlp(fc, h, width, out_width, tag):
     return fc(gated, out_width, tag + "_out")
 
 
+def _grouped_qkv(fc, h, seq_len, hq, hkv, dh):
+    """Grouped-query attention's three head-major tensors from ONE bias-free
+    projection ``qkv`` of h (B, T, M), rows q, then k, then v, each
+    head-major: q (B, hq, T, dh), k and v (B, hkv, T, dh)."""
+    qkv = fc(h, (hq + 2 * hkv) * dh, "qkv")
+    return (_split_heads(
+        sym.slice_axis(qkv, axis=2, begin=a * dh, end=(a + n) * dh),
+        seq_len, n, dh) for a, n in ((0, hq), (hq, hkv), (hq + hkv, hkv)))
+
+
 def _granite_layer(x, i, seq_len, attend, scan, block):
     """One Granite 4.0-H block on x (B, T, M): pre-norm RMSNorm, a mixer
     chosen by ``layer_types[i]``, then the gated SiLU MLP every layer has,
@@ -732,10 +761,7 @@ def _granite_layer(x, i, seq_len, attend, scan, block):
     else:
         hq, hkv, dh = block["num_heads"], block["num_kv_heads"], \
             block["head_dim"]
-        qkv = fc(h, (hq + 2 * hkv) * dh, "qkv")
-        q, k, v = (_split_heads(
-            sym.slice_axis(qkv, axis=2, begin=a * dh, end=(a + n) * dh),
-            seq_len, n, dh) for a, n in ((0, hq), (hq, hkv), (hq + hkv, hkv)))
+        q, k, v = _grouped_qkv(fc, h, seq_len, hq, hkv, dh)
         mixed = fc(_merge_heads(attend(i, q, k, v), seq_len, hq * dh), d,
                    "proj")
     x = x + mixed * res
@@ -846,6 +872,23 @@ def _deepseek_v3_sizes(num_layers, num_heads, model_dim, ffn_dim=None,
         scale=float(qk_nope_head_dim + qk_rope_head_dim) ** -0.5)
 
 
+def _sigmoid_experts(h, name, block):
+    """Layer ``name``'s routed experts on h (B, T, M) -> ``MoEFeedForward``'s
+    (y (B·T, M), load (E,)): sigmoid scores, chosen on the score plus
+    ``<name>_router_bias``, weighted by the unbiased score, renormalised and
+    scaled as ``block`` says."""
+    return sym.MoEFeedForward(
+        sym.Reshape(h, shape=(-1, block["model_dim"])),
+        *(sym.Variable("%s_%s" % (name, w)) for w in (
+            "router_weight", "experts_gate_weight", "experts_up_weight",
+            "experts_down_weight", "router_bias")),
+        num_experts=block["num_experts"], num_hidden=block["moe_ffn_dim"],
+        num_experts_per_tok=block["num_experts_per_tok"], scoring="sigmoid",
+        router_bias=True, norm_topk_prob=block["norm_topk_prob"],
+        routed_scaling_factor=block["routed_scaling_factor"],
+        name="%s_moe" % name)
+
+
 def _deepseek_v3_layer(x, i, positions, seq_len, attend, block):
     """One ``model_type: deepseek_v3`` block (no query-side low-rank
     projection) on x (B, T, M) -> (x', load (E,) or None for a dense layer).
@@ -887,16 +930,7 @@ def _deepseek_v3_layer(x, i, positions, seq_len, attend, block):
     h = sym.RMSNorm(x, eps=eps, name="%s_ln2" % name)
     if i < block["first_dense_layers"]:
         return x + _gated_mlp(fc, h, block["ffn_dim"], d, "mlp"), None
-    moe = sym.MoEFeedForward(
-        sym.Reshape(h, shape=(-1, d)),
-        *(sym.Variable("%s_%s" % (name, w)) for w in (
-            "router_weight", "experts_gate_weight", "experts_up_weight",
-            "experts_down_weight", "router_bias")),
-        num_experts=block["num_experts"], num_hidden=block["moe_ffn_dim"],
-        num_experts_per_tok=block["num_experts_per_tok"], scoring="sigmoid",
-        router_bias=True, norm_topk_prob=block["norm_topk_prob"],
-        routed_scaling_factor=block["routed_scaling_factor"],
-        name="%s_moe" % name)
+    moe = _sigmoid_experts(h, name, block)
     shared = _gated_mlp(
         fc, h, block["num_shared_experts"] * block["moe_ffn_dim"], d,
         "shared")
@@ -1023,6 +1057,191 @@ def _deepseek_v3_param_shapes(vocab_size, num_layers, **sizes):
     return shapes
 
 
+# ------------------------------------------- LFM2-MoE (gated short convolutions)
+def _lfm2_moe_sizes(num_layers, num_heads, model_dim, ffn_dim, layer_types,
+                    moe_ffn_dim=None, num_kv_heads=None, head_dim=None,
+                    num_experts=64, num_experts_per_tok=4,
+                    first_dense_layers=2, conv_kernel=3, rope_theta=1e6,
+                    rms_eps=1e-5, routed_scaling_factor=1.0,
+                    norm_topk_prob=True, dtype="float32", **kwargs):
+    """``_lfm2_moe_layer``'s keywords from a builder's (``ffn_dim`` is the
+    leading dense layers' width, ``moe_ffn_dim`` one expert's; defaults:
+    LFM2-24B-A2B's; keywords of the other architectures are dropped)."""
+    kinds = tuple(layer_types)
+    if len(kinds) != num_layers or set(kinds) - {"conv", "full_attention"}:
+        raise MXNetError("lfm2_moe: layer_types must name %d layers, each "
+                         "'conv' or 'full_attention', got %r"
+                         % (num_layers, kinds))
+    if not 0 <= first_dense_layers <= num_layers:
+        raise MXNetError("lfm2_moe: first_dense_layers %d outside [0, %d]"
+                         % (first_dense_layers, num_layers))
+    return dict(
+        layer_types=kinds, num_heads=num_heads,
+        num_kv_heads=num_kv_heads or num_heads,
+        head_dim=head_dim or model_dim // num_heads, model_dim=model_dim,
+        ffn_dim=ffn_dim, moe_ffn_dim=moe_ffn_dim, num_experts=num_experts,
+        num_experts_per_tok=num_experts_per_tok,
+        first_dense_layers=first_dense_layers, conv_kernel=conv_kernel,
+        rope_theta=float(rope_theta), rms_eps=rms_eps,
+        routed_scaling_factor=float(routed_scaling_factor),
+        norm_topk_prob=bool(norm_topk_prob), dtype=dtype)
+
+
+def _lfm2_moe_layer(x, i, positions, seq_len, attend, conv, block):
+    """One ``model_type: lfm2_moe`` block on x (B, T, M) -> (x', load (E,)
+    or None for a dense layer): pre-norm RMSNorm, a mixer chosen by
+    ``layer_types[i]``, then a feed-forward chosen by depth.
+
+    ``conv``: one bias-free projection to [B | C | u], asked for in float32
+    (the mixer computes so); ``conv(i, bcu)`` runs the gated short
+    convolution (ops/shortconv.py) and returns y (B, T, M), float32; back to
+    the weights' type and through the output projection. ``full_attention``:
+    one bias-free projection to [q | k | v] with fewer key/value heads than
+    query heads; q and k are normed PER HEAD (over ``head_dim`` features, one
+    gamma for all heads) BEFORE their rotation; ``attend(i, q, k, v)`` takes
+    the head-major (B, H or Hkv, T, dh) tensors and returns (B, H, T, dh).
+    ``conv`` and ``attend`` are the two things the prefill and the decode
+    graph do differently. The feed-forward: the gated SiLU MLP in the first
+    ``first_dense_layers`` layers; after them sigmoid-routed experts alone
+    (``_sigmoid_experts``: no shared one)."""
+    name = "layer%d" % i
+    d, eps = block["model_dim"], block["rms_eps"]
+    fc = lambda data, width, tag, **kw: sym.FullyConnected(
+        data=data, num_hidden=width, no_bias=True, flatten=False,
+        name="%s_%s" % (name, tag), **kw)
+    h = sym.RMSNorm(x, eps=eps, name="%s_ln1" % name)
+    if block["layer_types"][i] == "conv":
+        y = conv(i, fc(h, 3 * d, "conv_in", out_dtype="float32"))
+        mixed = fc(sym.Cast(y, dtype=block["dtype"]), d, "conv_out")
+    else:
+        hq, hkv, dh = block["num_heads"], block["num_kv_heads"], \
+            block["head_dim"]
+        q, k, v = _grouped_qkv(fc, h, seq_len, hq, hkv, dh)
+        q, k = (sym.RotaryEmbedding(
+            sym.RMSNorm(a, eps=eps, name="%s_%snorm" % (name, tag)),
+            positions, base=block["rope_theta"],
+            name="%s_%srope" % (name, tag)) for a, tag in ((q, "q"), (k, "k")))
+        mixed = fc(_merge_heads(attend(i, q, k, v), seq_len, hq * dh), d,
+                   "proj")
+    x = x + mixed
+    h = sym.RMSNorm(x, eps=eps, name="%s_ln2" % name)
+    if i < block["first_dense_layers"]:
+        return x + _gated_mlp(fc, h, block["ffn_dim"], d, "mlp"), None
+    moe = _sigmoid_experts(h, name, block)
+    return x + sym.Reshape(moe[0], shape=(-1, seq_len, d)), moe[1]
+
+
+def _lfm2_moe_stack(vocab_size, seq_len, positions, attend, conv, block):
+    """Embedding (tied to the head), the layers, the final norm and the head:
+    ``data`` (B, T) -> (float32 logits (B·T, vocab), moe_load (expert layers,
+    experts))."""
+    table = sym.Variable("embed_weight")
+    d = block["model_dim"]
+    x = sym.Embedding(data=sym.Variable("data"), weight=table,
+                      input_dim=vocab_size, output_dim=d, name="embed")
+    loads = []
+    for i in range(len(block["layer_types"])):
+        x, load = _lfm2_moe_layer(x, i, positions, seq_len, attend, conv,
+                                  block)
+        if load is not None:
+            loads.append(sym.Reshape(load, shape=(1, -1)))
+    x = sym.RMSNorm(x, eps=block["rms_eps"], name="final_ln")
+    logits = sym.FullyConnected(
+        data=sym.Reshape(x, shape=(-1, d)), weight=table,
+        num_hidden=vocab_size, no_bias=True, out_dtype="float32",
+        name="lm_head")
+    return logits, [sym.Concat(*loads, dim=0, name="moe_load")] if loads \
+        else []
+
+
+def _lfm2_conv(op, i, bcu, block, **inputs):
+    """One of ops/shortconv.py's two operators on conv layer ``i``'s taps."""
+    return op(bcu, sym.Variable("layer%d_conv_weight" % i),
+              kernel=block["conv_kernel"], name="layer%d_conv_core" % i,
+              **inputs)
+
+
+def _lfm2_moe_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
+    block = _lfm2_moe_sizes(num_layers, **sizes)
+    length = sym.Variable("length")     # (B, 1): real tokens of the bucket
+    positions = sym.Reshape(sym._arange(start=0, stop=prefill_len),
+                            shape=(1, prefill_len))
+    cache = []      # the layers are built in order, so is this
+
+    def attend(i, q, k, v):
+        cache.extend([k, v])    # the key as the pool keeps it: normed, rotated
+        return sym.MultiHeadAttention(query=q, key=k, value=v, causal=True,
+                                      name="layer%d_att" % i)
+
+    def conv(i, bcu):
+        core = _lfm2_conv(sym.GatedShortConv, i, bcu, block, length=length)
+        cache.append(core[1])
+        return core[0]
+
+    logits, load = _lfm2_moe_stack(vocab_size, prefill_len, positions,
+                                   attend, conv, block)
+    return sym.Group([logits] + cache + load)
+
+
+def _lfm2_moe_decode_symbol(vocab_size, num_layers, num_slots, page_size,
+                            token_out=True, **sizes):
+    block = _lfm2_moe_sizes(num_layers, **sizes)
+    hq, hkv, dh = (block[k] for k in ("num_heads", "num_kv_heads", "head_dim"))
+    pos_idx = sym.Variable("pos_idx")
+    write_slot = sym.Variable("write_slot")
+    oh, read = _pool_step_inputs(pos_idx, num_slots, page_size, write_slot)
+    cache = []      # the layers are built in order, so is this
+
+    def attend(i, q, k_new, v_new):
+        # one token a lane: the head-major (B, H, 1, dh) tensors are the
+        # pool's rows (B, H, dh)
+        q, k_new, v_new = (sym.Reshape(a, shape=(-1, n, dh)) for a, n in
+                           ((q, hq), (k_new, hkv), (v_new, hkv)))
+        ctx = _pool_attend(i, q, k_new, v_new, oh, read, cache)
+        return sym.Reshape(ctx, shape=(-1, hq, 1, dh))
+
+    def conv(i, bcu):
+        core = _lfm2_conv(
+            sym.GatedShortConvStep, i, sym.Reshape(bcu, shape=(0, -1)), block,
+            conv_state=sym.Variable("conv_state_%d" % i), stepped=write_slot)
+        cache.append(core[1])
+        return sym.Reshape(core[0], shape=(0, 1, -1))
+
+    logits, load = _lfm2_moe_stack(vocab_size, 1, pos_idx, attend, conv,
+                                   block)
+    # moe_load LAST: the cache and the token head keep their places
+    return sym.Group([_token_head(
+        logits, cache, "greedy_token" if token_out else None)] + load)
+
+
+def _lfm2_moe_param_shapes(vocab_size, num_layers, **sizes):
+    block = _lfm2_moe_sizes(num_layers, **sizes)
+    d, e, f = block["model_dim"], block["num_experts"], block["moe_ffn_dim"]
+    hq, hkv, dh = (block[k] for k in ("num_heads", "num_kv_heads", "head_dim"))
+    shapes = {"embed_weight": (vocab_size, d), "final_ln_gamma": (d,)}
+    for i, kind in enumerate(block["layer_types"]):
+        n = "layer%d_" % i
+        shapes.update({n + "ln1_gamma": (d,), n + "ln2_gamma": (d,)})
+        if kind == "conv":
+            shapes.update({n + "conv_in_weight": (3 * d, d),
+                           n + "conv_weight": (block["conv_kernel"], d),
+                           n + "conv_out_weight": (d, d)})
+        else:
+            shapes.update({n + "qkv_weight": ((hq + 2 * hkv) * dh, d),
+                           n + "qnorm_gamma": (dh,), n + "knorm_gamma": (dh,),
+                           n + "proj_weight": (d, hq * dh)})
+        if i < block["first_dense_layers"]:
+            shapes.update({n + "mlp_in_weight": (2 * block["ffn_dim"], d),
+                           n + "mlp_out_weight": (d, block["ffn_dim"])})
+            continue
+        shapes.update({
+            n + "router_weight": (e, d), n + "router_bias": (e,),
+            n + "experts_gate_weight": (e, d, f),
+            n + "experts_up_weight": (e, d, f),
+            n + "experts_down_weight": (e, f, d)})
+    return shapes
+
+
 def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
                  **sizes):
     """What a decode graph of ``arch`` keeps between steps, in the order its
@@ -1031,28 +1250,35 @@ def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
     is (heads, dh) and the buffer (heads, slots, dh); a ``"row"`` is
     addressed by lane, ``shape`` is one lane's and the buffer (lanes,) +
     shape, float32. Latent attention keeps ONE pool a layer, of one head:
-    the old kind, no new one."""
+    the old kind, no new one. Where ``layer_types`` chooses the mixer
+    (``granite_hybrid``, ``lfm2_moe``) the list mixes the two kinds, in layer
+    order."""
     if arch == "deepseek_v3":
         block = _deepseek_v3_sizes(num_layers, num_heads=num_heads,
                                    model_dim=model_dim, **sizes)
         return [("kv_c_%d" % i, "pool", (1, block["latent"] + block["rope"]))
                 for i in range(num_layers)]
-    if arch != "granite_hybrid":
+    if arch not in ("granite_hybrid", "lfm2_moe"):
         pool = (num_heads, head_dim or model_dim // num_heads)
         return [("kv_%s_%d" % (t, i), "pool", pool)
                 for i in range(num_layers) for t in "kv"]
-    block = _granite_sizes(num_layers, num_heads=num_heads,
-                           model_dim=model_dim, head_dim=head_dim, **sizes)
-    h, p, n = (block[k] for k in ("mamba_heads", "mamba_head_dim",
-                                  "mamba_state"))
-    per_kind = {
-        "attention": [("kv_k_%d", "pool",
-                       (block["num_kv_heads"], block["head_dim"])),
-                      ("kv_v_%d", "pool",
-                       (block["num_kv_heads"], block["head_dim"]))],
-        "mamba": [("ssm_state_%d", "row", (h, p, n)),
-                  ("conv_state_%d", "row",
-                   (block["mamba_conv"] - 1, h * p + 2 * n))]}
+    # a mixer chosen by layer_types: pools where it attends, rows elsewhere
+    sizes.update(num_heads=num_heads, model_dim=model_dim, head_dim=head_dim)
+    if arch == "lfm2_moe":
+        block = _lfm2_moe_sizes(num_layers, **sizes)
+        attends = "full_attention"
+        per_kind = {"conv": [("conv_state_%d", "row", (
+            block["conv_kernel"] - 1, block["model_dim"]))]}
+    else:
+        block = _granite_sizes(num_layers, **sizes)
+        h, p, n = (block[k] for k in ("mamba_heads", "mamba_head_dim",
+                                      "mamba_state"))
+        attends = "attention"
+        per_kind = {"mamba": [("ssm_state_%d", "row", (h, p, n)),
+                              ("conv_state_%d", "row",
+                               (block["mamba_conv"] - 1, h * p + 2 * n))]}
+    pool = (block["num_kv_heads"], block["head_dim"])
+    per_kind[attends] = [("kv_k_%d", "pool", pool), ("kv_v_%d", "pool", pool)]
     return [(name % i, kind, shape)
             for i, layer in enumerate(block["layer_types"])
             for name, kind, shape in per_kind[layer]]
@@ -1098,6 +1324,11 @@ def param_shapes(arch, vocab_size, num_layers, num_heads, model_dim, ffn_dim,
         return _deepseek_v3_param_shapes(
             vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
             ffn_dim=ffn_dim, num_experts=num_experts, **kwargs)
+    if arch == "lfm2_moe":
+        return _lfm2_moe_param_shapes(
+            vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
+            ffn_dim=ffn_dim, head_dim=head_dim, num_experts=num_experts,
+            **kwargs)
     if arch != "olmoe":
         raise MXNetError("param_shapes knows archs %s, not %r" % (
             ", ".join(repr(a) for a in ARCHS if a != "vaswani"), arch))

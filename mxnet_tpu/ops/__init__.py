@@ -24,6 +24,7 @@ from . import vision  # noqa: F401
 from . import attention  # noqa: F401
 from . import moe  # noqa: F401
 from . import ssm  # noqa: F401
+from . import shortconv  # noqa: F401
 from . import custom  # noqa: F401
 
 __all__ = [

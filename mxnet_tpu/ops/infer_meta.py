@@ -207,6 +207,15 @@ register_meta("_contrib_Mamba2Step",
                                conv_state=3, stepped=2),
               dtype_policy="first", param_slots=tuple(_MAMBA2_WEIGHTS),
               aliases=("Mamba2Step",))
+register_meta("_contrib_GatedShortConv",
+              input_ranks={"data": 3, "weight": 2, "length": 2},
+              dtype_policy="first", param_slots=("weight",),
+              aliases=("GatedShortConv",))
+register_meta("_contrib_GatedShortConvStep",
+              input_ranks={"data": 2, "weight": 2, "conv_state": 3,
+                           "stepped": 2},
+              dtype_policy="first", param_slots=("weight",),
+              aliases=("GatedShortConvStep",))
 register_meta("RNN",
               input_ranks={"data": 3, "parameters": 1,
                            "state": 3, "state_cell": 3},

@@ -200,6 +200,30 @@ def _mamba2_step(attrs, shapes):
     return shapes
 
 
+@rule("_contrib_GatedShortConv")
+@rule("GatedShortConv")
+def _gated_short_conv(attrs, shapes):
+    data = shapes[0]
+    if data is not None:            # (B, T, 3d): taps (K, d), length (B, 1)
+        if shapes[1] is None:
+            shapes[1] = (attrs.get("kernel", 3), data[-1] // 3)
+        if shapes[2] is None:
+            shapes[2] = (data[0], 1)
+    return shapes
+
+
+@rule("_contrib_GatedShortConvStep")
+@rule("GatedShortConvStep")
+def _gated_short_conv_step(attrs, shapes):
+    data = shapes[0]
+    if data is not None:            # (R, 3d): one token a row
+        k, d = attrs.get("kernel", 3), data[-1] // 3
+        for i, s in ((1, (k, d)), (2, (data[0], k - 1, d)), (3, (data[0], 1))):
+            if shapes[i] is None:
+                shapes[i] = s
+    return shapes
+
+
 @rule("RNN")
 def _rnn_shapes(attrs, shapes):
     data = shapes[0]

@@ -96,6 +96,14 @@ def _chunked_scan(x, dt, a, b, c, chunk):
     return jnp.moveaxis(y, 0, 1).reshape(bsz, nc * q, h, p)[:, :t], state
 
 
+def columns_before(padded, length, count):
+    """The ``count`` rows of each ``padded`` (B, count + T, C) that precede
+    position ``length`` (B,) of its sequence, (B, count, C): position p sits
+    at padded index p + count, so they start at padded index ``length``."""
+    return jax.vmap(lambda row, at: jax.lax.dynamic_slice_in_dim(
+        row, at, count, axis=0))(padded, length)
+
+
 @register(
     "_contrib_Mamba2Scan",
     attrs=dict(_SIZES, chunk_size=AttrSpec("int", default=256)),
@@ -126,10 +134,7 @@ def _mamba2_scan(attrs, data, dt, conv_weight, conv_bias, dt_bias, A_log, D,
     padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
     conv = jax.nn.silu(bias + sum(padded[:, j:j + t] * w[:, j]
                                   for j in range(k)))
-    # position p sits at padded index p + K - 1, so the K-1 columns before
-    # the length start at padded index ``length``
-    conv_state = jax.vmap(lambda row, at: jax.lax.dynamic_slice_in_dim(
-        row, at, k - 1, axis=0))(padded, n_real)
+    conv_state = columns_before(padded, n_real, k - 1)
     x, b, c = _split(attrs, conv)
     live = jnp.arange(t)[None, :] < n_real[:, None]
     dt = jnp.where(live[..., None], jax.nn.softplus(dt + dt_bias), 0.0)

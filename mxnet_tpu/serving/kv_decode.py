@@ -12,9 +12,9 @@ per sequence, and the lanes share ONE slot axis (``lanes * max_len`` slots
 per layer, KV ``(H, slots, dh)``) carved into refcounted page frames. What
 the decode graph keeps between steps is a list of named buffers the model
 gives (``models.transformer.decode_cache``), of two kinds: those pools,
-addressed by slot, and, where a layer keeps a recurrent state instead
-(``arch="granite_hybrid"``), per-lane rows ``(lanes, ...)`` addressed by
-lane. A
+addressed by slot, and, where a layer keeps a recurrent state or its last
+convolution columns instead (``arch="granite_hybrid"``, ``"lfm2_moe"``),
+per-lane rows ``(lanes, ...)`` addressed by lane. A
 token's write happens IN-GRAPH, through one-hot rows the program makes of
 each lane's ``write_slot`` (models/transformer.py ``get_decode_symbol``), as
 it makes each lane's attention mask of its ``page_table``: a step hands the
@@ -841,6 +841,14 @@ class PagedKVDecoder:
     lane. The prefill is told the prompt's length. ``fork`` and ``rollback``
     raise too: a state that is one row cannot be shared or taken back
     without a snapshot.
+
+    ``arch="lfm2_moe"`` serves the gated-short-convolution / attention block
+    with sparse experts (``layer_types``, ``num_kv_heads``, ``moe_ffn_dim``,
+    ``first_dense_layers``, ``conv_kernel``...): K/V pools for its attention
+    layers, a per-lane float32 row of the last ``conv_kernel - 1`` gated
+    columns for every conv layer, handed over and advanced as
+    ``granite_hybrid``'s rows are, and the experts' load read from both
+    graphs as for ``deepseek_v3``. It refuses what both of those refuse.
     """
 
     def __init__(self, arg_params: Dict[str, object], vocab_size,

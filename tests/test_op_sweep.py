@@ -654,6 +654,8 @@ EXEMPT = {
     "WarpCTC": "tests/test_ctc.py",
     "_contrib_MultiBoxDetection": "tests/test_vision.py",
     "_contrib_MultiBoxPrior": "tests/test_vision.py",
+    "_contrib_GatedShortConv": "tests/test_lfm2_moe_block.py",
+    "_contrib_GatedShortConvStep": "tests/test_lfm2_moe_block.py",
     "_contrib_KVPageMask": "tests/test_kv_pool_ops.py",
     "_contrib_KVPoolAttention": "tests/test_kv_pool_ops.py",
     "_contrib_KVPoolWrite": "tests/test_kv_pool_ops.py",

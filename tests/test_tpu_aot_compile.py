@@ -453,6 +453,109 @@ def test_latent_attention_serving_programs_compile_for_the_chip(v5e, program):
         assert mem.temp_size_in_bytes < 400 << 20
 
 
+_LFM2 = dict(arch="lfm2_moe", vocab_size=65536, num_layers=10, num_heads=32,
+             num_kv_heads=8, head_dim=64, model_dim=2048, ffn_dim=11776,
+             moe_ffn_dim=1536, num_experts=64, num_experts_per_tok=4,
+             first_dense_layers=2,
+             layer_types=["conv", "conv", "full_attention", "conv", "conv",
+                          "conv", "full_attention", "conv", "conv", "conv"],
+             conv_kernel=3, rope_theta=1e6, rms_eps=1e-5,
+             routed_scaling_factor=1.0, norm_topk_prob=True, dtype="bfloat16")
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode", "admit_scatter"])
+def test_lfm2_moe_serving_programs_compile_for_the_chip(v5e, program):
+    """The three programs ``PagedKVDecoder(arch="lfm2_moe")`` runs, lowered
+    for the v5e at LFM2-24B-A2B's published widths, all ten layers of the
+    benchmark's cut (5,267,090,176 parameters in bfloat16) and its serving
+    sizes (64 lanes x 2,048 slots, a 1,024 bucket). What has to hold on the
+    chip: the cache is a (64, 2, 2,048) float32 row a conv layer and two
+    (8, 131,072, 64) pools an attention layer, in layer order, each back in
+    the type it went in; the step's two attention layers read a lane's OWN
+    PAGES, as ``pool_read_own_pages`` says of (64, 32, 64) queries over two
+    pools of 8 x 131,072 x 64 (2.7 GB a layer against 3.5 for the whole
+    pool), so no (64, 131,072) mask is built and nothing as large as the
+    lanes' scores over the pool is made; the conv operators are in the
+    program under their nodes' names; both graphs keep the grouped matmul
+    and report the experts' load last; and everything fits beside the
+    weights: the step's temporaries under 0.7 GB, the admission's under 0.1."""
+    from types import SimpleNamespace
+
+    from mxnet_tpu.models import transformer as tf
+    from mxnet_tpu.ops.attention import pool_read_own_pages
+    from mxnet_tpu.serving.kv_decode import _AdmitScatter
+
+    lanes, max_len, bucket, page = 64, 2048, 1024, 16
+    slots, cfg = lanes * max_len, _LFM2
+    weights = {n: (s, "bfloat16") for n, s in tf.param_shapes(**cfg).items()}
+    cache = tf.decode_cache(**cfg)
+    assert [kind for _, kind, _ in cache] == \
+        ["row", "row", "pool", "pool", "row", "row", "row", "pool", "pool",
+         "row", "row", "row"]
+    buffers = [((8, slots, 64), "bfloat16") if kind == "pool"
+               else ((lanes, 2, 2048), "float32") for _, kind, _ in cache]
+    if program == "admit_scatter":
+        prog = _AdmitScatter(SimpleNamespace(
+            _cache=cache, page_size=page, prefill_len=bucket))
+        spec = lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, jnp.dtype(dtype), sharding=v5e)
+        new = [((1, 8, bucket, 64), "bfloat16") if kind == "pool"
+               else ((1, 2, 2048), "float32") for _, kind, _ in cache]
+        mem = prog._fn.lower(
+            tuple(spec(*b) for b in buffers), tuple(spec(*n) for n in new),
+            spec((bucket // page,), "int32"), spec((2,), "int32"),
+        ).compile().memory_analysis()
+        # every buffer of the cache is updated in place, pools and rows
+        assert mem.alias_size_in_bytes == 4 * 8 * slots * 64 * 2 \
+            + 8 * lanes * 2 * 2048 * 4
+        assert mem.temp_size_in_bytes < 1 << 20
+        return
+    if program == "prefill":
+        sym = tf.get_prefill_symbol(prefill_len=bucket, **cfg)
+        inputs = {"data": ((1, bucket), "float32"),
+                  "length": ((1, 1), "float32")}
+        want = [((bucket, 65536), "float32")] \
+            + [((1, 8, bucket, 64), "bfloat16") if kind == "pool"
+               else ((1, 2, 2048), "float32") for _, kind, _ in cache] \
+            + [((8, 64), "float32")]
+    else:
+        sym = tf.get_decode_symbol(max_len=slots, page_size=page, **cfg)
+        inputs = {"data": ((lanes, 1), "float32"),
+                  "pos_idx": ((lanes, 1), "float32"),
+                  "write_slot": ((lanes, 1), "float32"),
+                  "page_table": ((lanes, max_len // page), "float32")}
+        inputs.update({name: b for (name, _, _), b in zip(cache, buffers)})
+        want = [((lanes, 65536), "float32")] + buffers \
+            + [((lanes,), "float32"), ((8, 64), "float32")]
+    compiled = _compile_program(v5e, sym, {**weights, **inputs})
+    assert [(s.shape, str(s.dtype)) for s in compiled.out_info[0]] == want
+    hlo = compiled.as_text()
+    assert "ragged" in hlo.lower()
+    for i in (0, 1, 3, 4, 5, 7, 8, 9):
+        assert "layer%d_conv_core/" % i in hlo
+    mem = compiled.memory_analysis()
+    if program == "prefill":
+        assert mem.temp_size_in_bytes < 100 << 20
+        return
+    # the rule, asked as the operator asks it
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+    pool = struct((8, slots, 64), "bfloat16")
+    assert pool_read_own_pages(
+        struct((lanes, 32, 64), "bfloat16"), pool, pool,
+        struct((lanes, max_len // page), "float32"), page)
+    found = [(math.prod(int(d) for d in dims.split(",") if d), op)
+             for _n, dims, op, _a in _INSTRUCTION.findall(hlo)
+             if op != "parameter"]
+    # nothing made is as large as the lanes' scores over the pool (268 M
+    # elements), and no read's mask is built
+    assert not [n for n, _ in found if n >= lanes * 32 * slots]
+    assert "kv_mask" not in hlo and "slot_onehot" in hlo
+    gathers = [line for line in hlo.splitlines() if " gather(" in line]
+    for i in (2, 6):    # a gather for the keys, one for the values
+        assert sum("layer%d_att/" % i in g for g in gathers) == 2
+    assert mem.temp_size_in_bytes < 700 << 20
+
+
 def _program_alone(hlo):
     """A compiled program's text without what names the source it was
     traced from: the module's name (the graph's last node, numbered as it
