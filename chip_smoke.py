@@ -511,14 +511,35 @@ class Reference:
 
 def check_step_inputs(onehot, slots):
     """What a decode step makes on the device of a write slot and a page
-    table a lane (``KVSlotOneHot``, ``KVPageMask``) against the host's
-    arrays: ``onehot``, whose last lane is idle, and the mask of lanes at
-    random positions in frames scattered over the pool; float32, exactly."""
-    from mxnet_tpu.ops.attention import _kv_page_mask, _kv_slot_onehot
+    table a lane against the host's arrays: the write by slot index
+    (``KVPoolSlotWrite``, the pool DONATED as the decode program takes it)
+    against the host's one-hot blend (``KVPoolWrite`` fed ``onehot``, whose
+    last lane is idle), bit for bit in both pool types, and ``KVPageMask``
+    against the mask of lanes at random positions in frames scattered over
+    the pool; float32, exactly."""
+    from mxnet_tpu.ops.attention import (_kv_page_mask, _kv_pool_slot_write,
+                                         _kv_pool_write)
 
     (R, S), page = onehot.shape, SZ["page"]
+    H, D = SZ["pool"][1], SZ["pool"][3]
     write_slot = np.append(slots, -1).astype("float32")[:, None]
-    got = jax.jit(lambda w: _kv_slot_onehot({"num_slots": S}, w))(write_slot)
+    for dt in ("float32", "bfloat16"):
+        k1, k2 = jax.random.split(jax.random.PRNGKey(7 + len(dt)))
+        pool = jax.random.normal(k1, (H, S, D), jnp.float32).astype(dt)
+        rows = jax.random.normal(k2, (R, H, D), jnp.float32).astype(dt)
+        want = jax.jit(lambda *a: _kv_pool_write({}, *a))(pool, rows, onehot)
+        held = pool.unsafe_buffer_pointer()
+        got, = jax.jit(lambda *a: _kv_pool_slot_write({}, *a),
+                       donate_argnums=(0,))(pool, rows, write_slot)
+        bits = jnp.uint16 if dt == "bfloat16" else jnp.uint32
+        same = jax.jit(lambda a, b: jnp.all(
+            jax.lax.bitcast_convert_type(a, bits)
+            == jax.lax.bitcast_convert_type(b, bits)))
+        check(bool(same(got, want)) and pool.is_deleted()
+              and got.unsafe_buffer_pointer() == held,
+              "KVPoolSlotWrite %s %s: the host's one-hot blend, bit for "
+              "bit, written into the donated pool's own buffer"
+              % (dt, (H, S, D)))
     rs = np.random.RandomState(13)
     max_pages = S // R // page
     table = rs.permutation(S // page)[:R * max_pages].reshape(R, max_pages)
@@ -534,16 +555,15 @@ def check_step_inputs(onehot, slots):
         {"page_size": page, "num_slots": S}, *a))(
             table.astype("float32"), pos.astype("float32"),
             phys.astype("float32"))
-    check(got.dtype == got_mask.dtype == jnp.float32
-          and bool(jnp.all(got == onehot)) and bool(jnp.all(got_mask == mask)),
-          "KVSlotOneHot and KVPageMask %s, pages of %d: the host's one-hots "
-          "and masks, element for element" % ((R, S), page))
+    check(got_mask.dtype == jnp.float32 and bool(jnp.all(got_mask == mask)),
+          "KVPageMask %s, pages of %d: the host's masks, element for element"
+          % ((R, S), page))
 
 
 def check_pool_operators():
-    """The decode step's write into the shared pool and its read of it
-    (ops/attention.py), at the benchmark's lanes x heads x slots x dh. The
-    write against the blend of broadcast products it replaced, which
+    """The chunk's write into the shared pool, a decode step's write and
+    its read of it (ops/attention.py), at the benchmark's lanes x heads x
+    slots x dh. The one-hot write against the blend of broadcast products it replaced, which
     multiplies by exactly 0 and 1: bit for bit, float32 (on the chip only
     ``Precision.HIGHEST`` keeps a row's 24 bits through the one-hot matmul)
     and bfloat16. The read, two default-precision contractions, against the

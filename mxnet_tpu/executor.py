@@ -44,12 +44,13 @@ def _named(fn, name):
     return fn
 
 
-def _compiled_cost(lowered):
-    """XLA's cost analysis of a lowered jit call, compiled ahead of time:
-    a dict with ``flops`` and ``bytes accessed``. The AOT compile does not
-    share jit's executable cache, so this costs one compile (or one load
-    from the persistent compile cache)."""
-    cost = lowered.compile().cost_analysis()
+def _cost_of(compiled):
+    """XLA's cost analysis of a jit call compiled ahead of time
+    (``lower(...).compile()``): a dict with ``flops`` and ``bytes
+    accessed``. The AOT compile does not share jit's executable cache, so
+    it costs one compile (or one load from the persistent compile
+    cache)."""
+    cost = compiled.cost_analysis()
     return cost[0] if isinstance(cost, (list, tuple)) else cost
 
 
@@ -57,6 +58,41 @@ def _signature(arrays):
     """What ``jax.jit`` keys a call's arrays on, as far as the telemetry
     tells calls apart: each one's shape and type."""
     return tuple((tuple(a.shape), str(a.dtype)) for a in arrays)
+
+
+class _DonatedForward:
+    """A forward program jitted with some of its arguments DONATED, called
+    and lowered as the plain one is, ``(args, aux, rng)``. ``jax.jit``
+    donates whole parameters, so the jitted function takes the donated
+    arguments (``donated``: their indices among ``n_args``) as one tuple and
+    the rest as another, and puts them back in order before ``run``."""
+
+    def __init__(self, run, name, donated, n_args):
+        import jax
+
+        given = set(donated)
+        kept = [i for i in range(n_args) if i not in given]
+
+        def merged(held, rest, aux, rng):
+            args = [None] * n_args
+            for i, a in zip(donated, held):
+                args[i] = a
+            for i, a in zip(kept, rest):
+                args[i] = a
+            return run(tuple(args), aux, rng)
+
+        self._fn = jax.jit(_named(merged, name), donate_argnums=(0,))
+        self._donated, self._kept = tuple(donated), tuple(kept)
+
+    def _split(self, args):
+        return (tuple(args[i] for i in self._donated),
+                tuple(args[i] for i in self._kept))
+
+    def __call__(self, args, aux, rng):
+        return self._fn(*self._split(args), aux, rng)
+
+    def lower(self, args, aux, rng):
+        return self._fn.lower(*self._split(args), aux, rng)
 
 
 class _GraphProgram:
@@ -68,6 +104,11 @@ class _GraphProgram:
     def __init__(self, symbol, group2ctx=None, fusion=True):
         self.symbol = symbol
         self.label = None  # set by the owner before the first call
+        # arguments the forward program takes DONATED, by name (likewise):
+        # it may update them in place, and after a call the arrays that went
+        # in are dead; whoever owns the executor hands the outputs that took
+        # their place back (``Executor.rebind``). None donated: a plain jit
+        self.donated = ()
         self.topo = symbol._topo()
         self.group2ctx = dict(group2ctx or {})
         # fusion plan (fusion.py): structural rewrite map covering the
@@ -274,7 +315,11 @@ class _GraphProgram:
         def run(args, aux, rng):
             return self.interpret(args, aux, is_train, rng)
 
-        self._jit_cache[key] = jax.jit(_named(run, self.program_name("fwd")))
+        name = self.program_name("fwd")
+        self._jit_cache[key] = _DonatedForward(
+            run, name, [self._arg_index[n] for n in self.donated],
+            len(self.arg_names)) if self.donated \
+            else jax.jit(_named(run, name))
         return self._jit_cache[key]
 
     def _fwd_bwd_cached(self, with_head_grads):
@@ -435,16 +480,25 @@ class Executor:
             self._write_aux(new_aux)
         return self._set_outputs(outs)
 
+    def compiled(self, is_train=False):
+        """The bound forward program as it is dispatched (donated arguments
+        donated), compiled ahead of time at the bound shapes: what
+        ``cost_analysis()`` and ``memory_analysis()`` are asked of. The AOT
+        compile does not share jit's executable cache, so this costs one
+        compile (or one load from the persistent compile cache). Executes
+        nothing, donates nothing and draws no random key."""
+        import jax
+
+        args, aux = self._collect()
+        return self._prog._fwd(bool(is_train)).lower(
+            args, aux, jax.random.PRNGKey(0)).compile()
+
     def cost_analysis(self, is_train=False):
         """XLA's cost analysis of the bound forward program at the bound
         shapes — a dict with ``flops`` and ``bytes accessed`` (the
         compiler's count for its own program, not the least the algorithm
-        needs). Executes nothing and draws no random key."""
-        import jax
-
-        args, aux = self._collect()
-        return _compiled_cost(self._prog._fwd(bool(is_train)).lower(
-            args, aux, jax.random.PRNGKey(0)))
+        needs)."""
+        return _cost_of(self.compiled(is_train))
 
     def _note_telemetry(self, sp, key, args, aux, extra=()):
         """Count compile/cache_hit/retrace for this call and attach the

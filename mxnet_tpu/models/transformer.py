@@ -314,42 +314,50 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     return sym.Group([logits] + kvs)
 
 
-def _pool_attend(i, q, k_new, v_new, onehot, read, kv_outs, **attrs):
+def _pool_attend(i, q, k_new, v_new, write, read, kv_outs, **attrs):
     """Layer ``i``'s write into and read of the ONE shared KV pool, on rows
-    (N, H, dh): each row's new K/V lands in its one-hot slot of ``kv_k_i`` /
-    ``kv_v_i`` (H, slots, dh), which come back in the type they went in and
-    are collected in ``kv_outs``; then each row reads the updated pool as
-    ``read`` says, ``KVPoolAttention``'s operands after the pools by name:
-    its additive float32 ``mask`` (N, slots) and, in a decode step, what the
-    mask was made of (``_pool_step_inputs``). ``attrs``: a ``scale`` other
-    than 1/sqrt(dh). Returns the context (N, H, dh)."""
-    upd = [sym.KVPoolWrite(sym.Variable("kv_%s_%d" % (tag, i)), new, onehot,
-                           name="layer%d_%supd" % (i, tag))
-           for tag, new in (("k", k_new), ("v", v_new))]
+    (N, H, dh): ``write(i, {"k": k_new, "v": v_new})`` puts each row's new
+    K/V into its slot of ``kv_k_i`` / ``kv_v_i`` (H, slots, dh), which come
+    back in the type they went in and are collected in ``kv_outs``; then
+    each row reads the updated pool as ``read`` says, ``KVPoolAttention``'s
+    operands after the pools by name: its additive float32 ``mask``
+    (N, slots) and, in a decode step, what the mask was made of
+    (``_pool_step_inputs``). ``attrs``: a ``scale`` other than 1/sqrt(dh).
+    Returns the context (N, H, dh)."""
+    upd = write(i, {"k": k_new, "v": v_new})
     kv_outs += upd
     return sym.KVPoolAttention(q, upd[0], upd[1], name="layer%d_att" % i,
                                **read, **attrs)
 
 
 def _pool_step_inputs(pos_idx, num_slots, page_size, write_slot=None):
-    """``_pool_attend``'s one-hots and read for a decode step, made ON THE
-    DEVICE, once in front of the layers, from what the host knows of a lane:
-    ``write_slot`` (B, 1), the pool slot its token lands in (negative: the
-    lane rides along, writes nothing and sees nothing), and ``page_table``
-    (B, pages a lane), the frames of its pages in order. With ``pos_idx``
-    that is the lane's whole context: the read is its mask over the pool
-    (``KVPageMask``) beside the three it is made of, so ``KVPoolAttention``
-    may read a lane's own pages instead. A graph that reads ``write_slot``
-    elsewhere too hands its Variable in."""
+    """``_pool_attend``'s write and read for a decode step, from what the
+    host knows of a lane: ``write_slot`` (B, 1), the pool slot its token
+    lands in (negative: the lane rides along, writes nothing and sees
+    nothing), and ``page_table`` (B, pages a lane), the frames of its pages
+    in order. ``write(i, {tag: rows})`` is slot-indexed, ONE
+    ``KVPoolSlotWrite`` over layer ``i``'s pools ``kv_<tag>_i`` (a program
+    that takes them donated updates them in place) and gives them back in
+    the tags' order. With ``pos_idx`` the two inputs are the lane's whole
+    context: the read is its mask over the pool (``KVPageMask``, made ON
+    THE DEVICE, once in front of the layers) beside the three it is made
+    of, so ``KVPoolAttention`` may read a lane's own pages instead. A graph
+    that reads ``write_slot`` elsewhere too hands its Variable in."""
     if write_slot is None:
         write_slot = sym.Variable("write_slot")
     pages = dict(page_table=sym.Variable("page_table"), pos_idx=pos_idx,
                  write_slot=write_slot)
-    return (sym.KVSlotOneHot(write_slot, num_slots=num_slots,
-                             name="slot_onehot"),
-            dict(pages, page_size=page_size, mask=sym.KVPageMask(
-                page_size=page_size, num_slots=num_slots, name="kv_mask",
-                **pages)))
+
+    def write(i, news):
+        pairs = [x for tag, rows in news.items()
+                 for x in (sym.Variable("kv_%s_%d" % (tag, i)), rows)]
+        out = sym.KVPoolSlotWrite(
+            *pairs, write_slot, num_pools=len(news),
+            name="layer%d_%supd" % (i, "".join(news)))
+        return [out[j] for j in range(len(news))]
+
+    return write, dict(pages, page_size=page_size, mask=sym.KVPageMask(
+        page_size=page_size, num_slots=num_slots, name="kv_mask", **pages))
 
 
 def _token_head(logits, kv_outs, token_name):
@@ -367,11 +375,11 @@ def _pool_rows_symbol(vocab_size, num_layers, num_heads, model_dim, ffn_dim,
     the lanes of a decode step (``data`` (B, 1), ``seq_len`` 1) or the
     positions of one lane's chunk (``data`` (1, T), ``seq_len`` T); either
     way ``pos_idx`` has ``data``'s shape and ``pool_inputs(pos_idx)`` gives
-    the rows' one-hots (N, slots) and ``_pool_attend``'s ``read``."""
+    ``_pool_attend``'s ``write`` and ``read`` for the rows."""
     dh = model_dim // num_heads
     data = sym.Variable("data")
     pos_idx = sym.Variable("pos_idx")
-    oh, read = pool_inputs(pos_idx)
+    write, read = pool_inputs(pos_idx)
     emb = sym.Embedding(data=data, input_dim=vocab_size,
                         output_dim=model_dim, name="embed")
     posrow = sym.Embedding(data=pos_idx, input_dim=pos_len,
@@ -381,7 +389,7 @@ def _pool_rows_symbol(vocab_size, num_layers, num_heads, model_dim, ffn_dim,
 
     def attend(i, qkv):
         q, k_new, v_new = _split_rows(qkv, 3, num_heads, dh)
-        ctx = _pool_attend(i, q, k_new, v_new, oh, read, kv_outs)
+        ctx = _pool_attend(i, q, k_new, v_new, write, read, kv_outs)
         return sym.Reshape(ctx, shape=(-1, seq_len, model_dim))
 
     for i in range(num_layers):
@@ -405,12 +413,14 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
         position table, so ``pos < pos_len``).
       - ``write_slot`` (B, 1): the pool slot each lane's token writes, as an
         index; negative for a lane that rides along (it writes nothing and
-        attends nothing). The graph makes the (B, max_len) one-hots of it
-        once, in front of the layers (``KVSlotOneHot``), and the KV update
-        is in-graph — no per-step host scatter, no per-slot recompile. Lane
-        slots are disjoint by construction (the page allocator hands a frame
-        to one writer at a time), and an all-zero one-hot row writes
-        nothing, which is how idle lanes ride along for free.
+        attends nothing). The KV update is in-graph and by that index
+        (``KVPoolSlotWrite``): the page that holds the slot is read, the
+        row put in, the page written back — no per-step host scatter, no
+        per-slot recompile, nothing of the pool's size made, and in place
+        where the program takes the pool donated (``PagedKVDecoder`` does).
+        Lane slots are disjoint by construction (the page allocator hands a
+        frame to one writer at a time), and a negative slot writes back
+        what it read, which is how idle lanes ride along for free.
       - ``page_table`` (B, pages a lane): the frames of each lane's pages,
         in order, ``page_size`` slots each (entries past the lane's last
         page are never read). From it, ``pos_idx`` and ``write_slot`` the
@@ -433,15 +443,14 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
         as the next step's inputs (``PagedKVDecoder`` does).
 
     One token a row collapses attention to a masked weighted sum, spelled,
-    with the write before it, as the two registry operators of
-    ops/attention.py: ``KVPoolWrite`` (a matmul with the one-hots at
-    ``Precision.HIGHEST``: the stored row is the row bit for bit) and
-    ``KVPoolAttention`` (scores and context as contractions at the default
-    matmul precision with a float32 accumulator and softmax). On the CPU
-    that is float32 arithmetic, a few ulp from the full-sequence forward at
-    matching positions; on the chip the two reads are one bfloat16 pass
-    each, as ``MultiHeadAttention`` gives the same tokens in the prefill,
-    and all four run on the matrix unit.
+    with the write before it, as two registry operators of
+    ops/attention.py: ``KVPoolSlotWrite`` (the stored row is the row bit for
+    bit: it is moved, not multiplied) and ``KVPoolAttention`` (scores and
+    context as contractions at the default matmul precision with a float32
+    accumulator and softmax). On the CPU that is float32 arithmetic, a few
+    ulp from the full-sequence forward at matching positions; on the chip
+    the two reads are one bfloat16 pass each, as ``MultiHeadAttention``
+    gives the same tokens in the prefill, on the matrix unit.
 
     Outputs: ``[logits (B, vocab), k'_0, v'_0, ..., k'_{L-1}, v'_{L-1}]``,
     plus — with ``token_out=True`` (the default) — a trailing
@@ -461,7 +470,7 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     the same pool: positions reach the rotary operator as data
     (``pos_idx``), there is no position table, and the pool keeps the
     weights' ``dtype`` while ids, positions, slots and frames stay float32
-    inputs (and the one-hots and masks made of them float32).
+    inputs (and the masks made of them float32).
 
     ``arch="granite_hybrid"`` runs ``_granite_layer``: only its attention
     layers have ``kv_k_i`` / ``kv_v_i`` (Hkv, max_len, dh); a Mamba layer
@@ -542,11 +551,17 @@ def get_chunk_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     head so the speculative accept loop pulls T ids, not T·vocab floats.
     """
     _refuse_arch(kwargs.get("arch", "vaswani"), "get_chunk_symbol")
+    onehot = sym.Variable("write_onehot")
+
+    def write(i, news):
+        return [sym.KVPoolWrite(sym.Variable("kv_%s_%d" % (tag, i)), rows,
+                                onehot, name="layer%d_%supd" % (i, tag))
+                for tag, rows in news.items()]
+
     return _pool_rows_symbol(
         vocab_size, num_layers, num_heads, model_dim, ffn_dim, pos_len,
         seq_len=int(chunk_len),
-        pool_inputs=lambda pos: (sym.Variable("write_onehot"),
-                                 dict(mask=sym.Variable("att_mask"))),
+        pool_inputs=lambda pos: (write, dict(mask=sym.Variable("att_mask"))),
         token_name="chunk_token" if token_out else None)
 
 
@@ -640,7 +655,7 @@ def _olmoe_decode_symbol(vocab_size, num_layers, num_slots, page_size,
                                                    "model_dim"))
     data = sym.Variable("data")
     pos_idx = sym.Variable("pos_idx")
-    oh, read = _pool_step_inputs(pos_idx, num_slots, page_size)
+    write, read = _pool_step_inputs(pos_idx, num_slots, page_size)
     kv_outs = []
 
     def attend(i, q, k_new, v_new):
@@ -648,7 +663,7 @@ def _olmoe_decode_symbol(vocab_size, num_layers, num_slots, page_size,
         # pool's rows (B, H, dh)
         k_new, v_new, q = (sym.Reshape(a, shape=(-1, num_heads, dh))
                            for a in (k_new, v_new, q))
-        ctx = _pool_attend(i, q, k_new, v_new, oh, read, kv_outs)
+        ctx = _pool_attend(i, q, k_new, v_new, write, read, kv_outs)
         return sym.Reshape(ctx, shape=(-1, num_heads, 1, dh))
 
     x = sym.Embedding(data=data, input_dim=vocab_size, output_dim=model_dim,
@@ -814,7 +829,7 @@ def _granite_decode_symbol(vocab_size, num_layers, num_slots, page_size,
     hq, hkv, dh = (block[k] for k in ("num_heads", "num_kv_heads", "head_dim"))
     pos_idx = sym.Variable("pos_idx")
     write_slot = sym.Variable("write_slot")
-    oh, read = _pool_step_inputs(pos_idx, num_slots, page_size, write_slot)
+    write, read = _pool_step_inputs(pos_idx, num_slots, page_size, write_slot)
     cache = []      # the layers are built in order, so is this
 
     def attend(i, q, k_new, v_new):
@@ -822,7 +837,7 @@ def _granite_decode_symbol(vocab_size, num_layers, num_slots, page_size,
         # pool's rows (B, H, dh)
         q, k_new, v_new = (sym.Reshape(a, shape=(-1, n, dh)) for a, n in
                            ((q, hq), (k_new, hkv), (v_new, hkv)))
-        ctx = _pool_attend(i, q, k_new, v_new, oh, read, cache,
+        ctx = _pool_attend(i, q, k_new, v_new, write, read, cache,
                            scale=block["attention_multiplier"])
         return sym.Reshape(ctx, shape=(-1, hq, 1, dh))
 
@@ -987,15 +1002,14 @@ def _deepseek_v3_decode_symbol(vocab_size, num_layers, num_slots, page_size,
     hq, nope, rope, v_dim, lat = (block[k] for k in (
         "num_heads", "nope", "rope", "v_dim", "latent"))
     pos_idx = sym.Variable("pos_idx")
-    oh, read = _pool_step_inputs(pos_idx, num_slots, page_size)
+    write, read = _pool_step_inputs(pos_idx, num_slots, page_size)
     cache = []
 
     def attend(i, q_nope, q_rope, c, k_r):
         # one token a lane: its row of the latent pool is [c | k_r]
         row = sym.Concat(sym.Reshape(c, shape=(-1, 1, lat)),
                          sym.Reshape(k_r, shape=(-1, 1, rope)), dim=2)
-        pool = sym.KVPoolWrite(sym.Variable("kv_c_%d" % i), row, oh,
-                               name="layer%d_cupd" % i)
+        pool, = write(i, {"c": row})
         cache.append(pool)
         # absorbed: a head's rows of kvb are [Wuk_h | Wuv_h] over the
         # latent; Wuk goes into the query, Wuv onto the context, and no
@@ -1189,7 +1203,7 @@ def _lfm2_moe_decode_symbol(vocab_size, num_layers, num_slots, page_size,
     hq, hkv, dh = (block[k] for k in ("num_heads", "num_kv_heads", "head_dim"))
     pos_idx = sym.Variable("pos_idx")
     write_slot = sym.Variable("write_slot")
-    oh, read = _pool_step_inputs(pos_idx, num_slots, page_size, write_slot)
+    write, read = _pool_step_inputs(pos_idx, num_slots, page_size, write_slot)
     cache = []      # the layers are built in order, so is this
 
     def attend(i, q, k_new, v_new):
@@ -1197,7 +1211,7 @@ def _lfm2_moe_decode_symbol(vocab_size, num_layers, num_slots, page_size,
         # pool's rows (B, H, dh)
         q, k_new, v_new = (sym.Reshape(a, shape=(-1, n, dh)) for a, n in
                            ((q, hq), (k_new, hkv), (v_new, hkv)))
-        ctx = _pool_attend(i, q, k_new, v_new, oh, read, cache)
+        ctx = _pool_attend(i, q, k_new, v_new, write, read, cache)
         return sym.Reshape(ctx, shape=(-1, hq, 1, dh))
 
     def conv(i, bcu):

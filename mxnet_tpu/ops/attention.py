@@ -183,6 +183,72 @@ def _kv_pool_write(attrs, pool, rows, onehot):
     return pool * keep[None, :, None] + written.transpose(1, 0, 2)
 
 
+# slots a slot-indexed write reads and writes back around a row's slot: the
+# chip's tile of a pool it keeps slots-minor, so a run is whole tiles
+_WRITE_RUN = 128
+
+
+def _slot_write_inputs(attrs):
+    return [name % i for i in range(attrs.get("num_pools", 1))
+            for name in ("pool_%d", "rows_%d")] + ["write_slot"]
+
+
+@register(
+    "_contrib_KVPoolSlotWrite",
+    attrs={"num_pools": AttrSpec("int", default=1)},
+    input_names=_slot_write_inputs,
+    num_outputs=lambda attrs: attrs.get("num_pools", 1),
+    aliases=("KVPoolSlotWrite",),
+)
+def _kv_pool_slot_write(attrs, *inputs):
+    """``KVPoolWrite`` for rows that each name their slot, into
+    ``num_pools`` pools at once (a layer's K and V): ``pool_i`` (H, S, dh)
+    and ``rows_i`` (R, H, dh), pair after pair, then ``write_slot`` (R, 1),
+    row r's slot as an index (the float32 the executor binds its inputs in,
+    exact below 2^24 slots), negative for a row that writes nothing (a lane
+    that rides along). What comes back is every pool with
+    ``pool[:, slot_r, :] = rows[r]`` in the pool's dtype, row after row: a
+    stored row is the row bit for bit, and where two rows name one slot the
+    later one stays.
+
+    Nothing here is a pool's size. ONE loop over the rows updates every
+    pool: for a row, the aligned run of ``_WRITE_RUN`` slots that holds its
+    slot is read, the row put in by ``where`` and the run written back with
+    ``dynamic_update_slice``; a negative slot writes back what it read. So a
+    program that takes the pools DONATED updates them in place and moves a
+    run a row, not the pool. An update one slot wide, or a scatter over the
+    slot axis, says the same, but the chip keeps a dh = 64 pool slots-minor
+    and re-lays the WHOLE buffer out around either, twice a buffer
+    (``_AdmitScatter`` walks a prompt's pages the same way). The writes are
+    bound by their count, not their bytes, and the loop's shape was chosen
+    on the chip (``PERF.md`` §6, PR 37): a run of one tile beats a page of
+    16 slots, one loop a layer beats one a pool, and the index arithmetic
+    stays inside the loop."""
+    pools, rows = inputs[0:-1:2], inputs[1:-1:2]
+    n_rows, slots = rows[0].shape[0], pools[0].shape[1]
+    if n_rows == 0:
+        return tuple(pools)
+    run = min(_WRITE_RUN, slots)
+    slot = inputs[-1].reshape(-1).astype(jnp.int32)
+    rows = [r.astype(p.dtype) for r, p in zip(rows, pools)]
+    in_run = jnp.arange(run, dtype=jnp.int32)[None, :, None]
+
+    def write(r, pools):
+        # the last run is cut short by the pool's end: start it earlier
+        base = jnp.minimum(jnp.maximum(slot[r], 0) // run * run, slots - run)
+        at_slot = in_run == slot[r] - base
+        out = []
+        for pool, new in zip(pools, rows):
+            heads, _, dh = pool.shape
+            old = jax.lax.dynamic_slice(pool, (0, base, 0), (heads, run, dh))
+            row = jax.lax.dynamic_index_in_dim(new, r, 0, keepdims=False)
+            out.append(jax.lax.dynamic_update_slice(
+                pool, jnp.where(at_slot, row[:, None, :], old), (0, base, 0)))
+        return tuple(out)
+
+    return jax.lax.fori_loop(0, n_rows, write, tuple(pools))
+
+
 def pool_read_own_pages(query, pool_k, pool_v, page_table, page_size):
     """Whether ``KVPoolAttention`` gathers each row's own pages (True) or
     scores the whole pool (False): the smaller count of the bytes either
@@ -315,23 +381,6 @@ def _kv_pool_attention(attrs, query, pool_k, pool_v, mask, page_table=None,
     if attrs.get("value_dim", 0) > 0:
         out = out[..., :attrs["value_dim"]]
     return out.reshape(r, h, out.shape[-1]).astype(query.dtype)
-
-
-@register(
-    "_contrib_KVSlotOneHot",
-    attrs={"num_slots": AttrSpec("int", required=True)},
-    input_names=("write_slot",),
-    aliases=("KVSlotOneHot",),
-)
-def _kv_slot_onehot(attrs, write_slot):
-    """``KVPoolWrite``'s one-hots from one slot index a row: ``write_slot``
-    (R, 1) gives (R, num_slots) float32, row r 1.0 at its slot and all zero
-    where the slot is negative (a lane that rides along and writes nothing).
-    The indices arrive as the float32 the executor binds its inputs in,
-    exact below 2^24 slots."""
-    slot = write_slot.reshape(-1, 1).astype(jnp.int32)
-    slots = jnp.arange(attrs["num_slots"], dtype=jnp.int32)
-    return (slots[None, :] == slot).astype(jnp.float32)
 
 
 @register(

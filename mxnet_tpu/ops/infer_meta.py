@@ -184,6 +184,10 @@ register_meta("_contrib_RotaryEmbedding",
 register_meta("_contrib_KVPoolWrite",
               input_ranks={"pool": 3, "rows": 3, "onehot": 2},
               dtype_policy="first", aliases=("KVPoolWrite",))
+register_meta("_contrib_KVPoolSlotWrite",
+              input_ranks={"pool_0": 3, "rows_0": 3, "pool_1": 3, "rows_1": 3,
+                           "write_slot": 2},
+              dtype_policy="first", aliases=("KVPoolSlotWrite",))
 register_meta("_contrib_KVPoolAttention",
               input_ranks={"query": 3, "pool_k": 3, "pool_v": 3, "mask": 2,
                            "page_table": 2, "pos_idx": 2, "write_slot": 2},

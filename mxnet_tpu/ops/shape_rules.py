@@ -136,6 +136,16 @@ def _kv_pool_write(attrs, shapes):
     return shapes
 
 
+@rule("_contrib_KVPoolSlotWrite")
+@rule("KVPoolSlotWrite")
+def _kv_pool_slot_write(attrs, shapes):
+    # (H, S, dh), (R, H, dh) a pool, then (R, 1)
+    rows = next((s for s in shapes[1:-1:2] if s is not None), None)
+    if shapes[-1] is None and rows is not None:
+        shapes[-1] = (rows[0], 1)
+    return shapes
+
+
 @rule("_contrib_KVPoolAttention")
 @rule("KVPoolAttention")
 def _kv_pool_attention(attrs, shapes):

@@ -576,7 +576,7 @@ class SPMDTrainer:
         training-loop one."""
         import jax.numpy as jnp
 
-        from ..executor import _compiled_cost
+        from ..executor import _cost_of
 
         if not self.params and self.param_names:
             raise MXNetError("call init_params first")
@@ -584,9 +584,9 @@ class SPMDTrainer:
             self._step_fn = self._build_step()
         placed = self._place_batch(data, label)
         lr = self._opt_static_lr
-        return _compiled_cost(self._step_fn.lower(
+        return _cost_of(self._step_fn.lower(
             self.params, self.aux, self.opt_state, placed, self._base_key,
-            None if lr is None else jnp.asarray(lr, "float32")))
+            None if lr is None else jnp.asarray(lr, "float32")).compile())
 
     # ------------------------------------------------------------------ misc
     def get_params(self):

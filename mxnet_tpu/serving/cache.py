@@ -84,17 +84,22 @@ class PersistentExecutableCache:
     compiled program in a profiler trace (``jit_<label>``; executor.py
     ``_GraphProgram.label``). ``dtype`` types every input;
     ``input_dtypes`` ({name: dtype}) names the inputs that differ (a
-    bfloat16 KV pool beside float32 token ids and masks).
+    bfloat16 KV pool beside float32 token ids and masks). ``donated`` names
+    the inputs every bucket's program takes DONATED (a decode step's cache:
+    executor.py ``_GraphProgram.donated``): after a ``forward`` the arrays
+    they held are dead, the warm one's included, and the owner hands the
+    outputs that took their place back.
     """
 
     def __init__(self, symbol, arg_params=None, aux_params=None, ctx=None,
                  dtype="float32", model_key=None, cache_dir=None,
                  max_executables=None, program_label=None,
-                 input_dtypes=None):
+                 input_dtypes=None, donated=()):
         from ..context import current_context
 
         self._sym = symbol
         self._program_label = program_label
+        self._donated = tuple(donated)
         self._ctx = ctx or current_context()
         self._dtype = str(dtype)
         self._input_dtypes = {n: str(t) for n, t in
@@ -210,6 +215,7 @@ class PersistentExecutableCache:
                              aux_states=dict(self._shared_aux))
         # the program is jitted at its first forward: name it before that
         exe._prog.label = self._program_label
+        exe._prog.donated = self._donated
         return exe
 
     def _retrace_diagnosis(self):

@@ -15,12 +15,14 @@ gives (``models.transformer.decode_cache``), of two kinds: those pools,
 addressed by slot, and, where a layer keeps a recurrent state or its last
 convolution columns instead (``arch="granite_hybrid"``, ``"lfm2_moe"``),
 per-lane rows ``(lanes, ...)`` addressed by lane. A
-token's write happens IN-GRAPH, through one-hot rows the program makes of
-each lane's ``write_slot`` (models/transformer.py ``get_decode_symbol``), as
-it makes each lane's attention mask of its ``page_table``: a step hands the
-device a few numbers a lane. The updated buffers are program outputs the
-decoder swaps back in as the next step's inputs — a device-side pointer
-swap, no copy, no host round-trip. Attention over
+token's write happens IN-GRAPH, into the page that holds each lane's
+``write_slot`` (models/transformer.py ``get_decode_symbol``), as the program
+makes each lane's attention mask of its ``page_table``: a step hands the
+device a few numbers a lane. The decode program OWNS the cache: it takes
+every buffer donated and updates it in place, so the cache exists once, and
+the updated buffers are program outputs the decoder swaps back in as the
+next step's inputs — a device-side pointer swap, no copy, no host
+round-trip; the arrays that went in are dead from the enqueue on. Attention over
 slots is order-agnostic (position information lives in the embeddings), so
 a sequence's tokens may sit in any frames. Physical sharing is then free —
 the prefix cache (serving/prefix_cache.py) parks whole prompt chunks at a
@@ -49,6 +51,7 @@ import numpy as np
 
 from ..base import MXNetError
 from .. import telemetry as _tm
+from ..executor import _cost_of
 from ..ops.attention import _NEG
 from .cache import PersistentExecutableCache
 
@@ -248,10 +251,12 @@ def _pool_read_slots(cache, input_shapes):
 def _swap_cache(exe, names):
     """Hand the updated cache buffers (program outputs, in the cache's order
     after the logits) back as the next dispatch's inputs — device-side
-    pointer swaps, no copy. ``arg_dict`` owns the cache from here on:
-    ``exe.outputs[1..]`` still names the same arrays, and they die with the
-    next donated update (``_AdmitScatter``), so every reader takes a buffer
-    from ``arg_dict`` at the time of use."""
+    pointer swaps, no copy. The decode program took what ``arg_dict`` held
+    donated, so those arrays died at its enqueue and these stand in their
+    place. ``arg_dict`` owns the cache from here on: ``exe.outputs[1..]``
+    still names the same arrays, and they die with the next donated update
+    (the next step, ``_AdmitScatter``), so every reader takes a buffer from
+    ``arg_dict`` at the time of use."""
     exe.rebind(names, [o._jax() for o in exe.outputs[1:1 + len(names)]])
 
 
@@ -953,7 +958,7 @@ class PagedKVDecoder:
         self._prefill_takes_length = "length" in self._pf_cache.input_names
         self._dec_cache = PersistentExecutableCache(
             decode, arg_params, {}, model_key=key + "-decode",
-            program_label="mx_decode", **binding)
+            program_label="mx_decode", donated=self._cache_names, **binding)
         self._dec_exe = None
         self._decode_xla_bytes = None  # read at warmup when telemetry is on
         self._step_gathered_slots = 0  # likewise: slots a dispatch scores
@@ -1013,23 +1018,31 @@ class PagedKVDecoder:
         bucket: cold and cached admits must replay the SAME program for
         the bitwise parity gate to hold).
 
-        The warm dispatch leaves a whole copy of the cache in the decode
-        executable's outputs, which nothing reads, so the FIRST step holds
-        three copies (its inputs, those, its own outputs) where every later
-        one holds two. ``release_outputs=True`` drops that copy here: what a
-        deployment whose cache fills the chip asks for."""
+        The decode program takes the cache donated, the warm dispatch too:
+        its outputs are swapped back in here as a step's are, and the cache
+        exists once. ``release_outputs=True`` drops what else the warm
+        dispatch left (its logits): nothing of the cache."""
         if self._warm:
             return self
         self._dec_cache.warmup([self._decode_shapes()])
-        self._dec_exe = self._dec_cache.executable(self._decode_shapes())
+        self._dec_exe = exe = self._dec_cache.executable(self._decode_shapes())
+        _swap_cache(exe, self._cache_names)
         if release_outputs:
-            self._dec_exe.release_outputs()
+            exe.release_outputs()
         self._warm = True
         if _tm.enabled():
-            # XLA's own byte count for one decode dispatch, read once here
-            # so step() can add it to serving.decode_xla_bytes for free
+            # the program as it is DISPATCHED: XLA's own byte count for one
+            # decode dispatch, read once here so step() can add it to
+            # serving.decode_xla_bytes for free, and the bytes of its
+            # arguments it updates in place, beside the cache's
+            program = exe.compiled()
             self._decode_xla_bytes = int(
-                self._dec_exe.cost_analysis()["bytes accessed"])
+                _cost_of(program)["bytes accessed"])
+            _tm.gauge("serving.decode_aliased_bytes").set(
+                int(program.memory_analysis().alias_size_in_bytes))
+            _tm.gauge("serving.cache_bytes").set(sum(
+                exe.arg_dict[name]._jax().nbytes
+                for name in self._cache_names))
             reads = _pool_read_slots(self._dec_cache, self._decode_shapes())
             _tm.gauge("serving.pool_read.own_pages_layers").set(
                 sum(own for own, _ in reads))
@@ -1041,7 +1054,7 @@ class PagedKVDecoder:
             _tm.gauge("serving.state_bytes").set(sum(
                 4 * self.lanes * int(np.prod(shape))
                 for _, kind, shape in self._cache if kind == "row"))
-            latent = [self._dec_exe.arg_dict[name]._jax().nbytes
+            latent = [exe.arg_dict[name]._jax().nbytes
                       for name in self._pool_names
                       if name.startswith("kv_c_")]
             if latent:  # one pool a layer: a token's latent, not its heads
@@ -1482,10 +1495,12 @@ class PagedKVDecoder:
         lane, its token, its position, the slot the token lands in and the
         frames of its pages (``data``, ``pos_idx``, ``write_slot``,
         ``page_table``: ``lanes * (3 + max_len / page_size)`` float32 in
-        all); the one-hots and masks over the pool's slots are made of them
-        on the device. Lanes not stepped (or unoccupied) ride along with a
-        negative write slot — their KV is untouched and their logits
-        discarded."""
+        all); the masks over the pool's slots are made of them on the
+        device, and the token's K/V goes into the page that holds its slot.
+        The program takes the cache DONATED: from its enqueue to the swap in
+        ``serving.step.commit`` what ``arg_dict`` holds of it is dead. Lanes
+        not stepped (or unoccupied) ride along with a negative write slot —
+        their KV is untouched and their logits discarded."""
         import jax
 
         self.warmup()
@@ -1552,6 +1567,8 @@ class PagedKVDecoder:
                     _tm.counter("serving.paged_steps").inc()
                     _tm.counter("serving.step_gathered_slots").inc(
                         self._step_gathered_slots)
+                    _tm.counter("serving.step_slot_writes").inc(
+                        len(stepped) * len(self._pool_names))
                     _tm.counter("serving.step_input_bytes").inc(
                         sum(a.nbytes for a in staged.values()))
                     if self._decode_xla_bytes:

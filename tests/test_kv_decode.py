@@ -1086,3 +1086,128 @@ def test_pool_readers_after_a_donated_admit_stay_token_identical():
     dec.rollback(twin, dec.position(twin) - 1)
     assert int(np.argmax(dec.step({twin: ref[3]})[twin])) == ref[4]
     assert int(np.argmax(dec.step({sid: ref[4]})[sid])) == ref[5]
+
+
+# ------------------------------------------- a decode step owns its cache
+def _fresh_outputs(dec):
+    """``dec`` dispatching the SAME decode graph with nothing donated: every
+    output a fresh buffer, the arrays a step read alive after it. What the
+    donated program is held to, bit for bit."""
+    dec._dec_cache._donated = ()
+    return dec
+
+
+def _cache(dec):
+    return [dec._dec_exe.arg_dict[n]._jax() for n in dec._cache_names]
+
+
+def _bitwise(a):
+    return np.array(a, np.float32).tobytes()
+
+
+def _owned_scenario(case, dec):
+    """One use of the cache a step owns; returns every logits row, token and
+    cache row the scenario read, in order, as bytes."""
+    seen = []
+    note = lambda a: seen.append(_bitwise(a))
+    release = case == "warmup_release_outputs"
+    dec.warmup(release_outputs=release)
+    if release:
+        assert dec._dec_exe.outputs == []
+    prompt = np.array([3, 1, 4, 1, 5], np.float32)
+    sid, logits = dec.admit(prompt)
+    note(logits)
+    tok = int(np.argmax(logits))
+    for _ in range(2):
+        row = dec.step({sid: tok})[sid]
+        note(row)
+        tok = int(np.argmax(row))
+    if case == "lane_state":
+        for name, value in sorted(dec.lane_state(sid).items()):
+            note(value)
+        note(dec.step({sid: tok})[sid])
+        for name, value in sorted(dec.lane_state(sid).items()):
+            note(value)
+    elif case == "fork_copy_on_write":
+        twin = dec.fork(sid)
+        for a, b in ((7, 11), (2, 2)):      # both branches write the page
+            out = dec.step({sid: a, twin: b})
+            note(out[sid])
+            note(out[twin])
+        note(dec.step({twin: 5})[twin])
+    elif case == "rollback":
+        note(dec.step({sid: 9})[sid])
+        dec.rollback(sid, dec.position(sid) - 2)
+        note(dec.step({sid: tok})[sid])
+    elif case == "verify_chunk":
+        for row in dec.verify_chunk(sid, [tok, 7, 9]):
+            note(row)
+        dec.rollback(sid, dec.position(sid) - 1)
+        note(dec.step({sid: 4})[sid])
+    elif case == "megastep_after_steps":
+        ids = dec.step_megastep({sid: tok}, k=3)[sid]
+        note(ids)
+        note(dec.step({sid: int(ids[-1])})[sid])
+    elif case == "admission_between_steps":
+        other, logits = dec.admit(np.array([2, 7, 1, 8, 2, 8], np.float32))
+        note(logits)
+        out = dec.step({sid: tok, other: int(np.argmax(logits))})
+        note(out[sid])
+        note(out[other])
+    for buf in _cache(dec):     # what the scenario left in the cache
+        note(buf)
+    return seen
+
+
+OWNED = ["steps", "lane_state", "fork_copy_on_write", "rollback",
+         "verify_chunk", "megastep_after_steps", "admission_between_steps",
+         "warmup_release_outputs"]
+
+
+@pytest.mark.parametrize("case", OWNED)
+def test_a_donated_step_gives_what_fresh_outputs_give(case):
+    """Every reader of the cache, after steps that took it donated, against
+    the same decoder dispatching its decode graph un-donated (each output a
+    fresh buffer, as before the step owned its cache): the same logits,
+    tokens, state rows and cache, bit for bit."""
+    arch = "granite_hybrid" if case == "lane_state" else "vaswani"
+    got = _owned_scenario(case, _arch_decoder(arch))
+    want = _owned_scenario(case, _fresh_outputs(_arch_decoder(arch)))
+    assert len(got) == len(want) > 3
+    assert got == want
+
+
+@pytest.mark.parametrize("arch", sorted(ARCHS))
+def test_a_step_leaves_dead_what_it_read_and_live_what_it_wrote(tm, arch):
+    """The decode program takes every buffer of the cache, pools and rows,
+    donated: after ``step`` the arrays ``arg_dict`` held are deleted and
+    the ones it holds now are the program's outputs, alive; the weights and
+    the logits are not donated; the warm dispatch hands its outputs back
+    the same way, and the program's aliased bytes are the cache's."""
+    tm.set_mode("counters")
+    dec = _arch_decoder(arch).warmup()
+    gauges = tm.snapshot()
+    assert gauges["serving.decode_aliased_bytes"] \
+        == gauges["serving.cache_bytes"] \
+        == sum(a.nbytes for a in _cache(dec)) > 0
+    assert not any(a.is_deleted() for a in _cache(dec))
+    nxt = _admit_prompts(dec)
+    exe = dec._dec_exe
+    weights = [a._jax() for n, a in exe.arg_dict.items()
+               if n not in dec._cache_names]
+    before = _cache(dec)
+    rows = dec.step(nxt)
+    assert all(a.is_deleted() for a in before)
+    after = _cache(dec)
+    assert not any(a.is_deleted() for a in after)
+    assert [id(a) for a in after] == [
+        id(o._jax()) for o in exe.outputs[1:1 + len(after)]]
+    assert not any(w.is_deleted() for w in weights)
+    first = {sid: np.array(row) for sid, row in rows.items()}
+    dec.step({sid: int(np.argmax(row)) for sid, row in rows.items()})
+    assert all(a.is_deleted() for a in after)
+    # a step's logits are its own: the next step's donation leaves them
+    for sid, row in rows.items():
+        np.testing.assert_array_equal(np.asarray(row), first[sid])
+    writes = tm.counters()["serving.step_slot_writes"]
+    assert writes == 2 * len(nxt) * len(dec._pool_names)

@@ -6,9 +6,11 @@ out here as the decode, the OLMoE and the chunk builders had them.
 The write must agree bit for bit (it multiplies by exactly 0 and 1); the
 read sums the same float32 products in another order.
 
-And the two operators that make a decode step's one-hots and masks on the
-device (``KVSlotOneHot``, ``KVPageMask``) against the arrays
-``PagedKVDecoder.step`` used to build on the host, element for element.
+And a decode step's write by slot index (``KVPoolSlotWrite``: the page that
+holds a row's slot read, the row put in, the page written back) against that
+blend with the one-hots ``PagedKVDecoder.step`` used to build on the host,
+bit for bit; and the operator that makes a step's masks on the device
+(``KVPageMask``) against the host's arrays, element for element.
 
 And the read's second form, a row's own pages gathered by its table, against
 the whole-pool read under the mask made of the same table.
@@ -25,7 +27,7 @@ import mxnet_tpu as mx
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.ops import attention
 from mxnet_tpu.ops.attention import (_kv_page_mask, _kv_pool_attention,
-                                     _kv_pool_write, _kv_slot_onehot,
+                                     _kv_pool_slot_write, _kv_pool_write,
                                      pool_read_own_pages)
 
 H, S, DH = 4, 96, 16
@@ -206,6 +208,118 @@ def test_one_step_through_the_executor_is_the_two_operators(dtype):
                                rtol=1e-6, atol=1e-6)
 
 
+# ------------------------------------------------- the write by slot index
+# case -> the slots of its rows in a pool of SLOT_S slots (pages of 16)
+SLOT_S = 80
+SLOT_WRITES = {
+    "scattered": [7, 64, 33, 0],
+    "negative_slot": [5, -1, 40, -1],
+    "last_slot_of_a_page": [15, 47, 79],        # 79: the pool's last, too
+    "two_rows_in_one_page": [18, 29, 50],
+    "every_row_rides_along": [-1, -1],
+    "empty_step": [],
+}
+
+
+@pytest.mark.parametrize("case", list(SLOT_WRITES))
+@pytest.mark.parametrize("heads,width", [(8, 64), (1, 576)])
+@DTYPES
+def test_slot_write_is_the_onehot_blend_bit_for_bit(dtype, heads, width,
+                                                    case):
+    """``KVPoolSlotWrite`` against ``KVPoolWrite`` fed the host's one-hots of
+    the same slots, at the two pool shapes the serving cells have (8 heads
+    of 64; one latent row of 576): the same pool bit for bit, a written slot
+    the row itself, a pool shorter than a run and one whose length no run
+    divides, one pool and two in the one loop."""
+    slots = SLOT_WRITES[case]
+    rs = np.random.RandomState(len(case) + heads)
+    for pool_slots in (SLOT_S, 2 * attention._WRITE_RUN + 8):  # a cut run
+        pool = jnp.asarray(rs.randn(heads, pool_slots, width), dtype)
+        rows = jnp.asarray(rs.randn(len(slots), heads, width), dtype)
+        onehot = np.zeros((len(slots), pool_slots), "f")
+        for r, slot in enumerate(slots):
+            if slot >= 0:
+                onehot[r, slot] = 1.0
+        write_slot = jnp.asarray(slots, jnp.float32).reshape(-1, 1)
+        got, = _kv_pool_slot_write({}, pool, rows, write_slot)
+        assert got.dtype == pool.dtype and got.shape == pool.shape
+        want = _kv_pool_write({}, pool, rows, jnp.asarray(onehot))
+        np.testing.assert_array_equal(_bits(got), _bits(want))
+        # a layer's two pools in the one loop: each as it is alone
+        both = _kv_pool_slot_write({"num_pools": 2}, pool, rows, want,
+                                   rows[::-1], write_slot)
+        np.testing.assert_array_equal(_bits(both[0]), _bits(got))
+        np.testing.assert_array_equal(_bits(both[1]), _bits(
+            _kv_pool_write({}, want, rows[::-1], jnp.asarray(onehot))))
+        for r, slot in enumerate(slots):
+            if slot >= 0:
+                np.testing.assert_array_equal(_bits(got)[:, slot],
+                                              _bits(rows)[r])
+        written = [slot for slot in slots if slot >= 0]
+        untouched = np.setdiff1d(np.arange(pool_slots), written)
+        np.testing.assert_array_equal(_bits(got)[:, untouched],
+                                      _bits(pool)[:, untouched])
+
+
+def test_slot_write_moves_a_run_a_row_and_nothing_of_the_pools_size():
+    """What reaches the compiler: no contraction, no one-hot, nothing
+    (rows, slots) or pool-sized made; a loop whose body slices a run of the
+    pool and updates it."""
+    pool = jnp.zeros((8, 4096, 64), jnp.float32)
+    rows = jnp.zeros((5, 8, 64), jnp.float32)
+    text = jax.jit(lambda *a: _kv_pool_slot_write({}, *a)[0]).lower(
+        pool, rows, jnp.zeros((5, 1), jnp.float32)).as_text()
+    assert "dot_general" not in text and "5x4096" not in text
+    assert "dynamic_slice" in text and "dynamic_update_slice" in text
+    assert "stablehlo.while" in text
+    run = "8x%dx64" % attention._WRITE_RUN
+    assert run in text
+
+
+@pytest.mark.parametrize("arch", ["vaswani", "olmoe", "granite_hybrid",
+                                  "deepseek_v3", "lfm2_moe"])
+def test_decode_symbols_write_by_slot_and_build_no_onehot(arch):
+    """Every decode graph writes its pools through ``KVPoolSlotWrite``, one
+    node a layer that has pools; none names ``KVSlotOneHot`` (the operator is gone) or
+    ``KVPoolWrite`` (the chunk graph's, whose one-hots the host makes)."""
+    import json
+
+    from mxnet_tpu.models import transformer as tf
+
+    sizes = {
+        "vaswani": dict(pos_len=16),
+        "olmoe": dict(arch="olmoe", head_dim=8, num_experts=4,
+                      num_experts_per_tok=2),
+        "granite_hybrid": dict(
+            arch="granite_hybrid", num_kv_heads=2, head_dim=8,
+            layer_types=["mamba", "attention"], mamba_heads=2,
+            mamba_head_dim=8, mamba_state=4),
+        "deepseek_v3": dict(
+            arch="deepseek_v3", moe_ffn_dim=8, num_experts=4,
+            num_experts_per_tok=2, num_shared_experts=1, first_dense_layers=1,
+            qk_nope_head_dim=4, qk_rope_head_dim=4, v_head_dim=4,
+            kv_lora_rank=12),
+        "lfm2_moe": dict(
+            arch="lfm2_moe", num_kv_heads=2, head_dim=8,
+            layer_types=["conv", "full_attention"], moe_ffn_dim=8,
+            num_experts=4, num_experts_per_tok=2, first_dense_layers=1),
+    }[arch]
+    symbol = tf.get_decode_symbol(vocab_size=32, num_layers=2, num_heads=4,
+                                  model_dim=16, ffn_dim=32, max_len=64,
+                                  page_size=8, **sizes)
+    ops = [n["op"] for n in json.loads(symbol.tojson())["nodes"]]
+    assert "KVSlotOneHot" not in symbol.tojson()
+    assert "_contrib_KVPoolWrite" not in ops
+    pools = [n for n in symbol.list_arguments() if n.startswith("kv_")]
+    written = [n for n in json.loads(symbol.tojson())["nodes"]
+               if n["op"] == "_contrib_KVPoolSlotWrite"]
+    assert sum(int(n["attr"].get("num_pools", 1)) for n in written) \
+        == len(pools) > 0
+    assert len(written) == len({name.rsplit("_", 1)[1] for name in pools})
+    with pytest.raises(AttributeError):
+        mx.sym.KVSlotOneHot
+
+
 # ------------------------------------------------ a step's inputs, on device
 # lanes x slots a lane, page size: a small pool, olmoe-1b-7b.score's and
 # transformer-base.generate's
@@ -239,12 +353,18 @@ def _host_row(frames, pos, page, slots):
     return phys, onehot, mask
 
 
+# the pool a step of ``_step_inputs`` writes into: heads, row width
+STEP_POOL = (2, 8)
+
+
 @functools.lru_cache(maxsize=None)
 def _step_inputs(geometry):
     """One step of a seeded pool: every lane of ``LANES`` at frames drawn
     without order from the whole pool (never frame 0, which the table's
-    padding names), the rest of the lanes mid-context; then the operators'
-    arrays and the host's."""
+    padding names), the rest of the lanes mid-context; then what the
+    operators make of the step's few numbers a lane (the pool written by
+    slot index, the masks) and what the host's arrays give (the one-hot
+    blend of the same pool, the masks)."""
     lanes, per_lane, page = GEOMETRIES[geometry]
     slots, max_pages = lanes * per_lane, per_lane // page
     rs = np.random.RandomState(len(geometry))
@@ -267,37 +387,58 @@ def _step_inputs(geometry):
         pos_idx[r, 0] = 0 if pos is None else pos
         want_oh.append(onehot)
         want_mask.append(mask)
-    got_oh = _kv_slot_onehot({"num_slots": slots}, jnp.asarray(write_slot))
+    heads, width = STEP_POOL
+    pool = jnp.asarray(rs.randn(heads, slots, width), jnp.float32)
+    rows = jnp.asarray(rs.randn(lanes, heads, width), jnp.float32)
+    got_pool, = _kv_pool_slot_write({}, pool, rows, jnp.asarray(write_slot))
+    want_pool = _kv_pool_write({}, pool, rows, jnp.asarray(np.stack(want_oh)))
     got_mask = _kv_page_mask({"page_size": page, "num_slots": slots},
                              jnp.asarray(table), jnp.asarray(pos_idx),
                              jnp.asarray(write_slot))
-    return (kinds, np.asarray(got_oh), np.asarray(got_mask),
-            np.stack(want_oh), np.stack(want_mask), table)
+    return (kinds, np.asarray(got_pool), np.asarray(got_mask),
+            np.asarray(want_pool), np.stack(want_mask), table, write_slot,
+            np.asarray(pool), np.asarray(rows))
 
 
 @pytest.mark.parametrize("lane", list(LANES))
 @pytest.mark.parametrize("geometry", list(GEOMETRIES))
 def test_a_steps_onehot_and_mask_are_the_hosts_element_for_element(
         geometry, lane):
-    kinds, got_oh, got_mask, want_oh, want_mask, _ = _step_inputs(geometry)
+    """A lane's half of the write (its slot of the pool written by index
+    against the host's one-hot blend) and its row of the mask."""
+    (kinds, got_pool, got_mask, want_pool, want_mask, _, write_slot, pool,
+     rows) = _step_inputs(geometry)
     lanes, per_lane, page = GEOMETRIES[geometry]
-    assert got_oh.shape == got_mask.shape == (lanes, lanes * per_lane)
-    assert got_oh.dtype == got_mask.dtype == np.float32
+    assert got_pool.shape == want_pool.shape == STEP_POOL[:1] + (
+        lanes * per_lane,) + STEP_POOL[1:]
+    assert got_mask.shape == (lanes, lanes * per_lane)
+    assert got_pool.dtype == got_mask.dtype == np.float32
     r = kinds.index(lane)
-    np.testing.assert_array_equal(_bits(got_oh[r]), _bits(want_oh[r]))
-    np.testing.assert_array_equal(_bits(got_mask[r]), _bits(want_mask[r]))
     n_pages, pos = LANES[lane](page)
+    phys = int(write_slot[r, 0])
+    assert (phys >= 0) == (pos is not None)
+    if pos is not None:
+        # the slot holds the lane's row itself, as the blend leaves it, and
+        # the rest of its page what the pool held
+        np.testing.assert_array_equal(_bits(got_pool[:, phys]),
+                                      _bits(want_pool[:, phys]))
+        np.testing.assert_array_equal(_bits(got_pool[:, phys]),
+                                      _bits(rows[r]))
+        start = phys // page * page
+        rest = [s for s in range(start, start + page) if s != phys]
+        np.testing.assert_array_equal(_bits(got_pool[:, rest]),
+                                      _bits(pool[:, rest]))
+    np.testing.assert_array_equal(_bits(got_mask[r]), _bits(want_mask[r]))
     seen = 0 if pos is None else pos + 1
     assert (got_mask[r] == 0).sum() == seen
-    assert got_oh[r].sum() == (pos is not None)
     assert set(np.unique(got_mask[r])) <= {np.float32(0), np.float32(-1e9)}
 
 
 @pytest.mark.parametrize("geometry", list(GEOMETRIES))
 def test_every_lane_of_a_step_and_the_frame_two_lanes_share(geometry):
-    kinds, got_oh, got_mask, want_oh, want_mask, table = \
-        _step_inputs(geometry)
-    np.testing.assert_array_equal(_bits(got_oh), _bits(want_oh))
+    (kinds, got_pool, got_mask, want_pool, want_mask, table, write_slot, pool,
+     _) = _step_inputs(geometry)
+    np.testing.assert_array_equal(_bits(got_pool), _bits(want_pool))
     np.testing.assert_array_equal(_bits(got_mask), _bits(want_mask))
     page = GEOMETRIES[geometry][2]
     a, b = kinds.index("shared_a"), kinds.index("shared_b")
@@ -308,8 +449,12 @@ def test_every_lane_of_a_step_and_the_frame_two_lanes_share(geometry):
     others = [r for r in range(len(kinds)) if r not in (a, b)]
     assert (got_mask[others, first:first + page] == -1e9).all()
     assert (got_mask[:, :page] == -1e9).all()
-    # the written slots are disjoint: KVPoolWrite's matmul is a scatter
-    assert got_oh.sum(0).max() == 1
+    # the written slots are disjoint, one a writing lane, and nothing else
+    # of the pool moved: the lane that rides along wrote back what it read
+    moved = np.flatnonzero((_bits(got_pool) != _bits(pool)).any(axis=(0, 2)))
+    wrote = write_slot[write_slot >= 0].astype(np.int64)
+    assert len(set(wrote)) == len(wrote) == len(kinds) - 1
+    np.testing.assert_array_equal(moved, np.sort(wrote))
 
 
 def test_page_mask_refuses_a_page_that_does_not_tile_the_pool():
@@ -322,8 +467,18 @@ def test_page_mask_refuses_a_page_that_does_not_tile_the_pool():
 
 def test_symbols_infer_a_steps_inputs_from_the_row_count():
     v = mx.sym.Variable
-    oh = mx.sym.KVSlotOneHot(v("write_slot"), num_slots=S, name="oh")
-    assert oh.infer_shape(write_slot=(5, 1))[1] == [(5, S)]
+    wr = mx.sym.KVPoolSlotWrite(v("pool"), v("rows"), v("write_slot"),
+                                name="wr")
+    assert wr.list_arguments() == ["pool", "rows", "write_slot"]
+    assert wr.infer_shape(pool=(H, S, DH), rows=(5, H, DH)) == (
+        [(H, S, DH), (5, H, DH), (5, 1)], [(H, S, DH)], [])
+    kv = mx.sym.KVPoolSlotWrite(v("k"), v("k_new"), v("v"), v("v_new"),
+                                v("write_slot"), num_pools=2, name="kv")
+    assert kv.list_outputs() == ["kv_output0", "kv_output1"]
+    assert kv.infer_shape(k=(H, S, DH), k_new=(5, H, DH), v=(2, S, 8),
+                          v_new=(5, 2, 8)) == (
+        [(H, S, DH), (5, H, DH), (2, S, 8), (5, 2, 8), (5, 1)],
+        [(H, S, DH), (2, S, 8)], [])
     msk = mx.sym.KVPageMask(v("page_table"), v("pos_idx"), v("write_slot"),
                             page_size=8, num_slots=S, name="msk")
     assert msk.list_arguments() == ["page_table", "pos_idx", "write_slot"]
@@ -438,7 +593,7 @@ def test_own_pages_read_is_the_whole_pool_read(monkeypatch, case, dtype, tol):
     hq, hkv, d, value_dim = OWN_PAGES[case]
     lanes, per_lane, page = GEOMETRIES["small"]
     slots = lanes * per_lane
-    kinds, got_oh, *_, table = _step_inputs("small")
+    kinds, *_, table, write_slot, _, _ = _step_inputs("small")
     rs = np.random.RandomState(len(case))
     pool_k = jnp.asarray(rs.randn(hkv, slots, d), dtype)
     pool_v = pool_k if value_dim else jnp.asarray(rs.randn(hkv, slots, d),
@@ -446,8 +601,6 @@ def test_own_pages_read_is_the_whole_pool_read(monkeypatch, case, dtype, tol):
     q = jnp.asarray(rs.randn(lanes, hq, d) * (4.0 / np.sqrt(d)), dtype)
     pos_idx = np.asarray([[0 if LANES[k](page)[1] is None
                            else LANES[k](page)[1]] for k in kinds], "f")
-    write_slot = np.where(got_oh.any(axis=1), got_oh.argmax(axis=1),
-                          -1).astype("f").reshape(lanes, 1)
     attrs = {"scale": 0.25, "value_dim": value_dim}
     step = (q, pool_k, pool_v, table, pos_idx, write_slot, page)
     own, whole = _both_reads(monkeypatch, attrs, *step)

@@ -167,12 +167,15 @@ def test_admit_pool_update_stays_in_place_on_the_chip(v5e):
     assert mem.temp_size_in_bytes < 1 << 20
 
 
-def _compile_program(sharding, sym, args):
+def _compile_program(sharding, sym, args, donated=()):
     """``sym`` as the executor jits its forward, compiled for the chip from
-    ``args``, {argument: (shape, dtype)}."""
+    ``args``, {argument: (shape, dtype)}; ``donated``: the arguments the
+    program takes donated, in its outputs' order (a decode step's cache, as
+    ``PagedKVDecoder`` dispatches it)."""
     from mxnet_tpu.executor import _GraphProgram
 
     prog = _GraphProgram(sym)
+    prog.donated = tuple(donated)
     specs = tuple(jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
                                        sharding=sharding)
                   for shape, dtype in (args[n] for n in prog.arg_names))
@@ -185,16 +188,19 @@ _INSTRUCTION = re.compile(
 
 
 def _assert_pool_step_contracts(compiled, layers, rows, heads, slots, dh,
-                                temp_bytes):
-    """A shared-pool decode program as the chip runs it: each layer's two
-    writes and two reads of the pool are ``convolution``s or ``dot``s (the
-    matrix unit), nothing multiplies rows x heads x slots x dh elements out
-    to ``reduce`` them (the vector unit: 2.1 ms a product at the benchmark's
-    sizes), no buffer the size of a pool is copied or transposed (the chip
-    keeps a dh = 64 pool slots-minor and a dh = 128 one dh-minor; a
-    contraction spelled against that re-lays 134 MB out, 8 ms a step), the
-    temporaries stay under ``temp_bytes``, and no parameter of the program
-    is rows x slots: the one-hots and masks are made on the device."""
+                                temp_bytes, cache_bytes):
+    """A shared-pool decode program as the chip runs it, its pools donated:
+    each layer's two reads of the pool are ``convolution``s or ``dot``s (the
+    matrix unit) and its write of both pools is none: ONE loop a layer that
+    updates a run of slots a row in each, in place, so the program aliases
+    ``cache_bytes``, all of its cache; nothing multiplies rows x heads x slots x dh elements
+    out to ``reduce`` them (the vector unit: 2.1 ms a product at the
+    benchmark's sizes), no buffer the size of a pool is copied or transposed
+    (the chip keeps a dh = 64 pool slots-minor and a dh = 128 one dh-minor;
+    a contraction spelled against that, or an update one slot wide, re-lays
+    134 MB out, 8 ms a step), the temporaries stay under ``temp_bytes``, and
+    no parameter of the program is rows x slots: the masks are made on the
+    device and the write takes a slot index a row."""
     hlo = compiled.as_text()
     entry = hlo[hlo.index("\nENTRY "):]
     params = [tuple(int(d) for d in dims.split(",") if d) for dims in
@@ -210,25 +216,33 @@ def _assert_pool_step_contracts(compiled, layers, rows, heads, slots, dh,
         found.append((op, name, arg))
     contractions = [line for line in hlo.splitlines()
                     if re.search(r" (convolution|dot)\(", line)]
+    updates = [line for line in hlo.splitlines()
+               if " dynamic-update-slice(" in line]
+    loops = [line for line in hlo.splitlines() if " while(" in line]
     for i in range(layers):
-        for node, count in (("kupd", 1), ("vupd", 1), ("att", 2)):
+        for node, count in (("kvupd", 0), ("att", 2)):
             tag = "layer%d_%s/" % (i, node)
             assert sum(tag in line for line in contractions) == count, tag
+        assert sum("layer%d_kvupd/" % i in line for line in updates) == 2
+        assert sum("layer%d_kvupd/" % i in line for line in loops) == 1
+    assert "slot_onehot" not in hlo
     pool, product = heads * slots * dh, rows * heads * slots * dh
     assert not [(op, name) for op, name, arg in found
                 if op == "reduce" and size.get(arg, 0) >= product]
     assert max(size.values()) < product
     assert not [(op, name) for op, name, _ in found
                 if op in ("copy", "transpose") and size[name] >= pool]
-    assert compiled.memory_analysis().temp_size_in_bytes < temp_bytes
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < temp_bytes
+    assert mem.alias_size_in_bytes == cache_bytes
 
 
 def test_transformer_base_decode_step_contracts_on_the_chip(v5e):
     """``transformer-base.generate``'s decode program (two of its six layers,
-    64 lanes x 65,536 slots, float32) lowered for the v5e. The temporaries
-    are one layer's float32 scores, 64 x 8 x 65,536 x 4 = 134 MB, and small
-    change; the parent's broadcast spelling held 145 MB and ten reduces over
-    2.1 G elements a layer."""
+    64 lanes x 65,536 slots, float32) lowered for the v5e as it is
+    dispatched: the four pools donated. The temporaries are one layer's
+    float32 scores, 64 x 8 x 65,536 x 4 = 134 MB, and small change; all 512
+    MB of pool are updated in place."""
     from mxnet_tpu.models import transformer as tf
 
     layers, lanes, slots, heads, dh, page = 2, 64, 64 * 1024, 8, 64, 16
@@ -240,17 +254,17 @@ def test_transformer_base_decode_step_contracts_on_the_chip(v5e):
         page_table=(lanes, slots // lanes // page),
         **{"kv_%s_%d" % (t, i): (heads, slots, dh)
            for t in "kv" for i in range(layers)})
+    pools = ["kv_%s_%d" % (t, i) for i in range(layers) for t in "kv"]
     compiled = _compile_program(v5e, sym, {
         n: (shape, "float32")
-        for n, shape in zip(sym.list_arguments(), arg_shapes)})
-    _assert_pool_step_contracts(compiled, layers, lanes, heads, slots, dh,
-                                temp_bytes=160 << 20)
-    # the stored row is the row: the one-hot matmuls keep float32's 24 bits
-    assert compiled.as_text().count(
-        "operand_precision={highest,highest}") == 2 * layers
-    # XLA's count: 1.38 GB a layer of pool, scores, one-hots and masks, and
-    # the feed-forward and head; the parent's spelling read 2.37 GB a layer
-    assert compiled.cost_analysis()["bytes accessed"] < 3.5e9
+        for n, shape in zip(sym.list_arguments(), arg_shapes)}, donated=pools)
+    _assert_pool_step_contracts(
+        compiled, layers, lanes, heads, slots, dh, temp_bytes=160 << 20,
+        cache_bytes=2 * layers * heads * slots * dh * 4)
+    # XLA's count: 0.84 GB a layer of pool read twice, scores and masks, and
+    # the feed-forward and head; the one-hot blend of every pool read and
+    # rewrote each whole, 1.38 GB a layer and 3.10 GB in all
+    assert compiled.cost_analysis()["bytes accessed"] < 2.2e9
 
 
 # OLMoE-1B-7B's published widths with one layer, granite-4.0-h-micro's with
@@ -294,7 +308,9 @@ def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
                   "page_table": ((lanes, max_len // 16), "float32"),
                   "kv_k_0": ((16, slots, 128), "bfloat16"),
                   "kv_v_0": ((16, slots, 128), "bfloat16")}
-    compiled = _compile_program(v5e, sym, {**weights, **inputs})
+    compiled = _compile_program(
+        v5e, sym, {**weights, **inputs},
+        donated=[n for n in inputs if n.startswith("kv_")])
     assert "ragged" in compiled.as_text().lower()
     flops = compiled.cost_analysis()["flops"]
     if program == "prefill":
@@ -310,7 +326,8 @@ def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
         assert compiled.out_info[0][1].shape == (16, slots, 128)
         # no temporary of a pool's size (16 x 16,384 x 128 bfloat16 = 67 MB)
         _assert_pool_step_contracts(compiled, 1, lanes, 16, slots, 128,
-                                    temp_bytes=48 << 20)
+                                    temp_bytes=48 << 20,
+                                    cache_bytes=2 * 16 * slots * 128 * 2)
 
 
 @pytest.mark.parametrize("program", ["prefill", "decode"])
@@ -351,7 +368,9 @@ def test_granite_hybrid_serving_programs_compile_for_the_chip(v5e, program):
             inputs[name] = ((shape[0], slots, shape[1]), "bfloat16") \
                 if kind == "pool" else ((lanes,) + tuple(shape), "float32")
         want_types = ["float32"] * 11 + ["bfloat16"] * 2 + ["float32"]
-    compiled = _compile_program(v5e, sym, {**weights, **inputs})
+    compiled = _compile_program(
+        v5e, sym, {**weights, **inputs},
+        donated=[name for name, _, _ in cache] if program == "decode" else ())
     assert [str(s.dtype) for s in compiled.out_info[0]] == want_types
     hlo = compiled.as_text()
     size, moved = {}, []
@@ -365,8 +384,14 @@ def test_granite_hybrid_serving_programs_compile_for_the_chip(v5e, program):
         assert compiled.out_info[0][1].shape == (lanes, 64, 64, 128)
         assert compiled.out_info[0][11].shape == (8, slots, 64)
         # the scores of one attention layer, 32 x 32 x 65,536 float32 =
-        # 268 MB, and small change; no second copy of any cache buffer
+        # 268 MB, and small change; no second copy of any cache buffer:
+        # the five layers' states and columns and the two pools are all
+        # updated in place
         assert mem.temp_size_in_bytes < 400 << 20
+        assert mem.alias_size_in_bytes == 2 * pool * 2 + sum(
+            lanes * math.prod(shape) * 4
+            for _, kind, shape in cache if kind == "row")
+        assert "slot_onehot" not in hlo
     else:
         # 2 x 512 tokens x (5 x 76.2 M + 60.8 M + 205.5 M) MACs of matrices
         # and the head, and the chunked scan's products beside them
@@ -420,12 +445,16 @@ def test_latent_attention_serving_programs_compile_for_the_chip(v5e, program):
         want = [((lanes, 128256), "float32")] \
             + [((1, slots, 576), "bfloat16")] * layers \
             + [((lanes,), "float32"), ((layers - 1, 128), "float32")]
-    compiled = _compile_program(v5e, sym, {**weights, **inputs})
+    compiled = _compile_program(
+        v5e, sym, {**weights, **inputs},
+        donated=[name for name, _, _ in cache] if program == "decode" else ())
     assert [(s.shape, str(s.dtype)) for s in compiled.out_info[0]] == want
     hlo = compiled.as_text()
     assert "ragged" in hlo.lower()
     mem = compiled.memory_analysis()
     if program == "decode":
+        # every latent pool is updated in place
+        assert mem.alias_size_in_bytes == layers * slots * 576 * 2
         found = [(math.prod(int(d) for d in dims.split(",") if d), op)
                  for _n, dims, op, _a in _INSTRUCTION.findall(hlo)
                  if op != "parameter"]
@@ -434,7 +463,7 @@ def test_latent_attention_serving_programs_compile_for_the_chip(v5e, program):
         # weights is), and no read's mask is built
         assert not [n for n, _ in found
                     if n >= lanes * 32 * slots and n != 128256 * 2048]
-        assert "kv_mask" not in hlo and "slot_onehot" in hlo
+        assert "kv_mask" not in hlo and "slot_onehot" not in hlo
         assert not [n for n, op in found
                     if op == "select" and n >= lanes * max_len * 576]
         gathers = [line for line in hlo.splitlines() if " gather(" in line]
@@ -446,8 +475,8 @@ def test_latent_attention_serving_programs_compile_for_the_chip(v5e, program):
                    for line in hlo.splitlines()) == layers
         assert mem.temp_size_in_bytes < 200 << 20
         # 2 x (32 x 32 x 2,048 x (576 + 576)) the latent read, a layer, the
-        # write's one-hot matmul 2.4 G a layer, the head 8.4 G, the
-        # matrices of 32 rows; the whole-pool read was 0.51e12
+        # head 8.4 G, the matrices of 32 rows (the write is no matmul); the
+        # whole-pool read was 0.51e12
         assert compiled.cost_analysis()["flops"] < 0.1e12
     else:
         assert mem.temp_size_in_bytes < 400 << 20
@@ -527,7 +556,9 @@ def test_lfm2_moe_serving_programs_compile_for_the_chip(v5e, program):
         inputs.update({name: b for (name, _, _), b in zip(cache, buffers)})
         want = [((lanes, 65536), "float32")] + buffers \
             + [((lanes,), "float32"), ((8, 64), "float32")]
-    compiled = _compile_program(v5e, sym, {**weights, **inputs})
+    compiled = _compile_program(
+        v5e, sym, {**weights, **inputs},
+        donated=[name for name, _, _ in cache] if program == "decode" else ())
     assert [(s.shape, str(s.dtype)) for s in compiled.out_info[0]] == want
     hlo = compiled.as_text()
     assert "ragged" in hlo.lower()
@@ -549,7 +580,10 @@ def test_lfm2_moe_serving_programs_compile_for_the_chip(v5e, program):
     # nothing made is as large as the lanes' scores over the pool (268 M
     # elements), and no read's mask is built
     assert not [n for n, _ in found if n >= lanes * 32 * slots]
-    assert "kv_mask" not in hlo and "slot_onehot" in hlo
+    assert "kv_mask" not in hlo and "slot_onehot" not in hlo
+    # the step updates every row and pool in place
+    assert mem.alias_size_in_bytes == 4 * 8 * slots * 64 * 2 \
+        + 8 * lanes * 2 * 2048 * 4
     gathers = [line for line in hlo.splitlines() if " gather(" in line]
     for i in (2, 6):    # a gather for the keys, one for the values
         assert sum("layer%d_att/" % i in g for g in gathers) == 2
@@ -591,8 +625,8 @@ def test_a_pool_of_narrow_heads_keeps_its_decode_program(v5e, monkeypatch,
     cfg, lanes, max_len, dtype = _WHOLE_POOL_CELLS[cell]
     slots, page = lanes * max_len, 16
     def mask_only(*a):
-        onehot, read = step_inputs(*a)
-        return onehot, {"mask": read["mask"]}
+        write, read = step_inputs(*a)
+        return write, {"mask": read["mask"]}
 
     step_inputs = tf._pool_step_inputs
 
