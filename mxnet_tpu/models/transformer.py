@@ -8,7 +8,8 @@ is mean/var composed from broadcast ops to stay faithful to the op set)."""
 from .. import symbol as sym
 from ..base import MXNetError
 
-ARCHS = ("vaswani", "olmoe", "granite_hybrid", "deepseek_v3", "lfm2_moe")
+ARCHS = ("vaswani", "olmoe", "granite_hybrid", "deepseek_v3", "lfm2_moe",
+         "mimo_v2_flash")
 
 
 def _refuse_arch(arch, what):
@@ -297,11 +298,21 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     ``conv_kernel - 1`` gated columns before ``length``, float32, an
     attention layer's K (normed per head and rotated) and V
     (B, Hkv, P, dh); then ``moe_load``.
+
+    ``arch="mimo_v2_flash"`` builds the window / full attention block with
+    sparse experts (``_mimo_layer``, its attention chosen by
+    ``hybrid_layer_pattern``, its feed-forward by ``moe_layer_freq``). After
+    the logits come EVERY layer's K (rotated) and V (scaled), (B, Hkv, P,
+    head_dim) and (B, Hkv, P, v_head_dim), a window layer's with its own
+    head count: the admission takes the prompt's last ``sliding_window``
+    positions of those into the lane's rings; then ``moe_load``. A window
+    layer scores a band of the bucket (``MultiHeadAttention(window=)``).
     """
     builders = {"olmoe": _olmoe_prefill_symbol,
                 "granite_hybrid": _granite_prefill_symbol,
                 "deepseek_v3": _deepseek_v3_prefill_symbol,
-                "lfm2_moe": _lfm2_moe_prefill_symbol}
+                "lfm2_moe": _lfm2_moe_prefill_symbol,
+                "mimo_v2_flash": _mimo_prefill_symbol}
     if arch in builders:
         return builders[arch](
             vocab_size=vocab_size, num_layers=num_layers,
@@ -493,13 +504,24 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     rows come back bit for bit). The cache outputs follow the logits in
     ``decode_cache`` order, then the token head, then ``moe_load``.
 
+    ``arch="mimo_v2_flash"`` runs ``_mimo_layer``: a full layer has
+    ``kv_k_i`` (Hkv, max_len, head_dim) and ``kv_v_i`` (Hkv, max_len,
+    v_head_dim); a window layer takes and returns ``ring_k_i`` /
+    ``ring_v_i`` (B, swa Hkv, sliding_window, ·), one ring a lane, written
+    at ``pos_idx`` mod the window (``KVRingWrite``) and read whole
+    (``KVRingAttention``, the layer's sink in its softmax): no page and no
+    mask of the pool. The cache outputs follow the logits in
+    ``decode_cache`` order, then the token head, then ``moe_load`` (the
+    rows each of ALL the experts received, held here or not).
+
     ``page_size`` is the decoder's (``PagedKVDecoder``'s default here); it
     must divide ``max_len``.
     """
     builders = {"olmoe": _olmoe_decode_symbol,
                 "granite_hybrid": _granite_decode_symbol,
                 "deepseek_v3": _deepseek_v3_decode_symbol,
-                "lfm2_moe": _lfm2_moe_decode_symbol}
+                "lfm2_moe": _lfm2_moe_decode_symbol,
+                "mimo_v2_flash": _mimo_decode_symbol}
     if arch in builders:
         return builders[arch](
             vocab_size=vocab_size, num_layers=num_layers,
@@ -729,14 +751,18 @@ def _gated_mlp(fc, h, width, out_width, tag):
     return fc(gated, out_width, tag + "_out")
 
 
-def _grouped_qkv(fc, h, seq_len, hq, hkv, dh):
+def _grouped_qkv(fc, h, seq_len, hq, hkv, dh, dv=None):
     """Grouped-query attention's three head-major tensors from ONE bias-free
     projection ``qkv`` of h (B, T, M), rows q, then k, then v, each
-    head-major: q (B, hq, T, dh), k and v (B, hkv, T, dh)."""
-    qkv = fc(h, (hq + 2 * hkv) * dh, "qkv")
-    return (_split_heads(
-        sym.slice_axis(qkv, axis=2, begin=a * dh, end=(a + n) * dh),
-        seq_len, n, dh) for a, n in ((0, hq), (hq, hkv), (hq + hkv, hkv)))
+    head-major: q (B, hq, T, dh), k (B, hkv, T, dh) and v (B, hkv, T, dv),
+    a value head as wide as a key head unless ``dv`` says otherwise."""
+    dv = dv or dh
+    ends = (0, hq * dh, (hq + hkv) * dh, (hq + hkv) * dh + hkv * dv)
+    qkv = fc(h, ends[-1], "qkv")
+    return (_split_heads(sym.slice_axis(qkv, axis=2, begin=a, end=b),
+                         seq_len, n, width)
+            for a, b, n, width in zip(ends, ends[1:], (hq, hkv, hkv),
+                                      (dh, dh, dv)))
 
 
 def _granite_layer(x, i, seq_len, attend, scan, block):
@@ -891,7 +917,11 @@ def _sigmoid_experts(h, name, block):
     """Layer ``name``'s routed experts on h (B, T, M) -> ``MoEFeedForward``'s
     (y (B·T, M), load (E,)): sigmoid scores, chosen on the score plus
     ``<name>_router_bias``, weighted by the unbiased score, renormalised and
-    scaled as ``block`` says."""
+    scaled as ``block`` says. Where ``block`` names a share
+    (``num_local_experts`` of them from ``local_expert_offset`` on) the
+    layer holds those experts alone and computes their part."""
+    share = {k: block[k] for k in ("num_local_experts", "local_expert_offset")
+             if block.get("num_local_experts")}
     return sym.MoEFeedForward(
         sym.Reshape(h, shape=(-1, block["model_dim"])),
         *(sym.Variable("%s_%s" % (name, w)) for w in (
@@ -901,7 +931,7 @@ def _sigmoid_experts(h, name, block):
         num_experts_per_tok=block["num_experts_per_tok"], scoring="sigmoid",
         router_bias=True, norm_topk_prob=block["norm_topk_prob"],
         routed_scaling_factor=block["routed_scaling_factor"],
-        name="%s_moe" % name)
+        name="%s_moe" % name, **share)
 
 
 def _deepseek_v3_layer(x, i, positions, seq_len, attend, block):
@@ -952,14 +982,18 @@ def _deepseek_v3_layer(x, i, positions, seq_len, attend, block):
     return x + sym.Reshape(moe[0], shape=(-1, seq_len, d)) + shared, moe[1]
 
 
-def _deepseek_v3_stack(vocab_size, seq_len, positions, attend, block):
+def _deepseek_v3_stack(vocab_size, seq_len, positions, attend, block,
+                       layer=None):
     """Embedding, the layers, final norm and untied head: ``data`` (B, T) ->
-    (float32 logits (B·T, vocab), moe_load (expert layers, experts))."""
+    (float32 logits (B·T, vocab), moe_load (expert layers, experts)).
+    ``layer``: another block's layer of the same signature
+    (``_mimo_layer``)."""
+    layer = layer or _deepseek_v3_layer
     x = sym.Embedding(data=sym.Variable("data"), input_dim=vocab_size,
                       output_dim=block["model_dim"], name="embed")
     loads = []
     for i in range(block["num_layers"]):
-        x, load = _deepseek_v3_layer(x, i, positions, seq_len, attend, block)
+        x, load = layer(x, i, positions, seq_len, attend, block)
         if load is not None:
             loads.append(sym.Reshape(load, shape=(1, -1)))
     logits = _olmoe_head(x, vocab_size, block["model_dim"], block["rms_eps"])
@@ -1256,6 +1290,183 @@ def _lfm2_moe_param_shapes(vocab_size, num_layers, **sizes):
     return shapes
 
 
+# ---------------------------------- MiMo-V2-Flash (window and full attention)
+def _mimo_sizes(num_layers, num_heads, model_dim, ffn_dim, hybrid_layer_pattern,
+                moe_layer_freq, moe_ffn_dim=None, num_kv_heads=None,
+                swa_num_kv_heads=None, head_dim=None, v_head_dim=None,
+                sliding_window=128, rotary_dim=None, rope_theta=5e6,
+                swa_rope_theta=1e4, attention_value_scale=1.0,
+                num_experts=256, num_experts_per_tok=8, num_local_experts=0,
+                local_expert_offset=0, rms_eps=1e-5,
+                routed_scaling_factor=1.0, norm_topk_prob=True, **kwargs):
+    """``_mimo_layer``'s keywords from a builder's (``ffn_dim`` is a dense
+    layer's width, ``moe_ffn_dim`` one expert's; ``num_local_experts`` = 0
+    holds every expert; keywords of the other architectures are dropped)."""
+    window, sparse = tuple(hybrid_layer_pattern), tuple(moe_layer_freq)
+    for what, flags in (("hybrid_layer_pattern", window),
+                        ("moe_layer_freq", sparse)):
+        if len(flags) != num_layers or set(flags) - {0, 1}:
+            raise MXNetError("mimo_v2_flash: %s must give %d layers a 0 or a "
+                             "1, got %r" % (what, num_layers, flags))
+    head_dim = head_dim or model_dim // num_heads
+    rotary_dim = rotary_dim or head_dim
+    if rotary_dim % 2 or rotary_dim > head_dim:
+        raise MXNetError("mimo_v2_flash: rotary_dim %d must be even and at "
+                         "most head_dim %d" % (rotary_dim, head_dim))
+    return dict(
+        num_layers=num_layers, window_layers=window, expert_layers=sparse,
+        num_heads=num_heads,
+        num_kv_heads=num_kv_heads or num_heads,
+        swa_num_kv_heads=swa_num_kv_heads or num_kv_heads or num_heads,
+        head_dim=head_dim, v_head_dim=v_head_dim or head_dim,
+        model_dim=model_dim, ffn_dim=ffn_dim, moe_ffn_dim=moe_ffn_dim,
+        sliding_window=int(sliding_window), rotary_dim=rotary_dim,
+        rope_theta=float(rope_theta), swa_rope_theta=float(swa_rope_theta),
+        attention_value_scale=float(attention_value_scale),
+        num_experts=num_experts, num_experts_per_tok=num_experts_per_tok,
+        num_local_experts=int(num_local_experts),
+        local_expert_offset=int(local_expert_offset), rms_eps=rms_eps,
+        routed_scaling_factor=float(routed_scaling_factor),
+        norm_topk_prob=bool(norm_topk_prob))
+
+
+def _mimo_kv_heads(block, i):
+    """Key/value heads of layer ``i``: a window layer has its own count."""
+    return block["swa_num_kv_heads" if block["window_layers"][i]
+                 else "num_kv_heads"]
+
+
+def _mimo_layer(x, i, positions, seq_len, attend, block):
+    """One ``model_type: mimo_v2_flash`` block on x (B, T, M) -> (x', load
+    (E,) or None for a dense layer): pre-norm RMSNorm, grouped-query
+    attention whose KIND is ``hybrid_layer_pattern[i]``, then a feed-forward
+    chosen by ``moe_layer_freq[i]``.
+
+    Attention, both kinds: one bias-free projection to [q | k | v], keys as
+    wide as queries (``head_dim``) and values narrower (``v_head_dim``), no
+    q/k norm; rotary positions over the FIRST ``rotary_dim`` features of
+    each q and k head; the values scaled by ``attention_value_scale`` before
+    they are attended or cached. A full layer (0) has ``num_kv_heads``
+    key/value heads and rotates at ``rope_theta``; a window layer (1) has
+    ``swa_num_kv_heads``, rotates at ``swa_rope_theta``, attends the last
+    ``sliding_window`` positions and carries ``layer<i>_sink_bias`` (H,),
+    one logit a query head in its softmax's denominator.
+    ``attend(i, q, k, v, sink)`` is the one thing the prefill and the decode
+    graph do differently (``sink`` None in a full layer): it takes the
+    rotated head-major (B, H or Hkv, T, d) tensors and returns
+    (B, H, T, v_head_dim). The feed-forward: the gated SiLU MLP where
+    ``moe_layer_freq[i]`` is 0, else sigmoid-routed experts alone
+    (``_sigmoid_experts``), of which the layer may hold a share."""
+    name = "layer%d" % i
+    d, eps, hq = block["model_dim"], block["rms_eps"], block["num_heads"]
+    dk, dv = block["head_dim"], block["v_head_dim"]
+    windowed = block["window_layers"][i]
+    fc = lambda data, width, tag: sym.FullyConnected(
+        data=data, num_hidden=width, no_bias=True, flatten=False,
+        name="%s_%s" % (name, tag))
+    h = sym.RMSNorm(x, eps=eps, name="%s_ln1" % name)
+    q, k, v = _grouped_qkv(fc, h, seq_len, hq, _mimo_kv_heads(block, i), dk,
+                           dv)
+    q, k = (sym.RotaryEmbedding(
+        a, positions, rotary_dim=block["rotary_dim"],
+        base=block["swa_rope_theta" if windowed else "rope_theta"],
+        name="%s_%srope" % (name, tag)) for a, tag in ((q, "q"), (k, "k")))
+    sink = sym.Variable("%s_sink_bias" % name, shape=(hq,)) if windowed \
+        else None
+    att = attend(i, q, k, v * block["attention_value_scale"], sink)
+    x = x + fc(_merge_heads(att, seq_len, hq * dv), d, "proj")
+    h = sym.RMSNorm(x, eps=eps, name="%s_ln2" % name)
+    if not block["expert_layers"][i]:
+        return x + _gated_mlp(fc, h, block["ffn_dim"], d, "mlp"), None
+    moe = _sigmoid_experts(h, name, block)
+    return x + sym.Reshape(moe[0], shape=(-1, seq_len, d)), moe[1]
+
+
+def _mimo_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
+    block = _mimo_sizes(num_layers, **sizes)
+    positions = sym.Reshape(sym._arange(start=0, stop=prefill_len),
+                            shape=(1, prefill_len))
+    cache = []      # the layers are built in order, so is this
+
+    def attend(i, q, k, v, sink):
+        cache.extend([k, v])    # as the cache keeps them: rotated, scaled
+        if sink is None:
+            return sym.MultiHeadAttention(query=q, key=k, value=v,
+                                          causal=True, name="layer%d_att" % i)
+        # the sink is the fourth INPUT, ``sink=True`` the attribute that
+        # says so (as MoEFeedForward's router_bias)
+        return sym.MultiHeadAttention(
+            q, k, v, sink, causal=True, window=block["sliding_window"],
+            sink=True, name="layer%d_att" % i)
+
+    logits, load = _deepseek_v3_stack(vocab_size, prefill_len, positions,
+                                      attend, block, layer=_mimo_layer)
+    return sym.Group([logits] + cache + load)
+
+
+def _mimo_decode_symbol(vocab_size, num_layers, num_slots, page_size,
+                        token_out=True, **sizes):
+    block = _mimo_sizes(num_layers, **sizes)
+    hq, dk, dv = (block[k] for k in ("num_heads", "head_dim", "v_head_dim"))
+    pos_idx = sym.Variable("pos_idx")
+    write_slot = sym.Variable("write_slot")
+    write, read = _pool_step_inputs(pos_idx, num_slots, page_size, write_slot)
+    cache = []      # the layers are built in order, so is this
+
+    def attend(i, q, k_new, v_new, sink):
+        # one token a lane: the head-major (B, H, 1, d) tensors are rows
+        hkv = _mimo_kv_heads(block, i)
+        q, k_new, v_new = (sym.Reshape(a, shape=(-1, n, width))
+                           for a, n, width in ((q, hq, dk), (k_new, hkv, dk),
+                                               (v_new, hkv, dv)))
+        if sink is None:
+            ctx = _pool_attend(i, q, k_new, v_new, write, read, cache)
+        else:
+            # a window layer: the lane's own rings, no frame and no table
+            rings = sym.KVRingWrite(
+                sym.Variable("ring_k_%d" % i), k_new,
+                sym.Variable("ring_v_%d" % i), v_new, pos_idx, write_slot,
+                num_rings=2, name="layer%d_kvupd" % i)
+            cache.extend([rings[0], rings[1]])
+            ctx = sym.KVRingAttention(
+                q, rings[0], rings[1], pos_idx, write_slot, sink, sink=True,
+                name="layer%d_att" % i)
+        return sym.Reshape(ctx, shape=(-1, hq, 1, dv))
+
+    logits, load = _deepseek_v3_stack(vocab_size, 1, pos_idx, attend, block,
+                                      layer=_mimo_layer)
+    # moe_load LAST: the cache and the token head keep their places
+    return sym.Group([_token_head(
+        logits, cache, "greedy_token" if token_out else None)] + load)
+
+
+def _mimo_param_shapes(vocab_size, num_layers, **sizes):
+    block = _mimo_sizes(num_layers, **sizes)
+    d, e, f = block["model_dim"], block["num_experts"], block["moe_ffn_dim"]
+    held = block["num_local_experts"] or e
+    hq, dk, dv = (block[k] for k in ("num_heads", "head_dim", "v_head_dim"))
+    shapes = {"embed_weight": (vocab_size, d), "final_ln_gamma": (d,),
+              "lm_head_weight": (vocab_size, d)}
+    for i, windowed in enumerate(block["window_layers"]):
+        n = "layer%d_" % i
+        hkv = _mimo_kv_heads(block, i)
+        shapes.update({n + "ln1_gamma": (d,), n + "ln2_gamma": (d,),
+                       n + "qkv_weight": ((hq + hkv) * dk + hkv * dv, d),
+                       n + "proj_weight": (d, hq * dv)})
+        if windowed:
+            shapes[n + "sink_bias"] = (hq,)
+        if not block["expert_layers"][i]:
+            shapes.update({n + "mlp_in_weight": (2 * block["ffn_dim"], d),
+                           n + "mlp_out_weight": (d, block["ffn_dim"])})
+            continue
+        shapes.update({
+            n + "router_weight": (e, d), n + "router_bias": (e,),
+            n + "experts_gate_weight": (held, d, f),
+            n + "experts_up_weight": (held, d, f),
+            n + "experts_down_weight": (held, f, d)})
+    return shapes
+
+
 def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
                  **sizes):
     """What a decode graph of ``arch`` keeps between steps, in the order its
@@ -1266,7 +1477,25 @@ def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
     shape, float32. Latent attention keeps ONE pool a layer, of one head:
     the old kind, no new one. Where ``layer_types`` chooses the mixer
     (``granite_hybrid``, ``lfm2_moe``) the list mixes the two kinds, in layer
-    order."""
+    order. A ``"ring"`` is a WINDOW layer's K or V (``mimo_v2_flash``):
+    addressed by lane and position mod the window, ``shape`` is one lane's
+    (heads, window, d) and the buffer (lanes,) + shape in the pools' type;
+    it takes no frame and no page-table entry, whatever the lane's length.
+    A full layer beside it keeps its pools, the key's wider than the
+    value's."""
+    if arch == "mimo_v2_flash":
+        block = _mimo_sizes(num_layers, num_heads=num_heads,
+                            model_dim=model_dim, head_dim=head_dim, **sizes)
+        dk, dv, w = (block[k] for k in ("head_dim", "v_head_dim",
+                                        "sliding_window"))
+        out = []
+        for i, windowed in enumerate(block["window_layers"]):
+            hkv = _mimo_kv_heads(block, i)
+            out += [("ring_k_%d" % i, "ring", (hkv, w, dk)),
+                    ("ring_v_%d" % i, "ring", (hkv, w, dv))] if windowed \
+                else [("kv_k_%d" % i, "pool", (hkv, dk)),
+                      ("kv_v_%d" % i, "pool", (hkv, dv))]
+        return out
     if arch == "deepseek_v3":
         block = _deepseek_v3_sizes(num_layers, num_heads=num_heads,
                                    model_dim=model_dim, **sizes)
@@ -1338,8 +1567,10 @@ def param_shapes(arch, vocab_size, num_layers, num_heads, model_dim, ffn_dim,
         return _deepseek_v3_param_shapes(
             vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
             ffn_dim=ffn_dim, num_experts=num_experts, **kwargs)
-    if arch == "lfm2_moe":
-        return _lfm2_moe_param_shapes(
+    if arch in ("lfm2_moe", "mimo_v2_flash"):
+        shapes = _mimo_param_shapes if arch == "mimo_v2_flash" \
+            else _lfm2_moe_param_shapes
+        return shapes(
             vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
             ffn_dim=ffn_dim, head_dim=head_dim, num_experts=num_experts,
             **kwargs)

@@ -30,11 +30,14 @@ DISPATCH_COUNTS = {"ring": 0, "pallas": 0, "xla": 0}
     attrs={
         "causal": AttrSpec("bool", default=False),
         "scale": AttrSpec("float", default=-1.0),
+        "window": AttrSpec("int", default=0),
+        "sink": AttrSpec("bool", default=False),
     },
-    input_names=("query", "key", "value"),
+    input_names=lambda attrs: ("query", "key", "value") + (
+        ("sink",) if attrs.get("sink") else ()),
     aliases=("MultiHeadAttention",),
 )
-def _multi_head_attention(attrs, query, key, value):
+def _multi_head_attention(attrs, query, key, value, sink=None):
     """softmax(QKᵀ·scale + mask)V over (B, H, T, D) tensors. Computation in
     fp32 for a stable softmax regardless of the IO dtype (bf16 fast path).
     ``MXNET_USE_PALLAS_ATTENTION=1`` routes to the hand-tiled flash kernel
@@ -53,14 +56,31 @@ def _multi_head_attention(attrs, query, key, value):
     size 1 where the head counts are equal. Fewer key/value heads take the
     dense path only. ``value`` may be narrower or wider than ``key`` (latent
     attention's 128 under a 192-wide key): the output takes the value's
-    width, on the dense path."""
+    width, on the dense path.
+
+    ``window`` = W > 0 (causal self-attention only) lets position t attend
+    the W positions t - W < j <= t, itself among them. ``sink=True`` takes a
+    fourth input ``sink`` (H,), one logit a query head that joins the
+    softmax's denominator and carries no value: ``p_j = exp(s_j - m) /
+    (sum_k exp(s_k - m) + exp(b_h - m))``, ``m`` the maximum over the scores
+    and ``b_h``. Both take the dense path. A windowed call over T > W
+    positions, T a multiple of W, scores a BAND: a block of W queries
+    against its own and the previous block of keys, T x 2W scores instead
+    of T x T, the same positions under the same mask (the blocks are read
+    from the shapes; a shorter or ragged call masks the full scores)."""
     import os
 
     b, h, t, d = query.shape
     hkv, s_len = key.shape[1], key.shape[2]
     g = _kv_groups(h, hkv, "MultiHeadAttention")
+    window = attrs.get("window", 0)
+    plain = window <= 0 and sink is None
+    if window > 0 and not (attrs["causal"] and s_len == t):
+        raise MXNetError("MultiHeadAttention: a window needs causal "
+                         "self-attention, got causal=%s over %d queries and "
+                         "%d keys" % (attrs["causal"], t, s_len))
     mesh = None
-    if g == 1 and os.environ.get("MXNET_RING_ATTENTION", "1") == "1":
+    if plain and g == 1 and os.environ.get("MXNET_RING_ATTENTION", "1") == "1":
         from ..parallel.mesh import current_trace_mesh
 
         mesh = current_trace_mesh()
@@ -81,7 +101,8 @@ def _multi_head_attention(attrs, query, key, value):
                 batch_axis="data" if "data" in mesh.axis_names else None)
             return out.transpose(0, 2, 1, 3)
 
-    if g == 1 and os.environ.get("MXNET_USE_PALLAS_ATTENTION", "0") == "1":
+    if plain and g == 1 \
+            and os.environ.get("MXNET_USE_PALLAS_ATTENTION", "0") == "1":
         from . import pallas_attention as pa
 
         if pa.supported(query.shape, key.shape, causal=attrs["causal"]):
@@ -91,15 +112,63 @@ def _multi_head_attention(attrs, query, key, value):
                 scale=max(attrs["scale"], 0.0), interpret=not on_tpu)
     scale = attrs["scale"] if attrs["scale"] > 0 else 1.0 / np.sqrt(d)
     q = query.astype("float32").reshape(b, hkv, g, t, d)
+    if sink is not None:
+        sink = sink.astype("float32").reshape(hkv, g)
+    if 0 < window < t and t % window == 0:
+        out = _band_attention(q, key.astype("float32"),
+                              value.astype("float32"), window, scale, sink)
+        return out.reshape(b, h, t, value.shape[-1]).astype(query.dtype)
     s = jnp.einsum("bkgqd,bkud->bkgqu", q, key.astype("float32")) * scale
     if attrs["causal"]:
         # bottom-right aligned so a rectangular (decode) call — T queries over
         # S >= T keys — lets each query see all S-T+q past keys
         mask = jnp.tril(jnp.ones((t, s_len), bool), k=s_len - t)
+        if window > 0:
+            mask &= ~jnp.tril(jnp.ones((t, s_len), bool), k=-window)
         s = jnp.where(mask, s, -jnp.inf)
-    out = jnp.einsum("bkgqu,bkud->bkgqd", jax.nn.softmax(s, axis=-1),
-                     value.astype("float32"))
+    p = jax.nn.softmax(s, axis=-1) if sink is None \
+        else _sink_softmax(s, sink[None, :, :, None, None])
+    out = jnp.einsum("bkgqu,bkud->bkgqd", p, value.astype("float32"))
     return out.reshape(b, h, t, value.shape[-1]).astype(query.dtype)
+
+
+def _sink_softmax(s, sink):
+    """``softmax`` of float32 scores ``s`` over their last axis with one more
+    logit, ``sink`` (broadcast against ``s``, its last axis 1), in the
+    denominator: the sink takes weight and gives no value, so the weights
+    that come back sum to less than 1."""
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), sink)
+    e = jnp.exp(s - m)
+    return e / (jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(sink - m))
+
+
+def _band_attention(q, k, v, w, scale, sink):
+    """Causal attention under a window of ``w`` positions as a band: ``q``
+    (B, Hkv, G, T, d) in T / w blocks of w queries, each against its own
+    block of keys and the one before it, so the scores are T x 2w, float32.
+    Query r of a block sits w + r - j positions after key j of its 2w; it
+    attends where that is in [0, w). The first block has no block before it:
+    the zeros that stand there are masked as the future is."""
+    b, hkv, g, t, d = q.shape
+    nb = t // w
+
+    def banded(a):      # (B, Hkv, T, x) -> (B, Hkv, nb, 2w, x)
+        blocks = a.reshape(b, hkv, nb, w, a.shape[-1])
+        before = jnp.concatenate(
+            [jnp.zeros_like(blocks[:, :, :1]), blocks[:, :, :-1]], axis=2)
+        return jnp.concatenate([before, blocks], axis=3)
+
+    s = jnp.einsum("bkgnqd,bknud->bkgnqu", q.reshape(b, hkv, g, nb, w, d),
+                   banded(k)) * scale
+    ahead = w + jnp.arange(w)[:, None] - jnp.arange(2 * w)[None, :]
+    live = (ahead >= 0) & (ahead < w)
+    first = live & (jnp.arange(2 * w)[None, :] >= w)
+    mask = jnp.where(jnp.arange(nb)[:, None, None] == 0, first, live)
+    s = jnp.where(mask, s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1) if sink is None \
+        else _sink_softmax(s, sink[None, :, :, None, None, None])
+    out = jnp.einsum("bkgnqu,bknud->bkgnqd", p, banded(v))
+    return out.reshape(b, hkv, g, t, v.shape[-1])
 
 
 def _kv_groups(heads, kv_heads, what):
@@ -128,7 +197,8 @@ def _rms_norm(attrs, data, gamma):
 @register(
     "_contrib_RotaryEmbedding",
     attrs={"base": AttrSpec("float", default=10000.0),
-           "interleaved": AttrSpec("bool", default=False)},
+           "interleaved": AttrSpec("bool", default=False),
+           "rotary_dim": AttrSpec("int", default=0)},
     input_names=("data", "positions"),
     aliases=("RotaryEmbedding",),
 )
@@ -139,7 +209,19 @@ def _rotary_embedding(attrs, data, positions):
     i + dh/2), ``inv_freq_i = base^(-2i/dh)``. ``interleaved`` pairs
     feature 2i with 2i + 1 instead (``rope_interleave`` of
     ``model_type: deepseek_v3``), same frequencies. Angles, sine and cosine
-    are float32 whatever the IO dtype."""
+    are float32 whatever the IO dtype. ``rotary_dim`` = r > 0 rotates the
+    FIRST r features of a head alone, as a head of r features (pairs
+    (i, i + r/2), ``inv_freq_i = base^(-2i/r)``); the other dh - r pass
+    through (``partial_rotary_factor``)."""
+    part = attrs.get("rotary_dim", 0)
+    if part and part != data.shape[-1]:
+        if part % 2 or not 0 < part < data.shape[-1]:
+            raise MXNetError("RotaryEmbedding: rotary_dim %d must be even "
+                             "and inside a head of %d features"
+                             % (part, data.shape[-1]))
+        turned = _rotary_embedding(dict(attrs, rotary_dim=0),
+                                   data[..., :part], positions)
+        return jnp.concatenate([turned, data[..., part:]], axis=-1)
     dh = data.shape[-1]
     inv_freq = attrs["base"] ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
     angle = positions.astype(jnp.float32)[:, None, :, None] * inv_freq
@@ -249,26 +331,20 @@ def _kv_pool_slot_write(attrs, *inputs):
     return jax.lax.fori_loop(0, n_rows, write, tuple(pools))
 
 
-def pool_read_own_pages(query, pool_k, pool_v, page_table, page_size):
-    """Whether ``KVPoolAttention`` gathers each row's own pages (True) or
-    scores the whole pool (False): the smaller count of the bytes either
-    form moves through the chip's memory, from the operands' shapes and
-    types alone. Each operand carries ``.shape`` and ``.dtype``: ``query``
-    (R, H, dh), the pools (Hkv, S, d) (``pool_v`` None where the value is
-    read from the key's pool), ``page_table`` (R, max_pages) or None for a
-    read that was handed no table, which scores the whole pool.
+def pool_read_bytes(query, pool_k, pool_v, page_table, page_size):
+    """(whole, own): the bytes either form of ``KVPoolAttention``'s read
+    moves through the chip's memory, from the operands' shapes and types
+    alone (``pool_read_own_pages`` says what each operand is). Every pool is
+    counted ON ITS OWN: a key pool and a value pool may differ in width (192
+    beside 128), and so do their bytes and the padding of their copies.
 
     Whole: the pools once and the float32 scores, R x H x S, three times
     (written, read by the softmax, read by the context). Own pages: the
     pools re-laid from slots-minor to rows (read and written), then a copy
-    of R x Hkv x max_pages x page_size rows, the minor dimension padded to
-    the chip's 128 lanes, written by the gather and read by it and by both
-    contractions, and the small scores. So the gather pays where the pool is
-    small beside its scores (one wide latent row read by every head) and
-    loses where a 64-wide row pads to twice its size and R x max_pages x
-    page_size is the whole pool again."""
-    if page_table is None or page_size < 1:
-        return False
+    of R x Hkv x max_pages x page_size rows a pool, the minor dimension
+    padded to the chip's 128 lanes (a 192-wide key to 256, a 128-wide value
+    not at all), written by the gather and read by it and by both
+    contractions, and the small scores."""
     rows, heads = query.shape[:2]
     own_slots = page_table.shape[1] * page_size
     pool_bytes = copy_bytes = 0
@@ -279,6 +355,26 @@ def pool_read_own_pages(query, pool_k, pool_v, page_table, page_size):
         copy_bytes += rows * hkv * own_slots * -(-d // 128) * 128 * size
     whole = pool_bytes + 3 * 4 * rows * heads * pool_k.shape[1]
     own = 2 * pool_bytes + 4 * copy_bytes + 3 * 4 * rows * heads * own_slots
+    return whole, own
+
+
+def pool_read_own_pages(query, pool_k, pool_v, page_table, page_size):
+    """Whether ``KVPoolAttention`` gathers each row's own pages (True) or
+    scores the whole pool (False): the smaller count of the bytes either
+    form moves through the chip's memory (``pool_read_bytes``), from the
+    operands' shapes and types alone. Each operand carries ``.shape`` and
+    ``.dtype``: ``query`` (R, H, dh), the pools (Hkv, S, d), each of its own
+    width (``pool_v`` None where the value is read from the key's pool),
+    ``page_table`` (R, max_pages) or None for a read that was handed no
+    table, which scores the whole pool.
+
+    The gather pays where the pool is small beside its scores (one wide
+    latent row read by every head; 64 query heads over 4 key/value heads at
+    8,192 slots a lane) and loses where a 64-wide row pads to twice its size
+    and R x max_pages x page_size is the whole pool again."""
+    if page_table is None or page_size < 1:
+        return False
+    whole, own = pool_read_bytes(query, pool_k, pool_v, page_table, page_size)
     return own < whole
 
 
@@ -420,3 +516,80 @@ def _kv_page_mask(attrs, page_table, pos_idx, write_slot):
     held_in_frame = jnp.max(jnp.where(at_frame, held[:, :, None], 0), axis=1)
     live = jnp.arange(page, dtype=jnp.int32) < held_in_frame[:, :, None]
     return jnp.where(live, jnp.float32(0), _NEG).reshape(rows, slots)
+
+
+def _ring_write_inputs(attrs):
+    return [name % i for i in range(attrs.get("num_rings", 1))
+            for name in ("ring_%d", "rows_%d")] + ["pos_idx", "write_slot"]
+
+
+@register(
+    "_contrib_KVRingWrite",
+    attrs={"num_rings": AttrSpec("int", default=1)},
+    input_names=_ring_write_inputs,
+    num_outputs=lambda attrs: attrs.get("num_rings", 1),
+    aliases=("KVRingWrite",),
+)
+def _kv_ring_write(attrs, *inputs):
+    """A decode step's write into the per-lane rings of a WINDOW layer, into
+    ``num_rings`` rings at once (the layer's K and V): ``ring_i``
+    (R, Hkv, W, d) and ``rows_i`` (R, Hkv, d), pair after pair, then
+    ``pos_idx`` (R, 1), row r's position, and ``write_slot`` (R, 1), negative
+    for a row that writes nothing (a lane that rides along; its value is
+    otherwise the pools' business). What comes back is every ring with
+    ``ring[r, :, pos_r mod W, :] = rows[r]`` in the ring's dtype: after the
+    token at position t the ring holds exactly the positions t - W + 1 .. t,
+    each at its position mod W, and a stored row is the row bit for bit.
+
+    A ring is a lane's own: it takes no frame of the page allocator and no
+    entry of a page table, and its bytes do not depend on the lane's length.
+    The write is one ``where`` over the ring, W slots a lane: a program that
+    takes the rings donated updates them in place."""
+    rings, rows = inputs[0:-2:2], inputs[1:-2:2]
+    window = rings[0].shape[2]
+    pos = inputs[-2].reshape(-1).astype(jnp.int32)
+    at = (jnp.arange(window, dtype=jnp.int32)[None, :]
+          == (pos % window)[:, None]) & (inputs[-1].reshape(-1) >= 0)[:, None]
+    return tuple(jnp.where(at[:, None, :, None],
+                           new.astype(ring.dtype)[:, :, None, :], ring)
+                 for ring, new in zip(rings, rows))
+
+
+@register(
+    "_contrib_KVRingAttention",
+    attrs={"scale": AttrSpec("float", default=-1.0),
+           "sink": AttrSpec("bool", default=False)},
+    input_names=lambda attrs: ("query", "ring_k", "ring_v", "pos_idx",
+                               "write_slot") + (
+        ("sink",) if attrs.get("sink") else ()),
+    aliases=("KVRingAttention",),
+)
+def _kv_ring_attention(attrs, query, ring_k, ring_v, pos_idx, write_slot,
+                       sink=None):
+    """The read of a window layer's rings in a decode step: row r of
+    ``query`` (R, H, dk) attends ITS OWN ``ring_k`` (R, Hkv, W, dk) /
+    ``ring_v`` (R, Hkv, W, dv), written by ``KVRingWrite`` in the same step,
+    so the ring holds the row's last W positions, itself included. Keys are
+    cached rotated and attention over slots is order-agnostic, so the read
+    needs no positions, only which slots hold a position of THIS sequence:
+    the first ``pos + 1`` while the ring is filling (what a lane's last
+    occupant left past them is masked), all W from then on, none where
+    ``write_slot`` is negative. ``sink=True`` takes ``sink`` (H,), one logit
+    a query head in the softmax's denominator (``MultiHeadAttention`` says
+    how). Contractions, accumulator, softmax and grouped heads as
+    ``KVPoolAttention``'s; the output is (R, H, dv)."""
+    scale = attrs["scale"] if attrs["scale"] > 0 \
+        else 1.0 / np.sqrt(query.shape[-1])
+    r, h, dk = query.shape
+    hkv, window = ring_k.shape[1], ring_k.shape[2]
+    g = _kv_groups(h, hkv, "KVRingAttention")
+    live = jnp.arange(window, dtype=jnp.int32)[None, :] \
+        < _context_slots(pos_idx, write_slot)[:, None]
+    s = jnp.einsum("rkgd,rkwd->rkgw", query.reshape(r, hkv, g, dk), ring_k,
+                   preferred_element_type=jnp.float32) * scale \
+        + jnp.where(live, jnp.float32(0), _NEG)[:, None, None, :]
+    p = jax.nn.softmax(s, axis=-1) if sink is None else _sink_softmax(
+        s, sink.astype(jnp.float32).reshape(1, hkv, g, 1))
+    out = jnp.einsum("rkgw,rkwd->rkgd", p, ring_v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(r, h, out.shape[-1]).astype(query.dtype)

@@ -178,6 +178,9 @@ register_meta("SparseEmbedding",
 register_meta("_contrib_RMSNorm", input_ranks={"data": (1, None), "gamma": 1},
               dtype_policy="first", param_slots=("gamma",),
               shard_rule="elementwise", aliases=("RMSNorm",))
+register_meta("_contrib_MultiHeadAttention",
+              input_ranks={"query": 4, "key": 4, "value": 4, "sink": 1},
+              param_slots=("sink",), aliases=("MultiHeadAttention",))
 register_meta("_contrib_RotaryEmbedding",
               input_ranks={"data": 4, "positions": 2}, dtype_policy="first",
               aliases=("RotaryEmbedding",))
@@ -192,6 +195,15 @@ register_meta("_contrib_KVPoolAttention",
               input_ranks={"query": 3, "pool_k": 3, "pool_v": 3, "mask": 2,
                            "page_table": 2, "pos_idx": 2, "write_slot": 2},
               dtype_policy="first", aliases=("KVPoolAttention",))
+register_meta("_contrib_KVRingWrite",
+              input_ranks={"ring_0": 4, "rows_0": 3, "ring_1": 4, "rows_1": 3,
+                           "pos_idx": 2, "write_slot": 2},
+              dtype_policy="first", aliases=("KVRingWrite",))
+register_meta("_contrib_KVRingAttention",
+              input_ranks={"query": 3, "ring_k": 4, "ring_v": 4, "pos_idx": 2,
+                           "write_slot": 2, "sink": 1},
+              dtype_policy="first", param_slots=("sink",),
+              aliases=("KVRingAttention",))
 register_meta("_contrib_MoEFeedForward",
               input_ranks={"data": 2, "router_weight": 2, "gate_weight": 3,
                            "up_weight": 3, "down_weight": 3,

@@ -35,6 +35,8 @@ def _moe_names(attrs):
         "router_bias": AttrSpec("bool", default=False),
         "norm_topk_prob": AttrSpec("bool", default=False),
         "routed_scaling_factor": AttrSpec("float", default=1.0),
+        "num_local_experts": AttrSpec("int", default=0),
+        "local_expert_offset": AttrSpec("int", default=0),
     },
     input_names=_moe_names,
     num_outputs=2,
@@ -59,11 +61,29 @@ def _moe_feed_forward(attrs, data, router_weight, gate_weight, up_weight,
     (group, k, n) operand takes without a transpose. Returns ``(y (N, D),
     load (E,))``, ``load`` the number of rows each expert received, float32.
 
+    ``num_local_experts`` = L > 0 with ``local_expert_offset`` = o says which
+    experts this layer HOLDS, experts o .. o + L - 1 of the E it routes over
+    (one chip's share under expert parallelism): the three stacks then have
+    L leading rows, the router still E. Scores, selection and
+    renormalisation run over all E, wherever the chosen live; only the held
+    experts' products are computed and summed, an assignment to an absent
+    expert adds nothing, and ``load`` still counts all E. The shares of one
+    layer therefore add up to the uncut layer's output. Every assignment
+    keeps its row in the grouped matmul (rows of absent experts sort last,
+    past the last group), so no shape depends on the routing. The default,
+    0, holds every expert.
+
     The router's product and softmax run in float32 at the highest matmul
     precision whatever the storage type: one bfloat16 pass flips near-tied
     experts. Ties go to the lower expert index (``jax.lax.top_k``). The
     expert products multiply in the storage type and accumulate in float32."""
     k, n_exp = attrs["num_experts_per_tok"], attrs["num_experts"]
+    n_local = attrs.get("num_local_experts", 0) or n_exp
+    first = attrs.get("local_expert_offset", 0)
+    if not 0 <= first <= n_exp - n_local or gate_weight.shape[0] != n_local:
+        raise MXNetError(
+            "MoEFeedForward: experts %d..%d of %d held, stacks of %d"
+            % (first, first + n_local - 1, n_exp, gate_weight.shape[0]))
     n = data.shape[0]
     scoring = attrs.get("scoring", "softmax")
     if scoring not in _SCORES:
@@ -82,13 +102,23 @@ def _moe_feed_forward(attrs, data, router_weight, gate_weight, up_weight,
     if attrs.get("routed_scaling_factor", 1.0) != 1.0:
         weight = weight * attrs["routed_scaling_factor"]
     expert = expert.reshape(-1)
-    order = jnp.argsort(expert, stable=True)                # rows by expert
+    by, held = expert, None
+    if n_local < n_exp:
+        # an assignment to an absent expert keeps its row, sorted past the
+        # last group, and weighs nothing
+        held = (expert >= first) & (expert < first + n_local)
+        by = jnp.where(held, expert - first, n_local)
+        weight = jnp.where(held.reshape(n, k), weight, 0)
+    order = jnp.argsort(by, stable=True)                    # rows by expert
     load = jnp.bincount(expert, length=n_exp).astype(jnp.int32)
+    groups = load if held is None else load[first:first + n_local]
     rows = data[order // k]                                 # (N*k, D)
     dot = lambda a, b: jax.lax.ragged_dot(
-        a, b, load, preferred_element_type=jnp.float32)
+        a, b, groups, preferred_element_type=jnp.float32)
     act = jax.nn.silu(dot(rows, gate_weight)) * dot(rows, up_weight)
     out = dot(act.astype(data.dtype), down_weight)          # (N*k, D) f32
+    if held is not None:    # what a row past the groups reads is not defined
+        out = jnp.where(held[order][:, None], out, 0)
     # un-sort: row j of the sorted order is assignment order[j]; its inverse
     # permutation brings every token's k rows back side by side
     back = jnp.argsort(order).reshape(n, k)

@@ -149,13 +149,49 @@ def _kv_pool_slot_write(attrs, shapes):
 @rule("_contrib_KVPoolAttention")
 @rule("KVPoolAttention")
 def _kv_pool_attention(attrs, shapes):
-    # (R, H, dh), 2 x (H, S, dh), (R, S); a step's table and rows are bound
+    # (R, H, dh), 2 x (H, S, dh), (R, S); a step's table and rows are bound.
+    # A value pool may be narrower than the key's: one that is known stays
     query, pool_k, pool_v, mask = shapes[:4]
     pool = pool_k or pool_v
     if pool is not None:
-        shapes[1] = shapes[2] = pool
+        shapes[1], shapes[2] = pool_k or pool, pool_v or pool
         if mask is None and query is not None:
             shapes[3] = (query[0], pool[1])
+    return shapes
+
+
+@rule("_contrib_MultiHeadAttention")
+@rule("MultiHeadAttention")
+def _multi_head_attention(attrs, shapes):
+    # (B, H, T, d) x 3, then with sink=True one logit a query head
+    if len(shapes) > 3 and shapes[3] is None and shapes[0] is not None:
+        shapes[3] = (shapes[0][1],)
+    return shapes
+
+
+@rule("_contrib_KVRingWrite")
+@rule("KVRingWrite")
+def _kv_ring_write(attrs, shapes):
+    # (R, Hkv, W, d), (R, Hkv, d) a ring, then pos_idx and write_slot (R, 1)
+    rows = next((s for s in shapes[1:-2:2] if s is not None), None)
+    if rows is not None:
+        for i in (-2, -1):
+            if shapes[i] is None:
+                shapes[i] = (rows[0], 1)
+    return shapes
+
+
+@rule("_contrib_KVRingAttention")
+@rule("KVRingAttention")
+def _kv_ring_attention(attrs, shapes):
+    # (R, H, dk), (R, Hkv, W, dk), (R, Hkv, W, dv), 2 x (R, 1)[, (H,)]
+    query = shapes[0]
+    if query is not None:
+        for i in (3, 4):
+            if shapes[i] is None:
+                shapes[i] = (query[0], 1)
+        if len(shapes) > 5 and shapes[5] is None:
+            shapes[5] = (query[1],)
     return shapes
 
 
@@ -165,7 +201,8 @@ def _moe(attrs, shapes):
     data = shapes[0]
     if data is not None:
         e, f, d = attrs["num_experts"], attrs["num_hidden"], data[-1]
-        slots = ((e, d), (e, d, f), (e, d, f), (e, f, d), (e,))
+        held = attrs.get("num_local_experts", 0) or e   # the stacks' rows
+        slots = ((e, d), (held, d, f), (held, d, f), (held, f, d), (e,))
         for i, s in enumerate(slots[:len(shapes) - 1], 1):   # router_bias last
             if shapes[i] is None:
                 shapes[i] = s

@@ -11,10 +11,13 @@ One layout (``PagedKVDecoder``): the decode batch's rows are ``lanes``, one
 per sequence, and the lanes share ONE slot axis (``lanes * max_len`` slots
 per layer, KV ``(H, slots, dh)``) carved into refcounted page frames. What
 the decode graph keeps between steps is a list of named buffers the model
-gives (``models.transformer.decode_cache``), of two kinds: those pools,
-addressed by slot, and, where a layer keeps a recurrent state or its last
+gives (``models.transformer.decode_cache``), of three kinds: those pools,
+addressed by slot; where a layer keeps a recurrent state or its last
 convolution columns instead (``arch="granite_hybrid"``, ``"lfm2_moe"``),
-per-lane rows ``(lanes, ...)`` addressed by lane. A
+per-lane rows ``(lanes, ...)`` addressed by lane; and, where a layer attends
+a WINDOW (``arch="mimo_v2_flash"``), per-lane rings ``(lanes, heads, window,
+d)`` addressed by lane and position mod the window, which take no frame and
+no page-table entry whatever a lane's length. A
 token's write happens IN-GRAPH, into the page that holds each lane's
 ``write_slot`` (models/transformer.py ``get_decode_symbol``), as the program
 makes each lane's attention mask of its ``page_table``: a step hands the
@@ -589,11 +592,14 @@ class _AdmitScatter(_SealedProgram):
     """The cache update of a classic admission as ONE program: every cache
     buffer goes in DONATED and comes back updated in place — a pool with the
     prefill's K/V at positions ``0..length-1`` of the lane's page frames, a
-    per-lane buffer with the prefill's state in the lane's row.
+    per-lane buffer with the prefill's state in the lane's row, a window
+    layer's ring with the prompt's last ``window`` keys or values in the
+    lane's ring, each at its position mod the window.
 
     ``run(dec, new, frames, length, lane)``: ``new`` is the prefill
     executable's cache outputs, ``(1, H, prefill_len, dh)`` for a pool and
-    ``(1,) + row`` for a per-lane buffer (the sealed inputs), ``frames`` the
+    ``(1,) + row`` for a per-lane buffer, ``(1, H, prefill_len, d)`` again
+    for a ring (the sealed inputs), ``frames`` the
     lane's page-frame table, ``length`` the prompt length, ``lane`` the
     lane's index. Every shape is the decoder's, none the prompt's, so one
     compile serves every prompt length. The pool update walks
@@ -603,7 +609,11 @@ class _AdmitScatter(_SealedProgram):
     change. A scatter over the slot axis would say the same, but the TPU
     keeps the pool with slots minor-most and re-lays the WHOLE buffer out
     around a scatter, twice per buffer; the page walk leaves it in place.
-    A row is one ``dynamic_update_slice`` at the lane's index."""
+    A row is one ``dynamic_update_slice`` at the lane's index, and so is a
+    ring, after a gather of ``window`` positions of the prompt: slot j takes
+    the last position before ``length`` that is j mod the window (a slot
+    past a prompt shorter than the window takes anything: the read masks
+    it), so the ring is what the decode steps would have left."""
 
     def __init__(self, dec):
         import jax
@@ -616,6 +626,8 @@ class _AdmitScatter(_SealedProgram):
                               if kind == "pool"]
         self.rows = rows_at = [j for j in range(len(dec._cache))
                                if j not in pools]
+        rings = {j: shape[1] for j, (_, kind, shape) in enumerate(dec._cache)
+                 if kind == "ring"}
         ps = dec.page_size
         self.n_pages = -(-dec.prefill_len // ps)
         tail = self.n_pages * ps - dec.prefill_len
@@ -647,9 +659,15 @@ class _AdmitScatter(_SealedProgram):
                 out[j] = kv
             for j in rows_at:
                 at = (lane,) + (0,) * (bufs[j].ndim - 1)
+                value = new[j]
+                if j in rings:
+                    slot = jnp.arange(rings[j], dtype=jnp.int32)
+                    held = length - 1 - (length - 1 - slot) % rings[j]
+                    value = jnp.take(value, jnp.clip(
+                        held, 0, value.shape[2] - 1), axis=2)
                 # a zero-length prompt (the warm dispatch) changes nothing
-                row = jnp.where(length > 0, new[j], jax.lax.dynamic_slice(
-                    bufs[j], at, new[j].shape))
+                row = jnp.where(length > 0, value, jax.lax.dynamic_slice(
+                    bufs[j], at, value.shape))
                 out[j] = jax.lax.dynamic_update_slice(bufs[j], row, at)
             return tuple(out)
 
@@ -854,6 +872,24 @@ class PagedKVDecoder:
     columns for every conv layer, handed over and advanced as
     ``granite_hybrid``'s rows are, and the experts' load read from both
     graphs as for ``deepseek_v3``. It refuses what both of those refuse.
+
+    ``arch="mimo_v2_flash"`` serves the window / full attention block with
+    sparse experts (``hybrid_layer_pattern``, ``moe_layer_freq``,
+    ``num_kv_heads`` and ``swa_num_kv_heads``, ``head_dim`` above
+    ``v_head_dim``, ``sliding_window``, ``rotary_dim``, two rotary bases,
+    ``attention_value_scale``, ``num_local_experts`` of ``num_experts`` from
+    ``local_expert_offset`` on). A full layer keeps paged K and V pools, the
+    key's wider than the value's. A window layer keeps a lane's last
+    ``sliding_window`` keys and values in per-lane RINGS of the pools' type:
+    they take no frame of the page pool and no entry of the page table, so a
+    lane's window bytes do not depend on its length; an admission writes the
+    prompt's last positions there in the same donated program as the pages,
+    a step writes its token at ``pos mod window`` and reads the slots that
+    hold a position of THIS sequence (a re-admitted lane never sees its
+    predecessor's). The expert layers hold a SHARE of the experts they route
+    over; the load read from both graphs counts all of them. It refuses what
+    ``lfm2_moe`` refuses: a ring cannot be shared, and taking a token back
+    would need the one it overwrote.
     """
 
     def __init__(self, arg_params: Dict[str, object], vocab_size,
@@ -941,11 +977,20 @@ class PagedKVDecoder:
         self._pool_names = [name for name, kind, _ in self._cache
                             if kind == "pool"]
         self._has_rows = len(self._pool_names) < len(self._cache)
+        # window layers: (rings, slots a ring); the experts a layer holds of
+        # those it routes over (all of them unless the model names a share)
+        self._ring_names = [name for name, kind, _ in self._cache
+                            if kind == "ring"]
+        self._window = max((shape[1] for _, kind, shape in self._cache
+                            if kind == "ring"), default=0)
+        first = int(arch_sizes.get("local_expert_offset") or 0)
+        held = int(arch_sizes.get("num_local_experts") or 0)
+        self._held_experts = slice(first, first + held if held else None)
         if arch != "vaswani":
             # inputs are float32 whatever the weights are, a lane's recurrent
-            # state among them; only the pools take the weights' type
+            # state among them; only pools and rings take the weights' type
             binding.update(dtype="float32", input_dtypes={
-                n: dtype for n in self._pool_names})
+                n: dtype for n in self._pool_names + self._ring_names})
         prefill = _tf.get_prefill_symbol(prefill_len=self.prefill_len, **cfg)
         decode = _tf.get_decode_symbol(max_len=self.total_slots,
                                        page_size=self.page_size, **cfg)
@@ -982,14 +1027,15 @@ class PagedKVDecoder:
                              % (what, self.arch))
 
     def _refuse_rows(self, what):
-        """Sharing or dropping pages says nothing of a lane's recurrent
-        state: it is one row, overwritten at every token, and going back
-        needs a snapshot nobody keeps yet (ROADMAP R6)."""
+        """Sharing or dropping pages says nothing of what a lane keeps
+        beside them, a recurrent state or a window's ring: it is one row,
+        overwritten at every token, and going back needs a snapshot nobody
+        keeps yet (ROADMAP R6)."""
         if self._has_rows:
             raise MXNetError(
                 "paged_kv: %s is not built for arch %r yet: a recurrent "
-                "state cannot be shared or rolled back without a snapshot"
-                % (what, self.arch))
+                "state or a window's ring cannot be shared or rolled back "
+                "without a snapshot" % (what, self.arch))
 
     # ------------------------------------------------------------ lifecycle
     def _decode_shapes(self):
@@ -1054,11 +1100,16 @@ class PagedKVDecoder:
             _tm.gauge("serving.state_bytes").set(sum(
                 4 * self.lanes * int(np.prod(shape))
                 for _, kind, shape in self._cache if kind == "row"))
-            latent = [exe.arg_dict[name]._jax().nbytes
-                      for name in self._pool_names
-                      if name.startswith("kv_c_")]
+            held = lambda names: sum(exe.arg_dict[name]._jax().nbytes
+                                     for name in names)
+            latent = [n for n in self._pool_names if n.startswith("kv_c_")]
             if latent:  # one pool a layer: a token's latent, not its heads
-                _tm.gauge("serving.latent_pool_bytes").set(sum(latent))
+                _tm.gauge("serving.latent_pool_bytes").set(held(latent))
+            if self._ring_names:  # two kinds of attention cache, side by side
+                _tm.gauge("serving.full_pool_bytes").set(
+                    held(self._pool_names))
+                _tm.gauge("serving.window_ring_bytes").set(
+                    held(self._ring_names))
         if self._prefix is None:
             self._pf_cache.warmup([self._prefill_shapes()])
             self._admit_scatter = _AdmitScatter(self)
@@ -1386,13 +1437,14 @@ class PagedKVDecoder:
         """{name: array} of what the sequence's lane carries beside its
         pages: its row of every per-lane buffer of the cache (``names``: of
         those only), as the last ``admit`` or ``step`` left it. A recurrent
-        model's state; empty where the cache is pools only."""
+        model's state, a window layer's rings (heads, window, d), position p
+        at slot p mod the window; empty where the cache is pools only."""
         idx = self._seq_lane.get(seq_id)
         if idx is None:
             raise MXNetError("paged_kv: unknown seq_id %r" % (seq_id,))
         self.warmup()
         return {name: self._dec_exe.arg_dict[name]._jax()[idx]
-                for name, kind, _ in self._cache if kind == "row"
+                for name, kind, _ in self._cache if kind != "pool"
                 and (names is None or name in names)}
 
     # ----------------------------------------------------- fork / rollback
@@ -1567,6 +1619,11 @@ class PagedKVDecoder:
                     _tm.counter("serving.paged_steps").inc()
                     _tm.counter("serving.step_gathered_slots").inc(
                         self._step_gathered_slots)
+                    if self._window:
+                        # what a window layer's read finds live, a layer
+                        _tm.counter("serving.step_window_slots").inc(sum(
+                            min(lane.pos, self._window)
+                            for _, _, lane in stepped))
                     _tm.counter("serving.step_slot_writes").inc(
                         len(stepped) * len(self._pool_names))
                     _tm.counter("serving.step_input_bytes").inc(
@@ -1582,8 +1639,14 @@ class PagedKVDecoder:
                         load = exe.outputs[self._dec_moe_load].asnumpy()
                         _tm.counter("serving.moe.step_assignments").inc(
                             int(load.sum()))
+                        # of those, the ones that reached an expert held
+                        # here, and how many of the held received any
+                        local = load[:, self._held_experts]
+                        _tm.counter(
+                            "serving.moe.step_local_assignments").inc(
+                                int(local.sum()))
                         _tm.counter("serving.moe.step_experts_touched").inc(
-                            int(np.count_nonzero(load)))
+                            int(np.count_nonzero(local)))
                     _tm.gauge("decode.tokens_per_dispatch").set(len(stepped))
                     _tm.gauge("serving.paged_pages_in_use").set(
                         self.pool.in_use)

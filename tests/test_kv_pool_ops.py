@@ -28,7 +28,7 @@ from mxnet_tpu.base import MXNetError
 from mxnet_tpu.ops import attention
 from mxnet_tpu.ops.attention import (_kv_page_mask, _kv_pool_attention,
                                      _kv_pool_slot_write, _kv_pool_write,
-                                     pool_read_own_pages)
+                                     pool_read_bytes, pool_read_own_pages)
 
 H, S, DH = 4, 96, 16
 SCALE = 1.0 / np.sqrt(DH)
@@ -670,6 +670,36 @@ def test_the_read_takes_own_pages_for_the_latent_pool_alone(cell):
     pool_v = pool if pools == 2 else None
     assert pool_read_own_pages(query, pool, pool_v, table, 16) is own
     assert pool_read_own_pages(query, pool, pool_v, None, 0) is False
+
+
+def test_the_rule_counts_a_key_and_a_value_pool_each_at_its_own_width():
+    """``mimo-v2-flash.generate``'s full layers: 64 query heads over a key
+    pool (4, 262,144, 192) and a value pool (4, 262,144, 128), 32 lanes of
+    8,192 slots. Each pool's bytes and each copy's padding (192 to 256
+    lanes, 128 to 128) are its own: the whole-pool read is the pools' 0.67 GB
+    and 6.4 GB of scores, the lanes' own pages 2 x 0.67 + 4 x 0.81 GB of
+    copies and 0.2 GB of scores, so a lane's own pages it is. Counting the
+    value at the key's width would add 4 x 0.27 GB."""
+    spec = jax.ShapeDtypeStruct
+    lanes, slots = 32, 32 * 8192
+    query = spec((lanes, 64, 192), "bfloat16")
+    pool_k = spec((4, slots, 192), "bfloat16")
+    pool_v = spec((4, slots, 128), "bfloat16")
+    table = spec((lanes, 8192 // 16), "float32")
+    pools = 4 * slots * (192 + 128) * 2
+    copies = lanes * 4 * 8192 * (256 + 128) * 2
+    whole, own = pool_read_bytes(query, pool_k, pool_v, table, 16)
+    assert pools == 671_088_640 and copies == 805_306_368
+    assert whole == pools + 12 * lanes * 64 * slots == 7_113_539_584
+    assert own == 2 * pools + 4 * copies + 12 * lanes * 64 * 8192 \
+        == 4_764_729_344
+    assert pool_read_own_pages(query, pool_k, pool_v, table, 16) is True
+    alike = pool_read_bytes(query, pool_k, pool_k, table, 16)
+    assert alike[1] - own == 4 * lanes * 4 * 8192 * 128 * 2 \
+        + 2 * 4 * slots * 64 * 2
+    # one pool that is key and value both is counted once
+    assert pool_read_bytes(query, pool_k, None, table, 16)[0] \
+        == 4 * slots * 192 * 2 + 12 * lanes * 64 * slots
 
 
 # ------------------------------------------- the two forms through the decoder

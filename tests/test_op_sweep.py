@@ -658,6 +658,8 @@ EXEMPT = {
     "_contrib_GatedShortConvStep": "tests/test_lfm2_moe_block.py",
     "_contrib_KVPageMask": "tests/test_kv_pool_ops.py",
     "_contrib_KVPoolAttention": "tests/test_kv_pool_ops.py",
+    "_contrib_KVRingAttention": "tests/test_mimo_v2_flash_block.py",
+    "_contrib_KVRingWrite": "tests/test_mimo_v2_flash_block.py",
     "_contrib_KVPoolSlotWrite": "tests/test_kv_pool_ops.py",
     "_contrib_KVPoolWrite": "tests/test_kv_pool_ops.py",
     "_contrib_Mamba2Scan": "tests/test_granite_hybrid_block.py",

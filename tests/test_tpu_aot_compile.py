@@ -590,6 +590,115 @@ def test_lfm2_moe_serving_programs_compile_for_the_chip(v5e, program):
     assert mem.temp_size_in_bytes < 700 << 20
 
 
+_MIMO = dict(arch="mimo_v2_flash", vocab_size=19072, num_layers=7,
+             num_heads=64, num_kv_heads=4, swa_num_kv_heads=8, head_dim=192,
+             v_head_dim=128, model_dim=4096, ffn_dim=16384, moe_ffn_dim=2048,
+             num_experts=256, num_local_experts=16, local_expert_offset=0,
+             num_experts_per_tok=8, hybrid_layer_pattern=[0, 1, 1, 1, 1, 0, 1],
+             moe_layer_freq=[0, 1, 1, 1, 1, 1, 1], sliding_window=128,
+             rotary_dim=64, rope_theta=5e6, swa_rope_theta=1e4,
+             attention_value_scale=0.707, rms_eps=1e-5,
+             routed_scaling_factor=1.0, norm_topk_prob=True, dtype="bfloat16")
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode", "admit_scatter"])
+def test_mimo_v2_flash_serving_programs_compile_for_the_chip(v5e, program):
+    """The three programs ``PagedKVDecoder(arch="mimo_v2_flash")`` runs,
+    lowered for the v5e at MiMo-V2-Flash's published widths, the benchmark's
+    cut (layers 0-6, 16 of 256 experts, 19,072 rows of the vocabulary:
+    3,429,955,392 parameters in bfloat16) and its serving sizes (32 lanes x
+    8,192 slots, a 2,048 bucket). What has to hold on the chip: the cache is
+    a key pool (4, 262,144, 192) beside a value pool (4, 262,144, 128) for a
+    full layer and two rings (32, 8, 128, .) for a window layer, in layer
+    order, each back in the type it went in and updated in place; a window
+    layer's prefill scores a BAND (nothing of 2,048 x 2,048 a window head);
+    the step's two full layers read a lane's OWN PAGES, as
+    ``pool_read_own_pages`` says of a key and a value pool of different
+    width; both graphs keep the grouped matmul over the 16 held experts and
+    report the load of all 256 last; and everything fits beside 6.9 GB of
+    weights."""
+    from types import SimpleNamespace
+
+    from mxnet_tpu.models import transformer as tf
+    from mxnet_tpu.ops.attention import pool_read_own_pages
+    from mxnet_tpu.serving.kv_decode import _AdmitScatter
+
+    lanes, max_len, bucket, page = 32, 8192, 2048, 16
+    slots, cfg = lanes * max_len, _MIMO
+    shapes = tf.param_shapes(**cfg)
+    assert sum(math.prod(s) for s in shapes.values()) == 3_429_955_392
+    weights = {n: (s, "bfloat16") for n, s in shapes.items()}
+    cache = tf.decode_cache(**cfg)
+    assert [kind for _, kind, _ in cache] == \
+        ["pool"] * 2 + ["ring"] * 8 + ["pool"] * 2 + ["ring"] * 2
+    buffers = [((shape[0], slots, shape[1]) if kind == "pool"
+                else (lanes,) + shape, "bfloat16")
+               for _, kind, shape in cache]
+    cache_bytes = sum(2 * math.prod(shape) for shape, _ in buffers)
+    # the two full layers' pools 1.34 GB, the five window layers' rings 0.1
+    assert cache_bytes == 2 * slots * 4 * 320 * 2 + 5 * lanes * 128 * 8 * 640
+    exported = [((1, shape[0], bucket, shape[-1]), "bfloat16")
+                for _, _, shape in cache]
+    if program == "admit_scatter":
+        prog = _AdmitScatter(SimpleNamespace(
+            _cache=cache, page_size=page, prefill_len=bucket))
+        spec = lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, jnp.dtype(dtype), sharding=v5e)
+        mem = prog._fn.lower(
+            tuple(spec(*b) for b in buffers),
+            tuple(spec(*n) for n in exported),
+            spec((bucket // page,), "int32"), spec((2,), "int32"),
+        ).compile().memory_analysis()
+        # every buffer of the cache is updated in place, pools and rings
+        assert mem.alias_size_in_bytes == cache_bytes
+        assert mem.temp_size_in_bytes < 8 << 20
+        return
+    if program == "prefill":
+        sym = tf.get_prefill_symbol(prefill_len=bucket, **cfg)
+        inputs = {"data": ((1, bucket), "float32")}
+        want = [((bucket, 19072), "float32")] + exported \
+            + [((6, 256), "float32")]
+    else:
+        sym = tf.get_decode_symbol(max_len=slots, page_size=page, **cfg)
+        inputs = {"data": ((lanes, 1), "float32"),
+                  "pos_idx": ((lanes, 1), "float32"),
+                  "write_slot": ((lanes, 1), "float32"),
+                  "page_table": ((lanes, max_len // page), "float32")}
+        inputs.update({name: b for (name, _, _), b in zip(cache, buffers)})
+        want = [((lanes, 19072), "float32")] + buffers \
+            + [((lanes,), "float32"), ((6, 256), "float32")]
+    compiled = _compile_program(
+        v5e, sym, {**weights, **inputs},
+        donated=[name for name, _, _ in cache] if program == "decode" else ())
+    assert [(s.shape, str(s.dtype)) for s in compiled.out_info[0]] == want
+    hlo = compiled.as_text()
+    assert "ragged" in hlo.lower()
+    mem = compiled.memory_analysis()
+    found = [(math.prod(int(d) for d in dims.split(",") if d), op)
+             for _n, dims, op, _a in _INSTRUCTION.findall(hlo)
+             if op != "parameter"]
+    if program == "prefill":
+        # the full layers' float32 scores, 64 x 2,048 x 2,048, are the
+        # largest thing made; a window layer's are an eighth of that
+        assert max(n for n, _ in found) <= 64 * bucket * bucket
+        assert mem.temp_size_in_bytes < 3 << 30
+        return
+    # the rule, asked as the operator asks it: pools of different width
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+    assert pool_read_own_pages(
+        struct((lanes, 64, 192), "bfloat16"),
+        struct((4, slots, 192), "bfloat16"),
+        struct((4, slots, 128), "bfloat16"),
+        struct((lanes, max_len // page), "float32"), page)
+    # nothing made is as large as the lanes' scores over the pool (537 M
+    # elements), and no read's mask is built
+    assert not [n for n, _ in found if n >= lanes * 64 * slots]
+    assert "kv_mask" not in hlo and "slot_onehot" not in hlo
+    # the step updates every pool and ring in place
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < 3 << 30
+
+
 def _program_alone(hlo):
     """A compiled program's text without what names the source it was
     traced from: the module's name (the graph's last node, numbered as it
