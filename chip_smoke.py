@@ -11,11 +11,15 @@ a seed), and checks what comes out by the repo's own means:
              one-device mesh — the bench's path
   2 fit      the same network through mx.mod.Module(context=mx.tpu(0)).fit
              on a synthetic iterator, f32, batch 32 — the user's path
-  3 serve    the shared pool's write and read operators at the benchmark's
-             64 lanes x 65,536 slots (the written pool bit for bit); then
-             Transformer-base through serving.PagedKVDecoder (8 lanes x 1024
+  3 serve    Transformer-base through serving.PagedKVDecoder (8 lanes x 1024
              slots): greedy tokens against a full re-forward on the device;
-             then prefix cache + K-token megasteps
+             then prefix cache + K-token megasteps; a SECOND run of the phase
+             in the same call (another process, on the program store the
+             first left warm) loads its programs, imports nothing of Pallas
+             and steps the first's tokens; then the shared pool's write and
+             read operators at the benchmark's 64 lanes x 65,536 slots (the
+             written pool bit for bit, the kernel's read against the whole
+             pool's at the highest precision)
   4 kernels  every Pallas kernel a gate or pattern can reach, compiled by
              Mosaic, forward and backward, against its XLA reference; then a
              ResNet-50 step and a transformer step with the kernels forced
@@ -90,7 +94,7 @@ else:
         lanes=4, slots=64, page=8, prompts=(3, 9, 14), new_tokens=9,
         mega_k=4, shared_prefix=16,
         tf_train_batch=2, tf_train_seq=16,
-        pool=(4, 2, 64, 8),
+        pool=(4, 2, 64, 64),
         attn=(1, 2, 16, 8), mba=(16, 16, 128), ln=(16, 128),
         conv_batch=2,
         conv_sites=[((1, 1), (1, 1), 8, 16, 8, True),
@@ -518,14 +522,15 @@ def check_step_inputs(onehot, slots):
     against the mask of lanes at random positions in frames scattered over
     the pool; float32, exactly."""
     from mxnet_tpu.ops.attention import (_kv_page_mask, _kv_pool_slot_write,
-                                         _kv_pool_write)
+                                         _kv_pool_write, pool_shape)
 
     (R, S), page = onehot.shape, SZ["page"]
     H, D = SZ["pool"][1], SZ["pool"][3]
+    bound = pool_shape(H, D, S, page)   # page-major: a row of H x D is tiles
     write_slot = np.append(slots, -1).astype("float32")[:, None]
     for dt in ("float32", "bfloat16"):
         k1, k2 = jax.random.split(jax.random.PRNGKey(7 + len(dt)))
-        pool = jax.random.normal(k1, (H, S, D), jnp.float32).astype(dt)
+        pool = jax.random.normal(k1, bound, jnp.float32).astype(dt)
         rows = jax.random.normal(k2, (R, H, D), jnp.float32).astype(dt)
         want = jax.jit(lambda *a: _kv_pool_write({}, *a))(pool, rows, onehot)
         held = pool.unsafe_buffer_pointer()
@@ -539,7 +544,7 @@ def check_step_inputs(onehot, slots):
               and got.unsafe_buffer_pointer() == held,
               "KVPoolSlotWrite %s %s: the host's one-hot blend, bit for "
               "bit, written into the donated pool's own buffer"
-              % (dt, (H, S, D)))
+              % (dt, bound))
     rs = np.random.RandomState(13)
     max_pages = S // R // page
     table = rs.permutation(S // page)[:R * max_pages].reshape(R, max_pages)
@@ -568,9 +573,16 @@ def check_pool_operators():
     ``Precision.HIGHEST`` keeps a row's 24 bits through the one-hot matmul)
     and bfloat16. The read, two default-precision contractions, against the
     same sums at the highest precision."""
-    from mxnet_tpu.ops.attention import _kv_pool_attention, _kv_pool_write
+    from mxnet_tpu.ops.attention import (_kv_pool_attention, _kv_pool_write,
+                                         pool_shape)
 
     R, H, S, D = SZ["pool"]
+    bound = pool_shape(H, D, S, SZ["page"])
+    assert bound == (S // SZ["page"], SZ["page"], H * D)
+
+    def as_bound(pool):     # (H, S, D) by slot -> the layout a decoder binds
+        return pool.transpose(1, 0, 2).reshape(bound)
+
     rs = np.random.RandomState(11)
     slots = rs.choice(S, R - 1, replace=False)   # the last lane is idle
     onehot = np.zeros((R, S), "float32")
@@ -592,32 +604,79 @@ def check_pool_operators():
         k1, k2, k3 = jax.random.split(jax.random.PRNGKey(len(dt)), 3)
         pool = jax.random.normal(k1, (H, S, D), jnp.float32).astype(dt)
         rows = jax.random.normal(k2, (R, H, D), jnp.float32).astype(dt)
-        got = jax.jit(lambda *a: _kv_pool_write({}, *a))(pool, rows, onehot)
+        paged = jax.jit(as_bound)(pool)
+        got = jax.jit(lambda *a: _kv_pool_write({}, *a))(paged, rows, onehot)
         bits = jnp.uint16 if dt == "bfloat16" else jnp.uint32
         same = jax.jit(lambda a, b: jnp.all(
             jax.lax.bitcast_convert_type(a, bits)
             == jax.lax.bitcast_convert_type(b, bits)))
-        check(bool(same(got, jax.jit(blend)(pool, rows, onehot)))
-              and bool(same(got[:, slots], rows[:R - 1].transpose(1, 0, 2))),
+        written = got.reshape(S, H, D)[slots]
+        check(bool(same(got, jax.jit(lambda *a: as_bound(blend(*a)))(
+                  pool, rows, onehot)))
+              and bool(same(written, rows[:R - 1])),
               "KVPoolWrite %s %s: %d written slots hold their rows and the "
               "pool is the broadcast blend's, bit for bit"
-              % (dt, (H, S, D), R - 1))
+              % (dt, bound, R - 1))
         q = jax.random.normal(k3, (R, H, D), jnp.float32).astype(dt)
         ctx = jax.jit(lambda *a: _kv_pool_attention({"scale": -1.0}, *a))(
-            q, got, pool, mask)
+            q, got, paged, mask)
         with jax.default_matmul_precision("highest"):
             want = jax.jit(lambda *a: _kv_pool_attention(
                 {"scale": -1.0}, *(t.astype(jnp.float32) for t in a)))(
-                    q, got, pool, mask)
-        compare("KVPoolAttention %s (one bfloat16 pass)" % dt, [ctx], [want],
-                1e-2)
+                    q, got, paged, mask)
+        compare("KVPoolAttention %s, the whole pool (one bfloat16 pass)" % dt,
+                [ctx], [want], 1e-2)
+    check_paged_read()
+
+
+def check_paged_read():
+    """A decode step's read as the chip runs it: ``KVPoolAttention`` handed a
+    page table over page-major pools, which is the kernel that walks the
+    table (``ops/pallas_paged_read.py``; off the chip, XLA's gather), against
+    the whole-pool read under the mask made of the same table at the highest
+    precision. Lanes at random contexts, one that rides along, two that
+    share their first frame; both pool types."""
+    from mxnet_tpu.ops.attention import (_kv_page_mask, _kv_pool_attention,
+                                         pool_read_form, pool_shape)
+
+    R, H, S, D = SZ["pool"]
+    page = SZ["page"]
+    bound = pool_shape(H, D, S, page)
+    max_pages = S // R // page
+    rs = np.random.RandomState(17)
+    table = rs.permutation(S // page)[:R * max_pages].reshape(R, max_pages)
+    table[1, 0] = table[0, 0]
+    pos = rs.randint(0, max_pages * page, (R, 1))
+    pos[0], pos[1], pos[2] = 0, page - 1, max_pages * page - 1
+    write_slot = np.take_along_axis(table, pos // page, 1) * page + pos % page
+    write_slot[-1] = -1
+    step = [jnp.asarray(a, jnp.float32) for a in (table, pos, write_slot)]
+    mask = jax.jit(lambda *a: _kv_page_mask(
+        {"page_size": page, "num_slots": S}, *a))(*step)
+    for dt in ("float32", "bfloat16"):
+        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(3 + len(dt)), 3)
+        pool_k = jax.random.normal(k1, bound, jnp.float32).astype(dt)
+        pool_v = jax.random.normal(k2, bound, jnp.float32).astype(dt)
+        q = jax.random.normal(k3, (R, H, D), jnp.float32).astype(dt)
+        form = pool_read_form(q, pool_k, pool_v, step[0], page)
+        check(form == ("own_pages" if REHEARSE else "kernel"),
+              "the rule names the read of %s pools %s: %s" % (dt, bound, form))
+        ctx = jax.jit(lambda *a: _kv_pool_attention(
+            {"scale": -1.0, "page_size": page}, *a))(
+                q, pool_k, pool_v, mask, *step)
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(lambda *a: _kv_pool_attention(
+                {"scale": -1.0}, *(t.astype(jnp.float32) for t in a)))(
+                    q, pool_k, pool_v, mask)
+        check(bool(jnp.all(jnp.isfinite(ctx.astype(jnp.float32)))),
+              "the lane that rides along reads finite")
+        compare("KVPoolAttention %s, a lane's live pages (%s)" % (dt, form),
+                [ctx[:-1]], [want[:-1]], 1e-2)
 
 
 def phase_serve():
     from mxnet_tpu.serving import PagedKVDecoder
 
-    say("  -- the shared pool's write and read operators, %s" % (SZ["pool"],))
-    check_pool_operators()
     telemetry.set_mode("counters")
     ctx = mx.current_context()
     slots, lanes, page = SZ["slots"], SZ["lanes"], SZ["page"]
@@ -632,8 +691,11 @@ def phase_serve():
         return [np.concatenate([head, rs.randint(1, vocab, (n,))])
                 .astype(np.float32) for n in lengths]
 
+    tokens = {}   # label -> what each prompt generated
+
     def run(dec, prompts, label, k):
         toks = dec.greedy(prompts, n_new, k=k)
+        tokens[label] = [[int(t) for t in row] for row in toks]
         same = total = 0
         for i, (p, t) in enumerate(zip(prompts, toks)):
             s, n = ref.check(p.astype(np.int64), np.asarray(t, np.int64),
@@ -642,9 +704,19 @@ def phase_serve():
         return same, total
 
     say("  -- paged decode, one token per dispatch")
+    pallas = lambda: [m for m in sys.modules if m.startswith(
+        ("jax._src.pallas", "jax.experimental.pallas"))]
+    traced_before, c0 = pallas(), telemetry.counters()
     dec = PagedKVDecoder(params, max_len=slots, page_size=page, lanes=lanes,
                          ctx=ctx, **SZ["tf"])
     dec.warmup()
+    store = {what: counters_since(c0).get("serving.program_store." + what, 0)
+             for what in ("hit", "miss", "stale")}
+    say("    info: the program store: %s; Pallas %s"
+        % (store, "imported" if pallas() else "not imported"))
+    if store["hit"] == 2 and not traced_before:
+        check(not pallas(), "decode and prefill came out of the program "
+              "store: nothing was traced, and Pallas is not imported")
     same, total = run(dec, prompts_of(SZ["prompts"]), "k=1", 1)
     c0, n0 = telemetry.counters(), COMPILES.n
     s2, t2 = run(dec, prompts_of(SZ["prompts"][::-1]), "k=1 again", 1)
@@ -680,6 +752,19 @@ def phase_serve():
     say("    info: %d/%d tokens identical to the re-forward arg-max; peak "
         "device memory so far %.2f GiB"
         % (same + s2, total + t2, peak_gb()))
+    # a process before this one in the same call left what it generated: this
+    # one, on the store that one warmed, steps the same tokens
+    left = os.path.join("chiprun_out", "smoke_serve_tokens.json")
+    if os.path.exists(left):
+        with open(left) as f:
+            check(json.load(f) == tokens, "the tokens of the process before "
+                  "this one (%s), token for token" % left)
+    else:
+        os.makedirs("chiprun_out", exist_ok=True)
+        with open(left, "w") as f:
+            json.dump(tokens, f)
+    say("  -- the shared pool's write and read operators, %s" % (SZ["pool"],))
+    check_pool_operators()
 
 
 # ------------------------------------------------------------------ phase 4

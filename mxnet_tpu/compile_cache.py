@@ -12,10 +12,14 @@ on, and its location can be chosen from outside the program:
   in it is never found again by the next process.
 
 No other module sets ``jax_compilation_cache_dir``.
+
+The serving caches' exported programs (serving/cache.py: the program store)
+live in a directory of that one, ``program_store()``, so whoever moves, empties
+or turns off the compile cache does the same to them.
 """
 import os
 
-__all__ = ["DEFAULT_DIR", "configure", "directory"]
+__all__ = ["DEFAULT_DIR", "configure", "directory", "program_store"]
 
 DEFAULT_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
@@ -35,3 +39,15 @@ def directory():
     import jax
 
     return jax.config.jax_compilation_cache_dir
+
+
+def program_store():
+    """Where exported programs are kept, or None where nothing is: the store
+    is on exactly where the compile cache is (a process that holds itself out
+    of the one, as the tests and a rehearsal do, is out of the other)."""
+    import jax
+
+    root = directory()
+    if not root or not jax.config.jax_enable_compilation_cache:
+        return None
+    return os.path.join(root, "mx_programs")

@@ -65,9 +65,10 @@ class _DonatedForward:
     and lowered as the plain one is, ``(args, aux, rng)``. ``jax.jit``
     donates whole parameters, so the jitted function takes the donated
     arguments (``donated``: their indices among ``n_args``) as one tuple and
-    the rest as another, and puts them back in order before ``run``."""
+    the rest as another, and puts them back in order before ``run``. With a
+    ``store`` the jitted function is the store's (``_GraphProgram.store``)."""
 
-    def __init__(self, run, name, donated, n_args):
+    def __init__(self, run, name, donated, n_args, store=None):
         import jax
 
         given = set(donated)
@@ -83,6 +84,11 @@ class _DonatedForward:
 
         self._fn = jax.jit(_named(merged, name), donate_argnums=(0,))
         self._donated, self._kept = tuple(donated), tuple(kept)
+        if store is not None:
+            args, aux, rng = store.specs
+            self._fn = store.program(
+                self._fn, name, self._split(args) + (aux, rng),
+                donate_argnums=(0,))
 
     def _split(self, args):
         return (tuple(args[i] for i in self._donated),
@@ -109,6 +115,12 @@ class _GraphProgram:
         # in are dead; whoever owns the executor hands the outputs that took
         # their place back (``Executor.rebind``). None donated: a plain jit
         self.donated = ()
+        # where the inference program is kept EXPORTED (likewise; serving/
+        # cache.py ``_StoredProgram``): ``store.program(jitted, name)`` gives
+        # what is run in place of the traced function, called, lowered and
+        # donated alike, which a process that finds it in the store neither
+        # traces nor lowers. None: traced, as ever
+        self.store = None
         self.topo = symbol._topo()
         self.group2ctx = dict(group2ctx or {})
         # fusion plan (fusion.py): structural rewrite map covering the
@@ -316,11 +328,17 @@ class _GraphProgram:
             return self.interpret(args, aux, is_train, rng)
 
         name = self.program_name("fwd")
-        self._jit_cache[key] = _DonatedForward(
-            run, name, [self._arg_index[n] for n in self.donated],
-            len(self.arg_names)) if self.donated \
-            else jax.jit(_named(run, name))
-        return self._jit_cache[key]
+        store = None if is_train else self.store
+        if self.donated:
+            fn = _DonatedForward(
+                run, name, [self._arg_index[n] for n in self.donated],
+                len(self.arg_names), store)
+        else:
+            fn = jax.jit(_named(run, name))
+            if store is not None:
+                fn = store.program(fn, name)
+        self._jit_cache[key] = fn
+        return fn
 
     def _fwd_bwd_cached(self, with_head_grads):
         key = ("fwd_bwd", with_head_grads)
@@ -482,7 +500,9 @@ class Executor:
 
     def compiled(self, is_train=False):
         """The bound forward program as it is dispatched (donated arguments
-        donated), compiled ahead of time at the bound shapes: what
+        donated; the stored program where one is run, so a process that
+        loaded it traces nothing here either), compiled ahead of time at the
+        bound shapes: what
         ``cost_analysis()`` and ``memory_analysis()`` are asked of. The AOT
         compile does not share jit's executable cache, so this costs one
         compile (or one load from the persistent compile cache). Executes

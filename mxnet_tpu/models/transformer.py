@@ -446,12 +446,18 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
         live in the embeddings), so a lane's tokens may occupy ANY frames —
         what the allocator's non-contiguous placement relies on. The same
         three inputs reach every layer's ``KVPoolAttention`` beside the
-        mask, which may then gather a lane's own frames and never read the
-        mask: the operator's choice, from its operands' shapes
-        (``ops.attention.pool_read_own_pages``).
-      - ``kv_k_i`` / ``kv_v_i`` (H, max_len, dh) per layer: the pool. The
-        updated buffers are program OUTPUTS; the caller swaps them back in
-        as the next step's inputs (``PagedKVDecoder`` does).
+        mask, which may then read a lane's own frames (on the chip, over
+        page-major pools, with the kernel that walks the table and stops at
+        the lane's context) and never read the mask: the operator's choice,
+        from its operands' shapes and the backend
+        (``ops.attention.pool_read_form``).
+      - ``kv_k_i`` / ``kv_v_i`` per layer: the pool, H heads of dh over
+        ``max_len`` slots, bound in the layout its row gives it
+        (``ops.attention.pool_shape``: page-major (max_len / page_size,
+        page_size, H * dh) where H * dh is a multiple of 128, head-major
+        (H, max_len, dh) elsewhere; the operators read the layout off the
+        buffer). The updated buffers are program OUTPUTS; the caller swaps
+        them back in as the next step's inputs (``PagedKVDecoder`` does).
 
     One token a row collapses attention to a masked weighted sum, spelled,
     with the write before it, as two registry operators of
@@ -565,8 +571,8 @@ def get_chunk_symbol(vocab_size=32000, num_layers=6, num_heads=8,
         ones). A fully-masked pad row softmaxes uniformly over garbage
         and is discarded — finite, never NaN (max-subtraction zeroes the
         row first).
-      - ``kv_k_i`` / ``kv_v_i`` (H, total_slots, dh): the pool buffers,
-        as in ``get_decode_symbol``.
+      - ``kv_k_i`` / ``kv_v_i``: the pool buffers, in either layout, as in
+        ``get_decode_symbol``.
 
     Outputs: ``[logits (T, vocab), k'_0, v'_0, ...]`` plus — with
     ``token_out=True`` — a trailing on-device ``chunk_token (T,)`` argmax
@@ -1472,7 +1478,11 @@ def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
     """What a decode graph of ``arch`` keeps between steps, in the order its
     cache inputs' updates follow the logits (and the prefill's values do):
     ``[(name, kind, shape)]``. A ``"pool"`` is addressed by slot, ``shape``
-    is (heads, dh) and the buffer (heads, slots, dh); a ``"row"`` is
+    is (heads, dh) and the buffer what ``ops.attention.pool_shape`` makes of
+    it: (slots / page, page, heads * dh), a page one contiguous piece, where
+    a token's row of all its heads is whole tiles of the chip's 128 lanes,
+    (heads, slots, dh) where it is not (a latent row of 576; a toy model);
+    a ``"row"`` is
     addressed by lane, ``shape`` is one lane's and the buffer (lanes,) +
     shape, float32. Latent attention keeps ONE pool a layer, of one head:
     the old kind, no new one. Where ``layer_types`` chooses the mixer

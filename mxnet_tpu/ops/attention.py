@@ -239,6 +239,55 @@ def _rotary_embedding(attrs, data, positions):
     return y.astype(data.dtype)
 
 
+# the chip's lanes: a pool whose row is whole tiles of them is kept a page at
+# a time (``pool_shape``)
+_LANES = 128
+
+
+def pool_shape(heads, width, slots, page_size):
+    """The shape a cache entry of kind ``"pool"`` is bound in, ``heads``
+    key/value heads of ``width`` numbers over ``slots`` slots in pages of
+    ``page_size``; THE one rule of the pools' layout, and what every operator
+    here reads a pool's layout back from (``_paged``: its last dimension).
+
+    A token's row of all its heads side by side, ``heads * width``, that is a
+    multiple of the chip's 128 lanes: ``(frames, page_size, heads * width)``,
+    PAGE-MAJOR. A page is then one contiguous piece that carries every head,
+    the chip keeps the buffer row-major, and a kernel copies a lane's live
+    pages and nothing else (``ops/pallas_paged_read.py``). Any other row (a
+    latent cache's one head of 576; a toy model's heads of 8):
+    ``(heads, slots, width)``, HEAD-MAJOR, which the chip keeps slots-minor
+    where ``width`` is narrow. No row is padded to get there."""
+    if pool_paged(heads, width):
+        return (slots // page_size, page_size, heads * width)
+    return (heads, slots, width)
+
+
+def pool_paged(heads, width):
+    """Whether a pool of ``heads`` heads of ``width`` is kept page-major."""
+    return (heads * width) % _LANES == 0
+
+
+def _paged(pool):
+    """Whether ``pool`` (a shape, or anything with one) is bound page-major:
+    ``pool_shape``'s rule, read back from the last dimension (a head-major
+    pool's is never a multiple of 128: its row would be one)."""
+    return getattr(pool, "shape", pool)[-1] % _LANES == 0
+
+
+def pool_slots(shape):
+    """The slots of a pool of this shape, in either layout."""
+    return shape[0] * shape[1] if _paged(shape) else shape[1]
+
+
+def _slot_frame(slot, page):
+    """Slot -> (frame, offset in its page) of a page-major pool; a negative
+    slot (a row that writes nothing) lands on slot 0, which its caller then
+    writes back as it read it."""
+    slot = jnp.maximum(slot, 0)
+    return slot // page, slot % page
+
+
 @register(
     "_contrib_KVPoolWrite",
     input_names=("pool", "rows", "onehot"),
@@ -250,7 +299,9 @@ def _kv_pool_write(attrs, pool, rows, onehot):
     give ``pool * (1 - sum_r onehot) + einsum('rs,rhd->hsd', onehot, rows)``
     in the pool's dtype. Rows are the lanes of a decode step or the
     positions of a chunk; their slots are disjoint, so the matmul with the
-    one-hots IS the scatter, and an all-zero one-hot row writes nothing.
+    one-hots IS the scatter, and an all-zero one-hot row writes nothing. A
+    page-major pool (``pool_shape``: (frames, page, H * dh)) is the same
+    blend over its (S, H * dh) rows, element for element.
 
     A stored row is the row bit for bit. On the chip a float32 matmul at
     the default precision would round it to bfloat16, so the contraction
@@ -260,13 +311,21 @@ def _kv_pool_write(attrs, pool, rows, onehot):
     nowhere, whatever the accumulator."""
     dt = pool.dtype
     keep = (1.0 - jnp.sum(onehot, axis=0)).astype(dt)
+    if _paged(pool):
+        flat = pool.reshape(-1, pool.shape[2])
+        written = jnp.einsum(
+            "rs,rw->sw", onehot.astype(dt),
+            rows.astype(dt).reshape(rows.shape[0], pool.shape[2]),
+            precision=jax.lax.Precision.HIGHEST)
+        return (flat * keep[:, None] + written).reshape(pool.shape)
     written = jnp.einsum("rs,rhd->shd", onehot.astype(dt), rows.astype(dt),
                          precision=jax.lax.Precision.HIGHEST)
     return pool * keep[None, :, None] + written.transpose(1, 0, 2)
 
 
-# slots a slot-indexed write reads and writes back around a row's slot: the
-# chip's tile of a pool it keeps slots-minor, so a run is whole tiles
+# slots a slot-indexed write into a HEAD-MAJOR pool reads and writes back
+# around a row's slot: the chip's tile of a pool it keeps slots-minor, so a
+# run is whole tiles
 _WRITE_RUN = 128
 
 
@@ -284,59 +343,80 @@ def _slot_write_inputs(attrs):
 )
 def _kv_pool_slot_write(attrs, *inputs):
     """``KVPoolWrite`` for rows that each name their slot, into
-    ``num_pools`` pools at once (a layer's K and V): ``pool_i`` (H, S, dh)
-    and ``rows_i`` (R, H, dh), pair after pair, then ``write_slot`` (R, 1),
-    row r's slot as an index (the float32 the executor binds its inputs in,
+    ``num_pools`` pools at once (a layer's K and V): ``pool_i`` and
+    ``rows_i`` (R, H, dh), pair after pair, then ``write_slot`` (R, 1), row
+    r's slot as an index (the float32 the executor binds its inputs in,
     exact below 2^24 slots), negative for a row that writes nothing (a lane
-    that rides along). What comes back is every pool with
-    ``pool[:, slot_r, :] = rows[r]`` in the pool's dtype, row after row: a
-    stored row is the row bit for bit, and where two rows name one slot the
-    later one stays.
+    that rides along). What comes back is every pool with slot ``slot_r``
+    holding ``rows[r]`` in the pool's dtype, row after row: a stored row is
+    the row bit for bit, and where two rows name one slot the later one
+    stays.
 
     Nothing here is a pool's size. ONE loop over the rows updates every
-    pool: for a row, the aligned run of ``_WRITE_RUN`` slots that holds its
-    slot is read, the row put in by ``where`` and the run written back with
-    ``dynamic_update_slice``; a negative slot writes back what it read. So a
-    program that takes the pools DONATED updates them in place and moves a
-    run a row, not the pool. An update one slot wide, or a scatter over the
-    slot axis, says the same, but the chip keeps a dh = 64 pool slots-minor
-    and re-lays the WHOLE buffer out around either, twice a buffer
-    (``_AdmitScatter`` walks a prompt's pages the same way). The writes are
-    bound by their count, not their bytes, and the loop's shape was chosen
-    on the chip (``PERF.md`` §6, PR 37): a run of one tile beats a page of
-    16 slots, one loop a layer beats one a pool, and the index arithmetic
-    stays inside the loop."""
+    pool, each in its own layout (``pool_shape``), and a program that takes
+    the pools DONATED updates them in place.
+
+    A PAGE-MAJOR pool (frames, page, H * dh) takes a token's row as what it
+    is there, one contiguous ``(1, 1, H * dh)`` piece at ``(frame, offset,
+    0)``: the piece is read, the row put in its place (a negative slot puts
+    back what it read) and written with one ``dynamic_update_slice``, 1-2 KB
+    a row and pool.
+
+    A HEAD-MAJOR pool (H, S, dh): the aligned run of ``_WRITE_RUN`` slots
+    that holds the slot is read, the row put in by ``where`` and the run
+    written back. An update one slot wide, or a scatter over the slot axis,
+    says the same, but the chip keeps a narrow head-major pool slots-minor
+    and re-lays the WHOLE buffer out around either, twice a buffer. Those
+    writes are bound by their count, not their bytes, and the loop's shape
+    was chosen on the chip (``PERF.md`` §6, PR 37): a run of one tile beats a
+    page of 16 slots, one loop a layer beats one a pool, and the index
+    arithmetic stays inside the loop."""
     pools, rows = inputs[0:-1:2], inputs[1:-1:2]
-    n_rows, slots = rows[0].shape[0], pools[0].shape[1]
+    n_rows = rows[0].shape[0]
     if n_rows == 0:
         return tuple(pools)
-    run = min(_WRITE_RUN, slots)
     slot = inputs[-1].reshape(-1).astype(jnp.int32)
     rows = [r.astype(p.dtype) for r, p in zip(rows, pools)]
+
+    def write_page_major(pool, new, r):
+        _, page, width = pool.shape
+        at = _slot_frame(slot[r], page) + (0,)
+        old = jax.lax.dynamic_slice(pool, at, (1, 1, width))
+        row = jax.lax.dynamic_index_in_dim(new, r, 0, keepdims=False)
+        return jax.lax.dynamic_update_slice(
+            pool, jnp.where(slot[r] >= 0, row.reshape(1, 1, width), old), at)
+
+    # a head-major pool's run (the pools of a call have the same slots)
+    slots = next((p.shape[1] for p in pools if not _paged(p)), 0)
+    run = min(_WRITE_RUN, slots)
     in_run = jnp.arange(run, dtype=jnp.int32)[None, :, None]
 
-    def write(r, pools):
+    def write_head_major(pool, new, r):
         # the last run is cut short by the pool's end: start it earlier
         base = jnp.minimum(jnp.maximum(slot[r], 0) // run * run, slots - run)
         at_slot = in_run == slot[r] - base
-        out = []
-        for pool, new in zip(pools, rows):
-            heads, _, dh = pool.shape
-            old = jax.lax.dynamic_slice(pool, (0, base, 0), (heads, run, dh))
-            row = jax.lax.dynamic_index_in_dim(new, r, 0, keepdims=False)
-            out.append(jax.lax.dynamic_update_slice(
-                pool, jnp.where(at_slot, row[:, None, :], old), (0, base, 0)))
-        return tuple(out)
+        heads, _, dh = pool.shape
+        old = jax.lax.dynamic_slice(pool, (0, base, 0), (heads, run, dh))
+        row = jax.lax.dynamic_index_in_dim(new, r, 0, keepdims=False)
+        return jax.lax.dynamic_update_slice(
+            pool, jnp.where(at_slot, row[:, None, :], old), (0, base, 0))
+
+    def write(r, pools):
+        return tuple(
+            (write_page_major if _paged(pool) else write_head_major)(
+                pool, new, r) for pool, new in zip(pools, rows))
 
     return jax.lax.fori_loop(0, n_rows, write, tuple(pools))
 
 
 def pool_read_bytes(query, pool_k, pool_v, page_table, page_size):
-    """(whole, own): the bytes either form of ``KVPoolAttention``'s read
-    moves through the chip's memory, from the operands' shapes and types
-    alone (``pool_read_own_pages`` says what each operand is). Every pool is
-    counted ON ITS OWN: a key pool and a value pool may differ in width (192
-    beside 128), and so do their bytes and the padding of their copies.
+    """(whole, own): the bytes either XLA form of ``KVPoolAttention``'s read
+    moves through the chip's memory over HEAD-MAJOR pools (Hkv, S, d), from
+    the operands' shapes and types alone (``pool_read_form`` says what each
+    operand is, and asks this of head-major pools only: a page-major pool's
+    read on the chip is the kernel's). Every pool is counted ON ITS OWN: a
+    key pool and a value pool may differ in width, and so do their bytes and
+    the padding of their copies.
 
     Whole: the pools once and the float32 scores, R x H x S, three times
     (written, read by the softmax, read by the context). Own pages: the
@@ -358,29 +438,56 @@ def pool_read_bytes(query, pool_k, pool_v, page_table, page_size):
     return whole, own
 
 
-def pool_read_own_pages(query, pool_k, pool_v, page_table, page_size):
-    """Whether ``KVPoolAttention`` gathers each row's own pages (True) or
-    scores the whole pool (False): the smaller count of the bytes either
-    form moves through the chip's memory (``pool_read_bytes``), from the
-    operands' shapes and types alone. Each operand carries ``.shape`` and
-    ``.dtype``: ``query`` (R, H, dh), the pools (Hkv, S, d), each of its own
-    width (``pool_v`` None where the value is read from the key's pool),
-    ``page_table`` (R, max_pages) or None for a read that was handed no
-    table, which scores the whole pool.
+def _backend():
+    """Where the program being traced will run: Mosaic runs on the chip
+    alone. (A test that compiles for the chip from the CPU holds this to
+    ``"tpu"``.)"""
+    return jax.default_backend()
 
-    The gather pays where the pool is small beside its scores (one wide
-    latent row read by every head; 64 query heads over 4 key/value heads at
-    8,192 slots a lane) and loses where a 64-wide row pads to twice its size
-    and R x max_pages x page_size is the whole pool again."""
+
+def pool_read_form(query, pool_k, pool_v, page_table, page_size):
+    """THE rule that names the form of ``KVPoolAttention``'s read, from the
+    operands' shapes and types and the backend; no caller, option or
+    environment variable does. Each operand carries ``.shape`` and
+    ``.dtype``: ``query`` (R, H, dk), the pools in either layout
+    (``pool_shape``), each of its own width (``pool_v`` None where the value
+    is read from the key's pool), ``page_table`` (R, max_pages) or None for a
+    read that was handed no table.
+
+    ``"whole_pool"``: every row scores every slot under its mask. A read
+    with no table (a chunk's rows), and a head-major pool whose own pages
+    would move more bytes than the pool does (``pool_read_bytes``: a toy
+    model's narrow heads).
+
+    ``"own_pages"``: XLA gathers the frames a row's table names, all
+    ``max_pages`` of them, and scores those. A head-major pool that is small
+    beside its scores (one wide latent row read by every head), and a
+    page-major pool wherever the kernel cannot run: on the CPU, or where
+    ``pallas_paged_read.supported`` refuses the operands (one pool that is
+    key and value both; a page that is no whole tile).
+
+    ``"kernel"``: ``pallas_paged_read.paged_read`` copies a row's live pages,
+    up to its own context, out of page-major pools: two pools, on the
+    chip."""
     if page_table is None or page_size < 1:
-        return False
+        return "whole_pool"
+    pools = (pool_k,) if pool_v is None else (pool_k, pool_v)
+    if all(_paged(pool) for pool in pools):
+        from . import pallas_paged_read as kernel
+
+        if pool_v is not None and _backend() == "tpu" \
+                and kernel.supported(query, pool_k, pool_v):
+            return "kernel"
+        return "own_pages"
+    if any(_paged(pool) for pool in pools):   # one of each: no byte count
+        return "own_pages"
     whole, own = pool_read_bytes(query, pool_k, pool_v, page_table, page_size)
-    return own < whole
+    return "own_pages" if own < whole else "whole_pool"
 
 
 def _pool_softmax_context(scores, scale, mask, values, contraction):
     """``softmax(scores * scale + mask)`` in float32, then the context's
-    contraction with a float32 accumulator: what both forms of the pool's
+    contraction with a float32 accumulator: what the XLA forms of the pool's
     read share."""
     p = jax.nn.softmax(scores * scale + mask, axis=-1)
     return jnp.einsum(contraction, p, values,
@@ -394,16 +501,49 @@ def _context_slots(pos_idx, write_slot):
                      pos_idx.reshape(-1).astype(jnp.int32) + 1, 0)
 
 
-def _own_pages(pool, table, page):
-    """``pool`` (Hkv, S, d) at the frames ``table`` (R, max_pages) names:
-    (Hkv, R, max_pages * page, d), a row's pages in order. The host's table
-    is in bounds (frames, zeros past them), so nothing is clipped or
-    filled: the default mode adds a ``select`` over the whole copy."""
-    hkv, slots, d = pool.shape
+def _whole_pool(pool, hkv):
+    """``pool`` with its heads an axis, and that operand's einsum subscript
+    over (heads k, slots s, width d): a page-major pool's rows split into
+    their heads, a view."""
+    if _paged(pool):
+        return pool.reshape(-1, hkv, pool.shape[2] // hkv), "skd"
+    return pool, "ksd"
+
+
+def _own_pages(pool, table, page, hkv):
+    """``pool`` at the frames ``table`` (R, max_pages) names, a row's pages
+    in order, and that operand's einsum subscript over (heads k, rows r,
+    slots u, width d): (Hkv, R, max_pages * page, d) of a head-major pool,
+    (R, max_pages * page, Hkv, d) of a page-major one, whose frames are its
+    first axis. The host's table is in bounds (frames, zeros past them), so
+    nothing is clipped or filled: the default mode adds a ``select`` over the
+    whole copy."""
     rows, max_pages = table.shape
+    if _paged(pool):
+        own = pool.at[table].get(mode="promise_in_bounds")
+        return own.reshape(rows, max_pages * page, hkv, -1), "rukd"
+    _, slots, d = pool.shape
     own = pool.reshape(hkv, slots // page, page, d).at[:, table].get(
         mode="promise_in_bounds")
-    return own.reshape(hkv, rows, max_pages * page, d)
+    return own.reshape(hkv, rows, max_pages * page, d), "krud"
+
+
+def _pool_heads(query, pool_k):
+    """The key/value heads of ``pool_k`` (either layout) under ``query``
+    (R, H, dk)."""
+    return pool_k.shape[2] // query.shape[2] if _paged(pool_k) \
+        else pool_k.shape[0]
+
+
+def _kv_pool_attention_out(attrs, inputs):
+    """``KVPoolAttention``'s output from its operands' shapes: (R, H, the
+    value's width) in the query's type. Shape inference asks this and traces
+    no read: the kernel's form would import Pallas to say the same."""
+    query, pool_k, pool_v = inputs[:3]
+    width = attrs.get("value_dim", 0) or (
+        pool_v.shape[2] // _pool_heads(query, pool_k) if _paged(pool_v)
+        else pool_v.shape[2])
+    return [(query.shape[:2] + (width,), query.dtype)]
 
 
 @register(
@@ -415,6 +555,7 @@ def _own_pages(pool, table, page):
         ("page_table", "pos_idx", "write_slot")
         if attrs.get("page_size", 0) > 0 else ()),
     aliases=("KVPoolAttention",),
+    infer=_kv_pool_attention_out,
 )
 def _kv_pool_attention(attrs, query, pool_k, pool_v, mask, page_table=None,
                        pos_idx=None, write_slot=None):
@@ -429,7 +570,9 @@ def _kv_pool_attention(attrs, query, pool_k, pool_v, mask, page_table=None,
     heads (Hkv, S, dh) than the query's serves them in groups, as
     ``MultiHeadAttention`` does: the group is an axis of the query that both
     contractions carry (size 1 where the counts are equal), so the pool is
-    read once and never repeated.
+    read once and never repeated. A pool bound page-major (``pool_shape``:
+    (frames, page, Hkv * dh), slot s at frame ``s // page``) is the same
+    pool: its rows are split into their heads, a view.
 
     ``value_dim`` > 0 takes the value from the first ``value_dim`` columns
     of ``pool_v``, which may then BE ``pool_k``: a latent cache keeps one
@@ -441,39 +584,55 @@ def _kv_pool_attention(attrs, query, pool_k, pool_v, mask, page_table=None,
     ``page_size`` > 0 hands the read what ``mask`` was made of (a decode
     step's ``KVPageMask``): ``page_table`` (R, max_pages), ``pos_idx`` and
     ``write_slot`` (R, 1). The read may then take only the frames a row's
-    table names: it gathers them into (Hkv, R, max_pages * page_size, dh),
-    scores and contracts row by row over those slots, the first ``pos + 1``
-    of them live and none where the write slot is negative, and never reads
-    ``mask`` (a program none of whose reads does builds none). The same
-    mathematics in the same types: a slot outside a row's context weighs
-    exactly 0 in both forms, so they differ by the order of a float32 sum.
-    ``pool_read_own_pages`` chooses, from the shapes and types of the
-    operands; no caller does."""
+    table names and never read ``mask`` (a program none of whose reads does
+    builds none): XLA gathers them into (R, max_pages * page_size) slots a
+    row and scores those, the first ``pos + 1`` of them live and none where
+    the write slot is negative; or, over page-major pools on the chip, the
+    kernel of ``ops/pallas_paged_read.py`` copies the live ones and stops
+    there. The same mathematics in the same types: a slot outside a row's
+    context weighs exactly 0 in every form, so they differ by the order of a
+    float32 sum (a row with NO context is finite in every form and nobody's
+    to read: the mean of whatever the slots hold, or zeros from the kernel).
+    ``pool_read_form`` chooses, from the shapes and types of the operands and
+    the backend; no caller does."""
     scale = attrs["scale"] if attrs["scale"] > 0 \
         else 1.0 / np.sqrt(query.shape[-1])
     r, h, dh = query.shape
-    hkv = pool_k.shape[0]
+    hkv = _pool_heads(query, pool_k)
     q = query.reshape(r, hkv, _kv_groups(h, hkv, "KVPoolAttention"), dh)
     page = attrs.get("page_size", 0)
     shared = pool_v is pool_k
-    if pool_read_own_pages(query, pool_k, None if shared else pool_v,
-                           page_table, page):
+    form = pool_read_form(query, pool_k, None if shared else pool_v,
+                          page_table, page)
+    if form == "kernel":
+        from .pallas_paged_read import paged_read
+
+        # off the chip (a test that holds the rule to the kernel) Pallas
+        # interprets it
+        out = paged_read(query, pool_k, pool_v, page_table.astype(jnp.int32),
+                         _context_slots(pos_idx, write_slot),
+                         scale=float(scale), interpret=_backend() != "tpu"
+                         ).reshape(r, hkv, h // hkv, -1)
+    elif form == "own_pages":
         table = page_table.astype(jnp.int32)
-        own_k = _own_pages(pool_k, table, page)
-        own_v = own_k if shared else _own_pages(pool_v, table, page)
-        live = jnp.arange(own_k.shape[2], dtype=jnp.int32) \
+        own_k, sub_k = _own_pages(pool_k, table, page, hkv)
+        own_v, sub_v = (own_k, sub_k) if shared \
+            else _own_pages(pool_v, table, page, hkv)
+        live = jnp.arange(table.shape[1] * page, dtype=jnp.int32) \
             < _context_slots(pos_idx, write_slot)[:, None]
-        s = jnp.einsum("rkgd,krud->rkgu", q, own_k,
+        s = jnp.einsum("rkgd,%s->rkgu" % sub_k, q, own_k,
                        preferred_element_type=jnp.float32)
         out = _pool_softmax_context(
             s, scale, jnp.where(live, jnp.float32(0), _NEG)[:, None, None, :],
-            own_v, "rkgu,krud->rkgd")
+            own_v, "rkgu,%s->rkgd" % sub_v)
     else:
-        s = jnp.einsum("rkgd,ksd->rkgs", q, pool_k,
+        all_k, sub_k = _whole_pool(pool_k, hkv)
+        all_v, sub_v = (all_k, sub_k) if shared else _whole_pool(pool_v, hkv)
+        s = jnp.einsum("rkgd,%s->rkgs" % sub_k, q, all_k,
                        preferred_element_type=jnp.float32)
         out = _pool_softmax_context(
-            s, scale, mask.astype(jnp.float32)[:, None, None, :], pool_v,
-            "rkgs,ksd->rkgd")
+            s, scale, mask.astype(jnp.float32)[:, None, None, :], all_v,
+            "rkgs,%s->rkgd" % sub_v)
     if attrs.get("value_dim", 0) > 0:
         out = out[..., :attrs["value_dim"]]
     return out.reshape(r, h, out.shape[-1]).astype(query.dtype)

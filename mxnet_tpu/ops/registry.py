@@ -103,9 +103,15 @@ class OpDef:
         needs_train_flag: bool = False,
         aliases: Sequence[str] = (),
         doc: str = "",
+        infer: Optional[Callable] = None,
     ):
         self.name = name
         self.fn = fn
+        # ``infer(attrs, inputs) -> [(shape, dtype)]`` of the outputs, from
+        # inputs that carry ``.shape`` and ``.dtype``: shape inference asks it
+        # instead of abstractly evaluating ``fn`` (the default, which needs
+        # no rule), for an operator whose body is expensive to trace
+        self.infer = infer
         self.attr_specs = attrs or {}
         # input_names/aux_names/num_outputs may be callables of parsed attrs
         self._input_names = input_names
@@ -174,6 +180,7 @@ def register(
     needs_rng=False,
     needs_train_flag=False,
     aliases=(),
+    infer=None,
 ):
     """Decorator registering a JAX function as a framework operator."""
 
@@ -189,6 +196,7 @@ def register(
             needs_rng=needs_rng,
             needs_train_flag=needs_train_flag,
             aliases=aliases,
+            infer=infer,
         )
         if name in _REGISTRY:
             raise MXNetError(

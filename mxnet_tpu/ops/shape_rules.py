@@ -128,11 +128,14 @@ def _rotary(attrs, shapes):
 @rule("_contrib_KVPoolWrite")
 @rule("KVPoolWrite")
 def _kv_pool_write(attrs, shapes):
-    pool, rows, onehot = shapes     # (H, S, dh), (R, H, dh), (R, S)
+    # (H, S, dh) or page-major (frames, page, H * dh); (R, H, dh), (R, S)
+    from .attention import pool_slots
+
+    pool, rows, onehot = shapes
     if pool is None and rows is not None and onehot is not None:
-        shapes[0] = (rows[1], onehot[1], rows[2])
+        shapes[0] = (rows[1], onehot[1], rows[2])   # head-major: no page here
     elif onehot is None and pool is not None and rows is not None:
-        shapes[2] = (rows[0], pool[1])
+        shapes[2] = (rows[0], pool_slots(pool))
     return shapes
 
 
@@ -149,14 +152,17 @@ def _kv_pool_slot_write(attrs, shapes):
 @rule("_contrib_KVPoolAttention")
 @rule("KVPoolAttention")
 def _kv_pool_attention(attrs, shapes):
-    # (R, H, dh), 2 x (H, S, dh), (R, S); a step's table and rows are bound.
-    # A value pool may be narrower than the key's: one that is known stays
+    # (R, H, dh), 2 x (H, S, dh) or page-major (frames, page, H * dh),
+    # (R, S); a step's table and rows are bound. A value pool may be narrower
+    # than the key's: one that is known stays
+    from .attention import pool_slots
+
     query, pool_k, pool_v, mask = shapes[:4]
     pool = pool_k or pool_v
     if pool is not None:
         shapes[1], shapes[2] = pool_k or pool, pool_v or pool
         if mask is None and query is not None:
-            shapes[3] = (query[0], pool[1])
+            shapes[3] = (query[0], pool_slots(pool))
     return shapes
 
 

@@ -16,6 +16,18 @@ the same buckets without being told. The XLA *artifacts* themselves survive
 restarts through JAX's persistent compilation cache, whose location is
 decided in ``mxnet_tpu/compile_cache.py`` (``JAX_COMPILATION_CACHE_DIR``, else
 a fixed directory in the checkout) — never here.
+
+The program store. JAX keys that cache on the LOWERED module, so a process
+that starts on a warm cache still binds, traces and lowers every program
+before it can ask for it (and imports what tracing needs: Pallas, 1.5 to 2 s).
+So each bucket's program is also kept EXPORTED (``jax.export``), in a
+directory of the compile cache's (``compile_cache.program_store()``: on where
+that cache is on, moved and emptied with it), under a key of everything its
+lowering could depend on (``_StoredProgram``). A process that finds it runs
+``jax.jit(exported.call)`` under the same name with the same donation and
+traces nothing; one that does not traces, exports, writes the blob and runs
+that same ``jax.jit(exported.call)``: one module text whoever made it, so the
+compile cache holds ONE artifact a program and every later process hits it.
 """
 from __future__ import annotations
 
@@ -30,6 +42,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from ..base import MXNetError
+from .. import compile_cache as _compile_cache
 from .. import telemetry as _tm
 
 __all__ = ["PersistentExecutableCache", "serve_cache_dir"]
@@ -56,6 +69,178 @@ def _device_kind():
 def _shape_key(input_shapes):
     return tuple(sorted((str(n), tuple(int(d) for d in s))
                         for n, s in input_shapes.items()))
+
+
+_SOURCE_DIGEST = None
+
+
+def _source_digest():
+    """A digest of this package's own source files, read once a process: two
+    checkouts that differ in one byte of one file share no stored program
+    (the operators' lowering is code, and no version number follows it)."""
+    global _SOURCE_DIGEST
+    if _SOURCE_DIGEST is None:
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        digest = hashlib.sha256()
+        for folder, dirs, files in os.walk(root):
+            dirs.sort()
+            for name in sorted(f for f in files if f.endswith(".py")):
+                path = os.path.join(folder, name)
+                digest.update(os.path.relpath(path, root).encode() + b"\0")
+                with open(path, "rb") as f:
+                    digest.update(f.read() + b"\0")
+        _SOURCE_DIGEST = digest.hexdigest()
+    return _SOURCE_DIGEST
+
+
+def _program_key(exe, specs, label, donated, device):
+    """Everything the lowering of a bound executor's forward program could
+    depend on, as one text: the symbol, every argument's and aux state's
+    name, shape and type (and the key's), the donated names, the label, the
+    versions of jax and jaxlib, the platform and device kind, this package's
+    sources (``_source_digest``), every ``MXNET_*`` environment variable, the
+    jax settings a lowering reads, and the fusion tuner's verdicts where it
+    is on (they are measured, so no source names them)."""
+    import jax
+    import jaxlib
+
+    from .. import fusion_tune
+
+    named = lambda names, structs: [
+        (n, list(s.shape), str(s.dtype)) for n, s in zip(names, structs)]
+    return json.dumps({
+        "symbol": exe._symbol.tojson(),
+        "args": named(exe._prog.arg_names, specs[0]),
+        "aux": named(exe._prog.aux_names, specs[1]),
+        "rng": named(["rng"], specs[2:]),
+        "donated": list(donated), "label": label,
+        "jax": [jax.__version__, jaxlib.__version__],
+        "device": [device.platform, device.device_kind],
+        "source": _source_digest(),
+        "env": sorted((k, v) for k, v in os.environ.items()
+                      if k.startswith("MXNET_")),
+        "config": [str(getattr(jax.config, name)) for name in (
+            "jax_enable_x64", "jax_default_matmul_precision",
+            "jax_default_prng_impl", "jax_numpy_dtype_promotion")],
+        "fusion_tune": fusion_tune.entries_digest(
+            fusion_tune._entries(fusion_tune.device_kind()))
+        if fusion_tune.enabled() else None,
+    }, sort_keys=True)
+
+
+class _StoredProgram:
+    """One bucket's forward program in the program store (the module's
+    docstring): what ``executor._GraphProgram.store`` holds. ``specs``: what
+    the program is called with, ``(args, aux, rng)``; ``key``: a text that
+    names everything its lowering could depend on (``_program_key``). The
+    file is named by the key's digest and holds it again before the blob.
+
+    A file that is not there is a ``miss``; one that cannot be read, holds
+    another key or is refused by ``deserialize`` is ``stale`` (logged, then
+    treated as a miss and written over); neither is ever an error, and a
+    program that cannot be exported is run as traced. Counters
+    ``serving.program_store.hit`` / ``.miss`` / ``.stale``, one an
+    executable bound."""
+
+    _MAGIC = b"mxprog1\n"
+
+    def __init__(self, root, key, specs, platform):
+        self.specs = specs
+        self._key = hashlib.sha256(key.encode()).digest()
+        self._path = os.path.join(root, self._key.hex()[:40] + ".mxprog")
+        self._platform = platform
+
+    @classmethod
+    def of(cls, root, exe, label, donated):
+        """The place in the store under ``root`` of the forward program of
+        ``exe``, a bound executor whose program has this label and takes
+        these arguments donated."""
+        import jax
+
+        from .. import random as _random
+
+        spec = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+        arrays = lambda arrs: tuple(spec(a._jax()) for a in arrs)
+        specs = (arrays(exe.arg_arrays), arrays(exe.aux_arrays),
+                 spec(_random._constant_key()))
+        device = exe._ctx.jax_device
+        return cls(root, _program_key(exe, specs, label, donated, device),
+                   specs, device.platform)
+
+    def _count(self, what):
+        if _tm.enabled():
+            _tm.counter("serving.program_store." + what).inc()
+
+    def _stale(self, why):
+        log.warning("serving: stored program %s is stale (%s); tracing it "
+                    "again", self._path, why)
+        self._count("stale")
+
+    def _load(self):
+        """The program the store holds under this key, or None."""
+        from jax import export
+
+        try:
+            with open(self._path, "rb") as f:
+                blob = f.read()
+        except FileNotFoundError:
+            return self._count("miss")
+        except OSError as exc:
+            return self._stale(exc)
+        head = self._MAGIC + self._key
+        if not blob.startswith(head):
+            return self._stale("it holds another key")
+        try:
+            exported = export.deserialize(bytearray(blob[len(head):]))
+        except Exception as exc:  # a torn or foreign blob
+            return self._stale(exc)
+        self._count("hit")
+        return exported
+
+    def _export(self, traced, specs):
+        """``traced`` exported at ``specs`` and written to the store (whole or
+        not at all: ``tmp`` + ``os.replace``), or None where it cannot be
+        exported; a store that cannot be written is logged, no more."""
+        from jax import export
+
+        try:
+            exported = export.export(
+                traced, platforms=[self._platform])(*specs)
+            blob = exported.serialize()
+        except Exception as exc:
+            log.warning("serving: program %s cannot be exported (%s); it "
+                        "runs as traced", self._path, exc)
+            return None
+        tmp = "%s.%d.tmp" % (self._path, os.getpid())
+        try:
+            os.makedirs(os.path.dirname(self._path), exist_ok=True)
+            with open(tmp, "wb") as f:
+                f.write(self._MAGIC + self._key + bytes(blob))
+            os.replace(tmp, self._path)
+        except OSError as exc:
+            log.warning("serving: could not store program %s (%s)",
+                        self._path, exc)
+        return exported
+
+    def program(self, traced, name, specs=None, donate_argnums=()):
+        """What runs in place of the jitted ``traced``, which is called with
+        ``specs`` (``self.specs`` unless it takes them in another order): the
+        stored program (loaded, or exported from ``traced`` just now) under
+        ``jax.jit`` with the same name and donation."""
+        import jax
+
+        from ..executor import _named
+
+        exported = self._load()
+        if exported is None:
+            exported = self._export(traced, specs or self.specs)
+        if exported is None:
+            return traced
+
+        def call(*args):
+            return exported.call(*args)
+
+        return jax.jit(_named(call, name), donate_argnums=donate_argnums)
 
 
 def _plan_pattern_sites(exe):
@@ -216,6 +401,10 @@ class PersistentExecutableCache:
         # the program is jitted at its first forward: name it before that
         exe._prog.label = self._program_label
         exe._prog.donated = self._donated
+        root = _compile_cache.program_store()
+        if root is not None:
+            exe._prog.store = _StoredProgram.of(
+                root, exe, self._program_label, self._donated)
         return exe
 
     def _retrace_diagnosis(self):

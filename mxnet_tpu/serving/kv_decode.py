@@ -9,7 +9,12 @@ replaces costs O(T) work per token and a recompile per prompt length.
 
 One layout (``PagedKVDecoder``): the decode batch's rows are ``lanes``, one
 per sequence, and the lanes share ONE slot axis (``lanes * max_len`` slots
-per layer, KV ``(H, slots, dh)``) carved into refcounted page frames. What
+per layer) carved into refcounted page frames; a K or V pool of H heads of
+dh is bound ``(frames, page_size, H * dh)``, a page one contiguous piece,
+where that row is whole tiles of the chip's 128 lanes, and ``(H, slots,
+dh)`` where it is not (``ops.attention.pool_shape``: THE layout rule; the
+operators, ``_AdmitScatter`` and ``_cow_page`` follow it, and nothing else
+looks inside a pool). What
 the decode graph keeps between steps is a list of named buffers the model
 gives (``models.transformer.decode_cache``), of three kinds: those pools,
 addressed by slot; where a layer keeps a recurrent state or its last
@@ -55,7 +60,7 @@ import numpy as np
 from ..base import MXNetError
 from .. import telemetry as _tm
 from ..executor import _cost_of
-from ..ops.attention import _NEG
+from ..ops.attention import _NEG, pool_paged, pool_shape
 from .cache import PersistentExecutableCache
 
 __all__ = ["PagedKVDecoder", "PagedKVExhausted", "decode_megastep_k"]
@@ -220,15 +225,19 @@ def _output_at(symbol, name):
     return outs.index(name + "_output") if name + "_output" in outs else None
 
 
-def _pool_read_slots(cache, input_shapes):
+def _pool_reads(cache, input_shapes):
     """What one dispatch of a decode program reads of its pools, a layer:
-    ``[(own pages?, slots scored)]`` over the graph's ``KVPoolAttention``
-    nodes at these input shapes. The form is the operator's own rule
-    (``pool_read_own_pages``) asked of each node's operands as inferred,
-    which is what it is asked of when the program is traced."""
+    ``[(form, slots)]`` over the graph's ``KVPoolAttention`` nodes at these
+    input shapes. The form is the operator's own rule (``pool_read_form``)
+    asked of each node's operands as inferred, which is what it is asked of
+    when the program is traced. ``slots``: what an XLA form scores a
+    dispatch (the rows' tables whole, or the pool for every row); for the
+    kernel, whose fetch follows the rows' contexts, the slots of ONE block
+    (``serving.step_kernel_slots`` rounds each stepped lane's context up to
+    it)."""
     import jax
 
-    from ..ops.attention import pool_read_own_pages
+    from ..ops.attention import pool_read_form, pool_slots
     from ..symbol import Symbol
 
     reads = [n for n in cache._sym._topo()
@@ -243,11 +252,20 @@ def _pool_read_slots(cache, input_shapes):
         attrs = n.parsed_attrs()
         ops = dict(zip(n.opdef().input_names(attrs), structs))
         page, table = attrs.get("page_size", 0), ops.get("page_table")
-        own = pool_read_own_pages(
-            ops["query"], ops["pool_k"],
-            None if n.inputs[1] == n.inputs[2] else ops["pool_v"], table, page)
-        out.append((own, ops["query"].shape[0] * (
-            table.shape[1] * page if own else ops["pool_k"].shape[1])))
+        k, v = ops["pool_k"], ops["pool_v"]
+        form = pool_read_form(
+            ops["query"], k, None if n.inputs[1] == n.inputs[2] else v,
+            table, page)
+        rows = ops["query"].shape[0]
+        if form == "kernel":
+            from ..ops.pallas_paged_read import block_slots
+
+            slots = block_slots(k, v, table.shape[1])
+        elif form == "own_pages":
+            slots = rows * table.shape[1] * page
+        else:
+            slots = rows * pool_slots(k.shape)
+        out.append((form, slots))
     return out
 
 
@@ -604,11 +622,13 @@ class _AdmitScatter(_SealedProgram):
     lane's index. Every shape is the decoder's, none the prompt's, so one
     compile serves every prompt length. The pool update walks
     the prompt's pages with ``dynamic_update_slice`` — a page is a
-    contiguous slot run — and blends the last, partial page with what the
-    pool holds there, so exactly the slots of positions ``< length``
-    change. A scatter over the slot axis would say the same, but the TPU
-    keeps the pool with slots minor-most and re-lays the WHOLE buffer out
-    around a scatter, twice per buffer; the page walk leaves it in place.
+    contiguous slot run, and in a page-major pool
+    (``ops.attention.pool_shape``) ONE ``(page, H * d)`` piece at its frame —
+    and blends the last, partial page with what the pool holds there, so
+    exactly the slots of positions ``< length`` change. A scatter over the
+    slot axis would say the same, but the TPU keeps a narrow head-major pool
+    with slots minor-most and re-lays the WHOLE buffer out around a scatter,
+    twice per buffer; the page walk leaves either layout in place.
     A row is one ``dynamic_update_slice`` at the lane's index, and so is a
     ring, after a gather of ``window`` positions of the prompt: slot j takes
     the last position before ``length`` that is j mod the window (a slot
@@ -632,24 +652,38 @@ class _AdmitScatter(_SealedProgram):
         self.n_pages = -(-dec.prefill_len // ps)
         tail = self.n_pages * ps - dec.prefill_len
 
+        # a page-major pool (``pool_shape``) takes a page as one piece
+        paged = [pool_paged(*dec._cache[j][2]) for j in pools]
+
         def run(bufs, new, frames, at):
             length, lane = at[0], at[1]
-            rows = [new[j][0] for j in pools]
+            # (H, T, d) a pool; for a page-major one (T, H * d), a token's
+            # heads side by side as its pool keeps them
+            rows = [new[j][0].transpose(1, 0, 2).reshape(
+                        new[j].shape[2], -1) if pm else new[j][0]
+                    for j, pm in zip(pools, paged)]
             if tail:  # so a page-sized slice never runs off the end
-                rows = [jnp.pad(r, ((0, 0), (0, tail), (0, 0)))
-                        for r in rows]
+                rows = [jnp.pad(r, ((0, tail), (0, 0)) if pm
+                                else ((0, 0), (0, tail), (0, 0)))
+                        for r, pm in zip(rows, paged)]
             in_page = jnp.arange(ps, dtype=jnp.int32)[None, :, None]
 
             def page(j, kvs):
                 dst = frames[j] * ps
                 live = in_page < length - j * ps
                 out = []
-                for kv, r in zip(kvs, rows):
-                    page_shape = (kv.shape[0], ps, kv.shape[2])
-                    blk = jax.lax.dynamic_slice(r, (0, j * ps, 0), page_shape)
-                    old = jax.lax.dynamic_slice(kv, (0, dst, 0), page_shape)
+                for kv, r, pm in zip(kvs, rows, paged):
+                    if pm:      # (1, page, H * d) at frame frames[j]
+                        at = (frames[j], 0, 0)
+                        blk = jax.lax.dynamic_slice(
+                            r, (j * ps, 0), (ps, r.shape[1]))[None]
+                    else:       # (H, page, d) at slot frames[j] * page
+                        at = (0, dst, 0)
+                        blk = jax.lax.dynamic_slice(
+                            r, (0, j * ps, 0), (kv.shape[0], ps, kv.shape[2]))
+                    old = jax.lax.dynamic_slice(kv, at, blk.shape)
                     out.append(jax.lax.dynamic_update_slice(
-                        kv, jnp.where(live, blk, old), (0, dst, 0)))
+                        kv, jnp.where(live, blk, old), at))
                 return tuple(out)
 
             out = list(bufs)
@@ -970,8 +1004,8 @@ class PagedKVDecoder:
             if model_key is None:  # one architecture never answers for another
                 key += "-" + arch
         # what the decode graph keeps between steps, in program order:
-        # (name, "pool" | "row", shape) — pools (heads, slots, dh) addressed
-        # by slot, per-lane rows (lanes,) + shape addressed by lane
+        # (name, "pool" | "row", shape) — pools of (heads, dh) addressed by
+        # slot, per-lane rows (lanes,) + shape addressed by lane
         self._cache = _tf.decode_cache(**dict(cfg, arch=arch))
         self._cache_names = [name for name, _, _ in self._cache]
         self._pool_names = [name for name, kind, _ in self._cache
@@ -1007,6 +1041,7 @@ class PagedKVDecoder:
         self._dec_exe = None
         self._decode_xla_bytes = None  # read at warmup when telemetry is on
         self._step_gathered_slots = 0  # likewise: slots a dispatch scores
+        self._kernel_block = 0         # and the slots of the kernel's block
         self._lanes: Dict[int, _Lane] = {}   # lane index -> _Lane
         self._seq_lane: Dict[int, int] = {}  # seq_id -> lane index
         self._next_seq = 0
@@ -1043,8 +1078,10 @@ class PagedKVDecoder:
         shapes = {"data": (B, 1), "pos_idx": (B, 1), "write_slot": (B, 1),
                   "page_table": (B, self.pool.frames_per_lane)}
         for name, kind, shape in self._cache:
-            shapes[name] = (shape[0], S, shape[1]) if kind == "pool" \
-                else (B,) + tuple(shape)
+            # a pool in the layout its row's width gives it: page-major
+            # (frames, page, heads * d) or head-major (heads, slots, d)
+            shapes[name] = pool_shape(*shape, S, self.page_size) \
+                if kind == "pool" else (B,) + tuple(shape)
         return shapes
 
     def _prefill_shapes(self):
@@ -1089,14 +1126,16 @@ class PagedKVDecoder:
             _tm.gauge("serving.cache_bytes").set(sum(
                 exe.arg_dict[name]._jax().nbytes
                 for name in self._cache_names))
-            reads = _pool_read_slots(self._dec_cache, self._decode_shapes())
-            _tm.gauge("serving.pool_read.own_pages_layers").set(
-                sum(own for own, _ in reads))
-            _tm.gauge("serving.pool_read.whole_pool_layers").set(
-                sum(not own for own, _ in reads))
-            # a layer's: the mean over the program's reads, which are alike
-            self._step_gathered_slots = \
-                sum(slots for _, slots in reads) // max(len(reads), 1)
+            reads = _pool_reads(self._dec_cache, self._decode_shapes())
+            by_form = lambda form: [n for f, n in reads if f == form]
+            for form in ("kernel", "own_pages", "whole_pool"):
+                _tm.gauge("serving.pool_read.%s_layers" % form).set(
+                    len(by_form(form)))
+            # a layer's: the mean over the program's reads of a kind, which
+            # are alike. What XLA scores a dispatch; the kernel's block
+            scored = by_form("own_pages") + by_form("whole_pool")
+            self._step_gathered_slots = sum(scored) // max(len(scored), 1)
+            self._kernel_block = max(by_form("kernel"), default=0)
             _tm.gauge("serving.state_bytes").set(sum(
                 4 * self.lanes * int(np.prod(shape))
                 for _, kind, shape in self._cache if kind == "row"))
@@ -1160,9 +1199,13 @@ class PagedKVDecoder:
         dst = fresh * P + np.arange(P)
         exe = self._dec_exe
         # a buffer at a time: its old copy may die before the next is made
-        for tag in self._pool_names:
+        for tag, kind, shape in self._cache:
+            if kind != "pool":
+                continue
             buf = exe.arg_dict[tag]._jax()
-            exe.rebind([tag], [buf.at[:, dst, :].set(buf[:, src, :])])
+            exe.rebind([tag], [
+                buf.at[fresh].set(buf[frame]) if pool_paged(*shape)
+                else buf.at[:, dst, :].set(buf[:, src, :])])
         self.pool.release([frame])
         lane.frames[page] = fresh
         if _tm.enabled():
@@ -1619,6 +1662,12 @@ class PagedKVDecoder:
                     _tm.counter("serving.paged_steps").inc()
                     _tm.counter("serving.step_gathered_slots").inc(
                         self._step_gathered_slots)
+                    if self._kernel_block:
+                        # what one layer's kernel fetches: each stepped
+                        # lane's context, rounded up to a block
+                        _tm.counter("serving.step_kernel_slots").inc(sum(
+                            -(-lane.pos // self._kernel_block)
+                            * self._kernel_block for _, _, lane in stepped))
                     if self._window:
                         # what a window layer's read finds live, a layer
                         _tm.counter("serving.step_window_slots").inc(sum(

@@ -532,6 +532,9 @@ def _eval_node_shape(op_name, attrs_key, in_shapes, in_dtypes, n_aux):
     structs = [
         jax.ShapeDtypeStruct(tuple(s), np_dtype(d)) for s, d in zip(in_shapes, in_dtypes)
     ]
+    if opdef.infer is not None:
+        return tuple((tuple(shape), np.dtype(dtype).name)
+                     for shape, dtype in opdef.infer(attrs, structs))
     key = jax.random.PRNGKey(0) if opdef.needs_rng else None
 
     def run(*arrays):

@@ -30,6 +30,26 @@ def tm():
     telemetry.clear_events()
 
 
+@pytest.fixture(params=["head_major", "page_major"])
+def layout(request, monkeypatch):
+    """The KV pools' layout (``ops.attention.pool_shape``): 2 heads of 16
+    are a row of 32, kept head-major (heads, slots, 16); 2 heads of 64 a row
+    of 128, whole tiles of the chip's lanes, kept page-major (frames, page,
+    128). The model is otherwise the same."""
+    if request.param == "page_major":
+        monkeypatch.setitem(CFG, "model_dim", 128)
+    return request.param
+
+
+def _by_slot(pool):
+    """A pool buffer in either layout as (heads, slots, dh)."""
+    pool = np.asarray(pool)
+    if pool.shape[-1] % 128:
+        return pool
+    heads = CFG["num_heads"]
+    return pool.reshape(-1, heads, pool.shape[-1] // heads).transpose(1, 0, 2)
+
+
 def _trained_params(S, seed=0):
     """Random 'trained' weights harvested through the TRAINING symbol's
     bind shapes — the serving graphs must accept them by name."""
@@ -71,7 +91,7 @@ def _ref_greedy(exe, prompt, n_tokens, S, vocab):
     return out
 
 
-def test_greedy_decode_token_identical_32(tm):
+def test_greedy_decode_token_identical_32(tm, layout):
     """The PR acceptance bar: 32-token greedy decode through the KV-cache
     path produces token-identical output to full-sequence re-forward."""
     tm.set_mode("counters")
@@ -203,7 +223,7 @@ def test_page_pool_accounting_and_reuse():
         _PagePool(lanes=1, slots=10, page_size=4)
 
 
-def test_paged_multiplexed_token_identical():
+def test_paged_multiplexed_token_identical(layout):
     """The acceptance bar: >=2 concurrent sequences served from ONE
     decode batch, admitted at different times and advancing at different
     positions, produce token-identical output to sequential per-request
@@ -809,7 +829,7 @@ def _softmax(logits):
 
 
 @pytest.mark.parametrize("case", ["fork", "copy_on_write", "rollback"])
-def test_a_step_reads_a_cold_re_forward_after(tm, case):
+def test_a_step_reads_a_cold_re_forward_after(tm, case, layout):
     """The mask is made on the device from the lane's frame table, so what a
     lane sees after its table changed under it — a fork's shared frames, the
     private copy a write into one makes, the pages and the stale tail a
@@ -908,12 +928,13 @@ def _pool(dec):
 
 @pytest.mark.parametrize("case", ["L=1", "L=page-1", "L=page", "L=page+1",
                                   "L=prefill_len", "frames-not-contiguous"])
-def test_admit_pool_update_is_bitwise_the_op_by_op_scatter(case):
+def test_admit_pool_update_is_bitwise_the_op_by_op_scatter(case, layout):
     """After ``admit`` every pool buffer is what the parent's
     ``ring.at[:, phys, :].set(new[0, :, :L, :])`` gave: the slots of
     positions 0..L-1 hold the prefill's K/V, every other slot its old bits
-    (the rest of the last page included)."""
+    (the rest of the last page included); in either layout, read by slot."""
     dec = _paged_for_pool(lanes=3).warmup()
+    assert (_pool(dec)[0].shape[-1] == 128) == (layout == "page_major")
     page, P = dec.page_size, dec.prefill_len
     rs = np.random.RandomState(7)
     for name in dec._admit_scatter.kv_names:      # nothing to hide behind
@@ -927,7 +948,7 @@ def test_admit_pool_update_is_bitwise_the_op_by_op_scatter(case):
     else:
         L = {"L=1": 1, "L=page-1": page - 1, "L=page": page,
              "L=page+1": page + 1, "L=prefill_len": P}[case]
-    before = [np.asarray(a).copy() for a in _pool(dec)]
+    before = [_by_slot(a).copy() for a in _pool(dec)]
     sid, _ = dec.admit(rs.randint(1, CFG["vocab_size"], size=L)
                        .astype(np.float32))
     lane = dec._lanes[dec._seq_lane[sid]]
@@ -938,7 +959,7 @@ def test_admit_pool_update_is_bitwise_the_op_by_op_scatter(case):
     for old, got, new in zip(before, _pool(dec), pf.outputs[1:]):
         want = old.copy()
         want[:, phys, :] = np.asarray(new._jax())[0, :, :L, :]
-        got = np.asarray(got)
+        got = _by_slot(got)
         assert got.dtype == want.dtype and np.array_equal(got, want)
         rest = np.setdiff1d(np.arange(dec.total_slots), phys)
         assert np.array_equal(got[:, rest, :], old[:, rest, :])
@@ -1060,7 +1081,7 @@ def test_sealed_program_compiles_once_and_refuses_a_drifted_signature(
     assert all(a.is_deleted() for a in held) == donates
 
 
-def test_pool_readers_after_a_donated_admit_stay_token_identical():
+def test_pool_readers_after_a_donated_admit_stay_token_identical(layout):
     """``step``, ``fork``, ``rollback``, a megastep and a chunk dispatch
     after an admission that donated the pool (the decode executable's
     outputs name dead arrays by then) read the pool through ``arg_dict``
@@ -1165,7 +1186,7 @@ OWNED = ["steps", "lane_state", "fork_copy_on_write", "rollback",
 
 
 @pytest.mark.parametrize("case", OWNED)
-def test_a_donated_step_gives_what_fresh_outputs_give(case):
+def test_a_donated_step_gives_what_fresh_outputs_give(case, layout):
     """Every reader of the cache, after steps that took it donated, against
     the same decoder dispatching its decode graph un-donated (each output a
     fresh buffer, as before the step owned its cache): the same logits,

@@ -14,6 +14,12 @@ bit for bit; and the operator that makes a step's masks on the device
 
 And the read's second form, a row's own pages gathered by its table, against
 the whole-pool read under the mask made of the same table.
+
+Every operator in BOTH layouts of a pool (``pool_shape``): head-major
+(H, S, dh) where a token's row of all its heads is no whole tile of the
+chip's lanes, page-major (frames, page, H * dh) where it is. ``_by_slot``
+reads either as (H, S, dh), so a case says one thing of both. (The kernel that
+reads page-major pools on the chip: tests/test_paged_read_kernel.py.)
 """
 import functools
 
@@ -28,10 +34,16 @@ from mxnet_tpu.base import MXNetError
 from mxnet_tpu.ops import attention
 from mxnet_tpu.ops.attention import (_kv_page_mask, _kv_pool_attention,
                                      _kv_pool_slot_write, _kv_pool_write,
-                                     pool_read_bytes, pool_read_own_pages)
+                                     pool_read_bytes, pool_read_form,
+                                     pool_shape)
 
 H, S, DH = 4, 96, 16
 SCALE = 1.0 / np.sqrt(DH)
+PAGE = 8
+# a head's width -> the layout ``pool_shape`` gives H heads of it: 4 x 16 is
+# no whole tile of lanes, 4 x 32 is one
+LAYOUTS = pytest.mark.parametrize("dh", [DH, 32],
+                                  ids=["head_major", "page_major"])
 
 
 def _parent_write(pool, rows, onehot, chunk):
@@ -52,20 +64,22 @@ def _parent_write(pool, rows, onehot, chunk):
 def _parent_read(q, pool_k, pool_v, mask):
     """scores = Σ_d q·k, softmax, Σ_s p·v as float32 broadcast products."""
     q, k, v = (a.astype(jnp.float32) for a in (q, pool_k, pool_v))
-    scores = jnp.sum(q[:, :, None, :] * k[None], axis=3) * SCALE
+    scores = jnp.sum(q[:, :, None, :] * k[None], axis=3) / np.sqrt(q.shape[-1])
     p = jax.nn.softmax(scores + mask[:, None, :], axis=-1)
     return jnp.sum(p[..., None] * v[None], axis=2)
 
 
-def _case(dtype, rows, seed=0):
+def _case(dtype, rows, seed=0, dh=DH):
     """A pool, R new rows and their inputs. Decode: 5 lanes at scattered
     slots, lane 3 idle (no write, nothing visible). Chunk: 4 positions of one
     lane in a row of slots, each seeing the lane's past and the chunk up to
-    itself, the last a pad row (no write, fully masked)."""
+    itself, the last a pad row (no write, fully masked). The pools come
+    head-major (H, S, dh) whatever ``dh``: what the parent's spelling takes;
+    ``_bound`` lays them out as a decoder binds them."""
     rs = np.random.RandomState(seed)
     R = 4 if rows == "chunk" else 5
-    pool_k, pool_v = (jnp.asarray(rs.randn(H, S, DH), dtype) for _ in "kv")
-    q, k_new, v_new = (jnp.asarray(rs.randn(R, H, DH), dtype) for _ in "qkv")
+    pool_k, pool_v = (jnp.asarray(rs.randn(H, S, dh), dtype) for _ in "kv")
+    q, k_new, v_new = (jnp.asarray(rs.randn(R, H, dh), dtype) for _ in "qkv")
     onehot = np.zeros((R, S), "f")
     mask = np.full((R, S), -1e9, "f")
     if rows == "chunk":
@@ -87,16 +101,40 @@ def _bits(a):
     return a.view(np.uint16 if a.dtype.itemsize == 2 else np.uint32)
 
 
+def _by_slot(pool, heads):
+    """A pool of ``heads`` heads in either layout as (heads, slots, width):
+    a page-major pool's frames are runs of ``page`` slots and its row a
+    token's heads side by side."""
+    pool = np.asarray(pool)
+    if pool.shape[-1] % 128:
+        return pool
+    return pool.reshape(-1, heads, pool.shape[-1] // heads).transpose(1, 0, 2)
+
+
+def _bound(pool, page=PAGE):
+    """A head-major array (heads, slots, width) in the layout ``pool_shape``
+    binds a pool of its heads and width in."""
+    heads, slots, width = pool.shape
+    shape = pool_shape(heads, width, slots, page)
+    if shape == pool.shape:
+        return pool
+    return jnp.transpose(pool, (1, 0, 2)).reshape(shape)
+
+
 CASES = pytest.mark.parametrize("rows", ["decode", "chunk"])
 DTYPES = pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 
 
+@LAYOUTS
 @DTYPES
 @CASES
-def test_pool_write_is_the_parents_blend_bit_for_bit(dtype, rows):
-    pool, _, _, k_new, _, onehot, _ = _case(dtype, rows)
-    got = _kv_pool_write({}, pool, k_new, onehot)
-    assert got.dtype == pool.dtype and got.shape == pool.shape
+def test_pool_write_is_the_parents_blend_bit_for_bit(dtype, rows, dh):
+    pool, _, _, k_new, _, onehot, _ = _case(dtype, rows, dh=dh)
+    bound = _bound(pool)
+    assert (bound.shape == pool.shape) == (dh == DH)
+    got = _kv_pool_write({}, bound, k_new, onehot)
+    assert got.dtype == pool.dtype and got.shape == bound.shape
+    got = _by_slot(got, H)
     want = _parent_write(pool, k_new, onehot, chunk=(rows == "chunk"))
     np.testing.assert_array_equal(_bits(got), _bits(want))
     # a written slot holds the row itself, every other slot what it held
@@ -108,19 +146,22 @@ def test_pool_write_is_the_parents_blend_bit_for_bit(dtype, rows):
     assert written.sum() == len(onehot) - 1     # the idle lane, the pad row
 
 
+@LAYOUTS
 @DTYPES
-def test_pool_write_with_no_onehot_leaves_the_pool_bitwise(dtype):
+def test_pool_write_with_no_onehot_leaves_the_pool_bitwise(dtype, dh):
     """The replay of a fully cached prompt: every row's one-hot is zero."""
-    pool, _, _, k_new, _, onehot, _ = _case(dtype, "chunk")
-    got = _kv_pool_write({}, pool, k_new, jnp.zeros_like(onehot))
-    np.testing.assert_array_equal(_bits(got), _bits(pool))
+    pool, _, _, k_new, _, onehot, _ = _case(dtype, "chunk", dh=dh)
+    got = _kv_pool_write({}, _bound(pool), k_new, jnp.zeros_like(onehot))
+    np.testing.assert_array_equal(_bits(got), _bits(_bound(pool)))
 
 
+@LAYOUTS
 @DTYPES
 @CASES
-def test_pool_attention_is_the_parents_masked_weighted_sum(dtype, rows):
-    pool_k, pool_v, q, _, _, _, mask = _case(dtype, rows)
-    got = _kv_pool_attention({"scale": -1.0}, q, pool_k, pool_v, mask)
+def test_pool_attention_is_the_parents_masked_weighted_sum(dtype, rows, dh):
+    pool_k, pool_v, q, _, _, _, mask = _case(dtype, rows, dh=dh)
+    bound_k, bound_v = _bound(pool_k), _bound(pool_v)
+    got = _kv_pool_attention({"scale": -1.0}, q, bound_k, bound_v, mask)
     assert got.dtype == q.dtype and got.shape == q.shape
     want = _parent_read(q, pool_k, pool_v, mask)
     err = (np.linalg.norm(np.asarray(got, "f") - np.asarray(want), axis=-1)
@@ -129,17 +170,20 @@ def test_pool_attention_is_the_parents_masked_weighted_sum(dtype, rows):
     # another order; a bfloat16 query takes the context back in bfloat16,
     # one rounding of 2^-9 an element
     assert err.max() < (1e-6 if dtype == "float32" else 2.0 ** -8)
-    explicit = _kv_pool_attention({"scale": SCALE}, q, pool_k, pool_v, mask)
+    explicit = _kv_pool_attention({"scale": 1.0 / np.sqrt(dh)}, q, bound_k,
+                                  bound_v, mask)
     np.testing.assert_array_equal(_bits(explicit), _bits(got))
 
 
+@LAYOUTS
 @DTYPES
 @CASES
-def test_a_fully_masked_row_reads_finite_and_moves_no_other(dtype, rows):
+def test_a_fully_masked_row_reads_finite_and_moves_no_other(dtype, rows, dh):
     """The idle lane of a decode step and the pad row of a chunk see no slot:
     the softmax subtracts the row's maximum, so they come out finite (and are
     discarded), and the other rows read what they read without them."""
-    pool_k, pool_v, q, _, _, _, mask = _case(dtype, rows)
+    pool_k, pool_v, q, _, _, _, mask = _case(dtype, rows, dh=dh)
+    pool_k, pool_v = _bound(pool_k), _bound(pool_v)
     dead = 3
     assert float(mask[dead].max()) == -1e9
     got = np.asarray(_kv_pool_attention({"scale": -1.0}, q, pool_k, pool_v,
@@ -182,34 +226,46 @@ def test_symbols_infer_the_pool_and_the_row_inputs():
         "q": (5, H, DH), "pool": shapes[0], "rows": shapes[1],
         "onehot": (5, S), "pool_v": shapes[0], "mask": (5, S)}
     assert out_shapes == [(5, H, DH)]
+    # a page-major pool's slots are its frames x its page
+    paged = pool_shape(H, 32, S, PAGE)
+    assert paged == (S // PAGE, PAGE, H * 32)
+    assert w.infer_shape(pool=paged, rows=(5, H, 32))[0] \
+        == [paged, (5, H, 32), (5, S)]
+    arg_shapes, out_shapes, _ = a.infer_shape(
+        q=(5, H, 32), pool=paged, rows=(5, H, 32))
+    assert dict(zip(a.list_arguments(), arg_shapes))["mask"] == (5, S)
+    assert out_shapes == [(5, H, 32)]
 
 
+@LAYOUTS
 @DTYPES
-def test_one_step_through_the_executor_is_the_two_operators(dtype):
+def test_one_step_through_the_executor_is_the_two_operators(dtype, dh):
     """Bound as a graph (what the decode builders do): the written pool and
     the context of one step, against the operators called directly."""
-    pool_k, pool_v, q, k_new, v_new, onehot, mask = _case(dtype, "decode", 5)
+    pool_k, pool_v, q, k_new, v_new, onehot, mask = _case(dtype, "decode", 5,
+                                                          dh=dh)
     v = mx.sym.Variable
     k_upd = mx.sym.KVPoolWrite(v("kv_k"), v("k_new"), v("oh"), name="kupd")
     v_upd = mx.sym.KVPoolWrite(v("kv_v"), v("v_new"), v("oh"), name="vupd")
     ctx = mx.sym.KVPoolAttention(v("q"), k_upd, v_upd, v("msk"), name="att")
     exe = mx.sym.Group([ctx, k_upd, v_upd]).bind(mx.cpu(), {
         name: mx.nd.NDArray(a) for name, a in dict(
-            kv_k=pool_k, kv_v=pool_v, k_new=k_new, v_new=v_new, oh=onehot,
-            q=q, msk=mask).items()})
+            kv_k=_bound(pool_k), kv_v=_bound(pool_v), k_new=k_new,
+            v_new=v_new, oh=onehot, q=q, msk=mask).items()})
     exe.forward()
     got_ctx, got_k, got_v = (o._jax() for o in exe.outputs)
     want_k = _parent_write(pool_k, k_new, onehot, chunk=False)
     want_v = _parent_write(pool_v, v_new, onehot, chunk=False)
-    np.testing.assert_array_equal(_bits(got_k), _bits(want_k))
-    np.testing.assert_array_equal(_bits(got_v), _bits(want_v))
-    want = _kv_pool_attention({"scale": -1.0}, q, want_k, want_v, mask)
+    np.testing.assert_array_equal(_bits(_by_slot(got_k, H)), _bits(want_k))
+    np.testing.assert_array_equal(_bits(_by_slot(got_v, H)), _bits(want_v))
+    want = _kv_pool_attention({"scale": -1.0}, q, _bound(want_k),
+                              _bound(want_v), mask)
     np.testing.assert_allclose(np.asarray(got_ctx, "f"), np.asarray(want, "f"),
                                rtol=1e-6, atol=1e-6)
 
 
 # ------------------------------------------------- the write by slot index
-# case -> the slots of its rows in a pool of SLOT_S slots (pages of 16)
+# case -> the slots of its rows in a pool of SLOT_S slots (pages of 8)
 SLOT_S = 80
 SLOT_WRITES = {
     "scattered": [7, 64, 33, 0],
@@ -218,62 +274,104 @@ SLOT_WRITES = {
     "two_rows_in_one_page": [18, 29, 50],
     "every_row_rides_along": [-1, -1],
     "empty_step": [],
+    "two_rows_on_one_slot": [9, 30, 9],         # the later one stays
 }
 
 
 @pytest.mark.parametrize("case", list(SLOT_WRITES))
-@pytest.mark.parametrize("heads,width", [(8, 64), (1, 576)])
+@pytest.mark.parametrize("heads,width", [(8, 64), (1, 576), (2, 24)])
 @DTYPES
 def test_slot_write_is_the_onehot_blend_bit_for_bit(dtype, heads, width,
                                                     case):
     """``KVPoolSlotWrite`` against ``KVPoolWrite`` fed the host's one-hots of
-    the same slots, at the two pool shapes the serving cells have (8 heads
-    of 64; one latent row of 576): the same pool bit for bit, a written slot
-    the row itself, a pool shorter than a run and one whose length no run
-    divides, one pool and two in the one loop."""
+    the same slots, at the pool shapes the serving cells have (8 heads of
+    64: a row of 512, PAGE-MAJOR; one latent row of 576, head-major) and a
+    toy model's (2 heads of 24, head-major): the same pool bit for bit, a
+    written slot the row itself and every other slot what it held, a
+    negative slot nothing, the later of two rows on one slot; a head-major
+    pool shorter than a run and one whose length no run divides; one pool
+    and two in the one loop."""
     slots = SLOT_WRITES[case]
     rs = np.random.RandomState(len(case) + heads)
     for pool_slots in (SLOT_S, 2 * attention._WRITE_RUN + 8):  # a cut run
         pool = jnp.asarray(rs.randn(heads, pool_slots, width), dtype)
         rows = jnp.asarray(rs.randn(len(slots), heads, width), dtype)
+        bound = _bound(pool)
+        assert (bound.shape != pool.shape) == (heads * width == 512)
+        write_slot = jnp.asarray(slots, jnp.float32).reshape(-1, 1)
+        got, = _kv_pool_slot_write({}, bound, rows, write_slot)
+        assert got.dtype == pool.dtype and got.shape == bound.shape
+        # row after row, as numpy says it
+        want = np.asarray(pool).copy()
+        for r, slot in enumerate(slots):
+            if slot >= 0:
+                want[:, slot] = np.asarray(rows)[r]
+        np.testing.assert_array_equal(_bits(_by_slot(got, heads)),
+                                      _bits(want))
         onehot = np.zeros((len(slots), pool_slots), "f")
         for r, slot in enumerate(slots):
             if slot >= 0:
                 onehot[r, slot] = 1.0
-        write_slot = jnp.asarray(slots, jnp.float32).reshape(-1, 1)
-        got, = _kv_pool_slot_write({}, pool, rows, write_slot)
-        assert got.dtype == pool.dtype and got.shape == pool.shape
-        want = _kv_pool_write({}, pool, rows, jnp.asarray(onehot))
-        np.testing.assert_array_equal(_bits(got), _bits(want))
+        blend = _kv_pool_write({}, bound, rows, jnp.asarray(onehot))
+        if onehot.sum(0).max() <= 1:    # the blend SUMS two rows on a slot
+            np.testing.assert_array_equal(_bits(got), _bits(blend))
         # a layer's two pools in the one loop: each as it is alone
-        both = _kv_pool_slot_write({"num_pools": 2}, pool, rows, want,
+        both = _kv_pool_slot_write({"num_pools": 2}, bound, rows, blend,
                                    rows[::-1], write_slot)
         np.testing.assert_array_equal(_bits(both[0]), _bits(got))
         np.testing.assert_array_equal(_bits(both[1]), _bits(
-            _kv_pool_write({}, want, rows[::-1], jnp.asarray(onehot))))
-        for r, slot in enumerate(slots):
-            if slot >= 0:
-                np.testing.assert_array_equal(_bits(got)[:, slot],
-                                              _bits(rows)[r])
-        written = [slot for slot in slots if slot >= 0]
-        untouched = np.setdiff1d(np.arange(pool_slots), written)
-        np.testing.assert_array_equal(_bits(got)[:, untouched],
-                                      _bits(pool)[:, untouched])
+            _kv_pool_slot_write({}, blend, rows[::-1], write_slot)[0]))
+        untouched = np.setdiff1d(np.arange(pool_slots),
+                                 [slot for slot in slots if slot >= 0])
+        np.testing.assert_array_equal(
+            _bits(_by_slot(got, heads))[:, untouched],
+            _bits(pool)[:, untouched])
 
 
-def test_slot_write_moves_a_run_a_row_and_nothing_of_the_pools_size():
+def test_slot_write_takes_two_pools_each_in_its_own_layout():
+    """A layer whose key row is whole tiles and whose value row is not (2
+    heads of 64 beside 2 of 48): one loop, the key pool page-major and the
+    value pool head-major, each the one-hot blend of its own."""
+    rs = np.random.RandomState(3)
+    slots = [5, -1, 33, 79]
+    onehot = np.zeros((len(slots), SLOT_S), "f")
+    for r, slot in enumerate(slots):
+        if slot >= 0:
+            onehot[r, slot] = 1.0
+    write_slot = jnp.asarray(slots, jnp.float32).reshape(-1, 1)
+    pools = [_bound(jnp.asarray(rs.randn(2, SLOT_S, d), "float32"))
+             for d in (64, 48)]
+    rows = [jnp.asarray(rs.randn(len(slots), 2, d), "float32")
+            for d in (64, 48)]
+    assert [p.shape for p in pools] == [(10, 8, 128), (2, SLOT_S, 48)]
+    got = _kv_pool_slot_write({"num_pools": 2}, pools[0], rows[0], pools[1],
+                              rows[1], write_slot)
+    for pool, new, out in zip(pools, rows, got):
+        np.testing.assert_array_equal(
+            _bits(out), _bits(_kv_pool_write({}, pool, new,
+                                             jnp.asarray(onehot))))
+
+
+@pytest.mark.parametrize("layout,pool,piece", [
+    ("page_major", (256, 16, 512), "1x1x512"),
+    ("head_major", (1, 4096, 576), "1x%dx576" % attention._WRITE_RUN)])
+def test_slot_write_moves_a_row_or_a_run_and_nothing_of_the_pools_size(
+        layout, pool, piece):
     """What reaches the compiler: no contraction, no one-hot, nothing
-    (rows, slots) or pool-sized made; a loop whose body slices a run of the
-    pool and updates it."""
-    pool = jnp.zeros((8, 4096, 64), jnp.float32)
-    rows = jnp.zeros((5, 8, 64), jnp.float32)
+    (rows, slots) or pool-sized made; a loop whose body slices the piece of
+    the pool that holds a row's slot and updates it: a token's own row of a
+    page-major pool, a run of slots of a head-major one."""
+    heads = 8 if layout == "page_major" else 1
+    rows = jnp.zeros((5, heads, pool[2] // heads), jnp.float32)
     text = jax.jit(lambda *a: _kv_pool_slot_write({}, *a)[0]).lower(
-        pool, rows, jnp.zeros((5, 1), jnp.float32)).as_text()
+        jnp.zeros(pool, jnp.float32), rows,
+        jnp.zeros((5, 1), jnp.float32)).as_text()
     assert "dot_general" not in text and "5x4096" not in text
     assert "dynamic_slice" in text and "dynamic_update_slice" in text
     assert "stablehlo.while" in text
-    run = "8x%dx64" % attention._WRITE_RUN
-    assert run in text
+    assert piece in text
+    if layout == "page_major":      # no run: the row is the piece
+        assert "x%dx" % attention._WRITE_RUN not in text
 
 
 @pytest.mark.parametrize("arch", ["vaswani", "olmoe", "granite_hybrid",
@@ -353,12 +451,13 @@ def _host_row(frames, pos, page, slots):
     return phys, onehot, mask
 
 
-# the pool a step of ``_step_inputs`` writes into: heads, row width
-STEP_POOL = (2, 8)
+# the pools a step of ``_step_inputs`` writes into: layout -> heads, width
+STEP_POOLS = {"head_major": (2, 8), "page_major": (2, 64)}
+STEP_LAYOUTS = pytest.mark.parametrize("layout", list(STEP_POOLS))
 
 
 @functools.lru_cache(maxsize=None)
-def _step_inputs(geometry):
+def _step_inputs(geometry, layout="head_major"):
     """One step of a seeded pool: every lane of ``LANES`` at frames drawn
     without order from the whole pool (never frame 0, which the table's
     padding names), the rest of the lanes mid-context; then what the
@@ -387,11 +486,15 @@ def _step_inputs(geometry):
         pos_idx[r, 0] = 0 if pos is None else pos
         want_oh.append(onehot)
         want_mask.append(mask)
-    heads, width = STEP_POOL
+    heads, width = STEP_POOLS[layout]
     pool = jnp.asarray(rs.randn(heads, slots, width), jnp.float32)
     rows = jnp.asarray(rs.randn(lanes, heads, width), jnp.float32)
-    got_pool, = _kv_pool_slot_write({}, pool, rows, jnp.asarray(write_slot))
-    want_pool = _kv_pool_write({}, pool, rows, jnp.asarray(np.stack(want_oh)))
+    bound = _bound(pool, page)
+    assert (bound.shape != pool.shape) == (layout == "page_major")
+    got_pool, = _kv_pool_slot_write({}, bound, rows, jnp.asarray(write_slot))
+    want_pool = _kv_pool_write({}, bound, rows,
+                               jnp.asarray(np.stack(want_oh)))
+    got_pool, want_pool = (_by_slot(a, heads) for a in (got_pool, want_pool))
     got_mask = _kv_page_mask({"page_size": page, "num_slots": slots},
                              jnp.asarray(table), jnp.asarray(pos_idx),
                              jnp.asarray(write_slot))
@@ -400,17 +503,18 @@ def _step_inputs(geometry):
             np.asarray(pool), np.asarray(rows))
 
 
+@STEP_LAYOUTS
 @pytest.mark.parametrize("lane", list(LANES))
 @pytest.mark.parametrize("geometry", list(GEOMETRIES))
 def test_a_steps_onehot_and_mask_are_the_hosts_element_for_element(
-        geometry, lane):
+        geometry, lane, layout):
     """A lane's half of the write (its slot of the pool written by index
     against the host's one-hot blend) and its row of the mask."""
     (kinds, got_pool, got_mask, want_pool, want_mask, _, write_slot, pool,
-     rows) = _step_inputs(geometry)
+     rows) = _step_inputs(geometry, layout)
     lanes, per_lane, page = GEOMETRIES[geometry]
-    assert got_pool.shape == want_pool.shape == STEP_POOL[:1] + (
-        lanes * per_lane,) + STEP_POOL[1:]
+    assert got_pool.shape == want_pool.shape == STEP_POOLS[layout][:1] + (
+        lanes * per_lane,) + STEP_POOLS[layout][1:]
     assert got_mask.shape == (lanes, lanes * per_lane)
     assert got_pool.dtype == got_mask.dtype == np.float32
     r = kinds.index(lane)
@@ -434,10 +538,11 @@ def test_a_steps_onehot_and_mask_are_the_hosts_element_for_element(
     assert set(np.unique(got_mask[r])) <= {np.float32(0), np.float32(-1e9)}
 
 
+@STEP_LAYOUTS
 @pytest.mark.parametrize("geometry", list(GEOMETRIES))
-def test_every_lane_of_a_step_and_the_frame_two_lanes_share(geometry):
+def test_every_lane_of_a_step_and_the_frame_two_lanes_share(geometry, layout):
     (kinds, got_pool, got_mask, want_pool, want_mask, table, write_slot, pool,
-     _) = _step_inputs(geometry)
+     _) = _step_inputs(geometry, layout)
     np.testing.assert_array_equal(_bits(got_pool), _bits(want_pool))
     np.testing.assert_array_equal(_bits(got_mask), _bits(want_mask))
     page = GEOMETRIES[geometry][2]
@@ -561,6 +666,12 @@ OWN_PAGES = {
     "grouped_32_over_8": (32, 8, 16, 0),
     "value_dim_576_to_512": (4, 1, 576, 512),
     "unused_entries_zero": (4, 4, 16, 0),
+    # rows of whole tiles: the pools are page-major
+    "page_major_one_token": (4, 4, 32, 0),
+    "page_major_rides_along": (4, 4, 32, 0),
+    "page_major_grouped_32_over_8": (32, 8, 64, 0),
+    "page_major_value_dim_128_to_96": (4, 1, 128, 96),
+    "page_major_unused_entries_zero": (8, 2, 64, 0),
 }
 
 
@@ -568,13 +679,13 @@ def _both_reads(monkeypatch, attrs, q, pool_k, pool_v, table, pos_idx,
                 write_slot, page):
     """(own pages, whole pool): the operator with the rule held to each
     answer, on one step's operands."""
-    slots = pool_k.shape[1]
+    slots = attention.pool_slots(pool_k.shape)
     operands = [jnp.asarray(a) for a in (table, pos_idx, write_slot)]
     mask = _kv_page_mask({"page_size": page, "num_slots": slots}, *operands)
     got = []
-    for own in (True, False):
-        monkeypatch.setattr(attention, "pool_read_own_pages",
-                            lambda *a, own=own: own)
+    for form in ("own_pages", "whole_pool"):
+        monkeypatch.setattr(attention, "pool_read_form",
+                            lambda *a, form=form: form)
         got.append(np.asarray(_kv_pool_attention(
             dict(attrs, page_size=page), q, pool_k, pool_v, mask, *operands),
             np.float32))
@@ -595,9 +706,12 @@ def test_own_pages_read_is_the_whole_pool_read(monkeypatch, case, dtype, tol):
     slots = lanes * per_lane
     kinds, *_, table, write_slot, _, _ = _step_inputs("small")
     rs = np.random.RandomState(len(case))
-    pool_k = jnp.asarray(rs.randn(hkv, slots, d), dtype)
-    pool_v = pool_k if value_dim else jnp.asarray(rs.randn(hkv, slots, d),
-                                                  dtype)
+    pool_k = _bound(jnp.asarray(rs.randn(hkv, slots, d), dtype), page)
+    pool_v = pool_k if value_dim else _bound(
+        jnp.asarray(rs.randn(hkv, slots, d), dtype), page)
+    # 8 heads of 16 are a row of 128 too: that grouped case is page-major
+    assert (pool_k.shape[0] != hkv) == ((hkv * d) % 128 == 0) \
+        == (case.startswith("page_major") or case == "grouped_32_over_8")
     q = jnp.asarray(rs.randn(lanes, hq, d) * (4.0 / np.sqrt(d)), dtype)
     pos_idx = np.asarray([[0 if LANES[k](page)[1] is None
                            else LANES[k](page)[1]] for k in kinds], "f")
@@ -607,6 +721,7 @@ def test_own_pages_read_is_the_whole_pool_read(monkeypatch, case, dtype, tol):
     assert own.shape == whole.shape == (lanes, hq, value_dim or d)
     assert np.isfinite(own).all()
     live = [r for r, k in enumerate(kinds) if k != "idle"]
+    case = case.replace("page_major_", "")
     rows = {"partial_last_page": [kinds.index("mid_page")],
             "one_token": [kinds.index("one_slot")],
             "shared_frame": [kinds.index("shared_a"),
@@ -618,7 +733,8 @@ def test_own_pages_read_is_the_whole_pool_read(monkeypatch, case, dtype, tol):
     if case == "one_token":
         # the softmax of one slot is 1: the context IS that slot's value
         r = rows[0]
-        want = np.asarray(pool_v, np.float32)[:, int(write_slot[r, 0])]
+        want = _by_slot(np.asarray(pool_v, np.float32), hkv)[
+            :, int(write_slot[r, 0])]
         np.testing.assert_allclose(own[r].reshape(hkv, -1, d),
                                    np.broadcast_to(want[:, None], (hkv, 1, d)),
                                    rtol=tol, atol=tol)
@@ -646,40 +762,110 @@ def test_own_pages_read_is_the_whole_pool_read(monkeypatch, case, dtype, tol):
         np.testing.assert_array_equal(_bits(filled[live]), _bits(own[live]))
 
 
-# cell -> (lanes, query heads, pool heads, row width, slots a lane, type,
-# pools, own pages?) at the benchmark's serving sizes, pages of 16
+# cell -> (lanes, query heads, pool heads, key width, value width, slots a
+# lane, type, pools, the form on the CPU, the form on the chip) at the
+# benchmark's serving sizes, pages of 16
 POOL_READS = {
-    "kanana-2-30b-a3b": (32, 32, 1, 576, 2048, "bfloat16", 1, True),
-    "granite-4.0-h-micro": (32, 32, 8, 64, 2048, "bfloat16", 2, False),
-    "transformer-base": (64, 8, 8, 64, 1024, "float32", 2, False),
-    "olmoe-1b-7b": (8, 16, 16, 128, 2048, "bfloat16", 2, False),
+    "kanana-2-30b-a3b": (32, 32, 1, 576, 576, 2048, "bfloat16", 1,
+                         "own_pages", "own_pages"),
+    "granite-4.0-h-micro": (32, 32, 8, 64, 64, 2048, "bfloat16", 2,
+                            "own_pages", "kernel"),
+    "transformer-base": (64, 8, 8, 64, 64, 1024, "float32", 2,
+                         "own_pages", "kernel"),
+    "olmoe-1b-7b": (8, 16, 16, 128, 128, 2048, "bfloat16", 2,
+                    "own_pages", "kernel"),
+    "lfm2-24b-a2b": (64, 32, 8, 64, 64, 2048, "bfloat16", 2,
+                     "own_pages", "kernel"),
+    "mimo-v2-flash": (32, 64, 4, 192, 128, 8192, "bfloat16", 2,
+                      "own_pages", "kernel"),
 }
 
 
 @pytest.mark.parametrize("cell", list(POOL_READS))
-def test_the_read_takes_own_pages_for_the_latent_pool_alone(cell):
-    """The rule's answer at the shapes of the four serving configurations:
-    one 576-wide latent row read by 32 heads is small beside its scores and
-    is gathered; the pools of 64- and 128-wide heads are not. And a read
-    that was handed no table scores the whole pool."""
-    lanes, hq, hkv, d, per_lane, dtype, pools, own = POOL_READS[cell]
+def test_the_rule_names_the_form_of_each_serving_configuration(monkeypatch,
+                                                               cell):
+    """The rule's answer at the shapes of the six serving configurations as
+    a decoder binds them (``pool_shape``). One 576-wide latent row is no
+    whole tile of lanes: its pool stays head-major, small beside its scores,
+    and XLA gathers a lane's pages, on the chip as on the CPU. Every other
+    configuration's row is whole tiles (512, 768, 2,048 wide): page-major,
+    the kernel's on the chip, XLA's gather where Mosaic does not run. And a
+    read that was handed no table scores the whole pool."""
+    (lanes, hq, hkv, dk, dv, per_lane, dtype, pools, on_cpu,
+     on_chip) = POOL_READS[cell]
     spec = jax.ShapeDtypeStruct
-    query = spec((lanes, hq, d), dtype)
-    pool = spec((hkv, lanes * per_lane, d), dtype)
+    slots = lanes * per_lane
+    query = spec((lanes, hq, dk), dtype)
+    pool_k = spec(pool_shape(hkv, dk, slots, 16), dtype)
+    pool_v = spec(pool_shape(hkv, dv, slots, 16), dtype) if pools == 2 \
+        else None
+    assert (pool_k.shape[0] == hkv) == (cell == "kanana-2-30b-a3b")
     table = spec((lanes, per_lane // 16), "float32")
-    pool_v = pool if pools == 2 else None
-    assert pool_read_own_pages(query, pool, pool_v, table, 16) is own
-    assert pool_read_own_pages(query, pool, pool_v, None, 0) is False
+    assert pool_read_form(query, pool_k, pool_v, table, 16) == on_cpu
+    assert pool_read_form(query, pool_k, pool_v, None, 0) == "whole_pool"
+    monkeypatch.setattr(attention, "_backend", lambda: "tpu")
+    assert pool_read_form(query, pool_k, pool_v, table, 16) == on_chip
+    assert pool_read_form(query, pool_k, pool_v, None, 0) == "whole_pool"
 
 
-def test_the_rule_counts_a_key_and_a_value_pool_each_at_its_own_width():
-    """``mimo-v2-flash.generate``'s full layers: 64 query heads over a key
-    pool (4, 262,144, 192) and a value pool (4, 262,144, 128), 32 lanes of
-    8,192 slots. Each pool's bytes and each copy's padding (192 to 256
-    lanes, 128 to 128) are its own: the whole-pool read is the pools' 0.67 GB
-    and 6.4 GB of scores, the lanes' own pages 2 x 0.67 + 4 x 0.81 GB of
-    copies and 0.2 GB of scores, so a lane's own pages it is. Counting the
-    value at the key's width would add 4 x 0.27 GB."""
+@pytest.mark.parametrize("cell", list(POOL_READS))
+def test_the_output_rule_is_what_evaluating_the_read_gives(cell):
+    """Shape inference asks ``KVPoolAttention`` for its output's shape and
+    type (``OpDef.infer``) and traces no read, which on the chip would import
+    Pallas: at every serving configuration's operands, with and without a
+    step's table, the rule says what abstractly evaluating the operator
+    says; kanana's value is the first 512 columns of its one pool."""
+    from mxnet_tpu.ops.registry import get_op
+
+    (lanes, hq, hkv, dk, dv, per_lane, dtype, pools, _, _) = POOL_READS[cell]
+    spec = jax.ShapeDtypeStruct
+    slots = lanes * per_lane
+    query = spec((lanes, hq, dk), dtype)
+    pool_k = spec(pool_shape(hkv, dk, slots, 16), dtype)
+    pool_v = spec(pool_shape(hkv, dv, slots, 16), dtype)
+    mask = spec((lanes, slots), "float32")
+    step = [spec((lanes, per_lane // 16), "float32"),
+            spec((lanes, 1), "float32"), spec((lanes, 1), "float32")]
+    op = get_op("KVPoolAttention")
+    for page, value_dim in ((0, 0), (16, 0)) + (
+            ((16, 512),) if pools == 1 else ()):
+        attrs = {"scale": -1.0, "value_dim": value_dim, "page_size": page}
+        operands = [query, pool_k, pool_v, mask] + (step if page else [])
+        (want,) = jax.eval_shape(lambda *a: op.fn(attrs, *a), *operands),
+        assert op.infer(attrs, operands) == [(want.shape, want.dtype)]
+        assert want.shape == (lanes, hq, value_dim or dv)
+
+
+@pytest.mark.parametrize("hkv,d,own", [(8, 24, False), (1, 576, True)])
+def test_a_head_major_read_takes_the_form_that_moves_fewer_bytes(hkv, d, own):
+    """Head-major pools (a row that is no whole tile: 8 heads of 24; one
+    latent row of 576) at 32 lanes x 2,048 slots, 32 query heads: narrow
+    heads pad to the chip's 128 lanes in a gathered copy and the gather
+    moves more than the pool, so the whole pool is scored; one wide row read
+    by every head is small beside its scores and is gathered."""
+    spec = jax.ShapeDtypeStruct
+    lanes, slots = 32, 32 * 2048
+    query = spec((lanes, 32, d), "bfloat16")
+    pool = spec(pool_shape(hkv, d, slots, 16), "bfloat16")
+    assert pool.shape == (hkv, slots, d)
+    table = spec((lanes, 128), "float32")
+    pool_v = pool if hkv > 1 else None
+    whole, gathered = pool_read_bytes(query, pool, pool_v, table, 16)
+    assert (gathered < whole) == own
+    assert pool_read_form(query, pool, pool_v, table, 16) \
+        == ("own_pages" if own else "whole_pool")
+
+
+def test_the_byte_count_takes_a_key_and_a_value_pool_each_at_its_own_width():
+    """``pool_read_bytes`` of head-major pools of different width: 64 query
+    heads over a key pool (4, 262,144, 192) and a value pool (4, 262,144,
+    128), 32 lanes of 8,192 slots (``mimo-v2-flash.generate``'s full layers
+    as PR 38 bound them; they are page-major now and the rule no longer asks
+    this count of them). Each pool's bytes and each copy's padding (192 to
+    256 lanes, 128 to 128) are its own: the whole-pool read is the pools'
+    0.67 GB and 6.4 GB of scores, the lanes' own pages 2 x 0.67 + 4 x 0.81
+    GB of copies and 0.2 GB of scores. Counting the value at the key's width
+    would add 4 x 0.27 GB."""
     spec = jax.ShapeDtypeStruct
     lanes, slots = 32, 32 * 8192
     query = spec((lanes, 64, 192), "bfloat16")
@@ -693,7 +879,6 @@ def test_the_rule_counts_a_key_and_a_value_pool_each_at_its_own_width():
     assert whole == pools + 12 * lanes * 64 * slots == 7_113_539_584
     assert own == 2 * pools + 4 * copies + 12 * lanes * 64 * 8192 \
         == 4_764_729_344
-    assert pool_read_own_pages(query, pool_k, pool_v, table, 16) is True
     alike = pool_read_bytes(query, pool_k, pool_k, table, 16)
     assert alike[1] - own == 4 * lanes * 4 * 8192 * 128 * 2 \
         + 2 * 4 * slots * 64 * 2
@@ -703,21 +888,21 @@ def test_the_rule_counts_a_key_and_a_value_pool_each_at_its_own_width():
 
 
 # ------------------------------------------- the two forms through the decoder
-def _latent_decoder(monkeypatch, own):
+def _latent_decoder(monkeypatch, own, latent):
     """A ``deepseek_v3`` decoder small enough for the CPU whose read the rule
-    sends to own pages (64 heads on one 128-wide row, 8 lanes), or, with the
-    rule held to "no", the same decoder over the whole pool."""
+    sends to own pages (64 heads on one row of ``latent`` + 8, 8 lanes), or,
+    with the rule held to the whole pool, the same decoder over that."""
     from mxnet_tpu.models import transformer as tf
     from mxnet_tpu.serving import PagedKVDecoder
 
     if not own:
-        monkeypatch.setattr(attention, "pool_read_own_pages",
-                            lambda *a: False)
+        monkeypatch.setattr(attention, "pool_read_form",
+                            lambda *a: "whole_pool")
     cfg = dict(vocab_size=64, num_layers=3, num_heads=64, model_dim=32,
                ffn_dim=64, moe_ffn_dim=16, num_experts=8,
                num_experts_per_tok=2, num_shared_experts=1,
                first_dense_layers=1, qk_nope_head_dim=4, qk_rope_head_dim=8,
-               v_head_dim=4, kv_lora_rank=120)
+               v_head_dim=4, kv_lora_rank=latent)
     rs = np.random.RandomState(5)
     params = {n: mx.nd.array(rs.randn(*shape).astype("f") * 0.2)
               for n, shape in tf.param_shapes(arch="deepseek_v3",
@@ -726,11 +911,16 @@ def _latent_decoder(monkeypatch, own):
                           lanes=8, prefill_len=16, **cfg)
 
 
-def test_a_decoder_steps_alike_in_both_forms_and_says_which(monkeypatch):
+@pytest.mark.parametrize("latent,paged", [(112, False), (120, True)],
+                         ids=["head_major_120", "page_major_128"])
+def test_a_decoder_steps_alike_in_both_forms_and_says_which(monkeypatch,
+                                                            latent, paged):
     """Admissions of unequal lengths, a lane that joins late and one that
     retires, stepped side by side: the logits of the decoder whose lanes
     read their own pages are the whole-pool decoder's to float32's sum
-    order, and its telemetry says what a step read."""
+    order, and its telemetry says what a step read. A latent row of 120 is
+    kept head-major, one of 128 page-major (one pool that is key and value
+    both: XLA's gather in either)."""
     from mxnet_tpu import telemetry as tm
 
     saved = tm.current_override()
@@ -739,10 +929,13 @@ def test_a_decoder_steps_alike_in_both_forms_and_says_which(monkeypatch):
         logits = []
         for own in (True, False):
             tm.reset()
-            dec = _latent_decoder(monkeypatch, own).warmup()
+            dec = _latent_decoder(monkeypatch, own, latent).warmup()
+            pool = dec._dec_exe.arg_dict["kv_c_0"].shape
+            assert pool == ((64, 8, 128) if paged else (1, 512, 120))
             snap = tm.snapshot()
             assert snap["serving.pool_read.own_pages_layers"] == 3 * own
             assert snap["serving.pool_read.whole_pool_layers"] == 3 * (not own)
+            assert snap["serving.pool_read.kernel_layers"] == 0
             seqs = [dec.admit(np.arange(n) % 61)[0] for n in (3, 9, 16)]
             rows = []
             for t in range(12):
@@ -757,6 +950,7 @@ def test_a_decoder_steps_alike_in_both_forms_and_says_which(monkeypatch):
             # own pages: 8 lanes x 8 pages of 8; whole: 8 lanes x 512 slots
             assert moved["serving.step_gathered_slots"] \
                 == moved["serving.paged_steps"] * (512 if own else 8 * 512)
+            assert "serving.step_kernel_slots" not in moved
         np.testing.assert_allclose(logits[0], logits[1], rtol=2e-5, atol=2e-5)
     finally:
         tm.set_mode(saved)

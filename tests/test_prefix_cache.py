@@ -29,6 +29,16 @@ def tm():
     telemetry.clear_events()
 
 
+@pytest.fixture(params=["head_major", "page_major"])
+def layout(request, monkeypatch):
+    """The KV pools' layout (``ops.attention.pool_shape``): a row of 2 heads
+    of 16 is kept head-major, one of 2 heads of 64 (128: whole tiles of the
+    chip's lanes) page-major; the gates below hold in both."""
+    if request.param == "page_major":
+        monkeypatch.setitem(CFG, "model_dim", 128)
+    return request.param
+
+
 def _trained_params(S, seed=0):
     net = tfm.get_symbol(seq_len=S, **CFG)
     exe = net.simple_bind(mx.cpu(), grad_req="null", data=(1, S),
@@ -105,7 +115,7 @@ def test_evict_for_reports_failure_when_nothing_evictable():
 
 
 # --------------------------------------------------- serving-level parity
-def test_cached_admit_bitwise_identical_and_hit_accounting(tm):
+def test_cached_admit_bitwise_identical_and_hit_accounting(tm, layout):
     """The acceptance gate: admit a prompt cold, admit it again cached —
     the second admit adopts the cached pages (hit counters move, prefill
     work is saved) and returns BITWISE-identical logits; a retire +
@@ -146,7 +156,7 @@ def test_cached_admit_bitwise_identical_and_hit_accounting(tm):
     dec.retire(s2)
 
 
-def test_partial_prefix_match_decodes_token_identical(tm):
+def test_partial_prefix_match_decodes_token_identical(tm, layout):
     """Two prompts sharing a 4-token stem: the second admit reuses the
     stem chunk and computes only its tail, then decodes token-identical
     to a prefix-cache-OFF decoder over the same checkpoint."""
@@ -178,7 +188,7 @@ def test_partial_prefix_match_decodes_token_identical(tm):
 
 
 # ------------------------------------------------------------- COW / fork
-def test_fork_shares_pages_then_cow_isolates_writers(tm):
+def test_fork_shares_pages_then_cow_isolates_writers(tm, layout):
     """The mid-megastep COW satellite: fork a sequence (every page
     shared at a refcount), megastep BOTH forks down different token
     paths — the first write into the shared boundary page triggers a
@@ -272,7 +282,7 @@ def test_retire_while_shared_and_exhaustion_with_shared_pages(tm):
         assert dec.pool.refcount(f) >= 1
 
 
-def test_rollback_releases_whole_pages_only(tm):
+def test_rollback_releases_whole_pages_only(tm, layout):
     """Rollback (the speculative reject primitive): whole pages past the
     boundary are released, the partial boundary page is kept, and the
     re-decoded continuation is token-identical to never having rolled

@@ -39,9 +39,13 @@ def v5e():
 
 @pytest.fixture(autouse=True)
 def mosaic_not_interpret(monkeypatch):
-    # the kernels pick interpret mode from the default backend (CPU here)
+    # the kernels pick interpret mode from the default backend (CPU here),
+    # and the pool's read picks its form from it
+    from mxnet_tpu.ops import attention
+
     for mod in (pc, pm, pn):
         monkeypatch.setattr(mod, "_interpret_mode", lambda: False)
+    monkeypatch.setattr(attention, "_backend", lambda: "tpu")
 
 
 def _compile(sharding, fn, *shapes_dtypes):
@@ -140,12 +144,14 @@ def test_admit_pool_update_stays_in_place_on_the_chip(v5e):
     """The pool update of an admission (serving/kv_decode.py
     ``_AdmitScatter``) at the benchmark's sizes: all 12 pool buffers are
     aliased to their outputs and the program holds no whole-buffer
-    temporary. The chip keeps the pool with slots minor-most, so a scatter
-    over the slot axis — the form this program replaced — re-lays every
-    134 MB buffer out before and after (269 MB of temporaries, aliased or
-    not); the page walk must not."""
+    temporary. The pools are PAGE-MAJOR (4,096 frames of (16, 8 x 64)): the
+    chip keeps them row-major as bound, a page is one 32 KB piece, and the
+    page walk writes one piece a page and pool. (A head-major pool of 64-wide
+    heads sat slots-minor, and a scatter over its slot axis re-laid every
+    134 MB buffer out before and after.)"""
     from types import SimpleNamespace
 
+    from mxnet_tpu.ops.attention import pool_shape
     from mxnet_tpu.serving.kv_decode import _AdmitScatter
 
     layers, heads, dh, slots, prefill, page = 6, 8, 64, 64 * 1024, 1024, 16
@@ -153,18 +159,40 @@ def test_admit_pool_update_stays_in_place_on_the_chip(v5e):
         _cache=[("kv_%s_%d" % (t, i), "pool", (heads, dh))
                 for i in range(layers) for t in "kv"],
         page_size=page, prefill_len=prefill))
+    bound = pool_shape(heads, dh, slots, page)
+    assert bound == (slots // page, page, heads * dh)
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
 
-    mem = prog._fn.lower(
-        tuple(spec((heads, slots, dh), "float32") for _ in range(2 * layers)),
+    compiled = prog._fn.lower(
+        tuple(spec(bound, "float32") for _ in range(2 * layers)),
         tuple(spec((1, heads, prefill, dh), "float32")
               for _ in range(2 * layers)),
         spec((prefill // page,), "int32"), spec((2,), "int32"),
-    ).compile().memory_analysis()
+    ).compile()
+    mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == 2 * layers * heads * slots * dh * 4
-    assert mem.temp_size_in_bytes < 1 << 20
+    # the prompt's rows turned a token's heads side by side: 12 x 2 MB
+    assert mem.temp_size_in_bytes < 32 << 20
+    _assert_no_pool_sized_copy(compiled.as_text(), heads * slots * dh)
+
+
+def _paged_read_calls(hlo):
+    """The program's calls of the kernel that walks the page table
+    (``ops/pallas_paged_read.py``), a line each."""
+    return [line for line in hlo.splitlines() if " custom-call(" in line
+            and 'custom_call_target="tpu_custom_call"' in line
+            and "/paged_read/" in line]
+
+
+def _assert_no_pool_sized_copy(hlo, pool):
+    """No ``copy`` or ``transpose`` in the program makes ``pool`` elements or
+    more: no buffer of a pool's size is re-laid."""
+    moved = [(op, name, dims) for name, dims, op, _ in _INSTRUCTION.findall(hlo)
+             if op in ("copy", "transpose")
+             and math.prod(int(d) for d in dims.split(",") if d) >= pool]
+    assert not moved, moved
 
 
 def _compile_program(sharding, sym, args, donated=()):
@@ -188,50 +216,42 @@ _INSTRUCTION = re.compile(
 
 
 def _assert_pool_step_contracts(compiled, layers, rows, heads, slots, dh,
-                                temp_bytes, cache_bytes):
-    """A shared-pool decode program as the chip runs it, its pools donated:
-    each layer's two reads of the pool are ``convolution``s or ``dot``s (the
-    matrix unit) and its write of both pools is none: ONE loop a layer that
-    updates a run of slots a row in each, in place, so the program aliases
-    ``cache_bytes``, all of its cache; nothing multiplies rows x heads x slots x dh elements
-    out to ``reduce`` them (the vector unit: 2.1 ms a product at the
-    benchmark's sizes), no buffer the size of a pool is copied or transposed
-    (the chip keeps a dh = 64 pool slots-minor and a dh = 128 one dh-minor;
-    a contraction spelled against that, or an update one slot wide, re-lays
-    134 MB out, 8 ms a step), the temporaries stay under ``temp_bytes``, and
-    no parameter of the program is rows x slots: the masks are made on the
-    device and the write takes a slot index a row."""
+                                temp_bytes, cache_bytes, page=16):
+    """A shared-pool decode program as the chip runs it, its PAGE-MAJOR pools
+    ``(slots / page, page, heads x dh)`` donated: each layer's read of its
+    two pools is ONE ``tpu_custom_call`` (the kernel that walks the page
+    table; no contraction of XLA's touches a pool) and its write of both
+    pools is ONE loop a layer that updates a token's row a lane in each, in
+    place, so the program aliases ``cache_bytes``, all of its cache; no
+    buffer the size of a pool is copied or transposed, the temporaries stay
+    under ``temp_bytes`` (the whole-pool scores, rows x heads x slots, would
+    not), no mask over the pool's slots is built, and no parameter
+    of the program is rows x slots: the write takes a slot index a row."""
     hlo = compiled.as_text()
     entry = hlo[hlo.index("\nENTRY "):]
     params = [tuple(int(d) for d in dims.split(",") if d) for dims in
               re.findall(r" = \w+\[([\d,]*)\]\S* parameter\(",
                          entry[:entry.index("\n}")])]
-    assert (heads, slots, dh) in params and (rows, 1) in params
+    assert (slots // page, page, heads * dh) in params and (rows, 1) in params
     assert (rows, slots) not in params
     assert not [p for p in params if len(p) == 2 and math.prod(p) >= slots
                 and slots in p]
-    size, found = {}, []
-    for name, dims, op, arg in _INSTRUCTION.findall(hlo):
-        size[name] = math.prod(int(d) for d in dims.split(",") if d)
-        found.append((op, name, arg))
-    contractions = [line for line in hlo.splitlines()
+    lines = hlo.splitlines()
+    kernels = _paged_read_calls(hlo)
+    contractions = [line for line in lines
                     if re.search(r" (convolution|dot)\(", line)]
-    updates = [line for line in hlo.splitlines()
-               if " dynamic-update-slice(" in line]
-    loops = [line for line in hlo.splitlines() if " while(" in line]
+    updates = [line for line in lines if " dynamic-update-slice(" in line]
+    loops = [line for line in lines if " while(" in line]
+    assert len(kernels) == layers
     for i in range(layers):
-        for node, count in (("kvupd", 0), ("att", 2)):
+        assert sum("layer%d_att/" % i in line for line in kernels) == 1
+        for node in ("kvupd", "att"):
             tag = "layer%d_%s/" % (i, node)
-            assert sum(tag in line for line in contractions) == count, tag
+            assert not [line for line in contractions if tag in line], tag
         assert sum("layer%d_kvupd/" % i in line for line in updates) == 2
         assert sum("layer%d_kvupd/" % i in line for line in loops) == 1
-    assert "slot_onehot" not in hlo
-    pool, product = heads * slots * dh, rows * heads * slots * dh
-    assert not [(op, name) for op, name, arg in found
-                if op == "reduce" and size.get(arg, 0) >= product]
-    assert max(size.values()) < product
-    assert not [(op, name) for op, name, _ in found
-                if op in ("copy", "transpose") and size[name] >= pool]
+    assert "slot_onehot" not in hlo and "kv_mask" not in hlo
+    _assert_no_pool_sized_copy(hlo, heads * slots * dh)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < temp_bytes
     assert mem.alias_size_in_bytes == cache_bytes
@@ -240,10 +260,12 @@ def _assert_pool_step_contracts(compiled, layers, rows, heads, slots, dh,
 def test_transformer_base_decode_step_contracts_on_the_chip(v5e):
     """``transformer-base.generate``'s decode program (two of its six layers,
     64 lanes x 65,536 slots, float32) lowered for the v5e as it is
-    dispatched: the four pools donated. The temporaries are one layer's
-    float32 scores, 64 x 8 x 65,536 x 4 = 134 MB, and small change; all 512
-    MB of pool are updated in place."""
+    dispatched: the four pools donated, page-major (4,096, 16, 512). A
+    layer's read is the kernel, so no scores over the pool are made (64 x 8
+    x 65,536 x 4 = 134 MB a layer, before) and the temporaries are the
+    feed-forward's; all 512 MB of pool are updated in place."""
     from mxnet_tpu.models import transformer as tf
+    from mxnet_tpu.ops.attention import pool_shape
 
     layers, lanes, slots, heads, dh, page = 2, 64, 64 * 1024, 8, 64, 16
     sym = tf.get_decode_symbol(
@@ -252,19 +274,19 @@ def test_transformer_base_decode_step_contracts_on_the_chip(v5e):
     arg_shapes, _, _ = sym.infer_shape(
         data=(lanes, 1), pos_idx=(lanes, 1), write_slot=(lanes, 1),
         page_table=(lanes, slots // lanes // page),
-        **{"kv_%s_%d" % (t, i): (heads, slots, dh)
+        **{"kv_%s_%d" % (t, i): pool_shape(heads, dh, slots, page)
            for t in "kv" for i in range(layers)})
     pools = ["kv_%s_%d" % (t, i) for i in range(layers) for t in "kv"]
     compiled = _compile_program(v5e, sym, {
         n: (shape, "float32")
         for n, shape in zip(sym.list_arguments(), arg_shapes)}, donated=pools)
     _assert_pool_step_contracts(
-        compiled, layers, lanes, heads, slots, dh, temp_bytes=160 << 20,
+        compiled, layers, lanes, heads, slots, dh, temp_bytes=32 << 20,
         cache_bytes=2 * layers * heads * slots * dh * 4)
-    # XLA's count: 0.84 GB a layer of pool read twice, scores and masks, and
-    # the feed-forward and head; the one-hot blend of every pool read and
-    # rewrote each whole, 1.38 GB a layer and 3.10 GB in all
-    assert compiled.cost_analysis()["bytes accessed"] < 2.2e9
+    # XLA's count: the feed-forward and the head, and next to nothing for a
+    # custom call (its operands' small blocks, not the pages it copies): the
+    # whole-pool read counted 0.84 GB a layer and 2.2 GB in all
+    assert compiled.cost_analysis()["bytes accessed"] < 0.6e9
 
 
 # OLMoE-1B-7B's published widths with one layer, granite-4.0-h-micro's with
@@ -306,8 +328,8 @@ def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
                   "pos_idx": ((lanes, 1), "float32"),
                   "write_slot": ((lanes, 1), "float32"),
                   "page_table": ((lanes, max_len // 16), "float32"),
-                  "kv_k_0": ((16, slots, 128), "bfloat16"),
-                  "kv_v_0": ((16, slots, 128), "bfloat16")}
+                  "kv_k_0": ((slots // 16, 16, 16 * 128), "bfloat16"),
+                  "kv_v_0": ((slots // 16, 16, 16 * 128), "bfloat16")}
     compiled = _compile_program(
         v5e, sym, {**weights, **inputs},
         donated=[n for n in inputs if n.startswith("kv_")])
@@ -323,7 +345,7 @@ def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
     else:
         assert [str(s.dtype) for s in compiled.out_info[0]] \
             == ["float32", "bfloat16", "bfloat16", "float32"]
-        assert compiled.out_info[0][1].shape == (16, slots, 128)
+        assert compiled.out_info[0][1].shape == (slots // 16, 16, 16 * 128)
         # no temporary of a pool's size (16 x 16,384 x 128 bfloat16 = 67 MB)
         _assert_pool_step_contracts(compiled, 1, lanes, 16, slots, 128,
                                     temp_bytes=48 << 20,
@@ -344,6 +366,7 @@ def test_granite_hybrid_serving_programs_compile_for_the_chip(v5e, program):
     the type it went in, and that a step's temporaries stay small beside the
     3 GB of cache it rewrites."""
     from mxnet_tpu.models import transformer as tf
+    from mxnet_tpu.ops.attention import pool_shape
 
     lanes, max_len, bucket, page, layers = 32, 2048, 512, 16, 6
     slots = lanes * max_len
@@ -365,7 +388,7 @@ def test_granite_hybrid_serving_programs_compile_for_the_chip(v5e, program):
                   "write_slot": ((lanes, 1), "float32"),
                   "page_table": ((lanes, max_len // page), "float32")}
         for name, kind, shape in cache:
-            inputs[name] = ((shape[0], slots, shape[1]), "bfloat16") \
+            inputs[name] = (pool_shape(*shape, slots, page), "bfloat16") \
                 if kind == "pool" else ((lanes,) + tuple(shape), "float32")
         want_types = ["float32"] * 11 + ["bfloat16"] * 2 + ["float32"]
     compiled = _compile_program(
@@ -382,12 +405,13 @@ def test_granite_hybrid_serving_programs_compile_for_the_chip(v5e, program):
     mem = compiled.memory_analysis()
     if program == "decode":
         assert compiled.out_info[0][1].shape == (lanes, 64, 64, 128)
-        assert compiled.out_info[0][11].shape == (8, slots, 64)
-        # the scores of one attention layer, 32 x 32 x 65,536 float32 =
-        # 268 MB, and small change; no second copy of any cache buffer:
-        # the five layers' states and columns and the two pools are all
-        # updated in place
-        assert mem.temp_size_in_bytes < 400 << 20
+        assert compiled.out_info[0][11].shape == (slots // page, page, 512)
+        # the attention layer's read is the kernel (no scores over the pool:
+        # 32 x 32 x 65,536 float32 were 268 MB); no second copy of any cache
+        # buffer: the five layers' states and columns and the two pools are
+        # all updated in place
+        assert len(_paged_read_calls(hlo)) == 1 and "kv_mask" not in hlo
+        assert mem.temp_size_in_bytes < 150 << 20
         assert mem.alias_size_in_bytes == 2 * pool * 2 + sum(
             lanes * math.prod(shape) * 4
             for _, kind, shape in cache if kind == "row")
@@ -415,6 +439,7 @@ def test_latent_attention_serving_programs_compile_for_the_chip(v5e, program):
     65,536) mask, which none of its reads looks at. Both programs keep the
     grouped matmul and report the experts' load last."""
     from mxnet_tpu.models import transformer as tf
+    from mxnet_tpu.ops.attention import pool_shape
 
     lanes, max_len, bucket, page, layers = 32, 2048, 1024, 16, 3
     slots = lanes * max_len
@@ -464,6 +489,10 @@ def test_latent_attention_serving_programs_compile_for_the_chip(v5e, program):
         assert not [n for n, _ in found
                     if n >= lanes * 32 * slots and n != 128256 * 2048]
         assert "kv_mask" not in hlo and "slot_onehot" not in hlo
+        # a row of 576 is no whole tile of lanes: the pool stays head-major
+        # and its read XLA's gather, no kernel
+        assert pool_shape(1, 576, slots, page) == (1, slots, 576)
+        assert not _paged_read_calls(hlo)
         assert not [n for n, op in found
                     if op == "select" and n >= lanes * max_len * 576]
         gathers = [line for line in hlo.splitlines() if " gather(" in line]
@@ -499,19 +528,19 @@ def test_lfm2_moe_serving_programs_compile_for_the_chip(v5e, program):
     benchmark's cut (5,267,090,176 parameters in bfloat16) and its serving
     sizes (64 lanes x 2,048 slots, a 1,024 bucket). What has to hold on the
     chip: the cache is a (64, 2, 2,048) float32 row a conv layer and two
-    (8, 131,072, 64) pools an attention layer, in layer order, each back in
-    the type it went in; the step's two attention layers read a lane's OWN
-    PAGES, as ``pool_read_own_pages`` says of (64, 32, 64) queries over two
-    pools of 8 x 131,072 x 64 (2.7 GB a layer against 3.5 for the whole
-    pool), so no (64, 131,072) mask is built and nothing as large as the
-    lanes' scores over the pool is made; the conv operators are in the
-    program under their nodes' names; both graphs keep the grouped matmul
-    and report the experts' load last; and everything fits beside the
-    weights: the step's temporaries under 0.7 GB, the admission's under 0.1."""
+    page-major (8,192, 16, 512) pools an attention layer, in layer order,
+    each back in the type it went in; the step's two attention layers read
+    through the KERNEL that walks the page table, as ``pool_read_form`` says
+    of (64, 32, 64) queries over two such pools, so no (64, 131,072) mask is
+    built, nothing is gathered and no pool is re-laid; the conv operators
+    are in the program under their nodes' names; both graphs keep the
+    grouped matmul and report the experts' load last; and everything fits
+    beside the weights: the step's temporaries under 0.3 GB (0.7 with the
+    gathered copies), the admission's under 0.1."""
     from types import SimpleNamespace
 
     from mxnet_tpu.models import transformer as tf
-    from mxnet_tpu.ops.attention import pool_read_own_pages
+    from mxnet_tpu.ops.attention import pool_read_form, pool_shape
     from mxnet_tpu.serving.kv_decode import _AdmitScatter
 
     lanes, max_len, bucket, page = 64, 2048, 1024, 16
@@ -521,7 +550,9 @@ def test_lfm2_moe_serving_programs_compile_for_the_chip(v5e, program):
     assert [kind for _, kind, _ in cache] == \
         ["row", "row", "pool", "pool", "row", "row", "row", "pool", "pool",
          "row", "row", "row"]
-    buffers = [((8, slots, 64), "bfloat16") if kind == "pool"
+    bound = pool_shape(8, 64, slots, page)
+    assert bound == (slots // page, page, 512)
+    buffers = [(bound, "bfloat16") if kind == "pool"
                else ((lanes, 2, 2048), "float32") for _, kind, _ in cache]
     if program == "admit_scatter":
         prog = _AdmitScatter(SimpleNamespace(
@@ -530,14 +561,17 @@ def test_lfm2_moe_serving_programs_compile_for_the_chip(v5e, program):
             shape, jnp.dtype(dtype), sharding=v5e)
         new = [((1, 8, bucket, 64), "bfloat16") if kind == "pool"
                else ((1, 2, 2048), "float32") for _, kind, _ in cache]
-        mem = prog._fn.lower(
+        compiled = prog._fn.lower(
             tuple(spec(*b) for b in buffers), tuple(spec(*n) for n in new),
             spec((bucket // page,), "int32"), spec((2,), "int32"),
-        ).compile().memory_analysis()
+        ).compile()
+        mem = compiled.memory_analysis()
         # every buffer of the cache is updated in place, pools and rows
         assert mem.alias_size_in_bytes == 4 * 8 * slots * 64 * 2 \
             + 8 * lanes * 2 * 2048 * 4
-        assert mem.temp_size_in_bytes < 1 << 20
+        # the prompt's rows turned a token's heads side by side: 4 x 1 MB
+        assert mem.temp_size_in_bytes < 8 << 20
+        _assert_no_pool_sized_copy(compiled.as_text(), 8 * slots * 64)
         return
     if program == "prefill":
         sym = tf.get_prefill_symbol(prefill_len=bucket, **cfg)
@@ -570,24 +604,22 @@ def test_lfm2_moe_serving_programs_compile_for_the_chip(v5e, program):
         return
     # the rule, asked as the operator asks it
     struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
-    pool = struct((8, slots, 64), "bfloat16")
-    assert pool_read_own_pages(
+    pool = struct(bound, "bfloat16")
+    assert pool_read_form(
         struct((lanes, 32, 64), "bfloat16"), pool, pool,
-        struct((lanes, max_len // page), "float32"), page)
-    found = [(math.prod(int(d) for d in dims.split(",") if d), op)
-             for _n, dims, op, _a in _INSTRUCTION.findall(hlo)
-             if op != "parameter"]
-    # nothing made is as large as the lanes' scores over the pool (268 M
-    # elements), and no read's mask is built
-    assert not [n for n, _ in found if n >= lanes * 32 * slots]
+        struct((lanes, max_len // page), "float32"), page) == "kernel"
+    # one call of the kernel an attention layer; no read's mask is built,
+    # nothing is gathered for a read and no pool is re-laid
+    calls = _paged_read_calls(hlo)
+    assert len(calls) == 2
+    for i in (2, 6):
+        assert sum("layer%d_att/" % i in line for line in calls) == 1
     assert "kv_mask" not in hlo and "slot_onehot" not in hlo
+    _assert_no_pool_sized_copy(hlo, 8 * slots * 64)
     # the step updates every row and pool in place
     assert mem.alias_size_in_bytes == 4 * 8 * slots * 64 * 2 \
         + 8 * lanes * 2 * 2048 * 4
-    gathers = [line for line in hlo.splitlines() if " gather(" in line]
-    for i in (2, 6):    # a gather for the keys, one for the values
-        assert sum("layer%d_att/" % i in g for g in gathers) == 2
-    assert mem.temp_size_in_bytes < 700 << 20
+    assert mem.temp_size_in_bytes < 300 << 20
 
 
 _MIMO = dict(arch="mimo_v2_flash", vocab_size=19072, num_layers=7,
@@ -608,19 +640,19 @@ def test_mimo_v2_flash_serving_programs_compile_for_the_chip(v5e, program):
     cut (layers 0-6, 16 of 256 experts, 19,072 rows of the vocabulary:
     3,429,955,392 parameters in bfloat16) and its serving sizes (32 lanes x
     8,192 slots, a 2,048 bucket). What has to hold on the chip: the cache is
-    a key pool (4, 262,144, 192) beside a value pool (4, 262,144, 128) for a
-    full layer and two rings (32, 8, 128, .) for a window layer, in layer
-    order, each back in the type it went in and updated in place; a window
-    layer's prefill scores a BAND (nothing of 2,048 x 2,048 a window head);
-    the step's two full layers read a lane's OWN PAGES, as
-    ``pool_read_own_pages`` says of a key and a value pool of different
-    width; both graphs keep the grouped matmul over the 16 held experts and
-    report the load of all 256 last; and everything fits beside 6.9 GB of
-    weights."""
+    a page-major key pool (16,384, 16, 768) beside a value pool (16,384, 16,
+    512) for a full layer and two rings (32, 8, 128, .) for a window layer,
+    in layer order, each back in the type it went in and updated in place; a
+    window layer's prefill scores a BAND (nothing of 2,048 x 2,048 a window
+    head); the step's two full layers read through the KERNEL that walks the
+    page table, as ``pool_read_form`` says of a key and a value pool of
+    different width, and re-lay no pool; both graphs keep the grouped matmul
+    over the 16 held experts and report the load of all 256 last; and
+    everything fits beside 6.9 GB of weights."""
     from types import SimpleNamespace
 
     from mxnet_tpu.models import transformer as tf
-    from mxnet_tpu.ops.attention import pool_read_own_pages
+    from mxnet_tpu.ops.attention import pool_read_form, pool_shape
     from mxnet_tpu.serving.kv_decode import _AdmitScatter
 
     lanes, max_len, bucket, page = 32, 8192, 2048, 16
@@ -631,9 +663,11 @@ def test_mimo_v2_flash_serving_programs_compile_for_the_chip(v5e, program):
     cache = tf.decode_cache(**cfg)
     assert [kind for _, kind, _ in cache] == \
         ["pool"] * 2 + ["ring"] * 8 + ["pool"] * 2 + ["ring"] * 2
-    buffers = [((shape[0], slots, shape[1]) if kind == "pool"
+    buffers = [(pool_shape(*shape, slots, page) if kind == "pool"
                 else (lanes,) + shape, "bfloat16")
                for _, kind, shape in cache]
+    assert [b for b, _ in buffers[:2]] == [(slots // page, page, 768),
+                                           (slots // page, page, 512)]
     cache_bytes = sum(2 * math.prod(shape) for shape, _ in buffers)
     # the two full layers' pools 1.34 GB, the five window layers' rings 0.1
     assert cache_bytes == 2 * slots * 4 * 320 * 2 + 5 * lanes * 128 * 8 * 640
@@ -644,14 +678,17 @@ def test_mimo_v2_flash_serving_programs_compile_for_the_chip(v5e, program):
             _cache=cache, page_size=page, prefill_len=bucket))
         spec = lambda shape, dtype: jax.ShapeDtypeStruct(
             shape, jnp.dtype(dtype), sharding=v5e)
-        mem = prog._fn.lower(
+        compiled = prog._fn.lower(
             tuple(spec(*b) for b in buffers),
             tuple(spec(*n) for n in exported),
             spec((bucket // page,), "int32"), spec((2,), "int32"),
-        ).compile().memory_analysis()
+        ).compile()
+        mem = compiled.memory_analysis()
         # every buffer of the cache is updated in place, pools and rings
         assert mem.alias_size_in_bytes == cache_bytes
-        assert mem.temp_size_in_bytes < 8 << 20
+        # the prompt's rows turned a token's heads side by side: 10.5 MB
+        assert mem.temp_size_in_bytes < 24 << 20
+        _assert_no_pool_sized_copy(compiled.as_text(), 4 * slots * 128)
         return
     if program == "prefill":
         sym = tf.get_prefill_symbol(prefill_len=bucket, **cfg)
@@ -685,18 +722,21 @@ def test_mimo_v2_flash_serving_programs_compile_for_the_chip(v5e, program):
         return
     # the rule, asked as the operator asks it: pools of different width
     struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
-    assert pool_read_own_pages(
+    assert pool_read_form(
         struct((lanes, 64, 192), "bfloat16"),
-        struct((4, slots, 192), "bfloat16"),
-        struct((4, slots, 128), "bfloat16"),
-        struct((lanes, max_len // page), "float32"), page)
-    # nothing made is as large as the lanes' scores over the pool (537 M
-    # elements), and no read's mask is built
-    assert not [n for n, _ in found if n >= lanes * 64 * slots]
+        struct(buffers[0][0], "bfloat16"), struct(buffers[1][0], "bfloat16"),
+        struct((lanes, max_len // page), "float32"), page) == "kernel"
+    calls = _paged_read_calls(hlo)
+    assert len(calls) == 2
+    for i in (0, 5):
+        assert sum("layer%d_att/" % i in line for line in calls) == 1
+    # no read's mask is built and no pool is re-laid (the value pool is the
+    # smaller: 134 M elements)
     assert "kv_mask" not in hlo and "slot_onehot" not in hlo
+    _assert_no_pool_sized_copy(hlo, 4 * slots * 128)
     # the step updates every pool and ring in place
     assert mem.alias_size_in_bytes == cache_bytes
-    assert mem.temp_size_in_bytes < 3 << 30
+    assert mem.temp_size_in_bytes < 1 << 30
 
 
 def _program_alone(hlo):
@@ -710,29 +750,38 @@ def _program_alone(hlo):
                   "\n\n".join(blocks))
 
 
-# cell -> (the builder's sizes, lanes, slots a lane, weights' type)
-_WHOLE_POOL_CELLS = {
+# cell -> (the builder's sizes, lanes, slots a lane, weights' type, attention
+# layers, (key/value heads, width))
+_NARROW_POOL_CELLS = {
     "transformer-base": (dict(vocab_size=32000, num_layers=2, num_heads=8,
                               model_dim=512, ffn_dim=2048, pos_len=1024),
-                         64, 1024, "float32"),
-    "olmoe-1b-7b": (_OLMOE, 8, 2048, "bfloat16"),
-    "granite-4.0-h-micro": (_GRANITE, 32, 2048, "bfloat16"),
+                         64, 1024, "float32", 2, (8, 64)),
+    "olmoe-1b-7b": (_OLMOE, 8, 2048, "bfloat16", 1, (16, 128)),
+    "granite-4.0-h-micro": (_GRANITE, 32, 2048, "bfloat16", 1, (8, 64)),
 }
 
 
-@pytest.mark.parametrize("cell", list(_WHOLE_POOL_CELLS))
-def test_a_pool_of_narrow_heads_keeps_its_decode_program(v5e, monkeypatch,
+@pytest.mark.parametrize("cell", list(_NARROW_POOL_CELLS))
+def test_a_pool_of_narrow_heads_reads_through_the_kernel(v5e, monkeypatch,
                                                          cell):
     """The decode programs of the three configurations whose pools hold 64-
-    and 128-wide heads, at their cells' serving sizes: handing the read its
-    page table changes nothing the chip runs. The rule says whole pool, so
-    the program is, instruction for instruction, the one compiled from the
-    graph that hands ``KVPoolAttention`` a mask and nothing else, which is
-    the graph of before the read could take a table."""
+    and 128-wide heads, at their cells' serving sizes. (Until the pools were
+    page-major these kept the whole-pool read: the table changed nothing the
+    chip ran.) Handing the read its page table now IS the program: one call
+    of the kernel an attention layer, no mask over the pool's slots, no
+    pool-sized copy, the whole cache updated in place; the graph that hands
+    ``KVPoolAttention`` a mask and nothing else (a chunk's) scores the whole
+    pool over the same page-major buffers, and XLA counts more bytes for
+    it than for the kernel's program."""
     from mxnet_tpu.models import transformer as tf
+    from mxnet_tpu.ops.attention import pool_shape
 
-    cfg, lanes, max_len, dtype = _WHOLE_POOL_CELLS[cell]
+    cfg, lanes, max_len, dtype, att_layers, (hkv, d) = \
+        _NARROW_POOL_CELLS[cell]
     slots, page = lanes * max_len, 16
+    bound = pool_shape(hkv, d, slots, page)
+    assert bound == (slots // page, page, hkv * d)
+
     def mask_only(*a):
         write, read = step_inputs(*a)
         return write, {"mask": read["mask"]}
@@ -751,23 +800,37 @@ def test_a_pool_of_narrow_heads_keeps_its_decode_program(v5e, monkeypatch,
     if "arch" in cfg:
         args.update({n: (shape, dtype)
                      for n, shape in tf.param_shapes(**cfg).items()})
-        for name, kind, shape in tf.decode_cache(**cfg):
-            args[name] = ((shape[0], slots, shape[1]), dtype) \
+        cache = tf.decode_cache(**cfg)
+        for name, kind, shape in cache:
+            args[name] = (pool_shape(*shape, slots, page), dtype) \
                 if kind == "pool" else ((lanes,) + tuple(shape), "float32")
+        donated = [name for name, _, _ in cache]
     else:
-        heads, dh = cfg["num_heads"], cfg["model_dim"] // cfg["num_heads"]
-        pools = {"kv_%s_%d" % (t, i): (heads, slots, dh)
-                 for t in "kv" for i in range(cfg["num_layers"])}
+        donated = ["kv_%s_%d" % (t, i) for i in range(cfg["num_layers"])
+                   for t in "kv"]
         sym = build(False)
-        shapes, _, _ = sym.infer_shape(**pools, **{
+        shapes, _, _ = sym.infer_shape(**{n: bound for n in donated}, **{
             n: s for n, (s, _) in args.items()})
         args = {n: (s, dtype) for n, s in zip(sym.list_arguments(), shapes)}
     with_table, mask_alone = build(False), build(True)
     operands = [[len(n.inputs) for n in sym._topo()
                  if n.op == "_contrib_KVPoolAttention"]
                 for sym in (with_table, mask_alone)]
-    assert operands[0] and set(operands[0]) == {7}
-    assert len(operands[1]) == len(operands[0]) and set(operands[1]) == {4}
-    programs = [_program_alone(_compile_program(v5e, sym, args).as_text())
-                for sym in (with_table, mask_alone)]
-    assert programs[0] == programs[1]
+    assert len(operands[0]) == att_layers and set(operands[0]) == {7}
+    assert len(operands[1]) == att_layers and set(operands[1]) == {4}
+    kernel, whole = (_compile_program(v5e, sym, args, donated=donated)
+                     for sym in (with_table, mask_alone))
+    hlo = kernel.as_text()
+    assert len(_paged_read_calls(hlo)) == att_layers
+    assert "kv_mask" not in hlo
+    _assert_no_pool_sized_copy(hlo, hkv * slots * d)
+    itemsize = jnp.dtype(dtype).itemsize
+    cache_bytes = sum(
+        math.prod(args[n][0]) * jnp.dtype(args[n][1]).itemsize
+        for n in donated)
+    assert cache_bytes >= 2 * att_layers * hkv * slots * d * itemsize
+    assert kernel.memory_analysis().alias_size_in_bytes == cache_bytes
+    assert not _paged_read_calls(whole.as_text())
+    assert "kv_mask" in whole.as_text()
+    assert kernel.cost_analysis()["bytes accessed"] \
+        < whole.cost_analysis()["bytes accessed"]
