@@ -1,8 +1,12 @@
 """Sparse-expert feed-forward (the registry's first data-dependent operator).
 
-Router -> top-k -> sort the (token, expert) assignments by expert -> one
-grouped matmul per expert matrix (``jax.lax.ragged_dot``: XLA's own, no
-kernel of this repo) -> weighted un-sort. Every shape is static: N tokens
+Router -> top-k -> sort the (token, expert) assignments by expert -> the
+experts' grouped matmuls -> weighted un-sort. The grouped matmuls have two
+forms, named from the operands by ``pallas_grouped_matmul.moe_form``: one
+``jax.lax.ragged_dot`` per expert matrix (XLA's own: the CPU, and whatever
+the kernel does not take), or two calls of this repo's Pallas kernel, gate
+and up fused with the activation, then down (the chip). Every shape is
+static: N tokens
 always make N*k assignment rows, nothing is dropped and there is no capacity
 factor, so how evenly the router spreads its tokens changes the rows an
 expert gets and never a shape.
@@ -13,6 +17,8 @@ import jax
 import jax.numpy as jnp
 
 from ..base import MXNetError
+from . import attention as _attention
+from . import pallas_grouped_matmul as _kernel   # plain Python until traced
 from .registry import AttrSpec, register
 
 _SCORES = {"softmax": lambda z: jax.nn.softmax(z, axis=-1),
@@ -23,6 +29,16 @@ def _moe_names(attrs):
     names = ["data", "router_weight", "gate_weight", "up_weight",
              "down_weight"]
     return names + ["router_bias"] if attrs.get("router_bias") else names
+
+
+def _moe_out(attrs, inputs):
+    """``MoEFeedForward``'s outputs from its operands' shapes: ``y`` as the
+    data, ``load`` (num_experts,) float32. Shape inference asks this and
+    traces no expert product: the kernel's form would import Pallas to say
+    the same."""
+    data = inputs[0]
+    return [(data.shape, data.dtype),
+            ((attrs["num_experts"],), jnp.float32)]
 
 
 @register(
@@ -42,6 +58,7 @@ def _moe_names(attrs):
     num_outputs=2,
     output_names=("output", "load"),
     aliases=("MoEFeedForward",),
+    infer=_moe_out,
 )
 def _moe_feed_forward(attrs, data, router_weight, gate_weight, up_weight,
                       down_weight, router_bias=None):
@@ -113,12 +130,19 @@ def _moe_feed_forward(attrs, data, router_weight, gate_weight, up_weight,
     load = jnp.bincount(expert, length=n_exp).astype(jnp.int32)
     groups = load if held is None else load[first:first + n_local]
     rows = data[order // k]                                 # (N*k, D)
-    dot = lambda a, b: jax.lax.ragged_dot(
-        a, b, groups, preferred_element_type=jnp.float32)
-    act = jax.nn.silu(dot(rows, gate_weight)) * dot(rows, up_weight)
-    out = dot(act.astype(data.dtype), down_weight)          # (N*k, D) f32
-    if held is not None:    # what a row past the groups reads is not defined
-        out = jnp.where(held[order][:, None], out, 0)
+    if _kernel.moe_form(rows, gate_weight, down_weight) == "kernel":
+        # a row past the groups comes out zero; off the chip a test that
+        # holds the rule runs the kernel interpreted
+        out = _kernel.expert_ffn(
+            rows, gate_weight, up_weight, down_weight, groups, n_exp,
+            interpret=_attention._backend() != "tpu")       # (N*k, D) f32
+    else:
+        dot = lambda a, b: jax.lax.ragged_dot(
+            a, b, groups, preferred_element_type=jnp.float32)
+        act = jax.nn.silu(dot(rows, gate_weight)) * dot(rows, up_weight)
+        out = dot(act.astype(data.dtype), down_weight)      # (N*k, D) f32
+        if held is not None:    # what a row past the groups reads is not
+            out = jnp.where(held[order][:, None], out, 0)   # defined
     # un-sort: row j of the sorted order is assignment order[j]; its inverse
     # permutation brings every token's k rows back side by side
     back = jnp.argsort(order).reshape(n, k)

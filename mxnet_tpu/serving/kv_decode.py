@@ -225,34 +225,40 @@ def _output_at(symbol, name):
     return outs.index(name + "_output") if name + "_output" in outs else None
 
 
+def _operands_of(cache, input_shapes, op):
+    """``[(node, {input name: ShapeDtypeStruct})]`` of every ``op`` node of a
+    program's graph, in the graph's order, its operands as inferred at these
+    input shapes: what an operator's own rule over shapes and types is asked
+    of when the program is traced."""
+    import jax
+
+    from ..symbol import Symbol
+
+    nodes = [n for n in cache._sym._topo() if n.op == op]
+    if not nodes:
+        return []
+    res = cache._infer_full(
+        input_shapes, Symbol([e for n in nodes for e in n.inputs]))
+    structs = (jax.ShapeDtypeStruct(s, t) for s, t in zip(res[1], res[4]))
+    return [(n, dict(zip(n.opdef().input_names(n.parsed_attrs()), structs)))
+            for n in nodes]
+
+
 def _pool_reads(cache, input_shapes):
     """What one dispatch of a decode program reads of its pools, a layer:
     ``[(form, slots)]`` over the graph's ``KVPoolAttention`` nodes at these
-    input shapes. The form is the operator's own rule (``pool_read_form``)
-    asked of each node's operands as inferred, which is what it is asked of
-    when the program is traced. ``slots``: what an XLA form scores a
-    dispatch (the rows' tables whole, or the pool for every row); for the
-    kernel, whose fetch follows the rows' contexts, the slots of ONE block
-    (``serving.step_kernel_slots`` rounds each stepped lane's context up to
-    it)."""
-    import jax
-
+    input shapes. The form is the operator's own rule (``pool_read_form``).
+    ``slots``: what an XLA form scores a dispatch (the rows' tables whole, or
+    the pool for every row); for the kernel, whose fetch follows the rows'
+    contexts, the slots of ONE block (``serving.step_kernel_slots`` rounds
+    each stepped lane's context up to it)."""
     from ..ops.attention import pool_read_form, pool_slots
-    from ..symbol import Symbol
 
-    reads = [n for n in cache._sym._topo()
-             if n.op == "_contrib_KVPoolAttention"]
-    if not reads:
-        return []
-    res = cache._infer_full(
-        input_shapes, Symbol([e for n in reads for e in n.inputs]))
-    structs = (jax.ShapeDtypeStruct(s, t) for s, t in zip(res[1], res[4]))
     out = []
-    for n in reads:
-        attrs = n.parsed_attrs()
-        ops = dict(zip(n.opdef().input_names(attrs), structs))
-        page, table = attrs.get("page_size", 0), ops.get("page_table")
-        k, v = ops["pool_k"], ops["pool_v"]
+    for n, ops in _operands_of(cache, input_shapes,
+                               "_contrib_KVPoolAttention"):
+        page = n.parsed_attrs().get("page_size", 0)
+        table, k, v = ops.get("page_table"), ops["pool_k"], ops["pool_v"]
         form = pool_read_form(
             ops["query"], k, None if n.inputs[1] == n.inputs[2] else v,
             table, page)
@@ -266,6 +272,25 @@ def _pool_reads(cache, input_shapes):
         else:
             slots = rows * pool_slots(k.shape)
         out.append((form, slots))
+    return out
+
+
+def _moe_forms(cache, input_shapes):
+    """The form of every ``MoEFeedForward`` node of a program's graph at
+    these input shapes, ``["kernel" | "ragged_dot"]`` in the graph's order,
+    by the operator's own rule (``pallas_grouped_matmul.moe_form``)."""
+    import jax
+
+    from ..ops.pallas_grouped_matmul import moe_form
+
+    out = []
+    for n, ops in _operands_of(cache, input_shapes,
+                               "_contrib_MoEFeedForward"):
+        data = ops["data"]
+        rows = jax.ShapeDtypeStruct(
+            (data.shape[0] * n.parsed_attrs()["num_experts_per_tok"],
+             data.shape[1]), data.dtype)
+        out.append(moe_form(rows, ops["gate_weight"], ops["down_weight"]))
     return out
 
 
@@ -1136,6 +1161,17 @@ class PagedKVDecoder:
             scored = by_form("own_pages") + by_form("whole_pool")
             self._step_gathered_slots = sum(scored) // max(len(scored), 1)
             self._kernel_block = max(by_form("kernel"), default=0)
+            # the expert layers of each bound program by the form of their
+            # grouped matmuls
+            programs = {"decode": (self._dec_cache, self._decode_shapes())}
+            if self._prefix is None:
+                programs["prefill"] = (self._pf_cache, self._prefill_shapes())
+            for program, bound in programs.items():
+                forms = _moe_forms(*bound)
+                _tm.gauge("serving.moe.kernel_layers." + program).set(
+                    forms.count("kernel"))
+                _tm.gauge("serving.moe.xla_layers." + program).set(
+                    forms.count("ragged_dot"))
             _tm.gauge("serving.state_bytes").set(sum(
                 4 * self.lanes * int(np.prod(shape))
                 for _, kind, shape in self._cache if kind == "row"))

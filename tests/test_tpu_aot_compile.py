@@ -186,6 +186,71 @@ def _paged_read_calls(hlo):
             and "/paged_read/" in line]
 
 
+def _assert_expert_layers(hlo, layers, tokens, k, experts, d, f):
+    """The program's ``layers`` expert layers of ``tokens`` rows, ``k`` experts
+    a token, ``experts`` held stacks (D, F) run the form the operator's rule
+    (``pallas_grouped_matmul.moe_form``) names at those operands, asked as the
+    operator asks it: TWO calls of the kernel a kernel layer (gate and up
+    fused with the activation, then down) and no ``ragged-dot`` there; XLA's
+    grouped matmul and no call of the kernel otherwise. Returns the form."""
+    from mxnet_tpu.ops.pallas_grouped_matmul import moe_form
+
+    struct = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    form = moe_form(struct(tokens * k, d), struct(experts, d, f),
+                    struct(experts, f, d))
+    calls = [line for line in hlo.splitlines() if " custom-call(" in line
+             and 'custom_call_target="tpu_custom_call"' in line
+             and "/grouped_matmul" in line]
+    ragged = "ragged" in hlo.lower()    # XLA's form, by its name
+    if form == "kernel":
+        assert len(calls) == 2 * layers and not ragged
+        assert sum("/grouped_matmul_gated/" in line for line in calls) \
+            == layers
+    else:
+        assert not calls and ragged
+    return form
+
+
+# cell, program -> (tokens, experts a token, routed experts, held experts, D,
+# F) and what the rules answer there: the form, and the tiles (row tile,
+# column tile of gate and up, column tile of down)
+_EXPERT_LAYERS = {
+    ("olmoe-1b-7b.score", "prefill"): ((2048, 8, 64, 64, 2048, 1024),
+                                       "kernel", (128, 1024, 2048)),
+    ("olmoe-1b-7b.score", "decode"): ((8, 8, 64, 64, 2048, 1024),
+                                      "kernel", (32, 1024, 2048)),
+    ("kanana-2-30b-a3b.generate", "prefill"): (
+        (1024, 6, 128, 128, 2048, 768), "kernel", (128, 768, 2048)),
+    ("kanana-2-30b-a3b.generate", "decode"): (
+        (32, 6, 128, 128, 2048, 768), "kernel", (32, 768, 2048)),
+    ("lfm2-24b-a2b.generate", "prefill"): (
+        (1024, 4, 64, 64, 2048, 1536), "kernel", (128, 1536, 2048)),
+    ("lfm2-24b-a2b.generate", "decode"): (
+        (64, 4, 64, 64, 2048, 1536), "kernel", (32, 1536, 2048)),
+    ("mimo-v2-flash.generate", "prefill"): (
+        (2048, 8, 256, 16, 4096, 2048), "kernel", (128, 1024, 4096)),
+    ("mimo-v2-flash.generate", "decode"): (
+        (32, 8, 256, 16, 4096, 2048), "kernel", (32, 1024, 4096)),
+}
+
+
+@pytest.mark.parametrize("cell,program", list(_EXPERT_LAYERS))
+def test_the_expert_rules_at_the_cells_shapes(cell, program):
+    """What ``moe_form`` and ``tiles`` answer at the four cells' admissions
+    and steps (the widths as published, the benchmark's lanes and buckets),
+    pinned: a change of either rule changes eight programs of the benchmark,
+    and says so here first. Plain Python: no chip, no compile."""
+    from mxnet_tpu.ops import pallas_grouped_matmul as kernel
+
+    (tokens, k, routed, held, d, f), form, tiles = _EXPERT_LAYERS[cell,
+                                                                  program]
+    struct = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    assert kernel.moe_form(struct(tokens * k, d), struct(held, d, f),
+                           struct(held, f, d)) == form
+    assert kernel.tiles(tokens * k * held // routed, held, d, f,
+                        jnp.bfloat16) == tiles
+
+
 def _assert_no_pool_sized_copy(hlo, pool):
     """No ``copy`` or ``transpose`` in the program makes ``pool`` elements or
     more: no buffer of a pool's size is re-laid."""
@@ -310,9 +375,10 @@ def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
     v5e at OLMoE-1B-7B's published widths with one layer and the benchmark's
     serving sizes (8 lanes x 2,048 slots, bfloat16 weights and pool, float32
     ids, positions, write slots and page tables): a shape or layout XLA:TPU refuses
-    is found here, without a chip. The experts stay XLA's grouped matmul
-    (no per-expert dense expansion: the compiler's FLOP count is the sparse
-    one), and the decode step hands the pool back in the type it came in."""
+    is found here, without a chip. The expert layer runs the form its rule
+    names (the kernel's two calls since PR 41; no per-expert dense expansion:
+    the FLOP count, the kernel's cost estimate in it, is the sparse one),
+    and the decode step hands the pool back in the type it came in."""
     from mxnet_tpu.models import transformer as tf
 
     lanes, max_len = 8, 2048
@@ -333,7 +399,9 @@ def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
     compiled = _compile_program(
         v5e, sym, {**weights, **inputs},
         donated=[n for n in inputs if n.startswith("kv_")])
-    assert "ragged" in compiled.as_text().lower()
+    _assert_expert_layers(compiled.as_text(), 1,
+                          max_len if program == "prefill" else lanes, 8, 64,
+                          2048, 1024)
     flops = compiled.cost_analysis()["flops"]
     if program == "prefill":
         # 2 x 2,048 tokens x (67.2 M projections, router and 8 experts +
@@ -436,8 +504,9 @@ def test_latent_attention_serving_programs_compile_for_the_chip(v5e, program):
     65,536 float32 would be 268 MB and 155 GFLOP a layer): it gathers each
     lane's 128 frames (an 84 MB copy, in bounds by promise, so no ``select``
     passes over it) and scores 2,048 slots a lane, and builds no (32,
-    65,536) mask, which none of its reads looks at. Both programs keep the
-    grouped matmul and report the experts' load last."""
+    65,536) mask, which none of its reads looks at. Both programs run their
+    expert layers in the form the operator's rule names and report the
+    experts' load last."""
     from mxnet_tpu.models import transformer as tf
     from mxnet_tpu.ops.attention import pool_shape
 
@@ -475,7 +544,9 @@ def test_latent_attention_serving_programs_compile_for_the_chip(v5e, program):
         donated=[name for name, _, _ in cache] if program == "decode" else ())
     assert [(s.shape, str(s.dtype)) for s in compiled.out_info[0]] == want
     hlo = compiled.as_text()
-    assert "ragged" in hlo.lower()
+    _assert_expert_layers(hlo, layers - 1,
+                          bucket if program == "prefill" else lanes, 6, 128,
+                          2048, 768)
     mem = compiled.memory_analysis()
     if program == "decode":
         # every latent pool is updated in place
@@ -595,7 +666,8 @@ def test_lfm2_moe_serving_programs_compile_for_the_chip(v5e, program):
         donated=[name for name, _, _ in cache] if program == "decode" else ())
     assert [(s.shape, str(s.dtype)) for s in compiled.out_info[0]] == want
     hlo = compiled.as_text()
-    assert "ragged" in hlo.lower()
+    _assert_expert_layers(hlo, 8, bucket if program == "prefill" else lanes,
+                          4, 64, 2048, 1536)
     for i in (0, 1, 3, 4, 5, 7, 8, 9):
         assert "layer%d_conv_core/" % i in hlo
     mem = compiled.memory_analysis()
@@ -709,7 +781,8 @@ def test_mimo_v2_flash_serving_programs_compile_for_the_chip(v5e, program):
         donated=[name for name, _, _ in cache] if program == "decode" else ())
     assert [(s.shape, str(s.dtype)) for s in compiled.out_info[0]] == want
     hlo = compiled.as_text()
-    assert "ragged" in hlo.lower()
+    _assert_expert_layers(hlo, 6, bucket if program == "prefill" else lanes,
+                          8, 16, 4096, 2048)
     mem = compiled.memory_analysis()
     found = [(math.prod(int(d) for d in dims.split(",") if d), op)
              for _n, dims, op, _a in _INSTRUCTION.findall(hlo)
