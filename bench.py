@@ -122,50 +122,13 @@ def _place(trainer, name, arr):
         trainer.rules.batch_spec(arr.shape)))
 
 
-def _fused_report(batch, image, dtype):
-    """Engage status of the fused conv+BN stack at the bench's shapes,
-    forward AND backward, plus the analytic per-step HBM byte model
-    (docs/PERF.md §6/§6b). Pure gate/policy queries — no device work — so
-    the report always reflects exactly what the timed step could engage
-    under the ambient MXNET_FUSED_CONV_BN[_BWD] env and committed WINS
-    table."""
-    import jax.numpy as jnp
-
-    from mxnet_tpu import fusion
-    from mxnet_tpu.ops.conv_bn_bytes import resnet50_sites, step_byte_model
-
-    dt = jnp.dtype(dtype)
-    rep = {"sites": 0, "fwd_engaged": 0, "bwd_engaged": 0, "bwd_modes": {}}
-    for kernel, stride, K, N, H, count, res_count in resnet50_sites(
-            image=image):
-        x_shape = (batch, K, H, H)
-        w_shape = (N, K) + kernel
-        for res_flag, cnt in ((False, count - res_count),
-                              (True, res_count)):
-            if not cnt:
-                continue
-            rep["sites"] += cnt
-            if not fusion.gate(kernel, stride, x_shape, w_shape, dt, True,
-                               res=res_flag):
-                continue
-            rep["fwd_engaged"] += cnt
-            mode = fusion.bwd_mode(kernel, stride, x_shape, w_shape, dt,
-                                   True, res=res_flag)
-            if mode != "xla":
-                rep["bwd_engaged"] += cnt
-            rep["bwd_modes"][mode] = rep["bwd_modes"].get(mode, 0) + cnt
-    rep["byte_model_gb"] = step_byte_model(batch, image=image,
-                                           itemsize=dt.itemsize)
-    return rep
-
-
 _RESNET_BATCH, _RESNET_IMAGE, _RESNET_STEPS = 256, 224, 10
 
 
 def _resnet50_img_s(models, parallel, dev):
     """img/s of the bf16 ResNet-50 step at the ONE stated batch (a batch
     that does not fit is a failure of the leg, not a reason to try a
-    smaller one), under the ambient MXNET_FUSED_CONV_BN."""
+    smaller one)."""
     import jax.numpy as jnp
 
     from mxnet_tpu import telemetry
@@ -204,24 +167,7 @@ def _bench_resnet50(models, parallel, dev, peak):
            "step_ms": 1000 * batch / img_s,
            "flops_per_img": _TRAIN_FLOPS_PER_IMG}
     res.update(_mfu_fields(_TRAIN_FLOPS_PER_IMG * batch, batch / img_s, peak))
-    res["fused_conv_bn"] = _fused_report(batch, image, "bfloat16")
     return res
-
-
-def _bench_resnet50_fused(models, parallel, dev):
-    """The same step with the fused conv+BN Pallas path forced
-    (docs/PERF.md §6): the WINS table is empty, so ``auto`` never engages
-    it and only forcing measures it. Reported beside the headline number,
-    never instead of it."""
-    prev_env = os.environ.get("MXNET_FUSED_CONV_BN")
-    os.environ["MXNET_FUSED_CONV_BN"] = "1"
-    try:
-        return {"img_s": _resnet50_img_s(models, parallel, dev)}
-    finally:
-        if prev_env is None:
-            os.environ.pop("MXNET_FUSED_CONV_BN", None)
-        else:
-            os.environ["MXNET_FUSED_CONV_BN"] = prev_env
 
 
 def _bench_lstm(models, parallel, dev, peak):
@@ -1021,16 +967,13 @@ def main_inproc():
     legs = {}
     _leg(legs, "resnet50", lambda: _bench_resnet50(models, parallel, dev,
                                                    peak))
-    if os.environ.get("MXNET_FUSED_CONV_BN", "auto") == "auto":
-        _leg(legs, "resnet50_fused",
-             lambda: _bench_resnet50_fused(models, parallel, dev))
     _leg(legs, "lstm", lambda: _bench_lstm(models, parallel, dev, peak))
     _leg(legs, "input_pipeline", lambda: _bench_input_pipeline(peak))
     _leg(legs, "autoplan", _bench_autoplan)
     _leg(legs, "recommender",
          lambda: _bench_recommender(models, parallel, dev, peak))
     # MXNET_TELEMETRY=counters|trace: the registry's view of the same run —
-    # retraces, fused engage counts, kv bytes/step — next to the wall time
+    # retraces, pattern engage counts, kv bytes/step — next to the wall time
     # (docs/OBSERVABILITY.md). Off by default.
     if telemetry.enabled():
         _leg(legs, "telemetry", telemetry.summarize)
@@ -1075,15 +1018,7 @@ def main():
             "vs_baseline": round(rn["img_s"] / BASELINE_IMG_S, 3),
             "batch": rn["batch"], "image_size": rn["image"],
             "step_ms": round(rn["step_ms"], 2), "mfu": rn["mfu"],
-            "fused_conv_bn": rn["fused_conv_bn"],
-            # the headline flag the scoreboard reads: did the BACKWARD
-            # fused path have an engage route this run (docs/PERF.md §6b)
-            "fused_bwd_engaged": bool(rn["fused_conv_bn"]["bwd_engaged"]),
         })
-        fused = legs.get("resnet50_fused", {})
-        if "img_s" in fused:
-            result["fused_img_s"] = round(fused["img_s"], 2)
-            result["fused_faster"] = bool(fused["img_s"] > rn["img_s"])
     else:
         result.update({"value": None, "vs_baseline": None,
                        "error": rn["error"]})

@@ -20,9 +20,9 @@ a seed), and checks what comes out by the repo's own means:
              read operators at the benchmark's 64 lanes x 65,536 slots (the
              written pool bit for bit, the kernel's read against the whole
              pool's at the highest precision)
-  4 kernels  every Pallas kernel a gate or pattern can reach, compiled by
-             Mosaic, forward and backward, against its XLA reference; then a
-             ResNet-50 step and a transformer step with the kernels forced
+  4 kernels  every Pallas kernel a pattern can reach, compiled by Mosaic,
+             forward and backward, against its XLA reference; then a
+             transformer step with the kernels forced
   5 4 chips  (when >= 4 devices) phase 1 on a {"data": 4} mesh, Module on
              four contexts, ring attention on {"data": 2, "seq": 2}
 
@@ -80,9 +80,6 @@ if not REHEARSE:
         tf_train_batch=8, tf_train_seq=512,
         pool=(64, 8, 64 * 1024, 64),
         attn=(8, 8, 512, 64), mba=(4096, 512, 2048), ln=(4096, 512),
-        conv_batch=256,
-        conv_sites=[((1, 1), (1, 1), 128, 512, 28, True),
-                    ((3, 3), (1, 1), 128, 128, 28, False)],
         matmul_n=8192,
     )
 else:
@@ -96,9 +93,6 @@ else:
         tf_train_batch=2, tf_train_seq=16,
         pool=(4, 2, 64, 64),
         attn=(1, 2, 16, 8), mba=(16, 16, 128), ln=(16, 128),
-        conv_batch=2,
-        conv_sites=[((1, 1), (1, 1), 8, 16, 8, True),
-                    ((3, 3), (1, 1), 8, 8, 8, False)],
         matmul_n=256,
     )
 
@@ -384,7 +378,7 @@ def run_trainer_steps(trainer, x, y, batch, timed=5):
     return first, step_s
 
 
-FIRST_STEP = {}  # phase 1's first-step outputs, for phases 4 and 5
+FIRST_STEP = {}  # phase 1's first-step outputs, for phase 5
 
 
 def phase_train():
@@ -815,13 +809,12 @@ def kernel_case(name, kernel, reference, args, tol_fwd, tol_bwd, seed=0):
 
 def phase_kernels():
     from mxnet_tpu.ops import pallas_attention as pa
-    from mxnet_tpu.ops import pallas_conv_bn as pc
     from mxnet_tpu.ops import pallas_matmul_bias_act as pm
     from mxnet_tpu.ops import pallas_norm_residual as pn
 
     interp = REHEARSE
     if not REHEARSE:
-        check(not any(m._interpret_mode() for m in (pc, pm, pn)),
+        check(not any(m._interpret_mode() for m in (pm, pn)),
               "the kernels pick Mosaic, not interpret mode, on this backend")
     rs = np.random.RandomState(4)
     bf = jnp.bfloat16
@@ -874,79 +867,10 @@ def phase_kernels():
                                                       interpret=interp),
                 ln_ref, (x, g, be), 1e-5, 1e-4)
 
-    cb = SZ["conv_batch"]
-    for kernel, stride, K, N, H, res in SZ["conv_sites"]:
-        tag = "k%d s%d %d->%d at %d²%s, batch %d bf16" % (
-            kernel[0], stride[0], K, N, H, " +skip" if res else "", cb)
-        say("  -- pallas_conv_bn.conv_block, %s" % tag)
-        xs, ws = (cb, K, H, H), (N, K) + kernel
-        check(pc.supported(xs, ws, stride, 2, True, res),
-              "the forward planner accepts the shape")
-        x = arr(xs)
-        w = arr(ws, scale=0.05)
-        scale = jnp.asarray(rs.uniform(0.5, 1.5, (K,)), jnp.float32)
-        shift = jnp.asarray(rs.uniform(-0.2, 0.2, (K,)), jnp.float32)
-        Ho, Wo = pc.strided_dims(H, H, stride)
-        r = arr((cb, N, Ho, Wo)) if res else None
-
-        def ref(x, w, scale, shift, r=None):
-            c = pc._xla_conv(x, w, scale, shift, r, kernel, stride, True)
-            return (c,) + tuple(pc._stats_of(c))
-
-        for bwd in ("xla", "recompute", "stash"):
-            if bwd != "xla":
-                planned = pc.plan_bwd_blocks(xs, ws, stride, 2, True, res,
-                                             stash=(bwd == "stash"))
-                check(planned is not None,
-                      "the backward planner returns bk=%s for %s"
-                      % (planned, bwd))
-            kernel_case(
-                "conv_block[bwd=%s]" % bwd,
-                lambda x, w, scale, shift, r=None, _b=bwd: pc.conv_block(
-                    x, w, scale, shift, r, kernel, stride, True, True, _b,
-                    None),
-                ref, (x, w, scale, shift, r), 2e-2, 6e-2)
-        if not res:
-            got = jax.jit(lambda *a: pc.conv_block_infer(
-                *a, kernel, stride, True))(x, w, scale, shift)
-            with jax.default_matmul_precision("highest"):
-                want = jax.jit(ref)(x, w, scale, shift)[0]
-            compare("conv_block_infer forward", got, want, 2e-2)
-        del x, w, r
-        gc.collect()
-
-    say("  -- ResNet-50 training step with the conv+BN kernels forced "
-        "(forward, and backward by recompute)")
-    telemetry.set_mode("counters")
-    with environ(MXNET_FUSED_CONV_BN="1", MXNET_FUSED_CONV_BN_BWD="recompute"):
-        c0 = telemetry.counters()
-        batch = SZ["train_batch"]
-        mesh = parallel.make_mesh((1,), axis_names=("data",), devices=[DEV])
-        trainer = make_trainer(resnet50(), mesh, batch)
-        x, y = train_batch(trainer, batch)
-        first, step_s = run_trainer_steps(trainer, x, y, batch, timed=2)
-        ran = counters_since(c0)
-        say("    fusion counters: %s" % {k: v for k, v in sorted(ran.items())
-                                         if k.startswith("fusion.")})
-        check(ran.get("fusion.fwd_engaged", 0) > 0,
-              "fusion.fwd_engaged = %d" % ran.get("fusion.fwd_engaged", 0))
-        check(ran.get("fusion.bwd_engaged", 0) > 0,
-              "fusion.bwd_engaged = %d (the planner sends %d site(s) to the "
-              "XLA backward)" % (ran.get("fusion.bwd_engaged", 0),
-                                 ran.get("fusion.bwd_xla", 0)))
-        check(not ran.get("fusion.tune_error"), "no tuner/candidate error")
-        if "probs" in FIRST_STEP:
-            # same seed, same batch as phase 1: the fused step's first
-            # outputs must tell the same story as the XLA step's
-            compare("first-step outputs vs the unfused step of phase 1",
-                    first, FIRST_STEP["probs"], 5e-2)
-        say("    info: %.1f ms/step with every gate-accepted site forced "
-            "(information, not a claim)" % (step_s * 1e3))
-        del trainer, x, y
-    gc.collect()
-
     say("  -- Transformer-base training step with every pattern forced")
     from mxnet_tpu.models import transformer as tfm
+
+    telemetry.set_mode("counters")
 
     B, T = SZ["tf_train_batch"], SZ["tf_train_seq"]
     net = tfm.get_symbol(seq_len=T, **SZ["tf"])
@@ -1105,7 +1029,7 @@ PHASES = [
     ("train: SPMDTrainer, ResNet-50", phase_train),
     ("fit: Module.fit, ResNet-50", phase_fit),
     ("serve: PagedKVDecoder, Transformer-base", phase_serve),
-    ("kernels: Mosaic vs XLA, forced steps", phase_kernels),
+    ("kernels: Mosaic vs XLA, a forced step", phase_kernels),
     ("four chips", phase_four_chips),
 ]
 

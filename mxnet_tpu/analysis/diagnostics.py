@@ -80,10 +80,6 @@ CODES = {
     "GL203": (Severity.INFO,
               "shape-polymorphic inputs: compile-cache cardinality grows per shape"),
     # --- fusion explainer --------------------------------------------------
-    "GL301": (Severity.INFO,
-              "convolution rejected by the conv+BN fusion planner"),
-    "GL302": (Severity.INFO,
-              "BatchNorm not folded into its consumers by the fusion planner"),
     "GL303": (Severity.INFO,
               "generic fusion-pattern site inventory / near-miss rejection"),
     # --- sharding-plan lint ------------------------------------------------

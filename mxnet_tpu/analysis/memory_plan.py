@@ -11,9 +11,9 @@ Symbol DAG alone, per device under the sharding plan:
   * activation bytes counted per entry under ``ctx.entry_spec`` (the
     GL4xx propagation) — a dp=8 plan holds 1/8th of every batch-sharded
     activation per device,
-  * a stash-vs-recompute toggle in the ``ops/conv_bn_bytes.py`` accounting
-    style: ``stash`` keeps every op output across the fwd→bwd transition
-    (the no-remat executor default); ``recompute`` keeps only MXU-op
+  * a stash-vs-recompute toggle: ``stash`` keeps every op output across
+    the fwd→bwd transition (the no-remat executor default);
+    ``recompute`` keeps only MXU-op
     outputs (conv/FC/dot/embedding — the ``remat='dots'`` policy) and
     charges the recomputed operands transiently during each backward node.
 

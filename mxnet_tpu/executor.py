@@ -107,7 +107,7 @@ class _GraphProgram:
     names the forward program for whoever owns it (``mx_decode``); without
     one the programs are ``mx_<head name>_fwd`` / ``_fwd_bwd``."""
 
-    def __init__(self, symbol, group2ctx=None, fusion=True):
+    def __init__(self, symbol, group2ctx=None):
         self.symbol = symbol
         self.label = None  # set by the owner before the first call
         # arguments the forward program takes DONATED, by name (likewise):
@@ -123,44 +123,23 @@ class _GraphProgram:
         self.store = None
         self.topo = symbol._topo()
         self.group2ctx = dict(group2ctx or {})
-        # fusion plan (fusion.py): structural rewrite map covering the
-        # conv+BN Pallas stack AND the generic pattern engine (attention,
-        # matmul+bias+act, norm+residual, elementwise chains — each gated
-        # per shape by the fusion_tune measured verdict); disabled under
-        # ctx-group placement (a fused subgraph would straddle a device
-        # boundary). plan() itself honors the MXNET_FUSED_CONV_BN /
-        # MXNET_FUSED_PATTERNS kill-switches and returns {} when all off.
-        self._fusion_plan = {}
-        self._infer_fusion = False
-        if fusion and not self.group2ctx:
+        # fusion plan (fusion.py): the pattern engine's structural rewrite
+        # map (attention, matmul+bias+act, norm+residual, elementwise chains,
+        # each gated per shape by the fusion_tune measured verdict); none
+        # under ctx-group placement (a fused subgraph would straddle a
+        # device boundary). plan() honors MXNET_FUSED_PATTERNS and returns {}
+        # when every pattern is off. The plan's per-pattern site inventory is
+        # computed ONCE here: the serving cache, health probes and the
+        # graphlint --rewrite dump read it instead of re-walking the map
+        self._fusion_plan, self.pattern_sites = {}, {}
+        if not self.group2ctx:
             from . import fusion as _fusion
 
-            # graph-output node ids keep the planner from deferring (or
-            # folding) a node whose value must materialize as a program
-            # output — a deferred conv's PendingConv marker would otherwise
-            # escape interpret() into the jit output pytree (Group symbols)
+            # graph-output node ids keep a node whose value must materialize
+            # as a program output out of every pattern interior
             self._fusion_plan = _fusion.plan(
                 self.topo, output_ids={id(n) for n, _ in symbol._outputs})
-            # grad-less/inference executions additionally need the CONV+BN
-            # side of the plan declared ACTIVE for is_train=False
-            # (fusion.infer_default(): forced env, on-device WINS match, or
-            # a quantized variant) — the default keeps CPU eval numerics
-            # byte-identical to the unfused op-by-op lowering. Generic
-            # pattern directives stay live at inference (their fallback IS
-            # the unfused lowering; per-pattern inference gating happens in
-            # fusion.gate_pattern_explain).
-            self._infer_fusion = bool(self._fusion_plan) \
-                and _fusion.infer_default()
-        # the plan's per-pattern site inventory, computed ONCE here — the
-        # serving cache, health probes and the graphlint --rewrite dump all
-        # read this instead of re-walking the directive map per call
-        if self._fusion_plan:
-            from . import fusion as _fusion
-
-            self.pattern_sites, self.conv_bn_directives = \
-                _fusion.plan_sites(self._fusion_plan)
-        else:
-            self.pattern_sites, self.conv_bn_directives = {}, 0
+            self.pattern_sites = _fusion.plan_sites(self._fusion_plan)
         # PlaceDevice-pass analogue (reference: graph_executor.cc:242
         # AssignContext → nnvm PlaceDevice inserting _CrossDeviceCopy): map
         # each node carrying a __ctx_group__ attr to its concrete device;
@@ -267,13 +246,6 @@ class _GraphProgram:
             n_aux = len(opdef.aux_names(parsed))
             ins = [vals[(id(inp), oi)] for inp, oi in node.inputs]
             directive = self._fusion_plan.get(id(node)) if fusion_on else None
-            if (directive is not None and not is_train
-                    and not self._infer_fusion
-                    and directive["kind"] in _fusion.CONV_BN_KINDS):
-                # inference with the conv+BN plan INACTIVE: those nodes run
-                # the plain op-by-op lowering (byte-identical eval); generic
-                # pattern directives stay live
-                directive = None
             # trace-time only: every HLO instruction this node lowers to
             # carries the node's name in its op_name metadata, so a trace
             # viewer shows which layers a fusion.N holds
@@ -281,11 +253,7 @@ class _GraphProgram:
                 if directive is not None:
                     outs, aux_out = _fusion.execute(
                         directive, node,
-                        ins[: len(ins) - n_aux] if n_aux else ins,
-                        ins[len(ins) - n_aux :] if n_aux else [],
-                        is_train)
-                    if not isinstance(outs, tuple):
-                        outs = (outs,)
+                        ins[: len(ins) - n_aux] if n_aux else ins, is_train)
                 else:
                     if fusion_on:
                         ins = [_fusion.resolve(x) for x in ins]

@@ -1,11 +1,10 @@
 """Persistent measure-and-cache autotuning for the fusion pattern engine.
 
 TVM's thesis (PAPERS.md) applied to the pattern fuser: instead of a
-hand-curated, committed WINS table per kernel family, every (pattern, shape,
-dtype) site is MEASURED against its unfused baseline on first encounter —
-fused and baseline run as standalone jitted computations on synthetic
-inputs, forward and backward, exactly the PR 2 ``tools/fused_stats_bench.py``
-contract — and the verdict (engage or not, winning lowering, measured µs,
+hand-curated, committed table of wins per kernel family, every (pattern,
+shape, dtype) site is MEASURED against its unfused baseline on first
+encounter — fused and baseline run as standalone jitted computations on
+synthetic inputs, forward and backward — and the verdict (engage or not, winning lowering, measured µs,
 backward policy) is persisted to a per-device-kind JSON cache so every later
 run, in this process or any other, reuses it with zero re-tunes.
 
@@ -289,7 +288,7 @@ def _persist(kind, new_entries):
 # ------------------------------------------------------------------ lookups
 def peek(key):
     """The cached record for ``key`` (no telemetry, no measurement) — the
-    explain path (``gate_explain``/GL302) reads rejected verdicts here."""
+    explain path (``gate_pattern_explain``/GL303) reads rejected verdicts here."""
     if cache_dir() is None:
         return None
     kind = device_kind()
@@ -349,8 +348,8 @@ _ROUNDS = 3
 
 def _prepare(fn, operands, iters):
     """A timed runner for ``iters`` executions of ``fn(*operands)`` inside
-    one jitted scan (the fused_stats_bench discipline: the scan amortizes
-    dispatch, the scalar fetch is the device barrier). ``operands`` are jit
+    one jitted scan (the scan amortizes dispatch, the scalar fetch is the
+    device barrier). ``operands`` are jit
     ARGUMENTS, never closure constants — XLA would constant-fold (or
     loop-hoist) the entire measured computation otherwise. The scan carry
     feeds the first element of every output back into the next iteration's
@@ -414,7 +413,7 @@ def synth_like(args, seed=0):
 
 def _rel_err(a, b):
     """Max relative error over corresponding pytree leaves (an output may
-    be a tuple — e.g. conv_block's (c, Σc, Σc²))."""
+    be a tuple)."""
     import jax
     import jax.numpy as jnp
 

@@ -1,9 +1,8 @@
 """Declarative pattern registry for the subgraph fusion engine.
 
-The generalization of the conv+BN special case (fusion.py): each pattern is
-a matcher over the Symbol DAG plus one-or-more fused lowerings, gated per
-(shape, dtype) by the persistent measure-and-cache autotuner
-(``fusion_tune.py``) instead of a committed WINS table. The patterns here
+Each pattern of the fusion engine (fusion.py) is a matcher over the Symbol
+DAG plus one-or-more fused lowerings, gated per (shape, dtype) by the
+persistent measure-and-cache autotuner (``fusion_tune.py``). The patterns here
 cover exactly the chains "Operator Fusion in XLA" (PAPERS.md) names as the
 ones XLA leaves on the table over our Symbol DAG:
 

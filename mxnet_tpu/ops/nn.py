@@ -295,81 +295,33 @@ def _bn_outputs(attrs):
     return 3 if attrs.get("output_mean_var") else 1
 
 
-@functools.lru_cache(maxsize=None)
-def _bn_train_core(ndim, eps, fix_gamma):
-    """Hand-derived BN fwd/bwd as a custom_vjp.
+@jax.custom_vjp
+def _normalize(x, scale32, shift32):
+    """``x * scale + shift`` per channel (axis 1), in the activation's dtype:
+    the one elementwise pass of a training BatchNorm."""
+    b = (1, -1) + (1,) * (x.ndim - 2)
+    return x * scale32.astype(x.dtype).reshape(b) \
+        + shift32.astype(x.dtype).reshape(b)
 
-    Why not plain autodiff: differentiating through the fp32 stats view of the
-    activation makes XLA materialise fp32 cotangents of every BN input in the
-    backward pass — on a ResNet-50/224 b256 step that was ~28% of device time
-    in `multiply_reduce`/`add_any` fusions (see docs/PERF.md, round-4 profile).
-    Here every elementwise pass stays in the activation dtype (bf16 on the MXU
-    fast path) and fp32 appears only inside reduction accumulators — the
-    canonical memory-bound-TPU formulation. Math matches the reference's
-    batch_norm-inl.h Forward/Backward (biased batch variance, dgamma=0 under
-    fix_gamma).
-    """
-    axes = (0,) + tuple(range(2, ndim))
 
-    def stats(x):
-        # one fused pass: sum(x) and sum(x^2) with fp32 accumulators
-        cnt = 1
-        for a in axes:
-            cnt *= x.shape[a]
-        x32 = x.astype(jnp.float32)
-        mean = jnp.sum(x32, axis=axes) / cnt
-        var = jnp.sum(jnp.square(x32), axis=axes) / cnt - jnp.square(mean)
-        return mean, var
+def _normalize_fwd(x, scale32, shift32):
+    return _normalize(x, scale32, shift32), (x, scale32)
 
-    def fwd_impl(x, gamma, beta):
-        bshape = (1, -1) + (1,) * (ndim - 2)
-        mean, var = stats(x)
-        invstd = jax.lax.rsqrt(var + eps)
-        m = mean.astype(x.dtype)
-        istd = invstd.astype(x.dtype)
-        xhat = (x - m.reshape(bshape)) * istd.reshape(bshape)
-        if fix_gamma:
-            out = xhat + beta.reshape(bshape)
-        else:
-            out = xhat * gamma.reshape(bshape) + beta.reshape(bshape)
-        return out, mean, var, m, istd
 
-    @jax.custom_vjp
-    def bn(x, gamma, beta):
-        out, mean, var, _, _ = fwd_impl(x, gamma, beta)
-        return out, mean, var
+def _normalize_bwd(saved, dout):
+    # explicit f32 accumulators for the per-channel reductions (plain
+    # autodiff would reduce in the activation dtype: bf16 over B*H*W)
+    x, scale32 = saved
+    b = (1, -1) + (1,) * (x.ndim - 2)
+    axes = (0,) + tuple(range(2, x.ndim))
+    dx = dout * scale32.astype(dout.dtype).reshape(b)
+    dout32 = dout.astype(jnp.float32)
+    dscale = jnp.sum(dout32 * x.astype(jnp.float32), axis=axes)
+    dshift = jnp.sum(dout32, axis=axes)
+    return dx, dscale, dshift
 
-    def bn_fwd(x, gamma, beta):
-        out, mean, var, m, istd = fwd_impl(x, gamma, beta)
-        return (out, mean, var), (x, gamma, m, istd)
 
-    def bn_bwd(res, cts):
-        dy, ct_mean, ct_var = cts
-        x, gamma, m, istd = res
-        bshape = (1, -1) + (1,) * (ndim - 2)
-        cnt = 1
-        for a in axes:
-            cnt *= x.shape[a]
-        xhat = (x - m.reshape(bshape)) * istd.reshape(bshape)
-        # both reductions in one fused pass, fp32 accumulators
-        dbeta32 = jnp.sum(dy.astype(jnp.float32), axis=axes)
-        dgamma32 = jnp.sum((dy * xhat).astype(jnp.float32), axis=axes)
-        g_istd = (istd if fix_gamma else gamma * istd).astype(x.dtype)
-        c1 = (dbeta32 / cnt).astype(x.dtype)
-        c2 = (dgamma32 / cnt).astype(x.dtype)
-        dx = g_istd.reshape(bshape) * (dy - c1.reshape(bshape) - xhat * c2.reshape(bshape))
-        # graphs may differentiate through the mean/var heads too
-        # (output_mean_var=True): mean = Σx/n, var = Σx²/n − mean². The terms
-        # are per-channel scalars broadcast into the dx pass — they fuse, so
-        # the usual zero-cotangent case costs nothing extra in HBM traffic.
-        dx = dx + (ct_mean / cnt).astype(x.dtype).reshape(bshape)
-        cv = (2.0 * ct_var / cnt).astype(x.dtype).reshape(bshape)
-        dx = dx + cv * (x - m.reshape(bshape))
-        dgamma = (jnp.zeros_like(dgamma32) if fix_gamma else dgamma32).astype(gamma.dtype)
-        return dx, dgamma, dbeta32.astype(gamma.dtype)
-
-    bn.defvjp(bn_fwd, bn_bwd)
-    return bn
+_normalize.defvjp(_normalize_fwd, _normalize_bwd)
 
 
 @register(
@@ -397,10 +349,27 @@ def _batch_norm(attrs, inputs, aux, is_train=False):
     eps, momentum = attrs["eps"], attrs["momentum"]
     bshape = (1, -1) + (1,) * (data.ndim - 2)
     if is_train and not attrs["use_global_stats"]:
-        bn = _bn_train_core(data.ndim, float(eps), bool(attrs["fix_gamma"]))
-        out, mean, var = bn(data, gamma, beta)
-        new_mean = moving_mean * momentum + jax.lax.stop_gradient(mean) * (1 - momentum)
-        new_var = moving_var * momentum + jax.lax.stop_gradient(var) * (1 - momentum)
+        # batch moments from one pass of f32 sums; the per-channel scale and
+        # shift stay f32 and differentiate by plain autodiff (which is what
+        # gives the mean and var heads their cotangents); only _normalize,
+        # the pass over the activation, has a hand-written backward
+        axes = (0,) + tuple(range(2, data.ndim))
+        x32 = data.astype(jnp.float32)
+        ssum = jnp.sum(x32, axis=axes)
+        ssq = jnp.sum(x32 * x32, axis=axes)
+        cnt = data.size // data.shape[1]
+        mean = ssum / cnt
+        var = ssq / cnt - mean * mean
+        scale32 = jax.lax.rsqrt(var + eps)
+        if not attrs["fix_gamma"]:
+            scale32 = gamma.astype(jnp.float32) * scale32
+        shift32 = beta.astype(jnp.float32) - mean * scale32
+        sg = jax.lax.stop_gradient
+        new_mean = moving_mean * momentum \
+            + sg(mean).astype(moving_mean.dtype) * (1 - momentum)
+        new_var = moving_var * momentum \
+            + sg(var).astype(moving_var.dtype) * (1 - momentum)
+        out = _normalize(data, scale32, shift32)
         m, v = mean.astype(data.dtype), var.astype(data.dtype)
         outs = (out, m, v) if attrs["output_mean_var"] else (out,)
         return outs, (new_mean, new_var)

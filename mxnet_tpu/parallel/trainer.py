@@ -74,11 +74,6 @@ class SPMDTrainer:
         self.symbol = symbol
         self.mesh = mesh
         self.rules = rules or ShardingRules(mesh)
-        # conv+BN Pallas fusion: single-device meshes run the kernel
-        # directly; pure-dp meshes run it per-shard under shard_map with
-        # psum'd statistics (fusion._conv_block_sharded — a pallas_call has
-        # no GSPMD partitioning rule of its own); tensor/seq-sharded meshes
-        # fall back to the XLA lowering at trace time
         self._prog = _GraphProgram(symbol)
         self._remat = remat
         self._compute_dtype = np.dtype(compute_dtype) if compute_dtype else None

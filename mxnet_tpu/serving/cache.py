@@ -244,19 +244,13 @@ class _StoredProgram:
 
 
 def _plan_pattern_sites(exe):
-    """Static summary of one bound executor's fusion plan: generic-pattern
-    site counts, conv+BN directive count, and whether the conv+BN plan is
-    ACTIVE at inference — what a serving operator needs to know about the
-    fusion surface of a warmed bucket (per-site engage decisions land on
-    the ``fusion.pattern_*`` counters and trace events). Reads the
-    inventory the program computed once at plan time
-    (``_GraphProgram.pattern_sites``) — never re-walks the directive map."""
-    try:
-        return {"pattern_sites": dict(exe._prog.pattern_sites),
-                "conv_bn_directives": exe._prog.conv_bn_directives,
-                "conv_bn_infer_active": bool(exe._prog._infer_fusion)}
-    except Exception:  # observability must never sink a warmup
-        return {}
+    """One bound executor's fusion plan as pattern name -> sites: what a
+    serving operator needs to know about the fusion surface of a warmed
+    bucket (per-site engage decisions land on the ``fusion.pattern_*``
+    counters and trace events). Reads the inventory the program computed
+    once at plan time (``_GraphProgram.pattern_sites``) — never re-walks the
+    directive map."""
+    return dict(exe._prog.pattern_sites)
 
 
 class PersistentExecutableCache:
@@ -303,10 +297,9 @@ class PersistentExecutableCache:
         # evicts. None/0 = unbounded.
         self._max_exes = int(max_executables or 0) or None
         self._exes: "OrderedDict[tuple, object]" = OrderedDict()
-        # per-bucket fusion pattern-site summary (filled at compile time):
-        # which patterns the plan rooted in this model's graph, per-pattern
-        # site counts, and whether the conv+BN inference plan is active —
-        # the serving-side observability of the inference-mode gates.
+        # per-bucket fusion pattern-site counts (filled at compile time):
+        # which patterns the plan rooted in this model's graph, and how
+        # many sites each.
         # Guarded by its OWN lock: health() reads it, and the main _lock is
         # held for the full duration of a warmup compile (+ autotune) — a
         # liveness probe must never block on a compile.

@@ -34,8 +34,6 @@ _STEP_COLS = [
     ("compile", "executor.compile"),
     ("hit", "executor.cache_hit"),
     ("retrace", "executor.retrace"),
-    ("fused", "fusion.fwd_engaged"),
-    ("fallbk", "fusion.fwd_fallback"),
     ("kv_B", "kvstore.push_bytes"),
     ("io", "io.batches"),
     ("push", "engine.push"),

@@ -269,8 +269,6 @@ def merge_traces(dumps, offsets_s=None, labels=None):
 
 # counters the scoreboard cares about, reported per step when steps exist
 _KEY_COUNTERS = ("executor.retrace", "executor.compile", "executor.cache_hit",
-                 "fusion.fwd_engaged", "fusion.fwd_fallback",
-                 "fusion.bwd_engaged",
                  "kvstore.push_bytes", "kvstore.pull_bytes",
                  "engine.push")
 

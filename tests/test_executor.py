@@ -317,3 +317,41 @@ def test_program_label_names_the_forward_program():
     prog.label = "mx_decode"
     assert prog.program_name("fwd") == "mx_decode"
     assert prog._fwd(False).__name__ == "mx_decode"
+
+
+def test_every_node_is_computed_by_its_registered_operator(monkeypatch):
+    """ResNet-50's training interpretation applies the registry's operator
+    at every node: the graph layer holds no second lowering of a
+    Convolution, a BatchNorm, an Activation or an add."""
+    import collections
+
+    import jax
+
+    from mxnet_tpu import fusion, models
+    from mxnet_tpu.executor import _GraphProgram
+    from mxnet_tpu.ops.registry import OpDef
+
+    applied = collections.Counter()
+    apply = OpDef.apply
+
+    def counting(self, *args, **kwargs):
+        applied[self.name] += 1
+        return apply(self, *args, **kwargs)
+
+    monkeypatch.setattr(OpDef, "apply", counting)
+    net = models.get_symbol("resnet-50", num_classes=1000,
+                            image_shape="3,224,224")
+    prog = _GraphProgram(net)
+    arg_shapes, _, aux_shapes = net.infer_shape(data=(2, 3, 224, 224),
+                                                softmax_label=(2,))
+    applied.clear()  # shape inference applies operators too
+    spec = lambda shapes: tuple(jax.ShapeDtypeStruct(s, "float32")
+                                for s in shapes)
+    jax.eval_shape(lambda a, x, k: prog.interpret(a, x, True, k),
+                   spec(arg_shapes), spec(aux_shapes), jax.random.PRNGKey(0))
+    ops = collections.Counter(n.op for n in prog.topo if not n.is_variable)
+    assert applied == ops
+    assert (ops["Convolution"], ops["BatchNorm"], ops["Activation"],
+            ops["elemwise_add"]) == (53, 50, 50, 16)
+    for name in ("_exec_bn", "_exec_conv", "Deferred"):
+        assert not hasattr(fusion, name), name

@@ -2,7 +2,7 @@
 cold tune → persist → warm hits with zero re-tunes; corrupt or
 digest-mismatched cache files are ignored with a warning, never a crash;
 tuned-and-rejected verdicts surface their measured timings through the
-gate reasons (the GL302/GL303 explain contract)."""
+gate reasons (the GL303 explain contract)."""
 import json
 import os
 
@@ -128,35 +128,16 @@ def test_tuned_and_rejected_reason_reports_measured_timings(monkeypatch):
     the measured fused-vs-baseline µs from the cache, not a bare 'no
     verdict'."""
     # seed a rejection record directly through the verdict path
-    key = "conv_bn|k1s1p|float32(2, 8, 8, 8);(16, 8, 1, 1)"
+    key = "matmul_bias_act|relu|float32(256, 32);(256, 32);(256,)"
     rec = {"engage": False, "engage_fwd": False, "lowering": None,
            "base_fwd_us": 100.0, "base_bwd_us": 200.0,
-           "measured": {"pallas:xla": {"fwd_us": 400.0, "bwd_us": 500.0,
-                                       "rel_err": 0.0}}}
+           "measured": {"pallas": {"fwd_us": 400.0, "bwd_us": 500.0,
+                                   "rel_err": 0.0}}}
     got = fusion_tune.verdict(key, lambda: rec)
     assert got["engage"] is False
     note = fusion.tuned_reject_note(got)
     assert "tuned and rejected" in note
     assert "900" in note and "300" in note  # fused vs baseline fwd+bwd µs
-
-
-def test_conv_bn_gate_explain_quotes_tuned_timings(monkeypatch):
-    """fusion.gate_explain for a conv+BN shape with a cached rejection
-    must quote the measured timings (the GL302 feed)."""
-    kernel, stride = (1, 1), (1, 1)
-    x_shape, w_shape = (2, 8, 8, 8), (16, 8, 1, 1)
-    key = fusion._conv_bn_key(kernel, stride, x_shape, w_shape,
-                              np.float32, False)
-    rec = {"engage": False, "engage_fwd": False, "lowering": None,
-           "base_fwd_us": 50.0, "base_bwd_us": 70.0,
-           "measured": {"pallas:xla": {"fwd_us": 300.0, "bwd_us": 400.0,
-                                       "rel_err": 0.0}}}
-    assert fusion_tune.verdict(key, lambda: rec) is rec
-    monkeypatch.setenv("MXNET_FUSED_CONV_BN", "auto")
-    engaged, reason = fusion.gate_explain(kernel, stride, x_shape, w_shape,
-                                          np.float32, prologue=True)
-    assert engaged is False
-    assert "tuned and rejected" in reason and "µs" in reason
 
 
 def test_measure_candidates_rejects_parity_violations():

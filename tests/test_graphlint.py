@@ -138,35 +138,6 @@ def _gl203_clean():
         {"shapes": {"data": (2, 8)}}
 
 
-def _fusable_chain(kernel=(3, 3), pad=(1, 1), no_bias=True, name="c"):
-    d = mx.sym.Variable("data")
-    bn = mx.sym.BatchNorm(data=d, fix_gamma=False, name=name + "_bn")
-    act = mx.sym.Activation(data=bn, act_type="relu", name=name + "_relu")
-    return mx.sym.Convolution(data=act, num_filter=8, kernel=kernel, pad=pad,
-                              no_bias=no_bias, name=name + "_conv")
-
-
-def _gl301_broken():
-    # bias present -> the planner's first predicate fails
-    return _fusable_chain(no_bias=False, name="biased"), {}
-
-
-def _gl301_clean():
-    return _fusable_chain(name="fusable"), {}
-
-
-def _gl302_broken():
-    # BN feeding a pooling layer: eligible BN, but nothing to fold into
-    d = mx.sym.Variable("data")
-    bn = mx.sym.BatchNorm(data=d, fix_gamma=False, name="pool_bn")
-    return mx.sym.Pooling(data=bn, kernel=(2, 2), pool_type="max",
-                          name="pool"), {}
-
-
-def _gl302_clean():
-    return _fusable_chain(name="folded"), {}
-
-
 # --- GL4xx: sharding-plan lint (mesh/rules kwargs ride through lint()) -----
 def _gl401_broken():
     # weight (999, 783): both dims odd, prod >= min_shard_elems -> the rule
@@ -303,8 +274,6 @@ GRAPH_CODE_CASES = {
     "GL201": (_gl201_broken, _gl201_clean),
     "GL202": (_gl202_broken, _gl202_clean),
     "GL203": (_gl203_broken, _gl203_clean),
-    "GL301": (_gl301_broken, _gl301_clean),
-    "GL302": (_gl302_broken, _gl302_clean),
     "GL303": (_gl303_broken, _gl303_clean),
     "GL401": (_gl401_broken, _gl401_clean),
     "GL402": (_gl402_broken, _gl402_clean),

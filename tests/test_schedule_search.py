@@ -2,8 +2,7 @@
 both-direction version handling (v1 binary verdicts load and serve with
 zero re-tunes; unknown future versions are cleanly invalidated with one
 warning — never a crash, never a silent stale winner), schedule-annotated
-records, the bounded per-kernel schedule spaces, and the measured-stripe
-override threading into the conv kernel."""
+records, and the bounded per-kernel schedule spaces."""
 import json
 import os
 
@@ -181,52 +180,6 @@ def test_norm_residual_block_candidates():
     assert cands and cands[0] == max(cands)  # largest = planner default
     assert all(256 % br == 0 or 256 // br for br in cands)
     assert pn.block_candidates((4, 64, 100)) == []  # D not lane-aligned
-
-
-def test_conv_bn_candidates_and_stripe_override_parity():
-    """bn_candidates enumerates every tiling (default first) and the
-    conv_block bn override computes the same numbers as the planner
-    default — a schedule changes the grid, never the math."""
-    import jax.numpy as jnp
-
-    from mxnet_tpu.ops.pallas_conv_bn import bn_candidates, conv_block
-
-    rs = np.random.RandomState(0)
-    x = jnp.asarray(rs.randn(2, 8, 8, 8).astype("f"))
-    w = jnp.asarray(rs.randn(16, 8, 1, 1).astype("f") * 0.1)
-    scale = jnp.asarray(rs.uniform(0.5, 1.5, (8,)).astype("f"))
-    shift = jnp.asarray(rs.uniform(-0.2, 0.2, (8,)).astype("f"))
-    cands = bn_candidates(2, 8, 16, 64, 4, taps=1, prologue=True)
-    assert cands[0] == 16 and 8 in cands
-    ref = conv_block(x, w, scale, shift, None, (1, 1), (1, 1), True, True,
-                     "xla")
-    got = conv_block(x, w, scale, shift, None, (1, 1), (1, 1), True, True,
-                     "xla", 8)
-    for a, b in zip(ref, got):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=0, atol=1e-5)
-    # an INVALID override silently demotes to the planner pick
-    bad = conv_block(x, w, scale, shift, None, (1, 1), (1, 1), True, True,
-                     "xla", 3)
-    for a, b in zip(ref, bad):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_conv_schedule_reads_tuned_stripe():
-    kernel, stride = (1, 1), (1, 1)
-    x_shape, w_shape = (2, 8, 8, 8), (16, 8, 1, 1)
-    key = fusion._conv_bn_key(kernel, stride, x_shape, w_shape,
-                              np.float32, False)
-    fusion_tune.verdict(key, lambda: {
-        "engage": True, "lowering": "pallas:recompute@bn=8",
-        "measured": {"pallas:recompute@bn=8": {"fwd_us": 1.0}}})
-    assert fusion.conv_schedule(kernel, stride, x_shape, w_shape,
-                                np.float32, False) == 8
-    # and bwd_mode still parses the policy through the @-suffix
-    import jax.numpy as jnp
-
-    assert fusion.bwd_mode(kernel, stride, x_shape, w_shape, jnp.float32,
-                           True) in ("recompute", "xla")
 
 
 # -------------------------------------------------- cold-tune integration

@@ -404,118 +404,6 @@ def test_predictor_reshape_lru_bounded(tm, tmp_path, monkeypatch):
     assert tm.counters().get("executor.compile", 0) > c
 
 
-# ------------------------------------------- fusion inference mode + quant
-def _conv_bn_net():
-    s = mx.sym.Variable("data")
-    s = mx.sym.BatchNorm(s, name="bn0", fix_gamma=False)
-    s = mx.sym.Activation(s, act_type="relu")
-    s = mx.sym.Convolution(s, kernel=(3, 3), pad=(1, 1), num_filter=8,
-                           no_bias=True, name="conv1")
-    s = mx.sym.BatchNorm(s, name="bn1", fix_gamma=False)
-    s = mx.sym.Activation(s, act_type="relu")
-    s = mx.sym.Flatten(s)
-    s = mx.sym.FullyConnected(s, num_hidden=4, name="fc")
-    return mx.sym.SoftmaxOutput(s, name="softmax")
-
-
-def _infer_forward(seed=7):
-    net = _conv_bn_net()
-    exe = net.simple_bind(mx.cpu(), grad_req="null", data=(2, 8, 8, 8))
-    # deterministic per-param seeds (no hash(): PYTHONHASHSEED varies);
-    # moving stats near (0, 1) keep the post-BN relus from clamping the
-    # whole activation to zero, which would mask the quantized conv
-    for i, k in enumerate(sorted(exe.arg_dict)):
-        if k == "data":
-            continue
-        arr = exe.arg_dict[k]
-        rs = np.random.RandomState(100 + i)
-        arr[:] = (rs.randn(*arr.shape) * 0.3
-                  + (1.0 if "gamma" in k else 0.0)).astype("float32")
-    for i, k in enumerate(sorted(exe.aux_dict)):
-        arr = exe.aux_dict[k]
-        arr[:] = (np.full(arr.shape, 0.1, "float32") if "mean" in k
-                  else np.ones(arr.shape, "float32"))
-    x = np.random.RandomState(seed).rand(2, 8, 8, 8).astype("float32")
-    exe.arg_dict["data"][:] = x
-    exe.forward(is_train=False)
-    return exe.outputs[0].asnumpy()
-
-
-def test_fusion_inference_gate_trigger(tm, monkeypatch):
-    """Forced fusion engages the Pallas path on a grad-less bind
-    (fusion.infer_engaged fires) and matches the unfused inference
-    output."""
-    tm.set_mode("counters")
-    monkeypatch.setenv("MXNET_FUSED_CONV_BN", "0")
-    base = _infer_forward()
-    monkeypatch.setenv("MXNET_FUSED_CONV_BN", "1")
-    c0 = tm.counters()
-    fused = _infer_forward()
-    c1 = tm.counters()
-    assert c1.get("fusion.infer_engaged", 0) > \
-        c0.get("fusion.infer_engaged", 0)
-    np.testing.assert_allclose(fused, base, rtol=1e-5, atol=1e-6)
-
-
-def test_fusion_inference_gate_clean(tm, monkeypatch):
-    """Auto mode on CPU (no device-matched WINS table, no quant): the
-    inference plan stays INACTIVE — no engage/fallback counters, output
-    byte-identical to fusion-off."""
-    tm.set_mode("counters")
-    monkeypatch.setenv("MXNET_FUSED_CONV_BN", "0")
-    base = _infer_forward()
-    monkeypatch.delenv("MXNET_FUSED_CONV_BN", raising=False)
-    monkeypatch.delenv("MXNET_SERVE_QUANT", raising=False)
-    c0 = tm.counters()
-    auto = _infer_forward()
-    c1 = tm.counters()
-    assert c1.get("fusion.infer_engaged", 0) == \
-        c0.get("fusion.infer_engaged", 0)
-    assert c1.get("fusion.infer_fallback", 0) == \
-        c0.get("fusion.infer_fallback", 0)
-    np.testing.assert_array_equal(auto, base)
-
-
-@pytest.mark.parametrize("quant,tol", [("bf16", 0.05), ("int8", 0.02)])
-def test_quantized_inference_variants(tm, monkeypatch, quant, tol):
-    """MXNET_SERVE_QUANT activates the inference plan even in auto mode
-    (the quantized weights ride the fused execute path) and stays within
-    the quantization error budget of the fp32 output."""
-    tm.set_mode("counters")
-    monkeypatch.setenv("MXNET_FUSED_CONV_BN", "0")
-    base = _infer_forward()
-    monkeypatch.delenv("MXNET_FUSED_CONV_BN", raising=False)
-    monkeypatch.setenv("MXNET_SERVE_QUANT", quant)
-    from mxnet_tpu import fusion
-
-    assert fusion.quant_mode() == quant
-    assert fusion.infer_default()
-    q = _infer_forward()
-    assert np.abs(q - base).max() < tol
-    assert np.abs(q - base).max() > 0  # it actually quantized something
-
-
-def test_quant_mode_unrecognized_stays_off(monkeypatch):
-    from mxnet_tpu import fusion
-
-    monkeypatch.setenv("MXNET_SERVE_QUANT", "fp4")
-    assert fusion.quant_mode() == "off"
-
-
-def test_fusion_training_unchanged_by_inference_mode(monkeypatch):
-    """The inference predicate must not leak into training binds: a train
-    forward/backward under forced fusion still runs (regression guard for
-    the executor's fusion_on change)."""
-    monkeypatch.setenv("MXNET_FUSED_CONV_BN", "1")
-    net = _conv_bn_net()
-    exe = net.simple_bind(mx.cpu(), data=(2, 8, 8, 8), softmax_label=(2,))
-    exe.arg_dict["data"][:] = np.random.RandomState(0).rand(
-        2, 8, 8, 8).astype("float32")
-    exe.forward(is_train=True)
-    exe.backward()
-    assert np.isfinite(exe.outputs[0].asnumpy()).all()
-
-
 # ------------------------------------------------------------ serve_bench
 @pytest.mark.slow
 def test_serve_bench_check_smoke():

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 
 from mxnet_tpu.ops import pallas_attention as pa
-from mxnet_tpu.ops import pallas_conv_bn as pc
 from mxnet_tpu.ops import pallas_matmul_bias_act as pm
 from mxnet_tpu.ops import pallas_norm_residual as pn
 
@@ -43,7 +42,7 @@ def mosaic_not_interpret(monkeypatch):
     # and the pool's read picks its form from it
     from mxnet_tpu.ops import attention
 
-    for mod in (pc, pm, pn):
+    for mod in (pm, pn):
         monkeypatch.setattr(mod, "_interpret_mode", lambda: False)
     monkeypatch.setattr(attention, "_backend", lambda: "tpu")
 
@@ -86,58 +85,38 @@ def test_layer_norm_affine_fwd_bwd(v5e, dtype):
              ((512,), dtype))
 
 
-# ResNet-50 sites at the bench batch: a 1x1 with the skip add, the 3x3, and
-# the 7x7-spatial tail whose 49-wide rows pad to 128 lanes (the VMEM case)
-_CONV_SITES = [
-    ((1, 1), (1, 1), 128, 512, 28, True),
-    ((3, 3), (1, 1), 128, 128, 28, False),
-    ((1, 1), (2, 2), 1024, 2048, 14, False),
-]
+def test_resnet50_training_step_is_xla_alone(v5e):
+    """The training cells' step (``SPMDTrainer``, ResNet-50, 224x224, 256
+    images, bfloat16) lowered for the chip: every node is its registered
+    operator, so the program holds each of the 53 convolutions forward and
+    twice backward (less the stem's data gradient) and no Mosaic custom
+    call."""
+    from mxnet_tpu import models, parallel
 
-
-@pytest.mark.parametrize("bwd", ["xla", "recompute", "stash"])
-@pytest.mark.parametrize("site", _CONV_SITES,
-                         ids=lambda s: "k%ds%d_K%d_N%d_H%d%s" % (
-                             s[0][0], s[1][0], s[2], s[3], s[4],
-                             "_res" if s[5] else ""))
-def test_conv_bn_fwd_bwd(v5e, site, bwd):
-    kernel, stride, K, N, H, res = site
-    B, dt = 256, "bfloat16"
-    x, w = (B, K, H, H), (N, K) + kernel
-    assert pc.supported(x, w, stride, 2, True, res)
-    if bwd != "xla":
-        # what the planner passes, the compiler must accept
-        assert pc.plan_bwd_blocks(x, w, stride, 2, True, res,
-                                  stash=(bwd == "stash")) is not None
-    Ho, Wo = pc.strided_dims(H, H, stride)
-    r = ((B, N, Ho, Wo), dt) if res else None
-
-    def fn(x, w, scale, shift, r=None):
-        return pc.conv_block(x, w, scale, shift, r, kernel, stride, True,
-                             True, bwd, None)
-
-    _compile(v5e, _with_grads(fn, 5 if res else 4), (x, dt), (w, dt),
-             ((K,), "float32"), ((K,), "float32"), r)
-
-
-def test_conv_bn_infer(v5e):
-    fn = lambda x, w, scale, shift: pc.conv_block_infer(
-        x, w, scale, shift, (3, 3), (1, 1), True)
-    _compile(v5e, fn, ((256, 128, 28, 28), "bfloat16"),
-             ((128, 128, 3, 3), "bfloat16"), ((128,), "float32"),
-             ((128,), "float32"))
-
-
-def test_backward_planner_declines_what_the_compiler_refuses():
-    """k1 K64→N256 at 56² with the skip add: no lane-aligned K stripe fits
-    the scoped VMEM limit, so the planner — not a caught compile error —
-    sends the backward to XLA."""
-    x, w = (256, 64, 56, 56), (256, 64, 1, 1)
-    assert pc.supported(x, w, (1, 1), 2, True, True)
-    assert pc.plan_bwd_blocks(x, w, (1, 1), 2, True, True) is None
-    # a K stripe is the lane dim of the weight block: whole K or 128-multiples
-    assert pc.choose_bwd_blocks(256, 256, 64, 3136, 2, prologue=True) in (
-        128, 256)
+    net = models.get_symbol("resnet-50", num_classes=1000,
+                            image_shape="3,224,224")
+    shapes = {"data": (256, 3, 224, 224), "softmax_label": (256,)}
+    mesh = parallel.make_mesh({"data": 1}, devices=list(v5e.device_set))
+    trainer = parallel.SPMDTrainer(
+        net, mesh, optimizer="sgd",
+        optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
+        compute_dtype="bfloat16")
+    arg_shapes, _, aux_shapes = net.infer_shape(**shapes)
+    struct = lambda shape, dtype="float32": jax.ShapeDtypeStruct(
+        shape, jnp.dtype(dtype), sharding=v5e)
+    named = dict(zip(net.list_arguments(), arg_shapes))
+    params = {n: struct(named[n]) for n in trainer.param_names}
+    aux = dict(zip(trainer.aux_names, map(struct, aux_shapes)))
+    opt_state = jax.tree_util.tree_map(
+        lambda s: struct(s.shape, s.dtype),
+        jax.eval_shape(trainer._opt_init, params))
+    inputs = {"data": struct(shapes["data"], "bfloat16"),
+              "softmax_label": struct(shapes["softmax_label"])}
+    hlo = trainer._build_step().lower(
+        params, aux, opt_state, inputs, struct((2,), "uint32"),
+        struct(())).as_text()
+    assert "tpu_custom_call" not in hlo
+    assert hlo.count("stablehlo.convolution") == 3 * 53 - 1
 
 
 def test_admit_pool_update_stays_in_place_on_the_chip(v5e):

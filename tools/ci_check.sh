@@ -1,6 +1,6 @@
 #!/usr/bin/env bash
-# CI entrypoint: static analysis first, then the fused conv+BN machinery
-# smoke, then the telemetry trace smoke, then the 8-process kvstore
+# CI entrypoint: static analysis first, then the fusion pattern engine's
+# schedule-cache smoke, then the telemetry trace smoke, then the 8-process kvstore
 # bucket/overlap smoke, then the serving smoke, then the elastic
 # fault-tolerance chaos smoke, then the tier-1 test suite.
 #
@@ -20,10 +20,9 @@
 # dependency-free tools/src_lint.py fallback — always-on either way; the
 # every-source-compiles floor is additionally enforced by
 # tests/test_graphlint.py::test_package_sources_compile.
-# Step 3 exercises the fused conv+BN autotune harness end-to-end in Pallas
-# interpret mode (timing scaffolding, fwd+bwd parity, WINS-table emission +
-# loadability — docs/PERF.md §6b) plus the backward gradient-parity sweep's
-# non-slow subset. Step 4 runs a tiny fit loop under MXNET_TELEMETRY=trace,
+# Step 3 tunes one pattern site of the fusion engine into a temporary cache
+# and re-runs against it warm (the measure-and-cache contract, docs/PERF.md
+# §13/§15). Step 4 runs a tiny fit loop under MXNET_TELEMETRY=trace,
 # dumps the chrome trace, and gates it with tools/mxtrace --check
 # (docs/OBSERVABILITY.md — the telemetry dump is a machine contract, so CI
 # smokes it end to end). Step 5 runs the 8-process CPU kvstore smoke
@@ -244,26 +243,7 @@ else
         bench.py || { echo "src_lint fallback FAILED"; exit 1; }
 fi
 
-echo "== [3/10] fused conv+BN: interpret-mode autotune smoke + bwd parity subset =="
-FUSED_TABLE="$(mktemp /tmp/fused_conv_bn_table_ci.XXXXXX.py)"
-JAX_PLATFORMS=cpu python tools/fused_stats_bench.py --interpret --emit-table \
-    --table-out "$FUSED_TABLE" \
-    || { echo "fused_stats_bench smoke FAILED"; rm -f "$FUSED_TABLE"; exit 1; }
-python - "$FUSED_TABLE" <<'PYEOF' || { echo "emitted WINS table invalid"; rm -f "$FUSED_TABLE"; exit 1; }
-import importlib.util, sys
-spec = importlib.util.spec_from_file_location("t", sys.argv[1])
-m = importlib.util.module_from_spec(spec); spec.loader.exec_module(m)
-assert m.DEVICE, "DEVICE not stamped"
-assert m.WINS, "WINS table empty on the interpret backend"
-assert any(k[-1].endswith(":bwd") for k in m.WINS), "no backward entries"
-print("emitted table OK: DEVICE=%r, %d entries" % (m.DEVICE, len(m.WINS)))
-PYEOF
-rm -f "$FUSED_TABLE"
-# the subset also runs inside step 4's full sweep (~18 s overlap) — kept
-# here deliberately as a fail-fast signal before the 6-minute tier-1
-JAX_PLATFORMS=cpu python -m pytest tests/test_pallas_conv_bn_bwd.py -q \
-    -m 'not slow' -p no:cacheprovider \
-    || { echo "bwd parity subset FAILED"; exit 1; }
+echo "== [3/10] fusion pattern engine: schedule-cache smoke =="
 # pattern-engine schedule-cache smoke (docs/PERF.md §13/§15): tune ONE
 # matmul+bias+act site — large enough that the (bm, bn) schedule fan-out
 # has >1 distinct effective tiling — into a temp dir, then re-run the SAME
@@ -338,6 +318,10 @@ sym = mx.sym.Convolution(sym, kernel=(3, 3), pad=(1, 1), num_filter=8,
 sym = mx.sym.BatchNorm(sym, name="bn1")
 sym = mx.sym.Activation(sym, act_type="relu")
 sym = mx.sym.Flatten(sym)
+# FullyConnected -> relu: one site of the pattern engine, so the trace
+# carries a fusion.pattern event
+sym = mx.sym.FullyConnected(sym, num_hidden=16, name="fc1")
+sym = mx.sym.Activation(sym, act_type="relu")
 sym = mx.sym.FullyConnected(sym, num_hidden=4, name="fc")
 sym = mx.sym.SoftmaxOutput(sym, name="softmax")
 rs = np.random.RandomState(0)

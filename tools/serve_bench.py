@@ -1238,9 +1238,6 @@ def main(argv=None):
                     help="transformer-decode: K tokens per dispatch for "
                          "the megastep comparison leg (MXNET_DECODE_"
                          "MEGASTEP_K); 0 or 1 disables the leg")
-    ap.add_argument("--quant", default=None, choices=[None, "off", "bf16",
-                                                      "int8"],
-                    help="sets MXNET_SERVE_QUANT for the run")
     ap.add_argument("--workload", default="uniform",
                     choices=["uniform", "zipf-prefix"],
                     help="zipf-prefix: shared-prefix KV-cache + "
@@ -1287,8 +1284,6 @@ def main(argv=None):
                          "(with --chaos: the resilience gate)")
     args = ap.parse_args(argv)
 
-    if args.quant:
-        os.environ["MXNET_SERVE_QUANT"] = args.quant
     from mxnet_tpu import telemetry
 
     telemetry.set_mode("trace" if (args.check or args.trace_out)
@@ -1311,7 +1306,6 @@ def main(argv=None):
         res = bench_decode(args)
     else:
         res = bench_engine(args)
-    res["quant"] = args.quant or os.environ.get("MXNET_SERVE_QUANT", "off")
 
     ok = True
     if args.check:
