@@ -5,21 +5,31 @@ MultiHeadAttention op from ops/attention.py).
 Pre-norm blocks: x + MHA(LN(x)), x + FFN(LN(x)); LN via the registry's
 LayerNorm-equivalent composition (InstanceNorm is channel-first, so LN here
 is mean/var composed from broadcast ops to stay faithful to the op set)."""
+import math
+
 from .. import symbol as sym
 from ..base import MXNetError
+from ..ops.attention import _NEG, pool_paged
 
 ARCHS = ("vaswani", "olmoe", "granite_hybrid", "deepseek_v3", "lfm2_moe",
-         "mimo_v2_flash")
+         "mimo_v2_flash", "phi4flash")
+
+
+# the block every graph is derived from (ROADMAP D2); the others have the
+# serving prefill and the single-step decode graph alone
+EVERY_GRAPH = ARCHS[:1]
 
 
 def _refuse_arch(arch, what):
     """Everything but the serving prefill and the shared-pool decode graph
-    knows the Vaswani block only (ROADMAP D2: one block, every graph
-    derived from it)."""
+    knows the block of ``EVERY_GRAPH`` only (ROADMAP D2: one block, every
+    graph derived from it). Both refusals name the archs from the tuples
+    above: nobody else lists them."""
     if arch not in ARCHS:
         raise MXNetError("unknown arch %r (have: %s)" % (arch, ", ".join(ARCHS)))
-    if arch != "vaswani":
-        raise MXNetError("%s is not built for arch %r yet" % (what, arch))
+    if arch not in EVERY_GRAPH:
+        raise MXNetError("%s is not built for arch %r yet (built for: %s)"
+                         % (what, arch, ", ".join(EVERY_GRAPH)))
 
 
 def _layer_norm(x, name, dim):
@@ -307,12 +317,26 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     head count: the admission takes the prompt's last ``sliding_window``
     positions of those into the lane's rings; then ``moe_load``. A window
     layer scores a band of the bucket (``MultiHeadAttention(window=)``).
+
+    ``arch="phi4flash"`` builds the SambaY block with differential attention
+    (``_phi4flash_layer``, its mixer chosen by depth and parity), for ONE
+    prompt a call. It takes ``length`` (1, 1). Layers 0 to N/2 and layer
+    N/2 + 1's keys and values run over the bucket; that layer's query, its
+    MLP and every layer behind it run over the prompt's LAST real row alone,
+    gathered by the length, with the row of ``m`` (layer N/2's scan before
+    its gate) that belongs to it. The logits are that one row, (1, vocab).
+    After them come the cache's values in ``decode_cache`` order: a Mamba-1
+    layer's state at ``length`` (1, N, E) and its last convolution columns,
+    float32; a window layer's and layer N/2 + 1's K and V
+    (1, Hkv / 2, P, 2 * head_dim), pairs of heads side by side. The cross
+    layers export nothing.
     """
     builders = {"olmoe": _olmoe_prefill_symbol,
                 "granite_hybrid": _granite_prefill_symbol,
                 "deepseek_v3": _deepseek_v3_prefill_symbol,
                 "lfm2_moe": _lfm2_moe_prefill_symbol,
-                "mimo_v2_flash": _mimo_prefill_symbol}
+                "mimo_v2_flash": _mimo_prefill_symbol,
+                "phi4flash": _phi4flash_prefill_symbol}
     if arch in builders:
         return builders[arch](
             vocab_size=vocab_size, num_layers=num_layers,
@@ -520,6 +544,17 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     ``decode_cache`` order, then the token head, then ``moe_load`` (the
     rows each of ALL the experts received, held here or not).
 
+    ``arch="phi4flash"`` runs ``_phi4flash_layer``: a Mamba-1 layer takes
+    and returns ``ssm_state_i`` (B, N, E) and ``conv_state_i`` (B, K-1, E),
+    float32; a window layer its rings ``ring_k_i`` / ``ring_v_i``
+    (B, Hkv / 2, sliding_window, 2 * head_dim); layer N/2 + 1 writes the ONE
+    pool pair ``kv_k_i`` / ``kv_v_i`` (Hkv / 2 heads of 2 * head_dim) and
+    reads it, and every cross layer behind it takes that layer's UPDATED
+    pools as operands (the token's own key among them) and writes nothing:
+    eight ``KVPoolAttention`` nodes, one ``KVPoolSlotWrite``, one pair of
+    cache outputs. ``m``, layer N/2's scan before its gate, is carried to
+    the gated memory units inside the step and never cached.
+
     ``page_size`` is the decoder's (``PagedKVDecoder``'s default here); it
     must divide ``max_len``.
     """
@@ -527,7 +562,8 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
                 "granite_hybrid": _granite_decode_symbol,
                 "deepseek_v3": _deepseek_v3_decode_symbol,
                 "lfm2_moe": _lfm2_moe_decode_symbol,
-                "mimo_v2_flash": _mimo_decode_symbol}
+                "mimo_v2_flash": _mimo_decode_symbol,
+                "phi4flash": _phi4flash_decode_symbol}
     if arch in builders:
         return builders[arch](
             vocab_size=vocab_size, num_layers=num_layers,
@@ -1473,6 +1509,345 @@ def _mimo_param_shapes(vocab_size, num_layers, **sizes):
     return shapes
 
 
+# ----------------- Phi-4-mini-flash (SambaY: Mamba-1, differential attention)
+def _phi4flash_sizes(num_layers, num_heads, model_dim, ffn_dim,
+                     num_kv_heads=None, head_dim=None, sliding_window=512,
+                     mb_per_layer=2, mamba_state=16, mamba_conv=4,
+                     mamba_expand=2, mamba_dt_rank=None, dtype="float32",
+                     **kwargs):
+    """``_phi4flash_layer``'s keywords from a builder's (defaults: the
+    family's ``configuration_phi4flash.py``; keywords of the other
+    architectures are dropped). ``kinds`` names every layer's mixer, chosen
+    by DEPTH as well as parity: up to and including layer ``half`` =
+    N / 2 the self-decoder (even ``mamba``, odd ``window``), layer half + 1
+    ``full`` attention, whose keys and values are the one pool, then the
+    cross-decoder (even ``gmu``, odd ``cross``)."""
+    hkv = num_kv_heads or num_heads
+    if num_layers % 4 or int(mb_per_layer) != 2:
+        raise MXNetError("phi4flash: a multiple of 4 layers and "
+                         "mb_per_layer 2, got %d and %r"
+                         % (num_layers, mb_per_layer))
+    if num_heads % 2 or hkv % 2 or num_heads % hkv:
+        raise MXNetError("phi4flash: differential attention pairs its heads: "
+                         "%d query heads over %d key/value heads"
+                         % (num_heads, hkv))
+    half = num_layers // 2
+    kinds = tuple("mamba" if i <= half and i % 2 == 0
+                  else "window" if i < half
+                  else "full" if i == half + 1
+                  else "gmu" if i % 2 == 0 else "cross"
+                  for i in range(num_layers))
+    return dict(
+        kinds=kinds, half=half, num_heads=num_heads, num_kv_heads=hkv,
+        head_dim=head_dim or model_dim // num_heads, model_dim=model_dim,
+        ffn_dim=ffn_dim, sliding_window=int(sliding_window),
+        inner=int(mamba_expand) * model_dim, mamba_state=int(mamba_state),
+        mamba_conv=int(mamba_conv),
+        mamba_dt_rank=int(mamba_dt_rank or -(-model_dim // 16)), dtype=dtype)
+
+
+def _phi4flash_mamba(op, i, u, block, **inputs):
+    """One of ops/ssm.py's two Mamba-1 operators on layer ``i``'s weights,
+    whose shapes the graph names (the operator's data carries neither the
+    kernel, nor the rank, nor the state)."""
+    e, n, r = (block[k] for k in ("inner", "mamba_state", "mamba_dt_rank"))
+    shapes = (("conv_weight", (e, block["mamba_conv"])), ("conv_bias", (e,)),
+              ("x_weight", (r + 2 * n, e)), ("dt_weight", (e, r)),
+              ("dt_bias", (e,)), ("A_log", (e, n)), ("D", (e,)))
+    return op(u, *(sym.Variable("layer%d_mamba1_%s" % (i, w), shape=shape)
+                   for w, shape in shapes),
+              name="layer%d_mamba1_core" % i, **inputs)
+
+
+def _diff_queries(q, seq_len, hq, dh):
+    """Differential attention's queries for a read that knows one softmax:
+    q (B, T, hq * dh), head 2j the first and head 2j + 1 the second query of
+    pair j, becomes (B, hq, T, 2 dh), head 2j ``[q1_j | 0]`` and head 2j + 1
+    ``[0 | q2_j]``. Against keys kept ``[k1_g | k2_g]`` the zeros leave
+    ``q1 k1`` and ``q2 k2`` alone, and against values kept ``[v1_g | v2_g]``
+    each softmax applies to both halves: the two reads of a pair are two
+    ordinary heads of 2 dh, and every attention operator serves them."""
+    pairs = sym.Reshape(q, shape=(-1, seq_len, hq // 2, 2, dh))
+    first, second = (sym.slice_axis(pairs, axis=3, begin=c, end=c + 1)
+                     for c in (0, 1))
+    nothing = sym.zeros_like(first)
+    padded = sym.Concat(sym.Concat(first, nothing, dim=4),
+                        sym.Concat(nothing, second, dim=4), dim=3)
+    return _split_heads(padded, seq_len, hq, 2 * dh)
+
+
+def _diff_combine(att, name, depth, seq_len, hq, dh, dtype):
+    """What follows the two reads of every pair, element-wise and float32:
+    att (B, hq, T, 2 dh), heads 2j and 2j + 1 the contexts a1_j and a2_j ->
+    ``(1 - lam0) * rms(a1 - lam * a2; <name>_subln_gamma)`` (B, T, hq * dh),
+    ``lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam0`` of the layer's four
+    vectors ``<name>_lambda_*`` and ``lam0 = 0.8 - 0.6 exp(-0.3 depth)``."""
+    lam0 = 0.8 - 0.6 * math.exp(-0.3 * depth)
+    vec = lambda tag: sym.Cast(sym.Variable(
+        "%s_lambda_%s" % (name, tag), shape=(dh,)), dtype="float32")
+    lam = sym.exp(sym.sum(vec("q1") * vec("k1"))) \
+        - sym.exp(sym.sum(vec("q2") * vec("k2"))) + lam0
+    pairs = sym.Reshape(
+        sym.Cast(sym.SwapAxis(att, dim1=1, dim2=2), dtype="float32"),
+        shape=(-1, seq_len, hq // 2, 2, 2 * dh))
+    a1, a2 = (sym.slice_axis(pairs, axis=3, begin=c, end=c + 1)
+              for c in (0, 1))
+    diff = a1 - sym.broadcast_mul(a2, sym.Reshape(lam, shape=(1, 1, 1, 1, 1)))
+    out = sym.RMSNorm(diff, eps=1e-5, name="%s_subln" % name) * (1.0 - lam0)
+    return sym.Cast(sym.Reshape(out, shape=(-1, seq_len, hq * dh)),
+                    dtype=dtype)
+
+
+def _phi4flash_layer(x, i, seq_len, mix, block):
+    """One ``model_type: phi4flash`` block on x (B, T, M) -> (x', its rows):
+    pre-norm LayerNorm (weight and bias, float32 statistics), the mixer
+    ``kinds[i]`` names, then the gated SiLU MLP every layer has. No
+    positions anywhere. ``mix`` holds what the prefill and the decode graph
+    do differently:
+
+    ``mamba``: one bias-free projection to [u | z], asked for in float32;
+    ``mix["scan"](i, u)`` runs the Mamba-1 core and returns y (B, T, E), the
+    scan's output BEFORE its gate; layer ``half`` leaves it in ``mix["m"]``
+    for the gated memory units; ``y * silu(z)`` goes through the output
+    projection. ``gmu``: ``W_out (m * silu(W_in h))``, no state of its own.
+    ``window`` / ``full``: one projection with a bias to [q | k | v];
+    differential attention in its padded-query form (``_diff_queries``):
+    keys and values are kept as hkv / 2 heads of 2 dh, ``mix[kind](i, q, k,
+    v)`` takes the head-major tensors and returns the context
+    (B, hq, T, 2 dh); ``_diff_combine`` and the output projection with its
+    bias follow. ``cross``: its own query projection alone,
+    ``mix["cross"](i, q)`` reads what layer half + 1 kept. In a prefill
+    ``mix["last_row"]`` narrows x to the prompt's last real row at layer
+    half + 1, AFTER that layer's keys and values are made of the bucket:
+    its query, its MLP and every layer behind it compute one row."""
+    name = "layer%d" % i
+    kind = block["kinds"][i]
+    d, e, dtype = block["model_dim"], block["inner"], block["dtype"]
+    hq, hkv, dh = (block[k] for k in ("num_heads", "num_kv_heads",
+                                      "head_dim"))
+    fc = lambda data, width, tag, **kw: sym.FullyConnected(
+        data=data, num_hidden=width, flatten=False,
+        name="%s_%s" % (name, tag), **kw)
+    norm = lambda data, tag: sym.Cast(_layer_norm(
+        sym.Cast(data, dtype="float32"), "%s_%s" % (name, tag), d),
+        dtype=dtype)
+    silu = lambda data: sym.Activation(data, act_type="silu")
+    h = norm(x, "ln1")
+    if kind == "mamba":
+        uz = fc(h, 2 * e, "mamba1_in", no_bias=True, out_dtype="float32")
+        y = mix["scan"](i, sym.slice_axis(uz, axis=2, begin=0, end=e))
+        if i == block["half"]:
+            mix["m"] = y
+        gated = y * silu(sym.slice_axis(uz, axis=2, begin=e, end=2 * e))
+        mixed = fc(sym.Cast(gated, dtype=dtype), d, "mamba1_out",
+                   no_bias=True)
+    elif kind == "gmu":
+        gate = silu(fc(h, e, "gmu_in", no_bias=True, out_dtype="float32"))
+        mixed = fc(sym.Cast(mix["m"] * gate, dtype=dtype), d, "gmu_out",
+                   no_bias=True)
+    else:
+        tag = "cross" if kind == "cross" else "self"
+        if kind == "cross":
+            att = mix["cross"](i, _diff_queries(fc(h, hq * dh, "cross_q"),
+                                                seq_len, hq, dh))
+        else:
+            kv_len, ends = seq_len, (0, hq * dh, (hq + hkv) * dh,
+                                     (hq + 2 * hkv) * dh)
+            if kind == "full" and mix.get("last_row"):
+                # keys and values of the bucket, the query of one row: the
+                # fused matrix's rows, cut where the fused output would be
+                weight, bias = (sym.Variable(
+                    "%s_self_qkv_%s" % (name, w), shape=shape)
+                    for w, shape in (("weight", (ends[-1], d)),
+                                     ("bias", (ends[-1],))))
+                part = lambda data, a, b, what: sym.FullyConnected(
+                    data=data, num_hidden=b - a, flatten=False,
+                    weight=sym.slice_axis(weight, axis=0, begin=a, end=b),
+                    bias=sym.slice_axis(bias, axis=0, begin=a, end=b),
+                    name="%s_self_%s" % (name, what))
+                kv = part(h, ends[1], ends[3], "kv")
+                x, h, mix["m"] = (mix["last_row"](a)
+                                  for a in (x, h, mix["m"]))
+                seq_len = 1
+                q = part(h, ends[0], ends[1], "q")
+                k, v = (sym.slice_axis(kv, axis=2, begin=a - ends[1],
+                                       end=b - ends[1])
+                        for a, b in zip(ends[1:], ends[2:]))
+            else:
+                qkv = fc(h, ends[-1], "self_qkv")
+                q, k, v = (sym.slice_axis(qkv, axis=2, begin=a, end=b)
+                           for a, b in zip(ends, ends[1:]))
+            k, v = (_split_heads(a, kv_len, hkv // 2, 2 * dh)
+                    for a in (k, v))
+            att = mix[kind](i, _diff_queries(q, seq_len, hq, dh), k, v)
+        mixed = fc(_diff_combine(att, "%s_%s" % (name, tag), i, seq_len, hq,
+                                 dh, dtype), d, tag + "_proj")
+    x = x + mixed
+    no_bias = lambda data, width, tag: fc(data, width, tag, no_bias=True)
+    return x + _gated_mlp(no_bias, norm(x, "ln2"), block["ffn_dim"], d,
+                          "mlp"), seq_len
+
+
+def _phi4flash_stack(vocab_size, seq_len, mix, block):
+    """Embedding (tied to the head), the layers, the final LayerNorm and the
+    head: ``data`` (B, T) -> float32 logits (B x rows, vocab), ``rows`` what
+    the layers left of T (``_phi4flash_layer``)."""
+    table = sym.Variable("embed_weight")
+    d = block["model_dim"]
+    x = sym.Embedding(data=sym.Variable("data"), weight=table,
+                      input_dim=vocab_size, output_dim=d, name="embed")
+    for i in range(len(block["kinds"])):
+        x, seq_len = _phi4flash_layer(x, i, seq_len, mix, block)
+    x = sym.Cast(_layer_norm(sym.Cast(x, dtype="float32"), "final_ln", d),
+                 dtype=block["dtype"])
+    return sym.FullyConnected(
+        data=sym.Reshape(x, shape=(-1, d)), weight=table,
+        num_hidden=vocab_size, no_bias=True, out_dtype="float32",
+        name="lm_head")
+
+
+def _phi4flash_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
+    block = _phi4flash_sizes(num_layers, **sizes)
+    hq, hkv, dh, w = (block[k] for k in ("num_heads", "num_kv_heads",
+                                         "head_dim", "sliding_window"))
+    length = sym.Variable("length")     # (1, 1): real tokens of the bucket
+    cache = []      # the layers are built in order, so is this
+    kept = {}       # layer half + 1's keys and values, as a read takes them
+    # the bucket's slots a read of the one row may see: those of the prompt
+    seen = (1.0 - sym.broadcast_lesser(
+        sym.Reshape(sym._arange(start=0, stop=prefill_len),
+                    shape=(1, prefill_len)), length)) * float(_NEG)
+    at_end = sym.Reshape(length, shape=(-1,)) - 1.0
+
+    def scan(i, u):
+        core = _phi4flash_mamba(sym.Mamba1Scan, i, u, block, length=length)
+        cache.extend([core[1], core[2]])
+        return core[0]
+
+    def window(i, q, k, v):
+        cache.extend([k, v])
+        return sym.MultiHeadAttention(
+            query=q, key=k, value=v, causal=True, window=w, scale=dh ** -0.5,
+            name="layer%d_self_att" % i)
+
+    def read(i, q, tag):
+        # one row a prompt against the bucket's keys and values as ONE page
+        # of a pool, under the prompt's length: no row of the bucket but
+        # the last real one is a query from here on
+        ctx = sym.KVPoolAttention(
+            sym.Reshape(q, shape=(-1, hq, 2 * dh)), kept["k"], kept["v"],
+            seen, scale=dh ** -0.5, name="layer%d_%s_att" % (i, tag))
+        return sym.Reshape(ctx, shape=(-1, hq, 1, 2 * dh))
+
+    def full(i, q, k, v):
+        cache.extend([k, v])
+        for tag, a in (("k", k), ("v", v)):
+            kept[tag] = sym.Reshape(sym.SwapAxis(a, dim1=1, dim2=2), shape=(
+                -1, prefill_len, hkv * dh)) if pool_paged(hkv // 2, 2 * dh) \
+                else sym.Reshape(a, shape=(-1, prefill_len, 2 * dh))
+        return read(i, q, "self")
+
+    mix = dict(scan=scan, window=window, full=full,
+               cross=lambda i, q: read(i, q, "cross"),
+               last_row=lambda a: sym.take(a, at_end, axis=1))
+    return sym.Group([_phi4flash_stack(vocab_size, prefill_len, mix, block)]
+                     + cache)
+
+
+def _phi4flash_decode_symbol(vocab_size, num_layers, num_slots, page_size,
+                             token_out=True, **sizes):
+    block = _phi4flash_sizes(num_layers, **sizes)
+    hq, hkv, dh = (block[k] for k in ("num_heads", "num_kv_heads",
+                                      "head_dim"))
+    pos_idx = sym.Variable("pos_idx")
+    write_slot = sym.Variable("write_slot")
+    write, pages = _pool_step_inputs(pos_idx, num_slots, page_size,
+                                     write_slot)
+    cache = []      # the layers are built in order, so is this
+    pool = []       # layer half + 1's UPDATED pools: what eight layers read
+    # one token a lane: a head-major (B, H, 1, d) tensor is rows (B, H, d)
+    rows = lambda a, n: sym.Reshape(a, shape=(-1, n, 2 * dh))
+
+    def scan(i, u):
+        core = _phi4flash_mamba(
+            sym.Mamba1Step, i, sym.Reshape(u, shape=(0, -1)), block,
+            ssm_state=sym.Variable("ssm_state_%d" % i),
+            conv_state=sym.Variable("conv_state_%d" % i), stepped=write_slot)
+        cache.extend([core[1], core[2]])
+        return sym.Reshape(core[0], shape=(0, 1, -1))
+
+    def window(i, q, k_new, v_new):
+        # the lane's own rings, no frame and no table
+        rings = sym.KVRingWrite(
+            sym.Variable("ring_k_%d" % i), rows(k_new, hkv // 2),
+            sym.Variable("ring_v_%d" % i), rows(v_new, hkv // 2), pos_idx,
+            write_slot, num_rings=2, name="layer%d_self_kvupd" % i)
+        cache.extend([rings[0], rings[1]])
+        ctx = sym.KVRingAttention(
+            rows(q, hq), rings[0], rings[1], pos_idx, write_slot,
+            scale=dh ** -0.5, name="layer%d_self_att" % i)
+        return sym.Reshape(ctx, shape=(-1, hq, 1, 2 * dh))
+
+    def read(i, q, tag):
+        ctx = sym.KVPoolAttention(
+            rows(q, hq), pool[0], pool[1], scale=dh ** -0.5,
+            name="layer%d_%s_att" % (i, tag), **pages)
+        return sym.Reshape(ctx, shape=(-1, hq, 1, 2 * dh))
+
+    def full(i, q, k_new, v_new):
+        # the ONE write of the step's keys and values; the cross layers take
+        # the updated pools as operands (the token's own key among them) and
+        # write nothing, so the donated pools are swapped back once
+        pool.extend(write(i, {"k": rows(k_new, hkv // 2),
+                              "v": rows(v_new, hkv // 2)}))
+        cache.extend(pool)
+        return read(i, q, "self")
+
+    mix = dict(scan=scan, window=window, full=full,
+               cross=lambda i, q: read(i, q, "cross"))
+    return _token_head(_phi4flash_stack(vocab_size, 1, mix, block), cache,
+                       "greedy_token" if token_out else None)
+
+
+def _phi4flash_param_shapes(vocab_size, num_layers, **sizes):
+    block = _phi4flash_sizes(num_layers, **sizes)
+    d, e, f = block["model_dim"], block["inner"], block["ffn_dim"]
+    hq, hkv, dh = (block[k] for k in ("num_heads", "num_kv_heads",
+                                      "head_dim"))
+    n_state, rank = block["mamba_state"], block["mamba_dt_rank"]
+    shapes = {"embed_weight": (vocab_size, d), "final_ln_gamma": (d,),
+              "final_ln_beta": (d,)}
+    for i, kind in enumerate(block["kinds"]):
+        n = "layer%d_" % i
+        shapes.update({n + "ln1_gamma": (d,), n + "ln1_beta": (d,),
+                       n + "ln2_gamma": (d,), n + "ln2_beta": (d,),
+                       n + "mlp_in_weight": (2 * f, d),
+                       n + "mlp_out_weight": (d, f)})
+        if kind == "mamba":
+            m = n + "mamba1_"
+            shapes.update({
+                m + "in_weight": (2 * e, d),
+                m + "conv_weight": (e, block["mamba_conv"]),
+                m + "conv_bias": (e,), m + "x_weight": (rank + 2 * n_state, e),
+                m + "dt_weight": (e, rank), m + "dt_bias": (e,),
+                m + "A_log": (e, n_state), m + "D": (e,),
+                m + "out_weight": (d, e)})
+            continue
+        if kind == "gmu":
+            shapes.update({n + "gmu_in_weight": (e, d),
+                           n + "gmu_out_weight": (d, e)})
+            continue
+        a = n + ("cross_" if kind == "cross" else "self_")
+        shapes.update({a + "proj_weight": (d, hq * dh), a + "proj_bias": (d,),
+                       a + "subln_gamma": (2 * dh,)})
+        shapes.update({a + "lambda_" + v: (dh,)
+                       for v in ("q1", "k1", "q2", "k2")})
+        rows = hq * dh if kind == "cross" else (hq + 2 * hkv) * dh
+        proj = a + ("q_" if kind == "cross" else "qkv_")
+        shapes.update({proj + "weight": (rows, d), proj + "bias": (rows,)})
+    return shapes
+
+
 def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
                  **sizes):
     """What a decode graph of ``arch`` keeps between steps, in the order its
@@ -1492,7 +1867,32 @@ def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
     (heads, window, d) and the buffer (lanes,) + shape in the pools' type;
     it takes no frame and no page-table entry, whatever the lane's length.
     A full layer beside it keeps its pools, the key's wider than the
-    value's."""
+    value's.
+
+    A pool may be READ by more layers than write it: ``phi4flash`` keeps ONE
+    pool pair, layer N/2 + 1's, which that layer writes and it and every
+    cross layer behind it read; the cross layers keep nothing. Its keys and
+    values are kept in PAIRS of heads side by side (hkv / 2 heads of
+    2 * head_dim, ``_diff_queries``), in the pool and in the window layers'
+    rings alike; a Mamba-1 layer keeps its state (N, E), STATE-major (the
+    minor dimension whole tiles of the chip's lanes), and its last
+    convolution columns (K - 1, E), float32 rows."""
+    if arch == "phi4flash":
+        block = _phi4flash_sizes(num_layers, num_heads=num_heads,
+                                 model_dim=model_dim, head_dim=head_dim,
+                                 **sizes)
+        pairs, wide = block["num_kv_heads"] // 2, 2 * block["head_dim"]
+        per_kind = {
+            "mamba": [("ssm_state_%d", "row", (block["mamba_state"],
+                                               block["inner"])),
+                      ("conv_state_%d", "row", (block["mamba_conv"] - 1,
+                                                block["inner"]))],
+            "window": [("ring_%s_%%d" % t, "ring",
+                        (pairs, block["sliding_window"], wide)) for t in "kv"],
+            "full": [("kv_%s_%%d" % t, "pool", (pairs, wide)) for t in "kv"]}
+        return [(name % i, kind, shape)
+                for i, layer in enumerate(block["kinds"])
+                for name, kind, shape in per_kind.get(layer, ())]
     if arch == "mimo_v2_flash":
         block = _mimo_sizes(num_layers, num_heads=num_heads,
                             model_dim=model_dim, head_dim=head_dim, **sizes)
@@ -1577,9 +1977,10 @@ def param_shapes(arch, vocab_size, num_layers, num_heads, model_dim, ffn_dim,
         return _deepseek_v3_param_shapes(
             vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
             ffn_dim=ffn_dim, num_experts=num_experts, **kwargs)
-    if arch in ("lfm2_moe", "mimo_v2_flash"):
-        shapes = _mimo_param_shapes if arch == "mimo_v2_flash" \
-            else _lfm2_moe_param_shapes
+    if arch in ("lfm2_moe", "mimo_v2_flash", "phi4flash"):
+        shapes = {"lfm2_moe": _lfm2_moe_param_shapes,
+                  "mimo_v2_flash": _mimo_param_shapes,
+                  "phi4flash": _phi4flash_param_shapes}[arch]
         return shapes(
             vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
             ffn_dim=ffn_dim, head_dim=head_dim, num_experts=num_experts,

@@ -223,6 +223,17 @@ register_meta("_contrib_Mamba2Step",
                                conv_state=3, stepped=2),
               dtype_policy="first", param_slots=tuple(_MAMBA2_WEIGHTS),
               aliases=("Mamba2Step",))
+_MAMBA1_WEIGHTS = {"conv_weight": 2, "conv_bias": 1, "x_weight": 2,
+                   "dt_weight": 2, "dt_bias": 1, "A_log": 2, "D": 1}
+register_meta("_contrib_Mamba1Scan",
+              input_ranks=dict(_MAMBA1_WEIGHTS, data=3, length=2),
+              dtype_policy="first", param_slots=tuple(_MAMBA1_WEIGHTS),
+              aliases=("Mamba1Scan",))
+register_meta("_contrib_Mamba1Step",
+              input_ranks=dict(_MAMBA1_WEIGHTS, data=2, ssm_state=3,
+                               conv_state=3, stepped=2),
+              dtype_policy="first", param_slots=tuple(_MAMBA1_WEIGHTS),
+              aliases=("Mamba1Step",))
 register_meta("_contrib_GatedShortConv",
               input_ranks={"data": 3, "weight": 2, "length": 2},
               dtype_policy="first", param_slots=("weight",),

@@ -253,6 +253,36 @@ def _mamba2_step(attrs, shapes):
     return shapes
 
 
+def _mamba1_weights(shapes):
+    """conv_bias, dt_bias and D (slots 2, 5, 7) from the channels of
+    ``data``; the matrices carry sizes ``data`` does not (kernel, rank,
+    state), so the graph names their shapes (``_phi4flash_mamba``)."""
+    data = shapes[0]
+    if data is not None:
+        for i in (2, 5, 7):
+            if shapes[i] is None:
+                shapes[i] = (data[-1],)
+    return data
+
+
+@rule("_contrib_Mamba1Scan")
+@rule("Mamba1Scan")
+def _mamba1_scan(attrs, shapes):
+    data = _mamba1_weights(shapes)
+    if data is not None and shapes[8] is None:      # length (B, 1)
+        shapes[8] = (data[0], 1)
+    return shapes
+
+
+@rule("_contrib_Mamba1Step")
+@rule("Mamba1Step")
+def _mamba1_step(attrs, shapes):
+    data = _mamba1_weights(shapes)
+    if data is not None and shapes[10] is None:     # stepped (R, 1)
+        shapes[10] = (data[0], 1)
+    return shapes
+
+
 @rule("_contrib_GatedShortConv")
 @rule("GatedShortConv")
 def _gated_short_conv(attrs, shapes):

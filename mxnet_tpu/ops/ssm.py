@@ -1,6 +1,8 @@
-"""The Mamba-2 mixer's core (Dao & Gu 2024, "Transformers are SSMs"): the
+"""The cores of two state-space mixers, each a pair of registry operators in
+plain ``jax.numpy``: Mamba-2 (Dao & Gu 2024, "Transformers are SSMs") and,
+at the end of the file, Mamba-1 (Gu & Dao 2023, "Mamba"). A core is the
 depthwise causal convolution, its activation and the selective state-space
-recurrence, as two registry operators in plain ``jax.numpy``.
+recurrence.
 
 ``Mamba2Scan`` runs T positions of a right-padded sequence whose LENGTH is
 data, in the chunked (state-space-dual) form, and hands back the outputs and
@@ -176,4 +178,132 @@ def _mamba2_step(attrs, data, dt, conv_weight, conv_bias, dt_bias, A_log, D,
     moved = stepped.reshape(-1) >= 0
     return (y.reshape(y.shape[0], -1).astype(data.dtype),
             jnp.where(moved[:, None, None, None], new, ssm_state),
+            jnp.where(moved[:, None, None], window[:, 1:], conv_state))
+
+
+# ------------------------------------------------------------------- Mamba-1
+# Per channel e of E, state N, rank R, kernel K (no heads, no groups):
+#     u'_t = silu(sum_{j<K} w[:, j] * u_{t-K+1+j} + b)       zeros left of t = 0
+#     [r (R) | B_t (N) | C_t (N)] = Wx u'_t;  dt_t = softplus(Wdt r + dt_bias)
+#     S_t = exp(dt_t (outer) A) * S_{t-1} + (dt_t * u'_t) (outer) B_t
+#     A = -exp(A_log) (E, N);  y_t = S_t C_t + D * u'_t
+# ``A`` is per channel AND state, so there is no scalar decay a head and the
+# chunked dual form above does not compute it: the scan is the recurrence.
+# The state is kept STATE-MAJOR, (N, E): its minor dimension is then whole
+# tiles of the chip's 128 lanes (E = 5,120), where (E, 16) would be padded
+# eight times over in memory and in every step's read and write.
+_M1_WEIGHTS = ("conv_weight", "conv_bias", "x_weight", "dt_weight", "dt_bias",
+               "A_log", "D")
+_M1_BLOCK = 8       # positions a trip of the scan's loop: changes no function
+
+
+def _mamba1_selective(conv, x_weight, dt_weight, dt_bias):
+    """(dt (..., E) after its softplus, B (..., N), C (..., N)) of the
+    activated convolution ``conv`` (..., E), float32 on the matrix unit
+    too."""
+    rank = dt_weight.shape[1]
+    n = (x_weight.shape[0] - rank) // 2
+    if x_weight.shape != (rank + 2 * n, conv.shape[-1]):
+        raise MXNetError("Mamba1: x_weight %r does not project %d channels "
+                         "to a rank of %d and two states"
+                         % (x_weight.shape, conv.shape[-1], rank))
+    rbc = jnp.einsum("...e,oe->...o", conv, x_weight, precision=_HI)
+    dt = jnp.einsum("...r,er->...e", rbc[..., :rank], dt_weight,
+                    precision=_HI)
+    return jax.nn.softplus(dt + dt_bias), rbc[..., rank:rank + n], \
+        rbc[..., rank + n:]
+
+
+@register(
+    "_contrib_Mamba1Scan",
+    attrs={},
+    input_names=("data",) + _M1_WEIGHTS + ("length",),
+    num_outputs=3,
+    output_names=("output", "ssm_state", "conv_state"),
+    aliases=("Mamba1Scan",),
+)
+def _mamba1_scan(attrs, data, conv_weight, conv_bias, x_weight, dt_weight,
+                 dt_bias, A_log, D, length):
+    """The Mamba-1 core over a right-padded sequence: ``data`` (B, T, E) is
+    the projected u before its convolution, ``length`` (B, 1) the number of
+    real positions a row (data, so one program serves every length). Returns
+    ``(y (B, T, E), ssm_state (B, N, E), conv_state (B, K-1, E))``: the
+    scan's outputs BEFORE any gate, in ``data``'s type (those past the
+    length are meaningless), and in float32 the recurrent state after
+    position ``length - 1``, state-major, and the last K-1 PRE-activation
+    columns of u before ``length`` (zeros where the sequence is shorter).
+    Positions at and past the length get ``dt = 0``, so the state passes
+    through them unchanged. The recurrence runs one position after the
+    other, ``_M1_BLOCK`` of them a trip of a ``lax.scan``: the (T, N, E)
+    history of the state is never made, a block's (8, N, E) is (on the chip,
+    standing alone at T 2,048: 2.0 ms a layer against 2.7 for a trip a
+    position unrolled by 8 and 5.8 not unrolled; PERF.md section 6,
+    PR 45)."""
+    k = conv_weight.shape[1]
+    u, w, bias, x_weight, dt_weight, dt_bias, a_log, d = _f32(
+        data, conv_weight, conv_bias, x_weight, dt_weight, dt_bias, A_log, D)
+    bsz, t, _ = u.shape
+    n_real = length.reshape(bsz).astype(jnp.int32)
+    padded = jnp.pad(u, ((0, 0), (k - 1, 0), (0, 0)))
+    conv = jax.nn.silu(bias + sum(padded[:, j:j + t] * w[:, j]
+                                  for j in range(k)))
+    dt, b, c = _mamba1_selective(conv, x_weight, dt_weight, dt_bias)
+    live = jnp.arange(t)[None, :] < n_real[:, None]
+    dt = jnp.where(live[..., None], dt, 0.0)
+    a = -jnp.exp(a_log).T                               # (N, E)
+    q = min(_M1_BLOCK, t)
+    pad = -t % q
+    # (trips, B, q, .): padding has dt = 0 and changes no state
+    blocks = lambda v: jnp.moveaxis(jnp.pad(
+        v, ((0, 0), (0, pad), (0, 0))).reshape(bsz, -1, q, v.shape[-1]), 1, 0)
+
+    def one(state, blk):
+        dt_q, du_q, b_q, c_q = blk      # (B, q, E) twice, (B, q, N) twice
+        # what needs no state is made for the q positions at once; the q
+        # updates follow one another; the read-out is one sum over them
+        decay = jnp.exp(dt_q[:, :, None, :] * a)
+        fed = du_q[:, :, None, :] * b_q[:, :, :, None]
+        states = []
+        for j in range(q):
+            state = decay[:, j] * state + fed[:, j]
+            states.append(state)
+        return state, jnp.sum(jnp.stack(states, axis=1)
+                              * c_q[:, :, :, None], axis=2)
+
+    state, y = jax.lax.scan(
+        one, jnp.zeros((bsz,) + a.shape, jnp.float32),
+        tuple(blocks(v) for v in (dt, dt * conv, b, c)))
+    y = jnp.moveaxis(y, 0, 1).reshape(bsz, -1, y.shape[-1])[:, :t] + d * conv
+    return y.astype(data.dtype), state, columns_before(padded, n_real, k - 1)
+
+
+@register(
+    "_contrib_Mamba1Step",
+    attrs={},
+    input_names=("data",) + _M1_WEIGHTS + ("ssm_state", "conv_state",
+                                           "stepped"),
+    num_outputs=3,
+    output_names=("output", "ssm_state", "conv_state"),
+    aliases=("Mamba1Step",),
+)
+def _mamba1_step(attrs, data, conv_weight, conv_bias, x_weight, dt_weight,
+                 dt_bias, A_log, D, ssm_state, conv_state, stepped):
+    """One token a row: ``data`` (R, E) as in ``Mamba1Scan``, ``ssm_state``
+    (R, N, E) and ``conv_state`` (R, K-1, E) the row's state, ``stepped``
+    (R, 1) negative for a row that rides along (a decode step's
+    ``write_slot``). Returns ``(y (R, E), ssm_state', conv_state')``; the
+    state of a row that rides along comes back bit for bit and its ``y`` is
+    meaningless."""
+    k = conv_weight.shape[1]
+    u, w, bias, x_weight, dt_weight, dt_bias, a_log, d = _f32(
+        data, conv_weight, conv_bias, x_weight, dt_weight, dt_bias, A_log, D)
+    window = jnp.concatenate([conv_state, u[:, None, :]], axis=1)
+    conv = jax.nn.silu(bias + sum(window[:, j] * w[:, j] for j in range(k)))
+    dt, b, c = _mamba1_selective(conv, x_weight, dt_weight, dt_bias)
+    new = jnp.exp(dt[:, None, :] * -jnp.exp(a_log).T) * ssm_state \
+        + (dt * conv)[:, None, :] * b[:, :, None]
+    y = jnp.sum(new * c[:, :, None], axis=1) + d * conv
+    moved = stepped.reshape(-1) >= 0
+    return (y.astype(data.dtype),
+            jnp.where(moved[:, None, None], new, ssm_state),
             jnp.where(moved[:, None, None], window[:, 1:], conv_state))

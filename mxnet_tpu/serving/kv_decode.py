@@ -22,7 +22,11 @@ convolution columns instead (``arch="granite_hybrid"``, ``"lfm2_moe"``),
 per-lane rows ``(lanes, ...)`` addressed by lane; and, where a layer attends
 a WINDOW (``arch="mimo_v2_flash"``), per-lane rings ``(lanes, heads, window,
 d)`` addressed by lane and position mod the window, which take no frame and
-no page-table entry whatever a lane's length. A
+no page-table entry whatever a lane's length. A pool may be READ by more
+layers than write it (``arch="phi4flash"``: ONE pool pair that one layer
+writes and eight read, the seven behind it keeping nothing): it is one
+buffer, donated, written and swapped back once a step, and the accounting
+counts its bytes once and a read of it once for every layer that reads. A
 token's write happens IN-GRAPH, into the page that holds each lane's
 ``write_slot`` (models/transformer.py ``get_decode_symbol``), as the program
 makes each lane's attention mask of its ``page_table``: a step hands the
@@ -244,16 +248,35 @@ def _operands_of(cache, input_shapes, op):
             for n in nodes]
 
 
+def _pool_readers(symbol):
+    """``[(reading node, the pool its keys come from)]`` over a decode
+    graph's ``KVPoolAttention`` nodes, in the graph's order: the key operand
+    is a cache buffer itself or output j of the step's write, whose operands
+    are (pool, rows) pair after pair. A pool may be read by more nodes than
+    write it (``phi4flash``: eight layers, one write)."""
+    out = []
+    for n in symbol._topo():
+        if n.op == "_contrib_KVPoolAttention":
+            src, j = n.inputs[1]
+            out.append((n, src if src.is_variable else src.inputs[2 * j][0]))
+    return out
+
+
 def _pool_reads(cache, input_shapes):
-    """What one dispatch of a decode program reads of its pools, a layer:
-    ``[(form, slots)]`` over the graph's ``KVPoolAttention`` nodes at these
-    input shapes. The form is the operator's own rule (``pool_read_form``).
+    """What one dispatch of a decode program reads of its pools, a READING
+    node: ``[(node, pool, form, slots)]`` over the graph's
+    ``KVPoolAttention`` nodes at these input shapes, by name; ``pool`` is the
+    cache buffer its keys come from, through the step's write. A pool may be
+    read by more nodes than write it (``phi4flash``: eight layers, one
+    write), and is then listed once a reader: its bytes are moved once a
+    read. The form is the operator's own rule (``pool_read_form``).
     ``slots``: what an XLA form scores a dispatch (the rows' tables whole, or
     the pool for every row); for the kernel, whose fetch follows the rows'
     contexts, the slots of ONE block (``serving.step_kernel_slots`` rounds
     each stepped lane's context up to it)."""
     from ..ops.attention import pool_read_form, pool_slots
 
+    pools = {id(n): pool.name for n, pool in _pool_readers(cache._sym)}
     out = []
     for n, ops in _operands_of(cache, input_shapes,
                                "_contrib_KVPoolAttention"):
@@ -271,7 +294,7 @@ def _pool_reads(cache, input_shapes):
             slots = rows * table.shape[1] * page
         else:
             slots = rows * pool_slots(k.shape)
-        out.append((form, slots))
+        out.append((n.name, pools[id(n)], form, slots))
     return out
 
 
@@ -949,6 +972,22 @@ class PagedKVDecoder:
     over; the load read from both graphs counts all of them. It refuses what
     ``lfm2_moe`` refuses: a ring cannot be shared, and taking a token back
     would need the one it overwrote.
+
+    ``arch="phi4flash"`` serves the SambaY decoder-hybrid-decoder with
+    differential attention (``num_kv_heads``, ``head_dim``,
+    ``sliding_window``, ``mamba_state``, ``mamba_conv``, ``mamba_expand``,
+    ``mamba_dt_rank``; the mixer of a layer follows from its depth). The
+    self-decoder, layers 0 to N/2, keeps Mamba-1 rows (state (N, E) and
+    convolution columns, float32) and window rings as the two archs above
+    do; layer N/2 + 1 keeps THE pool pair, keys and values in pairs of heads
+    side by side; the layers behind it keep nothing: a gated memory unit
+    reads a tensor the step carries from layer N/2's scan, a cross layer
+    reads layer N/2 + 1's pool, in the same step after that layer's write.
+    An admission's prefill runs the self-decoder and layer N/2 + 1's keys
+    and values over the bucket and everything behind them over the prompt's
+    LAST row alone, so it hands back one row of logits
+    (``serving.admit_self_rows`` / ``serving.admit_cross_rows``). It refuses
+    what ``mimo_v2_flash`` refuses.
     """
 
     def __init__(self, arg_params: Dict[str, object], vocab_size,
@@ -1063,6 +1102,15 @@ class PagedKVDecoder:
         self._dec_cache = PersistentExecutableCache(
             decode, arg_params, {}, model_key=key + "-decode",
             program_label="mx_decode", donated=self._cache_names, **binding)
+        # readers of a pool past its first: layers that read what another
+        # layer writes; and the rows of the bucket an admission runs them
+        # over (read off the bound prefill at warmup: fewer than the
+        # bucket's where it narrows to the prompt's last row; 0 where no
+        # pool is shared)
+        readers = _pool_readers(decode)
+        self._shared_readers = len(readers) - len(
+            {id(pool) for _, pool in readers})
+        self._cross_rows = 0
         self._dec_exe = None
         self._decode_xla_bytes = None  # read at warmup when telemetry is on
         self._step_gathered_slots = 0  # likewise: slots a dispatch scores
@@ -1082,9 +1130,9 @@ class PagedKVDecoder:
         """The chunk, verify and megastep programs, and with them the
         prefix cache and speculation, know the Vaswani block only
         (ROADMAP D2)."""
-        if self.arch != "vaswani":
-            raise MXNetError("paged_kv: %s is not built for arch %r yet"
-                             % (what, self.arch))
+        from ..models.transformer import _refuse_arch
+
+        _refuse_arch(self.arch, "paged_kv: " + what)
 
     def _refuse_rows(self, what):
         """Sharing or dropping pages says nothing of what a lane keeps
@@ -1152,7 +1200,9 @@ class PagedKVDecoder:
                 exe.arg_dict[name]._jax().nbytes
                 for name in self._cache_names))
             reads = _pool_reads(self._dec_cache, self._decode_shapes())
-            by_form = lambda form: [n for f, n in reads if f == form]
+            by_form = lambda form: [n for _, _, f, n in reads if f == form]
+            _tm.gauge("serving.shared_pool_readers").set(
+                self._shared_readers)
             for form in ("kernel", "own_pages", "whole_pool"):
                 _tm.gauge("serving.pool_read.%s_layers" % form).set(
                     len(by_form(form)))
@@ -1189,6 +1239,9 @@ class PagedKVDecoder:
             self._pf_cache.warmup([self._prefill_shapes()])
             self._admit_scatter = _AdmitScatter(self)
             self._admit_scatter.warm(self)
+            if self._shared_readers:    # the warm prefill's rows of logits
+                self._cross_rows = self._pf_cache.executable(
+                    self._prefill_shapes()).outputs[0].shape[0]
         else:
             self._chunk_for(self.prefix_chunk)
         return self
@@ -1357,8 +1410,12 @@ class PagedKVDecoder:
             with _tm.span("serving.admit.prefill"):
                 pf.forward(is_train=False)
             with _tm.span("serving.admit.logits"):
-                row = pf.outputs[0]._jax().reshape(
-                    1, self.prefill_len, self.vocab_size)[0, L - 1, :]
+                row = pf.outputs[0]._jax()
+                # a graph that narrowed to the prompt's last real row itself
+                # (``phi4flash``) hands back that row alone
+                row = row[0] if row.shape[0] < self.prefill_len \
+                    else row.reshape(1, self.prefill_len,
+                                     self.vocab_size)[0, L - 1, :]
                 # the host blocked while the device runs the prefill; what
                 # is left of `logits` is enqueueing the two op-by-op
                 # programs above and reading one row
@@ -1371,6 +1428,10 @@ class PagedKVDecoder:
             with _tm.span("serving.admit.scatter"):
                 self._admit_scatter.run(self, self._prefill_cache(pf),
                                         lane.frames, L, idx)
+        if self._cross_rows and _tm.enabled():
+            # the bucket's rows either half of the depth computed
+            _tm.counter("serving.admit_self_rows").inc(self.prefill_len)
+            _tm.counter("serving.admit_cross_rows").inc(self._cross_rows)
         if self._pf_moe_load is not None and _tm.enabled():
             # rows each expert received, per layer, over every position the
             # prefill computed (padding included: the grouped matmul's work)
@@ -1513,18 +1574,32 @@ class PagedKVDecoder:
         return self._lanes[self._seq_lane[seq_id]].pos
 
     def lane_state(self, seq_id, names=None):
-        """{name: array} of what the sequence's lane carries beside its
-        pages: its row of every per-lane buffer of the cache (``names``: of
-        those only), as the last ``admit`` or ``step`` left it. A recurrent
-        model's state, a window layer's rings (heads, window, d), position p
-        at slot p mod the window; empty where the cache is pools only."""
+        """{name: array} of what the sequence's lane carries, by the cache's
+        own names, as the last ``admit`` or ``step`` left it. Without
+        ``names``: its row of every per-lane buffer. A recurrent model's
+        state; a window layer's rings (heads, window, d), position p at slot
+        p mod the window; empty where the cache is pools only. A POOL is
+        named like the rest, whoever reads it (``phi4flash``'s one pair is
+        eight layers'): ``names`` that hold one get the lane's own
+        positions of it, (heads, position, d), gathered from its pages in
+        order, whichever layout the pool is bound in."""
         idx = self._seq_lane.get(seq_id)
         if idx is None:
             raise MXNetError("paged_kv: unknown seq_id %r" % (seq_id,))
         self.warmup()
-        return {name: self._dec_exe.arg_dict[name]._jax()[idx]
-                for name, kind, _ in self._cache if kind != "pool"
-                and (names is None or name in names)}
+        out = {}
+        for name, kind, shape in self._cache:
+            wanted = kind != "pool" if names is None else name in names
+            if not wanted:
+                continue
+            buf = self._dec_exe.arg_dict[name]._jax()
+            if kind != "pool":
+                out[name] = buf[idx]
+                continue
+            slots = self._lane_slots(self._lanes[idx])
+            out[name] = buf.reshape((-1,) + tuple(shape))[slots].transpose(
+                1, 0, 2) if pool_paged(*shape) else buf[:, slots, :]
+        return out
 
     # ----------------------------------------------------- fork / rollback
     def fork(self, seq_id):

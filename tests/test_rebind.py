@@ -200,6 +200,11 @@ ARCHS = {
         mamba_head_dim=8, mamba_state=8, mamba_conv=4, mamba_chunk=8,
         embedding_multiplier=12.0, attention_multiplier=0.125,
         residual_multiplier=0.22, logits_scaling=8.0, rms_eps=1e-5),
+    # rows, rings AND one pool that two layers read (layers 5 and 7 of 8)
+    "phi4flash": dict(
+        arch="phi4flash", vocab_size=60, num_layers=8, num_heads=4,
+        num_kv_heads=2, head_dim=8, model_dim=32, ffn_dim=48,
+        sliding_window=4, mamba_state=4, mamba_dt_rank=3),
 }
 S, PAGE, LANES, PREFILL = 32, 4, 3, 16
 
@@ -254,7 +259,8 @@ def _admit_two(dec):
     ("vaswani", "step"), ("vaswani", "admit"), ("vaswani", "step_megastep"),
     ("vaswani", "chunked_admit"), ("vaswani", "copy_on_write"),
     ("olmoe", "step"), ("olmoe", "admit"),
-    ("granite_hybrid", "step"), ("granite_hybrid", "admit")])
+    ("granite_hybrid", "step"), ("granite_hybrid", "admit"),
+    ("phi4flash", "step"), ("phi4flash", "admit")])
 def test_the_decoder_hands_every_buffer_on_by_reference(tm, arch, what):
     """A steady step: the cache's buffers and the four staged inputs; an
     admission, a megastep and a chunk: the cache's buffers (their inputs are
@@ -264,7 +270,8 @@ def test_the_decoder_hands_every_buffer_on_by_reference(tm, arch, what):
     dec = _decoder(arch, **(dict(prefix_cache=True, prefix_chunk=4)
                             if chunked else {})).warmup()
     cache = len(dec._cache_names)
-    assert cache == {"vaswani": 4, "olmoe": 4, "granite_hybrid": 6}[arch]
+    assert cache == {"vaswani": 4, "olmoe": 4, "granite_hybrid": 6,
+                     "phi4flash": 12}[arch]
     if what in ("admit", "chunked_admit"):
         per_call = [cache * (-(-len(p) // 4) if chunked else 1)
                     for p in PROMPTS]

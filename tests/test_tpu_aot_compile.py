@@ -180,7 +180,9 @@ def _assert_expert_layers(hlo, layers, tokens, k, experts, d, f):
     calls = [line for line in hlo.splitlines() if " custom-call(" in line
              and 'custom_call_target="tpu_custom_call"' in line
              and "/grouped_matmul" in line]
-    ragged = "ragged" in hlo.lower()    # XLA's form, by its name
+    # XLA's form, by its name among the instructions: the tables of source
+    # names in front of them hold whatever test first traced a cached helper
+    ragged = "ragged" in _program_alone(hlo).lower()
     if form == "kernel":
         assert len(calls) == 2 * layers and not ragged
         assert sum("/grouped_matmul_gated/" in line for line in calls) \
@@ -886,3 +888,147 @@ def test_a_pool_of_narrow_heads_reads_through_the_kernel(v5e, monkeypatch,
     assert "kv_mask" in whole.as_text()
     assert kernel.cost_analysis()["bytes accessed"] \
         < whole.cost_analysis()["bytes accessed"]
+
+
+_PHI4 = dict(arch="phi4flash", vocab_size=200064, num_layers=32, num_heads=40,
+             num_kv_heads=20, head_dim=64, model_dim=2560, ffn_dim=10240,
+             sliding_window=512, mb_per_layer=2, mamba_state=16, mamba_conv=4,
+             mamba_expand=2, mamba_dt_rank=160, dtype="bfloat16")
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode", "admit_scatter"])
+def test_phi4flash_serving_programs_compile_for_the_chip(v5e, program):
+    """The three programs ``PagedKVDecoder(arch="phi4flash")`` runs, lowered
+    for the v5e at Phi-4-mini-flash-reasoning's published sizes, whole
+    (3,852,562,944 parameters in bfloat16) and the cell's serving sizes (64
+    lanes x 8,192 slots, a 2,048 bucket). What has to hold on the chip: the
+    cache is 18 float32 rows, 16 rings (64, 10, 512, 128) and ONE page-major
+    pool pair (32,768, 16, 1,280), in layer order, each back in the type it
+    went in and updated in place; the step reads that one pair through the
+    KERNEL that walks the page table EIGHT times (layer 17 and the seven
+    cross layers, 40 query heads over 10 key/value heads of 128) and writes
+    it once; the prefill's logits are ONE row, its nine scans are loops that
+    never make a state history, its window layers score a band; and
+    everything fits beside 7.7 GB of weights: 11.96 GB of arguments to a
+    step, as the configuration's arithmetic says."""
+    from types import SimpleNamespace
+
+    from mxnet_tpu.models import transformer as tf
+    from mxnet_tpu.ops.attention import pool_read_form, pool_shape
+    from mxnet_tpu.serving.kv_decode import _AdmitScatter
+
+    lanes, max_len, bucket, page = 64, 8192, 2048, 16
+    slots, cfg = lanes * max_len, _PHI4
+    shapes = tf.param_shapes(**cfg)
+    assert sum(math.prod(s) for s in shapes.values()) == 3_852_562_944
+    weights = {n: (s, "bfloat16") for n, s in shapes.items()}
+    cache = tf.decode_cache(**cfg)
+    kinds = [kind for _, kind, _ in cache]
+    assert kinds == (["row"] * 2 + ["ring"] * 2) * 8 + ["row"] * 2 \
+        + ["pool"] * 2
+    buffers = [(pool_shape(*shape, slots, page) if kind == "pool"
+                else (lanes,) + shape,
+                "float32" if kind == "row" else "bfloat16")
+               for _, kind, shape in cache]
+    assert buffers[-1] == ((slots // page, page, 1280), "bfloat16")
+    cache_bytes = sum(math.prod(shape) * (4 if t == "float32" else 2)
+                      for shape, t in buffers)
+    # the pool 2.68 GB, the rings 1.34, the rows 0.22
+    assert cache_bytes == slots * 5120 + 16 * lanes * 10 * 512 * 128 * 2 \
+        + 9 * lanes * (16 + 3) * 5120 * 4
+    exported = [((1,) + shape, "float32") if kind == "row"
+                else ((1, shape[0], bucket, shape[-1]), "bfloat16")
+                for _, kind, shape in cache]
+    if program == "admit_scatter":
+        prog = _AdmitScatter(SimpleNamespace(
+            _cache=cache, page_size=page, prefill_len=bucket))
+        spec = lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, jnp.dtype(dtype), sharding=v5e)
+        compiled = prog._fn.lower(
+            tuple(spec(*b) for b in buffers),
+            tuple(spec(*n) for n in exported),
+            spec((bucket // page,), "int32"), spec((2,), "int32"),
+        ).compile()
+        mem = compiled.memory_analysis()
+        # every buffer of the cache is updated in place: rows, rings, pool
+        assert mem.alias_size_in_bytes == cache_bytes
+        assert mem.temp_size_in_bytes < 24 << 20
+        _assert_no_pool_sized_copy(compiled.as_text(), 10 * slots * 128)
+        return
+    if program == "prefill":
+        sym = tf.get_prefill_symbol(prefill_len=bucket, **cfg)
+        inputs = {"data": ((1, bucket), "float32"),
+                  "length": ((1, 1), "float32")}
+        want = [((1, 200064), "float32")] + exported
+    else:
+        sym = tf.get_decode_symbol(max_len=slots, page_size=page, **cfg)
+        inputs = {"data": ((lanes, 1), "float32"),
+                  "pos_idx": ((lanes, 1), "float32"),
+                  "write_slot": ((lanes, 1), "float32"),
+                  "page_table": ((lanes, max_len // page), "float32")}
+        inputs.update({name: b for (name, _, _), b in zip(cache, buffers)})
+        want = [((lanes, 200064), "float32")] + buffers \
+            + [((lanes,), "float32")]
+    compiled = _compile_program(
+        v5e, sym, {**weights, **inputs},
+        donated=[name for name, _, _ in cache] if program == "decode" else ())
+    assert [(s.shape, str(s.dtype)) for s in compiled.out_info[0]] == want
+    hlo = compiled.as_text()
+    mem = compiled.memory_analysis()
+    found = [(math.prod(int(d) for d in dims.split(",") if d), op)
+             for _n, dims, op, _a in _INSTRUCTION.findall(hlo)
+             if op != "parameter"]
+    if program == "prefill":
+        # nine scans, each a loop; the largest thing made is a window
+        # layer's band of float32 scores, 40 x 2,048 x 1,024, an eighth of
+        # what a state history (2,048 x 16 x 5,120) or full scores would be
+        assert hlo.count(" while(") == 9
+        # (the tied head over ONE row is a multiply and a sum inside a
+        # fusion: the table's own size is listed and never made)
+        assert max(n for n, _ in found if n != 200064 * 2560) \
+            <= 40 * bucket * 1024
+        assert not _paged_read_calls(hlo)
+        assert mem.temp_size_in_bytes < 1 << 30
+        return
+    # the rule, asked as the operator asks it
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+    assert pool_read_form(
+        struct((lanes, 40, 128), "bfloat16"),
+        struct(buffers[-2][0], "bfloat16"), struct(buffers[-1][0], "bfloat16"),
+        struct((lanes, max_len // page), "float32"), page) == "kernel"
+    calls = _paged_read_calls(hlo)
+    assert len(calls) == 8
+    for i, tag in [(17, "self")] + [(i, "cross") for i in range(19, 32, 2)]:
+        assert sum("layer%d_%s_att/" % (i, tag) in line for line in calls) == 1
+    # ONE write of the pool pair: one loop, a row a lane into each
+    assert hlo.count(" while(") == 1
+    assert sum("layer17_kvupd/" in line for line in hlo.splitlines()
+               if " dynamic-update-slice(" in line) == 2
+    # no read's mask is built and the pool is not re-laid
+    assert "kv_mask" not in hlo and "slot_onehot" not in hlo
+    _assert_no_pool_sized_copy(hlo, 10 * slots * 128)
+    # the step updates every row, ring and the pool in place, and holds
+    # weights and cache once: 11.96 GB
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.argument_size_in_bytes < 11_970_000_000
+    assert mem.temp_size_in_bytes < 128 << 20
+
+
+def test_the_page_walk_at_forty_query_heads_over_ten_of_128(v5e):
+    """The kernel alone at the shape the padded-query form of differential
+    attention gives it: 64 rows of 40 query heads of 128 over page-major
+    pools of 10 key/value heads of 128 (a row of 1,280: ten whole tiles),
+    four query heads a key/value head, ``scale`` 1/8 of the 64-wide heads
+    the zeros pad."""
+    from mxnet_tpu.ops import pallas_paged_read as kernel
+    from mxnet_tpu.ops.attention import pool_shape
+
+    lanes, max_len, page = 64, 8192, 16
+    pool = (pool_shape(10, 128, lanes * max_len, page), "bfloat16")
+    struct = lambda sd: jax.ShapeDtypeStruct(sd[0], jnp.dtype(sd[1]))
+    query = ((lanes, 40, 128), "bfloat16")
+    assert kernel.supported(struct(query), struct(pool), struct(pool))
+    fn = lambda q, k, v, table, context: kernel.paged_read(
+        q, k, v, table, context, scale=0.125, interpret=False)
+    _compile(v5e, fn, query, pool, pool,
+             ((lanes, max_len // page), "int32"), ((lanes,), "int32"))
