@@ -223,12 +223,23 @@ def _vaswani_head(x, vocab_size, model_dim):
         name="lm_head")
 
 
+def _last_real_row(x, length):
+    """(B, T, ...) -> (B, 1, ...): of each prompt its last real row, row
+    ``length - 1`` of its own bucket (``length`` (B, 1); ``batch_take`` clips
+    the index into the bucket, so a length of 0 reads row 0). A prefill's
+    final norm and head run over this row alone: it is the one row of logits
+    an admission hands out, and a gather changes no value and no type."""
+    return sym.expand_dims(
+        sym.batch_take(x, sym.Reshape(length, shape=(-1,)) - 1.0), axis=1)
+
+
 def _vaswani_sequence(vocab_size, num_layers, num_heads, model_dim, ffn_dim,
-                      seq_len, pos_len=None, kvs=None):
+                      seq_len, pos_len=None, kvs=None, length=None):
     """The decoder-only stack over ``data`` (B, seq_len) -> logits
     (B·seq_len, vocab): training (``get_symbol``) and the serving prefill,
-    which reads the first ``seq_len`` rows of a ``pos_len``-row position table
-    and collects every layer's K/V in ``kvs``."""
+    which reads the first ``seq_len`` rows of a ``pos_len``-row position table,
+    collects every layer's K/V in ``kvs`` and, given each prompt's ``length``
+    (B, 1), heads its last real row alone: logits (B, vocab)."""
     pos_len = pos_len or seq_len
     data = sym.Variable("data")  # (B, T) int tokens
     embed = sym.Embedding(data=data, input_dim=vocab_size,
@@ -246,6 +257,8 @@ def _vaswani_sequence(vocab_size, num_layers, num_heads, model_dim, ffn_dim,
 
     for i in range(num_layers):
         x = _vaswani_layer(x, i, attend, model_dim, ffn_dim)
+    if length is not None:
+        x = _last_real_row(x, length)
     return _vaswani_head(x, vocab_size, model_dim)
 
 
@@ -274,7 +287,14 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     ``prefill_len`` by the caller, and causality guarantees pad tokens
     cannot influence earlier positions.
 
-    Outputs: ``[logits (B·P, vocab), k_0, v_0, ..., k_{L-1}, v_{L-1}]``
+    Inputs, for every ``arch``: ``data`` (B, P) and ``length`` (B, 1), the
+    real tokens of each prompt. The layers run over the bucket; the final
+    norm and the head run over each prompt's LAST REAL ROW alone, row
+    ``length - 1`` gathered inside the graph (``_last_real_row``; the index
+    is clipped into the bucket), because that row's logits are all an
+    admission hands out. What the cache keeps is exported over the bucket.
+
+    Outputs: ``[logits (B, vocab), k_0, v_0, ..., k_{L-1}, v_{L-1}]``
     with each k/v of shape (B, H, P, dh).
 
     ``arch="olmoe"`` builds the sparse-expert block instead (``_olmoe_layer``;
@@ -285,9 +305,9 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     each expert received, padding positions included.
 
     ``arch="granite_hybrid"`` builds the Mamba-2 / attention hybrid block
-    (``_granite_layer``, its mixer chosen by ``layer_types``). Its prefill
-    takes a second input, ``length`` (B, 1): a recurrence, unlike causal
-    attention, reads its padding unless told where the prompt ends. After the
+    (``_granite_layer``, its mixer chosen by ``layer_types``). Its scans
+    read ``length`` too: a recurrence, unlike causal attention, reads its
+    padding unless told where the prompt ends. After the
     logits come the cache's values in ``decode_cache`` order: a Mamba layer's
     recurrent state at ``length`` and its last convolution columns, float32,
     an attention layer's K and V (B, Hkv, P, dh).
@@ -301,8 +321,8 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
 
     ``arch="lfm2_moe"`` builds the gated-short-convolution / attention block
     with sparse experts (``_lfm2_moe_layer``, its mixer chosen by
-    ``layer_types``, its feed-forward by depth). Its prefill takes
-    ``length`` (B, 1) as ``granite_hybrid``'s does: a convolution's state is
+    ``layer_types``, its feed-forward by depth). Its convolutions read
+    ``length`` as ``granite_hybrid``'s scans do: a convolution's state is
     the last columns of the PROMPT, not of the bucket. After the logits come
     the cache's values in ``decode_cache`` order: a conv layer's last
     ``conv_kernel - 1`` gated columns before ``length``, float32, an
@@ -320,11 +340,11 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
 
     ``arch="phi4flash"`` builds the SambaY block with differential attention
     (``_phi4flash_layer``, its mixer chosen by depth and parity), for ONE
-    prompt a call. It takes ``length`` (1, 1). Layers 0 to N/2 and layer
-    N/2 + 1's keys and values run over the bucket; that layer's query, its
-    MLP and every layer behind it run over the prompt's LAST real row alone,
-    gathered by the length, with the row of ``m`` (layer N/2's scan before
-    its gate) that belongs to it. The logits are that one row, (1, vocab).
+    prompt a call (``length`` (1, 1)). It narrows EARLIER than the others:
+    layers 0 to N/2 and layer N/2 + 1's keys and values run over the bucket;
+    that layer's query, its MLP and every layer behind it run over the
+    prompt's last real row alone, with the row of ``m`` (layer N/2's scan
+    before its gate) that belongs to it.
     After them come the cache's values in ``decode_cache`` order: a Mamba-1
     layer's state at ``length`` (1, N, E) and its last convolution columns,
     float32; a window layer's and layer N/2 + 1's K and V
@@ -345,7 +365,8 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     _refuse_arch(arch, "get_prefill_symbol")
     kvs = []
     logits = _vaswani_sequence(vocab_size, num_layers, num_heads, model_dim,
-                               ffn_dim, prefill_len, pos_len, kvs)
+                               ffn_dim, prefill_len, pos_len, kvs,
+                               length=sym.Variable("length"))
     return sym.Group([logits] + kvs)
 
 
@@ -707,7 +728,8 @@ def _olmoe_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
     for i in range(num_layers):
         x, load = _olmoe_layer(x, i, positions, prefill_len, attend, **block)
         loads.append(sym.Reshape(load, shape=(1, -1)))
-    logits = _olmoe_head(x, vocab_size, block["model_dim"], block["rms_eps"])
+    logits = _olmoe_head(_last_real_row(x, sym.Variable("length")),
+                         vocab_size, block["model_dim"], block["rms_eps"])
     return sym.Group([logits] + kvs
                      + [sym.Concat(*loads, dim=0, name="moe_load")])
 
@@ -852,9 +874,12 @@ def _granite_layer(x, i, seq_len, attend, scan, block):
                           block["ffn_dim"], d, "mlp") * res
 
 
-def _granite_stack(data, vocab_size, num_layers, seq_len, attend, scan, block):
+def _granite_stack(data, vocab_size, num_layers, seq_len, attend, scan, block,
+                   length=None):
     """Embedding (scaled, and tied to the head), the layers, the final norm
-    and the head: ``data`` (B, T) -> float32 logits (B·T, vocab)."""
+    and the head: ``data`` (B, T) -> float32 logits (B·T, vocab), or
+    (B, vocab) where a prefill gives each prompt's ``length``
+    (``_last_real_row``)."""
     table = sym.Variable("embed_weight")
     d = block["model_dim"]
     x = sym.Embedding(data=data, weight=table, input_dim=vocab_size,
@@ -862,6 +887,8 @@ def _granite_stack(data, vocab_size, num_layers, seq_len, attend, scan, block):
         * block["embedding_multiplier"]
     for i in range(num_layers):
         x = _granite_layer(x, i, seq_len, attend, scan, block)
+    if length is not None:
+        x = _last_real_row(x, length)
     x = sym.RMSNorm(x, eps=block["rms_eps"], name="final_ln")
     return sym.FullyConnected(
         data=sym.Reshape(x, shape=(-1, d)), weight=table,
@@ -887,7 +914,7 @@ def _granite_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
         return core[0]
 
     logits = _granite_stack(sym.Variable("data"), vocab_size, num_layers,
-                            prefill_len, attend, scan, block)
+                            prefill_len, attend, scan, block, length=length)
     return sym.Group([logits] + cache)
 
 
@@ -1025,11 +1052,12 @@ def _deepseek_v3_layer(x, i, positions, seq_len, attend, block):
 
 
 def _deepseek_v3_stack(vocab_size, seq_len, positions, attend, block,
-                       layer=None):
+                       layer=None, length=None):
     """Embedding, the layers, final norm and untied head: ``data`` (B, T) ->
-    (float32 logits (B·T, vocab), moe_load (expert layers, experts)).
-    ``layer``: another block's layer of the same signature
-    (``_mimo_layer``)."""
+    (float32 logits (B·T, vocab), moe_load (expert layers, experts)); logits
+    (B, vocab) where a prefill gives each prompt's ``length``
+    (``_last_real_row``). ``layer``: another block's layer of the same
+    signature (``_mimo_layer``)."""
     layer = layer or _deepseek_v3_layer
     x = sym.Embedding(data=sym.Variable("data"), input_dim=vocab_size,
                       output_dim=block["model_dim"], name="embed")
@@ -1038,6 +1066,8 @@ def _deepseek_v3_stack(vocab_size, seq_len, positions, attend, block,
         x, load = layer(x, i, positions, seq_len, attend, block)
         if load is not None:
             loads.append(sym.Reshape(load, shape=(1, -1)))
+    if length is not None:
+        x = _last_real_row(x, length)
     logits = _olmoe_head(x, vocab_size, block["model_dim"], block["rms_eps"])
     return logits, [sym.Concat(*loads, dim=0, name="moe_load")] if loads \
         else []
@@ -1068,7 +1098,8 @@ def _deepseek_v3_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
             causal=True, scale=block["scale"], name="layer%d_att" % i)
 
     logits, load = _deepseek_v3_stack(vocab_size, prefill_len, positions,
-                                      attend, block)
+                                      attend, block,
+                                      length=sym.Variable("length"))
     return sym.Group([logits] + cache + load)
 
 
@@ -1221,10 +1252,12 @@ def _lfm2_moe_layer(x, i, positions, seq_len, attend, conv, block):
     return x + sym.Reshape(moe[0], shape=(-1, seq_len, d)), moe[1]
 
 
-def _lfm2_moe_stack(vocab_size, seq_len, positions, attend, conv, block):
+def _lfm2_moe_stack(vocab_size, seq_len, positions, attend, conv, block,
+                    length=None):
     """Embedding (tied to the head), the layers, the final norm and the head:
     ``data`` (B, T) -> (float32 logits (B·T, vocab), moe_load (expert layers,
-    experts))."""
+    experts)); logits (B, vocab) where a prefill gives each prompt's
+    ``length`` (``_last_real_row``)."""
     table = sym.Variable("embed_weight")
     d = block["model_dim"]
     x = sym.Embedding(data=sym.Variable("data"), weight=table,
@@ -1235,6 +1268,8 @@ def _lfm2_moe_stack(vocab_size, seq_len, positions, attend, conv, block):
                                   block)
         if load is not None:
             loads.append(sym.Reshape(load, shape=(1, -1)))
+    if length is not None:
+        x = _last_real_row(x, length)
     x = sym.RMSNorm(x, eps=block["rms_eps"], name="final_ln")
     logits = sym.FullyConnected(
         data=sym.Reshape(x, shape=(-1, d)), weight=table,
@@ -1269,7 +1304,7 @@ def _lfm2_moe_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
         return core[0]
 
     logits, load = _lfm2_moe_stack(vocab_size, prefill_len, positions,
-                                   attend, conv, block)
+                                   attend, conv, block, length=length)
     return sym.Group([logits] + cache + load)
 
 
@@ -1442,7 +1477,8 @@ def _mimo_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
             sink=True, name="layer%d_att" % i)
 
     logits, load = _deepseek_v3_stack(vocab_size, prefill_len, positions,
-                                      attend, block, layer=_mimo_layer)
+                                      attend, block, layer=_mimo_layer,
+                                      length=sym.Variable("length"))
     return sym.Group([logits] + cache + load)
 
 
@@ -1717,7 +1753,6 @@ def _phi4flash_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
     seen = (1.0 - sym.broadcast_lesser(
         sym.Reshape(sym._arange(start=0, stop=prefill_len),
                     shape=(1, prefill_len)), length)) * float(_NEG)
-    at_end = sym.Reshape(length, shape=(-1,)) - 1.0
 
     def scan(i, u):
         core = _phi4flash_mamba(sym.Mamba1Scan, i, u, block, length=length)
@@ -1749,7 +1784,7 @@ def _phi4flash_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
 
     mix = dict(scan=scan, window=window, full=full,
                cross=lambda i, q: read(i, q, "cross"),
-               last_row=lambda a: sym.take(a, at_end, axis=1))
+               last_row=lambda a: _last_real_row(a, length))
     return sym.Group([_phi4flash_stack(vocab_size, prefill_len, mix, block)]
                      + cache)
 

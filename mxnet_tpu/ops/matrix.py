@@ -280,8 +280,11 @@ def _take(attrs, a, indices):
 
 @register("batch_take", input_names=("a", "indices"))
 def _batch_take(attrs, a, indices):
-    """out[i] = a[i, indices[i]] (reference: indexing_op.cc batch_take)."""
-    return jnp.take_along_axis(a, indices.astype(jnp.int32)[:, None], axis=1)[:, 0]
+    """out[i] = a[i, indices[i]], the index clipped into a's second axis
+    (reference: indexing_op.cc batch_take, which clamps it). Rows of a matrix
+    there; here a (B, T, ...) takes out[i] = a[i, indices[i], ...] too."""
+    idx = indices.astype(jnp.int32).reshape((-1,) + (1,) * (a.ndim - 1))
+    return jnp.take_along_axis(a, idx, axis=1, mode="clip")[:, 0]
 
 
 @register(

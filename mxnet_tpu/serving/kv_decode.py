@@ -760,8 +760,7 @@ class _AdmitScatter(_SealedProgram):
         buffer comes back bitwise as it went in. The new values come from a
         prefill staged the way an admission stages it, so the arrays are of
         the kind a real call passes and jit never compiles again."""
-        pf = dec._pf_cache.executable(dec._prefill_shapes())
-        pf.arg_dict["data"][:] = np.zeros((1, dec.prefill_len), np.float32)
+        pf = dec._stage_prefill(np.zeros((1, 0), np.float32))
         pf.forward(is_train=False)
         return dec._prefill_cache(pf), ((), 0, 0)
 
@@ -1098,19 +1097,19 @@ class PagedKVDecoder:
         self._pf_cache = PersistentExecutableCache(
             prefill, arg_params, {}, model_key=key + "-prefill",
             program_label="mx_prefill", **binding)
-        self._prefill_takes_length = "length" in self._pf_cache.input_names
         self._dec_cache = PersistentExecutableCache(
             decode, arg_params, {}, model_key=key + "-decode",
             program_label="mx_decode", donated=self._cache_names, **binding)
         # readers of a pool past its first: layers that read what another
-        # layer writes; and the rows of the bucket an admission runs them
-        # over (read off the bound prefill at warmup: fewer than the
-        # bucket's where it narrows to the prompt's last row; 0 where no
-        # pool is shared)
+        # layer writes; and the rows of logits an admission's head computes,
+        # read off the bound prefill at warmup: 1 while the graph narrows to
+        # the prompt's last real row (where a pool is shared, the rows the
+        # cross layers ran over too)
         readers = _pool_readers(decode)
         self._shared_readers = len(readers) - len(
             {id(pool) for _, pool in readers})
-        self._cross_rows = 0
+        self._head_rows = 0
+        self._lengths = {}          # prompt length -> its (1, 1) device array
         self._dec_exe = None
         self._decode_xla_bytes = None  # read at warmup when telemetry is on
         self._step_gathered_slots = 0  # likewise: slots a dispatch scores
@@ -1158,13 +1157,10 @@ class PagedKVDecoder:
         return shapes
 
     def _prefill_shapes(self):
-        """The prefill bucket's inputs: the padded prompt and, where the
-        graph asks for it, the prompt's length (a recurrence reads its
-        padding unless told where the prompt ends)."""
-        shapes = {"data": (1, self.prefill_len)}
-        if self._prefill_takes_length:
-            shapes["length"] = (1, 1)
-        return shapes
+        """The prefill bucket's inputs: the padded prompt and its length (the
+        head runs over the prompt's last real row alone; a recurrence reads
+        its padding unless told where the prompt ends)."""
+        return {"data": (1, self.prefill_len), "length": (1, 1)}
 
     def warmup(self, release_outputs=False):
         """Compile the multiplexed decode executable plus the admit-side
@@ -1239,12 +1235,31 @@ class PagedKVDecoder:
             self._pf_cache.warmup([self._prefill_shapes()])
             self._admit_scatter = _AdmitScatter(self)
             self._admit_scatter.warm(self)
-            if self._shared_readers:    # the warm prefill's rows of logits
-                self._cross_rows = self._pf_cache.executable(
-                    self._prefill_shapes()).outputs[0].shape[0]
+            # the warm prefill's rows of logits
+            self._head_rows = self._pf_cache.executable(
+                self._prefill_shapes()).outputs[0].shape[0]
         else:
             self._chunk_for(self.prefix_chunk)
         return self
+
+    def _stage_prefill(self, prompt):
+        """The prefill executable with ``prompt`` (1, L) staged: the bucket,
+        right-padded with zeros, in ONE transfer, and the length by
+        reference: a host-to-device copy costs the host a quarter of a
+        millisecond however small, so a length's (1, 1) array is put on the
+        device once and kept (at most ``prefill_len + 1`` of four bytes)."""
+        import jax
+
+        pf = self._pf_cache.executable(self._prefill_shapes())
+        L = prompt.shape[1]
+        padded = np.zeros((1, self.prefill_len), np.float32)
+        padded[:, :L] = prompt
+        length = self._lengths.get(L)
+        if length is None:
+            length = self._lengths[L] = jax.device_put(
+                np.full((1, 1), L, np.float32))
+        pf.rebind(("data", "length"), (jax.device_put(padded), length))
+        return pf
 
     def _prefill_cache(self, pf):
         """The prefill executable's cache outputs, in the cache's order."""
@@ -1398,40 +1413,34 @@ class PagedKVDecoder:
         # a frame per page of the prompt, acquired before any device work
         for p in range(0, L, self.page_size):
             self._phys_slot(lane, p)
-        padded = np.zeros((1, self.prefill_len), np.float32)
-        padded[:, :L] = prompt
         with _tm.span("serving.paged_admit", seq=lane.seq_id,
                       prompt_len=L, lane=idx):
             with _tm.span("serving.admit.stage"):
-                pf = self._pf_cache.executable(self._prefill_shapes())
-                pf.arg_dict["data"][:] = padded
-                if self._prefill_takes_length:
-                    pf.arg_dict["length"][:] = np.full((1, 1), L, np.float32)
+                pf = self._stage_prefill(prompt)
             with _tm.span("serving.admit.prefill"):
                 pf.forward(is_train=False)
-            with _tm.span("serving.admit.logits"):
+                # the graph narrowed to the prompt's last real row itself:
+                # (1, vocab) sets out for the host as it is, behind the
+                # prefill alone, with no program between them
                 row = pf.outputs[0]._jax()
-                # a graph that narrowed to the prompt's last real row itself
-                # (``phi4flash``) hands back that row alone
-                row = row[0] if row.shape[0] < self.prefill_len \
-                    else row.reshape(1, self.prefill_len,
-                                     self.vocab_size)[0, L - 1, :]
-                # the host blocked while the device runs the prefill; what
-                # is left of `logits` is enqueueing the two op-by-op
-                # programs above and reading one row
                 row.copy_to_host_async()
-                with _tm.span("serving.admit.wait"):
-                    row.block_until_ready()
-                logits = np.asarray(row)
-            # the pool update stays on the device; only the last
-            # position's logits crossed above
+            # the pool update stays on the device, and is enqueued BEFORE
+            # the row is waited for: the device runs the prefill meanwhile
             with _tm.span("serving.admit.scatter"):
                 self._admit_scatter.run(self, self._prefill_cache(pf),
                                         lane.frames, L, idx)
-        if self._cross_rows and _tm.enabled():
-            # the bucket's rows either half of the depth computed
-            _tm.counter("serving.admit_self_rows").inc(self.prefill_len)
-            _tm.counter("serving.admit_cross_rows").inc(self._cross_rows)
+            with _tm.span("serving.admit.logits"):
+                # the host blocked while the device runs what is left of
+                # the prefill; then one row is read and indexed on the host
+                with _tm.span("serving.admit.wait"):
+                    row.block_until_ready()
+                logits = np.asarray(row)[0]
+        if _tm.enabled():
+            _tm.counter("serving.admit_head_rows").inc(self._head_rows)
+            if self._shared_readers:
+                # the bucket's rows either half of the depth computed
+                _tm.counter("serving.admit_self_rows").inc(self.prefill_len)
+                _tm.counter("serving.admit_cross_rows").inc(self._head_rows)
         if self._pf_moe_load is not None and _tm.enabled():
             # rows each expert received, per layer, over every position the
             # prefill computed (padding included: the grouped matmul's work)

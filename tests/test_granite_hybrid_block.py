@@ -323,9 +323,10 @@ def test_admit_then_steps_agree_with_the_full_forward(dtype, tol, length):
 
 def test_padding_of_the_bucket_never_reaches_the_state():
     """The same prompt, the bucket's padding filled with two different
-    things: the prefill's logits before the length, the state at the length
-    and the convolution columns are the same bit for bit (positions past the
-    length get dt = 0, the columns a slice that starts at the length)."""
+    things: the prefill's one row of logits (the prompt's last real row),
+    the state at the length and the convolution columns are the same bit for
+    bit (positions past the length get dt = 0, the columns a slice that
+    starts at the length); the padding's K and V are not."""
     params = _weights("float32")
     sym = tf.get_prefill_symbol(prefill_len=32, **CFG)
     names = sym.list_arguments()
@@ -344,14 +345,14 @@ def test_padding_of_the_bucket_never_reaches_the_state():
         return [o.asnumpy() for o in exe.outputs]
 
     a, b = run(0), run(417)
-    assert np.array_equal(a[0][:length], b[0][:length])
-    assert not np.array_equal(a[0][length:], b[0][length:])
+    assert a[0].shape == (1, CFG["vocab_size"]) and np.array_equal(a[0], b[0])
     cache = tf.decode_cache(**CFG)
     for (name, kind, _), x, y in zip(cache, a[1:], b[1:]):
         if kind == "row":
             assert np.array_equal(x, y), name
         else:   # K and V of the real positions
             assert np.array_equal(x[:, :, :length], y[:, :, :length]), name
+            assert not np.array_equal(x[:, :, length:], y[:, :, length:]), name
 
 
 def test_lanes_stepped_alternately_leave_each_others_state_alone():

@@ -176,7 +176,7 @@ def test_serving_symbols_share_training_weight_names():
     serving_only = {"data", "pos_idx", "write_slot", "page_table"} | \
         {"kv_%s_%d" % (t, i) for t in ("k", "v")
          for i in range(CFG["num_layers"])}
-    assert (pf_args - {"data"}) <= train_args
+    assert (pf_args - {"data", "length"}) <= train_args
     assert (dec_args - serving_only) <= train_args
 
 
@@ -576,7 +576,7 @@ def test_dispatch_host_gap_timer_ticks_only_when_enabled(tm):
 
 
 # ------------------------------------------------ phase spans and XLA bytes
-ADMIT_PHASES = ("stage", "prefill", "logits", "scatter")
+ADMIT_PHASES = ("stage", "prefill", "scatter", "logits")
 STEP_PHASES = ("stage", "dispatch", "read", "commit")
 
 
@@ -955,7 +955,7 @@ def test_admit_pool_update_is_bitwise_the_op_by_op_scatter(case, layout):
     if case == "frames-not-contiguous":
         assert np.any(np.abs(np.diff(lane.frames)) != 1)
     phys = [lane.frames[p // page] * page + p % page for p in range(L)]
-    pf = dec._pf_cache.executable({"data": (1, P)})
+    pf = dec._pf_cache.executable(dec._prefill_shapes())
     for old, got, new in zip(before, _pool(dec), pf.outputs[1:]):
         want = old.copy()
         want[:, phys, :] = np.asarray(new._jax())[0, :, :L, :]
@@ -1007,7 +1007,7 @@ def test_admit_scatter_is_one_sealed_donated_program(tm):
 
     # a drifted signature is the sealed-program error, before any donation
     held, c0 = _pool(dec), tm.counters()
-    pf = dec._pf_cache.executable({"data": (1, dec.prefill_len)})
+    pf = dec._pf_cache.executable(dec._prefill_shapes())
     new = dec._prefill_cache(pf)
     with pytest.raises(MXNetError, match="sealed"):
         prog.run(dec, tuple(a[:, :, :-1] for a in new), [0], 1, 0)

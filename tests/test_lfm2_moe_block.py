@@ -104,6 +104,17 @@ def _rel_l2(got, want):
     return np.linalg.norm(got - want, axis=-1) / np.linalg.norm(want, axis=-1)
 
 
+def _every_row(exe, rows):
+    """The bound prefill's one row of logits at every length up to ``rows``:
+    what its head gave over the whole bucket before it narrowed."""
+    out = []
+    for length in range(1, rows + 1):
+        exe.arg_dict["length"][:] = np.full((1, 1), length, "f")
+        exe.forward(is_train=False)
+        out.append(exe.outputs[0].asnumpy())
+    return np.concatenate(out)
+
+
 def _row_error(got, want):
     got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
     return np.max(np.abs(got - want)) / np.sqrt(np.mean(np.square(want)))
@@ -241,8 +252,8 @@ def test_heads_are_normed_one_by_one_before_the_rotation():
         else mx.nd.array(np.full((1, 1), 12, "f")) if n == "length"
         else mx.nd.NDArray(params[n]) for n in sym.list_arguments()},
         grad_req="null")
-    exe.forward(is_train=False)
-    assert _rel_l2(exe.outputs[0].asnumpy(), want).max() < F32_TOL
+    got = _every_row(exe, 12)
+    assert _rel_l2(got, want).max() < F32_TOL
     # the pool's key is the normed, rotated one
     h = ref.rms_norm(params["embed_weight"][toks], params["layer0_ln1_gamma"],
                      1e-5)
@@ -259,7 +270,7 @@ def test_heads_are_normed_one_by_one_before_the_rotation():
         x.transpose(1, 0, 2).reshape(12, -1),
         jnp.tile(gamma, x.shape[0]), eps).reshape(12, -1, 16).transpose(
         1, 0, 2)
-    assert _rel_l2(exe.outputs[0].asnumpy(), np.asarray(
+    assert _rel_l2(got, np.asarray(
         whole.logits(params, jnp.asarray(toks), cfg))).max() > 0.05
 
 
@@ -401,8 +412,8 @@ def test_the_router_selects_on_the_biased_score_and_weighs_by_the_unbiased():
         else mx.nd.array(np.full((1, 1), 10, "f")) if n == "length"
         else mx.nd.NDArray(params[n]) for n in sym.list_arguments()},
         grad_req="null")
-    exe.forward(is_train=False)
-    got = exe.outputs[0].asnumpy()
+    got = _every_row(exe, 10)
+    assert got.shape == (10, 600)
     assert _rel_l2(got, np.asarray(
         ref.logits(params, jnp.asarray(toks), cfg))).max() < F32_TOL
     biased, _ = _faulty("weights_from_biased_scores")
@@ -415,8 +426,9 @@ def test_the_router_selects_on_the_biased_score_and_weighs_by_the_unbiased():
 
 def test_padding_of_the_bucket_never_reaches_the_rows():
     """The same prompt, the bucket's padding filled with two different
-    things: the prefill's logits before the length and every row are the
-    same bit for bit (the rows are a slice that starts at the length)."""
+    things: the prefill's one row of logits (the prompt's last real row) and
+    every row are the same bit for bit (the rows are a slice that starts at
+    the length); the padding's K and V are not."""
     params = _weights("float32")
     sym = tf.get_prefill_symbol(prefill_len=32, **CFG)
     names = sym.list_arguments()
@@ -435,13 +447,13 @@ def test_padding_of_the_bucket_never_reaches_the_rows():
         return [o.asnumpy() for o in exe.outputs]
 
     a, b = run(0), run(417)
-    assert np.array_equal(a[0][:length], b[0][:length])
-    assert not np.array_equal(a[0][length:], b[0][length:])
+    assert a[0].shape == (1, CFG["vocab_size"]) and np.array_equal(a[0], b[0])
     for (name, kind, _), x, y in zip(tf.decode_cache(**CFG), a[1:], b[1:]):
         if kind == "row":
             assert np.array_equal(x, y), name
         else:   # K and V of the real positions
             assert np.array_equal(x[:, :, :length], y[:, :, :length]), name
+            assert not np.array_equal(x[:, :, length:], y[:, :, length:]), name
 
 
 def test_a_lane_that_rides_along_keeps_its_rows():

@@ -223,7 +223,7 @@ def test_moe_load_counts_every_position_and_the_counters_follow(tm):
     dec.warmup()
     before = dict(tm.counters())
     sid, _ = dec.admit(np.arange(300, 330, dtype=np.float32))
-    pf = dec._pf_cache.executable({"data": (1, dec.prefill_len)})
+    pf = dec._pf_cache.executable(dec._prefill_shapes())
     load = pf.outputs[1 + 2 * CFG["num_layers"]].asnumpy()
     assert load.shape == (CFG["num_layers"], CFG["num_experts"])
     # padding included: the prefill computes the whole bucket
@@ -294,7 +294,7 @@ def test_both_executables_hold_the_callers_weight_buffers():
     params = _weights("bfloat16")
     dec = _decoder(params, "bfloat16")
     dec.warmup()
-    pf = dec._pf_cache.executable({"data": (1, dec.prefill_len)})
+    pf = dec._pf_cache.executable(dec._prefill_shapes())
     for exe in (pf, dec._dec_exe):
         for name, arr in params.items():
             held = exe.arg_dict[name]._jax()

@@ -619,7 +619,7 @@ def test_the_admission_skips_the_cross_decoder_and_loses_nothing():
     params = _weights()
     dec = _decoder(params).warmup()
     pf = dec._pf_cache.executable(dec._prefill_shapes())
-    assert pf.outputs[0].shape == (1, 600) and dec._cross_rows == 1
+    assert pf.outputs[0].shape == (1, 600) and dec._head_rows == 1
     toks = np.random.RandomState(4).randint(1, 600, 19)
     whole, logits = dec.admit(toks.astype(np.float32))
     short, _ = dec.admit(toks[:-1].astype(np.float32))

@@ -263,9 +263,10 @@ def _admit_two(dec):
     ("phi4flash", "step"), ("phi4flash", "admit")])
 def test_the_decoder_hands_every_buffer_on_by_reference(tm, arch, what):
     """A steady step: the cache's buffers and the four staged inputs; an
-    admission, a megastep and a chunk: the cache's buffers (their inputs are
-    the program's own arguments, not the executable's); a copied page: the
-    pools. Never a copy."""
+    admission: the cache's buffers and the prefill's two (the bucket and the
+    length, one transfer); a megastep and a chunk: the cache's buffers (their
+    inputs are the program's own arguments, not the executable's); a copied
+    page: the pools. Never a copy."""
     chunked = what == "chunked_admit"
     dec = _decoder(arch, **(dict(prefix_cache=True, prefix_chunk=4)
                             if chunked else {})).warmup()
@@ -273,7 +274,7 @@ def test_the_decoder_hands_every_buffer_on_by_reference(tm, arch, what):
     assert cache == {"vaswani": 4, "olmoe": 4, "granite_hybrid": 6,
                      "phi4flash": 12}[arch]
     if what in ("admit", "chunked_admit"):
-        per_call = [cache * (-(-len(p) // 4) if chunked else 1)
+        per_call = [cache * -(-len(p) // 4) if chunked else cache + 2
                     for p in PROMPTS]
         calls = [lambda p=p: dec.admit(np.asarray(p, np.float32))
                  for p in PROMPTS]

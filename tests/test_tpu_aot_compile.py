@@ -261,6 +261,19 @@ _INSTRUCTION = re.compile(
     r"%?([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(%?([\w.\-]+)")
 
 
+def _assert_one_row_of_logits(compiled, bucket, vocab):
+    """A prefill heads the prompt's last real row alone: its first output is
+    that one row, and nothing in the program is a bucket's rows of logits
+    (``f32[bucket, vocab]``, whatever dimensions of 1 stand around them)."""
+    assert compiled.out_info[0][0].shape == (1, vocab)
+    assert str(compiled.out_info[0][0].dtype) == "float32"
+    rows = [(name, op) for name, dims, op, _ in
+            _INSTRUCTION.findall(compiled.as_text())
+            if [int(d) for d in dims.split(",") if d and int(d) != 1]
+            == [bucket, vocab]]
+    assert not rows, rows
+
+
 def _assert_pool_step_contracts(compiled, layers, rows, heads, slots, dh,
                                 temp_bytes, cache_bytes, page=16):
     """A shared-pool decode program as the chip runs it, its PAGE-MAJOR pools
@@ -335,6 +348,31 @@ def test_transformer_base_decode_step_contracts_on_the_chip(v5e):
     assert compiled.cost_analysis()["bytes accessed"] < 0.6e9
 
 
+def test_transformer_base_prefill_heads_one_row_on_the_chip(v5e):
+    """``transformer-base``'s prefill (two of its six layers, the 1,024
+    bucket, float32) lowered for the v5e: the head runs over the prompt's
+    last real row, gathered by ``length`` inside the program, so the first
+    output is ``(1, vocab)`` and no ``f32[1024, 32000]`` (131 MB) is made;
+    the K and V it exports are over the bucket as before."""
+    from mxnet_tpu.models import transformer as tf
+
+    layers, bucket, vocab = 2, 1024, 32000
+    sym = tf.get_prefill_symbol(
+        vocab_size=vocab, num_layers=layers, num_heads=8, model_dim=512,
+        ffn_dim=2048, prefill_len=bucket, pos_len=bucket)
+    arg_shapes, _, _ = sym.infer_shape(data=(1, bucket), length=(1, 1))
+    compiled = _compile_program(v5e, sym, {
+        n: (shape, "float32")
+        for n, shape in zip(sym.list_arguments(), arg_shapes)})
+    _assert_one_row_of_logits(compiled, bucket, vocab)
+    assert [s.shape for s in compiled.out_info[0][1:]] \
+        == [(1, 8, bucket, 64)] * 2 * layers
+    # 2 x 1,024 x 3.1 M MACs a layer and the dense scores; the head over the
+    # bucket alone was 2 x 1,024 x 16.4 M = 33.6 GFLOP
+    assert compiled.cost_analysis()["flops"] < 20e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 # OLMoE-1B-7B's published widths with one layer, granite-4.0-h-micro's with
 # its first six (five Mamba-2, one attention)
 _OLMOE = dict(arch="olmoe", vocab_size=50304, num_layers=1, num_heads=16,
@@ -368,7 +406,8 @@ def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
     weights = {n: (s, "bfloat16") for n, s in tf.param_shapes(**cfg).items()}
     if program == "prefill":
         sym = tf.get_prefill_symbol(prefill_len=max_len, **cfg)
-        inputs = {"data": ((1, max_len), "float32")}
+        inputs = {"data": ((1, max_len), "float32"),
+                  "length": ((1, 1), "float32")}
     else:
         sym = tf.get_decode_symbol(max_len=slots, page_size=16, **cfg)
         inputs = {"data": ((lanes, 1), "float32"),
@@ -386,9 +425,11 @@ def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
     flops = compiled.cost_analysis()["flops"]
     if program == "prefill":
         # 2 x 2,048 tokens x (67.2 M projections, router and 8 experts +
-        # 8.4 M dense attention + 103 M head) MACs; 64 dense experts a
-        # token would be 2.4 TFLOP
-        assert 0.70e12 < flops < 0.80e12
+        # 8.4 M dense attention) MACs and the head over ONE row; the head
+        # over the bucket was 0.42 TFLOP more, 64 dense experts a token
+        # would be 2.4 TFLOP
+        assert 0.29e12 < flops < 0.35e12
+        _assert_one_row_of_logits(compiled, max_len, 50304)
         assert [str(s.dtype) for s in compiled.out_info[0]] \
             == ["float32", "bfloat16", "bfloat16", "float32"]
     else:
@@ -466,9 +507,11 @@ def test_granite_hybrid_serving_programs_compile_for_the_chip(v5e, program):
             for _, kind, shape in cache if kind == "row")
         assert "slot_onehot" not in hlo
     else:
-        # 2 x 512 tokens x (5 x 76.2 M + 60.8 M + 205.5 M) MACs of matrices
-        # and the head, and the chunked scan's products beside them
-        assert 0.66e12 < compiled.cost_analysis()["flops"] < 0.80e12
+        # 2 x 512 tokens x (5 x 76.2 M + 60.8 M) MACs of matrices and the
+        # chunked scan's products beside them; the head is over ONE row (over
+        # the bucket it was 2 x 512 x 205.5 M = 0.21 TFLOP more)
+        assert 0.44e12 < compiled.cost_analysis()["flops"] < 0.50e12
+        _assert_one_row_of_logits(compiled, bucket, 100352)
         assert mem.temp_size_in_bytes < 400 << 20
 
 
@@ -505,8 +548,9 @@ def test_latent_attention_serving_programs_compile_for_the_chip(v5e, program):
     assert cache == [("kv_c_%d" % i, "pool", (1, 576)) for i in range(layers)]
     if program == "prefill":
         sym = tf.get_prefill_symbol(prefill_len=bucket, **cfg)
-        inputs = {"data": ((1, bucket), "float32")}
-        want = [((bucket, 128256), "float32")] \
+        inputs = {"data": ((1, bucket), "float32"),
+                  "length": ((1, 1), "float32")}
+        want = [((1, 128256), "float32")] \
             + [((1, 1, bucket, 576), "bfloat16")] * layers \
             + [((layers - 1, 128), "float32")]
     else:
@@ -560,6 +604,7 @@ def test_latent_attention_serving_programs_compile_for_the_chip(v5e, program):
         # whole-pool read was 0.51e12
         assert compiled.cost_analysis()["flops"] < 0.1e12
     else:
+        _assert_one_row_of_logits(compiled, bucket, 128256)
         assert mem.temp_size_in_bytes < 400 << 20
 
 
@@ -629,7 +674,7 @@ def test_lfm2_moe_serving_programs_compile_for_the_chip(v5e, program):
         sym = tf.get_prefill_symbol(prefill_len=bucket, **cfg)
         inputs = {"data": ((1, bucket), "float32"),
                   "length": ((1, 1), "float32")}
-        want = [((bucket, 65536), "float32")] \
+        want = [((1, 65536), "float32")] \
             + [((1, 8, bucket, 64), "bfloat16") if kind == "pool"
                else ((1, 2, 2048), "float32") for _, kind, _ in cache] \
             + [((8, 64), "float32")]
@@ -653,7 +698,11 @@ def test_lfm2_moe_serving_programs_compile_for_the_chip(v5e, program):
         assert "layer%d_conv_core/" % i in hlo
     mem = compiled.memory_analysis()
     if program == "prefill":
-        assert mem.temp_size_in_bytes < 100 << 20
+        # an attention layer's float32 scores, 32 x 1,024 x 1,024 = 134 MB,
+        # and the experts' rows: temporaries of their own since no 268 MB
+        # block of logits is there for them to be laid into
+        _assert_one_row_of_logits(compiled, bucket, 65536)
+        assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 256 << 20
         return
     # the rule, asked as the operator asks it
     struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
@@ -745,8 +794,9 @@ def test_mimo_v2_flash_serving_programs_compile_for_the_chip(v5e, program):
         return
     if program == "prefill":
         sym = tf.get_prefill_symbol(prefill_len=bucket, **cfg)
-        inputs = {"data": ((1, bucket), "float32")}
-        want = [((bucket, 19072), "float32")] + exported \
+        inputs = {"data": ((1, bucket), "float32"),
+                  "length": ((1, 1), "float32")}
+        want = [((1, 19072), "float32")] + exported \
             + [((6, 256), "float32")]
     else:
         sym = tf.get_decode_symbol(max_len=slots, page_size=page, **cfg)
@@ -772,6 +822,7 @@ def test_mimo_v2_flash_serving_programs_compile_for_the_chip(v5e, program):
         # the full layers' float32 scores, 64 x 2,048 x 2,048, are the
         # largest thing made; a window layer's are an eighth of that
         assert max(n for n, _ in found) <= 64 * bucket * bucket
+        _assert_one_row_of_logits(compiled, bucket, 19072)
         assert mem.temp_size_in_bytes < 3 << 30
         return
     # the rule, asked as the operator asks it: pools of different width
