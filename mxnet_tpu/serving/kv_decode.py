@@ -299,22 +299,28 @@ def _pool_reads(cache, input_shapes):
 
 
 def _moe_forms(cache, input_shapes):
-    """The form of every ``MoEFeedForward`` node of a program's graph at
-    these input shapes, ``["kernel" | "ragged_dot"]`` in the graph's order,
-    by the operator's own rule (``pallas_grouped_matmul.moe_form``)."""
+    """``(forms, depth)``: the form of every ``MoEFeedForward`` node of a
+    program's graph at these input shapes, ``["kernel" | "ragged_dot"]`` in
+    the graph's order, and the deepest fetch ring among its kernel layers (0
+    where none is), by the operator's own rules
+    (``pallas_grouped_matmul.moe_form``, ``layer_tiles``)."""
     import jax
 
-    from ..ops.pallas_grouped_matmul import moe_form
+    from ..ops.pallas_grouped_matmul import layer_tiles, moe_form
 
-    out = []
+    forms, depth = [], 0
     for n, ops in _operands_of(cache, input_shapes,
                                "_contrib_MoEFeedForward"):
-        data = ops["data"]
+        data, gate = ops["data"], ops["gate_weight"]
+        attrs = n.parsed_attrs()
         rows = jax.ShapeDtypeStruct(
-            (data.shape[0] * n.parsed_attrs()["num_experts_per_tok"],
-             data.shape[1]), data.dtype)
-        out.append(moe_form(rows, ops["gate_weight"], ops["down_weight"]))
-    return out
+            (data.shape[0] * attrs["num_experts_per_tok"], data.shape[1]),
+            data.dtype)
+        forms.append(moe_form(rows, gate, ops["down_weight"]))
+        if forms[-1] == "kernel":
+            depth = max(depth,
+                        layer_tiles(rows, gate, attrs["num_experts"])[3])
+    return forms, depth
 
 
 def _swap_cache(exe, names):
@@ -1213,11 +1219,12 @@ class PagedKVDecoder:
             if self._prefix is None:
                 programs["prefill"] = (self._pf_cache, self._prefill_shapes())
             for program, bound in programs.items():
-                forms = _moe_forms(*bound)
+                forms, depth = _moe_forms(*bound)
                 _tm.gauge("serving.moe.kernel_layers." + program).set(
                     forms.count("kernel"))
                 _tm.gauge("serving.moe.xla_layers." + program).set(
                     forms.count("ragged_dot"))
+                _tm.gauge("serving.moe.fetch_depth." + program).set(depth)
             _tm.gauge("serving.state_bytes").set(sum(
                 4 * self.lanes * int(np.prod(shape))
                 for _, kind, shape in self._cache if kind == "row"))

@@ -26,8 +26,15 @@ GROUPS = {
     "no_row_is_held": (32, [0, 0, 0]),
     "rows_no_multiple_of_the_tile": (40, [7, 21, 12]),
     "past_the_groups_and_no_multiple": (56, [9, 0, 17, 4]),
+    # what a ring of buffers can get wrong
+    "fewer_runs_than_the_ring": (32, [20, 12]),
+    "a_run_longer_than_the_ring": (112, [4, 0, 92, 16]),
+    "full_experts_between_empty_ones": (80, [0, 16, 0, 0, 33, 0, 31, 0, 0]),
 }
-K, N = 32, 256      # two column tiles of 128
+K, N = 32, 256      # two column tiles of 128: the ring is primed twice
+# the buffers a matrix of the ring the kernel fetches into by hand; 0: the
+# pipeline's own blocks, the form as PR 41 shipped it
+DEPTHS = [0, 1, 2, 3]
 
 
 def _operands(case, dtype, seed=0):
@@ -50,19 +57,27 @@ def _ragged(rows, weight, sizes):
     return jnp.where(held[:, None], out, 0)
 
 
+@pytest.mark.parametrize("depth", DEPTHS)
 @pytest.mark.parametrize("fused", [False, True], ids=["down", "gate_up"])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("case", list(GROUPS))
-def test_the_kernel_multiplies_what_ragged_dot_multiplies(case, dtype, fused):
+def test_the_kernel_multiplies_what_ragged_dot_multiplies(case, dtype, fused,
+                                                          depth):
     """One stack: the float32 products of every row with its own expert's
     matrix, zeros past the last group. Two stacks: ``silu(dot) * dot`` on the
     float32 sums, cast ONCE to the storage type. Products in the storage
     type and sums in float32 on both sides: what differs is the order of a
-    float32 sum and, fused, one rounding of the result."""
+    float32 sum and, fused, one rounding of the result. Whatever the ring's
+    ``depth``: it says when a matrix arrives, and the output is the
+    pipeline-fed form's bit for bit."""
     rows, (gate, up), sizes = _operands(case, dtype)
     meta = kernel.visits(sizes, rows.shape[0], TM)
-    got = kernel.grouped_matmul(rows, (gate, up) if fused else (gate,), meta,
-                                tm=TM, tn=128, interpret=True)
+    call = lambda buffers: kernel.grouped_matmul(
+        rows, (gate, up) if fused else (gate,), meta, tm=TM, tn=128,
+        depth=buffers, interpret=True)
+    got = call(depth)
+    assert np.array_equal(np.asarray(got, np.float32),
+                          np.asarray(call(0), np.float32))
     want = _ragged(rows, gate, sizes)
     if fused:
         want = (jax.nn.silu(want) * _ragged(rows, up, sizes)).astype(dtype)
@@ -84,14 +99,17 @@ def test_the_visits_are_the_pairs_that_share_a_row(seed, tm):
     first ``count`` visits are exactly the (tile, expert) pairs with a row in
     common, in order, plus one visit for every tile past the groups (which
     owns no row of its expert's); never more than tiles + experts - 1; and
-    what follows repeats the last visit's blocks."""
+    what follows repeats the last visit's blocks. The RUNS are a plain walk
+    over those visits: a new run wherever the expert changes, each run's
+    expert the one its visits name, and their count."""
     rs = np.random.RandomState(seed)
     experts = int(rs.randint(1, 12))
     sizes = rs.randint(0, 40, experts) * (rs.rand(experts) < 0.6)
     rows = int(sizes.sum()) + int(rs.randint(0, 3 * tm))
     rows += -rows % 8 or 8
-    off, tile, expert, count = (np.asarray(a) for a in kernel.visits(
-        jnp.asarray(sizes, jnp.int32), rows, tm))
+    off, tile, expert, count, run, run_expert, runs = (
+        np.asarray(a) for a in kernel.visits(jnp.asarray(sizes, jnp.int32),
+                                             rows, tm))
     n_tiles = -(-rows // tm)
     assert len(tile) == len(expert) == n_tiles + experts - 1
     assert list(off) == [0] + list(np.cumsum(sizes))
@@ -105,6 +123,47 @@ def test_the_visits_are_the_pairs_that_share_a_row(seed, tm):
     # every tile is visited (a tile nobody owns is written as zeros)
     assert sorted({t for t, _ in got}) == list(range(n_tiles))
     assert all(v == got[-1] for v in zip(tile[count:], expert[count:]))
+    walk, experts_of = [], []
+    for e in expert:
+        if not experts_of or e != experts_of[-1]:
+            experts_of.append(e)
+        walk.append(len(experts_of) - 1)
+    assert list(run) == walk and int(runs[0]) == len(experts_of)
+    assert len(run_expert) == min(experts, len(tile))
+    assert list(run_expert[:len(experts_of)]) == experts_of
+    assert all(e == experts_of[-1] for e in run_expert[len(experts_of):])
+    # a run is one non-empty group's visits (one run of nothing if none is)
+    assert experts_of == list(np.flatnonzero(sizes)) or not sizes.any()
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+@pytest.mark.parametrize("fused", [False, True], ids=["down", "gate_up"])
+@pytest.mark.parametrize("case", [
+    "even", "one_expert_has_every_row", "many_groups_in_one_tile",
+    "rows_past_the_last_group", "no_row_is_held", "fewer_runs_than_the_ring",
+    "a_run_longer_than_the_ring", "full_experts_between_empty_ones"])
+def test_the_ring_is_read_after_it_lands_and_written_after_it_is_read(
+        case, fused, depth):
+    """The by-hand fetch under Pallas's TPU interpreter, which runs a copy
+    only when it is WAITED for and watches every buffer for a race: a slot
+    read before its copy was waited for holds what the last run left there,
+    a slot written while a run still reads it is reported, and a copy
+    started and never waited for (or waited for and never started) hangs or
+    leaves its semaphore non-zero. The output is the pipeline-fed form's bit
+    for bit."""
+    from jax.experimental.pallas import tpu as pltpu
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+
+    rows, stacks, sizes = _operands(case, "bfloat16")
+    meta = kernel.visits(sizes, rows.shape[0], TM)
+    call = lambda buffers, interpret: kernel.grouped_matmul(
+        rows, tuple(stacks) if fused else (stacks[0],), meta, tm=TM, tn=128,
+        depth=buffers, interpret=interpret)
+    got = call(depth, pltpu.InterpretParams(detect_races=True,
+                                            dma_execution_mode="on_wait"))
+    assert not interpret_pallas_call.races.races_found
+    assert np.array_equal(np.asarray(got, np.float32),
+                          np.asarray(call(0, True), np.float32))
 
 
 @pytest.mark.parametrize("layer", ["olmoe", "kanana", "a_held_share"])
@@ -151,13 +210,17 @@ def test_the_operator_through_the_kernel_is_the_operator(monkeypatch, layer,
 def test_the_tiles_are_whole_and_fit(rows, experts, d, f):
     """Whatever the rule answers, at a cell's admission or step or at widths
     no cell has: a row tile of whole bfloat16 sublane tiles, column tiles
-    that divide the widths in whole lane tiles, and matrices whose
-    double-buffered blocks fit the budget the kernel's file states."""
-    tm, tn_up, tn_down = kernel.tiles(rows, experts, d, f, jnp.bfloat16)
+    that divide the widths in whole lane tiles, a ring of two buffers a
+    matrix or three, and matrices whose ``depth`` buffers fit the budget the
+    kernel's file states."""
+    tm, tn_up, tn_down, depth = kernel.tiles(rows, experts, d, f,
+                                             jnp.bfloat16)
     assert tm % 16 == 0 and 32 <= tm <= 128
     assert f % tn_up == 0 and tn_up % 128 == 0
     assert d % tn_down == 0 and tn_down % 128 == 0
-    assert 2 * 2 * max(2 * d * tn_up, f * tn_down) <= kernel._MATRIX_BYTES
+    assert depth in (2, 3)
+    assert 2 * depth * max(2 * d * tn_up, f * tn_down) \
+        <= kernel._MATRIX_BYTES
 
 
 def test_the_rule_names_the_kernel_on_the_chip_alone(monkeypatch):
@@ -182,9 +245,11 @@ def test_the_rule_names_the_kernel_on_the_chip_alone(monkeypatch):
 def test_a_decoder_says_which_form_its_programs_run(monkeypatch):
     """An OLMoE decoder with the rule held to the kernel (interpreted):
     ``warmup`` sets the gauges of both bound programs, every expert layer
-    the kernel's and none XLA's; the decoder as it stands on the CPU says
-    the opposite; and an admission and steps through either give the same
-    greedy tokens and logits to the fused activation's rounding."""
+    the kernel's and none XLA's, and the depth of the fetch ring ``tiles``
+    names at each program's rows; the decoder as it stands on the CPU says
+    the opposite, and a depth of 0; and an admission and steps through
+    either give the same greedy tokens and logits to the fused activation's
+    rounding."""
     import mxnet_tpu as mx
     from mxnet_tpu import telemetry as tm
     from mxnet_tpu.models import transformer as tf
@@ -216,6 +281,10 @@ def test_a_decoder_says_which_form_its_programs_run(monkeypatch):
                     == 2 * (form == "kernel")
                 assert snap["serving.moe.xla_layers." + program] \
                     == 2 * (form == "ragged_dot")
+                rows = 2 * (16 if program == "prefill" else 2)
+                assert snap["serving.moe.fetch_depth." + program] == (
+                    kernel.tiles(rows, 8, 32, 32, jnp.float32)[3]
+                    if form == "kernel" else 0)
             seq, first = dec.admit(np.arange(11) % 29)
             rows = [np.asarray(first)]
             for t in range(3):

@@ -165,14 +165,19 @@ def _paged_read_calls(hlo):
             and "/paged_read/" in line]
 
 
-def _assert_expert_layers(hlo, layers, tokens, k, experts, d, f):
+def _assert_expert_layers(hlo, layers, tokens, k, experts, d, f,
+                          routed=None):
     """The program's ``layers`` expert layers of ``tokens`` rows, ``k`` experts
-    a token, ``experts`` held stacks (D, F) run the form the operator's rule
-    (``pallas_grouped_matmul.moe_form``) names at those operands, asked as the
-    operator asks it: TWO calls of the kernel a kernel layer (gate and up
-    fused with the activation, then down) and no ``ragged-dot`` there; XLA's
-    grouped matmul and no call of the kernel otherwise. Returns the form."""
-    from mxnet_tpu.ops.pallas_grouped_matmul import moe_form
+    a token, ``experts`` held stacks (D, F) of ``routed`` run the form the
+    operator's rule (``pallas_grouped_matmul.moe_form``) names at those
+    operands, asked as the operator asks it: TWO calls of the kernel a kernel
+    layer (gate and up fused with the activation, then down) and no
+    ``ragged-dot`` there; XLA's grouped matmul and no call of the kernel
+    otherwise. A kernel call holds the fetch ring ``tiles`` names there,
+    ``depth`` buffers a matrix of its column tile, and what Mosaic gave it of
+    VMEM is that ring and the rows', the output's and the float32 sums' 16 MB
+    at most beside it, under the limit the call states. Returns the form."""
+    from mxnet_tpu.ops.pallas_grouped_matmul import layer_tiles, moe_form
 
     struct = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     form = moe_form(struct(tokens * k, d), struct(experts, d, f),
@@ -187,6 +192,19 @@ def _assert_expert_layers(hlo, layers, tokens, k, experts, d, f):
         assert len(calls) == 2 * layers and not ragged
         assert sum("/grouped_matmul_gated/" in line for line in calls) \
             == layers
+        _, tn_up, tn_down, depth = layer_tiles(
+            struct(tokens * k, d), struct(experts, d, f), routed)
+        assert depth >= 2
+        for line in calls:
+            ring = 2 * depth * (2 * d * tn_up if "_gated/" in line
+                                else f * tn_down)
+            # where XLA put the call's VMEM, and how far into it Mosaic went
+            (at, limit), (_, end) = (map(int, re.search(
+                r'"%s":\[\{"memory_space":"1","offset":"(\d+)","size":"(\d+)"'
+                % key, line).groups()) for key in (
+                    "scoped_memory_configs", "used_scoped_memory_configs"))
+            assert ring <= end - at <= min(ring + (16 << 20), limit), \
+                (ring, at, end, limit)
     else:
         assert not calls and ragged
     return form
@@ -194,24 +212,24 @@ def _assert_expert_layers(hlo, layers, tokens, k, experts, d, f):
 
 # cell, program -> (tokens, experts a token, routed experts, held experts, D,
 # F) and what the rules answer there: the form, and the tiles (row tile,
-# column tile of gate and up, column tile of down)
+# column tile of gate and up, column tile of down, depth of the fetch ring)
 _EXPERT_LAYERS = {
     ("olmoe-1b-7b.score", "prefill"): ((2048, 8, 64, 64, 2048, 1024),
-                                       "kernel", (128, 1024, 2048)),
+                                       "kernel", (128, 1024, 2048, 2)),
     ("olmoe-1b-7b.score", "decode"): ((8, 8, 64, 64, 2048, 1024),
-                                      "kernel", (32, 1024, 2048)),
+                                      "kernel", (32, 1024, 2048, 3)),
     ("kanana-2-30b-a3b.generate", "prefill"): (
-        (1024, 6, 128, 128, 2048, 768), "kernel", (128, 768, 2048)),
+        (1024, 6, 128, 128, 2048, 768), "kernel", (128, 768, 2048, 3)),
     ("kanana-2-30b-a3b.generate", "decode"): (
-        (32, 6, 128, 128, 2048, 768), "kernel", (32, 768, 2048)),
+        (32, 6, 128, 128, 2048, 768), "kernel", (32, 768, 2048, 3)),
     ("lfm2-24b-a2b.generate", "prefill"): (
-        (1024, 4, 64, 64, 2048, 1536), "kernel", (128, 1536, 2048)),
+        (1024, 4, 64, 64, 2048, 1536), "kernel", (128, 1536, 2048, 3)),
     ("lfm2-24b-a2b.generate", "decode"): (
-        (64, 4, 64, 64, 2048, 1536), "kernel", (32, 1536, 2048)),
+        (64, 4, 64, 64, 2048, 1536), "kernel", (32, 1536, 2048, 3)),
     ("mimo-v2-flash.generate", "prefill"): (
-        (2048, 8, 256, 16, 4096, 2048), "kernel", (128, 1024, 4096)),
+        (2048, 8, 256, 16, 4096, 2048), "kernel", (128, 1024, 4096, 2)),
     ("mimo-v2-flash.generate", "decode"): (
-        (32, 8, 256, 16, 4096, 2048), "kernel", (32, 1024, 4096)),
+        (32, 8, 256, 16, 4096, 2048), "kernel", (32, 1024, 4096, 2)),
 }
 
 
@@ -813,7 +831,7 @@ def test_mimo_v2_flash_serving_programs_compile_for_the_chip(v5e, program):
     assert [(s.shape, str(s.dtype)) for s in compiled.out_info[0]] == want
     hlo = compiled.as_text()
     _assert_expert_layers(hlo, 6, bucket if program == "prefill" else lanes,
-                          8, 16, 4096, 2048)
+                          8, 16, 4096, 2048, routed=256)
     mem = compiled.memory_analysis()
     found = [(math.prod(int(d) for d in dims.split(",") if d), op)
              for _n, dims, op, _a in _INSTRUCTION.findall(hlo)
