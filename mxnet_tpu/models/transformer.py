@@ -9,10 +9,10 @@ import math
 
 from .. import symbol as sym
 from ..base import MXNetError
-from ..ops.attention import _NEG, pool_paged
+from ..ops.attention import _LANES, _NEG, pool_paged
 
 ARCHS = ("vaswani", "olmoe", "granite_hybrid", "deepseek_v3", "lfm2_moe",
-         "mimo_v2_flash", "phi4flash")
+         "mimo_v2_flash", "phi4flash", "nemotron_h")
 
 
 # the block every graph is derived from (ROADMAP D2); the others have the
@@ -350,13 +350,21 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     float32; a window layer's and layer N/2 + 1's K and V
     (1, Hkv / 2, P, 2 * head_dim), pairs of heads side by side. The cross
     layers export nothing.
+
+    ``arch="nemotron_h"`` builds the one-mixer blocks of
+    ``_nemotron_h_layer`` (Mamba-2 with groups of B and C | ungated relu^2
+    experts beside a shared one | position-free grouped-query attention).
+    After the logits come the cache's values in ``decode_cache`` order, a
+    Mamba layer's state and columns as ``granite_hybrid``'s, an attention
+    layer's K and V; an expert block exports nothing; ``moe_load`` last.
     """
     builders = {"olmoe": _olmoe_prefill_symbol,
                 "granite_hybrid": _granite_prefill_symbol,
                 "deepseek_v3": _deepseek_v3_prefill_symbol,
                 "lfm2_moe": _lfm2_moe_prefill_symbol,
                 "mimo_v2_flash": _mimo_prefill_symbol,
-                "phi4flash": _phi4flash_prefill_symbol}
+                "phi4flash": _phi4flash_prefill_symbol,
+                "nemotron_h": _nemotron_h_prefill_symbol}
     if arch in builders:
         return builders[arch](
             vocab_size=vocab_size, num_layers=num_layers,
@@ -576,6 +584,14 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     cache outputs. ``m``, layer N/2's scan before its gate, is carried to
     the gated memory units inside the step and never cached.
 
+    ``arch="nemotron_h"`` runs ``_nemotron_h_layer``: a Mamba layer takes
+    and returns ``ssm_state_i`` (B, H, P, N) and ``conv_state_i`` (B, K-1,
+    H*P + 2GN), float32; an attention layer has ``kv_k_i`` / ``kv_v_i``
+    (Hkv, max_len, dh); an expert block keeps nothing. The cache outputs
+    follow the logits in ``decode_cache`` order, then the token head, then
+    ``moe_load`` (the rows each of ALL the experts received, held here or
+    not), one row an EXPERT layer.
+
     ``page_size`` is the decoder's (``PagedKVDecoder``'s default here); it
     must divide ``max_len``.
     """
@@ -584,7 +600,8 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
                 "deepseek_v3": _deepseek_v3_decode_symbol,
                 "lfm2_moe": _lfm2_moe_decode_symbol,
                 "mimo_v2_flash": _mimo_decode_symbol,
-                "phi4flash": _phi4flash_decode_symbol}
+                "phi4flash": _phi4flash_decode_symbol,
+                "nemotron_h": _nemotron_h_decode_symbol}
     if arch in builders:
         return builders[arch](
             vocab_size=vocab_size, num_layers=num_layers,
@@ -793,14 +810,64 @@ def _granite_sizes(num_layers, num_heads, model_dim, ffn_dim, layer_types,
 
 
 def _mamba_core(op, i, xbc, dt, block, **inputs):
-    """One of ops/ssm.py's two operators on Mamba layer ``i``'s weights."""
+    """One of ops/ssm.py's two operators on Mamba layer ``i``'s weights; a
+    block with several groups of B and C names them (``mamba_groups``)."""
     name = "layer%d" % i
+    if block.get("mamba_groups", 1) > 1:
+        inputs["num_groups"] = block["mamba_groups"]
     return op(xbc, dt, *(sym.Variable("%s_mamba_%s" % (name, w)) for w in
                          ("conv_weight", "conv_bias", "dt_bias", "A_log", "D")),
               num_heads=block["mamba_heads"], head_dim=block["mamba_head_dim"],
               state_size=block["mamba_state"],
               conv_kernel=block["mamba_conv"], name="%s_mamba_core" % name,
               **inputs)
+
+
+def _mamba_conv_dim(block):
+    """Features of a Mamba-2 layer's xBC: [x (H*P) | B (G*N) | C (G*N)]."""
+    return block["mamba_heads"] * block["mamba_head_dim"] \
+        + 2 * block.get("mamba_groups", 1) * block["mamba_state"]
+
+
+def _mamba2_mixer(fc, h, i, scan, block):
+    """The Mamba-2 mixer on the normed h (B, T, M): one bias-free projection
+    to [z | xBC | dt], asked for in float32 (the core computes so);
+    ``scan(i, xbc, dt)`` runs the core and returns y (B, T, H*P); the gated
+    norm ``rms(y * silu(z))``, over all H*P features where B and C are one
+    group and over each group's H*P / G where they are several
+    (``MambaRMSNormGated``'s ``group_size``, the gate first), back to the
+    weights' type; the output projection to ``model_dim``."""
+    name = "layer%d" % i
+    heads, groups = block["mamba_heads"], block.get("mamba_groups", 1)
+    inner = heads * block["mamba_head_dim"]
+    conv_dim = _mamba_conv_dim(block)
+    zxbcdt = fc(h, inner + conv_dim + heads, "mamba_in", out_dtype="float32")
+    ends = (0, inner, inner + conv_dim, inner + conv_dim + heads)
+    z, xbc, dt = (sym.slice_axis(zxbcdt, axis=2, begin=a, end=b)
+                  for a, b in zip(ends, ends[1:]))
+    y = scan(i, xbc, dt) * sym.Activation(z, act_type="silu")
+    if groups == 1:
+        y = sym.RMSNorm(y, eps=block["rms_eps"], name="%s_mamba_norm" % name)
+    else:   # the statistics a group, the learned scale a feature
+        y = sym.Reshape(sym.RMSNorm(
+            sym.Reshape(y, shape=(0, 0, groups, -1)),
+            sym.Reshape(sym.Variable("%s_mamba_norm_gamma" % name,
+                                     shape=(inner,)), shape=(groups, -1)),
+            eps=block["rms_eps"], name="%s_mamba_norm" % name),
+            shape=(0, 0, -1))
+    return fc(sym.Cast(y, dtype=block["dtype"]), block["model_dim"],
+              "mamba_out")
+
+
+def _gqa_mixer(fc, h, i, seq_len, attend, block):
+    """Grouped-query attention WITHOUT positions on the normed h (B, T, M):
+    one bias-free projection to [q | k | v] with fewer key/value heads than
+    query heads; ``attend(i, q, k, v)`` takes the head-major (B, H or Hkv,
+    T, dh) tensors and returns (B, H, T, dh); the output projection."""
+    hq, hkv, dh = block["num_heads"], block["num_kv_heads"], block["head_dim"]
+    q, k, v = _grouped_qkv(fc, h, seq_len, hq, hkv, dh)
+    return fc(_merge_heads(attend(i, q, k, v), seq_len, hq * dh),
+              block["model_dim"], "proj")
 
 
 def _gated_mlp(fc, h, width, out_width, tag):
@@ -851,24 +918,9 @@ def _granite_layer(x, i, seq_len, attend, scan, block):
         name="%s_%s" % (name, tag), **kw)
     h = sym.RMSNorm(x, eps=eps, name="%s_ln1" % name)
     if block["layer_types"][i] == "mamba":
-        inner = block["mamba_heads"] * block["mamba_head_dim"]
-        conv_dim = inner + 2 * block["mamba_state"]
-        zxbcdt = fc(h, inner + conv_dim + block["mamba_heads"], "mamba_in",
-                    out_dtype="float32")
-        ends = (0, inner, inner + conv_dim,
-                inner + conv_dim + block["mamba_heads"])
-        z, xbc, dt = (sym.slice_axis(zxbcdt, axis=2, begin=a, end=b)
-                      for a, b in zip(ends, ends[1:]))
-        y = scan(i, xbc, dt) * sym.Activation(z, act_type="silu")
-        y = sym.Cast(sym.RMSNorm(y, eps=eps, name="%s_mamba_norm" % name),
-                     dtype=block["dtype"])
-        mixed = fc(y, d, "mamba_out")
+        mixed = _mamba2_mixer(fc, h, i, scan, block)
     else:
-        hq, hkv, dh = block["num_heads"], block["num_kv_heads"], \
-            block["head_dim"]
-        q, k, v = _grouped_qkv(fc, h, seq_len, hq, hkv, dh)
-        mixed = fc(_merge_heads(attend(i, q, k, v), seq_len, hq * dh), d,
-                   "proj")
+        mixed = _gqa_mixer(fc, h, i, seq_len, attend, block)
     x = x + mixed * res
     return x + _gated_mlp(fc, sym.RMSNorm(x, eps=eps, name="%s_ln2" % name),
                           block["ffn_dim"], d, "mlp") * res
@@ -896,11 +948,12 @@ def _granite_stack(data, vocab_size, num_layers, seq_len, attend, scan, block,
         name="lm_head") / block["logits_scaling"]
 
 
-def _granite_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
-    block = _granite_sizes(num_layers, **sizes)
-    length = sym.Variable("length")     # (B, 1): real tokens of the bucket
-    cache = []      # the layers are built in order, so is this
-
+def _rows_and_pools_prefill(block, length, cache):
+    """``(attend, scan)`` of a prefill whose mixers are position-free
+    grouped-query attention and Mamba-2 (``granite_hybrid``,
+    ``nemotron_h``): each appends what the cache keeps of its layer to
+    ``cache``, K and V over the bucket or the state and the columns at
+    ``length``; the layers are built in order, so is the list."""
     def attend(i, q, k, v):
         cache.extend([k, v])
         return sym.MultiHeadAttention(
@@ -913,19 +966,28 @@ def _granite_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
         cache.extend([core[1], core[2]])
         return core[0]
 
+    return attend, scan
+
+
+def _granite_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
+    block = _granite_sizes(num_layers, **sizes)
+    length = sym.Variable("length")     # (B, 1): real tokens of the bucket
+    cache = []
+    attend, scan = _rows_and_pools_prefill(block, length, cache)
     logits = _granite_stack(sym.Variable("data"), vocab_size, num_layers,
                             prefill_len, attend, scan, block, length=length)
     return sym.Group([logits] + cache)
 
 
-def _granite_decode_symbol(vocab_size, num_layers, num_slots, page_size,
-                           token_out=True, **sizes):
-    block = _granite_sizes(num_layers, **sizes)
+def _rows_and_pools_step(block, num_slots, page_size, cache):
+    """``(attend, scan)`` of a decode step over the same two mixers: pools
+    addressed by slot for attention, a lane's row of state and columns for
+    Mamba-2, ``write_slot`` telling both which lanes ride along. Each appends
+    its layer's UPDATED cache to ``cache``, in layer order."""
     hq, hkv, dh = (block[k] for k in ("num_heads", "num_kv_heads", "head_dim"))
     pos_idx = sym.Variable("pos_idx")
     write_slot = sym.Variable("write_slot")
     write, read = _pool_step_inputs(pos_idx, num_slots, page_size, write_slot)
-    cache = []      # the layers are built in order, so is this
 
     def attend(i, q, k_new, v_new):
         # one token a lane: the head-major (B, H, 1, dh) tensors are the
@@ -945,9 +1007,181 @@ def _granite_decode_symbol(vocab_size, num_layers, num_slots, page_size,
         cache.extend([core[1], core[2]])
         return sym.Reshape(core[0], shape=(0, 1, -1))
 
+    return attend, scan
+
+
+def _granite_decode_symbol(vocab_size, num_layers, num_slots, page_size,
+                           token_out=True, **sizes):
+    block = _granite_sizes(num_layers, **sizes)
+    cache = []
+    attend, scan = _rows_and_pools_step(block, num_slots, page_size, cache)
     logits = _granite_stack(sym.Variable("data"), vocab_size, num_layers, 1,
                             attend, scan, block)
     return _token_head(logits, cache, "greedy_token" if token_out else None)
+
+
+# ------------------- Nemotron-H (ONE mixer a block: Mamba-2 | experts | attention)
+def _lane_tiles(width):
+    """``width`` rounded up to whole tiles of the chip's 128 lanes: what an
+    expert stack of a width that is none (1,856 = 14.5) is STORED at, zero
+    columns of ``up`` and zero rows of ``down`` behind the real ones
+    (relu(0)^2 = 0: the padding adds exactly nothing). The grouped-matmul
+    kernel takes whole lane tiles only, and the chip ruled for the padding
+    (``ops/pallas_grouped_matmul.supported``)."""
+    return -(-width // _LANES) * _LANES
+
+
+def _nemotron_h_sizes(num_layers, num_heads, model_dim, layer_types,
+                      ffn_dim=None, moe_ffn_dim=None, shared_ffn_dim=None,
+                      num_kv_heads=None, head_dim=None, mamba_heads=None,
+                      mamba_head_dim=64, mamba_state=128, mamba_groups=8,
+                      mamba_conv=4, mamba_chunk=128, num_experts=128,
+                      num_experts_per_tok=6, num_local_experts=0,
+                      local_expert_offset=0, routed_scaling_factor=1.0,
+                      norm_topk_prob=True, rms_eps=1e-5, dtype="float32",
+                      **kwargs):
+    """``_nemotron_h_layer``'s keywords from a builder's: ``layer_types``
+    names each block's ONE mixer (``hybrid_override_pattern``'s letters:
+    ``M`` "mamba", ``E`` "moe", ``*`` "attention"; a dense ``-`` MLP block
+    is not built, and ``ffn_dim``, its width, is read by nothing);
+    ``moe_ffn_dim`` is one routed expert's width as published and
+    ``moe_ffn_stored`` what its stacks are stored at (``_lane_tiles``),
+    ``shared_ffn_dim`` the shared expert's; ``num_local_experts`` = 0 holds
+    every expert; the Mamba-2 inner width is ``mamba_heads x
+    mamba_head_dim`` (NOT an expansion of ``model_dim``); keywords of the
+    other architectures are dropped."""
+    kinds = tuple(layer_types)
+    if len(kinds) != num_layers \
+            or set(kinds) - {"mamba", "moe", "attention"}:
+        raise MXNetError("nemotron_h: layer_types must name %d layers, each "
+                         "'mamba', 'moe' or 'attention', got %r"
+                         % (num_layers, kinds))
+    if "moe" in kinds and not (moe_ffn_dim and shared_ffn_dim):
+        raise MXNetError("nemotron_h: an expert layer needs moe_ffn_dim and "
+                         "shared_ffn_dim")
+    head_dim = head_dim or model_dim // num_heads
+    return dict(
+        num_layers=num_layers, layer_types=kinds, num_heads=num_heads,
+        num_kv_heads=num_kv_heads or num_heads, head_dim=head_dim,
+        model_dim=model_dim,
+        mamba_heads=mamba_heads or 2 * model_dim // mamba_head_dim,
+        mamba_head_dim=mamba_head_dim, mamba_state=mamba_state,
+        mamba_groups=int(mamba_groups), mamba_conv=mamba_conv,
+        mamba_chunk=mamba_chunk, attention_multiplier=head_dim ** -0.5,
+        moe_ffn_dim=moe_ffn_dim and _lane_tiles(moe_ffn_dim),
+        shared_ffn_dim=shared_ffn_dim, experts_activation="relu2",
+        num_experts=num_experts, num_experts_per_tok=num_experts_per_tok,
+        num_local_experts=int(num_local_experts),
+        local_expert_offset=int(local_expert_offset),
+        routed_scaling_factor=float(routed_scaling_factor),
+        norm_topk_prob=bool(norm_topk_prob), rms_eps=rms_eps, dtype=dtype)
+
+
+def _nemotron_h_layer(x, i, seq_len, attend, scan, block):
+    """One ``model_type: nemotron_h`` block on x (B, T, M) -> (x', load (E,)
+    or None): ``x + mixer(RMSNorm(x))``, ONE mixer a block, named by
+    ``layer_types[i]``; no MLP follows a mixer.
+
+    ``mamba``: ``_mamba2_mixer`` with ``mamba_groups`` groups of B and C and
+    the gated norm a group. ``attention``: ``_gqa_mixer``, no positions (the
+    Mamba layers carry order), scores over sqrt(head_dim). ``scan`` and
+    ``attend`` are what the prefill and the decode graph do differently, as
+    in ``_granite_layer``. ``moe``: ``_sigmoid_experts``' router over UNGATED
+    experts, ``down_e(relu(up_e h)^2)``, of which the layer may hold a
+    share, beside ONE shared expert of the same form every token takes
+    (``shared_up``, ``shared_down``)."""
+    name = "layer%d" % i
+    d, kind = block["model_dim"], block["layer_types"][i]
+    fc = lambda data, width, tag, **kw: sym.FullyConnected(
+        data=data, num_hidden=width, no_bias=True, flatten=False,
+        name="%s_%s" % (name, tag), **kw)
+    h = sym.RMSNorm(x, eps=block["rms_eps"], name="%s_ln1" % name)
+    if kind == "mamba":
+        return x + _mamba2_mixer(fc, h, i, scan, block), None
+    if kind == "attention":
+        return x + _gqa_mixer(fc, h, i, seq_len, attend, block), None
+    moe = _sigmoid_experts(h, name, block)
+    shared = sym.square(sym.Activation(
+        fc(h, block["shared_ffn_dim"], "shared_up"), act_type="relu"))
+    return x + sym.Reshape(moe[0], shape=(-1, seq_len, d)) \
+        + fc(shared, d, "shared_down"), moe[1]
+
+
+def _nemotron_h_stack(vocab_size, seq_len, attend, scan, block, length=None):
+    """``_deepseek_v3_stack`` (embedding, the blocks, final norm and untied
+    head; ``moe_load`` a row an expert block) over this block's layer, which
+    takes no positions and a ``scan`` beside its ``attend``."""
+    return _deepseek_v3_stack(
+        vocab_size, seq_len, None, attend, block, length=length,
+        layer=lambda x, i, _positions, seq, att, blk: _nemotron_h_layer(
+            x, i, seq, att, scan, blk))
+
+
+def _nemotron_h_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
+    block = _nemotron_h_sizes(num_layers, **sizes)
+    length = sym.Variable("length")     # (B, 1): real tokens of the bucket
+    cache = []
+    attend, scan = _rows_and_pools_prefill(block, length, cache)
+    logits, load = _nemotron_h_stack(vocab_size, prefill_len, attend, scan,
+                                     block, length=length)
+    return sym.Group([logits] + cache + load)
+
+
+def _nemotron_h_decode_symbol(vocab_size, num_layers, num_slots, page_size,
+                              token_out=True, **sizes):
+    block = _nemotron_h_sizes(num_layers, **sizes)
+    cache = []
+    attend, scan = _rows_and_pools_step(block, num_slots, page_size, cache)
+    logits, load = _nemotron_h_stack(vocab_size, 1, attend, scan, block)
+    # moe_load LAST: the cache and the token head keep their places
+    return sym.Group([_token_head(
+        logits, cache, "greedy_token" if token_out else None)] + load)
+
+
+def _mamba2_param_shapes(block, n):
+    """{name: shape} of Mamba-2 layer ``n``'s mixer (``n`` its prefix)."""
+    h, k = block["mamba_heads"], block["mamba_conv"]
+    inner, conv_dim = h * block["mamba_head_dim"], _mamba_conv_dim(block)
+    return {
+        n + "mamba_in_weight": (inner + conv_dim + h, block["model_dim"]),
+        n + "mamba_conv_weight": (conv_dim, k),
+        n + "mamba_conv_bias": (conv_dim,), n + "mamba_dt_bias": (h,),
+        n + "mamba_A_log": (h,), n + "mamba_D": (h,),
+        n + "mamba_norm_gamma": (inner,),
+        n + "mamba_out_weight": (block["model_dim"], inner)}
+
+
+def _gqa_param_shapes(block, n):
+    """{name: shape} of attention layer ``n``'s position-free mixer."""
+    hq, hkv, dh = (block[k] for k in ("num_heads", "num_kv_heads", "head_dim"))
+    return {n + "qkv_weight": ((hq + 2 * hkv) * dh, block["model_dim"]),
+            n + "proj_weight": (block["model_dim"], hq * dh)}
+
+
+def _nemotron_h_param_shapes(vocab_size, num_layers, **sizes):
+    """The routed experts' two stacks are ``_lane_tiles(moe_ffn_dim)`` wide:
+    whoever fills them leaves the columns of ``experts_up_weight`` and the
+    rows of ``experts_down_weight`` past ``moe_ffn_dim`` ZERO."""
+    block = _nemotron_h_sizes(num_layers, **sizes)
+    d, e, f = block["model_dim"], block["num_experts"], block["moe_ffn_dim"]
+    held, shared = block["num_local_experts"] or e, block["shared_ffn_dim"]
+    shapes = {"embed_weight": (vocab_size, d), "final_ln_gamma": (d,),
+              "lm_head_weight": (vocab_size, d)}
+    for i, kind in enumerate(block["layer_types"]):
+        n = "layer%d_" % i
+        shapes[n + "ln1_gamma"] = (d,)
+        if kind == "mamba":
+            shapes.update(_mamba2_param_shapes(block, n))
+        elif kind == "attention":
+            shapes.update(_gqa_param_shapes(block, n))
+        else:
+            shapes.update({
+                n + "router_weight": (e, d), n + "router_bias": (e,),
+                n + "experts_up_weight": (held, d, f),
+                n + "experts_down_weight": (held, f, d),
+                n + "shared_up_weight": (shared, d),
+                n + "shared_down_weight": (d, shared)})
+    return shapes
 
 
 # --------------------------------------------------- DeepSeek-V3 (latent attention)
@@ -988,14 +1222,20 @@ def _sigmoid_experts(h, name, block):
     ``<name>_router_bias``, weighted by the unbiased score, renormalised and
     scaled as ``block`` says. Where ``block`` names a share
     (``num_local_experts`` of them from ``local_expert_offset`` on) the
-    layer holds those experts alone and computes their part."""
+    layer holds those experts alone and computes their part. Where
+    ``block`` names ``experts_activation`` the experts are UNGATED, two
+    stacks and that activation (``nemotron_h``'s ``"relu2"``)."""
     share = {k: block[k] for k in ("num_local_experts", "local_expert_offset")
              if block.get("num_local_experts")}
+    stacks = ("experts_gate_weight", "experts_up_weight",
+              "experts_down_weight")
+    if block.get("experts_activation"):     # an UNGATED expert: up and down
+        stacks = stacks[1:]
+        share.update(gated=False, activation=block["experts_activation"])
     return sym.MoEFeedForward(
         sym.Reshape(h, shape=(-1, block["model_dim"])),
         *(sym.Variable("%s_%s" % (name, w)) for w in (
-            "router_weight", "experts_gate_weight", "experts_up_weight",
-            "experts_down_weight", "router_bias")),
+            "router_weight",) + stacks + ("router_bias",)),
         num_experts=block["num_experts"], num_hidden=block["moe_ffn_dim"],
         num_experts_per_tok=block["num_experts_per_tok"], scoring="sigmoid",
         router_bias=True, norm_topk_prob=block["norm_topk_prob"],
@@ -1897,7 +2137,8 @@ def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
     shape, float32. Latent attention keeps ONE pool a layer, of one head:
     the old kind, no new one. Where ``layer_types`` chooses the mixer
     (``granite_hybrid``, ``lfm2_moe``) the list mixes the two kinds, in layer
-    order. A ``"ring"`` is a WINDOW layer's K or V (``mimo_v2_flash``):
+    order; ``nemotron_h``'s expert blocks keep nothing and are skipped. A
+    ``"ring"`` is a WINDOW layer's K or V (``mimo_v2_flash``):
     addressed by lane and position mod the window, ``shape`` is one lane's
     (heads, window, d) and the buffer (lanes,) + shape in the pools' type;
     it takes no frame and no page-table entry, whatever the lane's length.
@@ -1946,7 +2187,7 @@ def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
                                    model_dim=model_dim, **sizes)
         return [("kv_c_%d" % i, "pool", (1, block["latent"] + block["rope"]))
                 for i in range(num_layers)]
-    if arch not in ("granite_hybrid", "lfm2_moe"):
+    if arch not in ("granite_hybrid", "lfm2_moe", "nemotron_h"):
         pool = (num_heads, head_dim or model_dim // num_heads)
         return [("kv_%s_%d" % (t, i), "pool", pool)
                 for i in range(num_layers) for t in "kv"]
@@ -1958,44 +2199,34 @@ def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
         per_kind = {"conv": [("conv_state_%d", "row", (
             block["conv_kernel"] - 1, block["model_dim"]))]}
     else:
-        block = _granite_sizes(num_layers, **sizes)
+        block = (_granite_sizes if arch == "granite_hybrid"
+                 else _nemotron_h_sizes)(num_layers, **sizes)
         h, p, n = (block[k] for k in ("mamba_heads", "mamba_head_dim",
                                       "mamba_state"))
         attends = "attention"
         per_kind = {"mamba": [("ssm_state_%d", "row", (h, p, n)),
                               ("conv_state_%d", "row",
-                               (block["mamba_conv"] - 1, h * p + 2 * n))]}
+                               (block["mamba_conv"] - 1,
+                                _mamba_conv_dim(block)))]}
     pool = (block["num_kv_heads"], block["head_dim"])
     per_kind[attends] = [("kv_k_%d", "pool", pool), ("kv_v_%d", "pool", pool)]
+    # a layer of another kind (``nemotron_h``'s experts) keeps nothing
     return [(name % i, kind, shape)
             for i, layer in enumerate(block["layer_types"])
-            for name, kind, shape in per_kind[layer]]
+            for name, kind, shape in per_kind.get(layer, ())]
 
 
 def _granite_param_shapes(vocab_size, num_layers, **sizes):
     block = _granite_sizes(num_layers, **sizes)
     d, ffn = block["model_dim"], block["ffn_dim"]
-    hq, hkv, dh = (block[k] for k in ("num_heads", "num_kv_heads", "head_dim"))
-    h, k = block["mamba_heads"], block["mamba_conv"]
-    inner = h * block["mamba_head_dim"]
-    conv_dim = inner + 2 * block["mamba_state"]
     shapes = {"embed_weight": (vocab_size, d), "final_ln_gamma": (d,)}
     for i, kind in enumerate(block["layer_types"]):
         n = "layer%d_" % i
         shapes.update({n + "ln1_gamma": (d,), n + "ln2_gamma": (d,),
                        n + "mlp_in_weight": (2 * ffn, d),
                        n + "mlp_out_weight": (d, ffn)})
-        if kind == "attention":
-            shapes.update({n + "qkv_weight": ((hq + 2 * hkv) * dh, d),
-                           n + "proj_weight": (d, hq * dh)})
-            continue
-        shapes.update({
-            n + "mamba_in_weight": (inner + conv_dim + h, d),
-            n + "mamba_conv_weight": (conv_dim, k),
-            n + "mamba_conv_bias": (conv_dim,), n + "mamba_dt_bias": (h,),
-            n + "mamba_A_log": (h,), n + "mamba_D": (h,),
-            n + "mamba_norm_gamma": (inner,),
-            n + "mamba_out_weight": (d, inner)})
+        shapes.update(_gqa_param_shapes(block, n) if kind == "attention"
+                      else _mamba2_param_shapes(block, n))
     return shapes
 
 
@@ -2012,10 +2243,11 @@ def param_shapes(arch, vocab_size, num_layers, num_heads, model_dim, ffn_dim,
         return _deepseek_v3_param_shapes(
             vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
             ffn_dim=ffn_dim, num_experts=num_experts, **kwargs)
-    if arch in ("lfm2_moe", "mimo_v2_flash", "phi4flash"):
+    if arch in ("lfm2_moe", "mimo_v2_flash", "phi4flash", "nemotron_h"):
         shapes = {"lfm2_moe": _lfm2_moe_param_shapes,
                   "mimo_v2_flash": _mimo_param_shapes,
-                  "phi4flash": _phi4flash_param_shapes}[arch]
+                  "phi4flash": _phi4flash_param_shapes,
+                  "nemotron_h": _nemotron_h_param_shapes}[arch]
         return shapes(
             vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
             ffn_dim=ffn_dim, head_dim=head_dim, num_experts=num_experts,

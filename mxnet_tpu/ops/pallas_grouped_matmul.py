@@ -4,7 +4,9 @@
 ``jax.lax.ragged_dot``, XLA's own). Rows arrive sorted by expert with the
 group sizes as data; an expert layer is TWO calls of the one kernel here:
 ``silu(rows . gate_e) * (rows . up_e)`` written once in the storage type (the
-two float32 products never reach HBM), then ``. down_e`` in float32.
+two float32 products never reach HBM), then ``. down_e`` in float32. An
+UNGATED expert (``down_e(relu(rows . up_e)^2)``) is the same two calls, the
+first over ONE stack with the squared ReLU on its float32 sums.
 
 The row tiles are cut at every group's first row: a VISIT is one (row tile,
 expert) pair that shares a row, at most ``row tiles + experts - 1`` of them,
@@ -66,10 +68,18 @@ _MATRIX_BYTES = 36 << 20
 
 def supported(rows, gate_weight, down_weight):
     """Whether Mosaic takes an expert layer of these operands (shapes and
-    types alone): bfloat16 rows and stacks (float32 experts stay XLA's:
+    types alone; ``gate_weight`` a stack of the first call, the one ``up`` of
+    an ungated expert): bfloat16 rows and stacks (float32 experts stay XLA's:
     no cell runs them and a block of theirs is twice the VMEM), both widths
     whole tiles of 128 lanes, the rows a whole number of bfloat16's sublane
-    tiles of 16."""
+    tiles of 16. A width that is NO whole lane tiles (1,856 = 14.5) is stored
+    padded to the next one by the model that has it (zero columns of up, zero
+    rows of down: ``models/transformer.py:_lane_tiles``): Mosaic slices no
+    such width out of HBM ("Slice shape along dimension 2 must be aligned to
+    tiling (128), but is 1856"), and through the pipeline's own blocks, which
+    it does take, a layer ran at HALF the padded one's rate (3.73 against
+    1.72 ms a step's layer, 4.73 against 2.10 an admission's: ``PERF.md``
+    section 6, PR 48)."""
     if not (rows.dtype == gate_weight.dtype == down_weight.dtype
             == jnp.bfloat16):
         return False
@@ -92,11 +102,12 @@ def _column_tile(k, n, buffers, itemsize):
     return _LANES
 
 
-def tiles(rows, experts, d, f, dtype):
+def tiles(rows, experts, d, f, dtype, gated=True):
     """``(row tile, column tile of gate and up, column tile of down, depth
     of the fetch ring)`` of an expert layer whose held ``experts`` of width
     ``f`` under a model width ``d`` get ``rows`` assignment rows between
-    them: THE rule, from the rows an expert gets on average, the matrices'
+    them (``gated``: the first call has two stacks, gate and up; else one):
+    THE rule, from the rows an expert gets on average, the matrices'
     bytes and the budget and nothing else (no option, no model's name),
     written from chip runs (``PERF.md`` section 6, PRs 41 and 47).
 
@@ -131,21 +142,23 @@ def tiles(rows, experts, d, f, dtype):
     tm = 32
     while tm < min(2 * per, 128):
         tm *= 2
-    wide = lambda depth: (_column_tile(d, f, 2 * depth, itemsize),
+    first = 2 if gated else 1
+    wide = lambda depth: (_column_tile(d, f, first * depth, itemsize),
                           _column_tile(f, d, depth, itemsize))
     depth = 3 if 2 * per <= 128 and wide(3) == wide(2) else 2
     return (tm, *wide(depth), depth)
 
 
-def layer_tiles(rows, gate_weight, routed_experts=None):
+def layer_tiles(rows, up_weight, routed_experts=None, gated=True):
     """``tiles`` of an expert layer from its operands (each carries
     ``.shape`` and ``.dtype``: ``rows`` (M, D) sorted by expert,
-    ``gate_weight`` (held experts, D, F)), at the rows that reach a held
-    expert under even routing: all M, or the held experts' share of them
-    where the layer holds some of ``routed_experts``."""
-    experts, d, f = gate_weight.shape
+    ``up_weight`` (held experts, D, F) one of the first call's stacks), at
+    the rows that reach a held expert under even routing: all M, or the held
+    experts' share of them where the layer holds some of
+    ``routed_experts``."""
+    experts, d, f = up_weight.shape
     return tiles(rows.shape[0] * experts // (routed_experts or experts),
-                 experts, d, f, rows.dtype)
+                 experts, d, f, rows.dtype, gated)
 
 
 def moe_form(rows, gate_weight, down_weight):
@@ -153,8 +166,9 @@ def moe_form(rows, gate_weight, down_weight):
     ``"kernel"`` or ``"ragged_dot"``, from the operands' shapes and types
     and the backend; no caller, option or environment variable does. Each
     operand carries ``.shape`` and ``.dtype``: ``rows`` (N * k, D) sorted by
-    expert, ``gate_weight`` (held experts, D, F), ``down_weight`` (held
-    experts, F, D).
+    expert, ``gate_weight`` (held experts, D, F) a stack of the first call
+    (the one ``up`` of an ungated expert), ``down_weight`` (held experts,
+    F, D).
 
     ``"ragged_dot"``: three calls of XLA's grouped matmul. The CPU (a test
     that wants the kernel holds this rule and runs it interpreted), and on
@@ -222,7 +236,7 @@ def visits(sizes, rows, tm):
 
 
 def _kernel(off_ref, tile_ref, expert_ref, count_ref, run_ref, run_expert_ref,
-            runs_ref, x_ref, *refs, tm, mats, depth):
+            runs_ref, x_ref, *refs, tm, mats, depth, activation):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -279,6 +293,8 @@ def _kernel(off_ref, tile_ref, expert_ref, count_ref, run_ref, run_expert_ref,
                                 preferred_element_type=jnp.float32)
         if mats == 2:   # gate and up: the activation on the sums
             out = jax.nn.silu(dot(0)) * dot(1)
+        elif activation == "relu2":
+            out = jnp.square(jnp.maximum(dot(0), 0.0))
         else:
             out = dot(0)
         row = row0 + jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
@@ -286,13 +302,15 @@ def _kernel(off_ref, tile_ref, expert_ref, count_ref, run_ref, run_expert_ref,
                                out.astype(o_ref.dtype), o_ref[...])
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("tm", "tn", "depth", "interpret"))
-def grouped_matmul(rows, weights, meta, *, tm, tn, depth, interpret=False):
+@functools.partial(jax.jit, static_argnames=("tm", "tn", "depth",
+                                             "activation", "interpret"))
+def grouped_matmul(rows, weights, meta, *, tm, tn, depth, activation=None,
+                   interpret=False):
     """Row r of ``rows`` (M, K), in group e by ``meta`` = ``visits(sizes, M,
     tm)``, times ``weights[i][e]`` (E, K, N). One stack: the products, (M, N)
-    float32. Two stacks (gate, up): ``silu(rows . gate_e) * (rows . up_e)``,
-    the activation on the float32 sums, cast once to the rows' type. A row
+    float32; with ``activation="relu2"`` ``relu(rows . up_e)^2``, the
+    activation on the float32 sums, cast once to the rows' type. Two stacks
+    (gate, up): ``silu(rows . gate_e) * (rows . up_e)``, likewise. A row
     past the last group comes out zero. ``tn`` divides N. ``depth``: the
     buffers a matrix of the ring the kernel fetches into by hand, a run's
     matrices ``depth - 1`` runs ahead; when a matrix arrives, never what is
@@ -305,7 +323,12 @@ def grouped_matmul(rows, weights, meta, *, tm, tn, depth, interpret=False):
 
     m, k = rows.shape
     n = weights[0].shape[2]
-    out_dtype = rows.dtype if len(weights) == 2 else jnp.float32
+    if activation not in (None, "relu2") or (activation
+                                             and len(weights) != 1):
+        raise ValueError("grouped_matmul: activation %r over %d stack(s)"
+                         % (activation, len(weights)))
+    fused = len(weights) == 2 or activation is not None
+    out_dtype = rows.dtype if fused else jnp.float32
     itemsize = jnp.dtype(rows.dtype).itemsize
     blocks = 2 * (tm * k * itemsize
                   + tm * tn * jnp.dtype(out_dtype).itemsize) \
@@ -321,7 +344,8 @@ def grouped_matmul(rows, weights, meta, *, tm, tn, depth, interpret=False):
                     for _ in weights]
         scratch = []
     return pl.pallas_call(
-        functools.partial(_kernel, tm=tm, mats=len(weights), depth=depth),
+        functools.partial(_kernel, tm=tm, mats=len(weights), depth=depth,
+                          activation=activation),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(meta),
             grid=(n // tn, meta[1].shape[0]),
@@ -339,27 +363,32 @@ def grouped_matmul(rows, weights, meta, *, tm, tn, depth, interpret=False):
         # (the most a call can fetch) every expert's matrix once
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n * len(weights),
-            transcendentals=m * n * (len(weights) - 1),
+            transcendentals=m * n * (len(weights) - 1),  # the SiLU
             bytes_accessed=(m * k + sum(w.size for w in weights)) * itemsize
             + m * n * jnp.dtype(out_dtype).itemsize),
         interpret=interpret,
         name="grouped_matmul_gated" if len(weights) == 2
-        else "grouped_matmul",
+        else "grouped_matmul_relu2" if activation else "grouped_matmul",
     )(*meta, rows, *weights)
 
 
 def expert_ffn(rows, gate_weight, up_weight, down_weight, sizes,
                routed_experts=None, interpret=False):
     """``(silu(rows . gate_e) * (rows . up_e)) . down_e`` for the rows of
-    every group e: ``rows`` (M, D) sorted by expert, ``sizes`` (E,) rows a
-    group, the stacks (E, D, F), (E, D, F), (E, F, D). Returns (M, D)
-    float32, zero for a row past the last group. The tiles are
-    ``layer_tiles``' at these operands."""
+    every group e, or, where ``gate_weight`` is None (an ungated expert),
+    ``relu(rows . up_e)^2 . down_e``: ``rows`` (M, D) sorted by expert,
+    ``sizes`` (E,) rows a group, the stacks (E, D, F), (E, D, F), (E, F, D).
+    Returns (M, D) float32, zero for a row past the last group. The tiles
+    are ``layer_tiles``' at these operands."""
     m = rows.shape[0]
-    tm, tn_up, tn_down, depth = layer_tiles(rows, gate_weight, routed_experts)
+    gated = gate_weight is not None
+    tm, tn_up, tn_down, depth = layer_tiles(rows, up_weight, routed_experts,
+                                            gated)
     tm = min(tm, m)     # fewer rows than a tile: one tile of them all
     meta = visits(sizes, m, tm)
-    act = grouped_matmul(rows, (gate_weight, up_weight), meta, tm=tm,
-                         tn=tn_up, depth=depth, interpret=interpret)
+    act = grouped_matmul(
+        rows, (gate_weight, up_weight) if gated else (up_weight,), meta,
+        tm=tm, tn=tn_up, depth=depth,
+        activation=None if gated else "relu2", interpret=interpret)
     return grouped_matmul(act, (down_weight,), meta, tm=tm, tn=tn_down,
                           depth=depth, interpret=interpret)

@@ -208,7 +208,8 @@ def _moe(attrs, shapes):
     if data is not None:
         e, f, d = attrs["num_experts"], attrs["num_hidden"], data[-1]
         held = attrs.get("num_local_experts", 0) or e   # the stacks' rows
-        slots = ((e, d), (held, d, f), (held, d, f), (held, f, d), (e,))
+        first = 2 if attrs.get("gated", True) else 1    # gate and up, or up
+        slots = ((e, d),) + ((held, d, f),) * first + ((held, f, d), (e,))
         for i, s in enumerate(slots[:len(shapes) - 1], 1):   # router_bias last
             if shapes[i] is None:
                 shapes[i] = s
@@ -219,7 +220,8 @@ def _mamba2_weights(attrs, shapes):
     """conv_weight, conv_bias, dt_bias, A_log, D (slots 2..6) from the
     operator's sizes; returns (heads, head_dim, state, kernel, channels)."""
     h, p, n = attrs["num_heads"], attrs["head_dim"], attrs["state_size"]
-    k, c = attrs.get("conv_kernel", 4), h * p + 2 * n
+    k = attrs.get("conv_kernel", 4)
+    c = h * p + 2 * attrs.get("num_groups", 1) * n
     for i, s in enumerate(((c, k), (c,), (h,), (h,), (h,)), 2):
         if shapes[i] is None:
             shapes[i] = s

@@ -12,11 +12,13 @@ they are fed, and the state is float32 (the published ``mamba_ssm`` kernels
 keep it so). The projections around the core, the gate and its norm stay in
 the graph (models/transformer.py ``_granite_layer``).
 
-Per head h of P features, state N, one group of B and C, kernel K:
+Per head h of P features, state N, G groups of B and C, kernel K (head h
+reads group g = h // (H / G); ``num_groups`` 1, the default: every head reads
+the one B and C):
     xBC_t = silu(sum_{j<K} w[:, j] * xBC_{t-K+1+j} + b)     zeros left of t = 0
-    [x (H x P) | B (N) | C (N)] = xBC_t;  dt_t = softplus(dt_t + dt_bias)
-    S_t = exp(dt_t A_h) S_{t-1} + dt_t * x_t (outer) B_t,    A_h = -exp(A_log_h)
-    y_t = S_t C_t + D_h x_t
+    [x (H x P) | B (G x N) | C (G x N)] = xBC_t;  dt_t = softplus(dt_t + dt_bias)
+    S_t = exp(dt_t A_h) S_{t-1} + dt_t * x_t (outer) B_{t,g}, A_h = -exp(A_log_h)
+    y_t = S_t C_{t,g} + D_h x_t
 """
 from __future__ import annotations
 
@@ -33,19 +35,28 @@ _SIZES = {
     "head_dim": AttrSpec("int", required=True),
     "state_size": AttrSpec("int", required=True),
     "conv_kernel": AttrSpec("int", default=4),
+    "num_groups": AttrSpec("int", default=1),
 }
 _WEIGHTS = ("conv_weight", "conv_bias", "dt_bias", "A_log", "D")
 
 
 def _split(attrs, xbc):
-    """[x (..., H, P) | B (..., N) | C (..., N)] of the activated xBC."""
+    """[x (..., H, P) | B | C] of the activated xBC; B and C (..., N) of one
+    group, (..., G, N) of several."""
     h, p, n = attrs["num_heads"], attrs["head_dim"], attrs["state_size"]
-    if xbc.shape[-1] != h * p + 2 * n:
+    g = attrs.get("num_groups", 1)
+    if g < 1 or h % g:
+        raise MXNetError("Mamba2: %d heads do not divide over %d groups"
+                         % (h, g))
+    if xbc.shape[-1] != h * p + 2 * g * n:
         raise MXNetError("Mamba2: xBC has %d features, %d heads of %d and "
-                         "two states of %d need %d"
-                         % (xbc.shape[-1], h, p, n, h * p + 2 * n))
+                         "two states of %d in %d group(s) need %d"
+                         % (xbc.shape[-1], h, p, n, g, h * p + 2 * g * n))
     x = xbc[..., :h * p].reshape(xbc.shape[:-1] + (h, p))
-    return x, xbc[..., h * p:h * p + n], xbc[..., h * p + n:]
+    b, c = xbc[..., h * p:h * p + g * n], xbc[..., h * p + g * n:]
+    if g > 1:
+        b, c = (v.reshape(v.shape[:-1] + (g, n)) for v in (b, c))
+    return x, b, c
 
 
 def _f32(*arrays):
@@ -98,6 +109,30 @@ def _chunked_scan(x, dt, a, b, c, chunk):
     return jnp.moveaxis(y, 0, 1).reshape(bsz, nc * q, h, p)[:, :t], state
 
 
+def _grouped_scan(x, dt, a, b, c, chunk):
+    """``_chunked_scan`` where b and c (B, T, G, N) are one a GROUP of heads:
+    the heads (H = G x H/G, group-major) of each group scanned with the
+    group's b and c, the groups side by side (``vmap``: every contraction
+    gains a batch axis, nothing is repeated per head)."""
+    g = b.shape[2]
+    split = lambda v: v.reshape(v.shape[:2] + (g, -1) + v.shape[3:])
+    y, state = jax.vmap(
+        lambda *group: _chunked_scan(*group, chunk),
+        in_axes=(2, 2, 0, 2, 2), out_axes=(2, 1))(
+            split(x), split(dt), a.reshape(g, -1), b, c)
+    return y.reshape(x.shape), state.reshape(state.shape[:1] + x.shape[2:]
+                                             + state.shape[-1:])
+
+
+def _of_head(v, heads):
+    """A row's B or C as each of its ``heads`` reads it, against a state
+    (R, H, P, N): (R, N) of one group -> (R, 1, 1, N); (R, G, N) ->
+    (R, H, 1, N), head h taking group h // (H / G)."""
+    if v.ndim == 2:
+        return v[:, None, None, :]
+    return jnp.repeat(v, heads // v.shape[1], axis=1)[:, :, None, :]
+
+
 def columns_before(padded, length, count):
     """The ``count`` rows of each ``padded`` (B, count + T, C) that precede
     position ``length`` (B,) of its sequence, (B, count, C): position p sits
@@ -116,11 +151,11 @@ def columns_before(padded, length, count):
 )
 def _mamba2_scan(attrs, data, dt, conv_weight, conv_bias, dt_bias, A_log, D,
                  length):
-    """The core over a right-padded sequence: ``data`` (B, T, H*P + 2N) is
+    """The core over a right-padded sequence: ``data`` (B, T, H*P + 2GN) is
     the projected xBC before its convolution, ``dt`` (B, T, H) the raw step
     sizes, ``length`` (B, 1) the number of real positions a row (data, so one
     program serves every length). Returns ``(y (B, T, H*P), ssm_state
-    (B, H, P, N), conv_state (B, K-1, H*P + 2N))``: the outputs in ``data``'s
+    (B, H, P, N), conv_state (B, K-1, H*P + 2GN))``: the outputs in ``data``'s
     type (those past the length are meaningless), and in float32 the
     recurrent state after position ``length - 1`` and the last K-1
     PRE-activation xBC columns before ``length`` (zeros where the sequence is
@@ -140,8 +175,8 @@ def _mamba2_scan(attrs, data, dt, conv_weight, conv_bias, dt_bias, A_log, D,
     x, b, c = _split(attrs, conv)
     live = jnp.arange(t)[None, :] < n_real[:, None]
     dt = jnp.where(live[..., None], jax.nn.softplus(dt + dt_bias), 0.0)
-    y, state = _chunked_scan(x, dt, -jnp.exp(a_log), b, c,
-                             attrs["chunk_size"])
+    scan = _chunked_scan if b.ndim == 3 else _grouped_scan
+    y, state = scan(x, dt, -jnp.exp(a_log), b, c, attrs["chunk_size"])
     y = y + d[:, None] * x
     return y.reshape(bsz, t, -1).astype(data.dtype), state, conv_state
 
@@ -157,9 +192,9 @@ def _mamba2_scan(attrs, data, dt, conv_weight, conv_bias, dt_bias, A_log, D,
 )
 def _mamba2_step(attrs, data, dt, conv_weight, conv_bias, dt_bias, A_log, D,
                  ssm_state, conv_state, stepped):
-    """One token a row: ``data`` (R, H*P + 2N) and ``dt`` (R, H) as in
+    """One token a row: ``data`` (R, H*P + 2GN) and ``dt`` (R, H) as in
     ``Mamba2Scan``, ``ssm_state`` (R, H, P, N) and ``conv_state``
-    (R, K-1, H*P + 2N) the row's state, ``stepped`` (R, 1) negative for a row
+    (R, K-1, H*P + 2GN) the row's state, ``stepped`` (R, 1) negative for a row
     that rides along (a decode step's ``write_slot``). Returns ``(y (R, H*P),
     ssm_state', conv_state')``; the state of a row that rides along comes
     back bit for bit and its ``y`` is meaningless. Elementwise float32 and a
@@ -173,8 +208,8 @@ def _mamba2_step(attrs, data, dt, conv_weight, conv_bias, dt_bias, A_log, D,
     x, b, c = _split(attrs, conv)
     dt = jax.nn.softplus(dt + dt_bias)
     new = jnp.exp(dt * -jnp.exp(a_log))[:, :, None, None] * ssm_state \
-        + (dt[..., None] * x)[..., None] * b[:, None, None, :]
-    y = jnp.sum(new * c[:, None, None, :], axis=-1) + d[:, None] * x
+        + (dt[..., None] * x)[..., None] * _of_head(b, x.shape[1])
+    y = jnp.sum(new * _of_head(c, x.shape[1]), axis=-1) + d[:, None] * x
     moved = stepped.reshape(-1) >= 0
     return (y.reshape(y.shape[0], -1).astype(data.dtype),
             jnp.where(moved[:, None, None, None], new, ssm_state),

@@ -18,7 +18,8 @@ looks inside a pool). What
 the decode graph keeps between steps is a list of named buffers the model
 gives (``models.transformer.decode_cache``), of three kinds: those pools,
 addressed by slot; where a layer keeps a recurrent state or its last
-convolution columns instead (``arch="granite_hybrid"``, ``"lfm2_moe"``),
+convolution columns instead (``arch="granite_hybrid"``, ``"lfm2_moe"``,
+``"nemotron_h"``, whose expert blocks keep NOTHING and are in no list),
 per-lane rows ``(lanes, ...)`` addressed by lane; and, where a layer attends
 a WINDOW (``arch="mimo_v2_flash"``), per-lane rings ``(lanes, heads, window,
 d)`` addressed by lane and position mod the window, which take no frame and
@@ -311,15 +312,16 @@ def _moe_forms(cache, input_shapes):
     forms, depth = [], 0
     for n, ops in _operands_of(cache, input_shapes,
                                "_contrib_MoEFeedForward"):
-        data, gate = ops["data"], ops["gate_weight"]
+        data, up = ops["data"], ops["up_weight"]
         attrs = n.parsed_attrs()
+        gated = attrs.get("gated", True)
         rows = jax.ShapeDtypeStruct(
             (data.shape[0] * attrs["num_experts_per_tok"], data.shape[1]),
             data.dtype)
-        forms.append(moe_form(rows, gate, ops["down_weight"]))
+        forms.append(moe_form(rows, up, ops["down_weight"]))
         if forms[-1] == "kernel":
-            depth = max(depth,
-                        layer_tiles(rows, gate, attrs["num_experts"])[3])
+            depth = max(depth, layer_tiles(rows, up, attrs["num_experts"],
+                                           gated)[3])
     return forms, depth
 
 
@@ -993,6 +995,20 @@ class PagedKVDecoder:
     LAST row alone, so it hands back one row of logits
     (``serving.admit_self_rows`` / ``serving.admit_cross_rows``). It refuses
     what ``mimo_v2_flash`` refuses.
+
+    ``arch="nemotron_h"`` serves blocks of ONE mixer each
+    (``models.transformer._nemotron_h_layer``; extra sizes: ``layer_types``
+    of ``"mamba" | "moe" | "attention"``, ``num_kv_heads``, ``head_dim``,
+    ``mamba_heads``, ``mamba_head_dim``, ``mamba_state``, ``mamba_groups``,
+    ``mamba_conv``, ``mamba_chunk``, ``moe_ffn_dim``, ``shared_ffn_dim``,
+    ``num_experts``, ``num_experts_per_tok``, ``num_local_experts`` /
+    ``local_expert_offset``, ``routed_scaling_factor``): a Mamba-2 block
+    keeps ``granite_hybrid``'s two rows (its B and C in groups), an
+    attention block its two pools, an expert block nothing, so the cache is
+    rows and pools in block order with gaps, and ``moe_load`` has a row an
+    EXPERT block: the ``serving.moe.*`` counters and the held experts'
+    slice read it as they read every other arch's. It refuses what
+    ``granite_hybrid`` refuses.
     """
 
     def __init__(self, arg_params: Dict[str, object], vocab_size,

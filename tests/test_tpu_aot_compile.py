@@ -1101,3 +1101,116 @@ def test_the_page_walk_at_forty_query_heads_over_ten_of_128(v5e):
         q, k, v, table, context, scale=0.125, interpret=False)
     _compile(v5e, fn, query, pool, pool,
              ((lanes, max_len // page), "int32"), ((lanes,), "int32"))
+
+
+_NEMOTRON = dict(
+    arch="nemotron_h", vocab_size=65536, num_layers=13, num_heads=32,
+    num_kv_heads=2, head_dim=128, model_dim=2688, ffn_dim=1856,
+    layer_types=[{"M": "mamba", "E": "moe", "*": "attention"}[c]
+                 for c in "MEMEM*EMEMEM*"],
+    mamba_heads=64, mamba_head_dim=64, mamba_state=128, mamba_groups=8,
+    mamba_conv=4, mamba_chunk=128, moe_ffn_dim=1856, shared_ffn_dim=3712,
+    num_experts=128, num_experts_per_tok=6, num_local_experts=64,
+    local_expert_offset=0, routed_scaling_factor=2.5, norm_topk_prob=True,
+    dtype="bfloat16")
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode", "kernel"])
+def test_nemotron_h_serving_programs_compile_for_the_chip(v5e, program):
+    """The two graphs ``PagedKVDecoder(arch="nemotron_h")`` runs, lowered for
+    the v5e at NVIDIA-Nemotron-3-Nano-30B-A3B's published widths, the cell's
+    cut (blocks 0-12, 64 of 128 experts held, half the vocabulary) and its
+    serving sizes (64 lanes x 8,192 slots, a 2,048 bucket), and the
+    1,856-wide UNGATED expert layer's kernel alone at the step's rows. What
+    has to hold on the chip: the five expert blocks run the Pallas kernel,
+    TWO calls a block (``grouped_matmul_relu2`` over the ONE ``up`` stack
+    stored 1,920 wide, then ``grouped_matmul``), with the ring ``tiles``
+    names for an ungated first call, and no ``ragged-dot``; the two
+    attention blocks read their page-major pools (2 heads of 128: a row of
+    256) through the kernel that walks the page table at 16 queries a
+    key/value head; the cache is twelve float32 rows and two pool pairs,
+    updated in place; an expert block keeps nothing; and a step's arguments
+    are the 7.96 GB of stored weights and the 1.91 GB of cache once."""
+    from mxnet_tpu.models import transformer as tf
+    from mxnet_tpu.ops import pallas_grouped_matmul as kernel
+    from mxnet_tpu.ops.attention import pool_shape
+
+    lanes, max_len, bucket, page = 64, 8192, 2048, 16
+    slots, cfg = lanes * max_len, _NEMOTRON
+    struct = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    stacks = struct(64, 2688, 1920), struct(64, 1920, 2688)
+    for tokens, tiles in ((lanes, (32, 1920, 2688, 3)),
+                          (bucket, (128, 1920, 2688, 2))):
+        rows = struct(tokens * 6, 2688)
+        assert kernel.moe_form(rows, *stacks) == "kernel"
+        assert kernel.layer_tiles(rows, stacks[0], 128, gated=False) == tiles
+    # the published width itself is no whole lane tiles: XLA's form
+    assert kernel.moe_form(struct(384, 2688), struct(64, 2688, 1856),
+                           struct(64, 1856, 2688)) == "ragged_dot"
+    if program == "kernel":
+        _compile(v5e, lambda rows, up, down, sizes: kernel.expert_ffn(
+            rows, None, up, down, sizes, 128),
+            ((384, 2688), "bfloat16"), ((64, 2688, 1920), "bfloat16"),
+            ((64, 1920, 2688), "bfloat16"), ((64,), "int32"))
+        return
+    weights = {n: (s, "bfloat16") for n, s in tf.param_shapes(**cfg).items()}
+    cache = tf.decode_cache(**cfg)
+    assert [kind for _, kind, _ in cache] == \
+        ["row"] * 6 + ["pool"] * 2 + ["row"] * 6 + ["pool"] * 2
+    if program == "prefill":
+        sym = tf.get_prefill_symbol(prefill_len=bucket, **cfg)
+        inputs = {"data": ((1, bucket), "float32"),
+                  "length": ((1, 1), "float32")}
+    else:
+        sym = tf.get_decode_symbol(max_len=slots, page_size=page, **cfg)
+        inputs = {"data": ((lanes, 1), "float32"),
+                  "pos_idx": ((lanes, 1), "float32"),
+                  "write_slot": ((lanes, 1), "float32"),
+                  "page_table": ((lanes, max_len // page), "float32")}
+        for name, kind, shape in cache:
+            inputs[name] = (pool_shape(*shape, slots, page), "bfloat16") \
+                if kind == "pool" else ((lanes,) + tuple(shape), "float32")
+    compiled = _compile_program(
+        v5e, sym, {**weights, **inputs},
+        donated=[name for name, _, _ in cache] if program == "decode" else ())
+    types = ["float32" if kind == "row" else "bfloat16"
+             for _, kind, _ in cache]
+    assert [str(s.dtype) for s in compiled.out_info[0]] == \
+        ["float32"] + types + ["float32"] * (1 if program == "prefill"
+                                             else 2)    # (token,) moe_load
+    assert compiled.out_info[0][-1].shape == (5, 128)       # moe_load
+    hlo = compiled.as_text()
+    calls = [line for line in hlo.splitlines() if " custom-call(" in line
+             and 'custom_call_target="tpu_custom_call"' in line
+             and "/grouped_matmul" in line]
+    assert len(calls) == 10 and "ragged" not in _program_alone(hlo).lower()
+    assert sum("/grouped_matmul_relu2/" in line for line in calls) == 5
+    depth = 3 if program == "decode" else 2
+    for line in calls:
+        ring = depth * 2 * 2688 * 1920      # ONE matrix a slot, both calls
+        (at, limit), (_, end) = (map(int, re.search(
+            r'"%s":\[\{"memory_space":"1","offset":"(\d+)","size":"(\d+)"'
+            % key, line).groups()) for key in (
+                "scoped_memory_configs", "used_scoped_memory_configs"))
+        assert ring <= end - at <= min(ring + (16 << 20), limit), \
+            (ring, at, end, limit)
+    mem = compiled.memory_analysis()
+    if program == "prefill":
+        _assert_one_row_of_logits(compiled, bucket, 65536)
+        # 2 x 2,048 tokens x (6 x 38.7 M + 2 x 23.4 M + 5 x (20.3 M + 3
+        # experts of 10.0 M)) MACs = 2.2 TFLOP, the chunked scans and the
+        # attention beside them; 128 dense experts a token would be 27
+        assert 2.5e12 < compiled.cost_analysis()["flops"] < 3.4e12
+        assert mem.temp_size_in_bytes < 1 << 30
+        return
+    assert compiled.out_info[0][1].shape == (lanes, 64, 64, 128)
+    assert compiled.out_info[0][7].shape == (slots // page, page, 256)
+    assert len(_paged_read_calls(hlo)) == 2 and "kv_mask" not in hlo
+    assert "slot_onehot" not in hlo
+    _assert_no_pool_sized_copy(hlo, min(lanes * 64 * 64 * 128,
+                                        2 * slots * 128))
+    cache_bytes = 2 * 2 * slots * 256 * 2 + 6 * lanes * (
+        64 * 64 * 128 + 3 * 6144) * 4
+    assert mem.alias_size_in_bytes == cache_bytes == 1_907_359_744
+    assert mem.argument_size_in_bytes < 9_990_000_000
+    assert mem.temp_size_in_bytes < 128 << 20
