@@ -25,6 +25,10 @@ a seed), and checks what comes out by the repo's own means:
              transformer step with the kernels forced
   5 4 chips  (when >= 4 devices) phase 1 on a {"data": 4} mesh, Module on
              four contexts, ring attention on {"data": 2, "seq": 2}
+  6 prefill  a prefill's causal attention at OLMoE's (1, 16, 2048, 128) and
+             nemotron's (1, 32 over 2, 2048, 128) in bfloat16: the blockwise
+             kernel the rule names against the dense path, the worst
+             relative difference and a layer's time in each
 
 Every phase prints PASS, FAIL or SKIP <reason>; a skip is never the result
 of an exception. Any FAIL makes the exit code 1. With no TPU the script
@@ -81,6 +85,10 @@ if not REHEARSE:
         pool=(64, 8, 64 * 1024, 64),
         attn=(8, 8, 512, 64), mba=(4096, 512, 2048), ln=(4096, 512),
         matmul_n=8192,
+        # a prefill's attention layer: (query heads, key/value heads, bucket,
+        # head width) of olmoe-1b-7b.score and nemotron-3-nano-30b-a3b.generate
+        prefill_attn={"olmoe": (16, 16, 2048, 128),
+                      "nemotron": (32, 2, 2048, 128)},
     )
 else:
     SZ = dict(
@@ -94,6 +102,7 @@ else:
         pool=(4, 2, 64, 64),
         attn=(1, 2, 16, 8), mba=(16, 16, 128), ln=(16, 128),
         matmul_n=256,
+        prefill_attn={"olmoe": (2, 2, 32, 16), "nemotron": (8, 2, 32, 16)},
     )
 
 
@@ -1023,6 +1032,69 @@ def phase_four_chips():
           "outputs are finite softmax rows")
 
 
+def phase_prefill_attention():
+    """``MultiHeadAttention`` as a prefill calls it (one sequence, causal,
+    bfloat16) at two cells' shapes: the form the rule names on this backend
+    (the blockwise kernel) against the dense path (the rule held to it), the
+    worst difference over the output's largest magnitude and a layer's time in
+    each, eight layers inside one program."""
+    from mxnet_tpu.ops import attention as attn_op
+    from mxnet_tpu.ops.registry import get_op
+
+    op = get_op("_contrib_MultiHeadAttention").fn
+    attrs = {"causal": True, "scale": -1.0, "window": 0}
+    layers, rs = 8, np.random.RandomState(6)
+    rule = attn_op.attention_form
+
+    def layer_ms(fn, args, n=20):
+        out = fn(*args)
+        out.block_until_ready()
+        times = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(n):
+                out = fn(*args)
+            out.block_until_ready()
+            times.append((time.perf_counter() - t0) / n / layers * 1e3)
+        return out, float(np.median(times))
+
+    for name, (h, hkv, t, d) in SZ["prefill_attn"].items():
+        say("  -- %s: %d query heads over %d key/value heads, %d positions, "
+            "width %d, bfloat16" % (name, h, hkv, t, d))
+        qs, k, v = (jnp.asarray(rs.randn(*shape).astype("float32"),
+                                jnp.bfloat16)
+                    for shape in ((layers, 1, h, t, d), (1, hkv, t, d),
+                                  (1, hkv, t, d)))
+        ruled = rule(qs[0], k, v, True)
+        if not REHEARSE:
+            check(ruled == "kernel", "the rule names the kernel (%s)" % ruled)
+        outs = {}
+        for form in ("kernel", "dense"):
+            # the rule is read at trace time: hold it while the form compiles
+            attn_op.attention_form = lambda *a, _f=form: _f
+            try:
+                fn = jax.jit(lambda qs, k, v: jax.lax.map(
+                    lambda q: op(attrs, q, k, v), qs))
+                if form == "kernel" and not REHEARSE:
+                    check(is_mosaic(fn, qs, k, v),
+                          "the operator lowers to a Mosaic custom call")
+                outs[form] = layer_ms(fn, (qs, k, v), 1 if REHEARSE else 20)
+            finally:
+                attn_op.attention_form = rule
+        got, want = (np.asarray(outs[f][0], np.float32)
+                     for f in ("kernel", "dense"))
+        diff = float(np.abs(got - want).max() / np.abs(want).max())
+        say("    a layer: kernel %.3f ms, dense %.3f ms (x%.2f); worst "
+            "difference over the largest output %.2e"
+            % (outs["kernel"][1], outs["dense"][1],
+               outs["dense"][1] / outs["kernel"][1], diff))
+        check(diff < 2e-2, "kernel and dense path agree to a bfloat16 "
+              "rounding of the output (%.2e)" % diff)
+        if not REHEARSE:
+            check(outs["kernel"][1] < outs["dense"][1],
+                  "the kernel is the faster form where the rule names it")
+
+
 # --------------------------------------------------------------------- main
 PHASES = [
     ("device", phase_device),
@@ -1031,6 +1103,8 @@ PHASES = [
     ("serve: PagedKVDecoder, Transformer-base", phase_serve),
     ("kernels: Mosaic vs XLA, a forced step", phase_kernels),
     ("four chips", phase_four_chips),
+    ("prefill attention: the kernel against the dense path",
+     phase_prefill_attention),
 ]
 
 

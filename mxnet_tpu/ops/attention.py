@@ -20,9 +20,75 @@ from .registry import AttrSpec, register
 # the additive mask of a pool slot a row does not hold: exp(-1e9) is 0 exactly
 _NEG = np.float32(-1e9)
 
-# trace-time dispatch counters (observability for tests and the multichip
-# dryrun: proves the seq-parallel path actually engaged)
-DISPATCH_COUNTS = {"ring": 0, "pallas": 0, "xla": 0}
+# trace-time dispatch counters, a form of ``attention_form`` each
+# (observability for tests and the multichip dryrun: proves the seq-parallel
+# path, or the kernel, actually engaged)
+DISPATCH_COUNTS = {"ring": 0, "kernel": 0, "band": 0, "dense": 0}
+
+
+def _backend():
+    """Where the program being traced will run: Mosaic runs on the chip
+    alone. (A test that compiles for the chip from the CPU holds this to
+    ``"tpu"``.)"""
+    return jax.default_backend()
+
+
+def attention_form(query, key, value, causal, window=0, sink=False,
+                   mesh=None):
+    """THE rule that names the form ``MultiHeadAttention`` runs in, from the
+    operands' shapes and types, the attributes, the mesh being traced under
+    and the backend; no caller, option or environment variable does. Each
+    operand carries ``.shape`` and ``.dtype``: ``query`` (B, H, T, dk), ``key``
+    (B, Hkv, S, dk), ``value`` (B, Hkv, S, dv).
+
+    ``"ring"``: plain self-attention (no window, no sink, equal head counts)
+    traced under a ``mesh`` with a ``seq`` axis that divides T:
+    ``parallel/ring_attention.py``.
+
+    ``"band"``: a window of W < T positions, T a multiple of W: T x 2W scores
+    (``_band_attention``).
+
+    ``"kernel"``: plain CAUSAL attention over S >= T keys on the chip, where
+    ``pallas_attention.takes`` the operands: blockwise with an online softmax,
+    the (heads, T, S) scores never written, nothing above the diagonal
+    computed or fetched; grouped heads and a value narrower than the key
+    among them. Never under a ``mesh`` of several devices: the compiler
+    partitions the dense path over a mesh and cannot partition a kernel (it
+    would gather the operands whole onto every device).
+
+    ``"dense"``: everything else, the scores of all T x S pairs in float32:
+    every backend but the chip (a test that wants the kernel holds this rule
+    and runs it interpreted), a step traced over several devices, cross- and
+    bidirectional attention, a sink, a ragged window, and on the chip the
+    shapes the kernel refuses or does not
+    win (``pallas_attention.takes`` says which, with the chip runs that
+    decided)."""
+    b, h, t, _ = query.shape
+    hkv, s = key.shape[1], key.shape[2]
+    plain = window <= 0 and not sink
+    if plain and h == hkv and mesh is not None \
+            and "seq" in mesh.axis_names and mesh.shape["seq"] > 1 \
+            and s == t and t % mesh.shape["seq"] == 0 \
+            and ("data" not in mesh.axis_names
+                 or b % mesh.shape["data"] == 0):
+        return "ring"
+    if 0 < window < t and t % window == 0:
+        return "band"
+    if plain and causal and _backend() == "tpu" \
+            and (mesh is None or mesh.size == 1):
+        from . import pallas_attention as kernel
+
+        if kernel.takes(query, key, value):
+            return "kernel"
+    return "dense"
+
+
+def _multi_head_attention_out(attrs, inputs):
+    """``MultiHeadAttention``'s output from its operands' shapes: (B, H, T,
+    the value's width) in the query's type. Shape inference asks this and
+    traces no form: the kernel's would import Pallas to say the same."""
+    query, _, value = inputs[:3]
+    return [(query.shape[:3] + (value.shape[3],), query.dtype)]
 
 
 @register(
@@ -36,27 +102,31 @@ DISPATCH_COUNTS = {"ring": 0, "pallas": 0, "xla": 0}
     input_names=lambda attrs: ("query", "key", "value") + (
         ("sink",) if attrs.get("sink") else ()),
     aliases=("MultiHeadAttention",),
+    infer=_multi_head_attention_out,
 )
 def _multi_head_attention(attrs, query, key, value, sink=None):
-    """softmax(QKᵀ·scale + mask)V over (B, H, T, D) tensors. Computation in
-    fp32 for a stable softmax regardless of the IO dtype (bf16 fast path).
-    ``MXNET_USE_PALLAS_ATTENTION=1`` routes to the hand-tiled flash kernel
-    (ops/pallas_attention.py) on TPU when the shapes tile cleanly.
+    """softmax(QKᵀ·scale + mask)V over (B, H, T, D) tensors, in the form
+    ``attention_form`` names from the shapes, the attributes and the backend.
+    The dense forms compute in fp32 for a stable softmax regardless of the IO
+    dtype (bf16 fast path). On the chip, plain causal attention runs
+    blockwise (``ops/pallas_attention.py``): the same mathematics in the same
+    types (both products one pass of the matrix unit in the operands' type
+    with a float32 accumulator, the softmax float32), so the forms differ by
+    the order of a float32 sum.
 
     Sequence parallelism: when traced inside an SPMD step whose mesh has a
     ``seq`` axis (parallel.make_mesh({"data": dp, "seq": sp})), self-attention
     dispatches to ring attention (parallel/ring_attention.py) — q stays put,
     k/v blocks rotate over ICI via ppermute, softmax accumulates online.
-    Disable with MXNET_RING_ATTENTION=0.
+    Disable with MXNET_RING_ATTENTION=0 (the call is then dense).
 
     Grouped queries: ``key`` / ``value`` may carry fewer heads (B, Hkv, S, D)
     than ``query`` (B, H, T, D), Hkv dividing H; key/value head j then serves
     query heads j * H/Hkv .. (j + 1) * H/Hkv - 1. The group is an axis of the
     query that both contractions carry (the keys are never repeated), of
-    size 1 where the head counts are equal. Fewer key/value heads take the
-    dense path only. ``value`` may be narrower or wider than ``key`` (latent
-    attention's 128 under a 192-wide key): the output takes the value's
-    width, on the dense path.
+    size 1 where the head counts are equal. ``value`` may be narrower or
+    wider than ``key`` (latent attention's 128 under a 192-wide key): the
+    output takes the value's width.
 
     ``window`` = W > 0 (causal self-attention only) lets position t attend
     the W positions t - W < j <= t, itself among them. ``sink=True`` takes a
@@ -74,47 +144,41 @@ def _multi_head_attention(attrs, query, key, value, sink=None):
     hkv, s_len = key.shape[1], key.shape[2]
     g = _kv_groups(h, hkv, "MultiHeadAttention")
     window = attrs.get("window", 0)
-    plain = window <= 0 and sink is None
     if window > 0 and not (attrs["causal"] and s_len == t):
         raise MXNetError("MultiHeadAttention: a window needs causal "
                          "self-attention, got causal=%s over %d queries and "
                          "%d keys" % (attrs["causal"], t, s_len))
-    mesh = None
-    if plain and g == 1 and os.environ.get("MXNET_RING_ATTENTION", "1") == "1":
-        from ..parallel.mesh import current_trace_mesh
+    from ..parallel.mesh import current_trace_mesh
 
-        mesh = current_trace_mesh()
-    if (mesh is not None and "seq" in mesh.axis_names
-            and mesh.shape["seq"] > 1):
-        batch_ok = ("data" not in mesh.axis_names
-                    or b % mesh.shape["data"] == 0)
-        if s_len == t and t % mesh.shape["seq"] == 0 and batch_ok:
-            # self-attention with divisible shards only; else dense fallback
-            from ..parallel.ring_attention import ring_attention
+    mesh = current_trace_mesh()
+    form = attention_form(query, key, value, attrs["causal"], window,
+                          sink is not None, mesh)
+    if form == "ring" and os.environ.get("MXNET_RING_ATTENTION", "1") != "1":
+        form = "dense"
+    DISPATCH_COUNTS[form] += 1
+    if form == "ring":
+        from ..parallel.ring_attention import ring_attention
 
-            DISPATCH_COUNTS["ring"] += 1
-            out = ring_attention(
-                query.transpose(0, 2, 1, 3), key.transpose(0, 2, 1, 3),
-                value.transpose(0, 2, 1, 3), mesh, seq_axis="seq",
-                causal=attrs["causal"],
-                scale=attrs["scale"] if attrs["scale"] > 0 else None,
-                batch_axis="data" if "data" in mesh.axis_names else None)
-            return out.transpose(0, 2, 1, 3)
-
-    if plain and g == 1 \
-            and os.environ.get("MXNET_USE_PALLAS_ATTENTION", "0") == "1":
+        out = ring_attention(
+            query.transpose(0, 2, 1, 3), key.transpose(0, 2, 1, 3),
+            value.transpose(0, 2, 1, 3), mesh, seq_axis="seq",
+            causal=attrs["causal"],
+            scale=attrs["scale"] if attrs["scale"] > 0 else None,
+            batch_axis="data" if "data" in mesh.axis_names else None)
+        return out.transpose(0, 2, 1, 3)
+    if form == "kernel":
         from . import pallas_attention as pa
 
-        if pa.supported(query.shape, key.shape, causal=attrs["causal"]):
-            on_tpu = jax.default_backend() == "tpu"
-            return pa.flash_attention(
-                query, key, value, causal=attrs["causal"],
-                scale=max(attrs["scale"], 0.0), interpret=not on_tpu)
+        # off the chip (a test that holds the rule to the kernel) Pallas
+        # interprets it
+        return pa.flash_attention(
+            query, key, value, causal=True, scale=max(attrs["scale"], 0.0),
+            interpret=_backend() != "tpu")
     scale = attrs["scale"] if attrs["scale"] > 0 else 1.0 / np.sqrt(d)
     q = query.astype("float32").reshape(b, hkv, g, t, d)
     if sink is not None:
         sink = sink.astype("float32").reshape(hkv, g)
-    if 0 < window < t and t % window == 0:
+    if form == "band":
         out = _band_attention(q, key.astype("float32"),
                               value.astype("float32"), window, scale, sink)
         return out.reshape(b, h, t, value.shape[-1]).astype(query.dtype)
@@ -436,13 +500,6 @@ def pool_read_bytes(query, pool_k, pool_v, page_table, page_size):
     whole = pool_bytes + 3 * 4 * rows * heads * pool_k.shape[1]
     own = 2 * pool_bytes + 4 * copy_bytes + 3 * 4 * rows * heads * own_slots
     return whole, own
-
-
-def _backend():
-    """Where the program being traced will run: Mosaic runs on the chip
-    alone. (A test that compiles for the chip from the CPU holds this to
-    ``"tpu"``.)"""
-    return jax.default_backend()
 
 
 def pool_read_form(query, pool_k, pool_v, page_table, page_size):

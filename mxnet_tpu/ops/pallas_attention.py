@@ -1,26 +1,41 @@
-"""Flash attention as a differentiable Pallas TPU kernel.
+"""Blockwise (online-softmax) attention as a differentiable Pallas TPU kernel.
 
-The fused-softmax-attention hot path, hand-tiled for VMEM. Queries tile over
-one grid axis and keys/values stream over the innermost grid axis in
-``block_k`` tiles — each grid step DMAs one (block_k, D) K/V tile from HBM,
-with the online-softmax running state (m, l, acc) carried across the k steps
-in VMEM scratch. The (T, S) score matrix never materializes and K/V never
-occupy more than one tile of VMEM, so long-S shapes stream instead of
-blowing VMEM. O(T·D) memory instead of O(T·S).
+``softmax(QKᵀ·scale + mask)V`` in blocks that never leave the chip: a block of
+queries stays in VMEM while the keys and values stream past it ``block_k`` at a
+time, and the running maximum, sum and accumulator (float32) are carried across
+the key steps in VMEM scratch. The (T, S) scores are never written to HBM:
+O(T·D) memory instead of O(T·S).
 
-Training-ready: ``jax.custom_vjp`` with recompute-style flash backward
-kernels (dq and dk/dv passes re-derive the probabilities from the saved
-logsumexp rather than storing P), the same structure cuDNN-era fused
-attention used on GPU. This is the kernel counterpart of the reference's
-cuDNN attention ops; the pure-XLA path (ops/attention.py) remains the
-default, and this kernel is opted in with ``MXNET_USE_PALLAS_ATTENTION=1``
-on TPU (it also runs anywhere under Pallas interpret mode, which is how the
-tests exercise it on CPU).
+The arithmetic is the dense path's (``ops/attention.py``), not a wider one:
+both products take their operands in the type they ARRIVE in (one bfloat16 pass
+on the matrix unit for bfloat16 inputs, float32 operands for float32 inputs)
+with a float32 accumulator; the scale multiplies the float32 scores after the
+product; the probabilities are rounded to the values' type for the second
+product, which is what the chip's default matmul precision does to the dense
+path's float32 probabilities.
 
-Layout: (B, H, T, D) folded to (B*H, T, D). The causal mask is bottom-right
-aligned for rectangular S >= T (decode) shapes, matching ops/attention.py;
-causal with S < T is rejected by ``supported()`` (fully-masked rows would
-poison the online softmax).
+Causal calls compute and fetch nothing above the diagonal: for a block of
+queries the key axis ends at the diagonal (the body runs under ``pl.when`` and
+the key/value ``index_map`` is clamped to the last block the queries need, so
+the pipeline fetches no block the body skips); a block wholly below the
+diagonal is not masked at all. The mask is bottom-right aligned for S >= T, as
+the dense path's is; causal with S < T is refused by ``supported()`` (rows with
+no key at all have no softmax).
+
+Grouped queries: ``k`` / ``v`` may carry fewer heads (B, Hkv, S, D) than ``q``
+(B, H, T, D). The keys are never repeated: the H / Hkv query heads a key/value
+head serves are folded into the ROWS of that head's query block, so one grid
+step multiplies ``group x block_q`` rows with one key block. ``v`` may be
+narrower or wider than ``k`` (a latent attention's 128 under a key of 192): the
+output takes the value's width.
+
+Training-ready: ``jax.custom_vjp`` with recompute-style backward kernels (the dq
+and dk/dv passes re-derive the probabilities from the saved logsumexp rather
+than storing P). ``MultiHeadAttention`` reaches the kernel through ONE rule,
+``ops.attention.attention_form``, which reads shapes, attributes and the
+backend (the kernel on the chip, the dense path everywhere else); the fusion
+engine's ``attention`` pattern reaches it by name (``pallas_flash``). It runs
+anywhere under Pallas interpret mode, which is how the CPU tests exercise it.
 """
 from __future__ import annotations
 
@@ -30,9 +45,89 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["flash_attention", "supported", "block_schedules"]
+__all__ = ["flash_attention", "supported", "blocks", "block_schedules"]
 
 _NEG_INF = -1e30
+
+_LANES = 128
+
+# the VMEM a call's blocks may take together (the chip has 128 MiB; the
+# compiler's own default limit is 16, asked for by name where a call needs
+# more)
+_VMEM_BUDGET = 24 << 20
+
+
+def _sublanes(dtype):
+    """Rows of one tile of ``dtype`` on the chip: 8 of float32, 16 of
+    bfloat16."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def block_bytes(block_q, block_k, group, dk, dv, dtype):
+    """The VMEM one grid step of the forward kernel holds, from its blocks
+    alone: the query and output blocks and the key and value blocks, each
+    twice (the pipeline's two buffers), the float32 scores and probabilities
+    and the probabilities once more in the values' type, and the scratch
+    (accumulator, maximum and sum a row, a lane tile wide each)."""
+    size = jnp.dtype(dtype).itemsize
+    rows = group * block_q
+    pad = lambda d: -(-d // _LANES) * _LANES
+    return (2 * rows * (pad(dk) + pad(dv)) * size
+            + 2 * block_k * (pad(dk) + pad(dv)) * size
+            + rows * block_k * (4 + 4 + size)
+            + rows * (pad(dv) + 2 * _LANES) * 4)
+
+
+# the rule's two sizes, settled on the chip (``blocks``)
+_ROWS = 1024
+_BLOCK_K = 1024
+
+
+def blocks(t, s, group, dk, dv, dtype):
+    """``(block_q, block_k)`` of the forward kernel for ``t`` queries a head
+    over ``s`` keys, ``group`` query heads a key/value head, a key of ``dk``
+    and a value of ``dv`` numbers of ``dtype``; None where no block tiles the
+    shape. THE rule of the kernel's tiling, from the shape, the operands'
+    bytes and the VMEM budget alone (no option, no model's name), written
+    from chip runs at the cells' shapes (``PERF.md`` section 6, PR 49).
+
+    ``block_k`` is the largest divisor of ``s`` in whole lane tiles up to
+    ``_BLOCK_K``, and a step holds up to ``_ROWS`` rows, ``group x block_q``
+    (``block_q`` a divisor of ``t`` in whole sublane tiles, so that the group
+    folds into the rows without a re-layout), halved while the step's blocks
+    overflow ``_VMEM_BUDGET``. Both are 1,024 because a step's cost is its
+    ROWS' as much as its scores': the running maximum, sum and the
+    accumulator's rescaling are a pass over ``rows`` lane-sparse vectors each
+    whatever ``block_k`` is, so a wide key block amortises them (OLMoE's
+    layer, 16 heads x 2,048 x 128: 0.68 ms at keys of 256, 0.34 at 512, 0.23
+    at 1,024 under 1,024 rows; the rows matter little, 0.25 at 512), and past
+    1,024 the diagonal's waste wins (0.30 at 2,048: a causal call computes
+    whole blocks the diagonal crosses). At t = 1,024 a head is ONE step.
+    Splitting a step's softmax into row chunks inside a loop, to keep the
+    scores in registers, read 2 to 4.6 times SLOWER at every shape and is
+    not here."""
+    unit = _sublanes(dtype)
+    bk = _largest_divisor(s, _BLOCK_K, _LANES) or _largest_divisor(s, s, unit)
+    bq = _largest_divisor(t, max(_ROWS // group, unit), unit)
+    if not bq or not bk:
+        return None
+    while block_bytes(bq, bk, group, dk, dv, dtype) > _VMEM_BUDGET:
+        if bq * group >= bk and bq % (2 * unit) == 0:
+            bq //= 2
+        elif bk % (2 * _LANES) == 0:
+            bk //= 2
+        else:
+            return None
+    return bq, bk
+
+
+def _largest_divisor(n, most, unit):
+    """The largest divisor of ``n`` that is a multiple of ``unit`` and at most
+    ``most``; 0 where there is none."""
+    for d in range(min(most, n) // unit * unit, 0, -unit):
+        if n % d == 0:
+            return d
+    return 0
 
 
 def block_schedules(q_shape, k_shape, causal=False):
@@ -43,7 +138,8 @@ def block_schedules(q_shape, k_shape, causal=False):
     T, S = q_shape[2], k_shape[2]
     seen, out = set(), []
     for bq, bk in ((128, 128), (128, 256), (256, 128), (64, 128),
-                   (128, 64), (64, 64), (256, 256), (32, 32)):
+                   (128, 64), (64, 64), (256, 256), (1024, 1024),
+                   (512, 1024), (512, 512), (32, 32)):
         eff = (min(bq, T), min(bk, S))
         if eff in seen or not supported(q_shape, k_shape, causal=causal,
                                         block_q=bq, block_k=bk):
@@ -61,28 +157,75 @@ def supported(q_shape, k_shape, causal=False, block_q=128, block_k=128):
         # bottom-right alignment would fully mask rows r < T-S; the online
         # softmax has no valid key for them — use the XLA path instead
         return False
+    if k_shape[1] < 1 or H % k_shape[1]:
+        return False
     bq, bk = min(block_q, T), min(block_k, S)
     # block dims must stay sublane-aligned (8 for f32) or Mosaic rejects them
     return (T % bq == 0 and S % bk == 0 and bq % 8 == 0 and bk % 8 == 0
             and D % 8 == 0)
 
 
+# the float32 scores the dense path makes, B x H x T x S x 4 bytes, up to which
+# it stays dense on the chip (``takes``)
+_DENSE_SCORES = 64 << 20
+
+
+def takes(query, key, value):
+    """Whether the chip runs plain causal attention over these operands as
+    this kernel rather than densely (shapes and types alone; each operand
+    carries ``.shape`` and ``.dtype``: ``query`` (B, H, T, dk), ``key``
+    (B, Hkv, S, dk), ``value`` (B, Hkv, S, dv)): what ``attention_form`` asks.
+
+    One type throughout, bfloat16 or float32; S >= T; T and S whole lane
+    tiles and head widths whole sublane tiles (what Mosaic tiles); a tiling
+    (``blocks``); and float32 scores of more than ``_DENSE_SCORES`` bytes.
+    Up to there the chip keeps the dense path's scores in its vector memory
+    and its fused program is the faster one: at 32 MiB of scores (32 heads x
+    512 x 512; 8 x 1,024 x 1,024, widths of 64) a layer standing alone read
+    0.039 and 0.035 ms dense against the kernel's 0.070 and 0.038 at its
+    best blocks, at 128 MiB (32 x 1,024 x 1,024) 0.59-0.66 dense against
+    0.17-0.20, and the gap widens from there (``PERF.md`` section 6, PR 49)."""
+    if not (query.dtype == key.dtype == value.dtype
+            and query.dtype in (jnp.bfloat16, jnp.float32)):
+        return False
+    (b, h, t, dk), (_, hkv, s, _), dv = query.shape, key.shape, value.shape[3]
+    if hkv < 1 or h % hkv or s < t or t % _LANES or s % _LANES \
+            or dk % 8 or dv % 8 or 4 * b * h * t * s <= _DENSE_SCORES:
+        return False
+    return blocks(t, s, h // hkv, dk, dv, query.dtype) is not None
+
+
 def _causal_mask(s, iq, jk, block_q, block_k, offset):
-    """Bottom-right-aligned causal mask for one (block_q, block_k) tile:
-    query row r sees key cols <= r + (S - T)."""
+    """Bottom-right-aligned causal mask for one tile of scores, ``s``
+    (groups x block_q, block_k): the query at row r of ANY group sees key
+    cols <= r + (S - T)."""
+    group = s.shape[0] // block_q
     rows = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    cols = jk * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+        jnp.int32, (group, block_q, block_k), 1).reshape(s.shape)
+    cols = jk * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     return jnp.where(cols <= rows + offset, s, _NEG_INF)
 
 
+def _last_key_block(iq, block_q, block_k, offset):
+    """The last key block the queries of block ``iq`` see: the one that holds
+    the last row's diagonal column."""
+    return ((iq + 1) * block_q - 1 + offset) // block_k
+
+
+def _first_query_block(jk, block_q, block_k, offset):
+    """The first query block that sees any key of block ``jk``."""
+    return jnp.maximum(jk * block_k - offset, 0) // block_q
+
+
 # --------------------------------------------------------------------- forward
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale, causal, block_q, block_k, nk, offset):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal, block_q,
+                block_k, nk, offset, with_lse):
     from jax.experimental import pallas as pl
 
+    lse_ref = rest[0] if with_lse else None
+    m_scr, l_scr, acc_scr = rest[-3:]
     iq, jk = pl.program_id(1), pl.program_id(2)
+    rows = acc_scr.shape[0]                           # groups x block_q
 
     @pl.when(jk == 0)
     def _init():
@@ -90,33 +233,66 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32) * scale          # (block_q, D)
-    k = k_ref[0].astype(jnp.float32)                  # (block_k, D)
-    v = v_ref[0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    if causal:
-        s = _causal_mask(s, iq, jk, block_q, block_k, offset)
+    def step(masked):
+        q = q_ref[0].reshape(rows, q_ref.shape[-1])   # the group into rows
+        k, v = k_ref[0], v_ref[0]                     # (block_k, dk | dv)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if masked:
+            s = _causal_mask(s, iq, jk, block_q, block_k, offset)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        m_scr[...] = m_new
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    m_prev, l_prev = m_scr[...], l_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    m_scr[...] = m_new
-    l_scr[...] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    if causal:
+        # a block wholly at or below the diagonal of its FIRST row needs no
+        # mask; one the diagonal crosses is masked; one above it does not run
+        below = (jk + 1) * block_k - 1 <= iq * block_q + offset
+        crossed = jnp.logical_and(
+            jnp.logical_not(below),
+            jk <= _last_key_block(iq, block_q, block_k, offset))
+        pl.when(below)(lambda: step(False))
+        pl.when(crossed)(lambda: step(True))
+    else:
+        step(False)
 
     @pl.when(jk == nk - 1)
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = (m_scr[...] + jnp.log(l)).astype(lse_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l).reshape(o_ref.shape[1:]).astype(
+            o_ref.dtype)
+        if with_lse:
+            lse_ref[0] = (m_scr[...] + jnp.log(l)).reshape(lse_ref.shape[1:])
 
 
 # ------------------------------------------------------------------- backward
+def _recompute(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, iq, jk, *,
+               scale, causal, block_q, block_k, offset):
+    """What both backward kernels re-derive of one (block_q, block_k) tile from
+    the saved logsumexp, float32: ``(q * scale, k, do, p, ds)``, the
+    probabilities and the scores' gradient."""
+    q = q_ref[0].astype(jnp.float32) * scale
+    k = k_ref[0].astype(jnp.float32)
+    v = v_ref[0].astype(jnp.float32)
+    do = do_ref[0].astype(jnp.float32)                # (block_q, dv)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if causal:
+        s = _causal_mask(s, iq, jk, block_q, block_k, offset)
+    p = jnp.exp(s - lse_ref[0])                       # lse, delta (block_q, 1)
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return q, k, do, p, p * (dp - delta_ref[0])
+
+
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, scale, causal, block_q, block_k, nk, offset):
+               dq_scr, *, nk, **tile):
     from jax.experimental import pallas as pl
 
     iq, jk = pl.program_id(1), pl.program_id(2)
@@ -125,32 +301,26 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    q = q_ref[0].astype(jnp.float32) * scale
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)                # (block_q, D)
-    lse = lse_ref[0].astype(jnp.float32)              # (block_q, 1)
-    delta = delta_ref[0].astype(jnp.float32)          # (block_q, 1)
+    def step():
+        _, k, _, _, ds = _recompute(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                    delta_ref, iq, jk, **tile)
+        dq_scr[...] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    if causal:
-        s = _causal_mask(s, iq, jk, block_q, block_k, offset)
-    p = jnp.exp(s - lse)                              # recomputed probs
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta)
-    dq_scr[...] += jax.lax.dot_general(
-        ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    if tile["causal"]:  # a block above the diagonal adds exact zeros: skipped
+        pl.when(jk <= _last_key_block(iq, tile["block_q"], tile["block_k"],
+                                      tile["offset"]))(step)
+    else:
+        step()
 
     @pl.when(jk == nk - 1)
     def _finish():
-        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[...] * tile["scale"]).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr,
-                *, scale, causal, block_q, block_k, nq, offset):
+                dk_ref, dv_ref, dk_scr, dv_scr, *, nq, **tile):
     from jax.experimental import pallas as pl
 
     jk, iq = pl.program_id(1), pl.program_id(2)      # q streams innermost
@@ -160,25 +330,21 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    q = q_ref[0].astype(jnp.float32) * scale
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0].astype(jnp.float32)
-    delta = delta_ref[0].astype(jnp.float32)
+    def step():
+        q, _, do, p, ds = _recompute(q_ref, k_ref, v_ref, do_ref, lse_ref,
+                                     delta_ref, iq, jk, **tile)
+        dv_scr[...] += jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_scr[...] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    if causal:
-        s = _causal_mask(s, iq, jk, block_q, block_k, offset)
-    p = jnp.exp(s - lse)                              # (block_q, block_k)
-    dv_scr[...] += jax.lax.dot_general(
-        p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta)                             # (block_q, block_k)
-    dk_scr[...] += jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    if tile["causal"]:  # the queries before a key block's diagonal never see it
+        pl.when(iq >= _first_query_block(jk, tile["block_q"], tile["block_k"],
+                                         tile["offset"]))(step)
+    else:
+        step()
 
     @pl.when(iq == nq - 1)
     def _finish():
@@ -188,58 +354,87 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 # ---------------------------------------------------------------- pallas glue
-def _compiler_params(n_parallel):
+def _compiler_params(n_parallel, vmem_bytes=0):
     from jax.experimental.pallas import tpu as pltpu
 
     sem = (pltpu.GridDimensionSemantics.PARALLEL,) * n_parallel + (
         pltpu.GridDimensionSemantics.ARBITRARY,)
-    return pltpu.CompilerParams(dimension_semantics=sem)
+    # the compiler's own limit is 16 MiB; a step that needs more asks by name
+    limit = {"vmem_limit_bytes": vmem_bytes + (8 << 20)} \
+        if vmem_bytes > (12 << 20) else {}
+    return pltpu.CompilerParams(dimension_semantics=sem, **limit)
 
 
-def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret):
+def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
+              with_lse=True):
+    """The forward kernel over ``q`` (BHkv, G, T, dk), ``k`` (BHkv, S, dk) and
+    ``v`` (BHkv, S, dv): ``(out (BHkv, G, T, dv), lse (BHkv, G, T, 1) | None)``.
+    The logsumexp is the backward's; a call nobody differentiates leaves it
+    out."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    BH, T, D = q.shape
-    S = k.shape[1]
+    BH, G, T, D = q.shape
+    S, Dv = k.shape[1], v.shape[2]
     nq, nk = T // block_q, S // block_k
     offset = S - T
     kern = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, nk=nk, offset=offset)
-    o, lse = pl.pallas_call(
+        block_k=block_k, nk=nk, offset=offset, with_lse=with_lse)
+    if causal:
+        # past the diagonal the index stays on the last block the queries
+        # need: the pipeline fetches nothing for a step that does not run
+        kv_block = lambda bh, iq, jk: (bh, jnp.minimum(
+            jk, _last_key_block(iq, block_q, block_k, offset)), 0)
+        live = T * (T + 1) // 2 + T * offset
+    else:
+        kv_block = lambda bh, iq, jk: (bh, jk, 0)
+        live = T * S
+    size = q.dtype.itemsize
+    out_specs = [pl.BlockSpec((1, G, block_q, Dv),
+                              lambda bh, iq, jk: (bh, 0, iq, 0))]
+    out_shape = [jax.ShapeDtypeStruct((BH, G, T, Dv), q.dtype)]
+    if with_lse:
+        out_specs.append(pl.BlockSpec((1, G, block_q, 1),
+                                      lambda bh, iq, jk: (bh, 0, iq, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((BH, G, T, 1), jnp.float32))
+    out = pl.pallas_call(
         kern,
         grid=(BH, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, iq, jk: (bh, iq, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, iq, jk: (bh, jk, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, iq, jk: (bh, jk, 0)),
+            pl.BlockSpec((1, G, block_q, D),
+                         lambda bh, iq, jk: (bh, 0, iq, 0)),
+            pl.BlockSpec((1, block_k, D), kv_block),
+            pl.BlockSpec((1, block_k, Dv), kv_block),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, iq, jk: (bh, iq, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, iq, jk: (bh, iq, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, T, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, T, 1), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((G * block_q, 1), jnp.float32),
+            pltpu.VMEM((G * block_q, 1), jnp.float32),
+            pltpu.VMEM((G * block_q, Dv), jnp.float32),
         ],
-        compiler_params=None if interpret else _compiler_params(2),
+        compiler_params=None if interpret else _compiler_params(
+            2, block_bytes(block_q, block_k, G, D, Dv, q.dtype)),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * BH * G * live * (D + Dv),
+            transcendentals=BH * G * live,
+            bytes_accessed=size * BH * (G * T * (D + Dv) + S * (D + Dv))),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
-    return o, lse
+    return out[0], (out[1] if with_lse else None)
 
 
 def _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret):
+    """The two backward kernels over one key/value head a query head: ``q``
+    (BH, T, dk), ``k`` (BH, S, dk), ``v`` (BH, S, dv), ``o`` and ``do``
+    (BH, T, dv), ``lse`` (BH, T, 1)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     BH, T, D = q.shape
-    S = k.shape[1]
+    S, Dv = k.shape[1], v.shape[2]
     nq, nk = T // block_q, S // block_k
     offset = S - T
     # delta_i = sum_d dO_i O_i — cheap elementwise, fused by XLA
@@ -254,8 +449,8 @@ def _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret):
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, iq, jk: (bh, iq, 0)),
             pl.BlockSpec((1, block_k, D), lambda bh, iq, jk: (bh, jk, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, iq, jk: (bh, jk, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, iq, jk: (bh, iq, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda bh, iq, jk: (bh, jk, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda bh, iq, jk: (bh, iq, 0)),
             pl.BlockSpec((1, block_q, 1), lambda bh, iq, jk: (bh, iq, 0)),
             pl.BlockSpec((1, block_q, 1), lambda bh, iq, jk: (bh, iq, 0)),
         ],
@@ -274,22 +469,22 @@ def _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret):
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, jk, iq: (bh, iq, 0)),
             pl.BlockSpec((1, block_k, D), lambda bh, jk, iq: (bh, jk, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, jk, iq: (bh, jk, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, jk, iq: (bh, iq, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda bh, jk, iq: (bh, jk, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda bh, jk, iq: (bh, iq, 0)),
             pl.BlockSpec((1, block_q, 1), lambda bh, jk, iq: (bh, iq, 0)),
             pl.BlockSpec((1, block_q, 1), lambda bh, jk, iq: (bh, iq, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda bh, jk, iq: (bh, jk, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, jk, iq: (bh, jk, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda bh, jk, iq: (bh, jk, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, S, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, S, D), v.dtype),
+            jax.ShapeDtypeStruct((BH, S, Dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         compiler_params=None if interpret else _compiler_params(2),
         interpret=interpret,
@@ -300,8 +495,8 @@ def _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret):
 # ------------------------------------------------------------------ custom vjp
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
-    o, _ = _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret)
-    return o
+    return _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
+                     with_lse=False)[0]
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
@@ -310,9 +505,26 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
 
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, res, do):
+    """The backward kernels know one key/value head a query head: a group's
+    key and value are repeated for them and its gradients summed (float32).
+    The forward's query block is a group's share of a step's rows; the
+    backward's blocks are the ungrouped rule's, or the forward's where the
+    caller named them."""
     q, k, v, o, lse = res
-    return _bwd_call(q, k, v, o, lse, do, causal, scale, block_q, block_k,
-                     interpret)
+    BH, G, T, D = q.shape
+    S, Dv = k.shape[1], v.shape[2]
+    if G > 1:
+        block_q, block_k = blocks(T, S, 1, D, Dv, q.dtype) or (block_q,
+                                                               block_k)
+    heads = lambda a: a.reshape((BH * G,) + a.shape[2:])
+    rep = lambda a: jnp.repeat(a, G, axis=0) if G > 1 else a
+    dq, dk, dv = _bwd_call(heads(q), rep(k), rep(v), heads(o), heads(lse),
+                           heads(do), causal, scale, block_q, block_k,
+                           interpret)
+    if G > 1:
+        dk, dv = (a.astype(jnp.float32).reshape((BH, G) + a.shape[1:]).sum(1)
+                  .astype(a.dtype) for a in (dk, dv))
+    return dq.reshape(q.shape), dk, dv
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -320,12 +532,15 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
                                              "block_k", "interpret"))
-def flash_attention(q, k, v, causal=False, scale=0.0, block_q=128,
-                    block_k=128, interpret=False):
-    """softmax(QKᵀ·scale)V over (B, H, T, D), streamed through VMEM.
-    Differentiable (custom_vjp flash backward)."""
+def flash_attention(q, k, v, causal=False, scale=0.0, block_q=None,
+                    block_k=None, interpret=False):
+    """softmax(QKᵀ·scale)V of ``q`` (B, H, T, dk) over ``k`` (B, Hkv, S, dk)
+    and ``v`` (B, Hkv, S, dv), Hkv dividing H, streamed through VMEM: (B, H,
+    T, dv). Differentiable (custom_vjp backward kernels). ``block_q`` /
+    ``block_k`` default to the rule's (``blocks``); a named block is clamped
+    to the shape."""
     B, H, T, D = q.shape
-    S = k.shape[2]
+    Hkv, S, Dv = k.shape[1], k.shape[2], v.shape[3]
     if causal and S < T:
         raise ValueError(
             "flash_attention(causal=True) requires S >= T (got T=%d, S=%d): "
@@ -333,9 +548,18 @@ def flash_attention(q, k, v, causal=False, scale=0.0, block_q=128,
             "XLA attention path for these shapes" % (T, S))
     if scale <= 0:
         scale = 1.0 / np.sqrt(D)
+    G = H // Hkv
+    if block_q is None or block_k is None:
+        ruled = blocks(T, S, G, D, Dv, q.dtype)
+        if ruled is None:
+            raise ValueError(
+                "flash_attention: no block tiles %d queries over %d keys "
+                "(%s); use the XLA attention path for these shapes"
+                % (T, S, q.dtype))
+        block_q, block_k = (block_q or ruled[0]), (block_k or ruled[1])
     block_q = min(block_q, T)
     block_k = min(block_k, S)
-    out = _flash(q.reshape(B * H, T, D), k.reshape(B * H, S, D),
-                 v.reshape(B * H, S, D), causal, float(scale),
+    out = _flash(q.reshape(B * Hkv, G, T, D), k.reshape(B * Hkv, S, D),
+                 v.reshape(B * Hkv, S, Dv), causal, float(scale),
                  block_q, block_k, interpret)
-    return out.reshape(B, H, T, D)
+    return out.reshape(B, H, T, Dv)

@@ -325,6 +325,23 @@ def _moe_forms(cache, input_shapes):
     return forms, depth
 
 
+def _attention_forms(cache, input_shapes):
+    """The form of every ``MultiHeadAttention`` node of a program's graph at
+    these input shapes, in the graph's order, by the operator's own rule
+    (``ops.attention.attention_form``; no program here traces under a
+    mesh)."""
+    from ..ops.attention import attention_form
+
+    forms = []
+    for n, ops in _operands_of(cache, input_shapes,
+                               "_contrib_MultiHeadAttention"):
+        attrs = n.parsed_attrs()
+        forms.append(attention_form(
+            ops["query"], ops["key"], ops["value"], attrs["causal"],
+            attrs.get("window", 0), bool(attrs.get("sink"))))
+    return forms
+
+
 def _swap_cache(exe, names):
     """Hand the updated cache buffers (program outputs, in the cache's order
     after the logits) back as the next dispatch's inputs — device-side
@@ -1241,6 +1258,12 @@ class PagedKVDecoder:
                 _tm.gauge("serving.moe.xla_layers." + program).set(
                     forms.count("ragged_dot"))
                 _tm.gauge("serving.moe.fetch_depth." + program).set(depth)
+            if "prefill" in programs:
+                # the prefill's attention layers by the form the rule names
+                forms = _attention_forms(*programs["prefill"])
+                for form in ("kernel", "dense", "band"):
+                    _tm.gauge("serving.prefill_attention.%s_layers"
+                              % form).set(forms.count(form))
             _tm.gauge("serving.state_bytes").set(sum(
                 4 * self.lanes * int(np.prod(shape))
                 for _, kind, shape in self._cache if kind == "row"))

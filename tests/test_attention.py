@@ -134,14 +134,167 @@ def test_pallas_flash_attention_matches_oracle(causal):
                                rtol=1e-4, atol=1e-5)
 
 
-def test_pallas_flash_attention_env_gate(monkeypatch):
+def _mha(q, k, v, **attrs):
+    from mxnet_tpu.ops.registry import get_op
+
+    return get_op("_contrib_MultiHeadAttention").fn(
+        {"causal": True, "scale": -1.0, "window": 0, **attrs}, q, k, v)
+
+
+def _operands(h, hkv, t, s, dk, dv, dtype, seed=3):
+    import jax.numpy as jnp
+
+    rs = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rs.randn(*shape).astype("float32"), dtype)
+                 for shape in ((1, h, t, dk), (1, hkv, s, dk),
+                               (1, hkv, s, dv)))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("group", [1, 4, 16])
+@pytest.mark.parametrize("widths", [(64, 64), (128, 128), (192, 128)])
+@pytest.mark.parametrize("t,s", [(64, 64), (32, 64)])
+def test_the_kernel_interpreted_is_the_dense_path(dtype, group, widths, t, s):
+    """The blockwise kernel against ``MultiHeadAttention``'s dense path over
+    one grid: both types, the groups and head widths of the cells (a key of
+    192 over a value of 128 among them), a bucket and S > T (bottom-right
+    aligned), in blocks that make the grid 2 x 4 or more with the diagonal
+    crossing some and passing over others. The same types in the same
+    places: float32 to a float32 sum's order, bfloat16 to one rounding of
+    the output."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    q, k, v = _operands(2 * group, 2, t, s, *widths, dtype)
+    got = pa.flash_attention(q, k, v, causal=True, block_q=16, block_k=16,
+                             interpret=True)
+    want = _mha(q, k, v)
+    assert got.shape == want.shape == (1, 2 * group, t, widths[1])
+    assert got.dtype == want.dtype == jnp.dtype(dtype)
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(np.asarray(got, "float32"),
+                               np.asarray(want, "float32"), rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("group", [1, 4])
+def test_a_block_above_the_diagonal_is_never_read(group):
+    """Keys and values past the first block of queries' diagonal are NaN: a
+    masked product would carry them into every row (0 x NaN), a skipped block
+    leaves the first block's rows what the clean operands give, bit for
+    bit. The later rows see the poison, as they must."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    q, k, v = _operands(2 * group, 2, 64, 64, 128, 128, "float32")
+    run = lambda k, v: np.asarray(pa.flash_attention(
+        q, k, v, causal=True, block_q=16, block_k=16, interpret=True))
+    poison = lambda a: a.at[:, :, 16:].set(jnp.nan)
+    clean, dirty = run(k, v), run(poison(k), poison(v))
+    np.testing.assert_array_equal(dirty[:, :, :16], clean[:, :, :16])
+    assert np.isnan(dirty[:, :, 16:]).all()
+
+
+# what the rule answers, by shape: (H, Hkv, T, S, dk, dv, dtype) and the
+# attributes -> the form on the chip (off the chip every one is dense or a
+# band)
+_RULE_CASES = {
+    "a bucket, equal heads": ((16, 16, 2048, 2048, 128, 128, "bfloat16"),
+                              {}, "kernel"),
+    "grouped heads": ((32, 2, 2048, 2048, 128, 128, "bfloat16"), {},
+                      "kernel"),
+    "a value narrower than the key": (
+        (32, 32, 1024, 1024, 192, 128, "bfloat16"), {}, "kernel"),
+    "float32 operands": ((16, 16, 2048, 2048, 64, 64, "float32"), {},
+                         "kernel"),
+    "more keys than queries": ((16, 16, 1024, 2048, 64, 64, "bfloat16"), {},
+                               "kernel"),
+    "fewer keys than queries": ((16, 16, 2048, 1024, 64, 64, "bfloat16"), {},
+                                "dense"),
+    "T no multiple of a block": ((16, 16, 2000, 2000, 64, 64, "bfloat16"),
+                                 {}, "dense"),
+    "scores the chip keeps in its vector memory": (
+        (8, 8, 1024, 1024, 64, 64, "bfloat16"), {}, "dense"),
+    "a toy model": ((2, 2, 16, 16, 8, 8, "float32"), {}, "dense"),
+    "bidirectional": ((16, 16, 2048, 2048, 64, 64, "bfloat16"),
+                      {"causal": False}, "dense"),
+    "a sink": ((16, 16, 2048, 2048, 64, 64, "bfloat16"), {"sink": True},
+               "dense"),
+    "a window that tiles": ((16, 16, 2048, 2048, 64, 64, "bfloat16"),
+                            {"window": 128}, "band"),
+    "a ragged window": ((16, 16, 2048, 2048, 64, 64, "bfloat16"),
+                        {"window": 100}, "dense"),
+    "mixed types": ((16, 16, 2048, 2048, 64, 64, ("bfloat16", "float32")),
+                    {}, "dense"),
+}
+
+
+@pytest.mark.parametrize("case", list(_RULE_CASES))
+def test_the_rule_names_the_form_from_shapes_attributes_and_backend(
+        case, monkeypatch):
+    """``attention_form`` (plain Python over shapes: nothing is traced): off
+    the chip no case is the kernel; held to the chip, each is what the table
+    says. No environment variable is read: ``MXNET_USE_PALLAS_ATTENTION`` set
+    moves nothing."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import attention as attn_op
+
+    (h, hkv, t, s, dk, dv, dtype), attrs, on_chip = _RULE_CASES[case]
+    qt, kt = dtype if isinstance(dtype, tuple) else (dtype, dtype)
+    struct = lambda dt, *shape: jax.ShapeDtypeStruct(shape, jnp.dtype(dt))
+    ops = (struct(qt, 1, h, t, dk), struct(kt, 1, hkv, s, dk),
+           struct(kt, 1, hkv, s, dv))
+    args = (attrs.get("causal", True), attrs.get("window", 0),
+            attrs.get("sink", False))
     monkeypatch.setenv("MXNET_USE_PALLAS_ATTENTION", "1")
-    rs = np.random.RandomState(3)
-    q, k, v = (rs.randn(1, 2, 16, 8).astype("float32") for _ in range(3))
-    out = mx.nd.MultiHeadAttention(mx.nd.array(q), mx.nd.array(k),
-                                   mx.nd.array(v), causal=True).asnumpy()
-    np.testing.assert_allclose(out, _ref_attention(q, k, v, True),
-                               rtol=1e-4, atol=1e-5)
+    off_chip = on_chip if on_chip == "band" else "dense"
+    assert attn_op.attention_form(*ops, *args) == off_chip
+    monkeypatch.setattr(attn_op, "_backend", lambda: "tpu")
+    assert attn_op.attention_form(*ops, *args) == on_chip
+
+
+def test_the_rule_keeps_a_step_over_several_devices_dense(monkeypatch):
+    """Under a mesh of several devices the compiler partitions the dense path
+    and could only replicate a kernel: the rule names the kernel for one
+    device (no mesh, or a mesh of one) and never for more; a ``seq`` axis is
+    the ring's."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import attention as attn_op
+
+    monkeypatch.setattr(attn_op, "_backend", lambda: "tpu")
+    struct = lambda b: jax.ShapeDtypeStruct((b, 16, 2048, 128), jnp.bfloat16)
+    form = lambda b, mesh: attn_op.attention_form(
+        struct(b), struct(b), struct(b), True, 0, False, mesh)
+    one = parallel.make_mesh({"data": 1}, devices=jax.devices()[:1])
+    data = parallel.make_mesh({"data": 4}, devices=jax.devices()[:4])
+    seq = parallel.make_mesh({"data": 2, "seq": 2}, devices=jax.devices()[:4])
+    assert form(1, None) == form(1, one) == form(4, None) == "kernel"
+    assert form(4, data) == "dense"
+    assert form(4, seq) == "ring"
+
+
+def test_the_op_runs_the_form_the_rule_names_and_counts_it(monkeypatch):
+    """``MultiHeadAttention`` off the chip is the dense path and counts it;
+    with the rule held to the kernel the same call runs it interpreted,
+    grouped heads and a narrower value through the operator, and counts
+    that."""
+    from mxnet_tpu.ops import attention as attn_op
+
+    q, k, v = _operands(8, 2, 32, 32, 16, 8, "float32")
+    before = dict(attn_op.DISPATCH_COUNTS)
+    dense = _mha(q, k, v)
+    assert attn_op.DISPATCH_COUNTS["dense"] == before["dense"] + 1
+    _mha(q, q, q, window=8)
+    assert attn_op.DISPATCH_COUNTS["band"] == before["band"] + 1
+    monkeypatch.setattr(attn_op, "attention_form", lambda *a: "kernel")
+    kernel = _mha(q, k, v)
+    assert attn_op.DISPATCH_COUNTS["kernel"] == before["kernel"] + 1
+    assert attn_op.DISPATCH_COUNTS["dense"] == before["dense"] + 1
+    np.testing.assert_allclose(np.asarray(kernel), np.asarray(dense),
+                               rtol=2e-5, atol=2e-5)
 
 
 # ------------------------------------------------------- flash attention grads
@@ -228,34 +381,23 @@ def test_pallas_flash_attention_grad_bf16_long_seq():
             atol=2e-2, err_msg="d%s bf16 mismatch" % name)
 
 
-def test_pallas_training_through_module_op():
-    """Training through the op (MXNET_USE_PALLAS_ATTENTION=1) must not
-    crash and must produce finite grads — the round-2 failure mode."""
+def test_pallas_training_through_module_op(monkeypatch):
+    """Training through the op where the rule names the kernel must not
+    crash and must produce the dense path's grads — the round-2 failure
+    mode. Grouped heads: the backward repeats the keys and sums the group."""
     import jax
     import jax.numpy as jnp
-    import os
 
-    from mxnet_tpu.ops import pallas_attention as pa
+    from mxnet_tpu.ops import attention as attn_op
 
-    old = os.environ.get("MXNET_USE_PALLAS_ATTENTION")
-    os.environ["MXNET_USE_PALLAS_ATTENTION"] = "1"
-    try:
-        rs = np.random.RandomState(7)
-        q, k, v = (jnp.asarray(rs.randn(1, 2, 16, 8).astype("float32"))
-                   for _ in range(3))
-        from mxnet_tpu.ops.registry import get_op
-        op = get_op("_contrib_MultiHeadAttention")
-
-        def loss(q, k, v):
-            return op.fn({"causal": True, "scale": -1.0}, q, k, v).sum()
-
-        grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        assert all(np.isfinite(np.asarray(g)).all() for g in grads)
-    finally:
-        if old is None:
-            os.environ.pop("MXNET_USE_PALLAS_ATTENTION", None)
-        else:
-            os.environ["MXNET_USE_PALLAS_ATTENTION"] = old
+    q, k, v = _operands(4, 2, 16, 16, 8, 8, "float32", seed=7)
+    loss = lambda q, k, v: jnp.square(_mha(q, k, v)).sum()
+    want = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    monkeypatch.setattr(attn_op, "attention_form", lambda *a: "kernel")
+    grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    for got, ref in zip(grads, want):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-4)
 
 
 def test_pallas_supported_rejects_causal_decode_underflow():

@@ -65,11 +65,19 @@ def _with_grads(fn, n_args):
     return fwd_bwd
 
 
-def test_flash_attention_fwd_bwd(v5e):
-    qkv = ((8, 8, 512, 64), "bfloat16")
+@pytest.mark.parametrize("heads,kv_heads,t,dk,dv", [
+    (8, 8, 512, 64, 64),          # chip_smoke.py's shape, a batch of 8
+    (32, 2, 2048, 128, 128),      # grouped heads folded into the rows
+    (32, 32, 1024, 192, 128),     # a value narrower than the key
+])
+def test_flash_attention_fwd_bwd(v5e, heads, kv_heads, t, dk, dv):
+    """The blockwise kernel at the rule's blocks, forward and both backward
+    kernels, through Mosaic for the v5e."""
+    spec = lambda h, d: ((8 if heads == 8 else 1, h, t, d), "bfloat16")
     fn = lambda q, k, v: pa.flash_attention(q, k, v, causal=True,
                                             interpret=False)
-    _compile(v5e, _with_grads(fn, 3), qkv, qkv, qkv)
+    _compile(v5e, _with_grads(fn, 3), spec(heads, dk), spec(kv_heads, dk),
+             spec(kv_heads, dv))
 
 
 def test_matmul_bias_act_fwd_bwd(v5e):
@@ -165,6 +173,22 @@ def _paged_read_calls(hlo):
             and "/paged_read/" in line]
 
 
+def _assert_attention_is_blockwise(hlo, layers, bucket):
+    """A prefill's ``layers`` plain causal attention layers each run as ONE
+    call of the blockwise kernel (``ops/pallas_attention.py``), and nothing
+    in the program is a layer's scores: no float32 buffer of heads x
+    ``bucket`` x ``bucket`` (``f32[..., 16, 2048, 2048]``, whatever
+    dimensions of 1 stand among them)."""
+    calls = [line for line in hlo.splitlines() if " custom-call(" in line
+             and 'custom_call_target="tpu_custom_call"' in line
+             and "/flash_attention" in line]
+    assert len(calls) == layers, (len(calls), layers)
+    scores = [dims for dims in re.findall(r"f32\[([\d,]+)\]", hlo)
+              if [int(d) for d in dims.split(",") if int(d) != 1][1:]
+              [-2:] == [bucket, bucket]]
+    assert not scores, scores[:4]
+
+
 def _assert_expert_layers(hlo, layers, tokens, k, experts, d, f,
                           routed=None):
     """The program's ``layers`` expert layers of ``tokens`` rows, ``k`` experts
@@ -248,6 +272,49 @@ def test_the_expert_rules_at_the_cells_shapes(cell, program):
                            struct(held, f, d)) == form
     assert kernel.tiles(tokens * k * held // routed, held, d, f,
                         jnp.bfloat16) == tiles
+
+
+# cell -> a full-attention layer of its prefill (query heads, key/value
+# heads, the bucket, key width, value width) and what the rules answer there:
+# the form, and the kernel's blocks (block_q a group's share of a step's
+# rows, block_k)
+_ATTENTION_LAYERS = {
+    "olmoe-1b-7b.score": ((16, 16, 2048, 128, 128), "kernel", (1024, 1024)),
+    "nemotron-3-nano-30b-a3b.generate": ((32, 2, 2048, 128, 128), "kernel",
+                                         (64, 1024)),
+    "mimo-v2-flash.generate": ((64, 4, 2048, 192, 128), "kernel",
+                               (64, 1024)),
+    "kanana-2-30b-a3b.generate": ((32, 32, 1024, 192, 128), "kernel",
+                                  (1024, 1024)),
+    "lfm2-24b-a2b.generate": ((32, 8, 1024, 64, 64), "kernel", (256, 1024)),
+    # 32 MiB of scores: the chip keeps them in its vector memory
+    "granite-4.0-h-micro.generate": ((32, 8, 512, 64, 64), "dense", None),
+    "transformer-base.generate": ((8, 8, 1024, 64, 64), "dense", None),
+    "transformer-base.score": ((8, 8, 1024, 64, 64), "dense", None),
+}
+
+
+@pytest.mark.parametrize("cell", list(_ATTENTION_LAYERS))
+def test_the_attention_rules_at_the_cells_shapes(cell):
+    """What ``attention_form`` and ``blocks`` answer at every cell's
+    admission (the widths as published, the benchmark's buckets, bfloat16
+    but for ``transformer-base``'s float32), pinned: a change of either rule
+    changes the prefill programs of the benchmark, and says so here first.
+    (``phi-4-mini-flash-reasoning.generate`` has no such layer: windows, and
+    a one-row read.) Plain Python: no chip, no compile."""
+    from mxnet_tpu.ops import attention
+
+    (h, hkv, t, dk, dv), form, blocks = _ATTENTION_LAYERS[cell]
+    dtype = jnp.float32 if cell.startswith("transformer-base") \
+        else jnp.bfloat16
+    struct = lambda heads, d: jax.ShapeDtypeStruct((1, heads, t, d), dtype)
+    ops = struct(h, dk), struct(hkv, dk), struct(hkv, dv)
+    assert attention.attention_form(*ops, True) == form
+    assert attention.attention_form(*ops, True, 128) == "band"
+    assert attention.attention_form(*ops, True, 0, True) == "dense"
+    assert attention.attention_form(*ops, False) == "dense"
+    if blocks:
+        assert pa.blocks(t, t, h // hkv, dk, dv, dtype) == blocks
 
 
 def _assert_no_pool_sized_copy(hlo, pool):
@@ -448,6 +515,7 @@ def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
         # would be 2.4 TFLOP
         assert 0.29e12 < flops < 0.35e12
         _assert_one_row_of_logits(compiled, max_len, 50304)
+        _assert_attention_is_blockwise(compiled.as_text(), 1, max_len)
         assert [str(s.dtype) for s in compiled.out_info[0]] \
             == ["float32", "bfloat16", "bfloat16", "float32"]
     else:
@@ -1202,6 +1270,7 @@ def test_nemotron_h_serving_programs_compile_for_the_chip(v5e, program):
         # attention beside them; 128 dense experts a token would be 27
         assert 2.5e12 < compiled.cost_analysis()["flops"] < 3.4e12
         assert mem.temp_size_in_bytes < 1 << 30
+        _assert_attention_is_blockwise(hlo, 2, bucket)
         return
     assert compiled.out_info[0][1].shape == (lanes, 64, 64, 128)
     assert compiled.out_info[0][7].shape == (slots // page, page, 256)

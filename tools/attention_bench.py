@@ -1,11 +1,13 @@
-"""A/B the Pallas flash-attention kernel against XLA's fused attention at
-transformer-base shapes (VERDICT r4 #7: "measure or flip the Pallas
-attention default").
+"""A/B the blockwise Pallas attention kernel against the dense path at
+transformer-base TRAINING shapes (batches of sequences, forward and
+backward), which no serving cell runs.
 
-Times the MultiHeadAttention op's two lowerings — fwd-only and fwd+bwd —
-at (B, H, T, D) transformer-base shapes, seq 512/1024, bf16, amortized
-inside one jitted scan with host-fetch sync (docs/PERF.md §0). The table
-lands in PERF.md §7 and grounds the MXNET_USE_PALLAS_ATTENTION default.
+Times the MultiHeadAttention op's two forms — fwd-only and fwd+bwd — at
+(B, H, T, D) transformer-base shapes, seq 512/1024/2048, bf16, amortized
+inside one jitted scan with host-fetch sync (docs/PERF.md §0), and prints
+beside each what ``ops.attention.attention_form`` names there on this
+backend: the table is what that rule's threshold (``pallas_attention.takes``)
+is held against (PERF.md §6, PR 49).
 
     python tools/attention_bench.py
 """
@@ -41,8 +43,14 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
+    from mxnet_tpu.ops import attention as attn_op
     from mxnet_tpu.ops import pallas_attention as pa
     from mxnet_tpu.ops.attention import _multi_head_attention
+
+    # the dense arm: the operator with its rule held to the dense path
+    attn_op.attention_form = lambda *a: "dense"
+    rule = lambda q, k, v, causal: (
+        "kernel" if causal and on_tpu and pa.takes(q, k, v) else "dense")
 
     dt = jnp.dtype(args.dtype)
     dev = jax.devices()[0]
@@ -76,14 +84,13 @@ def main():
         q, k, v = (jnp.asarray(rs.randn(B, H, T, D) * 0.3, dt)
                    for _ in range(3))
         attrs = {"causal": causal, "scale": -1.0}
-        rec = {"B": B, "H": H, "T": T, "D": D, "causal": causal}
+        rec = {"B": B, "H": H, "T": T, "D": D, "causal": causal,
+               "rule": rule(q, k, v, causal)}
         if not pa.supported(q.shape, k.shape, causal=causal):
             rec["skipped"] = "pallas unsupported"
             rows.append(rec)
             print(json.dumps(rec))
             continue
-
-        os.environ["MXNET_USE_PALLAS_ATTENTION"] = "0"  # op -> dense path
 
         def xla_fwd(q, k, v):
             return _multi_head_attention(attrs, q, k, v)
@@ -143,7 +150,6 @@ def main():
             "device": dev.device_kind, "dtype": str(dt),
             "shapes_measured": len(measured),
             "pallas_wins_both_directions": wins,
-            "recommend_default": "1" if wins == len(measured) else "0",
         }}))
 
 
