@@ -85,51 +85,102 @@ def decode_megastep_k(default=1):
     return k if k >= 1 else int(default)
 
 
-def _gap_mark(dec, site):
-    """``dispatch.host_gap``: host time from the moment the previous
-    dispatch's result was ready on the device (``_gap_return``) to this
-    dispatch's enqueue — the seam the GL7xx analyzer prices
-    (docs/OBSERVABILITY.md). Recorded per call site and in aggregate.
-    Off-mode cost is one predicate — no span objects, no clock reads."""
-    if not _tm.enabled():
-        return
-    now = time.perf_counter()
-    last = dec._last_return_t
-    if last is not None:
-        dt = now - last
-        _tm.timer("dispatch.host_gap").add(dt)
-        _tm.timer("dispatch.host_gap." + site).add(dt)
+def _no_span(name, **attrs):
+    """``_tm.span`` for a caller that has read the mode already."""
+    return _tm.NULL_SPAN
 
 
-def _gap_return(dec):
-    """Stamp the start of the ``dispatch.host_gap`` interval: where the
-    wait for the device ends (``serving.step.wait`` closes), so the copy to
-    the host, during which the device has nothing to run, is inside it."""
-    if _tm.enabled():
-        dec._last_return_t = time.perf_counter()
+class _DeviceRecord:
+    """What the decoder has sent to the device and not yet seen finish, on
+    the host's ``perf_counter``: the one record every program a
+    ``PagedKVDecoder`` enqueues passes through, kept only while telemetry is
+    on (the callers hold every touch behind one ``_tm.enabled()``).
+
+    Two facts, updated at the two seams the spans already mark. At an
+    ENQUEUE's close (``serving.step.dispatch``, ``serving.admit.prefill``,
+    ``serving.admit.scatter`` close; ``_cow_page``'s copies are queued) the
+    program's kind joins ``in_flight``: ``decode``, ``megastep``, ``chunk``,
+    ``prefill``, ``admit_scatter``, ``cow``. At an OBSERVED-READY
+    (``serving.step.wait``, ``serving.admit.wait`` close) the program waited
+    for and everything enqueued before it leave the list (programs run in the
+    order they were enqueued) and ``ready_t`` is stamped; what was enqueued
+    behind the waited program, an admission's scatter always, stays.
+
+    The next enqueue's close after a ready records one ``serving.device_gap``
+    span from ``ready_t`` to that close (``after``: the kind seen ready,
+    ``before``: the kind just enqueued, ``behind``: the kinds still in flight
+    at ``ready_t``, comma-joined, ``""`` if none): the host's estimate of an
+    interval in which the device had no NEW program, exact where ``behind``
+    is empty and an upper bound where it is not. The ``dispatch.host_gap``
+    timers read the same stamp (``mark``): from ``ready_t`` to where a
+    decode-side dispatch is about to be enqueued, only along a steady chain
+    (``admit`` clears ``steady`` when it is done, so the step after an
+    admission never counts the admission's time)."""
+
+    __slots__ = ("in_flight", "ready_t", "after", "behind", "steady")
+
+    def __init__(self):
+        self.in_flight = []     # kinds, oldest first
+        self.ready_t = None     # the last observed-ready
+        self.after = None       # its kind, until the gap behind it is written
+        self.behind = ""
+        self.steady = False     # a ready, and no admission finished since
+
+    def mark(self, site):
+        """A decode-side dispatch is about to be enqueued at ``site``: the
+        ``dispatch.host_gap`` timers take ready-to-here, the seam the GL7xx
+        analyzer prices (docs/OBSERVABILITY.md)."""
+        if self.steady:
+            dt = time.perf_counter() - self.ready_t
+            _tm.timer("dispatch.host_gap").add(dt)
+            _tm.timer("dispatch.host_gap." + site).add(dt)
+
+    def enqueued(self, kind):
+        """An enqueue of ``kind`` has just closed."""
+        if self.after is not None:
+            _tm.record_span(
+                "serving.device_gap", self.ready_t,
+                time.perf_counter() - self.ready_t, after=self.after,
+                before=kind, behind=self.behind)
+            self.after = None
+        self.in_flight.append(kind)
+
+    def ready(self, kind):
+        """The newest program of ``kind`` in flight has just been seen
+        finished (a program enqueued while the record was off is not in the
+        list: everything before the wait then leaves)."""
+        self.ready_t = time.perf_counter()
+        flying = self.in_flight
+        behind = flying[::-1].index(kind) if kind in flying else 0
+        del flying[:len(flying) - behind]
+        self.after, self.behind, self.steady = kind, ",".join(flying), True
 
 
-def _dispatch_and_pull(dec, site, span, enqueue, **span_args):
+def _dispatch_and_pull(dec, site, kind, span, enqueue, **span_args):
     """One decode-side dispatch and the blocking read of its result, the
-    same with telemetry on and off. ``enqueue()`` enqueues the program and
-    returns ``(pulled, kept)``: the device arrays the host reads now, and
-    what stays on the device for the caller. Returns ``(host arrays,
-    kept)``.
+    same with telemetry on and off. ``enqueue()`` enqueues the program (of
+    ``kind``, as ``_DeviceRecord`` names them) and returns ``(pulled,
+    kept)``: the device arrays the host reads now, and what stays on the
+    device for the caller. Returns ``(host arrays, kept)``.
 
     Inside ``span``: ``serving.step.dispatch`` around the enqueue, then
     ``serving.step.read`` around its two halves, ``serving.step.wait`` (the
     host blocked while the device runs the program) and
     ``serving.step.copy`` (device to host of arrays that are ready: the
     device idle; the copy itself is queued behind the program, so the span
-    holds what is left of it once the host has woken).
-    ``dispatch.host_gap`` runs from the wait's end to the next dispatch's
-    ``_gap_mark``."""
+    holds what is left of it once the host has woken). The decoder's record
+    of the device is told of the enqueue where ``dispatch`` closes and of
+    the result where ``wait`` closes, under one mode check."""
     import jax
 
-    _gap_mark(dec, site)
+    record = dec._device if _tm.enabled() else None
+    if record is not None:
+        record.mark(site)
     with _tm.span(span, **span_args):
         with _tm.span("serving.step.dispatch"):
             pulled, kept = enqueue()
+        if record is not None:
+            record.enqueued(kind)
         with _tm.span("serving.step.read"):
             # queued behind the program: the copy starts the moment the
             # device finishes, not a host wake-up later (0.12 ms a read)
@@ -137,7 +188,8 @@ def _dispatch_and_pull(dec, site, span, enqueue, **span_args):
                 a.copy_to_host_async()
             with _tm.span("serving.step.wait"):
                 jax.block_until_ready(pulled)
-            _gap_return(dec)
+            if record is not None:
+                record.ready(kind)
             with _tm.span("serving.step.copy",
                           bytes=sum(a.nbytes for a in pulled)):
                 host = [np.asarray(a) for a in pulled]
@@ -1157,12 +1209,18 @@ class PagedKVDecoder:
         self._seq_lane: Dict[int, int] = {}  # seq_id -> lane index
         self._next_seq = 0
         self._warm = False
-        self._last_return_t = None  # dispatch.host_gap interval start
+        self._device = _DeviceRecord()  # in flight and last seen ready
         self._megasteps = {}        # (K, sampler) -> _DecodeMegastep
         self._chunks = {}           # T -> _ChunkProgram
         self._admit_scatter = None  # _AdmitScatter, built in warmup
         self._sample_seed = sample_seed
         self._sample_key = None
+
+    @property
+    def _last_return_t(self):
+        """Where the last wait for the device ended: the start of the
+        ``dispatch.host_gap`` and ``serving.device_gap`` intervals."""
+        return self._device.ready_t
 
     def _refuse_arch(self, what):
         """The chunk, verify and megastep programs, and with them the
@@ -1359,6 +1417,7 @@ class PagedKVDecoder:
         self.pool.release([frame])
         lane.frames[page] = fresh
         if _tm.enabled():
+            self._device.enqueued("cow")
             _tm.counter("serving.cow_copies").inc()
         return fresh
 
@@ -1443,7 +1502,7 @@ class PagedKVDecoder:
             self._evict(idx)
             raise
         lane.pos = L
-        self._last_return_t = None  # admit breaks the steady decode chain
+        self._device.steady = False  # admit breaks the steady decode chain
         if _tm.enabled():
             _tm.counter("serving.paged_admits").inc()
             _tm.counter("serving.prefill_tokens").inc(L)
@@ -1459,6 +1518,8 @@ class PagedKVDecoder:
         # a frame per page of the prompt, acquired before any device work
         for p in range(0, L, self.page_size):
             self._phys_slot(lane, p)
+        # the record of the device, told of both enqueues and of the row
+        record = self._device if _tm.enabled() else None
         with _tm.span("serving.paged_admit", seq=lane.seq_id,
                       prompt_len=L, lane=idx):
             with _tm.span("serving.admit.stage"):
@@ -1470,24 +1531,30 @@ class PagedKVDecoder:
                 # prefill alone, with no program between them
                 row = pf.outputs[0]._jax()
                 row.copy_to_host_async()
+            if record is not None:
+                record.enqueued("prefill")
             # the pool update stays on the device, and is enqueued BEFORE
             # the row is waited for: the device runs the prefill meanwhile
             with _tm.span("serving.admit.scatter"):
                 self._admit_scatter.run(self, self._prefill_cache(pf),
                                         lane.frames, L, idx)
+            if record is not None:
+                record.enqueued("admit_scatter")
             with _tm.span("serving.admit.logits"):
                 # the host blocked while the device runs what is left of
                 # the prefill; then one row is read and indexed on the host
                 with _tm.span("serving.admit.wait"):
                     row.block_until_ready()
+                if record is not None:
+                    record.ready("prefill")
                 logits = np.asarray(row)[0]
-        if _tm.enabled():
+        if record is not None:
             _tm.counter("serving.admit_head_rows").inc(self._head_rows)
             if self._shared_readers:
                 # the bucket's rows either half of the depth computed
                 _tm.counter("serving.admit_self_rows").inc(self.prefill_len)
                 _tm.counter("serving.admit_cross_rows").inc(self._head_rows)
-        if self._pf_moe_load is not None and _tm.enabled():
+        if self._pf_moe_load is not None and record is not None:
             # rows each expert received, per layer, over every position the
             # prefill computed (padding included: the grouped matmul's work)
             load = np.asarray(pf.outputs[self._pf_moe_load]._jax())
@@ -1538,8 +1605,8 @@ class PagedKVDecoder:
             return (logits,), new_kvs
 
         (out,), new_kvs = _dispatch_and_pull(
-            self, "serving.chunk_prefill", "serving.chunk_prefill", enqueue,
-            t=T, rows=n, write=bool(write))
+            self, "serving.chunk_prefill", "chunk", "serving.chunk_prefill",
+            enqueue, t=T, rows=n, write=bool(write))
         out = out[:n]
         if write:
             self._dec_exe.rebind(prog.kv_names, new_kvs)
@@ -1767,39 +1834,44 @@ class PagedKVDecoder:
         self.warmup()
         if not tokens:
             return {}
+        # the stage's three parts hang on ONE mode read, not on one each
+        part = _tm.span if _tm.tracing() else _no_span
         with _tm.span("serving.paged_step", rows=len(tokens), paged=True):
             B = self.lanes
             exe = self._dec_exe
             with _tm.span("serving.step.stage"):
-                data = np.zeros((B, 1), np.float32)
-                pos_idx = np.zeros((B, 1), np.float32)
-                write_slot = np.full((B, 1), -1, np.float32)
-                stepped = []
-                for seq_id, tok in tokens.items():
-                    idx = self._seq_lane.get(seq_id)
-                    if idx is None:
-                        raise MXNetError("paged_kv: unknown seq_id %r"
-                                         % (seq_id,))
-                    lane = self._lanes[idx]
-                    if self.pos_len is not None \
-                            and lane.pos >= self.pos_len:
-                        raise MXNetError(
-                            "paged_kv: seq %d at position %d exceeds the "
-                            "trained position table (%d rows)"
-                            % (seq_id, lane.pos, self.pos_len))
-                    # resolves the frame first: a new page, or a private
-                    # copy of a shared one
-                    write_slot[idx, 0] = self._phys_slot(lane, lane.pos)
-                    data[idx, 0] = float(np.asarray(tok).reshape(()))
-                    pos_idx[idx, 0] = lane.pos
-                    stepped.append((seq_id, idx, lane))
+                with part("serving.step.stage.slots"):
+                    data = np.zeros((B, 1), np.float32)
+                    pos_idx = np.zeros((B, 1), np.float32)
+                    write_slot = np.full((B, 1), -1, np.float32)
+                    stepped = []
+                    for seq_id, tok in tokens.items():
+                        idx = self._seq_lane.get(seq_id)
+                        if idx is None:
+                            raise MXNetError("paged_kv: unknown seq_id %r"
+                                             % (seq_id,))
+                        lane = self._lanes[idx]
+                        if self.pos_len is not None \
+                                and lane.pos >= self.pos_len:
+                            raise MXNetError(
+                                "paged_kv: seq %d at position %d exceeds "
+                                "the trained position table (%d rows)"
+                                % (seq_id, lane.pos, self.pos_len))
+                        # resolves the frame first: a new page, or a private
+                        # copy of a shared one
+                        write_slot[idx, 0] = self._phys_slot(lane, lane.pos)
+                        data[idx, 0] = float(np.asarray(tok).reshape(()))
+                        pos_idx[idx, 0] = lane.pos
+                        stepped.append((seq_id, idx, lane))
+                with part("serving.step.stage.table"):
+                    table = self._page_table(
+                        (idx, lane) for _, idx, lane in stepped)
                 staged = {"data": data, "pos_idx": pos_idx,
-                          "write_slot": write_slot,
-                          "page_table": self._page_table(
-                              (idx, lane) for _, idx, lane in stepped)}
+                          "write_slot": write_slot, "page_table": table}
                 # ONE batched transfer: a host-to-device copy of a few KB
                 # costs the host 0.2 ms whatever its size
-                exe.rebind(staged, jax.device_put(list(staged.values())))
+                with part("serving.step.stage.put"):
+                    exe.rebind(staged, jax.device_put(list(staged.values())))
 
             def enqueue():
                 exe.forward(is_train=False)
@@ -1810,8 +1882,8 @@ class PagedKVDecoder:
 
             # graphlint: waive GL701 -- single-step tail of the megastep loop; the K-amortized body is the lax.scan in step_megastep
             (chosen,), block = _dispatch_and_pull(
-                self, "serving.paged_step", "serving.decode_step", enqueue,
-                rows=len(stepped), paged=True)
+                self, "serving.paged_step", "decode", "serving.decode_step",
+                enqueue, rows=len(stepped), paged=True)
             out = {}
             with _tm.span("serving.step.commit"):
                 _swap_cache(exe, self._cache_names)
@@ -1927,8 +1999,9 @@ class PagedKVDecoder:
         # (K, B) ids and the active mask: the only host pull
         # graphlint: waive GL701 -- one round-trip a K tokens: the amortized shape the single-step tail is measured against
         (ids, acts_h), new_kvs = _dispatch_and_pull(
-            self, "serving.paged_megastep", "serving.decode_megastep",
-            enqueue, rows=len(stepped), paged=True, k=k)
+            self, "serving.paged_megastep", "megastep",
+            "serving.decode_megastep", enqueue, rows=len(stepped), paged=True,
+            k=k)
         self._dec_exe.rebind(ms.kv_names, new_kvs)
         out = {}
         written = 0

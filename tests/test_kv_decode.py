@@ -725,19 +725,20 @@ def test_the_pull_queues_its_copy_before_it_waits(tm):
         calls.append("enqueue")
         return (Pending(),), "kept"
 
-    dec = types.SimpleNamespace(_last_return_t=None)
+    dec = types.SimpleNamespace(_device=kd._DeviceRecord())
     tm.set_mode("0")
-    (host,), kept = kd._dispatch_and_pull(dec, "site", "span", enqueue)
+    (host,), kept = kd._dispatch_and_pull(dec, "site", "decode", "span",
+                                          enqueue)
     assert calls == ["enqueue", "copy_to_host_async", "block_until_ready",
                      "__array__"]
     assert kept == "kept" and host.tolist() == [0.0, 1.0, 2.0]
-    assert dec._last_return_t is None and tm.drain_events() == []
+    assert dec._device.ready_t is None and tm.drain_events() == []
     tm.set_mode("trace")
-    kd._dispatch_and_pull(dec, "site", "span", enqueue, rows=1)
+    kd._dispatch_and_pull(dec, "site", "decode", "span", enqueue, rows=1)
     names = [e[0] for e in sorted(tm.drain_events(), key=lambda e: e[1])]
     assert names == ["span", "serving.step.dispatch", "serving.step.read",
                      "serving.step.wait", "serving.step.copy"]
-    assert dec._last_return_t is not None
+    assert dec._device.ready_t is not None and dec._device.in_flight == []
 
 
 def test_admit_waits_for_the_prefill_inside_the_logits_phase(traced_request):
