@@ -31,7 +31,9 @@ counts its bytes once and a read of it once for every layer that reads. A
 token's write happens IN-GRAPH, into the page that holds each lane's
 ``write_slot`` (models/transformer.py ``get_decode_symbol``), as the program
 makes each lane's attention mask of its ``page_table``: a step hands the
-device a few numbers a lane. The decode program OWNS the cache: it takes
+device a few numbers a lane, as ONE array the decoder keeps between steps
+and patches (``_step_in_symbol``: the program cuts the graph's four inputs
+out of it). The decode program OWNS the cache: it takes
 every buffer donated and updates it in place, so the cache exists once, and
 the updated buffers are program outputs the decoder swaps back in as the
 next step's inputs — a device-side pointer swap, no copy, no host
@@ -404,6 +406,37 @@ def _swap_cache(exe, names):
     (the next step, ``_AdmitScatter``), so every reader takes a buffer from
     ``arg_dict`` at the time of use."""
     exe.rebind(names, [o._jax() for o in exe.outputs[1:1 + len(names)]])
+
+
+# a lane's row of a step's ONE host-fed array: its token, its position, the
+# slot the token lands in, then the frames of its pages in order
+_STEP_COLUMNS = ("data", "pos_idx", "write_slot", "page_table")
+_SLOT_AT, _TABLE_AT = 2, 3
+
+
+def _step_in_symbol(decode, pages):
+    """``decode`` (``get_decode_symbol``'s graph, whatever the arch) fed by
+    ONE array: its four host-fed Variables (``_STEP_COLUMNS``: ``data``,
+    ``pos_idx``, ``write_slot`` (lanes, 1) and ``page_table`` (lanes,
+    ``pages``)) become static slices of ``step_in`` (lanes, 3 + ``pages``)
+    float32, cut inside the same program, so a step transfers one array and
+    every operator receives the values it received before. The graph is
+    rewired in place (the builders make a fresh one a call) and returned;
+    the model graphs, the megastep's feed and whoever binds a decode graph
+    directly keep the four names."""
+    from .. import symbol as _sym
+
+    step_in, cuts, at = _sym.Variable("step_in"), {}, 0
+    for name in _STEP_COLUMNS:
+        width = pages if name == "page_table" else 1
+        cuts[name] = _sym.slice_axis(
+            step_in, axis=1, begin=at, end=at + width,
+            name="step_" + name)._outputs[0]
+        at += width
+    for node in decode._topo():
+        node.inputs = [cuts.get(src.name, (src, j)) if src.is_variable
+                       else (src, j) for src, j in node.inputs]
+    return decode
 
 
 # ------------------------------------------------------------------ megastep
@@ -959,12 +992,27 @@ class _PagePool:
 
 
 class _Lane:
-    __slots__ = ("seq_id", "pos", "frames")
+    __slots__ = ("seq_id", "pos", "_frames", "stale")
 
     def __init__(self, seq_id):
         self.seq_id = seq_id
         self.pos = 0            # next position to be written
-        self.frames = []        # logical page -> physical frame index
+        self.frames = ()
+
+    @property
+    def frames(self):
+        """Logical page -> physical frame index: a tuple, so a page map
+        changes nowhere but in the setter."""
+        return self._frames
+
+    @frames.setter
+    def frames(self, frames):
+        """THE place a lane's page map changes (a page appended, copied,
+        adopted, shared or dropped): the lane's row of the decoder's staged
+        step input no longer holds it (``PagedKVDecoder.step`` rewrites a
+        stale row and no other)."""
+        self._frames = tuple(frames)
+        self.stale = True
 
 
 class PagedKVDecoder:
@@ -1180,8 +1228,10 @@ class PagedKVDecoder:
             binding.update(dtype="float32", input_dtypes={
                 n: dtype for n in self._pool_names + self._ring_names})
         prefill = _tf.get_prefill_symbol(prefill_len=self.prefill_len, **cfg)
-        decode = _tf.get_decode_symbol(max_len=self.total_slots,
-                                       page_size=self.page_size, **cfg)
+        decode = _step_in_symbol(
+            _tf.get_decode_symbol(max_len=self.total_slots,
+                                  page_size=self.page_size, **cfg),
+            self.pool.frames_per_lane)
         self._pf_moe_load = _output_at(prefill, "moe_load")
         self._dec_moe_load = _output_at(decode, "moe_load")
         self._dec_token = _output_at(decode, "greedy_token")
@@ -1206,6 +1256,15 @@ class PagedKVDecoder:
         self._step_gathered_slots = 0  # likewise: slots a dispatch scores
         self._kernel_block = 0         # and the slots of the kernel's block
         self._lanes: Dict[int, _Lane] = {}   # lane index -> _Lane
+        # a step's ONE host-fed array, kept between steps (``step``): a row a
+        # lane in ``_STEP_COLUMNS``' order, idle (token 0, position 0, write
+        # slot -1, no page) unless the lane was stepped last; and the lanes
+        # that were, by row
+        self._idle_row = np.zeros((_TABLE_AT + self.pool.frames_per_lane,),
+                                  np.float32)
+        self._idle_row[_SLOT_AT] = -1
+        self._step_in = np.tile(self._idle_row, (self.lanes, 1))
+        self._stepped: Dict[int, _Lane] = {}
         self._seq_lane: Dict[int, int] = {}  # seq_id -> lane index
         self._next_seq = 0
         self._warm = False
@@ -1244,8 +1303,7 @@ class PagedKVDecoder:
     # ------------------------------------------------------------ lifecycle
     def _decode_shapes(self):
         B, S = self.lanes, self.total_slots
-        shapes = {"data": (B, 1), "pos_idx": (B, 1), "write_slot": (B, 1),
-                  "page_table": (B, self.pool.frames_per_lane)}
+        shapes = {"step_in": self._step_in.shape}
         for name, kind, shape in self._cache:
             # a pool in the layout its row's width gives it: page-major
             # (frames, page, heads * d) or head-major (heads, slots, d)
@@ -1415,7 +1473,7 @@ class PagedKVDecoder:
                 buf.at[fresh].set(buf[frame]) if pool_paged(*shape)
                 else buf.at[:, dst, :].set(buf[:, src, :])])
         self.pool.release([frame])
-        lane.frames[page] = fresh
+        lane.frames = lane.frames[:page] + (fresh,) + lane.frames[page + 1:]
         if _tm.enabled():
             self._device.enqueued("cow")
             _tm.counter("serving.cow_copies").inc()
@@ -1432,7 +1490,7 @@ class PagedKVDecoder:
                 "quota (max_len %d)" % (pos, self.max_len))
         page, off = divmod(pos, self.page_size)
         while len(lane.frames) <= page:
-            lane.frames.append(self._acquire_frame())
+            lane.frames += (self._acquire_frame(),)
         frame = self._cow_page(lane, page)
         return frame * self.page_size + off
 
@@ -1626,7 +1684,7 @@ class PagedKVDecoder:
         matched, frames = self._prefix.match(hashes)
         for f in frames:
             self.pool.incref(f)
-        lane.frames = list(frames)
+        lane.frames = frames
         if _tm.enabled() and frames:
             _tm.counter("serving.pages_shared").inc(len(frames))
         if matched:
@@ -1744,7 +1802,7 @@ class PagedKVDecoder:
         self._next_seq += 1
         lane = _Lane(new_id)
         lane.pos = src.pos
-        lane.frames = list(src.frames)
+        lane.frames = src.frames
         for f in lane.frames:
             self.pool.incref(f)
         self._lanes[new_idx] = lane
@@ -1773,7 +1831,7 @@ class PagedKVDecoder:
                 % (pos, lane.pos))
         keep = (pos + self.page_size - 1) // self.page_size
         dropped = lane.frames[keep:]
-        del lane.frames[keep:]
+        lane.frames = lane.frames[:keep]
         self.pool.release(dropped)
         lane.pos = pos
         if _tm.enabled():
@@ -1811,6 +1869,63 @@ class PagedKVDecoder:
         return rows
 
     # --------------------------------------------------------------- decode
+    def _patch_step_in(self, tokens, part):
+        """``step``'s host half: ``tokens``' lanes written into the array the
+        decoder keeps between steps (``_step_in``), under the stage's spans
+        ``slots`` and ``table`` (``part``). Returns ``([(seq_id, lane index,
+        lane)], table rows written)``. The array is written only here, and a
+        step returns only after it has waited for the program that consumed
+        the transfer (``_dispatch_and_pull``): ``jax.device_put`` may read a
+        host array after it returns, and on the CPU may alias its memory, so
+        the patch must come after that wait. Keep this order if the wait
+        ever moves."""
+        staged = self._step_in
+        stepped, now, stale = [], {}, []
+        try:
+            with part("serving.step.stage.slots"):
+                for seq_id, tok in tokens.items():
+                    idx = self._seq_lane.get(seq_id)
+                    if idx is None:
+                        raise MXNetError("paged_kv: unknown seq_id %r"
+                                         % (seq_id,))
+                    lane = self._lanes[idx]
+                    if self.pos_len is not None and lane.pos >= self.pos_len:
+                        raise MXNetError(
+                            "paged_kv: seq %d at position %d exceeds the "
+                            "trained position table (%d rows)"
+                            % (seq_id, lane.pos, self.pos_len))
+                    # resolves the frame first: a new page, or a private copy
+                    # of a shared one
+                    slot = self._phys_slot(lane, lane.pos)
+                    staged[idx, 0] = float(np.asarray(tok).reshape(()))
+                    staged[idx, 1] = lane.pos
+                    staged[idx, _SLOT_AT] = slot
+                    if lane.stale:
+                        stale.append((idx, lane))
+                    now[idx] = lane
+                    stepped.append((seq_id, idx, lane))
+            with part("serving.step.stage.table"):
+                # most steps nothing: a lane that crossed a page, was
+                # admitted, copied or rolled back; one left out
+                for idx, lane in stale:
+                    upto = _TABLE_AT + len(lane.frames)
+                    staged[idx, _TABLE_AT:upto] = lane.frames
+                    staged[idx, upto:] = 0
+                    lane.stale = False
+                left = [idx for idx in self._stepped if idx not in now]
+                for idx in left:
+                    staged[idx] = self._idle_row
+                    self._stepped[idx].stale = True
+                self._stepped = now
+        except BaseException:
+            # a refused step leaves no half-written row behind
+            staged[:] = self._idle_row
+            for lane in (*self._stepped.values(), *now.values()):
+                lane.stale = True
+            self._stepped = {}
+            raise
+        return stepped, len(stale) + len(left)
+
     def step(self, tokens: Dict[int, object]):
         """One multiplexed decode dispatch: ``tokens`` maps seq_id -> next
         token id for any subset of active sequences; every stepped
@@ -1821,10 +1936,16 @@ class PagedKVDecoder:
         follows when a row is read as an array, once for the step's rows
         (``_LogitsRow``). What the host hands the program is, a
         lane, its token, its position, the slot the token lands in and the
-        frames of its pages (``data``, ``pos_idx``, ``write_slot``,
-        ``page_table``: ``lanes * (3 + max_len / page_size)`` float32 in
-        all); the masks over the pool's slots are made of them on the
-        device, and the token's K/V goes into the page that holds its slot.
+        frames of its pages, ONE array in ONE transfer (``step_in``:
+        ``lanes * (3 + max_len / page_size)`` float32, the graph's ``data``,
+        ``pos_idx``, ``write_slot`` and ``page_table`` side by side, cut
+        apart inside the program); the masks over the pool's slots are made
+        of them on the device, and the token's K/V goes into the page that
+        holds its slot. The array LIVES between steps: a stepped lane's
+        three numbers are written in place, its table row only when its
+        page map changed since it was last written (``_Lane.frames``), and
+        a lane stepped last time and not now gets the idle row back, so the
+        array is at every step what building it from nothing would give.
         The program takes the cache DONATED: from its enqueue to the swap in
         ``serving.step.commit`` what ``arg_dict`` holds of it is dead. Lanes
         not stepped (or unoccupied) ride along with a negative write slot —
@@ -1837,41 +1958,14 @@ class PagedKVDecoder:
         # the stage's three parts hang on ONE mode read, not on one each
         part = _tm.span if _tm.tracing() else _no_span
         with _tm.span("serving.paged_step", rows=len(tokens), paged=True):
-            B = self.lanes
             exe = self._dec_exe
             with _tm.span("serving.step.stage"):
-                with part("serving.step.stage.slots"):
-                    data = np.zeros((B, 1), np.float32)
-                    pos_idx = np.zeros((B, 1), np.float32)
-                    write_slot = np.full((B, 1), -1, np.float32)
-                    stepped = []
-                    for seq_id, tok in tokens.items():
-                        idx = self._seq_lane.get(seq_id)
-                        if idx is None:
-                            raise MXNetError("paged_kv: unknown seq_id %r"
-                                             % (seq_id,))
-                        lane = self._lanes[idx]
-                        if self.pos_len is not None \
-                                and lane.pos >= self.pos_len:
-                            raise MXNetError(
-                                "paged_kv: seq %d at position %d exceeds "
-                                "the trained position table (%d rows)"
-                                % (seq_id, lane.pos, self.pos_len))
-                        # resolves the frame first: a new page, or a private
-                        # copy of a shared one
-                        write_slot[idx, 0] = self._phys_slot(lane, lane.pos)
-                        data[idx, 0] = float(np.asarray(tok).reshape(()))
-                        pos_idx[idx, 0] = lane.pos
-                        stepped.append((seq_id, idx, lane))
-                with part("serving.step.stage.table"):
-                    table = self._page_table(
-                        (idx, lane) for _, idx, lane in stepped)
-                staged = {"data": data, "pos_idx": pos_idx,
-                          "write_slot": write_slot, "page_table": table}
-                # ONE batched transfer: a host-to-device copy of a few KB
-                # costs the host 0.2 ms whatever its size
+                stepped, rows_written = self._patch_step_in(tokens, part)
+                # ONE transfer: a host-to-device copy is paid by the array
+                # (0.24 ms the first, 0.17 each further), not by the byte
                 with part("serving.step.stage.put"):
-                    exe.rebind(staged, jax.device_put(list(staged.values())))
+                    exe.rebind(("step_in",),
+                               (jax.device_put(self._step_in),))
 
             def enqueue():
                 exe.forward(is_train=False)
@@ -1914,7 +2008,10 @@ class PagedKVDecoder:
                     _tm.counter("serving.step_slot_writes").inc(
                         len(stepped) * len(self._pool_names))
                     _tm.counter("serving.step_input_bytes").inc(
-                        sum(a.nbytes for a in staged.values()))
+                        self._step_in.nbytes)
+                    _tm.counter("serving.step_staged_arrays").inc()
+                    _tm.counter("serving.step_table_rows_written").inc(
+                        rows_written)
                     if self._decode_xla_bytes:
                         _tm.counter("serving.decode_xla_bytes").inc(
                             self._decode_xla_bytes)

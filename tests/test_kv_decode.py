@@ -791,8 +791,8 @@ def test_decode_xla_bytes_rises_by_the_program_s_count_per_step(
 def test_a_step_hands_the_program_a_few_numbers_a_lane(tm):
     """``serving.step_input_bytes`` over ``serving.paged_steps``: a token, a
     position, a write slot and a page table a lane, whatever the lanes hold
-    and however many of them step; no input of the decode program has the
-    pool's length."""
+    and however many of them step, in ONE array (``serving.step_staged_arrays``
+    a step); no input of the decode program has the pool's length."""
     tm.set_mode("trace")
     S, lanes, page = 16, 3, 4
     _, _, params = _trained_params(S)
@@ -808,11 +808,10 @@ def test_a_step_hands_the_program_a_few_numbers_a_lane(tm):
     c = tm.counters()
     assert c["serving.paged_steps"] == 4
     assert c["serving.step_input_bytes"] == 4 * lanes * (3 + S // page) * 4
+    assert c["serving.step_staged_arrays"] == 4
     inputs = {n: a.shape for n, a in dec._dec_exe.arg_dict.items()
               if n in dec._decode_shapes() and not n.startswith("kv_")}
-    assert inputs == {"data": (lanes, 1), "pos_idx": (lanes, 1),
-                      "write_slot": (lanes, 1),
-                      "page_table": (lanes, S // page)}
+    assert inputs == {"step_in": (lanes, 3 + S // page)}
 
 
 def _cold_probs(exe, tokens, S):
@@ -867,7 +866,7 @@ def test_a_step_reads_a_cold_re_forward_after(tm, case, layout):
         # still reads its own tokens there, then writes it in place
         check(dec.step({sid: t0})[sid], prompt + [t0])
         assert tm.counters()["serving.cow_copies"] == 1
-        assert dec._lanes[dec._seq_lane[sid]].frames == shared
+        assert list(dec._lanes[dec._seq_lane[sid]].frames) == shared
     else:
         toks = [t0]
         for _ in range(4):                      # positions 6..10: three pages
@@ -1233,3 +1232,256 @@ def test_a_step_leaves_dead_what_it_read_and_live_what_it_wrote(tm, arch):
         np.testing.assert_array_equal(np.asarray(row), first[sid])
     writes = tm.counters()["serving.step_slot_writes"]
     assert writes == 2 * len(nxt) * len(dec._pool_names)
+
+
+# ---------------- a step's ONE host input, kept between steps and patched
+def _from_nothing(dec, tokens):
+    """The four arrays the parent's ``step`` built FROM NOTHING at every
+    step (its code, kept here to hold the patched array to it), side by
+    side. Read after the step: a stepped lane's position is one behind
+    ``lane.pos`` and its frames are what ``_phys_slot`` left."""
+    B = dec.lanes
+    data = np.zeros((B, 1), np.float32)
+    pos_idx = np.zeros((B, 1), np.float32)
+    write_slot = np.full((B, 1), -1, np.float32)
+    table = np.zeros((B, dec.pool.frames_per_lane), np.float32)
+    for seq_id, tok in tokens.items():
+        idx = dec._seq_lane[seq_id]
+        lane = dec._lanes[idx]
+        page, off = divmod(lane.pos - 1, dec.page_size)
+        write_slot[idx, 0] = lane.frames[page] * dec.page_size + off
+        data[idx, 0] = float(np.asarray(tok).reshape(()))
+        pos_idx[idx, 0] = lane.pos - 1
+        table[idx, :len(lane.frames)] = lane.frames
+    return np.concatenate([data, pos_idx, write_slot, table], axis=1)
+
+
+def _aligned(array, offset):
+    """A copy of ``array`` whose memory starts ``offset`` bytes past a
+    64-byte boundary: at 0 the CPU backend's ``jax.device_put`` ALIASES it
+    (the device array is the host's memory, whatever is written there
+    later), at 4 it copies."""
+    raw = np.zeros(array.nbytes + 128, np.uint8)
+    start = (-raw.ctypes.data) % 64 + offset
+    out = raw[start:start + array.nbytes].view(array.dtype).reshape(
+        array.shape)
+    out[...] = array
+    assert out.ctypes.data % 64 == offset
+    return out
+
+
+class _Pair:
+    """A decoder whose staged array the CPU backend aliases, beside a twin
+    driven alike whose array is made idle and whose every row is marked stale
+    before each step (built from nothing, and copied by the transfer). Every
+    step is held to three things: ONE ``jax.device_put``, of the staged
+    array; that array, as it was handed over, equal to the parent's four
+    element for element; and the rows' logits equal to the twin's bit for
+    bit (a patch that came before the program's read would show here)."""
+
+    def __init__(self, make, monkeypatch):
+        import jax
+
+        self.dec, self.twin = make().warmup(), make().warmup()
+        self.dec._step_in = _aligned(self.dec._step_in, 0)
+        self.twin._step_in = _aligned(self.twin._step_in, 4)
+        self.puts = puts = []
+        put = jax.device_put
+
+        def recording(x, *args, **kwargs):
+            puts.append((x, np.array(x) if isinstance(x, np.ndarray) else x))
+            return put(x, *args, **kwargs)
+
+        monkeypatch.setattr(jax, "device_put", recording)
+        self.steps = 0
+
+    def both(self, call):
+        """``call(decoder)`` on the twin, then on the decoder, whose answer
+        is returned: ``PagedKVExhausted`` where the pool refused, which it
+        does to both or to neither."""
+        outs = []
+        for dec in (self.twin, self.dec):
+            try:
+                outs.append(call(dec))
+            except PagedKVExhausted:
+                outs.append(PagedKVExhausted)
+        assert (outs[0] is PagedKVExhausted) == (outs[1] is PagedKVExhausted)
+        return outs
+
+    def step(self, tokens):
+        """The step's rows, or None where the pool refused it (both)."""
+        dec, twin = self.dec, self.twin
+        twin._step_in[:] = twin._idle_row
+        for lane in twin._lanes.values():
+            lane.stale = True
+        twin._stepped = {}
+        del self.puts[:]
+        want, got = self.both(lambda d: d.step(tokens))
+        if got is PagedKVExhausted:
+            # a refused step leaves every row idle behind it
+            np.testing.assert_array_equal(
+                dec._step_in, np.tile(dec._idle_row, (dec.lanes, 1)))
+            return None
+        (_, _), (given, seen) = self.puts       # the twin's, then this one
+        assert given is dec._step_in
+        # the premise: what the program was handed IS the host's memory
+        assert dec._dec_exe.arg_dict["step_in"]._jax() \
+            .unsafe_buffer_pointer() == given.ctypes.data
+        np.testing.assert_array_equal(seen, _from_nothing(self.dec, tokens))
+        np.testing.assert_array_equal(self.dec._step_in, seen)
+        assert sorted(got) == sorted(want) == sorted(tokens)
+        for seq in tokens:
+            np.testing.assert_array_equal(np.asarray(got[seq]),
+                                          np.asarray(want[seq]))
+        self.steps += 1
+        return got
+
+    def caches_agree(self):
+        for name in self.dec._cache_names:
+            np.testing.assert_array_equal(
+                np.array(self.dec._dec_exe.arg_dict[name]._jax()),
+                np.array(self.twin._dec_exe.arg_dict[name]._jax()))
+
+
+WALKS = {
+    # what the arch's cache holds beside (or in place of) pools, and whether
+    # pages can be shared: pools only; pools with the prefix cache's adopted
+    # pages; per-lane rows; rows, rings and one pool
+    "pool_only": ("vaswani", {}),
+    "prefix_cache": ("vaswani", dict(prefix_cache=True, prefix_chunk=4)),
+    "hybrid_rows": ("granite_hybrid", {}),
+    "window_rings": ("phi4flash", {}),
+}
+
+
+@pytest.mark.parametrize("walk", sorted(WALKS))
+def test_the_staged_array_is_what_the_parent_built_from_nothing(
+        tm, monkeypatch, walk):
+    """A seeded random walk over admit, step of random subsets, retire and,
+    where pages can be shared, fork, rollback, a shared page's copy and a
+    prefix-cache hit, in pages of 4 so that lanes cross page boundaries all
+    the time: at EVERY step the one array the decoder keeps and patches is
+    the concatenation of the four the parent built from nothing."""
+    import test_rebind
+
+    arch, kw = WALKS[walk]
+    shares = arch == "vaswani"
+    pair = _Pair(lambda: test_rebind._decoder(arch, lanes=4, **kw),
+                 monkeypatch)
+    dec = pair.dec
+    rs = np.random.RandomState(51)
+    vocab = test_rebind.ARCHS[arch]["vocab_size"]
+    prompts = [rs.randint(1, vocab, n).astype(np.float32)
+               for n in (3, 4, 7, 8, 9, 13)]
+    seen = dict(admit=0, retire=0, fork=0, rollback=0, subset=0, crossed=0,
+                hit=0, cow=0)
+    tm.set_mode("counters")
+    for _ in range(90):
+        active = dec.active
+        for seq in active:          # a lane at its quota's end leaves
+            if dec.position(seq) >= test_rebind.S - 1:
+                pair.both(lambda d: d.retire(seq))
+        active = dec.active
+        op = rs.choice(["admit", "step", "step", "step", "retire",
+                        "fork", "rollback"])
+        if op == "admit" and len(active) < dec.lanes:
+            prompt = prompts[rs.randint(len(prompts))]
+            seen["admit"] += pair.both(
+                lambda d: d.admit(prompt)[0])[1] is not PagedKVExhausted
+        elif op == "retire" and active:
+            seq = active[rs.randint(len(active))]
+            pair.both(lambda d: d.retire(seq))
+            seen["retire"] += 1
+        elif op == "fork" and shares and active \
+                and len(active) < dec.lanes:
+            seq = active[rs.randint(len(active))]
+            seen["fork"] += pair.both(
+                lambda d: d.fork(seq))[1] is not PagedKVExhausted
+        elif op == "rollback" and shares and active:
+            seq = active[rs.randint(len(active))]
+            to = rs.randint(1, dec.position(seq) + 1)
+            pair.both(lambda d: d.rollback(seq, to))
+            seen["rollback"] += 1
+        elif op == "step" and active:
+            some = [s for s in active if rs.rand() < 0.7] or active[:1]
+            seen["subset"] += len(some) < len(active)
+            seen["crossed"] += sum(
+                dec.position(s) % test_rebind.PAGE == 0 for s in some)
+            pair.step({s: int(rs.randint(1, vocab)) for s in some})
+    c = tm.counters()
+    seen["hit"], seen["cow"] = (c.get("serving.prefix_hits", 0),
+                                c.get("serving.cow_copies", 0))
+    pair.caches_agree()
+    assert pair.steps >= 25 and seen["subset"] and seen["crossed"] >= 5
+    assert seen["admit"] >= 4 and seen["retire"] >= 2
+    if shares:
+        assert seen["fork"] and seen["rollback"] and seen["cow"]
+    if walk == "prefix_cache":
+        assert seen["hit"]
+
+
+def test_a_lane_left_out_of_a_step_rides_along_and_comes_back(
+        monkeypatch, layout):
+    """Stepped, left out of ``tokens``, stepped again: while it is left out
+    its row is the idle row (token 0, position 0, write slot -1, no page),
+    as a ride-along row always was, and when it comes back its three numbers
+    AND its table row are written again, a page crossed meanwhile by the
+    other lane and by itself included."""
+    S = 24
+    _, _, params = _trained_params(S)
+    pair = _Pair(lambda: _paged(params, S, lanes=3), monkeypatch)
+    dec = pair.dec
+    (_, a), (_, b) = [pair.both(lambda d: d.admit(np.asarray(p, np.float32)))
+                      for p in ([3, 1, 4, 1, 5, 9, 2], [2, 7, 1])]
+    a, b = a[0], b[0]
+    row = lambda seq: dec._step_in[dec._seq_lane[seq]]
+    pair.step({a: 5, b: 6})                      # a writes 7, b writes 3
+    assert row(a)[2] >= 0 and row(b)[2] >= 0
+    for tok in (7, 8):                           # b crosses into its page 1
+        pair.step({b: tok})
+        np.testing.assert_array_equal(row(a), dec._idle_row)
+    pair.step({a: 9, b: 1})                      # a comes back, at page 2
+    assert list(row(a)[:2]) == [9, 8] and row(a)[2] == \
+        dec._lanes[dec._seq_lane[a]].frames[2] * 4
+    assert list(row(a)[3:6]) == list(dec._lanes[dec._seq_lane[a]].frames)
+    pair.step({a: 2})                            # and b is the one left out
+    np.testing.assert_array_equal(row(b), dec._idle_row)
+    pair.both(lambda d: d.retire(b))
+    pair.step({a: 3})
+    pair.caches_agree()
+    assert pair.steps == 6
+
+
+def test_the_stage_counts_its_one_array_and_the_table_rows_it_wrote(tm):
+    """``serving.step_staged_arrays`` = ``serving.paged_steps`` (four arrays a
+    step on the parent); ``serving.step_table_rows_written``: nothing over
+    steps in which no lane crossed a page and none was admitted, left out or
+    retired, else those lanes (every stepped lane every step on the
+    parent)."""
+    tm.set_mode("counters")
+    S = 32
+    _, _, params = _trained_params(S)
+    dec = _paged(params, S, lanes=3).warmup()
+    a, _ = dec.admit(np.asarray([3, 1, 4, 1, 5], np.float32))   # at 5
+    b, _ = dec.admit(np.asarray([2, 7, 1], np.float32))         # at 3
+    wrote = []
+    for tokens in ({a: 1, b: 1},    # both admitted since the last step: 2
+                   {a: 1, b: 1},    # b writes position 4, a new page: 1
+                   {a: 1, b: 1},    # 7 and 5: none
+                   {a: 1, b: 1},    # a writes position 8, a new page: 1
+                   {a: 1},          # b left out, its row idle again: 1
+                   {a: 1},          # 10: none
+                   {a: 1, b: 1},    # b back (7): its row again: 1
+                   {a: 1, b: 1}):   # a at 12 and b at 8 both cross: 2
+        before = tm.counters().get("serving.step_table_rows_written", 0)
+        dec.step(tokens)
+        wrote.append(tm.counters()["serving.step_table_rows_written"]
+                     - before)
+    assert wrote == [2, 1, 0, 1, 1, 0, 1, 2]
+    dec.retire(b)
+    dec.step({a: 1})                # the retired lane's row goes idle: 1
+    dec.step({a: 1})                # 14: none
+    c = tm.counters()
+    assert c["serving.step_table_rows_written"] == sum(wrote) + 1
+    assert c["serving.step_staged_arrays"] == c["serving.paged_steps"] == 10
+    assert c["serving.step_input_bytes"] == 10 * 3 * (3 + S // 4) * 4

@@ -503,7 +503,7 @@ def test_two_kinds_of_cache_side_by_side():
         assert bufs["ring_v_4"] == (4, 2, W, 8)
         assert bufs["kv_k_0"] == (1, 4 * max_len, 12)
         assert bufs["kv_v_5"] == (1, 4 * max_len, 8)
-        assert dec._decode_shapes()["page_table"] == (4, max_len // 8)
+        assert dec._decode_shapes()["step_in"] == (4, 3 + max_len // 8)
         assert dec._ring_names == [n for n, k, _ in cache if k == "ring"]
         assert dec._pool_names == ["kv_k_0", "kv_v_0", "kv_k_5", "kv_v_5"]
         seq, _ = dec.admit(np.arange(1, 21, dtype=np.float32))

@@ -262,7 +262,7 @@ def _admit_two(dec):
     ("granite_hybrid", "step"), ("granite_hybrid", "admit"),
     ("phi4flash", "step"), ("phi4flash", "admit")])
 def test_the_decoder_hands_every_buffer_on_by_reference(tm, arch, what):
-    """A steady step: the cache's buffers and the four staged inputs; an
+    """A steady step: the cache's buffers and the ONE staged input; an
     admission: the cache's buffers and the prefill's two (the bucket and the
     length, one transfer); a megastep and a chunk: the cache's buffers (their
     inputs are the program's own arguments, not the executable's); a copied
@@ -281,12 +281,12 @@ def test_the_decoder_hands_every_buffer_on_by_reference(tm, arch, what):
     elif what == "copy_on_write":
         seq, logits = dec.admit(np.asarray(PROMPTS[0], np.float32))
         twin = dec.fork(seq)                    # position 6: mid-page
-        per_call = [len(dec._pool_names) + cache + 4]
+        per_call = [len(dec._pool_names) + cache + 1]
         calls = [lambda: dec.step({twin: int(np.argmax(logits))})]
     else:
         nxt = _admit_two(dec)
         if what == "step":
-            per_call = [cache + 4] * 3
+            per_call = [cache + 1] * 3
             calls = [lambda: nxt.update(
                 {s: int(np.argmax(l)) for s, l in dec.step(nxt).items()})] * 3
         else:

@@ -359,6 +359,14 @@ def _assert_one_row_of_logits(compiled, bucket, vocab):
     assert not rows, rows
 
 
+def _entry_parameters(hlo):
+    """The shapes of a program's parameters, in its entry's order."""
+    entry = hlo[hlo.index("\nENTRY "):]
+    return [tuple(int(d) for d in dims.split(",") if d) for dims in
+            re.findall(r" = \w+\[([\d,]*)\]\S* parameter\(",
+                       entry[:entry.index("\n}")])]
+
+
 def _assert_pool_step_contracts(compiled, layers, rows, heads, slots, dh,
                                 temp_bytes, cache_bytes, page=16):
     """A shared-pool decode program as the chip runs it, its PAGE-MAJOR pools
@@ -372,10 +380,7 @@ def _assert_pool_step_contracts(compiled, layers, rows, heads, slots, dh,
     not), no mask over the pool's slots is built, and no parameter
     of the program is rows x slots: the write takes a slot index a row."""
     hlo = compiled.as_text()
-    entry = hlo[hlo.index("\nENTRY "):]
-    params = [tuple(int(d) for d in dims.split(",") if d) for dims in
-              re.findall(r" = \w+\[([\d,]*)\]\S* parameter\(",
-                         entry[:entry.index("\n}")])]
+    params = _entry_parameters(hlo)
     assert (slots // page, page, heads * dh) in params and (rows, 1) in params
     assert (rows, slots) not in params
     assert not [p for p in params if len(p) == 2 and math.prod(p) >= slots
@@ -601,6 +606,15 @@ def test_granite_hybrid_serving_programs_compile_for_the_chip(v5e, program):
         assert mem.temp_size_in_bytes < 400 << 20
 
 
+_KANANA = dict(arch="deepseek_v3", vocab_size=128256, num_layers=3,
+               num_heads=32, model_dim=2048, ffn_dim=6144, moe_ffn_dim=768,
+               num_experts=128, num_experts_per_tok=6, num_shared_experts=2,
+               first_dense_layers=1, qk_nope_head_dim=128,
+               qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=512,
+               rope_theta=1e6, rms_eps=1e-6, routed_scaling_factor=2.448,
+               norm_topk_prob=True, dtype="bfloat16")
+
+
 @pytest.mark.parametrize("program", ["prefill", "decode"])
 def test_latent_attention_serving_programs_compile_for_the_chip(v5e, program):
     """The two graphs ``PagedKVDecoder(arch="deepseek_v3")`` runs, lowered for
@@ -622,13 +636,8 @@ def test_latent_attention_serving_programs_compile_for_the_chip(v5e, program):
 
     lanes, max_len, bucket, page, layers = 32, 2048, 1024, 16, 3
     slots = lanes * max_len
-    cfg = dict(arch="deepseek_v3", vocab_size=128256, num_layers=layers,
-               num_heads=32, model_dim=2048, ffn_dim=6144, moe_ffn_dim=768,
-               num_experts=128, num_experts_per_tok=6, num_shared_experts=2,
-               first_dense_layers=1, qk_nope_head_dim=128,
-               qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=512,
-               rope_theta=1e6, rms_eps=1e-6, routed_scaling_factor=2.448,
-               norm_topk_prob=True, dtype="bfloat16")
+    cfg = _KANANA
+    assert cfg["num_layers"] == layers
     weights = {n: (s, "bfloat16") for n, s in tf.param_shapes(**cfg).items()}
     cache = tf.decode_cache(**cfg)
     assert cache == [("kv_c_%d" % i, "pool", (1, 576)) for i in range(layers)]
@@ -1283,3 +1292,78 @@ def test_nemotron_h_serving_programs_compile_for_the_chip(v5e, program):
     assert mem.alias_size_in_bytes == cache_bytes == 1_907_359_744
     assert mem.argument_size_in_bytes < 9_990_000_000
     assert mem.temp_size_in_bytes < 128 << 20
+
+
+_VASWANI = dict(vocab_size=32000, num_layers=2, num_heads=8, model_dim=512,
+                ffn_dim=2048, pos_len=1024)
+_ONE_INPUT_STEPS = {
+    # arch: (sizes, lanes, slots a lane, reading nodes that are the kernel's,
+    # copies and transposes of a pool's size: the head-major latent pool's
+    # re-layout and its gathered frames', all 65,536 slots, one each a layer,
+    # as in the program of four inputs)
+    "vaswani": (_VASWANI, 64, 1024, 2, 0),
+    "olmoe": (_OLMOE, 8, 2048, 1, 0),
+    "granite_hybrid": (_GRANITE, 32, 2048, 1, 0),
+    "deepseek_v3": (_KANANA, 32, 2048, 0, 6),
+    "lfm2_moe": (_LFM2, 64, 2048, 2, 0),
+    "mimo_v2_flash": (_MIMO, 32, 8192, 2, 0),
+    "phi4flash": (_PHI4, 64, 8192, 8, 0),
+    "nemotron_h": (_NEMOTRON, 64, 8192, 2, 0),
+}
+
+
+@pytest.mark.parametrize("arch", list(_ONE_INPUT_STEPS))
+def test_a_decode_step_takes_one_host_fed_array_on_the_chip(v5e, arch):
+    """Each arch's decode program AS ``PagedKVDecoder`` BINDS IT
+    (``kv_decode._step_in_symbol`` over ``get_decode_symbol``), at the sizes
+    the cases above compile, lowered for the v5e with its cache donated:
+    beside the weights and the cache it has exactly ONE parameter, ``step_in``
+    (lanes, 3 + pages a lane) float32, and none of a token's, a slot's or a
+    table's shape; the four operands are cut out of it inside the program,
+    and what held before still holds: the whole cache is updated in place,
+    no buffer of a pool's size is copied or transposed (the latent pool's
+    re-layouts apart), and every reading node of a page-major
+    pool is one call of the kernel that walks the page table."""
+    from mxnet_tpu.models import transformer as tf
+    from mxnet_tpu.ops.attention import pool_shape
+    from mxnet_tpu.serving.kv_decode import _step_in_symbol
+
+    cfg, lanes, max_len, reads, relaid = _ONE_INPUT_STEPS[arch]
+    page, slots, dtype = 16, lanes * max_len, cfg.get("dtype", "float32")
+    sym = _step_in_symbol(
+        tf.get_decode_symbol(max_len=slots, page_size=page, **cfg),
+        max_len // page)
+    cache = tf.decode_cache(**dict(cfg, arch=arch))
+    args = {"step_in": ((lanes, 3 + max_len // page), "float32")}
+    for name, kind, shape in cache:
+        args[name] = (pool_shape(*shape, slots, page), dtype) \
+            if kind == "pool" else ((lanes,) + tuple(shape),
+                                    "float32" if kind == "row" else dtype)
+    fed = set(args)
+    assert not {"data", "pos_idx", "write_slot", "page_table"} \
+        & set(sym.list_arguments())
+    if arch == "vaswani":
+        shapes, _, _ = sym.infer_shape(**{n: s for n, (s, _) in args.items()})
+        weights = dict(zip(sym.list_arguments(), shapes))
+    else:
+        weights = tf.param_shapes(**cfg)
+    args.update({n: (s, dtype) for n, s in weights.items() if n not in fed})
+    assert set(args) == set(sym.list_arguments())
+    compiled = _compile_program(v5e, sym, args,
+                                donated=[name for name, _, _ in cache])
+    hlo = compiled.as_text()
+    params = _entry_parameters(hlo)
+    assert len(params) == len(args)         # weights, cache, step_in: no key
+    assert params.count((lanes, 3 + max_len // page)) == 1
+    assert (lanes, 1) not in params and (lanes, max_len // page) not in params
+    cache_bytes = sum(math.prod(shape) * jnp.dtype(t).itemsize
+                      for shape, t in (args[name] for name, _, _ in cache))
+    assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+    assert len(_paged_read_calls(hlo)) == reads
+    pool = min(math.prod(args[name][0]) for name, kind, _ in cache
+               if kind == "pool")
+    moved = [name for name, dims, op, _ in _INSTRUCTION.findall(hlo)
+             if op in ("copy", "transpose")
+             and math.prod(int(d) for d in dims.split(",") if d) >= pool]
+    assert len(moved) == relaid, moved
+    assert "kv_mask" not in hlo or not reads
