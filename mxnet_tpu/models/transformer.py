@@ -12,7 +12,7 @@ from ..base import MXNetError
 from ..ops.attention import _LANES, _NEG, pool_paged
 
 ARCHS = ("vaswani", "olmoe", "granite_hybrid", "deepseek_v3", "lfm2_moe",
-         "mimo_v2_flash", "phi4flash", "nemotron_h")
+         "mimo_v2_flash", "phi4flash", "nemotron_h", "dots3_note")
 
 
 # the block every graph is derived from (ROADMAP D2); the others have the
@@ -364,7 +364,8 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
                 "lfm2_moe": _lfm2_moe_prefill_symbol,
                 "mimo_v2_flash": _mimo_prefill_symbol,
                 "phi4flash": _phi4flash_prefill_symbol,
-                "nemotron_h": _nemotron_h_prefill_symbol}
+                "nemotron_h": _nemotron_h_prefill_symbol,
+                "dots3_note": _dots3_prefill_symbol}
     if arch in builders:
         return builders[arch](
             vocab_size=vocab_size, num_layers=num_layers,
@@ -601,7 +602,8 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
                 "lfm2_moe": _lfm2_moe_decode_symbol,
                 "mimo_v2_flash": _mimo_decode_symbol,
                 "phi4flash": _phi4flash_decode_symbol,
-                "nemotron_h": _nemotron_h_decode_symbol}
+                "nemotron_h": _nemotron_h_decode_symbol,
+                "dots3_note": _dots3_decode_symbol}
     if arch in builders:
         return builders[arch](
             vocab_size=vocab_size, num_layers=num_layers,
@@ -1281,7 +1283,16 @@ def _deepseek_v3_layer(x, i, positions, seq_len, attend, block):
                                        end=nope + rope), "qrope"),
                  c, rotate(k_r, "krope"))
     x = x + fc(_merge_heads(att, seq_len, hq * block["v_dim"]), d, "proj")
-    h = sym.RMSNorm(x, eps=eps, name="%s_ln2" % name)
+    return _deepseek_v3_ffn(x, i, fc, seq_len, block)
+
+
+def _deepseek_v3_ffn(x, i, fc, seq_len, block):
+    """The feed-forward half of a ``deepseek_v3`` block on x (B, T, M) ->
+    (x', load (E,) or None): the gated SiLU MLP in the first
+    ``first_dense_layers`` layers, after them ``_sigmoid_experts`` beside a
+    shared gated MLP every token takes."""
+    name, d = "layer%d" % i, block["model_dim"]
+    h = sym.RMSNorm(x, eps=block["rms_eps"], name="%s_ln2" % name)
     if i < block["first_dense_layers"]:
         return x + _gated_mlp(fc, h, block["ffn_dim"], d, "mlp"), None
     moe = _sigmoid_experts(h, name, block)
@@ -1313,6 +1324,45 @@ def _deepseek_v3_stack(vocab_size, seq_len, positions, attend, block,
         else []
 
 
+def _materialised(i, q_nope, q_rope, c, k_r, seq_len, hq, nope, v_dim, lat):
+    """Latent attention MATERIALISED, a prefill's form: (what the cache
+    keeps of the layer, one head of [c | k_r] (B, 1, T, lat + rope) as a
+    pool's or a ring's rows; ``MultiHeadAttention``'s query, key and value,
+    every head's key and value made of the latent through ``layer<i>_kvb``)."""
+    row = sym.Concat(sym.Reshape(c, shape=(-1, 1, seq_len, lat)), k_r, dim=3)
+    kv = _split_heads(sym.FullyConnected(
+        data=c, num_hidden=hq * (nope + v_dim), no_bias=True,
+        flatten=False, name="layer%d_kvb" % i), seq_len, hq, nope + v_dim)
+    key = sym.Concat(sym.slice_axis(kv, axis=3, begin=0, end=nope),
+                     sym.broadcast_axis(k_r, axis=1, size=hq), dim=3)
+    return row, dict(
+        query=sym.Concat(q_nope, q_rope, dim=3), key=key,
+        value=sym.slice_axis(kv, axis=3, begin=nope, end=nope + v_dim))
+
+
+def _absorbed(i, q_nope, q_rope, read, hq, nope, rope, v_dim, lat):
+    """Latent attention ABSORBED, a step's form, on one token a lane: a
+    head's rows of ``layer<i>_kvb_weight`` are [Wuk_h | Wuv_h] over the
+    latent; Wuk goes into the query, ``read(query)`` gives the context over
+    the cached [c | k_r] rows (R, H, lat), Wuv goes onto it, and no key or
+    value of a head is ever made. Returns (B, H, 1, v_dim)."""
+    w = sym.Reshape(sym.Variable("layer%d_kvb_weight" % i,
+                                 shape=(hq * (nope + v_dim), lat)),
+                    shape=(hq, nope + v_dim, lat))
+    by_head = lambda a, width: sym.SwapAxis(
+        sym.Reshape(a, shape=(-1, hq, width)), dim1=0, dim2=1)
+    q_lat = sym.batch_dot(by_head(q_nope, nope),
+                          sym.slice_axis(w, axis=1, begin=0, end=nope))
+    query = sym.Concat(sym.SwapAxis(q_lat, dim1=0, dim2=1),
+                       sym.Reshape(q_rope, shape=(-1, hq, rope)), dim=2)
+    out = sym.batch_dot(
+        by_head(read(query), lat),
+        sym.slice_axis(w, axis=1, begin=nope, end=nope + v_dim),
+        transpose_b=True)
+    return sym.Reshape(sym.SwapAxis(out, dim1=0, dim2=1),
+                       shape=(-1, hq, 1, v_dim))
+
+
 def _deepseek_v3_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
     block = _deepseek_v3_sizes(num_layers, **sizes)
     hq, nope, v_dim, lat = (block[k] for k in ("num_heads", "nope", "v_dim",
@@ -1322,20 +1372,12 @@ def _deepseek_v3_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
     cache = []
 
     def attend(i, q_nope, q_rope, c, k_r):
-        # what the cache keeps, as a pool's rows: one head of [c | k_r]
-        cache.append(sym.Concat(
-            sym.Reshape(c, shape=(-1, 1, prefill_len, lat)), k_r, dim=3))
-        # materialised: every head's key and value, made of the latent
-        kv = _split_heads(sym.FullyConnected(
-            data=c, num_hidden=hq * (nope + v_dim), no_bias=True,
-            flatten=False, name="layer%d_kvb" % i), prefill_len, hq,
-            nope + v_dim)
-        key = sym.Concat(sym.slice_axis(kv, axis=3, begin=0, end=nope),
-                         sym.broadcast_axis(k_r, axis=1, size=hq), dim=3)
+        row, operands = _materialised(i, q_nope, q_rope, c, k_r, prefill_len,
+                                      hq, nope, v_dim, lat)
+        cache.append(row)
         return sym.MultiHeadAttention(
-            query=sym.Concat(q_nope, q_rope, dim=3), key=key,
-            value=sym.slice_axis(kv, axis=3, begin=nope, end=nope + v_dim),
-            causal=True, scale=block["scale"], name="layer%d_att" % i)
+            causal=True, scale=block["scale"], name="layer%d_att" % i,
+            **operands)
 
     logits, load = _deepseek_v3_stack(vocab_size, prefill_len, positions,
                                       attend, block,
@@ -1358,28 +1400,12 @@ def _deepseek_v3_decode_symbol(vocab_size, num_layers, num_slots, page_size,
                          sym.Reshape(k_r, shape=(-1, 1, rope)), dim=2)
         pool, = write(i, {"c": row})
         cache.append(pool)
-        # absorbed: a head's rows of kvb are [Wuk_h | Wuv_h] over the
-        # latent; Wuk goes into the query, Wuv onto the context, and no
-        # key or value of a head is ever made
-        w = sym.Reshape(sym.Variable("layer%d_kvb_weight" % i,
-                                     shape=(hq * (nope + v_dim), lat)),
-                        shape=(hq, nope + v_dim, lat))
-        by_head = lambda a, width: sym.SwapAxis(
-            sym.Reshape(a, shape=(-1, hq, width)), dim1=0, dim2=1)
-        q_lat = sym.batch_dot(by_head(q_nope, nope),
-                              sym.slice_axis(w, axis=1, begin=0, end=nope))
-        query = sym.Concat(sym.SwapAxis(q_lat, dim1=0, dim2=1),
-                           sym.Reshape(q_rope, shape=(-1, hq, rope)), dim=2)
         # the pool is key (all its columns) and value (its first ``lat``)
-        ctx = sym.KVPoolAttention(query, pool, pool, scale=block["scale"],
-                                  value_dim=lat, name="layer%d_att" % i,
-                                  **read)
-        out = sym.batch_dot(
-            by_head(ctx, lat),
-            sym.slice_axis(w, axis=1, begin=nope, end=nope + v_dim),
-            transpose_b=True)
-        return sym.Reshape(sym.SwapAxis(out, dim1=0, dim2=1),
-                           shape=(-1, hq, 1, v_dim))
+        return _absorbed(
+            i, q_nope, q_rope, lambda query: sym.KVPoolAttention(
+                query, pool, pool, scale=block["scale"], value_dim=lat,
+                name="layer%d_att" % i, **read),
+            hq, nope, rope, v_dim, lat)
 
     logits, load = _deepseek_v3_stack(vocab_size, 1, pos_idx, attend, block)
     outs = [logits] + cache
@@ -1413,6 +1439,287 @@ def _deepseek_v3_param_shapes(vocab_size, num_layers, **sizes):
             n + "experts_gate_weight": (e, d, f),
             n + "experts_up_weight": (e, d, f),
             n + "experts_down_weight": (e, f, d),
+            n + "shared_in_weight": (2 * shared, d),
+            n + "shared_out_weight": (d, shared)})
+    return shapes
+
+
+# ------ dots3-note (learned sparse attention beside windowed latent attention)
+def _dots3_sizes(num_layers, num_heads, model_dim, layer_types, ffn_dim=None,
+                 moe_ffn_dim=None, num_experts=256, num_experts_per_tok=8,
+                 num_local_experts=0, local_expert_offset=0,
+                 num_shared_experts=1, first_dense_layers=1,
+                 q_lora_rank=1024, kv_lora_rank=512, qk_nope_head_dim=128,
+                 qk_rope_head_dim=64, v_head_dim=128, rope_theta=8e7,
+                 swa_num_heads=None, swa_q_lora_rank=None,
+                 swa_kv_lora_rank=None, swa_qk_nope_head_dim=None,
+                 swa_qk_rope_head_dim=None, swa_v_head_dim=None,
+                 swa_rope_theta=5e4, sliding_window=513, index_n_heads=64,
+                 index_head_dim=128, index_topk=2048, lora_rescale=True,
+                 rms_eps=1e-5, routed_scaling_factor=1.0, norm_topk_prob=True,
+                 dtype="float32", **kwargs):
+    """``_dots3_layer``'s keywords from a builder's. The two kinds of layer
+    have a latent geometry each, ``block["full_attention"]`` and
+    ``block["sliding_attention"]`` (a ``swa_`` size left out is the full
+    layers'): heads, the query's and the key/value's low rank, nope, rope
+    and value widths, the rotary base, the softmax scale over the whole
+    query-key width and, with ``lora_rescale``, the factors
+    ``sqrt(model_dim / rank)`` on both normed latents. Keywords of the other
+    architectures are dropped."""
+    kinds = tuple(layer_types)
+    if len(kinds) != num_layers \
+            or set(kinds) - {"full_attention", "sliding_attention"}:
+        raise MXNetError("dots3_note: layer_types must name %d layers "
+                         "'full_attention' or 'sliding_attention', got %r"
+                         % (num_layers, kinds))
+    if not 0 <= first_dense_layers <= num_layers:
+        raise MXNetError("dots3_note: first_dense_layers %d outside [0, %d]"
+                         % (first_dense_layers, num_layers))
+
+    def geometry(heads, q_rank, latent, nope, rope, v_dim, theta):
+        if rope % 2:
+            raise MXNetError("dots3_note: a rotary width of %d is odd" % rope)
+        rho = lambda rank: math.sqrt(model_dim / rank) if lora_rescale else 1.0
+        return dict(heads=heads, q_rank=q_rank, latent=latent, nope=nope,
+                    rope=rope, v_dim=v_dim, rope_theta=float(theta),
+                    scale=float(nope + rope) ** -0.5, rho_q=rho(q_rank),
+                    rho_kv=rho(latent))
+
+    full = (num_heads, q_lora_rank, kv_lora_rank, qk_nope_head_dim,
+            qk_rope_head_dim, v_head_dim)
+    swa = (swa_num_heads, swa_q_lora_rank, swa_kv_lora_rank,
+           swa_qk_nope_head_dim, swa_qk_rope_head_dim, swa_v_head_dim)
+    if index_head_dim < qk_rope_head_dim:
+        raise MXNetError("dots3_note: index_head_dim %d under the rotary "
+                         "width %d" % (index_head_dim, qk_rope_head_dim))
+    return dict(
+        num_layers=num_layers, layer_types=kinds, model_dim=model_dim,
+        full_attention=geometry(*full, rope_theta),
+        sliding_attention=geometry(
+            *(own or theirs for own, theirs in zip(swa, full)),
+            swa_rope_theta),
+        sliding_window=int(sliding_window), index_heads=int(index_n_heads),
+        index_dim=int(index_head_dim), index_topk=int(index_topk),
+        ffn_dim=ffn_dim, moe_ffn_dim=moe_ffn_dim, num_experts=num_experts,
+        num_experts_per_tok=num_experts_per_tok,
+        num_local_experts=int(num_local_experts),
+        local_expert_offset=int(local_expert_offset),
+        num_shared_experts=num_shared_experts,
+        first_dense_layers=first_dense_layers, rms_eps=rms_eps,
+        routed_scaling_factor=float(routed_scaling_factor),
+        norm_topk_prob=bool(norm_topk_prob), dtype=dtype)
+
+
+def _layer_norm_f32(x, name, dtype, eps=1e-5):
+    """LayerNorm with ``<name>_gamma`` and ``<name>_beta`` over the last
+    axis, its statistics float32 whatever the IO dtype (``RMSNorm``'s rule:
+    ``_layer_norm`` above is the naive composition in the data's type)."""
+    xf = sym.Cast(x, dtype="float32")
+    cent = sym.broadcast_sub(xf, sym.mean(xf, axis=-1, keepdims=True))
+    inv = sym.rsqrt(sym.mean(cent * cent, axis=-1, keepdims=True) + eps)
+    normed = sym.broadcast_mul(
+        sym.broadcast_mul(cent, inv),
+        sym.Cast(sym.Variable("%s_gamma" % name), dtype="float32"))
+    return sym.Cast(sym.broadcast_add(
+        normed, sym.Cast(sym.Variable("%s_beta" % name), dtype="float32")),
+        dtype=dtype, name=name)
+
+
+def _dots3_layer(x, i, positions, seq_len, attend, block):
+    """One ``model_type: dots3_note`` block on x (B, T, M) -> (x', load (E,)
+    or None for a dense layer). ``layer_types[i]`` names the attention's
+    kind, and with it the latent geometry (``_dots3_sizes``).
+
+    Latent attention with a QUERY-side low rank, both kinds: ``c_q = rho_q *
+    RMSNorm(W_qa h)``, ``q = W_qb c_q`` -> H heads of [q_nope | q_rope];
+    ``[c | k_r] = W_kva h``, ``c = rho_kv * RMSNorm(c)``; rotary positions
+    over interleaved pairs on q_rope and the ONE k_r every head shares, at
+    the kind's own base. A FULL layer carries an indexer beside it, fed by
+    the SAME query latent: ``q_I = W_iq c_q`` -> Hi heads of di,
+    ``k_I = LayerNorm(W_ik h)`` (one head, with bias), the first ``rope``
+    features of both rotated (half-split pairs, the layer's base), and a
+    weight a head ``w = W_iw h / sqrt(Hi * di)``; a query attends the
+    ``index_topk`` keys of largest ``sum_j w_j relu(q_I,j . k_I)`` alone. A
+    WINDOW layer attends the last ``sliding_window`` positions, itself
+    among them, and has no indexer. ``attend(i, q_nope, q_rope, c, k_r,
+    index)`` is the one thing the prefill and the decode graph do
+    differently (``index``: (q_I (B, Hi, T, di), k_I (B, 1, T, di), w (B, T,
+    Hi)) or None) and returns (B, H, T, v_dim), through
+    ``layer<i>_kvb_weight`` materialised or absorbed as in
+    ``_deepseek_v3_layer``. A head-wise gate ``sigmoid(W_g h)``, one a head,
+    multiplies a head's context before the output projection. The
+    feed-forward is ``_deepseek_v3_layer``'s."""
+    name = "layer%d" % i
+    kind = block["layer_types"][i]
+    geo, d, eps = block[kind], block["model_dim"], block["rms_eps"]
+    hq, nope, rope, lat = (geo[k] for k in ("heads", "nope", "rope",
+                                            "latent"))
+    fc = lambda data, width, tag, **kw: sym.FullyConnected(
+        data=data, num_hidden=width, no_bias=True, flatten=False,
+        name="%s_%s" % (name, tag), **kw)
+    rotate = lambda a, tag, **kw: sym.RotaryEmbedding(
+        a, positions, base=geo["rope_theta"], name="%s_%s" % (name, tag),
+        **kw)
+    h = sym.RMSNorm(x, eps=eps, name="%s_ln1" % name)
+    c_q = sym.RMSNorm(fc(h, geo["q_rank"], "qa"), eps=eps,
+                      name="%s_qnorm" % name) * geo["rho_q"]
+    q = _split_heads(fc(c_q, hq * (nope + rope), "qb"), seq_len, hq,
+                     nope + rope)
+    kva = fc(h, lat + rope, "kva")
+    c = sym.RMSNorm(sym.slice_axis(kva, axis=2, begin=0, end=lat), eps=eps,
+                    name="%s_kvnorm" % name) * geo["rho_kv"]
+    k_r = sym.Reshape(sym.slice_axis(kva, axis=2, begin=lat, end=lat + rope),
+                      shape=(-1, 1, seq_len, rope))
+    index = None
+    if kind == "full_attention":
+        hi, di = block["index_heads"], block["index_dim"]
+        k_i = _layer_norm_f32(fc(h, di, "ik"), "%s_iknorm" % name,
+                              block["dtype"])
+        index = (rotate(_split_heads(fc(c_q, hi * di, "iq"), seq_len, hi,
+                                     di), "iqrope", rotary_dim=rope),
+                 rotate(sym.Reshape(k_i, shape=(-1, 1, seq_len, di)),
+                        "ikrope", rotary_dim=rope),
+                 fc(h, hi, "iw") * float(hi * di) ** -0.5)
+    att = attend(i, sym.slice_axis(q, axis=3, begin=0, end=nope),
+                 rotate(sym.slice_axis(q, axis=3, begin=nope,
+                                       end=nope + rope), "qrope",
+                        interleaved=True),
+                 c, rotate(k_r, "krope", interleaved=True), index)
+    gate = sym.Reshape(sym.sigmoid(fc(h, hq, "gate")),
+                       shape=(-1, seq_len, hq, 1))
+    att = sym.Reshape(sym.broadcast_mul(
+        sym.transpose(att, axes=(0, 2, 1, 3)), gate),
+        shape=(-1, seq_len, hq * geo["v_dim"]))
+    return _deepseek_v3_ffn(x + fc(att, d, "proj"), i, fc, seq_len, block)
+
+
+def _dots3_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
+    block = _dots3_sizes(num_layers, **sizes)
+    positions = sym.Reshape(sym._arange(start=0, stop=prefill_len),
+                            shape=(1, prefill_len))
+    length = sym.Variable("length")
+    cache = []      # the layers are built in order, so is this
+
+    def attend(i, q_nope, q_rope, c, k_r, index):
+        geo = block[block["layer_types"][i]]
+        hq, nope, v_dim, lat = (geo[k] for k in ("heads", "nope", "v_dim",
+                                                 "latent"))
+        row, operands = _materialised(i, q_nope, q_rope, c, k_r, prefill_len,
+                                      hq, nope, v_dim, lat)
+        cache.append(row)
+        if index is None:   # a band of the bucket
+            return sym.MultiHeadAttention(
+                causal=True, window=block["sliding_window"],
+                scale=geo["scale"], name="layer%d_att" % i, **operands)
+        q_i, k_i, w_i = index
+        hi, di = block["index_heads"], block["index_dim"]
+        # the index keys as their pool keeps them, and what the prompt's
+        # last real row selected (the lane's row of ``sparse_sel_<i>``)
+        last = lambda a, width: sym.Reshape(_last_real_row(
+            a, length), shape=(-1,) + width)
+        cache.extend([k_i, sym.SparseIndexSelect(
+            last(sym.Reshape(sym.transpose(q_i, axes=(0, 2, 1, 3)),
+                             shape=(-1, prefill_len, hi * di)), (hi, di)),
+            last(w_i, (hi,)), sym.Reshape(k_i, shape=(-1, prefill_len, di)),
+            length, topk=block["index_topk"], name="layer%d_sel" % i)])
+        return sym.MultiHeadAttention(
+            operands["query"], operands["key"], operands["value"], q_i, k_i,
+            w_i, causal=True, topk=block["index_topk"], scale=geo["scale"],
+            name="layer%d_att" % i)
+
+    logits, load = _deepseek_v3_stack(vocab_size, prefill_len, positions,
+                                      attend, block, layer=_dots3_layer,
+                                      length=length)
+    return sym.Group([logits] + cache + load)
+
+
+def _dots3_decode_symbol(vocab_size, num_layers, num_slots, page_size,
+                         token_out=True, **sizes):
+    block = _dots3_sizes(num_layers, **sizes)
+    pos_idx = sym.Variable("pos_idx")
+    write_slot = sym.Variable("write_slot")
+    write, read = _pool_step_inputs(pos_idx, num_slots, page_size, write_slot)
+    cache = []      # the layers are built in order, so is this
+
+    def attend(i, q_nope, q_rope, c, k_r, index):
+        geo = block[block["layer_types"][i]]
+        hq, nope, rope, v_dim, lat = (geo[k] for k in (
+            "heads", "nope", "rope", "v_dim", "latent"))
+        # one token a lane: its row of the latent cache is [c | k_r]
+        row = sym.Concat(sym.Reshape(c, shape=(-1, 1, lat)),
+                         sym.Reshape(k_r, shape=(-1, 1, rope)), dim=2)
+        if index is None:
+            # a window layer: the lane's own ring of latents, key (all of a
+            # row) and value (its first ``lat``), no frame and no table
+            ring, = sym.KVRingWrite(
+                sym.Variable("ring_c_%d" % i), row, pos_idx, write_slot,
+                num_rings=1, name="layer%d_cupd" % i)
+            cache.append(ring)
+            read_rows = lambda query: sym.KVRingAttention(
+                query, ring, ring, pos_idx, write_slot, scale=geo["scale"],
+                value_dim=lat, name="layer%d_att" % i)
+        else:
+            # a full layer: two pools on one page table, written by the same
+            # slot; the index keys of the lane's own context are scored, the
+            # chosen rows of the latent pool read and no others
+            q_i, k_i, w_i = index
+            hi, di = block["index_heads"], block["index_dim"]
+            pool, keys = write(i, {
+                "c": row, "i": sym.Reshape(k_i, shape=(-1, 1, di))})
+            chosen = sym.SparseIndexSelect(
+                sym.Reshape(q_i, shape=(-1, hi, di)),
+                sym.Reshape(w_i, shape=(-1, hi)), keys, read["page_table"],
+                pos_idx, write_slot, sym.Variable("sparse_sel_%d" % i),
+                topk=block["index_topk"], page_size=page_size,
+                name="layer%d_sel" % i)
+            cache.extend([pool, keys, chosen])
+            read_rows = lambda query: sym.KVPoolAttention(
+                query, pool, pool, read["mask"], read["page_table"], pos_idx,
+                write_slot, chosen, scale=geo["scale"], value_dim=lat,
+                page_size=page_size, selected=True, name="layer%d_att" % i)
+        return _absorbed(i, q_nope, q_rope, read_rows, hq, nope, rope, v_dim,
+                         lat)
+
+    logits, load = _deepseek_v3_stack(vocab_size, 1, pos_idx, attend, block,
+                                      layer=_dots3_layer)
+    # moe_load LAST: the cache and the token head keep their places
+    return sym.Group([_token_head(
+        logits, cache, "greedy_token" if token_out else None)] + load)
+
+
+def _dots3_param_shapes(vocab_size, num_layers, **sizes):
+    block = _dots3_sizes(num_layers, **sizes)
+    d, e, f = block["model_dim"], block["num_experts"], block["moe_ffn_dim"]
+    held = block["num_local_experts"] or e
+    hi, di = block["index_heads"], block["index_dim"]
+    shapes = {"embed_weight": (vocab_size, d), "final_ln_gamma": (d,),
+              "lm_head_weight": (vocab_size, d)}
+    for i, kind in enumerate(block["layer_types"]):
+        n, geo = "layer%d_" % i, block[kind]
+        hq, rank, lat, nope, rope, v_dim = (geo[k] for k in (
+            "heads", "q_rank", "latent", "nope", "rope", "v_dim"))
+        shapes.update({
+            n + "ln1_gamma": (d,), n + "ln2_gamma": (d,),
+            n + "qa_weight": (rank, d), n + "qnorm_gamma": (rank,),
+            n + "qb_weight": (hq * (nope + rope), rank),
+            n + "kva_weight": (lat + rope, d), n + "kvnorm_gamma": (lat,),
+            n + "kvb_weight": (hq * (nope + v_dim), lat),
+            n + "gate_weight": (hq, d), n + "proj_weight": (d, hq * v_dim)})
+        if kind == "full_attention":
+            shapes.update({
+                n + "iq_weight": (hi * di, rank), n + "ik_weight": (di, d),
+                n + "iknorm_gamma": (di,), n + "iknorm_beta": (di,),
+                n + "iw_weight": (hi, d)})
+        if i < block["first_dense_layers"]:
+            shapes.update({n + "mlp_in_weight": (2 * block["ffn_dim"], d),
+                           n + "mlp_out_weight": (d, block["ffn_dim"])})
+            continue
+        shared = block["num_shared_experts"] * f
+        shapes.update({
+            n + "router_weight": (e, d), n + "router_bias": (e,),
+            n + "experts_gate_weight": (held, d, f),
+            n + "experts_up_weight": (held, d, f),
+            n + "experts_down_weight": (held, f, d),
             n + "shared_in_weight": (2 * shared, d),
             n + "shared_out_weight": (d, shared)})
     return shapes
@@ -2187,6 +2494,21 @@ def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
                                    model_dim=model_dim, **sizes)
         return [("kv_c_%d" % i, "pool", (1, block["latent"] + block["rope"]))
                 for i in range(num_layers)]
+    if arch == "dots3_note":
+        block = _dots3_sizes(num_layers, num_heads=num_heads,
+                             model_dim=model_dim, **sizes)
+        row = lambda kind: block[kind]["latent"] + block[kind]["rope"]
+        per_kind = {
+            "full_attention": [
+                ("kv_c_%d", "pool", (1, row("full_attention"))),
+                ("kv_i_%d", "pool", (1, block["index_dim"])),
+                ("sparse_sel_%d", "row", (block["index_topk"],))],
+            "sliding_attention": [
+                ("ring_c_%d", "ring", (1, block["sliding_window"],
+                                       row("sliding_attention")))]}
+        return [(name % i, kind, shape)
+                for i, layer in enumerate(block["layer_types"])
+                for name, kind, shape in per_kind[layer]]
     if arch not in ("granite_hybrid", "lfm2_moe", "nemotron_h"):
         pool = (num_heads, head_dim or model_dim // num_heads)
         return [("kv_%s_%d" % (t, i), "pool", pool)
@@ -2243,11 +2565,13 @@ def param_shapes(arch, vocab_size, num_layers, num_heads, model_dim, ffn_dim,
         return _deepseek_v3_param_shapes(
             vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
             ffn_dim=ffn_dim, num_experts=num_experts, **kwargs)
-    if arch in ("lfm2_moe", "mimo_v2_flash", "phi4flash", "nemotron_h"):
+    if arch in ("lfm2_moe", "mimo_v2_flash", "phi4flash", "nemotron_h",
+                "dots3_note"):
         shapes = {"lfm2_moe": _lfm2_moe_param_shapes,
                   "mimo_v2_flash": _mimo_param_shapes,
                   "phi4flash": _phi4flash_param_shapes,
-                  "nemotron_h": _nemotron_h_param_shapes}[arch]
+                  "nemotron_h": _nemotron_h_param_shapes,
+                  "dots3_note": _dots3_param_shapes}[arch]
         return shapes(
             vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
             ffn_dim=ffn_dim, head_dim=head_dim, num_experts=num_experts,

@@ -23,7 +23,12 @@ _NEG = np.float32(-1e9)
 # trace-time dispatch counters, a form of ``attention_form`` each
 # (observability for tests and the multichip dryrun: proves the seq-parallel
 # path, or the kernel, actually engaged)
-DISPATCH_COUNTS = {"ring": 0, "kernel": 0, "band": 0, "dense": 0}
+DISPATCH_COUNTS = {"ring": 0, "kernel": 0, "band": 0, "dense": 0,
+                   "sparse": 0}
+
+# float32 scores a blockwise form (a ragged band, a learned sparse selection)
+# makes at once: the query blocks are sized from it
+_SCORE_BYTES = 512 << 20
 
 
 def _backend():
@@ -33,8 +38,21 @@ def _backend():
     return jax.default_backend()
 
 
+def _band_block(t, window):
+    """The block of a band over ``t`` positions under ``window``: a block of
+    b queries scores its own b keys and the b before them, so b is the
+    window where that divides ``t``, else one less (query r of a block then
+    reaches back to key r of the block before, exactly ``window - 1``
+    positions) where THAT divides ``t``; 0 where neither does."""
+    if not 0 < window < t:
+        return 0
+    if t % window == 0:
+        return window
+    return window - 1 if window > 1 and t % (window - 1) == 0 else 0
+
+
 def attention_form(query, key, value, causal, window=0, sink=False,
-                   mesh=None):
+                   mesh=None, topk=0):
     """THE rule that names the form ``MultiHeadAttention`` runs in, from the
     operands' shapes and types, the attributes, the mesh being traced under
     and the backend; no caller, option or environment variable does. Each
@@ -45,8 +63,13 @@ def attention_form(query, key, value, causal, window=0, sink=False,
     traced under a ``mesh`` with a ``seq`` axis that divides T:
     ``parallel/ring_attention.py``.
 
-    ``"band"``: a window of W < T positions, T a multiple of W: T x 2W scores
-    (``_band_attention``).
+    ``"sparse"``: ``topk`` > 0, a learned selection: every query attends the
+    ``topk`` causal keys its indexer scores highest (all of them while it has
+    no more), in query blocks, so neither the index logits nor the scores of
+    all T x S pairs are made whole (``_sparse_attention``).
+
+    ``"band"``: a window of W < T positions, T a multiple of W or of W - 1
+    (``_band_block``): T x 2W scores (``_band_attention``).
 
     ``"kernel"``: plain CAUSAL attention over S >= T keys on the chip, where
     ``pallas_attention.takes`` the operands: blockwise with an online softmax,
@@ -65,6 +88,8 @@ def attention_form(query, key, value, causal, window=0, sink=False,
     decided)."""
     b, h, t, _ = query.shape
     hkv, s = key.shape[1], key.shape[2]
+    if topk > 0:
+        return "sparse"
     plain = window <= 0 and not sink
     if plain and h == hkv and mesh is not None \
             and "seq" in mesh.axis_names and mesh.shape["seq"] > 1 \
@@ -72,7 +97,7 @@ def attention_form(query, key, value, causal, window=0, sink=False,
             and ("data" not in mesh.axis_names
                  or b % mesh.shape["data"] == 0):
         return "ring"
-    if 0 < window < t and t % window == 0:
+    if _band_block(t, window):
         return "band"
     if plain and causal and _backend() == "tpu" \
             and (mesh is None or mesh.size == 1):
@@ -98,13 +123,16 @@ def _multi_head_attention_out(attrs, inputs):
         "scale": AttrSpec("float", default=-1.0),
         "window": AttrSpec("int", default=0),
         "sink": AttrSpec("bool", default=False),
+        "topk": AttrSpec("int", default=0),
     },
     input_names=lambda attrs: ("query", "key", "value") + (
-        ("sink",) if attrs.get("sink") else ()),
+        ("sink",) if attrs.get("sink") else ()) + (
+        ("index_query", "index_key", "index_weight")
+        if attrs.get("topk", 0) > 0 else ()),
     aliases=("MultiHeadAttention",),
     infer=_multi_head_attention_out,
 )
-def _multi_head_attention(attrs, query, key, value, sink=None):
+def _multi_head_attention(attrs, query, key, value, *more):
     """softmax(QKᵀ·scale + mask)V over (B, H, T, D) tensors, in the form
     ``attention_form`` names from the shapes, the attributes and the backend.
     The dense forms compute in fp32 for a stable softmax regardless of the IO
@@ -137,9 +165,22 @@ def _multi_head_attention(attrs, query, key, value, sink=None):
     positions, T a multiple of W, scores a BAND: a block of W queries
     against its own and the previous block of keys, T x 2W scores instead
     of T x T, the same positions under the same mask (the blocks are read
-    from the shapes; a shorter or ragged call masks the full scores)."""
+    from the shapes; a shorter or ragged call masks the full scores). Where
+    T is a multiple of W - 1 and not of W the blocks are W - 1 wide
+    (``_band_block``), and a band whose scores pass ``_SCORE_BYTES`` is
+    computed a run of blocks at a time.
+
+    ``topk`` = K > 0 (causal self-attention only) takes three more inputs, an
+    INDEXER's: ``index_query`` (B, Hi, T, di), ``index_key`` (B, 1, T, di)
+    and ``index_weight`` (B, T, Hi). Query t scores key s <= t
+    ``I[t, s] = sum_j index_weight[t, j] * relu(index_query[t, j] .
+    index_key[s])`` (products in the operands' type with a float32
+    accumulator, the rest float32) and attends the K keys of largest I
+    alone, all of them while t < K (``_sparse_attention``)."""
     import os
 
+    sink = more[0] if attrs.get("sink") else None
+    topk = attrs.get("topk", 0)
     b, h, t, d = query.shape
     hkv, s_len = key.shape[1], key.shape[2]
     g = _kv_groups(h, hkv, "MultiHeadAttention")
@@ -151,8 +192,14 @@ def _multi_head_attention(attrs, query, key, value, sink=None):
     from ..parallel.mesh import current_trace_mesh
 
     mesh = current_trace_mesh()
+    if topk > 0 and not (attrs["causal"] and s_len == t and window <= 0
+                         and sink is None):
+        raise MXNetError("MultiHeadAttention: topk needs plain causal "
+                         "self-attention (no window, no sink), got causal=%s "
+                         "over %d queries and %d keys"
+                         % (attrs["causal"], t, s_len))
     form = attention_form(query, key, value, attrs["causal"], window,
-                          sink is not None, mesh)
+                          sink is not None, mesh, topk)
     if form == "ring" and os.environ.get("MXNET_RING_ATTENTION", "1") != "1":
         form = "dense"
     DISPATCH_COUNTS[form] += 1
@@ -175,12 +222,15 @@ def _multi_head_attention(attrs, query, key, value, sink=None):
             query, key, value, causal=True, scale=max(attrs["scale"], 0.0),
             interpret=_backend() != "tpu")
     scale = attrs["scale"] if attrs["scale"] > 0 else 1.0 / np.sqrt(d)
+    if form == "sparse":
+        out = _sparse_attention(query.reshape(b, hkv, g, t, d), key, value,
+                                *more[-3:], topk, scale)
+        return out.reshape(b, h, t, value.shape[-1]).astype(query.dtype)
     q = query.astype("float32").reshape(b, hkv, g, t, d)
     if sink is not None:
         sink = sink.astype("float32").reshape(hkv, g)
     if form == "band":
-        out = _band_attention(q, key.astype("float32"),
-                              value.astype("float32"), window, scale, sink)
+        out = _band_attention(q, key, value, window, scale, sink)
         return out.reshape(b, h, t, value.shape[-1]).astype(query.dtype)
     s = jnp.einsum("bkgqd,bkud->bkgqu", q, key.astype("float32")) * scale
     if attrs["causal"]:
@@ -206,33 +256,149 @@ def _sink_softmax(s, sink):
     return e / (jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(sink - m))
 
 
+def _blocks_of(n, whole_bytes):
+    """The largest divisor of ``n`` blocks to take at once so that the
+    float32 scores made at once, ``whole_bytes`` for all ``n``, stay inside
+    ``_SCORE_BYTES`` (1 where one block alone is past it)."""
+    return next((c for c in range(n, 0, -1) if n % c == 0
+                 and whole_bytes * c <= _SCORE_BYTES * n), 1)
+
+
 def _band_attention(q, k, v, w, scale, sink):
     """Causal attention under a window of ``w`` positions as a band: ``q``
-    (B, Hkv, G, T, d) in T / w blocks of w queries, each against its own
-    block of keys and the one before it, so the scores are T x 2w, float32.
-    Query r of a block sits w + r - j positions after key j of its 2w; it
-    attends where that is in [0, w). The first block has no block before it:
-    the zeros that stand there are masked as the future is."""
+    (B, Hkv, G, T, d) float32 in T / b blocks of b queries (``_band_block``:
+    b is w, or w - 1), each against its own block of keys and the one before
+    it, so the scores are T x 2b, float32; ``k`` and ``v`` (B, Hkv, T, x)
+    in any type, computed in float32. Query r of a block sits b + r - j
+    positions after key j of its 2b; it attends where that is in [0, w).
+    The first block has no block before it: the zeros that stand there are
+    masked as the future is. A band whose scores pass ``_SCORE_BYTES`` is
+    computed a run of blocks at a time (``lax.map``), each run with the
+    block before its first."""
     b, hkv, g, t, d = q.shape
-    nb = t // w
-
-    def banded(a):      # (B, Hkv, T, x) -> (B, Hkv, nb, 2w, x)
-        blocks = a.reshape(b, hkv, nb, w, a.shape[-1])
-        before = jnp.concatenate(
-            [jnp.zeros_like(blocks[:, :, :1]), blocks[:, :, :-1]], axis=2)
-        return jnp.concatenate([before, blocks], axis=3)
-
-    s = jnp.einsum("bkgnqd,bknud->bkgnqu", q.reshape(b, hkv, g, nb, w, d),
-                   banded(k)) * scale
-    ahead = w + jnp.arange(w)[:, None] - jnp.arange(2 * w)[None, :]
+    blk = _band_block(t, w)
+    nb = t // blk
+    ahead = blk + jnp.arange(blk)[:, None] - jnp.arange(2 * blk)[None, :]
     live = (ahead >= 0) & (ahead < w)
-    first = live & (jnp.arange(2 * w)[None, :] >= w)
-    mask = jnp.where(jnp.arange(nb)[:, None, None] == 0, first, live)
-    s = jnp.where(mask, s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1) if sink is None \
-        else _sink_softmax(s, sink[None, :, :, None, None, None])
-    out = jnp.einsum("bkgnqu,bknud->bkgnqd", p, banded(v))
-    return out.reshape(b, hkv, g, t, v.shape[-1])
+    first = live & (jnp.arange(2 * blk)[None, :] >= blk)
+
+    def run(q, k, v, before_k, before_v, at):
+        """``n`` blocks from block ``at`` on: q (B, Hkv, G, n, b, d), k and v
+        (B, Hkv, n, b, x) float32, ``before_*`` the block before the first
+        (B, Hkv, 1, b, x)."""
+        banded = lambda a, before: jnp.concatenate([jnp.concatenate(
+            [before, a[:, :, :-1]], axis=2), a], axis=3)
+        s = jnp.einsum("bkgnqd,bknud->bkgnqu", q, banded(k, before_k)) * scale
+        mask = jnp.where((at + jnp.arange(q.shape[3]))[:, None, None] == 0,
+                         first, live)
+        s = jnp.where(mask, s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1) if sink is None \
+            else _sink_softmax(s, sink[None, :, :, None, None, None])
+        return jnp.einsum("bkgnqu,bknud->bkgnqd", p, banded(v, before_v))
+
+    blocks = lambda a: a.reshape(a.shape[:-2] + (nb, blk, a.shape[-1]))
+    n = _blocks_of(nb, 4 * b * hkv * g * t * 2 * blk)
+    f32 = lambda a: a.astype(jnp.float32)
+    if n == nb:     # the whole band at once, keys and values float32 whole
+        kb, vb = blocks(f32(k)), blocks(f32(v))
+        out = run(blocks(q), kb, vb, jnp.zeros_like(kb[:, :, :1]),
+                  jnp.zeros_like(vb[:, :, :1]), 0)
+        return out.reshape(b, hkv, g, t, v.shape[-1])
+    qb, kb, vb = blocks(q), blocks(k), blocks(v)
+    # the block before a run's first: block 0's is zeros
+    shifted = lambda a: jnp.concatenate(
+        [jnp.zeros_like(a[:, :, :1]), a], axis=2)
+    ks, vs = shifted(kb), shifted(vb)
+
+    def one(at):
+        cut = lambda a, axis, start, size: jax.lax.dynamic_slice_in_dim(
+            a, start, size, axis)
+        k_run, v_run = (f32(cut(a, 2, at, n + 1)) for a in (ks, vs))
+        return run(cut(qb, 3, at, n), k_run[:, :, 1:], v_run[:, :, 1:],
+                   k_run[:, :, :1], v_run[:, :, :1], at)
+
+    # (runs, B, Hkv, G, n, b, dv) -> (B, Hkv, G, T, dv)
+    out = jax.lax.map(one, jnp.arange(0, nb, n))
+    return jnp.moveaxis(out, 0, 3).reshape(b, hkv, g, t, v.shape[-1])
+
+
+def index_scores(index_query, index_weight, index_key):
+    """A sparse-attention INDEXER's scores: ``index_query`` (..., Q, Hi, di),
+    ``index_weight`` (..., Q, Hi) and ``index_key`` (..., S, di) give
+    ``I[q, s] = sum_j w[q, j] * relu(q[q, j] . k[s])`` (..., Q, S) float32:
+    the products in the operands' type with a float32 accumulator, the
+    ReLU, the weights and the sum over the indexer's heads float32."""
+    logits = jnp.einsum("...qhd,...sd->...qhs", index_query, index_key,
+                        preferred_element_type=jnp.float32)
+    return jnp.einsum("...qhs,...qh->...qs", jax.nn.relu(logits),
+                      index_weight.astype(jnp.float32))
+
+
+def _topk_mask(score, topk):
+    """``jax.lax.top_k``'s set over the last axis of ``score`` (..., S) as a
+    mask: what lies above its ``topk``-th value, and of the values that tie
+    there the positions up to the last one it took (the lower ones)."""
+    values, at = jax.lax.top_k(score, topk)
+    kth = values[..., -1:]
+    last = jnp.max(jnp.where(values == kth, at, -1), axis=-1, keepdims=True)
+    return (score > kth) | ((score == kth) & (
+        jnp.arange(score.shape[-1], dtype=at.dtype) <= last))
+
+
+def _sparse_attention(q, k, v, index_query, index_key, index_weight, topk,
+                      scale):
+    """Causal attention over a learned selection: ``q`` (B, Hkv, G, T, d),
+    ``k`` (B, Hkv, T, d), ``v`` (B, Hkv, T, dv), the indexer's
+    ``index_query`` (B, Hi, T, di), ``index_key`` (B, 1, T, di) and
+    ``index_weight`` (B, T, Hi). Query t attends the ``topk`` keys s <= t of
+    largest ``index_scores`` (``jax.lax.top_k``'s set: of keys that tie at
+    the last place the lower positions; ``_topk_mask``), every key while
+    t < ``topk``. Both products run in the operands' type with a float32
+    accumulator, the softmax's maximum, exponentials and sum float32, the
+    exponentials rounded to the values' type for the second product and the
+    context divided by their sum after it (the kernel's arithmetic).
+
+    The selection is a MASK over the scores of a block of queries: the
+    blocks are sized so that a block's float32 scores stay inside
+    ``_SCORE_BYTES``, and run in up to eight groups, each over the keys up to
+    its own last query (seven sixteenths of the pairs above the diagonal are
+    never scored), one after another (``lax.map``)."""
+    b, hkv, g, t, d = q.shape
+    iq = index_query.transpose(0, 2, 1, 3)              # (B, T, Hi, di)
+    ik = index_key[:, 0]
+    rows = max(1, _SCORE_BYTES // (4 * b * hkv * g * t))
+    blk = next(c for c in range(min(rows, t), 0, -1) if t % c == 0)
+    nb = t // blk
+    groups = next(c for c in (8, 4, 2, 1) if nb % c == 0)
+
+    def group(first, upto):
+        """Query blocks ``first`` .. over keys 0 .. ``upto`` - 1."""
+        keys, values, ikeys = k[:, :, :upto], v[:, :, :upto], ik[:, :upto]
+        at_key = jnp.arange(upto, dtype=jnp.int32)[None, :]
+
+        def one(block):
+            t0 = block * blk
+            cut = lambda a, axis: jax.lax.dynamic_slice_in_dim(a, t0, blk,
+                                                               axis)
+            seen = (at_key <= t0 + jnp.arange(
+                blk, dtype=jnp.int32)[:, None])[None]
+            if upto > topk:
+                seen = seen & _topk_mask(jnp.where(seen, index_scores(
+                    cut(iq, 1), cut(index_weight, 1), ikeys), -jnp.inf), topk)
+            s = jnp.einsum("bkgqd,bksd->bkgqs", cut(q, 3), keys,
+                           preferred_element_type=jnp.float32) * scale
+            s = jnp.where(seen[:, None, None], s, -jnp.inf)
+            e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+            out = jnp.einsum("bkgqs,bksd->bkgqd", e.astype(values.dtype),
+                             values, preferred_element_type=jnp.float32)
+            return out / jnp.sum(e, axis=-1, keepdims=True)
+
+        return jax.lax.map(one, first + jnp.arange(nb // groups))
+
+    out = jnp.concatenate([group(j * (nb // groups), (j + 1) * (t // groups))
+                           for j in range(groups)], axis=0)
+    # (blocks, B, Hkv, G, blk, dv) -> (B, Hkv, G, T, dv)
+    return jnp.moveaxis(out, 0, 3).reshape(b, hkv, g, t, v.shape[-1])
 
 
 def _kv_groups(heads, kv_heads, what):
@@ -502,7 +668,8 @@ def pool_read_bytes(query, pool_k, pool_v, page_table, page_size):
     return whole, own
 
 
-def pool_read_form(query, pool_k, pool_v, page_table, page_size):
+def pool_read_form(query, pool_k, pool_v, page_table, page_size,
+                   selected=None):
     """THE rule that names the form of ``KVPoolAttention``'s read, from the
     operands' shapes and types and the backend; no caller, option or
     environment variable does. Each operand carries ``.shape`` and
@@ -525,7 +692,14 @@ def pool_read_form(query, pool_k, pool_v, page_table, page_size):
 
     ``"kernel"``: ``pallas_paged_read.paged_read`` copies a row's live pages,
     up to its own context, out of page-major pools: two pools, on the
-    chip."""
+    chip.
+
+    ``"selected"``: the read was handed ``selected`` (R, K), the positions of
+    a row's own context an indexer chose (``SparseIndexSelect``): XLA gathers
+    those K rows of the pool through the row's table and scores them alone,
+    whatever the pool's layout."""
+    if selected is not None:
+        return "selected"
     if page_table is None or page_size < 1:
         return "whole_pool"
     pools = (pool_k,) if pool_v is None else (pool_k, pool_v)
@@ -585,6 +759,18 @@ def _own_pages(pool, table, page, hkv):
     return own.reshape(hkv, rows, max_pages * page, d), "krud"
 
 
+def _selected_rows(pool, slots, hkv):
+    """``pool`` at ``slots`` (R, K), a row's chosen slots, and that operand's
+    einsum subscript over (heads k, rows r, chosen u, width d): (Hkv, R, K,
+    d) of a head-major pool, (R, K, Hkv, d) of a page-major one. The slots
+    are in bounds (a row's own frames), so nothing is clipped or filled."""
+    if _paged(pool):
+        own = pool.reshape(-1, pool.shape[2]).at[slots].get(
+            mode="promise_in_bounds")
+        return own.reshape(slots.shape + (hkv, -1)), "rukd"
+    return pool.at[:, slots].get(mode="promise_in_bounds"), "krud"
+
+
 def _pool_heads(query, pool_k):
     """The key/value heads of ``pool_k`` (either layout) under ``query``
     (R, H, dk)."""
@@ -607,15 +793,17 @@ def _kv_pool_attention_out(attrs, inputs):
     "_contrib_KVPoolAttention",
     attrs={"scale": AttrSpec("float", default=-1.0),
            "value_dim": AttrSpec("int", default=0),
-           "page_size": AttrSpec("int", default=0)},
+           "page_size": AttrSpec("int", default=0),
+           "selected": AttrSpec("bool", default=False)},
     input_names=lambda attrs: ("query", "pool_k", "pool_v", "mask") + (
         ("page_table", "pos_idx", "write_slot")
-        if attrs.get("page_size", 0) > 0 else ()),
+        if attrs.get("page_size", 0) > 0 else ()) + (
+        ("selected",) if attrs.get("selected") else ()),
     aliases=("KVPoolAttention",),
     infer=_kv_pool_attention_out,
 )
 def _kv_pool_attention(attrs, query, pool_k, pool_v, mask, page_table=None,
-                       pos_idx=None, write_slot=None):
+                       pos_idx=None, write_slot=None, selected=None):
     """The read of the shared KV pool: every row of ``query`` (R, H, dh)
     attends ``pool_k`` / ``pool_v`` (H, S, dh) under its own additive
     ``mask`` (R, S): ``softmax(einsum('rhd,hsd->rhs') * scale + mask)`` then
@@ -651,7 +839,13 @@ def _kv_pool_attention(attrs, query, pool_k, pool_v, mask, page_table=None,
     float32 sum (a row with NO context is finite in every form and nobody's
     to read: the mean of whatever the slots hold, or zeros from the kernel).
     ``pool_read_form`` chooses, from the shapes and types of the operands and
-    the backend; no caller does."""
+    the backend; no caller does.
+
+    ``selected=True`` (with ``page_size``) takes one more input ``selected``
+    (R, K): positions of the row's own context, -1 where it has fewer than K
+    (``SparseIndexSelect``). The row attends THOSE rows of the pool and no
+    others: position p is slot ``table[p // page] * page + p % page``, the K
+    rows are gathered and scored under a mask of the -1s."""
     scale = attrs["scale"] if attrs["scale"] > 0 \
         else 1.0 / np.sqrt(query.shape[-1])
     r, h, dh = query.shape
@@ -660,8 +854,23 @@ def _kv_pool_attention(attrs, query, pool_k, pool_v, mask, page_table=None,
     page = attrs.get("page_size", 0)
     shared = pool_v is pool_k
     form = pool_read_form(query, pool_k, None if shared else pool_v,
-                          page_table, page)
-    if form == "kernel":
+                          page_table, page, selected)
+    if form == "selected":
+        at = selected.astype(jnp.int32)
+        chosen = jnp.maximum(at, 0)
+        slots = jnp.take_along_axis(page_table.astype(jnp.int32),
+                                    chosen // page, axis=1) * page \
+            + chosen % page
+        own_k, sub_k = _selected_rows(pool_k, slots, hkv)
+        own_v, sub_v = (own_k, sub_k) if shared \
+            else _selected_rows(pool_v, slots, hkv)
+        s = jnp.einsum("rkgd,%s->rkgu" % sub_k, q, own_k,
+                       preferred_element_type=jnp.float32)
+        out = _pool_softmax_context(
+            s, scale, jnp.where(at >= 0, jnp.float32(0),
+                                _NEG)[:, None, None, :],
+            own_v, "rkgu,%s->rkgd" % sub_v)
+    elif form == "kernel":
         from .pallas_paged_read import paged_read
 
         # off the chip (a test that holds the rule to the kernel) Pallas
@@ -774,7 +983,8 @@ def _kv_ring_write(attrs, *inputs):
 @register(
     "_contrib_KVRingAttention",
     attrs={"scale": AttrSpec("float", default=-1.0),
-           "sink": AttrSpec("bool", default=False)},
+           "sink": AttrSpec("bool", default=False),
+           "value_dim": AttrSpec("int", default=0)},
     input_names=lambda attrs: ("query", "ring_k", "ring_v", "pos_idx",
                                "write_slot") + (
         ("sink",) if attrs.get("sink") else ()),
@@ -793,7 +1003,11 @@ def _kv_ring_attention(attrs, query, ring_k, ring_v, pos_idx, write_slot,
     ``write_slot`` is negative. ``sink=True`` takes ``sink`` (H,), one logit
     a query head in the softmax's denominator (``MultiHeadAttention`` says
     how). Contractions, accumulator, softmax and grouped heads as
-    ``KVPoolAttention``'s; the output is (R, H, dv)."""
+    ``KVPoolAttention``'s; the output is (R, H, dv). ``value_dim`` > 0 takes
+    the value from the first ``value_dim`` columns of ``ring_v``, which may
+    then BE ``ring_k`` (a window over LATENTS: one ring a layer whose row
+    [c | k_r] is the key whole and the value in its first columns), cut
+    after the contraction as ``KVPoolAttention`` cuts it."""
     scale = attrs["scale"] if attrs["scale"] > 0 \
         else 1.0 / np.sqrt(query.shape[-1])
     r, h, dk = query.shape
@@ -808,4 +1022,51 @@ def _kv_ring_attention(attrs, query, ring_k, ring_v, pos_idx, write_slot,
         s, sink.astype(jnp.float32).reshape(1, hkv, g, 1))
     out = jnp.einsum("rkgw,rkwd->rkgd", p, ring_v,
                      preferred_element_type=jnp.float32)
+    if attrs.get("value_dim", 0) > 0:
+        out = out[..., :attrs["value_dim"]]
     return out.reshape(r, h, out.shape[-1]).astype(query.dtype)
+
+
+@register(
+    "_contrib_SparseIndexSelect",
+    attrs={"topk": AttrSpec("int", required=True),
+           "page_size": AttrSpec("int", default=0)},
+    input_names=lambda attrs: ("index_query", "index_weight") + (
+        ("pool", "page_table", "pos_idx", "write_slot", "kept")
+        if attrs.get("page_size", 0) > 0 else ("index_key", "length")),
+    aliases=("SparseIndexSelect",),
+)
+def _sparse_index_select(attrs, index_query, index_weight, keys, *more):
+    """The selection of learned sparse attention for ONE query a row: row
+    r's ``index_query`` (R, Hi, di) and ``index_weight`` (R, Hi) score the
+    index keys of the row's own context (``index_scores``), and the
+    positions of the ``topk`` largest come back, (R, topk) float32 in no
+    order, -1 where the context holds fewer: all of it, then.
+
+    ``page_size`` > 0, a decode step: ``pool`` is the layer's pool of index
+    keys (one head, either layout), ``page_table`` (R, max_pages),
+    ``pos_idx`` and ``write_slot`` (R, 1) as ``KVPoolAttention`` takes
+    them; a row's keys are the slots of its own pages in order (slot u of
+    them IS position u), the first ``pos + 1`` live; a row whose write slot
+    is negative (a lane that rides along) selects nothing and gets back
+    ``kept`` (R, topk), what it was handed. Otherwise, a prefill's last row:
+    ``index_key`` (R, T, di) and ``length`` (R, 1), the first ``length``
+    keys live."""
+    topk, page = attrs["topk"], attrs.get("page_size", 0)
+    kept = None
+    if page > 0:
+        table, pos_idx, write_slot, kept = more
+        keys = _own_pages(keys, table.astype(jnp.int32), page, 1)[0]
+        keys = keys.reshape(table.shape[0], table.shape[1] * page, -1)
+        count = _context_slots(pos_idx, write_slot)
+    else:
+        count = more[0].reshape(-1).astype(jnp.int32)
+    live = jnp.arange(keys.shape[1], dtype=jnp.int32)[None, :] \
+        < count[:, None]
+    score = jnp.where(live, index_scores(
+        index_query[:, None], index_weight[:, None], keys)[:, 0], -jnp.inf)
+    k = min(topk, keys.shape[1])
+    best, at = jax.lax.top_k(score, k)
+    at = jnp.pad(jnp.where(best > -jnp.inf, at, -1).astype(jnp.float32),
+                 ((0, 0), (0, topk - k)), constant_values=-1.0)
+    return at if kept is None else jnp.where(count[:, None] > 0, at, kept)

@@ -307,18 +307,22 @@ class Attention(Pattern):
         if node.op not in self._OPS or id(node) in ctx.claimed:
             return None
         a = node.parsed_attrs()
-        if a.get("window") or a.get("sink"):
-            return None     # the candidates know neither: the op's own path
+        if a.get("window") or a.get("sink") or a.get("topk"):
+            return None     # the candidates know none: the op's own path
         return Match(node, [], {"causal": bool(a.get("causal")),
                                 "scale": float(a.get("scale", -1.0))})
 
     def reject_reason(self, node, ctx):
-        # every attention node roots a match but a windowed or sunk one
+        # every attention node roots a match but a windowed, sunk or
+        # sparse one
         if node.op in self._OPS:
             a = node.parsed_attrs()
             if a.get("window") or a.get("sink"):
                 return ("a window or a sink in the softmax: no candidate "
                         "lowering computes either, the operator's band does")
+            if a.get("topk"):
+                return ("a learned selection: no candidate lowering takes an "
+                        "indexer, the operator's query blocks do")
         return None
 
     def externals(self, meta, ins, resolve):

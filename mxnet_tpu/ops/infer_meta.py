@@ -179,7 +179,9 @@ register_meta("_contrib_RMSNorm", input_ranks={"data": (1, None), "gamma": 1},
               dtype_policy="first", param_slots=("gamma",),
               shard_rule="elementwise", aliases=("RMSNorm",))
 register_meta("_contrib_MultiHeadAttention",
-              input_ranks={"query": 4, "key": 4, "value": 4, "sink": 1},
+              input_ranks={"query": 4, "key": 4, "value": 4, "sink": 1,
+                           "index_query": 4, "index_key": 4,
+                           "index_weight": 3},
               param_slots=("sink",), aliases=("MultiHeadAttention",))
 register_meta("_contrib_RotaryEmbedding",
               input_ranks={"data": 4, "positions": 2}, dtype_policy="first",
@@ -193,8 +195,14 @@ register_meta("_contrib_KVPoolSlotWrite",
               dtype_policy="first", aliases=("KVPoolSlotWrite",))
 register_meta("_contrib_KVPoolAttention",
               input_ranks={"query": 3, "pool_k": 3, "pool_v": 3, "mask": 2,
-                           "page_table": 2, "pos_idx": 2, "write_slot": 2},
+                           "page_table": 2, "pos_idx": 2, "write_slot": 2,
+                           "selected": 2},
               dtype_policy="first", aliases=("KVPoolAttention",))
+register_meta("_contrib_SparseIndexSelect",
+              input_ranks={"index_query": 3, "index_weight": 2, "pool": 3,
+                           "page_table": 2, "pos_idx": 2, "write_slot": 2,
+                           "kept": 2, "index_key": 3, "length": 2},
+              aliases=("SparseIndexSelect",))
 register_meta("_contrib_KVRingWrite",
               input_ranks={"ring_0": 4, "rows_0": 3, "ring_1": 4, "rows_1": 3,
                            "pos_idx": 2, "write_slot": 2},

@@ -169,8 +169,9 @@ def _kv_pool_attention(attrs, shapes):
 @rule("_contrib_MultiHeadAttention")
 @rule("MultiHeadAttention")
 def _multi_head_attention(attrs, shapes):
-    # (B, H, T, d) x 3, then with sink=True one logit a query head
-    if len(shapes) > 3 and shapes[3] is None and shapes[0] is not None:
+    # (B, H, T, d) x 3, then with sink=True one logit a query head (an
+    # indexer's three operands behind it are computed, never bound)
+    if attrs.get("sink") and shapes[3] is None and shapes[0] is not None:
         shapes[3] = (shapes[0][1],)
     return shapes
 

@@ -325,8 +325,8 @@ def _pool_reads(cache, input_shapes):
     read by more nodes than write it (``phi4flash``: eight layers, one
     write), and is then listed once a reader: its bytes are moved once a
     read. The form is the operator's own rule (``pool_read_form``).
-    ``slots``: what an XLA form scores a dispatch (the rows' tables whole, or
-    the pool for every row); for the kernel, whose fetch follows the rows'
+    ``slots``: what an XLA form scores a dispatch (the rows' tables whole, the
+    pool for every row, or the rows an indexer selected); for the kernel, whose fetch follows the rows'
     contexts, the slots of ONE block (``serving.step_kernel_slots`` rounds
     each stepped lane's context up to it)."""
     from ..ops.attention import pool_read_form, pool_slots
@@ -339,9 +339,11 @@ def _pool_reads(cache, input_shapes):
         table, k, v = ops.get("page_table"), ops["pool_k"], ops["pool_v"]
         form = pool_read_form(
             ops["query"], k, None if n.inputs[1] == n.inputs[2] else v,
-            table, page)
+            table, page, ops.get("selected"))
         rows = ops["query"].shape[0]
-        if form == "kernel":
+        if form == "selected":
+            slots = rows * ops["selected"].shape[1]
+        elif form == "kernel":
             from ..ops.pallas_paged_read import block_slots
 
             slots = block_slots(k, v, table.shape[1])
@@ -392,7 +394,8 @@ def _attention_forms(cache, input_shapes):
         attrs = n.parsed_attrs()
         forms.append(attention_form(
             ops["query"], ops["key"], ops["value"], attrs["causal"],
-            attrs.get("window", 0), bool(attrs.get("sink"))))
+            attrs.get("window", 0), bool(attrs.get("sink")),
+            topk=attrs.get("topk", 0)))
     return forms
 
 
@@ -1126,6 +1129,33 @@ class PagedKVDecoder:
     EXPERT block: the ``serving.moe.*`` counters and the held experts'
     slice read it as they read every other arch's. It refuses what
     ``granite_hybrid`` refuses.
+
+    ``arch="dots3_note"`` serves latent attention with a query-side low rank
+    in two geometries, chosen by ``layer_types`` (``"full_attention"`` |
+    ``"sliding_attention"``; sizes ``q_lora_rank``, ``kv_lora_rank``,
+    ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim`` and their
+    ``swa_`` twins with ``swa_num_heads``, two rotary bases,
+    ``sliding_window``, ``index_n_heads``, ``index_head_dim``,
+    ``index_topk``, ``lora_rescale``, and ``deepseek_v3``'s experts with a
+    held share). THREE kinds of cache side by side. A full layer keeps TWO
+    pools on the ONE page table, written by the same slot: ``kv_c_<i>``, a
+    token's [c | k_r], and ``kv_i_<i>``, its rotated index key; a step
+    scores the index keys of a lane's own pages, takes the ``index_topk``
+    best (``SparseIndexSelect``) and reads THOSE rows of the latent pool and
+    no others (``KVPoolAttention(selected=True)``), absorbed. A window layer
+    keeps ONE ring a lane, ``ring_c_<i>`` (1, ``sliding_window``, latent +
+    rope): the row is key (whole) and value (its first columns), read
+    absorbed (``KVRingAttention(value_dim=)``). And a full layer keeps a
+    per-lane float32 row ``sparse_sel_<i>`` (``index_topk``,): the positions
+    the lane's LAST token selected (-1 past a shorter context), written by
+    the admission for the prompt's last real row and by every step, never
+    read by the model: an operator-facing debug output that ``lane_state``
+    shows (which tokens of a long context a lane's answer is reading). The
+    admission's prefill is
+    materialised; a full layer's selection is a mask over query blocks
+    (``MultiHeadAttention(topk=)``), a window layer's scores a band. Counters
+    ``serving.sparse.*`` (docs/OBSERVABILITY.md). It refuses what
+    ``mimo_v2_flash`` refuses.
     """
 
     def __init__(self, arg_params: Dict[str, object], vocab_size,
@@ -1219,6 +1249,13 @@ class PagedKVDecoder:
                             if kind == "ring"]
         self._window = max((shape[1] for _, kind, shape in self._cache
                             if kind == "ring"), default=0)
+        # layers whose read is a learned selection keep what a lane's last
+        # token selected (``sparse_sel_<i>``, a row of ``index_topk``
+        # positions): how many, and how many positions each selects
+        chosen = [shape[0] for name, _, shape in self._cache
+                  if name.startswith("sparse_sel_")]
+        self._sparse_layers, self._sparse_topk = len(chosen), max(
+            chosen, default=0)
         first = int(arch_sizes.get("local_expert_offset") or 0)
         held = int(arch_sizes.get("num_local_experts") or 0)
         self._held_experts = slice(first, first + held if held else None)
@@ -1354,7 +1391,7 @@ class PagedKVDecoder:
             by_form = lambda form: [n for _, _, f, n in reads if f == form]
             _tm.gauge("serving.shared_pool_readers").set(
                 self._shared_readers)
-            for form in ("kernel", "own_pages", "whole_pool"):
+            for form in ("kernel", "own_pages", "whole_pool", "selected"):
                 _tm.gauge("serving.pool_read.%s_layers" % form).set(
                     len(by_form(form)))
             # a layer's: the mean over the program's reads of a kind, which
@@ -1377,7 +1414,7 @@ class PagedKVDecoder:
             if "prefill" in programs:
                 # the prefill's attention layers by the form the rule names
                 forms = _attention_forms(*programs["prefill"])
-                for form in ("kernel", "dense", "band"):
+                for form in ("kernel", "dense", "band", "sparse"):
                     _tm.gauge("serving.prefill_attention.%s_layers"
                               % form).set(forms.count(form))
             _tm.gauge("serving.state_bytes").set(sum(
@@ -1388,6 +1425,9 @@ class PagedKVDecoder:
             latent = [n for n in self._pool_names if n.startswith("kv_c_")]
             if latent:  # one pool a layer: a token's latent, not its heads
                 _tm.gauge("serving.latent_pool_bytes").set(held(latent))
+            index = [n for n in self._pool_names if n.startswith("kv_i_")]
+            if index:   # beside it, on the same page table: its index keys
+                _tm.gauge("serving.index_pool_bytes").set(held(index))
             if self._ring_names:  # two kinds of attention cache, side by side
                 _tm.gauge("serving.full_pool_bytes").set(
                     held(self._pool_names))
@@ -1608,6 +1648,11 @@ class PagedKVDecoder:
                 logits = np.asarray(row)[0]
         if record is not None:
             _tm.counter("serving.admit_head_rows").inc(self._head_rows)
+            if self._sparse_layers:
+                # causal (query, key) pairs of the prompt's real tokens the
+                # indexers scored, a sparse layer
+                _tm.counter("serving.sparse.admit_scored_pairs").inc(
+                    self._sparse_layers * L * (L + 1) // 2)
             if self._shared_readers:
                 # the bucket's rows either half of the depth computed
                 _tm.counter("serving.admit_self_rows").inc(self.prefill_len)
@@ -2005,6 +2050,16 @@ class PagedKVDecoder:
                         _tm.counter("serving.step_window_slots").inc(sum(
                             min(lane.pos, self._window)
                             for _, _, lane in stepped))
+                    if self._sparse_layers:
+                        # index keys the step scored, a lane's own context a
+                        # sparse layer, and the rows its read then took
+                        _tm.counter("serving.sparse.step_scored_slots").inc(
+                            self._sparse_layers
+                            * sum(lane.pos for _, _, lane in stepped))
+                        _tm.counter("serving.sparse.step_selected_slots").inc(
+                            self._sparse_layers * sum(
+                                min(lane.pos, self._sparse_topk)
+                                for _, _, lane in stepped))
                     _tm.counter("serving.step_slot_writes").inc(
                         len(stepped) * len(self._pool_names))
                     _tm.counter("serving.step_input_bytes").inc(

@@ -660,6 +660,7 @@ EXEMPT = {
     "_contrib_KVPoolAttention": "tests/test_kv_pool_ops.py",
     "_contrib_KVRingAttention": "tests/test_mimo_v2_flash_block.py",
     "_contrib_KVRingWrite": "tests/test_mimo_v2_flash_block.py",
+    "_contrib_SparseIndexSelect": "tests/test_dots3_note_block.py",
     "_contrib_KVPoolSlotWrite": "tests/test_kv_pool_ops.py",
     "_contrib_KVPoolWrite": "tests/test_kv_pool_ops.py",
     "_contrib_Mamba1Scan": "tests/test_phi4flash_block.py",

@@ -1296,6 +1296,124 @@ def test_nemotron_h_serving_programs_compile_for_the_chip(v5e, program):
 
 _VASWANI = dict(vocab_size=32000, num_layers=2, num_heads=8, model_dim=512,
                 ffn_dim=2048, pos_len=1024)
+_DOTS3 = dict(
+    arch="dots3_note", vocab_size=19008, num_layers=6, num_heads=128,
+    model_dim=5120, ffn_dim=13824, moe_ffn_dim=1536, num_experts=256,
+    num_local_experts=16, local_expert_offset=0, num_experts_per_tok=8,
+    num_shared_experts=1, first_dense_layers=1,
+    layer_types=["full_attention"] * 2 + ["sliding_attention"] * 3
+    + ["full_attention"],
+    q_lora_rank=1024, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, rope_theta=8e7, swa_num_heads=64,
+    swa_q_lora_rank=1024, swa_kv_lora_rank=1024, swa_qk_nope_head_dim=192,
+    swa_qk_rope_head_dim=64, swa_v_head_dim=128, swa_rope_theta=5e4,
+    sliding_window=513, index_n_heads=64, index_head_dim=128,
+    index_topk=2048, lora_rescale=True, rms_eps=1e-5,
+    routed_scaling_factor=1.0, norm_topk_prob=True, dtype="bfloat16")
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode", "admit_scatter"])
+def test_dots3_note_serving_programs_compile_for_the_chip(v5e, program):
+    """The three programs ``PagedKVDecoder(arch="dots3_note")`` runs, lowered
+    for the v5e at dots3-note-prev's published widths, the cell's cut (layers
+    0-5, 16 of 256 experts, 19,008 rows of the vocabulary: 3,123,656,192
+    parameters in bfloat16) and its serving sizes (32 lanes x 10,240 slots,
+    an 8,192 bucket). What has to hold on the chip: a full layer keeps a
+    head-major latent pool (1, 327,680, 576) beside a page-major index-key
+    pool (20,480, 16, 128) and a float32 row of 2,048 kept positions, a
+    window layer ONE ring (32, 1, 513, 1,088), in layer order, each back in
+    the type it went in and updated in place; the prefill makes nothing of a
+    head's 8,192 x 8,192 pairs (neither the index logits' 17 GB nor the
+    scores'): the largest thing made is a block's scores inside
+    ``_SCORE_BYTES``, and it fits beside the weights and the cache with room;
+    both graphs run the five expert layers' grouped matmuls as the kernel
+    over the 16 held experts; the step scores no mask over the pool."""
+    from types import SimpleNamespace
+
+    from mxnet_tpu.models import transformer as tf
+    from mxnet_tpu.ops import attention
+    from mxnet_tpu.ops.attention import pool_shape
+    from mxnet_tpu.serving.kv_decode import _AdmitScatter
+
+    lanes, max_len, bucket, page = 32, 10240, 8192, 16
+    slots, cfg = lanes * max_len, _DOTS3
+    shapes = tf.param_shapes(**cfg)
+    assert sum(math.prod(s) for s in shapes.values()) == 3_123_656_192
+    weights = {n: (s, "bfloat16") for n, s in shapes.items()}
+    cache = tf.decode_cache(**cfg)
+    assert [kind for _, kind, _ in cache] == \
+        ["pool", "pool", "row"] * 2 + ["ring"] * 3 + ["pool", "pool", "row"]
+    buffers = [(pool_shape(*shape, slots, page), "bfloat16") if kind == "pool"
+               else ((lanes,) + shape, "float32" if kind == "row"
+                     else "bfloat16") for _, kind, shape in cache]
+    assert [b for b, _ in buffers[:3]] == [
+        (1, slots, 576), (slots // page, page, 128), (lanes, 2048)]
+    assert buffers[6] == ((lanes, 1, 513, 1088), "bfloat16")
+    cache_bytes = sum(jnp.dtype(t).itemsize * math.prod(shape)
+                      for shape, t in buffers)
+    # latent pools 1.13 GB, index keys 0.25, rings 0.11, the kept rows 0.8 MB
+    assert cache_bytes == 3 * slots * 704 * 2 + 3 * lanes * 513 * 1088 * 2 \
+        + 3 * lanes * 2048 * 4
+    # the chip keeps a ring's row of 1,088 as nine tiles of lanes, 1,152
+    padding = 3 * lanes * 513 * 64 * 2
+    exported = [((1, 2048), "float32") if kind == "row"
+                else ((1, shape[0], bucket, shape[-1]), "bfloat16")
+                for _, kind, shape in cache]
+    if program == "admit_scatter":
+        prog = _AdmitScatter(SimpleNamespace(
+            _cache=cache, page_size=page, prefill_len=bucket))
+        spec = lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, jnp.dtype(dtype), sharding=v5e)
+        compiled = prog._fn.lower(
+            tuple(spec(*b) for b in buffers),
+            tuple(spec(*n) for n in exported),
+            spec((bucket // page,), "int32"), spec((2,), "int32"),
+        ).compile()
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == cache_bytes + padding
+        assert mem.temp_size_in_bytes < 64 << 20
+        return
+    if program == "prefill":
+        sym = tf.get_prefill_symbol(prefill_len=bucket, **cfg)
+        inputs = {"data": ((1, bucket), "float32"),
+                  "length": ((1, 1), "float32")}
+        want = [((1, 19008), "float32")] + exported \
+            + [((5, 256), "float32")]
+    else:
+        sym = tf.get_decode_symbol(max_len=slots, page_size=page, **cfg)
+        inputs = {"data": ((lanes, 1), "float32"),
+                  "pos_idx": ((lanes, 1), "float32"),
+                  "write_slot": ((lanes, 1), "float32"),
+                  "page_table": ((lanes, max_len // page), "float32")}
+        inputs.update({name: b for (name, _, _), b in zip(cache, buffers)})
+        want = [((lanes, 19008), "float32")] + buffers \
+            + [((lanes,), "float32"), ((5, 256), "float32")]
+    compiled = _compile_program(
+        v5e, sym, {**weights, **inputs},
+        donated=[name for name, _, _ in cache] if program == "decode" else ())
+    assert [(s.shape, str(s.dtype)) for s in compiled.out_info[0]] == want
+    hlo = compiled.as_text()
+    _assert_expert_layers(hlo, 5, bucket if program == "prefill" else lanes,
+                          8, 16, 5120, 1536, routed=256)
+    mem = compiled.memory_analysis()
+    if program == "prefill":
+        found = [math.prod(int(d) for d in dims.split(",") if d)
+                 for _n, dims, op, _a in _INSTRUCTION.findall(hlo)
+                 if op != "parameter"]
+        # the experts' 65,536 assignment rows of 5,120 are the largest thing
+        # made; a block's float32 scores stay inside the operator's budget
+        assert max(found) <= 8 * bucket * 5120
+        assert attention._SCORE_BYTES // 4 < 8 * bucket * 5120
+        _assert_one_row_of_logits(compiled, bucket, 19008)
+        # 4.05 GB when this was written: under 15 beside 6.25 of weights and
+        # 1.50 of cache
+        assert mem.temp_size_in_bytes < 5 << 30
+        return
+    assert "kv_mask" not in hlo and "slot_onehot" not in hlo
+    assert mem.alias_size_in_bytes == cache_bytes + padding
+    assert mem.temp_size_in_bytes < 1 << 30
+
+
 _ONE_INPUT_STEPS = {
     # arch: (sizes, lanes, slots a lane, reading nodes that are the kernel's,
     # copies and transposes of a pool's size: the head-major latent pool's
@@ -1309,7 +1427,13 @@ _ONE_INPUT_STEPS = {
     "mimo_v2_flash": (_MIMO, 32, 8192, 2, 0),
     "phi4flash": (_PHI4, 64, 8192, 8, 0),
     "nemotron_h": (_NEMOTRON, 64, 8192, 2, 0),
+    # the three latent pools' re-layouts (kanana's, at five times the slots)
+    # and the own pages of the three index pools, gathered and turned
+    "dots3_note": (_DOTS3, 32, 10240, 0, 6),
 }
+# what the chip's layout adds to a cache's bytes: a ring row of 1,088 is kept
+# 1,152 wide (nine tiles of lanes), three rings of 32 x 513 rows
+_LAYOUT_PADDING = {"dots3_note": 3 * 32 * 513 * 64 * 2}
 
 
 @pytest.mark.parametrize("arch", list(_ONE_INPUT_STEPS))
@@ -1358,7 +1482,8 @@ def test_a_decode_step_takes_one_host_fed_array_on_the_chip(v5e, arch):
     assert (lanes, 1) not in params and (lanes, max_len // page) not in params
     cache_bytes = sum(math.prod(shape) * jnp.dtype(t).itemsize
                       for shape, t in (args[name] for name, _, _ in cache))
-    assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == cache_bytes + _LAYOUT_PADDING.get(arch, 0)
     assert len(_paged_read_calls(hlo)) == reads
     pool = min(math.prod(args[name][0]) for name, kind, _ in cache
                if kind == "pool")
