@@ -28,7 +28,11 @@ a seed), and checks what comes out by the repo's own means:
   6 prefill  a prefill's causal attention at OLMoE's (1, 16, 2048, 128) and
              nemotron's (1, 32 over 2, 2048, 128) in bfloat16: the blockwise
              kernel the rule names against the dense path, the worst
-             relative difference and a layer's time in each
+             relative difference and a layer's time in each; then a full
+             layer of dots3-note-prev under its learned selection (128 heads
+             of 192 over 128, 8,192 positions, the 2,048 highest of a
+             64 x 128 indexer): the kernel under the layer's mask against
+             XLA's query blocks
 
 Every phase prints PASS, FAIL or SKIP <reason>; a skip is never the result
 of an exception. Any FAIL makes the exit code 1. With no TPU the script
@@ -89,6 +93,9 @@ if not REHEARSE:
         # head width) of olmoe-1b-7b.score and nemotron-3-nano-30b-a3b.generate
         prefill_attn={"olmoe": (16, 16, 2048, 128),
                       "nemotron": (32, 2, 2048, 128)},
+        # dots3-note-prev's full layer at its bucket: (heads, bucket, key
+        # width, value width, index heads, index width, topk)
+        sparse_attn=(128, 8192, 192, 128, 64, 128, 2048),
     )
 else:
     SZ = dict(
@@ -103,6 +110,7 @@ else:
         attn=(1, 2, 16, 8), mba=(16, 16, 128), ln=(16, 128),
         matmul_n=256,
         prefill_attn={"olmoe": (2, 2, 32, 16), "nemotron": (8, 2, 32, 16)},
+        sparse_attn=(4, 64, 16, 8, 4, 8, 16),
     )
 
 
@@ -1037,7 +1045,9 @@ def phase_prefill_attention():
     bfloat16) at two cells' shapes: the form the rule names on this backend
     (the blockwise kernel) against the dense path (the rule held to it), the
     worst difference over the output's largest magnitude and a layer's time in
-    each, eight layers inside one program."""
+    each, eight layers inside one program. Then ONE full layer under a
+    learned selection at dots3-note-prev's shapes: the form the rule names
+    (the kernel under the layer's mask) against XLA's query blocks."""
     from mxnet_tpu.ops import attention as attn_op
     from mxnet_tpu.ops.registry import get_op
 
@@ -1046,7 +1056,7 @@ def phase_prefill_attention():
     layers, rs = 8, np.random.RandomState(6)
     rule = attn_op.attention_form
 
-    def layer_ms(fn, args, n=20):
+    def layer_ms(fn, args, n, layers):
         out = fn(*args)
         out.block_until_ready()
         times = []
@@ -1058,6 +1068,35 @@ def phase_prefill_attention():
             times.append((time.perf_counter() - t0) / n / layers * 1e3)
         return out, float(np.median(times))
 
+    def both_forms(kernel, other, body, args, n, layers):
+        """``body`` jitted and timed with the rule held to the ``kernel``'s
+        form and to the ``other`` (it is read at trace time): (a layer's
+        milliseconds in each, their worst difference over the largest
+        output)."""
+        outs = {}
+        for form in (kernel, other):
+            attn_op.attention_form = lambda *a, _f=form: _f
+            try:
+                # a function of its own: jit keeps one trace a function
+                fn = jax.jit(lambda *a: body(*a))
+                if form == kernel and not REHEARSE:
+                    check(is_mosaic(fn, *args),
+                          "the operator lowers to a Mosaic custom call")
+                outs[form] = layer_ms(fn, args, 1 if REHEARSE else n, layers)
+            finally:
+                attn_op.attention_form = rule
+        got, want = (np.asarray(outs[f][0], np.float32)
+                     for f in (kernel, other))
+        return (outs[kernel][1], outs[other][1],
+                float(np.abs(got - want).max() / np.abs(want).max()))
+
+    def verdict(fast, slow, diff, what):
+        check(diff < 2e-2, "%s, to a bfloat16 rounding of the output (%.2e)"
+              % (what, diff))
+        if not REHEARSE:
+            check(fast < slow,
+                  "the kernel is the faster form where the rule names it")
+
     for name, (h, hkv, t, d) in SZ["prefill_attn"].items():
         say("  -- %s: %d query heads over %d key/value heads, %d positions, "
             "width %d, bfloat16" % (name, h, hkv, t, d))
@@ -1068,31 +1107,33 @@ def phase_prefill_attention():
         ruled = rule(qs[0], k, v, True)
         if not REHEARSE:
             check(ruled == "kernel", "the rule names the kernel (%s)" % ruled)
-        outs = {}
-        for form in ("kernel", "dense"):
-            # the rule is read at trace time: hold it while the form compiles
-            attn_op.attention_form = lambda *a, _f=form: _f
-            try:
-                fn = jax.jit(lambda qs, k, v: jax.lax.map(
-                    lambda q: op(attrs, q, k, v), qs))
-                if form == "kernel" and not REHEARSE:
-                    check(is_mosaic(fn, qs, k, v),
-                          "the operator lowers to a Mosaic custom call")
-                outs[form] = layer_ms(fn, (qs, k, v), 1 if REHEARSE else 20)
-            finally:
-                attn_op.attention_form = rule
-        got, want = (np.asarray(outs[f][0], np.float32)
-                     for f in ("kernel", "dense"))
-        diff = float(np.abs(got - want).max() / np.abs(want).max())
+        fast, slow, diff = both_forms(
+            "kernel", "dense", lambda qs, k, v: jax.lax.map(
+                lambda q: op(attrs, q, k, v), qs), (qs, k, v), 20, layers)
         say("    a layer: kernel %.3f ms, dense %.3f ms (x%.2f); worst "
             "difference over the largest output %.2e"
-            % (outs["kernel"][1], outs["dense"][1],
-               outs["dense"][1] / outs["kernel"][1], diff))
-        check(diff < 2e-2, "kernel and dense path agree to a bfloat16 "
-              "rounding of the output (%.2e)" % diff)
-        if not REHEARSE:
-            check(outs["kernel"][1] < outs["dense"][1],
-                  "the kernel is the faster form where the rule names it")
+            % (fast, slow, slow / fast, diff))
+        verdict(fast, slow, diff, "kernel and dense path agree")
+
+    h, t, dk, dv, hi, di, topk = SZ["sparse_attn"]
+    say("  -- dots3-note-prev, a full layer: %d heads of %d over %d, %d "
+        "positions, the %d highest of a %d x %d indexer, bfloat16"
+        % (h, dk, dv, t, topk, hi, di))
+    operands = tuple(
+        jnp.asarray(rs.randn(*shape).astype("float32"), jnp.bfloat16)
+        for shape in ((1, h, t, dk), (1, h, t, dk), (1, h, t, dv),
+                      (1, hi, t, di), (1, 1, t, di), (1, t, hi)))
+    ruled = rule(*operands[:3], True, 0, False, None, topk)
+    if not REHEARSE:
+        check(ruled == "sparse_kernel",
+              "the rule names the kernel under a selection (%s)" % ruled)
+    fast, slow, diff = both_forms(
+        "sparse_kernel", "sparse",
+        lambda *a: op(dict(attrs, topk=topk), *a), operands, 5, 1)
+    say("    a layer: the kernel under its mask %.2f ms, XLA's query blocks "
+        "%.2f ms (x%.2f); worst difference over the largest output %.2e"
+        % (fast, slow, slow / fast, diff))
+    verdict(fast, slow, diff, "both forms attend the same keys")
 
 
 # --------------------------------------------------------------------- main

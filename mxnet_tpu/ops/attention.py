@@ -24,7 +24,7 @@ _NEG = np.float32(-1e9)
 # (observability for tests and the multichip dryrun: proves the seq-parallel
 # path, or the kernel, actually engaged)
 DISPATCH_COUNTS = {"ring": 0, "kernel": 0, "band": 0, "dense": 0,
-                   "sparse": 0}
+                   "sparse": 0, "sparse_kernel": 0}
 
 # float32 scores a blockwise form (a ragged band, a learned sparse selection)
 # makes at once: the query blocks are sized from it
@@ -66,7 +66,17 @@ def attention_form(query, key, value, causal, window=0, sink=False,
     ``"sparse"``: ``topk`` > 0, a learned selection: every query attends the
     ``topk`` causal keys its indexer scores highest (all of them while it has
     no more), in query blocks, so neither the index logits nor the scores of
-    all T x S pairs are made whole (``_sparse_attention``).
+    all T x S pairs are made whole (``_sparse_attention``): every backend
+    but the chip, a mesh of several devices, shapes the kernel refuses.
+
+    ``"sparse_kernel"``: the same selection on the chip (one device), where
+    ``pallas_attention.takes`` the operands under a mask: the query blocks
+    make the SELECTION alone, a mask (B, T, S) int8 all heads share
+    (``_selection``: the same index scores, the same ``_topk_mask``, so the
+    same keys), and ONE call of the blockwise kernel a layer applies it to
+    its blocks in VMEM beside the causal mask; the (heads, block, S) float32
+    scores of a query block are never written. Differentiated, it is
+    ``"sparse"``'s backward.
 
     ``"band"``: a window of W < T positions, T a multiple of W or of W - 1
     (``_band_block``): T x 2W scores (``_band_attention``).
@@ -88,7 +98,13 @@ def attention_form(query, key, value, causal, window=0, sink=False,
     decided)."""
     b, h, t, _ = query.shape
     hkv, s = key.shape[1], key.shape[2]
+    alone = _backend() == "tpu" and (mesh is None or mesh.size == 1)
     if topk > 0:
+        if alone:
+            from . import pallas_attention as kernel
+
+            if kernel.takes(query, key, value, selected=True):
+                return "sparse_kernel"
         return "sparse"
     plain = window <= 0 and not sink
     if plain and h == hkv and mesh is not None \
@@ -99,8 +115,7 @@ def attention_form(query, key, value, causal, window=0, sink=False,
         return "ring"
     if _band_block(t, window):
         return "band"
-    if plain and causal and _backend() == "tpu" \
-            and (mesh is None or mesh.size == 1):
+    if plain and causal and alone:
         from . import pallas_attention as kernel
 
         if kernel.takes(query, key, value):
@@ -223,9 +238,10 @@ def _multi_head_attention(attrs, query, key, value, *more):
             interpret=_backend() != "tpu")
     scale = attrs["scale"] if attrs["scale"] > 0 else 1.0 / np.sqrt(d)
     if form == "sparse":
-        out = _sparse_attention(query.reshape(b, hkv, g, t, d), key, value,
-                                *more[-3:], topk, scale)
-        return out.reshape(b, h, t, value.shape[-1]).astype(query.dtype)
+        return _sparse_attention(query, key, value, *more[-3:], topk, scale)
+    if form == "sparse_kernel":
+        return _sparse_kernel_attention(query, key, value, *more[-3:], topk,
+                                        scale, _backend() != "tpu")
     q = query.astype("float32").reshape(b, hkv, g, t, d)
     if sink is not None:
         sink = sink.astype("float32").reshape(hkv, g)
@@ -345,60 +361,128 @@ def _topk_mask(score, topk):
         jnp.arange(score.shape[-1], dtype=at.dtype) <= last))
 
 
-def _sparse_attention(q, k, v, index_query, index_key, index_weight, topk,
-                      scale):
-    """Causal attention over a learned selection: ``q`` (B, Hkv, G, T, d),
-    ``k`` (B, Hkv, T, d), ``v`` (B, Hkv, T, dv), the indexer's
-    ``index_query`` (B, Hi, T, di), ``index_key`` (B, 1, T, di) and
-    ``index_weight`` (B, T, Hi). Query t attends the ``topk`` keys s <= t of
-    largest ``index_scores`` (``jax.lax.top_k``'s set: of keys that tie at
-    the last place the lower positions; ``_topk_mask``), every key while
-    t < ``topk``. Both products run in the operands' type with a float32
-    accumulator, the softmax's maximum, exponentials and sum float32, the
-    exponentials rounded to the values' type for the second product and the
-    context divided by their sum after it (the kernel's arithmetic).
+def _selected_blocks(heads, index_query, index_key, index_weight, topk,
+                     each):
+    """A learned selection over T causal positions, a block of queries at a
+    time: the indexer's ``index_query`` (B, Hi, T, di), ``index_key`` (B, 1,
+    T, di) and ``index_weight`` (B, T, Hi). ``each(t0, upto, seen)`` is
+    called a block (inside ``lax.map``): ``seen`` (B, block, ``upto``), true
+    where the block's query ``t0 + r`` attends key s < ``upto``: the
+    ``topk`` keys s <= t of largest ``index_scores`` (``jax.lax.top_k``'s
+    set: of keys that tie at the last place the lower positions;
+    ``_topk_mask``), every key while t < ``topk``. What the calls give back
+    comes stacked, (blocks, ...) in the blocks' order.
 
-    The selection is a MASK over the scores of a block of queries: the
-    blocks are sized so that a block's float32 scores stay inside
-    ``_SCORE_BYTES``, and run in up to eight groups, each over the keys up to
-    its own last query (seven sixteenths of the pairs above the diagonal are
-    never scored), one after another (``lax.map``)."""
-    b, hkv, g, t, d = q.shape
+    The blocks are sized so that the float32 scores of ``heads`` heads a
+    block stay inside ``_SCORE_BYTES``, and run in up to eight groups, each
+    over the keys up to its own last query, ``upto`` (seven sixteenths of the
+    pairs above the diagonal are never scored), one after another."""
+    b, _, t, _ = index_query.shape
     iq = index_query.transpose(0, 2, 1, 3)              # (B, T, Hi, di)
     ik = index_key[:, 0]
-    rows = max(1, _SCORE_BYTES // (4 * b * hkv * g * t))
+    rows = max(1, _SCORE_BYTES // (4 * b * heads * t))
     blk = next(c for c in range(min(rows, t), 0, -1) if t % c == 0)
     nb = t // blk
     groups = next(c for c in (8, 4, 2, 1) if nb % c == 0)
 
     def group(first, upto):
         """Query blocks ``first`` .. over keys 0 .. ``upto`` - 1."""
-        keys, values, ikeys = k[:, :, :upto], v[:, :, :upto], ik[:, :upto]
+        ikeys = ik[:, :upto]
         at_key = jnp.arange(upto, dtype=jnp.int32)[None, :]
 
         def one(block):
             t0 = block * blk
-            cut = lambda a, axis: jax.lax.dynamic_slice_in_dim(a, t0, blk,
-                                                               axis)
+            cut = lambda a: jax.lax.dynamic_slice_in_dim(a, t0, blk, 1)
             seen = (at_key <= t0 + jnp.arange(
                 blk, dtype=jnp.int32)[:, None])[None]
             if upto > topk:
                 seen = seen & _topk_mask(jnp.where(seen, index_scores(
-                    cut(iq, 1), cut(index_weight, 1), ikeys), -jnp.inf), topk)
-            s = jnp.einsum("bkgqd,bksd->bkgqs", cut(q, 3), keys,
-                           preferred_element_type=jnp.float32) * scale
-            s = jnp.where(seen[:, None, None], s, -jnp.inf)
-            e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
-            out = jnp.einsum("bkgqs,bksd->bkgqd", e.astype(values.dtype),
-                             values, preferred_element_type=jnp.float32)
-            return out / jnp.sum(e, axis=-1, keepdims=True)
+                    cut(iq), cut(index_weight), ikeys), -jnp.inf), topk)
+            return each(t0, upto, seen)
 
         return jax.lax.map(one, first + jnp.arange(nb // groups))
 
-    out = jnp.concatenate([group(j * (nb // groups), (j + 1) * (t // groups))
-                           for j in range(groups)], axis=0)
-    # (blocks, B, Hkv, G, blk, dv) -> (B, Hkv, G, T, dv)
-    return jnp.moveaxis(out, 0, 3).reshape(b, hkv, g, t, v.shape[-1])
+    return jnp.concatenate([group(j * (nb // groups), (j + 1) * (t // groups))
+                            for j in range(groups)], axis=0)
+
+
+def _sparse_attention(query, key, value, index_query, index_key,
+                      index_weight, topk, scale):
+    """Causal attention over a learned selection (``_selected_blocks``):
+    ``query`` (B, H, T, d), ``key`` (B, Hkv, T, d), ``value`` (B, Hkv, T,
+    dv) give (B, H, T, dv) in the query's type. Both products run in the
+    operands' type with a float32 accumulator, the softmax's maximum,
+    exponentials and sum float32, the exponentials rounded to the values'
+    type for the second product and the context divided by their sum after
+    it (the kernel's arithmetic). The selection is a MASK over the scores of
+    a block of queries, (B, H, block, ``upto``) float32 inside
+    ``_SCORE_BYTES``."""
+    b, h, t, d = query.shape
+    hkv = key.shape[1]
+    q = query.reshape(b, hkv, h // hkv, t, d)
+
+    def attend(t0, upto, seen):
+        keys, values = key[:, :, :upto], value[:, :, :upto]
+        s = jnp.einsum("bkgqd,bksd->bkgqs", jax.lax.dynamic_slice_in_dim(
+            q, t0, seen.shape[1], 3), keys,
+            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(seen[:, None, None], s, -jnp.inf)
+        e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        out = jnp.einsum("bkgqs,bksd->bkgqd", e.astype(values.dtype), values,
+                         preferred_element_type=jnp.float32)
+        return out / jnp.sum(e, axis=-1, keepdims=True)
+
+    out = _selected_blocks(h, index_query, index_key, index_weight, topk,
+                           attend)
+    # (blocks, B, Hkv, G, blk, dv) -> (B, H, T, dv)
+    return jnp.moveaxis(out, 0, 3).reshape(
+        b, h, t, value.shape[-1]).astype(query.dtype)
+
+
+def _selection(heads, index_query, index_key, index_weight, topk):
+    """``_selected_blocks``' selection whole, (B, T, T) int8: 1 where query
+    t attends key s, the mask every head shares (64 MiB at 8,192 positions,
+    in place of a block's float32 scores of ``heads`` heads). Blocks, groups
+    and arithmetic are ``_sparse_attention``'s, so are the selected keys."""
+    t = index_query.shape[2]
+    rows = _selected_blocks(
+        heads, index_query, index_key, index_weight, topk,
+        lambda t0, upto, seen: jnp.pad(seen.astype(jnp.int8), (
+            (0, 0), (0, 0), (0, t - upto))))
+    # (blocks, B, blk, T) -> (B, T, T)
+    return jnp.moveaxis(rows, 0, 1).reshape(rows.shape[1], t, t)
+
+
+def _sparse_kernel(query, key, value, index_query, index_key, index_weight,
+                   topk, scale, interpret):
+    """``_sparse_attention`` with the masked attention in the blockwise
+    kernel's blocks (``attention_form``'s ``"sparse_kernel"``): the layer's
+    ``_selection``, then ONE ``flash_attention`` under it. Off the chip (a
+    test that holds the rule) Pallas ``interpret``s it."""
+    from . import pallas_attention as pa
+
+    return pa.flash_attention(
+        query, key, value, causal=True, scale=scale, interpret=interpret,
+        selected=_selection(query.shape[1], index_query, index_key,
+                            index_weight, topk))
+
+
+# the kernel has no backward under a selection: differentiated, the form is
+# ``_sparse_attention``
+_sparse_kernel_attention = jax.custom_vjp(_sparse_kernel,
+                                          nondiff_argnums=(6, 7, 8))
+
+
+def _sparse_kernel_fwd(*operands_and_attrs):
+    return _sparse_kernel(*operands_and_attrs), operands_and_attrs[:6]
+
+
+def _sparse_kernel_bwd(topk, scale, _interpret, operands, cotangent):
+    return jax.vjp(lambda *a: _sparse_attention(*a, topk, scale),
+                   *operands)[1](cotangent)
+
+
+_sparse_kernel_attention.defvjp(_sparse_kernel_fwd, _sparse_kernel_bwd)
 
 
 def _kv_groups(heads, kv_heads, what):

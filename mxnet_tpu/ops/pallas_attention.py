@@ -22,6 +22,14 @@ diagonal is not masked at all. The mask is bottom-right aligned for S >= T, as
 the dense path's is; causal with S < T is refused by ``supported()`` (rows with
 no key at all have no softmax).
 
+A SELECTION: an optional mask (B, T, S) int8 that every head of a batch row
+shares (a learned sparse attention's chosen keys). Its ``(block_q, block_k)``
+block rides beside the key block, under the same clamp, and a score survives
+where it is causal AND selected; a block below the diagonal is then masked
+too. The masked value is finite, so a row whose first key blocks hold nothing
+selected carries garbage until its first real key arrives, whose maximum
+rescales it to exactly nothing (``alpha = exp(_NEG_INF - m) = 0``).
+
 Grouped queries: ``k`` / ``v`` may carry fewer heads (B, Hkv, S, D) than ``q``
 (B, H, T, D). The keys are never repeated: the H / Hkv query heads a key/value
 head serves are folded into the ROWS of that head's query block, so one grid
@@ -63,19 +71,21 @@ def _sublanes(dtype):
     return 32 // jnp.dtype(dtype).itemsize
 
 
-def block_bytes(block_q, block_k, group, dk, dv, dtype):
+def block_bytes(block_q, block_k, group, dk, dv, dtype, selected=False):
     """The VMEM one grid step of the forward kernel holds, from its blocks
     alone: the query and output blocks and the key and value blocks, each
     twice (the pipeline's two buffers), the float32 scores and probabilities
-    and the probabilities once more in the values' type, and the scratch
-    (accumulator, maximum and sum a row, a lane tile wide each)."""
+    and the probabilities once more in the values' type, the scratch
+    (accumulator, maximum and sum a row, a lane tile wide each) and, with a
+    selection, its int8 block twice."""
     size = jnp.dtype(dtype).itemsize
     rows = group * block_q
     pad = lambda d: -(-d // _LANES) * _LANES
     return (2 * rows * (pad(dk) + pad(dv)) * size
             + 2 * block_k * (pad(dk) + pad(dv)) * size
             + rows * block_k * (4 + 4 + size)
-            + rows * (pad(dv) + 2 * _LANES) * 4)
+            + rows * (pad(dv) + 2 * _LANES) * 4
+            + (2 * block_q * block_k if selected else 0))
 
 
 # the rule's two sizes, settled on the chip (``blocks``)
@@ -83,13 +93,15 @@ _ROWS = 1024
 _BLOCK_K = 1024
 
 
-def blocks(t, s, group, dk, dv, dtype):
+def blocks(t, s, group, dk, dv, dtype, selected=False):
     """``(block_q, block_k)`` of the forward kernel for ``t`` queries a head
     over ``s`` keys, ``group`` query heads a key/value head, a key of ``dk``
-    and a value of ``dv`` numbers of ``dtype``; None where no block tiles the
-    shape. THE rule of the kernel's tiling, from the shape, the operands'
-    bytes and the VMEM budget alone (no option, no model's name), written
-    from chip runs at the cells' shapes (``PERF.md`` section 6, PR 49).
+    and a value of ``dv`` numbers of ``dtype``, under a selection's mask or
+    not (its int8 block counts in the bytes and tiles in 32 rows); None where
+    no block tiles the shape. THE rule of the kernel's tiling, from the
+    shape, the operands' bytes and the VMEM budget alone (no option, no
+    model's name), written from chip runs at the cells' shapes (``PERF.md``
+    section 6, PR 49).
 
     ``block_k`` is the largest divisor of ``s`` in whole lane tiles up to
     ``_BLOCK_K``, and a step holds up to ``_ROWS`` rows, ``group x block_q``
@@ -106,12 +118,12 @@ def blocks(t, s, group, dk, dv, dtype):
     Splitting a step's softmax into row chunks inside a loop, to keep the
     scores in registers, read 2 to 4.6 times SLOWER at every shape and is
     not here."""
-    unit = _sublanes(dtype)
+    unit = _sublanes(jnp.int8 if selected else dtype)
     bk = _largest_divisor(s, _BLOCK_K, _LANES) or _largest_divisor(s, s, unit)
     bq = _largest_divisor(t, max(_ROWS // group, unit), unit)
     if not bq or not bk:
         return None
-    while block_bytes(bq, bk, group, dk, dv, dtype) > _VMEM_BUDGET:
+    while block_bytes(bq, bk, group, dk, dv, dtype, selected) > _VMEM_BUDGET:
         if bq * group >= bk and bq % (2 * unit) == 0:
             bq //= 2
         elif bk % (2 * _LANES) == 0:
@@ -170,11 +182,12 @@ def supported(q_shape, k_shape, causal=False, block_q=128, block_k=128):
 _DENSE_SCORES = 64 << 20
 
 
-def takes(query, key, value):
-    """Whether the chip runs plain causal attention over these operands as
-    this kernel rather than densely (shapes and types alone; each operand
-    carries ``.shape`` and ``.dtype``: ``query`` (B, H, T, dk), ``key``
-    (B, Hkv, S, dk), ``value`` (B, Hkv, S, dv)): what ``attention_form`` asks.
+def takes(query, key, value, selected=False):
+    """Whether the chip runs causal attention over these operands, plain or
+    under a selection's mask, as this kernel rather than in XLA's form
+    (shapes and types alone; each operand carries ``.shape`` and ``.dtype``:
+    ``query`` (B, H, T, dk), ``key`` (B, Hkv, S, dk), ``value`` (B, Hkv, S,
+    dv)): what ``attention_form`` asks.
 
     One type throughout, bfloat16 or float32; S >= T; T and S whole lane
     tiles and head widths whole sublane tiles (what Mosaic tiles); a tiling
@@ -192,7 +205,7 @@ def takes(query, key, value):
     if hkv < 1 or h % hkv or s < t or t % _LANES or s % _LANES \
             or dk % 8 or dv % 8 or 4 * b * h * t * s <= _DENSE_SCORES:
         return False
-    return blocks(t, s, h // hkv, dk, dv, query.dtype) is not None
+    return blocks(t, s, h // hkv, dk, dv, query.dtype, selected) is not None
 
 
 def _causal_mask(s, iq, jk, block_q, block_k, offset):
@@ -218,12 +231,27 @@ def _first_query_block(jk, block_q, block_k, offset):
 
 
 # --------------------------------------------------------------------- forward
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal, block_q,
-                block_k, nk, offset, with_lse):
+def _selected_mask(s, sel, block_q):
+    """One tile of scores, ``s`` (groups x block_q, block_k), under a
+    selection's block ``sel`` (block_q, block_k) int8: every group's rows
+    share it."""
+    group = s.shape[0] // block_q
+    sel = jnp.broadcast_to(sel.astype(jnp.int32)[None],
+                           (group,) + sel.shape).reshape(s.shape)
+    return jnp.where(sel != 0, s, _NEG_INF)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
+                nk, offset, with_lse, selected):
+    """``rest``: the selection's block (``selected`` alone), the output, the
+    logsumexp (``with_lse`` alone), then the scratch."""
     from jax.experimental import pallas as pl
 
-    lse_ref = rest[0] if with_lse else None
-    m_scr, l_scr, acc_scr = rest[-3:]
+    rest = list(rest)
+    sel_ref = rest.pop(0) if selected else None
+    o_ref = rest.pop(0)
+    lse_ref = rest.pop(0) if with_lse else None
+    m_scr, l_scr, acc_scr = rest
     iq, jk = pl.program_id(1), pl.program_id(2)
     rows = acc_scr.shape[0]                           # groups x block_q
 
@@ -240,6 +268,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal, block_q,
                                 preferred_element_type=jnp.float32) * scale
         if masked:
             s = _causal_mask(s, iq, jk, block_q, block_k, offset)
+        if selected:
+            s = _selected_mask(s, sel_ref[0], block_q)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -252,7 +282,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal, block_q,
 
     if causal:
         # a block wholly at or below the diagonal of its FIRST row needs no
-        # mask; one the diagonal crosses is masked; one above it does not run
+        # causal mask (a selection's it takes all the same); one the diagonal
+        # crosses is masked; one above it does not run
         below = (jk + 1) * block_k - 1 <= iq * block_q + offset
         crossed = jnp.logical_and(
             jnp.logical_not(below),
@@ -366,11 +397,14 @@ def _compiler_params(n_parallel, vmem_bytes=0):
 
 
 def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
-              with_lse=True):
+              with_lse=True, selected=None):
     """The forward kernel over ``q`` (BHkv, G, T, dk), ``k`` (BHkv, S, dk) and
     ``v`` (BHkv, S, dv): ``(out (BHkv, G, T, dv), lse (BHkv, G, T, 1) | None)``.
     The logsumexp is the backward's; a call nobody differentiates leaves it
-    out."""
+    out. ``selected`` (B, T, S) int8, absent at TRACE time for a plain call
+    (whose program is then what it was without the word): batch row ``bh //
+    (BHkv / B)``'s mask for every head of it, its block fetched beside the
+    key block's."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -380,7 +414,8 @@ def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
     offset = S - T
     kern = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, nk=nk, offset=offset, with_lse=with_lse)
+        block_k=block_k, nk=nk, offset=offset, with_lse=with_lse,
+        selected=selected is not None)
     if causal:
         # past the diagonal the index stays on the last block the queries
         # need: the pipeline fetches nothing for a step that does not run
@@ -391,6 +426,20 @@ def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
         kv_block = lambda bh, iq, jk: (bh, jk, 0)
         live = T * S
     size = q.dtype.itemsize
+    in_specs = [
+        pl.BlockSpec((1, G, block_q, D), lambda bh, iq, jk: (bh, 0, iq, 0)),
+        pl.BlockSpec((1, block_k, D), kv_block),
+        pl.BlockSpec((1, block_k, Dv), kv_block),
+    ]
+    operands, nbytes = (q, k, v), size * BH * (G * T * (D + Dv)
+                                               + S * (D + Dv))
+    if selected is not None:
+        # a batch row's mask for each of its heads, re-read a head
+        heads = BH // selected.shape[0]
+        in_specs.append(pl.BlockSpec(
+            (1, block_q, block_k), lambda bh, iq, jk: (
+                bh // heads, iq, kv_block(bh, iq, jk)[1])))
+        operands, nbytes = operands + (selected,), nbytes + BH * live
     out_specs = [pl.BlockSpec((1, G, block_q, Dv),
                               lambda bh, iq, jk: (bh, 0, iq, 0))]
     out_shape = [jax.ShapeDtypeStruct((BH, G, T, Dv), q.dtype)]
@@ -401,12 +450,7 @@ def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
     out = pl.pallas_call(
         kern,
         grid=(BH, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, G, block_q, D),
-                         lambda bh, iq, jk: (bh, 0, iq, 0)),
-            pl.BlockSpec((1, block_k, D), kv_block),
-            pl.BlockSpec((1, block_k, Dv), kv_block),
-        ],
+        in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
@@ -415,14 +459,14 @@ def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
             pltpu.VMEM((G * block_q, Dv), jnp.float32),
         ],
         compiler_params=None if interpret else _compiler_params(
-            2, block_bytes(block_q, block_k, G, D, Dv, q.dtype)),
+            2, block_bytes(block_q, block_k, G, D, Dv, q.dtype,
+                           selected is not None)),
         cost_estimate=pl.CostEstimate(
             flops=2 * BH * G * live * (D + Dv),
-            transcendentals=BH * G * live,
-            bytes_accessed=size * BH * (G * T * (D + Dv) + S * (D + Dv))),
+            transcendentals=BH * G * live, bytes_accessed=nbytes),
         interpret=interpret,
         name="flash_attention",
-    )(q, k, v)
+    )(*operands)
     return out[0], (out[1] if with_lse else None)
 
 
@@ -530,15 +574,39 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, do):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_selected(q, k, v, selected, causal, scale, block_q, block_k,
+                    interpret):
+    return _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
+                     with_lse=False, selected=selected)[0]
+
+
+def _flash_selected_bwd(*_):
+    raise NotImplementedError(
+        "flash_attention(selected=) is not differentiable: the backward "
+        "kernels know the causal mask alone. MultiHeadAttention(topk=) "
+        "keeps the XLA form's backward")
+
+
+_flash_selected.defvjp(lambda *a: (_flash_selected(*a), None),
+                       _flash_selected_bwd)
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, causal=False, scale=0.0, block_q=None,
-                    block_k=None, interpret=False):
+                    block_k=None, interpret=False, selected=None):
     """softmax(QKᵀ·scale)V of ``q`` (B, H, T, dk) over ``k`` (B, Hkv, S, dk)
     and ``v`` (B, Hkv, S, dv), Hkv dividing H, streamed through VMEM: (B, H,
     T, dv). Differentiable (custom_vjp backward kernels). ``block_q`` /
     ``block_k`` default to the rule's (``blocks``); a named block is clamped
-    to the shape."""
+    to the shape.
+
+    ``selected`` (B, T, S), nonzero where query t of batch row b may attend
+    key s, the same for every head: a score survives where it is causal (if
+    ``causal``) AND selected; every row must keep a key. The forward kernel
+    alone knows it: such a call is not differentiable (its caller keeps a
+    backward of its own, ``ops.attention``)."""
     B, H, T, D = q.shape
     Hkv, S, Dv = k.shape[1], k.shape[2], v.shape[3]
     if causal and S < T:
@@ -550,7 +618,7 @@ def flash_attention(q, k, v, causal=False, scale=0.0, block_q=None,
         scale = 1.0 / np.sqrt(D)
     G = H // Hkv
     if block_q is None or block_k is None:
-        ruled = blocks(T, S, G, D, Dv, q.dtype)
+        ruled = blocks(T, S, G, D, Dv, q.dtype, selected is not None)
         if ruled is None:
             raise ValueError(
                 "flash_attention: no block tiles %d queries over %d keys "
@@ -559,7 +627,12 @@ def flash_attention(q, k, v, causal=False, scale=0.0, block_q=None,
         block_q, block_k = (block_q or ruled[0]), (block_k or ruled[1])
     block_q = min(block_q, T)
     block_k = min(block_k, S)
-    out = _flash(q.reshape(B * Hkv, G, T, D), k.reshape(B * Hkv, S, D),
-                 v.reshape(B * Hkv, S, Dv), causal, float(scale),
-                 block_q, block_k, interpret)
+    heads = (q.reshape(B * Hkv, G, T, D), k.reshape(B * Hkv, S, D),
+             v.reshape(B * Hkv, S, Dv))
+    if selected is None:
+        out = _flash(*heads, causal, float(scale), block_q, block_k,
+                     interpret)
+    else:
+        out = _flash_selected(*heads, selected.astype(jnp.int8), causal,
+                              float(scale), block_q, block_k, interpret)
     return out.reshape(B, H, T, Dv)

@@ -394,8 +394,8 @@ def _attention_forms(cache, input_shapes):
         attrs = n.parsed_attrs()
         forms.append(attention_form(
             ops["query"], ops["key"], ops["value"], attrs["causal"],
-            attrs.get("window", 0), bool(attrs.get("sink")),
-            topk=attrs.get("topk", 0)))
+            attrs.get("window", 0), bool(attrs.get("sink")), None,
+            attrs.get("topk", 0)))
     return forms
 
 
@@ -1153,7 +1153,9 @@ class PagedKVDecoder:
     shows (which tokens of a long context a lane's answer is reading). The
     admission's prefill is
     materialised; a full layer's selection is a mask over query blocks
-    (``MultiHeadAttention(topk=)``), a window layer's scores a band. Counters
+    (``MultiHeadAttention(topk=)``; on the chip the blockwise kernel applies
+    it, gauge ``serving.prefill_attention.sparse_kernel_layers``), a window
+    layer's scores a band. Counters
     ``serving.sparse.*`` (docs/OBSERVABILITY.md). It refuses what
     ``mimo_v2_flash`` refuses.
     """
@@ -1414,7 +1416,8 @@ class PagedKVDecoder:
             if "prefill" in programs:
                 # the prefill's attention layers by the form the rule names
                 forms = _attention_forms(*programs["prefill"])
-                for form in ("kernel", "dense", "band", "sparse"):
+                for form in ("kernel", "dense", "band", "sparse",
+                             "sparse_kernel"):
                     _tm.gauge("serving.prefill_attention.%s_layers"
                               % form).set(forms.count(form))
             _tm.gauge("serving.state_bytes").set(sum(

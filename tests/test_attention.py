@@ -134,11 +134,11 @@ def test_pallas_flash_attention_matches_oracle(causal):
                                rtol=1e-4, atol=1e-5)
 
 
-def _mha(q, k, v, **attrs):
+def _mha(q, k, v, *more, **attrs):
     from mxnet_tpu.ops.registry import get_op
 
     return get_op("_contrib_MultiHeadAttention").fn(
-        {"causal": True, "scale": -1.0, "window": 0, **attrs}, q, k, v)
+        {"causal": True, "scale": -1.0, "window": 0, **attrs}, q, k, v, *more)
 
 
 def _operands(h, hkv, t, s, dk, dv, dtype, seed=3):
@@ -227,6 +227,25 @@ _RULE_CASES = {
                         {"window": 100}, "dense"),
     "mixed types": ((16, 16, 2048, 2048, 64, 64, ("bfloat16", "float32")),
                     {}, "dense"),
+    # a learned selection (``topk``): the kernel under a mask where it takes
+    # the operands, XLA's query blocks everywhere else
+    "a selection at dots3's admission": (
+        (128, 128, 8192, 8192, 192, 128, "bfloat16"), {"topk": 2048},
+        "sparse_kernel"),
+    "a selection over grouped heads": (
+        (32, 2, 2048, 2048, 128, 128, "bfloat16"), {"topk": 512},
+        "sparse_kernel"),
+    "a selection, float32": ((16, 16, 2048, 2048, 64, 64, "float32"),
+                             {"topk": 512}, "sparse_kernel"),
+    "a selection over scores the chip keeps": (
+        (8, 8, 1024, 1024, 64, 64, "bfloat16"), {"topk": 256}, "sparse"),
+    "a selection, T no multiple of a block": (
+        (16, 16, 2000, 2000, 64, 64, "bfloat16"), {"topk": 512}, "sparse"),
+    "a selection, mixed types": (
+        (16, 16, 2048, 2048, 64, 64, ("bfloat16", "float32")),
+        {"topk": 512}, "sparse"),
+    "a selection in a toy model": ((2, 2, 16, 16, 8, 8, "float32"),
+                                   {"topk": 4}, "sparse"),
 }
 
 
@@ -247,9 +266,10 @@ def test_the_rule_names_the_form_from_shapes_attributes_and_backend(
     ops = (struct(qt, 1, h, t, dk), struct(kt, 1, hkv, s, dk),
            struct(kt, 1, hkv, s, dv))
     args = (attrs.get("causal", True), attrs.get("window", 0),
-            attrs.get("sink", False))
+            attrs.get("sink", False), None, attrs.get("topk", 0))
     monkeypatch.setenv("MXNET_USE_PALLAS_ATTENTION", "1")
-    off_chip = on_chip if on_chip == "band" else "dense"
+    off_chip = on_chip if on_chip == "band" else \
+        "sparse" if "topk" in attrs else "dense"
     assert attn_op.attention_form(*ops, *args) == off_chip
     monkeypatch.setattr(attn_op, "_backend", lambda: "tpu")
     assert attn_op.attention_form(*ops, *args) == on_chip
@@ -266,14 +286,18 @@ def test_the_rule_keeps_a_step_over_several_devices_dense(monkeypatch):
 
     monkeypatch.setattr(attn_op, "_backend", lambda: "tpu")
     struct = lambda b: jax.ShapeDtypeStruct((b, 16, 2048, 128), jnp.bfloat16)
-    form = lambda b, mesh: attn_op.attention_form(
-        struct(b), struct(b), struct(b), True, 0, False, mesh)
+    form = lambda b, mesh, topk=0: attn_op.attention_form(
+        struct(b), struct(b), struct(b), True, 0, False, mesh, topk)
     one = parallel.make_mesh({"data": 1}, devices=jax.devices()[:1])
     data = parallel.make_mesh({"data": 4}, devices=jax.devices()[:4])
     seq = parallel.make_mesh({"data": 2, "seq": 2}, devices=jax.devices()[:4])
     assert form(1, None) == form(1, one) == form(4, None) == "kernel"
     assert form(4, data) == "dense"
     assert form(4, seq) == "ring"
+    # a learned selection: the masked kernel on one device, XLA's query
+    # blocks under any mesh of several
+    assert form(1, None, 512) == form(1, one, 512) == "sparse_kernel"
+    assert form(4, data, 512) == form(4, seq, 512) == "sparse"
 
 
 def test_the_op_runs_the_form_the_rule_names_and_counts_it(monkeypatch):
@@ -295,6 +319,206 @@ def test_the_op_runs_the_form_the_rule_names_and_counts_it(monkeypatch):
     assert attn_op.DISPATCH_COUNTS["dense"] == before["dense"] + 1
     np.testing.assert_allclose(np.asarray(kernel), np.asarray(dense),
                                rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------------------- the kernel under a selection
+def _index_operands(hi, t, di, dtype, seed=5, whole=False):
+    """An indexer's (index_query (1, Hi, T, di), index_key (1, 1, T, di),
+    index_weight (1, T, Hi)); ``whole``: small whole numbers, so that index
+    scores tie in droves (and are exactly 0 under the ReLU)."""
+    import jax.numpy as jnp
+
+    rs = np.random.RandomState(seed)
+    draw = (lambda *shape: rs.randint(-1, 2, size=shape)) if whole \
+        else rs.randn
+    return tuple(jnp.asarray(draw(*shape).astype("float32"), dtype)
+                 for shape in ((1, hi, t, di), (1, 1, t, di), (1, t, hi)))
+
+
+def _masked_softmax(q, k, v, allowed):
+    """Full float64 scores under a mask (T, S): the plain reference."""
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    k, v = (np.repeat(a, q.shape[1] // a.shape[1], axis=1) for a in (k, v))
+    s = np.einsum("bhtd,bhsd->bhts", q, k) / np.sqrt(q.shape[-1])
+    s = np.where(allowed, s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return np.einsum("bhts,bhsd->bhtd", p / p.sum(-1, keepdims=True), v)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("widths", [(128, 128), (192, 128)])
+@pytest.mark.parametrize("t", [256, 512])
+@pytest.mark.parametrize("group", [1, 2])
+def test_the_masked_kernel_interpreted_is_the_sparse_form(monkeypatch, dtype,
+                                                          widths, t, group):
+    """The blockwise kernel under ``_selection``'s mask against
+    ``_sparse_attention`` (XLA's query blocks) over one grid: both types,
+    the cell's widths (a key of 192 over a value of 128), a selection of 64
+    keys over 256 and 512 positions in kernel blocks of 128 and query blocks
+    of 32 in eight groups, so that rows below and above ``topk``, several
+    groups and several key blocks all occur; the mask shared by the heads of
+    a group and across groups. The same types in the same places: float32
+    to a float32 sum's order, bfloat16 to one rounding of the output."""
+    from mxnet_tpu.ops import attention as attn_op
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    heads, topk = 2 * group, 64
+    q, k, v = _operands(heads, 2, t, t, *widths, dtype)
+    index = _index_operands(4, t, 16, dtype)
+    monkeypatch.setattr(attn_op, "_SCORE_BYTES", 4 * heads * t * 32)
+    scale = widths[0] ** -0.5
+    want = attn_op._sparse_attention(q, k, v, *index, topk, scale)
+    selected = attn_op._selection(heads, *index, topk)
+    assert selected.shape == (1, t, t) and selected.dtype == np.int8
+    chosen = np.asarray(selected[0]).sum(axis=1)
+    assert (chosen == np.minimum(np.arange(t) + 1, topk)).all()
+    got = pa.flash_attention(q, k, v, causal=True, scale=scale, block_q=128,
+                             block_k=128, interpret=True, selected=selected)
+    assert got.shape == want.shape == (1, heads, t, widths[1])
+    assert got.dtype == want.dtype == q.dtype
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(np.asarray(got, "float32"),
+                               np.asarray(want, "float32"), rtol=tol, atol=tol)
+
+
+def test_a_row_with_no_selected_key_in_its_first_blocks_forgets_them():
+    """The masked value is finite: a row whose first key blocks hold no
+    selected key accumulates ``exp(0)`` of every masked score there, and the
+    first real key's maximum must wipe that to exactly nothing (``alpha =
+    0``). Rows that select keys of their LAST block alone, of a middle
+    block alone and one key only, in blocks of 16 over 64 positions, against
+    the plain float64 softmax under the same mask."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    t = 64
+    q, k, v = _operands(4, 2, t, t, 32, 16, "float32")
+    allowed = np.tril(np.ones((t, t), bool))
+    allowed[40, :32] = False            # its last two key blocks alone
+    allowed[50] = False
+    allowed[50, 20:30] = True           # a middle block (two of them) alone
+    allowed[63] = False
+    allowed[63, 63] = True              # one key, the diagonal's
+    allowed[33, :32] = False            # the crossed block alone
+    # the kernel applies the causal mask itself: ones above the diagonal
+    # select nothing
+    selected = jnp.asarray(allowed | ~np.tril(np.ones((t, t), bool)),
+                           jnp.int8)[None]
+    got = pa.flash_attention(q, k, v, causal=True, block_q=16, block_k=16,
+                             interpret=True, selected=selected)
+    want = _masked_softmax(q, k, v, allowed)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-6)
+    # and two batch rows each take their own mask, whatever the heads
+    both = pa.flash_attention(
+        *(jnp.concatenate([a, a]) for a in (q, k, v)), causal=True,
+        block_q=16, block_k=16, interpret=True, selected=jnp.concatenate(
+            [selected, jnp.ones_like(selected)]))
+    np.testing.assert_array_equal(np.asarray(both[0]), np.asarray(got[0]))
+    np.testing.assert_allclose(
+        np.asarray(both[1]), _masked_softmax(q, k, v, np.tril(allowed | True))
+        [0], rtol=2e-5, atol=2e-6)
+
+
+def test_tied_index_scores_go_to_the_lower_positions_in_the_kernels_mask():
+    """Index scores that tie at the ``topk``-th place (whole numbers: a
+    third of them exactly 0 under the ReLU): the mask the kernel is handed
+    holds ``jax.lax.top_k``'s set, the lower positions of a tie, whatever
+    the query blocks; and the kernel under it equals full scores under the
+    scattered ``top_k`` indices."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import attention as attn_op
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    t, topk = 128, 24
+    iq, ik, iw = _index_operands(2, t, 8, "float32", whole=True)
+    score = np.asarray(attn_op.index_scores(iq[0].transpose(1, 0, 2), iw[0],
+                                            ik[0, 0]))
+    causal = np.tril(np.ones((t, t), bool))
+    _, at = jax.lax.top_k(jnp.where(causal, score, -jnp.inf), topk)
+    want = np.zeros((t, t), bool)
+    want[np.arange(t)[:, None], np.asarray(at)] = True
+    want &= causal
+    kth = np.sort(np.where(causal, score, -np.inf), axis=1)[:, -topk]
+    tied = ((np.where(causal, score, -np.inf) == kth[:, None]).sum(1) > 1)
+    assert tied[topk:].sum() > t // 2       # the rule is exercised
+    for heads in (1, 16):                   # one block of queries, sixteen
+        saved, attn_op._SCORE_BYTES = attn_op._SCORE_BYTES, 4 * t * 8 * 16
+        try:
+            got = attn_op._selection(heads, iq, ik, iw, topk)
+        finally:
+            attn_op._SCORE_BYTES = saved
+        np.testing.assert_array_equal(np.asarray(got[0], bool), want)
+    q, k, v = _operands(2, 2, t, t, 16, 16, "float32")
+    out = pa.flash_attention(q, k, v, causal=True, block_q=32, block_k=32,
+                             interpret=True, selected=got)
+    np.testing.assert_allclose(np.asarray(out),
+                               _masked_softmax(q, k, v, want),
+                               rtol=2e-5, atol=2e-6)
+
+
+def test_under_a_selection_a_block_above_the_diagonal_is_never_read():
+    """As the plain kernel's: keys and values past the first block of
+    queries' diagonal are NaN and the MASK says "selected" everywhere above
+    the diagonal; the first block's rows are what the clean operands give,
+    bit for bit (a block above the diagonal is not run, and inside the
+    crossed block the causal mask holds whatever the selection says)."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    t = 64
+    q, k, v = _operands(4, 2, t, t, 128, 128, "float32")
+    rs = np.random.RandomState(11)
+    below = (rs.rand(t, t) < 0.5) | np.eye(t, dtype=bool)
+    above = ~np.tril(np.ones((t, t), bool))
+    run = lambda k, v, mask: np.asarray(pa.flash_attention(
+        q, k, v, causal=True, block_q=16, block_k=16, interpret=True,
+        selected=jnp.asarray(mask, jnp.int8)[None]))
+    poison = lambda a: a.at[:, :, 16:].set(jnp.nan)
+    clean = run(k, v, below & ~above)
+    dirty = run(poison(k), poison(v), below | above)
+    np.testing.assert_array_equal(dirty[:, :, :16], clean[:, :, :16])
+    assert np.isnan(dirty[:, :, 16:]).all()
+
+
+def test_the_op_runs_a_selection_through_the_kernel_and_counts_it(
+        monkeypatch):
+    """``MultiHeadAttention(topk=)`` off the chip is XLA's query blocks and
+    counts ``"sparse"``; with the rule held to ``"sparse_kernel"`` the same
+    call makes the mask, runs the kernel interpreted and counts that. Its
+    gradient is the XLA form's (the kernel has no backward under a
+    selection, and says so where it is differentiated alone)."""
+    from mxnet_tpu.ops import attention as attn_op
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    t = 64
+    q, k, v = _operands(4, 2, t, t, 16, 8, "float32")
+    index = _index_operands(4, t, 8, "float32")
+    before = dict(attn_op.DISPATCH_COUNTS)
+    sparse = _mha(q, k, v, *index, topk=16)
+    assert attn_op.DISPATCH_COUNTS["sparse"] == before["sparse"] + 1
+    loss = lambda q, k, v: (_mha(q, k, v, *index, topk=16) ** 2).sum()
+    want = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    monkeypatch.setattr(attn_op, "attention_form",
+                        lambda *a: "sparse_kernel")
+    kernel = _mha(q, k, v, *index, topk=16)
+    assert attn_op.DISPATCH_COUNTS["sparse_kernel"] \
+        == before["sparse_kernel"] + 1
+    # (the one call and the gradient's trace)
+    assert attn_op.DISPATCH_COUNTS["sparse"] == before["sparse"] + 2
+    np.testing.assert_allclose(np.asarray(kernel), np.asarray(sparse),
+                               rtol=2e-5, atol=2e-5)
+    got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5)
+    selected = attn_op._selection(4, *index, 16)
+    with pytest.raises(NotImplementedError, match="not differentiable"):
+        jax.grad(lambda q: pa.flash_attention(
+            q, k, v, causal=True, interpret=True,
+            selected=selected).sum())(q)
 
 
 # ------------------------------------------------------- flash attention grads

@@ -397,6 +397,48 @@ def test_the_sparse_prefill_is_the_topk_mask_in_query_blocks(monkeypatch, t,
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < 2e-6
 
 
+def test_the_block_with_the_rule_held_to_the_kernel_is_the_references(
+        monkeypatch):
+    """The block's prefill with ``attention_form`` held to
+    ``"sparse_kernel"`` for its three full layers (what the chip's rule
+    answers at the cell's shapes; here the kernel is interpreted): the mask
+    of every layer reaches the blockwise kernel, the admission's logits are
+    the reference's inside the float32 limit the XLA form holds, the kept
+    selection is the reference's ``top_k``, and the warm-up's gauges name
+    three kernel layers under a selection and no XLA one."""
+    rule = attention.attention_form
+    monkeypatch.setattr(
+        attention, "attention_form", lambda *a: "sparse_kernel"
+        if a[7] > 0 else rule(*a))
+    params = _weights()
+    before = dict(attention.DISPATCH_COUNTS)
+    telemetry.reset()
+    saved = telemetry.current_override()
+    telemetry.set_mode("counters")
+    try:
+        dec = _decoder(params).warmup()
+        forms = {form: telemetry.gauge(
+            "serving.prefill_attention.%s_layers" % form).value
+            for form in ("sparse_kernel", "sparse", "band")}
+    finally:
+        telemetry.set_mode(saved)
+        telemetry.reset()
+    assert forms == {"sparse_kernel": 3, "sparse": 0, "band": 3}
+    assert attention.DISPATCH_COUNTS["sparse_kernel"] \
+        == before["sparse_kernel"] + 3
+    assert attention.DISPATCH_COUNTS["sparse"] == before["sparse"]
+    for length in (56, 64):
+        toks = _tokens(T, seed=length)
+        seq, logits = dec.admit(np.asarray(toks[:length], np.float32))
+        chosen, = _kept(dec, seq, "sparse_sel_0")
+        dec.retire(seq)
+        want = np.asarray(REF_LOGITS(params, jnp.asarray(toks)))[length - 1]
+        assert _rel_l2(np.asarray(logits), want).max() < F32_TOL
+        allowed = np.asarray(REF_SELECTED(params, jnp.asarray(toks),
+                                          jnp.asarray((length - 1,))))[0]
+        assert _chosen(chosen) == np.nonzero(allowed)[0].tolist()
+
+
 def test_topk_refuses_a_window_a_sink_and_cross_attention():
     q, k, v = _qkv(16)
     iq, ik, iw = _indexer(16)

@@ -165,6 +165,23 @@ def test_admit_pool_update_stays_in_place_on_the_chip(v5e):
     _assert_no_pool_sized_copy(compiled.as_text(), heads * slots * dh)
 
 
+@pytest.mark.parametrize("heads,kv_heads,t,dk,dv", [
+    (128, 128, 8192, 192, 128),   # dots3-note-prev's full layer, the cell's
+    (32, 2, 2048, 128, 128),      # a group's rows share the mask's block
+])
+def test_flash_attention_under_a_selection(v5e, heads, kv_heads, t, dk, dv):
+    """The forward kernel with a selection's int8 mask (B, T, S) beside its
+    operands, at the rule's blocks (``blocks(..., selected=True)``: the
+    mask's two buffers counted), through Mosaic for the v5e."""
+    assert pa.blocks(t, t, heads // kv_heads, dk, dv, jnp.bfloat16, True) \
+        == (1024 * kv_heads // heads or 64, 1024)
+    spec = lambda h, d: ((1, h, t, d), "bfloat16")
+    fn = lambda q, k, v, selected: pa.flash_attention(
+        q, k, v, causal=True, interpret=False, selected=selected)
+    _compile(v5e, fn, spec(heads, dk), spec(kv_heads, dk), spec(kv_heads, dv),
+             ((1, t, t), "int8"))
+
+
 def _paged_read_calls(hlo):
     """The program's calls of the kernel that walks the page table
     (``ops/pallas_paged_read.py``), a line each."""
@@ -179,14 +196,19 @@ def _assert_attention_is_blockwise(hlo, layers, bucket):
     in the program is a layer's scores: no float32 buffer of heads x
     ``bucket`` x ``bucket`` (``f32[..., 16, 2048, 2048]``, whatever
     dimensions of 1 stand among them)."""
-    calls = [line for line in hlo.splitlines() if " custom-call(" in line
-             and 'custom_call_target="tpu_custom_call"' in line
-             and "/flash_attention" in line]
-    assert len(calls) == layers, (len(calls), layers)
+    assert len(_flash_attention_calls(hlo)) == layers
     scores = [dims for dims in re.findall(r"f32\[([\d,]+)\]", hlo)
               if [int(d) for d in dims.split(",") if int(d) != 1][1:]
               [-2:] == [bucket, bucket]]
     assert not scores, scores[:4]
+
+
+def _flash_attention_calls(hlo):
+    """The program's calls of the blockwise attention kernel
+    (``ops/pallas_attention.py``), a line each."""
+    return [line for line in hlo.splitlines() if " custom-call(" in line
+            and 'custom_call_target="tpu_custom_call"' in line
+            and "/flash_attention" in line]
 
 
 def _assert_expert_layers(hlo, layers, tokens, k, experts, d, f,
@@ -291,6 +313,10 @@ _ATTENTION_LAYERS = {
     "granite-4.0-h-micro.generate": ((32, 8, 512, 64, 64), "dense", None),
     "transformer-base.generate": ((8, 8, 1024, 64, 64), "dense", None),
     "transformer-base.score": ((8, 8, 1024, 64, 64), "dense", None),
+    # its full layers, under the selection of 2,048 (the mask's two buffers
+    # fit beside the blocks of a plain call)
+    "dots3-note-prev.generate": ((128, 128, 8192, 192, 128), "sparse_kernel",
+                                 (1024, 1024)),
 }
 
 
@@ -309,12 +335,14 @@ def test_the_attention_rules_at_the_cells_shapes(cell):
         else jnp.bfloat16
     struct = lambda heads, d: jax.ShapeDtypeStruct((1, heads, t, d), dtype)
     ops = struct(h, dk), struct(hkv, dk), struct(hkv, dv)
-    assert attention.attention_form(*ops, True) == form
+    selected = form == "sparse_kernel"
+    assert attention.attention_form(*ops, True, 0, False, None,
+                                    2048 if selected else 0) == form
     assert attention.attention_form(*ops, True, 128) == "band"
     assert attention.attention_form(*ops, True, 0, True) == "dense"
     assert attention.attention_form(*ops, False) == "dense"
     if blocks:
-        assert pa.blocks(t, t, h // hkv, dk, dv, dtype) == blocks
+        assert pa.blocks(t, t, h // hkv, dk, dv, dtype, selected) == blocks
 
 
 def _assert_no_pool_sized_copy(hlo, pool):
@@ -521,6 +549,8 @@ def test_olmoe_serving_programs_compile_for_the_chip(v5e, program):
         assert 0.29e12 < flops < 0.35e12
         _assert_one_row_of_logits(compiled, max_len, 50304)
         _assert_attention_is_blockwise(compiled.as_text(), 1, max_len)
+        assert _program_sha1(compiled.as_text()) \
+            == _PLAIN_KERNEL_PREFILLS["olmoe"]
         assert [str(s.dtype) for s in compiled.out_info[0]] \
             == ["float32", "bfloat16", "bfloat16", "float32"]
     else:
@@ -950,6 +980,44 @@ def _program_alone(hlo):
                   "\n\n".join(blocks))
 
 
+def _program_sha1(hlo):
+    """sha1 of a compiled program's text as ``_program_alone`` leaves it,
+    with every Mosaic kernel's serialized body (MLIR bytecode, which carries
+    the file and line of each operation it was traced from) replaced by its
+    assembly WITHOUT those locations: two trees whose programs are equal
+    instruction for instruction answer the same, wherever their lines sit."""
+    import base64
+    import hashlib
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    def assembly(found):
+        ctx = mlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True   # the serialized dialect's
+        with ctx:
+            kernel = ir.Module.parse(base64.b64decode(found.group(1)))
+            return '"body":%r' % kernel.operation.get_asm(
+                enable_debug_info=False)
+
+    text = re.sub(r'"body":"([A-Za-z0-9+/=]+)"', assembly,
+                  _program_alone(hlo))
+    return hashlib.sha1(text.encode()).hexdigest()
+
+
+# the prefills of two cells whose attention is the PLAIN causal kernel, as
+# the tests above compile them, at PR 52's tree (6ce9d69): what a change to
+# ``ops/pallas_attention.py`` that teaches the kernel something a plain call
+# does not use (PR 53: a selection's mask) must leave them. A PR that moves
+# these programs on purpose writes its own two lines here.
+_PLAIN_KERNEL_PREFILLS = {
+    "olmoe": "6d9ffceb605f56f469f17e0bf2cd4633dc979432",
+    "nemotron_h": "84307b1188cbe3ab689ddf67c8a18f67865b82b5",
+}
+
+
 # cell -> (the builder's sizes, lanes, slots a lane, weights' type, attention
 # layers, (key/value heads, width))
 _NARROW_POOL_CELLS = {
@@ -1280,6 +1348,7 @@ def test_nemotron_h_serving_programs_compile_for_the_chip(v5e, program):
         assert 2.5e12 < compiled.cost_analysis()["flops"] < 3.4e12
         assert mem.temp_size_in_bytes < 1 << 30
         _assert_attention_is_blockwise(hlo, 2, bucket)
+        assert _program_sha1(hlo) == _PLAIN_KERNEL_PREFILLS["nemotron_h"]
         return
     assert compiled.out_info[0][1].shape == (lanes, 64, 64, 128)
     assert compiled.out_info[0][7].shape == (slots // page, page, 256)
@@ -1324,8 +1393,9 @@ def test_dots3_note_serving_programs_compile_for_the_chip(v5e, program):
     window layer ONE ring (32, 1, 513, 1,088), in layer order, each back in
     the type it went in and updated in place; the prefill makes nothing of a
     head's 8,192 x 8,192 pairs (neither the index logits' 17 GB nor the
-    scores'): the largest thing made is a block's scores inside
-    ``_SCORE_BYTES``, and it fits beside the weights and the cache with room;
+    scores'): a full layer's masked attention is one call of the blockwise
+    kernel under the layer's int8 mask, and it fits beside the weights and
+    the cache with room;
     both graphs run the five expert layers' grouped matmuls as the kernel
     over the 16 held experts; the step scores no mask over the pool."""
     from types import SimpleNamespace
@@ -1404,6 +1474,17 @@ def test_dots3_note_serving_programs_compile_for_the_chip(v5e, program):
         # made; a block's float32 scores stay inside the operator's budget
         assert max(found) <= 8 * bucket * 5120
         assert attention._SCORE_BYTES // 4 < 8 * bucket * 5120
+        # a full layer's masked attention is ONE call of the blockwise
+        # kernel under the layer's mask, (1, 8192, 8192) int8 made a block of
+        # 128 queries at a time, and nothing is a query block's float32
+        # scores of 128 heads (``f32[1,128,1,128,<keys>]``)
+        assert len(_flash_attention_calls(hlo)) == 3
+        dims = lambda kind: [[int(d) for d in found.split(",") if int(d) != 1]
+                             for found in re.findall(kind + r"\[([\d,]+)\]",
+                                                     hlo)]
+        assert [bucket, bucket] in dims("s8")
+        scores = [d for d in dims("f32") if d[:2] == [128, 128] and len(d) == 3]
+        assert not scores, scores[:4]
         _assert_one_row_of_logits(compiled, bucket, 19008)
         # 4.05 GB when this was written: under 15 beside 6.25 of weights and
         # 1.50 of cache

@@ -10,6 +10,18 @@ backend: the table is what that rule's threshold (``pallas_attention.takes``)
 is held against (PERF.md §6, PR 49).
 
     python tools/attention_bench.py
+
+``--topk`` instead splits ONE full-attention layer of a long admission under
+a learned selection, standing alone at ``dots3-note-prev.generate``'s shapes
+(B = 1, 128 heads of 192 over a value of 128, T = S = 8,192, an indexer of
+64 heads of 128, the 2,048 highest index scores a query, bfloat16), into
+what ``ops/attention.py`` makes it of: the index scores, the ``top_k`` mask
+on top of them, and the masked attention, in XLA's query blocks
+(``_sparse_attention``) and in the blockwise kernel under the mask
+(``attention_form``'s ``"sparse_kernel"``), beside the plain causal kernel
+over the same operands (``PERF.md`` section 6, PR 53).
+
+    python tools/attention_bench.py --topk
 """
 import argparse
 import json
@@ -30,13 +42,98 @@ SHAPES = [
 ]
 
 
+# dots3-note-prev's full layer at the cell's bucket: (heads, T, key width,
+# value width, index heads, index width, topk)
+SPARSE_LAYER = (128, 8192, 192, 128, 64, 128, 2048)
+
+
+def sparse_split(args):
+    """One JSON line: a layer's milliseconds by part (medians of
+    ``--iters`` runs, each a program of its own, timed to
+    ``block_until_ready``), and the kernel's worst difference from XLA's
+    form over the output's largest magnitude."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import attention as attn_op
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    h, t, dk, dv, hi, di, topk = (4, 256, 24, 16, 4, 16, 64) if args.quick \
+        else SPARSE_LAYER
+    dt = jnp.dtype(args.dtype)
+    dev = jax.devices()[0]
+    on_tpu = dev.platform == "tpu"
+    rs = np.random.RandomState(0)
+    draw = lambda *shape: jnp.asarray(rs.randn(*shape).astype("float32"), dt)
+    q, k, v = draw(1, h, t, dk), draw(1, h, t, dk), draw(1, h, t, dv)
+    index = draw(1, hi, t, di), draw(1, 1, t, di), draw(1, t, hi)
+    scale = dk ** -0.5
+
+    def ms(fn, *arrs):
+        fn = jax.jit(fn)
+        out = jax.block_until_ready(fn(*arrs))
+        times = []
+        for _ in range(args.iters):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(*arrs))
+            times.append((time.perf_counter() - t0) * 1e3)
+        return out, round(float(np.median(times)), 3)
+
+    def unranked(*index):
+        # the query blocks with the index scores alone: the mask is a
+        # threshold over them and no ``top_k`` runs
+        ranked, attn_op._topk_mask = attn_op._topk_mask, \
+            lambda score, topk: score > 0
+        try:
+            return attn_op._selection(h, *index, topk)
+        finally:
+            attn_op._topk_mask = ranked
+
+    rec = {"device": dev.device_kind, "dtype": str(dt), "heads": h, "T": t,
+           "widths": [dk, dv], "indexer": [hi, di], "topk": topk,
+           "iters": args.iters,
+           "rule": attn_op.attention_form(q, k, v, True, topk=topk)}
+    _, rec["index_scores_ms"] = ms(unranked, *index)
+    selected, rec["selection_ms"] = ms(
+        lambda *index: attn_op._selection(h, *index, topk), *index)
+    want, rec["layer_xla_ms"] = ms(
+        lambda *a: attn_op._sparse_attention(*a, topk, scale), q, k, v,
+        *index)
+    got, rec["layer_kernel_ms"] = ms(
+        lambda *a: attn_op._sparse_kernel(*a, topk, scale, not on_tpu),
+        q, k, v, *index)
+    _, rec["kernel_masked_ms"] = ms(
+        lambda q, k, v, selected: pa.flash_attention(
+            q, k, v, causal=True, scale=scale, interpret=not on_tpu,
+            selected=selected), q, k, v, selected)
+    _, rec["kernel_causal_ms"] = ms(
+        lambda q, k, v: pa.flash_attention(
+            q, k, v, causal=True, scale=scale, interpret=not on_tpu), q, k, v)
+    rec["topk_mask_ms"] = round(rec["selection_ms"]
+                                - rec["index_scores_ms"], 3)
+    rec["attention_xla_ms"] = round(rec["layer_xla_ms"]
+                                    - rec["selection_ms"], 3)
+    rec["selected_share"] = round(float(jnp.mean(
+        selected.astype(jnp.float32))), 4)
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    rec["max_err_over_max"] = float(np.abs(got - want).max()
+                                    / np.abs(want).max())
+    print(json.dumps(rec))
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--quick", action="store_true",
                     help="one tiny shape (CPU plumbing smoke)")
+    ap.add_argument("--topk", action="store_true",
+                    help="split one full layer under a learned selection "
+                         "(dots3-note-prev's shapes) instead")
     args = ap.parse_args()
+    if args.topk:
+        return sparse_split(args)
     shapes = [(2, 2, 128, 64, True)] if args.quick else SHAPES
 
     import jax
