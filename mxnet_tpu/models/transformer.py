@@ -12,7 +12,7 @@ from ..base import MXNetError
 from ..ops.attention import _LANES, _NEG, pool_paged
 
 ARCHS = ("vaswani", "olmoe", "granite_hybrid", "deepseek_v3", "lfm2_moe",
-         "mimo_v2_flash", "phi4flash", "nemotron_h", "dots3_note")
+         "mimo_v2_flash", "phi4flash", "nemotron_h", "dots3_note", "ouro")
 
 
 # the block every graph is derived from (ROADMAP D2); the others have the
@@ -357,6 +357,16 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     After the logits come the cache's values in ``decode_cache`` order, a
     Mamba layer's state and columns as ``granite_hybrid``'s, an attention
     layer's K and V; an expert block exports nothing; ``moe_load`` last.
+
+    ``arch="ouro"`` builds the LOOPED stack (``_ouro_layer``: sandwich norms,
+    rotary attention, a gated MLP), the ``num_layers`` layers applied
+    ``total_ut_steps`` times over the same weights, for ONE prompt a call:
+    operators named ``pass<u>_layer<i>_*``, weights ``layer<i>_*``. After
+    every pass the one final norm; the exit gate and the rule of
+    ``_ouro_head`` choose which pass's output, at the prompt's last real row,
+    feeds the head. After the logits come a layer's K (rotated) and V of
+    EVERY pass, pass-major, (passes, H, P, dh), in ``decode_cache`` order;
+    then ``exit_pass (1,)``, the pass that fed the head, counted from 1.
     """
     builders = {"olmoe": _olmoe_prefill_symbol,
                 "granite_hybrid": _granite_prefill_symbol,
@@ -365,7 +375,8 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
                 "mimo_v2_flash": _mimo_prefill_symbol,
                 "phi4flash": _phi4flash_prefill_symbol,
                 "nemotron_h": _nemotron_h_prefill_symbol,
-                "dots3_note": _dots3_prefill_symbol}
+                "dots3_note": _dots3_prefill_symbol,
+                "ouro": _ouro_prefill_symbol}
     if arch in builders:
         return builders[arch](
             vocab_size=vocab_size, num_layers=num_layers,
@@ -593,6 +604,18 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     ``moe_load`` (the rows each of ALL the experts received, held here or
     not), one row an EXPERT layer.
 
+    ``arch="ouro"`` runs ``_ouro_layer`` ``total_ut_steps`` times over: a
+    layer's ONE pool pair ``kv_k_i`` / ``kv_v_i`` holds ``passes x max_len``
+    slots, pass ``u``'s keys and values ``u * max_len`` slots in (whole
+    frames), so pass ``u`` writes slot ``write_slot + u * max_len`` and reads
+    the frames ``page_table + u * max_len / page_size``: one page table and
+    one allocation a lane cover every pass, and a pass never reads another's
+    keys. ``KVPoolSlotWrite`` / ``KVPoolAttention`` / ``KVPageMask`` are the
+    operators every arch has, handed the moved slot and table. A buffer is
+    written ``passes`` times a step, in place where it is donated. The
+    trailing ``greedy_token`` is (B, 2): the token and, riding the same small
+    read, the pass that fed its head (``_ouro_head``).
+
     ``page_size`` is the decoder's (``PagedKVDecoder``'s default here); it
     must divide ``max_len``.
     """
@@ -603,7 +626,8 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
                 "mimo_v2_flash": _mimo_decode_symbol,
                 "phi4flash": _phi4flash_decode_symbol,
                 "nemotron_h": _nemotron_h_decode_symbol,
-                "dots3_note": _dots3_decode_symbol}
+                "dots3_note": _dots3_decode_symbol,
+                "ouro": _ouro_decode_symbol}
     if arch in builders:
         return builders[arch](
             vocab_size=vocab_size, num_layers=num_layers,
@@ -2430,6 +2454,224 @@ def _phi4flash_param_shapes(vocab_size, num_layers, **sizes):
     return shapes
 
 
+# ------------------------------- Ouro (ONE stack of layers, run several times)
+def _ouro_sizes(num_heads, model_dim, ffn_dim, head_dim=None, total_ut_steps=4,
+                early_exit_threshold=1.0, rope_theta=1e6, rms_eps=1e-6,
+                dtype="float32", **kwargs):
+    """``_ouro_layer``'s keywords from a builder's (defaults: Ouro-2.6B's;
+    keywords of the other architectures are dropped)."""
+    passes = int(total_ut_steps)
+    if passes < 1:
+        raise MXNetError("ouro: total_ut_steps must be at least 1, got %r"
+                         % (total_ut_steps,))
+    return dict(num_heads=num_heads,
+                head_dim=head_dim or model_dim // num_heads,
+                model_dim=model_dim, ffn_dim=ffn_dim, passes=passes,
+                exit_threshold=float(early_exit_threshold),
+                rope_theta=rope_theta, rms_eps=rms_eps, dtype=dtype)
+
+
+def loop_passes(arch, **sizes):
+    """How many times a graph of ``arch`` applies its stack of layers: every
+    pass keeps keys and values of its own, so a pool of ``decode_cache`` is
+    bound with that many times the decoder's slots, pass ``u`` in frames
+    ``u * frames`` onward (1 for every arch that runs its layers once)."""
+    return _ouro_sizes(**sizes)["passes"] if arch == "ouro" else 1
+
+
+def _shared_variables():
+    """``var(name)``: THE Variable of that name in the graph being built. A
+    looped stack names an operator by (pass, layer) and its weight by layer
+    alone, and two Variables of one name would be two arguments."""
+    made = {}
+    return lambda name: made.setdefault(name, sym.Variable(name))
+
+
+def _ouro_layer(x, u, i, positions, seq_len, attend, block, var):
+    """Layer ``i`` in pass ``u`` of the looped stack, on x (B, T, M): the
+    SAME weights in every pass (``layer<i>_*``, through ``var``), operators
+    named ``pass<u>_layer<i>_*``. Sandwich norms, four RMSNorms a layer:
+    ``x + ln2(W_o attend(rope(q), rope(k), v))`` of ``ln1(x)``'s fused
+    bias-free q, k, v (rotary half-split pairs over the whole head), then
+    ``x + ln4(gated SiLU MLP(ln3(x)))``. ``attend(u, i, q, k, v)`` takes the
+    head-major (B, H, T, dh) tensors and returns (B, H, T, dh): over the
+    bucket in the prefill, over pass ``u``'s own keys and values of layer
+    ``i`` in the pool in a decode step."""
+    name = "pass%d_layer%d" % (u, i)
+    h, dh, eps = block["num_heads"], block["head_dim"], block["rms_eps"]
+    fc = lambda data, width, tag, **kw: sym.FullyConnected(
+        data=data, weight=var("layer%d_%s_weight" % (i, tag)),
+        num_hidden=width, no_bias=True, flatten=False,
+        name="%s_%s" % (name, tag), **kw)
+    norm = lambda data, tag: sym.RMSNorm(
+        data, var("layer%d_%s_gamma" % (i, tag)), eps=eps,
+        name="%s_%s" % (name, tag))
+    q, k, v = _grouped_qkv(fc, norm(x, "ln1"), seq_len, h, h, dh)
+    q = sym.RotaryEmbedding(q, positions, base=block["rope_theta"],
+                            name="%s_qrope" % name)
+    k = sym.RotaryEmbedding(k, positions, base=block["rope_theta"],
+                            name="%s_krope" % name)
+    att = _merge_heads(attend(u, i, q, k, v), seq_len, h * dh)
+    x = x + norm(fc(att, block["model_dim"], "proj"), "ln2")
+    return x + norm(_gated_mlp(fc, norm(x, "ln3"), block["ffn_dim"],
+                               block["model_dim"], "mlp"), "ln4")
+
+
+def _ouro_passes(data, vocab_size, num_layers, positions, seq_len, attend,
+                 block, var, rows):
+    """The embedding and the stack ``passes`` times over: after layer N - 1 of
+    every pass the ONE final norm, whose output the next pass starts from.
+    ``rows(x)`` takes what the head may need of a pass's output, (R, M) rows
+    (the prompt's last real row; a step's lanes). Returns those rows, a
+    pass each."""
+    x = sym.Embedding(data=data, weight=var("embed_weight"),
+                      input_dim=vocab_size, output_dim=block["model_dim"],
+                      name="embed")
+    handed = []
+    for u in range(block["passes"]):
+        for i in range(num_layers):
+            x = _ouro_layer(x, u, i, positions, seq_len, attend, block, var)
+        x = sym.RMSNorm(x, var("final_ln_gamma"), eps=block["rms_eps"],
+                        name="pass%d_final_ln" % u)
+        handed.append(rows(x))
+    return handed
+
+
+def _ouro_head(handed, vocab_size, block, var):
+    """Which pass feeds the head, and the head: ``handed[u]`` (R, M) is
+    pass u + 1's output h. The exit gate reads every pass but the last:
+    ``lambda_u = sigmoid(w_g . h_u + b_g)`` in float32, ``p_u = lambda_u
+    prod_{j<u}(1 - lambda_j)``, and the head takes the FIRST pass whose
+    cumulated ``p`` reaches ``early_exit_threshold``, the last pass where
+    none does (the last pass takes what probability is left, so its own
+    gate is never read). Every pass has run by then, whatever is chosen:
+    later tokens attend this token's keys of every pass. Returns (float32
+    logits (R, vocab), the chosen pass (R,) float32, counted from 1)."""
+    passes = len(handed)
+    reached, survive, cum = [], None, None
+    for u, h in enumerate(handed[:-1]):
+        gate = sym.FullyConnected(
+            data=h, weight=var("exit_gate_weight"),
+            bias=var("exit_gate_bias"), num_hidden=1, out_dtype="float32",
+            name="pass%d_exit_gate" % u)
+        lam = sym.Reshape(sym.sigmoid(gate), shape=(-1,))
+        p = lam if survive is None else lam * survive
+        cum = p if cum is None else cum + p
+        survive = 1.0 - lam if survive is None else survive * (1.0 - lam)
+        reached.append(cum >= block["exit_threshold"])
+    # from the last pass down, so the FIRST pass that reached it stays; the
+    # cumulated p never falls, so a pass that reached it is followed by
+    # passes that did: the chosen pass is the last less those that reached it
+    chosen = handed[-1]
+    at = sym.Cast(sym.ones_like(sym.sum(handed[-1], axis=-1)),
+                  dtype="float32") * float(passes)
+    for u in reversed(range(passes - 1)):
+        chosen = sym.where(reached[u], handed[u], chosen)
+        at = at - reached[u]
+    logits = sym.FullyConnected(
+        data=chosen, weight=var("lm_head_weight"), num_hidden=vocab_size,
+        no_bias=True, out_dtype="float32", name="lm_head")
+    return logits, at
+
+
+def _ouro_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
+    block = _ouro_sizes(**sizes)
+    var = _shared_variables()
+    positions = sym.Reshape(sym._arange(start=0, stop=prefill_len),
+                            shape=(1, prefill_len))
+    length = sym.Variable("length")
+    kept = [([], []) for _ in range(num_layers)]  # a layer's K and V, a pass
+
+    def attend(u, i, q, k, v):
+        kept[i][0].append(k)
+        kept[i][1].append(v)
+        return sym.MultiHeadAttention(query=q, key=k, value=v, causal=True,
+                                      name="pass%d_layer%d_att" % (u, i))
+
+    handed = _ouro_passes(
+        sym.Variable("data"), vocab_size, num_layers, positions, prefill_len,
+        attend, block, var, lambda x: sym.Reshape(
+            _last_real_row(x, length), shape=(-1, block["model_dim"])))
+    logits, at = _ouro_head(handed, vocab_size, block, var)
+    # ONE prompt a call: a layer's keys of every pass, pass-major, as the
+    # layer's one pool keeps them (``loop_passes``)
+    cache = [sym.Concat(*one, dim=0, name="layer%d_%s_passes" % (i, t))
+             if len(one) > 1 else one[0]
+             for i, pair in enumerate(kept) for t, one in zip("kv", pair)]
+    return sym.Group([logits] + cache
+                     + [sym.identity(at, name="exit_pass")])
+
+
+def _ouro_decode_symbol(vocab_size, num_layers, num_slots, page_size,
+                        token_out=True, **sizes):
+    block = _ouro_sizes(**sizes)
+    var = _shared_variables()
+    h, dh = block["num_heads"], block["head_dim"]
+    pos_idx = sym.Variable("pos_idx")
+    write_slot = sym.Variable("write_slot")
+    page_table = sym.Variable("page_table")
+    pools = [[sym.Variable("kv_%s_%d" % (t, i)) for t in "kv"]
+             for i in range(num_layers)]
+    # pass u's keys and values of a layer sit ``u * num_slots`` slots into the
+    # layer's ONE pool pair: the lane's page table and write slot, moved by
+    # whole frames, address them; a lane that rides along stays negative
+    writes = write_slot >= 0.0
+    at_pass = []
+    for u in range(block["passes"]):
+        slot = write_slot + writes * float(u * num_slots)
+        pages = dict(page_table=page_table + float(u * num_slots // page_size),
+                     pos_idx=pos_idx, write_slot=slot)
+        at_pass.append((slot, dict(
+            pages, page_size=page_size, mask=sym.KVPageMask(
+                page_size=page_size, num_slots=block["passes"] * num_slots,
+                name="pass%d_kv_mask" % u, **pages))))
+
+    def attend(u, i, q, k_new, v_new):
+        # one token a lane: the head-major (B, H, 1, dh) tensors are the
+        # pool's rows (B, H, dh)
+        q, k_new, v_new = (sym.Reshape(a, shape=(-1, h, dh))
+                           for a in (q, k_new, v_new))
+        slot, read = at_pass[u]
+        upd = sym.KVPoolSlotWrite(
+            pools[i][0], k_new, pools[i][1], v_new, slot, num_pools=2,
+            name="pass%d_layer%d_kvupd" % (u, i))
+        pools[i] = [upd[0], upd[1]]
+        ctx = sym.KVPoolAttention(q, upd[0], upd[1],
+                                  name="pass%d_layer%d_att" % (u, i), **read)
+        return sym.Reshape(ctx, shape=(-1, h, 1, dh))
+
+    handed = _ouro_passes(
+        sym.Variable("data"), vocab_size, num_layers, pos_idx, 1, attend,
+        block, var, lambda x: sym.Reshape(x, shape=(-1, block["model_dim"])))
+    logits, at = _ouro_head(handed, vocab_size, block, var)
+    outs = [logits] + [pool for pair in pools for pool in pair]
+    if token_out:
+        # the greedy token and, riding the same small read, the pass that
+        # fed its head: (B, 2) float32
+        outs.append(sym.Concat(
+            sym.Reshape(sym.argmax(logits, axis=-1), shape=(-1, 1)),
+            sym.Reshape(at, shape=(-1, 1)), dim=1, name="greedy_token"))
+    return sym.Group(outs)
+
+
+def _ouro_param_shapes(vocab_size, num_layers, **sizes):
+    """One entry a LAYER: the passes share them."""
+    block = _ouro_sizes(**sizes)
+    d, f = block["model_dim"], block["ffn_dim"]
+    width = block["num_heads"] * block["head_dim"]
+    shapes = {"embed_weight": (vocab_size, d), "final_ln_gamma": (d,),
+              "exit_gate_weight": (1, d), "exit_gate_bias": (1,),
+              "lm_head_weight": (vocab_size, d)}
+    for i in range(num_layers):
+        n = "layer%d_" % i
+        shapes.update({n + "ln%d_gamma" % j: (d,) for j in (1, 2, 3, 4)})
+        shapes.update({n + "qkv_weight": (3 * width, d),
+                       n + "proj_weight": (d, width),
+                       n + "mlp_in_weight": (2 * f, d),
+                       n + "mlp_out_weight": (d, f)})
+    return shapes
+
+
 def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
                  **sizes):
     """What a decode graph of ``arch`` keeps between steps, in the order its
@@ -2459,7 +2701,12 @@ def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
     2 * head_dim, ``_diff_queries``), in the pool and in the window layers'
     rings alike; a Mamba-1 layer keeps its state (N, E), STATE-major (the
     minor dimension whole tiles of the chip's lanes), and its last
-    convolution columns (K - 1, E), float32 rows."""
+    convolution columns (K - 1, E), float32 rows.
+
+    A LOOPED stack (``ouro``) keeps every pass's keys and values and lists a
+    layer's pools ONCE: the buffer has ``loop_passes`` times the decoder's
+    slots, pass u a whole pass's frames behind pass u - 1 (96 buffers for 48
+    layers x 4 passes, not 384; a lane's page stands for all four)."""
     if arch == "phi4flash":
         block = _phi4flash_sizes(num_layers, num_heads=num_heads,
                                  model_dim=model_dim, head_dim=head_dim,
@@ -2566,12 +2813,13 @@ def param_shapes(arch, vocab_size, num_layers, num_heads, model_dim, ffn_dim,
             vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
             ffn_dim=ffn_dim, num_experts=num_experts, **kwargs)
     if arch in ("lfm2_moe", "mimo_v2_flash", "phi4flash", "nemotron_h",
-                "dots3_note"):
+                "dots3_note", "ouro"):
         shapes = {"lfm2_moe": _lfm2_moe_param_shapes,
                   "mimo_v2_flash": _mimo_param_shapes,
                   "phi4flash": _phi4flash_param_shapes,
                   "nemotron_h": _nemotron_h_param_shapes,
-                  "dots3_note": _dots3_param_shapes}[arch]
+                  "dots3_note": _dots3_param_shapes,
+                  "ouro": _ouro_param_shapes}[arch]
         return shapes(
             vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
             ffn_dim=ffn_dim, head_dim=head_dim, num_experts=num_experts,

@@ -776,7 +776,9 @@ class _AdmitScatter(_SealedProgram):
     lane's ring, each at its position mod the window.
 
     ``run(dec, new, frames, length, lane)``: ``new`` is the prefill
-    executable's cache outputs, ``(1, H, prefill_len, dh)`` for a pool and
+    executable's cache outputs, ``(1, H, prefill_len, dh)`` for a pool
+    (``(passes, H, prefill_len, dh)`` where the stack is looped: pass u's
+    rows land a whole pass's frames behind pass u - 1's, same table) and
     ``(1,) + row`` for a per-lane buffer, ``(1, H, prefill_len, d)`` again
     for a ring (the sealed inputs), ``frames`` the
     lane's page-frame table, ``length`` the prompt length, ``lane`` the
@@ -818,33 +820,42 @@ class _AdmitScatter(_SealedProgram):
 
         def run(bufs, new, frames, at):
             length, lane = at[0], at[1]
-            # (H, T, d) a pool; for a page-major one (T, H * d), a token's
-            # heads side by side as its pool keeps them
-            rows = [new[j][0].transpose(1, 0, 2).reshape(
-                        new[j].shape[2], -1) if pm else new[j][0]
+            # a pool's rows, an array a PASS (one pass for every arch but a
+            # looped one, whose prefill hands a layer's passes over
+            # together), cut apart HERE, outside the page walk: (H, T, d);
+            # for a page-major pool (T, H * d), a token's heads side by side
+            # as its pool keeps them
+            rows = [tuple(new[j][u].transpose(1, 0, 2).reshape(
+                              new[j].shape[2], -1) if pm else new[j][u]
+                          for u in range(new[j].shape[0]))
                     for j, pm in zip(pools, paged)]
             if tail:  # so a page-sized slice never runs off the end
-                rows = [jnp.pad(r, ((0, tail), (0, 0)) if pm
-                                else ((0, 0), (0, tail), (0, 0)))
-                        for r, pm in zip(rows, paged)]
+                rows = [tuple(jnp.pad(one, ((0, tail), (0, 0)) if pm
+                                      else ((0, 0), (0, tail), (0, 0)))
+                              for one in r) for r, pm in zip(rows, paged)]
             in_page = jnp.arange(ps, dtype=jnp.int32)[None, :, None]
 
             def page(j, kvs):
-                dst = frames[j] * ps
                 live = in_page < length - j * ps
                 out = []
                 for kv, r, pm in zip(kvs, rows, paged):
-                    if pm:      # (1, page, H * d) at frame frames[j]
-                        at = (frames[j], 0, 0)
-                        blk = jax.lax.dynamic_slice(
-                            r, (j * ps, 0), (ps, r.shape[1]))[None]
-                    else:       # (H, page, d) at slot frames[j] * page
-                        at = (0, dst, 0)
-                        blk = jax.lax.dynamic_slice(
-                            r, (0, j * ps, 0), (kv.shape[0], ps, kv.shape[2]))
-                    old = jax.lax.dynamic_slice(kv, at, blk.shape)
-                    out.append(jax.lax.dynamic_update_slice(
-                        kv, jnp.where(live, blk, old), at))
+                    # pass u of a pool sits a whole pass's frames further on
+                    for u, one in enumerate(r):
+                        if pm:  # (1, page, H * d) at frame frames[j]
+                            at = (frames[j] + u * (kv.shape[0] // len(r)),
+                                  0, 0)
+                            blk = jax.lax.dynamic_slice(
+                                one, (j * ps, 0), (ps, one.shape[1]))[None]
+                        else:   # (H, page, d) at slot frames[j] * page
+                            at = (0, frames[j] * ps
+                                  + u * (kv.shape[1] // len(r)), 0)
+                            blk = jax.lax.dynamic_slice(
+                                one, (0, j * ps, 0),
+                                (kv.shape[0], ps, kv.shape[2]))
+                        old = jax.lax.dynamic_slice(kv, at, blk.shape)
+                        kv = jax.lax.dynamic_update_slice(
+                            kv, jnp.where(live, blk, old), at)
+                    out.append(kv)
                 return tuple(out)
 
             out = list(bufs)
@@ -1158,6 +1169,23 @@ class PagedKVDecoder:
     layer's scores a band. Counters
     ``serving.sparse.*`` (docs/OBSERVABILITY.md). It refuses what
     ``mimo_v2_flash`` refuses.
+
+    ``arch="ouro"`` serves a LOOPED stack: ``num_layers`` layers of sandwich
+    norms, rotary attention and a gated MLP applied ``total_ut_steps`` times
+    over the same weights (``early_exit_threshold``, ``head_dim``,
+    ``rope_theta``, ``rms_eps``), the checkpoint one entry a LAYER. Every
+    pass keeps keys and values of its own, so a token costs ``passes`` times
+    a plain stack's cache, and the pool, not the weights, fills the chip. A
+    layer's passes share ONE pool pair of ``passes x lanes x max_len`` slots,
+    pass u a whole pass's frames behind pass u - 1: a lane's page table and
+    its one allocation address all of them, ``stats()`` counts a page once,
+    a retire returns every pass's slots at once, and the admission's scatter
+    writes a prompt's rows of every pass in the same donated program. All
+    passes run for every token; an exit gate read after each chooses which
+    pass's output feeds the head, and the chosen pass rides the token's read
+    (counters ``serving.loop.*``, docs/OBSERVABILITY.md). ``fork`` and
+    ``rollback`` work (a page is copied or dropped for every pass); the
+    prefix cache, the chunk and verify programs and the megastep raise.
     """
 
     def __init__(self, arg_params: Dict[str, object], vocab_size,
@@ -1192,11 +1220,6 @@ class PagedKVDecoder:
                               budget=page_budget)
         self.page_size = self.pool.page_size
         self.total_slots = self.lanes * self.max_len
-        if self.total_slots > 1 << 24:
-            # a step's slots and frames reach the program as float32
-            raise MXNetError("paged_kv: %d lanes x %d slots is past the 2^24 "
-                             "slots a float32 index counts exactly"
-                             % (self.lanes, self.max_len))
         if prefix_cache is None:
             prefix_cache = os.environ.get(
                 "MXNET_SERVE_PREFIX_CACHE", "").strip().lower() \
@@ -1241,6 +1264,16 @@ class PagedKVDecoder:
         # (name, "pool" | "row", shape) — pools of (heads, dh) addressed by
         # slot, per-lane rows (lanes,) + shape addressed by lane
         self._cache = _tf.decode_cache(**dict(cfg, arch=arch))
+        # a looped stack keeps every pass's keys and values: a layer's ONE
+        # pool pair holds ``passes`` times the slots, pass u a whole pass's
+        # frames behind pass u - 1, so a lane's page stands for all of them
+        self._passes = _tf.loop_passes(**dict(cfg, arch=arch))
+        if self.total_slots * self._passes > 1 << 24:
+            # a step's slots and frames reach the program as float32
+            raise MXNetError("paged_kv: %d lanes x %d slots x %d passes is "
+                             "past the 2^24 slots a float32 index counts "
+                             "exactly" % (self.lanes, self.max_len,
+                                          self._passes))
         self._cache_names = [name for name, _, _ in self._cache]
         self._pool_names = [name for name, kind, _ in self._cache
                             if kind == "pool"]
@@ -1272,6 +1305,7 @@ class PagedKVDecoder:
                                   page_size=self.page_size, **cfg),
             self.pool.frames_per_lane)
         self._pf_moe_load = _output_at(prefill, "moe_load")
+        self._pf_exit_pass = _output_at(prefill, "exit_pass")
         self._dec_moe_load = _output_at(decode, "moe_load")
         self._dec_token = _output_at(decode, "greedy_token")
         self._pf_cache = PersistentExecutableCache(
@@ -1346,7 +1380,8 @@ class PagedKVDecoder:
         for name, kind, shape in self._cache:
             # a pool in the layout its row's width gives it: page-major
             # (frames, page, heads * d) or head-major (heads, slots, d)
-            shapes[name] = pool_shape(*shape, S, self.page_size) \
+            shapes[name] = pool_shape(*shape, S * self._passes,
+                                      self.page_size) \
                 if kind == "pool" else (B,) + tuple(shape)
         return shapes
 
@@ -1466,11 +1501,21 @@ class PagedKVDecoder:
         pf.rebind(("data", "length"), (jax.device_put(padded), length))
         return pf
 
+    def _count_passes(self, exits):
+        """One dispatch of a looped stack, which headed ``len(exits)`` tokens
+        each from the pass ``exits`` names (from 1): every pass ran."""
+        _tm.counter("serving.loop.passes").inc(self._passes)
+        _tm.counter("serving.loop.exit_tokens").inc(len(exits))
+        _tm.counter("serving.loop.exit_pass_sum").inc(int(exits.sum()))
+
     def _prefill_cache(self, pf):
         """The prefill executable's cache outputs, in the cache's order."""
         return tuple(o._jax() for o in pf.outputs[1:1 + len(self._cache)])
 
     def stats(self):
+        """Lanes and pages now. A page counts ONCE whatever it holds: where
+        the stack is looped (``arch="ouro"``) it stands for the slots of
+        every pass, ``passes`` pieces of each pool."""
         out = {"lanes": self.lanes,
                "active": len(self._lanes),
                "pages_in_use": self.pool.in_use,
@@ -1507,14 +1552,19 @@ class PagedKVDecoder:
         src = frame * P + np.arange(P)
         dst = fresh * P + np.arange(P)
         exe = self._dec_exe
-        # a buffer at a time: its old copy may die before the next is made
+        # a buffer at a time: its old copy may die before the next is made;
+        # the page of EVERY pass a looped stack keeps there, a pass's frames
+        # (or slots) apart
         for tag, kind, shape in self._cache:
             if kind != "pool":
                 continue
             buf = exe.arg_dict[tag]._jax()
-            exe.rebind([tag], [
-                buf.at[fresh].set(buf[frame]) if pool_paged(*shape)
-                else buf.at[:, dst, :].set(buf[:, src, :])])
+            for u in range(self._passes):
+                by = u * self.pool.total_frames
+                buf = buf.at[fresh + by].set(buf[frame + by]) \
+                    if pool_paged(*shape) else buf.at[
+                        :, dst + by * P, :].set(buf[:, src + by * P, :])
+            exe.rebind([tag], [buf])
         self.pool.release([frame])
         lane.frames = lane.frames[:page] + (fresh,) + lane.frames[page + 1:]
         if _tm.enabled():
@@ -1660,6 +1710,9 @@ class PagedKVDecoder:
                 # the bucket's rows either half of the depth computed
                 _tm.counter("serving.admit_self_rows").inc(self.prefill_len)
                 _tm.counter("serving.admit_cross_rows").inc(self._head_rows)
+        if self._pf_exit_pass is not None and record is not None:
+            # graphlint: waive GL701 -- the instrument's own read, telemetry on only
+            self._count_passes(pf.outputs[self._pf_exit_pass].asnumpy())
         if self._pf_moe_load is not None and record is not None:
             # rows each expert received, per layer, over every position the
             # prefill computed (padding included: the grouped matmul's work)
@@ -1810,7 +1863,8 @@ class PagedKVDecoder:
         named like the rest, whoever reads it (``phi4flash``'s one pair is
         eight layers'): ``names`` that hold one get the lane's own
         positions of it, (heads, position, d), gathered from its pages in
-        order, whichever layout the pool is bound in."""
+        order, whichever layout the pool is bound in; where the stack is
+        looped, every pass's: (passes, heads, position, d)."""
         idx = self._seq_lane.get(seq_id)
         if idx is None:
             raise MXNetError("paged_kv: unknown seq_id %r" % (seq_id,))
@@ -1825,8 +1879,15 @@ class PagedKVDecoder:
                 out[name] = buf[idx]
                 continue
             slots = self._lane_slots(self._lanes[idx])
-            out[name] = buf.reshape((-1,) + tuple(shape))[slots].transpose(
-                1, 0, 2) if pool_paged(*shape) else buf[:, slots, :]
+            if self._passes > 1:    # (passes, position): a pass's slots apart
+                slots = slots + self.total_slots * np.arange(
+                    self._passes)[:, None]
+            if pool_paged(*shape):  # (..., position, heads, d) as gathered
+                out[name] = buf.reshape((-1,) + tuple(shape))[slots].swapaxes(
+                    -3, -2)
+            else:
+                own = buf[:, slots, :]
+                out[name] = own.swapaxes(0, 1) if self._passes > 1 else own
         return out
 
     # ----------------------------------------------------- fork / rollback
@@ -2029,9 +2090,12 @@ class PagedKVDecoder:
             out = {}
             with _tm.span("serving.step.commit"):
                 _swap_cache(exe, self._cache_names)
+                # a lane's row of the small read: its token and, where the
+                # stack is looped, the pass that fed the token's head
+                chosen = chosen.reshape(self.lanes, -1)
                 for seq_id, idx, lane in stepped:
                     lane.pos += 1
-                    out[seq_id] = _LogitsRow(block, idx, int(chosen[idx]))
+                    out[seq_id] = _LogitsRow(block, idx, int(chosen[idx, 0]))
             if _tm.enabled():
                 # the instrument's own work in a step, under its own name
                 with _tm.span("serving.step.account"):
@@ -2064,7 +2128,10 @@ class PagedKVDecoder:
                                 min(lane.pos, self._sparse_topk)
                                 for _, _, lane in stepped))
                     _tm.counter("serving.step_slot_writes").inc(
-                        len(stepped) * len(self._pool_names))
+                        len(stepped) * len(self._pool_names) * self._passes)
+                    if self._passes > 1:
+                        self._count_passes(
+                            chosen[[idx for _, idx, _ in stepped], 1])
                     _tm.counter("serving.step_input_bytes").inc(
                         self._step_in.nbytes)
                     _tm.counter("serving.step_staged_arrays").inc()

@@ -1573,3 +1573,103 @@ def test_a_decode_step_takes_one_host_fed_array_on_the_chip(v5e, arch):
              and math.prod(int(d) for d in dims.split(",") if d) >= pool]
     assert len(moved) == relaid, moved
     assert "kv_mask" not in hlo or not reads
+
+
+def test_a_looped_stacks_decode_step_updates_every_pass_in_place(v5e):
+    """``PagedKVDecoder(arch="ouro")``'s decode program lowered for the v5e at
+    Ouro-2.6B's published widths and the cell's serving sizes (16 lanes x 320
+    slots, pages of 16), FOUR of its 48 layers and all four passes (the 192
+    bodies of the whole depth compile in 101 s and say the same of each
+    buffer). A layer's ONE pool pair holds the four passes' slots,
+    (4 x 320, 16, 2,048) page-major; every pass writes its token's row into
+    its own piece and reads it back through the paged-read kernel, sixteen
+    kernel calls and sixteen write loops over eight buffers, and the program
+    aliases ALL of the cache: no pass's write makes a second copy of a pool
+    (at the whole depth the pools are 8.05 GB of the chip's 16). The moved
+    page table reaches the kernel as data; no mask over the slots is built.
+    The small read is (lanes, 2): the token beside the pass that fed it."""
+    from mxnet_tpu.models import transformer as tf
+    from mxnet_tpu.ops.attention import pool_shape
+    from mxnet_tpu.serving.kv_decode import _step_in_symbol
+
+    lanes, max_len, page, passes = 16, 320, 16, 4
+    slots = lanes * max_len
+    cfg = dict(arch="ouro", vocab_size=49152, num_layers=4, num_heads=16,
+               head_dim=128, model_dim=2048, ffn_dim=5632,
+               total_ut_steps=passes, early_exit_threshold=1.0,
+               rope_theta=1e6, rms_eps=1e-6, dtype="bfloat16")
+    assert tf.loop_passes(**cfg) == passes
+    weights = {n: (s, "bfloat16") for n, s in tf.param_shapes(**cfg).items()}
+    cache = tf.decode_cache(**cfg)
+    pool = pool_shape(16, 128, passes * slots, page)
+    assert pool == (passes * slots // page, page, 2048) and len(cache) == 8
+    # as the decoder binds it: ONE host-fed array, cut apart in the program
+    inputs = {"step_in": ((lanes, 3 + max_len // page), "float32")}
+    inputs.update({name: (pool, "bfloat16") for name, _, _ in cache})
+    sym = _step_in_symbol(
+        tf.get_decode_symbol(max_len=slots, page_size=page, **cfg),
+        max_len // page)
+    assert set(sym.list_arguments()) == set(weights) | set(inputs)
+    compiled = _compile_program(v5e, sym, {**weights, **inputs},
+                                donated=[name for name, _, _ in cache])
+    assert [(s.shape, str(s.dtype)) for s in compiled.out_info[0]] == \
+        [((lanes, 49152), "float32")] + [(pool, "bfloat16")] * 8 \
+        + [((lanes, 2), "float32")]
+    hlo = compiled.as_text()
+    kernels = _paged_read_calls(hlo)
+    assert len(kernels) == 4 * passes
+    for u in range(passes):
+        for i in range(4):
+            tag = "pass%d_layer%d_" % (u, i)
+            assert sum(tag + "att/" in line for line in kernels) == 1
+            assert sum(tag + "kvupd/" in line and " while(" in line
+                       for line in hlo.splitlines()) == 1
+    assert "kv_mask" not in hlo
+    _assert_no_pool_sized_copy(hlo, math.prod(pool))
+    mem = compiled.memory_analysis()
+    cache_bytes = 8 * 2 * math.prod(pool)
+    assert 12 * cache_bytes == 1_572_864 * slots     # 48 layers: 1.5 MiB a token
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < 256 << 20
+
+
+def test_the_admission_scatter_cuts_nothing_inside_its_page_walk(v5e):
+    """``_AdmitScatter`` at ``olmoe-1b-7b.score``'s sizes (32 page-major pools
+    of 8 lanes x 2,048 slots, a 2,048 bucket): the prefill's rows are turned
+    to the pool's layout ONCE, outside the walk over the prompt's pages, and
+    the walk moves a page a pool and step. The compiler's own byte count is
+    the guard: 0.946 GB when this was written; a pass cut out of its array
+    inside the walk's body (PR 54's first form, for a looped stack's several
+    passes) reads 1.5 GB here and ran the cell's admission 1.6 x as long on
+    the chip. A looped stack's passes are cut apart before the walk too."""
+    from types import SimpleNamespace
+
+    from mxnet_tpu.models import transformer as tf
+    from mxnet_tpu.ops.attention import pool_shape
+    from mxnet_tpu.serving.kv_decode import _AdmitScatter
+
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, jnp.dtype(dtype), sharding=v5e)
+
+    def scatter(cache, passes, lanes, max_len, bucket, page=16):
+        prog = _AdmitScatter(SimpleNamespace(
+            _cache=cache, page_size=page, prefill_len=bucket))
+        pools = tuple(spec(pool_shape(*shape, passes * lanes * max_len, page),
+                           "bfloat16") for _, _, shape in cache)
+        new = tuple(spec((passes, shape[0], bucket, shape[1]), "bfloat16")
+                    for _, _, shape in cache)
+        compiled = prog._fn.lower(pools, new, spec((bucket // page,), "int32"),
+                                  spec((2,), "int32")).compile()
+        cache_bytes = sum(2 * math.prod(p.shape) for p in pools)
+        assert compiled.memory_analysis().alias_size_in_bytes == cache_bytes
+        return compiled.cost_analysis()["bytes accessed"], \
+            sum(2 * math.prod(n.shape) for n in new)
+
+    moved, handed = scatter(tf.decode_cache("olmoe", 16, 16, 2048,
+                                            head_dim=128), 1, 8, 2048, 2048)
+    assert handed == 32 * 2048 * 2048 * 2
+    assert moved < 4 * handed          # 3.5 x: turned once, walked once
+    looped = tf.decode_cache("ouro", 4, 16, 2048, head_dim=128)
+    moved, handed = scatter(looped, 4, 16, 320, 128)
+    assert handed == 8 * 4 * 128 * 2048 * 2
+    assert moved < 5 * handed          # 4.4 x at a bucket of eight pages
