@@ -33,6 +33,11 @@ a seed), and checks what comes out by the repo's own means:
              of 192 over 128, 8,192 positions, the 2,048 highest of a
              64 x 128 indexer): the kernel under the layer's mask against
              XLA's query blocks
+  7 write    a decode step's write of its K/V rows into a layer's two
+             page-major pools at ouro-2.6b.generate's operands (16 rows,
+             bfloat16 (1280, 16, 2048)) and transformer-base.generate's (64
+             rows, float32 (4096, 16, 512)): the kernel the rule names
+             against XLA's scatter, the pools bit for bit
 
 Every phase prints PASS, FAIL or SKIP <reason>; a skip is never the result
 of an exception. Any FAIL makes the exit code 1. With no TPU the script
@@ -96,6 +101,10 @@ if not REHEARSE:
         # dots3-note-prev's full layer at its bucket: (heads, bucket, key
         # width, value width, index heads, index width, topk)
         sparse_attn=(128, 8192, 192, 128, 64, 128, 2048),
+        # a layer's two page-major pools and the lanes of a step:
+        # ouro-2.6b.generate's and transformer-base.generate's
+        pool_write={"ouro-2.6b": ((1280, 16, 2048), "bfloat16", 16),
+                    "transformer-base": ((4096, 16, 512), "float32", 64)},
     )
 else:
     SZ = dict(
@@ -111,6 +120,8 @@ else:
         matmul_n=256,
         prefill_attn={"olmoe": (2, 2, 32, 16), "nemotron": (8, 2, 32, 16)},
         sparse_attn=(4, 64, 16, 8, 4, 8, 16),
+        pool_write={"ouro-2.6b": ((24, 16, 256), "bfloat16", 4),
+                    "transformer-base": ((24, 16, 128), "float32", 8)},
     )
 
 
@@ -1136,6 +1147,82 @@ def phase_prefill_attention():
     verdict(fast, slow, diff, "both forms attend the same keys")
 
 
+# ---------------------------------------------------- phase 7: a step's write
+def phase_pool_write():
+    """A decode step's write of its new K/V rows as the chip runs it:
+    ``KVPoolSlotWrite`` over a layer's two page-major pools, one XLA scatter a
+    pool, against what the operator promises, bit for bit, at
+    ``ouro-2.6b.generate``'s operands and ``transformer-base.generate``'s: a
+    step's lanes (a page each, some riding along, two on one slot), a chunk's
+    rows in consecutive slots, one row. Every written slot holds its row (the
+    later of two), every other slot what it held. And what a call takes in a
+    chain."""
+    from mxnet_tpu.ops.attention import _kv_pool_slot_write, pool_write_form
+
+    def bits(a):
+        return jax.lax.bitcast_convert_type(
+            a, jnp.uint16 if a.dtype.itemsize == 2 else jnp.uint32)
+
+    @jax.jit
+    def kept(pool, new, out, at, row):
+        """``out`` is ``pool`` but for slots ``at``, which hold ``new[row]``."""
+        flat, was = (bits(a).reshape(-1, a.shape[-1]) for a in (out, pool))
+        rows = bits(new).reshape(new.shape[0], -1)
+        written = jnp.zeros(flat.shape[0], bool).at[at].set(True)
+        return (jnp.all(flat[at] == rows[row])
+                & jnp.all((flat == was) | written[:, None]))
+
+    write = jax.jit(lambda pk, rk, pv, rv, s: _kv_pool_slot_write(
+        {"num_pools": 2}, pk, rk, pv, rv, s))
+    for name, (shape, dt, lanes) in SZ["pool_write"].items():
+        frames, page, width = shape
+        rs = np.random.RandomState(len(name))
+        step = rs.permutation(frames)[:lanes] * page + rs.randint(0, page, lanes)
+        step[1::5] = -1
+        step[-1] = step[0]
+        chunk = (frames // 2) * page + 3 + np.arange(2 * page)
+        k1, k2 = jax.random.split(jax.random.PRNGKey(len(name)))
+        pools = [jax.random.normal(k, shape, jnp.float32).astype(dt)
+                 for k in (k1, k2)]
+        form = pool_write_form(pools)
+        check(form == "scatter",
+              "the rule names the write of %s pools %s: %s" % (dt, shape, form))
+        for what, slots in (("a step's lanes", step), ("a chunk's rows", chunk),
+                            ("one row", step[:1])):
+            rows = [jax.random.normal(k, (len(slots), 4, width // 4),
+                                      jnp.float32).astype(dt)
+                    for k in jax.random.split(jax.random.PRNGKey(len(slots)))]
+            got = write(pools[0], rows[0], pools[1], rows[1],
+                        jnp.asarray(slots, jnp.float32).reshape(-1, 1))
+            # slot -> the LAST row that names it
+            live = {int(s): r for r, s in enumerate(slots) if s >= 0}
+            at, row = (jnp.asarray(list(v)) for v in (live, live.values()))
+            check(all(bool(kept(pool, new, out, at, row))
+                      for pool, new, out in zip(pools, rows, got)),
+                  "KVPoolSlotWrite %s %s x 2, %s (%d rows, %d written): the "
+                  "rows at their slots and every other slot as it was, bit "
+                  "for bit" % (dt, shape, what, len(slots), len(live)))
+        # a call in a chain of 48 on the same two pools, donated
+        rows = [jax.random.normal(k1, (lanes, 4, width // 4),
+                                  jnp.float32).astype(dt)] * 2
+        slot = jnp.asarray(step, jnp.float32).reshape(-1, 1)
+
+        def chain(pk, pv):
+            for i in range(48):
+                pk, pv = _kv_pool_slot_write(
+                    {"num_pools": 2}, pk, rows[0], pv, rows[1],
+                    jnp.where(slot >= 0, (slot + page * i) % (frames * page),
+                              slot))
+            return pk, pv
+
+        run = jax.jit(chain, donate_argnums=(0, 1))
+        pools = jax.block_until_ready(run(*pools))
+        t0 = time.perf_counter()
+        pools = jax.block_until_ready(run(*pools))
+        say("    %s: %.1f us a call of %d rows x 2 pools (%s)"
+            % (name, 1e6 * (time.perf_counter() - t0) / 48, lanes, form))
+
+
 # --------------------------------------------------------------------- main
 PHASES = [
     ("device", phase_device),
@@ -1146,6 +1233,7 @@ PHASES = [
     ("four chips", phase_four_chips),
     ("prefill attention: the kernel against the dense path",
      phase_prefill_attention),
+    ("pool write: a step's rows into page-major pools", phase_pool_write),
 ]
 
 

@@ -413,7 +413,9 @@ def _pool_step_inputs(pos_idx, num_slots, page_size, write_slot=None):
     nothing), and ``page_table`` (B, pages a lane), the frames of its pages
     in order. ``write(i, {tag: rows})`` is slot-indexed, ONE
     ``KVPoolSlotWrite`` over layer ``i``'s pools ``kv_<tag>_i`` (a program
-    that takes them donated updates them in place) and gives them back in
+    that takes them donated updates them in place; all the lanes' rows reach
+    a page-major pool in one scatter, head-major ones in one loop over the
+    lanes: ``ops.attention.pool_write_form``) and gives them back in
     the tags' order. With ``pos_idx`` the two inputs are the lane's whole
     context: the read is its mask over the pool (``KVPageMask``, made ON
     THE DEVICE, once in front of the layers) beside the three it is made
@@ -490,13 +492,17 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
       - ``write_slot`` (B, 1): the pool slot each lane's token writes, as an
         index; negative for a lane that rides along (it writes nothing and
         attends nothing). The KV update is in-graph and by that index
-        (``KVPoolSlotWrite``): the page that holds the slot is read, the
-        row put in, the page written back — no per-step host scatter, no
-        per-slot recompile, nothing of the pool's size made, and in place
-        where the program takes the pool donated (``PagedKVDecoder`` does).
-        Lane slots are disjoint by construction (the page allocator hands a
-        frame to one writer at a time), and a negative slot writes back
-        what it read, which is how idle lanes ride along for free.
+        (``KVPoolSlotWrite``): all the lanes' rows go to their slots of a
+        layer's page-major pools in ONE device operation a pool (an XLA
+        scatter over the pool's rows), and into a head-major pool a lane at
+        a time, the run of slots around its own read, the row put in, the
+        run written back — no per-step
+        host scatter, no per-slot recompile, nothing of the pool's size
+        made, and in place where the program takes the pool donated
+        (``PagedKVDecoder`` does). Lane slots are disjoint by construction
+        (the page allocator hands a frame to one writer at a time), and a
+        negative slot writes nothing, which is how idle lanes ride along
+        for free.
       - ``page_table`` (B, pages a lane): the frames of each lane's pages,
         in order, ``page_size`` slots each (entries past the lane's last
         page are never read). From it, ``pos_idx`` and ``write_slot`` the

@@ -594,14 +594,6 @@ def pool_slots(shape):
     return shape[0] * shape[1] if _paged(shape) else shape[1]
 
 
-def _slot_frame(slot, page):
-    """Slot -> (frame, offset in its page) of a page-major pool; a negative
-    slot (a row that writes nothing) lands on slot 0, which its caller then
-    writes back as it read it."""
-    slot = jnp.maximum(slot, 0)
-    return slot // page, slot % page
-
-
 @register(
     "_contrib_KVPoolWrite",
     input_names=("pool", "rows", "onehot"),
@@ -648,6 +640,43 @@ def _slot_write_inputs(attrs):
             for name in ("pool_%d", "rows_%d")] + ["write_slot"]
 
 
+def pool_write_form(pools):
+    """THE rule that names the form ``KVPoolSlotWrite`` writes a call's pools
+    in, from their shapes alone, on every backend; no caller, option or
+    environment variable does. ``pools`` carry ``.shape``, in the operator's
+    order.
+
+    ``"scatter"``: one XLA scatter a PAGE-MAJOR pool over its (slots, H * dh)
+    rows puts all the call's rows in at once (``_scatter_rows``).
+
+    ``"loop"``: no pool of the call is page-major; one loop over the rows
+    updates the run of slots around each row's in every HEAD-MAJOR pool.
+
+    A call of both layouts is named by its page-major pools' form; its
+    head-major pools keep their loop."""
+    return "scatter" if any(_paged(pool) for pool in pools) else "loop"
+
+
+def _scatter_rows(pools, rows, slot):
+    """``rows[p][r]`` at slot ``slot[r]`` of page-major ``pools[p]``, one XLA
+    scatter a pool over its (slots, H * dh) rows, the slot the one scattered
+    index. A row that writes nothing (a negative slot; an earlier row whose
+    slot a later row names, so that the later one stays) is sent past the
+    pool's end, each to an index of its own, and dropped, as is a row whose
+    slot lies past the pool's end already."""
+    lane = jnp.arange(slot.shape[0], dtype=jnp.int32)
+    later = (slot[None, :] == slot[:, None]) & (lane[None, :] > lane[:, None])
+    dead = (slot < 0) | jnp.any(later, axis=1)
+    out = []
+    for pool, new in zip(pools, rows):
+        slots, width = pool.shape[0] * pool.shape[1], pool.shape[2]
+        flat = pool.reshape(slots, width).at[
+            jnp.where(dead, slots + lane, slot)].set(
+                new.reshape(-1, width), mode="drop", unique_indices=True)
+        out.append(flat.reshape(pool.shape))
+    return out
+
+
 @register(
     "_contrib_KVPoolSlotWrite",
     attrs={"num_pools": AttrSpec("int", default=1)},
@@ -666,42 +695,45 @@ def _kv_pool_slot_write(attrs, *inputs):
     the row bit for bit, and where two rows name one slot the later one
     stays.
 
-    Nothing here is a pool's size. ONE loop over the rows updates every
-    pool, each in its own layout (``pool_shape``), and a program that takes
-    the pools DONATED updates them in place.
+    Nothing here is a pool's size, every pool is written in its own layout
+    (``pool_shape``) and form (``pool_write_form``), and a program that takes
+    the pools DONATED updates them in place. A slot past the pool's end
+    writes nothing either, in both layouts; no caller sends one.
 
-    A PAGE-MAJOR pool (frames, page, H * dh) takes a token's row as what it
-    is there, one contiguous ``(1, 1, H * dh)`` piece at ``(frame, offset,
-    0)``: the piece is read, the row put in its place (a negative slot puts
-    back what it read) and written with one ``dynamic_update_slice``, 1-2 KB
-    a row and pool.
+    A PAGE-MAJOR pool (frames, page, H * dh) takes ALL the call's rows in ONE
+    device operation, an XLA scatter over its (slots, H * dh) rows
+    (``_scatter_rows``). The chip keeps such a pool row-major, a token's row
+    is one contiguous piece of it, and nothing is re-laid around a write by
+    index. Until PR 55 a loop wrote them a row at a time, a slice, a select
+    and an update each: 6,144 turns a step where a token keeps 384 rows
+    (``PERF.md`` section 6, PR 55).
 
-    A HEAD-MAJOR pool (H, S, dh): the aligned run of ``_WRITE_RUN`` slots
-    that holds the slot is read, the row put in by ``where`` and the run
-    written back. An update one slot wide, or a scatter over the slot axis,
-    says the same, but the chip keeps a narrow head-major pool slots-minor
-    and re-lays the WHOLE buffer out around either, twice a buffer. Those
-    writes are bound by their count, not their bytes, and the loop's shape
-    was chosen on the chip (``PERF.md`` §6, PR 37): a run of one tile beats a
-    page of 16 slots, one loop a layer beats one a pool, and the index
-    arithmetic stays inside the loop."""
-    pools, rows = inputs[0:-1:2], inputs[1:-1:2]
+    A HEAD-MAJOR pool (H, S, dh), in ONE loop over the rows for all of them:
+    the aligned run of ``_WRITE_RUN`` slots that holds the slot is read, the
+    row put in by ``where`` and the run written back. An update one slot
+    wide, or a scatter over the slot axis, says the same, but the chip keeps
+    a narrow head-major pool slots-minor and re-lays the WHOLE buffer out
+    around either, twice a buffer. Those writes are bound by their count, not
+    their bytes, and the loop's shape was chosen on the chip (``PERF.md``
+    section 6, PR 37): a run of one tile beats a page of 16 slots, one loop a
+    layer beats one a pool, and the index arithmetic stays inside the
+    loop."""
+    pools, rows = list(inputs[0:-1:2]), inputs[1:-1:2]
     n_rows = rows[0].shape[0]
     if n_rows == 0:
         return tuple(pools)
     slot = inputs[-1].reshape(-1).astype(jnp.int32)
     rows = [r.astype(p.dtype) for r, p in zip(rows, pools)]
-
-    def write_page_major(pool, new, r):
-        _, page, width = pool.shape
-        at = _slot_frame(slot[r], page) + (0,)
-        old = jax.lax.dynamic_slice(pool, at, (1, 1, width))
-        row = jax.lax.dynamic_index_in_dim(new, r, 0, keepdims=False)
-        return jax.lax.dynamic_update_slice(
-            pool, jnp.where(slot[r] >= 0, row.reshape(1, 1, width), old), at)
+    paged = [i for i, pool in enumerate(pools) if _paged(pool)]
+    for i, pool in zip(paged, _scatter_rows(
+            [pools[i] for i in paged], [rows[i] for i in paged], slot)):
+        pools[i] = pool
+    head_major = [i for i in range(len(pools)) if i not in paged]
+    if not head_major:
+        return tuple(pools)
 
     # a head-major pool's run (the pools of a call have the same slots)
-    slots = next((p.shape[1] for p in pools if not _paged(p)), 0)
+    slots = pools[head_major[0]].shape[1]
     run = min(_WRITE_RUN, slots)
     in_run = jnp.arange(run, dtype=jnp.int32)[None, :, None]
 
@@ -715,12 +747,14 @@ def _kv_pool_slot_write(attrs, *inputs):
         return jax.lax.dynamic_update_slice(
             pool, jnp.where(at_slot, row[:, None, :], old), (0, base, 0))
 
-    def write(r, pools):
-        return tuple(
-            (write_page_major if _paged(pool) else write_head_major)(
-                pool, new, r) for pool, new in zip(pools, rows))
+    def write(r, loop_pools):
+        return tuple(write_head_major(pool, rows[i], r)
+                     for i, pool in zip(head_major, loop_pools))
 
-    return jax.lax.fori_loop(0, n_rows, write, tuple(pools))
+    for i, pool in zip(head_major, jax.lax.fori_loop(
+            0, n_rows, write, tuple(pools[i] for i in head_major))):
+        pools[i] = pool
+    return tuple(pools)
 
 
 def pool_read_bytes(query, pool_k, pool_v, page_table, page_size):

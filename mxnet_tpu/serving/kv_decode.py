@@ -355,6 +355,23 @@ def _pool_reads(cache, input_shapes):
     return out
 
 
+def _pool_writes(cache, input_shapes):
+    """What one dispatch of a decode program writes into its pools, a
+    ``KVPoolSlotWrite`` node: ``[(form, rows)]`` at these input shapes, the
+    form the operator's own rule names for the node's pools
+    (``pool_write_form``) and the rows it writes, one a row and pool."""
+    from ..ops.attention import pool_write_form
+
+    out = []
+    for n, ops in _operands_of(cache, input_shapes,
+                               "_contrib_KVPoolSlotWrite"):
+        pools = n.parsed_attrs().get("num_pools", 1)
+        out.append((pool_write_form(
+            [ops["pool_%d" % i] for i in range(pools)]),
+            pools * ops["rows_0"].shape[0]))
+    return out
+
+
 def _moe_forms(cache, input_shapes):
     """``(forms, depth)``: the form of every ``MoEFeedForward`` node of a
     program's graph at these input shapes, ``["kernel" | "ragged_dot"]`` in
@@ -1431,6 +1448,14 @@ class PagedKVDecoder:
             for form in ("kernel", "own_pages", "whole_pool", "selected"):
                 _tm.gauge("serving.pool_read.%s_layers" % form).set(
                     len(by_form(form)))
+            # the step's writes, a node, by the form the operator's rule
+            # names, and the rows they put into a pool each
+            writes = _pool_writes(self._dec_cache, self._decode_shapes())
+            for form in ("scatter", "loop"):
+                _tm.gauge("serving.pool_write.%s_nodes" % form).set(
+                    sum(f == form for f, _ in writes))
+            _tm.gauge("serving.pool_write.rows_a_step").set(
+                sum(rows for _, rows in writes))
             # a layer's: the mean over the program's reads of a kind, which
             # are alike. What XLA scores a dispatch; the kernel's block
             scored = by_form("own_pages") + by_form("whole_pool")

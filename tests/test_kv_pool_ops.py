@@ -22,6 +22,8 @@ reads either as (H, S, dh), so a case says one thing of both. (The kernel that
 reads page-major pools on the chip: tests/test_paged_read_kernel.py.)
 """
 import functools
+import math
+import re
 
 import numpy as np
 import pytest
@@ -352,26 +354,161 @@ def test_slot_write_takes_two_pools_each_in_its_own_layout():
                                              jnp.asarray(onehot))))
 
 
+# a looped stack's step (``ouro-2.6b``: 16 lanes, a layer's two pools of 16
+# heads of 128 in bfloat16, 1,280 frames of 16): case -> the slots of its rows
+LOOPED_FRAMES, LOOPED_WIDTH = 1280, 2048
+LOOPED_WRITES = {
+    "a_page_a_lane": [16 * (7 + 79 * lane) + (5 * lane) % 16
+                      for lane in range(16)],
+    "negative_slots_among_them": [-1 if lane % 4 == 1 else 16 * (3 + 80 * lane)
+                                  + lane for lane in range(16)],
+    "two_rows_on_one_slot": [16 * (9 + 70 * lane) for lane in range(15)]
+    + [16 * 9],                                         # the later one stays
+    "a_chunks_rows_in_one_page": [16 * 611 + i for i in range(16)],
+    "one_row": [16 * LOOPED_FRAMES - 1],
+}
+
+
+@pytest.fixture(scope="module")
+def looped_pools():
+    rs = np.random.RandomState(55)
+    # seeded BITS: every pattern of a bfloat16 but the NaNs' and infinities'
+    bits = rs.randint(0, 1 << 16, (2, LOOPED_FRAMES, 16, LOOPED_WIDTH),
+                      np.uint16)
+    bits[(bits & 0x7F80) == 0x7F80] = 0
+    return tuple(jnp.asarray(b).view(jnp.bfloat16) for b in bits)
+
+
+def _rows_by_slot(pools, rows, slots):
+    """What numpy says a write leaves: the bits of each pool's (slots, row)
+    view with ``rows[r]`` at ``slots[r]``, row after row; a slot that is
+    negative or past the pool's end takes nothing."""
+    out = []
+    for pool, new in zip(pools, rows):
+        want = _bits(pool).reshape(-1, pool.shape[-1]).copy()
+        for r, slot in enumerate(slots):
+            if 0 <= slot < len(want):
+                want[slot] = _bits(new)[r].reshape(-1)
+        out.append(want)
+    return out
+
+
+def _slot_write_is_numpys(pools, rows, slots):
+    inputs = [a for pair in zip(pools, rows) for a in pair]
+    got = _kv_pool_slot_write(
+        {"num_pools": len(pools)}, *inputs,
+        jnp.asarray(slots, jnp.float32).reshape(-1, 1))
+    for pool, out, want in zip(pools, got, _rows_by_slot(pools, rows, slots)):
+        assert out.dtype == pool.dtype and out.shape == pool.shape
+        np.testing.assert_array_equal(
+            _bits(out).reshape(-1, pool.shape[-1]), want)
+
+
+@pytest.mark.parametrize("case", list(LOOPED_WRITES))
+def test_slot_write_at_a_looped_stacks_operands(looped_pools, case):
+    """A page-major write at ``ouro-2.6b.generate``'s operands: the pools
+    with the rows' bits at their slots, row after row as numpy says it, and
+    every other slot's bits what they were; a negative slot nothing, the
+    later of two rows on one slot, a chunk's 16 rows in ONE page, one row."""
+    slots = LOOPED_WRITES[case]
+    rs = np.random.RandomState(len(case))
+    rows = [jnp.asarray(rs.randn(len(slots), 16, 128), "bfloat16")
+            for _ in looped_pools]
+    _slot_write_is_numpys(looped_pools, rows, slots)
+
+
+# the other ``generate`` cells' page-major writes at few frames: cell ->
+# (dtype, the row widths of a layer's key and value pools, rows a step)
+CELL_FRAMES = 64
+CELL_WRITES = {
+    "transformer-base": ("float32", (512, 512), 64),
+    "mimo-v2-flash": ("bfloat16", (768, 512), 32),      # a narrower value
+    "phi-4-mini-flash-reasoning": ("bfloat16", (1280, 1280), 48),
+}
+# case -> the slots of a call's rows, from the rows a step and a generator
+CELL_SLOTS = {
+    "a_page_a_lane": lambda n, rs: rs.permutation(CELL_FRAMES)[:n] * 16
+    + rs.randint(0, 16, n),
+    "lanes_that_ride_along": lambda n, rs: np.where(
+        np.arange(n) % 3 == 1, -1, rs.permutation(CELL_FRAMES)[:n] * 16 + 5),
+    "nobody_writes": lambda n, rs: np.full(n, -1),
+    "an_odd_count": lambda n, rs: np.r_[0, 17, 34, -1, 68, 85, 15],
+    # a chunk's rows: one lane's consecutive slots
+    "a_chunk_over_three_pages": lambda n, rs: 2 * 16 + 9 + np.arange(2 * 16),
+    "a_page_shared_out_of_order": lambda n, rs: np.r_[48, 100, 50, 101, 49,
+                                                      48],
+    # what no caller sends: past the pool's end nothing is written
+    "a_slot_past_the_end": lambda n, rs: np.r_[3, CELL_FRAMES * 16,
+                                               CELL_FRAMES * 16 + 40, 19],
+}
+
+
+@pytest.mark.parametrize("case", list(CELL_SLOTS))
+@pytest.mark.parametrize("cell", list(CELL_WRITES))
+def test_slot_write_at_the_cells_operands(cell, case):
+    """The scatter at each cell's type and widths (float32; a value pool
+    narrower than its key pool) over the rows a step, a chunk or a verify
+    program hands: numpy's row after row, bit for bit."""
+    dtype, widths, lanes = CELL_WRITES[cell]
+    rs = np.random.RandomState(len(cell) + len(case))
+    slots = np.asarray(CELL_SLOTS[case](lanes, rs), np.int64)
+    pools = [jnp.asarray(rs.randn(CELL_FRAMES, 16, w), dtype) for w in widths]
+    rows = [jnp.asarray(rs.randn(len(slots), 4, w // 4), dtype)
+            for w in widths]
+    _slot_write_is_numpys(pools, rows, slots)
+
+
+@pytest.mark.parametrize("pools,form", [
+    ([(1280, 16, 2048)] * 2, "scatter"),            # ouro-2.6b
+    ([(4096, 16, 768), (4096, 16, 512)], "scatter"),  # mimo-v2-flash
+    ([(1, 4096, 576)], "loop"),                     # kanana's latent row
+    ([(2, 64, 16)] * 2, "loop"),                    # toy heads
+    # both layouts in one call: named by its page-major pool
+    ([(64, 16, 512), (1, 1024, 576)], "scatter"),
+])
+def test_the_write_rule_reads_the_layout_off_the_pools(monkeypatch, pools,
+                                                       form):
+    """``pool_write_form`` from the pools' shapes alone, and the same on
+    every backend: one form writes a page-major pool."""
+    specs = [jax.ShapeDtypeStruct(shape, "bfloat16") for shape in pools]
+    assert attention.pool_write_form(specs) == form
+    monkeypatch.setattr(attention, "_backend", lambda: "tpu")
+    assert attention.pool_write_form(specs) == form
+
+
 @pytest.mark.parametrize("layout,pool,piece", [
-    ("page_major", (256, 16, 512), "1x1x512"),
+    ("page_major", (256, 16, 512), "5x512"),
     ("head_major", (1, 4096, 576), "1x%dx576" % attention._WRITE_RUN)])
 def test_slot_write_moves_a_row_or_a_run_and_nothing_of_the_pools_size(
         layout, pool, piece):
     """What reaches the compiler: no contraction, no one-hot, nothing
-    (rows, slots) or pool-sized made; a loop whose body slices the piece of
-    the pool that holds a row's slot and updates it: a token's own row of a
-    page-major pool, a run of slots of a head-major one."""
+    (rows, slots) or pool-sized made. A call of PAGE-MAJOR pools alone is no
+    loop: ONE scatter a pool of the step's rows, (rows, H * dh), into the
+    pool's (slots, H * dh) view, the slot the one scattered index. A
+    HEAD-MAJOR pool: a loop whose body slices the run of slots that holds a
+    row's slot and updates it."""
     heads = 8 if layout == "page_major" else 1
     rows = jnp.zeros((5, heads, pool[2] // heads), jnp.float32)
     text = jax.jit(lambda *a: _kv_pool_slot_write({}, *a)[0]).lower(
         jnp.zeros(pool, jnp.float32), rows,
         jnp.zeros((5, 1), jnp.float32)).as_text()
     assert "dot_general" not in text and "5x4096" not in text
-    assert "dynamic_slice" in text and "dynamic_update_slice" in text
-    assert "stablehlo.while" in text
     assert piece in text
-    if layout == "page_major":      # no run: the row is the piece
+    made = {tuple(int(d) for d in dims.split("x")) for dims in
+            re.findall(r"tensor<((?:\d+x)*\d+)x[a-z]\w*>", text)}
+    if layout == "page_major":
+        assert "stablehlo.while" not in text
+        assert "dynamic_slice" not in text
+        assert text.count("\"stablehlo.scatter\"(") == 1
         assert "x%dx" % attention._WRITE_RUN not in text
+        # the pool, its (slots, row) view and nothing else of its size
+        assert [shape for shape in made
+                if math.prod(shape) >= math.prod(pool)] \
+            == [shape for shape in ((256, 16, 512), (4096, 512))
+                if shape in made]
+    else:
+        assert "dynamic_slice" in text and "dynamic_update_slice" in text
+        assert "stablehlo.while" in text and "stablehlo.scatter" not in text
 
 
 @pytest.mark.parametrize("arch", ["vaswani", "olmoe", "granite_hybrid",
@@ -918,9 +1055,9 @@ def test_a_decoder_steps_alike_in_both_forms_and_says_which(monkeypatch,
     """Admissions of unequal lengths, a lane that joins late and one that
     retires, stepped side by side: the logits of the decoder whose lanes
     read their own pages are the whole-pool decoder's to float32's sum
-    order, and its telemetry says what a step read. A latent row of 120 is
-    kept head-major, one of 128 page-major (one pool that is key and value
-    both: XLA's gather in either)."""
+    order, and its telemetry says what a step read and how it wrote. A latent
+    row of 120 is kept head-major, one of 128 page-major (one pool that is
+    key and value both: XLA's gather in either)."""
     from mxnet_tpu import telemetry as tm
 
     saved = tm.current_override()
@@ -936,6 +1073,11 @@ def test_a_decoder_steps_alike_in_both_forms_and_says_which(monkeypatch,
             assert snap["serving.pool_read.own_pages_layers"] == 3 * own
             assert snap["serving.pool_read.whole_pool_layers"] == 3 * (not own)
             assert snap["serving.pool_read.kernel_layers"] == 0
+            # the write: a loop a layer into a head-major latent pool, one
+            # scatter into a page-major one; 3 layers x 8 lanes
+            assert snap["serving.pool_write.loop_nodes"] == 3 * (not paged)
+            assert snap["serving.pool_write.scatter_nodes"] == 3 * paged
+            assert snap["serving.pool_write.rows_a_step"] == 24
             seqs = [dec.admit(np.arange(n) % 61)[0] for n in (3, 9, 16)]
             rows = []
             for t in range(12):

@@ -292,7 +292,8 @@ def test_the_exported_decode_program_is_the_chips(tmp_path, monkeypatch):
     exported for the v5e from here and read back from the store: the blob
     carries the ``tpu_custom_call``, and what a warm process would run,
     ``jax.jit(exported.call)`` with the pools donated, compiles for the chip
-    with its six kernel calls (one a layer) and the whole cache aliased."""
+    with its six kernel calls (one a layer), since PR 55 one scatter a pool
+    in place of a loop a layer, and the whole cache aliased."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
@@ -346,9 +347,11 @@ def test_the_exported_decode_program_is_the_chips(tmp_path, monkeypatch):
     on_chip = (tuple(spec(s, "float32", sharding=chip) for s in arg_shapes),
                (), spec((2,), "uint32", sharding=chip))
     compiled = run.lower(*on_chip).compile()
-    kernels = [line for line in compiled.as_text().splitlines()
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
     assert len(kernels) == layers
     assert all("/paged_read/" in line for line in kernels)
+    assert text.count(" scatter(") == 2 * layers and " while(" not in text
     assert compiled.memory_analysis().alias_size_in_bytes \
         == 2 * layers * heads * slots * dh * 4

@@ -190,6 +190,23 @@ def _paged_read_calls(hlo):
             and "/paged_read/" in line]
 
 
+def _assert_one_write_a_node(hlo, nodes, pools=2):
+    """Each ``KVPoolSlotWrite`` node named in ``nodes`` (its scope's tag)
+    writes each of its ``pools`` page-major pools in ONE device operation, a
+    scatter of all the node's rows (on the chip a fusion of its own, the pool
+    aliased), and nothing of the loop it replaced: no ``while``, no
+    ``dynamic-update-slice`` and no one-row slice under the node's name."""
+    lines = hlo.splitlines()
+    scatters = [line for line in lines
+                if " scatter(" in line and "kvupd/" in line]
+    assert len(scatters) == pools * len(nodes)
+    for tag in nodes:
+        assert sum(tag in line for line in scatters) == pools, tag
+        assert not [line for line in lines if tag in line and re.search(
+            r" (while|dynamic-update-slice|dynamic-slice|custom-call)\(",
+            line)]
+
+
 def _assert_attention_is_blockwise(hlo, layers, bucket):
     """A prefill's ``layers`` plain causal attention layers each run as ONE
     call of the blockwise kernel (``ops/pallas_attention.py``), and nothing
@@ -401,8 +418,8 @@ def _assert_pool_step_contracts(compiled, layers, rows, heads, slots, dh,
     ``(slots / page, page, heads x dh)`` donated: each layer's read of its
     two pools is ONE ``tpu_custom_call`` (the kernel that walks the page
     table; no contraction of XLA's touches a pool) and its write of both
-    pools is ONE loop a layer that updates a token's row a lane in each, in
-    place, so the program aliases ``cache_bytes``, all of its cache; no
+    pools is ONE scatter a pool that puts every lane's row in (no loop over
+    the rows), in place, so the program aliases ``cache_bytes``, all of its cache; no
     buffer the size of a pool is copied or transposed, the temporaries stay
     under ``temp_bytes`` (the whole-pool scores, rows x heads x slots, would
     not), no mask over the pool's slots is built, and no parameter
@@ -417,16 +434,15 @@ def _assert_pool_step_contracts(compiled, layers, rows, heads, slots, dh,
     kernels = _paged_read_calls(hlo)
     contractions = [line for line in lines
                     if re.search(r" (convolution|dot)\(", line)]
-    updates = [line for line in lines if " dynamic-update-slice(" in line]
-    loops = [line for line in lines if " while(" in line]
     assert len(kernels) == layers
     for i in range(layers):
         assert sum("layer%d_att/" % i in line for line in kernels) == 1
         for node in ("kvupd", "att"):
             tag = "layer%d_%s/" % (i, node)
             assert not [line for line in contractions if tag in line], tag
-        assert sum("layer%d_kvupd/" % i in line for line in updates) == 2
-        assert sum("layer%d_kvupd/" % i in line for line in loops) == 1
+    _assert_one_write_a_node(hlo, ["layer%d_kvupd/" % i
+                                   for i in range(layers)])
+    assert " while(" not in hlo
     assert "slot_onehot" not in hlo and "kv_mask" not in hlo
     _assert_no_pool_sized_copy(hlo, heads * slots * dh)
     mem = compiled.memory_analysis()
@@ -1214,10 +1230,10 @@ def test_phi4flash_serving_programs_compile_for_the_chip(v5e, program):
     assert len(calls) == 8
     for i, tag in [(17, "self")] + [(i, "cross") for i in range(19, 32, 2)]:
         assert sum("layer%d_%s_att/" % (i, tag) in line for line in calls) == 1
-    # ONE write of the pool pair: one loop, a row a lane into each
-    assert hlo.count(" while(") == 1
-    assert sum("layer17_kvupd/" in line for line in hlo.splitlines()
-               if " dynamic-update-slice(" in line) == 2
+    # ONE write of the pool pair: one scatter a pool, every lane's row in,
+    # and no loop in the program
+    _assert_one_write_a_node(hlo, ["layer17_kvupd/"])
+    assert " while(" not in hlo
     # no read's mask is built and the pool is not re-laid
     assert "kv_mask" not in hlo and "slot_onehot" not in hlo
     _assert_no_pool_sized_copy(hlo, 10 * slots * 128)
@@ -1583,7 +1599,7 @@ def test_a_looped_stacks_decode_step_updates_every_pass_in_place(v5e):
     buffer). A layer's ONE pool pair holds the four passes' slots,
     (4 x 320, 16, 2,048) page-major; every pass writes its token's row into
     its own piece and reads it back through the paged-read kernel, sixteen
-    kernel calls and sixteen write loops over eight buffers, and the program
+    read calls and thirty-two scatters over eight buffers, and the program
     aliases ALL of the cache: no pass's write makes a second copy of a pool
     (at the whole depth the pools are 8.05 GB of the chip's 16). The moved
     page table reaches the kernel as data; no mask over the slots is built.
@@ -1622,9 +1638,11 @@ def test_a_looped_stacks_decode_step_updates_every_pass_in_place(v5e):
         for i in range(4):
             tag = "pass%d_layer%d_" % (u, i)
             assert sum(tag + "att/" in line for line in kernels) == 1
-            assert sum(tag + "kvupd/" in line and " while(" in line
-                       for line in hlo.splitlines()) == 1
-    assert "kv_mask" not in hlo
+    # ONE write operation a (pass, layer) and pool: all 16 lanes' rows in
+    # one scatter, and no loop anywhere in the step
+    _assert_one_write_a_node(hlo, ["pass%d_layer%d_kvupd/" % (u, i)
+                                   for u in range(passes) for i in range(4)])
+    assert " while(" not in hlo and "kv_mask" not in hlo
     _assert_no_pool_sized_copy(hlo, math.prod(pool))
     mem = compiled.memory_analysis()
     cache_bytes = 8 * 2 * math.prod(pool)
