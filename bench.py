@@ -17,7 +17,7 @@ jax. The legs that run in-process on the chip (ResNet-50, LSTM, recommender
 step, input pipeline) run in ONE child (``bench.py --inproc``), which checks
 first thing that JAX's default device is a TPU and exits 2 otherwise — there
 is no CPU fallback, a bench without a chip is a failure. Children that need
-the chip (serve_bench legs, fusion workers) then run one after another;
+the chip (serve_bench legs) then run one after another;
 children that measure host-side paths (kvstore bandwidth, checkpoint,
 2-process wire smokes) carry ``JAX_PLATFORMS=cpu``. A leg that raises is
 reported under its name AND makes the exit code non-zero.
@@ -25,10 +25,6 @@ reported under its name AND makes the exit code non-zero.
 Timing: ``jax.block_until_ready`` is a real barrier on the chip (checked by
 chip_smoke.py phase 0: it agrees with a host scalar fetch to within 1 ms on
 a 48 ms matmul chain); ``_sync`` is the one helper every timing here uses.
-
-The ``fusion_patterns`` leg (docs/PERF.md §13) A/Bs the generic pattern
-fusion engine off-vs-on (warm measure-and-cache verdicts) on a transformer
-training step and asserts the warm arm re-tunes and retraces ZERO times.
 
 Prints ONE JSON line {"metric", "value", "unit", "vs_baseline", ...extras}.
 """
@@ -55,20 +51,6 @@ def _mfu_fields(flops_per_step, step_s, peak):
     return {"model_flops_per_step": int(flops_per_step),
             "model_tflops_per_s": round(flops_per_step / step_s / 1e12, 5),
             "mfu": round(flops_per_step / step_s / peak, 4)}
-
-
-def _transformer_train_flops(batch, seq, d, heads, layers, ffn, vocab):
-    """Per-step analytic training FLOPs of the decoder-only zoo
-    transformer: per token, the per-layer matmuls (qkv, proj, ffn up/down)
-    plus the attention score/apply contractions (counted dense — the
-    block-causal lowering computes ~half, which MFU deliberately does not
-    credit), plus the vocab head; ×3 for training."""
-    per_tok = layers * (2 * d * 3 * d       # qkv projection
-                        + 2 * d * d         # output projection
-                        + 2 * (d * ffn + ffn * d)   # ffn up + down
-                        + 4 * seq * d)      # scores (2TD) + apply (2TD)
-    per_tok += 2 * d * vocab                # lm head
-    return 3 * batch * seq * per_tok
 
 
 def _lstm_train_flops(batch, seq, hidden, embed, layers, vocab):
@@ -290,200 +272,6 @@ def _bench_allreduce(device):
         raise RuntimeError(
             "no JSON from measure.py (rc=%d): %s"
             % (out2.returncode, (out2.stderr or out2.stdout).strip()[-300:]))
-    return res
-
-
-_FUSION_BENCH_WORKER = r"""
-import json, os, sys, time
-import numpy as np
-sys.path.insert(0, sys.argv[1])
-os.environ.setdefault("MXNET_TELEMETRY", "counters")
-mode, dir_binary, dir_sched, steps = (sys.argv[2], sys.argv[3], sys.argv[4],
-                                      int(sys.argv[5]))
-os.environ["MXNET_FUSED_PATTERNS"] = "0"  # the off-arm bind comes first
-import mxnet_tpu as mx
-from mxnet_tpu import fusion_tune, telemetry
-
-B, T = 2, 512
-rs = np.random.RandomState(0)
-
-
-def build():
-    net = mx.models.get_symbol("transformer", vocab_size=1000, model_dim=128,
-                               num_heads=4, num_layers=2, seq_len=T)
-    exe = net.simple_bind(mx.context.current_context(), data=(B, T),
-                          softmax_label=(B, T))
-    for name, arr in exe.arg_dict.items():
-        if name not in ("data", "softmax_label"):
-            arr[:] = (rs.rand(*arr.shape) - 0.5).astype("float32") * 0.1
-    exe.arg_dict["data"][:] = rs.randint(1, 1000, (B, T)).astype("float32")
-    exe.arg_dict["softmax_label"][:] = \
-        rs.randint(1, 1000, (B, T)).astype("float32")
-    for _ in range(2):  # compile (+ tuning, on the engine arms) + warmup
-        outs = exe.forward_backward()
-    np.asarray(outs[0].asnumpy())
-    return exe
-
-
-if mode == "cold":
-    # cold-tune arm: engine on, empty cache — the first trace measures
-    # each pattern site and persists the verdicts. The parent sets
-    # MXNET_FUSION_TUNE_SCHEDULES per arm (0 = PR 9 binary verdicts,
-    # default = schedule search); dir_binary carries the arm's cache dir.
-    os.environ["MXNET_FUSED_PATTERNS"] = "auto"
-    os.environ["MXNET_FUSION_TUNE_DIR"] = dir_binary
-    build()
-    print(json.dumps({"fusion_bench": 1, "mode": mode,
-                      "tunes": telemetry.counter("fusion.tune").value}),
-          flush=True)
-    raise SystemExit(0)
-
-# A/B arm (warm caches): THREE executors in one process — engine off, the
-# PR 9 binary-verdict engine (warm cache tuned with SCHEDULES=0), and the
-# schedule-search engine (warm cache tuned with the schedule fan-out) —
-# timed in interleaved blocks so host-speed drift hits every arm equally
-# (the checkpoint leg's ABBA discipline)
-exe_off = build()
-os.environ["MXNET_FUSED_PATTERNS"] = "auto"
-os.environ["MXNET_FUSION_TUNE_SCHEDULES"] = "0"
-os.environ["MXNET_FUSION_TUNE_DIR"] = dir_binary
-exe_bin = build()
-fusion_tune.reset()  # drop the in-process memo: next bind reads dir_sched
-del os.environ["MXNET_FUSION_TUNE_SCHEDULES"]
-os.environ["MXNET_FUSION_TUNE_DIR"] = dir_sched
-exe_sched = build()
-tunes_warmup = telemetry.counter("fusion.tune").value
-pre = dict(telemetry.counters())
-
-BLOCK, ROUNDS = max(1, steps // 4), 4
-times = {"off": [], "binary": [], "sched": []}
-for _ in range(ROUNDS):
-    for arm, exe in (("off", exe_off), ("binary", exe_bin),
-                     ("sched", exe_sched)):
-        t0 = time.perf_counter()
-        for _ in range(BLOCK):
-            outs = exe.forward_backward()
-        np.asarray(outs[0].asnumpy())
-        times[arm].append((time.perf_counter() - t0) / BLOCK)
-post = dict(telemetry.counters())
-med = {arm: sorted(v)[len(v) // 2] for arm, v in times.items()}
-# the schedule-search cache's per-site winners, for the report
-schedules = {}
-try:
-    payload = json.load(open(fusion_tune.cache_path()))
-    for key, r in payload["entries"].items():
-        if r.get("engage"):
-            schedules[key.split("|", 1)[0]] = {
-                "lowering": r.get("lowering"),
-                "schedule": r.get("schedule"),
-                "schedules_searched": r.get("schedules_searched")}
-except Exception:
-    pass
-rec = {
-    "fusion_bench": 1, "mode": mode,
-    "step_ms_off": round(med["off"] * 1000, 3),
-    "step_ms_binary": round(med["binary"] * 1000, 3),
-    "step_ms_sched": round(med["sched"] * 1000, 3),
-    "tunes_warmup": tunes_warmup,
-    "tunes_post_warmup": post.get("fusion.tune", 0) - pre.get("fusion.tune", 0),
-    "retraces_post_warmup":
-        post.get("executor.retrace", 0) - pre.get("executor.retrace", 0),
-    "tune_cache_hits": post.get("fusion.tune_cache_hit", 0),
-    "schedules": schedules,
-    "pattern_engaged": {
-        k.split("fusion.pattern_engaged.", 1)[1]: v
-        for k, v in post.items()
-        if k.startswith("fusion.pattern_engaged.")},
-}
-print(json.dumps(rec), flush=True)
-"""
-
-
-def _bench_fusion_patterns(peak):
-    """Pattern-engine A/B leg (docs/PERF.md §13/§15): the SAME transformer
-    training step under three engines, in fresh subprocesses so trace
-    caches and telemetry cannot bleed:
-
-    - ``off``    — ``MXNET_FUSED_PATTERNS=0`` baseline.
-    - ``binary`` — the PR 9 binary-verdict engine: warm cache tuned with
-      ``MXNET_FUSION_TUNE_SCHEDULES=0`` (default candidate only).
-    - ``sched``  — the schedule-search engine (this round's tentpole):
-      warm cache whose winners carry measured block/chunk schedules.
-
-    Two cold subprocess runs tune the two caches; the warm A/B process
-    binds all three executors and times them in interleaved blocks. The
-    gate asserts zero re-tunes and zero post-warmup retraces on the warm
-    arms — the measure-and-cache contract — and the report carries the
-    per-site winning schedules plus analytic-FLOPs MFU per arm so the MFU
-    campaign's trajectory is tracked round over round."""
-    import tempfile
-
-    root = os.path.dirname(os.path.abspath(__file__))
-    steps = int(os.environ.get("MXTPU_BENCH_FUSION_STEPS", "12"))
-    out = {}
-    env_base = dict(os.environ)
-    # bound the cold arms' measurement cost (schedule search multiplies
-    # the candidate count); both arms tune at the same iters, so the A/B
-    # stays fair
-    env_base.setdefault("MXNET_FUSION_TUNE_ITERS", "4")
-    with tempfile.TemporaryDirectory(prefix="mxtpu_fusion_tune") as tdir:
-        dir_binary = os.path.join(tdir, "binary")
-        dir_sched = os.path.join(tdir, "sched")
-        script = os.path.join(tdir, "worker.py")
-        with open(script, "w") as f:
-            f.write(_FUSION_BENCH_WORKER)
-        for mode, arm_dir, schedules in (("cold", dir_binary, "0"),
-                                         ("cold", dir_sched, None),
-                                         ("ab", dir_binary, None)):
-            env = dict(env_base)
-            if schedules is not None:
-                env["MXNET_FUSION_TUNE_SCHEDULES"] = schedules
-            else:
-                env.pop("MXNET_FUSION_TUNE_SCHEDULES", None)
-            r = subprocess.run(
-                [sys.executable, script, root, mode, arm_dir, dir_sched,
-                 str(steps)],
-                capture_output=True, text=True, timeout=1500, cwd=root,
-                env=env)
-            rec = None
-            for l in r.stdout.splitlines():
-                if l.startswith("{") and "fusion_bench" in l:
-                    rec = json.loads(l)
-            if rec is None:
-                raise RuntimeError(
-                    "fusion bench %s arm produced no JSON (rc=%d): %s"
-                    % (mode, r.returncode,
-                       (r.stderr or r.stdout).strip()[-400:]))
-            rec.pop("fusion_bench", None)
-            rec.pop("mode", None)
-            out[mode + ("" if mode == "ab" else ":" + arm_dir)] = rec
-    ab = out["ab"]
-    res = {
-        "model": "transformer_b2_seq512_d128",
-        "step_ms_off": ab["step_ms_off"],
-        "step_ms_binary": ab["step_ms_binary"],
-        "step_ms_sched": ab["step_ms_sched"],
-        "speedup": round(ab["step_ms_off"] / ab["step_ms_sched"], 4),
-        "sched_vs_binary": round(
-            ab["step_ms_binary"] / ab["step_ms_sched"], 4),
-        "tunes_cold_binary": out["cold:" + dir_binary]["tunes"],
-        "tunes_cold_sched": out["cold:" + dir_sched]["tunes"],
-        "tunes_warm": ab["tunes_warmup"] + ab["tunes_post_warmup"],
-        "tune_cache_hits_warm": ab["tune_cache_hits"],
-        "retraces_post_warmup": ab["retraces_post_warmup"],
-        "schedules": ab["schedules"],
-        "pattern_engaged": ab["pattern_engaged"],
-    }
-    flops = _transformer_train_flops(2, 512, 128, 4, 2, 2048, 1000)
-    for arm in ("off", "binary", "sched"):
-        res["mfu_" + arm] = _mfu_fields(
-            flops, ab["step_ms_" + arm] / 1000.0, peak)
-    res["improved"] = bool(res["speedup"] > 1.0)
-    # the campaign acceptance: the schedule-search engine is no worse than
-    # the binary-verdict engine (1% timer-noise band)
-    res["sched_ge_binary"] = bool(
-        res["step_ms_sched"] <= res["step_ms_binary"] * 1.01)
-    res["zero_retune_warm"] = bool(res["tunes_warm"] == 0)
     return res
 
 
@@ -852,8 +640,7 @@ def _bench_input_pipeline(peak):
         # warmup pass for BOTH arms: the two fits share this process's
         # JAX trace/compile caches, so without it the second arm would
         # inherit the first's compile warmth and the wall/step numbers
-        # would measure run ORDER, not the pipeline (the fusion leg
-        # avoids the same bias with fresh subprocesses)
+        # would measure run ORDER, not the pipeline
         run(False)
         run(True)
         pct_off, wall_off, step_off, params_off = run(False)
@@ -973,7 +760,7 @@ def main_inproc():
     _leg(legs, "recommender",
          lambda: _bench_recommender(models, parallel, dev, peak))
     # MXNET_TELEMETRY=counters|trace: the registry's view of the same run —
-    # retraces, pattern engage counts, kv bytes/step — next to the wall time
+    # retraces, kv bytes/step — next to the wall time
     # (docs/OBSERVABILITY.md). Off by default.
     if telemetry.enabled():
         _leg(legs, "telemetry", telemetry.summarize)
@@ -1000,10 +787,8 @@ def _run_inproc():
 def main():
     inproc = _run_inproc()          # holds the chip, then exits
     device, legs = inproc["device"], inproc["legs"]
-    peak = device["bf16_peak_flops"]
     # chip children, one at a time
     _leg(legs, "serving", _bench_serving)
-    _leg(legs, "fusion_patterns", lambda: _bench_fusion_patterns(peak))
     # host-side children (JAX_PLATFORMS=cpu inside each)
     _leg(legs, "allreduce", lambda: _bench_allreduce(device))
     _leg(legs, "checkpoint", _bench_checkpoint)

@@ -34,8 +34,7 @@ from .dispatch_lint import (dispatch_gap_pct, lint_dispatch_gaps,
 from .engine_race import RecordingEngine, ScheduleTrace, analyze_trace
 from .manager import GraphContext, graph_pass, list_passes, run_graph_passes
 from .rewrite import (RewritePass, RewriteResult, graphrewrite_mode,
-                      pattern_site_counts, rewrite, rewrite_pass_names,
-                      verify_rewrite)
+                      rewrite, rewrite_pass_names, verify_rewrite)
 
 __all__ = [
     "CODES", "Diagnostic", "Report", "Severity", "describe_code",
@@ -43,7 +42,7 @@ __all__ = [
     "RecordingEngine", "ScheduleTrace", "analyze_trace",
     "lint", "lint_bind", "graphlint_mode",
     "rewrite", "verify_rewrite", "graphrewrite_mode", "RewritePass",
-    "RewriteResult", "rewrite_pass_names", "pattern_site_counts",
+    "RewriteResult", "rewrite_pass_names",
     "lint_dispatch_paths", "lint_dispatch_source", "lint_dispatch_gaps",
     "dispatch_gap_pct",
 ]

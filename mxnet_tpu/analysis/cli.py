@@ -248,9 +248,9 @@ def _run_autoplan(args, targets, shapes, types, devices) -> int:
     return 1 if plan_failed else 0
 
 
-def _format_rewrite(label, res, report, sites_before, sites_after) -> str:
+def _format_rewrite(label, res, report) -> str:
     """Human block for one target's rewrite run: per-pass node-count table,
-    fired-rule histogram, fusion-site delta, verifier outcome."""
+    fired-rule histogram, verifier outcome."""
     lines = ["== graphrewrite: %s ==" % label]
     lines.append("nodes %d -> %d (%d folded, %d merged, %d removed, "
                  "%d casts) rounds=%d fixpoint=%s"
@@ -271,11 +271,6 @@ def _format_rewrite(label, res, report, sites_before, sites_after) -> str:
     if rules:
         lines.append("fired rules:")
         lines.extend("  %-32s %d" % (k, v) for k, v in sorted(rules.items()))
-    if sites_before != sites_after:
-        names = sorted(set(sites_before) | set(sites_after))
-        lines.append("fusion sites: " + ", ".join(
-            "%s %d -> %d" % (n, sites_before.get(n, 0), sites_after.get(n, 0))
-            for n in names))
     if report is not None:
         bad = [d for d in report
                if d.code in ("GL601", "GL602", "GL603", "GL604")]
@@ -290,19 +285,16 @@ def _format_rewrite(label, res, report, sites_before, sites_after) -> str:
 
 def _format_rewrite_table(rows) -> str:
     """The --rewrite --all-models summary: one rewrite row per target."""
-    table = [("model", "nodes", "folded/merged/removed", "norm_residual",
-              "verdict")]
-    for label, res, report, sb, sa, err in rows:
+    table = [("model", "nodes", "folded/merged/removed", "verdict")]
+    for label, res, report, err in rows:
         if res is None:
-            table.append((label, "-", "-", "-", "ERROR: %s" % err))
+            table.append((label, "-", "-", "ERROR: %s" % err))
             continue
         codes = sorted({d.code for d in report.errors}) if report else []
         table.append((
             label, "%d->%d" % (res.nodes_before, res.nodes_after),
             "%d/%d/%d" % (res.counts["folded"], res.counts["merged"],
                           res.counts["removed"]),
-            "%d->%d" % (sb.get("norm_residual", 0),
-                        sa.get("norm_residual", 0)),
             "ok" if not codes else ",".join(codes)))
     widths = [max(len(r[i]) for r in table) for i in range(len(table[0]))]
     out = ["== graphrewrite summary =="]
@@ -313,15 +305,15 @@ def _format_rewrite_table(rows) -> str:
 
 def _run_rewrite(args, targets, shapes, types) -> int:
     """The --rewrite mode: rewrite every target (analysis/rewrite.py), run
-    the GL6xx verifier, dump per-pass node counts + the fired-rule table +
-    the fusion-site delta. ``--rewrite-json`` adds the full provenance
-    record list to the JSON payload.
+    the GL6xx verifier, dump per-pass node counts + the fired-rule table.
+    ``--rewrite-json`` adds the full provenance record list to the JSON
+    payload.
 
     Exit 0 when every target rewrites and verifies with zero
     GL601/GL602/GL604; 1 on any verifier error (or rewrite crash); 2 on
     load failure."""
     from . import verify_rewrite
-    from .rewrite import pattern_site_counts, rewrite as run_rewrite
+    from .rewrite import rewrite as run_rewrite
 
     rows, payload = [], []
     load_failed = verify_failed = False
@@ -332,28 +324,24 @@ def _run_rewrite(args, targets, shapes, types) -> int:
         except Exception as exc:
             print("graphlint: cannot load %r: %s: %s"
                   % (target, type(exc).__name__, exc), file=sys.stderr)
-            rows.append((target, None, None, {}, {}, str(exc)))
+            rows.append((target, None, None, str(exc)))
             payload.append({"target": target, "load_error": str(exc)})
             load_failed = True
             continue
         try:
             res = run_rewrite(sym, shapes=sh, types=ty, label=label)
             report = verify_rewrite(res, target=label)
-            sites_before = pattern_site_counts(sym)
-            sites_after = pattern_site_counts(res.symbol)
         except Exception as exc:
             print("graphlint: rewrite of %r failed: %s: %s"
                   % (label, type(exc).__name__, exc), file=sys.stderr)
-            rows.append((label, None, None, {}, {}, str(exc)))
+            rows.append((label, None, None, str(exc)))
             payload.append({"target": label, "rewrite_error": str(exc)})
             verify_failed = True
             continue
         if report.errors:
             verify_failed = True
-        rows.append((label, res, report, sites_before, sites_after, None))
+        rows.append((label, res, report, None))
         entry = {"target": label, "rewrite": res.to_dict(),
-                 "fusion_sites_before": sites_before,
-                 "fusion_sites_after": sites_after,
                  "verify": json.loads(report.to_json())}
         if args.rewrite_json:
             entry["records"] = res.records
@@ -361,10 +349,10 @@ def _run_rewrite(args, targets, shapes, types) -> int:
     if args.format == "json" or args.rewrite_json:
         print(json.dumps(payload, indent=2))
     else:
-        for label, res, report, sb, sa, err in rows:
+        for label, res, report, err in rows:
             if res is None:
                 continue
-            print(_format_rewrite(label, res, report, sb, sa))
+            print(_format_rewrite(label, res, report))
             print()
         if len(rows) > 1:
             print(_format_rewrite_table(rows))
@@ -512,8 +500,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="graphlint",
         description="Static graph lint for mxnet_tpu Symbols "
-                    "(shape/dtype propagation, retrace guard, fusion "
-                    "explainer). See docs/static_analysis.md.")
+                    "(shape/dtype propagation, retrace guard, sharding "
+                    "and memory plans). See docs/static_analysis.md.")
     ap.add_argument("targets", nargs="*",
                     help="model-zoo names (e.g. resnet-18) or *-symbol.json paths")
     ap.add_argument("--all-models", action="store_true",
@@ -537,9 +525,8 @@ def main(argv=None) -> int:
                          "(analysis/rewrite.py: const fold, CSE, "
                          "canonicalize, DCE) + the GL6xx provenance "
                          "verifier instead of the lint passes, and dump "
-                         "per-pass node counts, the fired-rule table and "
-                         "the fusion-site delta per target "
-                         "(docs/static_analysis.md §GL6xx)")
+                         "per-pass node counts and the fired-rule table "
+                         "per target (docs/static_analysis.md §GL6xx)")
     ap.add_argument("--dispatch", action="store_true",
                     help="run the source-level dispatch-discipline lint "
                          "(GL7xx: host sync inside dispatch loops, "

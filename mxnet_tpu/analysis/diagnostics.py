@@ -14,7 +14,6 @@ Codes are grouped by pass family:
   * ``GL0xx`` — shape/dtype propagation lint (``shape_lint.py``)
   * ``GL1xx`` — engine race analysis (``engine_race.py``)
   * ``GL2xx`` — pjit retrace guard (``retrace_guard.py``)
-  * ``GL3xx`` — fusion eligibility explainer (``fusion_explain.py``)
   * ``GL4xx`` — sharding-plan lint (``shard_lint.py``)
   * ``GL5xx`` — static memory-liveness / peak-HBM planner (``memory_plan.py``)
   * ``GL6xx`` — graph-rewrite provenance verifier (``rewrite.py``)
@@ -32,7 +31,7 @@ __all__ = ["Severity", "Diagnostic", "Report", "CODES", "describe_code"]
 class Severity:
     """Ordered severity levels. ``ERROR`` means a bind/run would fail or
     produce wrong results; ``WARNING`` means probably-unintended behavior;
-    ``INFO`` is explanatory (fusion rejections, retrace economics)."""
+    ``INFO`` is explanatory (retrace economics, rewrite summaries)."""
 
     INFO = "info"
     WARNING = "warning"
@@ -79,9 +78,6 @@ CODES = {
               "weak-dtype input alongside explicitly-typed variables"),
     "GL203": (Severity.INFO,
               "shape-polymorphic inputs: compile-cache cardinality grows per shape"),
-    # --- fusion explainer --------------------------------------------------
-    "GL303": (Severity.INFO,
-              "generic fusion-pattern site inventory / near-miss rejection"),
     # --- sharding-plan lint ------------------------------------------------
     "GL401": (Severity.WARNING,
               "parameter silently replicated: no dim divides the model axis"),

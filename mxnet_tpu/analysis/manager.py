@@ -91,7 +91,7 @@ class GraphContext:
         for node in self.topo:
             for inp, oi in node.inputs:
                 self.consumers.setdefault(id(inp), []).append((node, oi))
-        # filled by shape_lint, read by retrace_guard / fusion_explain
+        # filled by shape_lint, read by retrace_guard / memory_plan
         self.entry_shape: Dict[Tuple[int, int], Optional[tuple]] = {}
         self.entry_dtype: Dict[Tuple[int, int], object] = {}
         self.var_shape: Dict[str, Optional[tuple]] = {}
@@ -178,9 +178,8 @@ def run_graph_passes(symbol, shape_hints=None, type_hints=None,
     flakier than the thing it lints.
     """
     # passes live in sibling modules registered at import time
-    from . import (shape_lint, retrace_guard, fusion_explain,  # noqa: F401
-                   shard_lint, memory_plan, dispatch_lint,  # noqa: F401
-                   concurrency_lint)  # noqa: F401
+    from . import (shape_lint, retrace_guard, shard_lint,  # noqa: F401
+                   memory_plan, dispatch_lint, concurrency_lint)  # noqa: F401
 
     ctx = GraphContext(symbol, shape_hints=shape_hints, type_hints=type_hints,
                        strict_shapes=strict_shapes, mesh=mesh, rules=rules,

@@ -47,9 +47,13 @@ DOMINANT_FLOOR_BYTES = 1 << 30  # 1 GiB
 # the fused attention op: its dense lowering's autodiff stashes the
 # (B, H, T, S) softmax probabilities across fwd→bwd — an OP-INTERNAL
 # residual no graph entry carries, modeled explicitly below (and elided
-# when the flash training path will engage: the online-softmax recompute
-# backward keeps only the (B, H, T, 1) logsumexp)
+# where the operator's own rule names a form whose backward recomputes
+# them: ``_attention_recomputes_scores``)
 _ATTN_OPS = frozenset({"_contrib_MultiHeadAttention", "MultiHeadAttention"})
+
+# the forms of ``ops.attention.attention_form`` whose backward keeps no
+# scores between the passes
+_RECOMPUTING_FORMS = frozenset({"kernel", "sparse_kernel"})
 
 _TOP_LIVE = 8  # live tensors named at the peak
 
@@ -59,6 +63,44 @@ def _entry_label(ctx, node, oi):
     if node.num_outputs() > 1:
         name += "[%d]" % oi
     return name
+
+
+def _attention_recomputes_scores(ctx, node, mesh):
+    """Whether the attention site ``node`` holds NO scores from its forward
+    to its backward: asked of the operator's own rule,
+    ``ops.attention.attention_form``, with shape-and-dtype carriers for the
+    site's query, key and value, the node's ``causal`` / ``window`` /
+    ``sink`` / ``topk`` and the mesh the plan is made under, exactly as
+    ``MultiHeadAttention`` asks it at trace time.
+
+    Two forms count. ``"kernel"``: ``pallas_attention._flash`` is a
+    ``custom_vjp`` whose residuals are the operands, the output and the
+    (B, H, T) logsumexp; both backward kernels re-derive the probabilities
+    a block at a time. ``"sparse_kernel"``: ``_sparse_kernel_attention`` is a
+    ``custom_vjp`` whose residuals are its six operands alone; its backward
+    differentiates ``_sparse_attention`` afresh, so the probabilities live
+    only inside the backward node. Every other form (``"dense"``,
+    ``"band"``, ``"ring"``, ``"sparse"``) is differentiated by jax through
+    its softmax, which keeps the probabilities, and is charged the dense
+    (B, H, T, S) float32 bound. On the CPU the rule says ``"dense"`` or
+    ``"band"`` (``"sparse"`` under a selection) at every site."""
+    import jax
+
+    from ..ops.attention import attention_form
+
+    carriers = []
+    for inp, oi in node.inputs[:3]:
+        shape = ctx.entry_shape.get((id(inp), oi))
+        if not shape or len(shape) != 4:
+            return False
+        carriers.append(jax.ShapeDtypeStruct(
+            tuple(shape), ctx.entry_dtype.get((id(inp), oi)) or "float32"))
+    if len(carriers) < 3:
+        return False
+    a = node.parsed_attrs()
+    return attention_form(
+        *carriers, bool(a.get("causal")), a.get("window", 0),
+        bool(a.get("sink")), mesh, a.get("topk", 0)) in _RECOMPUTING_FORMS
 
 
 def plan_memory(ctx: GraphContext):
@@ -127,10 +169,10 @@ def plan_memory(ctx: GraphContext):
             if stash_all or node.op in _MXU_OPS:
                 stashed.add((id(node), oi))
 
-    # attention score-stash model: the dense lowering's backward needs the
-    # f32 (B, H, T, S) probabilities, held from the op's forward to its
-    # backward — charged per site unless the flash training path engages
-    # for that exact (shape, dtype) site (fusion.attention_trains_flash)
+    # attention score-stash model: a form that jax differentiates through
+    # its softmax needs the f32 (B, H, T, S) probabilities in its backward,
+    # held from the op's forward — charged per site unless the operator's
+    # rule names a form that recomputes them (_attention_recomputes_scores)
     attn_stash, attn_info = {}, None
     if ctx.train:
         attn_info = {"sites": 0, "score_bytes": 0, "flash_elided_sites": 0}
@@ -145,16 +187,7 @@ def plan_memory(ctx: GraphContext):
                 else None
             if not q_sh or not k_sh or len(q_sh) != 4 or len(k_sh) != 4:
                 continue
-            a = node.parsed_attrs()
-            try:
-                from .. import fusion as _fusion
-
-                flash = _fusion.attention_trains_flash(
-                    q_sh, k_sh, ctx.entry_dtype.get((id(node), 0))
-                    or "float32", a.get("causal"), a.get("scale", -1.0))
-            except Exception:
-                flash = False
-            if flash:
+            if _attention_recomputes_scores(ctx, node, mesh):
                 attn_info["flash_elided_sites"] += 1
                 continue
             out_spec = norm_spec(ctx.entry_spec.get((id(node), 0)), 4)
@@ -248,7 +281,6 @@ def plan_memory(ctx: GraphContext):
 
     act_peak = max(peak, 0)
     total = base + act_peak
-    fusion_info = _fusion_byte_view(ctx, op_nodes, sizes, stash_all)
     plan = {
         "per_device": {
             "params": int(params),
@@ -269,45 +301,9 @@ def plan_memory(ctx: GraphContext):
         "budget_bytes": (int(ctx.budget_bytes)
                          if ctx.budget_bytes is not None else None),
     }
-    if fusion_info is not None:
-        plan["fusion"] = fusion_info
     if attn_info is not None:
         plan["attention"] = attn_info
     return plan
-
-
-def _fusion_byte_view(ctx, op_nodes, sizes, stash_all):
-    """The fusion pattern engine's byte view of this graph: per-pattern
-    site counts and the interior (pattern-elided) bytes — activations that
-    never materialize when their site engages. Under the ``stash`` policy
-    those interiors would otherwise be HELD across the fwd→bwd transition,
-    so ``stash_elidable_bytes`` is the stash-watermark headroom (in bytes)
-    the engine can unlock there (0 under recompute/inference, where the
-    interiors are transient anyway); the prediction above stays the
-    conservative (unfused) upper bound. None when no pattern roots in
-    this graph."""
-    try:
-        from .. import fusion
-
-        directives = fusion.plan(
-            ctx.topo, output_ids={id(n) for n, _ in ctx.symbol._outputs})
-        sites, interior = {}, 0
-        for node in op_nodes:
-            d = directives.get(id(node))
-            if d is None:
-                continue
-            if d["kind"] == "pattern":
-                sites[d["pat"].name] = sites.get(d["pat"].name, 0) + 1
-            elif d["kind"] == "lazy":
-                interior += sizes.get((id(node), 0), 0)
-        if not sites:
-            return None
-        return {"pattern_sites": sites,
-                "interior_bytes": int(interior),
-                "stash_elidable_bytes":
-                    int(interior) if (ctx.train and stash_all) else 0}
-    except Exception:  # the refinement must never sink the prediction
-        return None
 
 
 @graph_pass("memory_plan")

@@ -2,7 +2,7 @@
 
 Every pass in this package used to be read-only: six GLxxx families
 diagnose the Symbol DAG, nothing improves it, so the graph handed to the
-fusion engine and the auto-parallel planner is as sloppy as the frontend
+executor and the auto-parallel planner is as sloppy as the frontend
 wrote it. Relay's thesis (PAPERS.md) is that framework-level rewrites —
 constant folding, CSE, DCE, dtype legalization — compose with and amplify
 downstream fusion; the XLA operator-fusion study quantifies what is left
@@ -21,12 +21,13 @@ Passes (run to fixpoint, ``MXNET_GRAPHREWRITE_ROUNDS`` budget):
   node-signature hash ``(op, frozen attrs, input entries)``; stateful ops
   (aux, rng) and program-output nodes never merge.
 * ``canonicalize`` — normalizes computationally-identical spellings into
-  the forms ``ops/fusion_patterns.py`` matchers expect (``x*x`` →
-  ``square``, positive reduction axes → negative, bare ``relu`` →
-  ``Activation``, ``1/sqrt`` → ``rsqrt``, scalar-identity/_copy elision)
-  so ``norm_residual``/``elemwise_chain``/``matmul_bias_act`` root more
-  sites. Every rule is bitwise-preserving on the XLA lowering, but
-  ``rsqrt_compose``, which is held to one ulp (tested).
+  ONE form each (``x*x`` → ``square``, positive reduction axes → negative,
+  bare ``relu`` → ``Activation``, ``1/sqrt`` → ``rsqrt``,
+  scalar-identity/_copy elision), so that ``cse`` merges what two
+  spellings had kept apart. Nothing downstream of the rewrite reads a
+  canonical form: the pattern matchers it was written for went with the
+  engine (ROADMAP D5). Every rule is bitwise-preserving on the XLA
+  lowering, but ``rsqrt_compose``, which is held to one ulp (tested).
 * ``bf16``         — dtype legalization (opt-in,
   ``MXNET_GRAPHREWRITE_BF16=1``): cast-sandwiches the MXU-bound operands
   declared in ``ops/infer_meta.py`` ``bf16_slots`` (f32 in → bf16 compute
@@ -66,7 +67,7 @@ from .diagnostics import Diagnostic, Report
 from .. import telemetry as _tm
 
 __all__ = ["rewrite", "verify_rewrite", "graphrewrite_mode", "RewritePass",
-           "RewriteResult", "rewrite_pass_names", "pattern_site_counts"]
+           "RewriteResult", "rewrite_pass_names"]
 
 _LOG = logging.getLogger("mxnet_tpu.graphrewrite")
 
@@ -381,18 +382,19 @@ def _same_entry(a, b):
 
 
 class CanonicalizePass(RewritePass):
-    """Normalize computationally-identical spellings into the canonical
-    forms the fusion-pattern matchers (``ops/fusion_patterns.py``) and the
-    other analysis passes expect. Every rule but ``rsqrt_compose`` is
-    bitwise-preserving on the XLA lowering (``tests/test_graph_rewrite.py``
-    pins this per rule):
+    """Normalize computationally-identical spellings into one canonical
+    form each, so that ``cse`` and a reader of the rewritten graph meet one
+    spelling (no consumer keys on a canonical form: ROADMAP D5). Every rule
+    but ``rsqrt_compose`` is bitwise-preserving on the XLA lowering
+    (``tests/test_graph_rewrite.py`` pins this per rule):
 
     * ``mul_self_to_square``  — ``elemwise_mul(x, x)`` / ``broadcast_mul``
       of one entry with itself → ``square(x)``.
     * ``negative_axis``       — positive reduction axes on ``mean``/``sum``
-      (known rank) → the negative canonical form ``norm_residual`` keys on.
+      (known rank) → the negative form, which names the axis whatever
+      the rank.
     * ``relu_to_activation``  — the bare ``relu`` op → ``Activation
-      (act_type=relu)``, the spelling ``matmul_bias_act`` roots.
+      (act_type=relu)``, the model zoo's spelling.
     * ``rsqrt_compose``       — ``reciprocal(sqrt(x))`` and ``1/sqrt(x)``
       (``_rdiv_scalar`` scalar=1) → ``rsqrt(x)``. To ONE ULP, not bitwise:
       the rule replaces a division by a reciprocal square root, and XLA's
@@ -909,17 +911,6 @@ def verify_rewrite(result: RewriteResult, grad_req=None,
 
 
 # ------------------------------------------------------------ bind helper
-def pattern_site_counts(symbol) -> Dict[str, int]:
-    """Per-pattern fusion site counts the fusion engine would root on this
-    symbol — the before/after metric of the canonicalization pass (the
-    ``graphlint --rewrite`` dump and the CI gate read it)."""
-    from .. import fusion
-
-    plan = fusion.plan(symbol._topo(),
-                       output_ids={id(n) for n, _ in symbol._outputs})
-    return fusion.plan_sites(plan)
-
-
 def rewrite_for_bind(symbol, shapes, types, grad_req=None, target="bind"):
     """The ``executor.bind``/``SPMDStepAdapter`` hook: rewrite under the
     ``MXNET_GRAPHREWRITE`` gate and return the symbol the program should

@@ -123,23 +123,6 @@ class _GraphProgram:
         self.store = None
         self.topo = symbol._topo()
         self.group2ctx = dict(group2ctx or {})
-        # fusion plan (fusion.py): the pattern engine's structural rewrite
-        # map (attention, matmul+bias+act, norm+residual, elementwise chains,
-        # each gated per shape by the fusion_tune measured verdict); none
-        # under ctx-group placement (a fused subgraph would straddle a
-        # device boundary). plan() honors MXNET_FUSED_PATTERNS and returns {}
-        # when every pattern is off. The plan's per-pattern site inventory is
-        # computed ONCE here: the serving cache, health probes and the
-        # graphlint --rewrite dump read it instead of re-walking the map
-        self._fusion_plan, self.pattern_sites = {}, {}
-        if not self.group2ctx:
-            from . import fusion as _fusion
-
-            # graph-output node ids keep a node whose value must materialize
-            # as a program output out of every pattern interior
-            self._fusion_plan = _fusion.plan(
-                self.topo, output_ids={id(n) for n, _ in symbol._outputs})
-            self.pattern_sites = _fusion.plan_sites(self._fusion_plan)
         # PlaceDevice-pass analogue (reference: graph_executor.cc:242
         # AssignContext → nnvm PlaceDevice inserting _CrossDeviceCopy): map
         # each node carrying a __ctx_group__ attr to its concrete device;
@@ -228,10 +211,6 @@ class _GraphProgram:
         """Run the graph on jax values. Returns (outputs, new_aux_tuple)."""
         import jax
 
-        fusion_on = bool(self._fusion_plan)
-        if fusion_on:
-            from . import fusion as _fusion
-
         vals = {}
         new_aux = list(aux_vals)
         for node in self.topo:
@@ -245,32 +224,24 @@ class _GraphProgram:
             parsed = node.parsed_attrs()
             n_aux = len(opdef.aux_names(parsed))
             ins = [vals[(id(inp), oi)] for inp, oi in node.inputs]
-            directive = self._fusion_plan.get(id(node)) if fusion_on else None
             # trace-time only: every HLO instruction this node lowers to
             # carries the node's name in its op_name metadata, so a trace
             # viewer shows which layers a fusion.N holds
             with jax.named_scope(node.name):
-                if directive is not None:
-                    outs, aux_out = _fusion.execute(
-                        directive, node,
-                        ins[: len(ins) - n_aux] if n_aux else ins, is_train)
-                else:
-                    if fusion_on:
-                        ins = [_fusion.resolve(x) for x in ins]
-                    dev = self._node_devices.get(id(node))
-                    if dev is not None:
-                        # cross-device copy at a ctx-group boundary
-                        ins = [jax.device_put(x, dev) for x in ins]
-                    node_rng = None
-                    if opdef.needs_rng:
-                        node_rng = jax.random.fold_in(rng, self._rng_ids[id(node)])
-                    outs, aux_out = opdef.apply(
-                        parsed,
-                        ins[: len(ins) - n_aux] if n_aux else ins,
-                        aux=ins[len(ins) - n_aux :] if n_aux else [],
-                        is_train=is_train,
-                        rng=node_rng,
-                    )
+                dev = self._node_devices.get(id(node))
+                if dev is not None:
+                    # cross-device copy at a ctx-group boundary
+                    ins = [jax.device_put(x, dev) for x in ins]
+                node_rng = None
+                if opdef.needs_rng:
+                    node_rng = jax.random.fold_in(rng, self._rng_ids[id(node)])
+                outs, aux_out = opdef.apply(
+                    parsed,
+                    ins[: len(ins) - n_aux] if n_aux else ins,
+                    aux=ins[len(ins) - n_aux :] if n_aux else [],
+                    is_train=is_train,
+                    rng=node_rng,
+                )
             for i, o in enumerate(outs):
                 vals[(id(node), i)] = o
             if n_aux:
@@ -281,8 +252,6 @@ class _GraphProgram:
                         )
                     new_aux[self._aux_index[inp.name]] = new
         outputs = tuple(vals[(id(n), i)] for n, i in self.outputs)
-        if fusion_on:
-            outputs = tuple(_fusion.resolve(o) for o in outputs)
         return outputs, tuple(new_aux)
 
     # --------------------------------------------------------------- compiled
@@ -721,7 +690,7 @@ def bind(symbol, ctx, args, args_grad=None, grad_req="write", aux_states=None, s
             shared_exec._symbol is symbol
             or getattr(shared_exec, "_orig_symbol", None) is symbol):
         # reuse the shared program's (possibly rewritten) symbol so the
-        # jit cache and fusion plan carry over (reshape/bucketing path)
+        # jit cache carries over (reshape/bucketing path)
         symbol = shared_exec._symbol
     else:
         symbol = _rewrite_at_bind(symbol, args, grad_req, aux_states)

@@ -35,15 +35,13 @@ def _refuse_arch(arch, what):
 def _layer_norm(x, name, dim):
     # Deliberately the naive frontend composition: the variance branch
     # recomputes its own mean/centering, and the square is spelled as a
-    # self-multiply. Bit-identical to the canonical single-chain form (XLA
-    # CSEs the duplicates; x*x IS jnp.square), but the norm_residual fusion
-    # matcher cannot root it until the bind-time rewrite pipeline
-    # (MXNET_GRAPHREWRITE: cse merges the duplicate mean/center,
-    # canonicalize turns the self-multiply into square) normalizes it —
-    # the sloppy-frontend contract docs/static_analysis.md §GL6xx gates.
-    # Default-config perf is unaffected: pattern sites only ENGAGE via the
-    # opt-in autotuner (MXNET_FUSION_TUNE_DIR) or a force, and a tuned
-    # deployment turns rewrites on alongside it.
+    # self-multiply. Bit-identical to the single-chain form (XLA CSEs the
+    # duplicates; x*x IS jnp.square). It is spelled so for the rewrite
+    # pipeline's tests (MXNET_GRAPHREWRITE, default off: cse merges the
+    # duplicate mean/center, canonicalize turns the self-multiply into
+    # square; docs/static_analysis.md §GL6xx), and nothing else reads the
+    # difference (ROADMAP D5). The arithmetic is ``vaswani``'s and
+    # ``phi4flash``'s graph, so respelling it changes two cells' programs.
     mean = sym.mean(x, axis=-1, keepdims=True)
     cent = sym.broadcast_sub(x, mean, name="%s_cent" % name)
     cent_v = sym.broadcast_sub(x, sym.mean(x, axis=-1, keepdims=True))

@@ -41,8 +41,7 @@ Training-ready: ``jax.custom_vjp`` with recompute-style backward kernels (the dq
 and dk/dv passes re-derive the probabilities from the saved logsumexp rather
 than storing P). ``MultiHeadAttention`` reaches the kernel through ONE rule,
 ``ops.attention.attention_form``, which reads shapes, attributes and the
-backend (the kernel on the chip, the dense path everywhere else); the fusion
-engine's ``attention`` pattern reaches it by name (``pallas_flash``). It runs
+backend (the kernel on the chip, the dense path everywhere else). It runs
 anywhere under Pallas interpret mode, which is how the CPU tests exercise it.
 """
 from __future__ import annotations

@@ -5,8 +5,8 @@ distributed plan — GL402 emits bytes-moved per implicit reshard edge
 (``analysis/shard_lint.py``) and GL5xx predicts peak HBM per device under
 any PartitionSpec assignment (``analysis/memory_plan.py``) — but until now
 a human picked the mesh and the specs by hand, and a model over budget was
-just a GL501 error. This module closes the loop, the same move PR 9 made
-for fusion (TVM's cost-model-driven search replacing hand tuning, PAPERS.md):
+just a GL501 error. This module closes the loop (TVM's cost-model-driven
+search replacing hand tuning, PAPERS.md):
 
 * ``plan_parallel(symbol, shapes, devices=8, ...)`` enumerates mesh
   factorizations ``data=dp, model=tp`` of the device count and per-param

@@ -8,7 +8,7 @@ recorders:
     (viewable in TensorBoard/Perfetto, a superset of the chrome-trace
     contract), and
   * the framework telemetry spans (mxnet_tpu.telemetry) — engine/executor/
-    fusion/kvstore/io/serving/trainer seams, forced to ``trace`` mode for
+    kvstore/io/serving/trainer seams, forced to ``trace`` mode for
     the window even when ``MXNET_TELEMETRY`` is off.
 
 The two share one clock: in ``trace`` mode every telemetry span is also a

@@ -98,13 +98,10 @@ def _program_key(exe, specs, label, donated, device):
     depend on, as one text: the symbol, every argument's and aux state's
     name, shape and type (and the key's), the donated names, the label, the
     versions of jax and jaxlib, the platform and device kind, this package's
-    sources (``_source_digest``), every ``MXNET_*`` environment variable, the
-    jax settings a lowering reads, and the fusion tuner's verdicts where it
-    is on (they are measured, so no source names them)."""
+    sources (``_source_digest``), every ``MXNET_*`` environment variable and
+    the jax settings a lowering reads."""
     import jax
     import jaxlib
-
-    from .. import fusion_tune
 
     named = lambda names, structs: [
         (n, list(s.shape), str(s.dtype)) for n, s in zip(names, structs)]
@@ -122,9 +119,6 @@ def _program_key(exe, specs, label, donated, device):
         "config": [str(getattr(jax.config, name)) for name in (
             "jax_enable_x64", "jax_default_matmul_precision",
             "jax_default_prng_impl", "jax_numpy_dtype_promotion")],
-        "fusion_tune": fusion_tune.entries_digest(
-            fusion_tune._entries(fusion_tune.device_kind()))
-        if fusion_tune.enabled() else None,
     }, sort_keys=True)
 
 
@@ -243,16 +237,6 @@ class _StoredProgram:
         return jax.jit(_named(call, name), donate_argnums=donate_argnums)
 
 
-def _plan_pattern_sites(exe):
-    """One bound executor's fusion plan as pattern name -> sites: what a
-    serving operator needs to know about the fusion surface of a warmed
-    bucket (per-site engage decisions land on the ``fusion.pattern_*``
-    counters and trace events). Reads the inventory the program computed
-    once at plan time (``_GraphProgram.pattern_sites``) — never re-walks the
-    directive map."""
-    return dict(exe._prog.pattern_sites)
-
-
 class PersistentExecutableCache:
     """One pre-compiled grad-less executor per input-shape bucket.
 
@@ -297,14 +281,6 @@ class PersistentExecutableCache:
         # evicts. None/0 = unbounded.
         self._max_exes = int(max_executables or 0) or None
         self._exes: "OrderedDict[tuple, object]" = OrderedDict()
-        # per-bucket fusion pattern-site counts (filled at compile time):
-        # which patterns the plan rooted in this model's graph, and how
-        # many sites each.
-        # Guarded by its OWN lock: health() reads it, and the main _lock is
-        # held for the full duration of a warmup compile (+ autotune) — a
-        # liveness probe must never block on a compile.
-        self._fusion_sites: Dict[tuple, dict] = {}
-        self._sites_lock = _tm.named_lock("serving.cache.sites")
         self._lock = _tm.named_rlock("serving.cache")
         self._sealed = False
         typed = self._dtype + ("|%r" % self._input_dtypes
@@ -444,23 +420,15 @@ class PersistentExecutableCache:
                           shapes=str(dict(input_shapes))):
                 exe = self._bind(input_shapes)
                 # force the XLA compile NOW (bind only traces lazily):
-                # warmup pays it, the request path never does — this is
-                # also where the fusion pattern engine's per-site
-                # inference gates run (and, with MXNET_FUSION_TUNE_DIR
-                # set, where a cold site gets tuned: warmup pays the
-                # measurement, the request path reuses the verdict)
+                # warmup pays it, the request path never does
                 exe.forward(is_train=False)
                 np.asarray(exe.outputs[0].asnumpy())
-            with self._sites_lock:
-                self._fusion_sites[key] = _plan_pattern_sites(exe)
             if _tm.enabled():
                 _tm.counter("serving.executable_compile").inc()
             self._exes[key] = exe
             if self._max_exes and not self._sealed \
                     and len(self._exes) > self._max_exes:
                 old_key, _ = self._exes.popitem(last=False)
-                with self._sites_lock:
-                    self._fusion_sites.pop(old_key, None)
                 log.info("serving: evicted LRU executable %s from %r "
                          "(cap %d)", dict(old_key), self._model_key,
                          self._max_exes)
@@ -503,15 +471,6 @@ class PersistentExecutableCache:
     def seal(self):
         """Freeze the bucket set: from now on any lookup miss raises."""
         self._sealed = True
-
-    def fusion_sites(self):
-        """Per-bucket fusion pattern-site summaries (compile-time static
-        view; see ``_plan_pattern_sites``). Keys are the bucket shape keys
-        rendered as dicts. Non-blocking with respect to warmup compiles
-        (own lock — safe for health probes)."""
-        with self._sites_lock:
-            return {str(dict(k)): v
-                    for k, v in self._fusion_sites.items()}
 
     # --------------------------------------------------------- persistence
     def _manifest_path(self):

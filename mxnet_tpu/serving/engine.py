@@ -899,7 +899,4 @@ class InferenceEngine:
             "reloads": reloads,
             "deadline_ms": None if self.default_deadline_s is None
             else self.default_deadline_s * 1000.0,
-            # fusion pattern surface of the warmed buckets (inference-mode
-            # gating is per pattern per shape; see docs/PERF.md §13)
-            "fusion": self.cache.fusion_sites(),
         }

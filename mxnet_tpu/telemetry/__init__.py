@@ -4,9 +4,8 @@ The framework-level counterpart of the reference engine profiler
 (src/engine/profiler.cc hand-stamped per-op start/end times and dumped
 chrome-trace JSON): a process-wide registry of named counters/gauges/timers
 with per-step snapshots, structured spans at the hot seams (engine push,
-executor compile-vs-cache-hit, fusion engage/fallback, kvstore push/pull,
-io batch fetch), and a chrome-trace exporter that merges with the XLA
-capture directory. Gated by ``MXNET_TELEMETRY=0|counters|trace``
+executor compile-vs-cache-hit, kvstore push/pull, io batch fetch), and a
+chrome-trace exporter that merges with the XLA capture directory. Gated by ``MXNET_TELEMETRY=0|counters|trace``
 (docs/ENV_VARS.md); off is the default and costs one mode check per
 instrumented seam. Taxonomy and usage: docs/OBSERVABILITY.md.
 

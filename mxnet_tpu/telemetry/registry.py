@@ -3,7 +3,7 @@
 The reference engine's profiler kept per-op stat tables inside the engine
 (src/engine/profiler.cc); here the registry is the framework-wide single
 source of truth every layer reports into — executor compiles/cache hits,
-fusion engage decisions, kvstore bytes, io fetch latency — and every
+kvstore bytes, io fetch latency — and every
 consumer reads out of (Speedometer, Monitor.toc, bench.py, mxtrace).
 
 Thread-safety: one process-wide lock guards instrument *creation*; each

@@ -319,39 +319,179 @@ def test_program_label_names_the_forward_program():
     assert prog._fwd(False).__name__ == "mx_decode"
 
 
-def test_every_node_is_computed_by_its_registered_operator(monkeypatch):
-    """ResNet-50's training interpretation applies the registry's operator
-    at every node: the graph layer holds no second lowering of a
-    Convolution, a BatchNorm, an Activation or an add."""
+# ------------------------------------------------ one way to compute a node
+def _block_sizes(arch):
+    """An architecture's sizes and serving sizes as its own block test has
+    them (``vaswani``'s are the paged decoder's test's)."""
+    import importlib
+
+    if arch == "vaswani":
+        cfg = importlib.import_module("test_kv_decode").CFG
+        return (dict(cfg, pos_len=32),
+                dict(max_len=32, prefill_len=16, page_size=8, lanes=4))
+    block = importlib.import_module("test_%s_block" % arch)
+    if arch == "ouro":
+        return block.SIZES, block.SERVING
+    return block.CFG, dict(block.SERVE, prefill_len=block.SERVE.get(
+        "prefill_len", block.SERVE["max_len"]))
+
+
+def _bound_graph(graph):
+    """(symbol, the shapes of its data, training or not) of one graph the
+    system binds: ResNet-50's and ``vaswani``'s training graphs (decoder
+    alone, and encoder with decoder), ``vaswani``'s chunk graph, and every
+    architecture's prefill and single-step decode graph."""
+    from mxnet_tpu import models
+    from mxnet_tpu.models import transformer as tf
+    from mxnet_tpu.ops.attention import pool_shape
+
+    if graph == "resnet50_train":
+        return (models.get_symbol("resnet-50", num_classes=1000,
+                                  image_shape="3,224,224"),
+                dict(data=(2, 3, 224, 224), softmax_label=(2,)), True)
+    if graph == "vaswani_train":
+        cfg, _ = _block_sizes("vaswani")
+        return (tf.get_symbol(seq_len=16, **dict(cfg, pos_len=None)),
+                dict(data=(2, 16), softmax_label=(2, 16)), True)
+    if graph == "vaswani_mt_train":
+        cfg, _ = _block_sizes("vaswani")
+        return (tf.get_symbol_mt(src_len=8, tgt_len=16,
+                                 **dict(cfg, pos_len=None)),
+                dict(data=(2, 8), dec_data=(2, 16), softmax_label=(2, 16)),
+                True)
+    arch, program = graph.rsplit("_", 1)
+    cfg, serve = _block_sizes(arch)
+    if program == "chunk":
+        slots, chunk = serve["lanes"] * serve["max_len"], serve["page_size"]
+        shapes = dict(data=(1, chunk), pos_idx=(1, chunk),
+                      write_onehot=(chunk, slots), att_mask=(chunk, slots))
+        for name, _, shape in tf.decode_cache(arch=arch, **cfg):
+            shapes[name] = pool_shape(*shape, slots, serve["page_size"])
+        return (tf.get_chunk_symbol(chunk_len=chunk, total_slots=slots,
+                                    **cfg), shapes, False)
+    sizes = dict({"arch": arch}, **cfg)
+    sizes.pop("pos_len", None)
+    # the checkpoint's shapes, as a decoder binds them (``vaswani``'s follow
+    # from the data's)
+    weights = {} if arch == "vaswani" else tf.param_shapes(**sizes)
+    if program == "prefill":
+        bucket = serve["prefill_len"]
+        return (tf.get_prefill_symbol(prefill_len=bucket, **cfg),
+                dict(weights, data=(1, bucket), length=(1, 1)), False)
+    lanes, page = serve["lanes"], serve["page_size"]
+    slots = lanes * serve["max_len"]
+    shapes = dict(weights, data=(lanes, 1), pos_idx=(lanes, 1),
+                  write_slot=(lanes, 1),
+                  page_table=(lanes, serve["max_len"] // page))
+    for name, kind, shape in tf.decode_cache(**sizes):
+        shapes[name] = pool_shape(
+            *shape, slots * tf.loop_passes(**sizes), page) \
+            if kind == "pool" else (lanes,) + tuple(shape)
+    return (tf.get_decode_symbol(max_len=slots, page_size=page, **cfg),
+            shapes, False)
+
+
+def _graph_names():
+    from mxnet_tpu.models.transformer import ARCHS
+
+    return ["resnet50_train", "vaswani_train", "vaswani_mt_train",
+            "vaswani_chunk"] + ["%s_%s" % (arch, program) for arch in ARCHS
+                                for program in ("prefill", "decode")]
+
+
+@pytest.fixture(scope="module")
+def interpreted():
+    """``interpreted(graph)``: ONE abstract interpretation of a bound graph
+    (``jax.make_jaxpr`` of ``_GraphProgram.interpret``: no compile, no bind)
+    with ``OpDef.apply`` wrapped, kept for the module. Gives the program,
+    how often each operator was applied, every name on the name stack of an
+    equation, and for each node a function that interprets that node ALONE
+    over the same abstract operands and counts the equations it emits."""
     import collections
 
     import jax
 
-    from mxnet_tpu import fusion, models
     from mxnet_tpu.executor import _GraphProgram
     from mxnet_tpu.ops.registry import OpDef
 
-    applied = collections.Counter()
-    apply = OpDef.apply
+    kept = {}
+    abstract = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
 
-    def counting(self, *args, **kwargs):
-        applied[self.name] += 1
-        return apply(self, *args, **kwargs)
+    def interpret(graph):
+        if graph in kept:
+            return kept[graph]
+        net, shapes, is_train = _bound_graph(graph)
+        prog = _GraphProgram(net)
+        arg_shapes, _, aux_shapes = net.infer_shape(**shapes)
+        # an operator is handed its node's parsed attributes, the same dict
+        # every time: that names the node an application is for
+        node_of = {id(n.parsed_attrs()): n for n in prog.topo
+                   if not n.is_variable}
+        applied, alone = collections.Counter(), {}
+        apply = OpDef.apply
 
-    monkeypatch.setattr(OpDef, "apply", counting)
-    net = models.get_symbol("resnet-50", num_classes=1000,
-                            image_shape="3,224,224")
-    prog = _GraphProgram(net)
-    arg_shapes, _, aux_shapes = net.infer_shape(data=(2, 3, 224, 224),
-                                                softmax_label=(2,))
-    applied.clear()  # shape inference applies operators too
-    spec = lambda shapes: tuple(jax.ShapeDtypeStruct(s, "float32")
-                                for s in shapes)
-    jax.eval_shape(lambda a, x, k: prog.interpret(a, x, True, k),
-                   spec(arg_shapes), spec(aux_shapes), jax.random.PRNGKey(0))
-    ops = collections.Counter(n.op for n in prog.topo if not n.is_variable)
+        def watching(self, attrs, inputs, aux=None, is_train=False, rng=None):
+            applied[self.name] += 1
+            operands = ([abstract(x) for x in inputs],
+                        [abstract(x) for x in aux or []],
+                        None if rng is None else abstract(rng))
+
+            def equations():
+                return len(jax.make_jaxpr(
+                    lambda ins, ax, key: apply(
+                        self, attrs, ins, aux=ax, is_train=is_train,
+                        rng=key))(*operands).jaxpr.eqns)
+
+            alone[node_of[id(attrs)].name] = equations
+            return apply(self, attrs, inputs, aux=aux, is_train=is_train,
+                         rng=rng)
+
+        spec = lambda found: tuple(jax.ShapeDtypeStruct(s, "float32")
+                                   for s in found)
+        OpDef.apply = watching
+        try:
+            closed = jax.make_jaxpr(
+                lambda a, x, k: prog.interpret(a, x, is_train, k))(
+                    spec(arg_shapes), spec(aux_shapes), jax.random.PRNGKey(0))
+        finally:
+            OpDef.apply = apply
+        scopes = {part for eqn in closed.jaxpr.eqns
+                  for part in str(eqn.source_info.name_stack).split("/")}
+        kept[graph] = (prog, applied, alone, scopes)
+        return kept[graph]
+
+    return interpret
+
+
+@pytest.mark.parametrize("graph", _graph_names())
+def test_every_node_is_computed_by_its_registered_operator(interpreted,
+                                                           graph):
+    """Interpreting a graph the system binds applies the registry's operator
+    once at every node and nothing else: the graph layer holds no second way
+    to compute a node and no module that could plan one."""
+    import collections
+    import importlib.util
+
+    prog, applied, _, _ = interpreted(graph)
+    ops = collections.Counter(n.opdef().name for n in prog.topo
+                              if not n.is_variable)
     assert applied == ops
-    assert (ops["Convolution"], ops["BatchNorm"], ops["Activation"],
-            ops["elemwise_add"]) == (53, 50, 50, 16)
-    for name in ("_exec_bn", "_exec_conv", "Deferred"):
-        assert not hasattr(fusion, name), name
+    if graph == "resnet50_train":
+        assert (ops["Convolution"], ops["BatchNorm"], ops["Activation"],
+                ops["elemwise_add"]) == (53, 50, 50, 16)
+    assert importlib.util.find_spec("mxnet_tpu.fusion") is None
+
+
+@pytest.mark.parametrize("graph", _graph_names())
+def test_every_node_leaves_its_own_name(interpreted, graph):
+    """Every operator node that emits an equation at all has its own name on
+    the name stack of at least one: an instruction's ``op_name`` carries its
+    symbol node's name, not its consumer's (``PERF.md`` section 3). A node
+    whose name is on none is interpreted alone and must emit nothing (an
+    identity, a constant): exempt by that rule, not by a list of names."""
+    prog, _, alone, scopes = interpreted(graph)
+    nodes = {n.name for n in prog.topo if not n.is_variable}
+    assert set(alone) == nodes
+    assert len(nodes & scopes) > len(nodes) // 2, sorted(nodes - scopes)
+    under_another = {name: alone[name]() for name in nodes - scopes}
+    assert not any(under_another.values()), under_another

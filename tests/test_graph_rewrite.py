@@ -3,10 +3,8 @@
 Covers each builtin pass (const fold, CSE, canonicalize, bf16 legalize,
 DCE) with its bit-parity contract, pipeline idempotence (running twice is a
 no-op with zero provenance records on pass 2), the bind-time
-MXNET_GRAPHREWRITE integration on both executor paths, the fusion-site
-acceptance (canonicalization strictly increases matched norm_residual
-sites on the transformer zoo model), and the cached per-program fusion
-site inventory.
+MXNET_GRAPHREWRITE integration on both executor paths, and the
+``graphlint --rewrite`` dump.
 """
 import numpy as np
 import pytest
@@ -159,7 +157,7 @@ def test_canonicalize_keeps_output_identity_nodes():
     assert res.symbol.list_outputs() == net.list_outputs()
 
 
-# ----------------------------------------------- transformer parity + sites
+# ------------------------------------------------------ transformer parity
 def test_transformer_rewrite_parity_and_node_reduction():
     """The zoo transformer's sloppy-frontend LN: CSE+canonicalize+DCE must
     shrink the graph, keep the forward BITWISE, and keep the backward
@@ -178,21 +176,6 @@ def test_transformer_rewrite_parity_and_node_reduction():
         # than the duplicated one — ≤1e-6 absolute (measured ~3e-8)
         np.testing.assert_allclose(g1[k], g2[k], atol=1e-6, rtol=0,
                                    err_msg=k)
-
-
-def test_canonicalization_strictly_increases_norm_residual_sites(
-        monkeypatch):
-    """Acceptance (ISSUE 14): the transformer zoo model matches strictly
-    MORE norm_residual fusion sites after the rewrite pipeline."""
-    monkeypatch.setenv("MXNET_FUSED_PATTERNS", "auto")
-    net = _tiny_transformer()
-    before = analysis.pattern_site_counts(net)
-    after = analysis.pattern_site_counts(analysis.rewrite(net).symbol)
-    assert after.get("norm_residual", 0) > before.get("norm_residual", 0)
-    assert after.get("norm_residual") == 3
-    # the other patterns are untouched
-    assert after.get("attention") == before.get("attention")
-    assert after.get("matmul_bias_act") == before.get("matmul_bias_act")
 
 
 def test_rewrite_idempotent_second_run_is_noop():
@@ -305,21 +288,7 @@ def test_rewrite_telemetry_counters(monkeypatch):
     assert telemetry.counter("rewrite.nodes_removed").value > 0
 
 
-def test_program_caches_pattern_site_inventory(monkeypatch):
-    """Satellite: the bound program carries the plan's per-pattern site
-    inventory, computed once — the serving cache reads it verbatim."""
-    monkeypatch.setenv("MXNET_FUSED_PATTERNS", "auto")
-    from mxnet_tpu.executor import _GraphProgram
-    from mxnet_tpu import fusion
-
-    net = analysis.rewrite(_tiny_transformer()).symbol
-    prog = _GraphProgram(net)
-    assert prog.pattern_sites == fusion.plan_sites(prog._fusion_plan)
-    assert prog.pattern_sites.get("norm_residual") == 3
-
-
-def test_cli_rewrite_dump_and_json(capsys, monkeypatch):
-    monkeypatch.setenv("MXNET_FUSED_PATTERNS", "auto")
+def test_cli_rewrite_dump_and_json(capsys):
     from mxnet_tpu.analysis.cli import main
 
     rc = main(["transformer", "--rewrite"])
@@ -327,7 +296,6 @@ def test_cli_rewrite_dump_and_json(capsys, monkeypatch):
     assert rc == 0
     assert "graphrewrite: transformer" in out
     assert "cse.merge" in out and "mul_self_to_square" in out
-    assert "norm_residual 0 -> 13" in out
     rc = main(["transformer", "--rewrite", "--rewrite-json"])
     import json as _json
 
@@ -335,7 +303,7 @@ def test_cli_rewrite_dump_and_json(capsys, monkeypatch):
     assert rc == 0
     entry = payload[0]
     assert entry["rewrite"]["nodes_after"] < entry["rewrite"]["nodes_before"]
-    assert entry["fusion_sites_after"]["norm_residual"] == 13
+    assert sorted(entry) == ["records", "rewrite", "target", "verify"]
     assert entry["records"], "provenance records missing from the dump"
     assert not [d for d in entry["verify"]["diagnostics"]
                 if d["code"] in ("GL601", "GL602", "GL604")]

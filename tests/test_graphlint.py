@@ -220,23 +220,6 @@ def _gl405_clean():
             {"shapes": {"data": (8, 512)}, "mesh": mesh, "rules": rules})
 
 
-def _gl303_broken():
-    # NEAR miss: the FullyConnected has a fusable relu consumer but also a
-    # second consumer, so the matmul_bias_act pattern cannot root
-    d = mx.sym.Variable("data")
-    fc = mx.sym.FullyConnected(data=d, num_hidden=8, name="fc_shared")
-    relu = mx.sym.Activation(data=fc, act_type="relu", name="relu")
-    return relu + fc, {"shapes": {"data": (4, 16)}}
-
-
-def _gl303_clean():
-    # sole fusable consumer: the pattern roots, nothing to report
-    d = mx.sym.Variable("data")
-    fc = mx.sym.FullyConnected(data=d, num_hidden=8, name="fc")
-    return (mx.sym.Activation(data=fc, act_type="relu", name="relu"),
-            {"shapes": {"data": (4, 16)}})
-
-
 # --- GL5xx: memory planner (no mesh needed: plans replicated) --------------
 def _gl501_broken():
     d = mx.sym.Variable("data")
@@ -274,7 +257,6 @@ GRAPH_CODE_CASES = {
     "GL201": (_gl201_broken, _gl201_clean),
     "GL202": (_gl202_broken, _gl202_clean),
     "GL203": (_gl203_broken, _gl203_clean),
-    "GL303": (_gl303_broken, _gl303_clean),
     "GL401": (_gl401_broken, _gl401_clean),
     "GL402": (_gl402_broken, _gl402_clean),
     "GL403": (_gl403_broken, _gl403_clean),
@@ -1064,6 +1046,49 @@ def test_memory_plan_structure_and_policies():
     dp = analysis.lint(net, shapes=sh, mesh="dp=8").memory_plan
     assert dp["per_device"]["act_peak"] < pd["act_peak"]
     assert dp["per_device"]["params"] == pd["params"]  # replicated
+
+
+@pytest.mark.parametrize("backend,attrs,mesh,form,charged", [
+    ("cpu", {}, None, "dense", 1.0),
+    ("tpu", {}, None, "kernel", 0.0),
+    ("tpu", {"window": 256}, None, "band", 1.0),
+    ("tpu", {}, "dp=2", "dense", 0.5),
+], ids=["dense", "kernel", "band", "several_devices"])
+def test_attention_score_stash_follows_the_operators_rule(
+        monkeypatch, backend, attrs, mesh, form, charged):
+    """An attention site's scores are charged from its forward to its
+    backward unless ``ops.attention.attention_form``, asked as the operator
+    asks it, names a form whose backward recomputes them: the kernel on one
+    chip is elided; the dense path, a band (the same dense bound) and a step
+    over several devices (the rule keeps it dense; the scores are a device's
+    share) are charged B x H x T x S x 4 bytes."""
+    from mxnet_tpu.ops import attention as attn_op
+
+    B, H, T, D = 2, 16, 1024, 64
+    q, k, v = (mx.sym.Variable(n) for n in "qkv")
+    net = mx.sym.MakeLoss(mx.sym.sum(mx.sym.MultiHeadAttention(
+        q, k, v, causal=True, name="attn", **attrs)), name="loss")
+    shapes = {n: (B, H, T, D) for n in "qkv"}
+    named, rule = [], attn_op.attention_form
+
+    def spy(*operands_and_attrs):
+        named.append(rule(*operands_and_attrs))
+        return named[-1]
+
+    monkeypatch.setattr(attn_op, "attention_form", spy)
+    dense = analysis.lint(net, shapes=shapes, mesh=mesh).memory_plan
+    assert named == ["band" if "window" in attrs else "dense"]  # the CPU's
+    monkeypatch.setattr(attn_op, "_backend", lambda: backend)
+    plan = analysis.lint(net, shapes=shapes, mesh=mesh).memory_plan
+    assert named[1:] == [form]
+    scores = B * H * T * T * 4
+    assert plan["attention"] == {
+        "sites": 1, "score_bytes": int(scores * charged),
+        "flash_elided_sites": 0 if charged else 1}
+    # what the CPU says of the same graph is the dense bound, and the
+    # elided site's peak is that much lower
+    assert dense["per_device"]["peak"] - plan["per_device"]["peak"] == (
+        0 if charged else scores)
 
 
 def test_predicted_peak_within_2x_of_measured_live_buffers():

@@ -18,7 +18,7 @@ import pytest
 import jax
 
 import mxnet_tpu as mx
-from mxnet_tpu import chips, compile_cache, context, telemetry
+from mxnet_tpu import chips, compile_cache, context
 from mxnet_tpu.base import MXNetError
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -210,29 +210,3 @@ def test_replica_supervisor_refuses_more_replicas_than_chips(monkeypatch,
     # on the CPU the same fleet is fine (nothing is spawned until start())
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     ReplicaSupervisor({"model": "mlp"}, n_replicas=4, workdir=str(tmp_path))
-
-
-# ------------------------------------------------------ the tuner says so
-def test_tuner_counts_and_logs_a_failing_candidate(caplog):
-    import jax.numpy as jnp
-
-    from mxnet_tpu import fusion_tune
-
-    def refused(x):
-        raise RuntimeError("Mosaic failed to compile TPU kernel: test")
-
-    saved = telemetry.current_override()
-    telemetry.set_mode("counters")
-    fusion_tune.reset()
-    try:
-        before = telemetry.counter("fusion.tune_error").value
-        with caplog.at_level("WARNING", logger="mxnet_tpu"):
-            rec = fusion_tune.measure_candidates(
-                lambda x: x * 2.0, [("refused", refused)],
-                (jnp.ones((8, 8)),), train=False, iters=1)
-        assert not rec["engage"]
-        assert "Mosaic failed" in rec["measured"]["refused"]["error"]
-        assert telemetry.counter("fusion.tune_error").value == before + 1
-        assert "Mosaic failed" in caplog.text
-    finally:
-        telemetry.set_mode(saved)

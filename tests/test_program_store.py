@@ -125,7 +125,7 @@ def _other_jax(monkeypatch):
 
 
 def _other_env(monkeypatch):
-    monkeypatch.setenv("MXNET_FUSED_PATTERNS", "0")
+    monkeypatch.setenv("MXNET_RING_ATTENTION", "0")
 
 
 # what changes -> how: keywords of ``_cache``, or a patch

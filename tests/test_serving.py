@@ -1,8 +1,7 @@
 """Serving subsystem (mxnet_tpu/serving/, docs/SERVING.md): executable
 cache warmup/seal/persistence, continuous batching over shape buckets
 (pad-to-bucket correctness, deadline partials, oversize rejection,
-cross-thread ordering), the predictor's zero-recompile contract, and the
-fusion gate's inference mode with the bf16/int8 quantized variants."""
+cross-thread ordering) and the predictor's zero-recompile contract."""
 import json
 import os
 import threading

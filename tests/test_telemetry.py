@@ -36,8 +36,6 @@ def _conv_bn_net():
     sym = mx.sym.BatchNorm(sym, name="bn1")
     sym = mx.sym.Activation(sym, act_type="relu")
     sym = mx.sym.Flatten(sym)
-    # FullyConnected -> relu: one site of the pattern engine, so a traced
-    # bind carries a fusion.pattern event
     sym = mx.sym.FullyConnected(sym, num_hidden=16, name="fc1")
     sym = mx.sym.Activation(sym, act_type="relu")
     sym = mx.sym.FullyConnected(sym, num_hidden=4, name="fc")
@@ -490,7 +488,7 @@ def test_speedometer_reads_step_registry(tm, caplog):
 @pytest.mark.slow
 def test_fit_trace_end_to_end(tm, tmp_path):
     """The acceptance path: a 3-step fit with MXNET_TELEMETRY=trace dumps a
-    chrome trace holding engine/executor/fusion/kvstore/io spans, >=1
+    chrome trace holding engine/executor/kvstore/io spans, >=1
     compile and >=1 cache-hit step, and mxtrace --check passes."""
     tm.set_mode("trace")
     from mxnet_tpu import profiler
@@ -510,7 +508,7 @@ def test_fit_trace_end_to_end(tm, tmp_path):
     path = profiler.dump_profile()
     trace = json.load(open(path))
     cats = {e.get("cat") for e in trace["traceEvents"] if e["ph"] == "X"}
-    assert {"engine", "executor", "fusion", "kvstore", "io"} <= cats, cats
+    assert {"engine", "executor", "kvstore", "io"} <= cats, cats
     counters = trace["otherData"]["counters"]
     assert counters.get("executor.compile", 0) >= 1
     assert counters.get("executor.cache_hit", 0) >= 1

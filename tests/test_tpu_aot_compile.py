@@ -4,8 +4,8 @@ without one.
 The CPU tests run the kernels in interpret mode, which accepts block shapes
 and vector ops the TPU compiler refuses. libtpu can compile for a "TPU v5
 lite" from this sandbox through the topology API, so each kernel is lowered
-with ``interpret=False`` and compiled, forward and backward, at one
-chip_smoke.py shape. This is the guard that a kernel edited on the CPU still
+with ``interpret=False`` and compiled at the cells' shapes (the attention
+kernel forward and backward). This is the guard that a kernel edited on the CPU still
 compiles on the chip (the first on-chip run found two that did not).
 """
 import math
@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 
 from mxnet_tpu.ops import pallas_attention as pa
-from mxnet_tpu.ops import pallas_matmul_bias_act as pm
-from mxnet_tpu.ops import pallas_norm_residual as pn
 
 
 @pytest.fixture(scope="module")
@@ -38,12 +36,10 @@ def v5e():
 
 @pytest.fixture(autouse=True)
 def mosaic_not_interpret(monkeypatch):
-    # the kernels pick interpret mode from the default backend (CPU here),
-    # and the pool's read picks its form from it
+    # the rules pick a kernel or XLA's form from the default backend (the
+    # CPU here)
     from mxnet_tpu.ops import attention
 
-    for mod in (pm, pn):
-        monkeypatch.setattr(mod, "_interpret_mode", lambda: False)
     monkeypatch.setattr(attention, "_backend", lambda: "tpu")
 
 
@@ -66,7 +62,7 @@ def _with_grads(fn, n_args):
 
 
 @pytest.mark.parametrize("heads,kv_heads,t,dk,dv", [
-    (8, 8, 512, 64, 64),          # chip_smoke.py's shape, a batch of 8
+    (8, 8, 512, 64, 64),          # a training step's, a batch of 8
     (32, 2, 2048, 128, 128),      # grouped heads folded into the rows
     (32, 32, 1024, 192, 128),     # a value narrower than the key
 ])
@@ -78,19 +74,6 @@ def test_flash_attention_fwd_bwd(v5e, heads, kv_heads, t, dk, dv):
                                             interpret=False)
     _compile(v5e, _with_grads(fn, 3), spec(heads, dk), spec(kv_heads, dk),
              spec(kv_heads, dv))
-
-
-def test_matmul_bias_act_fwd_bwd(v5e):
-    fn = lambda a, w, b: pm.matmul_bias_act(a, w, b, "relu")
-    _compile(v5e, _with_grads(fn, 3), ((4096, 512), "bfloat16"),
-             ((2048, 512), "bfloat16"), ((2048,), "bfloat16"))
-
-
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_layer_norm_affine_fwd_bwd(v5e, dtype):
-    fn = lambda x, g, b: pn.layer_norm_affine(x, g, b, interpret=False)
-    _compile(v5e, _with_grads(fn, 3), ((4096, 512), dtype), ((512,), dtype),
-             ((512,), dtype))
 
 
 def test_resnet50_training_step_is_xla_alone(v5e):

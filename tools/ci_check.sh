@@ -1,15 +1,13 @@
 #!/usr/bin/env bash
-# CI entrypoint: static analysis first, then the fusion pattern engine's
-# schedule-cache smoke, then the telemetry trace smoke, then the 8-process kvstore
-# bucket/overlap smoke, then the serving smoke, then the elastic
-# fault-tolerance chaos smoke, then the tier-1 test suite.
+# CI entrypoint: static analysis first, then the telemetry trace smoke,
+# then the 8-process kvstore bucket/overlap smoke, then the serving smoke,
+# then the elastic fault-tolerance chaos smoke, then the tier-1 test suite.
 #
 # Step 1 dogfoods the graphlint subsystem on every bundled model (the
 # acceptance gate: every model must lint with zero error-severity
 # diagnostics), then runs the graph-rewrite gate: the zoo sweep under
 # MXNET_GRAPHREWRITE=verify (zero GL601/602/604, transformer node-count
-# reduction + strictly more norm_residual fusion sites), the 3-model
-# raw-vs-rewritten bit-parity subcheck (tests/nightly/rewrite_parity.py),
+# reduction), the 3-model raw-vs-rewritten bit-parity subcheck (tests/nightly/rewrite_parity.py),
 # and the GL7xx dispatch-discipline gates: the zoo mesh sweep must carry
 # zero GL7xx findings while the `graphlint --dispatch` source scan must
 # keep flagging the known kv_decode host-sync sites — present AND waived
@@ -20,22 +18,20 @@
 # dependency-free tools/src_lint.py fallback — always-on either way; the
 # every-source-compiles floor is additionally enforced by
 # tests/test_graphlint.py::test_package_sources_compile.
-# Step 3 tunes one pattern site of the fusion engine into a temporary cache
-# and re-runs against it warm (the measure-and-cache contract, docs/PERF.md
-# §13/§15). Step 4 runs a tiny fit loop under MXNET_TELEMETRY=trace,
+# Step 3 runs a tiny fit loop under MXNET_TELEMETRY=trace,
 # dumps the chrome trace, and gates it with tools/mxtrace --check
 # (docs/OBSERVABILITY.md — the telemetry dump is a machine contract, so CI
-# smokes it end to end). Step 5 runs the 8-process CPU kvstore smoke
+# smokes it end to end). Step 4 runs the 8-process CPU kvstore smoke
 # (tests/nightly/dist_kvstore_overlap.py): bucket-plan overlap counters
 # during a Module.fit, sharded-vs-replicated weight parity, and the
 # bucketed allreduce bandwidth floor (docs/PERF.md §11).
-# Step 6 runs the 2-process recommender sparse-kvstore smoke
+# Step 5 runs the 2-process recommender sparse-kvstore smoke
 # (tests/nightly/dist_sparse_kvstore.py, docs/SPARSE.md): a sparse-push fit
 # must be weight-parity (atol 1e-6) with a dense-push control while moving
 # strictly fewer wire bytes (kvstore.bytes.sparse < the control's
 # allreduce bytes), plus the budget-armed autoplan gate: the 8-device plan
 # for the recommender must shard an embedding table over the model axis.
-# Step 7 runs the serving engine smoke (tools/serve_bench.py --check):
+# Step 6 runs the serving engine smoke (tools/serve_bench.py --check):
 # QPS/p99 under a tiny open-loop load with zero post-warmup retraces, for
 # both the bucketed engine and the transformer KV-cache decode path
 # including the K=8 decode-megastep leg (token-identical parity +
@@ -46,13 +42,13 @@
 # fault injection on the dispatch path + a mid-run hitless weight reload,
 # gated on zero hung futures, zero retraces, and recovery to `healthy`
 # (docs/RESILIENCE.md).
-# Step 8 runs the serving FLEET chaos smoke (serve_bench --fleet,
+# Step 7 runs the serving FLEET chaos smoke (serve_bench --fleet,
 # docs/SERVING.md §Fleet): open-loop load through the replica router over
 # 4 replica processes with injected dispatch faults, a mid-run replica
 # SIGKILL (supervised restart), and a mid-run fleet-wide hitless rollout —
 # gated on zero hung/lost requests, aggregate QPS above the single-replica
 # closed-loop baseline and recovery to healthy.
-# Step 9 runs the elastic fault-tolerance chaos smoke
+# Step 8 runs the elastic fault-tolerance chaos smoke
 # (tests/nightly/dist_elastic_chaos.py --orchestrate): an 8-process
 # Module.fit in sharded-update mode with periodic async checkpoints, one
 # worker killed mid-run — the survivors must re-form to 7, reseed from the
@@ -60,11 +56,11 @@
 # 7-process control run; it also asserts checkpoint.inflight was observed
 # > 0 mid-fit, i.e. the async write really overlapped the step
 # (docs/FAULT_TOLERANCE.md).
-# Step 10 is the repo's tier-1 pytest command (ROADMAP.md).
+# Step 9 is the repo's tier-1 pytest command (ROADMAP.md).
 set -uo pipefail
 cd "$(dirname "$0")/.."
 
-echo "== [1/10] graphlint: all bundled models (plain + sharding-plan sweep) =="
+echo "== [1/9] graphlint: all bundled models (plain + sharding-plan sweep) =="
 JAX_PLATFORMS=cpu python tools/graphlint --all-models --min-severity warning \
     || { echo "graphlint FAILED"; exit 1; }
 # the same zoo under an abstract dp=8,model=2 mesh: the GL4xx sharding-plan
@@ -132,8 +128,7 @@ PYEOF
 rm -f "$AUTOPLAN_SWEEP"
 # graph-rewrite gate (docs/static_analysis.md §GL6xx): the whole zoo must
 # rewrite + verify under MXNET_GRAPHREWRITE=verify with ZERO GL601/602/604,
-# and the transformer must show real gains — nodes merged/removed > 0 AND
-# strictly more norm_residual fusion sites after canonicalization (the
+# and the transformer must show real gains — nodes merged/removed > 0 (the
 # sloppy-frontend LN contract, models/transformer.py). The JSON dump is
 # the committed CI record of the per-model rewrite plans.
 REWRITE_SWEEP="$(mktemp /tmp/graphlint_rewrite_ci.XXXXXX.json)"
@@ -160,13 +155,9 @@ assert not bad, "rewrite verify errors: %s" % "; ".join(bad)
 tf = next(e for e in payload if e["target"] == "transformer")
 c = tf["rewrite"]["counts"]
 assert c["merged"] + c["removed"] + c["folded"] > 0, c
-before = tf["fusion_sites_before"].get("norm_residual", 0)
-after = tf["fusion_sites_after"].get("norm_residual", 0)
-assert after > before, "norm_residual sites %d -> %d" % (before, after)
-print("rewrite sweep OK: %d models verified; transformer %d->%d nodes, "
-      "norm_residual sites %d->%d"
+print("rewrite sweep OK: %d models verified; transformer %d->%d nodes"
       % (len(payload), tf["rewrite"]["nodes_before"],
-         tf["rewrite"]["nodes_after"], before, after))
+         tf["rewrite"]["nodes_after"]))
 PYEOF
 rm -f "$REWRITE_SWEEP"
 # bit-parity subcheck on 3 representative models: forward must be BITWISE
@@ -231,7 +222,7 @@ JAX_PLATFORMS=cpu python tools/graphlint --concurrency --format json \
     || { echo "graphlint --concurrency FAILED (unwaived GL8xx)"; exit 1; }
 echo "concurrency source gate OK (zero unwaived GL8xx)"
 
-echo "== [2/10] source lint (pinned ruff, src_lint.py fallback — always on) =="
+echo "== [2/9] source lint (pinned ruff, src_lint.py fallback — always on) =="
 # the rule set is pinned in ruff.toml; when ruff is absent (the CI image
 # ships no third-party linters and must not pip install) the
 # dependency-free tools/src_lint.py enforces the same codes, so this step
@@ -243,67 +234,7 @@ else
         bench.py || { echo "src_lint fallback FAILED"; exit 1; }
 fi
 
-echo "== [3/10] fusion pattern engine: schedule-cache smoke =="
-# pattern-engine schedule-cache smoke (docs/PERF.md §13/§15): tune ONE
-# matmul+bias+act site — large enough that the (bm, bn) schedule fan-out
-# has >1 distinct effective tiling — into a temp dir, then re-run the SAME
-# fit against the warmed cache. Gate: the cold run tunes exactly once AND
-# searches ≥1 schedule variant (the persisted record carries
-# schedules_searched ≥ 1); the warm run is all cache hits with ZERO
-# re-tunes and ZERO post-warmup retraces. This is the measure-and-cache
-# contract: tune once per device kind, ever — now per SCHEDULE.
-TUNE_DIR="$(mktemp -d /tmp/fusion_tune_ci.XXXXXX)"
-for run in 1 2; do
-JAX_PLATFORMS=cpu MXNET_DEFAULT_CONTEXT=cpu MXNET_TELEMETRY=counters \
-MXNET_FUSION_TUNE_DIR="$TUNE_DIR" MXNET_FUSED_PATTERNS=matmul_bias_act \
-MXNET_FUSION_TUNE_ITERS=2 \
-python - "$run" <<'PYEOF' || { echo "schedule-cache smoke FAILED (run $run)"; rm -rf "$TUNE_DIR"; exit 1; }
-import json, sys
-import numpy as np
-import mxnet_tpu as mx
-from mxnet_tpu import fusion_tune, telemetry
-
-run = int(sys.argv[1])
-x = mx.sym.Variable("data")
-h = mx.sym.FullyConnected(x, num_hidden=256, name="fc1")
-h = mx.sym.Activation(h, act_type="relu", name="act1")
-net = mx.sym.SoftmaxOutput(
-    mx.sym.FullyConnected(h, num_hidden=4, name="fc2"), name="softmax")
-rs = np.random.RandomState(0)
-ex = net.simple_bind(mx.cpu(), data=(256, 32), softmax_label=(256,),
-                     grad_req="write")
-for name, arr in zip(net.list_arguments(), ex.arg_arrays):
-    arr[:] = (rs.randint(0, 4, arr.shape) if "label" in name
-              else rs.uniform(-0.5, 0.5, arr.shape)).astype("f")
-ex.forward(is_train=True)
-ex.backward()
-# a second execution through the same executor: any retrace here would
-# break the warm-run zero-retrace contract
-ex.forward(is_train=True)
-ex.backward()
-tunes = telemetry.counter("fusion.tune").value
-hits = telemetry.counter("fusion.tune_cache_hit").value
-retraces = telemetry.counter("executor.retrace").value
-sched = 0
-payload = json.load(open(fusion_tune.cache_path()))
-assert payload["version"] == 2, payload.get("version")
-for rec in payload["entries"].values():
-    sched = max(sched, rec.get("schedules_searched", 0))
-if run == 1:
-    assert tunes == 1, "cold run must tune exactly once, got %d" % tunes
-    assert sched >= 1, "cold run must search >=1 schedule variant"
-else:
-    assert tunes == 0, "warm run must NOT re-tune, got %d" % tunes
-    assert hits >= 1, "warm run must serve the verdict from the cache"
-assert retraces == 0, "post-warmup retraces: %d" % retraces
-print("schedule-cache smoke run %d OK: tunes=%d cache_hits=%d "
-      "schedules_searched=%d retraces=%d" % (run, tunes, hits, sched,
-                                             retraces))
-PYEOF
-done
-rm -rf "$TUNE_DIR"
-
-echo "== [4/10] telemetry: trace-on fit smoke + mxtrace schema gate =="
+echo "== [3/9] telemetry: trace-on fit smoke + mxtrace schema gate =="
 TRACE_DIR="$(mktemp -d /tmp/mxtrace_ci.XXXXXX)"
 JAX_PLATFORMS=cpu MXNET_DEFAULT_CONTEXT=cpu MXNET_TELEMETRY=trace \
 python - "$TRACE_DIR" <<'PYEOF' || { echo "telemetry fit smoke FAILED"; rm -rf "$TRACE_DIR"; exit 1; }
@@ -318,8 +249,6 @@ sym = mx.sym.Convolution(sym, kernel=(3, 3), pad=(1, 1), num_filter=8,
 sym = mx.sym.BatchNorm(sym, name="bn1")
 sym = mx.sym.Activation(sym, act_type="relu")
 sym = mx.sym.Flatten(sym)
-# FullyConnected -> relu: one site of the pattern engine, so the trace
-# carries a fusion.pattern event
 sym = mx.sym.FullyConnected(sym, num_hidden=16, name="fc1")
 sym = mx.sym.Activation(sym, act_type="relu")
 sym = mx.sym.FullyConnected(sym, num_hidden=4, name="fc")
@@ -337,7 +266,7 @@ mx.nd.waitall()
 path = mx.profiler.dump_profile()
 trace = json.load(open(path))
 cats = {e.get("cat") for e in trace["traceEvents"] if e.get("ph") == "X"}
-need = {"engine", "executor", "fusion", "kvstore", "io"}
+need = {"engine", "executor", "kvstore", "io"}
 assert need <= cats, "missing span families: %s" % (need - cats)
 c = trace["otherData"]["counters"]
 assert c.get("executor.compile", 0) >= 1 and c.get("executor.cache_hit", 0) >= 1, c
@@ -348,7 +277,7 @@ python tools/mxtrace "$TRACE_DIR/profile.json" --check \
     || { echo "mxtrace --check FAILED"; rm -rf "$TRACE_DIR"; exit 1; }
 rm -rf "$TRACE_DIR"
 
-echo "== [5/10] kvstore: 8-process bucket/overlap smoke (docs/PERF.md §11) =="
+echo "== [4/9] kvstore: 8-process bucket/overlap smoke (docs/PERF.md §11) =="
 # functional leg: overlap counters fire during Module.fit on the per-key
 # priority path, and sharded-update weights bit-match replicated (atol 1e-6)
 JAX_PLATFORMS=cpu MXNET_DEFAULT_CONTEXT=cpu \
@@ -369,7 +298,7 @@ JAX_PLATFORMS=cpu MXNET_DEFAULT_CONTEXT=cpu MXNET_KVSTORE_BUCKET_MB=16 \
     "${BW_CMD[@]}" || { echo "kvstore bandwidth smoke FAILED"; exit 1; }
 }
 
-echo "== [6/10] sparse kvstore: 2-proc recommender smoke (docs/SPARSE.md) =="
+echo "== [5/9] sparse kvstore: 2-proc recommender smoke (docs/SPARSE.md) =="
 # sparse-push fit weight-parity with the dense-push control (atol 1e-6) AND
 # kvstore.bytes.sparse strictly below the control's table allreduce bytes;
 # both gates assert inside the script on every rank
@@ -400,7 +329,7 @@ print("recommender autoplan OK: mesh %s, sharded tables %s, comm %.2f KiB "
 PYEOF
 rm -f "$SPARSE_PLAN"
 
-echo "== [7/10] serving: serve_bench smoke (docs/SERVING.md) =="
+echo "== [6/9] serving: serve_bench smoke (docs/SERVING.md) =="
 # tiny-model CPU serving smoke: sustained QPS > 0, finite p99, ZERO
 # post-warmup retraces/compiles (the sealed executable-cache contract,
 # gated via the GL201-203 guard + executor compile/cache-hit telemetry),
@@ -440,7 +369,7 @@ python tools/serve_bench.py --model mlp --chaos --qps 150 --duration 2 \
     --check \
     || { echo "serve_bench chaos smoke FAILED"; exit 1; }
 
-echo "== [8/10] serving fleet: 4-replica router chaos smoke (docs/SERVING.md §Fleet) =="
+echo "== [7/9] serving fleet: 4-replica router chaos smoke (docs/SERVING.md §Fleet) =="
 # open-loop load through the Router over 4 replica PROCESSES with the
 # seeded chaos plan: injected fleet.dispatch faults (re-dispatch path),
 # one replica SIGKILLed mid-run (supervisor restart with capped backoff),
@@ -488,7 +417,7 @@ print("fleet trace gate OK: %d events across %d pids, %d cross-process "
 PYEOF
 rm -f "$FLEET_TRACE"
 
-echo "== [9/10] elastic: 8-proc chaos smoke (docs/FAULT_TOLERANCE.md) =="
+echo "== [8/9] elastic: 8-proc chaos smoke (docs/FAULT_TOLERANCE.md) =="
 # kill 1 of 8 workers mid-fit: survivors pause, re-form to 7, reseed from
 # the sharded async checkpoint, resume — and must reach weight parity with
 # an uninterrupted 7-proc control run; checkpoint.inflight must have been
@@ -500,7 +429,7 @@ python tests/nightly/dist_elastic_chaos.py --orchestrate "$CHAOS_DIR" \
     || { echo "elastic chaos smoke FAILED"; rm -rf "$CHAOS_DIR"; exit 1; }
 rm -rf "$CHAOS_DIR"
 
-echo "== [10/10] tier-1 tests =="
+echo "== [9/9] tier-1 tests =="
 rm -f /tmp/_t1.log
 timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' \
     --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly \
