@@ -10,10 +10,16 @@ chip keeps such a pool row-major and a Pallas operand needs no re-layout).
 
 The grid walks the rows. The page table and the contexts are scalar-prefetched;
 per row a loop over ``ceil(context / block)`` blocks of ``pages_per_block``
-pages, each page one ``make_async_copy`` a pool into a double-buffered VMEM
-scratch, the next block in flight while this one is scored (the next ROW's
-first block too: the work items of all rows are one pipeline). Scores, online
-softmax and accumulator are float32; the matrix unit takes the pool's dtype.
+pages, each LIVE page one ``make_async_copy`` a pool into a double-buffered
+VMEM scratch (a row's last block fetches the pages its context reaches and no
+further: what is fetched is the context rounded up to a page, whatever the
+block), the next block in flight while this one is scored (the next ROW's
+first block too: the work items of all rows are one pipeline). A block is
+sized by the BYTES a loop turn keeps in flight, not by the table's length: a
+turn has a cost of its own (0.47 us at one page of ouro's rows, 1.15 us at
+eight: PERF.md section 6, PR 57), so under small blocks the core paces the
+read and not the memory. Scores, online softmax and accumulator are float32;
+the matrix unit takes the pool's dtype.
 
 Grouped and unequal head widths without a lane slice: the query comes in
 BLOCK-DIAGONAL, ``(H, Hkv * dk)`` with head (k, g)'s numbers in columns
@@ -47,6 +53,9 @@ _NEG = -1e30
 _SCRATCH_BYTES = 4 << 20
 # the most slots a block holds
 _BLOCK_SLOTS = 256
+# the key and value bytes a block brings, where the slots allow: what a loop
+# turn keeps in flight (PERF.md section 6, PR 57, has the sweep that named it)
+_BLOCK_BYTES = 1 << 20
 
 
 def supported(query, pool_k, pool_v):
@@ -71,18 +80,15 @@ def supported(query, pool_k, pool_v):
 
 
 def pages_per_block(max_pages, page, row_bytes):
-    """Pages a block of the kernel fetches, from the shapes alone: a
-    sixteenth of the longest context a row may hold, at most ``_BLOCK_SLOTS``
-    slots and what two buffers of ``row_bytes`` a slot (key and value) fit in
-    ``_SCRATCH_BYTES``, at least a page, and a divisor of ``max_pages``. A
-    row fetches its context rounded up to a block, so where contexts are
-    short (a table of few pages) the block is small."""
-    slots = min(max(max_pages * page // 16, page), _BLOCK_SLOTS,
-                max(_SCRATCH_BYTES // (2 * row_bytes), page))
-    pages = max(slots // page, 1)
-    while max_pages % pages:
-        pages -= 1
-    return pages
+    """Pages a block of the kernel fetches, from the shapes alone: as many as
+    bring a block's ``row_bytes`` a slot (key and value) to ``_BLOCK_BYTES``,
+    at most ``_BLOCK_SLOTS`` slots, what two buffers fit in
+    ``_SCRATCH_BYTES`` and the table's ``max_pages``, at least a page. A
+    row's last block fetches its live pages only, so a large block costs a
+    short context nothing and need not divide the table."""
+    most = min(_BLOCK_SLOTS, _SCRATCH_BYTES // (2 * row_bytes)) // page
+    want = -(-_BLOCK_BYTES // (page * row_bytes))
+    return max(min(want, most, max_pages), 1)
 
 
 def block_slots(pool_k, pool_v, max_pages):
@@ -105,23 +111,58 @@ def _kernel(table_ref, ctx_ref, start_ref, next_ref, q_ref, k_hbm, v_hbm,
     context = ctx_ref[row]
     blocks = (context + block - 1) // block
 
-    def copies(slot, lane=None, blk=0):
-        """The 2 x ``pages`` copies of block ``blk`` of ``lane`` into buffer
-        ``slot``; a wait needs the shapes and the semaphore alone, so it
-        names no lane and looks no frame up."""
-        out = []
-        for i in range(pages):
-            frame = 0 if lane is None else table_ref[lane, blk * pages + i]
-            at = pl.ds(i * page, page)
-            out.append(pltpu.make_async_copy(
-                k_hbm.at[frame], k_buf.at[slot, at], sem.at[0, slot]))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[frame], v_buf.at[slot, at], sem.at[1, slot]))
-        return out
+    def live(lane, blk):
+        """The pages of ``lane``'s block ``blk`` that its context reaches."""
+        return jnp.minimum(
+            pages, (ctx_ref[lane] - blk * block + page - 1) // page)
+
+    def copies(slot, i, frame=0):
+        """The two copies of a block's page ``i`` into buffer ``slot``; a
+        wait needs the shapes and the semaphore alone, so it names no
+        frame."""
+        at = pl.ds(i * page if isinstance(i, int)
+                   else pl.multiple_of(i * page, page), page)
+        return (pltpu.make_async_copy(
+                    k_hbm.at[frame], k_buf.at[slot, at], sem.at[0, slot]),
+                pltpu.make_async_copy(
+                    v_hbm.at[frame], v_buf.at[slot, at], sem.at[1, slot]))
+
+    def each_live_page(n, do):
+        """``do(i)`` for the ``n`` live pages of a block: a whole block's
+        unrolled (the scheduler interleaves the copies' address arithmetic
+        and bounds checks, which a loop runs a page after a page), a last
+        block's in a loop."""
+        @pl.when(n == pages)
+        def _():
+            for i in range(pages):
+                do(i)
+
+        @pl.when(n < pages)
+        def _():
+            jax.lax.fori_loop(0, n, lambda i, _: do(i), None)
 
     def fetch(lane, blk, slot):
-        for copy in copies(slot, lane, blk):
-            copy.start()
+        def start(i):
+            for copy in copies(slot, i, table_ref[lane, blk * pages + i]):
+                copy.start()
+
+        each_live_page(live(lane, blk), start)
+
+    def arrive(blk, slot):
+        """Every copy ``fetch`` started for this row's block ``blk``, copy
+        for copy: a semaphore counts what was started and no more."""
+        def wait(i):
+            for copy in copies(slot, i):
+                copy.wait()
+
+        each_live_page(live(row, blk), wait)
+
+    # the pages a last block leaves unfetched are scored with p = 0.0, and
+    # 0 x what a buffer held before its first use must be 0: zeros, once
+    # (later, a dead row holds an earlier block's, pool data and finite)
+    @pl.when(row == 0)
+    def _():
+        v_buf[...] = jnp.zeros_like(v_buf)
 
     first = start_ref[row] % 2      # the buffer this row's first block is in
 
@@ -146,8 +187,7 @@ def _kernel(table_ref, ctx_ref, start_ref, next_ref, q_ref, k_hbm, v_hbm,
         def _():
             fetch(next_ref[row], 0, 1 - slot)
 
-        for copy in copies(slot):
-            copy.wait()
+        arrive(i, slot)
         s = jax.lax.dot_general(
             q, k_buf[slot], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale

@@ -327,8 +327,9 @@ def _pool_reads(cache, input_shapes):
     read. The form is the operator's own rule (``pool_read_form``).
     ``slots``: what an XLA form scores a dispatch (the rows' tables whole, the
     pool for every row, or the rows an indexer selected); for the kernel, whose fetch follows the rows'
-    contexts, the slots of ONE block (``serving.step_kernel_slots`` rounds
-    each stepped lane's context up to it)."""
+    contexts, the slots of ONE block (``serving.step_kernel_blocks`` counts
+    the blocks of each stepped lane's context, ``serving.step_kernel_slots``
+    the pages they fetch)."""
     from ..ops.attention import pool_read_form, pool_slots
 
     pools = {id(n): pool.name for n, pool in _pool_readers(cache._sym)}
@@ -2132,11 +2133,15 @@ class PagedKVDecoder:
                     _tm.counter("serving.step_gathered_slots").inc(
                         self._step_gathered_slots)
                     if self._kernel_block:
-                        # what one layer's kernel fetches: each stepped
-                        # lane's context, rounded up to a block
+                        # what one layer's kernel fetches, each stepped
+                        # lane's context rounded up to a page, and the loop
+                        # turns it takes, a block each
                         _tm.counter("serving.step_kernel_slots").inc(sum(
+                            -(-lane.pos // self.page_size) * self.page_size
+                            for _, _, lane in stepped))
+                        _tm.counter("serving.step_kernel_blocks").inc(sum(
                             -(-lane.pos // self._kernel_block)
-                            * self._kernel_block for _, _, lane in stepped))
+                            for _, _, lane in stepped))
                     if self._window:
                         # what a window layer's read finds live, a layer
                         _tm.counter("serving.step_window_slots").inc(sum(

@@ -2,9 +2,9 @@
 interpreted on the CPU, against XLA's own-pages form of the same read on the
 same page-major pools: ``KVPoolAttention`` with its rule held to each form.
 
-The cases are the four ``generate`` cells' (dtype, key/value heads, group,
+The cases are the five ``generate`` cells' (dtype, key/value heads, group,
 key width, value width), the widths as published where the CPU affords
-them, at few lanes and a short table."""
+them, at few lanes and a short table that the block does not divide."""
 import numpy as np
 import pytest
 
@@ -15,7 +15,8 @@ from mxnet_tpu.ops.attention import _kv_pool_attention, pool_read_form
 from mxnet_tpu.ops.pallas_paged_read import (paged_read, pages_per_block,
                                              supported)
 
-PAGE, MAX_PAGES, LANES = 16, 32, 4
+# 40 pages under a block of 16: two blocks and a half
+PAGE, MAX_PAGES, LANES = 16, 40, 4
 # cell -> (dtype, Hkv, G, dk, dv, scale, tolerance of a row's norm)
 CELLS = {
     # 64 heads over 4 of 192 / 128 there; the same 3 : 2 at half the width
@@ -23,12 +24,17 @@ CELLS = {
     "transformer-base": ("float32", 8, 1, 64, 64, -1.0, 1e-5),
     "granite-4.0-h-micro": ("bfloat16", 8, 4, 64, 64, 0.015625, 1e-2),
     "lfm2-24b-a2b": ("bfloat16", 8, 4, 64, 64, -1.0, 1e-2),
+    # 16 heads of 128, a row of 2,048 a pool: the widest any cell runs
+    "ouro-2.6b": ("bfloat16", 16, 1, 128, 128, -1.0, 1e-2),
 }
 
 
-def _block():
-    """The slots of the kernel's block at this table (its edge is a case)."""
-    return PAGE * pages_per_block(MAX_PAGES, PAGE, 2 * 512 * 2)
+def _block(cell):
+    """The slots of the kernel's block at this cell's rows and this table
+    (its edge is a case)."""
+    dtype, hkv, _, dk, dv, _, _ = CELLS[cell]
+    return PAGE * pages_per_block(
+        MAX_PAGES, PAGE, hkv * (dk + dv) * jnp.dtype(dtype).itemsize)
 
 
 # case -> the contexts of the four lanes; lane 0 is the one the case names
@@ -40,7 +46,15 @@ CONTEXTS = {
     "page_and_one": lambda b: [PAGE + 1, PAGE, 5, 60],
     "a_blocks_edge": lambda b: [b, b + 1, 2 * b, 2 * b - 1],
     "the_whole_table": lambda b: [PAGE * MAX_PAGES, 17, PAGE * MAX_PAGES, 1],
-    "ragged": lambda b: [3 * b + 5, 7, 0, 5 * PAGE + 9],
+    "ragged": lambda b: [2 * b + 5, 7, 0, 5 * PAGE + 9],
+    # the partial tail: a last block fetches its live pages and no more
+    "a_pages_edge_in_a_block": lambda b: [3 * PAGE, b + 2 * PAGE, 5 * PAGE,
+                                          PAGE],
+    "one_slot_into_a_second_page": lambda b: [b + PAGE + 1, PAGE + 1,
+                                              2 * b + PAGE + 1, 1],
+    # the prefetch of the NEXT row's first block takes that row's page count
+    "none_between_two": lambda b: [b + 3, 0, 2 * PAGE + 1, 5],
+    "a_tail_after_a_whole_block": lambda b: [1, b, 0, b + 1],
 }
 
 
@@ -91,7 +105,7 @@ def test_the_kernel_reads_what_the_own_pages_form_reads(monkeypatch, cell,
     probabilities' one rounding); a lane with none comes out finite (zeros
     from the kernel) and moves no other lane."""
     dtype, hkv, group, dk, dv, _, tol = CELLS[cell]
-    contexts = CONTEXTS[case](_block())
+    contexts = CONTEXTS[case](_block(cell))
     step = _step(cell, contexts, shared)
     assert supported(*step[:3])
     kernel = _read(monkeypatch, "kernel", cell, step)
@@ -142,17 +156,29 @@ def test_the_rule_names_the_kernel_on_the_chip_alone(monkeypatch):
 
 
 @pytest.mark.parametrize("max_pages,page,row_bytes,pages", [
-    (64, 16, 4096, 4),      # transformer-base.generate: 64 slots
-    (128, 16, 2048, 8),     # granite, lfm2: 128 slots
-    (512, 16, 2560, 16),    # mimo-v2-flash.generate: 256 slots
-    (3, 16, 2048, 1),       # a table of three pages: a page, which divides it
-    (96, 16, 2048, 6),      # 96 slots: six pages divide 96
+    (20, 16, 8192, 8),      # ouro-2.6b.generate: 128 slots, 1 MB (a page was)
+    (64, 16, 4096, 16),     # transformer-base.generate: 256 slots, 1 MB
+    (128, 16, 2048, 16),    # granite, lfm2: 256 slots, 512 KB
+    (512, 16, 2560, 16),    # mimo-v2-flash.generate: 256 slots, 640 KB
+    (512, 16, 5120, 13),    # phi-4-mini-flash-reasoning: 208 slots, 1 MB
+    (512, 16, 1024, 16),    # nemotron: 256 slots of a row of 256 lanes
+    (3, 16, 2048, 3),       # a table of three pages: all three
+    (7, 16, 8192, 7),       # a block need not divide the table, nor it a block
+    (96, 32, 2048, 8),      # pages of 32: 256 slots are eight
     (512, 16, 1 << 20, 1),  # a row so wide that two buffers fit a page alone
 ])
-def test_the_block_follows_the_longest_context(max_pages, page, row_bytes,
+def test_the_block_follows_the_bytes_in_flight(max_pages, page, row_bytes,
                                                pages):
+    """A block brings ``_BLOCK_BYTES`` of keys and values where 256 slots,
+    the scratch and the table allow; the table's length sizes nothing
+    else."""
+    from mxnet_tpu.ops import pallas_paged_read as kernel
+
     assert pages_per_block(max_pages, page, row_bytes) == pages
-    assert max_pages % pages == 0
+    assert pages * page <= kernel._BLOCK_SLOTS or pages == 1
+    assert 2 * pages * page * row_bytes <= kernel._SCRATCH_BYTES or pages == 1
+    assert pages == pages_per_block(max_pages * 16, page, row_bytes) \
+        or pages == max_pages
 
 
 def test_a_call_outside_the_operator_takes_contexts_as_given():
@@ -193,15 +219,20 @@ def test_a_decoder_steps_through_the_kernel_and_says_what_it_fetched(
     retires, stepped side by side through the (interpreted) kernel and
     through XLA's gather: the same logits to float32's sum order, the same
     greedy tokens; and the telemetry says how many of the program's reads
-    are the kernel's and what one layer's kernel fetched, each stepped
-    lane's context rounded up to a block."""
+    are the kernel's, what one layer's kernel fetched (each stepped lane's
+    context rounded up to a page) and in how many loop turns (a block
+    each)."""
     from mxnet_tpu import telemetry as tm
+    from mxnet_tpu.ops import pallas_paged_read as kernel
 
     saved = tm.current_override()
     tm.set_mode("counters")
     try:
+        # a block of 32 slots under this table of 128, so that contexts cross
+        # blocks here as they do at a cell's widths
+        monkeypatch.setattr(kernel, "_BLOCK_BYTES", 2 * PAGE * 2 * 128 * 4)
         logits, block = [], PAGE * pages_per_block(8, PAGE, 2 * 128 * 4)
-        assert block == PAGE
+        assert block == 2 * PAGE
         for form in ("kernel", "own_pages"):
             tm.reset()
             dec = _decoder(monkeypatch, form).warmup()
@@ -211,14 +242,15 @@ def test_a_decoder_steps_through_the_kernel_and_says_what_it_fetched(
                 assert snap["serving.pool_read.%s_layers" % name] \
                     == 2 * (name == form)
             seqs = [dec.admit(np.arange(n) % 47)[0] for n in (3, 15, 32)]
-            rows, fetched = [], 0
+            rows, fetched, blocks = [], 0, 0
             for t in range(20):
                 if t == 4:
                     seqs.append(dec.admit(np.arange(5) % 43)[0])
                 if t == 8:
                     dec.retire(seqs.pop(0))
-                fetched += sum(-(-(dec.position(s) + 1) // block) * block
-                               for s in seqs)
+                contexts = [dec.position(s) + 1 for s in seqs]
+                fetched += sum(-(-n // PAGE) * PAGE for n in contexts)
+                blocks += sum(-(-n // block) for n in contexts)
                 out = dec.step({s: (7 * t + s) % 48 for s in seqs})
                 rows += [np.asarray(out[s]) for s in seqs]
             logits.append(np.stack(rows))
@@ -226,11 +258,15 @@ def test_a_decoder_steps_through_the_kernel_and_says_what_it_fetched(
             if form == "kernel":
                 assert moved["serving.step_kernel_slots"] == fetched
                 assert moved["serving.step_gathered_slots"] == 0
-                # what it fetched is the contexts, rounded up to a block
+                # what it fetched is the contexts, rounded up to a PAGE
+                # whatever the block; its loop turns, a block each
                 assert 1.0 <= fetched / moved["serving.step_context_tokens"] \
                     < 2.0
+                assert moved["serving.step_kernel_blocks"] == blocks
+                assert len(rows) < blocks < fetched // PAGE
             else:
                 assert "serving.step_kernel_slots" not in moved
+                assert "serving.step_kernel_blocks" not in moved
                 assert moved["serving.step_gathered_slots"] \
                     == moved["serving.paged_steps"] * 4 * 128
         np.testing.assert_allclose(logits[0], logits[1], rtol=2e-5, atol=2e-5)
@@ -261,7 +297,7 @@ attention._backend = lambda: "tpu"
 assert attention.pool_read_form(
     query, pool, pool, spec((32, 128), "float32"), 16) == "kernel"
 assert kernel.supported(query, pool, pool)
-assert kernel.block_slots(pool, pool, 128) == 128
+assert kernel.block_slots(pool, pool, 128) == 256
 pallas = lambda: sorted(m for m in sys.modules if "pallas" in m
                         and not m.startswith("mxnet_tpu"))
 assert pallas() == [], pallas()

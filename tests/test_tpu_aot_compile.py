@@ -173,6 +173,55 @@ def _paged_read_calls(hlo):
             and "/paged_read/" in line]
 
 
+def _kernel_assembly(body):
+    """A Mosaic kernel's serialized body (the base64 of a custom call's
+    ``"body"``) as assembly WITHOUT the file and line of each operation."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    ctx = mlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True   # the serialized dialect's
+    with ctx:
+        return ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+            enable_debug_info=False)
+
+
+def _paged_read_blocks(hlo):
+    """What the program's calls of the page-walk kernel keep in VMEM: the set
+    of ``(slots, lanes, type)`` of their double-buffered scratch blocks, read
+    off each call's serialized body (key and value buffers alike, two
+    buffers each: ``memref<2 x slots x lanes>``)."""
+    found = set()
+    for line in _paged_read_calls(hlo):
+        asm = _kernel_assembly(re.search(
+            r'"body":"([A-Za-z0-9+/=]+)"', line).group(1))
+        head = asm[asm.index("^bb0("):].split("\n", 1)[0]
+        scratch = re.findall(
+            r"memref<2x(\d+)x(\d+)x(bf16|f32), #tpu.memory_space<vmem>>",
+            head)
+        assert len(scratch) == 2, head
+        found |= {(int(slots), int(lanes), dt) for slots, lanes, dt in scratch}
+    return found
+
+
+def _assert_the_block_the_rule_names(hlo, max_pages, page, lanes, dtype):
+    """Every page-walk call of the program holds the block ``pages_per_block``
+    names for pools of ``lanes`` a row under a table of ``max_pages``, both
+    buffers of both pools inside ``_SCRATCH_BYTES``."""
+    from mxnet_tpu.ops import pallas_paged_read as kernel
+
+    width = jnp.dtype(dtype).itemsize
+    pages = kernel.pages_per_block(max_pages, page, 2 * lanes * width)
+    assert _paged_read_blocks(hlo) == {
+        (pages * page, lanes, {"bfloat16": "bf16", "float32": "f32"}[dtype])}
+    assert 2 * pages * page * 2 * lanes * width <= kernel._SCRATCH_BYTES
+    return pages * page
+
+
 def _assert_one_write_a_node(hlo, nodes, pools=2):
     """Each ``KVPoolSlotWrite`` node named in ``nodes`` (its scope's tag)
     writes each of its ``pools`` page-major pools in ONE device operation, a
@@ -463,6 +512,12 @@ def test_transformer_base_decode_step_contracts_on_the_chip(v5e):
     # custom call (its operands' small blocks, not the pages it copies): the
     # whole-pool read counted 0.84 GB a layer and 2.2 GB in all
     assert compiled.cost_analysis()["bytes accessed"] < 0.6e9
+    # the kernel's block by the bytes a turn keeps in flight: 256 slots of a
+    # 4,096-byte row (a sixteenth of the table, 64, until PR 57), 2 MB of
+    # scratch
+    assert _assert_the_block_the_rule_names(
+        compiled.as_text(), slots // lanes // page, page, heads * dh,
+        "float32") == 256
 
 
 def test_transformer_base_prefill_heads_one_row_on_the_chip(v5e):
@@ -985,21 +1040,10 @@ def _program_sha1(hlo):
     the file and line of each operation it was traced from) replaced by its
     assembly WITHOUT those locations: two trees whose programs are equal
     instruction for instruction answer the same, wherever their lines sit."""
-    import base64
     import hashlib
 
-    from jax._src.interpreters import mlir
-    from jax._src.lib import tpu
-    from jax._src.lib.mlir import ir
-
     def assembly(found):
-        ctx = mlir.make_ir_context()
-        tpu.register_dialect(ctx)
-        ctx.allow_unregistered_dialects = True   # the serialized dialect's
-        with ctx:
-            kernel = ir.Module.parse(base64.b64decode(found.group(1)))
-            return '"body":%r' % kernel.operation.get_asm(
-                enable_debug_info=False)
+        return '"body":%r' % _kernel_assembly(found.group(1))
 
     text = re.sub(r'"body":"([A-Za-z0-9+/=]+)"', assembly,
                   _program_alone(hlo))
@@ -1626,6 +1670,11 @@ def test_a_looped_stacks_decode_step_updates_every_pass_in_place(v5e):
     _assert_one_write_a_node(hlo, ["pass%d_layer%d_kvupd/" % (u, i)
                                    for u in range(passes) for i in range(4)])
     assert " while(" not in hlo and "kv_mask" not in hlo
+    # every read keeps the block its rule names: 128 slots of an 8,192-byte
+    # row, 1 MB a turn in flight and 2 MB of scratch (ONE page of the table's
+    # twenty until PR 57, 128 KB a turn)
+    assert _assert_the_block_the_rule_names(
+        hlo, max_len // page, page, 2048, "bfloat16") == 128
     _assert_no_pool_sized_copy(hlo, math.prod(pool))
     mem = compiled.memory_analysis()
     cache_bytes = 8 * 2 * math.prod(pool)
