@@ -12,7 +12,8 @@ from ..base import MXNetError
 from ..ops.attention import _LANES, _NEG, pool_paged
 
 ARCHS = ("vaswani", "olmoe", "granite_hybrid", "deepseek_v3", "lfm2_moe",
-         "mimo_v2_flash", "phi4flash", "nemotron_h", "dots3_note", "ouro")
+         "mimo_v2_flash", "phi4flash", "nemotron_h", "dots3_note", "ouro",
+         "laguna")
 
 
 # the block every graph is derived from (ROADMAP D2); the others have the
@@ -365,6 +366,14 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     feeds the head. After the logits come a layer's K (rotated) and V of
     EVERY pass, pass-major, (passes, H, P, dh), in ``decode_cache`` order;
     then ``exit_pass (1,)``, the pass that fed the head, counted from 1.
+
+    ``arch="laguna"`` builds window and full layers whose QUERY-head counts
+    differ (``_laguna_layer``: ``swa_num_heads`` and ``num_heads`` over the
+    same ``num_kv_heads``, a q/k norm a head, a gate a head, YaRN on the
+    full layers' partial rotary alone, routed experts beside a shared one),
+    its attention ``_window_prefill_attend``'s as ``mimo_v2_flash``'s is.
+    After the logits come every layer's K (rotated) and V in
+    ``decode_cache`` order, then ``moe_load``.
     """
     builders = {"olmoe": _olmoe_prefill_symbol,
                 "granite_hybrid": _granite_prefill_symbol,
@@ -374,7 +383,8 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
                 "phi4flash": _phi4flash_prefill_symbol,
                 "nemotron_h": _nemotron_h_prefill_symbol,
                 "dots3_note": _dots3_prefill_symbol,
-                "ouro": _ouro_prefill_symbol}
+                "ouro": _ouro_prefill_symbol,
+                "laguna": _laguna_prefill_symbol}
     if arch in builders:
         return builders[arch](
             vocab_size=vocab_size, num_layers=num_layers,
@@ -620,6 +630,12 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     trailing ``greedy_token`` is (B, 2): the token and, riding the same small
     read, the pass that fed its head (``_ouro_head``).
 
+    ``arch="laguna"`` runs ``_laguna_layer`` over ``mimo_v2_flash``'s cache
+    (``_window_step_attend``: ``kv_k_<i>`` / ``kv_v_<i>`` pools for a full
+    layer, ``ring_k_<i>`` / ``ring_v_<i>`` rings for a window layer, the
+    same ``num_kv_heads`` in both), a full layer's query ``num_heads`` wide
+    and a window layer's ``swa_num_heads``; ``moe_load`` last.
+
     ``page_size`` is the decoder's (``PagedKVDecoder``'s default here); it
     must divide ``max_len``.
     """
@@ -631,7 +647,8 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
                 "phi4flash": _phi4flash_decode_symbol,
                 "nemotron_h": _nemotron_h_decode_symbol,
                 "dots3_note": _dots3_decode_symbol,
-                "ouro": _ouro_decode_symbol}
+                "ouro": _ouro_decode_symbol,
+                "laguna": _laguna_decode_symbol}
     if arch in builders:
         return builders[arch](
             vocab_size=vocab_size, num_layers=num_layers,
@@ -1330,6 +1347,24 @@ def _deepseek_v3_ffn(x, i, fc, seq_len, block):
     return x + sym.Reshape(moe[0], shape=(-1, seq_len, d)) + shared, moe[1]
 
 
+def _deepseek_v3_ffn_shapes(block, i):
+    """{name: shape} of what ``_deepseek_v3_ffn`` loads for layer ``i``: the
+    dense MLP's two matrices, or the router, the HELD experts' three stacks
+    (all of them unless ``block`` names a share) and the shared MLP's two."""
+    n, d, e = "layer%d_" % i, block["model_dim"], block["num_experts"]
+    if i < block["first_dense_layers"]:
+        return {n + "mlp_in_weight": (2 * block["ffn_dim"], d),
+                n + "mlp_out_weight": (d, block["ffn_dim"])}
+    f, held = block["moe_ffn_dim"], block.get("num_local_experts") or e
+    shared = block["num_shared_experts"] * f
+    return {n + "router_weight": (e, d), n + "router_bias": (e,),
+            n + "experts_gate_weight": (held, d, f),
+            n + "experts_up_weight": (held, d, f),
+            n + "experts_down_weight": (held, f, d),
+            n + "shared_in_weight": (2 * shared, d),
+            n + "shared_out_weight": (d, shared)}
+
+
 def _deepseek_v3_stack(vocab_size, seq_len, positions, attend, block,
                        layer=None, length=None):
     """Embedding, the layers, final norm and untied head: ``data`` (B, T) ->
@@ -1444,10 +1479,9 @@ def _deepseek_v3_decode_symbol(vocab_size, num_layers, num_slots, page_size,
 
 def _deepseek_v3_param_shapes(vocab_size, num_layers, **sizes):
     block = _deepseek_v3_sizes(num_layers, **sizes)
-    d, hq, e = block["model_dim"], block["num_heads"], block["num_experts"]
+    d, hq = block["model_dim"], block["num_heads"]
     nope, rope, v_dim, lat = (block[k] for k in ("nope", "rope", "v_dim",
                                                  "latent"))
-    f = block["moe_ffn_dim"]
     shapes = {"embed_weight": (vocab_size, d), "final_ln_gamma": (d,),
               "lm_head_weight": (vocab_size, d)}
     for i in range(num_layers):
@@ -1457,18 +1491,7 @@ def _deepseek_v3_param_shapes(vocab_size, num_layers, **sizes):
             n + "kva_weight": (lat + rope, d), n + "kvnorm_gamma": (lat,),
             n + "kvb_weight": (hq * (nope + v_dim), lat),
             n + "proj_weight": (d, hq * v_dim), n + "ln2_gamma": (d,)})
-        if i < block["first_dense_layers"]:
-            shapes.update({n + "mlp_in_weight": (2 * block["ffn_dim"], d),
-                           n + "mlp_out_weight": (d, block["ffn_dim"])})
-            continue
-        shared = block["num_shared_experts"] * f
-        shapes.update({
-            n + "router_weight": (e, d), n + "router_bias": (e,),
-            n + "experts_gate_weight": (e, d, f),
-            n + "experts_up_weight": (e, d, f),
-            n + "experts_down_weight": (e, f, d),
-            n + "shared_in_weight": (2 * shared, d),
-            n + "shared_out_weight": (d, shared)})
+        shapes.update(_deepseek_v3_ffn_shapes(block, i))
     return shapes
 
 
@@ -1717,9 +1740,7 @@ def _dots3_decode_symbol(vocab_size, num_layers, num_slots, page_size,
 
 def _dots3_param_shapes(vocab_size, num_layers, **sizes):
     block = _dots3_sizes(num_layers, **sizes)
-    d, e, f = block["model_dim"], block["num_experts"], block["moe_ffn_dim"]
-    held = block["num_local_experts"] or e
-    hi, di = block["index_heads"], block["index_dim"]
+    d, hi, di = block["model_dim"], block["index_heads"], block["index_dim"]
     shapes = {"embed_weight": (vocab_size, d), "final_ln_gamma": (d,),
               "lm_head_weight": (vocab_size, d)}
     for i, kind in enumerate(block["layer_types"]):
@@ -1738,18 +1759,7 @@ def _dots3_param_shapes(vocab_size, num_layers, **sizes):
                 n + "iq_weight": (hi * di, rank), n + "ik_weight": (di, d),
                 n + "iknorm_gamma": (di,), n + "iknorm_beta": (di,),
                 n + "iw_weight": (hi, d)})
-        if i < block["first_dense_layers"]:
-            shapes.update({n + "mlp_in_weight": (2 * block["ffn_dim"], d),
-                           n + "mlp_out_weight": (d, block["ffn_dim"])})
-            continue
-        shared = block["num_shared_experts"] * f
-        shapes.update({
-            n + "router_weight": (e, d), n + "router_bias": (e,),
-            n + "experts_gate_weight": (held, d, f),
-            n + "experts_up_weight": (held, d, f),
-            n + "experts_down_weight": (held, f, d),
-            n + "shared_in_weight": (2 * shared, d),
-            n + "shared_out_weight": (d, shared)})
+        shapes.update(_deepseek_v3_ffn_shapes(block, i))
     return shapes
 
 
@@ -1967,7 +1977,7 @@ def _mimo_sizes(num_layers, num_heads, model_dim, ffn_dim, hybrid_layer_pattern,
                          "most head_dim %d" % (rotary_dim, head_dim))
     return dict(
         num_layers=num_layers, window_layers=window, expert_layers=sparse,
-        num_heads=num_heads,
+        num_heads=num_heads, swa_num_heads=num_heads,
         num_kv_heads=num_kv_heads or num_heads,
         swa_num_kv_heads=swa_num_kv_heads or num_kv_heads or num_heads,
         head_dim=head_dim, v_head_dim=v_head_dim or head_dim,
@@ -1982,10 +1992,113 @@ def _mimo_sizes(num_layers, num_heads, model_dim, ffn_dim, hybrid_layer_pattern,
         norm_topk_prob=bool(norm_topk_prob))
 
 
-def _mimo_kv_heads(block, i):
-    """Key/value heads of layer ``i``: a window layer has its own count."""
-    return block["swa_num_kv_heads" if block["window_layers"][i]
-                 else "num_kv_heads"]
+def _window_heads(block, i):
+    """(query heads, key/value heads) of layer ``i`` of a block of window
+    and full layers: a window layer has counts of its own (``swa_``)."""
+    kind = "swa_" if block["window_layers"][i] else ""
+    return block[kind + "num_heads"], block[kind + "num_kv_heads"]
+
+
+def _sink_input(sink):
+    """(inputs, attributes) a window layer's sink adds to its attention
+    operator: the sink is one more INPUT, ``sink=True`` the attribute that
+    says so (as MoEFeedForward's router_bias); nothing where there is none."""
+    return ((), {}) if sink is None else ((sink,), {"sink": True})
+
+
+def _window_prefill_attend(block, cache):
+    """``attend(i, q, k, v, sink)`` of a PREFILL over window and full layers
+    side by side (``mimo_v2_flash``, ``laguna``): the rotated head-major
+    tensors go to ``cache`` as the cache keeps them, then causal
+    ``MultiHeadAttention``, under ``sliding_window`` where
+    ``block["window_layers"][i]``; ``sink`` (None: no sink) is that
+    operator's fourth input."""
+    def attend(i, q, k, v, sink=None):
+        cache.extend([k, v])    # as the cache keeps them: rotated, scaled
+        if not block["window_layers"][i]:
+            return sym.MultiHeadAttention(query=q, key=k, value=v,
+                                          causal=True, name="layer%d_att" % i)
+        return sym.MultiHeadAttention(
+            q, k, v, *_sink_input(sink)[0], causal=True,
+            window=block["sliding_window"], name="layer%d_att" % i,
+            **_sink_input(sink)[1])
+    return attend
+
+
+def _window_step_attend(block, cache, pos_idx, write_slot, write, read):
+    """``attend(i, q, k_new, v_new, sink)`` of a decode STEP over window and
+    full layers side by side: a full layer writes and reads its pools
+    (``_pool_attend``), a window layer the lane's own rings, no frame and no
+    table; the written buffers go to ``cache`` in layer order."""
+    dk, dv = block["head_dim"], block["v_head_dim"]
+
+    def attend(i, q, k_new, v_new, sink=None):
+        # one token a lane: the head-major (B, H, 1, d) tensors are rows
+        hq, hkv = _window_heads(block, i)
+        q, k_new, v_new = (sym.Reshape(a, shape=(-1, n, width))
+                           for a, n, width in ((q, hq, dk), (k_new, hkv, dk),
+                                               (v_new, hkv, dv)))
+        if not block["window_layers"][i]:
+            ctx = _pool_attend(i, q, k_new, v_new, write, read, cache)
+        else:
+            # a window layer: the lane's own rings, no frame and no table
+            rings = sym.KVRingWrite(
+                sym.Variable("ring_k_%d" % i), k_new,
+                sym.Variable("ring_v_%d" % i), v_new, pos_idx, write_slot,
+                num_rings=2, name="layer%d_kvupd" % i)
+            cache.extend([rings[0], rings[1]])
+            ctx = sym.KVRingAttention(
+                q, rings[0], rings[1], pos_idx, write_slot,
+                *_sink_input(sink)[0], name="layer%d_att" % i,
+                **_sink_input(sink)[1])
+        return sym.Reshape(ctx, shape=(-1, hq, 1, dv))
+    return attend
+
+
+def _window_prefill_symbol(block, layer, vocab_size, prefill_len):
+    """The prefill graph of a block of window and full layers (``layer``:
+    ``_mimo_layer`` or ``_laguna_layer``): logits, every layer's K and V as
+    the cache keeps them, ``moe_load``."""
+    positions = sym.Reshape(sym._arange(start=0, stop=prefill_len),
+                            shape=(1, prefill_len))
+    cache = []      # the layers are built in order, so is this
+    logits, load = _deepseek_v3_stack(
+        vocab_size, prefill_len, positions,
+        _window_prefill_attend(block, cache), block, layer=layer,
+        length=sym.Variable("length"))
+    return sym.Group([logits] + cache + load)
+
+
+def _window_decode_symbol(block, layer, vocab_size, num_slots, page_size,
+                          token_out):
+    """The decode graph of the same: logits, pools and rings in layer order,
+    the token head, ``moe_load``."""
+    pos_idx = sym.Variable("pos_idx")
+    write_slot = sym.Variable("write_slot")
+    write, read = _pool_step_inputs(pos_idx, num_slots, page_size, write_slot)
+    cache = []      # the layers are built in order, so is this
+    logits, load = _deepseek_v3_stack(
+        vocab_size, 1, pos_idx,
+        _window_step_attend(block, cache, pos_idx, write_slot, write, read),
+        block, layer=layer)
+    # moe_load LAST: the cache and the token head keep their places
+    return sym.Group([_token_head(
+        logits, cache, "greedy_token" if token_out else None)] + load)
+
+
+def _window_cache(block):
+    """``decode_cache``'s list for a block of window and full layers: a
+    ring pair a window layer, a pool pair a full one, in layer order."""
+    dk, dv, w = (block[k] for k in ("head_dim", "v_head_dim",
+                                    "sliding_window"))
+    out = []
+    for i, windowed in enumerate(block["window_layers"]):
+        hkv = _window_heads(block, i)[1]
+        out += [("ring_k_%d" % i, "ring", (hkv, w, dk)),
+                ("ring_v_%d" % i, "ring", (hkv, w, dv))] if windowed \
+            else [("kv_k_%d" % i, "pool", (hkv, dk)),
+                  ("kv_v_%d" % i, "pool", (hkv, dv))]
+    return out
 
 
 def _mimo_layer(x, i, positions, seq_len, attend, block):
@@ -2017,8 +2130,8 @@ def _mimo_layer(x, i, positions, seq_len, attend, block):
         data=data, num_hidden=width, no_bias=True, flatten=False,
         name="%s_%s" % (name, tag))
     h = sym.RMSNorm(x, eps=eps, name="%s_ln1" % name)
-    q, k, v = _grouped_qkv(fc, h, seq_len, hq, _mimo_kv_heads(block, i), dk,
-                           dv)
+    q, k, v = _grouped_qkv(fc, h, seq_len, hq, _window_heads(block, i)[1],
+                           dk, dv)
     q, k = (sym.RotaryEmbedding(
         a, positions, rotary_dim=block["rotary_dim"],
         base=block["swa_rope_theta" if windowed else "rope_theta"],
@@ -2035,62 +2148,14 @@ def _mimo_layer(x, i, positions, seq_len, attend, block):
 
 
 def _mimo_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
-    block = _mimo_sizes(num_layers, **sizes)
-    positions = sym.Reshape(sym._arange(start=0, stop=prefill_len),
-                            shape=(1, prefill_len))
-    cache = []      # the layers are built in order, so is this
-
-    def attend(i, q, k, v, sink):
-        cache.extend([k, v])    # as the cache keeps them: rotated, scaled
-        if sink is None:
-            return sym.MultiHeadAttention(query=q, key=k, value=v,
-                                          causal=True, name="layer%d_att" % i)
-        # the sink is the fourth INPUT, ``sink=True`` the attribute that
-        # says so (as MoEFeedForward's router_bias)
-        return sym.MultiHeadAttention(
-            q, k, v, sink, causal=True, window=block["sliding_window"],
-            sink=True, name="layer%d_att" % i)
-
-    logits, load = _deepseek_v3_stack(vocab_size, prefill_len, positions,
-                                      attend, block, layer=_mimo_layer,
-                                      length=sym.Variable("length"))
-    return sym.Group([logits] + cache + load)
+    return _window_prefill_symbol(_mimo_sizes(num_layers, **sizes), _mimo_layer,
+                                  vocab_size, prefill_len)
 
 
 def _mimo_decode_symbol(vocab_size, num_layers, num_slots, page_size,
                         token_out=True, **sizes):
-    block = _mimo_sizes(num_layers, **sizes)
-    hq, dk, dv = (block[k] for k in ("num_heads", "head_dim", "v_head_dim"))
-    pos_idx = sym.Variable("pos_idx")
-    write_slot = sym.Variable("write_slot")
-    write, read = _pool_step_inputs(pos_idx, num_slots, page_size, write_slot)
-    cache = []      # the layers are built in order, so is this
-
-    def attend(i, q, k_new, v_new, sink):
-        # one token a lane: the head-major (B, H, 1, d) tensors are rows
-        hkv = _mimo_kv_heads(block, i)
-        q, k_new, v_new = (sym.Reshape(a, shape=(-1, n, width))
-                           for a, n, width in ((q, hq, dk), (k_new, hkv, dk),
-                                               (v_new, hkv, dv)))
-        if sink is None:
-            ctx = _pool_attend(i, q, k_new, v_new, write, read, cache)
-        else:
-            # a window layer: the lane's own rings, no frame and no table
-            rings = sym.KVRingWrite(
-                sym.Variable("ring_k_%d" % i), k_new,
-                sym.Variable("ring_v_%d" % i), v_new, pos_idx, write_slot,
-                num_rings=2, name="layer%d_kvupd" % i)
-            cache.extend([rings[0], rings[1]])
-            ctx = sym.KVRingAttention(
-                q, rings[0], rings[1], pos_idx, write_slot, sink, sink=True,
-                name="layer%d_att" % i)
-        return sym.Reshape(ctx, shape=(-1, hq, 1, dv))
-
-    logits, load = _deepseek_v3_stack(vocab_size, 1, pos_idx, attend, block,
-                                      layer=_mimo_layer)
-    # moe_load LAST: the cache and the token head keep their places
-    return sym.Group([_token_head(
-        logits, cache, "greedy_token" if token_out else None)] + load)
+    return _window_decode_symbol(_mimo_sizes(num_layers, **sizes), _mimo_layer,
+                                 vocab_size, num_slots, page_size, token_out)
 
 
 def _mimo_param_shapes(vocab_size, num_layers, **sizes):
@@ -2102,7 +2167,7 @@ def _mimo_param_shapes(vocab_size, num_layers, **sizes):
               "lm_head_weight": (vocab_size, d)}
     for i, windowed in enumerate(block["window_layers"]):
         n = "layer%d_" % i
-        hkv = _mimo_kv_heads(block, i)
+        hkv = _window_heads(block, i)[1]
         shapes.update({n + "ln1_gamma": (d,), n + "ln2_gamma": (d,),
                        n + "qkv_weight": ((hq + hkv) * dk + hkv * dv, d),
                        n + "proj_weight": (d, hq * dv)})
@@ -2117,6 +2182,142 @@ def _mimo_param_shapes(vocab_size, num_layers, **sizes):
             n + "experts_gate_weight": (held, d, f),
             n + "experts_up_weight": (held, d, f),
             n + "experts_down_weight": (held, f, d)})
+    return shapes
+
+
+# ------------- Laguna (window and full layers of different query-head counts)
+def _laguna_sizes(num_layers, num_heads, model_dim, ffn_dim, layer_types,
+                  swa_num_heads=None, num_kv_heads=None, head_dim=None,
+                  sliding_window=512, rotary_dim=None, rope_theta=5e5,
+                  swa_rope_theta=1e4, yarn_factor=0.0,
+                  yarn_original_max_position=0, yarn_beta_fast=32.0,
+                  yarn_beta_slow=1.0, attention_factor=1.0, moe_ffn_dim=None,
+                  num_experts=256, num_experts_per_tok=10, num_local_experts=0,
+                  local_expert_offset=0, num_shared_experts=1,
+                  first_dense_layers=1, rms_eps=1e-6,
+                  routed_scaling_factor=1.0, norm_topk_prob=True,
+                  dtype="float32", **kwargs):
+    """``_laguna_layer``'s keywords from a builder's (``num_heads`` is a
+    full layer's query heads, ``swa_num_heads`` a window layer's, over the
+    same ``num_kv_heads``; ``ffn_dim`` is the leading dense layers' width,
+    ``moe_ffn_dim`` one expert's; ``num_local_experts`` = 0 holds every
+    expert; ``yarn_factor`` = 0 leaves the full layers' rotary plain;
+    keywords of the other architectures are dropped)."""
+    kinds = tuple(layer_types)
+    if len(kinds) != num_layers \
+            or set(kinds) - {"full_attention", "sliding_attention"}:
+        raise MXNetError("laguna: layer_types must name %d layers "
+                         "'full_attention' or 'sliding_attention', got %r"
+                         % (num_layers, kinds))
+    if not 0 <= first_dense_layers <= num_layers:
+        raise MXNetError("laguna: first_dense_layers %d outside [0, %d]"
+                         % (first_dense_layers, num_layers))
+    head_dim = head_dim or model_dim // num_heads
+    rotary_dim = rotary_dim or head_dim
+    if rotary_dim % 2 or rotary_dim > head_dim:
+        raise MXNetError("laguna: rotary_dim %d must be even and at most "
+                         "head_dim %d" % (rotary_dim, head_dim))
+    num_kv_heads = num_kv_heads or num_heads
+    return dict(
+        num_layers=num_layers, layer_types=kinds,
+        window_layers=tuple(k == "sliding_attention" for k in kinds),
+        num_heads=num_heads, swa_num_heads=swa_num_heads or num_heads,
+        num_kv_heads=num_kv_heads, swa_num_kv_heads=num_kv_heads,
+        head_dim=head_dim, v_head_dim=head_dim, model_dim=model_dim,
+        ffn_dim=ffn_dim, moe_ffn_dim=moe_ffn_dim,
+        sliding_window=int(sliding_window),
+        # a full layer's rotary, then a window layer's: ``RotaryEmbedding``'s
+        # attributes
+        rotary=dict(
+            base=float(rope_theta), rotary_dim=rotary_dim,
+            **(dict(yarn_factor=float(yarn_factor),
+                    yarn_original_max_position=int(
+                        yarn_original_max_position),
+                    yarn_beta_fast=float(yarn_beta_fast),
+                    yarn_beta_slow=float(yarn_beta_slow),
+                    attention_factor=float(attention_factor))
+               if yarn_factor else {})),
+        swa_rotary=dict(base=float(swa_rope_theta)),
+        num_experts=num_experts, num_experts_per_tok=num_experts_per_tok,
+        num_local_experts=int(num_local_experts),
+        local_expert_offset=int(local_expert_offset),
+        num_shared_experts=num_shared_experts,
+        first_dense_layers=first_dense_layers, rms_eps=rms_eps,
+        routed_scaling_factor=float(routed_scaling_factor),
+        norm_topk_prob=bool(norm_topk_prob), dtype=dtype)
+
+
+def _laguna_layer(x, i, positions, seq_len, attend, block):
+    """One ``model_type: laguna`` block on x (B, T, M) -> (x', load (E,) or
+    None for a dense layer): pre-norm RMSNorm, grouped-query attention whose
+    KIND is ``layer_types[i]``, then ``_deepseek_v3_ffn`` (a gated SiLU MLP
+    in the first ``first_dense_layers`` layers, after them sigmoid-routed
+    experts, of which the layer may hold a share, beside a shared gated MLP
+    every token takes).
+
+    Attention: one bias-free projection to [q | k | v] whose WIDTH follows
+    the kind: a window layer has ``swa_num_heads`` query heads, a full layer
+    ``num_heads``, both over ``num_kv_heads`` key/value heads of
+    ``head_dim`` (``_window_heads``); an RMSNorm over each q and each k
+    head's features (``qnorm`` / ``knorm``: one gamma of ``head_dim`` the
+    heads share); then rotary positions by the kind: a window layer turns
+    all its features plainly at ``swa_rope_theta``, a full layer the FIRST
+    ``rotary_dim`` at ``rope_theta`` under YaRN (``RotaryEmbedding``'s
+    ``yarn_*`` attributes and ``attention_factor``). ``attend(i, q, k, v)``
+    is the one thing the prefill and the decode graph do differently
+    (``_window_prefill_attend`` / ``_window_step_attend``: a band or a ring
+    for a window layer, causal attention or the pools for a full one). A
+    gate a head, ``softplus(W_g h)`` in float32 from the layer's normed
+    input, multiplies a head's context before the output projection."""
+    name = "layer%d" % i
+    d, eps, dh = block["model_dim"], block["rms_eps"], block["head_dim"]
+    windowed = block["window_layers"][i]
+    hq, hkv = _window_heads(block, i)
+    fc = lambda data, width, tag, **kw: sym.FullyConnected(
+        data=data, num_hidden=width, no_bias=True, flatten=False,
+        name="%s_%s" % (name, tag), **kw)
+    h = sym.RMSNorm(x, eps=eps, name="%s_ln1" % name)
+    q, k, v = _grouped_qkv(fc, h, seq_len, hq, hkv, dh)
+    q, k = (sym.RotaryEmbedding(
+        sym.RMSNorm(a, eps=eps, name="%s_%snorm" % (name, tag)), positions,
+        name="%s_%srope" % (name, tag),
+        **block["swa_rotary" if windowed else "rotary"])
+        for a, tag in ((q, "q"), (k, "k")))
+    att = attend(i, q, k, v)
+    gate = sym.Reshape(sym.Activation(
+        fc(h, hq, "gate", out_dtype="float32"), act_type="softrelu"),
+        shape=(-1, seq_len, hq, 1))
+    att = sym.Reshape(sym.Cast(sym.broadcast_mul(
+        sym.Cast(sym.transpose(att, axes=(0, 2, 1, 3)), dtype="float32"),
+        gate), dtype=block["dtype"]), shape=(-1, seq_len, hq * dh))
+    return _deepseek_v3_ffn(x + fc(att, d, "proj"), i, fc, seq_len, block)
+
+
+def _laguna_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
+    return _window_prefill_symbol(_laguna_sizes(num_layers, **sizes), _laguna_layer,
+                                  vocab_size, prefill_len)
+
+
+def _laguna_decode_symbol(vocab_size, num_layers, num_slots, page_size,
+                          token_out=True, **sizes):
+    return _window_decode_symbol(_laguna_sizes(num_layers, **sizes), _laguna_layer,
+                                 vocab_size, num_slots, page_size, token_out)
+
+
+def _laguna_param_shapes(vocab_size, num_layers, **sizes):
+    block = _laguna_sizes(num_layers, **sizes)
+    d, dh = block["model_dim"], block["head_dim"]
+    shapes = {"embed_weight": (vocab_size, d), "final_ln_gamma": (d,),
+              "lm_head_weight": (vocab_size, d)}
+    for i in range(num_layers):
+        n = "layer%d_" % i
+        hq, hkv = _window_heads(block, i)
+        shapes.update({
+            n + "ln1_gamma": (d,), n + "ln2_gamma": (d,),
+            n + "qkv_weight": ((hq + 2 * hkv) * dh, d),
+            n + "qnorm_gamma": (dh,), n + "knorm_gamma": (dh,),
+            n + "gate_weight": (hq, d), n + "proj_weight": (d, hq * dh)})
+        shapes.update(_deepseek_v3_ffn_shapes(block, i))
     return shapes
 
 
@@ -2728,18 +2929,13 @@ def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
                 for i, layer in enumerate(block["kinds"])
                 for name, kind, shape in per_kind.get(layer, ())]
     if arch == "mimo_v2_flash":
-        block = _mimo_sizes(num_layers, num_heads=num_heads,
-                            model_dim=model_dim, head_dim=head_dim, **sizes)
-        dk, dv, w = (block[k] for k in ("head_dim", "v_head_dim",
-                                        "sliding_window"))
-        out = []
-        for i, windowed in enumerate(block["window_layers"]):
-            hkv = _mimo_kv_heads(block, i)
-            out += [("ring_k_%d" % i, "ring", (hkv, w, dk)),
-                    ("ring_v_%d" % i, "ring", (hkv, w, dv))] if windowed \
-                else [("kv_k_%d" % i, "pool", (hkv, dk)),
-                      ("kv_v_%d" % i, "pool", (hkv, dv))]
-        return out
+        return _window_cache(_mimo_sizes(
+            num_layers, num_heads=num_heads, model_dim=model_dim,
+            head_dim=head_dim, **sizes))
+    if arch == "laguna":
+        return _window_cache(_laguna_sizes(
+            num_layers, num_heads=num_heads, model_dim=model_dim,
+            head_dim=head_dim, **sizes))
     if arch == "deepseek_v3":
         block = _deepseek_v3_sizes(num_layers, num_heads=num_heads,
                                    model_dim=model_dim, **sizes)
@@ -2817,13 +3013,14 @@ def param_shapes(arch, vocab_size, num_layers, num_heads, model_dim, ffn_dim,
             vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
             ffn_dim=ffn_dim, num_experts=num_experts, **kwargs)
     if arch in ("lfm2_moe", "mimo_v2_flash", "phi4flash", "nemotron_h",
-                "dots3_note", "ouro"):
+                "dots3_note", "ouro", "laguna"):
         shapes = {"lfm2_moe": _lfm2_moe_param_shapes,
                   "mimo_v2_flash": _mimo_param_shapes,
                   "phi4flash": _phi4flash_param_shapes,
                   "nemotron_h": _nemotron_h_param_shapes,
                   "dots3_note": _dots3_param_shapes,
-                  "ouro": _ouro_param_shapes}[arch]
+                  "ouro": _ouro_param_shapes,
+                  "laguna": _laguna_param_shapes}[arch]
         return shapes(
             vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
             ffn_dim=ffn_dim, head_dim=head_dim, num_experts=num_experts,

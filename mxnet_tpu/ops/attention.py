@@ -508,11 +508,39 @@ def _rms_norm(attrs, data, gamma):
     return y.astype(data.dtype)
 
 
+def yarn_inv_freq(dim, base, factor, original_max_position, beta_fast=32.0,
+                  beta_slow=1.0):
+    """YaRN's ``dim / 2`` inverse frequencies (float64), the same at every
+    position (static, ``truncate`` on): ``e_i = base^(-2i/dim)`` where a
+    feature turns more than ``beta_fast`` times over the
+    ``original_max_position`` positions of the first training (extrapolated:
+    left as they are), ``e_i / factor`` where it turns fewer than
+    ``beta_slow`` times (interpolated: the new context squeezed into the
+    old), a linear blend between. ``corr(n) = dim ln(L0 / (2 pi n)) /
+    (2 ln base)`` is the index of the feature that turns n times;
+    ``low = max(floor(corr(beta_fast)), 0)``, ``high = min(ceil(corr(
+    beta_slow)), dim - 1)``, ``ramp_i = clip((i - low) / (high - low), 0,
+    1)``, ``inv_freq_i = e_i / factor * ramp_i + e_i * (1 - ramp_i)``."""
+    corr = lambda turns: dim * np.log(
+        original_max_position / (turns * 2 * np.pi)) / (2 * np.log(base))
+    low = max(int(np.floor(corr(beta_fast))), 0)
+    high = min(int(np.ceil(corr(beta_slow))), dim - 1)
+    plain = float(base) ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low if high > low else 0.001), 0, 1)
+    return plain / factor * ramp + plain * (1 - ramp)
+
+
 @register(
     "_contrib_RotaryEmbedding",
     attrs={"base": AttrSpec("float", default=10000.0),
            "interleaved": AttrSpec("bool", default=False),
-           "rotary_dim": AttrSpec("int", default=0)},
+           "rotary_dim": AttrSpec("int", default=0),
+           "yarn_factor": AttrSpec("float", default=0.0),
+           "yarn_original_max_position": AttrSpec("int", default=0),
+           "yarn_beta_fast": AttrSpec("float", default=32.0),
+           "yarn_beta_slow": AttrSpec("float", default=1.0),
+           "attention_factor": AttrSpec("float", default=1.0)},
     input_names=("data", "positions"),
     aliases=("RotaryEmbedding",),
 )
@@ -526,7 +554,12 @@ def _rotary_embedding(attrs, data, positions):
     are float32 whatever the IO dtype. ``rotary_dim`` = r > 0 rotates the
     FIRST r features of a head alone, as a head of r features (pairs
     (i, i + r/2), ``inv_freq_i = base^(-2i/r)``); the other dh - r pass
-    through (``partial_rotary_factor``)."""
+    through (``partial_rotary_factor``). ``yarn_factor`` = s > 0 takes
+    YaRN's inverse frequencies over the rotated features instead
+    (``yarn_inv_freq``: ``base``, s, ``yarn_original_max_position``,
+    ``yarn_beta_fast``, ``yarn_beta_slow``) and multiplies sine and cosine
+    by ``attention_factor``, so a rotated feature leaves scaled and a
+    feature that passes through does not."""
     part = attrs.get("rotary_dim", 0)
     if part and part != data.shape[-1]:
         if part % 2 or not 0 < part < data.shape[-1]:
@@ -537,16 +570,28 @@ def _rotary_embedding(attrs, data, positions):
                                    data[..., :part], positions)
         return jnp.concatenate([turned, data[..., part:]], axis=-1)
     dh = data.shape[-1]
-    inv_freq = attrs["base"] ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
+    yarn = attrs.get("yarn_factor", 0.0) > 0
+    if yarn:
+        inv_freq = jnp.asarray(yarn_inv_freq(
+            dh, attrs["base"], attrs["yarn_factor"],
+            attrs["yarn_original_max_position"], attrs["yarn_beta_fast"],
+            attrs["yarn_beta_slow"]), jnp.float32)
+    else:
+        inv_freq = attrs["base"] ** (
+            -jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
     angle = positions.astype(jnp.float32)[:, None, :, None] * inv_freq
+    # (the plain path's lowered text is every cell's compile-cache key: it
+    # takes each function of the angle where it always did)
+    turned = (lambda f: f(angle) * jnp.float32(attrs["attention_factor"])) \
+        if yarn else (lambda f: f(angle))
     if attrs.get("interleaved"):
         x = data.astype(jnp.float32).reshape(data.shape[:-1] + (dh // 2, 2))
         x1, x2 = x[..., 0], x[..., 1]
-        cos, sin = jnp.cos(angle), jnp.sin(angle)
+        cos, sin = turned(jnp.cos), turned(jnp.sin)
         y = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
         return y.reshape(data.shape).astype(data.dtype)
-    cos = jnp.concatenate([jnp.cos(angle), jnp.cos(angle)], axis=-1)
-    sin = jnp.concatenate([jnp.sin(angle), jnp.sin(angle)], axis=-1)
+    cos = jnp.concatenate([turned(jnp.cos), turned(jnp.cos)], axis=-1)
+    sin = jnp.concatenate([turned(jnp.sin), turned(jnp.sin)], axis=-1)
     x = data.astype(jnp.float32)
     x1, x2 = x[..., :dh // 2], x[..., dh // 2:]
     y = x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin

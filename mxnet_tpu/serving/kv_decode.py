@@ -67,7 +67,7 @@ import numpy as np
 from ..base import MXNetError
 from .. import telemetry as _tm
 from ..executor import _cost_of
-from ..ops.attention import _NEG, pool_paged, pool_shape
+from ..ops.attention import _NEG, _band_block, pool_paged, pool_shape
 from .cache import PersistentExecutableCache
 
 __all__ = ["PagedKVDecoder", "PagedKVExhausted", "decode_megastep_k"]
@@ -1188,6 +1188,19 @@ class PagedKVDecoder:
     ``serving.sparse.*`` (docs/OBSERVABILITY.md). It refuses what
     ``mimo_v2_flash`` refuses.
 
+    ``arch="laguna"`` serves window and full layers whose QUERY-head counts
+    differ (``layer_types``, ``num_heads`` a full layer's and
+    ``swa_num_heads`` a window layer's over the same ``num_kv_heads``,
+    ``head_dim``, ``sliding_window``, ``rotary_dim`` and ``rope_theta`` with
+    the ``yarn_*`` numbers and ``attention_factor`` for the full layers,
+    ``swa_rope_theta``, and ``deepseek_v3``'s experts with a held share
+    beside a shared one): a q/k norm a head, a gate a head on the context.
+    The cache is ``mimo_v2_flash``'s, pools for a full layer and rings for a
+    window layer, both of ``num_kv_heads`` heads; an ``admit`` counts what
+    ONE window layer's prefill scored over the bucket and what of it a real
+    position attends (``serving.admit_window_pairs_scored`` / ``_live``, for
+    every arch with rings). It refuses what ``mimo_v2_flash`` refuses.
+
     ``arch="ouro"`` serves a LOOPED stack: ``num_layers`` layers of sandwich
     norms, rotary attention and a gated MLP applied ``total_ut_steps`` times
     over the same weights (``early_exit_threshold``, ``head_dim``,
@@ -1732,6 +1745,16 @@ class PagedKVDecoder:
                 # indexers scored, a sparse layer
                 _tm.counter("serving.sparse.admit_scored_pairs").inc(
                     self._sparse_layers * L * (L + 1) // 2)
+            if self._window:
+                # (query, key) pairs ONE window layer's prefill scored over
+                # the bucket (a band of two blocks a query, else all of
+                # them), and those among them a real position attends
+                w, t = self._window, self.prefill_len
+                block = _band_block(t, w)
+                _tm.counter("serving.admit_window_pairs_scored").inc(
+                    t * 2 * block if block else t * t)
+                _tm.counter("serving.admit_window_pairs_live").inc(
+                    min(L, w) * (min(L, w) + 1) // 2 + max(L - w, 0) * w)
             if self._shared_readers:
                 # the bucket's rows either half of the depth computed
                 _tm.counter("serving.admit_self_rows").inc(self.prefill_len)

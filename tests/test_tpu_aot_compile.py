@@ -325,6 +325,10 @@ _EXPERT_LAYERS = {
         (2048, 8, 256, 16, 4096, 2048), "kernel", (128, 1024, 4096, 2)),
     ("mimo-v2-flash.generate", "decode"): (
         (32, 8, 256, 16, 4096, 2048), "kernel", (32, 1024, 4096, 2)),
+    ("laguna-s-2.1.generate", "prefill"): (
+        (8192, 10, 256, 64, 3072, 1024), "kernel", (128, 1024, 3072, 2)),
+    ("laguna-s-2.1.generate", "decode"): (
+        (32, 10, 256, 64, 3072, 1024), "kernel", (32, 1024, 3072, 3)),
 }
 
 
@@ -366,6 +370,9 @@ _ATTENTION_LAYERS = {
     # fit beside the blocks of a plain call)
     "dots3-note-prev.generate": ((128, 128, 8192, 192, 128), "sparse_kernel",
                                  (1024, 1024)),
+    # its full layers: a group of 6, the first that is no power of two, so a
+    # step's rows are 6 x 128 = 768 where every other cell folds to 1,024
+    "laguna-s-2.1.generate": ((48, 8, 8192, 128, 128), "kernel", (128, 1024)),
 }
 
 
@@ -1021,6 +1028,142 @@ def test_mimo_v2_flash_serving_programs_compile_for_the_chip(v5e, program):
     # the step updates every pool and ring in place
     assert mem.alias_size_in_bytes == cache_bytes
     assert mem.temp_size_in_bytes < 1 << 30
+
+
+_LAGUNA = dict(arch="laguna", vocab_size=25088, num_layers=6, num_heads=48,
+               swa_num_heads=72, num_kv_heads=8, head_dim=128, model_dim=3072,
+               ffn_dim=12288, moe_ffn_dim=1024, num_experts=256,
+               num_local_experts=64, local_expert_offset=0,
+               num_experts_per_tok=10, num_shared_experts=1,
+               first_dense_layers=1,
+               layer_types=["full_attention"] + ["sliding_attention"] * 3
+               + ["full_attention", "sliding_attention"],
+               sliding_window=512, rotary_dim=64, rope_theta=5e5,
+               swa_rope_theta=1e4, yarn_factor=128.0,
+               yarn_original_max_position=8192, yarn_beta_fast=32.0,
+               yarn_beta_slow=1.0, attention_factor=1.4852030263919618,
+               rms_eps=1e-6, routed_scaling_factor=2.5, norm_topk_prob=True,
+               dtype="bfloat16")
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode", "admit_scatter"])
+def test_laguna_serving_programs_compile_for_the_chip(v5e, program):
+    """The three programs ``PagedKVDecoder(arch="laguna")`` runs, lowered for
+    the v5e at Laguna-S-2.1's published widths, the benchmark's cut (layers
+    0-5, 64 of 256 experts, 25,088 rows of the vocabulary: 3,679,364,864
+    parameters in bfloat16) and its serving sizes (32 lanes x 9,216 slots,
+    an 8,192 bucket). What has to hold on the chip: the cache is a page-major
+    pool pair (18,432, 16, 1,024) for a full layer and two rings (32, 8, 512,
+    128) for a window layer, in layer order, each updated in place; a full
+    layer's admission is ONE call of the blockwise kernel at a group of 6
+    (no float32 buffer of 48 x 8,192 x 8,192), a window layer's a BAND of
+    512-wide blocks at 72 heads, a run of blocks at a time (the 2.4 GB of a
+    layer's scores never whole); the step's two full layers read through the
+    kernel that walks the page table at a (48, 1,024) block-diagonal query
+    and re-lay no pool; both graphs keep the grouped matmul over the 64 held
+    experts and report the load of all 256 last; and everything fits beside
+    7.4 GB of weights."""
+    from types import SimpleNamespace
+
+    from mxnet_tpu.models import transformer as tf
+    from mxnet_tpu.ops.attention import (_band_block, attention_form,
+                                         pool_read_form, pool_shape)
+    from mxnet_tpu.serving.kv_decode import _AdmitScatter
+
+    lanes, max_len, bucket, page = 32, 9216, 8192, 16
+    slots, cfg = lanes * max_len, _LAGUNA
+    shapes = tf.param_shapes(**cfg)
+    assert sum(math.prod(s) for s in shapes.values()) == 3_679_364_864
+    weights = {n: (s, "bfloat16") for n, s in shapes.items()}
+    cache = tf.decode_cache(**cfg)
+    assert [kind for _, kind, _ in cache] == \
+        ["pool"] * 2 + ["ring"] * 6 + ["pool"] * 2 + ["ring"] * 2
+    buffers = [(pool_shape(*shape, slots, page) if kind == "pool"
+                else (lanes,) + shape, "bfloat16")
+               for _, kind, shape in cache]
+    assert buffers[0] == buffers[1] == ((slots // page, page, 1024),
+                                        "bfloat16")
+    assert buffers[2][0] == (lanes, 8, 512, 128)
+    cache_bytes = sum(2 * math.prod(shape) for shape, _ in buffers)
+    # the two full layers' pools 2.42 GB, the four window layers' rings 0.27
+    assert cache_bytes == 2 * slots * 4096 + 4 * lanes * (2 << 20)
+    exported = [((1, shape[0], bucket, shape[-1]), "bfloat16")
+                for _, _, shape in cache]
+    if program == "admit_scatter":
+        prog = _AdmitScatter(SimpleNamespace(
+            _cache=cache, page_size=page, prefill_len=bucket))
+        spec = lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, jnp.dtype(dtype), sharding=v5e)
+        compiled = prog._fn.lower(
+            tuple(spec(*b) for b in buffers),
+            tuple(spec(*n) for n in exported),
+            spec((bucket // page,), "int32"), spec((2,), "int32"),
+        ).compile()
+        mem = compiled.memory_analysis()
+        # every buffer of the cache is updated in place, pools and rings
+        assert mem.alias_size_in_bytes == cache_bytes
+        # the prompt's rows turned a token's heads side by side: 16 MB
+        assert mem.temp_size_in_bytes < 32 << 20
+        _assert_no_pool_sized_copy(compiled.as_text(), slots * 1024)
+        return
+    if program == "prefill":
+        sym = tf.get_prefill_symbol(prefill_len=bucket, **cfg)
+        inputs = {"data": ((1, bucket), "float32"),
+                  "length": ((1, 1), "float32")}
+        want = [((1, 25088), "float32")] + exported \
+            + [((5, 256), "float32")]
+    else:
+        sym = tf.get_decode_symbol(max_len=slots, page_size=page, **cfg)
+        inputs = {"data": ((lanes, 1), "float32"),
+                  "pos_idx": ((lanes, 1), "float32"),
+                  "write_slot": ((lanes, 1), "float32"),
+                  "page_table": ((lanes, max_len // page), "float32")}
+        inputs.update({name: b for (name, _, _), b in zip(cache, buffers)})
+        want = [((lanes, 25088), "float32")] + buffers \
+            + [((lanes,), "float32"), ((5, 256), "float32")]
+    compiled = _compile_program(
+        v5e, sym, {**weights, **inputs},
+        donated=[name for name, _, _ in cache] if program == "decode" else ())
+    assert [(s.shape, str(s.dtype)) for s in compiled.out_info[0]] == want
+    hlo = compiled.as_text()
+    _assert_expert_layers(hlo, 5, bucket if program == "prefill" else lanes,
+                          10, 64, 3072, 1024, routed=256)
+    mem = compiled.memory_analysis()
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+    if program == "prefill":
+        # the rules, asked as the operator asks them
+        heads = lambda n: struct((1, n, bucket, 128), "bfloat16")
+        assert attention_form(heads(48), heads(8), heads(8), True) == "kernel"
+        assert attention_form(heads(72), heads(8), heads(8), True,
+                              512) == "band"
+        assert _band_block(bucket, 512) == 512
+        _assert_attention_is_blockwise(hlo, 2, bucket)
+        # a window layer's band a run of blocks at a time: four loops, and no
+        # float32 buffer of a layer's 72 x 8,192 x 1,024 scores
+        assert hlo.count(" while(") == 4
+        made = [math.prod(int(d) for d in dims.split(",") if d)
+                for dims in re.findall(r"f32\[([\d,]+)\]", hlo)]
+        assert max(made) < 72 * bucket * 1024
+        _assert_one_row_of_logits(compiled, bucket, 25088)
+        # 3.34 GB: with the weights' 7.36 and the cache's 2.68, 13.4 of 16
+        assert mem.temp_size_in_bytes < 3.6e9
+        return
+    assert pool_read_form(
+        struct((lanes, 48, 128), "bfloat16"),
+        struct(buffers[0][0], "bfloat16"), struct(buffers[1][0], "bfloat16"),
+        struct((lanes, max_len // page), "float32"), page) == "kernel"
+    calls = _paged_read_calls(hlo)
+    assert len(calls) == 2
+    for i in (0, 4):
+        assert sum("layer%d_att/" % i in line for line in calls) == 1
+    _assert_the_block_the_rule_names(hlo, max_len // page, page, 1024,
+                                     "bfloat16")
+    _assert_one_write_a_node(hlo, ["layer0_kvupd/", "layer4_kvupd/"])
+    assert "kv_mask" not in hlo and "slot_onehot" not in hlo
+    _assert_no_pool_sized_copy(hlo, slots * 1024)
+    # the step updates every pool and ring in place
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < 64 << 20
 
 
 def _program_alone(hlo):
