@@ -378,9 +378,12 @@ def _moe_forms(cache, input_shapes):
     program's graph at these input shapes, ``["kernel" | "ragged_dot"]`` in
     the graph's order, and the deepest fetch ring among its kernel layers (0
     where none is), by the operator's own rules
-    (``pallas_grouped_matmul.moe_form``, ``layer_tiles``)."""
+    (``pallas_grouped_matmul.moe_form``, ``layer_tiles``) at the rows its
+    products take: every assignment's, or a chunk of the held ones
+    (``moe.held_rows_chunk``)."""
     import jax
 
+    from ..ops.moe import held_rows_chunk
     from ..ops.pallas_grouped_matmul import layer_tiles, moe_form
 
     forms, depth = [], 0
@@ -389,13 +392,15 @@ def _moe_forms(cache, input_shapes):
         data, up = ops["data"], ops["up_weight"]
         attrs = n.parsed_attrs()
         gated = attrs.get("gated", True)
-        rows = jax.ShapeDtypeStruct(
-            (data.shape[0] * attrs["num_experts_per_tok"], data.shape[1]),
-            data.dtype)
+        tokens, k = data.shape[0], attrs["num_experts_per_tok"]
+        chunk = held_rows_chunk(tokens, k, up.shape[0], attrs["num_experts"])
+        rows = jax.ShapeDtypeStruct((chunk or tokens * k, data.shape[1]),
+                                    data.dtype)
         forms.append(moe_form(rows, up, ops["down_weight"]))
         if forms[-1] == "kernel":
-            depth = max(depth, layer_tiles(rows, up, attrs["num_experts"],
-                                           gated)[3])
+            depth = max(depth, layer_tiles(
+                rows, up, None if chunk else attrs["num_experts"],
+                gated)[3])
     return forms, depth
 
 
@@ -1325,6 +1330,12 @@ class PagedKVDecoder:
         first = int(arch_sizes.get("local_expert_offset") or 0)
         held = int(arch_sizes.get("num_local_experts") or 0)
         self._held_experts = slice(first, first + held if held else None)
+        # the rows of a chunk where an admission's expert layers move their
+        # HELD rows alone, by the operator's own rule; 0: every row
+        from ..ops.moe import held_rows_chunk
+        self._admit_chunk = held_rows_chunk(
+            self.prefill_len, int(cfg.get("num_experts_per_tok") or 0),
+            held, int(cfg.get("num_experts") or 0))
         if arch != "vaswani":
             # inputs are float32 whatever the weights are, a lane's recurrent
             # state among them; only pools and rings take the weights' type
@@ -1769,6 +1780,17 @@ class PagedKVDecoder:
             _tm.counter("serving.moe.assignments").inc(int(load.sum()))
             _tm.counter("serving.moe.max_expert_assignments").inc(
                 int(load.max(axis=1).sum()))
+            # of those, the ones that reached an expert held here: the rows
+            # a layer that compacts them moves, and the layers whose held
+            # rows outgrew one chunk (they ran a second turn, nothing lost)
+            local = load[:, self._held_experts].sum(axis=1)
+            _tm.counter("serving.moe.admit_local_assignments").inc(
+                int(local.sum()))
+            if self._admit_chunk:
+                _tm.counter("serving.moe.admit_compact_layers").inc(
+                    len(local))
+                _tm.counter("serving.moe.admit_overflow_layers").inc(
+                    int(np.count_nonzero(local > self._admit_chunk)))
         return logits
 
     def _chunk_for(self, t):

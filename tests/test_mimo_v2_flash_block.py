@@ -649,3 +649,48 @@ def test_spans_gauges_and_counters(tm):
             chain.append(spans[parent][0])
             parent = spans[parent][1]
         assert chain == ["serving.admit.scatter", "serving.paged_admit"]
+
+
+def test_an_admission_that_moves_its_held_rows_alone_counts_them(
+        tm, monkeypatch):
+    """The rule held to these sizes (8 of 32 experts held, chunks of whole
+    16-row tiles): the prefill's six expert layers gather, multiply and
+    combine their held rows alone, the admission's logits are the all-rows
+    decoder's to a float32 sum's order, and the counters say what ran: the
+    assignments that reached a held expert (the prefill's own ``moe_load``
+    over experts 8..15), the six layers, and those among them whose held
+    rows outgrew one chunk."""
+    monkeypatch.setattr(moe, "_ROW_TILE", 16)
+    monkeypatch.setattr(moe, "_ROWS_WORTH_A_CHUNK", 16)
+    params = _weights()
+    prompt = np.arange(1, 21, dtype=np.float32)
+    with mx.name.NameManager():
+        dec = _decoder(params)
+    bucket = dec.prefill_len
+    chunk = moe.held_rows_chunk(bucket, 4, 8, 32)
+    assert dec._admit_chunk == chunk > 0 and chunk % 16 == 0
+    before = tm.counters()
+    _, logits = dec.admit(prompt)
+    moved = {k: v - before.get(k, 0) for k, v in tm.counters().items()}
+    pf = dec._pf_cache.executable(dec._prefill_shapes())
+    load = np.asarray(pf.outputs[dec._pf_moe_load]._jax())
+    assert load.shape == (6, 32) and load.sum() == 6 * bucket * 4
+    held = load[:, 8:16].sum(axis=1)
+    assert moved["serving.moe.assignments"] == load.sum()
+    assert moved["serving.moe.admit_local_assignments"] == held.sum() > 0
+    assert moved["serving.moe.admit_compact_layers"] == 6
+    assert moved.get("serving.moe.admit_overflow_layers", 0) \
+        == np.count_nonzero(held > chunk)
+    # the same weights through a decoder whose layers keep every row
+    monkeypatch.setattr(moe, "_ROWS_WORTH_A_CHUNK", 1 << 40)
+    with mx.name.NameManager():
+        plain = _decoder(params)
+    assert plain._admit_chunk == 0
+    before = tm.counters()
+    _, want = plain.admit(prompt)
+    moved = {k: v - before.get(k, 0) for k, v in tm.counters().items()}
+    assert moved["serving.moe.admit_local_assignments"] == held.sum()
+    assert "serving.moe.admit_compact_layers" not in moved \
+        or moved["serving.moe.admit_compact_layers"] == 0
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)

@@ -271,26 +271,42 @@ def _assert_expert_layers(hlo, layers, tokens, k, experts, d, f,
     otherwise. A kernel call holds the fetch ring ``tiles`` names there,
     ``depth`` buffers a matrix of its column tile, and what Mosaic gave it of
     VMEM is that ring and the rows', the output's and the float32 sums' 16 MB
-    at most beside it, under the limit the call states. Returns the form."""
+    at most beside it, under the limit the call states. Where the layer
+    moves its HELD rows alone (``moe.held_rows_chunk`` names a chunk: the
+    admissions of mimo, dots3 and laguna) a call's rows are the CHUNK's and
+    the program holds nothing of ``tokens * k`` rows by ``d``, nor
+    (tokens, k, d), in any type. Returns the form."""
+    from mxnet_tpu.ops.moe import held_rows_chunk
     from mxnet_tpu.ops.pallas_grouped_matmul import layer_tiles, moe_form
 
     struct = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
-    form = moe_form(struct(tokens * k, d), struct(experts, d, f),
+    chunk = held_rows_chunk(tokens, k, experts, routed or experts)
+    rows = chunk or tokens * k
+    form = moe_form(struct(rows, d), struct(experts, d, f),
                     struct(experts, f, d))
+    if chunk:
+        every = re.findall(r"\w+\[(?:%d,%d|%d,%d,%d)\]"
+                           % (tokens * k, d, tokens, k, d), hlo)
+        assert not every, every[:4]
     calls = [line for line in hlo.splitlines() if " custom-call(" in line
              and 'custom_call_target="tpu_custom_call"' in line
              and "/grouped_matmul" in line]
     # XLA's form, by its name among the instructions: the tables of source
     # names in front of them hold whatever test first traced a cached helper
     ragged = "ragged" in _program_alone(hlo).lower()
+    # a layer that moves its held rows alone holds a turn twice: the first
+    # in front of the loop, every further one inside it
+    turns = 2 if chunk else 1
     if form == "kernel":
-        assert len(calls) == 2 * layers and not ragged
+        assert len(calls) == 2 * turns * layers and not ragged
         assert sum("/grouped_matmul_gated/" in line for line in calls) \
-            == layers
+            == turns * layers
         _, tn_up, tn_down, depth = layer_tiles(
-            struct(tokens * k, d), struct(experts, d, f), routed)
+            struct(rows, d), struct(experts, d, f),
+            None if chunk else routed)
         assert depth >= 2
         for line in calls:
+            assert int(re.search(r"= \w+\[(\d+),", line).group(1)) == rows
             ring = 2 * depth * (2 * d * tn_up if "_gated/" in line
                                 else f * tn_down)
             # where XLA put the call's VMEM, and how far into it Mosaic went
@@ -1009,8 +1025,10 @@ def test_mimo_v2_flash_serving_programs_compile_for_the_chip(v5e, program):
         # largest thing made; a window layer's are an eighth of that
         assert max(n for n, _ in found) <= 64 * bucket * bucket
         _assert_one_row_of_logits(compiled, bucket, 19072)
-        assert mem.temp_size_in_bytes < 3 << 30
+        # PR 58's program, every assignment's rows in it: 710,681,088
+        assert mem.temp_size_in_bytes <= 710_681_088
         return
+    assert _step_sha1(hlo) == _HELD_SHARE_STEPS["mimo_v2_flash"]
     # the rule, asked as the operator asks it: pools of different width
     struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
     assert pool_read_form(
@@ -1138,16 +1156,20 @@ def test_laguna_serving_programs_compile_for_the_chip(v5e, program):
                               512) == "band"
         assert _band_block(bucket, 512) == 512
         _assert_attention_is_blockwise(hlo, 2, bucket)
-        # a window layer's band a run of blocks at a time: four loops, and no
-        # float32 buffer of a layer's 72 x 8,192 x 1,024 scores
-        assert hlo.count(" while(") == 4
+        # a window layer's band a run of blocks at a time: four loops (and
+        # one an expert layer: the turns past the first over the held rows'
+        # chunks), and no float32 buffer of a layer's 72 x 8,192 x 1,024
+        # scores
+        assert hlo.count(" while(") == 4 + 5
         made = [math.prod(int(d) for d in dims.split(",") if d)
                 for dims in re.findall(r"f32\[([\d,]+)\]", hlo)]
         assert max(made) < 72 * bucket * 1024
         _assert_one_row_of_logits(compiled, bucket, 25088)
-        # 3.34 GB: with the weights' 7.36 and the cache's 2.68, 13.4 of 16
-        assert mem.temp_size_in_bytes < 3.6e9
+        # PR 58's program, every assignment's rows in it: 3,344,669,696;
+        # with the weights' 7.36 GB and the cache's 2.68, 13.4 of 16
+        assert mem.temp_size_in_bytes <= 3_344_669_696
         return
+    assert _step_sha1(hlo) == _HELD_SHARE_STEPS["laguna"]
     assert pool_read_form(
         struct((lanes, 48, 128), "bfloat16"),
         struct(buffers[0][0], "bfloat16"), struct(buffers[1][0], "bfloat16"),
@@ -1201,6 +1223,30 @@ def _program_sha1(hlo):
 _PLAIN_KERNEL_PREFILLS = {
     "olmoe": "6d9ffceb605f56f469f17e0bf2cd4633dc979432",
     "nemotron_h": "84307b1188cbe3ab689ddf67c8a18f67865b82b5",
+}
+
+
+def _step_sha1(hlo):
+    """``_program_sha1`` with the kernels' calls named without their number:
+    a Pallas call's instruction is numbered by how many calls of its name
+    the PROCESS lowered before it (``%paged_read.6`` alone, ``.8`` behind
+    two other cells' steps), which says nothing of the program."""
+    return _program_sha1(re.sub(
+        r"%(paged_read|grouped_matmul\w*?|flash_attention)\.\d+", r"%\1",
+        hlo))
+
+
+# the DECODE programs of the four cells whose expert layers hold a share, as
+# the tests above compile them, at PR 58's tree (21789b6): a step's 256 to
+# 384 assignment rows keep a row each (``moe.held_rows_chunk`` names no
+# chunk there), so a change to how an ADMISSION moves its held rows (PR 59)
+# must leave them instruction for instruction. A PR that moves these
+# programs on purpose writes its own lines here.
+_HELD_SHARE_STEPS = {
+    "mimo_v2_flash": "353027a38d6939d394960d77a5963f8399020b56",
+    "dots3_note": "e4bd38401d088c717ca0415e0f412a51f74542f1",
+    "laguna": "f90cac47c167857d4fd32a1bccc3a1451b23ce69",
+    "nemotron_h": "823ea1fae773a557dc5e121d4abea8eb73defcf7",
 }
 
 
@@ -1536,6 +1582,7 @@ def test_nemotron_h_serving_programs_compile_for_the_chip(v5e, program):
         _assert_attention_is_blockwise(hlo, 2, bucket)
         assert _program_sha1(hlo) == _PLAIN_KERNEL_PREFILLS["nemotron_h"]
         return
+    assert _step_sha1(hlo) == _HELD_SHARE_STEPS["nemotron_h"]
     assert compiled.out_info[0][1].shape == (lanes, 64, 64, 128)
     assert compiled.out_info[0][7].shape == (slots // page, page, 256)
     assert len(_paged_read_calls(hlo)) == 2 and "kv_mask" not in hlo
@@ -1656,8 +1703,9 @@ def test_dots3_note_serving_programs_compile_for_the_chip(v5e, program):
         found = [math.prod(int(d) for d in dims.split(",") if d)
                  for _n, dims, op, _a in _INSTRUCTION.findall(hlo)
                  if op != "parameter"]
-        # the experts' 65,536 assignment rows of 5,120 are the largest thing
-        # made; a block's float32 scores stay inside the operator's budget
+        # nothing made is larger than the experts' 65,536 assignment rows of
+        # 5,120 were until PR 59 (the held rows' chunk is 4,096 of them); a
+        # block's float32 scores stay inside the operator's budget
         assert max(found) <= 8 * bucket * 5120
         assert attention._SCORE_BYTES // 4 < 8 * bucket * 5120
         # a full layer's masked attention is ONE call of the blockwise
@@ -1672,10 +1720,11 @@ def test_dots3_note_serving_programs_compile_for_the_chip(v5e, program):
         scores = [d for d in dims("f32") if d[:2] == [128, 128] and len(d) == 3]
         assert not scores, scores[:4]
         _assert_one_row_of_logits(compiled, bucket, 19008)
-        # 4.05 GB when this was written: under 15 beside 6.25 of weights and
-        # 1.50 of cache
-        assert mem.temp_size_in_bytes < 5 << 30
+        # PR 58's program, every assignment's rows in it: 3,945,426,432;
+        # under 15 GB beside 6.25 of weights and 1.50 of cache
+        assert mem.temp_size_in_bytes <= 3_945_426_432
         return
+    assert _step_sha1(hlo) == _HELD_SHARE_STEPS["dots3_note"]
     assert "kv_mask" not in hlo and "slot_onehot" not in hlo
     assert mem.alias_size_in_bytes == cache_bytes + padding
     assert mem.temp_size_in_bytes < 1 << 30
