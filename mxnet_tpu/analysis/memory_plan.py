@@ -53,7 +53,7 @@ _ATTN_OPS = frozenset({"_contrib_MultiHeadAttention", "MultiHeadAttention"})
 
 # the forms of ``ops.attention.attention_form`` whose backward keeps no
 # scores between the passes
-_RECOMPUTING_FORMS = frozenset({"kernel", "sparse_kernel"})
+_RECOMPUTING_FORMS = frozenset({"kernel", "sparse_kernel", "window_kernel"})
 
 _TOP_LIVE = 8  # live tensors named at the peak
 
@@ -73,16 +73,18 @@ def _attention_recomputes_scores(ctx, node, mesh):
     ``sink`` / ``topk`` and the mesh the plan is made under, exactly as
     ``MultiHeadAttention`` asks it at trace time.
 
-    Two forms count. ``"kernel"``: ``pallas_attention._flash`` is a
+    Three forms count. ``"kernel"``: ``pallas_attention._flash`` is a
     ``custom_vjp`` whose residuals are the operands, the output and the
     (B, H, T) logsumexp; both backward kernels re-derive the probabilities
     a block at a time. ``"sparse_kernel"``: ``_sparse_kernel_attention`` is a
     ``custom_vjp`` whose residuals are its six operands alone; its backward
     differentiates ``_sparse_attention`` afresh, so the probabilities live
-    only inside the backward node. Every other form (``"dense"``,
-    ``"band"``, ``"ring"``, ``"sparse"``) is differentiated by jax through
-    its softmax, which keeps the probabilities, and is charged the dense
-    (B, H, T, S) float32 bound. On the CPU the rule says ``"dense"`` or
+    only inside the backward node. ``"window_kernel"``:
+    ``_window_kernel_attention`` is a ``custom_vjp`` whose residuals are its
+    three operands; its backward differentiates the band afresh. Every other
+    form (``"dense"``, ``"band"``, ``"ring"``, ``"sparse"``) is
+    differentiated by jax through its softmax, which keeps the
+    probabilities, and is charged the dense (B, H, T, S) float32 bound. On the CPU the rule says ``"dense"`` or
     ``"band"`` (``"sparse"`` under a selection) at every site."""
     import jax
 

@@ -24,7 +24,7 @@ _NEG = np.float32(-1e9)
 # (observability for tests and the multichip dryrun: proves the seq-parallel
 # path, or the kernel, actually engaged)
 DISPATCH_COUNTS = {"ring": 0, "kernel": 0, "band": 0, "dense": 0,
-                   "sparse": 0, "sparse_kernel": 0}
+                   "sparse": 0, "sparse_kernel": 0, "window_kernel": 0}
 
 # float32 scores a blockwise form (a ragged band, a learned sparse selection)
 # makes at once: the query blocks are sized from it
@@ -78,8 +78,18 @@ def attention_form(query, key, value, causal, window=0, sink=False,
     scores of a query block are never written. Differentiated, it is
     ``"sparse"``'s backward.
 
+    ``"window_kernel"``: a window of W < T positions on the chip (one
+    device, no sink), where ``pallas_attention.takes`` the operands under the
+    window: ONE call of the blockwise kernel a layer, whose key axis runs
+    from the block of a query block's oldest key to its diagonal's; operands
+    in the type they arrive in, float32 sums, no score written, T any whole
+    number of lane tiles (no multiple of W). ``takes`` refuses a band small
+    enough that XLA's wins. Differentiated, it is the band's (or the dense
+    form's) backward.
+
     ``"band"``: a window of W < T positions, T a multiple of W or of W - 1
-    (``_band_block``): T x 2W scores (``_band_attention``).
+    (``_band_block``): T x 2W scores (``_band_attention``): every backend
+    but the chip, a sink, a mesh of several devices, what ``takes`` refuses.
 
     ``"kernel"``: plain CAUSAL attention over S >= T keys on the chip, where
     ``pallas_attention.takes`` the operands: blockwise with an online softmax,
@@ -113,6 +123,11 @@ def attention_form(query, key, value, causal, window=0, sink=False,
             and ("data" not in mesh.axis_names
                  or b % mesh.shape["data"] == 0):
         return "ring"
+    if window > 0 and causal and not sink and alone:
+        from . import pallas_attention as kernel
+
+        if kernel.takes(query, key, value, window=window):
+            return "window_kernel"
     if _band_block(t, window):
         return "band"
     if plain and causal and alone:
@@ -155,7 +170,10 @@ def _multi_head_attention(attrs, query, key, value, *more):
     blockwise (``ops/pallas_attention.py``): the same mathematics in the same
     types (both products one pass of the matrix unit in the operands' type
     with a float32 accumulator, the softmax float32), so the forms differ by
-    the order of a float32 sum.
+    the order of a float32 sum. So does a WINDOW layer's prefill there
+    (``"window_kernel"``: the same kernel, its key axis a query block's own
+    blocks), where the band is large enough that the kernel wins; a sink, a
+    mesh of several devices and a backward stay XLA's band.
 
     Sequence parallelism: when traced inside an SPMD step whose mesh has a
     ``seq`` axis (parallel.make_mesh({"data": dp, "seq": sp})), self-attention
@@ -198,7 +216,7 @@ def _multi_head_attention(attrs, query, key, value, *more):
     topk = attrs.get("topk", 0)
     b, h, t, d = query.shape
     hkv, s_len = key.shape[1], key.shape[2]
-    g = _kv_groups(h, hkv, "MultiHeadAttention")
+    _kv_groups(h, hkv, "MultiHeadAttention")
     window = attrs.get("window", 0)
     if window > 0 and not (attrs["causal"] and s_len == t):
         raise MXNetError("MultiHeadAttention: a window needs causal "
@@ -242,14 +260,28 @@ def _multi_head_attention(attrs, query, key, value, *more):
     if form == "sparse_kernel":
         return _sparse_kernel_attention(query, key, value, *more[-3:], topk,
                                         scale, _backend() != "tpu")
-    q = query.astype("float32").reshape(b, hkv, g, t, d)
+    if form == "window_kernel":
+        return _window_kernel_attention(query, key, value, window, scale,
+                                        _backend() != "tpu")
+    return _xla_attention(query, key, value, sink, attrs["causal"], window,
+                          scale)
+
+
+def _xla_attention(query, key, value, sink, causal, window, scale):
+    """XLA's forms over float32 operands (``attention_form``'s ``"band"`` and
+    ``"dense"``): a band where a block tiles T under the window
+    (``_band_block``, ``_band_attention``), else the scores of all T x S
+    pairs under the causal and the window's mask."""
+    b, h, t, d = query.shape
+    hkv, s_len = key.shape[1], key.shape[2]
+    q = query.astype("float32").reshape(b, hkv, h // hkv, t, d)
     if sink is not None:
-        sink = sink.astype("float32").reshape(hkv, g)
-    if form == "band":
+        sink = sink.astype("float32").reshape(hkv, h // hkv)
+    if _band_block(t, window):
         out = _band_attention(q, key, value, window, scale, sink)
         return out.reshape(b, h, t, value.shape[-1]).astype(query.dtype)
     s = jnp.einsum("bkgqd,bkud->bkgqu", q, key.astype("float32")) * scale
-    if attrs["causal"]:
+    if causal:
         # bottom-right aligned so a rectangular (decode) call — T queries over
         # S >= T keys — lets each query see all S-T+q past keys
         mask = jnp.tril(jnp.ones((t, s_len), bool), k=s_len - t)
@@ -260,6 +292,35 @@ def _multi_head_attention(attrs, query, key, value, *more):
         else _sink_softmax(s, sink[None, :, :, None, None])
     out = jnp.einsum("bkgqu,bkud->bkgqd", p, value.astype("float32"))
     return out.reshape(b, h, t, value.shape[-1]).astype(query.dtype)
+
+
+def _window_kernel(query, key, value, window, scale, interpret):
+    """A window layer's attention in the blockwise kernel's blocks
+    (``attention_form``'s ``"window_kernel"``): ONE ``flash_attention`` under
+    the window. Off the chip (a test that holds the rule) Pallas
+    ``interpret``s it."""
+    from . import pallas_attention as pa
+
+    return pa.flash_attention(query, key, value, causal=True, scale=scale,
+                              interpret=interpret, window=window)
+
+
+# the kernel has no backward under a window: differentiated, the form is the
+# band's (the dense form's where no block tiles T)
+_window_kernel_attention = jax.custom_vjp(_window_kernel,
+                                          nondiff_argnums=(3, 4, 5))
+
+
+def _window_kernel_fwd(*operands_and_attrs):
+    return _window_kernel(*operands_and_attrs), operands_and_attrs[:3]
+
+
+def _window_kernel_bwd(window, scale, _interpret, operands, cotangent):
+    return jax.vjp(lambda q, k, v: _xla_attention(
+        q, k, v, None, True, window, scale), *operands)[1](cotangent)
+
+
+_window_kernel_attention.defvjp(_window_kernel_fwd, _window_kernel_bwd)
 
 
 def _sink_softmax(s, sink):

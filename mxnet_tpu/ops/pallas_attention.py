@@ -30,6 +30,19 @@ too. The masked value is finite, so a row whose first key blocks hold nothing
 selected carries garbage until its first real key arrives, whose maximum
 rescales it to exactly nothing (``alpha = exp(_NEG_INF - m) = 0``).
 
+A WINDOW: ``window=W`` (causal calls) lets query r attend the W keys
+``r + offset - W < j <= r + offset``, itself among them. It is one more bound
+on the key axis: for a block of queries the key axis STARTS at the block that
+holds the first row's oldest key and ends at the diagonal's, the grid's key
+axis is as long as the most blocks a query block can visit
+(``window_key_blocks``) and the key/value ``index_map`` is clamped to
+``[first, last]``; a block the window's lower edge crosses is masked as one
+the diagonal crosses is, a block wholly inside both is not masked at all. T
+need not be a multiple of W. The forward kernel alone knows a window: its
+caller keeps a backward of its own (``ops.attention``'s band), as a
+selection's does. What the kernel still refuses: a SINK (one more logit in
+the denominator), a mesh of several devices (``attention_form``).
+
 Grouped queries: ``k`` / ``v`` may carry fewer heads (B, Hkv, S, D) than ``q``
 (B, H, T, D). The keys are never repeated: the H / Hkv query heads a key/value
 head serves are folded into the ROWS of that head's query block, so one grid
@@ -52,7 +65,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["flash_attention", "supported", "blocks", "block_schedules"]
+__all__ = ["flash_attention", "supported", "blocks", "block_schedules",
+           "window_key_blocks", "window_pairs_scored"]
 
 _NEG_INF = -1e30
 
@@ -92,7 +106,7 @@ _ROWS = 1024
 _BLOCK_K = 1024
 
 
-def blocks(t, s, group, dk, dv, dtype, selected=False):
+def blocks(t, s, group, dk, dv, dtype, selected=False, window=0):
     """``(block_q, block_k)`` of the forward kernel for ``t`` queries a head
     over ``s`` keys, ``group`` query heads a key/value head, a key of ``dk``
     and a value of ``dv`` numbers of ``dtype``, under a selection's mask or
@@ -116,10 +130,28 @@ def blocks(t, s, group, dk, dv, dtype, selected=False):
     whole blocks the diagonal crosses). At t = 1,024 a head is ONE step.
     Splitting a step's softmax into row chunks inside a loop, to keep the
     scores in registers, read 2 to 4.6 times SLOWER at every shape and is
-    not here."""
+    not here.
+
+    Under a ``window`` (causal) the key block stays 1,024 and ``block_q`` is
+    held to the window's whole lane tiles: a query block visits the key
+    blocks from its first row's oldest key to its diagonal's, so its cost is
+    the key blocks it VISITS (the rows' pass, each visit) before the keys it
+    scores. Key blocks of 1,024 over a window of 512 are visited 1.5 times a
+    query block (half the query blocks reach back into a second one), blocks
+    of 512 twice and of 256 three times: laguna's layer (72 over 8 heads x
+    8,192, a group of 9) read 4.23 ms at (64, 1,024), 4.94 at (64, 512),
+    5.80 at (64, 256), 9.60 at (64, 128), though 1,024 scores 2.9 times the
+    needed pairs and 256 1.5 times; phi4flash's 0.63 at (256, 1,024), 0.72
+    at (128, 512), 0.93 at (128, 256). A query block longer than the window
+    widens the span its key blocks must cover: dots3's layer (64 heads, a
+    key of 256, W 513) read 5.59 ms at (1,024, 1,024), 4.96 at (512, 1,024),
+    4.76 at (512, 512) (``PERF.md`` section 6, PR 60)."""
     unit = _sublanes(jnp.int8 if selected else dtype)
+    most_q = _ROWS // group
+    if window:
+        most_q = min(most_q, -(-(window - 1) // _LANES) * _LANES)
     bk = _largest_divisor(s, _BLOCK_K, _LANES) or _largest_divisor(s, s, unit)
-    bq = _largest_divisor(t, max(_ROWS // group, unit), unit)
+    bq = _largest_divisor(t, max(most_q, unit), unit)
     if not bq or not bk:
         return None
     while block_bytes(bq, bk, group, dk, dv, dtype, selected) > _VMEM_BUDGET:
@@ -181,7 +213,12 @@ def supported(q_shape, k_shape, causal=False, block_q=128, block_k=128):
 _DENSE_SCORES = 64 << 20
 
 
-def takes(query, key, value, selected=False):
+# the float32 scores a window's band makes, B x H x T x 2W x 4 bytes, up to
+# which the band stays XLA's on the chip (``takes``)
+_BAND_SCORES = 128 << 20
+
+
+def takes(query, key, value, selected=False, window=0):
     """Whether the chip runs causal attention over these operands, plain or
     under a selection's mask, as this kernel rather than in XLA's form
     (shapes and types alone; each operand carries ``.shape`` and ``.dtype``:
@@ -196,7 +233,19 @@ def takes(query, key, value, selected=False):
     512 x 512; 8 x 1,024 x 1,024, widths of 64) a layer standing alone read
     0.039 and 0.035 ms dense against the kernel's 0.070 and 0.038 at its
     best blocks, at 128 MiB (32 x 1,024 x 1,024) 0.59-0.66 dense against
-    0.17-0.20, and the gap widens from there (``PERF.md`` section 6, PR 49)."""
+    0.17-0.20, and the gap widens from there (``PERF.md`` section 6, PR 49).
+
+    ``window`` = W > 0 asks for a causal call under a window (no selection):
+    0 < W < T and a band, B x H x T x 2W float32 scores, of more than
+    ``_BAND_SCORES`` bytes. XLA's band wins below: a layer standing alone
+    read 0.19-0.21 ms as a band against the kernel's 0.36 at its best blocks
+    at 32 MiB (16 heads x 2,048, W 128), 0.23 against 0.50 at 64 MiB, 0.88
+    against 1.00 at 128 MiB (mimo's 64 over 8 heads of 192 / 128 x 2,048, W
+    128, without its sink); the kernel from there: 1.38 against 0.88 at 256
+    MiB (32 over 8 heads x 4,096, W 256), 1.66 against 0.63 at phi4flash's
+    320 MiB, 13.7 against 5.0 at dots3's 2.0 GiB and 11.8 against 4.2 at
+    laguna's 2.3 GiB, whose band runs a few blocks at a time through HBM
+    (``PERF.md`` section 6, PR 60)."""
     if not (query.dtype == key.dtype == value.dtype
             and query.dtype in (jnp.bfloat16, jnp.float32)):
         return False
@@ -204,24 +253,62 @@ def takes(query, key, value, selected=False):
     if hkv < 1 or h % hkv or s < t or t % _LANES or s % _LANES \
             or dk % 8 or dv % 8 or 4 * b * h * t * s <= _DENSE_SCORES:
         return False
-    return blocks(t, s, h // hkv, dk, dv, query.dtype, selected) is not None
+    if window and (selected or not 0 < window < t
+                   or 4 * b * h * t * 2 * window <= _BAND_SCORES):
+        return False
+    return blocks(t, s, h // hkv, dk, dv, query.dtype, selected,
+                  window) is not None
 
 
-def _causal_mask(s, iq, jk, block_q, block_k, offset):
+def _causal_mask(s, iq, jk, block_q, block_k, offset, window=0):
     """Bottom-right-aligned causal mask for one tile of scores, ``s``
     (groups x block_q, block_k): the query at row r of ANY group sees key
-    cols <= r + (S - T)."""
+    cols <= r + (S - T) and, under a ``window``, cols > r + (S - T) -
+    window."""
     group = s.shape[0] // block_q
     rows = iq * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (group, block_q, block_k), 1).reshape(s.shape)
     cols = jk * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(cols <= rows + offset, s, _NEG_INF)
+    seen = cols <= rows + offset
+    if window:
+        seen = jnp.logical_and(seen, cols > rows + offset - window)
+    return jnp.where(seen, s, _NEG_INF)
 
 
 def _last_key_block(iq, block_q, block_k, offset):
     """The last key block the queries of block ``iq`` see: the one that holds
     the last row's diagonal column."""
     return ((iq + 1) * block_q - 1 + offset) // block_k
+
+
+def _first_key_block(iq, block_q, block_k, offset, window):
+    """The first key block the queries of block ``iq`` see under a window:
+    the one that holds the first row's oldest key."""
+    return jnp.maximum(iq * block_q + offset - window + 1, 0) // block_k
+
+
+def _key_blocks_visited(t, s, block_q, block_k, window):
+    """The key blocks each block of ``block_q`` queries visits under a causal
+    ``window`` over ``t`` queries and ``s`` keys in blocks of ``block_k``,
+    from the block of its first row's oldest key to its diagonal's (plain
+    Python over shapes)."""
+    offset = s - t
+    return [_last_key_block(iq, block_q, block_k, offset)
+            - max(iq * block_q + offset - window + 1, 0) // block_k + 1
+            for iq in range(t // block_q)]
+
+
+def window_key_blocks(t, s, block_q, block_k, window):
+    """The most key blocks one block of queries visits under a window: the
+    length of the windowed kernel's key axis."""
+    return max(_key_blocks_visited(t, s, block_q, block_k, window))
+
+
+def window_pairs_scored(t, s, block_q, block_k, window):
+    """The (query, key) pairs the windowed kernel scores a head: every block
+    a query block visits, whole."""
+    return block_q * block_k * sum(
+        _key_blocks_visited(t, s, block_q, block_k, window))
 
 
 def _first_query_block(jk, block_q, block_k, offset):
@@ -241,9 +328,11 @@ def _selected_mask(s, sel, block_q):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
-                nk, offset, with_lse, selected):
+                nk, offset, with_lse, selected, window=0):
     """``rest``: the selection's block (``selected`` alone), the output, the
-    logsumexp (``with_lse`` alone), then the scratch."""
+    logsumexp (``with_lse`` alone), then the scratch. Under a ``window`` grid
+    step ``jk`` is key block ``first + jk`` of the query block's own
+    ``[first, last]``."""
     from jax.experimental import pallas as pl
 
     rest = list(rest)
@@ -260,13 +349,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def step(masked):
+    def step(masked, at=jk):
         q = q_ref[0].reshape(rows, q_ref.shape[-1])   # the group into rows
         k, v = k_ref[0], v_ref[0]                     # (block_k, dk | dv)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if masked:
-            s = _causal_mask(s, iq, jk, block_q, block_k, offset)
+            s = _causal_mask(s, iq, at, block_q, block_k, offset, window)
         if selected:
             s = _selected_mask(s, sel_ref[0], block_q)
         m_prev = m_scr[...]
@@ -279,7 +368,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
+    if window:
+        # key block ``at`` of [first, last]: clear where it lies wholly at or
+        # below the diagonal of the block's FIRST row and wholly inside the
+        # window of its LAST row; masked where either edge crosses it; past
+        # ``last`` it does not run
+        at = _first_key_block(iq, block_q, block_k, offset, window) + jk
+        runs = at <= _last_key_block(iq, block_q, block_k, offset)
+        clear = jnp.logical_and(
+            (at + 1) * block_k - 1 <= iq * block_q + offset,
+            at * block_k >= (iq + 1) * block_q + offset - window)
+        pl.when(jnp.logical_and(runs, clear))(lambda: step(False, at))
+        pl.when(jnp.logical_and(runs, jnp.logical_not(clear)))(
+            lambda: step(True, at))
+    elif causal:
         # a block wholly at or below the diagonal of its FIRST row needs no
         # causal mask (a selection's it takes all the same); one the diagonal
         # crosses is masked; one above it does not run
@@ -396,14 +498,15 @@ def _compiler_params(n_parallel, vmem_bytes=0):
 
 
 def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
-              with_lse=True, selected=None):
+              with_lse=True, selected=None, window=0):
     """The forward kernel over ``q`` (BHkv, G, T, dk), ``k`` (BHkv, S, dk) and
     ``v`` (BHkv, S, dv): ``(out (BHkv, G, T, dv), lse (BHkv, G, T, 1) | None)``.
     The logsumexp is the backward's; a call nobody differentiates leaves it
     out. ``selected`` (B, T, S) int8, absent at TRACE time for a plain call
     (whose program is then what it was without the word): batch row ``bh //
     (BHkv / B)``'s mask for every head of it, its block fetched beside the
-    key block's."""
+    key block's. ``window`` > 0 (static; causal, no selection): the key axis
+    of the grid is a query block's own ``[first, last]`` blocks."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -411,11 +514,20 @@ def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
     S, Dv = k.shape[1], v.shape[2]
     nq, nk = T // block_q, S // block_k
     offset = S - T
+    if window:
+        nk = window_key_blocks(T, S, block_q, block_k, window)
     kern = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, nk=nk, offset=offset, with_lse=with_lse,
-        selected=selected is not None)
-    if causal:
+        selected=selected is not None, window=window)
+    if window:
+        # a query block's key axis starts at the block of its first row's
+        # oldest key and stays on its last block past the diagonal
+        kv_block = lambda bh, iq, jk: (bh, jnp.minimum(
+            _first_key_block(iq, block_q, block_k, offset, window) + jk,
+            _last_key_block(iq, block_q, block_k, offset)), 0)
+        live = sum(min(r + offset + 1, window) for r in range(T))
+    elif causal:
         # past the diagonal the index stays on the last block the queries
         # need: the pipeline fetches nothing for a step that does not run
         kv_block = lambda bh, iq, jk: (bh, jnp.minimum(
@@ -464,7 +576,7 @@ def _fwd_call(q, k, v, causal, scale, block_q, block_k, interpret,
             flops=2 * BH * G * live * (D + Dv),
             transcendentals=BH * G * live, bytes_accessed=nbytes),
         interpret=interpret,
-        name="flash_attention",
+        name="window_attention" if window else "flash_attention",
     )(*operands)
     return out[0], (out[1] if with_lse else None)
 
@@ -591,10 +703,27 @@ _flash_selected.defvjp(lambda *a: (_flash_selected(*a), None),
                        _flash_selected_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_window(q, k, v, window, scale, block_q, block_k, interpret):
+    return _fwd_call(q, k, v, True, scale, block_q, block_k, interpret,
+                     with_lse=False, window=window)[0]
+
+
+def _flash_window_bwd(*_):
+    raise NotImplementedError(
+        "flash_attention(window=) is not differentiable: the backward "
+        "kernels know the causal mask alone. MultiHeadAttention(window=) "
+        "keeps the band's backward")
+
+
+_flash_window.defvjp(lambda *a: (_flash_window(*a), None), _flash_window_bwd)
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
-                                             "block_k", "interpret"))
+                                             "block_k", "interpret",
+                                             "window"))
 def flash_attention(q, k, v, causal=False, scale=0.0, block_q=None,
-                    block_k=None, interpret=False, selected=None):
+                    block_k=None, interpret=False, selected=None, window=0):
     """softmax(QKᵀ·scale)V of ``q`` (B, H, T, dk) over ``k`` (B, Hkv, S, dk)
     and ``v`` (B, Hkv, S, dv), Hkv dividing H, streamed through VMEM: (B, H,
     T, dv). Differentiable (custom_vjp backward kernels). ``block_q`` /
@@ -605,9 +734,17 @@ def flash_attention(q, k, v, causal=False, scale=0.0, block_q=None,
     key s, the same for every head: a score survives where it is causal (if
     ``causal``) AND selected; every row must keep a key. The forward kernel
     alone knows it: such a call is not differentiable (its caller keeps a
-    backward of its own, ``ops.attention``)."""
+    backward of its own, ``ops.attention``).
+
+    ``window`` = W > 0 (static; ``causal`` and no selection): query r attends
+    the W keys ``r + (S - T) - W < j <= r + (S - T)``, itself among them,
+    and the kernel visits a query block's own key blocks alone
+    (``window_key_blocks``). The forward kernel alone knows it too."""
     B, H, T, D = q.shape
     Hkv, S, Dv = k.shape[1], k.shape[2], v.shape[3]
+    if window and (not causal or selected is not None):
+        raise ValueError("flash_attention(window=) takes causal calls "
+                         "without a selection")
     if causal and S < T:
         raise ValueError(
             "flash_attention(causal=True) requires S >= T (got T=%d, S=%d): "
@@ -617,7 +754,7 @@ def flash_attention(q, k, v, causal=False, scale=0.0, block_q=None,
         scale = 1.0 / np.sqrt(D)
     G = H // Hkv
     if block_q is None or block_k is None:
-        ruled = blocks(T, S, G, D, Dv, q.dtype, selected is not None)
+        ruled = blocks(T, S, G, D, Dv, q.dtype, selected is not None, window)
         if ruled is None:
             raise ValueError(
                 "flash_attention: no block tiles %d queries over %d keys "
@@ -628,7 +765,10 @@ def flash_attention(q, k, v, causal=False, scale=0.0, block_q=None,
     block_k = min(block_k, S)
     heads = (q.reshape(B * Hkv, G, T, D), k.reshape(B * Hkv, S, D),
              v.reshape(B * Hkv, S, Dv))
-    if selected is None:
+    if window:
+        out = _flash_window(*heads, window, float(scale), block_q, block_k,
+                            interpret)
+    elif selected is None:
         out = _flash(*heads, causal, float(scale), block_q, block_k,
                      interpret)
     else:

@@ -404,22 +404,46 @@ def _moe_forms(cache, input_shapes):
     return forms, depth
 
 
-def _attention_forms(cache, input_shapes):
-    """The form of every ``MultiHeadAttention`` node of a program's graph at
-    these input shapes, in the graph's order, by the operator's own rule
-    (``ops.attention.attention_form``; no program here traces under a
-    mesh)."""
+def _attention_sites(cache, input_shapes):
+    """``[(form, window, operands)]`` of every ``MultiHeadAttention`` node of
+    a program's graph at these input shapes, in the graph's order: the form
+    by the operator's own rule (``ops.attention.attention_form``; no program
+    here traces under a mesh)."""
     from ..ops.attention import attention_form
 
-    forms = []
+    sites = []
     for n, ops in _operands_of(cache, input_shapes,
                                "_contrib_MultiHeadAttention"):
         attrs = n.parsed_attrs()
-        forms.append(attention_form(
-            ops["query"], ops["key"], ops["value"], attrs["causal"],
-            attrs.get("window", 0), bool(attrs.get("sink")), None,
-            attrs.get("topk", 0)))
-    return forms
+        window = attrs.get("window", 0)
+        sites.append((attention_form(
+            ops["query"], ops["key"], ops["value"], attrs["causal"], window,
+            bool(attrs.get("sink")), None, attrs.get("topk", 0)), window,
+            ops))
+    return sites
+
+
+def _window_pairs_scored(cache, input_shapes):
+    """The (query, key) pairs ONE window layer of a prefill program scores
+    over its bucket, in the form the operator's own rule names for the
+    program's first ``MultiHeadAttention(window=)`` node: every block a
+    query block visits, whole, under the blockwise kernel
+    (``"window_kernel"``: ``pallas_attention.window_pairs_scored`` at the
+    rule's blocks), ``T x 2 x block`` as a band, ``T x T`` dense; 0 where the
+    program has no such node."""
+    from ..ops import pallas_attention as pa
+
+    for form, w, ops in _attention_sites(cache, input_shapes):
+        if w <= 0:
+            continue
+        q, k, v = ops["query"], ops["key"], ops["value"]
+        t = q.shape[2]
+        if form == "window_kernel":
+            return pa.window_pairs_scored(t, t, *pa.blocks(
+                t, t, q.shape[1] // k.shape[1], q.shape[3], v.shape[3],
+                q.dtype, window=w), w)
+        return t * 2 * _band_block(t, w) if form == "band" else t * t
+    return 0
 
 
 def _swap_cache(exe, names):
@@ -1320,6 +1344,9 @@ class PagedKVDecoder:
                             if kind == "ring"]
         self._window = max((shape[1] for _, kind, shape in self._cache
                             if kind == "ring"), default=0)
+        # pairs one window layer's prefill scores: read off the program at
+        # the first admission that counts them (telemetry on)
+        self._window_scored = None
         # layers whose read is a learned selection keep what a lane's last
         # token selected (``sparse_sel_<i>``, a row of ``index_topk``
         # positions): how many, and how many positions each selects
@@ -1500,9 +1527,10 @@ class PagedKVDecoder:
                 _tm.gauge("serving.moe.fetch_depth." + program).set(depth)
             if "prefill" in programs:
                 # the prefill's attention layers by the form the rule names
-                forms = _attention_forms(*programs["prefill"])
+                forms = [form for form, _, _ in _attention_sites(
+                    *programs["prefill"])]
                 for form in ("kernel", "dense", "band", "sparse",
-                             "sparse_kernel"):
+                             "sparse_kernel", "window_kernel"):
                     _tm.gauge("serving.prefill_attention.%s_layers"
                               % form).set(forms.count(form))
             _tm.gauge("serving.state_bytes").set(sum(
@@ -1758,12 +1786,15 @@ class PagedKVDecoder:
                     self._sparse_layers * L * (L + 1) // 2)
             if self._window:
                 # (query, key) pairs ONE window layer's prefill scored over
-                # the bucket (a band of two blocks a query, else all of
-                # them), and those among them a real position attends
-                w, t = self._window, self.prefill_len
-                block = _band_block(t, w)
+                # the bucket in the form it runs in (the kernel's blocks, a
+                # band of two blocks a query, else all of them), and those
+                # among them a real position attends
+                w = self._window
+                if self._window_scored is None:
+                    self._window_scored = _window_pairs_scored(
+                        self._pf_cache, self._prefill_shapes())
                 _tm.counter("serving.admit_window_pairs_scored").inc(
-                    t * 2 * block if block else t * t)
+                    self._window_scored)
                 _tm.counter("serving.admit_window_pairs_live").inc(
                     min(L, w) * (min(L, w) + 1) // 2 + max(L - w, 0) * w)
             if self._shared_readers:

@@ -225,6 +225,31 @@ _RULE_CASES = {
                             {"window": 128}, "band"),
     "a ragged window": ((16, 16, 2048, 2048, 64, 64, "bfloat16"),
                         {"window": 100}, "dense"),
+    # a window whose band is large: the kernel under the window, at the
+    # cells' window layers (T no multiple of the window asked of the kernel:
+    # dots3's 513 is masked, not tiled)
+    "laguna's window layer": ((72, 8, 8192, 8192, 128, 128, "bfloat16"),
+                              {"window": 512}, "window_kernel"),
+    "dots3's window layer": ((64, 64, 8192, 8192, 256, 128, "bfloat16"),
+                             {"window": 513}, "window_kernel"),
+    "phi4flash's window layer": (
+        (40, 10, 2048, 2048, 128, 128, "bfloat16"), {"window": 512},
+        "window_kernel"),
+    "mimo's window layer, a sink": (
+        (64, 8, 2048, 2048, 192, 128, "bfloat16"),
+        {"window": 128, "sink": True}, "band"),
+    "a large ragged window": ((72, 8, 8192, 8192, 128, 128, "bfloat16"),
+                              {"window": 500}, "window_kernel"),
+    "a large window, float32": ((72, 8, 8192, 8192, 128, 128, "float32"),
+                                {"window": 512}, "window_kernel"),
+    "a large window, mixed types": (
+        (72, 8, 8192, 8192, 128, 128, ("bfloat16", "float32")),
+        {"window": 512}, "band"),
+    "a large window, T no whole lane tiles": (
+        (72, 8, 8200, 8200, 128, 128, "bfloat16"), {"window": 512}, "dense"),
+    "a window as long as the bucket": (
+        (72, 8, 8192, 8192, 128, 128, "bfloat16"), {"window": 8192},
+        "dense"),
     "mixed types": ((16, 16, 2048, 2048, 64, 64, ("bfloat16", "float32")),
                     {}, "dense"),
     # a learned selection (``topk``): the kernel under a mask where it takes
@@ -268,8 +293,8 @@ def test_the_rule_names_the_form_from_shapes_attributes_and_backend(
     args = (attrs.get("causal", True), attrs.get("window", 0),
             attrs.get("sink", False), None, attrs.get("topk", 0))
     monkeypatch.setenv("MXNET_USE_PALLAS_ATTENTION", "1")
-    off_chip = on_chip if on_chip == "band" else \
-        "sparse" if "topk" in attrs else "dense"
+    off_chip = "sparse" if "topk" in attrs else "band" if attn_op._band_block(
+        t, attrs.get("window", 0)) else "dense"
     assert attn_op.attention_form(*ops, *args) == off_chip
     monkeypatch.setattr(attn_op, "_backend", lambda: "tpu")
     assert attn_op.attention_form(*ops, *args) == on_chip
@@ -298,6 +323,12 @@ def test_the_rule_keeps_a_step_over_several_devices_dense(monkeypatch):
     # blocks under any mesh of several
     assert form(1, None, 512) == form(1, one, 512) == "sparse_kernel"
     assert form(4, data, 512) == form(4, seq, 512) == "sparse"
+    # a window: the kernel on one device, XLA's band under any mesh of
+    # several
+    window = lambda b, mesh: attn_op.attention_form(
+        struct(b), struct(b), struct(b), True, 512, False, mesh, 0)
+    assert window(4, None) == window(4, one) == "window_kernel"
+    assert window(4, data) == window(4, seq) == "band"
 
 
 def test_the_op_runs_the_form_the_rule_names_and_counts_it(monkeypatch):
@@ -519,6 +550,189 @@ def test_the_op_runs_a_selection_through_the_kernel_and_counts_it(
         jax.grad(lambda q: pa.flash_attention(
             q, k, v, causal=True, interpret=True,
             selected=selected).sum())(q)
+
+
+# --------------------------------------------------- the kernel under a window
+def _windowed_softmax(q, k, v, window, scale):
+    """The dense form's masked softmax in numpy float64: query r attends keys
+    ``r + (S - T) - window < j <= r + (S - T)``; grouped heads, any widths."""
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    (b, h, t, d), (_, hkv, s, _) = q.shape, k.shape
+    sc = np.einsum("bkgqd,bkud->bkgqu", q.reshape(b, hkv, h // hkv, t, d),
+                   k) * scale
+    at = np.arange(t)[:, None] + (s - t) - np.arange(s)[None, :]
+    sc = np.where((at >= 0) & (at < window), sc, -np.inf)
+    p = np.exp(sc - sc.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return np.einsum("bkgqu,bkud->bkgqd", p, v).reshape(b, h, t, -1)
+
+
+@pytest.mark.parametrize("widths", [(128, 128), (192, 128)])
+@pytest.mark.parametrize("group", [1, 2, 9])
+@pytest.mark.parametrize("t,block", [(1024, (128, 128)), (704, (32, 64))])
+@pytest.mark.parametrize("window", [128, 512, 513])
+def test_the_windowed_kernel_interpreted_is_the_masked_softmax(
+        window, t, block, group, widths):
+    """``flash_attention(window=W)`` against the dense form's masked softmax:
+    the three configurations' windows, T a multiple of W (1,024 of 128 and
+    512) and of neither W nor W - 1 (704), the groups of the cells (laguna's
+    9 among them), a value narrower than the key. The first W - 1 rows see
+    fewer than W keys; the grid's key axis is shorter than the keys'."""
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    q, k, v = _operands(group, 1, t, t, *widths, "float32")
+    scale = widths[0] ** -0.5
+    got = pa.flash_attention(q, k, v, causal=True, scale=scale,
+                             block_q=block[0], block_k=block[1],
+                             interpret=True, window=window)
+    assert got.shape == (1, group, t, widths[1])
+    assert pa.window_key_blocks(t, t, *block, window) < t // block[1]
+    np.testing.assert_allclose(np.asarray(got),
+                               _windowed_softmax(q, k, v, window, scale),
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("window", [24, 128, 200])
+def test_the_windowed_kernel_over_more_keys_than_queries(window, dtype):
+    """S > T: the window hangs from the bottom-right diagonal, query r's
+    newest key ``r + (S - T)``; and the operands' types: bfloat16 to one
+    rounding of the output."""
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    q, k, v = _operands(4, 2, 128, 384, 64, 32, dtype)
+    got = pa.flash_attention(q, k, v, causal=True, scale=0.125, block_q=32,
+                             block_k=64, interpret=True, window=window)
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(got, "float32"),
+        _windowed_softmax(q, k, v, window, 0.125), rtol=tol, atol=tol)
+
+
+def test_the_first_rows_of_a_window_see_the_keys_they_have():
+    """Row 0 attends itself alone and row r < W its r + 1 keys: the output's
+    first row IS the value's, and a window as long as the bucket is plain
+    causal attention."""
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    q, k, v = _operands(2, 2, 64, 64, 16, 16, "float32")
+    run = lambda w: np.asarray(pa.flash_attention(
+        q, k, v, causal=True, block_q=16, block_k=16, interpret=True,
+        window=w))
+    np.testing.assert_allclose(run(8)[:, :, 0], np.asarray(v)[:, :, 0],
+                               rtol=1e-6)
+    np.testing.assert_allclose(run(8)[:, :, :8], run(64)[:, :, :8],
+                               rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(run(64), np.asarray(pa.flash_attention(
+        q, k, v, causal=True, block_q=16, block_k=16, interpret=True)),
+        rtol=1e-6, atol=1e-7)
+
+
+@pytest.mark.parametrize("group", [1, 4])
+def test_a_block_outside_the_window_is_never_read(group):
+    """Keys and values OUTSIDE the blocks query block 4 visits (before the
+    block of its first row's oldest key, past its diagonal's) are NaN: a
+    masked product would carry them into every row (0 x NaN), a block that
+    is neither run nor fetched leaves the block's rows what the clean
+    operands give, bit for bit."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    w, blk = 24, 16
+    q, k, v = _operands(2 * group, 2, 128, 128, 128, 128, "float32")
+    run = lambda k, v: np.asarray(pa.flash_attention(
+        q, k, v, causal=True, block_q=blk, block_k=blk, interpret=True,
+        window=w))
+    # rows 64..79 reach back to key 64 - 23 = 41 (block 2) and up to 79
+    rows = slice(4 * blk, 5 * blk)
+    poison = lambda a: a.at[:, :, :2 * blk].set(jnp.nan).at[
+        :, :, 5 * blk:].set(jnp.nan)
+    clean, dirty = run(k, v), run(poison(k), poison(v))
+    np.testing.assert_array_equal(dirty[:, :, rows], clean[:, :, rows])
+    assert np.isnan(dirty[:, :, :2 * blk]).all()
+    assert pa.window_key_blocks(128, 128, blk, blk, w) == 3
+
+
+# a window layer of each configuration with one: (H, Hkv, dk, dv, window)
+_WINDOW_LAYERS = {
+    "laguna-s-2.1": (72, 8, 128, 128, 512),
+    "dots3-note-prev": (64, 64, 256, 128, 513),
+    "phi-4-mini-flash-reasoning": (40, 10, 128, 128, 512),
+    "mimo-v2-flash": (64, 8, 192, 128, 128),
+}
+
+
+@pytest.mark.parametrize("layer", list(_WINDOW_LAYERS))
+def test_every_tiling_the_rule_names_under_a_window_holds_the_output(layer):
+    """``blocks(window=)`` at a cell's window layer (one key/value head of
+    it, bfloat16 sizes, the bucket cut to 2,048: the rule's answer is the
+    cell's own 8,192's) tiles the call, and the kernel at that tiling is the
+    masked softmax."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    h, hkv, dk, dv, w = _WINDOW_LAYERS[layer]
+    g, t = h // hkv, 2048
+    tiling = pa.blocks(t, t, g, dk, dv, jnp.bfloat16, window=w)
+    assert tiling == pa.blocks(8192, 8192, g, dk, dv, jnp.bfloat16, window=w)
+    assert t % tiling[0] == 0 and t % tiling[1] == 0
+    assert pa.block_bytes(*tiling, g, dk, dv, jnp.bfloat16) \
+        <= pa._VMEM_BUDGET
+    q, k, v = _operands(g, 1, t, t, dk, dv, "float32")
+    got = pa.flash_attention(q, k, v, causal=True, scale=dk ** -0.5,
+                             block_q=tiling[0], block_k=tiling[1],
+                             interpret=True, window=w)
+    np.testing.assert_allclose(np.asarray(got),
+                               _windowed_softmax(q, k, v, w, dk ** -0.5),
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("t,window", [(64, 16), (60, 16), (64, 13)])
+def test_the_op_runs_a_window_through_the_kernel_and_its_gradient_is_the_bands(
+        monkeypatch, t, window):
+    """``MultiHeadAttention(window=)`` off the chip is a band (the dense form
+    where no block tiles T) and counts it; with the rule held to
+    ``"window_kernel"`` the same call runs the kernel interpreted and counts
+    that. Its gradient is XLA's form's, to the last bit (the kernel has no
+    backward under a window, and says so where it is differentiated
+    alone)."""
+    from mxnet_tpu.ops import attention as attn_op
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    q, _, v = _operands(4, 2, t, t, 16, 8, "float32")
+    k = _operands(4, 2, t, t, 16, 8, "float32", seed=9)[1]
+    xla = "band" if attn_op._band_block(t, window) else "dense"
+    before = dict(attn_op.DISPATCH_COUNTS)
+    want = _mha(q, k, v, window=window)
+    assert attn_op.DISPATCH_COUNTS[xla] == before[xla] + 1
+    loss = lambda q, k, v: (_mha(q, k, v, window=window) ** 2).sum()
+    want_grad = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    monkeypatch.setattr(attn_op, "attention_form",
+                        lambda *a: "window_kernel")
+    blocks = pa.blocks
+    monkeypatch.setattr(pa, "blocks", lambda *a, **kw: (
+        4 if t % 8 else 8, 4 if t % 8 else 16))
+    got = _mha(q, k, v, window=window)
+    assert attn_op.DISPATCH_COUNTS["window_kernel"] \
+        == before["window_kernel"] + 1
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    # the gradient's cotangent comes from the KERNEL's output, its pull-back
+    # is XLA's form's
+    got_grad = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got_grad, want_grad):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5)
+    monkeypatch.setattr(pa, "blocks", blocks)
+    with pytest.raises(NotImplementedError, match="not differentiable"):
+        jax.grad(lambda q: pa.flash_attention(
+            q, k, v, causal=True, block_q=4, block_k=4, interpret=True,
+            window=window).sum())(q)
+    with pytest.raises(ValueError, match="causal calls without a selection"):
+        pa.flash_attention(q, k, v, causal=False, block_q=4, block_k=4,
+                           interpret=True, window=window)
 
 
 # ------------------------------------------------------- flash attention grads

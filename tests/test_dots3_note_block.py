@@ -419,11 +419,14 @@ def test_the_block_with_the_rule_held_to_the_kernel_is_the_references(
         dec = _decoder(params).warmup()
         forms = {form: telemetry.gauge(
             "serving.prefill_attention.%s_layers" % form).value
-            for form in ("sparse_kernel", "sparse", "band")}
+            for form in ("sparse_kernel", "sparse", "band",
+                         "window_kernel")}
     finally:
         telemetry.set_mode(saved)
         telemetry.reset()
-    assert forms == {"sparse_kernel": 3, "sparse": 0, "band": 3}
+    # (off the chip a window layer's prefill stays XLA's band)
+    assert forms == {"sparse_kernel": 3, "sparse": 0, "band": 3,
+                     "window_kernel": 0}
     assert attention.DISPATCH_COUNTS["sparse_kernel"] \
         == before["sparse_kernel"] + 3
     assert attention.DISPATCH_COUNTS["sparse"] == before["sparse"]

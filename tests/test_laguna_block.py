@@ -663,6 +663,47 @@ def test_spans_gauges_and_counters(tm):
     assert spans.count("serving.admit.state") == 2
 
 
+@pytest.mark.parametrize("length", [5, 20, 32])
+def test_a_prefill_held_to_the_windowed_kernel_is_the_references(
+        monkeypatch, tm, length):
+    """The rule held to ``"window_kernel"`` for the four window layers (the
+    chip's answer at the cell's sizes), the kernel interpreted in (8, 16)
+    blocks at groups of three: the admission's logits and the first window
+    layer's ring are the reference's inside the float32 limit the band holds,
+    the warm-up's gauges name four kernel layers under a window and no band,
+    and the admission counts the pairs THAT form scores: of the four
+    query blocks of 8 the third alone reaches back into a second key block
+    of 16, 8 x 16 x 5, where the band scored 32 x 2 x 8."""
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    rule = attention.attention_form
+    monkeypatch.setattr(
+        attention, "attention_form", lambda *a: "window_kernel"
+        if a[4] > 0 and not a[5] else rule(*a))
+    monkeypatch.setattr(pa, "blocks", lambda *a, **kw: (8, 16))
+    params = _weights()
+    before = dict(attention.DISPATCH_COUNTS)
+    dec = _decoder(params).warmup()
+    assert tm.gauge("serving.prefill_attention.window_kernel_layers").value \
+        == 4
+    assert tm.gauge("serving.prefill_attention.band_layers").value == 0
+    assert attention.DISPATCH_COUNTS["window_kernel"] \
+        == before["window_kernel"] + 4
+    assert attention.DISPATCH_COUNTS["band"] == before["band"]
+    toks = np.random.RandomState(length).randint(1, CFG["vocab_size"],
+                                                 length + 3)
+    counted = tm.counters()
+    got, admitted, _ = _admit_and_step(dec, toks, length)
+    moved = {k: v - counted.get(k, 0) for k, v in tm.counters().items()}
+    assert pa.window_key_blocks(32, 32, 8, 16, W) == 2
+    assert moved["serving.admit_window_pairs_scored"] == 8 * 16 * 5 \
+        == pa.window_pairs_scored(32, 32, 8, 16, W)
+    want = np.asarray(ref.logits(params, jnp.asarray(toks), CFG, last=4))
+    assert _rel_l2(got, want).max() < F32_TOL
+    keys = ref.first_window_keys(params, jnp.asarray(toks), CFG)
+    assert _ring_error(admitted, keys, length - 1) < 1e-5
+
+
 def test_a_dense_window_scores_the_whole_bucket(tm):
     """Where the bucket is no multiple of the window (or of the window less
     one) the window layers mask full T x T scores, and the counter says so."""

@@ -76,6 +76,33 @@ def test_flash_attention_fwd_bwd(v5e, heads, kv_heads, t, dk, dv):
              spec(kv_heads, dv))
 
 
+@pytest.mark.parametrize("heads,kv_heads,t,dk,dv,window,blocks", [
+    (72, 8, 8192, 128, 128, 512, (64, 1024)),     # laguna: a group of 9
+    (64, 64, 8192, 256, 128, 513, (512, 1024)),   # dots3: T no multiple
+    (40, 10, 2048, 128, 128, 512, (256, 1024)),   # phi4flash's padded pairs
+])
+def test_flash_attention_under_a_window(v5e, heads, kv_heads, t, dk, dv,
+                                        window, blocks):
+    """The forward kernel under a window at the three cells' window layers
+    and the rule's blocks, through Mosaic for the v5e; and the operator
+    differentiated there (``"window_kernel"``'s backward is the band's:
+    XLA's alone, the kernel forward)."""
+    from mxnet_tpu.ops import attention
+
+    assert pa.blocks(t, t, heads // kv_heads, dk, dv, jnp.bfloat16,
+                     window=window) == blocks
+    spec = lambda h, d: ((1, h, t, d), "bfloat16")
+    specs = spec(heads, dk), spec(kv_heads, dk), spec(kv_heads, dv)
+    _compile(v5e, lambda q, k, v: pa.flash_attention(
+        q, k, v, causal=True, interpret=False, window=window), *specs)
+    if heads == 40:     # the smallest: the op's forward and backward
+        op = lambda q, k, v: attention._multi_head_attention(
+            {"causal": True, "scale": -1.0, "window": window}, q, k, v)
+        # (a loss that reads the output, or the forward is dead code)
+        _compile(v5e, jax.grad(lambda *a: jnp.sum(
+            op(*a).astype(jnp.float32) ** 2), argnums=(0, 1, 2)), *specs)
+
+
 def test_resnet50_training_step_is_xla_alone(v5e):
     """The training cells' step (``SPMDTrainer``, ResNet-50, 224x224, 256
     images, bfloat16) lowered for the chip: every node is its registered
@@ -260,6 +287,15 @@ def _flash_attention_calls(hlo):
             and "/flash_attention" in line]
 
 
+def _window_attention_calls(hlo):
+    """The program's calls of the blockwise attention kernel under a WINDOW
+    (``flash_attention(window=)``: ``attention_form``'s ``"window_kernel"``),
+    a line each."""
+    return [line for line in hlo.splitlines() if " custom-call(" in line
+            and 'custom_call_target="tpu_custom_call"' in line
+            and "/window_attention" in line]
+
+
 def _assert_expert_layers(hlo, layers, tokens, k, experts, d, f,
                           routed=None):
     """The program's ``layers`` expert layers of ``tokens`` rows, ``k`` experts
@@ -410,7 +446,12 @@ def test_the_attention_rules_at_the_cells_shapes(cell):
     selected = form == "sparse_kernel"
     assert attention.attention_form(*ops, True, 0, False, None,
                                     2048 if selected else 0) == form
-    assert attention.attention_form(*ops, True, 128) == "band"
+    # a window of 128: the kernel under the window where it takes the
+    # operands and the band's float32 scores pass its threshold (dots3's and
+    # laguna's wide full layers alone), XLA's band below
+    assert attention.attention_form(*ops, True, 128) == (
+        "window_kernel" if blocks and 4 * h * t * 256 > pa._BAND_SCORES
+        else "band")
     assert attention.attention_form(*ops, True, 0, True) == "dense"
     assert attention.attention_form(*ops, False) == "dense"
     if blocks:
@@ -1027,6 +1068,9 @@ def test_mimo_v2_flash_serving_programs_compile_for_the_chip(v5e, program):
         _assert_one_row_of_logits(compiled, bucket, 19072)
         # PR 58's program, every assignment's rows in it: 710,681,088
         assert mem.temp_size_in_bytes <= 710_681_088
+        # its window layers carry a sink: XLA's band, the parent's program
+        assert not _window_attention_calls(hlo)
+        assert _step_sha1(hlo) == _WINDOW_BYPASSED["mimo_v2_flash prefill"]
         return
     assert _step_sha1(hlo) == _HELD_SHARE_STEPS["mimo_v2_flash"]
     # the rule, asked as the operator asks it: pools of different width
@@ -1074,9 +1118,10 @@ def test_laguna_serving_programs_compile_for_the_chip(v5e, program):
     pool pair (18,432, 16, 1,024) for a full layer and two rings (32, 8, 512,
     128) for a window layer, in layer order, each updated in place; a full
     layer's admission is ONE call of the blockwise kernel at a group of 6
-    (no float32 buffer of 48 x 8,192 x 8,192), a window layer's a BAND of
-    512-wide blocks at 72 heads, a run of blocks at a time (the 2.4 GB of a
-    layer's scores never whole); the step's two full layers read through the
+    (no float32 buffer of 48 x 8,192 x 8,192), a window layer's ONE call of
+    the same kernel under the window at a group of 9 (no band: no float32
+    copy of its operands, no score written); the step's two full layers read
+    through the
     kernel that walks the page table at a (48, 1,024) block-diagonal query
     and re-lay no pool; both graphs keep the grouped matmul over the 64 held
     experts and report the load of all 256 last; and everything fits beside
@@ -1084,8 +1129,8 @@ def test_laguna_serving_programs_compile_for_the_chip(v5e, program):
     from types import SimpleNamespace
 
     from mxnet_tpu.models import transformer as tf
-    from mxnet_tpu.ops.attention import (_band_block, attention_form,
-                                         pool_read_form, pool_shape)
+    from mxnet_tpu.ops.attention import (attention_form, pool_read_form,
+                                         pool_shape)
     from mxnet_tpu.serving.kv_decode import _AdmitScatter
 
     lanes, max_len, bucket, page = 32, 9216, 8192, 16
@@ -1153,17 +1198,27 @@ def test_laguna_serving_programs_compile_for_the_chip(v5e, program):
         heads = lambda n: struct((1, n, bucket, 128), "bfloat16")
         assert attention_form(heads(48), heads(8), heads(8), True) == "kernel"
         assert attention_form(heads(72), heads(8), heads(8), True,
-                              512) == "band"
-        assert _band_block(bucket, 512) == 512
+                              512) == "window_kernel"
+        assert pa.blocks(bucket, bucket, 9, 128, 128, jnp.bfloat16,
+                         window=512) == (64, 1024)
         _assert_attention_is_blockwise(hlo, 2, bucket)
-        # a window layer's band a run of blocks at a time: four loops (and
-        # one an expert layer: the turns past the first over the held rows'
-        # chunks), and no float32 buffer of a layer's 72 x 8,192 x 1,024
-        # scores
-        assert hlo.count(" while(") == 4 + 5
-        made = [math.prod(int(d) for d in dims.split(",") if d)
+        # a window layer's admission is ONE call of the same kernel under
+        # the window, 72 heads folded nine to a key/value head: no band, so
+        # no loop but an expert layer's (the turns past the first over the
+        # held rows' chunks), and no float32 copy of a window layer's
+        # keys and values in the band's blocks, let alone its 72 x 8,192 x
+        # 1,024 scores
+        calls = _window_attention_calls(hlo)
+        assert len(calls) == 4
+        for i in (1, 2, 3, 5):
+            assert sum("layer%d_att/" % i in line for line in calls) == 1
+        assert hlo.count(" while(") == 5
+        made = [[int(d) for d in dims.split(",") if d]
                 for dims in re.findall(r"f32\[([\d,]+)\]", hlo)]
-        assert max(made) < 72 * bucket * 1024
+        assert max(math.prod(d) for d in made) < 72 * bucket * 1024
+        # (the band's blocks of 512: float32 operands (.., 512, 128) and
+        # scores (.., 512, 1,024))
+        assert not [d for d in made if d[-2:] in ([512, 128], [512, 1024])]
         _assert_one_row_of_logits(compiled, bucket, 25088)
         # PR 58's program, every assignment's rows in it: 3,344,669,696;
         # with the weights' 7.36 GB and the cache's 2.68, 13.4 of 16
@@ -1247,6 +1302,19 @@ _HELD_SHARE_STEPS = {
     "dots3_note": "e4bd38401d088c717ca0415e0f412a51f74542f1",
     "laguna": "f90cac47c167857d4fd32a1bccc3a1451b23ce69",
     "nemotron_h": "823ea1fae773a557dc5e121d4abea8eb73defcf7",
+}
+
+
+# what the window inside the blockwise kernel (PR 60) must leave
+# instruction for instruction, as the tests above compile them, at PR 59's
+# tree (e2c981b): mimo's PREFILL, whose window layers carry a sink and stay
+# XLA's band, and phi4flash's DECODE program (a step reads rings, never
+# ``MultiHeadAttention`` under a window; mimo's, dots3's and laguna's steps
+# are ``_HELD_SHARE_STEPS``'). A PR that moves these programs on purpose
+# writes its own lines here.
+_WINDOW_BYPASSED = {
+    "mimo_v2_flash prefill": "8cced02a9c8063ce8ae67d5ccff6670190335341",
+    "phi4flash decode": "158c0fee5e91eddd7ed8c558214114c106b6a33c",
 }
 
 
@@ -1354,7 +1422,8 @@ def test_phi4flash_serving_programs_compile_for_the_chip(v5e, program):
     KERNEL that walks the page table EIGHT times (layer 17 and the seven
     cross layers, 40 query heads over 10 key/value heads of 128) and writes
     it once; the prefill's logits are ONE row, its nine scans are loops that
-    never make a state history, its window layers score a band; and
+    never make a state history, its window layers run the blockwise kernel
+    under the window (no band of scores); and
     everything fits beside 7.7 GB of weights: 11.96 GB of arguments to a
     step, as the configuration's arithmetic says."""
     from types import SimpleNamespace
@@ -1425,14 +1494,20 @@ def test_phi4flash_serving_programs_compile_for_the_chip(v5e, program):
              for _n, dims, op, _a in _INSTRUCTION.findall(hlo)
              if op != "parameter"]
     if program == "prefill":
-        # nine scans, each a loop; the largest thing made is a window
-        # layer's band of float32 scores, 40 x 2,048 x 1,024, an eighth of
-        # what a state history (2,048 x 16 x 5,120) or full scores would be
+        # nine scans, each a loop; a window layer is ONE call of the
+        # blockwise kernel under the window (40 query heads over 10 of 128),
+        # so nothing made is as large as a band of float32 scores, 40 x
+        # 2,048 x 1,024, was: an eighth of what a state history (2,048 x 16
+        # x 5,120) or full scores would be
         assert hlo.count(" while(") == 9
+        calls = _window_attention_calls(hlo)
+        assert len(calls) == 8 and not _flash_attention_calls(hlo)
+        for i in range(1, 16, 2):
+            assert sum("layer%d_self_att/" % i in line for line in calls) == 1
         # (the tied head over ONE row is a multiply and a sum inside a
         # fusion: the table's own size is listed and never made)
         assert max(n for n, _ in found if n != 200064 * 2560) \
-            <= 40 * bucket * 1024
+            < 40 * bucket * 1024
         assert not _paged_read_calls(hlo)
         assert mem.temp_size_in_bytes < 1 << 30
         return
@@ -1458,6 +1533,7 @@ def test_phi4flash_serving_programs_compile_for_the_chip(v5e, program):
     assert mem.alias_size_in_bytes == cache_bytes
     assert mem.argument_size_in_bytes < 11_970_000_000
     assert mem.temp_size_in_bytes < 128 << 20
+    assert _step_sha1(hlo) == _WINDOW_BYPASSED["phi4flash decode"]
 
 
 def test_the_page_walk_at_forty_query_heads_over_ten_of_128(v5e):
@@ -1713,6 +1789,11 @@ def test_dots3_note_serving_programs_compile_for_the_chip(v5e, program):
         # 128 queries at a time, and nothing is a query block's float32
         # scores of 128 heads (``f32[1,128,1,128,<keys>]``)
         assert len(_flash_attention_calls(hlo)) == 3
+        # a window layer's (64 heads, a key of 256 over a value of 128, a
+        # window of 513 the bucket is no multiple of) ONE call of the same
+        # kernel under the window
+        calls = _window_attention_calls(hlo)
+        assert len(calls) == 3
         dims = lambda kind: [[int(d) for d in found.split(",") if int(d) != 1]
                              for found in re.findall(kind + r"\[([\d,]+)\]",
                                                      hlo)]

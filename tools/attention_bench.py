@@ -22,6 +22,17 @@ on top of them, and the masked attention, in XLA's query blocks
 over the same operands (``PERF.md`` section 6, PR 53).
 
     python tools/attention_bench.py --topk
+
+``--window`` instead times ONE window layer of an admission standing alone,
+XLA's band (``_band_attention``) against the blockwise kernel under the
+window (``attention_form``'s ``"window_kernel"``), at the cells' shapes
+(``WINDOW_LAYERS``): the kernel at the blocks ``pallas_attention.blocks``
+names and, with ``--sweep``, at every tiling beside them. A form is timed as
+a CHAIN of four layers in one program, each layer's output the next one's
+query (or value, or the query's first columns, where the widths differ), so
+that nothing is hoisted or overlapped (``PERF.md`` section 6, PR 60).
+
+    python tools/attention_bench.py --window [--sweep]
 """
 import argparse
 import json
@@ -45,6 +56,109 @@ SHAPES = [
 # dots3-note-prev's full layer at the cell's bucket: (heads, T, key width,
 # value width, index heads, index width, topk)
 SPARSE_LAYER = (128, 8192, 192, 128, 64, 128, 2048)
+
+
+# a window layer at its cell's bucket: (heads, key/value heads, T, key width,
+# value width, window). mimo's carries a sink in its cell and stays XLA's
+# there; it stands here without one, for the rule's threshold
+WINDOW_LAYERS = {
+    "laguna-s-2.1": (72, 8, 8192, 128, 128, 512),
+    "dots3-note-prev": (64, 64, 8192, 256, 128, 513),
+    "phi-4-mini-flash-reasoning": (40, 10, 2048, 128, 128, 512),
+    "mimo-v2-flash (no sink)": (64, 8, 2048, 192, 128, 128),
+    # smaller bands, for where the rule's threshold falls (no cell's)
+    "probe 32 MiB": (16, 16, 2048, 64, 64, 128),
+    "probe 64 MiB": (32, 8, 2048, 128, 128, 128),
+    "probe 256 MiB": (32, 8, 4096, 128, 128, 256),
+}
+_CHAIN = 4
+
+
+def window_layers(args):
+    """One JSON line a shape: a layer's milliseconds as XLA's band and as the
+    kernel (medians of ``--iters`` runs of a chain of ``_CHAIN`` layers, timed
+    to ``block_until_ready``, over ``_CHAIN``), the kernel's worst difference
+    from the band over the output's largest magnitude and, with ``--sweep``,
+    every other tiling's milliseconds."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops import attention as attn_op
+    from mxnet_tpu.ops import pallas_attention as pa
+
+    dt = jnp.dtype(args.dtype)
+    dev = jax.devices()[0]
+    on_tpu = dev.platform == "tpu"
+    layers = {"quick": (4, 2, 128, 16, 8, 32)} if args.quick \
+        else WINDOW_LAYERS
+    rs = np.random.RandomState(0)
+
+    def chain_ms(layer, q, k, v, n):
+        def chain(q, k, v):
+            for _ in range(n):
+                out = layer(q, k, v)
+                if out.shape == q.shape:
+                    q = out
+                elif out.shape == v.shape:
+                    v = out
+                else:   # a value narrower than the key over grouped heads
+                    q = jnp.concatenate(
+                        [out, q[..., out.shape[-1]:]], axis=-1)
+            return out
+
+        fn = jax.jit(chain)
+        jax.block_until_ready(fn(q, k, v))
+        times = []
+        for _ in range(args.iters):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(q, k, v))
+            times.append((time.perf_counter() - t0) * 1e3)
+        return round(float(np.median(times)) / n, 3)
+
+    for name, (h, hkv, t, dk, dv, w) in layers.items():
+        if args.only and args.only not in name:
+            continue
+        draw = lambda *shape: jnp.asarray(
+            rs.randn(*shape).astype("float32"), dt)
+        q, k, v = draw(1, h, t, dk), draw(1, hkv, t, dk), draw(1, hkv, t, dv)
+        scale = dk ** -0.5
+        band = lambda q, k, v: attn_op._xla_attention(
+            q, k, v, None, True, w, scale)
+        kernel = lambda bq, bk: lambda q, k, v: pa.flash_attention(
+            q, k, v, causal=True, scale=scale, window=w, block_q=bq,
+            block_k=bk, interpret=not on_tpu)
+        ruled = pa.blocks(t, t, h // hkv, dk, dv, dt, False, w)
+        rec = {"layer": name, "device": dev.device_kind, "dtype": str(dt),
+               "heads": [h, hkv], "T": t, "widths": [dk, dv], "window": w,
+               "iters": args.iters, "blocks": ruled,
+               "band_block": attn_op._band_block(t, w),
+               "takes": bool(pa.takes(q, k, v, window=w))}
+        want = np.asarray(jax.jit(band)(q, k, v), np.float32)
+        got = np.asarray(jax.jit(kernel(*ruled))(q, k, v), np.float32)
+        rec["max_err_over_max"] = float(np.abs(got - want).max()
+                                        / np.abs(want).max())
+        n = 1 if args.quick else _CHAIN
+        rec["band_ms"] = chain_ms(band, q, k, v, n)
+        rec["kernel_ms"] = chain_ms(kernel(*ruled), q, k, v, n)
+        if args.sweep:
+            g, unit = h // hkv, 32 // dt.itemsize
+            rec["sweep"] = {}
+            for bq in (16, 32, 64, 128, 256, 512, 1024):
+                for bk in (128, 256, 512, 1024):
+                    if (bq, bk) == tuple(ruled) or bq % unit or t % bq \
+                            or t % bk or not 256 <= g * bq <= 2048 \
+                            or pa.block_bytes(bq, bk, g, dk, dv, dt) \
+                            > pa._VMEM_BUDGET:
+                        continue
+                    try:
+                        rec["sweep"]["%dx%d" % (bq, bk)] = [
+                            chain_ms(kernel(bq, bk), q, k, v, n),
+                            pa.window_key_blocks(t, t, bq, bk, w)]
+                    except Exception as exc:   # a tiling Mosaic refuses
+                        rec["sweep"]["%dx%d" % (bq, bk)] = "%s: %s" % (
+                            type(exc).__name__, str(exc)[:120])
+        print(json.dumps(rec), flush=True)
 
 
 def sparse_split(args):
@@ -131,9 +245,18 @@ def main():
     ap.add_argument("--topk", action="store_true",
                     help="split one full layer under a learned selection "
                          "(dots3-note-prev's shapes) instead")
+    ap.add_argument("--window", action="store_true",
+                    help="time one window layer, XLA's band against the "
+                         "kernel under the window (the cells' shapes) instead")
+    ap.add_argument("--sweep", action="store_true",
+                    help="with --window: every tiling beside the rule's")
+    ap.add_argument("--only", default="",
+                    help="with --window: the layers whose name holds this")
     args = ap.parse_args()
     if args.topk:
         return sparse_split(args)
+    if args.window:
+        return window_layers(args)
     shapes = [(2, 2, 128, 64, True)] if args.quick else SHAPES
 
     import jax
