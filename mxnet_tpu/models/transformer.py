@@ -13,7 +13,7 @@ from ..ops.attention import _LANES, _NEG, pool_paged
 
 ARCHS = ("vaswani", "olmoe", "granite_hybrid", "deepseek_v3", "lfm2_moe",
          "mimo_v2_flash", "phi4flash", "nemotron_h", "dots3_note", "ouro",
-         "laguna")
+         "laguna", "longcat_flash")
 
 
 # the block every graph is derived from (ROADMAP D2); the others have the
@@ -374,6 +374,13 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     its attention ``_window_prefill_attend``'s as ``mimo_v2_flash``'s is.
     After the logits come every layer's K (rotated) and V in
     ``decode_cache`` order, then ``moe_load``.
+
+    ``arch="longcat_flash"`` builds layers of TWO latent attentions, two
+    dense MLPs and one shortcut-connected expert layer whose router also
+    chooses among zero-compute experts (``_longcat_layer``), the attentions
+    MATERIALISED as ``deepseek_v3``'s. After the logits come TWO tensors a
+    layer, sublayer 2i's and 2i + 1's [c | k_r] (B, 1, P, kv_lora_rank +
+    qk_rope_head_dim); then ``moe_load (layers, experts + zero experts)``.
     """
     builders = {"olmoe": _olmoe_prefill_symbol,
                 "granite_hybrid": _granite_prefill_symbol,
@@ -384,7 +391,8 @@ def get_prefill_symbol(vocab_size=32000, num_layers=6, num_heads=8,
                 "nemotron_h": _nemotron_h_prefill_symbol,
                 "dots3_note": _dots3_prefill_symbol,
                 "ouro": _ouro_prefill_symbol,
-                "laguna": _laguna_prefill_symbol}
+                "laguna": _laguna_prefill_symbol,
+                "longcat_flash": _longcat_prefill_symbol}
     if arch in builders:
         return builders[arch](
             vocab_size=vocab_size, num_layers=num_layers,
@@ -636,6 +644,11 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
     same ``num_kv_heads`` in both), a full layer's query ``num_heads`` wide
     and a window layer's ``swa_num_heads``; ``moe_load`` last.
 
+    ``arch="longcat_flash"`` runs ``_longcat_layer`` ABSORBED over TWO pools
+    a layer, ``kv_c_<2i>`` and ``kv_c_<2i + 1>`` (``deepseek_v3``'s row, one
+    a sublayer); after the cache outputs and the token head comes
+    ``moe_load (layers, experts + zero experts)``.
+
     ``page_size`` is the decoder's (``PagedKVDecoder``'s default here); it
     must divide ``max_len``.
     """
@@ -648,7 +661,8 @@ def get_decode_symbol(vocab_size=32000, num_layers=6, num_heads=8,
                 "nemotron_h": _nemotron_h_decode_symbol,
                 "dots3_note": _dots3_decode_symbol,
                 "ouro": _ouro_decode_symbol,
-                "laguna": _laguna_decode_symbol}
+                "laguna": _laguna_decode_symbol,
+                "longcat_flash": _longcat_decode_symbol}
     if arch in builders:
         return builders[arch](
             vocab_size=vocab_size, num_layers=num_layers,
@@ -1290,43 +1304,86 @@ def _sigmoid_experts(h, name, block):
         name="%s_moe" % name, **share)
 
 
+def _latent_operands(h, fc, name, positions, seq_len, eps, *, heads, nope,
+                     rope, latent, rope_theta, q_rank=None, rho_q=None,
+                     rho_kv=None, beside=None):
+    """Latent attention's operands of the normed h (B, T, M), the ONE copy
+    ``deepseek_v3``, ``dots3_note`` (both kinds of layer) and
+    ``longcat_flash`` (both sublayers) build from: ``((q_nope (B, H, T, nope),
+    q_rope (B, H, T, rope), c (B, T, latent), k_r (B, 1, T, rope)),
+    beside(c_q))``, the two rotary parts rotated over interleaved pairs at
+    ``rope_theta``: what an ``attend`` closure takes, materialised in a
+    prefill and absorbed in a step.
+
+    ``fc(data, width, tag)`` is the layer's bias-free projection
+    ``<name>_<tag>``. The query is ONE projection ``<name>_q`` to H heads of
+    [q_nope | q_rope], or with ``q_rank`` a LOW RANK: ``c_q =
+    RMSNorm(W_qa h)`` (``<name>_qa``, ``<name>_qnorm``) times ``rho_q`` where
+    given, then ``<name>_qb`` (the factor reaches both parts of every head).
+    ``[c | k_r] = W_kva h``; ``c = RMSNorm(c)`` (``<name>_kvnorm``) times
+    ``rho_kv`` where given, so the factor reaches every head's key AND value
+    through ``<name>_kvb`` and the cache keeps the scaled latent; the ONE
+    ``k_r`` every head shares is not scaled. The defaults (no low rank, no
+    factor) are ``deepseek_v3``'s and add no node. ``beside(c_q)`` builds
+    what else reads the query latent (``dots3_note``'s indexer), after k_r
+    and before the query is cut and rotated: the nodes' order is part of a
+    graph's JSON."""
+    rotate = lambda a, tag: sym.RotaryEmbedding(
+        a, positions, base=rope_theta, name="%s_%s" % (name, tag),
+        interleaved=True)
+    c_q = None
+    if q_rank:
+        c_q = sym.RMSNorm(fc(h, q_rank, "qa"), eps=eps,
+                          name="%s_qnorm" % name)
+        if rho_q is not None:
+            c_q = c_q * rho_q
+    q = _split_heads(fc(h, heads * (nope + rope), "q") if c_q is None
+                     else fc(c_q, heads * (nope + rope), "qb"),
+                     seq_len, heads, nope + rope)
+    kva = fc(h, latent + rope, "kva")
+    c = sym.RMSNorm(sym.slice_axis(kva, axis=2, begin=0, end=latent),
+                    eps=eps, name="%s_kvnorm" % name)
+    if rho_kv is not None:
+        c = c * rho_kv
+    k_r = sym.Reshape(sym.slice_axis(kva, axis=2, begin=latent,
+                                     end=latent + rope),
+                      shape=(-1, 1, seq_len, rope))
+    extra = beside(c_q) if beside else None
+    return (sym.slice_axis(q, axis=3, begin=0, end=nope),
+            rotate(sym.slice_axis(q, axis=3, begin=nope, end=nope + rope),
+                   "qrope"),
+            c, rotate(k_r, "krope")), extra
+
+
 def _deepseek_v3_layer(x, i, positions, seq_len, attend, block):
     """One ``model_type: deepseek_v3`` block (no query-side low-rank
     projection) on x (B, T, M) -> (x', load (E,) or None for a dense layer).
 
-    Latent attention: a bias-free query projection to H heads of
-    [q_nope | q_rope]; ONE bias-free projection to [c | k_r], the latent
-    (normed, ``kvnorm``) and a single rotary key every head shares; rotary
-    positions over interleaved pairs on q_rope and k_r. ``attend(i, q_nope,
-    q_rope, c, k_r)`` is the one thing the prefill and the decode graph do
-    differently: it takes (B, H, T, nope), (B, H, T, rope), (B, T, latent)
-    and (B, 1, T, rope) and returns attention's (B, H, T, v_dim) output,
-    through ``layer<i>_kvb_weight`` (H x [k_nope | v] rows over the latent)
-    either materialised into keys and values or absorbed into the query and
-    the output. Then the feed-forward: the gated SiLU MLP in the first
-    ``first_dense_layers`` layers; after them sigmoid-routed experts
-    (selected on the biased score, weighted by the unbiased one,
-    renormalised, scaled) beside a shared gated MLP every token takes."""
+    Latent attention (``_latent_operands`` at its defaults): a bias-free
+    query projection to H heads of [q_nope | q_rope]; ONE bias-free
+    projection to [c | k_r], the latent (normed, ``kvnorm``) and a single
+    rotary key every head shares; rotary positions over interleaved pairs on
+    q_rope and k_r. ``attend(i, q_nope, q_rope, c, k_r)`` is the one thing
+    the prefill and the decode graph do differently: it takes (B, H, T,
+    nope), (B, H, T, rope), (B, T, latent) and (B, 1, T, rope) and returns
+    attention's (B, H, T, v_dim) output, through ``layer<i>_kvb_weight`` (H x
+    [k_nope | v] rows over the latent) either materialised into keys and
+    values or absorbed into the query and the output. Then the feed-forward:
+    the gated SiLU MLP in the first ``first_dense_layers`` layers; after them
+    sigmoid-routed experts (selected on the biased score, weighted by the
+    unbiased one, renormalised, scaled) beside a shared gated MLP every
+    token takes."""
     name = "layer%d" % i
     d, eps, hq = block["model_dim"], block["rms_eps"], block["num_heads"]
-    nope, rope, lat = block["nope"], block["rope"], block["latent"]
     fc = lambda data, width, tag: sym.FullyConnected(
         data=data, num_hidden=width, no_bias=True, flatten=False,
         name="%s_%s" % (name, tag))
-    rotate = lambda a, tag: sym.RotaryEmbedding(
-        a, positions, base=block["rope_theta"], interleaved=True,
-        name="%s_%s" % (name, tag))
     h = sym.RMSNorm(x, eps=eps, name="%s_ln1" % name)
-    q = _split_heads(fc(h, hq * (nope + rope), "q"), seq_len, hq, nope + rope)
-    kva = fc(h, lat + rope, "kva")
-    c = sym.RMSNorm(sym.slice_axis(kva, axis=2, begin=0, end=lat), eps=eps,
-                    name="%s_kvnorm" % name)
-    k_r = sym.Reshape(sym.slice_axis(kva, axis=2, begin=lat, end=lat + rope),
-                      shape=(-1, 1, seq_len, rope))
-    att = attend(i, sym.slice_axis(q, axis=3, begin=0, end=nope),
-                 rotate(sym.slice_axis(q, axis=3, begin=nope,
-                                       end=nope + rope), "qrope"),
-                 c, rotate(k_r, "krope"))
+    operands, _ = _latent_operands(
+        h, fc, name, positions, seq_len, eps, heads=hq, nope=block["nope"],
+        rope=block["rope"], latent=block["latent"],
+        rope_theta=block["rope_theta"])
+    att = attend(i, *operands)
     x = x + fc(_merge_heads(att, seq_len, hq * block["v_dim"]), d, "proj")
     return _deepseek_v3_ffn(x, i, fc, seq_len, block)
 
@@ -1426,8 +1483,11 @@ def _absorbed(i, q_nope, q_rope, read, hq, nope, rope, v_dim, lat):
                        shape=(-1, hq, 1, v_dim))
 
 
-def _deepseek_v3_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
-    block = _deepseek_v3_sizes(num_layers, **sizes)
+def _latent_prefill_symbol(block, layer, vocab_size, prefill_len):
+    """The prefill graph of a stack whose every attention is latent and
+    keeps ONE pool (``deepseek_v3``; ``longcat_flash``, two a layer), its
+    keys and values MATERIALISED: ``attend`` is called once an attention, in
+    the cache's order, with that attention's index."""
     hq, nope, v_dim, lat = (block[k] for k in ("num_heads", "nope", "v_dim",
                                                "latent"))
     positions = sym.Reshape(sym._arange(start=0, stop=prefill_len),
@@ -1443,14 +1503,15 @@ def _deepseek_v3_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
             **operands)
 
     logits, load = _deepseek_v3_stack(vocab_size, prefill_len, positions,
-                                      attend, block,
+                                      attend, block, layer=layer,
                                       length=sym.Variable("length"))
     return sym.Group([logits] + cache + load)
 
 
-def _deepseek_v3_decode_symbol(vocab_size, num_layers, num_slots, page_size,
-                               token_out=True, **sizes):
-    block = _deepseek_v3_sizes(num_layers, **sizes)
+def _latent_decode_symbol(block, layer, vocab_size, num_slots, page_size,
+                          token_out):
+    """``_latent_prefill_symbol``'s stack one token a lane, its attentions
+    ABSORBED over their pools ``kv_c_<i>``."""
     hq, nope, rope, v_dim, lat = (block[k] for k in (
         "num_heads", "nope", "rope", "v_dim", "latent"))
     pos_idx = sym.Variable("pos_idx")
@@ -1470,28 +1531,182 @@ def _deepseek_v3_decode_symbol(vocab_size, num_layers, num_slots, page_size,
                 name="layer%d_att" % i, **read),
             hq, nope, rope, v_dim, lat)
 
-    logits, load = _deepseek_v3_stack(vocab_size, 1, pos_idx, attend, block)
+    logits, load = _deepseek_v3_stack(vocab_size, 1, pos_idx, attend, block,
+                                      layer=layer)
     outs = [logits] + cache
     if token_out:
         outs.append(sym.argmax(logits, axis=-1, name="greedy_token"))
     return sym.Group(outs + load)
 
 
+def _deepseek_v3_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
+    return _latent_prefill_symbol(_deepseek_v3_sizes(num_layers, **sizes),
+                                  None, vocab_size, prefill_len)
+
+
+def _deepseek_v3_decode_symbol(vocab_size, num_layers, num_slots, page_size,
+                               token_out=True, **sizes):
+    return _latent_decode_symbol(_deepseek_v3_sizes(num_layers, **sizes),
+                                 None, vocab_size, num_slots, page_size,
+                                 token_out)
+
+
+def _latent_param_shapes(n, d, *, heads, nope, rope, v_dim, latent,
+                         q_rank=None):
+    """{name: shape} of what ``_latent_operands`` and the two forms of its
+    ``attend`` load under the prefix ``n``, with the two norms around the
+    attention and its output projection."""
+    query = {n + "q_weight": (heads * (nope + rope), d)} if not q_rank else {
+        n + "qa_weight": (q_rank, d), n + "qnorm_gamma": (q_rank,),
+        n + "qb_weight": (heads * (nope + rope), q_rank)}
+    return dict(query, **{
+        n + "ln1_gamma": (d,), n + "kva_weight": (latent + rope, d),
+        n + "kvnorm_gamma": (latent,),
+        n + "kvb_weight": (heads * (nope + v_dim), latent),
+        n + "proj_weight": (d, heads * v_dim), n + "ln2_gamma": (d,)})
+
+
 def _deepseek_v3_param_shapes(vocab_size, num_layers, **sizes):
     block = _deepseek_v3_sizes(num_layers, **sizes)
-    d, hq = block["model_dim"], block["num_heads"]
-    nope, rope, v_dim, lat = (block[k] for k in ("nope", "rope", "v_dim",
-                                                 "latent"))
+    d = block["model_dim"]
     shapes = {"embed_weight": (vocab_size, d), "final_ln_gamma": (d,),
               "lm_head_weight": (vocab_size, d)}
     for i in range(num_layers):
-        n = "layer%d_" % i
-        shapes.update({
-            n + "ln1_gamma": (d,), n + "q_weight": (hq * (nope + rope), d),
-            n + "kva_weight": (lat + rope, d), n + "kvnorm_gamma": (lat,),
-            n + "kvb_weight": (hq * (nope + v_dim), lat),
-            n + "proj_weight": (d, hq * v_dim), n + "ln2_gamma": (d,)})
+        shapes.update(_latent_param_shapes(
+            "layer%d_" % i, d, heads=block["num_heads"], nope=block["nope"],
+            rope=block["rope"], v_dim=block["v_dim"],
+            latent=block["latent"]))
         shapes.update(_deepseek_v3_ffn_shapes(block, i))
+    return shapes
+
+
+# ---- LongCat-Flash (two latent attentions, two MLPs, a shortcut expert layer)
+def _longcat_sizes(num_layers, num_heads, model_dim, ffn_dim=None,
+                   moe_ffn_dim=None, num_experts=512, num_zero_experts=256,
+                   num_experts_per_tok=12, num_local_experts=0,
+                   local_expert_offset=0, q_lora_rank=1536, kv_lora_rank=512,
+                   qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+                   rope_theta=1e7, rms_eps=1e-5, routed_scaling_factor=6.0,
+                   **kwargs):
+    """``_longcat_layer``'s keywords from a builder's (``ffn_dim`` is a dense
+    MLP's width, ``moe_ffn_dim`` one expert's; ``num_experts`` experts have
+    weights and ``num_zero_experts`` more router outputs are the identity;
+    ``mla_scale_q_lora`` and ``mla_scale_kv_lora``: the factors
+    ``sqrt(model_dim / rank)`` go on both normed latents). Keywords of the
+    other architectures are dropped."""
+    if qk_rope_head_dim % 2:
+        raise MXNetError("longcat_flash: qk_rope_head_dim %d is odd"
+                         % qk_rope_head_dim)
+    rho = lambda rank: math.sqrt(model_dim / rank)
+    return dict(
+        num_layers=num_layers, num_heads=num_heads, model_dim=model_dim,
+        ffn_dim=ffn_dim, moe_ffn_dim=moe_ffn_dim, num_experts=num_experts,
+        num_zero_experts=int(num_zero_experts),
+        num_experts_per_tok=num_experts_per_tok,
+        num_local_experts=int(num_local_experts),
+        local_expert_offset=int(local_expert_offset),
+        q_rank=q_lora_rank, nope=qk_nope_head_dim, rope=qk_rope_head_dim,
+        v_dim=v_head_dim, latent=kv_lora_rank, rope_theta=float(rope_theta),
+        rho_q=rho(q_lora_rank), rho_kv=rho(kv_lora_rank), rms_eps=rms_eps,
+        routed_scaling_factor=float(routed_scaling_factor),
+        scale=float(qk_nope_head_dim + qk_rope_head_dim) ** -0.5)
+
+
+def _longcat_layer(x, i, positions, seq_len, attend, block):
+    """One ``model_type: longcat_flash`` layer on x (B, T, M) -> (x', load
+    (E + Z,)): TWO sublayers of a latent attention and a dense gated SiLU
+    MLP each, and ONE expert layer that reads the first sublayer's
+    post-attention norm and joins the residual stream only after the second
+    sublayer's MLP (the shortcut-connected expert layer: the second
+    attention and both MLPs lie between the router and the sum):
+
+        for s in (0, 1):
+            x = x + MLA_s(RMSNorm(x));  h = RMSNorm(x)
+            if s == 0: m = MoE(h)
+            x = x + MLP_s(h)
+        x = x + m
+
+    Names count SUBLAYERS: sublayer s of layer i is ``layer<2i + s>_*``
+    (``ln1``, ``qa``, ``qnorm``, ``qb``, ``kva``, ``kvnorm``, ``kvb``,
+    ``proj``, ``ln2``, ``mlp_in``, ``mlp_out``), its pool ``kv_c_<2i + s>``,
+    and ``attend`` is called with that index; the expert layer's weights are
+    its first sublayer's, ``layer<2i>_router_*`` and ``layer<2i>_experts_*``.
+    The attention is ``_latent_operands`` with a low-rank query and both
+    factors. The experts: softmax scores over ``num_experts +
+    num_zero_experts`` router outputs, chosen on the score plus the
+    selection bias, weighted by the unbiased score NOT renormalised, times
+    ``routed_scaling_factor``; a zero-compute expert is the identity
+    (``MoEFeedForward(num_zero_experts=)``); no shared expert."""
+    d, eps, hq = block["model_dim"], block["rms_eps"], block["num_heads"]
+    moe = None
+    for j in (2 * i, 2 * i + 1):
+        name = "layer%d" % j
+        fc = lambda data, width, tag, name=name: sym.FullyConnected(
+            data=data, num_hidden=width, no_bias=True, flatten=False,
+            name="%s_%s" % (name, tag))
+        h = sym.RMSNorm(x, eps=eps, name="%s_ln1" % name)
+        operands, _ = _latent_operands(
+            h, fc, name, positions, seq_len, eps, heads=hq, nope=block["nope"],
+            rope=block["rope"], latent=block["latent"],
+            rope_theta=block["rope_theta"], q_rank=block["q_rank"],
+            rho_q=block["rho_q"], rho_kv=block["rho_kv"])
+        att = attend(j, *operands)
+        x = x + fc(_merge_heads(att, seq_len, hq * block["v_dim"]), d, "proj")
+        h = sym.RMSNorm(x, eps=eps, name="%s_ln2" % name)
+        if moe is None:
+            share = {k: block[k] for k in ("num_local_experts",
+                                           "local_expert_offset")
+                     if block["num_local_experts"]}
+            moe = sym.MoEFeedForward(
+                sym.Reshape(h, shape=(-1, d)),
+                *(sym.Variable("%s_%s" % (name, w)) for w in (
+                    "router_weight", "experts_gate_weight",
+                    "experts_up_weight", "experts_down_weight",
+                    "router_bias")),
+                num_experts=block["num_experts"],
+                num_zero_experts=block["num_zero_experts"],
+                num_hidden=block["moe_ffn_dim"],
+                num_experts_per_tok=block["num_experts_per_tok"],
+                scoring="softmax", router_bias=True,
+                routed_scaling_factor=block["routed_scaling_factor"],
+                name="%s_moe" % name, **share)
+        x = x + _gated_mlp(fc, h, block["ffn_dim"], d, "mlp")
+    return x + sym.Reshape(moe[0], shape=(-1, seq_len, d)), moe[1]
+
+
+def _longcat_prefill_symbol(vocab_size, num_layers, prefill_len, **sizes):
+    return _latent_prefill_symbol(_longcat_sizes(num_layers, **sizes),
+                                  _longcat_layer, vocab_size, prefill_len)
+
+
+def _longcat_decode_symbol(vocab_size, num_layers, num_slots, page_size,
+                           token_out=True, **sizes):
+    return _latent_decode_symbol(_longcat_sizes(num_layers, **sizes),
+                                 _longcat_layer, vocab_size, num_slots,
+                                 page_size, token_out)
+
+
+def _longcat_param_shapes(vocab_size, num_layers, **sizes):
+    block = _longcat_sizes(num_layers, **sizes)
+    d, f, ffn = block["model_dim"], block["moe_ffn_dim"], block["ffn_dim"]
+    routed = block["num_experts"] + block["num_zero_experts"]
+    held = block["num_local_experts"] or block["num_experts"]
+    shapes = {"embed_weight": (vocab_size, d), "final_ln_gamma": (d,),
+              "lm_head_weight": (vocab_size, d)}
+    for j in range(2 * num_layers):
+        n = "layer%d_" % j
+        shapes.update(_latent_param_shapes(
+            n, d, heads=block["num_heads"], nope=block["nope"],
+            rope=block["rope"], v_dim=block["v_dim"],
+            latent=block["latent"], q_rank=block["q_rank"]))
+        shapes.update({n + "mlp_in_weight": (2 * ffn, d),
+                       n + "mlp_out_weight": (d, ffn)})
+        if j % 2 == 0:
+            shapes.update({
+                n + "router_weight": (routed, d), n + "router_bias": (routed,),
+                n + "experts_gate_weight": (held, d, f),
+                n + "experts_up_weight": (held, d, f),
+                n + "experts_down_weight": (held, f, d)})
     return shapes
 
 
@@ -1581,7 +1796,8 @@ def _dots3_layer(x, i, positions, seq_len, attend, block):
     or None for a dense layer). ``layer_types[i]`` names the attention's
     kind, and with it the latent geometry (``_dots3_sizes``).
 
-    Latent attention with a QUERY-side low rank, both kinds: ``c_q = rho_q *
+    Latent attention with a QUERY-side low rank, both kinds
+    (``_latent_operands``): ``c_q = rho_q *
     RMSNorm(W_qa h)``, ``q = W_qb c_q`` -> H heads of [q_nope | q_rope];
     ``[c | k_r] = W_kva h``, ``c = rho_kv * RMSNorm(c)``; rotary positions
     over interleaved pairs on q_rope and the ONE k_r every head shares, at
@@ -1612,30 +1828,24 @@ def _dots3_layer(x, i, positions, seq_len, attend, block):
         a, positions, base=geo["rope_theta"], name="%s_%s" % (name, tag),
         **kw)
     h = sym.RMSNorm(x, eps=eps, name="%s_ln1" % name)
-    c_q = sym.RMSNorm(fc(h, geo["q_rank"], "qa"), eps=eps,
-                      name="%s_qnorm" % name) * geo["rho_q"]
-    q = _split_heads(fc(c_q, hq * (nope + rope), "qb"), seq_len, hq,
-                     nope + rope)
-    kva = fc(h, lat + rope, "kva")
-    c = sym.RMSNorm(sym.slice_axis(kva, axis=2, begin=0, end=lat), eps=eps,
-                    name="%s_kvnorm" % name) * geo["rho_kv"]
-    k_r = sym.Reshape(sym.slice_axis(kva, axis=2, begin=lat, end=lat + rope),
-                      shape=(-1, 1, seq_len, rope))
-    index = None
-    if kind == "full_attention":
+
+    def indexer(c_q):
+        if kind != "full_attention":
+            return None
         hi, di = block["index_heads"], block["index_dim"]
         k_i = _layer_norm_f32(fc(h, di, "ik"), "%s_iknorm" % name,
                               block["dtype"])
-        index = (rotate(_split_heads(fc(c_q, hi * di, "iq"), seq_len, hi,
-                                     di), "iqrope", rotary_dim=rope),
-                 rotate(sym.Reshape(k_i, shape=(-1, 1, seq_len, di)),
-                        "ikrope", rotary_dim=rope),
-                 fc(h, hi, "iw") * float(hi * di) ** -0.5)
-    att = attend(i, sym.slice_axis(q, axis=3, begin=0, end=nope),
-                 rotate(sym.slice_axis(q, axis=3, begin=nope,
-                                       end=nope + rope), "qrope",
-                        interleaved=True),
-                 c, rotate(k_r, "krope", interleaved=True), index)
+        return (rotate(_split_heads(fc(c_q, hi * di, "iq"), seq_len, hi,
+                                    di), "iqrope", rotary_dim=rope),
+                rotate(sym.Reshape(k_i, shape=(-1, 1, seq_len, di)),
+                       "ikrope", rotary_dim=rope),
+                fc(h, hi, "iw") * float(hi * di) ** -0.5)
+
+    operands, index = _latent_operands(
+        h, fc, name, positions, seq_len, eps, heads=hq, nope=nope, rope=rope,
+        latent=lat, rope_theta=geo["rope_theta"], q_rank=geo["q_rank"],
+        rho_q=geo["rho_q"], rho_kv=geo["rho_kv"], beside=indexer)
+    att = attend(i, *operands, index)
     gate = sym.Reshape(sym.sigmoid(fc(h, hq, "gate")),
                        shape=(-1, seq_len, hq, 1))
     att = sym.Reshape(sym.broadcast_mul(
@@ -1745,15 +1955,10 @@ def _dots3_param_shapes(vocab_size, num_layers, **sizes):
               "lm_head_weight": (vocab_size, d)}
     for i, kind in enumerate(block["layer_types"]):
         n, geo = "layer%d_" % i, block[kind]
-        hq, rank, lat, nope, rope, v_dim = (geo[k] for k in (
-            "heads", "q_rank", "latent", "nope", "rope", "v_dim"))
-        shapes.update({
-            n + "ln1_gamma": (d,), n + "ln2_gamma": (d,),
-            n + "qa_weight": (rank, d), n + "qnorm_gamma": (rank,),
-            n + "qb_weight": (hq * (nope + rope), rank),
-            n + "kva_weight": (lat + rope, d), n + "kvnorm_gamma": (lat,),
-            n + "kvb_weight": (hq * (nope + v_dim), lat),
-            n + "gate_weight": (hq, d), n + "proj_weight": (d, hq * v_dim)})
+        rank = geo["q_rank"]
+        shapes.update(_latent_param_shapes(n, d, **{k: geo[k] for k in (
+            "heads", "nope", "rope", "v_dim", "latent", "q_rank")}))
+        shapes[n + "gate_weight"] = (geo["heads"], d)
         if kind == "full_attention":
             shapes.update({
                 n + "iq_weight": (hi * di, rank), n + "ik_weight": (di, d),
@@ -2888,10 +3093,11 @@ def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
     (heads, slots, dh) where it is not (a latent row of 576; a toy model);
     a ``"row"`` is
     addressed by lane, ``shape`` is one lane's and the buffer (lanes,) +
-    shape, float32. Latent attention keeps ONE pool a layer, of one head:
-    the old kind, no new one. Where ``layer_types`` chooses the mixer
-    (``granite_hybrid``, ``lfm2_moe``) the list mixes the two kinds, in layer
-    order; ``nemotron_h``'s expert blocks keep nothing and are skipped. A
+    shape, float32. Latent attention keeps ONE pool an attention, of one
+    head: the old kind, no new one (``longcat_flash``: TWO a layer,
+    ``kv_c_<2i>`` and ``kv_c_<2i + 1>``, one a sublayer). Where
+    ``layer_types`` chooses the mixer (``granite_hybrid``, ``lfm2_moe``) the
+    list mixes the two kinds, in layer order; ``nemotron_h``'s expert blocks keep nothing and are skipped. A
     ``"ring"`` is a WINDOW layer's K or V (``mimo_v2_flash``):
     addressed by lane and position mod the window, ``shape`` is one lane's
     (heads, window, d) and the buffer (lanes,) + shape in the pools' type;
@@ -2941,6 +3147,11 @@ def decode_cache(arch, num_layers, num_heads, model_dim, head_dim=None,
                                    model_dim=model_dim, **sizes)
         return [("kv_c_%d" % i, "pool", (1, block["latent"] + block["rope"]))
                 for i in range(num_layers)]
+    if arch == "longcat_flash":     # a pool a SUBLAYER, two a layer
+        block = _longcat_sizes(num_layers, num_heads=num_heads,
+                               model_dim=model_dim, **sizes)
+        return [("kv_c_%d" % j, "pool", (1, block["latent"] + block["rope"]))
+                for j in range(2 * num_layers)]
     if arch == "dots3_note":
         block = _dots3_sizes(num_layers, num_heads=num_heads,
                              model_dim=model_dim, **sizes)
@@ -3013,14 +3224,15 @@ def param_shapes(arch, vocab_size, num_layers, num_heads, model_dim, ffn_dim,
             vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
             ffn_dim=ffn_dim, num_experts=num_experts, **kwargs)
     if arch in ("lfm2_moe", "mimo_v2_flash", "phi4flash", "nemotron_h",
-                "dots3_note", "ouro", "laguna"):
+                "dots3_note", "ouro", "laguna", "longcat_flash"):
         shapes = {"lfm2_moe": _lfm2_moe_param_shapes,
                   "mimo_v2_flash": _mimo_param_shapes,
                   "phi4flash": _phi4flash_param_shapes,
                   "nemotron_h": _nemotron_h_param_shapes,
                   "dots3_note": _dots3_param_shapes,
                   "ouro": _ouro_param_shapes,
-                  "laguna": _laguna_param_shapes}[arch]
+                  "laguna": _laguna_param_shapes,
+                  "longcat_flash": _longcat_param_shapes}[arch]
         return shapes(
             vocab_size, num_layers, num_heads=num_heads, model_dim=model_dim,
             ffn_dim=ffn_dim, head_dim=head_dim, num_experts=num_experts,

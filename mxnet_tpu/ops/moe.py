@@ -52,8 +52,8 @@ def _moe_out(attrs, inputs):
     traces no expert product: the kernel's form would import Pallas to say
     the same."""
     data = inputs[0]
-    return [(data.shape, data.dtype),
-            ((attrs["num_experts"],), jnp.float32)]
+    routed = attrs["num_experts"] + attrs.get("num_zero_experts", 0)
+    return [(data.shape, data.dtype), ((routed,), jnp.float32)]
 
 
 @register(
@@ -70,6 +70,7 @@ def _moe_out(attrs, inputs):
         "local_expert_offset": AttrSpec("int", default=0),
         "gated": AttrSpec("bool", default=True),
         "activation": AttrSpec("str", default="silu"),
+        "num_zero_experts": AttrSpec("int", default=0),
     },
     input_names=_moe_names,
     num_outputs=2,
@@ -122,11 +123,25 @@ def _moe_feed_forward(attrs, data, router_weight, *weights):
     ``down``, so that F is whole lane tiles; ``num_hidden`` is then the
     stored width): relu(0)^2 = 0, the padding adds exactly nothing.
 
+    ``num_zero_experts`` = Z > 0 makes the router WIDER than the experts:
+    ``router_weight`` is (E + Z, D), ``router_bias`` and ``load`` (E + Z,),
+    and ids E .. E + Z - 1 are ZERO-COMPUTE experts, the identity: an
+    assignment to one adds ``weight * x`` and multiplies nothing. Scores,
+    selection, renormalisation and scaling run over all E + Z, so how many
+    of a token's k are real experts (0 to k) is the routing's; such an
+    assignment belongs to no group of the grouped matmul, sorted past the
+    last one with the absent experts' (``_all_rows``) or left out with them
+    (``_held_rows``, whose chunk is the held share of E + Z). A layer that
+    holds a share computes the identity part for EVERY row all the same (in
+    a deployment a token's own chip adds it): like a shared expert it is
+    counted ONCE when the shares of a layer are summed.
+
     The router's product and softmax run in float32 at the highest matmul
     precision whatever the storage type: one bfloat16 pass flips near-tied
     experts. Ties go to the lower expert index (``jax.lax.top_k``). The
     expert products multiply in the storage type and accumulate in float32."""
     k, n_exp = attrs["num_experts_per_tok"], attrs["num_experts"]
+    n_routed = n_exp + attrs.get("num_zero_experts", 0)
     n_local = attrs.get("num_local_experts", 0) or n_exp
     first = attrs.get("local_expert_offset", 0)
     gated = bool(attrs.get("gated", True))
@@ -163,24 +178,31 @@ def _moe_feed_forward(attrs, data, router_weight, *weights):
         weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + 1e-20)
     if attrs.get("routed_scaling_factor", 1.0) != 1.0:
         weight = weight * attrs["routed_scaling_factor"]
+    identity = None
+    if n_routed > n_exp:
+        # a zero-compute expert is the identity: its weights' sum times x
+        identity = jnp.sum(jnp.where(expert >= n_exp, weight, 0), axis=-1,
+                           keepdims=True) * data.astype(jnp.float32)
     expert = expert.reshape(-1)
     by, held = expert, None
-    if n_local < n_exp:
-        # an assignment to an absent expert keeps its row, sorted past the
-        # last group, and weighs nothing
+    if n_local < n_routed:
+        # an assignment to an absent expert (or to a zero-compute one) keeps
+        # its row, sorted past the last group, and weighs nothing
         held = (expert >= first) & (expert < first + n_local)
         by = jnp.where(held, expert - first, n_local)
         weight = jnp.where(held.reshape(n, k), weight, 0)
     order = jnp.argsort(by, stable=True)                    # rows by expert
-    load = jnp.bincount(expert, length=n_exp).astype(jnp.int32)
+    load = jnp.bincount(expert, length=n_routed).astype(jnp.int32)
     groups = load if held is None else load[first:first + n_local]
-    chunk = held_rows_chunk(n, k, n_local, n_exp)
+    chunk = held_rows_chunk(n, k, n_local, n_routed)
     if chunk:
-        y = _held_rows((k, n_exp, expert_fn), chunk, data, weight, order,
+        y = _held_rows((k, n_routed, expert_fn), chunk, data, weight, order,
                        groups, *first_stacks, down_weight)
     else:
-        y = _all_rows((k, n_exp, expert_fn), data, weight, order, groups,
+        y = _all_rows((k, n_routed, expert_fn), data, weight, order, groups,
                       held, *first_stacks, down_weight)
+    if identity is not None:
+        y = y + identity
     return y.astype(data.dtype), load.astype(jnp.float32)
 
 

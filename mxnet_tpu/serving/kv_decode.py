@@ -393,7 +393,9 @@ def _moe_forms(cache, input_shapes):
         attrs = n.parsed_attrs()
         gated = attrs.get("gated", True)
         tokens, k = data.shape[0], attrs["num_experts_per_tok"]
-        chunk = held_rows_chunk(tokens, k, up.shape[0], attrs["num_experts"])
+        chunk = held_rows_chunk(
+            tokens, k, up.shape[0],
+            attrs["num_experts"] + attrs.get("num_zero_experts", 0))
         rows = jax.ShapeDtypeStruct((chunk or tokens * k, data.shape[1]),
                                     data.dtype)
         forms.append(moe_form(rows, up, ops["down_weight"]))
@@ -1230,6 +1232,20 @@ class PagedKVDecoder:
     position attends (``serving.admit_window_pairs_scored`` / ``_live``, for
     every arch with rings). It refuses what ``mimo_v2_flash`` refuses.
 
+    ``arch="longcat_flash"`` serves layers of TWO latent attentions with a
+    low-rank query, two dense MLPs and ONE shortcut-connected expert layer
+    (``q_lora_rank``, ``kv_lora_rank``, ``qk_nope_head_dim``,
+    ``qk_rope_head_dim``, ``v_head_dim``, ``ffn_dim`` a dense MLP's and
+    ``moe_ffn_dim`` an expert's width, ``num_experts`` with weights and
+    ``num_zero_experts`` more router outputs that are the identity,
+    ``num_local_experts`` held from ``local_expert_offset``): the cache is
+    ``deepseek_v3``'s latent pool, TWO a layer (``kv_c_<2l>``,
+    ``kv_c_<2l + 1>``), all on the lane's one page table, an admission's rows
+    scattered into each. ``serving.moe.zero_assignments`` and
+    ``serving.moe.step_zero_assignments`` count the assignments that
+    multiplied nothing, from the tail of the ``load`` the programs return. It
+    refuses what ``deepseek_v3`` is refused.
+
     ``arch="ouro"`` serves a LOOPED stack: ``num_layers`` layers of sandwich
     norms, rotary attention and a gated MLP applied ``total_ut_steps`` times
     over the same weights (``early_exit_threshold``, ``head_dim``,
@@ -1356,13 +1372,19 @@ class PagedKVDecoder:
             chosen, default=0)
         first = int(arch_sizes.get("local_expert_offset") or 0)
         held = int(arch_sizes.get("num_local_experts") or 0)
-        self._held_experts = slice(first, first + held if held else None)
+        # zero-compute experts (ids past the experts with weights, the
+        # identity) are routed over, held by nobody and counted apart
+        experts = int(cfg.get("num_experts") or 0)
+        zero = int(arch_sizes.get("num_zero_experts") or 0)
+        self._zero_experts = slice(experts, experts + zero)
+        end = first + held if held else experts if zero else None
+        self._held_experts = slice(first, end)
         # the rows of a chunk where an admission's expert layers move their
         # HELD rows alone, by the operator's own rule; 0: every row
         from ..ops.moe import held_rows_chunk
         self._admit_chunk = held_rows_chunk(
             self.prefill_len, int(cfg.get("num_experts_per_tok") or 0),
-            held, int(cfg.get("num_experts") or 0))
+            held, experts + zero)
         if arch != "vaswani":
             # inputs are float32 whatever the weights are, a lane's recurrent
             # state among them; only pools and rings take the weights' type
@@ -1809,6 +1831,8 @@ class PagedKVDecoder:
             # prefill computed (padding included: the grouped matmul's work)
             load = np.asarray(pf.outputs[self._pf_moe_load]._jax())
             _tm.counter("serving.moe.assignments").inc(int(load.sum()))
+            _tm.counter("serving.moe.zero_assignments").inc(
+                int(load[:, self._zero_experts].sum()))
             _tm.counter("serving.moe.max_expert_assignments").inc(
                 int(load.max(axis=1).sum()))
             # of those, the ones that reached an expert held here: the rows
@@ -2254,6 +2278,9 @@ class PagedKVDecoder:
                         load = exe.outputs[self._dec_moe_load].asnumpy()
                         _tm.counter("serving.moe.step_assignments").inc(
                             int(load.sum()))
+                        _tm.counter(
+                            "serving.moe.step_zero_assignments").inc(
+                                int(load[:, self._zero_experts].sum()))
                         # of those, the ones that reached an expert held
                         # here, and how many of the held received any
                         local = load[:, self._held_experts]

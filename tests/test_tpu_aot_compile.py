@@ -381,6 +381,11 @@ _EXPERT_LAYERS = {
         (8192, 10, 256, 64, 3072, 1024), "kernel", (128, 1024, 3072, 2)),
     ("laguna-s-2.1.generate", "decode"): (
         (32, 10, 256, 64, 3072, 1024), "kernel", (32, 1024, 3072, 3)),
+    # routed over 768 router outputs, 256 of them zero-compute experts
+    ("longcat-flash-omni.generate", "prefill"): (
+        (4096, 12, 768, 16, 6144, 2048), "kernel", (128, 512, 3072, 3)),
+    ("longcat-flash-omni.generate", "decode"): (
+        (32, 12, 768, 16, 6144, 2048), "kernel", (32, 512, 3072, 3)),
 }
 
 
@@ -425,6 +430,9 @@ _ATTENTION_LAYERS = {
     # its full layers: a group of 6, the first that is no power of two, so a
     # step's rows are 6 x 128 = 768 where every other cell folds to 1,024
     "laguna-s-2.1.generate": ((48, 8, 8192, 128, 128), "kernel", (128, 1024)),
+    # both sublayers' materialised latent attention, a key/value head a head
+    "longcat-flash-omni.generate": ((64, 64, 4096, 192, 128), "kernel",
+                                    (1024, 1024)),
 }
 
 
@@ -1996,3 +2004,114 @@ def test_the_admission_scatter_cuts_nothing_inside_its_page_walk(v5e):
     moved, handed = scatter(looped, 4, 16, 320, 128)
     assert handed == 8 * 4 * 128 * 2048 * 2
     assert moved < 5 * handed          # 4.4 x at a bucket of eight pages
+
+
+_LONGCAT = dict(arch="longcat_flash", vocab_size=16384, num_layers=4,
+                num_heads=64, model_dim=6144, ffn_dim=12288, moe_ffn_dim=2048,
+                num_experts=512, num_zero_experts=256, num_local_experts=16,
+                local_expert_offset=0, num_experts_per_tok=12,
+                q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+                qk_rope_head_dim=64, v_head_dim=128, rope_theta=1e7,
+                rms_eps=1e-5, routed_scaling_factor=6.0, dtype="bfloat16")
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode", "admit_scatter"])
+def test_longcat_flash_serving_programs_compile_for_the_chip(v5e, program):
+    """The three programs ``PagedKVDecoder(arch="longcat_flash")`` runs,
+    lowered for the v5e at LongCat-Flash-Omni's published widths, the
+    benchmark's cut (layers 0-3, 16 of 512 experts held beside 256
+    zero-compute ones, 16,384 rows of the vocabulary: 5,172,749,312
+    parameters in bfloat16) and its serving sizes (32 lanes x 5,120 slots, a
+    4,096 bucket). What has to hold on the chip: the cache is EIGHT
+    head-major latent pools (1, 163,840, 576), two a layer, each updated in
+    place; an admission's eight materialised attentions are one call of the
+    blockwise kernel each (no float32 buffer of 64 x 4,096 x 4,096); both
+    graphs keep the grouped matmul over the 16 held experts, an admission
+    over a CHUNK of the held rows and nothing of 49,152 rows by 6,144, and
+    report the load of all 768 router outputs last; and arguments, outputs
+    and temporaries fit the chip's 16 GB beside each other."""
+    from types import SimpleNamespace
+
+    from mxnet_tpu.models import transformer as tf
+    from mxnet_tpu.ops.attention import pool_shape
+    from mxnet_tpu.serving.kv_decode import _AdmitScatter
+
+    lanes, max_len, bucket, page = 32, 5120, 4096, 16
+    slots, cfg = lanes * max_len, _LONGCAT
+    shapes = tf.param_shapes(**cfg)
+    assert sum(math.prod(s) for s in shapes.values()) == 5_172_749_312
+    weights = {n: (s, "bfloat16") for n, s in shapes.items()}
+    cache = tf.decode_cache(**cfg)
+    assert [(n, k) for n, k, _ in cache] == [("kv_c_%d" % j, "pool")
+                                             for j in range(8)]
+    buffers = [(pool_shape(*shape, slots, page), "bfloat16")
+               for _, _, shape in cache]
+    assert buffers[0] == ((1, slots, 576), "bfloat16")
+    cache_bytes = sum(2 * math.prod(shape) for shape, _ in buffers)
+    assert cache_bytes == 8 * slots * 576 * 2 == 1_509_949_440
+    exported = [((1, 1, bucket, 576), "bfloat16")] * 8
+    if program == "admit_scatter":
+        prog = _AdmitScatter(SimpleNamespace(
+            _cache=cache, page_size=page, prefill_len=bucket))
+        spec = lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, jnp.dtype(dtype), sharding=v5e)
+        compiled = prog._fn.lower(
+            tuple(spec(*b) for b in buffers),
+            tuple(spec(*n) for n in exported),
+            spec((bucket // page,), "int32"), spec((2,), "int32"),
+        ).compile()
+        mem = compiled.memory_analysis()
+        # every pool is updated in place, the second of a layer as the first
+        assert mem.alias_size_in_bytes == cache_bytes
+        assert mem.temp_size_in_bytes < 64 << 20
+        return
+    if program == "prefill":
+        sym = tf.get_prefill_symbol(prefill_len=bucket, **cfg)
+        inputs = {"data": ((1, bucket), "float32"),
+                  "length": ((1, 1), "float32")}
+        want = [((1, 16384), "float32")] + exported \
+            + [((4, 768), "float32")]
+    else:
+        sym = tf.get_decode_symbol(max_len=slots, page_size=page, **cfg)
+        inputs = {"data": ((lanes, 1), "float32"),
+                  "pos_idx": ((lanes, 1), "float32"),
+                  "write_slot": ((lanes, 1), "float32"),
+                  "page_table": ((lanes, max_len // page), "float32")}
+        inputs.update({name: b for (name, _, _), b in zip(cache, buffers)})
+        want = [((lanes, 16384), "float32")] + buffers \
+            + [((lanes,), "float32"), ((4, 768), "float32")]
+    compiled = _compile_program(
+        v5e, sym, {**weights, **inputs},
+        donated=[name for name, _, _ in cache] if program == "decode" else ())
+    assert [(s.shape, str(s.dtype)) for s in compiled.out_info[0]] == want
+    hlo = compiled.as_text()
+    assert _assert_expert_layers(
+        hlo, 4, bucket if program == "prefill" else lanes, 12, 16, 6144,
+        2048, routed=768) == "kernel"
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    if program == "prefill":
+        calls = _flash_attention_calls(hlo)
+        assert len(calls) == 8
+        for j in range(8):
+            assert sum("layer%d_att/" % j in line for line in calls) == 1
+        _assert_attention_is_blockwise(hlo, 8, bucket)
+        # a loop an expert layer: the turns past the first over the held
+        # rows' chunks
+        assert hlo.count(" while(") == 4
+        _assert_one_row_of_logits(compiled, bucket, 16384)
+        # weights 10.35 GB, the bucket's temporaries 1.36; with the decoder's
+        # pools beside them 13.3 of 16 GB
+        assert mem.argument_size_in_bytes < 10.4e9
+        assert mem.temp_size_in_bytes < 1.5e9
+        assert held + cache_bytes < 14e9
+        return
+    # a head-major pool's write is the loop over the lanes, one a pool; its
+    # read gathers the lanes' own pages (no kernel takes a row of 576)
+    assert not _paged_read_calls(hlo)
+    assert hlo.count(" while(") == 8
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.argument_size_in_bytes < 11.9e9
+    assert mem.temp_size_in_bytes < 0.6e9
+    assert held < 13e9
